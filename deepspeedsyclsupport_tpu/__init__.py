@@ -13,13 +13,6 @@ Public API parity (reference ``deepspeed/__init__.py``):
   * :mod:`comm`               — ``deepspeed.comm``
 """
 from .version import __version__
-from .utils import jax_compat as _jax_compat
-
-if _jax_compat.enabled_by_env():
-    # DSTPU_JAX_COMPAT=1: graft modern jax spellings (jax.shard_map with
-    # check_vma/axis_names, lax.axis_size, sharding.get_abstract_mesh) onto
-    # an older jax (utils/jax_compat.py). Opt-in — see the module docstring.
-    _jax_compat.install()
 from .accelerator import get_accelerator, set_accelerator
 from .comm import init_distributed
 from .comm.topology import MeshTopology, build_topology, get_world_topology

@@ -69,12 +69,9 @@ class CollectiveExpectation:
         return self.param_gather_bytes + self.grad_sync_bytes
 
 
-def _leaf_entries(tree: Any, shardings: Any = None,
-                  itemsize: int = None) -> List[Tuple[int, bool]]:
-    """[(full_bytes, fsdp_sharded)] per array leaf of ``tree``.
-    ``itemsize`` overrides each leaf's dtype width — ``itemsize=1`` yields
-    the int8-transport byte signature of every leaf (the quantized
-    collectives' payload size, ``comm/quantized.py``)."""
+def _leaf_entries(tree: Any, shardings: Any = None
+                  ) -> List[Tuple[int, bool, Tuple[int, ...]]]:
+    """[(full_bytes, fsdp_sharded, shape)] per array leaf of ``tree``."""
     import jax
     from jax.sharding import NamedSharding
 
@@ -88,12 +85,12 @@ def _leaf_entries(tree: Any, shardings: Any = None,
         if not shape:
             continue  # scalars sync in the scalar class
         dt = np.dtype(getattr(leaf, "dtype", np.float32))
-        nbytes = int(math.prod(shape)) * (itemsize or dt.itemsize)
+        nbytes = int(math.prod(shape)) * dt.itemsize
         s = s if s is not None else getattr(leaf, "sharding", None)
         spec = getattr(s, "spec", None) or ()
         axes = {a for e in spec for a in
                 ((e,) if not isinstance(e, tuple) else e) if a}
-        out.append((nbytes, "fsdp" in axes))
+        out.append((nbytes, "fsdp" in axes, tuple(shape)))
     return out
 
 
@@ -116,7 +113,7 @@ def expected_train_collectives(params: Any, topo: Any, stage: int,
     entries = _leaf_entries(params, param_shardings)
     grad_entries = (_leaf_entries(params, grad_shardings)
                     if grad_shardings is not None else entries)
-    sharded = [(b, s) for b, s in entries if s] if stage >= 3 else []
+    sharded = [e for e in entries if e[1]] if stage >= 3 else []
     axes = topo.axis_sizes
     group = axes.get("data", 1) * axes.get("fsdp", 1)
     # a group of 1 moves no bytes: XLA emits no collective for a
@@ -128,9 +125,9 @@ def expected_train_collectives(params: Any, topo: Any, stage: int,
         grad_entries = []
     return CollectiveExpectation(
         param_gather_count=len(sharded) * gathers_per_param,
-        param_gather_bytes=sum(b for b, _ in sharded) * gathers_per_param,
+        param_gather_bytes=sum(e[0] for e in sharded) * gathers_per_param,
         grad_sync_count=len(grad_entries),
-        grad_sync_bytes=sum(b for b, _ in grad_entries),
+        grad_sync_bytes=sum(e[0] for e in grad_entries),
         group_size=group,
         notes={"stage": stage, "gathers_per_param": gathers_per_param,
                "n_param_leaves": len(entries),
@@ -147,7 +144,9 @@ class CollectiveClasses:
     other: List[Dict[str, Any]] = field(default_factory=list)
 
     def bytes_of(self, cls: str) -> int:
-        return sum(e["bytes"] for e in getattr(self, cls))
+        """Bytes the class moves in ONE run of the module."""
+        return sum(e["bytes"] * e.get("executions", 1)
+                   for e in getattr(self, cls))
 
     def counts(self) -> Dict[str, int]:
         return {c: len(getattr(self, c)) for c in
@@ -162,51 +161,69 @@ class CollectiveClasses:
 GRAD_SYNC_OPS = ("all-reduce", "reduce-scatter")
 
 
+def _parts(rec: Dict[str, Any]) -> List[Dict[str, int]]:
+    """Array payloads of one census record (several for a combined op). A
+    hand-written record without ``parts`` is one fp32 array."""
+    return rec.get("parts") or [{"bytes": rec["bytes"],
+                                 "elems": rec["bytes"] // 4}]
+
+
+def _leaf_elems(tree: Any, shardings: Any = None
+                ) -> Tuple[set, set]:
+    """(element counts a collective over ANY param leaf can move, the same
+    for fsdp-sharded leaves only). Counted in elements, not bytes, because
+    the transport dtype is the compiler's business: a TPU step casts the
+    fp32 master shards to bf16 BEFORE gathering them and sums bf16 grads,
+    and the quantized collectives move int8. A leaf contributes its full
+    size and — for a stacked ``[L, ...]`` leaf of a scanned trunk, whose
+    collectives run per layer inside the loop — the size of one slice along
+    its leading dim."""
+    every, sharded = set(), set()
+    for _, is_sharded, shape in _leaf_entries(tree, shardings):
+        sizes = {math.prod(shape)}
+        if len(shape) >= 2:
+            sizes.add(math.prod(shape[1:]))
+        every |= sizes
+        if is_sharded:
+            sharded |= sizes
+    return every, sharded
+
+
 def classify_collectives(census: Sequence[Dict[str, Any]],
                          params: Any,
                          param_shardings: Any = None,
                          ) -> CollectiveClasses:
-    """Attribute each observed collective to a traffic class by byte-matching
-    against the param tree:
+    """Attribute each observed collective to a traffic class by matching
+    the ELEMENT COUNT of what it moves against the param tree
+    (:func:`_leaf_elems` — full leaves and per-layer slices, any dtype):
 
-    * ``param_gather`` — an all-gather whose payload equals a sharded
-      param's full bytes;
-    * ``grad_sync`` — an all-reduce/reduce-scatter whose payload equals any
-      param leaf's full bytes (grads are param-shaped);
+    * ``param_gather`` — an all-gather of a sharded param;
+    * ``grad_sync`` — an all-reduce/reduce-scatter of a param-shaped array
+      (grads are param-shaped), or the all-to-all of a ONE-byte-per-element
+      transport (ZeRO++ qgZ int8 quant-reduce, ``comm/quantized.py``);
     * ``scalar_sync`` — payload ≤ ``SCALAR_BYTES`` (loss/overflow/norm);
-    * ``other`` — everything else: quantization scale sidecars, exotic
-      grad-sync lowerings and genuine resharding traffic. A canonical
+    * ``other`` — everything else: quantization scale sidecars, padded or
+      exotic grad-sync lowerings and genuine resharding traffic. A canonical
       layout leaves this class empty; growth here is the resharding signal.
 
-    Quantized transports (ZeRO++ qwZ int8 all-gather / qgZ int8
-    all-to-all quant-reduce, ``comm/quantized.py``) are recognized by the
-    ONE-byte-per-element signature: an all-gather moving exactly a sharded
-    param's element count is that param's quantized gather, an
-    all-reduce/reduce-scatter/all-to-all moving a grad leaf's element
-    count is its quantized sync. The fp32 block scales ride separate small
-    collectives and land in ``other``/``scalar_sync`` — honest: they are
-    overhead the quantization pays, not param/grad payload. (A same-dtype
-    leaf whose byte size collides with another leaf's element count is
-    caught by the full-dtype clauses first.)
+    A COMBINED op (XLA's combiner merges several leaves' syncs into one
+    tuple-result collective — jax 0.9 lowers the whole ZeRO-3 grad sync
+    that way) belongs to a class when EVERY element of its result tuple
+    does. Class totals are bytes per run of the module (an op in a layer
+    scan's body counts once per trip), never op counts.
     """
-    entries = _leaf_entries(params, param_shardings)
-    param_sizes = {b for b, _ in entries}
-    sharded_sizes = {b for b, s in entries if s}
-    q_entries = _leaf_entries(params, param_shardings, itemsize=1)
-    q_param_sizes = {b for b, _ in q_entries}
-    q_sharded_sizes = {b for b, s in q_entries if s}
+    every, sharded = _leaf_elems(params, param_shardings)
     out = CollectiveClasses()
     for rec in census:
+        elems = [p["elems"] for p in _parts(rec)]
         if rec["bytes"] <= SCALAR_BYTES:
             out.scalar_sync.append(rec)
-        elif rec["op"] == "all-gather" and rec["bytes"] in sharded_sizes:
+        elif rec["op"] == "all-gather" and all(e in sharded for e in elems):
             out.param_gather.append(rec)
-        elif rec["op"] == "all-gather" and rec["bytes"] in q_sharded_sizes:
-            out.param_gather.append(rec)
-        elif rec["op"] in GRAD_SYNC_OPS and rec["bytes"] in param_sizes:
+        elif rec["op"] in GRAD_SYNC_OPS and all(e in every for e in elems):
             out.grad_sync.append(rec)
-        elif rec["op"] in GRAD_SYNC_OPS + ("all-to-all",) \
-                and rec["bytes"] in q_param_sizes:
+        elif rec["op"] == "all-to-all" and rec["bytes"] == sum(elems) \
+                and all(e in every for e in elems):
             out.grad_sync.append(rec)
         else:
             out.other.append(rec)
@@ -258,9 +275,10 @@ def check_collectives(census: Sequence[Dict[str, Any]],
     problems: List[str] = []
     pg_bytes, gs_bytes = classes.bytes_of("param_gather"), classes.bytes_of("grad_sync")
     if exact:
-        if len(classes.param_gather) != expectation.param_gather_count:
+        n_gathered = sum(len(_parts(r)) for r in classes.param_gather)
+        if n_gathered != expectation.param_gather_count:
             problems.append(
-                f"param_gather count {len(classes.param_gather)} != expected "
+                f"param_gather payloads {n_gathered} != expected "
                 f"{expectation.param_gather_count}")
         if pg_bytes != expectation.param_gather_bytes:
             problems.append(f"param_gather bytes {pg_bytes} != expected "

@@ -124,9 +124,9 @@ def calibrate_cpu_spec(force: bool = False) -> DeviceSpec:
 def device_spec(device: Any = None,
                 calibrate_cpu: bool = True) -> DeviceSpec:
     """Spec for a jax device (default: ``jax.devices()[0]``), matched on
-    ``device_kind``/platform. Unknown TPU generations fall back to the
-    newest known entry (with its name kept honest); CPU returns the
-    calibrated CPU-sim entry."""
+    ``device_kind``/platform. A TPU generation that is not in
+    ``DEVICE_SPECS`` is an error, never another chip's peaks; CPU returns
+    the calibrated CPU-sim entry."""
     import jax
 
     device = device if device is not None else jax.devices()[0]
@@ -138,12 +138,9 @@ def device_spec(device: Any = None,
                      ("v5", "tpu-v5e"), ("v4", "tpu-v4")):
         if tag in kind:
             return DEVICE_SPECS[key]
-    # unknown generation: borrow the newest known peaks but SAY SO in the
-    # spec name — every ledger/artifact then carries the guess visibly
-    # instead of silently claiming the chip is a v6e
-    base = DEVICE_SPECS["tpu-v6e"]
-    return DeviceSpec(f"tpu-unknown({kind or '?'})~tpu-v6e",
-                      base.peak_flops, base.hbm_gbps, base.ici_gbps)
+    raise KeyError(
+        f"no peak spec for TPU device_kind {kind!r}: add its published "
+        f"peaks (with their source) to analysis/roofline.py DEVICE_SPECS")
 
 
 # ----------------------------------------------------------- region costing

@@ -80,7 +80,7 @@ class InferenceEngineV2:
                                         bits=cfg.quant_bits))(
                 self.params["layers"])
 
-        self.kv = init_blocked_kv(model.config, cfg)
+        self.kv = init_blocked_kv(model.config, cfg, self.topology)
         self.allocator = BlockedAllocator(cfg.num_blocks)
         self.seqs: Dict[int, SequenceDescriptor] = {}
         # SLA layer (serving.ServingSession) installs a scheduler.SlackPolicy
@@ -102,6 +102,9 @@ class InferenceEngineV2:
 
         self._decode_multi: "OrderedDict[Any, Any]" = OrderedDict()
         self._decode_multi_cap = 16
+        # name -> (jitted fn, abstract args) of every forward program this
+        # engine has dispatched (compiled_programs)
+        self._dispatched: Dict[str, Tuple[Any, Any]] = {}
         self.host_dispatches = 0  # host-scheduled device dispatches (bench)
         self._rng = jax.random.PRNGKey(cfg.seed)
         # only the sampling STRUCTURE is static; temperature/top_p are
@@ -192,6 +195,26 @@ class InferenceEngineV2:
         eng_cfg.update(config_overrides)
         return cls(model, state["params"], config=eng_cfg,
                    topology=topology)
+
+    # ---------------------------------------------------- compiled programs
+    def _dispatch(self, name: str, fn, *args):
+        """Call forward program ``fn``, remembering the abstract arguments
+        of its first dispatch (taken BEFORE the call: the pool is donated)."""
+        if name not in self._dispatched:
+            from ...analysis.capture import abstract_step_args
+
+            self._dispatched[name] = (fn, abstract_step_args(args))
+        self.host_dispatches += 1
+        return fn(*args)
+
+    def compiled_programs(self) -> Dict[str, Any]:
+        """``{name: jax.stages.Compiled}`` for every forward program this
+        engine has actually run (``ragged_forward``, ``decode_forward``,
+        ``decode_multi_<K>``), re-lowered at the arguments it ran with — so a
+        caller can check WHAT ran (``.as_text()``: is the attention a
+        ``tpu_custom_call``?) and what it needs (``.memory_analysis()``)."""
+        return {name: fn.lower(*avals).compile()
+                for name, (fn, avals) in self._dispatched.items()}
 
     # --------------------------------------------------------------- warmup
     def warmup(self, fused_ladder: bool = False) -> None:
@@ -620,12 +643,12 @@ class InferenceEngineV2:
                          jnp.asarray(batch.atom_qlen),
                          jnp.asarray(batch.atom_tables),
                          jnp.asarray(batch.atom_inv))
-        logits, self.kv = self._forward(
+        logits, self.kv = self._dispatch(
+            "ragged_forward", self._forward,
             self.params, self.kv, jnp.asarray(batch.tokens),
             jnp.asarray(batch.token_seq), jnp.asarray(batch.token_pos),
             jnp.asarray(batch.block_tables), jnp.asarray(batch.last_tok_idx),
             *atom_args)
-        self.host_dispatches += 1
         # DEVICE-resident: per-slot rows are sliced on device and only
         # fetched when a caller materializes them (query()/np.asarray) —
         # generate()'s sampler consumes them without a host round trip
@@ -660,10 +683,10 @@ class InferenceEngineV2:
         tokens = np.zeros((cfg.max_sequences,), np.int32)
         for slot, (d, _n) in enumerate(chunks):
             tokens[slot] = d.pending[0]
-        logits, self.kv = self._decode_forward(
+        logits, self.kv = self._dispatch(
+            "decode_forward", self._decode_forward,
             self.params, self.kv, jnp.asarray(tokens), jnp.asarray(positions),
             jnp.asarray(tables), jnp.asarray(active))
-        self.host_dispatches += 1
         # DEVICE-resident: per-slot rows are sliced on device and only
         # fetched when a caller materializes them (query()/np.asarray) —
         # generate()'s sampler consumes them without a host round trip
@@ -783,13 +806,13 @@ class InferenceEngineV2:
         logits0 = jnp.zeros((s_max, stacked.shape[-1]),
                             jnp.float32).at[:n].set(stacked)
 
-        toks_d, logits_f, pos_f, act_f, sl_f, self.kv = fn(
+        toks_d, logits_f, pos_f, act_f, sl_f, self.kv = self._dispatch(
+            f"decode_multi_{k}", fn,
             self.params, self.kv, logits0, jnp.asarray(positions),
             jnp.asarray(tables), jnp.asarray(active),
             jnp.asarray(steps_left), rng,
             jnp.float32(sp.temperature), jnp.float32(sp.top_p),
             jnp.int32(-1 if eos_token_id is None else eos_token_id))
-        self.host_dispatches += 1
         self._tick += k
         # ONE host transfer for the K×S token block + the small state rows
         toks = np.asarray(toks_d)

@@ -33,21 +33,28 @@ def lane_padded_head_dim(head_dim: int, pad) -> int:
     attention seam (q pre-scaled by sqrt(d_pad/d) to compensate the impls'
     1/sqrt(trailing-dim) softmax scale) and the output sliced back, which
     leaves scores mathematically identical. ``pad`` None/0 = auto (128 on
-    TPU, none
-    elsewhere). HBM note: a d=64 model pays 2x KV pool for kernel decode."""
-    import jax
-
+    TPU, none elsewhere). HBM note: a d=64 model pays 2x KV pool for kernel decode."""
     if pad in (None, 0):
         pad = 128 if jax.default_backend() == "tpu" else 1
     return -(-head_dim // pad) * pad
 
 
-def init_blocked_kv(model_config, cfg: RaggedInferenceConfig) -> BlockedKV:
+def init_blocked_kv(model_config, cfg: RaggedInferenceConfig,
+                    topology) -> BlockedKV:
+    """Zeroed pool, allocated under ``jit`` straight into its placement on
+    the engine's mesh: KV heads over ``model`` where they divide (the layout
+    the TP-sharded wk/wv projections produce), replicated otherwise — never
+    whole on the default device first."""
     d = lane_padded_head_dim(model_config.head_dim,
                              getattr(cfg, "head_dim_lane_pad", None))
-    shape = (model_config.num_layers, cfg.num_blocks * cfg.block_size,
-             model_config.num_kv_heads, d)
-    return BlockedKV(jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
+    kvh = model_config.num_kv_heads
+    shape = (model_config.num_layers, cfg.num_blocks * cfg.block_size, kvh, d)
+    tp = topology.axis_sizes["model"]
+    sharding = (topology.sharding(None, None, "model", None)
+                if tp > 1 and kvh % tp == 0 else topology.replicated())
+    zeros = jax.jit(lambda: jnp.zeros(shape, cfg.dtype),
+                    out_shardings=sharding)
+    return BlockedKV(zeros(), zeros())
 
 
 def kv_pool_stats(kv: BlockedKV, allocator) -> dict:

@@ -38,11 +38,7 @@ def constrain(x: jnp.ndarray, *spec) -> jnp.ndarray:
     # inside a shard_map manual region (ZeRO++ explicit step, pipeline ring)
     # a constraint naming manual axes is rejected at lowering — and the data
     # is already placed per-shard there, so the constraint is meaningless.
-    # get_abstract_mesh is a modern spelling (shimmed by utils/jax_compat);
-    # without it — old jax, shims off — there is no manual-region tracking
-    # to consult, so fall through to the constraint attempt.
-    _gam = getattr(jax.sharding, "get_abstract_mesh", None)
-    manual = set(getattr(_gam(), "manual_axes", ()) or ()) if _gam else set()
+    manual = _manual_axes()
     if manual:
         used = {a for s in spec
                 for a in (s if isinstance(s, (tuple, list)) else (s,)) if a}
@@ -52,6 +48,11 @@ def constrain(x: jnp.ndarray, *spec) -> jnp.ndarray:
         return jax.lax.with_sharding_constraint(x, topo.sharding(*spec))
     except (ValueError, TypeError):
         return x
+
+
+def _manual_axes() -> set:
+    """Mesh axes the enclosing ``shard_map`` (if any) made manual."""
+    return set(jax.sharding.get_abstract_mesh().manual_axes)
 
 
 BATCH = ("data", "fsdp")  # input batch dim is split over both DP-ish axes
@@ -249,6 +250,53 @@ def _cached_flash_attention(q, k, v, causal, kv_positions_below, kv_mask,
                            interpret=interpret)
 
 
+def _flash_over_mesh(q, k, v, *, causal, segment_ids, alibi, window):
+    """The flash kernel under the world mesh. XLA cannot partition a Mosaic
+    kernel ("wrap the call in a shard_map" — raised at lowering on a
+    multi-chip TPU, where it used to be caught and turned into the XLA
+    reference), so on a multi-device topology the call runs per shard:
+    batch over (data, fsdp), heads over model — attention is independent
+    across both, so no collective is needed. Inside an enclosing manual
+    region (ZeRO++ explicit step, pipeline ring) the data is per-shard
+    already and the kernel is called as is."""
+    from jax.sharding import PartitionSpec as P
+
+    from ..comm import topology as topo_mod
+    from ..ops.flash_attention import flash_attention
+
+    call = partial(flash_attention, causal=causal, window=window)
+    topo = topo_mod._WORLD_TOPOLOGY
+    if topo is None or topo.world_size() == 1 or _manual_axes():
+        return call(q, k, v, segment_ids=segment_ids, alibi=alibi)
+    sizes = topo.axis_sizes
+    if sizes["seq"] > 1:
+        raise NotImplementedError(
+            "attn_impl='flash' on a sequence-parallel mesh: use "
+            "'ring:flash' or 'ulysses:flash'")
+    batch = tuple(a for a in BATCH if sizes[a] > 1) or None
+    heads = "model" if sizes["model"] > 1 else None
+    n_batch = int(np.prod([sizes[a] for a in batch or ()]))
+    if q.shape[0] % n_batch or q.shape[2] % sizes["model"] \
+            or k.shape[2] % sizes["model"]:
+        raise ValueError(
+            f"flash attention over mesh {sizes}: batch {q.shape[0]} must "
+            f"divide by {n_batch} and heads {q.shape[2]}/{k.shape[2]} by "
+            f"{sizes['model']}")
+    qkv = P(batch, None, heads, None)
+    extra = [(name, val, spec) for name, val, spec in (
+        ("segment_ids", segment_ids, P(batch, None)),
+        ("alibi", alibi, P(heads))) if val is not None]
+
+    def per_shard(q, k, v, *vals):
+        return call(q, k, v, **{n: x for (n, _, _), x in zip(extra, vals)})
+
+    return jax.shard_map(
+        per_shard, mesh=topo.mesh,
+        in_specs=(qkv, qkv, qkv) + tuple(spec for _, _, spec in extra),
+        out_specs=qkv, check_vma=False)(
+        q, k, v, *(jnp.asarray(val) for _, val, _ in extra))
+
+
 def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
               impl: str = "auto",
               causal: bool = True,
@@ -307,14 +355,9 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                                            kv_positions=kv_positions)
         impl = "xla"
     if impl == "flash":
-        from ..ops.flash_attention import flash_attention
-
-        try:
-            return flash_attention(q, k, v, causal=causal,
-                                   segment_ids=segment_ids, alibi=alibi,
-                                   window=window)
-        except NotImplementedError:
-            impl = "xla"
+        return _flash_over_mesh(q, k, v, causal=causal,
+                                segment_ids=segment_ids, alibi=alibi,
+                                window=window)
     if impl in ("ring", "ulysses") and (alibi is not None
                                         or window is not None):
         # silently materializing O(S²) logits would defeat the point of SP
@@ -330,6 +373,9 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
         return ulysses_attention(q, k, v, causal=causal,
                                  segment_ids=segment_ids, inner=inner)
+    if impl != "xla":
+        raise ValueError(f"unknown attn_impl {impl!r} "
+                         f"(auto | xla | flash | ring | ulysses)")
     return reference_attention(q, k, v, causal=causal, segment_ids=segment_ids,
                                kv_positions_below=kv_positions_below,
                                kv_mask=kv_mask, alibi=alibi, window=window,

@@ -36,11 +36,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# modern spelling with a version-tolerant fallback (jax<=0.4.x names the
-# same dataclass TPUCompilerParams) — without it every kernel call dies on
-# an AttributeError before reaching the TPU/interpret path at all
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
+_CompilerParams = pltpu.CompilerParams
 
 NEG_INF = -1e30
 _LANES = 128

@@ -33,6 +33,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# Mosaic's default scoped-VMEM limit; the ragged kernel states its own need
+# above it (see _ragged_vmem_limit) instead of shrinking the atom
+_DEFAULT_SCOPED_VMEM = 16 << 20
+# stay under the 128 MiB of physical VMEM a v4/v5e/v6e core has
+_VMEM_CAP = 100 << 20
 
 
 # --------------------------------------------------------------------- kernel
@@ -87,6 +92,9 @@ def paged_decode_attention(q, k_cache, v_cache, block_tables, seq_lens, *,
             q, k_cache, v_cache, block_tables, seq_lens,
             block_size=block_size, alibi=alibi, window=window,
             interpret=True)
+    if impl != "xla":
+        raise ValueError(f"unknown paged decode attention impl {impl!r} "
+                         f"(auto | pallas | pallas_interpret | xla)")
     return paged_decode_attention_reference(
         q, k_cache, v_cache, block_tables, seq_lens, block_size=block_size,
         alibi=alibi, window=window)
@@ -211,6 +219,32 @@ def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref,  # scalar prefetch
     out_ref[0] = out.reshape(bq, h, d).astype(out_ref.dtype)
 
 
+def _ragged_vmem_limit(bq: int, h: int, kvh: int, d: int, block_size: int,
+                       itemsize: int) -> int:
+    """Scoped-VMEM limit stated to the compiler for one grid step of
+    :func:`_prefill_kernel`. The q/out tiles are double-buffered by the
+    pipeline and the body keeps fp32 copies of q, the accumulator and its
+    update, so a 128-row atom at 32 heads x d 128 needs 21-22 MiB (bisected
+    against the v5e compiler) — over Mosaic's 16 MiB default, which refused
+    the kernel at every real width. The shape model below came within
+    0.8-1.06x of the bisected need across atoms 64-256 and KVH 4-32; the
+    limit is only a ceiling, so twice the model is stated."""
+    q_tile = bq * h * d
+    kv_tile = block_size * kvh * d
+    scores = bq * h * block_size
+    need = (4 * q_tile * itemsize        # q + out tiles, double-buffered
+            + 5 * q_tile * 4             # fp32 q, q_g, acc, acc_new, pv
+            + 4 * kv_tile * itemsize     # k/v scratch, two slots each
+            + 4 * kv_tile * 4            # fp32 k, v and their transposes
+            + 6 * scores * 4)            # scores, pos, valid, p, exp temps
+    if need > _VMEM_CAP:
+        raise ValueError(
+            f"ragged prefill atom of {bq} rows x {h} heads x d {d} needs "
+            f"~{need >> 20} MiB of VMEM (cap {_VMEM_CAP >> 20} MiB); lower "
+            f"RaggedInferenceConfig.atom_q_size")
+    return min(max(2 * need, _DEFAULT_SCOPED_VMEM), _VMEM_CAP)
+
+
 def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
                                     atom_pos0, atom_qlen, *,
                                     block_size: int, alibi=None, window=None,
@@ -258,6 +292,9 @@ def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
         kernel,
         out_shape=jax.ShapeDtypeStruct((a, bq, h, d), q_atoms.dtype),
         grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_ragged_vmem_limit(
+                bq, h, kvh, d, block_size, q_atoms.dtype.itemsize)),
         interpret=interpret,
     )(jnp.asarray(atom_tables, jnp.int32), jnp.asarray(atom_pos0, jnp.int32),
       jnp.asarray(atom_qlen, jnp.int32), q_atoms, k_cache, v_cache, ab)
@@ -314,6 +351,9 @@ def ragged_prefill_attention(q_atoms, k_cache, v_cache, atom_tables,
             q_atoms, k_cache, v_cache, atom_tables, atom_pos0, atom_qlen,
             block_size=block_size, alibi=alibi, window=window,
             interpret=True)
+    if impl != "xla":
+        raise ValueError(f"unknown ragged prefill attention impl {impl!r} "
+                         f"(auto | pallas | pallas_interpret | xla)")
     return ragged_prefill_attention_reference(
         q_atoms, k_cache, v_cache, atom_tables, atom_pos0, atom_qlen,
         block_size=block_size, alibi=alibi, window=window)

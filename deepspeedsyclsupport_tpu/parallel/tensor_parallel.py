@@ -95,9 +95,7 @@ def vocab_parallel_embedding(table, input_ids):
     # ZeRO++ explicit step is manual over {data, fsdp} with 'model' auto, so
     # probing lax.axis_size('model') alone would miss it and this would
     # nest a shard_map over already-manual axes (trace error)
-    in_manual_region = bool(
-        set(getattr(jax.sharding.get_abstract_mesh(), "manual_axes",
-                    ()) or ()))
+    in_manual_region = bool(jax.sharding.get_abstract_mesh().manual_axes)
     sizes = topo.axis_sizes if topo is not None else {}
     bdiv = sizes.get("data", 1) * sizes.get("fsdp", 1)
     divisible = (topo is not None

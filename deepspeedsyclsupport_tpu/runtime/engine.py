@@ -79,6 +79,8 @@ def initialize(model: Any = None,
     ``model``: anything exposing ``loss(params, batch, rng) -> loss | (loss, aux)``
     (our ``models/`` follow this protocol) — or pass ``loss_fn`` directly.
     ``params``: the initial parameter pytree (host arrays fine; engine places them).
+    Left out, the engine initialises ``model.init_params`` under ``jit`` straight
+    into its shards — the fp32 tree never lands whole on the default device.
     """
     from ..comm import init_distributed
 
@@ -93,7 +95,7 @@ def initialize(model: Any = None,
         loss_fn = model.loss
     if params is None:
         if model is not None and hasattr(model, "init_params"):
-            params = model.init_params()
+            params = model.init_params  # materialised sharded by the engine
         else:
             raise ValueError("provide params, or a model with init_params()")
     if sharding_rules is None and model is not None:
@@ -218,6 +220,12 @@ class Engine:
         # ---------------------------------------------------------- placement
         stage = self.config.zero.stage
         self.zero_stage = stage
+        # ``initialize`` hands over the model's init FUNCTION when the caller
+        # gave no params: shardings come from its abstract shapes and the
+        # values are generated under jit with those out_shardings below
+        init_fn = params if callable(params) else None
+        if init_fn is not None:
+            params = jax.eval_shape(init_fn)
         self.param_shardings = zero_lib.tree_param_shardings(
             params, self.topology, stage, extra_rules=sharding_rules)
         # Stage >= 2: gradients (and the fp32 grad accumulator the scan
@@ -278,12 +286,17 @@ class Engine:
                                    else "cpu")
         self._swapper = None
         if self.offload_device is not None:
-            self._init_offload(params, tx, off_opt, off_par)
+            self._init_offload(init_fn() if init_fn is not None else params,
+                               tx, off_opt, off_par)
         else:
             self.master_params = None
-            self.params = jax.tree_util.tree_map(
-                lambda x, s: jax.device_put(jnp.asarray(x), s), params,
-                self.param_shardings)
+            if init_fn is not None:
+                self.params = jax.jit(
+                    init_fn, out_shardings=self.param_shardings)()
+            else:
+                self.params = jax.tree_util.tree_map(
+                    lambda x, s: jax.device_put(jnp.asarray(x), s), params,
+                    self.param_shardings)
             opt_shapes = jax.eval_shape(tx.init, self.params)
             self.opt_shardings = zero_lib.tree_optimizer_shardings(
                 opt_shapes, self.params, self.param_shardings, self.topology,
@@ -1258,9 +1271,7 @@ class Engine:
                 "(config comms_logger.enabled: true)")
         from ..comm.hlo_comms import summarize_compiled
 
-        compiled = self._train_batch_fn.lower(
-            *self._last_train_avals).compile()
-        summary = summarize_compiled(compiled)
+        summary = summarize_compiled(self.compiled_train_step())
         comms_logger.record_hlo(summary, tag="train_step")
         if log:
             comms_logger.log_summary(show_straggler=show_straggler)
@@ -1289,6 +1300,16 @@ class Engine:
         if self.telemetry is not None:
             self.telemetry.record_census(payload)
         return payload
+
+    def compiled_train_step(self):
+        """The fused train step EXACTLY as the last ``train_batch`` ran it
+        (``jax.stages.Compiled``), re-lowered from the avals captured at its
+        call site — ``.as_text()`` shows the kernels and collectives that
+        ran, ``.memory_analysis()`` the bytes per device."""
+        avals = getattr(self, "_last_train_avals", None)
+        if self._train_batch_fn is None or avals is None:
+            raise RuntimeError("run train_batch() first")
+        return self._train_batch_fn.lower(*avals).compile()
 
     GRAPH_ANALYZERS = ("collectives", "donation", "resharding", "dtype")
 
@@ -1325,10 +1346,8 @@ class Engine:
                 "graph_report audits the fused train step; the offload path "
                 "splits the step into a grads fn + host apply — audit those "
                 "directly with the analysis.* functions")
-        avals = getattr(self, "_last_train_avals", None)
-        if self._train_batch_fn is None or avals is None:
-            raise RuntimeError("run train_batch() first")
-        compiled = self._train_batch_fn.lower(*avals).compile()
+        compiled = self.compiled_train_step()
+        avals = self._last_train_avals
         report: Dict[str, Any] = {}
         if "collectives" in analyzers or "resharding" in analyzers:
             report["census"] = collective_census(compiled)
@@ -1432,11 +1451,12 @@ class Engine:
                 "() first (ZeRO++ explicit-shard_map and offload split "
                 "steps are not supported)")
         avals = self._last_train_avals
-        compiled = self._train_batch_fn.lower(*avals).compile()
+        compiled = self.compiled_train_step()
         opmap = mfu_mod.build_opmap(compiled.as_text())
         costs = roofline.region_costs(
             jax.make_jaxpr(self._train_batch_raw)(*avals))
-        census_bytes = sum(e["bytes"] for e in collective_census(compiled))
+        census_bytes = sum(e["bytes"] * e["executions"]
+                           for e in collective_census(compiled))
         spec = spec or roofline.device_spec()
         table = roofline.roofline_table(costs, spec,
                                         census_bytes=census_bytes)

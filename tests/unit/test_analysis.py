@@ -136,14 +136,8 @@ class TestDonationAudit:
         ROADMAP MFU levers) on the real transformer: params + optimizer
         state must donate — an undonated tree is a silent HBM doubling."""
         from deepspeedsyclsupport_tpu.models import build_model, get_config
-        from deepspeedsyclsupport_tpu.utils import jax_compat
 
-        # the transformer stack uses modern jax spellings (see jax_compat)
-        jax_compat.install()
-        try:
-            self._run_bench_shaped_donation(build_model, get_config)
-        finally:
-            jax_compat.uninstall()
+        self._run_bench_shaped_donation(build_model, get_config)
 
     def _run_bench_shaped_donation(self, build_model, get_config):
         cfg = get_config("tiny", remat=True, max_seq_len=64)

@@ -1,0 +1,141 @@
+"""Compile the main path's Pallas kernels for a *described* TPU v5e.
+
+Interpret mode (the rest of the suite) proves numerics, and ``jax.export``
+(``test_tpu_lowering.py``) proves the Pallas→Mosaic lowering — neither runs
+the chip's compiler, which is what refuses a kernel for VMEM it may not use
+or a slice off the tiling. libtpu compiles for a chip that is described and
+not attached, so these cases hold every later PR to "the kernels of the
+train and serve paths compile at real widths" at no chip time.
+
+Only ONE process may load libtpu, so: this is the only file that describes
+a topology, it does so inside a module-scoped fixture (never at import,
+never autouse), and it compiles in the test's own process.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deepspeedsyclsupport_tpu.inference.v2.config import RaggedInferenceConfig
+from deepspeedsyclsupport_tpu.models import get_config
+from deepspeedsyclsupport_tpu.ops.flash_attention import flash_attention
+from deepspeedsyclsupport_tpu.ops.paged_attention import (
+    paged_decode_attention_pallas, ragged_prefill_attention_pallas)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        try:
+            topo = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:  # no libtpu / lock held by another process
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
+def _compile(f, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    return jax.jit(f).lower(*args).compile()
+
+
+def _widths(name):
+    """(heads, kv heads, head dim as the kernel sees it) of a preset."""
+    cfg = get_config(name)
+    return cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+
+
+# ------------------------------------------------------------------- flash
+# training attention: mistral-7b (32/8 heads, d 128, S 4096, window 4096)
+# and phi-2's head_dim 80 (lane-padded to 128 inside the kernel wrapper)
+FLASH_CASES = {
+    "mistral-7b": dict(seq=4096, window=4096),
+    "phi-2": dict(seq=2048, window=None),
+}
+
+
+def _flash_fn(window, grad):
+    f = functools.partial(flash_attention, causal=True, window=window,
+                          interpret=False)
+    if not grad:
+        return f
+    return jax.grad(lambda q, k, v: f(q, k, v).astype(jnp.float32).sum(),
+                    argnums=(0, 1, 2))
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
+@pytest.mark.parametrize("name", sorted(FLASH_CASES))
+def test_flash_compiles(one_chip, name, grad):
+    h, kvh, d = _widths(name)
+    case = FLASH_CASES[name]
+    q = ((1, case["seq"], h, d), jnp.bfloat16)
+    kv = ((1, case["seq"], kvh, d), jnp.bfloat16)
+    compiled = _compile(_flash_fn(case["window"], grad), one_chip, q, kv, kv)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# ------------------------------------------------------------------- paged
+def _paged_shapes(name):
+    """Kernel operands at the engine's DEFAULT geometry for ``name``: the
+    pool's head dim is lane-padded to 128 on TPU (phi-2: 80 -> 128)."""
+    cfg = RaggedInferenceConfig()
+    h, kvh, d = _widths(name)
+    d = -(-d // 128) * 128
+    slots = cfg.num_blocks * cfg.block_size
+    return cfg, h, kvh, d, ((slots, kvh, d), jnp.bfloat16)
+
+
+def _paged_decode(one_chip, name):
+    cfg, h, kvh, d, pool = _paged_shapes(name)
+    s = cfg.max_sequences
+    window = get_config(name).sliding_window
+    f = functools.partial(paged_decode_attention_pallas,
+                          block_size=cfg.block_size, window=window)
+    return _compile(f, one_chip, ((s, h, d), jnp.bfloat16), pool, pool,
+                    ((s, cfg.blocks_per_seq), jnp.int32), ((s,), jnp.int32))
+
+
+@pytest.mark.parametrize("name", ["mistral-7b", "phi-2"])
+def test_paged_decode_compiles(one_chip, name):
+    _paged_decode(one_chip, name)
+
+
+def _ragged_default_atom(one_chip, name):
+    cfg, h, kvh, d, pool = _paged_shapes(name)
+    bq = cfg.atom_q_size   # the DEFAULT atom: no user-picked atom_q_size
+    atoms = cfg.max_tokens_per_batch // bq + cfg.max_sequences
+    window = get_config(name).sliding_window
+    f = functools.partial(ragged_prefill_attention_pallas,
+                          block_size=cfg.block_size, window=window)
+    return _compile(f, one_chip, ((atoms, bq, h, d), jnp.bfloat16), pool,
+                    pool, ((atoms, cfg.blocks_per_seq), jnp.int32),
+                    ((atoms,), jnp.int32), ((atoms,), jnp.int32))
+
+
+@pytest.mark.parametrize("name", ["mistral-7b", "phi-2"])
+def test_ragged_prefill_compiles_with_default_atom(one_chip, name):
+    """Refused before this file existed: a 128-row atom at 32 heads x d 128
+    needs 21-22 MiB of VMEM against Mosaic's 16 MiB default scoped limit."""
+    _ragged_default_atom(one_chip, name)
+
+
+def test_paged_kernel_is_a_tpu_custom_call(one_chip):
+    """The compiled text names the Mosaic kernel — the same string
+    ``chip_smoke.py`` looks for in the programs it ran on the chip."""
+    assert "tpu_custom_call" in _paged_decode(one_chip, "phi-2").as_text()

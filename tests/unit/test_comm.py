@@ -3,11 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from jax import shard_map
-except ImportError:  # jax < 0.5: the experimental spelling (or opt into the
-    # modern surface process-wide with DSTPU_JAX_COMPAT=1 — utils/jax_compat)
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 import deepspeedsyclsupport_tpu.comm as dist

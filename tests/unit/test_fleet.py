@@ -28,24 +28,12 @@ import time
 import jax.numpy as jnp
 import pytest
 
-from deepspeedsyclsupport_tpu.utils import jax_compat
-
-_added = []
-
-
-def setup_module():
-    global _added
-    _added = jax_compat.install()
-
-
 def teardown_module():
     # the engines built here install a world topology; drop it so later
     # modules (alphabetically: test_serving_bench) start mesh-agnostic
     from deepspeedsyclsupport_tpu.comm.topology import reset_world_topology
 
     reset_world_topology()
-    if _added:
-        jax_compat.uninstall()
 
 
 from deepspeedsyclsupport_tpu.inference.v2 import (  # noqa: E402
@@ -653,8 +641,7 @@ def _fleet_spec(root, requests, env=None, n_replicas=3, timeout_s=420):
         # survivors (the headline), not wait out a local restart
         "supervisor_args": ["--restart-limit", "0",
                             "--backoff-seconds", "0.1"],
-        # the model stack needs the modern-jax shims in every worker
-        "env": {"*": {"DSTPU_JAX_COMPAT": "1"}, **(env or {})},
+        "env": dict(env or {}),
         "router": {"affinity": "none", "dead_after_s": 1.5},
         "requests": requests,
         "out": os.path.join(root, "out.json"),
@@ -740,7 +727,6 @@ class TestFleetChaosE2E:
                                        "max_sequences": 4}},
                            supervisor_args=["--restart-limit", "1",
                                             "--backoff-seconds", "0.1"],
-                           env={"DSTPU_JAX_COMPAT": "1"},
                            dead_after_s=3.0)
             for i in range(2)]
         pool = ReplicaPool(replicas)

@@ -50,6 +50,47 @@ class TestHloParser:
         assert s["reduce-scatter"]["count"] == 1
 
 
+class TestTpuTextAndLoops:
+    """What the chip's compiler writes and the CPU's does not: layouts with
+    parentheses of their own inside tuples, combined (tuple-result) ops, and
+    a layer scan whose per-layer collectives sit once in the loop body."""
+    HLO = """
+%cond.1 (p: (s32[], bf16[6,1024,4096])) -> pred[] {
+  %c = s32[]{:T(128)} constant(6)
+  %i = s32[]{:T(128)} get-tuple-element(%p), index=0
+  ROOT %lt = pred[]{:T(512)} compare(%i, %c), direction=LT
+}
+
+%body.1 (p.1: (s32[], bf16[6,1024,4096])) -> (s32[], bf16[6,1024,4096]) {
+  %ag = bf16[4096,4096]{1,0:T(8,128)(2,1)} all-gather(%slice), channel_id=1, replica_groups=[1,4]<=[4], dimensions={0}
+  %cps = (bf16[96,1024]{1,0:T(8,128)(2,1)}, bf16[96,1024]{1,0:T(8,128)(2,1)}, u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%s2), source_target_pairs={{0,1}}
+}
+
+ENTRY %main (a: f32[8]) -> f32[8] {
+  %w = (s32[]{:T(128)}, bf16[6,1024,4096]{2,1,0:T(8,128)(2,1)}) while(%t), condition=%cond.1, body=%body.1
+  %ar = (f32[2048]{0:T(1024)}, f32[2048,256]{1,0:T(8,128)}) all-reduce(%g0, %g1), channel_id=2, replica_groups=[1,4]<=[4], to_apply=%add
+}
+"""
+
+    def test_loop_body_ops_run_once_per_trip(self):
+        recs = {r["op"]: r for r in parse_collectives(self.HLO)}
+        assert recs["all-gather"]["executions"] == 6
+        assert recs["collective-permute"]["executions"] == 6
+        assert recs["all-reduce"]["executions"] == 1
+        s = summarize_collectives(self.HLO)
+        assert s["all-gather"]["total_bytes"] == 6 * 4096 * 4096 * 2
+
+    def test_tuple_results_with_tpu_layouts(self):
+        recs = {r["op"]: r for r in parse_collectives(self.HLO)}
+        # async start: (operand, output, context scalars) -> the output
+        assert recs["collective-permute"]["bytes"] == 96 * 1024 * 2
+        # combined all-reduce: every element of the tuple is payload
+        ar = recs["all-reduce"]
+        assert [p["elems"] for p in ar["parts"]] == [2048, 2048 * 256]
+        assert ar["bytes"] == (2048 + 2048 * 256) * 4
+        assert ar["shape"] == "(f32[2048], f32[2048,256])"
+
+
 class TestEngineSummary:
     def _engine(self, stage, model=None):
         model = model or SimpleModel(hidden_dim=64)

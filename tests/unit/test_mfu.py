@@ -449,17 +449,11 @@ class TestEngineLedgerE2E:
         """The satellite contract: per-region measured times re-sum to the
         measured clean-step time within 5%, the window's step lands in
         goodput's productive bucket, and accounting stays ~100%."""
-        from deepspeedsyclsupport_tpu.utils import jax_compat
-
-        jax_compat.install()
-        try:
-            engine, batch = _mfu_engine(tmp_path)
-            for _ in range(5):
-                engine.train_batch(batch)
-            assert engine._mfu_window is not None, "no clean-step window"
-            led = engine.mfu_ledger()
-        finally:
-            jax_compat.uninstall()
+        engine, batch = _mfu_engine(tmp_path)
+        for _ in range(5):
+            engine.train_batch(batch)
+        assert engine._mfu_window is not None, "no clean-step window"
+        led = engine.mfu_ledger()
         try:
             assert not mfu.validate_ledger(led)
             # reconciliation: regions (host included) re-sum to the step
@@ -498,9 +492,7 @@ class TestEngineLedgerE2E:
         """Step 3 recompiles (fresh shape): the window must skip it and
         capture a LATER clean step instead of blessing a compile as the
         clean-step sample."""
-        from deepspeedsyclsupport_tpu.utils import jax_compat
 
-        jax_compat.install()
         try:
             engine, batch = _mfu_engine(tmp_path, seq=64)
             for _ in range(2):
@@ -512,7 +504,6 @@ class TestEngineLedgerE2E:
             assert engine._mfu_window is not None
             assert engine._mfu_window["step"] == 4
         finally:
-            jax_compat.uninstall()
             engine.telemetry.close("test")
 
 
@@ -540,9 +531,7 @@ class TestRingInner:
             reference_attention
         from deepspeedsyclsupport_tpu.parallel.ring_attention import \
             ring_attention
-        from deepspeedsyclsupport_tpu.utils import jax_compat
 
-        jax_compat.install()
         try:
             reset_world_topology()
             build_topology(dp=4, sp=2)
@@ -568,16 +557,13 @@ class TestRingInner:
                 reset_world_topology as rwt
 
             rwt()
-            jax_compat.uninstall()
 
     def test_attention_dispatch_colon_syntax(self):
         from deepspeedsyclsupport_tpu.comm.topology import (
             build_topology, reset_world_topology)
         from deepspeedsyclsupport_tpu.models.layers import (
             attention, reference_attention)
-        from deepspeedsyclsupport_tpu.utils import jax_compat
 
-        jax_compat.install()
         try:
             reset_world_topology()
             build_topology(dp=4, sp=2)
@@ -594,7 +580,6 @@ class TestRingInner:
                 reset_world_topology as rwt
 
             rwt()
-            jax_compat.uninstall()
 
     @pytest.mark.slow  # two full engine compiles with interpret-mode
     def test_ring_ab_under_the_ledger(self, tmp_path):  # pallas (~40s)
@@ -603,9 +588,7 @@ class TestRingInner:
         attention time reported for BOTH arms. The bench ``train_ring``
         rung runs the same A/B in every round; this is its tier-2 twin."""
         from deepspeedsyclsupport_tpu.comm.topology import build_topology
-        from deepspeedsyclsupport_tpu.utils import jax_compat
 
-        jax_compat.install()
         engines = []
         try:
             attn_s = {}
@@ -623,7 +606,6 @@ class TestRingInner:
         finally:
             for e in engines:
                 e.telemetry.close("test")
-            jax_compat.uninstall()
 
 
 # ===================================================================

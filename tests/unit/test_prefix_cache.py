@@ -19,31 +19,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeedsyclsupport_tpu.utils import jax_compat
-
-_added = []
-
-
-def setup_module():
-    global _added
-    _added = jax_compat.install()
-
-
-def teardown_module():
-    if _added:
-        jax_compat.uninstall()
-
-
-from deepspeedsyclsupport_tpu.inference.v2 import (  # noqa: E402
+from deepspeedsyclsupport_tpu.inference.v2 import (
     BlockedAllocator, CapacityModel, InferenceEngineV2, ServingPolicyConfig,
     ServingSession)
-from deepspeedsyclsupport_tpu.inference.v2.kv_cache import (  # noqa: E402
+from deepspeedsyclsupport_tpu.inference.v2.kv_cache import (
     kv_pool_stats)
-from deepspeedsyclsupport_tpu.inference.v2.prefix_cache import (  # noqa: E402
+from deepspeedsyclsupport_tpu.inference.v2.prefix_cache import (
     PrefixCache, chain_hash)
-from deepspeedsyclsupport_tpu.inference.v2.serving import (  # noqa: E402
+from deepspeedsyclsupport_tpu.inference.v2.serving import (
     SERVE_PREFIX)
-from deepspeedsyclsupport_tpu.models import build_model  # noqa: E402
+from deepspeedsyclsupport_tpu.models import build_model
 
 
 class FakeClock:

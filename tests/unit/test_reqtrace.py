@@ -18,29 +18,14 @@ import sys
 import jax.numpy as jnp
 import pytest
 
-from deepspeedsyclsupport_tpu.utils import jax_compat
-
-_added = []
-
-
-def setup_module():
-    global _added
-    _added = jax_compat.install()
-
-
-def teardown_module():
-    if _added:
-        jax_compat.uninstall()
-
-
-from deepspeedsyclsupport_tpu.analysis import codelint  # noqa: E402
-from deepspeedsyclsupport_tpu.inference.v2 import (  # noqa: E402
+from deepspeedsyclsupport_tpu.analysis import codelint
+from deepspeedsyclsupport_tpu.inference.v2 import (
     InferenceEngineV2, ServingPolicyConfig, ServingSession)
-from deepspeedsyclsupport_tpu.inference.v2.supervisor import (  # noqa: E402
+from deepspeedsyclsupport_tpu.inference.v2.supervisor import (
     journal_path)
-from deepspeedsyclsupport_tpu.models import build_model  # noqa: E402
-from deepspeedsyclsupport_tpu.monitor import reqtrace  # noqa: E402
-from deepspeedsyclsupport_tpu.monitor.telemetry import (  # noqa: E402
+from deepspeedsyclsupport_tpu.models import build_model
+from deepspeedsyclsupport_tpu.monitor import reqtrace
+from deepspeedsyclsupport_tpu.monitor.telemetry import (
     export_metrics_textfile, prometheus_name)
 
 REPO = os.path.dirname(os.path.dirname(

@@ -16,34 +16,16 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from deepspeedsyclsupport_tpu.utils import jax_compat
-
-# the v2 ragged forward uses modern sharding spellings once a world topology
-# is installed (engine construction installs one); graft them for this
-# module and restore on exit so later-collected modules see stock jax
-_added = []
-
-
-def setup_module():
-    global _added
-    _added = jax_compat.install()
-
-
-def teardown_module():
-    if _added:
-        jax_compat.uninstall()
-
-
-from deepspeedsyclsupport_tpu.inference.v2 import (  # noqa: E402
+from deepspeedsyclsupport_tpu.inference.v2 import (
     BlockedAllocator, CapacityModel, InferenceEngineV2, ServingPolicyConfig,
     ServingSession)
-from deepspeedsyclsupport_tpu.inference.v2.ragged import (  # noqa: E402
+from deepspeedsyclsupport_tpu.inference.v2.ragged import (
     SequenceDescriptor)
-from deepspeedsyclsupport_tpu.inference.v2.scheduler import (  # noqa: E402
+from deepspeedsyclsupport_tpu.inference.v2.scheduler import (
     SLACK_CAP, SlackPolicy, schedule_chunks, slack_of)
-from deepspeedsyclsupport_tpu.inference.v2.serving import (  # noqa: E402
+from deepspeedsyclsupport_tpu.inference.v2.serving import (
     SERVE_EVENT_NAMES)
-from deepspeedsyclsupport_tpu.models import build_model  # noqa: E402
+from deepspeedsyclsupport_tpu.models import build_model
 
 
 class FakeClock:

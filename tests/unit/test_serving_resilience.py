@@ -41,32 +41,17 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from deepspeedsyclsupport_tpu.utils import jax_compat
-
-_added = []
-
-
-def setup_module():
-    global _added
-    _added = jax_compat.install()
-
-
-def teardown_module():
-    if _added:
-        jax_compat.uninstall()
-
-
-from deepspeedsyclsupport_tpu.comm.watchdog import (  # noqa: E402
+from deepspeedsyclsupport_tpu.comm.watchdog import (
     COMM_HANG_EXIT_CODE, SERVE_HANG_EXIT_CODE, CollectiveWatchdog)
-from deepspeedsyclsupport_tpu.elasticity import DSElasticAgent  # noqa: E402
-from deepspeedsyclsupport_tpu.inference.v2 import (  # noqa: E402
+from deepspeedsyclsupport_tpu.elasticity import DSElasticAgent
+from deepspeedsyclsupport_tpu.inference.v2 import (
     InferenceEngineV2, ReplicaSupervisor, RequestJournal, ServingPolicyConfig,
     ServingSession, load_journal, reconstruct_outputs, recover_requests)
-from deepspeedsyclsupport_tpu.monitor.monitor import (  # noqa: E402
+from deepspeedsyclsupport_tpu.monitor.monitor import (
     resilience_counters)
-from deepspeedsyclsupport_tpu.utils.fault_injection import (  # noqa: E402
+from deepspeedsyclsupport_tpu.utils.fault_injection import (
     ENV_SPEC, FaultInjector, configure_fault_injection)
-from deepspeedsyclsupport_tpu.models import build_model  # noqa: E402
+from deepspeedsyclsupport_tpu.models import build_model
 
 pytestmark = pytest.mark.resilience
 
@@ -804,7 +789,6 @@ def _run_supervised(tmp_path, name, inject=None, policy=None, args=()):
     spec_path, spec = _spec(tmp_path, name, policy=policy)
     env = dict(os.environ)
     env["PYTHONPATH"] = REPO + os.pathsep + env.get("PYTHONPATH", "")
-    env.setdefault("DSTPU_JAX_COMPAT", "1")
     if inject:
         env[ENV_SPEC] = json.dumps(inject)
     else:
