@@ -337,14 +337,22 @@ class UndeclaredStageName(Rule):
                    "{'stage': ...} record payloads) must resolve against "
                    "monitor/reqtrace.py's stage registries — a typo'd stage "
                    "silently orphans its interval as 'unattributed' in every "
-                   "request waterfall")
+                   "request waterfall; round-phase literals (self._phase / "
+                   "<round spans>.phase calls) against its ROUND_PHASES "
+                   "the same way")
 
     STAGE_CALLS = ("stage", "_stage", "note_stage")
+    # ``phase`` is too common a name to claim repo-wide: only the round
+    # clock's own call shapes are held to the registry
+    PHASE_CALLS = ("self._phase", "spans.phase", "_spans.phase",
+                   "round_spans.phase")
 
     def __init__(self):
-        from ..monitor.reqtrace import FLEET_STAGES, SERVE_STAGES
+        from ..monitor.reqtrace import (FLEET_STAGES, ROUND_PHASES,
+                                        SERVE_STAGES)
 
         self._stages = set(SERVE_STAGES) | set(FLEET_STAGES)
+        self._phases = set(ROUND_PHASES)
 
     def _literals(self, node):
         """String constants reachable from a stage argument (plain literal
@@ -361,12 +369,20 @@ class UndeclaredStageName(Rule):
         docstrings = _docstring_linenos(tree)
 
         def _flag(value, lineno, where):
-            if value in self._stages or lineno in docstrings:
+            phase = where == "phase call"
+            if value in (self._phases if phase else self._stages) \
+                    or lineno in docstrings:
                 return None
             if _suppressed(source_lines, lineno, self.name):
                 return None
             snippet = source_lines[lineno - 1].strip() \
                 if lineno <= len(source_lines) else ""
+            if phase:
+                return Violation(
+                    self.name, relpath, lineno,
+                    f"round phase {value!r} is not declared in "
+                    f"monitor/reqtrace.py ROUND_PHASES — no reader of the "
+                    f"round record would sum its time", snippet)
             return Violation(
                 self.name, relpath, lineno,
                 f"stage {value!r} ({where}) is not declared in "
@@ -375,8 +391,15 @@ class UndeclaredStageName(Rule):
                 f"declare the stage + its Serve/stage.* event)", snippet)
 
         for node in ast.walk(tree):
-            if isinstance(node, ast.Call) and len(node.args) >= 2 and \
-                    _call_name(node).split(".")[-1] in self.STAGE_CALLS:
+            parts = _call_name(node).split(".") \
+                if isinstance(node, ast.Call) else [""]
+            called = parts[-1]
+            if ".".join(parts[-2:]) in self.PHASE_CALLS and node.args:
+                for value, lineno in self._literals(node.args[0]):
+                    v = _flag(value, lineno, "phase call")
+                    if v is not None:
+                        yield v
+            elif called in self.STAGE_CALLS and len(node.args) >= 2:
                 for value, lineno in self._literals(node.args[1]):
                     v = _flag(value, lineno, "stage call")
                     if v is not None:

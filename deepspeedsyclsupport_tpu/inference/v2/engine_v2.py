@@ -26,6 +26,7 @@ from .scheduler import schedule_chunks
 from ..params import place_inference_params
 from ..sampling import SamplingParams, sample_token_dyn
 from ...comm.topology import MeshTopology, build_topology
+from ...monitor.reqtrace import NO_PHASE
 from ...utils.logging import log_dist
 
 
@@ -87,6 +88,11 @@ class InferenceEngineV2:
         # here; put() then orders chunks by slack instead of arrival. None =
         # the pre-SLA least-recently-served ordering.
         self.slack_policy = None
+        # ... and, for the length of one scheduling round, its phase clock
+        # (serving.RoundSpans): put() and the fused dispatch then charge
+        # their schedule/build/dispatch/collect time to the round's record.
+        # None = the engine is driven without a session, nothing is timed.
+        self.round_spans = None
         # cross-request prefix cache (install_prefix_cache). None = every
         # stream prefills its full prompt (the pre-sharing behavior).
         self.prefix_cache = None
@@ -105,7 +111,11 @@ class InferenceEngineV2:
         # name -> (jitted fn, abstract args) of every forward program this
         # engine has dispatched (compiled_programs)
         self._dispatched: Dict[str, Tuple[Any, Any]] = {}
-        self.host_dispatches = 0  # host-scheduled device dispatches (bench)
+        # forward programs dispatched (_dispatch) plus sampler calls: 2 a
+        # per-token round. NOT the round's device launches: the per-row
+        # logits slices, their stack, the rng split and the scalar converts
+        # are ~100 more at 32 live (benchmark: launches_per_round)
+        self.host_dispatches = 0
         self._rng = jax.random.PRNGKey(cfg.seed)
         # only the sampling STRUCTURE is static; temperature/top_p are
         # operands (sweeping them reuses one compiled sampler)
@@ -205,7 +215,31 @@ class InferenceEngineV2:
 
             self._dispatched[name] = (fn, abstract_step_args(args))
         self.host_dispatches += 1
-        return fn(*args)
+        out = fn(*args)
+        if self.round_spans is not None:
+            self.round_spans.launched(name)
+        return out
+
+    def _phase(self, name: str):
+        """The running round's span around ``name``
+        (``reqtrace.ROUND_PHASES``); nothing without a session's clock."""
+        spans = self.round_spans
+        return NO_PHASE if spans is None else spans.phase(name)
+
+    def _note_forward(self, descs, lengths) -> None:
+        """What the forward about to be launched covers, for the round's
+        record; called BEFORE it, so ``ctx_tokens`` is the context the
+        attention kernel must read and ``kv_blocks`` the tables it walks."""
+        if self.round_spans is None:
+            return
+        self.round_spans.fields.update(
+            n_seqs=len(descs), tokens=sum(lengths),
+            # the rule _run routes by: one token on top of cached context
+            # is a decode step, anything else is prompt
+            prefill_tokens=sum(n for d, n in zip(descs, lengths)
+                               if not (n == 1 and d.n_cached > 0)),
+            ctx_tokens=sum(d.n_cached for d in descs),
+            kv_blocks=sum(len(d.blocks) for d in descs))
 
     def compiled_programs(self) -> Dict[str, Any]:
         """``{name: jax.stages.Compiled}`` for every forward program this
@@ -383,6 +417,49 @@ class InferenceEngineV2:
         prefill enters at the first uncached token with positions exact
         (``token_pos`` continues from ``n_cached``)."""
         cfg = self.config
+        with self._phase("schedule"):
+            admission = self._enqueue(uids, tokens_list, strict)
+        out = PutResult()
+        out.admission = admission
+        while True:
+            with self._phase("schedule"):
+                chunks = schedule_chunks(
+                    list(self.seqs.values()), self.allocator,
+                    max_tokens=cfg.max_tokens_per_batch,
+                    max_sequences=cfg.max_sequences,
+                    block_size=cfg.block_size, max_context=cfg.max_context,
+                    max_prefill_fraction=cfg.max_prefill_fraction,
+                    policy=self.slack_policy)
+                if self.prefix_cache is not None:
+                    for d, n in chunks:
+                        self._ensure_writable(d, n)
+            if not chunks:
+                break
+            logits = self._run(chunks)
+            with self._phase("collect"):
+                self._tick += 1
+                served_s = time.perf_counter()  # aging base for slack order
+                for slot, (d, n) in enumerate(chunks):
+                    d.last_scheduled = self._tick
+                    d.last_service_s = served_s
+                    if self.prefix_cache is not None:
+                        d.history.extend(int(t) for t in d.pending[:n])
+                    del d.pending[:n]
+                    d.n_cached += n
+                    if self.prefix_cache is not None:
+                        self._commit_prefix(d)
+                    if not d.pending:
+                        d.last_logits = logits[slot]
+                        out[d.uid] = d.last_logits
+            if not drain:
+                break
+            if all(not d.pending for d in self.seqs.values()):
+                break
+        return out
+
+    def _enqueue(self, uids, tokens_list, strict: bool) -> AdmissionResult:
+        """put()'s admission: check what fits, create the fresh descriptors
+        (mapping any cached prefix) and queue the admitted tokens."""
         cached_peek: Dict[int, int] = {}
         if self.prefix_cache is not None:
             for uid, toks in zip(uids, tokens_list):
@@ -410,42 +487,7 @@ class InferenceEngineV2:
                     skip = self.map_cached_prefix(uid, toks)
             d.pending.extend(int(t) for t in toks[skip:])
             d.last_logits = None
-
-        out = PutResult()
-        out.admission = admission
-        while True:
-            chunks = schedule_chunks(
-                list(self.seqs.values()), self.allocator,
-                max_tokens=cfg.max_tokens_per_batch,
-                max_sequences=cfg.max_sequences, block_size=cfg.block_size,
-                max_context=cfg.max_context,
-                max_prefill_fraction=cfg.max_prefill_fraction,
-                policy=self.slack_policy)
-            if not chunks:
-                break
-            if self.prefix_cache is not None:
-                for d, n in chunks:
-                    self._ensure_writable(d, n)
-            logits = self._run(chunks)
-            self._tick += 1
-            served_s = time.perf_counter()  # aging base for slack ordering
-            for slot, (d, n) in enumerate(chunks):
-                d.last_scheduled = self._tick
-                d.last_service_s = served_s
-                if self.prefix_cache is not None:
-                    d.history.extend(int(t) for t in d.pending[:n])
-                del d.pending[:n]
-                d.n_cached += n
-                if self.prefix_cache is not None:
-                    self._commit_prefix(d)
-                if not d.pending:
-                    d.last_logits = logits[slot]
-                    out[d.uid] = d.last_logits
-            if not drain:
-                break
-            if all(not d.pending for d in self.seqs.values()):
-                break
-        return out
+        return admission
 
     def _evict_index(self, uids: Sequence[int]) -> int:
         """Victim index under the configured ``eviction_policy``:
@@ -632,27 +674,31 @@ class InferenceEngineV2:
         cfg = self.config
         if all(n == 1 and d.n_cached > 0 for d, n in chunks):
             return self._run_decode(chunks)  # kernel fast path
-        batch = build_ragged_batch(
-            chunks, cfg.max_tokens_per_batch, cfg.max_sequences,
-            cfg.blocks_per_seq,
-            atom_q=cfg.atom_q_size if self._use_atoms else None)
-        atom_args = ()
-        if self._use_atoms:
-            atom_args = (jnp.asarray(batch.atom_qidx),
-                         jnp.asarray(batch.atom_pos0),
-                         jnp.asarray(batch.atom_qlen),
-                         jnp.asarray(batch.atom_tables),
-                         jnp.asarray(batch.atom_inv))
-        logits, self.kv = self._dispatch(
-            "ragged_forward", self._forward,
-            self.params, self.kv, jnp.asarray(batch.tokens),
-            jnp.asarray(batch.token_seq), jnp.asarray(batch.token_pos),
-            jnp.asarray(batch.block_tables), jnp.asarray(batch.last_tok_idx),
-            *atom_args)
+        with self._phase("build"):
+            batch = build_ragged_batch(
+                chunks, cfg.max_tokens_per_batch, cfg.max_sequences,
+                cfg.blocks_per_seq,
+                atom_q=cfg.atom_q_size if self._use_atoms else None)
+            self._note_forward(*zip(*chunks))
+        with self._phase("dispatch"):
+            atom_args = ()
+            if self._use_atoms:
+                atom_args = (jnp.asarray(batch.atom_qidx),
+                             jnp.asarray(batch.atom_pos0),
+                             jnp.asarray(batch.atom_qlen),
+                             jnp.asarray(batch.atom_tables),
+                             jnp.asarray(batch.atom_inv))
+            logits, self.kv = self._dispatch(
+                "ragged_forward", self._forward,
+                self.params, self.kv, jnp.asarray(batch.tokens),
+                jnp.asarray(batch.token_seq), jnp.asarray(batch.token_pos),
+                jnp.asarray(batch.block_tables),
+                jnp.asarray(batch.last_tok_idx), *atom_args)
         # DEVICE-resident: per-slot rows are sliced on device and only
         # fetched when a caller materializes them (query()/np.asarray) —
         # generate()'s sampler consumes them without a host round trip
-        return logits[:len(chunks)]
+        with self._phase("collect"):
+            return logits[:len(chunks)]
 
     def _slot_arrays(self, descs):
         """Per-slot decode metadata padded to max_sequences — the ONE
@@ -678,19 +724,24 @@ class InferenceEngineV2:
         if self._decode_forward is None:
             self._decode_forward = build_decode_forward_fn(
                 self.model, cfg.block_size, attn_impl=cfg.decode_attn)
-        positions, tables, active = self._slot_arrays(
-            [d for d, _n in chunks])
-        tokens = np.zeros((cfg.max_sequences,), np.int32)
-        for slot, (d, _n) in enumerate(chunks):
-            tokens[slot] = d.pending[0]
-        logits, self.kv = self._dispatch(
-            "decode_forward", self._decode_forward,
-            self.params, self.kv, jnp.asarray(tokens), jnp.asarray(positions),
-            jnp.asarray(tables), jnp.asarray(active))
+        with self._phase("build"):
+            positions, tables, active = self._slot_arrays(
+                [d for d, _n in chunks])
+            tokens = np.zeros((cfg.max_sequences,), np.int32)
+            for slot, (d, _n) in enumerate(chunks):
+                tokens[slot] = d.pending[0]
+            self._note_forward(*zip(*chunks))
+        with self._phase("dispatch"):
+            logits, self.kv = self._dispatch(
+                "decode_forward", self._decode_forward,
+                self.params, self.kv, jnp.asarray(tokens),
+                jnp.asarray(positions), jnp.asarray(tables),
+                jnp.asarray(active))
         # DEVICE-resident: per-slot rows are sliced on device and only
         # fetched when a caller materializes them (query()/np.asarray) —
         # generate()'s sampler consumes them without a host round trip
-        return logits[:len(chunks)]
+        with self._phase("collect"):
+            return logits[:len(chunks)]
 
     def _decode_multi_dispatch(self, running: Dict[int, int],
                                sp: "SamplingParams",
@@ -726,6 +777,81 @@ class InferenceEngineV2:
 
         cfg = self.config
         uids = list(running)
+        with self._phase("schedule"):
+            k = self._fund_decode_multi(running, uids, sp, k_cap)
+        if k is None:
+            return None
+        key = (k, sp.structure)
+        fn = self._decode_multi.get(key)
+        if fn is None:
+            fn = self._decode_multi[key] = build_decode_multi_fn(
+                self.model, cfg.block_size, k, sp.structure,
+                cfg.max_context, attn_impl=cfg.decode_attn)
+            while len(self._decode_multi) > self._decode_multi_cap:
+                self._decode_multi.popitem(last=False)
+        else:
+            self._decode_multi.move_to_end(key)
+        s_max = cfg.max_sequences
+        n = len(uids)
+        with self._phase("build"):
+            positions, tables, active = self._slot_arrays(
+                [self.seqs[u] for u in uids])
+            steps_left = np.zeros((s_max,), np.int32)
+            steps_left[:n] = [running[u] for u in uids]
+            self._note_forward([self.seqs[u] for u in uids], [0] * n)
+        with self._phase("dispatch"):
+            stacked = jnp.stack([self.seqs[u].last_logits for u in uids])
+            logits0 = jnp.zeros((s_max, stacked.shape[-1]),
+                                jnp.float32).at[:n].set(stacked)
+            toks_d, logits_f, pos_f, act_f, sl_f, self.kv = self._dispatch(
+                f"decode_multi_{k}", fn,
+                self.params, self.kv, logits0, jnp.asarray(positions),
+                jnp.asarray(tables), jnp.asarray(active),
+                jnp.asarray(steps_left), rng,
+                jnp.float32(sp.temperature), jnp.float32(sp.top_p),
+                jnp.int32(-1 if eos_token_id is None else eos_token_id))
+        self._tick += k
+        # ONE host transfer for the K×S token block + the small state rows
+        with self._phase("readback"):
+            toks = np.asarray(toks_d)
+            pos_h = np.asarray(pos_f)
+            act_h = np.asarray(act_f)
+            sl_h = np.asarray(sl_f)
+        emitted: Dict[int, List[int]] = {}
+        served_s = time.perf_counter()
+        with self._phase("collect"):
+            for i, u in enumerate(uids):
+                d = self.seqs[u]
+                emitted[u] = [int(t) for t in toks[:, i] if t >= 0]
+                d.n_cached = int(pos_h[i])
+                d.last_scheduled = self._tick
+                d.last_service_s = served_s
+                d.emitted += len(emitted[u])
+                if self.prefix_cache is not None:
+                    # committed tokens this dispatch = sampled tokens
+                    # appended to KV; clamp to n_cached (an early-retiring
+                    # slot appends nothing past its final position)
+                    d.history.extend(emitted[u])
+                    del d.history[d.n_cached:]
+                    self._commit_prefix(d)
+                if act_h[i]:
+                    running[u] = int(sl_h[i])
+                    d.last_logits = logits_f[i]
+                else:
+                    del running[u]
+                    self.flush([u])
+            if self.round_spans is not None:
+                self.round_spans.fields["tokens"] = sum(
+                    map(len, emitted.values()))
+        return emitted
+
+    def _fund_decode_multi(self, running: Dict[int, int], uids: List[int],
+                           sp: "SamplingParams", k_cap: Optional[int]
+                           ) -> Optional[int]:
+        """The rung K of :meth:`_decode_multi_dispatch`'s next program, with
+        the KV blocks of its worst-case K appends allocated; ``None`` when
+        the pool cannot pre-fund two steps."""
+        cfg = self.config
         k = cfg.decode_steps_per_dispatch
         if k_cap is not None:
             cap = max(2, int(k_cap))
@@ -785,63 +911,7 @@ class InferenceEngineV2:
                 self._ensure_writable(
                     d, min(k, running[u],
                            max(0, cfg.max_context - d.n_cached)))
-
-        key = (k, sp.structure)
-        fn = self._decode_multi.get(key)
-        if fn is None:
-            fn = self._decode_multi[key] = build_decode_multi_fn(
-                self.model, cfg.block_size, k, sp.structure,
-                cfg.max_context, attn_impl=cfg.decode_attn)
-            while len(self._decode_multi) > self._decode_multi_cap:
-                self._decode_multi.popitem(last=False)
-        else:
-            self._decode_multi.move_to_end(key)
-        s_max = cfg.max_sequences
-        n = len(uids)
-        positions, tables, active = self._slot_arrays(
-            [self.seqs[u] for u in uids])
-        steps_left = np.zeros((s_max,), np.int32)
-        steps_left[:n] = [running[u] for u in uids]
-        stacked = jnp.stack([self.seqs[u].last_logits for u in uids])
-        logits0 = jnp.zeros((s_max, stacked.shape[-1]),
-                            jnp.float32).at[:n].set(stacked)
-
-        toks_d, logits_f, pos_f, act_f, sl_f, self.kv = self._dispatch(
-            f"decode_multi_{k}", fn,
-            self.params, self.kv, logits0, jnp.asarray(positions),
-            jnp.asarray(tables), jnp.asarray(active),
-            jnp.asarray(steps_left), rng,
-            jnp.float32(sp.temperature), jnp.float32(sp.top_p),
-            jnp.int32(-1 if eos_token_id is None else eos_token_id))
-        self._tick += k
-        # ONE host transfer for the K×S token block + the small state rows
-        toks = np.asarray(toks_d)
-        pos_h = np.asarray(pos_f)
-        act_h = np.asarray(act_f)
-        sl_h = np.asarray(sl_f)
-        emitted: Dict[int, List[int]] = {}
-        served_s = time.perf_counter()
-        for i, u in enumerate(uids):
-            d = self.seqs[u]
-            emitted[u] = [int(t) for t in toks[:, i] if t >= 0]
-            d.n_cached = int(pos_h[i])
-            d.last_scheduled = self._tick
-            d.last_service_s = served_s
-            d.emitted += len(emitted[u])
-            if self.prefix_cache is not None:
-                # committed tokens this dispatch = sampled tokens appended
-                # to KV; clamp to n_cached (an early-retiring slot appends
-                # nothing past its final position)
-                d.history.extend(emitted[u])
-                del d.history[d.n_cached:]
-                self._commit_prefix(d)
-            if act_h[i]:
-                running[u] = int(sl_h[i])
-                d.last_logits = logits_f[i]
-            else:
-                del running[u]
-                self.flush([u])
-        return emitted
+        return k
 
     # ------------------------------------------------------------ query/flush
     def query(self, uid: int) -> Optional[jax.Array]:

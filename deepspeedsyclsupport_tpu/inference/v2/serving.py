@@ -29,6 +29,7 @@ Everything here is host-side policy over monotonic time
 policy with a synthetic clock and capacity model. See ``docs/serving.md``
 for the overload-behavior contract and config keys.
 """
+import contextlib
 import math
 import os
 import time
@@ -45,6 +46,8 @@ from .kv_cache import kv_pool_stats
 from .scheduler import SlackPolicy, slack_of
 from ..sampling import SamplingParams
 from ...comm.watchdog import SERVE_HANG_EXIT_CODE, CollectiveWatchdog
+from ...monitor.reqtrace import (FORWARD_FIELDS, NO_PHASE, ROUND_PHASES,
+                                 check_phase)
 from ...utils.fault_injection import get_fault_injector
 from ...utils.logging import logger
 
@@ -187,6 +190,83 @@ class CapacityModel:
                          else self.prefill_tok_s)
 
 
+class _Phase:
+    """One phase's context manager, made once per ``RoundSpans`` and
+    re-entered every round: a round passes a dozen of these, so entering
+    one is two clock reads, a list append and the profiler's annotation."""
+
+    __slots__ = ("spans", "name", "label", "open")
+
+    def __init__(self, spans: "RoundSpans", name: str):
+        self.spans, self.name = spans, name
+        self.label = "dstpu/serve/" + name
+        self.open: List[Any] = []   # annotations entered and not yet left
+
+    def __enter__(self) -> None:
+        self.spans._charge()
+        self.spans._stack.append(self.name)
+        annotation = jax.profiler.TraceAnnotation(self.label)
+        annotation.__enter__()
+        self.open.append(annotation)
+
+    def __exit__(self, *exc) -> None:
+        self.open.pop().__exit__(*exc)
+        self.spans._charge()
+        self.spans._stack.pop()
+
+
+class RoundSpans:
+    """The phase clock of the running scheduling round
+    (``reqtrace.ROUND_PHASES``; docs/observability.md "Round phases").
+
+    ``with spans.phase(name):`` charges the block's time on the session's
+    clock to ``name``, less what a phase entered inside it claims, and opens
+    ``dstpu/serve/<name>`` in the profiler's trace, nested in the
+    ``dstpu/serve/round`` annotation :meth:`round` opened: under any
+    ``jax.profiler`` trace the same spans sit beside the device's timeline.
+    Time no phase claims is ``other``, so ``phases`` partitions
+    ``[t0, t1]``. ``fields`` collects what the round's ``round`` record says
+    besides: the session notes the mode and the sampled uids, the engine
+    (handed this object for the round as ``engine.round_spans``) the program
+    it launched and what that forward covered."""
+
+    def __init__(self, clock: Callable[[], float]):
+        self.clock = clock
+        self._phases = {name: _Phase(self, name) for name in ROUND_PHASES}
+
+    @contextlib.contextmanager
+    def round(self, number: int, t0: float):
+        """Round ``number``, which began at ``t0`` of the session's clock."""
+        self.t0 = self._mark = t0
+        self.t1: Optional[float] = None
+        self.phases: Dict[str, float] = {}
+        self.fields: Dict[str, Any] = {}
+        self._stack = ["other"]
+        with jax.profiler.TraceAnnotation("dstpu/serve/round", round=number):
+            try:
+                yield
+            finally:
+                self._charge()
+                self.t1 = self._mark
+
+    def _charge(self) -> None:
+        """Close the running phase's stretch at the clock's next reading."""
+        now = self.clock()
+        name = self._stack[-1]
+        self.phases[name] = self.phases.get(name, 0.0) + now - self._mark
+        self._mark = now
+
+    def phase(self, name: str) -> _Phase:
+        if name not in self._phases:
+            check_phase(name)      # raises: not in the registry
+        return self._phases[name]
+
+    def launched(self, program: str) -> None:
+        """The engine's ``_dispatch`` of ``program`` has just returned."""
+        self.fields["program"] = program
+        self.fields["launch_t"] = self.clock()
+
+
 @dataclass
 class ServeEvent:
     """One observable serving outcome, stamped on the session clock.
@@ -280,6 +360,9 @@ class ServingSession:
         # join can order router and replica streams together.
         self._tracing = bool(self.policy.trace_stages)
         self.trace_log: deque = deque(maxlen=65536)
+        self.trace_dropped = 0     # records the full ring pushed out
+        self._round_spans = RoundSpans(clock) if self._tracing else None
+        self._spans: Optional[RoundSpans] = None   # the running round's
         self._wall0 = time.time() - self.clock()  # dslint: allow(wall-clock-in-step-path)
         # SLO burn accounting (Serve/slo.* gauges): sliding windows of
         # (t, first-token-met-SLA) and (t, outcome-was-shed) samples
@@ -345,6 +428,8 @@ class ServingSession:
         """Mirror one lifecycle record (journal-record shape) into the
         in-memory ring, stamped on the session-clock→wall mapping."""
         if self._tracing:
+            if len(self.trace_log) == self.trace_log.maxlen:
+                self.trace_dropped += 1
             self.trace_log.append(
                 {"name": name, "t": t + self._wall0, "data": data})
 
@@ -355,11 +440,9 @@ class ServingSession:
         clock base, no second transport."""
         if not self._tracing:
             return
-        payload = {"uid": int(uid), "stage": stage,
-                   **({"dur": float(dur)} if dur is not None else {}),
-                   **data}
-        self.trace_log.append(
-            {"name": "serve/stage", "t": t + self._wall0, "data": payload})
+        self._trace("serve/stage", t, {
+            "uid": int(uid), "stage": stage,
+            **({"dur": float(dur)} if dur is not None else {}), **data})
         if self.journal is not None:
             self.journal.stage(uid, stage, dur=dur, **data)
 
@@ -369,6 +452,25 @@ class ServingSession:
         stamps ``spool_wait`` through this; a future RPC front-end stamps
         its ingress edge the same way)."""
         self._stage(uid, stage, self.clock(), dur=dur, **data)
+
+    def _phase(self, name: str):
+        """The running round's span around ``name``
+        (``reqtrace.ROUND_PHASES``); nothing when ``trace_stages`` is off or
+        the round began with no work."""
+        return NO_PHASE if self._spans is None else self._spans.phase(name)
+
+    def _stamp_round(self, spans: RoundSpans) -> None:
+        """The round's one record: when it ran (``t0``/``t1``/``launch_t``
+        on the SESSION's clock — the record's own ``t`` is shifted onto the
+        wall), what it dispatched and what that forward covered
+        (``reqtrace.FORWARD_FIELDS``), and where its time went (``phases``
+        sums to ``t1 - t0``). ``uids``/``mode`` are what the join fans out
+        to each request's round count."""
+        self._stage(-1, "round", spans.t1, **{
+            "round": self._round, "t0": spans.t0, "t1": spans.t1,
+            "launch_t": None, "program": None, "mode": "per_token",
+            "uids": [], **dict.fromkeys(FORWARD_FIELDS, 0),
+            **spans.fields, "phases": dict(spans.phases)})
 
     def drain_trace(self) -> List[Dict[str, Any]]:
         """Hand over and clear the in-memory lifecycle records — the bench
@@ -688,7 +790,8 @@ class ServingSession:
         ``os._exit(219)`` — the serving twin of the rc-218 collective-hang
         contract — instead of a silent forever-hang the supervisor can
         only guess at."""
-        now = self.clock() if now is None else now
+        t_start = self.clock()
+        now = t_start if now is None else now
         self._round += 1
         injector = get_fault_injector()
         rc = injector.should_serve_crash(self._round, self._tokens_emitted)
@@ -700,33 +803,51 @@ class ServingSession:
                          self._round, self._tokens_emitted, rc)
             os._exit(rc)
         events: List[ServeEvent] = []
-        self._maintain_queue(now, events)
-        self.eng.slack_policy = self._slack_policy(now)
-        # arm only when the round has work: an idle poll (the natural
-        # serving-loop pattern while awaiting the first request) must not
-        # consume the one-shot warmup allowance — the first REAL round
-        # compiles prefill + sampler + fused rungs and needs it
-        wd = self.watchdog if (self.running or self.queue) else None
-        if wd is not None:
-            wd.arm(self._round)
-        dispatches0 = self.eng.host_dispatches
-        try:
-            # decode_wedge lands HERE — after arming, inside the watched
-            # window — so the injected stall is exactly the hang the
-            # watchdog exists to convert into rc 219
-            injector.maybe_wedge_decode(self._round)
-            fused = self._can_fuse() and self._fused_round(now, events)
-            if not fused:
-                self._per_token_round(now, events)
-        finally:
-            # disarm in a finally: an exception mid-round must not leave
-            # the deadline live to rc-219 the process during ordinary
-            # error handling (the PR 6 watchdog lesson)
-            if wd is not None:
-                wd.disarm(self._round)
-            self.eng.slack_policy = None
-        self._note_progress(events, dispatches0, now)
-        self._flush_gauges(now)
+        # a round is timed and recorded only if it begins with work: a loop
+        # that polls an idle session every millisecond must not push the
+        # requests' own records out of the ring, nor grow the journal
+        spans = self._spans = self._round_spans \
+            if (self.running or self.queue) else None
+        with (NO_PHASE if spans is None
+              else spans.round(self._round, t_start)):
+            wd = None
+            try:
+                with self._phase("queue"):
+                    self._maintain_queue(now, events)
+                    self.eng.slack_policy = self._slack_policy(now)
+                    # arm only when the round has work: an idle poll (the
+                    # natural serving-loop pattern while awaiting the first
+                    # request) must not consume the one-shot warmup
+                    # allowance — the first REAL round compiles prefill +
+                    # sampler + fused rungs and needs it
+                    wd = self.watchdog if (self.running or self.queue) \
+                        else None
+                    if wd is not None:
+                        wd.arm(self._round)
+                # the engine splits its own part of the round (put():
+                # schedule, build, dispatch, collect) on the same clock
+                self.eng.round_spans = spans
+                dispatches0 = self.eng.host_dispatches
+                # decode_wedge lands HERE — after arming, inside the watched
+                # window — so the injected stall is exactly the hang the
+                # watchdog exists to convert into rc 219
+                injector.maybe_wedge_decode(self._round)
+                fused = self._can_fuse() and self._fused_round(now, events)
+                if not fused:
+                    self._per_token_round(now, events)
+            finally:
+                # disarm in a finally: an exception mid-round must not leave
+                # the deadline live to rc-219 the process during ordinary
+                # error handling (the PR 6 watchdog lesson)
+                if wd is not None:
+                    wd.disarm(self._round)
+                self.eng.slack_policy = None
+                self.eng.round_spans = None
+            with self._phase("account"):
+                self._note_progress(events, dispatches0, now)
+                self._flush_gauges(now)
+        if spans is not None:
+            self._stamp_round(spans)
         return events
 
     def _note_progress(self, events: List[ServeEvent], dispatches0: int,
@@ -842,35 +963,37 @@ class ServingSession:
         return None if cap is None else max(2, cap)
 
     def _fused_round(self, now: float, events: List[ServeEvent]) -> bool:
-        budgets = {u: self.running[u].budget for u in self.running}
-        self._rng, sub = jax.random.split(self._rng)
+        with self._phase("schedule"):
+            budgets = {u: self.running[u].budget for u in self.running}
+            self._rng, sub = jax.random.split(self._rng)
+            k_cap = self._k_cap(now)
+        # the engine's own phases: schedule (rung, pre-funded blocks), build,
+        # dispatch, readback (the K x S token block), collect
         emitted = self.eng._decode_multi_dispatch(
-            budgets, self.sampling, self.eos_token_id, sub,
-            k_cap=self._k_cap(now))
+            budgets, self.sampling, self.eos_token_id, sub, k_cap=k_cap)
         if emitted is None:
             return False  # KV pool can't pre-fund ≥2 steps → per-token path
         t1 = self.clock()
         steps = max((len(v) for v in emitted.values()), default=0)
         self.capacity.record_decode(steps, t1 - now)
         self._last_decode_s = t1
-        # one record per scheduling round (uid −1 = session scope; the
-        # scheduled uids ride in data) — per-uid stamps here would double
-        # the journal volume for no join benefit
-        self._stage(-1, "decode_round", t1, dur=t1 - now, mode="fused",
-                    k=steps, uids=sorted(emitted))
-        for uid, toks in emitted.items():
-            req = self.running[uid]
-            req.budget -= len(toks)
-            if toks:
-                events.append(ServeEvent("token", uid, t1, tokens=list(toks)))
-                self._note_emission(req, toks, t1)
-            if uid not in budgets:  # retired on device; engine flushed it
-                reason = ("eos" if (toks and self.eos_token_id is not None
-                                    and toks[-1] == self.eos_token_id)
-                          else ("done" if req.budget <= 0 else "context"))
-                self._finish(uid, t1, events, reason, flush=False)
-            else:
-                req.budget = budgets[uid]  # authoritative (device counted)
+        if self._spans is not None:
+            self._spans.fields.update(mode="fused", uids=sorted(emitted))
+        with self._phase("emit"):
+            for uid, toks in emitted.items():
+                req = self.running[uid]
+                req.budget -= len(toks)
+                if toks:
+                    events.append(
+                        ServeEvent("token", uid, t1, tokens=list(toks)))
+                    self._note_emission(req, toks, t1)
+                if uid not in budgets:  # retired on device; engine flushed it
+                    reason = ("eos" if (toks and self.eos_token_id is not None
+                                        and toks[-1] == self.eos_token_id)
+                              else ("done" if req.budget <= 0 else "context"))
+                    self._finish(uid, t1, events, reason, flush=False)
+                else:
+                    req.budget = budgets[uid]  # authoritative (device counted)
         return True
 
     # ------------------------------------------------------ per-token round
@@ -879,81 +1002,93 @@ class ServingSession:
         sp = self.sampling
         # 1. one batched device sample over every drained stream
         drained: List[Tuple[int, jax.Array]] = []
-        for uid in list(self.running):
-            if uid in self._pending_tok:
-                continue
-            lg = eng.query(uid)
-            if lg is not None:
-                drained.append((uid, lg))
+        with self._phase("gather"):
+            for uid in list(self.running):
+                if uid in self._pending_tok:
+                    continue
+                lg = eng.query(uid)
+                if lg is not None:
+                    drained.append((uid, lg))
+            if drained:
+                self._rng, sub = jax.random.split(self._rng)
+                rows = jnp.stack([lg for _, lg in drained])
         if drained:
-            self._rng, sub = jax.random.split(self._rng)
-            toks = np.asarray(eng._sample_fn(
-                jnp.stack([lg for _, lg in drained]), sub,
-                jnp.float32(sp.temperature), jnp.float32(sp.top_p),
-                sp.structure))
-            eng.host_dispatches += 1  # the sampler is a dispatch too
+            with self._phase("sample"):
+                toks = eng._sample_fn(
+                    rows, sub, jnp.float32(sp.temperature),
+                    jnp.float32(sp.top_p), sp.structure)
+                eng.host_dispatches += 1  # the sampler is a dispatch too
+            with self._phase("readback"):
+                toks = np.asarray(toks)
             t1 = self.clock()
             if self._last_decode_s is not None:
                 self.capacity.record_decode(1, t1 - self._last_decode_s)
             self._last_decode_s = t1
-            self._stage(-1, "decode_round", t1, dur=t1 - now,
-                        mode="per_token",
-                        uids=sorted(u for u, _lg in drained))
-            for (uid, _lg), tok in zip(drained, toks):
-                tok = int(tok)
-                req = self.running[uid]
-                events.append(ServeEvent("token", uid, t1, tokens=[tok]))
-                self._note_emission(req, [tok], t1)
-                req.budget -= 1
-                d = eng.seqs[uid]
-                d.emitted += 1
-                done = (req.budget <= 0
-                        or (self.eos_token_id is not None
-                            and tok == self.eos_token_id)
-                        or d.n_cached >= eng.config.max_context)
-                if done:
-                    reason = ("eos" if (self.eos_token_id is not None
-                                        and tok == self.eos_token_id)
-                              else ("done" if req.budget <= 0 else "context"))
-                    self._finish(uid, t1, events, reason)
-                else:
-                    self._pending_tok[uid] = tok
+            if self._spans is not None:
+                self._spans.fields["uids"] = sorted(u for u, _lg in drained)
+            with self._phase("emit"):
+                for (uid, _lg), tok in zip(drained, toks):
+                    tok = int(tok)
+                    req = self.running[uid]
+                    events.append(ServeEvent("token", uid, t1, tokens=[tok]))
+                    self._note_emission(req, [tok], t1)
+                    req.budget -= 1
+                    d = eng.seqs[uid]
+                    d.emitted += 1
+                    done = (req.budget <= 0
+                            or (self.eos_token_id is not None
+                                and tok == self.eos_token_id)
+                            or d.n_cached >= eng.config.max_context)
+                    if done:
+                        reason = ("eos" if (self.eos_token_id is not None
+                                            and tok == self.eos_token_id)
+                                  else ("done" if req.budget <= 0
+                                        else "context"))
+                        self._finish(uid, t1, events, reason)
+                    else:
+                        self._pending_tok[uid] = tok
         else:
             self._last_decode_s = None  # no decode this round: break the
             #                             ITL chain across prefill-only gaps
         # 2. KV pressure: preempt the lowest-slack stream until the decode
         # tokens fit (never stall the whole batch on an exhausted pool)
         put_uids = list(self._pending_tok)
-        while put_uids:
-            res = eng.check_schedule(put_uids, [1] * len(put_uids))
-            if not any(res.reasons.get(u, "").startswith("kv")
-                       for u in res.rejected):
-                break
-            victim = self._eviction_victim(now)
-            if victim is None:
-                break
-            self._evict(victim, now, events)
-            put_uids = [u for u in put_uids if u != victim]
+        with self._phase("schedule"):
+            while put_uids:
+                res = eng.check_schedule(put_uids, [1] * len(put_uids))
+                if not any(res.reasons.get(u, "").startswith("kv")
+                           for u in res.rejected):
+                    break
+                victim = self._eviction_victim(now)
+                if victim is None:
+                    break
+                self._evict(victim, now, events)
+                put_uids = [u for u in put_uids if u != victim]
+            submit = bool(put_uids) or any(
+                d.pending for d in eng.seqs.values())
         # 3. submit: decode tokens + (slack-ordered, tenant-capped) prompt
-        # chunks fuse into the same forward inside put()
-        if put_uids or any(d.pending for d in eng.seqs.values()):
-            t0 = self.clock()
-            pend0 = ({u: len(d.pending) for u, d in eng.seqs.items()
-                      if d.pending} if self._tracing else {})
-            res = eng.put(put_uids, [[self._pending_tok[u]] for u in put_uids],
-                          drain=False)
+        # chunks fuse into the same forward inside put(), which splits its
+        # own time into schedule, build, dispatch and collect
+        if not submit:
+            return
+        pend0 = ({u: len(d.pending) for u, d in eng.seqs.items()
+                  if d.pending} if self._tracing else {})
+        res = eng.put(put_uids, [[self._pending_tok[u]] for u in put_uids],
+                      drain=False)
+        with self._phase("account"):
             for uid in res.admission.admitted:
                 self._pending_tok.pop(uid, None)
             t1 = self.clock()
             # prefill-chunk edges: which uids advanced their prompt this
-            # forward and by how many tokens (dur is the whole mixed
-            # forward's wall — chunks share the dispatch, annotation only)
+            # forward and by how many tokens. No duration: put() returns
+            # when the forward is LAUNCHED, and the round record these
+            # point to holds the launch and the round's true ends.
             for u, n0 in pend0.items():
                 d = eng.seqs.get(u)
                 n1 = len(d.pending) if d is not None else 0
                 if n1 < n0:
-                    self._stage(u, "prefill_chunk", t1, dur=t1 - t0,
-                                tokens=n0 - n1)
+                    self._stage(u, "prefill_chunk", t1, tokens=n0 - n1,
+                                round=self._round)
             # first-token landings this pass: prefill capacity samples.
             # DELIBERATELY enqueue-to-first-token per request, not raw
             # forward throughput: the sample folds in the scheduling delay
@@ -1035,7 +1170,8 @@ class ServingSession:
             # caller sees the tokens (step() returns the events after this),
             # which is what makes crash replay exactly-once
             self.journal.emit(req.uid, toks, len(req.out))
-        self._trace("serve/emit", t, {"uid": int(req.uid), "n": len(toks)})
+        self._trace("serve/emit", t, {"uid": int(req.uid), "n": len(toks),
+                                      "round": self._round})
         if req.first_token_s is None:
             req.first_token_s = t
             d = self.eng.seqs.get(req.uid)
@@ -1144,6 +1280,7 @@ class ServingSession:
                   for n, v in self.recovery_counters.items()},
                "queue_depth": len(self.queue),
                "live_seqs": len(self.running),
+               "trace_dropped": self.trace_dropped,
                "kv_occupancy": round(self._kv_occupancy(), 4),
                "prefill_tok_s_est": round(self.capacity.prefill_tok_s, 1),
                "decode_step_s_est": round(self.capacity.decode_step_s, 5)}

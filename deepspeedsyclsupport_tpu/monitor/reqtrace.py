@@ -32,6 +32,7 @@ DELIBERATELY STDLIB-ONLY: ``tools/trace_report.py`` loads this file by path
 on jax-less login nodes (the ``pod.py``/``mfu.py`` contract —
 telemetry/serving import FROM here, never the reverse).
 """
+import contextlib
 import glob as _glob
 import json
 import math
@@ -45,9 +46,12 @@ from typing import (Any, Dict, Iterable, List, Optional, Sequence, Tuple)
 #: other). The rest are DERIVED by the join from the emit/close stream:
 #: ``decode`` from inter-emit gaps, ``finalize`` (last emit → close),
 #: ``unattributed`` (any interval the classifier cannot name — the
-#: reconciliation residual).
+#: reconciliation residual). ``round`` is the session-scope record of one
+#: scheduling round (uid -1: its times, what it launched, its
+#: :data:`ROUND_PHASES`); ``decode_round`` is what older journals carry in
+#: its place and only the join still reads.
 STAMPED_SERVE_STAGES = ("gate", "queue_wait", "requeue_wait", "prefill",
-                        "prefill_chunk", "decode_round", "preempt",
+                        "prefill_chunk", "decode_round", "round", "preempt",
                         "replay", "spool_wait")
 DERIVED_SERVE_STAGES = ("decode", "finalize", "unattributed")
 SERVE_STAGES = STAMPED_SERVE_STAGES + DERIVED_SERVE_STAGES
@@ -65,8 +69,44 @@ FLEET_STAGES = STAMPED_FLEET_STAGES + DERIVED_FLEET_STAGES
 #: satellite family, ``Serve/queue_wait_s``).
 STAGE_HISTOGRAMS = ("prefill", "decode")
 
+#: Phases of one scheduling round (``ServingSession.step``), in the order a
+#: per-token round passes them. The session's ``RoundSpans`` charges every
+#: instant of the round to exactly one of them (``other`` is the residual),
+#: opens a ``dstpu/serve/<phase>`` profiler annotation around each, and
+#: writes their seconds into the ``round`` stage record's ``phases``. A
+#: phase is time on the HOST's clock, not host work alone: ``readback``
+#: asks the device for a value, and ANY launch (a ``collect`` logits slice,
+#: a ``gather`` stack, the next ``dispatch``) blocks while the device's
+#: launch queue is full, so a phase behind a long forward holds that
+#: forward's time. What the host costs the device is read off a profile:
+#: the idle gaps at the round's ends and its number of launches.
+ROUND_PHASES = (
+    "queue",      # _maintain_queue, slack policy, watchdog arm
+    "gather",     # eng.query rows + jnp.stack of the drained logits
+    "sample",     # the sampler's dispatch
+    "readback",   # np.asarray(tokens): the round asks the device for a value
+    "emit",       # events, _note_emission, _finish/flush
+    "schedule",   # KV-pressure loop, check_schedule, schedule_chunks, CoW
+    "build",      # build_ragged_batch / _slot_arrays (numpy only)
+    "dispatch",   # host-to-device copies + the forward's launch, until it returns
+    "collect",    # put() after the launch: logits rows, descriptors, prefix index
+    "account",    # prefill_chunk stamps, capacity samples, progress valve, gauges
+    "other")      # whatever no phase claimed
+
+#: What a ``round`` record says of the forward it launched, counted before
+#: the launch: sequences, tokens, how many of those were prompt (a chunk is
+#: a decode step when it is one token on top of cached context), the cached
+#: tokens attention must read, and the blocks in the sequences' tables.
+FORWARD_FIELDS = ("n_seqs", "tokens", "prefill_tokens", "ctx_tokens",
+                  "kv_blocks")
+
+#: what a phase is where nothing times the round: ``trace_stages`` off, or
+#: an engine driven without a session
+NO_PHASE = contextlib.nullcontext()
+
 _SERVE_STAGE_SET = frozenset(SERVE_STAGES)
 _FLEET_STAGE_SET = frozenset(FLEET_STAGES)
+_ROUND_PHASE_SET = frozenset(ROUND_PHASES)
 
 
 def check_stage(name: str, fleet: bool = False) -> str:
@@ -79,6 +119,16 @@ def check_stage(name: str, fleet: bool = False) -> str:
         declared = FLEET_STAGES if fleet else SERVE_STAGES
         raise ValueError(f"undeclared {kind} stage {name!r}; declared: "
                          f"{declared} (monitor/reqtrace.py)")
+    return name
+
+
+def check_phase(name: str) -> str:
+    """Validate a round-phase literal against :data:`ROUND_PHASES` (the
+    :func:`check_stage` pattern: a typo'd phase must fail loudly, not open a
+    bucket no reader sums)."""
+    if name not in _ROUND_PHASE_SET:
+        raise ValueError(f"undeclared round phase {name!r}; declared: "
+                         f"{ROUND_PHASES} (monitor/reqtrace.py)")
     return name
 
 
@@ -211,8 +261,9 @@ def join_traces(streams: Iterable[Tuple[str, str, Sequence[Dict[str, Any]]]],
     def _push(uid: int, t: float, kind: str, payload: Dict[str, Any]) -> None:
         nonlocal idx
         if int(uid) < 0:
-            # batch-scope stamps (decode_round fanout carriers, the
-            # router's fleet-wide failover_claim) are not requests
+            # batch-scope stamps (round records, which fan out to their
+            # ``uids``; the router's fleet-wide failover_claim) are not
+            # requests
             return
         idx += 1
         events.setdefault(int(uid), []).append((float(t), idx, kind, payload))
@@ -259,7 +310,9 @@ def join_traces(streams: Iterable[Tuple[str, str, Sequence[Dict[str, Any]]]],
                 _push(uid, t, "close", {"reason": data.get("reason", "")})
             elif name == "serve/stage":
                 stage = data.get("stage", "")
-                if stage == "decode_round":
+                if stage in ("round", "decode_round"):
+                    # the round's record names the uids it sampled for
+                    # (journals from before it carried them on decode_round)
                     for u in data.get("uids", ()):
                         _push(u, t, "round",
                               {"mode": data.get("mode", "per_token")})
@@ -397,17 +450,63 @@ def join_traces(streams: Iterable[Tuple[str, str, Sequence[Dict[str, Any]]]],
     return traces
 
 
-def join_root(root: str, since: Optional[float] = None
-              ) -> Dict[int, Dict[str, Any]]:
-    """Disk entry point: discover + load + join a fleet root (or bare
-    journal dir)."""
+def load_root(root: str) -> Tuple[List[Tuple[str, str, List[Dict[str, Any]]]],
+                                  List[Dict[str, Any]]]:
+    """``(streams, router_records)`` of a fleet root (or bare journal dir),
+    in the shapes :func:`join_traces` and :func:`round_phases` take."""
     replicas, router_files = discover_root(root)
     router_records: List[Dict[str, Any]] = []
     for path in router_files:
         router_records.extend(load_stream(path))
     streams = [(rid, file_attempt(path), load_stream(path))
                for rid, files in sorted(replicas.items()) for path in files]
+    return streams, router_records
+
+
+def join_root(root: str, since: Optional[float] = None
+              ) -> Dict[int, Dict[str, Any]]:
+    """Disk entry point: discover + load + join a fleet root (or bare
+    journal dir)."""
+    streams, router_records = load_root(root)
     return join_traces(streams, router_records, since=since)
+
+
+def round_phases(streams: Iterable[Tuple[str, str, Sequence[Dict[str, Any]]]]
+                 ) -> Optional[Dict[str, Any]]:
+    """Where the host's time goes inside a scheduling round, from the
+    ``round`` stage records of ``streams``: per :data:`ROUND_PHASES` phase
+    the p50/p99/mean seconds a round spent in it, the same for the whole
+    round and for how far into it the forward was launched (``launch_s``),
+    and per program launched the number of rounds and the mean of what its
+    forward covered (:data:`FORWARD_FIELDS`). ``None`` when the streams
+    hold no such record (journals from before it, or ``trace_stages``
+    off)."""
+    rounds = [rec["data"] for _rid, _att, records in streams
+              for rec in records if rec.get("name") == "serve/stage"
+              and (rec.get("data") or {}).get("stage") == "round"]
+    if not rounds:
+        return None
+
+    def _summary(vals: List[float]) -> Dict[str, float]:
+        return {"p50": _rank_quantile(vals, 0.5),
+                "p99": _rank_quantile(vals, 0.99),
+                "mean_s": sum(vals) / len(vals)}
+
+    by_program: Dict[str, List[Dict[str, Any]]] = {}
+    for d in rounds:
+        by_program.setdefault(d.get("program") or "(nothing launched)",
+                              []).append(d)
+    launched = [d["launch_t"] - d["t0"] for d in rounds
+                if d.get("launch_t") is not None]
+    return {"rounds": len(rounds),
+            "round_s": _summary([d["t1"] - d["t0"] for d in rounds]),
+            "launch_s": _summary(launched) if launched else None,
+            "programs": {name: {"rounds": len(ds), **{
+                f: sum(d.get(f, 0) for d in ds) / len(ds)
+                for f in FORWARD_FIELDS}} for name, ds in by_program.items()},
+            "phases": {p: _summary([float((d.get("phases") or {}).get(p, 0.0))
+                                    for d in rounds])
+                       for p in ROUND_PHASES}}
 
 
 # =========================================================================

@@ -59,7 +59,7 @@ def paged_decode_attention_pallas(q, k_cache, v_cache, block_tables, seq_lens,
     out = ragged_prefill_attention_pallas(
         q[:, None], k_cache, v_cache, block_tables, pos0, qlen,
         block_size=block_size, alibi=alibi, window=window,
-        interpret=interpret)
+        interpret=interpret, name="paged_decode")
     return out[:, 0]
 
 
@@ -248,12 +248,15 @@ def _ragged_vmem_limit(bq: int, h: int, kvh: int, d: int, block_size: int,
 def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
                                     atom_pos0, atom_qlen, *,
                                     block_size: int, alibi=None, window=None,
-                                    interpret: bool = False):
+                                    interpret: bool = False,
+                                    name: str = "ragged_prefill"):
     """q_atoms: [A, BQ, H, D] (one sequence per atom row block);
     k/v_cache: [num_slots, KVH, D]; atom_tables: [A, Bps] (the owning
     sequence's block-table row per atom); atom_pos0/atom_qlen: [A].
     ``alibi``: per-head slopes [H]; ``window``: sliding-window bound.
-    Returns [A, BQ, H, D]."""
+    ``name`` is what a profile calls the kernel: its custom call's
+    instruction and scope (the decode entry passes its own). Returns
+    [A, BQ, H, D]."""
     a, bq, h, d = q_atoms.shape
     kvh = k_cache.shape[1]
     g = h // kvh
@@ -296,6 +299,7 @@ def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
             vmem_limit_bytes=_ragged_vmem_limit(
                 bq, h, kvh, d, block_size, q_atoms.dtype.itemsize)),
         interpret=interpret,
+        name=name,
     )(jnp.asarray(atom_tables, jnp.int32), jnp.asarray(atom_pos0, jnp.int32),
       jnp.asarray(atom_qlen, jnp.int32), q_atoms, k_cache, v_cache, ab)
 

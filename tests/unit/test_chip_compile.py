@@ -139,3 +139,17 @@ def test_paged_kernel_is_a_tpu_custom_call(one_chip):
     """The compiled text names the Mosaic kernel — the same string
     ``chip_smoke.py`` looks for in the programs it ran on the chip."""
     assert "tpu_custom_call" in _paged_decode(one_chip, "phi-2").as_text()
+
+
+def test_the_two_kernels_carry_their_names_onto_the_custom_call(one_chip):
+    """What a device trace prints for the instruction (it read
+    ``closed_call.<n>`` for both kernels): the decode entry's calls are
+    ``paged_decode``, the prefill entry's ``ragged_prefill``."""
+    for compiled, name in ((_paged_decode(one_chip, "phi-2"), "paged_decode"),
+                           (_ragged_default_atom(one_chip, "phi-2"),
+                            "ragged_prefill")):
+        calls = [ln for ln in compiled.as_text().splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in ln]
+        assert calls and all(
+            ln.split(" = ")[0].split("%")[-1].split(".")[0] == name
+            for ln in calls), calls

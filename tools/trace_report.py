@@ -559,7 +559,8 @@ def requests_report(root: str, worst_n: int = 5, window_s: float = 60.0,
     waterfalls. ``root`` may be a fleet root (``replica*/`` + router
     stream) or a single journal directory."""
     rt = _load_reqtrace()
-    traces = rt.join_root(root)
+    streams, router_records = rt.load_root(root)
+    traces = rt.join_traces(streams, router_records)
     if not traces:
         return None
     att = rt.attribution(traces, worst_n=worst_n, slo_window_s=window_s,
@@ -624,6 +625,25 @@ def requests_report(root: str, worst_n: int = 5, window_s: float = 60.0,
     if dr["fused"] or dr["per_token"]:
         lines.append(f"  decode rounds: {dr['fused']} fused, "
                      f"{dr['per_token']} per-token")
+    rp = rt.round_phases(streams)
+    if rp:
+        launch = (f"; forward launched {_qline(rp['launch_s'])} into it"
+                  if rp["launch_s"] else "")
+        lines.append(f"  round phases ({rp['rounds']} round record(s); round "
+                     f"{_qline(rp['round_s'])}{launch}):")
+        for phase, qs in sorted(rp["phases"].items(),
+                                key=lambda kv: -kv[1]["mean_s"]):
+            if qs["mean_s"] > 0:
+                lines.append(f"    {phase:<14}mean={_fmt_s(qs['mean_s'])}  "
+                             f"{_qline(qs)}")
+        # what each program's forward covered, a round's mean
+        lines.append(f"    {'launched':<20}{'rounds':>7}{'seqs':>8}"
+                     f"{'tokens':>9}{'prompt':>9}{'context':>10}"
+                     f"{'kv blocks':>11}")
+        for name, m in sorted(rp["programs"].items()):
+            lines.append(f"    {name:<20}{m['rounds']:>7}{m['n_seqs']:>8.1f}"
+                         f"{m['tokens']:>9.1f}{m['prefill_tokens']:>9.1f}"
+                         f"{m['ctx_tokens']:>10.1f}{m['kv_blocks']:>11.1f}")
     if att["cached_prefix_tokens_mean"]:
         lines.append(f"  cached prefix: "
                      f"{att['cached_prefix_tokens_mean']:.1f} token(s)/request "
