@@ -3,8 +3,12 @@
 Analog of ``BlockedKVCache`` (``inference/v2/ragged/kv_cache.py``): a pool of
 fixed-size KV blocks; sequences own arbitrary block lists, indirected through
 block tables. Layout [L, num_blocks * block_size, KVH, D] — flat slot axis so
-(de)referencing a slot is ``block_id * block_size + offset`` with one gather /
-scatter, which XLA lowers to efficient dynamic-slice traffic on TPU.
+(de)referencing a slot is ``block_id * block_size + offset``. The serving
+forwards carry the whole pool through their layer loop, scatter new rows
+into ``[layer, slot]`` in place and hand the kernels the pool and the layer
+(``model._pool_write`` / ``_scan_layers``): sliced by layer outside a
+kernel, each layer cost a slice, a copy and a write-back of itself on the
+v5e and the pool was held twice (PERF.md, PR 25).
 """
 from typing import NamedTuple
 

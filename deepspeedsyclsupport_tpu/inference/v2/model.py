@@ -40,9 +40,13 @@ NEG_INF = jnp.finfo(jnp.float32).min
 class PrefillAttnContext(NamedTuple):
     """Everything a prefill-attention implementation may consume — the
     uniform contract registered impls are called with (the reference's
-    ConfigBundle role, ``modules/module_registry.py``)."""
+    ConfigBundle role, ``modules/module_registry.py``). ``k_cache`` /
+    ``v_cache`` are the WHOLE pool [L, num_slots, KVH, D] and ``layer`` the
+    (traced) layer to read: the kernels index it in their DMAs, the other
+    impls slice ``pool[layer]`` at their seam (:func:`_layer_kv`)."""
     k_cache: Any
     v_cache: Any
+    layer: Any
     token_seq: Any
     token_pos: Any
     block_tables: Any
@@ -246,6 +250,12 @@ def _packed_flash_attention(q, k_cache, v_cache, token_seq, token_pos,
     return out[0]
 
 
+def _layer_kv(ctx):
+    """One layer's [num_slots, KVH, D] K and V for the impls that gather
+    from it in XLA (CPU and parity tests; no cell runs them)."""
+    return ctx.k_cache[ctx.layer], ctx.v_cache[ctx.layer]
+
+
 # ------------------------------------------ registered prefill-attn impls
 # (the reference's modules/implementations/* + heuristics, as registry
 # entries; users can register_impl their own and name it in the config)
@@ -267,8 +277,8 @@ def _prefill_kernel_impl(q, ctx: PrefillAttnContext, interpret=False):
     q_at = q[ctx.atom_qidx]                          # [A, BQ, H, D]
     out_at = ragged_prefill_attention(
         q_at, ctx.k_cache, ctx.v_cache, ctx.atom_tables, ctx.atom_pos0,
-        ctx.atom_qlen, block_size=ctx.block_size, alibi=ctx.alibi,
-        window=ctx.window,
+        ctx.atom_qlen, block_size=ctx.block_size, layer=ctx.layer,
+        alibi=ctx.alibi, window=ctx.window,
         impl="pallas_interpret" if interpret else "pallas")
     flat = out_at.reshape(-1, *out_at.shape[2:])
     return flat[ctx.atom_inv]                        # back to packed rows
@@ -284,7 +294,7 @@ def _prefill_kernel_interpret_impl(q, ctx: PrefillAttnContext):
 @register_impl("prefill_attn", "flash", priority=5,
                auto_eligible=lambda c: c.get("backend") == "tpu")
 def _prefill_flash_impl(q, ctx: PrefillAttnContext):
-    return _packed_flash_attention(q, ctx.k_cache, ctx.v_cache,
+    return _packed_flash_attention(q, *_layer_kv(ctx),
                                    ctx.token_seq, ctx.token_pos,
                                    ctx.block_tables, ctx.block_size,
                                    alibi=ctx.alibi, window=ctx.window)
@@ -292,7 +302,7 @@ def _prefill_flash_impl(q, ctx: PrefillAttnContext):
 
 @register_impl("prefill_attn", "xla", priority=0)
 def _prefill_xla_impl(q, ctx: PrefillAttnContext):
-    return _paged_attention(q, ctx.k_cache, ctx.v_cache, ctx.token_seq,
+    return _paged_attention(q, *_layer_kv(ctx), ctx.token_seq,
                             ctx.token_pos, ctx.block_tables, ctx.block_size,
                             alibi=ctx.alibi, window=ctx.window)
 
@@ -305,14 +315,16 @@ def _decode_dispatch(impl_name):
 
         return paged_decode_attention(
             q, ctx.k_cache, ctx.v_cache, ctx.block_tables, ctx.seq_lens,
-            block_size=ctx.block_size, impl=impl_name, alibi=ctx.alibi,
-            window=ctx.window)
+            block_size=ctx.block_size, impl=impl_name, layer=ctx.layer,
+            alibi=ctx.alibi, window=ctx.window)
     return fn
 
 
 class DecodeAttnContext(NamedTuple):
+    """As :class:`PrefillAttnContext`: the whole pool and the layer."""
     k_cache: Any
     v_cache: Any
+    layer: Any
     block_tables: Any
     seq_lens: Any
     block_size: int
@@ -327,6 +339,30 @@ register_impl("decode_attn", "pallas_interpret", priority=-10,
               auto_eligible=lambda c: False)(
     _decode_dispatch("pallas_interpret"))
 register_impl("decode_attn", "xla", priority=0)(_decode_dispatch("xla"))
+
+
+def _pool_write(k_pool, v_pool, layer, dest, k, v):
+    """Scatter the new tokens' K and V rows [n, KVH, d] into layer ``layer``
+    of the pool at flat slots ``dest`` [n] (out-of-range = dropped). A
+    scatter on the WHOLE loop-carried pool: XLA updates it in place, where
+    a per-layer slice as the scan's xs/ys cost a slice, a copy and a
+    write-back of the layer (157 MB at phi-2's pool) for 32 rows."""
+    d_pool = k_pool.shape[-1]
+    with jax.named_scope("kv_pool_write"):
+        return tuple(
+            pool.at[layer, dest].set(_lane_pad(rows, d_pool).astype(pool.dtype),
+                                     mode="drop")
+            for pool, rows in ((k_pool, k), (v_pool, v)))
+
+
+def _scan_layers(layer, x, kv: BlockedKV, layer_params):
+    """The layer loop of both serving forwards: the pool rides as CARRY
+    beside ``x`` (never as the scan's xs/ys, which would slice it by layer
+    and stack a second pool), the stacked params and the layer index as xs."""
+    num_layers = kv.k.shape[0]
+    (x, k_pool, v_pool), _ = jax.lax.scan(
+        layer, (x, kv.k, kv.v), (layer_params, jnp.arange(num_layers)))
+    return x, k_pool, v_pool
 
 
 def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
@@ -356,8 +392,9 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
 
     x = _embed(params, tokens, token_pos, cfg)
 
-    def layer(x, inp):
-        p, k_cache, v_cache = inp
+    def layer(carry, inp):
+        x, k_pool, v_pool = carry
+        p, l = inp
         p = _dequant(p, x.dtype)
 
         # resolved through the pluggable registry (module_registry.py — the
@@ -369,19 +406,13 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
         })
 
         def attn_fn(y):
-            nonlocal k_cache, v_cache
+            nonlocal k_pool, v_pool
             q, k, v = _qkv(p["attn"], y, cfg, t)
             q, k = _positionize(cfg, q, k, token_pos)
-            d_pool = k_cache.shape[-1]
-            q = _lane_pad(q, d_pool, is_q=True)
-            k, v = _lane_pad(k, d_pool), _lane_pad(v, d_pool)
-            with jax.named_scope("kv_pool_write"):
-                k_cache = k_cache.at[dest].set(k.astype(k_cache.dtype),
-                                               mode="drop")
-                v_cache = v_cache.at[dest].set(v.astype(v_cache.dtype),
-                                               mode="drop")
+            k_pool, v_pool = _pool_write(k_pool, v_pool, l, dest, k, v)
+            q = _lane_pad(q, k_pool.shape[-1], is_q=True)
             ctx = PrefillAttnContext(
-                k_cache=k_cache, v_cache=v_cache, token_seq=token_seq,
+                k_cache=k_pool, v_cache=v_pool, layer=l, token_seq=token_seq,
                 token_pos=token_pos, block_tables=block_tables,
                 block_size=bs, alibi=ab, window=window,
                 atom_qidx=atom_qidx, atom_pos0=atom_pos0,
@@ -390,9 +421,9 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
             return spec.fn(q, ctx)[..., :cfg.head_dim]
 
         x = _block(cfg, p, x, attn_fn)
-        return x, (k_cache, v_cache)
+        return (x, k_pool, v_pool), None
 
-    x, (nk, nv) = jax.lax.scan(layer, x, (params["layers"], kv.k, kv.v))
+    x, nk, nv = _scan_layers(layer, x, kv, params["layers"])
 
     x = norm(x, params["final_norm"], cfg)
     h_last = x[last_tok_idx]  # [S, d] — logits_gather
@@ -444,38 +475,29 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
 
     x = _embed(params, tokens, positions, cfg)
 
-    def layer(x, inp):
-        p, k_cache, v_cache = inp
+    def layer(carry, inp):
+        x, k_pool, v_pool = carry
+        p, l = inp
         p = _dequant(p, x.dtype)
 
         spec = select_impl("decode_attn", attn_impl,
                            {"backend": jax.default_backend()})
 
         def attn_fn(y):
-            nonlocal k_cache, v_cache
+            nonlocal k_pool, v_pool
             q, k, v = _qkv(p["attn"], y, cfg, s)
             q, k = _positionize(cfg, q, k, positions)
-            d_pool = k_cache.shape[-1]
-            q = _lane_pad(q, d_pool, is_q=True)
-            k, v = _lane_pad(k, d_pool), _lane_pad(v, d_pool)
-            with jax.named_scope("kv_pool_write"):
-                k_cache = k_cache.at[dest].set(k.astype(k_cache.dtype),
-                                               mode="drop")
-                v_cache = v_cache.at[dest].set(v.astype(v_cache.dtype),
-                                               mode="drop")
+            k_pool, v_pool = _pool_write(k_pool, v_pool, l, dest, k, v)
+            q = _lane_pad(q, k_pool.shape[-1], is_q=True)
             return spec.fn(q, DecodeAttnContext(
-                k_cache=k_cache, v_cache=v_cache, block_tables=block_tables,
-                seq_lens=seq_lens, block_size=bs, alibi=ab,
-                window=window))[..., :cfg.head_dim]
+                k_cache=k_pool, v_cache=v_pool, layer=l,
+                block_tables=block_tables, seq_lens=seq_lens, block_size=bs,
+                alibi=ab, window=window))[..., :cfg.head_dim]
 
         x = _block(cfg, p, x, attn_fn)
-        return x, (k_cache, v_cache)
+        return (x, k_pool, v_pool), None
 
-    # the scan slices each layer's [num_slots, KVH, D] out of the pool and
-    # puts it back: on the chip those whole-pool slices and copies, not the
-    # kernel, are most of this program — the scope names them in a profile
-    with jax.named_scope("kv_pool_layers"):
-        x, (nk, nv) = jax.lax.scan(layer, x, (params["layers"], kv.k, kv.v))
+    x, nk, nv = _scan_layers(layer, x, kv, params["layers"])
     x = norm(x, params["final_norm"], cfg)
     logits = _unembed(params, x, cfg)
     return logits.astype(jnp.float32), BlockedKV(nk, nv)

@@ -42,12 +42,14 @@ _VMEM_CAP = 100 << 20
 
 # --------------------------------------------------------------------- kernel
 def paged_decode_attention_pallas(q, k_cache, v_cache, block_tables, seq_lens,
-                                  *, block_size: int,
+                                  *, block_size: int, layer=0,
                                   alibi=None, window=None,
                                   interpret: bool = False):
-    """q: [S, H, D]; k/v_cache: [num_slots, KVH, D]; block_tables: [S, Bps];
-    seq_lens: [S] valid KV tokens per slot. ``alibi``: per-head slopes [H];
-    ``window``: sliding-window bound. Returns [S, H, D].
+    """q: [S, H, D]; k/v_cache: [num_slots, KVH, D], or the whole pool
+    [L, num_slots, KVH, D] with ``layer`` naming the one to read;
+    block_tables: [S, Bps]; seq_lens: [S] valid KV tokens per slot.
+    ``alibi``: per-head slopes [H]; ``window``: sliding-window bound.
+    Returns [S, H, D].
 
     Decode IS the single-row case of the generalized ragged kernel below
     (the paper's prefill/decode unification): each slot becomes a BQ=1 atom
@@ -58,14 +60,14 @@ def paged_decode_attention_pallas(q, k_cache, v_cache, block_tables, seq_lens,
     qlen = jnp.where(seq_lens > 0, 1, 0).astype(jnp.int32)
     out = ragged_prefill_attention_pallas(
         q[:, None], k_cache, v_cache, block_tables, pos0, qlen,
-        block_size=block_size, alibi=alibi, window=window,
+        block_size=block_size, layer=layer, alibi=alibi, window=window,
         interpret=interpret, name="paged_decode")
     return out[:, 0]
 
 
 # ------------------------------------------------------------------ reference
 def paged_decode_attention_reference(q, k_cache, v_cache, block_tables,
-                                     seq_lens, *, block_size: int,
+                                     seq_lens, *, block_size: int, layer=0,
                                      alibi=None, window=None):
     """Exact jnp oracle — decode as the BQ=1 case of the ragged reference
     (one oracle to maintain, mirroring the Pallas unification)."""
@@ -73,35 +75,35 @@ def paged_decode_attention_reference(q, k_cache, v_cache, block_tables,
     out = ragged_prefill_attention_reference(
         q[:, None], k_cache, v_cache, block_tables,
         jnp.maximum(seq_lens - 1, 0), (seq_lens > 0).astype(jnp.int32),
-        block_size=block_size, alibi=alibi, window=window)
+        block_size=block_size, layer=layer, alibi=alibi, window=window)
     return out[:, 0]
 
 
+def _resolve_impl(impl: str, what: str) -> str:
+    if impl == "auto":
+        return "pallas" if jax.default_backend() == "tpu" else "xla"
+    if impl not in ("pallas", "pallas_interpret", "xla"):
+        raise ValueError(f"unknown {what} attention impl {impl!r} "
+                         f"(auto | pallas | pallas_interpret | xla)")
+    return impl
+
+
 def paged_decode_attention(q, k_cache, v_cache, block_tables, seq_lens, *,
-                           block_size: int, impl: str = "auto",
+                           block_size: int, impl: str = "auto", layer=0,
                            alibi=None, window=None):
     """Dispatch (the op-binding seam, like ``models/layers.attention``)."""
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
-    if impl == "pallas":
-        return paged_decode_attention_pallas(
-            q, k_cache, v_cache, block_tables, seq_lens,
-            block_size=block_size, alibi=alibi, window=window)
-    if impl == "pallas_interpret":
-        return paged_decode_attention_pallas(
-            q, k_cache, v_cache, block_tables, seq_lens,
-            block_size=block_size, alibi=alibi, window=window,
-            interpret=True)
-    if impl != "xla":
-        raise ValueError(f"unknown paged decode attention impl {impl!r} "
-                         f"(auto | pallas | pallas_interpret | xla)")
-    return paged_decode_attention_reference(
-        q, k_cache, v_cache, block_tables, seq_lens, block_size=block_size,
-        alibi=alibi, window=window)
+    impl = _resolve_impl(impl, "paged decode")
+    kw = dict(block_size=block_size, layer=layer, alibi=alibi, window=window)
+    if impl == "xla":
+        return paged_decode_attention_reference(
+            q, k_cache, v_cache, block_tables, seq_lens, **kw)
+    return paged_decode_attention_pallas(
+        q, k_cache, v_cache, block_tables, seq_lens,
+        interpret=impl == "pallas_interpret", **kw)
 
 
 # ===================================================================== prefill
-def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref,  # scalar prefetch
+def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
                     q_ref, k_hbm, v_hbm, ab_ref,           # tensors
                     out_ref,                               # output
                     k_vmem, v_vmem, sem,                   # scratch
@@ -118,6 +120,7 @@ def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref,  # scalar prefetch
     a = pl.program_id(0)
     pos0 = pos0_ref[a]
     qlen = qlen_ref[a]
+    layer = layer_ref[0]   # which [num_slots, KVH, D] of the pool to read
     # kv tokens this atom may see, clamped to the block table's capacity so
     # the prefetch below can never index past the table or start a DMA that
     # is never awaited
@@ -142,10 +145,12 @@ def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref,  # scalar prefetch
     def copies(j, slot):
         blk = block_tables_ref[a, j]
         cp_k = pltpu.make_async_copy(
-            k_hbm.at[pl.ds(blk * block_size, block_size)], k_vmem.at[slot],
+            k_hbm.at[layer, pl.ds(blk * block_size, block_size)],
+            k_vmem.at[slot],
             sem.at[slot, 0])
         cp_v = pltpu.make_async_copy(
-            v_hbm.at[pl.ds(blk * block_size, block_size)], v_vmem.at[slot],
+            v_hbm.at[layer, pl.ds(blk * block_size, block_size)],
+            v_vmem.at[slot],
             sem.at[slot, 1])
         return cp_k, cp_v
 
@@ -247,18 +252,23 @@ def _ragged_vmem_limit(bq: int, h: int, kvh: int, d: int, block_size: int,
 
 def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
                                     atom_pos0, atom_qlen, *,
-                                    block_size: int, alibi=None, window=None,
-                                    interpret: bool = False,
+                                    block_size: int, layer=0, alibi=None,
+                                    window=None, interpret: bool = False,
                                     name: str = "ragged_prefill"):
     """q_atoms: [A, BQ, H, D] (one sequence per atom row block);
-    k/v_cache: [num_slots, KVH, D]; atom_tables: [A, Bps] (the owning
-    sequence's block-table row per atom); atom_pos0/atom_qlen: [A].
-    ``alibi``: per-head slopes [H]; ``window``: sliding-window bound.
-    ``name`` is what a profile calls the kernel: its custom call's
-    instruction and scope (the decode entry passes its own). Returns
-    [A, BQ, H, D]."""
+    k/v_cache: the whole pool [L, num_slots, KVH, D], read at ``layer``
+    (a traced scalar inside the serving forwards' layer loop: the pool
+    stays in HBM and is never sliced by layer outside the kernel), or one
+    layer's [num_slots, KVH, D] — the L = 1, layer 0 case of the same body;
+    atom_tables: [A, Bps] (the owning sequence's block-table row per atom);
+    atom_pos0/atom_qlen: [A]. ``alibi``: per-head slopes [H]; ``window``:
+    sliding-window bound. ``name`` is what a profile calls the kernel: its
+    custom call's instruction and scope (the decode entry passes its own).
+    Returns [A, BQ, H, D]."""
     a, bq, h, d = q_atoms.shape
-    kvh = k_cache.shape[1]
+    if k_cache.ndim == 3:
+        k_cache, v_cache, layer = k_cache[None], v_cache[None], 0
+    kvh = k_cache.shape[2]
     g = h // kvh
     max_blocks = atom_tables.shape[1]
     if alibi is not None:
@@ -269,7 +279,7 @@ def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
     else:
         ab = jnp.zeros((kvh, bq * g, 1), jnp.float32)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(a,),
         in_specs=[
             pl.BlockSpec((1, bq, h, d), lambda i, *_: (i, 0, 0, 0),
@@ -301,15 +311,19 @@ def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
         interpret=interpret,
         name=name,
     )(jnp.asarray(atom_tables, jnp.int32), jnp.asarray(atom_pos0, jnp.int32),
-      jnp.asarray(atom_qlen, jnp.int32), q_atoms, k_cache, v_cache, ab)
+      jnp.asarray(atom_qlen, jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q_atoms, k_cache, v_cache, ab)
 
 
 def ragged_prefill_attention_reference(q_atoms, k_cache, v_cache, atom_tables,
                                        atom_pos0, atom_qlen, *,
-                                       block_size: int, alibi=None,
+                                       block_size: int, layer=0, alibi=None,
                                        window=None):
-    """Exact jnp oracle for the prefill kernel (parity tests + off-TPU)."""
+    """Exact jnp oracle for the prefill kernel (parity tests + off-TPU).
+    Given the whole pool it slices ``pool[layer]`` here, at its seam."""
     a, bq, h, d = q_atoms.shape
+    if k_cache.ndim == 4:
+        k_cache, v_cache = k_cache[layer], v_cache[layer]
     kvh = k_cache.shape[1]
     bps = atom_tables.shape[1]
     max_ctx = bps * block_size
@@ -343,21 +357,14 @@ def ragged_prefill_attention_reference(q_atoms, k_cache, v_cache, atom_tables,
 
 def ragged_prefill_attention(q_atoms, k_cache, v_cache, atom_tables,
                              atom_pos0, atom_qlen, *, block_size: int,
-                             impl: str = "auto", alibi=None, window=None):
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "xla"
-    if impl == "pallas":
-        return ragged_prefill_attention_pallas(
+                             impl: str = "auto", layer=0, alibi=None,
+                             window=None):
+    impl = _resolve_impl(impl, "ragged prefill")
+    kw = dict(block_size=block_size, layer=layer, alibi=alibi, window=window)
+    if impl == "xla":
+        return ragged_prefill_attention_reference(
             q_atoms, k_cache, v_cache, atom_tables, atom_pos0, atom_qlen,
-            block_size=block_size, alibi=alibi, window=window)
-    if impl == "pallas_interpret":
-        return ragged_prefill_attention_pallas(
-            q_atoms, k_cache, v_cache, atom_tables, atom_pos0, atom_qlen,
-            block_size=block_size, alibi=alibi, window=window,
-            interpret=True)
-    if impl != "xla":
-        raise ValueError(f"unknown ragged prefill attention impl {impl!r} "
-                         f"(auto | pallas | pallas_interpret | xla)")
-    return ragged_prefill_attention_reference(
+            **kw)
+    return ragged_prefill_attention_pallas(
         q_atoms, k_cache, v_cache, atom_tables, atom_pos0, atom_qlen,
-        block_size=block_size, alibi=alibi, window=window)
+        interpret=impl == "pallas_interpret", **kw)

@@ -91,48 +91,70 @@ def test_flash_compiles(one_chip, name, grad):
 
 
 # ------------------------------------------------------------------- paged
-def _paged_shapes(name):
+# what the kernels are handed: one layer's [slots, KVH, D] cache (the unit
+# tests, chip_smoke.py) or, as in the serving forwards, the whole pool
+# [L, slots, KVH, D] and a traced layer index their DMAs take
+CACHE_FORMS = ["layer_cache", "whole_pool"]
+
+
+def _paged_shapes(name, form="layer_cache"):
     """Kernel operands at the engine's DEFAULT geometry for ``name``: the
-    pool's head dim is lane-padded to 128 on TPU (phi-2: 80 -> 128)."""
-    cfg = RaggedInferenceConfig()
+    pool's head dim is lane-padded to 128 on TPU (phi-2: 80 -> 128). The
+    whole pool has the model's full depth and, for phi-2, the benchmark
+    cell's 150 blocks (32 layers x 9600 slots x 32 heads x 128)."""
+    cfg = RaggedInferenceConfig(num_blocks=150 if name == "phi-2" else None)
     h, kvh, d = _widths(name)
     d = -(-d // 128) * 128
-    slots = cfg.num_blocks * cfg.block_size
-    return cfg, h, kvh, d, ((slots, kvh, d), jnp.bfloat16)
+    cache = (cfg.num_blocks * cfg.block_size, kvh, d)
+    if form == "whole_pool":
+        cache = (get_config(name).num_layers,) + cache
+    return cfg, h, d, (cache, jnp.bfloat16)
 
 
-def _paged_decode(one_chip, name):
-    cfg, h, kvh, d, pool = _paged_shapes(name)
+def _with_layer(kernel, form, **static):
+    """``kernel`` with its statics bound; for the whole pool the layer is
+    the LAST positional operand, a traced int32 scalar."""
+    f = functools.partial(kernel, **static)
+    if form == "layer_cache":
+        return f, ()
+    return (lambda *args: f(*args[:-1], layer=args[-1])), (((), jnp.int32),)
+
+
+def _paged_decode(one_chip, name, form="layer_cache"):
+    cfg, h, d, cache = _paged_shapes(name, form)
     s = cfg.max_sequences
-    window = get_config(name).sliding_window
-    f = functools.partial(paged_decode_attention_pallas,
-                          block_size=cfg.block_size, window=window)
-    return _compile(f, one_chip, ((s, h, d), jnp.bfloat16), pool, pool,
-                    ((s, cfg.blocks_per_seq), jnp.int32), ((s,), jnp.int32))
+    f, layer = _with_layer(paged_decode_attention_pallas, form,
+                           block_size=cfg.block_size,
+                           window=get_config(name).sliding_window)
+    return _compile(f, one_chip, ((s, h, d), jnp.bfloat16), cache, cache,
+                    ((s, cfg.blocks_per_seq), jnp.int32), ((s,), jnp.int32),
+                    *layer)
 
 
+@pytest.mark.parametrize("form", CACHE_FORMS)
 @pytest.mark.parametrize("name", ["mistral-7b", "phi-2"])
-def test_paged_decode_compiles(one_chip, name):
-    _paged_decode(one_chip, name)
+def test_paged_decode_compiles(one_chip, name, form):
+    _paged_decode(one_chip, name, form)
 
 
-def _ragged_default_atom(one_chip, name):
-    cfg, h, kvh, d, pool = _paged_shapes(name)
+def _ragged_default_atom(one_chip, name, form="layer_cache"):
+    cfg, h, d, cache = _paged_shapes(name, form)
     bq = cfg.atom_q_size   # the DEFAULT atom: no user-picked atom_q_size
     atoms = cfg.max_tokens_per_batch // bq + cfg.max_sequences
-    window = get_config(name).sliding_window
-    f = functools.partial(ragged_prefill_attention_pallas,
-                          block_size=cfg.block_size, window=window)
-    return _compile(f, one_chip, ((atoms, bq, h, d), jnp.bfloat16), pool,
-                    pool, ((atoms, cfg.blocks_per_seq), jnp.int32),
-                    ((atoms,), jnp.int32), ((atoms,), jnp.int32))
+    f, layer = _with_layer(ragged_prefill_attention_pallas, form,
+                           block_size=cfg.block_size,
+                           window=get_config(name).sliding_window)
+    return _compile(f, one_chip, ((atoms, bq, h, d), jnp.bfloat16), cache,
+                    cache, ((atoms, cfg.blocks_per_seq), jnp.int32),
+                    ((atoms,), jnp.int32), ((atoms,), jnp.int32), *layer)
 
 
+@pytest.mark.parametrize("form", CACHE_FORMS)
 @pytest.mark.parametrize("name", ["mistral-7b", "phi-2"])
-def test_ragged_prefill_compiles_with_default_atom(one_chip, name):
+def test_ragged_prefill_compiles_with_default_atom(one_chip, name, form):
     """Refused before this file existed: a 128-row atom at 32 heads x d 128
     needs 21-22 MiB of VMEM against Mosaic's 16 MiB default scoped limit."""
-    _ragged_default_atom(one_chip, name)
+    _ragged_default_atom(one_chip, name, form)
 
 
 def test_paged_kernel_is_a_tpu_custom_call(one_chip):
@@ -145,9 +167,10 @@ def test_the_two_kernels_carry_their_names_onto_the_custom_call(one_chip):
     """What a device trace prints for the instruction (it read
     ``closed_call.<n>`` for both kernels): the decode entry's calls are
     ``paged_decode``, the prefill entry's ``ragged_prefill``."""
-    for compiled, name in ((_paged_decode(one_chip, "phi-2"), "paged_decode"),
-                           (_ragged_default_atom(one_chip, "phi-2"),
-                            "ragged_prefill")):
+    for compiled, name in (
+            (_paged_decode(one_chip, "phi-2", "whole_pool"), "paged_decode"),
+            (_ragged_default_atom(one_chip, "phi-2", "whole_pool"),
+             "ragged_prefill")):
         calls = [ln for ln in compiled.as_text().splitlines()
                  if 'custom_call_target="tpu_custom_call"' in ln]
         assert calls and all(

@@ -1,0 +1,201 @@
+"""The serving forwards keep the KV pool in place (ISSUE 25).
+
+``decode_forward`` / ``ragged_forward`` carry the pool [L, slots, KVH, D]
+through their layer loop and hand it whole, with the layer index, to the
+attention impls; before, the pool was the scan's xs/ys and XLA sliced,
+copied and wrote back one whole layer of it per layer for a few new rows.
+
+* frozen case: the three forwards return what the parent commit returned
+  (``data/kv_pool_forward_golden.npz``, written by running THIS file as a
+  script against a checkout of the commit to freeze:
+  ``PYTHONPATH=<checkout> python tests/unit/test_kv_pool_in_place.py``) —
+  logits, emitted tokens, the rows written, every other row bit-identical;
+* structure: compiled with a pool far larger than everything else, neither
+  forward needs a temporary the size of one layer's K + V, and the donated
+  pool is the output (a count from ``memory_analysis()``, not a time).
+"""
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeedsyclsupport_tpu.inference.v2 import model as M
+from deepspeedsyclsupport_tpu.inference.v2.kv_cache import BlockedKV
+from deepspeedsyclsupport_tpu.inference.v2.ragged import (SequenceDescriptor,
+                                                          build_ragged_batch)
+from deepspeedsyclsupport_tpu.models import build_model, get_config
+
+GOLDEN = os.path.join(os.path.dirname(__file__), "data",
+                      "kv_pool_forward_golden.npz")
+BS, BPS, S, T, BQ = 8, 4, 3, 16, 8     # block size, blocks/seq, slots, tokens
+NUM_BLOCKS = S * BPS + 1
+# (decode impl, prefill impl) pairs the frozen case runs under
+IMPLS = {"xla": ("xla", "xla"),
+         "pallas_interpret": ("pallas_interpret", "kernel_interpret")}
+
+
+def _model(num_layers=3, **kw):
+    cfg = dataclasses.replace(get_config("tiny"), dtype="float32",
+                              num_layers=num_layers, **kw)
+    model = build_model(cfg)
+    return model, model.init_params()
+
+
+def _pool(cfg, num_slots, seed=0):
+    """A pool of seeded noise: rows no forward writes must come back as is."""
+    shape = (cfg.num_layers, num_slots, cfg.num_kv_heads, cfg.head_dim)
+    k, v = jax.random.split(jax.random.PRNGKey(seed))
+    return BlockedKV(jax.random.normal(k, shape, jnp.float32),
+                     jax.random.normal(v, shape, jnp.float32))
+
+
+# three sequences over disjoint, out-of-order blocks: 11, 9 and 0 tokens cached
+TABLES = np.asarray([[5, 2, 9, 0], [7, 11, 1, 3], [4, 6, 8, 10]], np.int32)
+CACHED = np.asarray([11, 9, 0], np.int32)
+
+
+def _slots(seq, positions):
+    positions = np.asarray(positions)
+    return TABLES[seq, positions // BS] * BS + positions % BS
+
+
+def _decode_case(decode_impl):
+    """Slots 0 and 1 decode one token each, slot 2 is idle."""
+    model, params = _model()
+    kv = _pool(model.config, NUM_BLOCKS * BS)
+    tokens = jnp.asarray([17, 230, 0], jnp.int32)
+    active = jnp.asarray([True, True, False])
+    logits, new = M.decode_forward(
+        model, params, kv, tokens, jnp.asarray(CACHED), jnp.asarray(TABLES),
+        active, block_size=BS, attn_impl=decode_impl)
+    written = np.concatenate([_slots(0, [11]), _slots(1, [9])])
+    return kv, {"logits": logits[:2]}, new, written
+
+
+def _ragged_case(prefill_impl):
+    """Slot 0 continues a prompt with 6 tokens (crossing a block edge),
+    slot 1 decodes one, slot 2 starts a 9-token prompt (two atoms)."""
+    model, params = _model()
+    kv = _pool(model.config, NUM_BLOCKS * BS)
+    rng = np.random.RandomState(3)
+    chunks = [(SequenceDescriptor(uid=i, pending=list(rng.randint(1, 500, n)),
+                                  n_cached=int(CACHED[i]),
+                                  blocks=list(TABLES[i])), n)
+              for i, n in enumerate((6, 1, 9))]
+    b = build_ragged_batch(chunks, T, S, BPS, atom_q=BQ)
+    atoms = () if prefill_impl == "xla" else tuple(
+        jnp.asarray(a) for a in (b.atom_qidx, b.atom_pos0, b.atom_qlen,
+                                 b.atom_tables, b.atom_inv))
+    logits, new = M.ragged_forward(
+        model, params, kv, *(jnp.asarray(a) for a in (
+            b.tokens, b.token_seq, b.token_pos, b.block_tables,
+            b.last_tok_idx)), *atoms, block_size=BS, attn_impl=prefill_impl)
+    written = np.concatenate([_slots(0, range(11, 17)), _slots(1, [9]),
+                              _slots(2, range(9))])
+    return kv, {"logits": logits}, new, written
+
+
+def _multi_case(decode_impl):
+    """Fused greedy decode: slot 0 has a budget of 4 tokens, slot 1 of 2."""
+    model, params = _model()
+    kv = _pool(model.config, NUM_BLOCKS * BS)
+    logits0 = jax.random.normal(jax.random.PRNGKey(5),
+                                (S, model.config.vocab_size), jnp.float32)
+    buf, logits, pos, act, left, new = M.decode_multi_forward(
+        model, params, kv, logits0, jnp.asarray(CACHED), jnp.asarray(TABLES),
+        jnp.asarray([True, True, False]), jnp.asarray([4, 2, 0], jnp.int32),
+        jax.random.PRNGKey(0), jnp.float32(1.0), jnp.float32(1.0),
+        jnp.int32(-1), block_size=BS, num_steps=4,
+        samp_struct=(False, 0, False), max_context=BS * BPS,
+        attn_impl=decode_impl)
+    # the token that spends a budget is emitted, never appended
+    written = np.concatenate([_slots(0, range(11, 14)), _slots(1, [9])])
+    return kv, {"tokens": buf, "logits": logits[:2], "pos": pos,
+                "left": left}, new, written
+
+
+CASES = {"decode_forward": (_decode_case, 0),
+         "ragged_forward": (_ragged_case, 1),
+         "decode_multi_forward": (_multi_case, 0)}
+
+
+def _run(program, impl):
+    case, which = CASES[program]
+    kv, outs, new, written = case(IMPLS[impl][which])
+    outs = {k: np.asarray(v) for k, v in outs.items()}
+    outs["k_rows"] = np.asarray(new.k)[:, written]
+    outs["v_rows"] = np.asarray(new.v)[:, written]
+    return kv, outs, new, written
+
+
+@pytest.mark.parametrize("impl", sorted(IMPLS))
+@pytest.mark.parametrize("program", sorted(CASES))
+def test_forward_returns_what_it_returned_before(program, impl):
+    golden = np.load(GOLDEN)
+    kv, outs, new, written = _run(program, impl)
+    for name, got in outs.items():
+        want = golden[f"{program}.{name}"]
+        if got.dtype.kind in "iu":
+            np.testing.assert_array_equal(got, want, err_msg=name)
+        else:
+            np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4,
+                                       err_msg=name)
+    untouched = np.setdiff1d(np.arange(kv.num_slots), written)
+    for before, after in ((kv.k, new.k), (kv.v, new.v)):
+        assert after.shape == before.shape
+        np.testing.assert_array_equal(np.asarray(after)[:, untouched],
+                                      np.asarray(before)[:, untouched])
+
+
+# ---------------------------------------------------------------- structure
+def _compiled(program):
+    """The jitted program (pool donated, as the engine builds it) lowered
+    with a pool of 128 MiB a layer (K + V) beside a model and a batch of
+    well under 1 MiB."""
+    model, params = _model(num_layers=2)
+    cfg = model.config
+    slots = 1024 * BS
+    pool = jax.ShapeDtypeStruct(
+        (cfg.num_layers, slots, cfg.num_kv_heads, 1024), jnp.float32)
+    kv = BlockedKV(pool, pool)
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    if program == "decode_forward":
+        fn = M.build_decode_forward_fn(model, BS, "xla")
+        args = (i32(S), i32(S), i32(S, BPS),
+                jax.ShapeDtypeStruct((S,), jnp.bool_))
+    else:
+        fn = M.build_ragged_forward_fn(model, BS, "xla")
+        args = (i32(T), i32(T), i32(T), i32(S, BPS), i32(S))
+    layer_kv = 2 * slots * cfg.num_kv_heads * 1024 * 4
+    return fn.lower(params, kv, *args).compile(), layer_kv, cfg.num_layers
+
+
+@pytest.mark.parametrize("program", ["decode_forward", "ragged_forward"])
+def test_forward_neither_copies_nor_doubles_the_pool(program):
+    """Would have caught the per-layer slice, copy and write-back: as the
+    scan's xs/ys the pool was held twice and each layer's K and V copied."""
+    compiled, layer_kv, num_layers = _compiled(program)
+    mem = compiled.memory_analysis()
+    pool = num_layers * layer_kv
+    assert mem.temp_size_in_bytes < layer_kv, (
+        f"{program} holds {mem.temp_size_in_bytes} B of temporaries: one "
+        f"layer's K + V is {layer_kv} B, so something copies a layer")
+    assert mem.alias_size_in_bytes >= pool, "the donated pool is not reused"
+    assert (mem.output_size_in_bytes - mem.alias_size_in_bytes
+            + mem.temp_size_in_bytes) < 0.5 * pool
+
+
+if __name__ == "__main__":   # freeze the case from the code on PYTHONPATH
+    out = {}
+    for program in CASES:
+        for name, val in _run(program, "xla")[1].items():
+            out[f"{program}.{name}"] = val
+    os.makedirs(os.path.dirname(GOLDEN), exist_ok=True)
+    np.savez(GOLDEN, **out)
+    print({k: v.shape for k, v in out.items()})

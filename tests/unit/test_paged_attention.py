@@ -24,16 +24,65 @@ def _setup(rng, s=3, h=8, kvh=4, d=32, bs=16, bps=4, seq_lens=None):
     return q, k, v, jnp.asarray(tables), jnp.asarray(lens)
 
 
+def _in_pool(cache, layer, num_layers=3):
+    """``cache`` [slots, KVH, D] as layer ``layer`` of a whole pool
+    [L, slots, KVH, D] whose other layers hold other noise (the serving
+    forwards hand the kernels the pool and the layer, never a slice)."""
+    noise = jax.random.normal(jax.random.PRNGKey(11),
+                              (num_layers,) + cache.shape, cache.dtype)
+    return noise.at[layer].set(cache)
+
+
+# None: one layer's 3-D cache, as before; 0 and L-1: the same cache read as
+# that layer of a 4-D pool — the kernel entries tell the two apart by rank
+POOL_LAYERS = [None, 0, 2]
+ARCH_KW = {"plain": {}, "alibi": {"alibi": True}, "window": {"window": 6},
+           "alibi_window": {"alibi": True, "window": 9}}
+
+
+def _arch_kw(name, h):
+    from deepspeedsyclsupport_tpu.models.layers import alibi_slopes
+
+    kw = dict(ARCH_KW[name])
+    if kw.pop("alibi", False):
+        kw["alibi"] = jnp.asarray(alibi_slopes(h))
+    return kw
+
+
+def _assert_pool_layer_parity(call, k, v, layer, ref):
+    """``call(k, v, **kw)`` runs the kernel; with ``layer`` it must return,
+    from the pool, exactly what it returns from the layer's own cache."""
+    flat = call(k, v)
+    np.testing.assert_allclose(np.asarray(flat), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    if layer is not None:
+        k_pool, v_pool = _in_pool(k, layer), _in_pool(v, layer)
+        pooled = call(k_pool, v_pool, layer=layer)
+        np.testing.assert_array_equal(np.asarray(pooled), np.asarray(flat))
+        # a traced layer, as inside the forwards' layer loop
+        traced = jax.jit(lambda kp, vp, l: call(kp, vp, layer=l))(
+            k_pool, v_pool, jnp.int32(layer))
+        np.testing.assert_array_equal(np.asarray(traced), np.asarray(flat))
+
+
 class TestPagedDecodeParity:
+    @pytest.mark.parametrize("layer", POOL_LAYERS)
+    @pytest.mark.parametrize("arch", ["plain", "alibi_window"])
     @pytest.mark.parametrize("seq_lens", [[64, 19, 1], [5, 5, 5], [64, 64, 64]])
-    def test_kernel_matches_reference(self, seq_lens):
-        q, k, v, tables, lens = _setup(0, seq_lens=seq_lens)
+    def test_kernel_matches_reference(self, seq_lens, arch, layer):
+        q, k, v, tables, lens = _setup(0, seq_lens=seq_lens)   # GQA 8 over 4
+        kw = _arch_kw(arch, q.shape[1])
         ref = paged_decode_attention_reference(q, k, v, tables, lens,
-                                               block_size=16)
-        got = paged_decode_attention(q, k, v, tables, lens, block_size=16,
-                                     impl="pallas_interpret")
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
+                                               block_size=16, **kw)
+        if layer is not None:   # the oracle slices pool[layer] at its seam
+            np.testing.assert_array_equal(
+                np.asarray(paged_decode_attention_reference(
+                    q, _in_pool(k, layer), _in_pool(v, layer), tables, lens,
+                    block_size=16, layer=layer, **kw)), np.asarray(ref))
+        _assert_pool_layer_parity(
+            lambda kc, vc, **at: paged_decode_attention(
+                q, kc, vc, tables, lens, block_size=16,
+                impl="pallas_interpret", **kw, **at), k, v, layer, ref)
 
     def test_mha_no_gqa(self):
         q, k, v, tables, lens = _setup(1, h=4, kvh=4)
@@ -169,10 +218,12 @@ class TestEngineKernelPath:
             assert g == seq[len(p):]
 
 
-def test_ragged_prefill_alibi_window_parity():
+@pytest.mark.parametrize("layer", POOL_LAYERS)
+@pytest.mark.parametrize("arch", ["alibi", "window", "alibi_window"])
+def test_ragged_prefill_alibi_window_parity(arch, layer):
     """ALiBi + sliding window through the atom kernel (bloom/mistral TTFT
-    stays on the fast path)."""
-    from deepspeedsyclsupport_tpu.models.layers import alibi_slopes
+    stays on the fast path), GQA 4 over 2; from one layer's cache and from
+    the whole pool."""
     from deepspeedsyclsupport_tpu.ops.paged_attention import (
         ragged_prefill_attention_pallas, ragged_prefill_attention_reference)
 
@@ -184,15 +235,13 @@ def test_ragged_prefill_alibi_window_parity():
     tables = jnp.asarray(rng.randint(0, 8, (A, bps)), jnp.int32)
     pos0 = jnp.asarray([0, 13, 5], jnp.int32)
     qlen = jnp.asarray([16, 9, 4], jnp.int32)
-    sl = jnp.asarray(alibi_slopes(h))
-    for kw in (dict(alibi=sl), dict(window=6), dict(alibi=sl, window=9)):
-        ref = ragged_prefill_attention_reference(
-            q, k_cache, v_cache, tables, pos0, qlen, block_size=bs, **kw)
-        got = ragged_prefill_attention_pallas(
-            q, k_cache, v_cache, tables, pos0, qlen, block_size=bs,
-            interpret=True, **kw)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   atol=2e-5, rtol=2e-5)
+    kw = _arch_kw(arch, h)
+    ref = ragged_prefill_attention_reference(
+        q, k_cache, v_cache, tables, pos0, qlen, block_size=bs, **kw)
+    _assert_pool_layer_parity(
+        lambda kc, vc, **at: ragged_prefill_attention_pallas(
+            q, kc, vc, tables, pos0, qlen, block_size=bs, interpret=True,
+            **kw, **at), k_cache, v_cache, layer, ref)
 
 
 def test_engine_kernel_path_alibi_and_window():

@@ -155,18 +155,40 @@ class TestFlashLowering:
 
 
 # ----------------------------------------------------- paged/ragged attention
+# the paged kernels' K/V operand: one layer's [slots, KVH, D] cache, or the
+# serving forwards' whole pool at phi-2's benchmark widths with a traced
+# layer index (a Mosaic refusal of ``k_hbm.at[layer, pl.ds(...)]`` shows here)
+PAGED_GEOMETRY = {
+    "layer_cache": dict(layers=None, slots=8192, bs=128, bps=16, h=H,
+                        kvh=KVH, d=D),
+    "phi2_whole_pool": dict(layers=32, slots=9600, bs=64, bps=32, h=32,
+                            kvh=32, d=128),
+}
+
+
+def _lower_paged(kernel, g, q, *ints):
+    """Lower ``kernel(q, cache, cache, *ints)`` at geometry ``g``."""
+    cache = (g["slots"], g["kvh"], g["d"])
+    if g["layers"] is None:
+        f, layer = functools.partial(kernel, block_size=g["bs"]), ()
+    else:
+        cache = (g["layers"],) + cache
+        f = lambda *a: kernel(*a[:-1], block_size=g["bs"],  # noqa: E731
+                              layer=a[-1])
+        layer = (sds((), jnp.int32),)
+    lower_tpu(f, q, sds(cache), sds(cache), *ints, *layer)
+
+
 class TestPagedLowering:
     SLOTS, BS, BPS = 8192, 128, 16   # kv-cache slots, block size, blocks/seq
 
-    def test_paged_decode(self):
+    @pytest.mark.parametrize("form", sorted(PAGED_GEOMETRY))
+    def test_paged_decode(self, form):
+        g = PAGED_GEOMETRY[form]
         s = 64  # sequence slots in the decode batch
-        q = sds((s, H, D))
-        kc = sds((self.SLOTS, KVH, D))
-        bt = sds((s, self.BPS), jnp.int32)
-        sl = sds((s,), jnp.int32)
-        f = functools.partial(paged_decode_attention_pallas,
-                              block_size=self.BS)
-        lower_tpu(f, q, kc, kc, bt, sl)
+        _lower_paged(paged_decode_attention_pallas, g,
+                     sds((s, g["h"], g["d"])), sds((s, g["bps"]), jnp.int32),
+                     sds((s,), jnp.int32))
 
     def test_paged_decode_alibi_window(self):
         s = 64
@@ -182,16 +204,14 @@ class TestPagedLowering:
                               block_size=self.BS, window=512)
         lower_tpu(f, q, kc, kc, bt, sl)
 
-    def test_ragged_prefill(self):
+    @pytest.mark.parametrize("form", sorted(PAGED_GEOMETRY))
+    def test_ragged_prefill(self, form):
+        g = PAGED_GEOMETRY[form]
         a, bq = 16, 128  # atoms x tokens-per-atom (SplitFuse chunking)
-        q = sds((a, bq, H, D))
-        kc = sds((self.SLOTS, KVH, D))
-        at = sds((a, self.BPS), jnp.int32)
-        p0 = sds((a,), jnp.int32)
-        ql = sds((a,), jnp.int32)
-        f = functools.partial(ragged_prefill_attention_pallas,
-                              block_size=self.BS)
-        lower_tpu(f, q, kc, kc, at, p0, ql)
+        _lower_paged(ragged_prefill_attention_pallas, g,
+                     sds((a, bq, g["h"], g["d"])),
+                     sds((a, g["bps"]), jnp.int32), sds((a,), jnp.int32),
+                     sds((a,), jnp.int32))
 
     def test_ragged_prefill_mha(self):
         a, bq = 8, 256
