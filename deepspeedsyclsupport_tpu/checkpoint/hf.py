@@ -249,6 +249,17 @@ def config_from_hf(hf: Dict[str, Any], **overrides) -> ModelConfig:
                       num_experts_per_tok=hf.get("num_experts_per_tok", 2),
                       aux_loss_coef=float(hf.get("router_aux_loss_coef",
                                                  0.01)))
+        if mt == "olmoe":
+            # intermediate_size is ONE expert's width; the config has no key
+            # for the QK-norm, the architecture always has it
+            if hf.get("clip_qkv") is not None:
+                raise ValueError("olmoe with clip_qkv is not supported")
+            kw.update(num_experts=hf.get("num_experts", 64),
+                      num_experts_per_tok=hf.get("num_experts_per_tok", 8),
+                      norm_topk_prob=bool(hf.get("norm_topk_prob", False)),
+                      qk_norm=True,
+                      aux_loss_coef=float(hf.get("router_aux_loss_coef",
+                                                 0.01)))
     kw.update(overrides)
     return ModelConfig(**kw)
 
@@ -753,10 +764,26 @@ def _family_phi(cfg: ModelConfig):
     return top, layer
 
 
+def _family_olmoe(cfg: ModelConfig):
+    """The llama names, the router at ``mlp.gate`` and the two projection
+    norms (HF ``modeling_olmoe``); experts: :func:`_expert_names`."""
+    top, llama_layer = _family_llama(cfg)
+
+    def layer(i: int):
+        pre = f"model.layers.{i}."
+        m = llama_layer(i)
+        m[("moe", "router")] = (pre + "mlp.gate.weight", _t)
+        m[("attn", "q_norm", "scale")] = (pre + "self_attn.q_norm.weight", _id)
+        m[("attn", "k_norm", "scale")] = (pre + "self_attn.k_norm.weight", _id)
+        return m
+
+    return top, layer
+
+
 FAMILIES = {
     "llama": _family_llama, "mistral": _family_llama,
     "mixtral": _family_llama, "qwen2": _family_llama,
-    "internlm": _family_llama,
+    "internlm": _family_llama, "olmoe": _family_olmoe,
     "gpt2": _family_gpt2, "gpt_neo": _family_gpt_neo,
     "opt": _family_opt, "bloom": _family_bloom,
     "falcon": _family_falcon, "gpt_neox": _family_gpt_neox,
@@ -764,7 +791,16 @@ FAMILIES = {
 }
 
 
-def _expert_names(i: int, e: int) -> Dict[str, Tuple[str, Callable]]:
+def _expert_names(mt: str, i: int, e: int
+                  ) -> Dict[str, Tuple[str, Callable]]:
+    """HF tensor name -> (leaf, transform) of expert ``e`` in layer ``i``;
+    the loader stacks the experts of a layer to ``[E, D, F]`` / ``[E, F, D]``
+    in index order."""
+    if mt == "olmoe":
+        pre = f"model.layers.{i}.mlp.experts.{e}."
+        return {pre + "gate_proj.weight": ("w_gate", _t),
+                pre + "up_proj.weight": ("w_up", _t),
+                pre + "down_proj.weight": ("w_down", _t)}
     pre = f"model.layers.{i}.block_sparse_moe.experts.{e}."
     # Mixtral: w1=gate, w3=up, w2=down (reference mixtral container mapping)
     return {pre + "w1.weight": ("w_gate", _t),
@@ -879,7 +915,7 @@ def load_hf_checkpoint(path: str,
                 for i in range(L):
                     for e in range(E):
                         name, (_, fn) = next(
-                            (n, v) for n, v in _expert_names(i, e).items()
+                            (n, v) for n, v in _expert_names(mt, i, e).items()
                             if v[0] == key)
                         p = fn(src.get(name))
                         if buf is None:
@@ -902,7 +938,7 @@ def load_hf_checkpoint(path: str,
             if cfg.any_moe:
                 stacked: Dict[str, list] = {}
                 for e in range(cfg.num_experts):
-                    for name, (key, fn) in _expert_names(i, e).items():
+                    for name, (key, fn) in _expert_names(mt, i, e).items():
                         stacked.setdefault(key, []).append(fn(src.get(name)))
                 for key, mats in stacked.items():
                     lp.setdefault("moe", {})[key] = _put(

@@ -30,6 +30,15 @@ from ...monitor.reqtrace import NO_PHASE
 from ...utils.logging import log_dist
 
 
+def _sample_with_tail(logits, rng, temperature, top_p, structure, tail=None):
+    """``sample_token_dyn``; a ``tail`` (device int32 scalar) is appended to
+    the tokens [n] -> [n + 1], so that it reaches the host in the ONE
+    transfer that brings the tokens (a sparse-expert model's ``moe_touched``:
+    no launch and no transfer of its own)."""
+    toks = sample_token_dyn(logits, rng, temperature, top_p, structure)
+    return toks if tail is None else jnp.concatenate([toks, tail[None]])
+
+
 @dataclasses.dataclass(frozen=True)
 class AdmissionResult:
     """Structured admission decision (reference ``can_schedule:179`` returns
@@ -119,7 +128,11 @@ class InferenceEngineV2:
         self._rng = jax.random.PRNGKey(cfg.seed)
         # only the sampling STRUCTURE is static; temperature/top_p are
         # operands (sweeping them reuses one compiled sampler)
-        self._sample_fn = jax.jit(sample_token_dyn, static_argnums=(4,))
+        self._sample_fn = jax.jit(_sample_with_tail, static_argnums=(4,))
+        # live tokens the forwards were given, counted here on the host: what
+        # a sparse-expert model's device counters are held against
+        # (moe_stats: load[l].sum() == k x this)
+        self._forward_tokens = 0
         # atoms feed only impls that declare needs_atoms — decide ONCE
         # whether that path runs so prefill forwards skip the host atom
         # build + five-array transfer when it cannot (registry metadata;
@@ -229,7 +242,9 @@ class InferenceEngineV2:
     def _note_forward(self, descs, lengths) -> None:
         """What the forward about to be launched covers, for the round's
         record; called BEFORE it, so ``ctx_tokens`` is the context the
-        attention kernel must read and ``kv_blocks`` the tables it walks."""
+        attention kernel must read and ``kv_blocks`` the tables it walks.
+        Its live tokens are counted whether or not a round is recorded."""
+        self._forward_tokens += sum(lengths)
         if self.round_spans is None:
             return
         self.round_spans.fields.update(
@@ -240,6 +255,19 @@ class InferenceEngineV2:
                                if not (n == 1 and d.n_cached > 0)),
             ctx_tokens=sum(d.n_cached for d in descs),
             kv_blocks=sum(len(d.blocks) for d in descs))
+
+    def moe_stats(self) -> Optional[Dict[str, Any]]:
+        """A sparse-expert model's routing since the engine was built (None
+        for a dense one): ``load`` [L, E], the (token, choice) rows each
+        layer's router gave each expert, read from the device NOW (a
+        transfer: for a report, not for a round), and ``live_tokens``, the
+        tokens the host put into those forwards. Every layer routes every
+        live token ``k`` times and no pad row, so ``load[l].sum() == k *
+        live_tokens`` (``kv_cache.MoeCounters``)."""
+        if self.kv.moe is None:
+            return None
+        return {"load": np.asarray(self.kv.moe.load),
+                "live_tokens": self._forward_tokens}
 
     def compiled_programs(self) -> Dict[str, Any]:
         """``{name: jax.stages.Compiled}`` for every forward program this
@@ -817,6 +845,8 @@ class InferenceEngineV2:
             pos_h = np.asarray(pos_f)
             act_h = np.asarray(act_f)
             sl_h = np.asarray(sl_f)
+        # every step appended one live row per slot that advanced
+        self._forward_tokens += int(pos_h[:n].sum() - positions[:n].sum())
         emitted: Dict[int, List[int]] = {}
         served_s = time.perf_counter()
         with self._phase("collect"):

@@ -10,7 +10,7 @@ into ``[layer, slot]`` in place and hand the kernels the pool and the layer
 kernel, each layer cost a slice, a copy and a write-back of itself on the
 v5e and the pool was held twice (PERF.md, PR 25).
 """
-from typing import NamedTuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -19,9 +19,26 @@ import numpy as np
 from .config import RaggedInferenceConfig
 
 
+class MoeCounters(NamedTuple):
+    """What the serving forwards of a sparse-expert model count about
+    routing, on the device. ``load`` [L, E] int32: the (token, choice) rows
+    each layer's router gave each expert, summed over every forward since
+    the engine was built (live rows only: ``load[l].sum() == k × live
+    tokens``). ``touched`` scalar int32: of the LAST forward, the experts
+    with at least one live row, summed over layers — the expert weights that
+    forward had to read."""
+    load: jnp.ndarray
+    touched: jnp.ndarray
+
+
 class BlockedKV(NamedTuple):
     k: jnp.ndarray  # [L, num_blocks*block_size, KVH, D]
     v: jnp.ndarray
+    # sparse-expert models only (None elsewhere: no leaf, the same program).
+    # The counters ride with the pool because they live the pool's life: on
+    # the device, through every forward's layer loop, donated and handed
+    # back, so counting costs no launch and no transfer.
+    moe: Optional[MoeCounters] = None
 
     @property
     def num_slots(self) -> int:
@@ -58,7 +75,13 @@ def init_blocked_kv(model_config, cfg: RaggedInferenceConfig,
                 if tp > 1 and kvh % tp == 0 else topology.replicated())
     zeros = jax.jit(lambda: jnp.zeros(shape, cfg.dtype),
                     out_shardings=sharding)
-    return BlockedKV(zeros(), zeros())
+    moe = None
+    if model_config.any_moe:
+        moe = jax.jit(lambda: MoeCounters(
+            jnp.zeros((model_config.num_layers, model_config.num_experts),
+                      jnp.int32), jnp.zeros((), jnp.int32)),
+            out_shardings=topology.replicated())()
+    return BlockedKV(zeros(), zeros(), moe)
 
 
 def kv_pool_stats(kv: BlockedKV, allocator) -> dict:
@@ -100,8 +123,10 @@ def build_block_copy_fn(block_size: int):
         sizes = (L, block_size, H, D)
         ks = jax.lax.dynamic_slice(kv.k, (0, src * block_size, 0, 0), sizes)
         vs = jax.lax.dynamic_slice(kv.v, (0, src * block_size, 0, 0), sizes)
-        return BlockedKV(
-            jax.lax.dynamic_update_slice(kv.k, ks, (0, dst * block_size, 0, 0)),
-            jax.lax.dynamic_update_slice(kv.v, vs, (0, dst * block_size, 0, 0)))
+        return kv._replace(
+            k=jax.lax.dynamic_update_slice(kv.k, ks,
+                                           (0, dst * block_size, 0, 0)),
+            v=jax.lax.dynamic_update_slice(kv.v, vs,
+                                           (0, dst * block_size, 0, 0)))
 
     return jax.jit(_copy, donate_argnums=0)

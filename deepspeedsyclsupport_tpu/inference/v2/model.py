@@ -30,9 +30,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .kv_cache import BlockedKV
+from .kv_cache import BlockedKV, MoeCounters
 from .module_registry import register_impl, select_impl
-from ...models.layers import alibi_slopes, apply_rope, mlp_block, norm
+from ...models.layers import (alibi_slopes, apply_rope, mlp_block, norm,
+                              qk_norm)
 
 NEG_INF = jnp.finfo(jnp.float32).min
 
@@ -67,19 +68,21 @@ def _dequant(p, dtype):
     return dequantize_tree(p, dtype)
 
 
-def _mlp(p, y, cfg):
+def _mlp(p, y, cfg, live):
     """Per-layer MLP over flat tokens [T, D]: dense (GLU or fc1/fc2), or exact
     top-k MoE via grouped GEMMs (the moe_scatter/cutlass-multi-GEMM/moe_gather
-    analog, ``parallel/moe.moe_mlp_nodrop``)."""
+    analog, ``parallel/moe.moe_mlp_nodrop``) over the ``live`` rows [T].
+    Returns (out, the rows each expert was given [E] — None when dense)."""
     if cfg.any_moe:
         from ...parallel.moe import moe_mlp_nodrop
 
-        return moe_mlp_nodrop(p["moe"], y, cfg)
-    return mlp_block(p["mlp"], y[None], cfg)[0]
+        return moe_mlp_nodrop(p["moe"], y, cfg, live)
+    return mlp_block(p["mlp"], y[None], cfg)[0], None
 
 
 def _qkv(p, y, cfg, n):
-    """Fused qkv projection over flat tokens [n, D] (+ optional biases)."""
+    """Fused qkv projection over flat tokens [n, D] (+ optional biases,
+    + the projection-wide QK-norm of ``cfg.qk_norm``)."""
     q = jnp.einsum("td,dq->tq", y, p["wq"])
     k = jnp.einsum("td,dk->tk", y, p["wk"])
     v = jnp.einsum("td,dk->tk", y, p["wv"])
@@ -87,6 +90,7 @@ def _qkv(p, y, cfg, n):
         q = q + p["bq"].astype(q.dtype)
         k = k + p["bk"].astype(k.dtype)
         v = v + p["bv"].astype(v.dtype)
+    q, k = qk_norm(p, q, k, cfg)
     return (q.reshape(n, cfg.num_heads, cfg.head_dim),
             k.reshape(n, cfg.num_kv_heads, cfg.head_dim),
             v.reshape(n, cfg.num_kv_heads, cfg.head_dim))
@@ -153,17 +157,20 @@ def _unembed(params, x, cfg):
     return logits
 
 
-def _block(cfg, p, x, attn_fn):
+def _block(cfg, p, x, attn_fn, live):
     """One transformer block over flat tokens, covering sequential and
-    parallel (GPT-J/NeoX/Falcon/Phi) residual forms."""
+    parallel (GPT-J/NeoX/Falcon/Phi) residual forms. ``live`` [T]: the rows
+    that are tokens, not padding. Returns (x, :func:`_mlp`'s expert rows)."""
     x_norm = norm(x, p["attn_norm"], cfg)
     attn = attn_fn(x_norm)
     h = _attn_out(p["attn"], attn, cfg, x.shape[0])
     if cfg.parallel_block:
         y = x_norm if cfg.shared_block_norm else norm(x, p["mlp_norm"], cfg)
-        return (x + h + _mlp(p, y, cfg)).astype(x.dtype)
+        m, rows = _mlp(p, y, cfg, live)
+        return (x + h + m).astype(x.dtype), rows
     x = (x + h).astype(x.dtype)
-    return (x + _mlp(p, norm(x, p["mlp_norm"], cfg), cfg)).astype(x.dtype)
+    m, rows = _mlp(p, norm(x, p["mlp_norm"], cfg), cfg, live)
+    return (x + m).astype(x.dtype), rows
 
 
 def _paged_attention(q, k_cache, v_cache, token_seq, token_pos, block_tables,
@@ -358,11 +365,18 @@ def _pool_write(k_pool, v_pool, layer, dest, k, v):
 def _scan_layers(layer, x, kv: BlockedKV, layer_params):
     """The layer loop of both serving forwards: the pool rides as CARRY
     beside ``x`` (never as the scan's xs/ys, which would slice it by layer
-    and stack a second pool), the stacked params and the layer index as xs."""
+    and stack a second pool), the stacked params and the layer index as xs.
+    ``layer`` returns ``(carry, expert rows [E] or None)``: a sparse-expert
+    model's rows stack to [L, E] and fold into ``kv.moe``. Returns
+    ``(x, the new BlockedKV)``."""
     num_layers = kv.k.shape[0]
-    (x, k_pool, v_pool), _ = jax.lax.scan(
+    (x, k_pool, v_pool), rows = jax.lax.scan(
         layer, (x, kv.k, kv.v), (layer_params, jnp.arange(num_layers)))
-    return x, k_pool, v_pool
+    moe = kv.moe
+    if rows is not None:
+        moe = MoeCounters(moe.load + rows,
+                          jnp.sum(rows > 0, dtype=jnp.int32))
+    return x, BlockedKV(k_pool, v_pool, moe)
 
 
 def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
@@ -420,15 +434,15 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
                 atom_inv=atom_inv)
             return spec.fn(q, ctx)[..., :cfg.head_dim]
 
-        x = _block(cfg, p, x, attn_fn)
-        return (x, k_pool, v_pool), None
+        x, rows = _block(cfg, p, x, attn_fn, ~pad)
+        return (x, k_pool, v_pool), rows
 
-    x, nk, nv = _scan_layers(layer, x, kv, params["layers"])
+    x, kv = _scan_layers(layer, x, kv, params["layers"])
 
     x = norm(x, params["final_norm"], cfg)
     h_last = x[last_tok_idx]  # [S, d] — logits_gather
     logits = _unembed(params, h_last, cfg)
-    return logits.astype(jnp.float32), BlockedKV(nk, nv)
+    return logits.astype(jnp.float32), kv
 
 
 def _jit_program(name: str, fn, model, **static):
@@ -494,13 +508,13 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
                 block_tables=block_tables, seq_lens=seq_lens, block_size=bs,
                 alibi=ab, window=window))[..., :cfg.head_dim]
 
-        x = _block(cfg, p, x, attn_fn)
-        return (x, k_pool, v_pool), None
+        x, rows = _block(cfg, p, x, attn_fn, active)
+        return (x, k_pool, v_pool), rows
 
-    x, nk, nv = _scan_layers(layer, x, kv, params["layers"])
+    x, kv = _scan_layers(layer, x, kv, params["layers"])
     x = norm(x, params["final_norm"], cfg)
     logits = _unembed(params, x, cfg)
-    return logits.astype(jnp.float32), BlockedKV(nk, nv)
+    return logits.astype(jnp.float32), kv
 
 
 def build_decode_forward_fn(model, block_size: int, attn_impl: str = "auto"):

@@ -1014,12 +1014,20 @@ class ServingSession:
                 rows = jnp.stack([lg for _, lg in drained])
         if drained:
             with self._phase("sample"):
+                # a sparse-expert model's last forward counted the experts
+                # it touched on the device: the scalar rides behind the tokens
+                moe = eng.kv.moe
                 toks = eng._sample_fn(
                     rows, sub, jnp.float32(sp.temperature),
-                    jnp.float32(sp.top_p), sp.structure)
+                    jnp.float32(sp.top_p), sp.structure,
+                    None if moe is None else moe.touched)
                 eng.host_dispatches += 1  # the sampler is a dispatch too
             with self._phase("readback"):
                 toks = np.asarray(toks)
+            if moe is not None:
+                toks, touched = toks[:-1], int(toks[-1])
+                if self._spans is not None:
+                    self._spans.fields["moe_touched"] = touched
             t1 = self.clock()
             if self._last_decode_s is not None:
                 self.capacity.record_decode(1, t1 - self._last_decode_s)

@@ -61,6 +61,13 @@ class ModelConfig:
     capacity_factor: float = 1.25
     aux_loss_coef: float = 0.01
     router_jitter: float = 0.0
+    # divide the top-k router weights by their sum (mixtral); OLMoE's config
+    # publishes norm_topk_prob=false and combines with the raw softmax mass
+    norm_topk_prob: bool = True
+    # RMSNorm with a learned scale over the WHOLE q and k projections
+    # ([q_dim] / [kv_dim], eps rms_norm_eps), before the head split and rotary
+    # (OLMoE, HF modeling_olmoe OlmoeAttention.q_norm/k_norm)
+    qk_norm: bool = False
 
     # Training-time behavior
     remat: bool = False             # jax.checkpoint each layer (activation ckpt)
@@ -141,6 +148,8 @@ class ModelConfig:
         mlp = (3 if self.mlp_type == "glu" else 2) * d * f
         if self.num_experts > 0:
             mlp = mlp * self.num_experts + d * self.num_experts
+        if self.qk_norm:
+            attn += self.q_dim + self.kv_dim
         per_layer = attn + mlp + 2 * d
         total = per_layer * self.num_layers + v * d + d
         if not self.tie_embeddings:
@@ -233,6 +242,12 @@ PRESETS = {
     "mixtral-8x7b": _p(vocab_size=32000, hidden_size=4096, intermediate_size=14336,
                        num_layers=32, num_heads=32, num_kv_heads=8, max_seq_len=8192,
                        num_experts=8, num_experts_per_tok=2),
+    # allenai/OLMoE-1B-7B-0125-Instruct (arXiv:2409.02060): intermediate_size
+    # is ONE expert's width; no shared expert, top-8 weights not renormalised
+    "olmoe-1b-7b": _p(vocab_size=50304, hidden_size=2048, intermediate_size=1024,
+                      num_layers=16, num_heads=16, num_kv_heads=16,
+                      max_seq_len=4096, num_experts=64, num_experts_per_tok=8,
+                      norm_topk_prob=False, qk_norm=True),
 }
 
 

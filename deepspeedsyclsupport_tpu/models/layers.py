@@ -87,6 +87,19 @@ def norm(x: jnp.ndarray, p: Params, cfg: ModelConfig) -> jnp.ndarray:
     return rms_norm(x, p["scale"], cfg.rms_norm_eps)
 
 
+def qk_norm(p: Params, q: jnp.ndarray, k: jnp.ndarray,
+            cfg: ModelConfig) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """OLMoE's QK-norm (``cfg.qk_norm``): RMSNorm over the WHOLE q and k
+    projections, ``[..., q_dim]`` and ``[..., kv_dim]`` with one learned
+    scale each, BEFORE the split into heads and before rotary (HF
+    ``modeling_olmoe``: ``q_norm(q_proj(x))``). Shared by the training block
+    and the serving block's ``_qkv``."""
+    if not cfg.qk_norm:
+        return q, k
+    return (rms_norm(q, p["q_norm"]["scale"], cfg.rms_norm_eps),
+            rms_norm(k, p["k_norm"]["scale"], cfg.rms_norm_eps))
+
+
 # --------------------------------------------------------------------------- rope
 def rope_frequencies(head_dim: int, theta: float) -> np.ndarray:
     return 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float32) / head_dim))
@@ -441,6 +454,7 @@ def _attention_block_impl(p, x, cfg, positions, segment_ids, kv_cache, impl,
         q = q + p["bq"].astype(q.dtype)
         k = k + p["bk"].astype(k.dtype)
         v = v + p["bv"].astype(v.dtype)
+    q, k = qk_norm(p, q, k, cfg)
     q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
     k = k.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
     v = v.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)

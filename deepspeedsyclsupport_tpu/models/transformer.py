@@ -77,6 +77,9 @@ class CausalLM:
                             bv=jnp.zeros((kv,), jnp.float32))
             if cfg.attn_out_bias:
                 attn["bo"] = jnp.zeros((d,), jnp.float32)
+            if cfg.qk_norm:
+                attn.update(q_norm={"scale": jnp.ones((q,), jnp.float32)},
+                            k_norm={"scale": jnp.ones((kv,), jnp.float32)})
             p: Params = {"attn_norm": norm_params(), "attn": attn}
             if not cfg.shared_block_norm:
                 p["mlp_norm"] = norm_params()

@@ -44,6 +44,12 @@ SCOPE_REGIONS = ("embed", "attn", "mlp", "head", "loss", "optimizer")
 DERIVED_REGIONS = ("collective", "other", "host")
 REGIONS = SCOPE_REGIONS + DERIVED_REGIONS
 
+#: Named scopes INSIDE a region (:func:`scope`), for a reader that wants one
+#: piece of a layer: the compiled program's ``op_name`` metadata carries the
+#: label, the device trace only instruction names, so the reader maps label
+#: to names from the program's own text (benchmark/metrics/moe_share_pct.py).
+SUB_SCOPES = ("moe_route", "moe_experts", "moe_combine")
+
 #: named_scope label prefix — ``mfu.attn`` etc. Kept short and distinctive
 #: so the metadata regex can't false-positive on user scopes.
 SCOPE_PREFIX = "mfu."
@@ -85,6 +91,18 @@ def region_scope(name: str):
     import jax  # lazy: this module must import stdlib-only
 
     return jax.named_scope(SCOPE_PREFIX + name)
+
+
+def scope(name: str):
+    """``jax.named_scope`` for a declared sub-scope (:data:`SUB_SCOPES`):
+    as :func:`region_scope`, a typo'd label raises instead of leaving its
+    reader nothing to find."""
+    if name not in SUB_SCOPES:
+        raise ValueError(f"undeclared scope {name!r}; declared: "
+                         f"{SUB_SCOPES} (monitor/mfu.py)")
+    import jax  # lazy: this module must import stdlib-only
+
+    return jax.named_scope(name)
 
 
 def region_of(op_name: str) -> Optional[str]:

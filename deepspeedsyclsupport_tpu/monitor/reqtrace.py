@@ -97,8 +97,13 @@ ROUND_PHASES = (
 #: the launch: sequences, tokens, how many of those were prompt (a chunk is
 #: a decode step when it is one token on top of cached context), the cached
 #: tokens attention must read, and the blocks in the sequences' tables.
+#: ``moe_touched`` is the one field the DEVICE counts (a sparse-expert
+#: model's experts with at least one live row, summed over layers: the
+#: expert weights a forward had to read; 0 for a dense model). It comes back
+#: behind the sampled tokens, so a record carries the count of the forward
+#: whose logits its round SAMPLED: the launch of the record before it.
 FORWARD_FIELDS = ("n_seqs", "tokens", "prefill_tokens", "ctx_tokens",
-                  "kv_blocks")
+                  "kv_blocks", "moe_touched")
 
 #: what a phase is where nothing times the round: ``trace_stages`` off, or
 #: an engine driven without a session
@@ -481,9 +486,15 @@ def round_phases(streams: Iterable[Tuple[str, str, Sequence[Dict[str, Any]]]]
     forward covered (:data:`FORWARD_FIELDS`). ``None`` when the streams
     hold no such record (journals from before it, or ``trace_stages``
     off)."""
-    rounds = [rec["data"] for _rid, _att, records in streams
-              for rec in records if rec.get("name") == "serve/stage"
-              and (rec.get("data") or {}).get("stage") == "round"]
+    rounds: List[Dict[str, Any]] = []
+    for _rid, _att, records in streams:
+        own = [rec["data"] for rec in records
+               if rec.get("name") == "serve/stage"
+               and (rec.get("data") or {}).get("stage") == "round"]
+        # moe_touched reaches the host a round late (FORWARD_FIELDS): give
+        # each record the count of the forward it launched, its successor's
+        rounds += [{**d, "moe_touched": nxt.get("moe_touched", 0)}
+                   for d, nxt in zip(own, own[1:] + [{}])]
     if not rounds:
         return None
 

@@ -25,9 +25,22 @@ import numpy as np
 from ..models.layers import constrain
 
 
+def topk_weights(probs: jnp.ndarray, k: int,
+                 normalise: bool) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The ONE top-k weighting of both MoE paths: the ``k`` largest router
+    probabilities of each token [T, k] and their experts [T, k]; divided by
+    their sum where the model says so (``cfg.norm_topk_prob``: mixtral does,
+    OLMoE combines with the raw softmax mass)."""
+    gate_w, expert_idx = jax.lax.top_k(probs, k)
+    if normalise:
+        gate_w = gate_w / jnp.maximum(gate_w.sum(-1, keepdims=True), 1e-9)
+    return gate_w, expert_idx
+
+
 def topk_gating(logits: jnp.ndarray, k: int, capacity: int,
                 rng: Optional[jax.Array] = None,
-                jitter: float = 0.0) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+                jitter: float = 0.0, normalise: bool = True
+                ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Top-k gating with capacity (reference ``top1gating``/``top2gating``,
     ``moe/sharded_moe.py:184,282``).
 
@@ -39,8 +52,8 @@ def topk_gating(logits: jnp.ndarray, k: int, capacity: int,
             rng, logits.shape, logits.dtype, 1.0 - jitter, 1.0 + jitter)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)  # [T, E]
 
-    # top-k expert ids per token
-    _, expert_idx = jax.lax.top_k(probs, k)                       # [T, k]
+    # top-k expert ids per token, and their (re)normalised weights
+    gate_w, expert_idx = topk_weights(probs, k, normalise)        # [T, k]
     onehot = jax.nn.one_hot(expert_idx, e, dtype=jnp.float32)     # [T, k, E]
 
     # Load-balance aux loss (top2gating: uses the top-1 assignment fraction).
@@ -58,8 +71,6 @@ def topk_gating(logits: jnp.ndarray, k: int, capacity: int,
     pos = (pos_in_expert * flat).sum(axis=-1)                     # [k*T]
     keep = flat.sum(axis=-1)                                      # [k*T] 0/1
 
-    gate_w = jnp.take_along_axis(probs, expert_idx, axis=1)       # [T, k]
-    gate_w = gate_w / jnp.maximum(gate_w.sum(axis=-1, keepdims=True), 1e-9)
     gate_flat = gate_w.transpose(1, 0).reshape(k * t) * keep      # [k*T]
 
     cap_onehot = jax.nn.one_hot(pos.astype(jnp.int32), capacity,
@@ -88,7 +99,8 @@ def moe_mlp(p: Dict[str, Any], x: jnp.ndarray, cfg,
     logits = jnp.einsum("td,de->te", xt.astype(jnp.float32),
                         p["router"].astype(jnp.float32))
     dispatch, combine, aux = topk_gating(logits, k, capacity, rng,
-                                         cfg.router_jitter)
+                                         cfg.router_jitter,
+                                         cfg.norm_topk_prob)
 
     # dispatch → [E, C, D]; sharded over the expert axis so the einsum below is
     # the all-to-all the reference implements by hand (_AllToAll, sharded_moe.py:95)
@@ -111,7 +123,9 @@ def moe_mlp(p: Dict[str, Any], x: jnp.ndarray, cfg,
     return out.reshape(b, s, d), aux.astype(jnp.float32)
 
 
-def moe_mlp_nodrop(p: Dict[str, Any], x: jnp.ndarray, cfg) -> jnp.ndarray:
+def moe_mlp_nodrop(p: Dict[str, Any], x: jnp.ndarray, cfg,
+                   live: Optional[jnp.ndarray] = None
+                   ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Exact top-k MoE for flat token streams (the serving path).
 
     The reference serves MoE through ``moe_scatter`` → CUTLASS grouped GEMM →
@@ -122,32 +136,48 @@ def moe_mlp_nodrop(p: Dict[str, Any], x: jnp.ndarray, cfg) -> jnp.ndarray:
     inference must never drop a routed token (unlike the training path's
     capacity buffers, :func:`moe_mlp`).
 
-    x: [T, D] flat tokens → [T, D].
+    ``live`` [T] bool: the serving forwards always carry their full row
+    budget, pads included. A row that is not live gets NO expert: it sorts
+    behind the last group, outside ``group_sizes``, and its output is zero.
+
+    x: [T, D] flat tokens → (out [T, D], group_sizes [E] int32: the (token,
+    choice) rows each expert was given, ``sum == k × live rows``).
     """
+    from ..monitor.mfu import scope
+
     t, d = x.shape
     e, k = cfg.num_experts, cfg.num_experts_per_tok
-    logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
-                        p["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate_w, expert_idx = jax.lax.top_k(probs, k)              # [T, k]
-    gate_w = gate_w / jnp.maximum(gate_w.sum(-1, keepdims=True), 1e-9)
+    with scope("moe_route"):
+        logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
+                            p["router"].astype(jnp.float32))
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_w, expert_idx = topk_weights(probs, k, cfg.norm_topk_prob)
 
-    flat_expert = expert_idx.reshape(t * k)
-    flat_tok = jnp.repeat(jnp.arange(t), k)
-    order = jnp.argsort(flat_expert, stable=True)             # moe_scatter
-    sorted_tok = flat_tok[order]
-    xs = x[sorted_tok]                                        # [T*k, D]
-    group_sizes = jnp.bincount(flat_expert, length=e).astype(jnp.int32)
+        flat_expert = expert_idx.reshape(t * k)
+        if live is not None:
+            # expert id E = "none": sorts last and is in no group
+            live_rows = jnp.repeat(live, k)
+            flat_expert = jnp.where(live_rows, flat_expert, e)
+        flat_tok = jnp.repeat(jnp.arange(t), k)
+        order = jnp.argsort(flat_expert, stable=True)         # moe_scatter
+        sorted_tok = flat_tok[order]
+        xs = x[sorted_tok]                                    # [T*k, D]
+        group_sizes = jnp.bincount(flat_expert, length=e).astype(jnp.int32)
 
     act = jax.nn.silu if cfg.activation == "silu" else jax.nn.gelu
-    wg = p["w_gate"].astype(x.dtype)
-    wu = p["w_up"].astype(x.dtype)
-    wd = p["w_down"].astype(x.dtype)
-    gate = jax.lax.ragged_dot(xs, wg, group_sizes)
-    up = jax.lax.ragged_dot(xs, wu, group_sizes)
-    ys = jax.lax.ragged_dot(act(gate) * up, wd, group_sizes)  # [T*k, D]
+    with scope("moe_experts"):
+        wg = p["w_gate"].astype(x.dtype)
+        wu = p["w_up"].astype(x.dtype)
+        wd = p["w_down"].astype(x.dtype)
+        gate = jax.lax.ragged_dot(xs, wg, group_sizes)
+        up = jax.lax.ragged_dot(xs, wu, group_sizes)
+        ys = jax.lax.ragged_dot(act(gate) * up, wd, group_sizes)  # [T*k, D]
 
-    w_flat = gate_w.reshape(t * k)[order].astype(x.dtype)
-    out = jnp.zeros((t, d), x.dtype).at[sorted_tok].add(      # moe_gather
-        ys * w_flat[:, None])
-    return out
+    with scope("moe_combine"):
+        ys = ys * gate_w.reshape(t * k)[order].astype(x.dtype)[:, None]
+        if live is not None:
+            # what ragged_dot leaves in rows past the last group is its own
+            # business: a pad row contributes an exact zero
+            ys = jnp.where(live_rows[order][:, None], ys, 0)
+        out = jnp.zeros((t, d), x.dtype).at[sorted_tok].add(ys)  # moe_gather
+    return out, group_sizes

@@ -321,7 +321,8 @@ class TestEngineV2:
         p0 = jax.tree_util.tree_map(lambda x: x[0], p)  # layer 0 weights
         x = jax.random.normal(jax.random.PRNGKey(0), (1, 24, cfg.hidden_size))
         want, _ = moe_mlp(p0, x, cfg)
-        got = moe_mlp_nodrop(p0, x[0], cfg)
+        got, rows = moe_mlp_nodrop(p0, x[0], cfg)
+        assert int(rows.sum()) == 24 * cfg.num_experts_per_tok
         np.testing.assert_allclose(np.asarray(got), np.asarray(want[0]),
                                    rtol=2e-4, atol=2e-4)
 

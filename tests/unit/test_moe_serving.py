@@ -1,0 +1,205 @@
+"""Sparse experts on the serving path (``parallel/moe.moe_mlp_nodrop``, the
+forwards' ``live`` mask, ``kv_cache.MoeCounters``): a pad row gets no expert,
+the device's load counter equals ``k x live tokens`` in every layer over
+rounds, through the fused path and through an eviction with requeue, the
+round record carries the experts the forward before it touched, and
+mixtral's renormalised weighting is bit for bit what it was."""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeedsyclsupport_tpu.inference.v2 import (
+    InferenceEngineV2, ServingPolicyConfig, ServingSession)
+from deepspeedsyclsupport_tpu.models import build_model, get_config
+from deepspeedsyclsupport_tpu.parallel.moe import (moe_mlp_nodrop,
+                                                   topk_gating, topk_weights)
+
+
+@pytest.fixture(scope="module")
+def tiny_moe():
+    model = build_model("tiny-moe", dtype="float32")
+    return model, model.init_params()
+
+
+def _layer0(params):
+    return jax.tree_util.tree_map(lambda x: x[0], params["layers"]["moe"])
+
+
+def _engine(tiny_moe, **kw):
+    model, params = tiny_moe
+    return InferenceEngineV2(model, params, dtype=jnp.float32, **{
+        "block_size": 8, "max_context": 64, "max_tokens_per_batch": 16,
+        "max_sequences": 4, "prefill_attn": "xla", "decode_attn": "xla",
+        **kw})
+
+
+def _drive(sess, requests, rounds=400):
+    for uid, prompt, budget in requests:
+        assert sess.submit(uid, prompt, budget) == "admitted"
+    events = []
+    for _ in range(rounds):
+        if sess.idle:
+            break
+        events += sess.step()
+    assert sess.idle
+    return events
+
+
+def _check_load(eng):
+    """Every layer routed every live token k times, and nothing else."""
+    stats = eng.moe_stats()
+    k = eng.model.config.num_experts_per_tok
+    assert stats["live_tokens"] > 0
+    assert stats["load"].shape == (eng.model.config.num_layers,
+                                   eng.model.config.num_experts)
+    assert stats["load"].sum(1).tolist() \
+        == [k * stats["live_tokens"]] * len(stats["load"])
+    return stats
+
+
+# ------------------------------------------------------------ the function
+def _nodrop_before(p, x, cfg):
+    """``moe_mlp_nodrop`` as it was before the live mask and the shared
+    weighting (PR 25's tree), kept to pin mixtral's result."""
+    t, d = x.shape
+    e, k = cfg.num_experts, cfg.num_experts_per_tok
+    logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
+                        p["router"].astype(jnp.float32))
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate_w, expert_idx = jax.lax.top_k(probs, k)
+    gate_w = gate_w / jnp.maximum(gate_w.sum(-1, keepdims=True), 1e-9)
+    flat_expert = expert_idx.reshape(t * k)
+    flat_tok = jnp.repeat(jnp.arange(t), k)
+    order = jnp.argsort(flat_expert, stable=True)
+    sorted_tok = flat_tok[order]
+    xs = x[sorted_tok]
+    group_sizes = jnp.bincount(flat_expert, length=e).astype(jnp.int32)
+    gate = jax.lax.ragged_dot(xs, p["w_gate"], group_sizes)
+    up = jax.lax.ragged_dot(xs, p["w_up"], group_sizes)
+    ys = jax.lax.ragged_dot(jax.nn.silu(gate) * up, p["w_down"], group_sizes)
+    w_flat = gate_w.reshape(t * k)[order].astype(x.dtype)
+    return jnp.zeros((t, d), x.dtype).at[sorted_tok].add(ys * w_flat[:, None])
+
+
+@pytest.mark.parametrize("live", [None, "some"])
+def test_mixtral_weighting_is_bit_identical_to_before(tiny_moe, live):
+    model, params = tiny_moe
+    cfg = model.config
+    assert cfg.norm_topk_prob
+    x = jax.random.normal(jax.random.PRNGKey(0), (24, cfg.hidden_size))
+    mask = None if live is None else jnp.arange(24) % 3 != 1
+    want = np.asarray(_nodrop_before(_layer0(params), x, cfg))
+    got, _rows = moe_mlp_nodrop(_layer0(params), x, cfg, mask)
+    keep = slice(None) if mask is None else np.asarray(mask)
+    assert np.array_equal(np.asarray(got)[keep], want[keep])
+
+
+def test_pad_rows_get_no_expert_and_give_zero(tiny_moe):
+    model, params = tiny_moe
+    cfg = model.config
+    x = jax.random.normal(jax.random.PRNGKey(1), (16, cfg.hidden_size))
+    live = jnp.asarray([True] * 5 + [False] * 11)
+    out, rows = moe_mlp_nodrop(_layer0(params), x, cfg, live)
+    assert int(rows.sum()) == cfg.num_experts_per_tok * 5
+    assert not np.asarray(out)[5:].any()
+    # the live rows do not see the pads: alone they give the same
+    alone, rows_alone = moe_mlp_nodrop(_layer0(params), x[:5], cfg)
+    assert np.array_equal(np.asarray(rows), np.asarray(rows_alone))
+    np.testing.assert_allclose(np.asarray(out)[:5], np.asarray(alone),
+                               rtol=1e-6, atol=1e-7)
+    # no live row at all: nothing is routed
+    _out, rows = moe_mlp_nodrop(_layer0(params), x, cfg,
+                                jnp.zeros(16, bool))
+    assert int(rows.sum()) == 0
+
+
+def test_one_weighting_serves_both_paths_and_olmoe_does_not_renormalise():
+    probs = jax.nn.softmax(jax.random.normal(jax.random.PRNGKey(2), (6, 8)))
+    raw, idx = topk_weights(probs, 3, normalise=False)
+    norm, idx2 = topk_weights(probs, 3, normalise=True)
+    assert np.array_equal(np.asarray(idx), np.asarray(idx2))
+    assert np.array_equal(np.asarray(raw),
+                          np.asarray(jax.lax.top_k(probs, 3)[0]))
+    np.testing.assert_allclose(np.asarray(norm.sum(-1)), 1.0, rtol=1e-6)
+    assert float(raw.sum(-1).max()) < 1.0
+    # the training path's combine weights carry the same choice
+    logits = jnp.log(probs)
+    for normalise, want in ((False, raw), (True, norm)):
+        _d, combine, _aux = topk_gating(logits, 3, capacity=6,
+                                        normalise=normalise)
+        np.testing.assert_allclose(np.asarray(combine.sum((1, 2))),
+                                   np.asarray(want.sum(-1)), rtol=1e-5)
+    assert not get_config("olmoe-1b-7b").norm_topk_prob
+
+
+# -------------------------------------------------------------- the engine
+def test_load_adds_up_over_rounds_and_the_record_carries_touched(tiny_moe):
+    eng = _engine(tiny_moe)
+    eng.warmup()
+    before = _check_load(eng)              # warm-up's forwards count too
+    sess = ServingSession(eng, ServingPolicyConfig(admission="none"))
+    _drive(sess, [(1, [1, 2, 3], 6), (2, list(range(4, 30)), 6),
+                  (3, [7, 8, 9], 6)])
+    after = _check_load(eng)
+    assert after["live_tokens"] > before["live_tokens"]
+    assert (after["load"] >= before["load"]).all()
+    rounds = [r["data"] for r in sess.drain_trace()
+              if r["data"].get("stage") == "round"]
+    cfg = eng.model.config
+    k, e, n_layers = (cfg.num_experts_per_tok, cfg.num_experts,
+                      cfg.num_layers)
+    checked = 0
+    for d, nxt in zip(rounds, rounds[1:]):
+        if not d["program"] or not nxt["uids"]:
+            continue
+        # what the round AFTER a launch read back is that forward's count:
+        # one token touches k experts a layer, many at most all of them
+        assert n_layers * k <= nxt["moe_touched"] \
+            <= n_layers * min(e, k * d["tokens"])
+        checked += 1
+    assert checked >= 5
+    sess.close()
+
+
+def test_load_counts_the_fused_decode_steps(tiny_moe):
+    eng = _engine(tiny_moe, decode_steps_per_dispatch=4)
+    sess = ServingSession(eng, ServingPolicyConfig(admission="none"))
+    _drive(sess, [(1, [1, 2, 3], 12), (2, [5, 6], 9)])
+    rounds = [r["data"] for r in sess.drain_trace()
+              if r["data"].get("stage") == "round"]
+    assert any(d["mode"] == "fused" for d in rounds)
+    _check_load(eng)
+    sess.close()
+
+
+def test_load_survives_an_eviction_and_requeue(tiny_moe):
+    """A pool of 8 blocks where 4 live sequences want 16: streams are
+    evicted, prefilled again and finish; every token of every forward,
+    the second prefill's among them, is in the counter once."""
+    eng = _engine(tiny_moe, num_blocks=8)
+    sess = ServingSession(eng, ServingPolicyConfig(
+        admission="none", preempt_policy="requeue"))
+    events = _drive(sess, [(u, list(range(u, u + 10)), 20)
+                           for u in range(1, 5)])
+    assert any(e.kind == "evict" for e in events)
+    done = [e for e in events if e.kind == "finish"]
+    assert len(done) == 4 and {e.reason for e in done} == {"done"}
+    _check_load(eng)
+    assert eng.allocator.free_blocks == eng.allocator.num_blocks
+    sess.close()
+
+
+def test_a_dense_model_carries_no_counter_and_an_unchanged_sampler():
+    model = build_model("tiny", dtype="float32")
+    eng = InferenceEngineV2(model, model.init_params(), dtype=jnp.float32,
+                            block_size=8, max_context=64,
+                            max_tokens_per_batch=16, max_sequences=4)
+    assert eng.kv.moe is None and eng.moe_stats() is None
+    assert len(jax.tree_util.tree_leaves(eng.kv)) == 2
+    sess = ServingSession(eng, ServingPolicyConfig(admission="none"))
+    _drive(sess, [(1, [1, 2, 3], 4)])
+    rounds = [r["data"] for r in sess.drain_trace()
+              if r["data"].get("stage") == "round"]
+    assert rounds and all(d["moe_touched"] == 0 for d in rounds)
+    sess.close()
