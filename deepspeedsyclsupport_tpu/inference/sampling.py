@@ -4,7 +4,7 @@ The reference scatters sampling across HF ``generate`` (it never owns the sample
 ``inference/engine.py`` wraps the HF module). The TPU engine owns its jitted decode
 loop, so the sampler lives here as pure jnp — one function usable under ``lax.scan``.
 """
-from typing import NamedTuple, Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +29,15 @@ class SamplingParams(NamedTuple):
         except TypeError:  # traced scalar: filtering must be in the program
             use_top_p = True
         return True, int(self.top_k), use_top_p
+
+
+@jax.jit
+def split_key(key: jax.Array) -> Tuple[jax.Array, jax.Array]:
+    """``jax.random.split(key)`` as the two keys, in ONE launch (unpacking
+    the ``[2, ...]`` array on the host is a second one): a serving round's
+    whole bookkeeping beside its forward and its sampler."""
+    first, second = jax.random.split(key)
+    return first, second
 
 
 def sample_token_dyn(logits: jnp.ndarray, rng: Optional[jax.Array],

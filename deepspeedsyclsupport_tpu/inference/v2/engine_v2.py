@@ -8,11 +8,14 @@ above the reference engine.
 Data flow per :meth:`put` (reference ``engine_v2.py:107`` → §3.5 call stack):
 host scheduler picks chunks → ``RaggedBatch`` metadata built and shipped →
 ONE jitted ragged forward (QKV+RoPE+paged-append, blocked attention, MLP,
-logits gather) → last-token logits land back in each sequence descriptor.
+logits gather) → each drained sequence's descriptor gets a handle to its row
+of the forward's ``[max_sequences, V]`` logits, which :meth:`sample_drained`
+samples in one more launch.
 """
 import dataclasses
 import time
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from collections.abc import Mapping
+from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -21,21 +24,32 @@ import numpy as np
 from .config import RaggedInferenceConfig
 from .kv_cache import init_blocked_kv
 from .model import build_ragged_forward_fn
-from .ragged import BlockedAllocator, SequenceDescriptor, build_ragged_batch
+from .ragged import (BlockedAllocator, LogitsRef, SequenceDescriptor,
+                     build_ragged_batch)
 from .scheduler import schedule_chunks
 from ..params import place_inference_params
-from ..sampling import SamplingParams, sample_token_dyn
+from ..sampling import SamplingParams, sample_token_dyn, split_key
 from ...comm.topology import MeshTopology, build_topology
 from ...monitor.reqtrace import NO_PHASE
 from ...utils.logging import log_dist
 
 
-def _sample_with_tail(logits, rng, temperature, top_p, structure, tail=None):
-    """``sample_token_dyn``; a ``tail`` (device int32 scalar) is appended to
-    the tokens [n] -> [n + 1], so that it reaches the host in the ONE
-    transfer that brings the tokens (a sparse-expert model's ``moe_touched``:
-    no launch and no transfer of its own)."""
-    toks = sample_token_dyn(logits, rng, temperature, top_p, structure)
+def _gather_rows(logits, slots):
+    """Row ``i`` of the result is row ``slots[i]`` of a forward's whole
+    ``[max_sequences, V]`` logits: always ``max_sequences`` rows, so the
+    program's shape does not follow the number of live sequences."""
+    return logits[slots]
+
+
+def _sample_rows(logits, slots, rng, temperature, top_p, structure,
+                 tail=None):
+    """``sample_token_dyn`` over :func:`_gather_rows`; a ``tail`` (device
+    int32 scalar) is appended to the tokens [S] -> [S + 1], so that it
+    reaches the host in the ONE transfer that brings the tokens (a
+    sparse-expert model's ``moe_touched``: no launch and no transfer of its
+    own)."""
+    toks = sample_token_dyn(_gather_rows(logits, slots), rng, temperature,
+                            top_p, structure)
     return toks if tail is None else jnp.concatenate([toks, tail[None]])
 
 
@@ -55,12 +69,34 @@ class AdmissionResult:
         return not self.reasons
 
 
-class PutResult(Dict[int, jax.Array]):
-    """:meth:`InferenceEngineV2.put`'s return: the {uid: last-token logits}
-    mapping (drop-in for the plain dict earlier rounds returned) plus the
-    admission outcome, so schedulers see partial rejection without an
-    exception tearing down the whole batch."""
+class PutResult(Mapping):
+    """:meth:`InferenceEngineV2.put`'s return: the {uid: last-token logits
+    [V]} mapping plus the admission outcome, so schedulers see partial
+    rejection without an exception tearing down the whole batch. The mapping
+    holds :class:`~.ragged.LogitsRef` handles: a row is cut out of its
+    forward's array when a caller READS it (once; counted in the engine's
+    ``logit_rows_sliced``), membership and iteration launch nothing."""
     admission: AdmissionResult
+
+    def __init__(self, engine: "InferenceEngineV2"):
+        self._engine = engine
+        self._refs: Dict[int, LogitsRef] = {}
+        self._rows: Dict[int, jax.Array] = {}
+
+    def __getitem__(self, uid: int) -> jax.Array:
+        row = self._rows.get(uid)
+        if row is None:
+            row = self._rows[uid] = self._engine._slice_row(self._refs[uid])
+        return row
+
+    def __contains__(self, uid) -> bool:
+        return uid in self._refs
+
+    def __iter__(self) -> Iterator[int]:
+        return iter(self._refs)
+
+    def __len__(self) -> int:
+        return len(self._refs)
 
 
 class InferenceEngineV2:
@@ -120,15 +156,20 @@ class InferenceEngineV2:
         # name -> (jitted fn, abstract args) of every forward program this
         # engine has dispatched (compiled_programs)
         self._dispatched: Dict[str, Tuple[Any, Any]] = {}
-        # forward programs dispatched (_dispatch) plus sampler calls: 2 a
-        # per-token round. NOT the round's device launches: the per-row
-        # logits slices, their stack, the rng split and the scalar converts
-        # are ~100 more at 32 live (benchmark: launches_per_round)
+        # forward programs dispatched (_dispatch) plus sampler calls
+        # (sample_drained): 2 a per-token round, which with the rng split
+        # are all of its device launches (benchmark: launches_per_round);
+        # a K-step round adds the gather of its logits0
         self.host_dispatches = 0
+        # [V] rows cut out of a forward's logits because a caller READ one
+        # (query(), a PutResult item), a launch each; sampling gathers by
+        # slot inside its one program, so a serving session leaves this at 0
+        self.logit_rows_sliced = 0
         self._rng = jax.random.PRNGKey(cfg.seed)
         # only the sampling STRUCTURE is static; temperature/top_p are
         # operands (sweeping them reuses one compiled sampler)
-        self._sample_fn = jax.jit(_sample_with_tail, static_argnums=(4,))
+        self._sample_fn = jax.jit(_sample_rows, static_argnums=(5,))
+        self._gather_fn = jax.jit(_gather_rows)
         # live tokens the forwards were given, counted here on the host: what
         # a sparse-expert model's device counters are held against
         # (moe_stats: load[l].sum() == k x this)
@@ -299,7 +340,6 @@ class InferenceEngineV2:
                  [[2]],                        # decode path, state A
                  [[2, 2]],                     # prefill path, state B
                  [[2]])                        # decode path, state B
-        out = None
         for toks in steps:
             out = self.put([uid], toks)
             if uid not in out and out.admission.rejected:
@@ -307,6 +347,12 @@ class InferenceEngineV2:
                 raise RuntimeError(
                     f"warmup could not admit its sequence — call warmup() "
                     f"on an idle engine ({dict(out.admission.reasons)})")
+        # a round's other two programs: the key's split, and the greedy
+        # sampler over the forward's whole logits with the tail a serving
+        # session gives it
+        _, key = split_key(jax.random.PRNGKey(0))
+        self.sample_drained([uid], key, SamplingParams(),
+                            tail=self.moe_tail())
         if cfg.decode_steps_per_dispatch > 1:
             # compile the fused K-step steady-state program too, for
             # generate()'s default greedy/no-eos config (non-default sampling
@@ -447,7 +493,7 @@ class InferenceEngineV2:
         cfg = self.config
         with self._phase("schedule"):
             admission = self._enqueue(uids, tokens_list, strict)
-        out = PutResult()
+        out = PutResult(self)
         out.admission = admission
         while True:
             with self._phase("schedule"):
@@ -477,8 +523,10 @@ class InferenceEngineV2:
                     if self.prefix_cache is not None:
                         self._commit_prefix(d)
                     if not d.pending:
-                        d.last_logits = logits[slot]
-                        out[d.uid] = d.last_logits
+                        # a handle, no launch: the row stays in the
+                        # forward's array until somebody reads it
+                        d.last_logits = out._refs[d.uid] = LogitsRef(
+                            logits, slot)
             if not drain:
                 break
             if all(not d.pending for d in self.seqs.values()):
@@ -699,6 +747,10 @@ class InferenceEngineV2:
         return d
 
     def _run(self, chunks) -> jax.Array:
+        """One forward over ``chunks``; its whole ``[max_sequences, V]``
+        logits, on the device, row ``slot`` being chunk ``slot``'s: put()
+        hands out handles and sample_drained gathers by slot, nothing is
+        cut out here."""
         cfg = self.config
         if all(n == 1 and d.n_cached > 0 for d, n in chunks):
             return self._run_decode(chunks)  # kernel fast path
@@ -722,11 +774,7 @@ class InferenceEngineV2:
                 jnp.asarray(batch.token_seq), jnp.asarray(batch.token_pos),
                 jnp.asarray(batch.block_tables),
                 jnp.asarray(batch.last_tok_idx), *atom_args)
-        # DEVICE-resident: per-slot rows are sliced on device and only
-        # fetched when a caller materializes them (query()/np.asarray) —
-        # generate()'s sampler consumes them without a host round trip
-        with self._phase("collect"):
-            return logits[:len(chunks)]
+        return logits
 
     def _slot_arrays(self, descs):
         """Per-slot decode metadata padded to max_sequences — the ONE
@@ -765,11 +813,7 @@ class InferenceEngineV2:
                 self.params, self.kv, jnp.asarray(tokens),
                 jnp.asarray(positions), jnp.asarray(tables),
                 jnp.asarray(active))
-        # DEVICE-resident: per-slot rows are sliced on device and only
-        # fetched when a caller materializes them (query()/np.asarray) —
-        # generate()'s sampler consumes them without a host round trip
-        with self._phase("collect"):
-            return logits[:len(chunks)]
+        return logits
 
     def _decode_multi_dispatch(self, running: Dict[int, int],
                                sp: "SamplingParams",
@@ -827,10 +871,9 @@ class InferenceEngineV2:
             steps_left = np.zeros((s_max,), np.int32)
             steps_left[:n] = [running[u] for u in uids]
             self._note_forward([self.seqs[u] for u in uids], [0] * n)
+        with self._phase("gather"):
+            logits0 = self._drained_rows(uids)
         with self._phase("dispatch"):
-            stacked = jnp.stack([self.seqs[u].last_logits for u in uids])
-            logits0 = jnp.zeros((s_max, stacked.shape[-1]),
-                                jnp.float32).at[:n].set(stacked)
             toks_d, logits_f, pos_f, act_f, sl_f, self.kv = self._dispatch(
                 f"decode_multi_{k}", fn,
                 self.params, self.kv, logits0, jnp.asarray(positions),
@@ -866,7 +909,7 @@ class InferenceEngineV2:
                     self._commit_prefix(d)
                 if act_h[i]:
                     running[u] = int(sl_h[i])
-                    d.last_logits = logits_f[i]
+                    d.last_logits = LogitsRef(logits_f, i)
                 else:
                     del running[u]
                     self.flush([u])
@@ -944,13 +987,100 @@ class InferenceEngineV2:
         return k
 
     # ------------------------------------------------------------ query/flush
-    def query(self, uid: int) -> Optional[jax.Array]:
-        """Last-token logits if the uid's input has drained (reference
-        ``query:153``). DEVICE-resident (a jax array): ``np.asarray`` it to
-        materialize on host; device consumers (samplers) use it without a
-        host round trip."""
+    def has_logits(self, uid: int) -> bool:
+        """Whether ``uid``'s input has drained and its last-token logits
+        wait to be sampled: a test on the host, nothing is launched."""
         d = self.seqs.get(uid)
-        return None if d is None else d.last_logits
+        return d is not None and d.last_logits is not None
+
+    def query(self, uid: int) -> Optional[jax.Array]:
+        """Last-token logits [V] if the uid's input has drained (reference
+        ``query:153``), else None. DEVICE-resident (a jax array):
+        ``np.asarray`` it to materialize on host. The row is cut out of its
+        forward's array HERE, a launch per call: a loop that only samples
+        asks :meth:`has_logits` and :meth:`sample_drained`."""
+        d = self.seqs.get(uid)
+        if d is None or d.last_logits is None:
+            return None
+        return self._slice_row(d.last_logits)
+
+    def _slice_row(self, ref: LogitsRef) -> jax.Array:
+        self.logit_rows_sliced += 1
+        return ref.array[ref.slot]
+
+    # ---------------------------------------------------------------- sample
+    def _logit_groups(self, uids: Sequence[int]
+                      ) -> List[Tuple[jax.Array, np.ndarray, List[int]]]:
+        """The drained ``uids``' logits by the forward that holds them:
+        ``(array, slots [max_sequences] int32, places)`` with ``slots[i]``
+        the row of ``uids[i]`` in ``array`` for every ``i`` in ``places``
+        (0 elsewhere: a pad reads row 0 and nobody reads its result). One
+        group in a serving session, where every drained row is the last
+        forward's; more only for rows kept from an earlier forward."""
+        groups: Dict[int, Tuple[jax.Array, np.ndarray, List[int]]] = {}
+        for i, uid in enumerate(uids):
+            ref = self.seqs[uid].last_logits
+            group = groups.get(id(ref.array))
+            if group is None:
+                group = groups[id(ref.array)] = (
+                    ref.array,
+                    np.zeros((self.config.max_sequences,), np.int32), [])
+            group[1][i] = ref.slot
+            group[2].append(i)
+        return list(groups.values())
+
+    def _drained_rows(self, uids: Sequence[int]) -> jax.Array:
+        """``[max_sequences, V]`` with ``uids[i]``'s logits in row ``i``:
+        the K-step program's ``logits0`` (rows past ``len(uids)`` belong to
+        inactive slots, whatever they hold)."""
+        rows = None
+        for array, slots, places in self._logit_groups(uids):
+            got = self._gather_fn(array, slots)
+            if rows is None:
+                rows = got
+            else:
+                mine = np.zeros((len(slots), 1), bool)
+                mine[places] = True
+                rows = jnp.where(mine, got, rows)
+        return rows
+
+    def moe_tail(self) -> Optional[jax.Array]:
+        """What a serving round gives :meth:`sample_drained` as ``tail``: a
+        sparse-expert model's ``moe_touched`` of the last forward (device
+        int32 scalar), None for a dense model."""
+        return None if self.kv.moe is None else self.kv.moe.touched
+
+    def sample_drained(self, uids: Sequence[int], rng: jax.Array,
+                       sampling: SamplingParams,
+                       tail: Optional[jax.Array] = None
+                       ) -> Tuple[np.ndarray, Optional[int]]:
+        """One token for each of ``uids`` (all :meth:`has_logits`), sampled
+        on the device from the forward's whole logits: ONE launch of one
+        fixed-shape program (gather the rows by slot, ``sample_token_dyn``)
+        and ONE read-back of ``[max_sequences]`` tokens, whatever the number
+        of live sequences. ``tail`` (device int32 scalar) rides behind the
+        tokens in that read-back. Returns ``(tokens [len(uids)] int32 on the
+        host, the tail's value or None)``.
+
+        Row ``i``'s draw depends on ``rng``, ``i`` and its own logits alone,
+        so rows held by DIFFERENT forwards (a caller driving ``put`` by hand)
+        are sampled a launch per forward with the same key, and give what
+        one launch would."""
+        with self._phase("gather"):
+            groups = self._logit_groups(uids)
+            temperature = np.float32(sampling.temperature)
+            top_p = np.float32(sampling.top_p)
+        with self._phase("sample"):
+            outs = [self._sample_fn(array, slots, rng, temperature, top_p,
+                                    sampling.structure, tail)
+                    for array, slots, _places in groups]
+            self.host_dispatches += len(outs)  # a sampler is a dispatch too
+        with self._phase("readback"):
+            outs = [np.asarray(o) for o in outs]
+        toks = np.zeros((len(uids),), np.int32)
+        for (_array, _slots, places), got in zip(groups, outs):
+            toks[places] = got[places]
+        return toks, (None if tail is None else int(outs[0][-1]))
 
     def flush(self, uids: Sequence[int]) -> None:
         """Release sequences and their KV blocks (reference ``flush:228``)."""
@@ -979,7 +1109,7 @@ class InferenceEngineV2:
         sp = SamplingParams(do_sample, float(temperature), int(top_k),
                             float(top_p))
         if rng is None:
-            self._rng, rng = jax.random.split(self._rng)
+            self._rng, rng = split_key(self._rng)
         for p in prompts:
             if len(p) > cfg.max_context:
                 raise ValueError(f"prompt of {len(p)} tokens can never fit "
@@ -1000,8 +1130,8 @@ class InferenceEngineV2:
                 [uid_base + waiting[0][0]], [len(waiting[0][1])])
             if (cfg.decode_steps_per_dispatch > 1 and running
                     and (not waiting or backlog_stuck)
-                    and all(self.query(u) is not None for u in running)):
-                rng, sub = jax.random.split(rng)
+                    and all(self.has_logits(u) for u in running)):
+                rng, sub = split_key(rng)
                 emitted = self._decode_multi_dispatch(running, sp,
                                                       eos_token_id, sub)
                 if emitted is not None:
@@ -1011,19 +1141,13 @@ class InferenceEngineV2:
             # 1. one batched sample over every drained sequence
             put_uids: List[int] = []
             put_toks: List[List[int]] = []
-            drained = [(u, self.query(u)) for u in list(running)]
-            drained = [(u, lg) for u, lg in drained if lg is not None]
+            drained = [u for u in running if self.has_logits(u)]
             if drained:
-                rng, sub = jax.random.split(rng)
-                # logits are device-resident: stack + sample stay on device;
-                # only the sampled token ids (one int per sequence) cross to
-                # the host — not 2×V floats per sequence per step
-                toks = np.asarray(self._sample_fn(
-                    jnp.stack([lg for _, lg in drained]), sub,
-                    jnp.float32(sp.temperature), jnp.float32(sp.top_p),
-                    sp.structure))
-                self.host_dispatches += 1  # the sampler is a dispatch too
-                for (uid, _), tok in zip(drained, toks):
+                rng, sub = split_key(rng)
+                # the logits stay on the device; only the sampled token ids
+                # (one int per slot) cross to the host
+                toks, _ = self.sample_drained(drained, sub, sp)
+                for uid, tok in zip(drained, toks):
                     tok = int(tok)
                     results[uid - uid_base].append(tok)
                     running[uid] -= 1

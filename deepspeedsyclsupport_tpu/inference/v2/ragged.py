@@ -16,7 +16,7 @@ blocks_per_seq) so ONE compiled XLA program serves every batch composition —
 the TPU equivalent of the reference building variable-size batches eagerly.
 """
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -137,6 +137,16 @@ class BlockedAllocator:
     free = release
 
 
+class LogitsRef(NamedTuple):
+    """Where a drained sequence's last-token logits are: row ``slot`` of the
+    ``[max_sequences, V]`` array ONE forward returned. The slot is the
+    sequence's place in THAT forward's chunks (it changes from forward to
+    forward); the row is cut out only for a caller that reads it
+    (``InferenceEngineV2.query``), the sampler gathers by slot."""
+    array: Any   # the forward's whole logits, on the device
+    slot: int
+
+
 @dataclass(eq=False)  # identity semantics: descriptors live in scheduler sets
 class SequenceDescriptor:
     """Per-sequence serving state (reference ``DSSequenceDescriptor``)."""
@@ -145,7 +155,7 @@ class SequenceDescriptor:
     pending: List[int] = field(default_factory=list)  # tokens awaiting forward
     n_cached: int = 0                                 # tokens with KV in cache
     blocks: List[int] = field(default_factory=list)   # owned KV block ids
-    last_logits: Optional[np.ndarray] = None          # set when pending drains
+    last_logits: Optional[LogitsRef] = None           # set when pending drains
     # --- prefix-cache state (inference/v2/prefix_cache.py) ---------------
     cached_prefix_len: int = 0  # tokens adopted from the prefix cache at
     #                             admission (block-aligned; positions/

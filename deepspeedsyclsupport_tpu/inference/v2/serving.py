@@ -38,13 +38,11 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 
 from .config import ServingPolicyConfig
 from .kv_cache import kv_pool_stats
 from .scheduler import SlackPolicy, slack_of
-from ..sampling import SamplingParams
+from ..sampling import SamplingParams, split_key
 from ...comm.watchdog import SERVE_HANG_EXIT_CODE, CollectiveWatchdog
 from ...monitor.reqtrace import (FORWARD_FIELDS, NO_PHASE, ROUND_PHASES,
                                  check_phase)
@@ -965,7 +963,7 @@ class ServingSession:
     def _fused_round(self, now: float, events: List[ServeEvent]) -> bool:
         with self._phase("schedule"):
             budgets = {u: self.running[u].budget for u in self.running}
-            self._rng, sub = jax.random.split(self._rng)
+            self._rng, sub = split_key(self._rng)
             k_cap = self._k_cap(now)
         # the engine's own phases: schedule (rung, pre-funded blocks), build,
         # dispatch, readback (the K x S token block), collect
@@ -1000,42 +998,30 @@ class ServingSession:
     def _per_token_round(self, now: float, events: List[ServeEvent]) -> None:
         eng = self.eng
         sp = self.sampling
-        # 1. one batched device sample over every drained stream
-        drained: List[Tuple[int, jax.Array]] = []
+        # 1. one device sample over every drained stream, from the last
+        # forward's whole logits: one launch and one read-back at any count
         with self._phase("gather"):
-            for uid in list(self.running):
-                if uid in self._pending_tok:
-                    continue
-                lg = eng.query(uid)
-                if lg is not None:
-                    drained.append((uid, lg))
+            drained = [uid for uid in self.running
+                       if uid not in self._pending_tok
+                       and eng.has_logits(uid)]
             if drained:
-                self._rng, sub = jax.random.split(self._rng)
-                rows = jnp.stack([lg for _, lg in drained])
+                self._rng, sub = split_key(self._rng)
         if drained:
-            with self._phase("sample"):
-                # a sparse-expert model's last forward counted the experts
-                # it touched on the device: the scalar rides behind the tokens
-                moe = eng.kv.moe
-                toks = eng._sample_fn(
-                    rows, sub, jnp.float32(sp.temperature),
-                    jnp.float32(sp.top_p), sp.structure,
-                    None if moe is None else moe.touched)
-                eng.host_dispatches += 1  # the sampler is a dispatch too
-            with self._phase("readback"):
-                toks = np.asarray(toks)
-            if moe is not None:
-                toks, touched = toks[:-1], int(toks[-1])
-                if self._spans is not None:
-                    self._spans.fields["moe_touched"] = touched
+            # a sparse-expert model's last forward counted the experts it
+            # touched on the device: the scalar rides behind the tokens.
+            # The engine times its own gather, sample and readback
+            toks, touched = eng.sample_drained(drained, sub, sp,
+                                               tail=eng.moe_tail())
+            if touched is not None and self._spans is not None:
+                self._spans.fields["moe_touched"] = touched
             t1 = self.clock()
             if self._last_decode_s is not None:
                 self.capacity.record_decode(1, t1 - self._last_decode_s)
             self._last_decode_s = t1
             if self._spans is not None:
-                self._spans.fields["uids"] = sorted(u for u, _lg in drained)
+                self._spans.fields["uids"] = sorted(drained)
             with self._phase("emit"):
-                for (uid, _lg), tok in zip(drained, toks):
+                for uid, tok in zip(drained, toks):
                     tok = int(tok)
                     req = self.running[uid]
                     events.append(ServeEvent("token", uid, t1, tokens=[tok]))
@@ -1111,7 +1097,7 @@ class ServingSession:
             # (a uid drained this round has first_token_s set by
             # _note_emission, so only freshly-landed prefills sample here)
             for uid, req in self.running.items():
-                if req.first_token_s is None and eng.query(uid) is not None:
+                if req.first_token_s is None and eng.has_logits(uid):
                     self.capacity.record_prefill(len(req.tokens),
                                                  t1 - req.enqueue_s)
 

@@ -75,21 +75,22 @@ STAGE_HISTOGRAMS = ("prefill", "decode")
 #: opens a ``dstpu/serve/<phase>`` profiler annotation around each, and
 #: writes their seconds into the ``round`` stage record's ``phases``. A
 #: phase is time on the HOST's clock, not host work alone: ``readback``
-#: asks the device for a value, and ANY launch (a ``collect`` logits slice,
-#: a ``gather`` stack, the next ``dispatch``) blocks while the device's
-#: launch queue is full, so a phase behind a long forward holds that
-#: forward's time. What the host costs the device is read off a profile:
-#: the idle gaps at the round's ends and its number of launches.
+#: asks the device for a value (the tokens the sampler drew behind the last
+#: forward: it holds what is left of that forward's time), and ANY launch
+#: blocks while the device's launch queue is full. A round launches three
+#: programs (the key's split, the sampler, the forward), so the queue does
+#: not fill. What the host costs the device is read off a profile: the idle
+#: gaps at the round's ends and its number of launches.
 ROUND_PHASES = (
     "queue",      # _maintain_queue, slack policy, watchdog arm
-    "gather",     # eng.query rows + jnp.stack of the drained logits
+    "gather",     # who has drained (host), rng split, the [S] slot vector
     "sample",     # the sampler's dispatch
     "readback",   # np.asarray(tokens): the round asks the device for a value
     "emit",       # events, _note_emission, _finish/flush
     "schedule",   # KV-pressure loop, check_schedule, schedule_chunks, CoW
     "build",      # build_ragged_batch / _slot_arrays (numpy only)
     "dispatch",   # host-to-device copies + the forward's launch, until it returns
-    "collect",    # put() after the launch: logits rows, descriptors, prefix index
+    "collect",    # put() after the launch: descriptors, logits handles, prefix index
     "account",    # prefill_chunk stamps, capacity samples, progress valve, gauges
     "other")      # whatever no phase claimed
 

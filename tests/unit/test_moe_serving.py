@@ -162,6 +162,26 @@ def test_load_adds_up_over_rounds_and_the_record_carries_touched(tiny_moe):
     sess.close()
 
 
+def test_touched_rides_behind_the_sampled_tokens(tiny_moe):
+    """``sample_drained`` with the model's tail: the same tokens as without
+    it, and the last forward's ``moe_touched`` in the same read-back."""
+    from deepspeedsyclsupport_tpu.inference.sampling import SamplingParams
+
+    eng = _engine(tiny_moe)
+    eng.put([1, 2], [[1, 2, 3], list(range(4, 12))])
+    cfg = eng.model.config
+    key = jax.random.PRNGKey(0)
+    plain, none = eng.sample_drained([2, 1], key, SamplingParams())
+    toks, touched = eng.sample_drained([2, 1], key, SamplingParams(),
+                                       tail=eng.moe_tail())
+    assert none is None and toks.tolist() == plain.tolist()
+    assert toks.shape == (2,)
+    assert touched == int(eng.kv.moe.touched)
+    assert cfg.num_layers * cfg.num_experts_per_tok <= touched \
+        <= cfg.num_layers * cfg.num_experts
+    assert eng.logit_rows_sliced == 0
+
+
 def test_load_counts_the_fused_decode_steps(tiny_moe):
     eng = _engine(tiny_moe, decode_steps_per_dispatch=4)
     sess = ServingSession(eng, ServingPolicyConfig(admission="none"))
