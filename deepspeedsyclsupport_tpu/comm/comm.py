@@ -230,8 +230,8 @@ def init_distributed(coordinator_address: Optional[str] = None,
                      dist_init_required: Optional[bool] = None) -> bool:
     """Initialize multi-host JAX runtime.
 
-    Single-host (the common test/bench path) is a no-op: JAX already sees all local
-    devices. Multi-host reads env — JAX-native vars or the reference's
+    Single-host (the common test/benchmark path) is a no-op: JAX already sees
+    all local devices. Multi-host reads env — JAX-native vars or the reference's
     RANK/WORLD_SIZE/MASTER_ADDR convention set by its launcher
     (``launcher/launch.py:132``) — and calls ``jax.distributed.initialize``.
     ``auto_mpi_discovery`` mirrors ``mpi_discovery`` (``comm/comm.py:673``) by reading

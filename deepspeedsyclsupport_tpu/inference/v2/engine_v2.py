@@ -329,7 +329,7 @@ class InferenceEngineV2:
         real requests pay two spurious recompiles (measured ~1.7s each on
         the CPU sim; worse on TPU). ``fused_ladder=True`` additionally
         compiles EVERY fused-decode rung {K/2, ..., 2}, not just K — a
-        serving bench must not pay a mid-run compile when a short tail
+        timed window must not pay a mid-run compile when a short tail
         first selects a smaller rung (off by default: tests and callers
         that never hit the fused path shouldn't pay log2(K) compiles)."""
         cfg = self.config

@@ -3,9 +3,8 @@ journal-based cross-replica failover.
 
 One :class:`FleetRouter` fronts N replicas behind a uniform
 :class:`ReplicaEndpoint` seam — in-process sessions
-(:class:`LocalReplica`, what the bench's CPU-sim fleet and the unit tests
-drive) and supervised worker processes
-(:class:`~.pool.ProcessReplica`) route identically. The router never
+(:class:`LocalReplica`, what the unit tests drive) and supervised worker
+processes (:class:`~.pool.ProcessReplica`) route identically. The router never
 touches engine internals: it observes each replica through the SAME
 artifacts an operator has — the ``health.json`` readiness probe and the
 request-journal stream — so everything here keeps working when the
@@ -170,8 +169,8 @@ class LocalReplica(ReplicaEndpoint):
     """In-process replica: one :class:`~..serving.ServingSession` behind the
     endpoint seam. ``kill()`` emulates a hard replica death (engine KV and
     session state dropped, journal left UNclosed — exactly what a crash
-    leaves on disk), which is how the bench's CPU-sim fleet injects its
-    mid-sweep fault."""
+    leaves on disk), which is how the failover tests inject their
+    mid-stream fault."""
 
     def __init__(self, replica_id: str, session, *,
                  journal_dir: Optional[str] = None):
@@ -318,7 +317,7 @@ class FleetRouter:
         self._sticky: Dict[str, str] = {}
         self._dead: set = set()
         #: in-memory mirror of the router stream — journal-record-shaped
-        #: dicts the bench's per-load-point request-waterfall join drains
+        #: dicts an in-process request-waterfall join drains
         #: (``monitor.reqtrace`` reads the same shape off disk)
         self.trace_log: deque = deque(maxlen=65536)
         self._slo_ttft: deque = deque()   # (t, ok) at first token
@@ -353,8 +352,8 @@ class FleetRouter:
 
     # ------------------------------------------------------------- plumbing
     def _record(self, name: str, data: Dict[str, Any]) -> None:
-        # the in-memory ring always mirrors the stream (the bench joins it
-        # without a log_path); the flight recorder only when configured
+        # the in-memory ring always mirrors the stream (an in-process join
+        # needs no log_path); the flight recorder only when configured
         self.trace_log.append({"name": name, "t": self.clock(),
                                "data": dict(data)})
         if self._rec is not None:
@@ -573,9 +572,8 @@ class FleetRouter:
     # ------------------------------------------------------------- failover
     def mark_dead(self, replica_id: str,
                   now: Optional[float] = None) -> List[FleetEvent]:
-        """Operator/driver override: declare a replica dead NOW (the bench's
-        injected kill) and run failover without waiting for the health
-        grace."""
+        """Operator/driver override: declare a replica dead NOW and run
+        failover without waiting for the health grace."""
         if replica_id in self._dead:
             return []
         return self.failover(replica_id, self.clock() if now is None
@@ -786,7 +784,7 @@ class FleetRouter:
                 "per_replica": per}
 
     def stats(self) -> Dict[str, Any]:
-        """Counters + per-replica breakdown for bench lines and operators."""
+        """Counters + per-replica breakdown for the fleet CLI and operators."""
         out = {**self.counters,
                **{f"failover_{n}": v
                   for n, v in self.failover_counters.items()},

@@ -2,9 +2,8 @@
 
 The scheduling policy the "Ragged Paged Attention" stack assumes sits above
 the paged KV cache (PAPERS.md): the engine below this module is a batch
-executor — it will happily admit everyone and let everyone miss deadline
-(the r05 SLA bench: 100% miss at 10 clients). This layer makes overload
-degrade *gracefully* instead:
+executor — it will happily admit everyone and let everyone miss deadline.
+This layer makes overload degrade *gracefully* instead:
 
 * **admission control** — every request carries a deadline budget (TTFT
   bound + decode token-rate SLA, stamped onto its
@@ -311,7 +310,7 @@ class _Request:
 
 class ServingSession:
     """Drives one engine under the SLA policy; the serving loop an MII-style
-    frontend (or ``bench.py``'s closed-loop clients) sits on.
+    frontend (or the benchmark harness's load loop) sits on.
 
     ``submit()`` is the admission gate; ``step()`` runs one scheduling
     round — queue maintenance, slack-ordered batch composition, fused or
@@ -350,8 +349,8 @@ class ServingSession:
         self._stall_rounds = 0     # consecutive no-progress rounds
         # request-time attribution (monitor/reqtrace.py; docs/
         # observability.md): lifecycle-edge records mirrored into a bounded
-        # in-memory ring so bench load points join per-request waterfalls
-        # with zero disk IO in the measured path (the journal — when
+        # in-memory ring so a caller joins per-request waterfalls with
+        # zero disk IO in the measured path (the journal — when
         # configured — carries the same records durably). The fixed wall
         # offset maps this session's monotonic clock onto the journal's
         # wall stamps: every record rides ONE clock base, so the offline
@@ -471,9 +470,9 @@ class ServingSession:
             **spans.fields, "phases": dict(spans.phases)})
 
     def drain_trace(self) -> List[Dict[str, Any]]:
-        """Hand over and clear the in-memory lifecycle records — the bench
-        rungs drain per load point so each point's waterfall joins only its
-        own requests."""
+        """Hand over and clear the in-memory lifecycle records — the
+        benchmark harness drains once per window so the waterfall joins only
+        that window's requests."""
         out = list(self.trace_log)
         self.trace_log.clear()
         return out
@@ -1268,7 +1267,8 @@ class ServingSession:
         return None if pc is None else pc.stats()
 
     def stats(self) -> Dict[str, float]:
-        """Counters + instantaneous state, for bench lines and operators."""
+        """Counters + instantaneous state, for the worker's result file and
+        operators."""
         out = {**self.counters,
                **{f"recovery_{n}": v
                   for n, v in self.recovery_counters.items()},

@@ -458,8 +458,8 @@ def journal_path(journal_dir: str, rank: int = 0,
                  attempt: Any = None) -> str:
     """Per-incarnation journal filename — the ONE place the
     ``journal_rank<r>.att<N>.jsonl`` convention lives (``_journal_files``
-    discovers it, the worker and bench construct it). ``attempt`` defaults
-    to this incarnation's ``DSTPU_ELASTIC_ATTEMPT``. Under a fleet pool,
+    discovers it, the worker constructs it). ``attempt`` defaults to this
+    incarnation's ``DSTPU_ELASTIC_ATTEMPT``. Under a fleet pool,
     ``DSTPU_FLEET_GEN`` (the supervisor *generation* — bumped on every
     pool respawn) namespaces the attempt so a respawned supervisor's
     attempt 0 never appends to a dead generation's file — appending would

@@ -223,13 +223,6 @@ PRESETS = {
                   rotary_pct=0.25, parallel_block=True, shared_block_norm=True,
                   lm_head_bias=True),
     # Llama-2 family (FastGen/ZeRO baselines; blogs/deepspeed-fastgen/README.md:135)
-    # llama-650m: single-v5e bench size — fp32 master + Adam moments + grads
-    # (16 bytes/param peak) fit a 16GB chip with headroom, unlike the 1b
-    "llama-650m": _p(vocab_size=32000, hidden_size=1792, intermediate_size=4864,
-                     num_layers=14, num_heads=14, num_kv_heads=14,
-                     max_seq_len=4096),
-    "llama2-1b": _p(vocab_size=32000, hidden_size=2048, intermediate_size=5504,
-                    num_layers=16, num_heads=16, num_kv_heads=16, max_seq_len=4096),
     "llama2-7b": _p(vocab_size=32000, hidden_size=4096, intermediate_size=11008,
                     num_layers=32, num_heads=32, num_kv_heads=32, max_seq_len=4096),
     "llama2-13b": _p(vocab_size=32000, hidden_size=5120, intermediate_size=13824,

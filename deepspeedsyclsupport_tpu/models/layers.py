@@ -326,8 +326,8 @@ def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     Sequence-parallel impls take an inner (per-shard) implementation after
     a colon — ``"ring:flash"`` / ``"ring:xla"`` / ``"ulysses:flash"`` /
-    ``"ulysses:xla"`` — the ``attn_impl`` spelling the bench's ring A/B
-    arms use; bare ``"ring"``/``"ulysses"`` auto-select (flash on TPU).
+    ``"ulysses:xla"``; bare ``"ring"``/``"ulysses"`` auto-select (flash on
+    TPU).
     """
     inner = None
     if impl and ":" in impl:

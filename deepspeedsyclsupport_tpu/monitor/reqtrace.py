@@ -25,8 +25,8 @@ owns the three pieces that answer it:
 * **attribution** — :func:`attribution`: TTFT and ITL decomposed per stage
   at p50/p95/p99, tail attribution (which stage grew for the slowest
   decile vs the median cohort), SLO burn over sliding windows, and the
-  N worst requests' waterfalls — the ``detail.request_waterfall`` payload
-  the bench rungs emit and ``tools/trace_report.py --requests`` renders.
+  N worst requests' waterfalls — the payload ``tools/trace_report.py
+  --requests`` renders.
 
 DELIBERATELY STDLIB-ONLY: ``tools/trace_report.py`` loads this file by path
 on jax-less login nodes (the ``pod.py``/``mfu.py`` contract —
@@ -705,8 +705,7 @@ def attribution(traces: Dict[int, Dict[str, Any]], worst_n: int = 5,
 def waterfall(streams: Iterable[Tuple[str, str, Sequence[Dict[str, Any]]]],
               router_records: Sequence[Dict[str, Any]] = (),
               since: Optional[float] = None, **kw) -> Dict[str, Any]:
-    """join + attribution in one call (the bench rungs' per-load-point
-    entry: hand over the in-memory ``trace_log`` buffers, get the
-    ``detail.request_waterfall`` payload)."""
+    """join + attribution in one call: hand over the in-memory
+    ``trace_log`` buffers, get the payload :func:`attribution` returns."""
     return attribution(join_traces(streams, router_records, since=since),
                        **kw)

@@ -1324,7 +1324,8 @@ class Engine:
         avals captured at its call site (re-lowering is a compile-cache
         hit). ``analyzers`` selects a subset — the dtype audit re-traces
         the raw step with ``make_jaxpr``, which a caller that only wants
-        the donation report (the bench) should not pay for.
+        one report (``benchmark/run.py`` asks for the census alone) should
+        not pay for.
 
         ``gathers_per_param`` defaults from this engine's own remat config
         (2 when activation checkpointing is on — backward may legally

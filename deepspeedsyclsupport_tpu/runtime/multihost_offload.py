@@ -100,7 +100,8 @@ class MultiHostCPUAdam:
                            else np.dtype(push_dtype))
         self._host_device = jax.local_devices(backend="cpu")[0]
         #: last step's OffloadStats dict (engine telemetry pulls it) and
-        #: run-cumulative totals (bench reads effective bandwidths off it)
+        #: run-cumulative totals (:meth:`offload_summary` derives the
+        #: effective bandwidths from them)
         self.last_stats: Optional[Dict[str, Any]] = None
         self.totals: Dict[str, float] = {}
 
@@ -430,7 +431,7 @@ class MultiHostCPUAdam:
 
     def offload_summary(self) -> Dict[str, Any]:
         """Run-cumulative transfer/compute ledger + derived effective
-        bandwidths — the bench rung's per-arm evidence."""
+        bandwidths."""
         t = dict(self.totals)
         out: Dict[str, Any] = {k: v for k, v in t.items()}
         for direction, secs in (("d2h", t.get("d2h_s", 0.0)),

@@ -12,7 +12,8 @@ without devices:
 * :class:`OffloadStats` — per-step byte/seconds ledger for every tier
   (D2H grad pull, host compute, H2D master push, NVMe moment window) with
   the *exposed* stall separated from total transfer occupancy; overlap
-  efficiency = 1 − exposed/total is the bench headline.
+  efficiency = 1 − exposed/total is the headline ``tools/trace_report.py``
+  prints.
 * :class:`ShardPull` — one async device→host grad-shard fetch
   (non-blocking ``jax.device_put`` to the host backend with a delayed
   wait) so every pull is in flight before anything blocks on it.

@@ -18,7 +18,7 @@ REPO_CACHE_DIR = os.path.join(
 
 def place_compile_cache() -> str:
     """Point jax at the compile cache; returns the directory in use. Call
-    before the first compile (``chip_smoke.py``, ``bench.py`` children,
+    before the first compile (``chip_smoke.py``, ``benchmark/run.py``,
     ``__graft_entry__.py``)."""
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:

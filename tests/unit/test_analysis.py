@@ -5,7 +5,7 @@ Three layers:
 * graph analyzers against a REAL compiled ZeRO-3 engine step on the 8-device
   virtual mesh — the collective census must match the analytic expectation
   exactly (counts AND bytes), and the fused train step must donate params +
-  optimizer state (the bench training config's contract);
+  optimizer state (the bf16 + activation-checkpointing config's contract);
 * analyzer unit behavior on small hand-built programs (donation miss, dtype
   upcasts, resharding boundary/internal, jaxpr walker trip counts);
 * the codebase lint rule engine + baseline workflow + the ``tools/dslint.py``
@@ -132,7 +132,7 @@ class TestDonationAudit:
         assert rep.wasted_bytes == 0
 
     def test_bench_train_config_donates(self):
-        """The bench training config (bf16 + activation_checkpointing, the
+        """The chip training config (bf16 + activation_checkpointing, the
         ROADMAP MFU levers) on the real transformer: params + optimizer
         state must donate — an undonated tree is a silent HBM doubling."""
         from deepspeedsyclsupport_tpu.models import build_model, get_config

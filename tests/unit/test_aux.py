@@ -393,52 +393,34 @@ class TestNuma:
         assert len(nodes) == 1 and len(nodes[0]) >= 1
 
 
-class TestBenchLadder:
-    """bench.py: the train ladder steps down on failure, and the parent
-    refuses to run anything when no TPU answers."""
+class TestTuneChainTimer:
+    """tools/tpu_tune.py carries its own chain timer (the block-size sweeps'
+    clock) and imports nothing from outside the package and jax."""
 
-    def test_train_ladder_steps_down(self, monkeypatch):
-        import types
+    @pytest.fixture
+    def tune(self, monkeypatch):
+        import importlib.util
+        import os
+        import sys
 
-        import bench
+        monkeypatch.setattr(sys, "path", list(sys.path))  # the tool prepends
+        path = os.path.join(os.path.dirname(__file__), "..", "..", "tools",
+                            "tpu_tune.py")
+        spec = importlib.util.spec_from_file_location("tpu_tune", path)
+        tune = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(tune)
+        assert "bench" not in sys.modules
+        return tune
 
-        calls = []
-
-        def fake_measure(name, seq, micro, steps, remat, platform):
-            calls.append((name, micro, remat))
-            if len(calls) < 3:
-                raise RuntimeError("RESOURCE_EXHAUSTED: out of memory")
-            return {"metric": "m", "value": 1.0, "unit": "tok/s",
-                    "vs_baseline": 0.5, "detail": {}}
-
-        class FakeDev:
-            platform = "tpu"
-
-        monkeypatch.setattr(bench, "_measure", fake_measure)
-        monkeypatch.setattr(bench, "_child_jax", lambda: types.SimpleNamespace(
-            devices=lambda *a: [FakeDev()], clear_caches=lambda: None))
-        bench.run_train()
-        assert len(calls) == 3
-        assert calls[0][0] == "llama2-1b" and calls[2][0] == "llama-650m"
-
-    def test_parent_runs_nothing_and_exits_nonzero_without_a_tpu(
-            self, monkeypatch, capsys):
-        """No TPU answers the probe: no rung runs (there is no CPU plan),
-        nothing is printed under a metric's name, the exit code is 2."""
-        import bench
-
-        seen = []
-
-        def fake_spawn(rung, timeout, env):
-            seen.append(rung)
-            return [{"metric": "probe", "value": 8,
-                     "detail": {"platform": "cpu"}}], None
-
-        monkeypatch.setattr(bench, "_spawn", fake_spawn)
-        assert bench.main() == 2
-        assert seen == ["probe"]
-        out = capsys.readouterr()
-        assert out.out == "" and "no TPU answered" in out.err
+    # one iteration has no chain to subtract the floor from: the verdict
+    # must say so and not report a difference of two samples
+    @pytest.mark.parametrize("iters,verdicts", [
+        (4, ("chained", "dispatch_bound")), (1, ("dispatch_bound",))])
+    def test_chain_timer_times_a_matmul_chain(self, tune, iters, verdicts):
+        b = jnp.eye(16, dtype=jnp.float32)
+        dt, how = tune._bench_chain(lambda x, b: x @ b, jnp.ones((16, 16)),
+                                    (b,), iters)
+        assert dt > 0 and how in verdicts
 
 
 class TestSpatialAndTiling:

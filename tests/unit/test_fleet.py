@@ -30,7 +30,7 @@ import pytest
 
 def teardown_module():
     # the engines built here install a world topology; drop it so later
-    # modules (alphabetically: test_serving_bench) start mesh-agnostic
+    # modules start mesh-agnostic
     from deepspeedsyclsupport_tpu.comm.topology import reset_world_topology
 
     reset_world_topology()

@@ -1,8 +1,8 @@
 """Encoder-architecture ingestion parity: BERT / DistilBERT / CLIP vs the
 real HuggingFace implementations (reference per-arch policies:
 ``deepspeed/module_inject/containers/bert.py``, ``distil_bert.py``,
-``clip.py``), plus an engine-protocol training smoke — BERT-base + ZeRO-1 is
-a BASELINE.json target config.
+``clip.py``), plus an engine-protocol training smoke: BERT-base under ZeRO-1
+(optimizer state sharded over the data axis, parameters replicated).
 """
 import numpy as np
 import pytest
@@ -163,7 +163,7 @@ class TestCLIPParity:
 
 class TestEncoderTraining:
     def test_bert_zero1_engine(self):
-        """BERT + ZeRO-1 through the engine (BASELINE.json config #1)."""
+        """BERT + ZeRO-1 (stage 1, Adam) through the engine."""
         import deepspeedsyclsupport_tpu as ds
         from deepspeedsyclsupport_tpu.comm.topology import (
             reset_world_topology)

@@ -23,7 +23,7 @@ the stream's own meta record. Torn/truncated streams (a rank killed
 mid-write) are salvaged and flagged, never fatal.
 
 The per-class table needs a static census in the streams — run with
-``engine.emit_comm_census()`` (the multichip dryrun and bench do) — or
+``engine.emit_comm_census()`` (the multichip dryrun does) — or
 pass ``--census census.json`` (a ``CollectiveClasses.summary()`` dict).
 
 Exit code 0 on success, 2 when no input yields any records.

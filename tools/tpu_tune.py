@@ -5,7 +5,7 @@ Sections, cheapest first:
   calib   — XLA matmul at known-FLOP shapes: separates dispatch overhead
             from device compute (a 1.1 TFLOP matmul at v5e peak is ~6 ms;
             if measured time is tens of ms, the gap is dispatch).
-  flash   — flash-attention block_q/block_k sweep at the bench shape.
+  flash   — flash-attention block_q/block_k sweep at one training shape.
   paged   — paged-decode block_size sweep at serving shapes.
 
 Usage:  python tools/tpu_tune.py [calib|flash|paged|all]
@@ -19,11 +19,41 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# the flash and paged sections import the package from the checkout
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
-from bench import _bench_chain  # noqa: E402  (chained timing —
-# single-dispatch fori_loop chains, immune to per-call dispatch latency)
 
 V5E_PEAK = 197e12
+
+
+def _bench_chain(fn_one, x0, extra_args, iters):
+    """Per-iteration device time of ``fn_one(x, *extra) -> x'`` measured as
+    ``iters`` data-dependent applications inside ONE jitted fori_loop — a
+    single dispatch, so per-call dispatch latency (enough to swamp a sub-ms
+    kernel) cancels out. The chained data dependency defeats CSE/DCE. The
+    one-dispatch floor is measured separately and subtracted. Returns
+    ``(seconds, "chained" | "dispatch_bound")``."""
+    def chained(x, extra):
+        return jax.lax.fori_loop(0, iters,
+                                 lambda i, xx: fn_one(xx, *extra), x)
+
+    def best_of(f, n=3):
+        jax.block_until_ready(f(x0, extra_args))    # compile/warm
+        ts = []
+        for _ in range(n):
+            t0 = time.perf_counter()
+            jax.block_until_ready(f(x0, extra_args))
+            ts.append(time.perf_counter() - t0)
+        return min(ts)
+
+    total = best_of(jax.jit(chained))
+    # dispatch floor: same structure, 1 iteration
+    floor = best_of(jax.jit(lambda x, extra: fn_one(x, *extra)))
+    if total <= floor or iters < 2:
+        # dispatch jitter swamped the kernel — the difference of two noisy
+        # samples is meaningless; report the per-dispatch bound honestly
+        # instead of clamping to an absurd number
+        return floor, "dispatch_bound"
+    return (total - floor) / (iters - 1), "chained"
 
 
 def bench(fn, args, iters=10):
