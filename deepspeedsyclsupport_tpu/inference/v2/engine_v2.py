@@ -280,16 +280,20 @@ class InferenceEngineV2:
         spans = self.round_spans
         return NO_PHASE if spans is None else spans.phase(name)
 
-    def _note_forward(self, descs, lengths) -> None:
+    def _note_forward(self, descs, lengths, atoms: int = 0) -> None:
         """What the forward about to be launched covers, for the round's
         record; called BEFORE it, so ``ctx_tokens`` is the context the
         attention kernel must read and ``kv_blocks`` the tables it walks.
+        ``atoms``: the live ``atom_q_size``-row tiles a ``ragged_forward``'s
+        batch was cut into; its one-token chunks, like every row of a
+        ``decode_forward``, are ``decode_rows``, a one-row tile each.
         Its live tokens are counted whether or not a round is recorded."""
         self._forward_tokens += sum(lengths)
         if self.round_spans is None:
             return
         self.round_spans.fields.update(
             n_seqs=len(descs), tokens=sum(lengths),
+            decode_rows=sum(n == 1 for n in lengths), atoms=atoms,
             # the rule _run routes by: one token on top of cached context
             # is a decode step, anything else is prompt
             prefill_tokens=sum(n for d, n in zip(descs, lengths)
@@ -759,21 +763,15 @@ class InferenceEngineV2:
                 chunks, cfg.max_tokens_per_batch, cfg.max_sequences,
                 cfg.blocks_per_seq,
                 atom_q=cfg.atom_q_size if self._use_atoms else None)
-            self._note_forward(*zip(*chunks))
+            self._note_forward(*zip(*chunks), atoms=batch.live_atoms)
         with self._phase("dispatch"):
-            atom_args = ()
-            if self._use_atoms:
-                atom_args = (jnp.asarray(batch.atom_qidx),
-                             jnp.asarray(batch.atom_pos0),
-                             jnp.asarray(batch.atom_qlen),
-                             jnp.asarray(batch.atom_tables),
-                             jnp.asarray(batch.atom_inv))
             logits, self.kv = self._dispatch(
                 "ragged_forward", self._forward,
                 self.params, self.kv, jnp.asarray(batch.tokens),
                 jnp.asarray(batch.token_seq), jnp.asarray(batch.token_pos),
                 jnp.asarray(batch.block_tables),
-                jnp.asarray(batch.last_tok_idx), *atom_args)
+                jnp.asarray(batch.last_tok_idx),
+                *map(jnp.asarray, batch.tile_args))
         return logits
 
     def _slot_arrays(self, descs):

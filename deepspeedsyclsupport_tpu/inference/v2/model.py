@@ -5,11 +5,13 @@ kernel set (``inference/v2/kernels/ragged_ops/``):
 
 * ``linear_blocked_kv_rotary`` — fused QKV + RoPE + paged-KV append → here the
   qkv einsums + :func:`apply_rope` + one scatter into the flat slot axis.
-* ``blocked_flash`` (attention over ragged atoms) → :func:`_paged_attention`,
-  an exact XLA implementation gathering each slot's block-table-resolved KV.
-  (A Pallas blocked-flash variant is the planned fast path; this is the
-  correctness reference the kernel will be tested against, the same
-  kernel-vs-reference pattern the CUDA tests use, SURVEY.md §4.)
+* ``blocked_flash`` (attention over ragged atoms) → the ``kernel`` impl
+  (:func:`_prefill_kernel_impl`: the Pallas ragged kernel over the atoms
+  and, for the one-token chunks, the decode entry's one-row tile) on the
+  chip; :func:`_paged_attention`, an exact XLA implementation gathering
+  each slot's block-table-resolved KV, is the correctness reference the
+  kernel is tested against (the kernel-vs-reference pattern the CUDA tests
+  use, SURVEY.md §4).
 * ``logits_gather`` — only each sequence's last scheduled token reaches the
   unembedding matmul (``engine_v2.py`` forward tail).
 
@@ -59,6 +61,8 @@ class PrefillAttnContext(NamedTuple):
     atom_qlen: Any = None
     atom_tables: Any = None
     atom_inv: Any = None
+    dec_row: Any = None
+    dec_len: Any = None
 
 
 def _dequant(p, dtype):
@@ -276,19 +280,33 @@ def _has_atoms(ctx):
                metadata={"needs_atoms": True})
 def _prefill_kernel_impl(q, ctx: PrefillAttnContext, interpret=False):
     """Ragged paged-attention Pallas kernel (arXiv:2604.15464; reference
-    blocked_flash + atom_builder): q gathers into fixed-size
-    single-sequence atoms; KV blocks stream via block-table DMA — the
-    [S, max_ctx] HBM gather of the xla impl never happens."""
-    from ...ops.paged_attention import ragged_prefill_attention
+    blocked_flash + atom_builder), the tile height following the chunk's
+    length: TWO calls of the one kernel body on the same pool and layer.
+    The rows of chunks of two tokens or more gather into fixed-size
+    single-sequence atoms of ``atom_q_size`` rows; the one-token chunks
+    (``dec_row`` / ``dec_len``, one per slot: the decoding sequences of a
+    mixed round) go through :func:`paged_decode_attention`, the one-row
+    tile ``decode_forward`` calls, and are scattered back to their packed
+    rows. KV blocks stream via block-table DMA in both — the [S, max_ctx]
+    HBM gather of the xla impl never happens."""
+    from ...ops.paged_attention import (paged_decode_attention,
+                                        ragged_prefill_attention)
 
+    impl = "pallas_interpret" if interpret else "pallas"
+    kw = dict(block_size=ctx.block_size, layer=ctx.layer, alibi=ctx.alibi,
+              window=ctx.window, impl=impl)
     q_at = q[ctx.atom_qidx]                          # [A, BQ, H, D]
     out_at = ragged_prefill_attention(
         q_at, ctx.k_cache, ctx.v_cache, ctx.atom_tables, ctx.atom_pos0,
-        ctx.atom_qlen, block_size=ctx.block_size, layer=ctx.layer,
-        alibi=ctx.alibi, window=ctx.window,
-        impl="pallas_interpret" if interpret else "pallas")
+        ctx.atom_qlen, **kw)
     flat = out_at.reshape(-1, *out_at.shape[2:])
-    return flat[ctx.atom_inv]                        # back to packed rows
+    out = flat[ctx.atom_inv]                         # back to packed rows
+    out_dec = paged_decode_attention(                # [S, H, D]
+        q[ctx.dec_row], ctx.k_cache, ctx.v_cache, ctx.block_tables,
+        ctx.dec_len, **kw)
+    # a slot with no one-token chunk scatters out of range (dropped)
+    rows = jnp.where(ctx.dec_len > 0, ctx.dec_row, q.shape[0])
+    return out.at[rows].set(out_dec, mode="drop")
 
 
 @register_impl("prefill_attn", "kernel_interpret", priority=-10,
@@ -382,13 +400,15 @@ def _scan_layers(layer, x, kv: BlockedKV, layer_params):
 def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
                    token_pos, block_tables, last_tok_idx,
                    atom_qidx=None, atom_pos0=None, atom_qlen=None,
-                   atom_tables=None, atom_inv=None, *, block_size: int,
-                   attn_impl: str = "auto"
+                   atom_tables=None, atom_inv=None, dec_row=None,
+                   dec_len=None, *, block_size: int, attn_impl: str = "auto"
                    ) -> Tuple[jnp.ndarray, BlockedKV]:
     """Flat-token forward. Returns (per-slot last-token logits [S, V], new kv).
 
     ``model``: a ``models.CausalLM`` — its stacked-layer params drive a
     ``lax.scan`` here exactly as in training (``models/transformer.py``).
+    ``atom_*`` and ``dec_*`` are ``RaggedBatch.tile_args``, what the
+    ``kernel`` attention takes (the others route by ``token_seq`` alone).
     """
     cfg = model.config
     assert cfg.scan_layers, "ragged engine requires scan_layers param layout"
@@ -431,7 +451,7 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
                 block_size=bs, alibi=ab, window=window,
                 atom_qidx=atom_qidx, atom_pos0=atom_pos0,
                 atom_qlen=atom_qlen, atom_tables=atom_tables,
-                atom_inv=atom_inv)
+                atom_inv=atom_inv, dec_row=dec_row, dec_len=dec_len)
             return spec.fn(q, ctx)[..., :cfg.head_dim]
 
         x, rows = _block(cfg, p, x, attn_fn, ~pad)

@@ -203,16 +203,41 @@ class RaggedBatch:
     seq_active: np.ndarray    # [S] bool
     uids: List[int]           # slot -> uid (host only)
     # atom decomposition (reference atom_builder, ragged_ops/): fixed-size
-    # single-sequence q tiles for the ragged paged-attention kernel
+    # single-sequence q tiles of ``atom_q`` rows for the ragged
+    # paged-attention kernel. Only a chunk of TWO tokens or more is cut into
+    # atoms; a one-token chunk (a decode step in a mixed round, or a
+    # one-token prompt: the same mathematics) is no atom but a row of the
+    # per-slot vectors below, and attends through the kernel's one-row tile
     atom_qidx: Optional[np.ndarray] = None    # [A, BQ] packed-row gather idx
     atom_pos0: Optional[np.ndarray] = None    # [A] first q position
     atom_qlen: Optional[np.ndarray] = None    # [A] valid rows (0 = dead atom)
     atom_tables: Optional[np.ndarray] = None  # [A, Bps] owning block-table row
     atom_inv: Optional[np.ndarray] = None     # [T] packed row -> a*BQ + off
+    #                   (padding and one-token rows: the reserved dead atom)
+    # the one-token chunks, by slot (their tables are ``block_tables``)
+    dec_row: Optional[np.ndarray] = None      # [S] packed row of the token
+    dec_len: Optional[np.ndarray] = None      # [S] sequence length WITH that
+    #                   token (0 = this slot has no one-token chunk)
 
     @property
     def current_tokens(self) -> int:
         return int((self.token_seq < len(self.seq_active)).sum())
+
+    @property
+    def live_atoms(self) -> int:
+        """Atoms with at least one row (0 for a batch built without atoms)."""
+        return 0 if self.atom_qlen is None else \
+            int(np.count_nonzero(self.atom_qlen))
+
+    @property
+    def tile_args(self) -> Tuple[np.ndarray, ...]:
+        """The atoms and the one-token rows in the order ``ragged_forward``
+        takes them after ``last_tok_idx``; empty for a batch built without
+        atoms (the attention impls that cost a row per token anyway)."""
+        if self.atom_qidx is None:
+            return ()
+        return (self.atom_qidx, self.atom_pos0, self.atom_qlen,
+                self.atom_tables, self.atom_inv, self.dec_row, self.dec_len)
 
 
 def build_ragged_batch(chunks: Sequence[Tuple[SequenceDescriptor, int]],
@@ -253,9 +278,11 @@ def build_ragged_batch(chunks: Sequence[Tuple[SequenceDescriptor, int]],
 
     atoms = {}
     if atom_q:
-        # atoms: ≤atom_q-row single-sequence q tiles (reference atom_builder).
-        # Worst case sum(ceil(n_i/BQ)) ≤ S + T//BQ; slot A_max-1 is reserved
-        # DEAD (qlen 0) so padded packed rows gather a guaranteed-zero output
+        # atoms: ≤atom_q-row single-sequence q tiles (reference atom_builder)
+        # of the chunks of two tokens or more. Worst case sum(ceil(n_i/BQ))
+        # ≤ S + T//BQ; slot A_max-1 is reserved DEAD (qlen 0) so padded
+        # packed rows, and the one-token chunks' (dec_row / dec_len: a
+        # whole atom would hold one live row), gather a guaranteed zero
         BQ = atom_q
         A_max = S + T // BQ + 1
         atom_qidx = np.zeros((A_max, BQ), np.int32)
@@ -263,25 +290,29 @@ def build_ragged_batch(chunks: Sequence[Tuple[SequenceDescriptor, int]],
         atom_qlen = np.zeros((A_max,), np.int32)
         atom_tables = np.zeros((A_max, blocks_per_seq), np.int32)
         atom_inv = np.full((T,), (A_max - 1) * BQ, np.int32)
+        dec_row = np.zeros((S,), np.int32)
+        dec_len = np.zeros((S,), np.int32)
         a = 0
         cur = 0
         for slot, (desc, n) in enumerate(chunks):
             pos0 = desc.n_cached
-            k = 0
-            while k * BQ < n:
-                ql = min(BQ, n - k * BQ)
-                rows = cur + k * BQ + np.arange(ql)
-                atom_qidx[a, :ql] = rows
-                atom_pos0[a] = pos0 + k * BQ
-                atom_qlen[a] = ql
-                atom_tables[a] = block_tables[slot]
-                atom_inv[rows] = a * BQ + np.arange(ql)
-                a += 1
-                k += 1
+            if n == 1:
+                dec_row[slot] = cur
+                dec_len[slot] = pos0 + 1
+            else:
+                for k in range(0, n, BQ):
+                    ql = min(BQ, n - k)
+                    rows = cur + k + np.arange(ql)
+                    atom_qidx[a, :ql] = rows
+                    atom_pos0[a] = pos0 + k
+                    atom_qlen[a] = ql
+                    atom_tables[a] = block_tables[slot]
+                    atom_inv[rows] = a * BQ + np.arange(ql)
+                    a += 1
             cur += n
         assert a <= A_max - 1, "atom overflow — builder bug"
         atoms = dict(atom_qidx=atom_qidx, atom_pos0=atom_pos0,
                      atom_qlen=atom_qlen, atom_tables=atom_tables,
-                     atom_inv=atom_inv)
+                     atom_inv=atom_inv, dec_row=dec_row, dec_len=dec_len)
     return RaggedBatch(tokens, token_seq, token_pos, block_tables, last_tok,
                        active, uids, **atoms)

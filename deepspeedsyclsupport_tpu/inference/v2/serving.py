@@ -356,7 +356,10 @@ class ServingSession:
         # wall stamps: every record rides ONE clock base, so the offline
         # join can order router and replica streams together.
         self._tracing = bool(self.policy.trace_stages)
-        self.trace_log: deque = deque(maxlen=65536)
+        # a record a token, six a request, one a round: 65,536 held under a
+        # minute at 1,150 tokens/s, and a window whose oldest rounds were
+        # pushed out gives the round readers nothing (benchmark/spans.py)
+        self.trace_log: deque = deque(maxlen=262144)
         self.trace_dropped = 0     # records the full ring pushed out
         self._round_spans = RoundSpans(clock) if self._tracing else None
         self._spans: Optional[RoundSpans] = None   # the running round's

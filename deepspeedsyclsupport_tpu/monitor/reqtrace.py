@@ -97,14 +97,18 @@ ROUND_PHASES = (
 #: What a ``round`` record says of the forward it launched, counted before
 #: the launch: sequences, tokens, how many of those were prompt (a chunk is
 #: a decode step when it is one token on top of cached context), the cached
-#: tokens attention must read, and the blocks in the sequences' tables.
+#: tokens attention must read, and the blocks in the sequences' tables;
+#: then the tiles attention ran them in: ``decode_rows``, the one-token
+#: chunks (a one-row tile each; every row of a ``decode_forward``), and
+#: ``atoms``, the live ``atom_q_size``-row tiles the longer chunks of a
+#: ``ragged_forward`` were cut into (0 where the attention takes no atoms).
 #: ``moe_touched`` is the one field the DEVICE counts (a sparse-expert
 #: model's experts with at least one live row, summed over layers: the
 #: expert weights a forward had to read; 0 for a dense model). It comes back
 #: behind the sampled tokens, so a record carries the count of the forward
 #: whose logits its round SAMPLED: the launch of the record before it.
 FORWARD_FIELDS = ("n_seqs", "tokens", "prefill_tokens", "ctx_tokens",
-                  "kv_blocks", "moe_touched")
+                  "kv_blocks", "decode_rows", "atoms", "moe_touched")
 
 #: what a phase is where nothing times the round: ``trace_stages`` off, or
 #: an engine driven without a session

@@ -1,17 +1,29 @@
-"""Paged (blocked-KV) decode attention — Pallas TPU kernel.
+"""Paged (blocked-KV) attention, decode and ragged prefill — ONE Pallas TPU
+kernel body at two tile heights.
 
 The performance core of the v2 ragged engine: the reference's
 ``blocked_flash`` CUDA kernel family (``inference/v2/kernels/ragged_ops/
-blocked_flash``, atom-based flash attention over paged KV). One query token
-per sequence slot attends over its sequence's KV blocks, resolved through a
-block table.
+blocked_flash``, atom-based flash attention over paged KV). A tile of query
+rows of ONE sequence attends over that sequence's KV blocks, resolved
+through a block table. Which rows take which tile follows the chunk's
+length (``inference/v2/ragged.build_ragged_batch``):
+
+* a ONE-token chunk (every row of ``decode_forward``; in a mixed
+  ``ragged_forward`` the decoding sequences and a one-token prompt) is a
+  tile of one row: :func:`paged_decode_attention`, one grid program per
+  sequence slot, the custom call a profile names ``paged_decode``;
+* a chunk of two tokens or more is cut into ATOMS of ``atom_q_size`` (128)
+  rows: :func:`ragged_prefill_attention`, one grid program per atom,
+  ``ragged_prefill`` in a profile. A 128-row tile costs the MXU and the
+  mask/exp over ``[KVH, 128·G, block]`` whether one of its rows is live or
+  all, which is why a decode step is never given one.
 
 Kernel shape (TPU-first, not a CUDA translation):
 
-* grid = one program per sequence slot; the block table row and sequence
-  length ride in as SCALAR-PREFETCH args so KV block DMAs can be issued
-  immediately (``PrefetchScalarGridSpec`` — the Pallas idiom for indirect
-  addressing).
+* grid = one program per tile; the block table row, the tile's first
+  position and its live rows ride in as SCALAR-PREFETCH args so KV block
+  DMAs can be issued immediately (``PrefetchScalarGridSpec`` — the Pallas
+  idiom for indirect addressing).
 * K/V stay in HBM; each loop iteration DMAs ONE KV block into VMEM scratch
   and folds it into an online-softmax accumulator (flash recurrence), so VMEM
   holds O(block_size · D) regardless of context length, and compute overlaps
@@ -110,7 +122,9 @@ def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
                     *, block_size: int, max_blocks: int, group: int,
                     use_alibi: bool, window):
     """One program per ATOM: a ≤block_q-token slice of ONE sequence's packed
-    prefill chunk. The atom's q tile attends over the sequence's paged KV
+    prefill chunk — or, at ``BQ = 1`` (the decode entry), one sequence's
+    newest token; the serving forwards never put a one-token chunk into a
+    taller tile. The atom's q tile attends over the sequence's paged KV
     (resolved through its block-table row) with per-row causality — the
     'ragged paged attention' unification of prefill and decode (paper
     arXiv:2604.15464; reference atom_builder + blocked_flash,

@@ -61,6 +61,60 @@ class TestRaggedBatch:
         assert b.uids == [1, 2]
         assert b.current_tokens == 4
 
+    # (cached, scheduled) per chunk, in slot order; atoms of 8 rows
+    MIXES = {
+        "decoders_and_a_chunked_prompt": [(11, 1), (9, 1), (0, 20), (40, 1),
+                                          (3, 1)],
+        "a_one_token_prompt": [(0, 1), (16, 9), (7, 1)],
+        "prompts_only": [(0, 8), (5, 2), (0, 17)],
+        "one_token_chunks_only": [(4, 1), (0, 1), (30, 1)],
+    }
+
+    @pytest.mark.parametrize("mix", sorted(MIXES))
+    def test_one_token_chunks_take_no_atom(self, mix):
+        """A chunk of one token is a row of ``dec_row`` / ``dec_len`` and
+        of no live atom; only the longer chunks are cut into atoms, and
+        between the two every packed row is reachable exactly once."""
+        bq, t_max, s_max = 8, 48, 6
+        chunks = [(SequenceDescriptor(uid=i, pending=list(range(1, n + 1)),
+                                      n_cached=c, blocks=[i]), n)
+                  for i, (c, n) in enumerate(self.MIXES[mix])]
+        b = build_ragged_batch(chunks, t_max, s_max, 8, atom_q=bq)
+        dead = (b.atom_qidx.shape[0] - 1) * bq
+        live = np.flatnonzero(b.atom_qlen)
+        assert len(live) == b.live_atoms == sum(
+            -(-n // bq) for _d, n in chunks if n > 1)
+        reached, cur = [], 0
+        for slot, (d, n) in enumerate(chunks):
+            if n == 1:
+                assert (b.dec_row[slot], b.dec_len[slot]) == (
+                    cur, d.n_cached + 1)
+                assert b.atom_inv[cur] == dead     # as padding
+                reached.append(cur)
+            else:
+                assert b.dec_len[slot] == 0
+            cur += n
+        assert not b.dec_len[len(chunks):].any()
+        for a in live:
+            rows = b.atom_qidx[a, :b.atom_qlen[a]]
+            np.testing.assert_array_equal(       # the gather's inverse
+                b.atom_inv[rows], a * bq + np.arange(len(rows)))
+            seq = set(b.token_seq[rows])
+            assert len(seq) == 1 and chunks[seq.pop()][1] > 1
+            np.testing.assert_array_equal(
+                b.token_pos[rows], b.atom_pos0[a] + np.arange(len(rows)))
+            reached += list(rows)
+        assert sorted(reached) == list(range(b.current_tokens))
+        assert (b.atom_inv[b.current_tokens:] == dead).all()
+        assert b.atom_qlen[-1] == 0
+        assert len(b.tile_args) == 7
+
+    def test_a_batch_built_without_atoms_has_no_tile_args(self):
+        d = SequenceDescriptor(uid=1, pending=[5], n_cached=3, blocks=[0])
+        b = build_ragged_batch([(d, 1)], max_tokens=4, max_sequences=2,
+                               blocks_per_seq=2)
+        assert b.tile_args == () and b.dec_len is None and not b.live_atoms
+
     def test_budget_overflow_rejected(self):
         d = SequenceDescriptor(uid=1, pending=list(range(10)))
         with pytest.raises(ValueError):

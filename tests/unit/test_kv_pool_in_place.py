@@ -75,9 +75,20 @@ def _decode_case(decode_impl):
     return kv, {"logits": logits[:2]}, new, written
 
 
+def _ragged_forward(model, params, kv, b, impl):
+    """``ragged_forward`` over batch ``b``; the XLA path takes no tiles."""
+    return M.ragged_forward(
+        model, params, kv, *(jnp.asarray(a) for a in (
+            b.tokens, b.token_seq, b.token_pos, b.block_tables,
+            b.last_tok_idx)),
+        *(() if impl == "xla" else map(jnp.asarray, b.tile_args)),
+        block_size=BS, attn_impl=impl)
+
+
 def _ragged_case(prefill_impl):
     """Slot 0 continues a prompt with 6 tokens (crossing a block edge),
-    slot 1 decodes one, slot 2 starts a 9-token prompt (two atoms)."""
+    slot 1 decodes one (the one-row tile), slot 2 starts a 9-token prompt
+    (two atoms)."""
     model, params = _model()
     kv = _pool(model.config, NUM_BLOCKS * BS)
     rng = np.random.RandomState(3)
@@ -86,13 +97,7 @@ def _ragged_case(prefill_impl):
                                   blocks=list(TABLES[i])), n)
               for i, n in enumerate((6, 1, 9))]
     b = build_ragged_batch(chunks, T, S, BPS, atom_q=BQ)
-    atoms = () if prefill_impl == "xla" else tuple(
-        jnp.asarray(a) for a in (b.atom_qidx, b.atom_pos0, b.atom_qlen,
-                                 b.atom_tables, b.atom_inv))
-    logits, new = M.ragged_forward(
-        model, params, kv, *(jnp.asarray(a) for a in (
-            b.tokens, b.token_seq, b.token_pos, b.block_tables,
-            b.last_tok_idx)), *atoms, block_size=BS, attn_impl=prefill_impl)
+    logits, new = _ragged_forward(model, params, kv, b, prefill_impl)
     written = np.concatenate([_slots(0, range(11, 17)), _slots(1, [9]),
                               _slots(2, range(9))])
     return kv, {"logits": logits}, new, written
@@ -148,6 +153,78 @@ def test_forward_returns_what_it_returned_before(program, impl):
         assert after.shape == before.shape
         np.testing.assert_array_equal(np.asarray(after)[:, untouched],
                                       np.asarray(before)[:, untouched])
+
+
+# ------------------------------------------------- a mixed round's two tiles
+# six slots: three decode at different context lengths, one continues a
+# chunked prompt with 13 tokens (two atoms of 8), one is a one-token prompt,
+# one is idle. (cached, scheduled) by slot:
+MIXED = [(11, 1), (9, 1), (25, 1), (8, 13), (0, 1)]
+MIX_S, MIX_T = 6, 32
+MIX_TABLES = np.random.RandomState(5).permutation(MIX_S * BPS).reshape(
+    MIX_S, BPS).astype(np.int32)
+MIX_ARCHS = {"plain": {}, "alibi": {"pos_embed": "alibi"},
+             "window": {"sliding_window": 6}}
+
+
+def _mixed_batch():
+    rng = np.random.RandomState(9)
+    chunks = [(SequenceDescriptor(uid=i, pending=list(rng.randint(1, 500, n)),
+                                  n_cached=c, blocks=list(MIX_TABLES[i])), n)
+              for i, (c, n) in enumerate(MIXED)]
+    return build_ragged_batch(chunks, MIX_T, MIX_S, BPS, atom_q=BQ)
+
+
+@pytest.mark.parametrize("arch", sorted(MIX_ARCHS))
+def test_mixed_round_attends_row_for_row_as_the_xla_path(arch):
+    """The attention of one layer, per packed row: atoms for the prompt
+    chunk, the one-row tile for the four one-token chunks, against the XLA
+    path that gathers every token's context."""
+    model, _params = _model(**MIX_ARCHS[arch])
+    cfg = model.config
+    kv = _pool(cfg, (MIX_S * BPS + 1) * BS)
+    b = _mixed_batch()
+    q = jax.random.normal(jax.random.PRNGKey(2),
+                          (MIX_T, cfg.num_heads, cfg.head_dim), jnp.float32)
+    alibi, window = M._arch_bias(cfg)
+    ctx = M.PrefillAttnContext(
+        k_cache=kv.k, v_cache=kv.v, layer=jnp.int32(1),
+        token_seq=jnp.asarray(b.token_seq), token_pos=jnp.asarray(b.token_pos),
+        block_tables=jnp.asarray(b.block_tables), block_size=BS, alibi=alibi,
+        window=window, **{f: jnp.asarray(getattr(b, f)) for f in (
+            "atom_qidx", "atom_pos0", "atom_qlen", "atom_tables", "atom_inv",
+            "dec_row", "dec_len")})
+    got = np.asarray(M._prefill_kernel_interpret_impl(q, ctx))
+    want = np.asarray(M._prefill_xla_impl(q, ctx))
+    n = b.current_tokens
+    np.testing.assert_allclose(got[:n], want[:n], rtol=2e-5, atol=2e-5)
+    assert not got[n:].any()           # padding rows: the dead atom's zeros
+
+
+@pytest.mark.parametrize("arch", sorted(MIX_ARCHS))
+def test_mixed_round_logits_match_xla_and_decode_forward(arch):
+    """The whole forward: every slot's logits equal the XLA path's, and a
+    one-token chunk's equal what ``decode_forward`` gives for the same
+    pool — the same one-row tile over the same blocks."""
+    model, params = _model(**MIX_ARCHS[arch])
+    kv = _pool(model.config, (MIX_S * BPS + 1) * BS)
+    b = _mixed_batch()
+    got, new = _ragged_forward(model, params, kv, b, "kernel_interpret")
+    want, new_xla = _ragged_forward(model, params, kv, b, "xla")
+    live = len(MIXED)
+    np.testing.assert_allclose(np.asarray(got)[:live], np.asarray(want)[:live],
+                               rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(np.asarray(new.k), np.asarray(new_xla.k),
+                               rtol=2e-4, atol=2e-4)
+    one = np.asarray([n == 1 for _c, n in MIXED] + [False])
+    tokens = np.where(one, b.tokens[b.last_tok_idx], 0)
+    cached = np.asarray([c for c, _n in MIXED] + [0], np.int32)
+    dec, _ = M.decode_forward(
+        model, params, kv, jnp.asarray(tokens, jnp.int32), jnp.asarray(cached),
+        jnp.asarray(b.block_tables), jnp.asarray(one), block_size=BS,
+        attn_impl="pallas_interpret")
+    np.testing.assert_allclose(np.asarray(got)[one], np.asarray(dec)[one],
+                               rtol=2e-5, atol=2e-5)
 
 
 # ---------------------------------------------------------------- structure

@@ -218,6 +218,28 @@ class TestEngineKernelPath:
             assert g == seq[len(p):]
 
 
+    @pytest.mark.parametrize("newcomer", [[4, 100, 42, 8, 19, 77, 5, 3, 61],
+                                          [250]],
+                             ids=["two_atom_prompt", "one_token_prompt"])
+    def test_mixed_round_matches_dense(self, newcomer):
+        """Two sequences decode (one-row tiles) in the forward that takes a
+        newcomer's prompt (atoms, or a third one-row tile): every row's
+        logits are the dense model's over the whole sequence."""
+        model, params, eng = self._engine()
+        seqs = {1: [7, 3, 11], 2: [9, 200, 31, 5, 88, 2, 14, 6, 90, 120]}
+        eng.put([1, 2], [seqs[1], seqs[2]])
+        step = {1: [33], 2: [64], 3: newcomer}
+        forwards = eng._tick
+        out = eng.put([1, 2, 3], [step[1], step[2], step[3]])
+        assert eng._tick == forwards + 1       # ONE mixed forward
+        seqs[3] = []
+        for uid in (1, 2, 3):
+            seq = seqs[uid] + step[uid]
+            dense = model.apply(params, jnp.asarray([seq], jnp.int32))
+            np.testing.assert_allclose(out[uid], np.asarray(dense[0, -1]),
+                                       rtol=2e-4, atol=2e-4)
+
+
 @pytest.mark.parametrize("layer", POOL_LAYERS)
 @pytest.mark.parametrize("arch", ["alibi", "window", "alibi_window"])
 def test_ragged_prefill_alibi_window_parity(arch, layer):

@@ -149,6 +149,37 @@ def test_context_and_block_counters_equal_a_hand_count(tiny):
     sess.close()
 
 
+@pytest.mark.parametrize("program", ["decode_forward", "ragged_forward"])
+def test_the_record_counts_the_tiles_attention_ran(tiny, program):
+    """Through the kernel path (interpreted, atoms of 8 rows): two short
+    prompts, then a 10-token prompt while they decode, then all three
+    decode. A one-token chunk is a one-row tile in either program; only a
+    ``ragged_forward``'s longer chunks are atoms, and the tiles' rows hold
+    the tokens."""
+    bq = 8
+    eng = _engine(tiny, prefill_attn="kernel_interpret",
+                  decode_attn="pallas_interpret", atom_q_size=bq)
+    sess = ServingSession(eng, ServingPolicyConfig(admission="none"))
+    sess.submit(1, [1, 2, 3], 6)
+    sess.submit(2, [4, 5, 6, 7, 8], 6)
+    sess.step()                      # 3 + 5 prompt tokens: two atoms
+    sess.submit(3, list(range(10, 20)), 6)
+    sess.step()                      # two decode rows + 10 prompt tokens
+    sess.step()                      # three decode rows
+    prompts, mixed, decode = _rounds(sess.drain_trace())
+    if program == "ragged_forward":
+        assert [(d["program"], d["tokens"], d["decode_rows"], d["atoms"])
+                for d in (prompts, mixed)] == [
+            ("ragged_forward", 8, 0, 2), ("ragged_forward", 12, 2, 2)]
+    else:
+        assert (decode["program"], decode["tokens"], decode["decode_rows"],
+                decode["atoms"]) == ("decode_forward", 3, 3, 0)
+    for d in (prompts, mixed, decode):
+        assert d["atoms"] * bq + d["decode_rows"] >= d["tokens"]
+        assert d["decode_rows"] == d["tokens"] - d["prefill_tokens"]
+    sess.close()
+
+
 @pytest.mark.parametrize("polls", [1, 50])
 def test_a_round_that_begins_with_no_work_writes_no_record(tiny, monkeypatch,
                                                            polls):
@@ -339,7 +370,8 @@ def test_the_journal_carries_the_record_and_the_report_prints_its_phases(
     cover = [ln.split() for ln in out.stdout.splitlines()
              if ln.strip().startswith(("launched", "decode_forward"))]
     assert cover[0] == ["launched", "rounds", "seqs", "tokens", "prompt",
-                        "context", "kv", "blocks", "experts"]
+                        "context", "kv", "blocks", "1-row", "atoms",
+                        "experts"]
     assert int(cover[1][1]) == decode["rounds"]
     assert [float(x) for x in cover[1][2:]] == [
         pytest.approx(decode[f], abs=0.05) for f in reqtrace.FORWARD_FIELDS]

@@ -15,6 +15,7 @@ window, which interpret mode accepts but Mosaic rejects on every call (fixed
 to a whole-array SMEM ref indexed by head program id).
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -223,6 +224,50 @@ class TestPagedLowering:
         f = functools.partial(ragged_prefill_attention_pallas,
                               block_size=self.BS)
         lower_tpu(f, q, kc, kc, at, p0, ql)
+
+
+def _custom_calls(exp):
+    """(kernel name, operand and result types) of every Mosaic custom call
+    in an exported module's text."""
+    return [(re.search(r'kernel_name = "(\w+)"', ln).group(1),
+             ln.rsplit(" : ", 1)[1])
+            for ln in exp.mlir_module().splitlines()
+            if "@tpu_custom_call" in ln]
+
+
+@pytest.mark.parametrize("arch", ["plain", "alibi", "window"])
+def test_ragged_forward_holds_two_kernel_calls_a_layer(arch):
+    """The whole serving program, lowered for TPU with the ``kernel``
+    attention: the layer loop's body holds TWO custom calls, the atoms at
+    ``atom_q_size`` rows and the one-token chunks through the decode entry
+    (``paged_decode``), whose q tile is one row high."""
+    import dataclasses
+
+    from deepspeedsyclsupport_tpu.inference.v2 import model as M
+    from deepspeedsyclsupport_tpu.inference.v2.kv_cache import BlockedKV
+    from deepspeedsyclsupport_tpu.models import build_model, get_config
+
+    kw = {"plain": {}, "alibi": {"pos_embed": "alibi"},
+          "window": {"sliding_window": 96}}[arch]
+    cfg = dataclasses.replace(get_config("tiny"), num_layers=3, head_dim=128,
+                              num_heads=4, num_kv_heads=2, hidden_size=512,
+                              **kw)
+    model = build_model(cfg)
+    params = jax.eval_shape(model.init_params)
+    s, t, bs, bps, bq = 8, 256, 64, 4, 128
+    a = s + t // bq + 1
+    pool = sds((cfg.num_layers, 40 * bs, cfg.num_kv_heads, 128))
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    exp = export.export(M.build_ragged_forward_fn(model, bs, "kernel"),
+                        platforms=["tpu"])(
+        params, BlockedKV(pool, pool), i32(t), i32(t), i32(t), i32(s, bps),
+        i32(s), i32(a, bq), i32(a), i32(a), i32(a, bps), i32(t), i32(s),
+        i32(s))
+    calls = dict(_custom_calls(exp))
+    assert sorted(calls) == ["paged_decode", "ragged_prefill"], calls
+    q_tile = f"x{cfg.num_heads}x128x"
+    assert f"tensor<{a}x{bq}{q_tile}" in calls["ragged_prefill"]
+    assert f"tensor<{s}x1{q_tile}" in calls["paged_decode"]
 
 
 # -------------------------------------------------- long-context composites
