@@ -25,7 +25,7 @@ from .config import RaggedInferenceConfig
 from .kv_cache import init_blocked_kv
 from .model import build_ragged_forward_fn
 from .ragged import (BlockedAllocator, LogitsRef, SequenceDescriptor,
-                     build_ragged_batch)
+                     attention_work, build_ragged_batch)
 from .scheduler import schedule_chunks
 from ..params import place_inference_params
 from ..sampling import SamplingParams, sample_token_dyn, split_key
@@ -287,11 +287,15 @@ class InferenceEngineV2:
         ``atoms``: the live ``atom_q_size``-row tiles a ``ragged_forward``'s
         batch was cut into; its one-token chunks, like every row of a
         ``decode_forward``, are ``decode_rows``, a one-row tile each.
-        Its live tokens are counted whether or not a round is recorded."""
+        What those tiles cover (``attn_pairs``, ``dec_ctx_tokens``) is
+        ``ragged.attention_work``'s count. Its live tokens are counted
+        whether or not a round is recorded."""
         self._forward_tokens += sum(lengths)
         if self.round_spans is None:
             return
+        attn_pairs, dec_ctx_tokens = attention_work(descs, lengths)
         self.round_spans.fields.update(
+            attn_pairs=attn_pairs, dec_ctx_tokens=dec_ctx_tokens,
             n_seqs=len(descs), tokens=sum(lengths),
             decode_rows=sum(n == 1 for n in lengths), atoms=atoms,
             # the rule _run routes by: one token on top of cached context

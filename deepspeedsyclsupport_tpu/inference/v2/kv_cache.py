@@ -3,7 +3,10 @@
 Analog of ``BlockedKVCache`` (``inference/v2/ragged/kv_cache.py``): a pool of
 fixed-size KV blocks; sequences own arbitrary block lists, indirected through
 block tables. Layout [L, num_blocks * block_size, KVH, D] — flat slot axis so
-(de)referencing a slot is ``block_id * block_size + offset``. The serving
+(de)referencing a slot is ``block_id * block_size + offset``. A latent-
+attention model (``ModelConfig.kv_lora_rank``) caches ONE row a token and
+layer, [L, num_blocks * block_size, D]: the normed latent and the rotated
+key all heads share, and no V pool at all. The serving
 forwards carry the whole pool through their layer loop, scatter new rows
 into ``[layer, slot]`` in place and hand the kernels the pool and the layer
 (``model._pool_write`` / ``_scan_layers``): sliced by layer outside a
@@ -21,8 +24,9 @@ from .config import RaggedInferenceConfig
 
 class MoeCounters(NamedTuple):
     """What the serving forwards of a sparse-expert model count about
-    routing, on the device. ``load`` [L, E] int32: the (token, choice) rows
-    each layer's router gave each expert, summed over every forward since
+    routing, on the device. ``load`` [L_moe, E] int32 (the expert layers:
+    leading dense layers have no row): the (token, choice) rows each
+    layer's router gave each expert, summed over every forward since
     the engine was built (live rows only: ``load[l].sum() == k × live
     tokens``). ``touched`` scalar int32: of the LAST forward, the experts
     with at least one live row, summed over layers — the expert weights that
@@ -32,8 +36,8 @@ class MoeCounters(NamedTuple):
 
 
 class BlockedKV(NamedTuple):
-    k: jnp.ndarray  # [L, num_blocks*block_size, KVH, D]
-    v: jnp.ndarray
+    k: jnp.ndarray  # [L, num_blocks*block_size, KVH, D]; latent: [L, .., D]
+    v: Optional[jnp.ndarray]  # None for a latent pool: V is K's leading lanes
     # sparse-expert models only (None elsewhere: no leaf, the same program).
     # The counters ride with the pool because they live the pool's life: on
     # the device, through every forward's layer loop, donated and handed
@@ -43,6 +47,11 @@ class BlockedKV(NamedTuple):
     @property
     def num_slots(self) -> int:
         return self.k.shape[1]
+
+    @property
+    def pools(self):
+        """The pool arrays there are: (k, v), or (k,) for a latent pool."""
+        return (self.k,) if self.v is None else (self.k, self.v)
 
 
 def lane_padded_head_dim(head_dim: int, pad) -> int:
@@ -66,22 +75,25 @@ def init_blocked_kv(model_config, cfg: RaggedInferenceConfig,
     the engine's mesh: KV heads over ``model`` where they divide (the layout
     the TP-sharded wk/wv projections produce), replicated otherwise — never
     whole on the default device first."""
-    d = lane_padded_head_dim(model_config.head_dim,
-                             getattr(cfg, "head_dim_lane_pad", None))
+    pad = getattr(cfg, "head_dim_lane_pad", None)
+    latent = model_config.latent_kv_dim
     kvh = model_config.num_kv_heads
-    shape = (model_config.num_layers, cfg.num_blocks * cfg.block_size, kvh, d)
+    row = (lane_padded_head_dim(latent, pad),) if latent else (
+        kvh, lane_padded_head_dim(model_config.head_dim, pad))
+    shape = (model_config.num_layers, cfg.num_blocks * cfg.block_size, *row)
     tp = topology.axis_sizes["model"]
     sharding = (topology.sharding(None, None, "model", None)
-                if tp > 1 and kvh % tp == 0 else topology.replicated())
+                if tp > 1 and kvh % tp == 0 and not latent
+                else topology.replicated())
     zeros = jax.jit(lambda: jnp.zeros(shape, cfg.dtype),
                     out_shardings=sharding)
     moe = None
     if model_config.any_moe:
         moe = jax.jit(lambda: MoeCounters(
-            jnp.zeros((model_config.num_layers, model_config.num_experts),
+            jnp.zeros((model_config.num_moe_layers, model_config.num_experts),
                       jnp.int32), jnp.zeros((), jnp.int32)),
             out_shardings=topology.replicated())()
-    return BlockedKV(zeros(), zeros(), moe)
+    return BlockedKV(zeros(), None if latent else zeros(), moe)
 
 
 def kv_pool_stats(kv: BlockedKV, allocator) -> dict:
@@ -92,8 +104,8 @@ def kv_pool_stats(kv: BlockedKV, allocator) -> dict:
     ``logical_occupancy`` prices every block-table entry at full cost
     (sum of refcounts / total): the gap between the two is exactly the HBM
     the prefix cache's cross-request sharing is saving. ``pool_bytes``
-    counts BOTH k and v arrays at the (possibly lane-padded) allocated
-    head dim."""
+    counts every pool array there is (k and v; a latent pool has one) at
+    the (possibly lane-padded) allocated row width."""
     total = allocator.num_blocks
     free = allocator.free_blocks
     physical = total - free
@@ -101,32 +113,33 @@ def kv_pool_stats(kv: BlockedKV, allocator) -> dict:
     # physical, shared == 0 — the pre-sharing report
     logical = int(getattr(allocator, "logical_blocks", physical))
     shared = int(getattr(allocator, "shared_blocks", 0))
-    per_slot = int(np.prod(kv.k.shape[2:])) * kv.k.dtype.itemsize \
-        * kv.k.shape[0]
+    per_slot = sum(int(np.prod(pool.shape[2:])) * pool.dtype.itemsize
+                   * pool.shape[0] for pool in kv.pools)
     return {"blocks_total": total, "blocks_free": free,
             "blocks_physical": physical, "blocks_logical": logical,
             "blocks_shared": shared,
             "occupancy": 1.0 - free / total,
             "logical_occupancy": logical / total,
-            "pool_bytes": 2 * per_slot * kv.num_slots}
+            "pool_bytes": per_slot * kv.num_slots}
 
 
 def build_block_copy_fn(block_size: int):
-    """Jitted copy of one KV block (both k and v) to a fresh block — the
-    copy-on-write seam for the prefix cache. ``src``/``dst`` are traced
+    """Jitted copy of one KV block (k and, where the pool has one, v) to a
+    fresh block — the copy-on-write seam for the prefix cache. ``src``/``dst`` are traced
     int32 operands, so ONE compiled program serves every block pair; the
     pool is donated (the copy is an in-place update as far as the caller
     is concerned)."""
 
+    def _copy_pool(pool, src, dst):
+        rest = (0,) * (pool.ndim - 2)
+        block = jax.lax.dynamic_slice(
+            pool, (0, src * block_size, *rest),
+            (pool.shape[0], block_size, *pool.shape[2:]))
+        return jax.lax.dynamic_update_slice(
+            pool, block, (0, dst * block_size, *rest))
+
     def _copy(kv: BlockedKV, src, dst) -> BlockedKV:
-        L, _, H, D = kv.k.shape
-        sizes = (L, block_size, H, D)
-        ks = jax.lax.dynamic_slice(kv.k, (0, src * block_size, 0, 0), sizes)
-        vs = jax.lax.dynamic_slice(kv.v, (0, src * block_size, 0, 0), sizes)
-        return kv._replace(
-            k=jax.lax.dynamic_update_slice(kv.k, ks,
-                                           (0, dst * block_size, 0, 0)),
-            v=jax.lax.dynamic_update_slice(kv.v, vs,
-                                           (0, dst * block_size, 0, 0)))
+        return kv._replace(**{name: _copy_pool(pool, src, dst) for name, pool
+                              in zip(("k", "v"), kv.pools)})
 
     return jax.jit(_copy, donate_argnums=0)

@@ -25,6 +25,14 @@ positions, partial rotary, gated/standard MLP, parallel residual blocks,
 biases, sliding window) serves here exactly as in training — the analog of the
 reference's v2 model zoo (``inference/v2/model_implementations/{llama_v2,
 mistral,mixtral,opt,falcon,phi}.py``) as config axes instead of classes.
+
+Three things serve ONLY here (``models/transformer.py`` refuses to train
+them): latent attention (``cfg.kv_lora_rank``: :func:`_mla_rows` caches one
+``[c_kv | k_r]`` row a token and attends in absorbed form through the same
+kernels), hyper-connection streams (``cfg.hc_mult``: :func:`_hc_block`
+carries ``[n, T, d]`` and mixes it around each sublayer) and leading dense
+layers before the expert layers (``params["dense_layers"]``, a stack of its
+own, walked first by :func:`_scan_layers`).
 """
 from typing import Any, NamedTuple, Optional, Tuple
 
@@ -35,7 +43,8 @@ import numpy as np
 from .kv_cache import BlockedKV, MoeCounters
 from .module_registry import register_impl, select_impl
 from ...models.layers import (alibi_slopes, apply_rope, mlp_block, norm,
-                              qk_norm)
+                              qk_norm, rms_norm)
+from ...monitor.mfu import scope
 
 NEG_INF = jnp.finfo(jnp.float32).min
 
@@ -46,7 +55,9 @@ class PrefillAttnContext(NamedTuple):
     ConfigBundle role, ``modules/module_registry.py``). ``k_cache`` /
     ``v_cache`` are the WHOLE pool [L, num_slots, KVH, D] and ``layer`` the
     (traced) layer to read: the kernels index it in their DMAs, the other
-    impls slice ``pool[layer]`` at their seam (:func:`_layer_kv`)."""
+    impls slice ``pool[layer]`` at their seam (:func:`_layer_kv`). A latent
+    pool [L, num_slots, D] has ``v_cache`` None and ``v_dim``, the leading
+    lanes of its rows that are the value."""
     k_cache: Any
     v_cache: Any
     layer: Any
@@ -63,6 +74,7 @@ class PrefillAttnContext(NamedTuple):
     atom_inv: Any = None
     dec_row: Any = None
     dec_len: Any = None
+    v_dim: Optional[int] = None
 
 
 def _dequant(p, dtype):
@@ -77,7 +89,7 @@ def _mlp(p, y, cfg, live):
     top-k MoE via grouped GEMMs (the moe_scatter/cutlass-multi-GEMM/moe_gather
     analog, ``parallel/moe.moe_mlp_nodrop``) over the ``live`` rows [T].
     Returns (out, the rows each expert was given [E] — None when dense)."""
-    if cfg.any_moe:
+    if "moe" in p:    # by the tree: a leading dense layer of a sparse model
         from ...parallel.moe import moe_mlp_nodrop
 
         return moe_mlp_nodrop(p["moe"], y, cfg, live)
@@ -101,10 +113,66 @@ def _qkv(p, y, cfg, n):
 
 
 def _attn_out(p, attn, cfg, n):
+    if cfg.kv_lora_rank:
+        return _mla_out(p, attn, cfg, n)
     out = jnp.einsum("tq,qd->td", attn.reshape(n, cfg.q_dim), p["wo"])
     if cfg.attn_out_bias:
         out = out + p["bo"].astype(out.dtype)
     return out
+
+
+def _q_and_rows(p, y, cfg, positions):
+    """What attention takes of flat tokens y [n, D]: the positioned queries
+    [n, H, D_k] and the rows the pool caches of them, ``(k, v)`` [n, KVH, D]
+    each, or for latent attention the one ``[c_kv | k_r]`` row [n, D_k]."""
+    if cfg.kv_lora_rank:
+        return _mla_rows(p, y, cfg, positions)
+    q, k, v = _qkv(p, y, cfg, y.shape[0])
+    q, k = _positionize(cfg, q, k, positions)
+    return q, (k, v)
+
+
+def _mla_rows(p, y, cfg, positions):
+    """Latent attention (DeepSeek-V2's MLA) in ABSORBED form. The pool row
+    is ``[RMSNorm(c_kv) | rope(k_r)]``, ``kv_lora_rank + qk_rope_head_dim``
+    wide, one for all heads. Each head's query is laid against it:
+    ``q_nope W_UK^T`` (the up-projection of keys folded into the query)
+    beside its rotated part, so that ``q . row`` is the head's whole score.
+    The value is the row's leading ``kv_lora_rank`` lanes; :func:`_mla_out`
+    takes the attended latent up through ``W_UV``. The softmax scale
+    (``cfg.softmax_scale``, YaRN's mscale squared in it) rides in q: every
+    impl divides by sqrt of the row's width, so q carries that too."""
+    n, h = y.shape[0], cfg.num_heads
+    r, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    rot = lambda t: apply_rope(  # noqa: E731
+        t[None], positions[None], cfg.rope_theta,
+        scaling=cfg.rope_scaling)[0]
+    with scope("mla_proj"):
+        c_q = rms_norm(y @ p["w_qa"], p["q_norm"]["scale"], cfg.rms_norm_eps)
+        q = (c_q @ p["w_qb"]).reshape(n, h, -1)
+        ckv = y @ p["w_kva"]
+        row = jnp.concatenate([
+            rms_norm(ckv[:, :r], p["kv_norm"]["scale"], cfg.rms_norm_eps),
+            rot(ckv[:, None, r:])[:, 0]], axis=-1)
+        q_r = rot(q[..., nope:])
+    with scope("mla_absorb"):
+        w_uk = p["w_kvb"].reshape(r, h, -1)[..., :nope]
+        q_lat = jnp.einsum("thn,rhn->thr", q[..., :nope], w_uk,
+                           preferred_element_type=jnp.float32)
+        q = jnp.concatenate([q_lat, q_r.astype(jnp.float32)], axis=-1) \
+            * (cfg.softmax_scale * np.sqrt(cfg.latent_kv_dim))
+    return q.astype(y.dtype), (row,)
+
+
+def _mla_out(p, attn, cfg, n):
+    """attn [n, H, kv_lora_rank], the attended latents: up through each
+    head's ``W_UV`` to [n, H, v_head_dim], then the output projection."""
+    r, h = cfg.kv_lora_rank, cfg.num_heads
+    with scope("mla_absorb"):
+        w_uv = p["w_kvb"].reshape(r, h, -1)[..., cfg.qk_nope_head_dim:]
+        out = jnp.einsum("thr,rhv->thv", attn, w_uv)
+    with scope("mla_proj"):
+        return out.reshape(n, -1) @ p["wo"]
 
 
 def _lane_pad(x, d_pad: int, is_q: bool = False):
@@ -147,7 +215,17 @@ def _embed(params, tokens, positions, cfg):
     x = x.astype(jnp.dtype(cfg.dtype))
     if cfg.embed_norm:
         x = norm(x, params["embed_norm"], cfg)
+    if cfg.hc_mult > 1:    # every residual stream starts as the embedding
+        with scope("mhc"):
+            x = jnp.broadcast_to(x, (cfg.hc_mult, *x.shape))
     return x
+
+
+def _final_norm(params, x, cfg):
+    if cfg.hc_mult > 1:    # the streams close by their sum
+        with scope("mhc"):
+            x = x.astype(jnp.float32).sum(0).astype(x.dtype)
+    return norm(x, params["final_norm"], cfg)
 
 
 def _unembed(params, x, cfg):
@@ -165,6 +243,8 @@ def _block(cfg, p, x, attn_fn, live):
     """One transformer block over flat tokens, covering sequential and
     parallel (GPT-J/NeoX/Falcon/Phi) residual forms. ``live`` [T]: the rows
     that are tokens, not padding. Returns (x, :func:`_mlp`'s expert rows)."""
+    if cfg.hc_mult > 1:
+        return _hc_block(cfg, p, x, attn_fn, live)
     x_norm = norm(x, p["attn_norm"], cfg)
     attn = attn_fn(x_norm)
     h = _attn_out(p["attn"], attn, cfg, x.shape[0])
@@ -175,6 +255,59 @@ def _block(cfg, p, x, attn_fn, live):
     x = (x + h).astype(x.dtype)
     m, rows = _mlp(p, norm(x, p["mlp_norm"], cfg), cfg, live)
     return (x + m).astype(x.dtype), rows
+
+
+def _hc_maps(hc, x, cfg):
+    """One sublayer's hyper-connection maps from the streams x [n, T, d]
+    (mHC, arXiv:2512.24880): ``pre`` [n, T] and ``post`` [n, T], how the
+    sublayer reads the streams and writes them, and ``res`` [n, n, T], how
+    the streams mix: ``exp`` of the clamped map, then ``hc_sinkhorn_iters``
+    rounds of columns, then rows, each divided by its sum + ``hc_eps``, so
+    doubly stochastic to rounding. All from ONE RMSNorm over the token's
+    ``n * d`` values and one [n*d, n + n + n*n] product, in float32; tokens
+    ride the minor axis (a [T, 4, 4] would be tiled up to [T, 8, 128])."""
+    n, t, d = x.shape
+    xf = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(xf), axis=(0, 2), keepdims=True)
+    xn = xf * jax.lax.rsqrt(var + cfg.rms_norm_eps) \
+        * hc["norm"]["scale"].astype(jnp.float32).reshape(n, 1, d)
+    a, b = hc["a"].astype(jnp.float32), hc["b"].astype(jnp.float32)
+    maps = jnp.einsum("ntd,ndk->kt", xn,
+                      hc["phi"].astype(jnp.float32).reshape(n, d, -1),
+                      precision=jax.lax.Precision.HIGHEST)
+    pre = jax.nn.sigmoid(a[0] * maps[:n] + b[:n, None])
+    post = 2.0 * jax.nn.sigmoid(a[1] * maps[n:2 * n] + b[n:2 * n, None])
+    res = jnp.exp(jnp.clip(a[2] * maps[2 * n:] + b[2 * n:, None],
+                           cfg.mhc_h_res_clamp_min, cfg.mhc_h_res_clamp_max)
+                  ).reshape(n, n, t)                  # [row i, column j, T]
+    for _ in range(cfg.hc_sinkhorn_iters):
+        res = res / (res.sum(0, keepdims=True) + cfg.hc_eps)
+        res = res / (res.sum(1, keepdims=True) + cfg.hc_eps)
+    return pre, post, res
+
+
+def _hc_block(cfg, p, x, attn_fn, live):
+    """:func:`_block` over ``hc_mult`` residual streams x [n, T, d]: each
+    sublayer F (attention, then the MLP, each with maps of its own) reads
+    ``u = pre . x``, and the streams become ``res x + post^T F(norm(u))``.
+    Per token: nothing of it is cached."""
+    def sublayer(x, hc, f):
+        with scope("mhc"):
+            pre, post, res = _hc_maps(hc, x, cfg)
+            xf = x.astype(jnp.float32)
+            u = (pre[:, :, None] * xf).sum(0).astype(x.dtype)
+        y, rows = f(u)
+        with scope("mhc"):
+            x = ((res[:, :, :, None] * xf[None]).sum(1)
+                 + post[:, :, None] * y.astype(jnp.float32)[None]
+                 ).astype(x.dtype)
+        return x, rows
+
+    t = x.shape[1]
+    x, _ = sublayer(x, p["hc_attn"], lambda u: (_attn_out(
+        p["attn"], attn_fn(norm(u, p["attn_norm"], cfg)), cfg, t), None))
+    return sublayer(x, p["hc_mlp"], lambda u: _mlp(
+        p, norm(u, p["mlp_norm"], cfg), cfg, live))
 
 
 def _paged_attention(q, k_cache, v_cache, token_seq, token_pos, block_tables,
@@ -263,8 +396,13 @@ def _packed_flash_attention(q, k_cache, v_cache, token_seq, token_pos,
 
 def _layer_kv(ctx):
     """One layer's [num_slots, KVH, D] K and V for the impls that gather
-    from it in XLA (CPU and parity tests; no cell runs them)."""
-    return ctx.k_cache[ctx.layer], ctx.v_cache[ctx.layer]
+    from it in XLA (CPU and parity tests; no cell runs them); of a latent
+    pool, its rows as the one KV head and their leading lanes as V."""
+    k = ctx.k_cache[ctx.layer]
+    if ctx.v_cache is None:
+        k = k[:, None]
+        return k, k[..., :ctx.v_dim]
+    return k, ctx.v_cache[ctx.layer]
 
 
 # ------------------------------------------ registered prefill-attn impls
@@ -294,7 +432,7 @@ def _prefill_kernel_impl(q, ctx: PrefillAttnContext, interpret=False):
 
     impl = "pallas_interpret" if interpret else "pallas"
     kw = dict(block_size=ctx.block_size, layer=ctx.layer, alibi=ctx.alibi,
-              window=ctx.window, impl=impl)
+              window=ctx.window, v_dim=ctx.v_dim, impl=impl)
     q_at = q[ctx.atom_qidx]                          # [A, BQ, H, D]
     out_at = ragged_prefill_attention(
         q_at, ctx.k_cache, ctx.v_cache, ctx.atom_tables, ctx.atom_pos0,
@@ -341,7 +479,7 @@ def _decode_dispatch(impl_name):
         return paged_decode_attention(
             q, ctx.k_cache, ctx.v_cache, ctx.block_tables, ctx.seq_lens,
             block_size=ctx.block_size, impl=impl_name, layer=ctx.layer,
-            alibi=ctx.alibi, window=ctx.window)
+            alibi=ctx.alibi, window=ctx.window, v_dim=ctx.v_dim)
     return fn
 
 
@@ -355,6 +493,7 @@ class DecodeAttnContext(NamedTuple):
     block_size: int
     alibi: Any
     window: Optional[int]
+    v_dim: Optional[int] = None
 
 
 register_impl("decode_attn", "pallas", priority=10,
@@ -366,35 +505,49 @@ register_impl("decode_attn", "pallas_interpret", priority=-10,
 register_impl("decode_attn", "xla", priority=0)(_decode_dispatch("xla"))
 
 
-def _pool_write(k_pool, v_pool, layer, dest, k, v):
-    """Scatter the new tokens' K and V rows [n, KVH, d] into layer ``layer``
-    of the pool at flat slots ``dest`` [n] (out-of-range = dropped). A
-    scatter on the WHOLE loop-carried pool: XLA updates it in place, where
-    a per-layer slice as the scan's xs/ys cost a slice, a copy and a
-    write-back of the layer (157 MB at phi-2's pool) for 32 rows."""
-    d_pool = k_pool.shape[-1]
+def _pool_write(pools, layer, dest, rows):
+    """Scatter the new tokens' rows (K and V [n, KVH, d] each; a latent
+    pool's one [n, d]) into layer ``layer`` of ``pools`` at flat slots
+    ``dest`` [n] (out-of-range = dropped). A scatter on the WHOLE
+    loop-carried pool: XLA updates it in place, where a per-layer slice as
+    the scan's xs/ys cost a slice, a copy and a write-back of the layer
+    (157 MB at phi-2's pool) for 32 rows."""
     with jax.named_scope("kv_pool_write"):
         return tuple(
-            pool.at[layer, dest].set(_lane_pad(rows, d_pool).astype(pool.dtype),
-                                     mode="drop")
-            for pool, rows in ((k_pool, k), (v_pool, v)))
+            pool.at[layer, dest].set(
+                _lane_pad(new, pool.shape[-1]).astype(pool.dtype), mode="drop")
+            for pool, new in zip(pools, rows))
 
 
-def _scan_layers(layer, x, kv: BlockedKV, layer_params):
+def _attn_views(cfg, pools):
+    """``(k_cache, v_cache, v_dim, width of the output to keep)`` of the
+    loop-carried ``pools`` for the attention contexts."""
+    if cfg.kv_lora_rank:
+        return pools[0], None, cfg.kv_lora_rank, cfg.kv_lora_rank
+    return pools[0], pools[1], None, cfg.head_dim
+
+
+def _scan_layers(layer, x, kv: BlockedKV, params):
     """The layer loop of both serving forwards: the pool rides as CARRY
     beside ``x`` (never as the scan's xs/ys, which would slice it by layer
     and stack a second pool), the stacked params and the layer index as xs.
+    A model with leading dense layers (``params["dense_layers"]``) walks
+    that stack first, then the expert layers, one pool index through both.
     ``layer`` returns ``(carry, expert rows [E] or None)``: a sparse-expert
-    model's rows stack to [L, E] and fold into ``kv.moe``. Returns
+    model's rows stack to [L_moe, E] and fold into ``kv.moe``. Returns
     ``(x, the new BlockedKV)``."""
-    num_layers = kv.k.shape[0]
-    (x, k_pool, v_pool), rows = jax.lax.scan(
-        layer, (x, kv.k, kv.v), (layer_params, jnp.arange(num_layers)))
+    carry, first = (x, kv.pools), 0
+    if "dense_layers" in params:
+        dense = params["dense_layers"]
+        first = jax.tree_util.tree_leaves(dense)[0].shape[0]
+        carry, _ = jax.lax.scan(layer, carry, (dense, jnp.arange(first)))
+    (x, pools), rows = jax.lax.scan(
+        layer, carry, (params["layers"], jnp.arange(first, kv.k.shape[0])))
     moe = kv.moe
     if rows is not None:
         moe = MoeCounters(moe.load + rows,
                           jnp.sum(rows > 0, dtype=jnp.int32))
-    return x, BlockedKV(k_pool, v_pool, moe)
+    return x, kv._replace(moe=moe, **dict(zip(("k", "v"), pools)))
 
 
 def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
@@ -427,7 +580,7 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
     x = _embed(params, tokens, token_pos, cfg)
 
     def layer(carry, inp):
-        x, k_pool, v_pool = carry
+        x, pools = carry
         p, l = inp
         p = _dequant(p, x.dtype)
 
@@ -440,26 +593,28 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
         })
 
         def attn_fn(y):
-            nonlocal k_pool, v_pool
-            q, k, v = _qkv(p["attn"], y, cfg, t)
-            q, k = _positionize(cfg, q, k, token_pos)
-            k_pool, v_pool = _pool_write(k_pool, v_pool, l, dest, k, v)
-            q = _lane_pad(q, k_pool.shape[-1], is_q=True)
+            nonlocal pools
+            q, new = _q_and_rows(p["attn"], y, cfg, token_pos)
+            pools = _pool_write(pools, l, dest, new)
+            q = _lane_pad(q, pools[0].shape[-1], is_q=True)
+            k_cache, v_cache, v_dim, keep = _attn_views(cfg, pools)
             ctx = PrefillAttnContext(
-                k_cache=k_pool, v_cache=v_pool, layer=l, token_seq=token_seq,
+                k_cache=k_cache, v_cache=v_cache, layer=l,
+                token_seq=token_seq,
                 token_pos=token_pos, block_tables=block_tables,
                 block_size=bs, alibi=ab, window=window,
                 atom_qidx=atom_qidx, atom_pos0=atom_pos0,
                 atom_qlen=atom_qlen, atom_tables=atom_tables,
-                atom_inv=atom_inv, dec_row=dec_row, dec_len=dec_len)
-            return spec.fn(q, ctx)[..., :cfg.head_dim]
+                atom_inv=atom_inv, dec_row=dec_row, dec_len=dec_len,
+                v_dim=v_dim)
+            return spec.fn(q, ctx)[..., :keep]
 
         x, rows = _block(cfg, p, x, attn_fn, ~pad)
-        return (x, k_pool, v_pool), rows
+        return (x, pools), rows
 
-    x, kv = _scan_layers(layer, x, kv, params["layers"])
+    x, kv = _scan_layers(layer, x, kv, params)
 
-    x = norm(x, params["final_norm"], cfg)
+    x = _final_norm(params, x, cfg)
     h_last = x[last_tok_idx]  # [S, d] — logits_gather
     logits = _unembed(params, h_last, cfg)
     return logits.astype(jnp.float32), kv
@@ -510,7 +665,7 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
     x = _embed(params, tokens, positions, cfg)
 
     def layer(carry, inp):
-        x, k_pool, v_pool = carry
+        x, pools = carry
         p, l = inp
         p = _dequant(p, x.dtype)
 
@@ -518,21 +673,21 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
                            {"backend": jax.default_backend()})
 
         def attn_fn(y):
-            nonlocal k_pool, v_pool
-            q, k, v = _qkv(p["attn"], y, cfg, s)
-            q, k = _positionize(cfg, q, k, positions)
-            k_pool, v_pool = _pool_write(k_pool, v_pool, l, dest, k, v)
-            q = _lane_pad(q, k_pool.shape[-1], is_q=True)
+            nonlocal pools
+            q, new = _q_and_rows(p["attn"], y, cfg, positions)
+            pools = _pool_write(pools, l, dest, new)
+            q = _lane_pad(q, pools[0].shape[-1], is_q=True)
+            k_cache, v_cache, v_dim, keep = _attn_views(cfg, pools)
             return spec.fn(q, DecodeAttnContext(
-                k_cache=k_pool, v_cache=v_pool, layer=l,
+                k_cache=k_cache, v_cache=v_cache, layer=l,
                 block_tables=block_tables, seq_lens=seq_lens, block_size=bs,
-                alibi=ab, window=window))[..., :cfg.head_dim]
+                alibi=ab, window=window, v_dim=v_dim))[..., :keep]
 
         x, rows = _block(cfg, p, x, attn_fn, active)
-        return (x, k_pool, v_pool), rows
+        return (x, pools), rows
 
-    x, kv = _scan_layers(layer, x, kv, params["layers"])
-    x = norm(x, params["final_norm"], cfg)
+    x, kv = _scan_layers(layer, x, kv, params)
+    x = _final_norm(params, x, cfg)
     logits = _unembed(params, x, cfg)
     return logits.astype(jnp.float32), kv
 

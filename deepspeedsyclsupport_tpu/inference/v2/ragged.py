@@ -240,6 +240,21 @@ class RaggedBatch:
                 self.atom_tables, self.atom_inv, self.dec_row, self.dec_len)
 
 
+def attention_work(descs: Sequence[SequenceDescriptor],
+                   lengths: Sequence[int]) -> Tuple[int, int]:
+    """What the attention kernels of one forward over these chunks must
+    cover, counted on the host from the chunks alone: ``attn_pairs``, the
+    (row, cached token) pairs of the chunks of two tokens or more (the
+    atoms' kernel; row r of a chunk that starts at position p0 attends
+    p0 + r + 1 tokens, itself among them), and ``dec_ctx_tokens``, the
+    context lengths of the one-token chunks, their own token included (the
+    one-row tile's kernel reads that many cached rows a layer)."""
+    pairs = sum(n * d.n_cached + n * (n + 1) // 2
+                for d, n in zip(descs, lengths) if n > 1)
+    ctx = sum(d.n_cached + 1 for d, n in zip(descs, lengths) if n == 1)
+    return pairs, ctx
+
+
 def build_ragged_batch(chunks: Sequence[Tuple[SequenceDescriptor, int]],
                        max_tokens: int, max_sequences: int,
                        blocks_per_seq: int,

@@ -7,8 +7,9 @@ mixtral, opt, falcon, phi). Here the framework owns the model definition outrigh
 one config dataclass covers the dense Llama/GPT family and the Mixtral-style MoE
 family; per-family presets live in :data:`PRESETS`.
 """
+import math
 from dataclasses import dataclass, field, replace
-from typing import Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 
 @dataclass
@@ -69,6 +70,37 @@ class ModelConfig:
     # (OLMoE, HF modeling_olmoe OlmoeAttention.q_norm/k_norm)
     qk_norm: bool = False
 
+    # The xing4_0 / DeepSeek-V3 family, under the names its config.json
+    # publishes. Latent attention (MLA): kv_lora_rank > 0 turns it on; the
+    # serving pool then caches ONE row a token and layer, the normed latent
+    # and the rotated shared key (``latent_kv_dim``), and no V.
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # {"type": "yarn", "factor", "original_max_position_embeddings",
+    # "beta_fast", "beta_slow", "mscale", "mscale_all_dim"} or None
+    rope_scaling: Optional[Dict[str, Any]] = None
+    # layers < first_k_dense_replace keep a dense MLP of intermediate_size;
+    # the rest route over experts of moe_intermediate_size (None: experts
+    # are intermediate_size wide, as mixtral's and OLMoE's)
+    first_k_dense_replace: int = 0
+    moe_intermediate_size: Optional[int] = None
+    n_shared_experts: int = 0       # always-on experts beside the routed
+    scoring_func: str = "softmax"   # softmax | sigmoid router scores
+    # "noaux_tc": a learned bias joins the scores for the top-k CHOICE only
+    topk_method: str = "greedy"
+    routed_scaling_factor: float = 1.0
+    # manifold-constrained hyper-connections: hc_mult residual streams,
+    # mixed by a Sinkhorn-normalised matrix in every sublayer (1 = the
+    # plain x + f(norm(x)) stream)
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    mhc_h_res_clamp_min: float = -30.0
+    mhc_h_res_clamp_max: float = 30.0
+
     # Training-time behavior
     remat: bool = False             # jax.checkpoint each layer (activation ckpt)
     remat_policy: Optional[str] = None  # jax.checkpoint_policies name
@@ -109,6 +141,19 @@ class ModelConfig:
             raise ValueError(f"unknown mlp_type {self.mlp_type!r}")
         if self.shared_block_norm and not self.parallel_block:
             raise ValueError("shared_block_norm requires parallel_block")
+        if self.scoring_func not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown scoring_func {self.scoring_func!r}")
+        if self.topk_method not in ("greedy", "noaux_tc"):
+            raise ValueError(f"unknown topk_method {self.topk_method!r}")
+        if self.rope_scaling and self.rope_scaling.get("type") != "yarn":
+            raise ValueError(f"unknown rope_scaling {self.rope_scaling!r}")
+        if self.first_k_dense_replace and (
+                not self.any_moe or self.moe_layer_freq != 1
+                or self.first_k_dense_replace >= self.num_layers
+                or self.attn_windows is not None):
+            raise ValueError(
+                "first_k_dense_replace needs experts in every later layer, "
+                "stacked (scan_layers)")
         if self.attn_windows is not None:
             self.attn_windows = tuple(self.attn_windows)
             if len(self.attn_windows) != self.num_layers:
@@ -134,8 +179,37 @@ class ModelConfig:
     def kv_dim(self) -> int:
         return self.num_kv_heads * self.head_dim
 
+    @property
+    def latent_kv_dim(self) -> int:
+        """Width of the ONE row latent attention caches a token and layer:
+        the normed latent and the rotated key every head shares (0: K and V
+        per head)."""
+        return self.kv_lora_rank + self.qk_rope_head_dim \
+            if self.kv_lora_rank else 0
+
+    @property
+    def num_moe_layers(self) -> int:
+        return self.num_layers - self.first_k_dense_replace \
+            if self.any_moe else 0
+
+    @property
+    def softmax_scale(self) -> Optional[float]:
+        """Latent attention's logit scale: 1/sqrt(qk_nope + qk_rope) times
+        YaRN's mscale(factor, mscale_all_dim) squared (DeepSeek-V2's
+        ``softmax_scale * mscale * mscale``)."""
+        if not self.kv_lora_rank:
+            return None
+        scale = (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        rs = self.rope_scaling
+        if rs and rs.get("mscale_all_dim") and rs["factor"] > 1:
+            m = 0.1 * rs["mscale_all_dim"] * math.log(rs["factor"]) + 1.0
+            scale *= m * m
+        return scale
+
     def is_moe_layer(self, layer_idx: int) -> bool:
-        return self.num_experts > 0 and (layer_idx % self.moe_layer_freq == 0)
+        return self.num_experts > 0 and (
+            layer_idx % self.moe_layer_freq == 0) and (
+            layer_idx >= self.first_k_dense_replace)
 
     @property
     def any_moe(self) -> bool:
@@ -145,13 +219,25 @@ class ModelConfig:
         """Approximate parameter count (embeddings + layers)."""
         d, f, v = self.hidden_size, self.intermediate_size, self.vocab_size
         attn = d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
-        mlp = (3 if self.mlp_type == "glu" else 2) * d * f
-        if self.num_experts > 0:
-            mlp = mlp * self.num_experts + d * self.num_experts
+        if self.kv_lora_rank:
+            h, qk = self.num_heads, self.qk_nope_head_dim
+            attn = (d * self.q_lora_rank
+                    + self.q_lora_rank * h * (qk + self.qk_rope_head_dim)
+                    + d * self.latent_kv_dim
+                    + self.kv_lora_rank * h * (qk + self.v_head_dim)
+                    + h * self.v_head_dim * d)
+        mats = 3 if self.mlp_type == "glu" else 2
+        dense = mats * d * f
+        fe = self.moe_intermediate_size or f
+        moe = (mats * d * fe * (self.num_experts + self.n_shared_experts)
+               + d * self.num_experts)
         if self.qk_norm:
             attn += self.q_dim + self.kv_dim
-        per_layer = attn + mlp + 2 * d
-        total = per_layer * self.num_layers + v * d + d
+        n_moe = self.num_moe_layers
+        hc = 2 * (self.hc_mult * d * (2 + self.hc_mult) * self.hc_mult
+                  + self.hc_mult * d) if self.hc_mult > 1 else 0
+        total = ((attn + 2 * d + hc) * self.num_layers + moe * n_moe
+                 + dense * (self.num_layers - n_moe) + v * d + d)
         if not self.tie_embeddings:
             total += d * v
         return total
@@ -241,6 +327,26 @@ PRESETS = {
                       num_layers=16, num_heads=16, num_kv_heads=16,
                       max_seq_len=4096, num_experts=64, num_experts_per_tok=8,
                       norm_topk_prob=False, qk_norm=True),
+    # XingChen-AGI/Xing4.0-29B-A4B (model_type xing4_0): latent attention,
+    # four residual streams (mHC), two leading dense layers, then 64 routed
+    # experts top-4 by sigmoid scores + one shared expert. head_dim is the
+    # q/k head (128 un-rotated + 64 rotated). The multi-token-prediction
+    # block (num_nextn_predict_layers 1) is not part of the trunk's forward
+    # and has no field here. Serving only (inference/v2).
+    "xing4-29b-a4b": _p(
+        vocab_size=131072, hidden_size=3584, intermediate_size=9216,
+        num_layers=40, num_heads=32, num_kv_heads=32, head_dim=192,
+        max_seq_len=262144, rms_norm_eps=1e-6, rope_theta=10000.0,
+        kv_lora_rank=512, q_lora_rank=768, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128,
+        rope_scaling={"type": "yarn", "factor": 64, "beta_fast": 32,
+                      "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+                      "original_max_position_embeddings": 4096},
+        num_experts=64, num_experts_per_tok=4, moe_intermediate_size=1024,
+        first_k_dense_replace=2, n_shared_experts=1, scoring_func="sigmoid",
+        topk_method="noaux_tc", norm_topk_prob=True,
+        routed_scaling_factor=2.0, hc_mult=4, hc_sinkhorn_iters=20,
+        hc_eps=1e-6, mhc_h_res_clamp_min=-30.0, mhc_h_res_clamp_max=30.0),
 }
 
 

@@ -101,21 +101,46 @@ def qk_norm(p: Params, q: jnp.ndarray, k: jnp.ndarray,
 
 
 # --------------------------------------------------------------------------- rope
-def rope_frequencies(head_dim: int, theta: float) -> np.ndarray:
-    return 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float32) / head_dim))
+def rope_frequencies(head_dim: int, theta: float,
+                     scaling: Optional[Dict[str, Any]] = None) -> np.ndarray:
+    """Rotary frequencies [head_dim / 2]. ``scaling`` (``cfg.rope_scaling``,
+    type ``yarn``; Peng et al., arXiv:2309.00071, as DeepSeek-V2 computes
+    it): each frequency is a blend of itself and itself / ``factor`` by a
+    linear ramp between the two correction dimensions, the pair indices
+    that turn ``beta_fast`` and ``beta_slow`` times over the original
+    context: fast pairs keep their frequency, slow ones are interpolated."""
+    freqs = 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float32)
+                             / head_dim))
+    if not scaling:
+        return freqs
+    orig = scaling["original_max_position_embeddings"]
+
+    def correction_dim(turns):
+        return head_dim * np.log(orig / (turns * 2 * np.pi)) \
+            / (2 * np.log(theta))
+
+    low = max(int(np.floor(correction_dim(scaling["beta_fast"]))), 0)
+    high = min(int(np.ceil(correction_dim(scaling["beta_slow"]))),
+               head_dim - 1)
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float32) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    return (freqs / scaling["factor"] * ramp
+            + freqs * (1.0 - ramp)).astype(np.float32)
 
 
 def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
-               rotary_dim: Optional[int] = None) -> jnp.ndarray:
+               rotary_dim: Optional[int] = None,
+               scaling: Optional[Dict[str, Any]] = None) -> jnp.ndarray:
     """Rotary embedding (reference kernel: ``csrc/transformer/inference/csrc/
     apply_rotary_pos_emb.cu``). x: [B, S, H, D]; positions: [B, S] or [S].
     ``rotary_dim < D`` rotates only the leading dims (GPT-NeoX/GPT-J/Phi
     partial rotary; ingestion converts interleaved layouts to this split-half
-    convention by permuting q/k weight columns)."""
+    convention by permuting q/k weight columns). ``scaling``:
+    :func:`rope_frequencies`' YaRN blend."""
     head_dim = x.shape[-1]
     rd = head_dim if rotary_dim is None else rotary_dim
     x_rot, x_pass = (x, None) if rd == head_dim else (x[..., :rd], x[..., rd:])
-    freqs = jnp.asarray(rope_frequencies(rd, theta))
+    freqs = jnp.asarray(rope_frequencies(rd, theta, scaling))
     if positions.ndim == 1:
         positions = positions[None, :]
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, rd/2]

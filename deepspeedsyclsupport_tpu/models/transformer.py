@@ -54,22 +54,49 @@ class CausalLM:
         def dense(shape, key, scale=std):
             return (jax.random.normal(key, shape, jnp.float32) * scale)
 
+        def down_scale(fan_in):
+            """Std of a projection back into the stream: GPT-2's depth
+            scaling, or, where hyper-connection maps gate every write
+            themselves (``H_post``), by fan-in, so that a sublayer writes at
+            about the embedding's size."""
+            if cfg.hc_mult > 1:
+                return std / np.sqrt(fan_in)
+            return std / np.sqrt(2 * cfg.num_layers)
+
         def norm_params() -> Params:
             p = {"scale": jnp.ones((cfg.hidden_size,), jnp.float32)}
             if cfg.norm_type == "layernorm":
                 p["bias"] = jnp.zeros((cfg.hidden_size,), jnp.float32)
             return p
 
-        def layer_params(key) -> Params:
-            ks = iter(jax.random.split(key, 16))
-            d, q, kv, f = (cfg.hidden_size, cfg.q_dim, cfg.kv_dim,
-                           cfg.intermediate_size)
+        def attn_params(ks) -> Params:
+            d, q, kv = cfg.hidden_size, cfg.q_dim, cfg.kv_dim
+            if cfg.kv_lora_rank:
+                # latent attention: q and kv go down to a normed latent and
+                # up again; w_kva also gives the one rotated key all heads
+                # share, w_kvb each head's un-rotated key and its value
+                h, r = cfg.num_heads, cfg.kv_lora_rank
+                return {
+                    "w_qa": dense((d, cfg.q_lora_rank), next(ks)),
+                    "q_norm": {"scale": jnp.ones((cfg.q_lora_rank,),
+                                                 jnp.float32)},
+                    "w_qb": dense((cfg.q_lora_rank, h * (
+                        cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)),
+                        next(ks)),
+                    "w_kva": dense((d, cfg.latent_kv_dim), next(ks)),
+                    "kv_norm": {"scale": jnp.ones((r,), jnp.float32)},
+                    "w_kvb": dense((r, h * (cfg.qk_nope_head_dim
+                                            + cfg.v_head_dim)), next(ks)),
+                    # by ONE head's fan-in: what it projects is a mean of
+                    # values over the tokens attended, small already
+                    "wo": dense((h * cfg.v_head_dim, d), next(ks),
+                                scale=down_scale(cfg.v_head_dim)),
+                }
             attn: Params = {
                 "wq": dense((d, q), next(ks)),
                 "wk": dense((d, kv), next(ks)),
                 "wv": dense((d, kv), next(ks)),
-                "wo": dense((q, d), next(ks),
-                            scale=std / np.sqrt(2 * cfg.num_layers)),
+                "wo": dense((q, d), next(ks), scale=down_scale(q)),
             }
             if cfg.qkv_bias:
                 attn.update(bq=jnp.zeros((q,), jnp.float32),
@@ -80,18 +107,58 @@ class CausalLM:
             if cfg.qk_norm:
                 attn.update(q_norm={"scale": jnp.ones((q,), jnp.float32)},
                             k_norm={"scale": jnp.ones((kv,), jnp.float32)})
-            p: Params = {"attn_norm": norm_params(), "attn": attn}
+            return attn
+
+        def glu_params(ks, f) -> Params:
+            d = cfg.hidden_size
+            return {"w_gate": dense((d, f), next(ks)),
+                    "w_up": dense((d, f), next(ks)),
+                    "w_down": dense((f, d), next(ks), scale=down_scale(f))}
+
+        def hc_params(key) -> Params:
+            """One sublayer's hyper-connection maps: the norm over all
+            ``n * d`` stream values, ``phi`` [n*d, n + n + n*n] (columns
+            pre | post | res), the three gains ``a`` and the biases ``b``:
+            unit noise for pre and post (``H_post`` about 1), and for res
+            twice the identity plus half-unit noise, so that the Sinkhorn
+            matrix leans to the identity and is neither it nor uniform."""
+            n, (k1, k2) = cfg.hc_mult, jax.random.split(key)
+            noise = jax.random.normal(k2, (2 * n + n * n,), jnp.float32)
+            return {"norm": {"scale": jnp.ones((n * cfg.hidden_size,),
+                                               jnp.float32)},
+                    "phi": dense((n * cfg.hidden_size, 2 * n + n * n), k1),
+                    "a": jnp.asarray([0.5, 0.1, 0.5], jnp.float32),
+                    "b": jnp.concatenate([
+                        noise[:2 * n],
+                        2.0 * jnp.eye(n).reshape(-1) + 0.5 * noise[2 * n:]])}
+
+        def layer_params(key, moe=cfg.any_moe) -> Params:
+            ks = iter(jax.random.split(key, 16))
+            d, f = cfg.hidden_size, cfg.intermediate_size
+            p: Params = {"attn_norm": norm_params(), "attn": attn_params(ks)}
             if not cfg.shared_block_norm:
                 p["mlp_norm"] = norm_params()
-            if cfg.any_moe:
+            if cfg.hc_mult > 1:
+                p["hc_attn"] = hc_params(next(ks))
+                p["hc_mlp"] = hc_params(next(ks))
+            if moe:
                 e = cfg.num_experts
+                fe = cfg.moe_intermediate_size or f
                 p["moe"] = {
                     "router": dense((d, e), next(ks)),
-                    "w_gate": dense((e, d, f), next(ks)),
-                    "w_up": dense((e, d, f), next(ks)),
-                    "w_down": dense((e, f, d), next(ks),
-                                    scale=std / np.sqrt(2 * cfg.num_layers)),
+                    "w_gate": dense((e, d, fe), next(ks)),
+                    "w_up": dense((e, d, fe), next(ks)),
+                    # beside a shared expert the routed ones share the
+                    # write: each is drawn at 1 / E of the shared one's size
+                    "w_down": dense((e, fe, d), next(ks), scale=down_scale(
+                        fe) / (e if cfg.n_shared_experts else 1)),
                 }
+                if cfg.topk_method == "noaux_tc":
+                    # the selection-only bias
+                    p["moe"]["router_bias"] = dense((e,), next(ks))
+                if cfg.n_shared_experts:
+                    p["moe"]["shared"] = glu_params(
+                        ks, fe * cfg.n_shared_experts)
             elif cfg.mlp_type == "mlp":
                 p["mlp"] = {
                     "fc1": dense((d, f), next(ks)),
@@ -102,17 +169,15 @@ class CausalLM:
                     p["mlp"].update(b1=jnp.zeros((f,), jnp.float32),
                                     b2=jnp.zeros((d,), jnp.float32))
             else:
-                p["mlp"] = {
-                    "w_gate": dense((d, f), next(ks)),
-                    "w_up": dense((d, f), next(ks)),
-                    "w_down": dense((f, d), next(ks),
-                                    scale=std / np.sqrt(2 * cfg.num_layers)),
-                }
+                p["mlp"] = glu_params(ks, f)
             return p
 
+        n_dense = cfg.first_k_dense_replace
         if cfg.scan_layers:
             lkeys = jax.random.split(next(keys), cfg.num_layers)
-            layers = jax.vmap(layer_params)(lkeys)  # stacked leaves [L, ...]
+            # stacked leaves [L, ...]; the leading dense layers of a
+            # first_k_dense_replace model are a stack of their own
+            layers = jax.vmap(layer_params)(lkeys[n_dense:])
         else:
             layers = [layer_params(k)
                       for k in jax.random.split(next(keys), cfg.num_layers)]
@@ -122,6 +187,9 @@ class CausalLM:
             "layers": layers,
             "final_norm": norm_params(),
         }
+        if n_dense:
+            params["dense_layers"] = jax.vmap(
+                lambda k: layer_params(k, moe=False))(lkeys[:n_dense])
         if cfg.pos_embed == "learned":
             # OPT-style tables carry pos_embed_offset extra rows and are
             # indexed at position + offset (HF OPTLearnedPositionalEmbedding)
@@ -193,6 +261,12 @@ class CausalLM:
                  ) -> Tuple[jnp.ndarray, Optional[KVCache], jnp.ndarray]:
         """Returns (logits [B,S,V] fp32, new_cache, total_aux_loss)."""
         cfg = self.config
+        if cfg.kv_lora_rank or cfg.hc_mult > 1 or cfg.first_k_dense_replace:
+            raise NotImplementedError(
+                "latent attention, hyper-connection streams and leading "
+                "dense layers run on the serving path only "
+                "(inference/v2/model.py): their training forward and "
+                "backward are not written")
         b, s = input_ids.shape
         if positions is None:
             base = cache.write_pos if cache is not None else 0
@@ -499,7 +573,8 @@ class CausalLM:
         layer dim, which must never shard (scan iterates it)."""
         names = [getattr(k, "key", getattr(k, "name", str(k))) for k in path]
         s = "/".join(str(n) for n in names)
-        stacked = "layers" in names and self.config.scan_layers
+        stacked = self.config.scan_layers and (
+            "layers" in names or "dense_layers" in names)
         if stacked:
             # under pipeline parallelism the stacked layer dim shards over
             # ``pipe`` (each stage owns its contiguous layer block — the

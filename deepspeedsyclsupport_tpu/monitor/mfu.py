@@ -48,7 +48,13 @@ REGIONS = SCOPE_REGIONS + DERIVED_REGIONS
 #: piece of a layer: the compiled program's ``op_name`` metadata carries the
 #: label, the device trace only instruction names, so the reader maps label
 #: to names from the program's own text (benchmark/metrics/moe_share_pct.py).
-SUB_SCOPES = ("moe_route", "moe_experts", "moe_combine")
+SUB_SCOPES = ("moe_route", "moe_experts", "moe_combine", "moe_shared",
+              # latent attention (inference/v2/model.py): the down/up
+              # projections with their norms, rotary and the pool write;
+              # the absorption of W_UK into q and of W_UV out of the output
+              "mla_proj", "mla_absorb",
+              # hyper-connection streams: norm, maps, Sinkhorn, the mixes
+              "mhc")
 
 #: named_scope label prefix — ``mfu.attn`` etc. Kept short and distinctive
 #: so the metadata regex can't false-positive on user scopes.

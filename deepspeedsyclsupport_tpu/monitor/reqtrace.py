@@ -101,14 +101,18 @@ ROUND_PHASES = (
 #: then the tiles attention ran them in: ``decode_rows``, the one-token
 #: chunks (a one-row tile each; every row of a ``decode_forward``), and
 #: ``atoms``, the live ``atom_q_size``-row tiles the longer chunks of a
-#: ``ragged_forward`` were cut into (0 where the attention takes no atoms).
+#: ``ragged_forward`` were cut into (0 where the attention takes no atoms);
+#: and what those tiles cover (``ragged.attention_work``): ``attn_pairs``,
+#: the (row, cached token) pairs of the chunks of two tokens or more, and
+#: ``dec_ctx_tokens``, the one-token chunks' context lengths.
 #: ``moe_touched`` is the one field the DEVICE counts (a sparse-expert
 #: model's experts with at least one live row, summed over layers: the
 #: expert weights a forward had to read; 0 for a dense model). It comes back
 #: behind the sampled tokens, so a record carries the count of the forward
 #: whose logits its round SAMPLED: the launch of the record before it.
 FORWARD_FIELDS = ("n_seqs", "tokens", "prefill_tokens", "ctx_tokens",
-                  "kv_blocks", "decode_rows", "atoms", "moe_touched")
+                  "kv_blocks", "decode_rows", "atoms", "attn_pairs",
+                  "dec_ctx_tokens", "moe_touched")
 
 #: what a phase is where nothing times the round: ``trace_stages`` off, or
 #: an engine driven without a session

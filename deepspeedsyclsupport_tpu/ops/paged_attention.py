@@ -32,6 +32,16 @@ Kernel shape (TPU-first, not a CUDA translation):
   group — grouped heads share the streamed KV block, the reason GQA decode is
   bandwidth-cheap on TPU.
 
+Latent (MLA) pools: ``v_cache=None, v_dim=n`` means the pool holds ONE row
+a token, ``[L, num_slots, D]`` with no head axis (an axis of one would be
+tiled up to two in HBM), and the value is its leading ``n`` lanes (the
+normed latent; the row's tail is the rotated key all heads share). The same
+body then streams one tile a block instead of two, slices V from the K tile
+already in VMEM, returns ``[..., H, n]``, and feeds the MXU the pool's own
+dtype: under 32 query heads the absorbed form is ~70 kFLOP a (row, cached
+token), compute-bound, where float32 operands cost several passes. Decided
+at trace time: a K-and-V pool's program is what it was.
+
 An exact jnp reference (:func:`paged_decode_attention_reference`) serves
 off-TPU fallback and the kernel-vs-reference parity tests (the pattern the
 reference repo uses for every CUDA kernel, SURVEY.md §4).
@@ -55,7 +65,7 @@ _VMEM_CAP = 100 << 20
 # --------------------------------------------------------------------- kernel
 def paged_decode_attention_pallas(q, k_cache, v_cache, block_tables, seq_lens,
                                   *, block_size: int, layer=0,
-                                  alibi=None, window=None,
+                                  alibi=None, window=None, v_dim=None,
                                   interpret: bool = False):
     """q: [S, H, D]; k/v_cache: [num_slots, KVH, D], or the whole pool
     [L, num_slots, KVH, D] with ``layer`` naming the one to read;
@@ -73,21 +83,22 @@ def paged_decode_attention_pallas(q, k_cache, v_cache, block_tables, seq_lens,
     out = ragged_prefill_attention_pallas(
         q[:, None], k_cache, v_cache, block_tables, pos0, qlen,
         block_size=block_size, layer=layer, alibi=alibi, window=window,
-        interpret=interpret, name="paged_decode")
+        v_dim=v_dim, interpret=interpret, name="paged_decode")
     return out[:, 0]
 
 
 # ------------------------------------------------------------------ reference
 def paged_decode_attention_reference(q, k_cache, v_cache, block_tables,
                                      seq_lens, *, block_size: int, layer=0,
-                                     alibi=None, window=None):
+                                     alibi=None, window=None, v_dim=None):
     """Exact jnp oracle — decode as the BQ=1 case of the ragged reference
     (one oracle to maintain, mirroring the Pallas unification)."""
     seq_lens = jnp.asarray(seq_lens, jnp.int32)
     out = ragged_prefill_attention_reference(
         q[:, None], k_cache, v_cache, block_tables,
         jnp.maximum(seq_lens - 1, 0), (seq_lens > 0).astype(jnp.int32),
-        block_size=block_size, layer=layer, alibi=alibi, window=window)
+        block_size=block_size, layer=layer, alibi=alibi, window=window,
+        v_dim=v_dim)
     return out[:, 0]
 
 
@@ -102,10 +113,11 @@ def _resolve_impl(impl: str, what: str) -> str:
 
 def paged_decode_attention(q, k_cache, v_cache, block_tables, seq_lens, *,
                            block_size: int, impl: str = "auto", layer=0,
-                           alibi=None, window=None):
+                           alibi=None, window=None, v_dim=None):
     """Dispatch (the op-binding seam, like ``models/layers.attention``)."""
     impl = _resolve_impl(impl, "paged decode")
-    kw = dict(block_size=block_size, layer=layer, alibi=alibi, window=window)
+    kw = dict(block_size=block_size, layer=layer, alibi=alibi, window=window,
+              v_dim=v_dim)
     if impl == "xla":
         return paged_decode_attention_reference(
             q, k_cache, v_cache, block_tables, seq_lens, **kw)
@@ -116,11 +128,9 @@ def paged_decode_attention(q, k_cache, v_cache, block_tables, seq_lens, *,
 
 # ===================================================================== prefill
 def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
-                    q_ref, k_hbm, v_hbm, ab_ref,           # tensors
-                    out_ref,                               # output
-                    k_vmem, v_vmem, sem,                   # scratch
-                    *, block_size: int, max_blocks: int, group: int,
-                    use_alibi: bool, window):
+                    q_ref, k_hbm, *refs,
+                    block_size: int, max_blocks: int, group: int,
+                    use_alibi: bool, window, v_dim=None):
     """One program per ATOM: a ≤block_q-token slice of ONE sequence's packed
     prefill chunk — or, at ``BQ = 1`` (the decode entry), one sequence's
     newest token; the serving forwards never put a one-token chunk into a
@@ -130,7 +140,19 @@ def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
     arXiv:2604.15464; reference atom_builder + blocked_flash,
     ``inference/v2/kernels/ragged_ops/``). KV blocks stream through the same
     double-buffered DMA pipeline as the decode kernel, so per-sequence KV is
-    NEVER materialized in HBM (the O(S·max_ctx) gather this replaces)."""
+    NEVER materialized in HBM (the O(S·max_ctx) gather this replaces).
+
+    ``refs`` after K: V in HBM, the alibi slopes, the output, the K and V
+    scratch and the DMA semaphores; a latent pool (``v_dim``: V is the
+    leading lanes of the K tile) has neither V nor its scratch. A second
+    grid axis, where the wrapper made one, tiles the heads of the ONE kv
+    head: the body sees its tile's heads only and needs no index of it."""
+    if v_dim is None:
+        v_hbm, ab_ref, out_ref, k_vmem, v_vmem, sem = refs
+        mxu = jnp.float32
+    else:
+        ab_ref, out_ref, k_vmem, sem = refs
+        mxu = k_vmem.dtype
     a = pl.program_id(0)
     pos0 = pos0_ref[a]
     qlen = qlen_ref[a]
@@ -147,26 +169,24 @@ def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
         lo_blk = jnp.int32(0)
     q = q_ref[0].astype(jnp.float32)          # [BQ, H, D]
     bq, h, d = q.shape
-    kvh = k_vmem.shape[2]
+    d_v = d if v_dim is None else v_dim
+    kvh = 1 if v_dim is not None else k_vmem.shape[2]
     g = group
     # [KVH, BQ·G, D]: kv head-major so each kv head batch-matmuls its group
     q_g = jnp.transpose(q.reshape(bq, kvh, g, d), (1, 0, 2, 3)) \
-        .reshape(kvh, bq * g, d)
+        .reshape(kvh, bq * g, d).astype(mxu)
     # q row of each [BQ·G] lane (its position is pos0 + row)
     row = jax.lax.broadcasted_iota(jnp.int32, (kvh, bq * g, block_size),
                                    1) // g
 
     def copies(j, slot):
         blk = block_tables_ref[a, j]
-        cp_k = pltpu.make_async_copy(
-            k_hbm.at[layer, pl.ds(blk * block_size, block_size)],
-            k_vmem.at[slot],
-            sem.at[slot, 0])
-        cp_v = pltpu.make_async_copy(
-            v_hbm.at[layer, pl.ds(blk * block_size, block_size)],
-            v_vmem.at[slot],
-            sem.at[slot, 1])
-        return cp_k, cp_v
+        pools = ((k_hbm, k_vmem),) if v_dim is not None \
+            else ((k_hbm, k_vmem), (v_hbm, v_vmem))
+        return [pltpu.make_async_copy(
+            hbm.at[layer, pl.ds(blk * block_size, block_size)],
+            vmem.at[slot], sem.at[slot, i])
+            for i, (hbm, vmem) in enumerate(pools)]
 
     # guard on lo_blk (not just kv_hi > 0): with a sliding window and pos0
     # beyond the table's capacity, lo_blk can reach max_blocks — the loop
@@ -174,9 +194,8 @@ def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
     # the table out of bounds and start a DMA that is never awaited
     @pl.when(lo_blk * block_size < kv_hi)
     def _():
-        cp_k, cp_v = copies(lo_blk, jax.lax.rem(lo_blk, 2))
-        cp_k.start()
-        cp_v.start()
+        for cp in copies(lo_blk, jax.lax.rem(lo_blk, 2)):
+            cp.start()
 
     def body(j, carry):
         m, l, acc = carry
@@ -186,20 +205,22 @@ def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
         @pl.when(jnp.logical_and((j + 1) * block_size < kv_hi,
                                  j + 1 < max_blocks))
         def _():
-            cp_k, cp_v = copies(j + 1, jax.lax.rem(j + 1, 2))
-            cp_k.start()
-            cp_v.start()
+            for cp in copies(j + 1, jax.lax.rem(j + 1, 2)):
+                cp.start()
 
         @pl.when(active)
         def _():
-            cp_k, cp_v = copies(j, cur)
-            cp_k.wait()
-            cp_v.wait()
+            for cp in copies(j, cur):
+                cp.wait()
 
-        k = k_vmem[cur].astype(jnp.float32)    # [bs, KVH, D]
-        v = v_vmem[cur].astype(jnp.float32)
-        k_t = jnp.transpose(k, (1, 0, 2))      # [KVH, bs, D]
-        v_t = jnp.transpose(v, (1, 0, 2))
+        if v_dim is not None:                  # rows [bs, D], one kv head
+            k_t = k_vmem[cur][None]
+            v_t = k_t[..., :d_v]
+        else:
+            k = k_vmem[cur].astype(jnp.float32)    # [bs, KVH, D]
+            v = v_vmem[cur].astype(jnp.float32)
+            k_t = jnp.transpose(k, (1, 0, 2))      # [KVH, bs, D]
+            v_t = jnp.transpose(v, (1, 0, 2))
         scores = jax.lax.dot_general(           # [KVH, BQ·G, bs]
             q_g, k_t, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) / np.sqrt(d)
@@ -219,7 +240,7 @@ def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
         p = jnp.where(valid, jnp.exp(scores - m_new), 0.0)
         l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
-            p, v_t, (((2,), (1,)), ((0,), (0,))),
+            p.astype(mxu), v_t, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
         acc_new = acc * alpha + pv
         return (jnp.where(active, m_new, m), jnp.where(active, l_new, l),
@@ -227,15 +248,50 @@ def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
 
     m0 = jnp.full((kvh, bq * g, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((kvh, bq * g, 1), jnp.float32)
-    acc0 = jnp.zeros((kvh, bq * g, d), jnp.float32)
+    acc0 = jnp.zeros((kvh, bq * g, d_v), jnp.float32)
     # DYNAMIC trip count: dead atoms (kv_hi = 0) run zero iterations — with
     # A_max sized for the worst case, most grid programs of a typical batch
     # are dead and must not burn max_blocks MXU loops each
     n_blk = (kv_hi + block_size - 1) // block_size
     m, l, acc = jax.lax.fori_loop(lo_blk, n_blk, body, (m0, l0, acc0))
     out = acc / jnp.maximum(l, 1e-30)
-    out = jnp.transpose(out.reshape(kvh, bq, g, d), (1, 0, 2, 3))
-    out_ref[0] = out.reshape(bq, h, d).astype(out_ref.dtype)
+    out = jnp.transpose(out.reshape(kvh, bq, g, d_v), (1, 0, 2, 3))
+    out_ref[0] = out.reshape(bq, h, d_v).astype(out_ref.dtype)
+
+
+# what one grid step may need by _ragged_vmem_need before the heads of a
+# single kv head are tiled over a second grid axis: twice it must stay
+# under _VMEM_CAP, and 32 heads x d 128 (21 MiB) must stay one tile
+_HEAD_TILE_BUDGET = 48 << 20
+
+
+def _head_tile(bq: int, h: int, kvh: int, d: int, block_size: int,
+               itemsize: int) -> int:
+    """Query heads one grid step takes, by the SHAPE: all of them, unless
+    they share ONE kv head (every tile then reads the same KV block and
+    needs nothing of the others) and their tile would pass the budget; then
+    the largest halving that fits and stays a multiple of 16 (a bf16 tile's
+    sublanes). A 128-row atom of 32 heads x 640 (absorbed latent attention)
+    models at 80 MiB and runs as two tiles of 16."""
+    ht = h
+    while (kvh == 1 and ht % 32 == 0 and _ragged_vmem_need(
+            bq, ht, kvh, d, block_size, itemsize) > _HEAD_TILE_BUDGET):
+        ht //= 2
+    return ht
+
+
+def _ragged_vmem_need(bq: int, h: int, kvh: int, d: int, block_size: int,
+                      itemsize: int) -> int:
+    """Bytes of VMEM one grid step of :func:`_prefill_kernel` needs, by the
+    shape model :func:`_ragged_vmem_limit` explains."""
+    q_tile = bq * h * d
+    kv_tile = block_size * kvh * d
+    scores = bq * h * block_size
+    return (4 * q_tile * itemsize        # q + out tiles, double-buffered
+            + 5 * q_tile * 4             # fp32 q, q_g, acc, acc_new, pv
+            + 4 * kv_tile * itemsize     # k/v scratch, two slots each
+            + 4 * kv_tile * 4            # fp32 k, v and their transposes
+            + 6 * scores * 4)            # scores, pos, valid, p, exp temps
 
 
 def _ragged_vmem_limit(bq: int, h: int, kvh: int, d: int, block_size: int,
@@ -247,15 +303,9 @@ def _ragged_vmem_limit(bq: int, h: int, kvh: int, d: int, block_size: int,
     against the v5e compiler) — over Mosaic's 16 MiB default, which refused
     the kernel at every real width. The shape model below came within
     0.8-1.06x of the bisected need across atoms 64-256 and KVH 4-32; the
-    limit is only a ceiling, so twice the model is stated."""
-    q_tile = bq * h * d
-    kv_tile = block_size * kvh * d
-    scores = bq * h * block_size
-    need = (4 * q_tile * itemsize        # q + out tiles, double-buffered
-            + 5 * q_tile * 4             # fp32 q, q_g, acc, acc_new, pv
-            + 4 * kv_tile * itemsize     # k/v scratch, two slots each
-            + 4 * kv_tile * 4            # fp32 k, v and their transposes
-            + 6 * scores * 4)            # scores, pos, valid, p, exp temps
+    limit is only a ceiling, so twice the model is stated. ``h`` is the
+    heads of ONE grid step (:func:`_head_tile`)."""
+    need = _ragged_vmem_need(bq, h, kvh, d, block_size, itemsize)
     if need > _VMEM_CAP:
         raise ValueError(
             f"ragged prefill atom of {bq} rows x {h} heads x d {d} needs "
@@ -267,7 +317,8 @@ def _ragged_vmem_limit(bq: int, h: int, kvh: int, d: int, block_size: int,
 def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
                                     atom_pos0, atom_qlen, *,
                                     block_size: int, layer=0, alibi=None,
-                                    window=None, interpret: bool = False,
+                                    window=None, v_dim=None,
+                                    interpret: bool = False,
                                     name: str = "ragged_prefill"):
     """q_atoms: [A, BQ, H, D] (one sequence per atom row block);
     k/v_cache: the whole pool [L, num_slots, KVH, D], read at ``layer``
@@ -278,73 +329,101 @@ def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
     atom_pos0/atom_qlen: [A]. ``alibi``: per-head slopes [H]; ``window``:
     sliding-window bound. ``name`` is what a profile calls the kernel: its
     custom call's instruction and scope (the decode entry passes its own).
-    Returns [A, BQ, H, D]."""
+    ``v_cache=None, v_dim=n``: a latent pool, V the leading ``n`` lanes of
+    K's rows (the module's docstring). Returns [A, BQ, H, D] ([.., n])."""
     a, bq, h, d = q_atoms.shape
-    if k_cache.ndim == 3:
-        k_cache, v_cache, layer = k_cache[None], v_cache[None], 0
-    kvh = k_cache.shape[2]
-    g = h // kvh
+    latent = v_cache is None
+    if latent and not v_dim:
+        raise ValueError("a pool without V needs v_dim, the lanes of K's "
+                         "rows that are the value")
+    pools = (k_cache,) if latent else (k_cache, v_cache)
+    if k_cache.ndim == (2 if latent else 3):     # one layer's cache
+        pools, layer = tuple(pool[None] for pool in pools), 0
+    kvh = 1 if latent else k_cache.shape[-2]
+    row = pools[0].shape[2:]                     # (KVH, D), latent: (D,)
+    d_out = v_dim if latent else d
+    itemsize = q_atoms.dtype.itemsize
+    # heads of one grid step; > 1 tiles only under a single kv head
+    ht = _head_tile(bq, h, kvh, d, block_size, itemsize)
+    tiles = h // ht
+    g = ht // kvh
     max_blocks = atom_tables.shape[1]
     if alibi is not None:
         # per-lane slope layout matches the kernel's [KVH, BQ·G] score rows:
-        # lane (r·G + gi) of kv head kh carries q head kh·G + gi
-        ab = jnp.tile(jnp.asarray(alibi, jnp.float32).reshape(kvh, 1, g),
-                      (1, bq, 1)).reshape(kvh, bq * g, 1)
+        # lane (r·G + gi) of kv head kh carries q head kh·G + gi (under one
+        # kv head, head tile j's rows follow tile j - 1's)
+        ab = jnp.tile(
+            jnp.asarray(alibi, jnp.float32).reshape(kvh * tiles, 1, g),
+            (1, bq, 1)).reshape(kvh * tiles, bq * g, 1)
     else:
-        ab = jnp.zeros((kvh, bq * g, 1), jnp.float32)
+        ab = jnp.zeros((kvh * tiles, bq * g, 1), jnp.float32)
+
+    def tile_of(grid_idx):      # (atom[, head tile]) of a grid step
+        return grid_idx[0], (grid_idx[1] if tiles > 1 else 0)
+
+    def qo_map(*idx):
+        i, j = tile_of(idx)
+        return i, 0, j, 0
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(a,),
+        grid=(a, tiles) if tiles > 1 else (a,),
         in_specs=[
-            pl.BlockSpec((1, bq, h, d), lambda i, *_: (i, 0, 0, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec(memory_space=pl.ANY),   # K stays in HBM
-            pl.BlockSpec(memory_space=pl.ANY),   # V stays in HBM
-            pl.BlockSpec((kvh, bq * g, 1), lambda i, *_: (0, 0, 0),
+            pl.BlockSpec((1, bq, ht, d), qo_map, memory_space=pltpu.VMEM),
+            # K (and V) stay in HBM
+            *(pl.BlockSpec(memory_space=pl.ANY) for _ in pools),
+            pl.BlockSpec((kvh, bq * g, 1),
+                         lambda *idx: (tile_of(idx)[1], 0, 0),
                          memory_space=pltpu.VMEM),  # slopes per lane
         ],
-        out_specs=pl.BlockSpec((1, bq, h, d), lambda i, *_: (i, 0, 0, 0),
+        out_specs=pl.BlockSpec((1, bq, ht, d_out), qo_map,
                                memory_space=pltpu.VMEM),
         scratch_shapes=[
-            pltpu.VMEM((2, block_size, kvh, d), k_cache.dtype),
-            pltpu.VMEM((2, block_size, kvh, d), v_cache.dtype),
-            pltpu.SemaphoreType.DMA((2, 2)),
+            *(pltpu.VMEM((2, block_size, *row), pool.dtype)
+              for pool in pools),
+            pltpu.SemaphoreType.DMA((2, len(pools))),
         ],
     )
     kernel = functools.partial(_prefill_kernel, block_size=block_size,
                                max_blocks=max_blocks, group=g,
                                use_alibi=alibi is not None,
-                               window=None if window is None else int(window))
+                               window=None if window is None else int(window),
+                               v_dim=v_dim if latent else None)
     return pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((a, bq, h, d), q_atoms.dtype),
+        out_shape=jax.ShapeDtypeStruct((a, bq, h, d_out), q_atoms.dtype),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_ragged_vmem_limit(
-                bq, h, kvh, d, block_size, q_atoms.dtype.itemsize)),
+                bq, ht, kvh, d, block_size, itemsize)),
         interpret=interpret,
         name=name,
     )(jnp.asarray(atom_tables, jnp.int32), jnp.asarray(atom_pos0, jnp.int32),
       jnp.asarray(atom_qlen, jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), q_atoms, k_cache, v_cache, ab)
+      jnp.asarray(layer, jnp.int32).reshape(1), q_atoms, *pools, ab)
 
 
 def ragged_prefill_attention_reference(q_atoms, k_cache, v_cache, atom_tables,
                                        atom_pos0, atom_qlen, *,
                                        block_size: int, layer=0, alibi=None,
-                                       window=None):
+                                       window=None, v_dim=None):
     """Exact jnp oracle for the prefill kernel (parity tests + off-TPU).
-    Given the whole pool it slices ``pool[layer]`` here, at its seam."""
+    Given the whole pool it slices ``pool[layer]`` here, at its seam; given
+    no V it takes the leading ``v_dim`` lanes of K's rows."""
     a, bq, h, d = q_atoms.shape
+    if v_cache is None:                # latent rows: the one kv head's
+        k_cache = k_cache[..., None, :]
     if k_cache.ndim == 4:
-        k_cache, v_cache = k_cache[layer], v_cache[layer]
+        k_cache = k_cache[layer]
+        v_cache = None if v_cache is None else v_cache[layer]
     kvh = k_cache.shape[1]
     bps = atom_tables.shape[1]
     max_ctx = bps * block_size
     j = jnp.arange(max_ctx)
     slot = atom_tables[:, j // block_size] * block_size + j % block_size
     k_seq = k_cache[slot].astype(jnp.float32)   # [A, C, KVH, D]
-    v_seq = v_cache[slot].astype(jnp.float32)
+    v_seq = k_seq[..., :v_dim] if v_cache is None \
+        else v_cache[slot].astype(jnp.float32)
     if kvh != h:
         rep = h // kvh
         k_seq = jnp.repeat(k_seq, rep, axis=2)
@@ -372,9 +451,10 @@ def ragged_prefill_attention_reference(q_atoms, k_cache, v_cache, atom_tables,
 def ragged_prefill_attention(q_atoms, k_cache, v_cache, atom_tables,
                              atom_pos0, atom_qlen, *, block_size: int,
                              impl: str = "auto", layer=0, alibi=None,
-                             window=None):
+                             window=None, v_dim=None):
     impl = _resolve_impl(impl, "ragged prefill")
-    kw = dict(block_size=block_size, layer=layer, alibi=alibi, window=window)
+    kw = dict(block_size=block_size, layer=layer, alibi=alibi, window=window,
+              v_dim=v_dim)
     if impl == "xla":
         return ragged_prefill_attention_reference(
             q_atoms, k_cache, v_cache, atom_tables, atom_pos0, atom_qlen,

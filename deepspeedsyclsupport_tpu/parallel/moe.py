@@ -22,19 +22,35 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.layers import constrain
+from ..models.layers import constrain, glu_mlp
 
 
-def topk_weights(probs: jnp.ndarray, k: int,
-                 normalise: bool) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def topk_weights(probs: jnp.ndarray, k: int, normalise: bool,
+                 select_bias: Optional[jnp.ndarray] = None,
+                 scale: float = 1.0) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The ONE top-k weighting of both MoE paths: the ``k`` largest router
-    probabilities of each token [T, k] and their experts [T, k]; divided by
-    their sum where the model says so (``cfg.norm_topk_prob``: mixtral does,
-    OLMoE combines with the raw softmax mass)."""
-    gate_w, expert_idx = jax.lax.top_k(probs, k)
+    scores of each token [T, k] and their experts [T, k]; divided by their
+    sum where the model says so (``cfg.norm_topk_prob``: mixtral does, OLMoE
+    combines with the raw softmax mass). ``select_bias`` [E] (``noaux_tc``)
+    joins the scores for the CHOICE only: the weights are the chosen
+    experts' unbiased scores. ``scale``: ``cfg.routed_scaling_factor``."""
+    if select_bias is None:
+        gate_w, expert_idx = jax.lax.top_k(probs, k)
+    else:
+        _, expert_idx = jax.lax.top_k(probs + select_bias, k)
+        gate_w = jnp.take_along_axis(probs, expert_idx, axis=-1)
     if normalise:
         gate_w = gate_w / jnp.maximum(gate_w.sum(-1, keepdims=True), 1e-9)
-    return gate_w, expert_idx
+    return gate_w if scale == 1.0 else gate_w * scale, expert_idx
+
+
+def router_scores(logits: jnp.ndarray, cfg) -> jnp.ndarray:
+    """Router scores [T, E] in float32: a softmax over the experts, or
+    (``scoring_func: sigmoid``) each expert's own sigmoid."""
+    logits = logits.astype(jnp.float32)
+    if cfg.scoring_func == "sigmoid":
+        return jax.nn.sigmoid(logits)
+    return jax.nn.softmax(logits, axis=-1)
 
 
 def topk_gating(logits: jnp.ndarray, k: int, capacity: int,
@@ -140,6 +156,9 @@ def moe_mlp_nodrop(p: Dict[str, Any], x: jnp.ndarray, cfg,
     budget, pads included. A row that is not live gets NO expert: it sorts
     behind the last group, outside ``group_sizes``, and its output is zero.
 
+    A model with shared experts (``p["shared"]``) adds their SwiGLU of every
+    row beside the routed sum.
+
     x: [T, D] flat tokens → (out [T, D], group_sizes [E] int32: the (token,
     choice) rows each expert was given, ``sum == k × live rows``).
     """
@@ -150,8 +169,11 @@ def moe_mlp_nodrop(p: Dict[str, Any], x: jnp.ndarray, cfg,
     with scope("moe_route"):
         logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
                             p["router"].astype(jnp.float32))
-        probs = jax.nn.softmax(logits, axis=-1)
-        gate_w, expert_idx = topk_weights(probs, k, cfg.norm_topk_prob)
+        bias = p.get("router_bias")
+        gate_w, expert_idx = topk_weights(
+            router_scores(logits, cfg), k, cfg.norm_topk_prob,
+            None if bias is None else bias.astype(jnp.float32),
+            cfg.routed_scaling_factor)
 
         flat_expert = expert_idx.reshape(t * k)
         if live is not None:
@@ -180,4 +202,7 @@ def moe_mlp_nodrop(p: Dict[str, Any], x: jnp.ndarray, cfg,
             # business: a pad row contributes an exact zero
             ys = jnp.where(live_rows[order][:, None], ys, 0)
         out = jnp.zeros((t, d), x.dtype).at[sorted_tok].add(ys)  # moe_gather
+    if "shared" in p:
+        with scope("moe_shared"):
+            out = out + glu_mlp(p["shared"], x[None], cfg)[0]
     return out, group_sizes

@@ -157,6 +157,41 @@ def test_ragged_prefill_compiles_with_default_atom(one_chip, name, form):
     _ragged_default_atom(one_chip, name, form)
 
 
+# the latent pool of xing4-docs-sat: [6, 6400 x 64, 640] bf16 rows, the value
+# their leading 512 lanes, ONE kv head under 32 query heads of 640 (absorbed)
+LATENT = dict(layers=6, slots=6400 * 64, row=640, v_dim=512, heads=32,
+              block_size=64, max_context=16384, max_sequences=16,
+              max_tokens=768, atom=128)
+
+
+@pytest.mark.parametrize("kernel", ["ragged_prefill", "paged_decode"])
+def test_latent_kernels_compile_at_the_cells_widths(one_chip, kernel):
+    """Refused while the pool had a head axis of one (tiled up to two in
+    HBM: "Slice shape along dimension 2 must be aligned to tiling (2)"); the
+    128-row atom of 32 x 640 runs as two head tiles of 16."""
+    g = LATENT
+    pool = ((g["layers"], g["slots"], g["row"]), jnp.bfloat16)
+    bps = g["max_context"] // g["block_size"]
+    if kernel == "ragged_prefill":
+        n = g["max_tokens"] // g["atom"] + g["max_sequences"] + 1
+        q = ((n, g["atom"], g["heads"], g["row"]), jnp.bfloat16)
+        extra = (((n,), jnp.int32), ((n,), jnp.int32))
+        f = ragged_prefill_attention_pallas
+    else:
+        n = g["max_sequences"]
+        q = ((n, g["heads"], g["row"]), jnp.bfloat16)
+        extra = (((n,), jnp.int32),)
+        f = paged_decode_attention_pallas
+    compiled = _compile(
+        lambda q, k, tables, *rest: f(
+            q, k, None, tables, *rest[:-1], block_size=g["block_size"],
+            layer=rest[-1], v_dim=g["v_dim"]),
+        one_chip, q, pool, ((n, bps), jnp.int32), *extra, ((), jnp.int32))
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1 and kernel in calls[0].split(" = ")[0]
+
+
 def test_paged_kernel_is_a_tpu_custom_call(one_chip):
     """The compiled text names the Mosaic kernel — the same string
     ``chip_smoke.py`` looks for in the programs it ran on the chip."""

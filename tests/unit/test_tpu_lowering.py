@@ -270,6 +270,62 @@ def test_ragged_forward_holds_two_kernel_calls_a_layer(arch):
     assert f"tensor<{s}x1{q_tile}" in calls["paged_decode"]
 
 
+def _lowered_ragged_forward(preset, pool_row, **overrides):
+    """``ragged_forward`` of a tiny ``preset`` through the ``kernel``
+    attention, lowered for TPU; ``pool_row``: a pool row's trailing dims."""
+    from deepspeedsyclsupport_tpu.inference.v2 import model as M
+    from deepspeedsyclsupport_tpu.inference.v2.kv_cache import (BlockedKV,
+                                                                MoeCounters)
+    from deepspeedsyclsupport_tpu.models import build_model
+
+    model = build_model(preset, **overrides)
+    cfg = model.config
+    params = jax.eval_shape(model.init_params)
+    s, t, bs, bps, bq = 8, 256, 64, 4, 128
+    a = s + t // bq + 1
+    pool = sds((cfg.num_layers, 40 * bs, *pool_row))
+    i32 = lambda *shape: sds(shape, jnp.int32)  # noqa: E731
+    moe = MoeCounters(i32(cfg.num_moe_layers, cfg.num_experts), i32()) \
+        if cfg.any_moe else None
+    kv = BlockedKV(pool, None if cfg.kv_lora_rank else pool, moe)
+    exp = export.export(M.build_ragged_forward_fn(model, bs, "kernel"),
+                        platforms=["tpu"])(
+        params, kv, i32(t), i32(t), i32(t), i32(s, bps), i32(s), i32(a, bq),
+        i32(a), i32(a), i32(a, bps), i32(t), i32(s), i32(s))
+    return [(name, sig.split(") -> ")[0].count("tensor<"))
+            for name, sig in _custom_calls(exp)]
+
+
+TINY_WIDTHS = dict(hidden_size=512, num_layers=2, num_heads=4, vocab_size=512)
+# (kernel, operands) of every Mosaic call in the program's text, in order:
+# four scalar-prefetch arrays, q, the pool's arrays and the slopes
+KV_CALLS = [("ragged_prefill", 8), ("paged_decode", 8)]
+
+
+@pytest.mark.parametrize("preset,more", [
+    ("phi-2", dict(intermediate_size=1024, num_kv_heads=4, head_dim=80)),
+    ("olmoe-1b-7b", dict(intermediate_size=256, num_kv_heads=4, head_dim=128,
+                         num_experts=8, num_experts_per_tok=2))])
+def test_a_k_and_v_pool_lowers_to_the_calls_it_had(preset, more):
+    """The guard that latent attention moved no other model: phi-2's and
+    OLMoE's programs hold the same two custom calls with the same operand
+    counts as before it (K AND V both reach each kernel; the V-from-K path
+    is decided at trace time, by the pool)."""
+    calls = _lowered_ragged_forward(preset, (4, 128), **TINY_WIDTHS, **more)
+    assert calls == KV_CALLS, calls
+
+
+def test_a_latent_pool_lowers_to_calls_with_one_pool_operand():
+    """The same two kernels a layer stack (the leading dense layer's, then
+    the expert layers'), each with ONE pool operand: no V reaches them."""
+    calls = _lowered_ragged_forward(
+        "xing4-29b-a4b", (640,), **TINY_WIDTHS, intermediate_size=1024,
+        moe_intermediate_size=256, num_experts=8, num_experts_per_tok=2,
+        first_k_dense_replace=1, q_lora_rank=128, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128)
+    assert calls == [("ragged_prefill", 7), ("paged_decode", 7)] * 2, calls
+
+
 # -------------------------------------------------- long-context composites
 class TestLongContextLowering:
     """The long-context parallel attention paths (ring CP over ppermute,

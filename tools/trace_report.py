@@ -640,13 +640,15 @@ def requests_report(root: str, worst_n: int = 5, window_s: float = 60.0,
         lines.append(f"    {'launched':<20}{'rounds':>7}{'seqs':>8}"
                      f"{'tokens':>9}{'prompt':>9}{'context':>10}"
                      f"{'kv blocks':>11}{'1-row':>8}{'atoms':>8}"
-                     f"{'experts':>9}")
+                     f"{'pairs':>12}{'1-row-ctx':>11}{'experts':>9}")
         for name, m in sorted(rp["programs"].items()):
             lines.append(f"    {name:<20}{m['rounds']:>7}{m['n_seqs']:>8.1f}"
                          f"{m['tokens']:>9.1f}{m['prefill_tokens']:>9.1f}"
                          f"{m['ctx_tokens']:>10.1f}{m['kv_blocks']:>11.1f}"
                          f"{m.get('decode_rows', 0):>8.1f}"
                          f"{m.get('atoms', 0):>8.1f}"
+                         f"{m.get('attn_pairs', 0):>12.1f}"
+                         f"{m.get('dec_ctx_tokens', 0):>11.1f}"
                          f"{m.get('moe_touched', 0):>9.1f}")
     if att["cached_prefix_tokens_mean"]:
         lines.append(f"  cached prefix: "
