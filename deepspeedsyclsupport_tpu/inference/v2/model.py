@@ -84,15 +84,19 @@ def _dequant(p, dtype):
     return dequantize_tree(p, dtype)
 
 
-def _mlp(p, y, cfg, live):
+def _mlp(p, y, cfg, live, experts=None):
     """Per-layer MLP over flat tokens [T, D]: dense (GLU or fc1/fc2), or exact
     top-k MoE via grouped GEMMs (the moe_scatter/cutlass-multi-GEMM/moe_gather
     analog, ``parallel/moe.moe_mlp_nodrop``) over the ``live`` rows [T].
+    ``experts``: what :func:`_scan_layers` kept out of ``p``, ``(the stacked
+    expert leaves [L_moe, E, ., .], this layer's index in them)``, read where
+    they lie; a leaf still in ``p["moe"]`` is the layer's own slice.
     Returns (out, the rows each expert was given [E] — None when dense)."""
     if "moe" in p:    # by the tree: a leading dense layer of a sparse model
         from ...parallel.moe import moe_mlp_nodrop
 
-        return moe_mlp_nodrop(p["moe"], y, cfg, live)
+        stack, layer = experts or ({}, 0)
+        return moe_mlp_nodrop({**p["moe"], **stack}, y, cfg, live, layer)
     return mlp_block(p["mlp"], y[None], cfg)[0], None
 
 
@@ -239,21 +243,22 @@ def _unembed(params, x, cfg):
     return logits
 
 
-def _block(cfg, p, x, attn_fn, live):
+def _block(cfg, p, x, attn_fn, live, experts=None):
     """One transformer block over flat tokens, covering sequential and
     parallel (GPT-J/NeoX/Falcon/Phi) residual forms. ``live`` [T]: the rows
-    that are tokens, not padding. Returns (x, :func:`_mlp`'s expert rows)."""
+    that are tokens, not padding; ``experts``: :func:`_mlp`'s. Returns
+    (x, :func:`_mlp`'s expert rows)."""
     if cfg.hc_mult > 1:
-        return _hc_block(cfg, p, x, attn_fn, live)
+        return _hc_block(cfg, p, x, attn_fn, live, experts)
     x_norm = norm(x, p["attn_norm"], cfg)
     attn = attn_fn(x_norm)
     h = _attn_out(p["attn"], attn, cfg, x.shape[0])
     if cfg.parallel_block:
         y = x_norm if cfg.shared_block_norm else norm(x, p["mlp_norm"], cfg)
-        m, rows = _mlp(p, y, cfg, live)
+        m, rows = _mlp(p, y, cfg, live, experts)
         return (x + h + m).astype(x.dtype), rows
     x = (x + h).astype(x.dtype)
-    m, rows = _mlp(p, norm(x, p["mlp_norm"], cfg), cfg, live)
+    m, rows = _mlp(p, norm(x, p["mlp_norm"], cfg), cfg, live, experts)
     return (x + m).astype(x.dtype), rows
 
 
@@ -286,7 +291,7 @@ def _hc_maps(hc, x, cfg):
     return pre, post, res
 
 
-def _hc_block(cfg, p, x, attn_fn, live):
+def _hc_block(cfg, p, x, attn_fn, live, experts=None):
     """:func:`_block` over ``hc_mult`` residual streams x [n, T, d]: each
     sublayer F (attention, then the MLP, each with maps of its own) reads
     ``u = pre . x``, and the streams become ``res x + post^T F(norm(u))``.
@@ -307,7 +312,7 @@ def _hc_block(cfg, p, x, attn_fn, live):
     x, _ = sublayer(x, p["hc_attn"], lambda u: (_attn_out(
         p["attn"], attn_fn(norm(u, p["attn_norm"], cfg)), cfg, t), None))
     return sublayer(x, p["hc_mlp"], lambda u: _mlp(
-        p, norm(u, p["mlp_norm"], cfg), cfg, live))
+        p, norm(u, p["mlp_norm"], cfg), cfg, live, experts))
 
 
 def _paged_attention(q, k_cache, v_cache, token_seq, token_pos, block_tables,
@@ -527,22 +532,60 @@ def _attn_views(cfg, pools):
     return pools[0], pools[1], None, cfg.head_dim
 
 
+def _experts_in_place(layers, dtype):
+    """Split the stacked ``layers`` into ``(what rides as the scan's xs, the
+    routed experts' three leaves kept OUT of them)``, the second empty for
+    a dense model. By the tree: an expert leaf stays out when it is a plain
+    ``[L_moe, E, ., .]`` array of the activations' ``dtype``. Two kinds keep
+    their per-layer slice, told by type and dtype: a ``QuantTensor`` (the
+    layer is materialised by :func:`_dequant` anyway) and a leaf of another
+    dtype (the cast would convert the whole stack every layer)."""
+    from ...compression.quantize import QuantTensor
+
+    moe = layers.get("moe", {})
+    stack = {n: moe[n] for n in ("w_gate", "w_up", "w_down")
+             if n in moe and not isinstance(moe[n], QuantTensor)
+             and moe[n].ndim == 4 and moe[n].dtype == dtype}
+    if not stack:
+        return layers, stack
+    rest = {n: w for n, w in moe.items() if n not in stack}
+    return {**layers, "moe": rest}, stack
+
+
 def _scan_layers(layer, x, kv: BlockedKV, params):
     """The layer loop of both serving forwards: the pool rides as CARRY
     beside ``x`` (never as the scan's xs/ys, which would slice it by layer
     and stack a second pool), the stacked params and the layer index as xs.
     A model with leading dense layers (``params["dense_layers"]``) walks
     that stack first, then the expert layers, one pool index through both.
-    ``layer`` returns ``(carry, expert rows [E] or None)``: a sparse-expert
-    model's rows stack to [L_moe, E] and fold into ``kv.moe``. Returns
-    ``(x, the new BlockedKV)``."""
+
+    The routed experts' matrices are NOT among the xs
+    (:func:`_experts_in_place`): their consumer, ``jax.lax.ragged_dot``, is a
+    custom call on the TPU and takes whole buffers, so the scan's slice of
+    a layer's ``[E, ., .]`` was materialised before each read, three copies
+    a layer that cost 1.56 x the grouped GEMMs they fed. The loop closes over
+    the stacks instead (loop-invariant operands, as the pool is its carry)
+    and hands ``layer`` the index WITHIN them, ``l - first``: one index for
+    the pool, one for the expert stack. Router, shared expert, norms and
+    attention stay in the xs: dense operands, whose slices fuse.
+
+    ``layer(carry, p, l, experts)`` returns ``(carry, expert rows [E] or
+    None)``: a sparse-expert model's rows stack to [L_moe, E] and fold into
+    ``kv.moe``. Returns ``(x, the new BlockedKV)``."""
     carry, first = (x, kv.pools), 0
     if "dense_layers" in params:
         dense = params["dense_layers"]
         first = jax.tree_util.tree_leaves(dense)[0].shape[0]
-        carry, _ = jax.lax.scan(layer, carry, (dense, jnp.arange(first)))
+        carry, _ = jax.lax.scan(lambda c, inp: layer(c, *inp, None), carry,
+                                (dense, jnp.arange(first)))
+    layers, stack = _experts_in_place(params["layers"], x.dtype)
+
+    def body(carry, inp):
+        p, l = inp
+        return layer(carry, p, l, (stack, l - first))
+
     (x, pools), rows = jax.lax.scan(
-        layer, carry, (params["layers"], jnp.arange(first, kv.k.shape[0])))
+        body, carry, (layers, jnp.arange(first, kv.k.shape[0])))
     moe = kv.moe
     if rows is not None:
         moe = MoeCounters(moe.load + rows,
@@ -579,9 +622,8 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
 
     x = _embed(params, tokens, token_pos, cfg)
 
-    def layer(carry, inp):
+    def layer(carry, p, l, experts):
         x, pools = carry
-        p, l = inp
         p = _dequant(p, x.dtype)
 
         # resolved through the pluggable registry (module_registry.py — the
@@ -609,7 +651,7 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
                 v_dim=v_dim)
             return spec.fn(q, ctx)[..., :keep]
 
-        x, rows = _block(cfg, p, x, attn_fn, ~pad)
+        x, rows = _block(cfg, p, x, attn_fn, ~pad, experts)
         return (x, pools), rows
 
     x, kv = _scan_layers(layer, x, kv, params)
@@ -664,9 +706,8 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
 
     x = _embed(params, tokens, positions, cfg)
 
-    def layer(carry, inp):
+    def layer(carry, p, l, experts):
         x, pools = carry
-        p, l = inp
         p = _dequant(p, x.dtype)
 
         spec = select_impl("decode_attn", attn_impl,
@@ -683,7 +724,7 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
                 block_tables=block_tables, seq_lens=seq_lens, block_size=bs,
                 alibi=ab, window=window, v_dim=v_dim))[..., :keep]
 
-        x, rows = _block(cfg, p, x, attn_fn, active)
+        x, rows = _block(cfg, p, x, attn_fn, active, experts)
         return (x, pools), rows
 
     x, kv = _scan_layers(layer, x, kv, params)

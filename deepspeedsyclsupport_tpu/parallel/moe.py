@@ -139,8 +139,31 @@ def moe_mlp(p: Dict[str, Any], x: jnp.ndarray, cfg,
     return out.reshape(b, s, d), aux.astype(jnp.float32)
 
 
+def _layer_groups(w: jnp.ndarray, group_sizes: jnp.ndarray, layer, dtype
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """What ``ragged_dot`` takes of an expert leaf ``w``: the whole stack
+    ``[L, E, a, b]`` with the ``layer`` to read, or one layer's ``[E, a, b]``
+    (told apart by RANK: a stack of one, read at 0). Returns the ``[L x E, a,
+    b]`` view (a reshape of leading axes: a bitcast, nothing moves) and its
+    ``[L x E]`` sizes: this layer's E ``group_sizes`` at ``layer x E`` among
+    zeros, so the sorted rows fall on this layer's experts where they lie
+    and no other layer's are read. Only one layer's leaf may be of another
+    ``dtype``: casting a stack would convert L x the bytes every layer."""
+    if w.ndim == 3:
+        w, layer = w.astype(dtype)[None], 0
+    elif w.dtype != dtype:
+        raise ValueError(
+            f"a stack of expert weights must have the activations' dtype "
+            f"({w.dtype} != {dtype}): hand one layer's slice instead")
+    n_layers, e = w.shape[:2]
+    sizes = jax.lax.dynamic_update_slice(
+        jnp.zeros((n_layers * e,), group_sizes.dtype), group_sizes,
+        (layer * e,))
+    return w.reshape((n_layers * e,) + w.shape[2:]), sizes
+
+
 def moe_mlp_nodrop(p: Dict[str, Any], x: jnp.ndarray, cfg,
-                   live: Optional[jnp.ndarray] = None
+                   live: Optional[jnp.ndarray] = None, layer=0
                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Exact top-k MoE for flat token streams (the serving path).
 
@@ -158,6 +181,16 @@ def moe_mlp_nodrop(p: Dict[str, Any], x: jnp.ndarray, cfg,
 
     A model with shared experts (``p["shared"]``) adds their SwiGLU of every
     row beside the routed sum.
+
+    ``p["w_gate" | "w_up" | "w_down"]`` are one layer's ``[E, ., .]`` or the
+    whole stack ``[L, E, ., .]`` with ``layer`` (static or traced) the layer
+    to read: on the TPU ``ragged_dot`` is a custom call, which takes whole
+    buffers, so a slice ``w[layer]`` handed to it is first COPIED out of the
+    stack (three matrices a layer, 1.56 x the GEMMs' own time in OLMoE's
+    decode step). The serving layer loop therefore hands the stack whole
+    (``inference/v2/model.py:_scan_layers``) and :func:`_layer_groups` places
+    the rows on the layer's experts. Every other leaf of ``p`` is the
+    layer's own.
 
     x: [T, D] flat tokens → (out [T, D], group_sizes [E] int32: the (token,
     choice) rows each expert was given, ``sum == k × live rows``).
@@ -188,12 +221,13 @@ def moe_mlp_nodrop(p: Dict[str, Any], x: jnp.ndarray, cfg,
 
     act = jax.nn.silu if cfg.activation == "silu" else jax.nn.gelu
     with scope("moe_experts"):
-        wg = p["w_gate"].astype(x.dtype)
-        wu = p["w_up"].astype(x.dtype)
-        wd = p["w_down"].astype(x.dtype)
-        gate = jax.lax.ragged_dot(xs, wg, group_sizes)
-        up = jax.lax.ragged_dot(xs, wu, group_sizes)
-        ys = jax.lax.ragged_dot(act(gate) * up, wd, group_sizes)  # [T*k, D]
+        def grouped(rows, w):
+            return jax.lax.ragged_dot(
+                rows, *_layer_groups(w, group_sizes, layer, x.dtype))
+
+        gate = grouped(xs, p["w_gate"])
+        up = grouped(xs, p["w_up"])
+        ys = grouped(act(gate) * up, p["w_down"])             # [T*k, D]
 
     with scope("moe_combine"):
         ys = ys * gate_w.reshape(t * k)[order].astype(x.dtype)[:, None]
