@@ -5,8 +5,7 @@ Kept with the benchmark so that no PR that claims a gain can change how its
 gain is counted. Each function is checked in ``tests/benchmark`` against a
 hand count at one tiny shape. FLOPs per trained token are a model
 family's own count (``families/<model_type>.py:train_flops_per_token``): the
-usual ``6`` per matmul weight a token meets (2 forward, 4 backward;
-re-derived, ``bench.py:model_flops_per_token`` has the same arithmetic) plus
+usual ``6`` per matmul weight a token meets (2 forward, 4 backward) plus
 the attention term below. Activation recomputation is work the hardware
 does and the model does not need: NOT counted.
 """

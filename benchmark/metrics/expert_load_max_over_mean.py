@@ -4,9 +4,15 @@ engine's device-resident ``moe_load`` counter over the whole run
 (``engine.moe_stats()``; 1.0 = uniform). Seeded-noise weights route near
 uniformly, which a trained router does not: this says how near.
 
-Also holds the counter to its invariant: every layer routed every live token
-``num_experts_per_tok`` times and no pad row. Where it does not hold the
-metric is left out and stderr says by how much."""
+``moe_stats()["load"]`` is ``[expert layers, experts]`` over the router's
+WHOLE width, whatever part of the experts the chip holds: routing is over
+all of them. So the counter is held to its invariant as it stands: every
+layer routed every live token ``num_experts_per_tok`` times and no pad row.
+Where it does not hold the metric is left out and stderr says by how much.
+
+A program that holds a share of the experts also gives ``"held"``, the
+columns of ``load`` that are its own; the maximum and the mean are then over
+those columns: the busiest expert HERE sets this chip's time."""
 import sys
 
 
@@ -23,4 +29,6 @@ def read(obs):
               f"{want}: a pad row was routed or a live one was not",
               file=sys.stderr)
         return None
+    if stats.get("held") is not None:
+        load = load[:, stats["held"]]
     return float((load.max(1) / load.mean(1)).mean())

@@ -1,9 +1,9 @@
 """Program launches the host made per scheduling round: the trace's
 ``PJRT_LoadedExecutable_Execute`` events that start inside the program's own
 round spans (``round`` records laid on the trace's clock), over the traced
-rounds. ``dispatch_per_tok`` counts two of them a round, the forward and the
-sampler; the rest are the per-sequence slices, their stack, the rng split
-and scalar converts."""
+rounds: the forward, the one program that gathers by slot and samples, and
+whatever else the host launches between a round's ends (a program that
+slices, stacks and converts per sequence shows here as some hundred)."""
 import bisect
 
 from benchmark import spans, trace

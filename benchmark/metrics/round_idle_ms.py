@@ -4,7 +4,10 @@ round's start ``t0`` to the first operation of its forward program, ``tail``
 from that program's last operation to the round's end ``t1`` (the program's
 ``round`` record on the trace's clock; operations that ran in between, such
 as the sampler, are taken off). A round that launched no forward is idle
-from end to end and counts as all head."""
+from end to end and counts as all head. Where a round returns while its
+forward runs the tail is 0 by construction, and no metric names it; a
+program that waits for its own forward inside the round has one, and names
+it by an alias."""
 from benchmark import spans, window
 
 

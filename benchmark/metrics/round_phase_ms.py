@@ -7,8 +7,8 @@ one round add up to its ``t1 - t0``; the medians of a window that mixes
 clock, NOT host work alone: a launch blocks while the device's launch queue
 is full, so ``post`` (the ``collect`` slices behind the forward) and, where
 the forward ends in the next round, ``pre`` hold mostly the forward's own
-time. What the host costs the chip is ``round_idle_head_ms``,
-``round_idle_tail_ms`` and ``launches_per_round``."""
+time. What the host costs the chip is ``round_idle_head_ms`` and
+``launches_per_round``."""
 from benchmark import spans, window
 
 

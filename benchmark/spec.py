@@ -4,7 +4,8 @@
 belongs to one of them sits in a file of its own under one of the
 benchmark's ``paths``:
 
-* a configuration — the ``file`` its ``configs`` entry names;
+* a configuration — the ``file`` its ``configs`` entry names; what its
+  ``reduced`` may hold is ``reduced_problems``' to say;
 * a traffic mix — ``<path>/traffic/<name>.json``;
 * a per-layer metric — ``<path>/metrics/<name>.py`` (a module with
   ``read(obs)``) or ``<path>/metrics/<name>.json`` (``{"reader": <other
@@ -30,10 +31,100 @@ SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
 TRAFFIC_KINDS = ("closed", "open-fixed-rate", "train-batches")
 CONFIG_PATHS = ("train", "zero3", "serve")
 
+# ``reduced``: what a configuration may change from its source. Every entry
+# says what its key COUNTS; a key that counts nothing is a width.
+CUTS = ("layers", "experts", "heads", "vocabulary")
+SHARES = CUTS[1:]               # this chip's part of a layer, not fewer layers
+SHARED_BY = (2, 4, 8, 16, 32)   # chips that may share each layer
+MIN_EXPERTS_HELD = 8            # the model-configs guide's floors for a share
+MIN_VOCABULARY_PART = 8         # ... at least an eighth of the rows
+MIN_LAYERS_AFTER_DENSE = 4
+# the published keys the floors read (the catalog's names; all integers)
+DEPTH_KEYS = ("num_hidden_layers", "num_layers")
+LEADING_DENSE_KEYS = ("first_k_dense_replace", "num_dense_layers",
+                      "n_dense_first_layers")
+HEADS_RE = re.compile(r"(^|_)heads$")
+# what the driver refuses under ``reduced`` whatever the entry says of it
+WIDTH_RE = re.compile(
+    r"(hidden|intermediate|latent|state|proj\w*)_size$|_dim$|_rank$"
+    r"|head_size|expan\w*_factor|experts_per_tok")
+
 
 def load_json(path):
     with open(path) as f:
         return json.load(f)
+
+
+def reduced_problems(cfg):
+    """What is wrong with a configuration's ``reduced``, as sentences that
+    name the key and the reason (empty = sound).
+
+    An entry is ``{key: {"published", "run", "counts", "why"}}``: the file
+    runs ``cfg[key] == run != published``, and ``counts`` says what kind of
+    cut that is. ``"layers"``: the depth and the counts that go with it (the
+    leading dense layers, the multi-token-prediction blocks). ``"experts"``,
+    ``"heads"``, ``"vocabulary"``: this chip's SHARE of a layer that
+    ``layer_shared_by`` chips divide between them, so ``run x
+    layer_shared_by == published``, all head counts go together, and the
+    guide's floors hold. Anything else is a width, and a width is never
+    cut. The label is the builder's statement (a reviewer holds it against
+    the published config); the arithmetic and the floors are checked here."""
+    reduced, out, kinds = cfg.get("reduced", {}), [], {}
+    for key, cut in reduced.items():
+        counts = cut.get("counts")
+        if counts not in CUTS:
+            out.append(f"reduced[{key!r}]: counts is {counts!r}, not one of "
+                       f"{CUTS}: a width is never cut")
+            continue
+        if WIDTH_RE.search(key):
+            out.append(f"reduced[{key!r}]: the key names a width, whatever "
+                       f"it is said to count: a width is never cut")
+        if not (cfg.get(key) == cut.get("run") != cut.get("published")):
+            out.append(f"reduced[{key!r}]: the file runs {cfg.get(key)!r}, "
+                       f"the entry says run {cut.get('run')!r}, published "
+                       f"{cut.get('published')!r} (want file == run != "
+                       f"published)")
+        if not cut.get("why"):
+            out.append(f"reduced[{key!r}]: no why")
+        kinds.setdefault(counts, []).append(key)
+    shares = [key for counts in SHARES for key in kinds.get(counts, ())]
+    if not shares:
+        return out
+    by = cfg.get("layer_shared_by")
+    if by not in SHARED_BY:
+        return out + [
+            f"reduced[{key!r}]: a share ({reduced[key]['counts']}) needs "
+            f"\"layer_shared_by\": N beside deployment, N in {SHARED_BY}; "
+            f"the file has {by!r}" for key in shares]
+    for key in shares:
+        run, published = reduced[key]["run"], reduced[key]["published"]
+        if run * by != published:
+            out.append(f"reduced[{key!r}]: {run} x layer_shared_by {by} is "
+                       f"not the published {published}")
+    for key in kinds.get("experts", ()):
+        if reduced[key]["run"] < MIN_EXPERTS_HELD:
+            out.append(f"reduced[{key!r}]: {reduced[key]['run']} experts "
+                       f"held, the floor is {MIN_EXPERTS_HELD}")
+    for key in kinds.get("vocabulary", ()):
+        run, published = reduced[key]["run"], reduced[key]["published"]
+        if run * MIN_VOCABULARY_PART < published:
+            out.append(f"reduced[{key!r}]: {run} of {published} rows, the "
+                       f"floor is 1/{MIN_VOCABULARY_PART} of the vocabulary")
+    if "heads" in kinds:
+        whole = sorted(k for k, v in cfg.items() if HEADS_RE.search(k)
+                       and isinstance(v, int) and k not in kinds["heads"])
+        if whole:
+            out.append(f"reduced[{kinds['heads'][0]!r}]: query and key-value "
+                       f"heads are cut together or not at all; {whole} "
+                       f"stay whole")
+    depth_key = next((k for k in DEPTH_KEYS if k in cfg), None)
+    dense = sum(cfg[k] for k in LEADING_DENSE_KEYS
+                if isinstance(cfg.get(k), int))
+    if depth_key and cfg[depth_key] < dense + MIN_LAYERS_AFTER_DENSE:
+        out.append(f"reduced: a share at {depth_key} {cfg[depth_key]}: the "
+                   f"floor is the {dense} leading dense layers + "
+                   f"{MIN_LAYERS_AFTER_DENSE}")
+    return out
 
 
 class Bench:
@@ -54,9 +145,18 @@ class Bench:
     def cell(self, name):
         return self._entry("workloads", name)
 
-    def config(self, name):
+    def _config_file(self, name):
         entry = self._entry("configs", name)
         return {**load_json(self.root / entry["file"]), "name": name}
+
+    def config(self, name):
+        """The configuration as its file has it; one whose ``reduced`` cuts
+        what may not be cut is refused here, before anything runs it."""
+        cfg = self._config_file(name)
+        wrong = reduced_problems(cfg)
+        if wrong:
+            raise ValueError(f"configuration {name!r}: " + "; ".join(wrong))
+        return cfg
 
     def _find(self, sub, name, suffixes):
         for p in self.doc["paths"]:
@@ -112,7 +212,8 @@ class Bench:
         """Everything wrong with the benchmark's data, as a list of
         sentences (empty = sound). Checks what a later PR's added files must
         also meet: names, units, sources, files found by name, and that each
-        per-layer metric's ``moves`` is reported by every cell reporting it."""
+        per-layer metric's ``moves`` is reported by every cell reporting it,
+        and what each configuration's ``reduced`` cuts."""
         d, out = self.doc, []
         cells = [w["name"] for w in d["workloads"]]
         e2e = {m["name"]: m for m in d["end_to_end"]}
@@ -145,12 +246,24 @@ class Bench:
                 self.reader(m["name"])
             except (FileNotFoundError, KeyError, AttributeError) as e:
                 out.append(f"{m['name']}: no reader ({e})")
+        for c in d["configs"]:
+            try:
+                cfg = self._config_file(c["name"])
+            except FileNotFoundError as e:
+                out.append(f"{c['name']}: {e}")
+                continue
+            out += [f"{c['name']}: {p}" for p in reduced_problems(cfg)]
+            keys = sorted(cfg.get("reduced", {}))
+            if c["reduced"] != keys:
+                out.append(f"{c['name']}: BENCHMARK.json lists reduced "
+                           f"{c['reduced']}, the file's keys sorted are "
+                           f"{keys}")
         for w in d["workloads"]:
             for key in ("config", "traffic"):
                 if not NAME_RE.match(w[key]):
                     out.append(f"{w['name']}: bad {key} {w[key]!r}")
             try:
-                cfg = self.config(w["config"])
+                cfg = self._config_file(w["config"])
                 mix = self.traffic(w["traffic"])
             except (KeyError, FileNotFoundError) as e:
                 out.append(f"{w['name']}: {e}")

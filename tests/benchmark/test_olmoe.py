@@ -277,18 +277,144 @@ def test_scoped_ops_picks_a_programs_instructions_out_of_a_trace():
 
 def test_expert_work_is_a_hand_count(family):
     reader = spec.Bench()._module("metrics", "moe_roofline")
-    arch = {"hidden_size": 4, "intermediate_size": 3,
-            "num_experts_per_tok": 2}
-    # 5 tokens x 2 experts = 10 rows, each through three 4x3 matrices
-    # (2 FLOPs a weight); 7 expert-layers' weights of 3 x 12 numbers read
-    # once, 10 rows of 4 in and out, 2 bytes each
-    assert reader.expert_work(arch, touched=7, tokens=5) \
+    arch = {"hidden_size": 4, "intermediate_size": 3}
+    # 10 rows, each through three 4x3 matrices (2 FLOPs a weight); 7
+    # expert-layers' weights of 3 x 12 numbers read once, 10 rows of 4 in
+    # and out, 2 bytes each
+    assert reader.expert_work(arch, touched=7, rows=10) \
         == (10 * 3 * 2 * 12, (7 * 36 + 2 * 10 * 4) * 2)
-    # the published sizes at a full decode step: 32 tokens, 631 expert-layers
-    # touched of 640 -> 7.94 GB of weights, weight-bound
-    flops_, nbytes = reader.expert_work(
-        family.arch({**TINY_OLMOE, "hidden_size": 2048,
-                     "intermediate_size": 1024, "num_experts_per_tok": 8}),
-        touched=631, tokens=32)
-    assert nbytes == pytest.approx(7.94e9, rel=1e-3)
+    # the published sizes at a full decode step: 32 tokens x 8 experts in
+    # each of 10 layers, 631 expert-layers touched of 640 -> 7.94 GB of
+    # weights beside 0.02 GB of rows, weight-bound
+    published = family.arch({**TINY_OLMOE, "hidden_size": 2048,
+                             "intermediate_size": 1024,
+                             "num_hidden_layers": 10,
+                             "num_experts_per_tok": 8})
+    assert reader.expert_layers(published) == 10
+    assert reader.expert_layers({"num_layers": 6, "num_dense_layers": 2}) == 4
+    flops_, nbytes = reader.expert_work(published, touched=631,
+                                        rows=32 * 8 * 10)
+    assert nbytes == pytest.approx(7.96e9, rel=1e-3)
     assert flops_ / 197e12 < nbytes / 819e9
+
+
+# --------------------- the expert readers on a chip's share of the experts
+V5E = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+OLMOE_D10 = {"hidden_size": 2048, "intermediate_size": 1024,
+             "num_layers": 10, "num_experts_per_tok": 8}
+GEMM = ('%ragged-dot-none.1 = bf16[8,4]{1,0} custom-call(%a), '
+        'custom_call_target="tpu_custom_call"')
+
+
+def traced_obs(tokens, touched, gemm_s, moe_rows=None,
+               program="decode_forward"):
+    """``obs`` of a traced run of three rounds, each launching one forward
+    of ``tokens`` live tokens whose grouped GEMMs took ``gemm_s`` on the
+    device; the counts of a forward ride on the record AFTER its own."""
+    import types
+
+    from benchmark import spans
+
+    offset, rounds, t = 5.0, [], 100.0
+    for took in (0.030, 0.041, 0.052, 0.063, 0.074):    # no two alike
+        rounds.append((t, t + took, 32, 0))
+        t += took + 0.001
+    stages, host, modules, ops = [], [], [], []
+    for i, (t0, t1, *_) in enumerate(rounds):
+        data = {"stage": "round", "round": i, "t0": t0 + 1e-4,
+                "t1": t1 - 1e-4, "launch_t": t0 + 0.0031, "tokens": tokens,
+                "program": program, "moe_touched": touched}
+        if moe_rows is not None:
+            data["moe_rows"] = moe_rows
+        stages.append({"name": "serve/stage", "data": data})
+        if 1 <= i <= 3:                                  # the traced ones
+            host += [[spans.ROUND_SPAN, t0 + offset, t1 - t0],
+                     [f"PjitFunction({program})", t0 + offset + 0.002, 0.001]]
+            modules.append([f"jit_{program}(7)", t0 + offset + 0.004, 0.02])
+            ops.append([GEMM, t0 + offset + 0.005, gemm_s])
+
+    class Compiled:
+        def as_text(self):
+            return ""
+
+    engine = types.SimpleNamespace(
+        compiled_programs=lambda: {program: Compiled()})
+    return {"trace": {"host": host, "devices": {"/device:TPU:0": {
+                "modules": modules, "ops": ops}}},
+            "trace_window": (rounds[1][0] + offset - 1e-3,
+                             rounds[3][1] + offset + 1e-3),
+            "rounds": rounds, "stages": stages, "engine": engine,
+            "config": {}, "peaks": V5E,
+            "family": types.SimpleNamespace(arch=lambda cfg: OLMOE_D10)}
+
+
+def ideal_s(touched, rows):
+    """The hand count at OLMoE's widths: the larger of the GEMMs' FLOPs at
+    197 TFLOP/s and of the touched weights + the rows in and out, bf16, at
+    819 GB/s."""
+    return max(rows * 6 * 2048 * 1024 / 197e12,
+               (touched * 3 * 2048 * 1024 + 2 * rows * 2048) * 2 / 819e9)
+
+
+@pytest.mark.parametrize("case", ["decode_round_every_expert_held",
+                                  "chunk_round_every_expert_held",
+                                  "chunk_round_a_quarter_held",
+                                  "a_quarter_held_and_not_counted"])
+def test_moe_roofline_counts_the_rows_of_the_experts_held(case):
+    read = spec.Bench().reader("moe_roofline")
+    if case == "decode_round_every_expert_held":
+        # no moe_rows on the record: 32 tokens x 8 x 10 layers; the rows
+        # were ONE layer's until PR 32, which read 0.24 % less here
+        got = read(traced_obs(tokens=32, touched=631, gemm_s=0.016))
+        assert got == pytest.approx(100 * ideal_s(631, 2560) / 0.016)
+        assert got / (100 * ideal_s(631, 256) / 0.016) == pytest.approx(
+            1.0024, abs=2e-4)
+    elif case == "chunk_round_every_expert_held":
+        # 768 tokens: 61,440 rows, 0.5 GB beside 8.05 GB of weights: 5.6 %
+        # over one layer's rows, still bound by the bytes (10.4 ms, the
+        # FLOPs 3.9)
+        got = read(traced_obs(768, 640, 0.018, program="ragged_forward"))
+        assert got == pytest.approx(100 * ideal_s(640, 61440) / 0.018)
+        assert ideal_s(640, 61440) == pytest.approx(0.01044, rel=1e-3)
+        assert got / (100 * ideal_s(640, 6144) / 0.018) == pytest.approx(
+            1.056, abs=2e-3)
+    elif case == "chunk_round_a_quarter_held":
+        # 16 of 64 experts held: 160 expert-layers touched, a quarter of
+        # the rows, counted on the device: 2.61 ms of bytes in 3.0
+        got = read(traced_obs(768, 160, 0.003, moe_rows=15360,
+                              program="ragged_forward"))
+        assert got == pytest.approx(100 * ideal_s(160, 15360) / 0.003)
+        assert 85 < got < 100
+    else:
+        # the same forward read without its count: every token's 8 rows,
+        # four times what went through the experts here, 3.9 ms of FLOPs in
+        # 3.0: the reading the driver refuses (over 105), which is why a
+        # program that holds a share MUST write moe_rows
+        got = read(traced_obs(768, 160, 0.003, program="ragged_forward"))
+        assert got == pytest.approx(100 * ideal_s(160, 61440) / 0.003)
+        assert got > 105
+
+
+@pytest.mark.parametrize("held", [None, [2, 3]])
+def test_expert_load_is_over_the_columns_the_chip_holds(held, capsys):
+    """``load`` is over the router's whole width either way and is held to
+    its invariant there; with ``held`` the busiest and the mean expert are
+    this chip's own."""
+    import types
+
+    read = spec.Bench().reader("expert_load_max_over_mean")
+    load = np.array([[6, 2, 3, 1], [3, 3, 5, 1]])     # 2 layers, 4 experts
+
+    def obs(load):
+        stats = {"load": load, "live_tokens": 6}
+        if held is not None:
+            stats["held"] = held
+        return {"engine": types.SimpleNamespace(moe_stats=lambda: stats),
+                "config": {}, "family": types.SimpleNamespace(
+                    arch=lambda cfg: {"num_experts_per_tok": 2})}
+
+    want = (6 / 3 + 5 / 3) / 2 if held is None else (3 / 2 + 5 / 3) / 2
+    assert read(obs(load)) == pytest.approx(want)
+    # a row too many in a column the chip does NOT hold still voids it
+    assert read(obs(load + np.array([[1, 0, 0, 0], [0, 0, 0, 0]]))) is None
+    assert "pad row" in capsys.readouterr().err

@@ -64,7 +64,8 @@ def test_closed_loop_cell_runs_and_counts_tokens_in_the_window(bench):
     assert t0 in ends and t1 in ends
     assert m["serve_tok_s"] > 0 and m["itl_p99_ms"] > 0
     assert m["live_seqs_mean"] == pytest.approx(4.0, abs=0.5)
-    assert m["dispatch_per_tok"] > 0 and "rounds_per_s" not in m
+    assert m["round_p50_ms"] > 0 and "rounds_per_s" not in m
+    assert "dispatch_per_tok" not in m         # retired with PR 32
     # the traced metrics have nothing to read off the chip: left out
     assert "decode_fwd_ms" not in m and "serve_idle_pct" not in m
 

@@ -123,8 +123,8 @@ def test_launches_per_round_counts_every_program_launch(recorded):
     assert per_round == [103, 104, 104]
     assert BENCH.reader("launches_per_round")(recorded) == pytest.approx(
         311 / 3)
-    # what dispatch_per_tok counts of them: the forward and the sampler
-    assert BENCH.reader("launches_per_round.prefill")(recorded) > 50 * 2
+    assert BENCH.reader("launches_per_round.prefill")(recorded) \
+        == pytest.approx(311 / 3)
 
 
 def test_head_and_tail_idle_of_the_recorded_decode_rounds(recorded):
@@ -140,8 +140,12 @@ def test_head_and_tail_idle_of_the_recorded_decode_rounds(recorded):
         assert sum(p[i] for p in decode) / 2 == pytest.approx(want, abs=1e-3)
     assert BENCH.reader("round_idle_head_ms")(recorded) == pytest.approx(
         14.13, abs=0.05)
-    assert BENCH.reader("round_idle_tail_ms")(recorded) == pytest.approx(
+    # no metric names the tail since PR 32 (0 by construction once a round
+    # returns while its forward runs); the reader still takes either end
+    assert module.read(recorded, end="tail") == pytest.approx(
         17.97, abs=0.05)
+    with pytest.raises(FileNotFoundError):
+        BENCH.reader("round_idle_tail_ms")
     # head + tail is the traced rounds' idle time: all but what the device
     # idles INSIDE its programs and between two rounds
     lo, hi = recorded["trace_window"]
@@ -174,9 +178,23 @@ def test_window_readers_take_the_phase_groups_from_the_records(recorded):
         records[0]["phases"])          # the groups partition the phases
     assert BENCH.reader("round_max_ms")(recorded) == pytest.approx(
         152.1, abs=0.1)
-    took = [d["t1"] - d["t0"] for d in records]
-    assert BENCH.reader("ragged_round_share_pct")(recorded) == pytest.approx(
-        100 * took[2] / sum(took))
+    # a record's time belongs to the forward the round BEFORE it launched
+    # (the one it waits for): 104-106 each follow a decode_forward, and 107
+    # follows 106's ragged_forward
+    share = BENCH.reader("ragged_round_share_pct")
+    assert share(recorded) == 0.0
+    longer = {**recorded, "window": (recorded["window"][0],
+                                     recorded["rounds"][6][1])}
+    took = [d["t1"] - d["t0"] for d in spans.window_records(longer)]
+    assert len(took) == 4
+    assert share(longer) == pytest.approx(100 * took[3] / sum(took))
+    assert BENCH.reader("share_ragged_rounds_pct.moe")(longer) \
+        == share(longer)
+    # a ring that dropped the record before the window's first charges that
+    # one to no program and keeps it in the total
+    kept = {**longer, "stages": [st for st in longer["stages"]
+                                 if st["data"]["round"] >= 104]}
+    assert share(kept) == share(longer)
 
 
 def test_readers_say_so_and_read_nothing_where_records_are_missing(
@@ -187,7 +205,7 @@ def test_readers_say_so_and_read_nothing_where_records_are_missing(
              if m["name"].startswith(("round_", "launches_", "ragged_round",
                                       "paged_roofline"))
              and not m["name"].startswith("round_p50_ms")]
-    assert len(names) == 17
+    assert len(names) == 16
     for stages in ([], recorded["stages"][4:]):
         obs = {**recorded, "stages": stages}
         for name in names:

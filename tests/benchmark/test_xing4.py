@@ -120,9 +120,9 @@ def test_the_preset_has_the_published_widths(family):
 
 def test_the_configuration_departs_from_the_source_only_where_it_says():
     """Every key of the catalog's ``config`` is in the file under the same
-    name with the same value, but for the depth under ``reduced``; the
-    multi-token-prediction block, which the trunk's forward leaves out, is
-    accounted for under ``assumed``."""
+    name with the same value, but for what stands under ``reduced``: the
+    depth, and the multi-token-prediction block, which the trunk's forward
+    leaves out and no weight instantiates."""
     catalog = "/opt/skills/guides/model-configs/architectures.jsonl"
     try:
         rows = [json.loads(ln) for ln in open(catalog)]
@@ -132,10 +132,15 @@ def test_the_configuration_departs_from_the_source_only_where_it_says():
     cfg = spec.Bench().config("xing4-29b-a4b-d6")
     assert cfg["source"] == row["source_url"]
     differs = {k for k, v in row["config"].items() if cfg.get(k) != v}
-    assert differs == set(cfg["reduced"]) == {"num_hidden_layers"}
+    assert differs == set(cfg["reduced"]) == {"num_hidden_layers",
+                                              "num_nextn_predict_layers"}
     for k, r in cfg["reduced"].items():
         assert (r["published"], r["run"]) == (row["config"][k], cfg[k])
-    assert cfg["num_nextn_predict_layers"] == 1 and "mtp" in cfg["assumed"]
+        assert r["counts"] == "layers"
+    assert cfg["num_nextn_predict_layers"] == 0
+    # both leading dense layers stay whole
+    assert cfg["first_k_dense_replace"] == row["config"][
+        "first_k_dense_replace"] == 2
     assert cfg["overrides"] == {"num_layers": 6}
 
 
@@ -235,13 +240,19 @@ def test_the_benchmark_is_sound_with_the_new_entries():
         "xing4-29b-a4b-d6", "docs-8k-sat", 1)
     reports = {m["name"] for section in ("end_to_end", "per_layer")
                for m in bench.metrics_of("xing4-docs-sat", section)}
-    assert {"serve_tok_s", "setup_s", "mla_share_pct", "mla_prefill_roofline",
-            "mla_decode_roofline", "mhc_share_pct", "kv_bytes_per_token",
-            "moe_roofline", "expert_load_max_over_mean",
-            "ragged_tile_fill_pct"} <= reports
-    for m in bench.doc["per_layer"][-5:]:
-        assert m["workloads"] == ["xing4-docs-sat"]
-        assert m["moves"] == "serve_tok_s"
+    assert {"itl_p95_ms", "setup_s", "serve_tok_s.docs", "mla_share_pct",
+            "mla_prefill_roofline", "mla_decode_roofline", "mhc_share_pct",
+            "kv_bytes_per_token", "moe_docs_roofline",
+            "expert_load_max_over_mean.docs",
+            "ragged_tile_fill_pct.docs"} <= reports
+    # tokens/s swing too widely here to be judged (PERF.md section 2): the
+    # cell's end-to-end metric is the tail of the gaps, and every per-layer
+    # metric it reports moves that one
+    assert "serve_tok_s" not in reports
+    for m in bench.metrics_of("xing4-docs-sat", "per_layer"):
+        if "workloads" in m:     # without the key: every cell's, setup_s
+            assert m["workloads"] == ["xing4-docs-sat"]
+            assert m["moves"] == "itl_p95_ms"
 
 
 def test_the_mix_is_the_issues_grid():
@@ -296,11 +307,12 @@ def tiny_cell(tmp_path_factory, family):
 def test_the_cell_runs_is_checked_and_counts_its_pool(tiny_cell):
     obs, m = tiny_cell
     assert obs["correct"] and obs["failed"] == 0 and obs["attempted"] > 4
-    assert m["serve_tok_s"] > 0 and m["live_seqs_mean"] > 1
+    assert m["serve_tok_s.docs"] > 0 and m["live_seqs_mean.docs"] > 1
+    assert m["itl_p99_ms.docs"] >= m["itl_p95_ms"] > 0
     eng = obs["engine"]
     # three layers of one 40-wide float32 row (no lane padding off the TPU)
     assert eng.kv.v is None and m["kv_bytes_per_token"] == 3 * 40 * 4
-    assert 1.0 <= m["expert_load_max_over_mean"] < 4.0
+    assert 1.0 <= m["expert_load_max_over_mean.docs"] < 4.0
     stats = eng.moe_stats()
     assert stats["load"].shape == (2, 8)
     assert (stats["load"].sum(1) == 3 * stats["live_tokens"]).all()
