@@ -43,14 +43,15 @@ def _gather_rows(logits, slots):
 
 def _sample_rows(logits, slots, rng, temperature, top_p, structure,
                  tail=None):
-    """``sample_token_dyn`` over :func:`_gather_rows`; a ``tail`` (device
-    int32 scalar) is appended to the tokens [S] -> [S + 1], so that it
-    reaches the host in the ONE transfer that brings the tokens (a
-    sparse-expert model's ``moe_touched``: no launch and no transfer of its
-    own)."""
+    """``sample_token_dyn`` over :func:`_gather_rows`; a ``tail`` (a tuple
+    of device int32 scalars) is appended to the tokens [S] -> [S + n], so
+    that it reaches the host in the ONE transfer that brings the tokens (a
+    sparse-expert model's ``moe_touched``, and ``moe_rows`` where it holds
+    a share of the experts: no launch and no transfer of their own)."""
     toks = sample_token_dyn(_gather_rows(logits, slots), rng, temperature,
                             top_p, structure)
-    return toks if tail is None else jnp.concatenate([toks, tail[None]])
+    return toks if tail is None else jnp.concatenate(
+        [toks, *(t[None] for t in tail)])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -188,6 +189,18 @@ class InferenceEngineV2:
             # get_impl's message already names the registered impls
             raise ValueError(str(e)) from e
         self._use_atoms = bool(spec.metadata.get("needs_atoms"))
+        chose_atom = isinstance(config, RaggedInferenceConfig) or {
+            **(config or {}), **kw}.get("atom_q_size") is not None
+        if self._use_atoms and not chose_atom:
+            # nobody chose the atom's rows: the pool's shape does (one kv
+            # head for a latent pool, which has no head axis)
+            from ...ops.paged_attention import default_atom_rows
+
+            kvh = 1 if self.kv.v is None else self.kv.k.shape[-2]
+            cfg.atom_q_size = default_atom_rows(
+                cfg.atom_q_size, model.config.num_heads, kvh,
+                self.kv.k.shape[-1], cfg.block_size,
+                jnp.dtype(cfg.dtype).itemsize)
         log_dist(f"ragged engine: {cfg.num_blocks} KV blocks × {cfg.block_size} "
                  f"tokens, budget {cfg.max_tokens_per_batch} tok/fwd, "
                  f"≤{cfg.max_sequences} seqs")
@@ -312,11 +325,17 @@ class InferenceEngineV2:
         transfer: for a report, not for a round), and ``live_tokens``, the
         tokens the host put into those forwards. Every layer routes every
         live token ``k`` times and no pad row, so ``load[l].sum() == k *
-        live_tokens`` (``kv_cache.MoeCounters``)."""
+        live_tokens`` (``kv_cache.MoeCounters``). ``load`` is over the
+        router's WHOLE width; a program that holds a share of the experts
+        adds ``held``, the columns of ``load`` that are its own."""
         if self.kv.moe is None:
             return None
-        return {"load": np.asarray(self.kv.moe.load),
-                "live_tokens": self._forward_tokens}
+        stats = {"load": np.asarray(self.kv.moe.load),
+                 "live_tokens": self._forward_tokens}
+        if self.kv.moe.rows is not None:
+            cfg = self.model.config
+            stats["held"] = np.arange(cfg.num_experts)[cfg.held_experts]
+        return stats
 
     def compiled_programs(self) -> Dict[str, Any]:
         """``{name: jax.stages.Compiled}`` for every forward program this
@@ -1046,23 +1065,28 @@ class InferenceEngineV2:
                 rows = jnp.where(mine, got, rows)
         return rows
 
-    def moe_tail(self) -> Optional[jax.Array]:
+    def moe_tail(self) -> Optional[Tuple[jax.Array, ...]]:
         """What a serving round gives :meth:`sample_drained` as ``tail``: a
-        sparse-expert model's ``moe_touched`` of the last forward (device
-        int32 scalar), None for a dense model."""
-        return None if self.kv.moe is None else self.kv.moe.touched
+        sparse-expert model's ``moe_touched`` of the last forward and, where
+        it holds a share of the experts, its ``moe_rows`` (device int32
+        scalars), None for a dense model."""
+        moe = self.kv.moe
+        if moe is None:
+            return None
+        return (moe.touched,) if moe.rows is None else (moe.touched,
+                                                         moe.rows)
 
     def sample_drained(self, uids: Sequence[int], rng: jax.Array,
                        sampling: SamplingParams,
-                       tail: Optional[jax.Array] = None
-                       ) -> Tuple[np.ndarray, Optional[int]]:
+                       tail: Optional[Tuple[jax.Array, ...]] = None
+                       ) -> Tuple[np.ndarray, Optional[Tuple[int, ...]]]:
         """One token for each of ``uids`` (all :meth:`has_logits`), sampled
         on the device from the forward's whole logits: ONE launch of one
         fixed-shape program (gather the rows by slot, ``sample_token_dyn``)
         and ONE read-back of ``[max_sequences]`` tokens, whatever the number
-        of live sequences. ``tail`` (device int32 scalar) rides behind the
+        of live sequences. ``tail`` (device int32 scalars) rides behind the
         tokens in that read-back. Returns ``(tokens [len(uids)] int32 on the
-        host, the tail's value or None)``.
+        host, the tail's values or None)``.
 
         Row ``i``'s draw depends on ``rng``, ``i`` and its own logits alone,
         so rows held by DIFFERENT forwards (a caller driving ``put`` by hand)
@@ -1082,7 +1106,8 @@ class InferenceEngineV2:
         toks = np.zeros((len(uids),), np.int32)
         for (_array, _slots, places), got in zip(groups, outs):
             toks[places] = got[places]
-        return toks, (None if tail is None else int(outs[0][-1]))
+        return toks, (None if tail is None else tuple(
+            int(v) for v in outs[0][len(outs[0]) - len(tail):]))
 
     def flush(self, uids: Sequence[int]) -> None:
         """Release sequences and their KV blocks (reference ``flush:228``)."""

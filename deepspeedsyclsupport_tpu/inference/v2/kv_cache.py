@@ -25,14 +25,20 @@ from .config import RaggedInferenceConfig
 class MoeCounters(NamedTuple):
     """What the serving forwards of a sparse-expert model count about
     routing, on the device. ``load`` [L_moe, E] int32 (the expert layers:
-    leading dense layers have no row): the (token, choice) rows each
+    leading dense layers have no row; E the router's WHOLE width, whatever
+    part of the experts the program holds): the (token, choice) rows each
     layer's router gave each expert, summed over every forward since
     the engine was built (live rows only: ``load[l].sum() == k × live
     tokens``). ``touched`` scalar int32: of the LAST forward, the experts
-    with at least one live row, summed over layers — the expert weights that
-    forward had to read."""
+    HELD HERE with at least one live row, summed over layers — the expert
+    weights that forward had to read. ``rows`` scalar int32, only where the
+    program holds a share of the experts (None, no leaf, where it holds
+    them all: every live token then brings ``k`` rows a layer and the host
+    can count them): of the last forward, the (token, choice) rows that
+    went through the experts held here, summed over layers."""
     load: jnp.ndarray
     touched: jnp.ndarray
+    rows: Optional[jnp.ndarray] = None
 
 
 class BlockedKV(NamedTuple):
@@ -89,9 +95,11 @@ def init_blocked_kv(model_config, cfg: RaggedInferenceConfig,
                     out_shardings=sharding)
     moe = None
     if model_config.any_moe:
+        share = model_config.experts_held != model_config.num_experts
         moe = jax.jit(lambda: MoeCounters(
             jnp.zeros((model_config.num_moe_layers, model_config.num_experts),
-                      jnp.int32), jnp.zeros((), jnp.int32)),
+                      jnp.int32), jnp.zeros((), jnp.int32),
+            jnp.zeros((), jnp.int32) if share else None),
             out_shardings=topology.replicated())()
     return BlockedKV(zeros(), None if latent else zeros(), moe)
 

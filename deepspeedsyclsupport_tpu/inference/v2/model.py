@@ -91,7 +91,8 @@ def _mlp(p, y, cfg, live, experts=None):
     ``experts``: what :func:`_scan_layers` kept out of ``p``, ``(the stacked
     expert leaves [L_moe, E, ., .], this layer's index in them)``, read where
     they lie; a leaf still in ``p["moe"]`` is the layer's own slice.
-    Returns (out, the rows each expert was given [E] — None when dense)."""
+    Returns (out, the rows the router gave each expert [E] — None when
+    dense)."""
     if "moe" in p:    # by the tree: a leading dense layer of a sparse model
         from ...parallel.moe import moe_mlp_nodrop
 
@@ -552,7 +553,7 @@ def _experts_in_place(layers, dtype):
     return {**layers, "moe": rest}, stack
 
 
-def _scan_layers(layer, x, kv: BlockedKV, params):
+def _scan_layers(layer, x, kv: BlockedKV, params, held):
     """The layer loop of both serving forwards: the pool rides as CARRY
     beside ``x`` (never as the scan's xs/ys, which would slice it by layer
     and stack a second pool), the stacked params and the layer index as xs.
@@ -570,8 +571,10 @@ def _scan_layers(layer, x, kv: BlockedKV, params):
     attention stay in the xs: dense operands, whose slices fuse.
 
     ``layer(carry, p, l, experts)`` returns ``(carry, expert rows [E] or
-    None)``: a sparse-expert model's rows stack to [L_moe, E] and fold into
-    ``kv.moe``. Returns ``(x, the new BlockedKV)``."""
+    None)``: a sparse-expert model's rows stack to [L_moe, E], over the
+    router's whole width, and fold into ``kv.moe``; a program that holds a
+    share of the experts (``kv.moe.rows``) counts ``touched`` and ``rows``
+    over its own columns, ``held``. Returns ``(x, the new BlockedKV)``."""
     carry, first = (x, kv.pools), 0
     if "dense_layers" in params:
         dense = params["dense_layers"]
@@ -588,8 +591,13 @@ def _scan_layers(layer, x, kv: BlockedKV, params):
         body, carry, (layers, jnp.arange(first, kv.k.shape[0])))
     moe = kv.moe
     if rows is not None:
-        moe = MoeCounters(moe.load + rows,
-                          jnp.sum(rows > 0, dtype=jnp.int32))
+        load = moe.load + rows
+        if moe.rows is None:
+            moe = MoeCounters(load, jnp.sum(rows > 0, dtype=jnp.int32))
+        else:
+            rows = rows[:, held]
+            moe = MoeCounters(load, jnp.sum(rows > 0, dtype=jnp.int32),
+                              jnp.sum(rows, dtype=jnp.int32))
     return x, kv._replace(moe=moe, **dict(zip(("k", "v"), pools)))
 
 
@@ -654,7 +662,7 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
         x, rows = _block(cfg, p, x, attn_fn, ~pad, experts)
         return (x, pools), rows
 
-    x, kv = _scan_layers(layer, x, kv, params)
+    x, kv = _scan_layers(layer, x, kv, params, cfg.held_experts)
 
     x = _final_norm(params, x, cfg)
     h_last = x[last_tok_idx]  # [S, d] — logits_gather
@@ -727,7 +735,7 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
         x, rows = _block(cfg, p, x, attn_fn, active, experts)
         return (x, pools), rows
 
-    x, kv = _scan_layers(layer, x, kv, params)
+    x, kv = _scan_layers(layer, x, kv, params, cfg.held_experts)
     x = _final_norm(params, x, cfg)
     logits = _unembed(params, x, cfg)
     return logits.astype(jnp.float32), kv

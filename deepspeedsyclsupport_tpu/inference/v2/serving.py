@@ -43,8 +43,8 @@ from .kv_cache import kv_pool_stats
 from .scheduler import SlackPolicy, slack_of
 from ..sampling import SamplingParams, split_key
 from ...comm.watchdog import SERVE_HANG_EXIT_CODE, CollectiveWatchdog
-from ...monitor.reqtrace import (FORWARD_FIELDS, NO_PHASE, ROUND_PHASES,
-                                 check_phase)
+from ...monitor.reqtrace import (FORWARD_FIELDS, MOE_TAIL_FIELDS, NO_PHASE,
+                                 ROUND_PHASES, check_phase)
 from ...utils.fault_injection import get_fault_injector
 from ...utils.logging import logger
 
@@ -1010,12 +1010,13 @@ class ServingSession:
                 self._rng, sub = split_key(self._rng)
         if drained:
             # a sparse-expert model's last forward counted the experts it
-            # touched on the device: the scalar rides behind the tokens.
+            # touched on the device (and, holding a share of them, the rows
+            # it gave them): the scalars ride behind the tokens.
             # The engine times its own gather, sample and readback
-            toks, touched = eng.sample_drained(drained, sub, sp,
+            toks, counted = eng.sample_drained(drained, sub, sp,
                                                tail=eng.moe_tail())
-            if touched is not None and self._spans is not None:
-                self._spans.fields["moe_touched"] = touched
+            if counted is not None and self._spans is not None:
+                self._spans.fields.update(zip(MOE_TAIL_FIELDS, counted))
             t1 = self.clock()
             if self._last_decode_s is not None:
                 self.capacity.record_decode(1, t1 - self._last_decode_s)

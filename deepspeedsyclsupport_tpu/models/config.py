@@ -89,9 +89,24 @@ class ModelConfig:
     moe_intermediate_size: Optional[int] = None
     n_shared_experts: int = 0       # always-on experts beside the routed
     scoring_func: str = "softmax"   # softmax | sigmoid router scores
-    # "noaux_tc": a learned bias joins the scores for the top-k CHOICE only
+    # "noaux_tc": a learned bias joins the scores for the top-k CHOICE only;
+    # "group_limited_greedy" (DeepSeek-V2): the experts lie in n_group equal
+    # groups, a token keeps the topk_group groups whose best score is
+    # highest and takes its top-k among their experts alone
     topk_method: str = "greedy"
+    n_group: int = 1
+    topk_group: int = 1
     routed_scaling_factor: float = 1.0
+    # Expert parallelism's share of a layer: of the router's num_experts
+    # this program HOLDS num_experts_held (None: all), the contiguous ids
+    # from first_expert_held. It routes over all of them and computes its
+    # own experts' part of the result (parallel/moe.moe_mlp_nodrop).
+    # first_expert_held is the deployment's to say and 0 in every preset and
+    # cell: only tests/unit/test_expert_share.py and tests/benchmark/
+    # test_deepseek_v2.py set it, to add the four shares up to the uncut
+    # layer; it stays a field until an exchange across chips names a rank
+    num_experts_held: Optional[int] = None
+    first_expert_held: int = 0
     # manifold-constrained hyper-connections: hc_mult residual streams,
     # mixed by a Sinkhorn-normalised matrix in every sublayer (1 = the
     # plain x + f(norm(x)) stream)
@@ -123,6 +138,11 @@ class ModelConfig:
 
     # Initializer
     initializer_range: float = 0.02
+    # a routed expert's w_down as init_params SEEDS it beside shared experts,
+    # as a share of their rule (None: 1 / num_experts; a checkpoint
+    # overwrites it). It sets how much of a logit the routed experts carry
+    # when a seeded model is held against its reference: deepseek-v2 below
+    routed_write_share: Optional[float] = None
 
     def __post_init__(self):
         if self.num_kv_heads is None:
@@ -143,8 +163,24 @@ class ModelConfig:
             raise ValueError("shared_block_norm requires parallel_block")
         if self.scoring_func not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown scoring_func {self.scoring_func!r}")
-        if self.topk_method not in ("greedy", "noaux_tc"):
+        if self.topk_method not in ("greedy", "noaux_tc",
+                                    "group_limited_greedy"):
             raise ValueError(f"unknown topk_method {self.topk_method!r}")
+        if self.topk_method == "group_limited_greedy" and (
+                self.num_experts % self.n_group
+                or not 0 < self.topk_group <= self.n_group
+                or self.topk_group * (self.num_experts // self.n_group)
+                < self.num_experts_per_tok):
+            raise ValueError(
+                f"group_limited_greedy: {self.num_experts} experts in "
+                f"{self.n_group} groups, the best {self.topk_group} kept, "
+                f"cannot give {self.num_experts_per_tok} a token")
+        if not (0 <= self.first_expert_held and self.first_expert_held
+                + self.experts_held <= self.num_experts):
+            raise ValueError(
+                f"experts {self.first_expert_held} to "
+                f"{self.first_expert_held + self.experts_held} held of "
+                f"{self.num_experts}")
         if self.rope_scaling and self.rope_scaling.get("type") != "yarn":
             raise ValueError(f"unknown rope_scaling {self.rope_scaling!r}")
         if self.first_k_dense_replace and (
@@ -188,6 +224,19 @@ class ModelConfig:
             if self.kv_lora_rank else 0
 
     @property
+    def experts_held(self) -> int:
+        """Routed experts whose weights this program has: all the router's,
+        or the ``num_experts_held`` of an expert-parallel share."""
+        return self.num_experts if self.num_experts_held is None \
+            else self.num_experts_held
+
+    @property
+    def held_experts(self) -> slice:
+        """The experts held, as the columns of the router's width."""
+        return slice(self.first_expert_held,
+                     self.first_expert_held + self.experts_held)
+
+    @property
     def num_moe_layers(self) -> int:
         return self.num_layers - self.first_k_dense_replace \
             if self.any_moe else 0
@@ -229,7 +278,7 @@ class ModelConfig:
         mats = 3 if self.mlp_type == "glu" else 2
         dense = mats * d * f
         fe = self.moe_intermediate_size or f
-        moe = (mats * d * fe * (self.num_experts + self.n_shared_experts)
+        moe = (mats * d * fe * (self.experts_held + self.n_shared_experts)
                + d * self.num_experts)
         if self.qk_norm:
             attn += self.q_dim + self.kv_dim
@@ -347,6 +396,30 @@ PRESETS = {
         topk_method="noaux_tc", norm_topk_prob=True,
         routed_scaling_factor=2.0, hc_mult=4, hc_sinkhorn_iters=20,
         hc_eps=1e-6, mhc_h_res_clamp_min=-30.0, mhc_h_res_clamp_max=30.0),
+    # deepseek-ai/DeepSeek-V2 (model_type deepseek_v2, arXiv:2405.04434):
+    # latent attention at 128 heads, one leading dense layer, then 160
+    # routed experts in 8 groups, top-6 within the best 3 groups by softmax
+    # scores, not renormalised, x 16, beside two shared experts (one SwiGLU
+    # of twice the width). Serving only (inference/v2); a chip of an
+    # expert-parallel deployment overrides num_experts_held.
+    "deepseek-v2": _p(
+        vocab_size=102400, hidden_size=5120, intermediate_size=12288,
+        num_layers=60, num_heads=128, num_kv_heads=128, head_dim=192,
+        max_seq_len=163840, rms_norm_eps=1e-6, rope_theta=10000.0,
+        kv_lora_rank=512, q_lora_rank=1536, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128,
+        rope_scaling={"type": "yarn", "factor": 40, "beta_fast": 32,
+                      "beta_slow": 1, "mscale": 0.707,
+                      "mscale_all_dim": 0.707,
+                      "original_max_position_embeddings": 4096},
+        num_experts=160, num_experts_per_tok=6, moe_intermediate_size=1536,
+        first_k_dense_replace=1, n_shared_experts=2, scoring_func="softmax",
+        topk_method="group_limited_greedy", n_group=8, topk_group=3,
+        norm_topk_prob=False, routed_scaling_factor=16.0,
+        # by a sweep on the v5e (PERF.md section 6, PR 33): at 1/25 a router
+        # near-tie that bf16 breaks the other way moves a row of logits by
+        # up to ~0.07 logit-std, the routed experts left out by 0.15-0.4
+        routed_write_share=0.04),
 }
 
 

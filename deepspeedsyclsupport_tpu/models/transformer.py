@@ -56,10 +56,12 @@ class CausalLM:
 
         def down_scale(fan_in):
             """Std of a projection back into the stream: GPT-2's depth
-            scaling, or, where hyper-connection maps gate every write
-            themselves (``H_post``), by fan-in, so that a sublayer writes at
-            about the embedding's size."""
-            if cfg.hc_mult > 1:
+            scaling, a rule of training; the latent-attention families,
+            which only serve, draw it by fan-in, so that each sublayer
+            writes at about the embedding's size and a comparison of logits
+            weighs attention, MLP and embedding alike (where hyper-
+            connection maps gate every write themselves, ``H_post`` ~ 1)."""
+            if cfg.kv_lora_rank:
                 return std / np.sqrt(fan_in)
             return std / np.sqrt(2 * cfg.num_layers)
 
@@ -142,16 +144,21 @@ class CausalLM:
                 p["hc_attn"] = hc_params(next(ks))
                 p["hc_mlp"] = hc_params(next(ks))
             if moe:
-                e = cfg.num_experts
+                # the router over all the experts, the matrices of those
+                # held here (all, unless this is an expert-parallel share)
+                e, held = cfg.num_experts, cfg.experts_held
                 fe = cfg.moe_intermediate_size or f
+                # beside a shared expert the routed ones share the write:
+                # each is drawn at 1 / E of the shared one's size, or at
+                # the share the preset gives
+                down = down_scale(fe) / (e if cfg.n_shared_experts else 1)
+                if cfg.routed_write_share is not None:
+                    down = down_scale(fe) * cfg.routed_write_share
                 p["moe"] = {
                     "router": dense((d, e), next(ks)),
-                    "w_gate": dense((e, d, fe), next(ks)),
-                    "w_up": dense((e, d, fe), next(ks)),
-                    # beside a shared expert the routed ones share the
-                    # write: each is drawn at 1 / E of the shared one's size
-                    "w_down": dense((e, fe, d), next(ks), scale=down_scale(
-                        fe) / (e if cfg.n_shared_experts else 1)),
+                    "w_gate": dense((held, d, fe), next(ks)),
+                    "w_up": dense((held, d, fe), next(ks)),
+                    "w_down": dense((held, fe, d), next(ks), scale=down),
                 }
                 if cfg.topk_method == "noaux_tc":
                     # the selection-only bias
@@ -261,10 +268,13 @@ class CausalLM:
                  ) -> Tuple[jnp.ndarray, Optional[KVCache], jnp.ndarray]:
         """Returns (logits [B,S,V] fp32, new_cache, total_aux_loss)."""
         cfg = self.config
-        if cfg.kv_lora_rank or cfg.hc_mult > 1 or cfg.first_k_dense_replace:
+        if cfg.kv_lora_rank or cfg.hc_mult > 1 or cfg.first_k_dense_replace \
+                or cfg.experts_held != cfg.num_experts \
+                or cfg.topk_method == "group_limited_greedy":
             raise NotImplementedError(
-                "latent attention, hyper-connection streams and leading "
-                "dense layers run on the serving path only "
+                "latent attention, hyper-connection streams, leading dense "
+                "layers, group-limited routing and a share of the experts "
+                "run on the serving path only "
                 "(inference/v2/model.py): their training forward and "
                 "backward are not written")
         b, s = input_ids.shape

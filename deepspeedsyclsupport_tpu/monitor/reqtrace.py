@@ -113,6 +113,14 @@ ROUND_PHASES = (
 FORWARD_FIELDS = ("n_seqs", "tokens", "prefill_tokens", "ctx_tokens",
                   "kv_blocks", "decode_rows", "atoms", "attn_pairs",
                   "dec_ctx_tokens", "moe_touched")
+#: What the device counts, in the order it rides behind the sampled tokens
+#: (``engine.moe_tail``). ``moe_rows`` is on the record ONLY of a program
+#: that holds a share of the router's experts (one chip of an expert-
+#: parallel layer): the (token, choice) rows that went through the experts
+#: held here, summed over layers; ``moe_touched`` then counts the held
+#: experts with a row. A program that holds every expert leaves it out: each
+#: live token brings ``num_experts_per_tok`` rows a layer, as a reader knows.
+MOE_TAIL_FIELDS = ("moe_touched", "moe_rows")
 
 #: what a phase is where nothing times the round: ``trace_stages`` off, or
 #: an engine driven without a session
@@ -500,9 +508,11 @@ def round_phases(streams: Iterable[Tuple[str, str, Sequence[Dict[str, Any]]]]
         own = [rec["data"] for rec in records
                if rec.get("name") == "serve/stage"
                and (rec.get("data") or {}).get("stage") == "round"]
-        # moe_touched reaches the host a round late (FORWARD_FIELDS): give
-        # each record the count of the forward it launched, its successor's
-        rounds += [{**d, "moe_touched": nxt.get("moe_touched", 0)}
+        # what the device counts reaches the host a round late
+        # (MOE_TAIL_FIELDS): give each record the counts of the forward it
+        # launched, its successor's
+        rounds += [{**d, **{f: nxt.get(f, 0) for f in MOE_TAIL_FIELDS
+                            if f in d or f in nxt}}
                    for d, nxt in zip(own, own[1:] + [{}])]
     if not rounds:
         return None
@@ -512,6 +522,10 @@ def round_phases(streams: Iterable[Tuple[str, str, Sequence[Dict[str, Any]]]]
                 "p99": _rank_quantile(vals, 0.99),
                 "mean_s": sum(vals) / len(vals)}
 
+    # a field only some programs write (moe_rows) is reported where written
+    fields = FORWARD_FIELDS + tuple(
+        f for f in MOE_TAIL_FIELDS
+        if f not in FORWARD_FIELDS and any(f in d for d in rounds))
     by_program: Dict[str, List[Dict[str, Any]]] = {}
     for d in rounds:
         by_program.setdefault(d.get("program") or "(nothing launched)",
@@ -522,8 +536,8 @@ def round_phases(streams: Iterable[Tuple[str, str, Sequence[Dict[str, Any]]]]
             "round_s": _summary([d["t1"] - d["t0"] for d in rounds]),
             "launch_s": _summary(launched) if launched else None,
             "programs": {name: {"rounds": len(ds), **{
-                f: sum(d.get(f, 0) for d in ds) / len(ds)
-                for f in FORWARD_FIELDS}} for name, ds in by_program.items()},
+                f: sum(d.get(f, 0) for d in ds) / len(ds) for f in fields}}
+                for name, ds in by_program.items()},
             "phases": {p: _summary([float((d.get("phases") or {}).get(p, 0.0))
                                     for d in rounds])
                        for p in ROUND_PHASES}}

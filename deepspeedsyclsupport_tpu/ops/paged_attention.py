@@ -12,7 +12,8 @@ length (``inference/v2/ragged.build_ragged_batch``):
   ``ragged_forward`` the decoding sequences and a one-token prompt) is a
   tile of one row: :func:`paged_decode_attention`, one grid program per
   sequence slot, the custom call a profile names ``paged_decode``;
-* a chunk of two tokens or more is cut into ATOMS of ``atom_q_size`` (128)
+* a chunk of two tokens or more is cut into ATOMS of ``atom_q_size`` (128,
+  fewer under many heads of one kv head: :func:`default_atom_rows`)
   rows: :func:`ragged_prefill_attention`, one grid program per atom,
   ``ragged_prefill`` in a profile. A 128-row tile costs the MXU and the
   mask/exp over ``[KVH, 128·G, block]`` whether one of its rows is live or
@@ -278,6 +279,31 @@ def _head_tile(bq: int, h: int, kvh: int, d: int, block_size: int,
             bq, ht, kvh, d, block_size, itemsize) > _HEAD_TILE_BUDGET):
         ht //= 2
     return ht
+
+
+def default_atom_rows(bq: int, h: int, kvh: int, d: int, block_size: int,
+                      itemsize: int) -> int:
+    """Rows of an atom nobody chose (``RaggedInferenceConfig.atom_q_size``
+    None), by the SHAPE: ``bq`` where the heads of a single kv head take at
+    most two grid steps at that height (:func:`_head_tile`); where they
+    would take more, the tallest halving at which ONE step takes them all,
+    and never under 16 rows (a bf16 tile's sublanes). A grid step's tile,
+    rows x heads, stays what the budget allows; every head tile reads the
+    same KV blocks again and moves its slice of q and of the result by
+    strided DMA, and the forward gathers q into ``[atoms, rows, H, d]`` for
+    ``max_sequences`` atoms whether live or not. On the v5e, 128 heads x
+    640 over a 768-row chunk: 9.17 ms a layer at 128 rows (eight tiles of
+    16 heads, a gather of 1.49 GB at 64 sequences), 6.30 at 32 (two of 64,
+    0.47 GB), 5.83 at 16 (one, 0.30 GB). 32 heads x 640 keep the 128 rows
+    and two tiles of 16 they were accepted with."""
+    def tiles(rows):
+        return h // _head_tile(rows, h, kvh, d, block_size, itemsize)
+
+    if tiles(bq) <= 2:
+        return bq
+    while bq >= 32 and bq % 2 == 0 and tiles(bq) > 1:
+        bq //= 2
+    return bq
 
 
 def _ragged_vmem_need(bq: int, h: int, kvh: int, d: int, block_size: int,

@@ -27,13 +27,27 @@ from ..models.layers import constrain, glu_mlp
 
 def topk_weights(probs: jnp.ndarray, k: int, normalise: bool,
                  select_bias: Optional[jnp.ndarray] = None,
-                 scale: float = 1.0) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                 scale: float = 1.0,
+                 groups: Optional[Tuple[int, int]] = None
+                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The ONE top-k weighting of both MoE paths: the ``k`` largest router
     scores of each token [T, k] and their experts [T, k]; divided by their
     sum where the model says so (``cfg.norm_topk_prob``: mixtral does, OLMoE
     combines with the raw softmax mass). ``select_bias`` [E] (``noaux_tc``)
     joins the scores for the CHOICE only: the weights are the chosen
-    experts' unbiased scores. ``scale``: ``cfg.routed_scaling_factor``."""
+    experts' unbiased scores. ``scale``: ``cfg.routed_scaling_factor``.
+    ``groups`` ``(n_group, topk_group)`` (``group_limited_greedy``): the
+    experts lie in ``n_group`` equal runs of ids; a token keeps the
+    ``topk_group`` groups whose BEST score is highest, the scores of every
+    other group are set to 0, and the ``k`` are taken of what is left (ties
+    go to the lower id, among groups as among experts)."""
+    if groups is not None:
+        n_group, topk_group = groups
+        t, e = probs.shape
+        best = probs.reshape(t, n_group, e // n_group).max(-1)      # [T, G]
+        _, kept = jax.lax.top_k(best, topk_group)
+        kept = jax.nn.one_hot(kept, n_group, dtype=jnp.bool_).any(1)
+        probs = jnp.where(jnp.repeat(kept, e // n_group, axis=1), probs, 0.0)
     if select_bias is None:
         gate_w, expert_idx = jax.lax.top_k(probs, k)
     else:
@@ -179,26 +193,36 @@ def moe_mlp_nodrop(p: Dict[str, Any], x: jnp.ndarray, cfg,
     budget, pads included. A row that is not live gets NO expert: it sorts
     behind the last group, outside ``group_sizes``, and its output is zero.
 
+    The router is ``cfg.num_experts`` wide; the expert leaves hold
+    ``cfg.experts_held`` of them, ids ``cfg.first_expert_held`` onward (all
+    of them unless the program is one chip's share of an expert-parallel
+    layer). A (token, choice) row routed to an expert that is not here gets
+    no expert, exactly as a dead row: what the absent experts would have
+    added is left out, and the partial sum is the layer's result. On one
+    chip there is no exchange, and nothing stands in for the other chips.
+
     A model with shared experts (``p["shared"]``) adds their SwiGLU of every
     row beside the routed sum.
 
-    ``p["w_gate" | "w_up" | "w_down"]`` are one layer's ``[E, ., .]`` or the
-    whole stack ``[L, E, ., .]`` with ``layer`` (static or traced) the layer
-    to read: on the TPU ``ragged_dot`` is a custom call, which takes whole
-    buffers, so a slice ``w[layer]`` handed to it is first COPIED out of the
-    stack (three matrices a layer, 1.56 x the GEMMs' own time in OLMoE's
-    decode step). The serving layer loop therefore hands the stack whole
-    (``inference/v2/model.py:_scan_layers``) and :func:`_layer_groups` places
-    the rows on the layer's experts. Every other leaf of ``p`` is the
+    ``p["w_gate" | "w_up" | "w_down"]`` are one layer's ``[E_held, ., .]`` or
+    the whole stack ``[L, E_held, ., .]`` with ``layer`` (static or traced)
+    the layer to read: on the TPU ``ragged_dot`` is a custom call, which
+    takes whole buffers, so a slice ``w[layer]`` handed to it is first COPIED
+    out of the stack (three matrices a layer, 1.56 x the GEMMs' own time in
+    OLMoE's decode step). The serving layer loop therefore hands the stack
+    whole (``inference/v2/model.py:_scan_layers``) and :func:`_layer_groups`
+    places the rows on the layer's experts. Every other leaf of ``p`` is the
     layer's own.
 
-    x: [T, D] flat tokens → (out [T, D], group_sizes [E] int32: the (token,
-    choice) rows each expert was given, ``sum == k × live rows``).
+    x: [T, D] flat tokens → (out [T, D], routed [E] int32: the (token,
+    choice) rows the router gave each of ITS experts, here or not, ``sum ==
+    k × live rows``).
     """
     from ..monitor.mfu import scope
 
     t, d = x.shape
     e, k = cfg.num_experts, cfg.num_experts_per_tok
+    held, first = cfg.experts_held, cfg.first_expert_held
     with scope("moe_route"):
         logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
                             p["router"].astype(jnp.float32))
@@ -206,18 +230,28 @@ def moe_mlp_nodrop(p: Dict[str, Any], x: jnp.ndarray, cfg,
         gate_w, expert_idx = topk_weights(
             router_scores(logits, cfg), k, cfg.norm_topk_prob,
             None if bias is None else bias.astype(jnp.float32),
-            cfg.routed_scaling_factor)
+            cfg.routed_scaling_factor,
+            (cfg.n_group, cfg.topk_group)
+            if cfg.topk_method == "group_limited_greedy" else None)
 
         flat_expert = expert_idx.reshape(t * k)
+        has_expert = None      # [T*k] bool: the rows that get an expert
         if live is not None:
             # expert id E = "none": sorts last and is in no group
-            live_rows = jnp.repeat(live, k)
-            flat_expert = jnp.where(live_rows, flat_expert, e)
+            has_expert = jnp.repeat(live, k)
+            flat_expert = jnp.where(has_expert, flat_expert, e)
+        here = flat_expert
+        if held != e:
+            # the id among the experts held; ``held`` = "none here"
+            here = flat_expert - first
+            has_expert = (here >= 0) & (here < held)
+            here = jnp.where(has_expert, here, held)
         flat_tok = jnp.repeat(jnp.arange(t), k)
-        order = jnp.argsort(flat_expert, stable=True)         # moe_scatter
+        order = jnp.argsort(here, stable=True)                # moe_scatter
         sorted_tok = flat_tok[order]
         xs = x[sorted_tok]                                    # [T*k, D]
-        group_sizes = jnp.bincount(flat_expert, length=e).astype(jnp.int32)
+        routed = jnp.bincount(flat_expert, length=e).astype(jnp.int32)
+        group_sizes = routed if held == e else routed[cfg.held_experts]
 
     act = jax.nn.silu if cfg.activation == "silu" else jax.nn.gelu
     with scope("moe_experts"):
@@ -231,12 +265,12 @@ def moe_mlp_nodrop(p: Dict[str, Any], x: jnp.ndarray, cfg,
 
     with scope("moe_combine"):
         ys = ys * gate_w.reshape(t * k)[order].astype(x.dtype)[:, None]
-        if live is not None:
+        if has_expert is not None:
             # what ragged_dot leaves in rows past the last group is its own
-            # business: a pad row contributes an exact zero
-            ys = jnp.where(live_rows[order][:, None], ys, 0)
+            # business: a row with no expert here contributes an exact zero
+            ys = jnp.where(has_expert[order][:, None], ys, 0)
         out = jnp.zeros((t, d), x.dtype).at[sorted_tok].add(ys)  # moe_gather
     if "shared" in p:
         with scope("moe_shared"):
             out = out + glu_mlp(p["shared"], x[None], cfg)[0]
-    return out, group_sizes
+    return out, routed

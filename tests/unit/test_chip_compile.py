@@ -157,19 +157,37 @@ def test_ragged_prefill_compiles_with_default_atom(one_chip, name, form):
     _ragged_default_atom(one_chip, name, form)
 
 
-# the latent pool of xing4-docs-sat: [6, 6400 x 64, 640] bf16 rows, the value
-# their leading 512 lanes, ONE kv head under 32 query heads of 640 (absorbed)
-LATENT = dict(layers=6, slots=6400 * 64, row=640, v_dim=512, heads=32,
-              block_size=64, max_context=16384, max_sequences=16,
-              max_tokens=768, atom=128)
+# the latent pools of the two cells that have one: [layers, blocks x 64, 640]
+# bf16 rows, the value their leading 512 lanes, ONE kv head under the query
+# heads of 640 (absorbed). xing4-docs-sat: 32 heads, 128-row atoms;
+# dsv2-answers-sat: 128 heads, 16-row atoms, 64 sequences
+LATENT = {
+    "xing4": dict(layers=6, slots=6400 * 64, row=640, v_dim=512, heads=32,
+                  block_size=64, max_context=16384, max_sequences=16,
+                  max_tokens=768, atom=128, head_tile=16),
+    "dsv2": dict(layers=5, slots=7680 * 64, row=640, v_dim=512, heads=128,
+                 block_size=64, max_context=8192, max_sequences=64,
+                 max_tokens=768, atom=16, head_tile=128)}
 
 
 @pytest.mark.parametrize("kernel", ["ragged_prefill", "paged_decode"])
-def test_latent_kernels_compile_at_the_cells_widths(one_chip, kernel):
+@pytest.mark.parametrize("cell", sorted(LATENT))
+def test_latent_kernels_compile_at_the_cells_widths(one_chip, kernel, cell):
     """Refused while the pool had a head axis of one (tiled up to two in
     HBM: "Slice shape along dimension 2 must be aligned to tiling (2)"); the
-    128-row atom of 32 x 640 runs as two head tiles of 16."""
-    g = LATENT
+    128-row atom of 32 x 640 runs as two head tiles of 16, the 16-row atom
+    of 128 x 640 as one of 128 (the same tile), and a 128-row atom of 128 x
+    640 would run as eight of 16."""
+    from deepspeedsyclsupport_tpu.ops.paged_attention import (
+        _head_tile, default_atom_rows)
+
+    g = LATENT[cell]
+    # the atom is the engine's own choice in both cells
+    assert default_atom_rows(128, g["heads"], 1, g["row"], g["block_size"],
+                             2) == g["atom"]
+    assert _head_tile(g["atom"], g["heads"], 1, g["row"], g["block_size"],
+                      2) == g["head_tile"]
+    assert _head_tile(128, 128, 1, 640, 64, 2) == 16
     pool = ((g["layers"], g["slots"], g["row"]), jnp.bfloat16)
     bps = g["max_context"] // g["block_size"]
     if kernel == "ragged_prefill":

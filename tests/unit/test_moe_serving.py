@@ -172,8 +172,8 @@ def test_touched_rides_behind_the_sampled_tokens(tiny_moe):
     cfg = eng.model.config
     key = jax.random.PRNGKey(0)
     plain, none = eng.sample_drained([2, 1], key, SamplingParams())
-    toks, touched = eng.sample_drained([2, 1], key, SamplingParams(),
-                                       tail=eng.moe_tail())
+    toks, (touched,) = eng.sample_drained([2, 1], key, SamplingParams(),
+                                          tail=eng.moe_tail())
     assert none is None and toks.tolist() == plain.tolist()
     assert toks.shape == (2,)
     assert touched == int(eng.kv.moe.touched)
