@@ -10,7 +10,9 @@ host scheduler picks chunks → ``RaggedBatch`` metadata built and shipped →
 ONE jitted ragged forward (QKV+RoPE+paged-append, blocked attention, MLP,
 logits gather) → each drained sequence's descriptor gets a handle to its row
 of the forward's ``[max_sequences, V]`` logits, which :meth:`sample_drained`
-samples in one more launch.
+samples in one more launch. A serving round launches that sampler, then the
+NEXT forward with its decode tokens still on the device
+(:class:`SampledTokens`), and only then reads the tokens back.
 """
 import dataclasses
 import time
@@ -25,7 +27,8 @@ from .config import RaggedInferenceConfig
 from .kv_cache import init_blocked_kv
 from .model import build_ragged_forward_fn
 from .ragged import (BlockedAllocator, LogitsRef, SequenceDescriptor,
-                     attention_work, build_ragged_batch)
+                     attention_work, build_ragged_batch, device_token,
+                     split_device_tokens)
 from .scheduler import schedule_chunks
 from ..params import place_inference_params
 from ..sampling import SamplingParams, sample_token_dyn, split_key
@@ -52,6 +55,29 @@ def _sample_rows(logits, slots, rng, temperature, top_p, structure,
                             top_p, structure)
     return toks if tail is None else jnp.concatenate(
         [toks, *(t[None] for t in tail)])
+
+
+class SampledTokens:
+    """One launch of the sampler whose tokens the host has not read:
+    ``array`` ([max_sequences] int32 and the tail behind it, on the device)
+    holds ``uids[i]``'s token in row ``i``, and its copy to the host is
+    under way. From :meth:`InferenceEngineV2.sample_launch` until
+    :meth:`InferenceEngineV2.read_sampled` the VALUES belong to the device:
+    the host may hand a sequence's token on by reference (:meth:`ref`, into
+    ``put(..., sampled=this)``: the forward selects the value itself), and
+    may decide nothing that depends on it. ``taken`` collects the uids whose
+    reference a forward ate. One at a time: read it before the next
+    launch."""
+    __slots__ = ("array", "uids", "rows", "n_tail", "taken")
+
+    def __init__(self, array: jax.Array, uids: Sequence[int], n_tail: int):
+        self.array, self.uids, self.n_tail = array, list(uids), n_tail
+        self.rows = {uid: i for i, uid in enumerate(self.uids)}
+        self.taken: set = set()
+
+    def ref(self, uid: int) -> int:
+        """``uid``'s token as ``put`` takes it before it is read."""
+        return device_token(self.rows[uid])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -171,6 +197,11 @@ class InferenceEngineV2:
         # operands (sweeping them reuses one compiled sampler)
         self._sample_fn = jax.jit(_sample_rows, static_argnums=(5,))
         self._gather_fn = jax.jit(_gather_rows)
+        # the forwards' ``sampled`` operand where every token is the host's
+        # (_host_tokens_only), placed as the sampler places its output
+        self._no_sampled = None
+        self._sampled_sharding = jax.sharding.NamedSharding(
+            self.topology.mesh, jax.sharding.PartitionSpec())
         # live tokens the forwards were given, counted here on the host: what
         # a sparse-expert model's device counters are held against
         # (moe_stats: load[l].sum() == k x this)
@@ -354,7 +385,9 @@ class InferenceEngineV2:
         placement, so each program's SECOND call in that state is the one
         that compiles the steady-state variant — without this, the first
         real requests pay two spurious recompiles (measured ~1.7s each on
-        the CPU sim; worse on TPU). ``fused_ladder=True`` additionally
+        the CPU sim; worse on TPU). A decode step here takes its token from
+        the device as a serving round's does: the sampler is launched, the
+        forward eats its output, then it is read. ``fused_ladder=True`` additionally
         compiles EVERY fused-decode rung {K/2, ..., 2}, not just K — a
         timed window must not pay a mid-run compile when a short tail
         first selects a smaller rung (off by default: tests and callers
@@ -363,23 +396,28 @@ class InferenceEngineV2:
         uid = -(1 << 40) - 1   # reserved: below any sane caller uid
         # leave room for the 4 follow-up tokens within max_context
         n = max(2, min(cfg.max_tokens_per_batch - 1, cfg.max_context - 4, 8))
-        steps = ([[1] * n],                    # prefill, state A
-                 [[2]],                        # decode path, state A
-                 [[2, 2]],                     # prefill path, state B
-                 [[2]])                        # decode path, state B
+        steps = ([1] * n,                      # prefill, state A
+                 None,                         # decode path, state A
+                 [2, 2],                       # prefill path, state B
+                 None)                         # decode path, state B
+        # a round's other two programs: the key's split, and the greedy
+        # sampler over the forward's whole logits with the tail a serving
+        # session gives it
+        _, key = split_key(jax.random.PRNGKey(0))
         for toks in steps:
-            out = self.put([uid], toks)
+            sampled = None
+            if toks is None:     # a decode step: the token the sampler drew
+                sampled = self.sample_launch([uid], key, SamplingParams(),
+                                             tail=self.moe_tail())
+                toks = [sampled.ref(uid)]
+            out = self.put([uid], [toks], sampled=sampled)
+            if sampled is not None:
+                self.read_sampled(sampled)
             if uid not in out and out.admission.rejected:
                 self.flush([uid])
                 raise RuntimeError(
                     f"warmup could not admit its sequence — call warmup() "
                     f"on an idle engine ({dict(out.admission.reasons)})")
-        # a round's other two programs: the key's split, and the greedy
-        # sampler over the forward's whole logits with the tail a serving
-        # session gives it
-        _, key = split_key(jax.random.PRNGKey(0))
-        self.sample_drained([uid], key, SamplingParams(),
-                            tail=self.moe_tail())
         if cfg.decode_steps_per_dispatch > 1:
             # compile the fused K-step steady-state program too, for
             # generate()'s default greedy/no-eos config (non-default sampling
@@ -498,7 +536,8 @@ class InferenceEngineV2:
     # -------------------------------------------------------------------- put
     def put(self, uids: Sequence[int],
             tokens_list: Sequence[Sequence[int]],
-            strict: bool = False, drain: bool = True) -> "PutResult":
+            strict: bool = False, drain: bool = True,
+            sampled: Optional[SampledTokens] = None) -> "PutResult":
         """Enqueue tokens and run ragged forwards over what fits.
 
         Returns a :class:`PutResult`: {uid: last-token logits [V]} for
@@ -510,6 +549,15 @@ class InferenceEngineV2:
         at most ONE scheduler pass + forward (the granularity an external
         serving loop — or a TTFT benchmark — drives the engine at); the
         default drains every pending token before returning.
+
+        ``sampled``: a sampler launch that has not been read
+        (:meth:`sample_launch`). A uid among its ``uids`` may then be given
+        ``[sampled.ref(uid)]`` in place of its decode token: the forward
+        launched here takes the value from the device, and
+        :meth:`read_sampled` gives it to the host afterwards (to ``pending``
+        if no forward ate the reference, to the prefix cache's ``history``
+        if one did). A caller that has the values passes them and no
+        ``sampled``, and nothing is taken from the device.
 
         With a prefix cache installed, each FRESH uid's prompt is probed at
         admission: matched block-aligned prefix blocks are mapped (shared)
@@ -536,14 +584,19 @@ class InferenceEngineV2:
                         self._ensure_writable(d, n)
             if not chunks:
                 break
-            logits = self._run(chunks)
+            logits = self._run(chunks, sampled)
             with self._phase("collect"):
                 self._tick += 1
                 served_s = time.perf_counter()  # aging base for slack order
                 for slot, (d, n) in enumerate(chunks):
                     d.last_scheduled = self._tick
                     d.last_service_s = served_s
-                    if self.prefix_cache is not None:
+                    if d.pending[0] < 0:
+                        # its value is the device's: history gets it at the
+                        # read-back, and no block it completes is indexed
+                        # before (_commit_prefix counts history)
+                        sampled.taken.add(d.uid)
+                    elif self.prefix_cache is not None:
                         d.history.extend(int(t) for t in d.pending[:n])
                     del d.pending[:n]
                     d.n_cached += n
@@ -773,14 +826,38 @@ class InferenceEngineV2:
         d.last_scheduled = -1
         return d
 
-    def _run(self, chunks) -> jax.Array:
+    def _host_tokens_only(self) -> jax.Array:
+        """The ``sampled`` operand of a forward whose every token is the
+        host's (``take_from`` all -1: never read): zeros of the sampler's
+        shape, placed as the sampler places its output, so that the forward
+        is ONE compiled program whoever drives it."""
+        if self._no_sampled is None:
+            self._no_sampled = jax.device_put(
+                np.zeros((self.config.max_sequences
+                          + len(self.moe_tail() or ()),), np.int32),
+                self._sampled_sharding)
+        return self._no_sampled
+
+    def _token_operands(self, tokens: np.ndarray,
+                        sampled: Optional[SampledTokens]):
+        """``(tokens, sampled, take_from)`` as the forwards take them."""
+        tokens, take_from = split_device_tokens(tokens)
+        if sampled is None and take_from.max(initial=-1) >= 0:
+            raise ValueError("a token still on the device was put without "
+                             "the sampler launch that holds it (sampled=)")
+        return (jnp.asarray(tokens),
+                self._host_tokens_only() if sampled is None
+                else sampled.array, jnp.asarray(take_from))
+
+    def _run(self, chunks, sampled: Optional[SampledTokens] = None
+             ) -> jax.Array:
         """One forward over ``chunks``; its whole ``[max_sequences, V]``
         logits, on the device, row ``slot`` being chunk ``slot``'s: put()
-        hands out handles and sample_drained gathers by slot, nothing is
+        hands out handles and the sampler gathers by slot, nothing is
         cut out here."""
         cfg = self.config
         if all(n == 1 and d.n_cached > 0 for d, n in chunks):
-            return self._run_decode(chunks)  # kernel fast path
+            return self._run_decode(chunks, sampled)  # kernel fast path
         with self._phase("build"):
             batch = build_ragged_batch(
                 chunks, cfg.max_tokens_per_batch, cfg.max_sequences,
@@ -788,13 +865,19 @@ class InferenceEngineV2:
                 atom_q=cfg.atom_q_size if self._use_atoms else None)
             self._note_forward(*zip(*chunks), atoms=batch.live_atoms)
         with self._phase("dispatch"):
+            tokens, sampled, take_from = self._token_operands(batch.tokens,
+                                                              sampled)
+            # an attention that takes no atoms leaves their seven places
+            # empty: the token operands keep theirs behind them
+            tiles = batch.tile_args or (None,) * 7
             logits, self.kv = self._dispatch(
                 "ragged_forward", self._forward,
-                self.params, self.kv, jnp.asarray(batch.tokens),
+                self.params, self.kv, tokens,
                 jnp.asarray(batch.token_seq), jnp.asarray(batch.token_pos),
                 jnp.asarray(batch.block_tables),
                 jnp.asarray(batch.last_tok_idx),
-                *map(jnp.asarray, batch.tile_args))
+                *(a if a is None else jnp.asarray(a) for a in tiles),
+                sampled, take_from)
         return logits
 
     def _slot_arrays(self, descs):
@@ -812,7 +895,8 @@ class InferenceEngineV2:
             active[slot] = True
         return positions, tables, active
 
-    def _run_decode(self, chunks) -> jax.Array:
+    def _run_decode(self, chunks, sampled: Optional[SampledTokens] = None
+                    ) -> jax.Array:
         """Pure-decode batches (serving's steady state) route through the
         Pallas paged-attention program (``ops/paged_attention``)."""
         from .model import build_decode_forward_fn
@@ -829,11 +913,13 @@ class InferenceEngineV2:
                 tokens[slot] = d.pending[0]
             self._note_forward(*zip(*chunks))
         with self._phase("dispatch"):
+            tokens, sampled, take_from = self._token_operands(tokens,
+                                                              sampled)
             logits, self.kv = self._dispatch(
                 "decode_forward", self._decode_forward,
-                self.params, self.kv, jnp.asarray(tokens),
+                self.params, self.kv, tokens,
                 jnp.asarray(positions), jnp.asarray(tables),
-                jnp.asarray(active))
+                jnp.asarray(active), sampled, take_from)
         return logits
 
     def _decode_multi_dispatch(self, running: Dict[int, int],
@@ -1076,38 +1162,78 @@ class InferenceEngineV2:
         return (moe.touched,) if moe.rows is None else (moe.touched,
                                                          moe.rows)
 
-    def sample_drained(self, uids: Sequence[int], rng: jax.Array,
-                       sampling: SamplingParams,
-                       tail: Optional[Tuple[jax.Array, ...]] = None
-                       ) -> Tuple[np.ndarray, Optional[Tuple[int, ...]]]:
+    def sample_launch(self, uids: Sequence[int], rng: jax.Array,
+                      sampling: SamplingParams,
+                      tail: Optional[Tuple[jax.Array, ...]] = None
+                      ) -> SampledTokens:
         """One token for each of ``uids`` (all :meth:`has_logits`), sampled
         on the device from the forward's whole logits: ONE launch of one
-        fixed-shape program (gather the rows by slot, ``sample_token_dyn``)
-        and ONE read-back of ``[max_sequences]`` tokens, whatever the number
-        of live sequences. ``tail`` (device int32 scalars) rides behind the
-        tokens in that read-back. Returns ``(tokens [len(uids)] int32 on the
-        host, the tail's values or None)``.
+        fixed-shape program (gather the rows by slot, ``sample_token_dyn``),
+        whatever the number of live sequences, and NO read-back: the tokens
+        stay on the device (:class:`SampledTokens` says who owns them) with
+        their copy to the host started, ordered before whatever is launched
+        next. ``tail`` (device int32 scalars) rides behind the tokens in
+        that copy. :meth:`read_sampled` waits for it.
 
         Row ``i``'s draw depends on ``rng``, ``i`` and its own logits alone,
         so rows held by DIFFERENT forwards (a caller driving ``put`` by hand)
-        are sampled a launch per forward with the same key, and give what
-        one launch would."""
+        are sampled a launch per forward with the same key, laid into one
+        array, and give what one launch would."""
         with self._phase("gather"):
             groups = self._logit_groups(uids)
             temperature = np.float32(sampling.temperature)
             top_p = np.float32(sampling.top_p)
         with self._phase("sample"):
-            outs = [self._sample_fn(array, slots, rng, temperature, top_p,
-                                    sampling.structure, tail)
-                    for array, slots, _places in groups]
-            self.host_dispatches += len(outs)  # a sampler is a dispatch too
+            out = None
+            for array, slots, places in groups:
+                got = self._sample_fn(array, slots, rng, temperature, top_p,
+                                      sampling.structure, tail)
+                self.host_dispatches += 1  # a sampler is a dispatch too
+                if out is None:
+                    out = got
+                else:
+                    mine = np.zeros(got.shape, bool)
+                    mine[places] = True
+                    out = jnp.where(mine, got, out)
+            out.copy_to_host_async()
+            if out.sharding != self._sampled_sharding:
+                self._sampled_sharding, self._no_sampled = out.sharding, None
+        return SampledTokens(out, uids, len(tail or ()))
+
+    def read_sampled(self, sampled: SampledTokens
+                     ) -> Tuple[np.ndarray, Optional[Tuple[int, ...]]]:
+        """Wait for ``sampled``'s tokens: ONE read-back of ``[max_sequences]``
+        tokens and the tail behind them. Returns ``(tokens [len(uids)]
+        int32 on the host, the tail's values or None)``. From here the host
+        owns the values: a reference that was put and that no forward ate
+        becomes its value in ``pending``; one that a forward ate goes, with
+        a prefix cache installed, to the sequence's ``history``, and the
+        blocks it completes are indexed now."""
         with self._phase("readback"):
-            outs = [np.asarray(o) for o in outs]
-        toks = np.zeros((len(uids),), np.int32)
-        for (_array, _slots, places), got in zip(groups, outs):
-            toks[places] = got[places]
-        return toks, (None if tail is None else tuple(
-            int(v) for v in outs[0][len(outs[0]) - len(tail):]))
+            got = np.asarray(sampled.array)
+        toks = got[:len(sampled.uids)]
+        for uid, tok in zip(sampled.uids, toks):
+            d = self.seqs.get(uid)
+            if d is None:
+                continue
+            if d.pending and d.pending[0] < 0:
+                d.pending[0] = int(tok)
+            elif uid in sampled.taken and self.prefix_cache is not None:
+                d.history.append(int(tok))
+                self._commit_prefix(d)
+        return toks, (tuple(int(v) for v in got[len(got) - sampled.n_tail:])
+                      if sampled.n_tail else None)
+
+    def sample_drained(self, uids: Sequence[int], rng: jax.Array,
+                       sampling: SamplingParams,
+                       tail: Optional[Tuple[jax.Array, ...]] = None
+                       ) -> Tuple[np.ndarray, Optional[Tuple[int, ...]]]:
+        """:meth:`sample_launch` and :meth:`read_sampled` at once, for a
+        caller that wants the token values before it puts them (``generate``,
+        a loop that drives ``put`` by hand): nothing stays on the device,
+        and its forwards take every token from the host."""
+        return self.read_sampled(self.sample_launch(uids, rng, sampling,
+                                                    tail))
 
     def flush(self, uids: Sequence[int]) -> None:
         """Release sequences and their KV blocks (reference ``flush:228``)."""

@@ -601,11 +601,26 @@ def _scan_layers(layer, x, kv: BlockedKV, params, held):
     return x, kv._replace(moe=moe, **dict(zip(("k", "v"), pools)))
 
 
+def _tokens_in(tokens, sampled, take_from):
+    """The token ids a forward embeds. ``take_from[i] >= 0`` says that row
+    ``i``'s token is row ``take_from[i]`` of ``sampled``, the sampler's
+    output over the LAST forward's logits, which the host has launched and
+    not read (``engine_v2.SampledTokens``); every other row's is
+    ``tokens[i]``, which the host wrote. Selected here, inside the forward:
+    the host need not know a decode token to launch the forward that eats
+    it. Without ``sampled`` every token is the host's."""
+    if sampled is None:
+        return tokens
+    return jnp.where(take_from >= 0, sampled[jnp.maximum(take_from, 0)],
+                     tokens)
+
+
 def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
                    token_pos, block_tables, last_tok_idx,
                    atom_qidx=None, atom_pos0=None, atom_qlen=None,
                    atom_tables=None, atom_inv=None, dec_row=None,
-                   dec_len=None, *, block_size: int, attn_impl: str = "auto"
+                   dec_len=None, sampled=None, take_from=None, *,
+                   block_size: int, attn_impl: str = "auto"
                    ) -> Tuple[jnp.ndarray, BlockedKV]:
     """Flat-token forward. Returns (per-slot last-token logits [S, V], new kv).
 
@@ -613,6 +628,7 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
     ``lax.scan`` here exactly as in training (``models/transformer.py``).
     ``atom_*`` and ``dec_*`` are ``RaggedBatch.tile_args``, what the
     ``kernel`` attention takes (the others route by ``token_seq`` alone).
+    ``sampled`` / ``take_from`` [T]: :func:`_tokens_in`.
     """
     cfg = model.config
     assert cfg.scan_layers, "ragged engine requires scan_layers param layout"
@@ -628,7 +644,8 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
                               token_pos // bs]
     dest = jnp.where(pad, num_slots, dest_block * bs + token_pos % bs)
 
-    x = _embed(params, tokens, token_pos, cfg)
+    x = _embed(params, _tokens_in(tokens, sampled, take_from), token_pos,
+               cfg)
 
     def layer(carry, p, l, experts):
         x, pools = carry
@@ -691,8 +708,9 @@ def build_ragged_forward_fn(model, block_size: int, attn_impl: str = "auto"):
 
 # ------------------------------------------------------------ decode fast path
 def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
-                   block_tables, active, *, block_size: int,
-                   attn_impl: str = "auto") -> Tuple[jnp.ndarray, BlockedKV]:
+                   block_tables, active, sampled=None, take_from=None, *,
+                   block_size: int, attn_impl: str = "auto"
+                   ) -> Tuple[jnp.ndarray, BlockedKV]:
     """All-decode forward: ONE token per slot, attention via the Pallas paged
     decode kernel (``ops/paged_attention`` — the ``blocked_flash`` analog).
 
@@ -700,6 +718,7 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
     cached (the new token writes slot ``positions[s]``). This is the program
     serving spends most of its life in, so it gets the kernel; mixed
     prefill+decode batches take :func:`ragged_forward`.
+    ``sampled`` / ``take_from`` [S]: :func:`_tokens_in`.
     """
     cfg = model.config
     bs = block_size
@@ -712,7 +731,8 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
     dest = jnp.where(active, dest_block * bs + positions % bs, num_slots)
     seq_lens = jnp.where(active, positions + 1, 0)
 
-    x = _embed(params, tokens, positions, cfg)
+    x = _embed(params, _tokens_in(tokens, sampled, take_from), positions,
+               cfg)
 
     def layer(carry, p, l, experts):
         x, pools = carry

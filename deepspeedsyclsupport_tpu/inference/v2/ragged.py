@@ -147,12 +147,32 @@ class LogitsRef(NamedTuple):
     slot: int
 
 
+def device_token(row: int) -> int:
+    """What stands in ``SequenceDescriptor.pending`` for a token whose VALUE
+    is still on the device: row ``row`` of a sampler output that was
+    launched and not read back (``engine_v2.SampledTokens``). Negative, as
+    no token id is; a forward that eats it selects the value on the device
+    (:func:`split_device_tokens`), and the read-back writes the value over
+    whatever reference no forward ate."""
+    return -(row + 1)
+
+
+def split_device_tokens(tokens: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+    """A forward's token vector as its two operands: the host's tokens with
+    0 where a :func:`device_token` stood, and ``take_from``, that
+    reference's row of the sampler's output (-1: the host's token)."""
+    on_device = tokens < 0
+    return (np.where(on_device, 0, tokens).astype(np.int32),
+            np.where(on_device, -tokens - 1, -1).astype(np.int32))
+
+
 @dataclass(eq=False)  # identity semantics: descriptors live in scheduler sets
 class SequenceDescriptor:
     """Per-sequence serving state (reference ``DSSequenceDescriptor``)."""
 
     uid: int
     pending: List[int] = field(default_factory=list)  # tokens awaiting forward
+    #                             (a lone negative one: device_token)
     n_cached: int = 0                                 # tokens with KV in cache
     blocks: List[int] = field(default_factory=list)   # owned KV block ids
     last_logits: Optional[LogitsRef] = None           # set when pending drains

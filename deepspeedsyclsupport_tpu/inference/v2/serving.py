@@ -317,6 +317,16 @@ class ServingSession:
     per-token dispatch, KV-pressure eviction — and returns the round's
     :class:`ServeEvent` stream. The caller owns pacing (when to call
     ``step``) and delivery; the session owns policy.
+
+    A per-token round launches the sampler over the last forward's logits,
+    then the NEXT forward, and only then reads the sampled tokens back
+    (:meth:`_per_token_round`). In between the token values belong to the
+    DEVICE (``engine_v2.SampledTokens``): the session hands them to ``put``
+    by reference, keeps ``_pending_tok`` references for what was refused,
+    and writes the values over them at the read-back. Nothing stays unread
+    when ``step()`` returns: token N and a ``finish`` are returned by the
+    ``step()`` after forward N's launch, as a round that read first returned
+    them, and at most ONE forward is in flight ahead of a read-back.
     """
 
     def __init__(self, engine, policy: Optional[ServingPolicyConfig] = None,
@@ -342,7 +352,11 @@ class ServingSession:
         #: crash-replay recovery accounting (``Serve/recovery.*`` family)
         self.recovery_counters: Dict[str, int] = {"replays": 0,
                                                   "replay_sheds": 0}
-        self._pending_tok: Dict[int, int] = {}  # sampled, not yet submitted
+        # sampled, not yet submitted: the token, or inside a round a
+        # reference to it while the device still has it (ragged.device_token)
+        self._pending_tok: Dict[int, int] = {}
+        self._launched_ahead = 0   # forwards launched before a read-back
+        self._spec_rows = 0        # rows launched for a stream that had ended
         self._last_decode_s: Optional[float] = None
         self._round = 0            # scheduling rounds (watchdog step label)
         self._tokens_emitted = 0   # serve_crash fault trigger input
@@ -998,57 +1012,45 @@ class ServingSession:
 
     # ------------------------------------------------------ per-token round
     def _per_token_round(self, now: float, events: List[ServeEvent]) -> None:
+        """Sample over the last forward's logits, launch the NEXT forward,
+        and only then read the sampled tokens back: the device goes from
+        the sampler straight into the forward while the host waits for the
+        copy, emits, and closes what ended. Between the sampler's launch and
+        its read-back the token VALUES are the device's
+        (``engine_v2.SampledTokens``): the forward takes them by reference,
+        and what the round decides before the read-back (who decodes on, who
+        is evicted, which prompt chunk rides along) rests on what the host
+        knew before the last forward ended. A budget or the context's end is
+        known; an EOS is not, and is learnt one forward late."""
         eng = self.eng
-        sp = self.sampling
         # 1. one device sample over every drained stream, from the last
-        # forward's whole logits: one launch and one read-back at any count
+        # forward's whole logits: one launch at any count, nothing read yet
         with self._phase("gather"):
             drained = [uid for uid in self.running
                        if uid not in self._pending_tok
                        and eng.has_logits(uid)]
             if drained:
                 self._rng, sub = split_key(self._rng)
+        sampled = None
+        ending: Dict[int, str] = {}
+        reqs = {uid: self.running[uid] for uid in drained}
         if drained:
             # a sparse-expert model's last forward counted the experts it
             # touched on the device (and, holding a share of them, the rows
-            # it gave them): the scalars ride behind the tokens.
-            # The engine times its own gather, sample and readback
-            toks, counted = eng.sample_drained(drained, sub, sp,
-                                               tail=eng.moe_tail())
-            if counted is not None and self._spans is not None:
-                self._spans.fields.update(zip(MOE_TAIL_FIELDS, counted))
-            t1 = self.clock()
-            if self._last_decode_s is not None:
-                self.capacity.record_decode(1, t1 - self._last_decode_s)
-            self._last_decode_s = t1
-            if self._spans is not None:
-                self._spans.fields["uids"] = sorted(drained)
-            with self._phase("emit"):
-                for uid, tok in zip(drained, toks):
-                    tok = int(tok)
-                    req = self.running[uid]
-                    events.append(ServeEvent("token", uid, t1, tokens=[tok]))
-                    self._note_emission(req, [tok], t1)
-                    req.budget -= 1
-                    d = eng.seqs[uid]
-                    d.emitted += 1
-                    done = (req.budget <= 0
-                            or (self.eos_token_id is not None
-                                and tok == self.eos_token_id)
-                            or d.n_cached >= eng.config.max_context)
-                    if done:
-                        reason = ("eos" if (self.eos_token_id is not None
-                                            and tok == self.eos_token_id)
-                                  else ("done" if req.budget <= 0
-                                        else "context"))
-                        self._finish(uid, t1, events, reason)
-                    else:
-                        self._pending_tok[uid] = tok
+            # it gave them): the scalars ride behind the tokens, and are
+            # read from the pool the NEXT dispatch replaces, so the sampler
+            # goes first. The engine times its own gather and sample
+            sampled = eng.sample_launch(drained, sub, self.sampling,
+                                        tail=eng.moe_tail())
+            with self._phase("schedule"):
+                ending = self._plan_drained(reqs, sampled, now)
         else:
             self._last_decode_s = None  # no decode this round: break the
             #                             ITL chain across prefill-only gaps
         # 2. KV pressure: preempt the lowest-slack stream until the decode
-        # tokens fit (never stall the whole batch on an exhausted pool)
+        # tokens fit (never stall the whole batch on an exhausted pool).
+        # Said after the tokens are: a victim's last token comes first
+        evicted: List[_Request] = []
         put_uids = list(self._pending_tok)
         with self._phase("schedule"):
             while put_uids:
@@ -1059,22 +1061,68 @@ class ServingSession:
                 victim = self._eviction_victim(now)
                 if victim is None:
                     break
-                self._evict(victim, now, events)
+                evicted.append(self._preempt(victim))
                 put_uids = [u for u in put_uids if u != victim]
             submit = bool(put_uids) or any(
                 d.pending for d in eng.seqs.values())
-        # 3. submit: decode tokens + (slack-ordered, tenant-capped) prompt
-        # chunks fuse into the same forward inside put(), which splits its
-        # own time into schedule, build, dispatch and collect
-        if not submit:
-            return
+        # 3. submit: decode tokens (by reference where the device still has
+        # them) + (slack-ordered, tenant-capped) prompt chunks fuse into the
+        # same forward inside put(), which splits its own time into
+        # schedule, build, dispatch and collect
+        if submit:
+            self._submit(put_uids, sampled, reqs)
+        # 4. the tokens: the host blocks HERE, behind a forward that runs
+        if sampled is not None:
+            self._emit_sampled(sampled, reqs, ending, evicted, events)
+        if evicted:
+            with self._phase("emit"):
+                for req in evicted:
+                    self._say_evicted(req, now, events)
+
+    def _plan_drained(self, reqs: Dict[int, _Request], sampled,
+                      now: float) -> Dict[int, str]:
+        """What the host knows of each sampled stream before it has the
+        token: one token more is out, and a stream whose budget or context
+        that token ends closes with it. Those are released now and get no
+        row in the next forward (``{uid: reason}``; said at the read-back);
+        every other stream's token is pending by reference."""
+        eng = self.eng
+        ending: Dict[int, str] = {}
+        for uid, req in reqs.items():
+            d = eng.seqs[uid]
+            req.budget -= 1
+            d.emitted += 1
+            if d.first_token_s is None:
+                d.first_token_s = now  # for this round's slack order; the
+                #                        read-back stamps the true instant
+            if req.budget <= 0:
+                ending[uid] = "done"
+            elif d.n_cached >= eng.config.max_context:
+                ending[uid] = "context"
+            else:
+                self._pending_tok[uid] = sampled.ref(uid)
+                continue
+            eng.flush([uid])
+        return ending
+
+    def _submit(self, put_uids: List[int], sampled,
+                reqs: Dict[int, _Request]) -> None:
+        """Launch the round's forward over ``put_uids``' decode tokens and
+        whatever prompt chunks the scheduler adds."""
+        eng = self.eng
         pend0 = ({u: len(d.pending) for u, d in eng.seqs.items()
                   if d.pending} if self._tracing else {})
+        dispatches0 = eng.host_dispatches
         res = eng.put(put_uids, [[self._pending_tok[u]] for u in put_uids],
-                      drain=False)
+                      drain=False, sampled=sampled)
         with self._phase("account"):
             for uid in res.admission.admitted:
                 self._pending_tok.pop(uid, None)
+            if sampled is not None and eng.host_dispatches > dispatches0:
+                # a forward is under way and the last one's tokens are unread
+                self._launched_ahead += 1
+                if self._spans is not None:
+                    self._spans.fields["ahead"] = 1
             t1 = self.clock()
             # prefill-chunk edges: which uids advanced their prompt this
             # forward and by how many tokens. No duration: put() returns
@@ -1097,12 +1145,58 @@ class ServingSession:
             # how many streams share the budget; gating on it admits far
             # past capacity and every admitted stream goes borderline-miss
             # (measured: 25-client shed 80%→28%, goodput 76→9 tok/s).
-            # (a uid drained this round has first_token_s set by
-            # _note_emission, so only freshly-landed prefills sample here)
+            # (a uid sampled this round is about to get its first token at
+            # the read-back, so only freshly-landed prefills sample here)
             for uid, req in self.running.items():
-                if req.first_token_s is None and eng.has_logits(uid):
+                if req.first_token_s is None and uid not in reqs \
+                        and eng.has_logits(uid):
                     self.capacity.record_prefill(len(req.tokens),
                                                  t1 - req.enqueue_s)
+
+    def _emit_sampled(self, sampled, reqs: Dict[int, _Request],
+                      ending: Dict[int, str], evicted: List[_Request],
+                      events: List[ServeEvent]) -> None:
+        """Read the sampled tokens back, hand them out and close what
+        ended. A stream that ended on an EOS has, by now, a row in the
+        forward that runs: a speculative row, whose logits nobody samples
+        and whose KV lands in blocks released here (programs run in order
+        on the device, and a block's next owner writes a position before it
+        reads it). If it was preempted meanwhile (``evicted``) it is closed,
+        not requeued."""
+        eng = self.eng
+        # the engine times the readback and gives the values to whatever
+        # reference no forward ate
+        toks, counted = eng.read_sampled(sampled)
+        if counted is not None and self._spans is not None:
+            self._spans.fields.update(zip(MOE_TAIL_FIELDS, counted))
+        t1 = self.clock()
+        if self._last_decode_s is not None:
+            self.capacity.record_decode(1, t1 - self._last_decode_s)
+        self._last_decode_s = t1
+        if self._spans is not None:
+            self._spans.fields["uids"] = sorted(reqs)
+        spec_rows = 0
+        with self._phase("emit"):
+            for (uid, req), tok in zip(reqs.items(), toks):
+                tok = int(tok)
+                events.append(ServeEvent("token", uid, t1, tokens=[tok]))
+                self._note_emission(req, [tok], t1)
+                eos = self.eos_token_id is not None \
+                    and tok == self.eos_token_id
+                spec_rows += eos and uid in sampled.taken
+                if eos and req in evicted:
+                    evicted.remove(req)
+                    self.running[uid] = req
+                if eos or uid in ending:
+                    self._finish(uid, t1, events,
+                                 "eos" if eos else ending[uid])
+                elif uid in self._pending_tok:
+                    self._pending_tok[uid] = tok   # put was refused: the
+                    #                                value, for the next
+        if spec_rows:
+            self._spec_rows += spec_rows
+            if self._spans is not None:
+                self._spans.fields["spec_rows"] = spec_rows
 
     def _exclusive_blocks(self, uid: int) -> int:
         """Blocks only ``uid`` holds (refcount 1): preempting it frees
@@ -1131,9 +1225,19 @@ class ServingSession:
             -self.eng.seqs[u].n_cached))
 
     def _evict(self, uid: int, now: float, events: List[ServeEvent]) -> None:
+        self._say_evicted(self._preempt(uid), now, events)
+
+    def _preempt(self, uid: int) -> _Request:
+        """Take ``uid`` off the engine (blocks and slot freed) and out of
+        the running set; :meth:`_say_evicted` tells the world."""
         req = self.running.pop(uid)
         self._pending_tok.pop(uid, None)
         self.eng.preempt(uid)
+        return req
+
+    def _say_evicted(self, req: _Request, now: float,
+                     events: List[ServeEvent]) -> None:
+        uid = req.uid
         self._count("evicted")
         requeue = self.policy.preempt_policy == "requeue"
         self._stage(uid, "preempt", now,
@@ -1279,6 +1383,8 @@ class ServingSession:
                "queue_depth": len(self.queue),
                "live_seqs": len(self.running),
                "trace_dropped": self.trace_dropped,
+               "launched_ahead": self._launched_ahead,
+               "speculative_rows": self._spec_rows,
                "kv_occupancy": round(self._kv_occupancy(), 4),
                "prefill_tok_s_est": round(self.capacity.prefill_tok_s, 1),
                "decode_step_s_est": round(self.capacity.decode_step_s, 5)}

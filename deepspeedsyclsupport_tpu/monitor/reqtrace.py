@@ -70,13 +70,18 @@ FLEET_STAGES = STAMPED_FLEET_STAGES + DERIVED_FLEET_STAGES
 STAGE_HISTOGRAMS = ("prefill", "decode")
 
 #: Phases of one scheduling round (``ServingSession.step``), in the order a
-#: per-token round passes them. The session's ``RoundSpans`` charges every
+#: per-token round passes them: it launches the sampler over the LAST
+#: forward's logits, plans and launches the NEXT forward (whose decode rows
+#: take their tokens from the sampler's output on the device), and only then
+#: reads the sampled tokens back and hands them out. The session's
+#: ``RoundSpans`` charges every
 #: instant of the round to exactly one of them (``other`` is the residual),
 #: opens a ``dstpu/serve/<phase>`` profiler annotation around each, and
 #: writes their seconds into the ``round`` stage record's ``phases``. A
 #: phase is time on the HOST's clock, not host work alone: ``readback``
 #: asks the device for a value (the tokens the sampler drew behind the last
-#: forward: it holds what is left of that forward's time), and ANY launch
+#: forward: it holds what is left of that forward's time, while the forward
+#: this round launched already waits behind it on the device), and ANY launch
 #: blocks while the device's launch queue is full. A round launches three
 #: programs (the key's split, the sampler, the forward), so the queue does
 #: not fill. What the host costs the device is read off a profile: the idle
@@ -84,13 +89,13 @@ STAGE_HISTOGRAMS = ("prefill", "decode")
 ROUND_PHASES = (
     "queue",      # _maintain_queue, slack policy, watchdog arm
     "gather",     # who has drained (host), rng split, the [S] slot vector
-    "sample",     # the sampler's dispatch
-    "readback",   # np.asarray(tokens): the round asks the device for a value
-    "emit",       # events, _note_emission, _finish/flush
-    "schedule",   # KV-pressure loop, check_schedule, schedule_chunks, CoW
+    "sample",     # the sampler's dispatch and the start of its copy to the host
+    "schedule",   # budgets that end, KV-pressure loop, check_schedule, schedule_chunks, CoW
     "build",      # build_ragged_batch / _slot_arrays (numpy only)
     "dispatch",   # host-to-device copies + the forward's launch, until it returns
     "collect",    # put() after the launch: descriptors, logits handles, prefix index
+    "readback",   # np.asarray(tokens): the round asks the device for a value
+    "emit",       # events, _note_emission, _finish/flush, evictions said
     "account",    # prefill_chunk stamps, capacity samples, progress valve, gauges
     "other")      # whatever no phase claimed
 
@@ -110,9 +115,14 @@ ROUND_PHASES = (
 #: expert weights a forward had to read; 0 for a dense model). It comes back
 #: behind the sampled tokens, so a record carries the count of the forward
 #: whose logits its round SAMPLED: the launch of the record before it.
+#: ``ahead`` is 1 where the forward was dispatched BEFORE the last forward's
+#: sampled tokens were read back (every per-token round but the first after
+#: idle, which has nothing to read; 0 for a fused round), and ``spec_rows``
+#: counts its rows that belonged to a stream which had already ended: on an
+#: EOS, which the host learns at the read-back, one forward late.
 FORWARD_FIELDS = ("n_seqs", "tokens", "prefill_tokens", "ctx_tokens",
                   "kv_blocks", "decode_rows", "atoms", "attn_pairs",
-                  "dec_ctx_tokens", "moe_touched")
+                  "dec_ctx_tokens", "moe_touched", "ahead", "spec_rows")
 #: What the device counts, in the order it rides behind the sampled tokens
 #: (``engine.moe_tail``). ``moe_rows`` is on the record ONLY of a program
 #: that holds a share of the router's experts (one chip of an expert-

@@ -251,6 +251,52 @@ def test_decode_forward_reads_the_expert_stack_in_place(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < one_matrix
 
 
+# ------------------------------------- the forwards' tokens from the device
+@pytest.mark.parametrize("program", ["decode_forward", "ragged_forward"])
+def test_the_forwards_take_decode_tokens_from_the_sampler(one_chip, program):
+    """Both serving forwards at phi-2's widths (two layers) and the cell's
+    shapes, as the engine builds and calls them since a round launches the
+    next forward before it reads the sampled tokens back: ``sampled`` (the
+    sampler's ``[max_sequences]`` output, here with a tail of one behind it)
+    and ``take_from`` follow the other operands, and the select is part of
+    the forward: ONE program for the chip, the kernels still custom calls,
+    the sampler's output a live parameter of it."""
+    from deepspeedsyclsupport_tpu.inference.v2 import model as M
+    from deepspeedsyclsupport_tpu.inference.v2.kv_cache import BlockedKV
+    from deepspeedsyclsupport_tpu.models import build_model
+
+    model = build_model("phi-2", num_layers=2, dtype="bfloat16")
+    cfg = model.config
+    bs, blocks, seqs, toks, atom = 64, 150, 32, 768, 128
+    bps = cfg.max_seq_len // bs
+
+    def on_chip(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: on_chip(x.shape, jnp.bfloat16 if jnp.issubdtype(
+            x.dtype, jnp.floating) else x.dtype),
+        jax.eval_shape(model.init_params))
+    pool = on_chip((2, blocks * bs, cfg.num_kv_heads, 128), jnp.bfloat16)
+    kv = BlockedKV(pool, pool)
+    sampled = on_chip((seqs + 1,))
+    if program == "decode_forward":
+        fn = M.build_decode_forward_fn(model, bs, "pallas")
+        args = (on_chip((seqs,)), on_chip((seqs,)), on_chip((seqs, bps)),
+                on_chip((seqs,), jnp.bool_), sampled, on_chip((seqs,)))
+    else:
+        fn = M.build_ragged_forward_fn(model, bs, "kernel")
+        atoms = seqs + toks // atom + 1
+        args = (on_chip((toks,)), on_chip((toks,)), on_chip((toks,)),
+                on_chip((seqs, bps)), on_chip((seqs,)),
+                on_chip((atoms, atom)), on_chip((atoms,)), on_chip((atoms,)),
+                on_chip((atoms, bps)), on_chip((toks,)), on_chip((seqs,)),
+                on_chip((seqs,)), sampled, on_chip((toks,)))
+    text = fn.lower(params, kv, *args).compile().as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    assert f"s32[{seqs + 1}]" in text, "the sampler's output is not read"
+
+
 def test_paged_kernel_is_a_tpu_custom_call(one_chip):
     """The compiled text names the Mosaic kernel — the same string
     ``chip_smoke.py`` looks for in the programs it ran on the chip."""

@@ -320,10 +320,10 @@ class TestEvictionIdempotency:
         pending state, never through put()'s arguments)."""
         orig = eng._run
 
-        def spy(chunks):
+        def spy(chunks, *sampled):
             for d, n in chunks:
                 log.append((d.uid, list(d.pending[:n])))
-            return orig(chunks)
+            return orig(chunks, *sampled)
 
         eng._run = spy
         return eng

@@ -636,13 +636,16 @@ def requests_report(root: str, worst_n: int = 5, window_s: float = 60.0,
             if qs["mean_s"] > 0:
                 lines.append(f"    {phase:<14}mean={_fmt_s(qs['mean_s'])}  "
                              f"{_qline(qs)}")
-        # what each program's forward covered, a round's mean
+        # what each program's forward covered, a round's mean (ahead: the
+        # share of its launches made before the last forward's tokens were
+        # read back; spec-rows: rows launched for a stream an EOS had ended)
         # a program that holds a share of the experts counts their rows too
         share = any("moe_rows" in m for m in rp["programs"].values())
         lines.append(f"    {'launched':<20}{'rounds':>7}{'seqs':>8}"
                      f"{'tokens':>9}{'prompt':>9}{'context':>10}"
                      f"{'kv blocks':>11}{'1-row':>8}{'atoms':>8}"
                      f"{'pairs':>12}{'1-row-ctx':>11}{'experts':>9}"
+                     f"{'ahead':>7}{'spec-rows':>11}"
                      + (f"{'exp-rows':>10}" if share else ""))
         for name, m in sorted(rp["programs"].items()):
             lines.append(f"    {name:<20}{m['rounds']:>7}{m['n_seqs']:>8.1f}"
@@ -653,6 +656,8 @@ def requests_report(root: str, worst_n: int = 5, window_s: float = 60.0,
                          f"{m.get('attn_pairs', 0):>12.1f}"
                          f"{m.get('dec_ctx_tokens', 0):>11.1f}"
                          f"{m.get('moe_touched', 0):>9.1f}"
+                         f"{m.get('ahead', 0):>7.2f}"
+                         f"{m.get('spec_rows', 0):>11.2f}"
                          + (f"{m.get('moe_rows', 0):>10.1f}" if share
                             else ""))
     if att["cached_prefix_tokens_mean"]:
