@@ -8,10 +8,16 @@ labels, XLA propagates those labels into every compiled instruction's
 one timed event per executed HLO op named by instruction. This module owns
 the three joins between those worlds:
 
-* :func:`build_opmap` — compiled-HLO text → ``{instruction: {region,
+* :func:`build_opmap` — compiled-HLO text → ``{instruction: {region, pass,
   category}}`` (the named_scope metadata is read here; collectives override
   to the ``collective`` region by opcode, since the partitioner inserts
-  them with no scope).
+  them with no scope; the same ``op_name`` path says which PASS of the step
+  the instruction belongs to: forward, backward, remat's recomputed forward).
+* :func:`publish` / :func:`published` — the map of the step the engine
+  compiled, kept by program name (``train_batch_fn``) for whoever holds a
+  trace of that program: :func:`ledger`'s caller, ``tools/mfu_report.py``
+  through the persisted ``mfu_opmap.json``, and the benchmark's
+  ``train_*_ms`` readers.
 * :func:`parse_trace` — ``trace.json.gz`` (Chrome-trace) → timed op events,
   with truncation salvage: a torn gzip / half-written JSON from a killed
   run yields everything parseable plus a ``truncated`` flag, never a crash
@@ -43,6 +49,10 @@ from typing import Any, Dict, Iterable, List, Optional, Sequence, Tuple
 SCOPE_REGIONS = ("embed", "attn", "mlp", "head", "loss", "optimizer")
 DERIVED_REGIONS = ("collective", "other", "host")
 REGIONS = SCOPE_REGIONS + DERIVED_REGIONS
+#: the scope regions that are differentiated: their instructions run in one
+#: of :data:`PASSES` (``optimizer`` runs once, after all three)
+MODEL_REGIONS = SCOPE_REGIONS[:-1]
+PASSES = ("fwd", "bwd", "recompute")
 
 #: Named scopes INSIDE a region (:func:`scope`), for a reader that wants one
 #: piece of a layer: the compiled program's ``op_name`` metadata carries the
@@ -122,6 +132,29 @@ def region_of(op_name: str) -> Optional[str]:
     return name if name in SCOPE_REGIONS else None
 
 
+def pass_of(op_name: str, region: Optional[str]) -> Optional[str]:
+    """Pass of the training step an HLO ``metadata op_name`` path lies in,
+    by its ``/``-separated components: ``recompute`` where one is
+    ``rematted_computation`` (what ``jax.checkpoint`` names the forward it
+    runs again inside the backward), else ``bwd`` where one starts with
+    ``transpose(``, else ``fwd`` where ``region`` is a model region, else
+    ``None`` (the update, plumbing outside the differentiated function).
+
+    Matched on JAX 0.9.0: forward ``jit(f)/jvp(mfu.mlp)/dot_general`` or,
+    under a scanned trunk, ``jit(f)/jvp()/while/body/closed_call/mfu.mlp/..``;
+    backward ``jit(f)/transpose(jvp())/while/body/closed_call/checkpoint/
+    mfu.mlp/..``; the recomputed forward ``../checkpoint/
+    rematted_computation/mfu.mlp/..``; the update ``jit(f)/mfu.optimizer/..``.
+    The CPU's and the TPU's (libtpu 0.0.34) compiled text print the same
+    paths: the metadata is the lowering's, not the backend's."""
+    parts = (op_name or "").split("/")
+    if "rematted_computation" in parts:
+        return "recompute"
+    if any(p.startswith("transpose(") for p in parts):
+        return "bwd"
+    return "fwd" if region in MODEL_REGIONS else None
+
+
 def _category_of(opcode: str) -> str:
     for cat, ops in _CATEGORY:
         if opcode in ops:
@@ -130,31 +163,37 @@ def _category_of(opcode: str) -> str:
 
 
 # one HLO instruction definition: `  %name = type opcode(...), ...` or
-# `  ROOT %name = ...`. Names may carry dots/dashes (`dot.12`,
+# `  ROOT %name = ...`, the `%` there or not (as_text() prints it, a dump with
+# print_percent off does not). Names may carry dots/dashes (`dot.12`,
 # `subtract_exponential_fusion`); the result type may be a parenthesized
 # TUPLE with internal spaces — `(f32[8]{0}, s32[])` — which is exactly what
 # `while` loops and combined (variadic) all-reduces produce, i.e. the scan
 # trunk and the main grad-sync traffic this instrument exists to name. On
-# TPU the layouts inside the tuple carry one level of NESTED parens
-# (tiling annotations: `bf16[4096]{0:T(1024)}`), so the tuple branch must
-# admit them.
+# TPU the tuple nests (an `async-start` returns `((operands), result,
+# s32[])`) and its layouts carry parens of their own
+# (`bf16[4,2048]{1,0:T(8,128)(2,1)S(1)}`), so the tuple branch does not
+# count them: it ends at the first `)` that whitespace and `opcode(` follow,
+# and inside a type no `)` is followed by whitespace.
 _INSTR_RE = re.compile(
-    r"^\s*(?:ROOT\s+)?%([\w.\-]+)\s*=\s*"
-    r"(?:\((?:[^()]|\([^()]*\))*\)|[^\s]+)\s+"
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*"
+    r"(?:\(.*?\)|[^\s(]\S*)\s+"
     r"([a-z][\w\-]*)\(")
 _METADATA_RE = re.compile(r'metadata=\{[^}]*op_name="([^"]*)"')
 
 
-def build_opmap(hlo_text: str) -> Dict[str, Dict[str, str]]:
-    """Compiled-HLO text → ``{instruction_name: {"region", "category",
-    "opcode"}}`` for every instruction in every computation (trace events
-    are named by instruction; names are unique module-wide).
+def build_opmap(hlo_text: str) -> Dict[str, Dict[str, Any]]:
+    """Compiled-HLO text → ``{instruction_name: {"region", "pass",
+    "category", "opcode", "op_name"}}`` for every instruction in every
+    computation (trace events are named by instruction; names are unique
+    module-wide).
 
     Region precedence: collective opcode > ``mfu.<region>`` scope in the
-    op_name metadata > ``other``. Trivial bookkeeping opcodes (parameter/
+    op_name metadata > ``other``. ``pass`` is :func:`pass_of` of the same
+    path (a fusion's line carries its root's path, so a fusion is its
+    root's region and pass). Trivial bookkeeping opcodes (parameter/
     constant/tuple plumbing) are skipped — they never carry measured time.
     """
-    out: Dict[str, Dict[str, str]] = {}
+    out: Dict[str, Dict[str, Any]] = {}
     for line in hlo_text.splitlines():
         m = _INSTR_RE.match(line)
         if not m:
@@ -162,15 +201,39 @@ def build_opmap(hlo_text: str) -> Dict[str, Dict[str, str]]:
         name, opcode = m.group(1), m.group(2)
         if opcode in ("parameter", "constant", "tuple", "get-tuple-element"):
             continue
+        meta = _METADATA_RE.search(line)
+        path = meta.group(1) if meta else ""
         if opcode in COLLECTIVE_OPCODES:
             region = "collective"
         else:
-            meta = _METADATA_RE.search(line)
-            region = region_of(meta.group(1)) if meta else None
-            region = region or "other"
-        out[name] = {"region": region, "category": _category_of(opcode),
-                     "opcode": opcode}
+            region = region_of(path) or "other"
+        out[name] = {"region": region, "pass": pass_of(path, region),
+                     "category": _category_of(opcode), "opcode": opcode,
+                     "op_name": path}
     return out
+
+
+# the compiled step of each program name, as the engine last handed it over;
+# replaced by its opmap the first time somebody asks (as_text() and the parse
+# of mistral-7b-d2's step took 44 ms on the v5e: a run that never asks pays
+# nothing)
+_PUBLISHED: Dict[str, Any] = {}
+
+
+def publish(program: str, compiled: Any) -> None:
+    """Keep ``compiled`` (anything with ``as_text()``) as the LAST compiled
+    step of ``program`` — the name the profiler's ``XLA Modules`` line gives
+    it, less ``jit_``. ``Engine.compiled_train_step()`` calls this."""
+    _PUBLISHED[program] = compiled
+
+
+def published(program: str) -> Optional[Dict[str, Dict[str, Any]]]:
+    """:func:`build_opmap` of the last step published under ``program``;
+    ``None`` where nothing was."""
+    entry = _PUBLISHED.get(program)
+    if entry is not None and not isinstance(entry, dict):
+        entry = _PUBLISHED[program] = build_opmap(entry.as_text())
+    return entry
 
 
 # ------------------------------------------------------------------ trace IO
@@ -290,47 +353,46 @@ def _union_us(intervals: Iterable[Tuple[float, float]]) -> float:
 
 
 def _self_segments(events: List[Dict[str, Any]],
-                   opmap: Dict[str, Dict[str, str]]
-                   ) -> List[Tuple[float, float, str, str]]:
+                   opmap: Dict[str, Dict[str, Any]]
+                   ) -> List[Tuple[float, float, Dict[str, Any]]]:
     """Flatten one THREAD's (properly nested) op events into disjoint
-    ``(start, end, region, category)`` self-time segments: a ``while`` op's
+    ``(start, end, opmap entry)`` self-time segments: a ``while`` op's
     event covers its whole loop while every body op is ALSO recorded inside
     it — a plain duration sum double-counts that containment (observed
     1.7× on the CPU executor). Each event owns only the parts of its span
     not covered by a nested event."""
     es = sorted((e for e in events), key=lambda e: (e["ts"], -e["dur"]))
-    segs: List[Tuple[float, float, str, str]] = []
-    # stack entries: [end, cursor, region, category]; cursor = where this
-    # event's uncovered span resumes after the current child
+    segs: List[Tuple[float, float, Dict[str, Any]]] = []
+    # stack entries: [end, cursor, entry]; cursor = where this event's
+    # uncovered span resumes after the current child
     stack: List[List[Any]] = []
 
     def pop_to(ts: float) -> None:
         while stack and stack[-1][0] <= ts:
-            end, cursor, region, cat = stack.pop()
+            end, cursor, info = stack.pop()
             if end > cursor:
-                segs.append((cursor, end, region, cat))
+                segs.append((cursor, end, info))
             if stack:
                 stack[-1][1] = max(stack[-1][1], end)
 
     for e in es:
         ts = float(e["ts"])
         end = ts + float(e["dur"])
-        info = opmap[str(e["name"])]
         pop_to(ts)
         if stack and stack[-1][1] < ts:
             # parent's uncovered span up to this child
-            segs.append((stack[-1][1], ts, stack[-1][2], stack[-1][3]))
+            segs.append((stack[-1][1], ts, stack[-1][2]))
             stack[-1][1] = ts
-        stack.append([end, ts, info["region"], info["category"]])
+        stack.append([end, ts, opmap[str(e["name"])]])
     pop_to(float("inf"))
     return segs
 
 
 def measure_regions(events: Sequence[Dict[str, Any]],
-                    opmap: Dict[str, Dict[str, str]],
+                    opmap: Dict[str, Dict[str, Any]],
                     steps: int = 1) -> Dict[str, Any]:
-    """Join timed trace events against the opmap into per-region and
-    per-HLO-category seconds (per step).
+    """Join timed trace events against the opmap into per-region, per-pass
+    and per-HLO-category seconds (per step).
 
     Attribution is WALL-CLOCK-exact, not duration-sum: per thread, nested
     events flatten to self-time segments (:func:`_self_segments`); across
@@ -340,19 +402,24 @@ def measure_regions(events: Sequence[Dict[str, Any]],
     So ``sum(regions) == mapped-op union`` by construction, and the ledger
     reconciliation catches the one thing that can still go missing:
     op events whose name is NOT in the opmap (``orphan_s``) — exactly what
-    a typo'd/missing scope or a stale opmap produces.
+    a typo'd/missing scope or a stale opmap produces (the Chrome trace names
+    an op event by the bare instruction, on the CPU executor and on a TPU's
+    ``XLA Ops`` line alike; only the ``.xplane.pb`` carries the whole text).
+    ``passes`` is ``{region: {pass or "-": seconds}}``, each region's row
+    re-summing to its ``regions`` entry.
 
     ``device_busy_s`` is the union over ALL op events (an event counts as
-    an op when its name is in the opmap or it carries an ``hlo_op`` arg),
-    mapped or not."""
+    an op when its name is in the opmap or it carries the arg the
+    profiler gives an op: ``hlo_op`` on the CPU executor's events,
+    ``hlo_category`` on a TPU's ``XLA Ops`` line), mapped or not."""
     steps = max(1, int(steps))
     by_thread: Dict[Tuple[Any, Any], List[Dict[str, Any]]] = {}
     all_intervals: List[Tuple[float, float]] = []
     n_mapped = n_orphan = 0
     for e in events:
-        name = str(e.get("name", ""))
-        mapped = name in opmap
-        is_op = mapped or "hlo_op" in (e.get("args") or {})
+        mapped = str(e.get("name", "")) in opmap
+        args = e.get("args") or {}
+        is_op = mapped or "hlo_op" in args or "hlo_category" in args
         if not is_op:
             continue
         ts = float(e["ts"])
@@ -364,32 +431,34 @@ def measure_regions(events: Sequence[Dict[str, Any]],
         by_thread.setdefault((e.get("pid"), e.get("tid")), []).append(e)
 
     # per-thread disjoint self segments → global even-split sweep
-    threads = [
-        _self_segments(es, opmap) for es in by_thread.values()]
-    points: List[Tuple[float, int, int, str, str]] = []
-    for ti, segs in enumerate(threads):
-        for s, e, region, cat in segs:
-            points.append((s, 1, ti, region, cat))
-            points.append((e, -1, ti, region, cat))
+    points: List[Tuple[float, int, int, Dict[str, Any]]] = []
+    for ti, es in enumerate(by_thread.values()):
+        for s, e, info in _self_segments(es, opmap):
+            points.append((s, 1, ti, info))
+            points.append((e, -1, ti, info))
     # closes (-1) before opens (+1) at equal t: per-thread segments are
     # disjoint, so a segment ending exactly where the next begins must
     # release the thread slot before the successor claims it
     points.sort(key=lambda p: (p[0], p[1]))
     regions: Dict[str, float] = {}
     categories: Dict[str, float] = {}
-    active: Dict[int, Tuple[str, str]] = {}
+    passes: Dict[str, Dict[str, float]] = {}
+    active: Dict[int, Dict[str, Any]] = {}
     prev = None
     mapped_union = 0.0
-    for t, kind, ti, region, cat in points:
+    for t, kind, ti, info in points:
         if prev is not None and active and t > prev:
             share = (t - prev) / len(active)
             mapped_union += t - prev
-            for r, c in active.values():
+            for a in active.values():
+                r, c, p = a["region"], a["category"], a.get("pass") or "-"
                 regions[r] = regions.get(r, 0.0) + share
                 categories[c] = categories.get(c, 0.0) + share
+                row = passes.setdefault(r, {})
+                row[p] = row.get(p, 0.0) + share
         prev = t
         if kind == 1:
-            active[ti] = (region, cat)
+            active[ti] = info
         else:
             active.pop(ti, None)
 
@@ -397,6 +466,8 @@ def measure_regions(events: Sequence[Dict[str, Any]],
     return {
         "regions": {r: s / 1e6 / steps for r, s in regions.items()},
         "categories": {c: s / 1e6 / steps for c, s in categories.items()},
+        "passes": {r: {p: s / 1e6 / steps for p, s in row.items()}
+                   for r, row in passes.items()},
         "device_busy_s": union_all / 1e6 / steps,
         "mapped_union_s": mapped_union / 1e6 / steps,
         "orphan_s": max(0.0, union_all - mapped_union) / 1e6 / steps,
@@ -506,6 +577,9 @@ def ledger(roofline: Optional[Dict[str, Any]],
         "truncated_trace": bool(truncated_trace),
         "device": (roofline or {}).get("device"),
         "categories": dict(measured.get("categories", {})),
+        "passes": dict(measured.get("passes", {})),
+        "n_mapped": int(measured.get("n_mapped", 0)),
+        "n_unmapped": int(measured.get("n_unmapped", 0)),
     }
 
 
@@ -601,6 +675,14 @@ def render_ledger(led: Dict[str, Any], top: int = 10) -> str:
         if led.get("orphan_s"):
             lines.append(f"  orphaned op time (not in opmap): "
                          f"{_fmt_s(led['orphan_s'])}")
+    passes = led.get("passes", {})
+    if passes:
+        cols = PASSES + ("-",)
+        lines.append(f"  {'region x pass':<14}" + "".join(
+            f"{c:>11}" for c in cols))
+        for name in sorted(passes, key=lambda r: -sum(passes[r].values())):
+            lines.append(f"  {name:<14}" + "".join(
+                f"{_fmt_s(passes[name].get(c) or None):>11}" for c in cols))
     cats = led.get("categories", {})
     if cats:
         order = sorted(cats, key=lambda c: -cats[c])

@@ -1305,11 +1305,19 @@ class Engine:
         """The fused train step EXACTLY as the last ``train_batch`` ran it
         (``jax.stages.Compiled``), re-lowered from the avals captured at its
         call site — ``.as_text()`` shows the kernels and collectives that
-        ran, ``.memory_analysis()`` the bytes per device."""
+        ran, ``.memory_analysis()`` the bytes per device. The step is also
+        handed to ``monitor/mfu.py`` under its program name
+        (``train_batch_fn``), which reads every instruction's region and
+        pass off its text for whoever asks (``mfu.published``): this
+        engine's ``mfu_ledger()``, the benchmark's ``train_*_ms`` readers."""
         avals = getattr(self, "_last_train_avals", None)
         if self._train_batch_fn is None or avals is None:
             raise RuntimeError("run train_batch() first")
-        return self._train_batch_fn.lower(*avals).compile()
+        compiled = self._train_batch_fn.lower(*avals).compile()
+        from ..monitor import mfu as mfu_mod
+
+        mfu_mod.publish(self._train_batch_fn.__name__, compiled)
+        return compiled
 
     GRAPH_ANALYZERS = ("collectives", "donation", "resharding", "dtype")
 
@@ -1453,7 +1461,7 @@ class Engine:
                 "steps are not supported)")
         avals = self._last_train_avals
         compiled = self.compiled_train_step()
-        opmap = mfu_mod.build_opmap(compiled.as_text())
+        opmap = mfu_mod.published(self._train_batch_fn.__name__)
         costs = roofline.region_costs(
             jax.make_jaxpr(self._train_batch_raw)(*avals))
         census_bytes = sum(e["bytes"] * e["executions"]

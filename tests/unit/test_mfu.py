@@ -118,6 +118,41 @@ class TestOpmap:
         with pytest.raises(ValueError, match="undeclared MFU region"):
             mfu.region_scope("attnn")
 
+    @pytest.mark.parametrize("path,region,want", [
+        ("jit(f)/jvp(mfu.mlp)/dot_general", "mlp", "fwd"),
+        ("jit(f)/jvp()/while/body/closed_call/mfu.attn/exp", "attn", "fwd"),
+        ("jit(f)/transpose(jvp(mfu.head))/dot_general", "head", "bwd"),
+        ("jit(f)/transpose(jvp())/while/body/closed_call/checkpoint/"
+         "mfu.mlp/dot_general", "mlp", "bwd"),
+        ("jit(f)/transpose(jvp())/while/body/closed_call/checkpoint/"
+         "rematted_computation/mfu.mlp/tanh", "mlp", "recompute"),
+        # unscoped work inside the differentiated function keeps its pass
+        ("jit(f)/transpose(jvp())/while/body/closed_call/checkpoint/"
+         "rematted_computation/rms_norm/mul", "other", "recompute"),
+        ("jit(f)/transpose(jvp())/mul", "other", "bwd"),
+        # outside it there is none: the update, the loss scale, plumbing
+        ("jit(f)/mfu.optimizer/sub", "optimizer", None),
+        ("jit(f)/convert_element_type", "other", None),
+        ("", "other", None),
+        # a component that merely CONTAINS the words is not the component
+        ("jit(f)/jvp(mfu.mlp)/my_rematted_computation_probe/x", "mlp",
+         "fwd"),
+    ])
+    def test_pass_of_reads_the_path_components(self, path, region, want):
+        assert mfu.pass_of(path, region) == want
+
+    def test_opmap_carries_pass_and_path_with_or_without_percent(self):
+        om = mfu.build_opmap(_HLO)
+        assert om["dot.12"]["pass"] == "fwd"
+        assert om["dot.33"]["pass"] == "bwd"
+        assert om["norm.2"]["pass"] is None
+        assert om["all-gather.7"]["region"] == "collective"
+        assert om["dot.33"]["op_name"] == \
+            "jit(f)/transpose(jvp(mfu.mlp))/dot_general"
+        # a dump printed without the sigil (print_percent off; the text
+        # benchmark/scopes.py also accepts) maps the same
+        assert mfu.build_opmap(_HLO.replace("%", "")) == om
+
 
 # ===================================================================
 # trace parsing + salvage
@@ -226,6 +261,70 @@ class TestMeasureRegions:
         events = [self._ev("dot.1", 0, 10), self._ev("dot.1", 100, 10)]
         m = mfu.measure_regions(events, self.OPMAP, steps=2)
         assert m["regions"]["attn"] == pytest.approx(10e-6)
+
+    def test_a_tpu_op_the_map_lacks_is_an_orphan_not_nothing(self):
+        """A v5e's ``XLA Ops`` events carry ``hlo_category`` and
+        ``long_name``, no ``hlo_op`` (read off its Chrome trace, PR 35):
+        110 ``async-start`` instructions a parser bug kept out of the opmap
+        went uncounted there, ``n_unmapped`` 0, until the arg was read."""
+        tpu = {"ph": "X", "pid": 3, "tid": 3, "ts": 20.0, "dur": 5.0,
+               "name": "slice-start.48",
+               "args": {"hlo_category": "async-start",
+                        "long_name": "%slice-start.48 = ((f32[2]), f32[1], "
+                                     "s32[]) async-start(f32[2] %p)"}}
+        module = {"ph": "X", "pid": 3, "tid": 2, "ts": 0.0, "dur": 30.0,
+                  "name": "jit_train_batch_fn(6095717265277617000)",
+                  "args": {"run_id": "22"}}
+        m = mfu.measure_regions([self._ev("dot.1", 0, 10), tpu, module],
+                                self.OPMAP)
+        assert (m["n_mapped"], m["n_unmapped"]) == (1, 1)
+        assert m["orphan_s"] == pytest.approx(5e-6)
+        # ... and the line that instruction has on a TPU now parses: a
+        # tuple in a tuple, layouts with parens of their own
+        line = ('  %slice-start.48 = ((f32[2,4096,1024]{2,1,0:T(8,128)}), '
+                'f32[1,4096,1024]{2,1,0:T(8,128)S(1)}, s32[]{:S(2)}) '
+                'async-start(f32[2,4096,1024]{2,1,0:T(8,128)} %p), '
+                'calls=%async_computation.3\n'
+                '  %copy-start.5 = (bf16[4,2048]{1,0:T(8,128)(2,1)S(1)}, '
+                'bf16[4,2048]{1,0:T(8,128)(2,1)}, u32[]{:S(2)}) copy-start('
+                'bf16[4,2048]{1,0:T(8,128)(2,1)} %q), metadata={op_name='
+                '"jit(f)/transpose(jvp())/checkpoint/mfu.mlp/mul"}\n')
+        om = mfu.build_opmap(line)
+        assert om["slice-start.48"]["opcode"] == "async-start"
+        assert (om["copy-start.5"]["region"], om["copy-start.5"]["pass"]) \
+            == ("mlp", "bwd")
+
+    def test_region_by_pass_rows_resum_to_the_regions(self):
+        opmap = {
+            "dot.1": {"region": "attn", "pass": "fwd", "category": "dot"},
+            "dot.2": {"region": "attn", "pass": "bwd", "category": "dot"},
+            "fus.3": {"region": "attn", "pass": "recompute",
+                      "category": "fusion"},
+            "fus.4": {"region": "optimizer", "pass": None,
+                      "category": "fusion"},
+            # an opmap persisted before the pass existed still joins
+            "fus.5": {"region": "other", "category": "fusion"},
+        }
+        events = [self._ev("dot.1", 0, 10), self._ev("dot.2", 10, 20),
+                  self._ev("fus.3", 30, 5), self._ev("fus.4", 40, 8),
+                  self._ev("fus.5", 50, 2)]
+        m = mfu.measure_regions(events, opmap)
+        assert m["passes"] == {
+            "attn": {"fwd": pytest.approx(10e-6), "bwd": pytest.approx(20e-6),
+                     "recompute": pytest.approx(5e-6)},
+            "optimizer": {"-": pytest.approx(8e-6)},
+            "other": {"-": pytest.approx(2e-6)}}
+        for region, row in m["passes"].items():
+            assert sum(row.values()) == pytest.approx(m["regions"][region])
+        led = mfu.ledger(None, m, step_s=50e-6)
+        assert led["passes"] == m["passes"]
+        assert (led["n_mapped"], led["n_unmapped"]) == (5, 0)
+        text = mfu.render_ledger(led)
+        assert "region x pass" in text
+        row = next(ln for ln in text.splitlines()
+                   if ln.split()[:1] == ["attn"] and "us" in ln
+                   and "%" not in ln)
+        assert row.split() == ["attn", "10us", "20us", "5us", "-"]
 
 
 # ===================================================================
@@ -505,6 +604,103 @@ class TestEngineLedgerE2E:
             assert engine._mfu_window["step"] == 4
         finally:
             engine.telemetry.close("test")
+
+
+# ===================================================================
+# the pass, and the map the engine publishes for the step it compiled
+# ===================================================================
+@pytest.fixture(scope="module")
+def remat_engine():
+    """A tiny engine as the benchmark's training cells configure theirs
+    (bf16, AdamW, whole-layer remat, a scanned trunk), one step run."""
+    import deepspeedsyclsupport_tpu as dstpu
+    from deepspeedsyclsupport_tpu.comm.topology import reset_world_topology
+    from deepspeedsyclsupport_tpu.models import build_model, get_config
+
+    reset_world_topology()
+    cfg = get_config("tiny", max_seq_len=32)
+    config = {"train_batch_size": 8, "bf16": {"enabled": True},
+              "optimizer": {"type": "adamw",
+                            "params": {"lr": 1e-4, "weight_decay": 0.01}},
+              "activation_checkpointing": {},
+              "steps_per_print": 10_000}
+    engine, _, _, _ = dstpu.initialize(model=build_model(cfg), config=config)
+    batch = {"input_ids": np.random.default_rng(0).integers(
+        0, cfg.vocab_size, (8, 32)).astype(np.int32)}
+    mfu._PUBLISHED.pop("train_batch_fn", None)
+    engine.train_batch(batch)
+    return engine, batch
+
+
+class TestPassAndPublish:
+    def test_train_batch_alone_publishes_nothing(self, remat_engine):
+        # first in the class: nothing has asked for the compiled step yet
+        engine, batch = remat_engine
+        engine.train_batch(batch)
+        assert mfu.published("train_batch_fn") is None
+
+    def test_compiled_train_step_publishes_under_the_program_name(
+            self, remat_engine, monkeypatch):
+        engine, _ = remat_engine
+        compiled = engine.compiled_train_step()
+        # handed over as it is: the text is read when somebody asks
+        assert mfu._PUBLISHED["train_batch_fn"] is compiled
+        om = mfu.published("train_batch_fn")
+        assert om == mfu.build_opmap(compiled.as_text())
+        assert "jit_train_batch_fn" in compiled.as_text().split("\n")[0]
+        # ... once: the second ask parses nothing
+        monkeypatch.setattr(mfu, "build_opmap", None)
+        assert mfu.published("train_batch_fn") is om
+        assert mfu.published("no_such_program") is None
+
+    @pytest.mark.parametrize("region,passes", [
+        # the scanned trunk is what jax.checkpoint wraps: all three
+        ("attn", {"fwd", "bwd", "recompute"}),
+        ("mlp", {"fwd", "bwd", "recompute"}),
+        # around it nothing is recomputed
+        ("embed", {"fwd", "bwd"}),
+        ("head", {"fwd", "bwd"}),
+        ("loss", {"fwd", "bwd"}),
+        ("optimizer", {None}),
+    ])
+    def test_every_region_has_its_passes(self, remat_engine, region, passes):
+        engine, _ = remat_engine
+        engine.compiled_train_step()
+        om = mfu.published("train_batch_fn")
+        assert {e["pass"] for e in om.values()
+                if e["region"] == region} == passes
+
+    def test_unscoped_instructions_and_the_text_without_percent(
+            self, remat_engine):
+        engine, _ = remat_engine
+        text = engine.compiled_train_step().as_text()
+        om = mfu.build_opmap(text)
+        assert mfu.build_opmap(text.replace("%", "")) == om
+        # norm chains of a rematted layer: no region, but a pass
+        other = {e["pass"] for e in om.values() if e["region"] == "other"}
+        assert {"bwd", "recompute", None} <= other
+        assert "fwd" not in other
+        assert all(e["pass"] is None or e["pass"] in mfu.PASSES
+                   for e in om.values())
+
+    def test_the_ledger_reads_the_published_map(self, remat_engine,
+                                                monkeypatch):
+        """``mfu_ledger()`` asks ``published`` and builds no second map."""
+        engine, _ = remat_engine
+        seen = []
+        monkeypatch.setattr(mfu, "measure_regions", lambda ev, om, steps=1:
+                            seen.append(om) or {"regions": {}})
+        monkeypatch.setattr(mfu, "find_trace", lambda root: "t.json")
+        monkeypatch.setattr(mfu, "parse_trace", lambda path: (
+            [], {"truncated": False}))
+        engine._mfu_window = {"step": 1, "step_s": 1.0, "steps": 1,
+                              "trace_dir": "unused"}
+        try:
+            engine.mfu_ledger(persist=False)
+        finally:
+            engine._mfu_window = None
+        assert len(seen) == 1
+        assert seen[0] is mfu.published("train_batch_fn")
 
 
 # ===================================================================

@@ -6,7 +6,9 @@ clean-step profiler window (``telemetry.mfu``) — or any bare
 ``trace.json.gz`` + opmap pair — into the step-time attribution ledger:
 achieved MFU, the gap waterfall (hardware peak → roofline-achievable →
 measured), per-region measured-vs-achievable time with bound-by verdicts,
-and the region↔step reconciliation. Offline and device-free (no jax, no
+the region x pass table (forward, backward, remat's recomputed forward: the
+same regions and passes the benchmark's ``train_*_ms`` entries read, from the
+same ``mfu_opmap.json``), and the region↔step reconciliation. Offline and device-free (no jax, no
 backend): safe on a login node over files rsynced from a dead job — the
 ``pod_report.py``/``trace_report.py`` contract.
 
