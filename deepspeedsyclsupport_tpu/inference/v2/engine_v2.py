@@ -25,7 +25,7 @@ import numpy as np
 
 from .config import RaggedInferenceConfig
 from .kv_cache import init_blocked_kv
-from .model import build_ragged_forward_fn
+from .model import build_ragged_forward_fn, moe_tile_rows
 from .ragged import (BlockedAllocator, LogitsRef, SequenceDescriptor,
                      attention_work, build_ragged_batch, device_token,
                      split_device_tokens)
@@ -33,7 +33,7 @@ from .scheduler import schedule_chunks
 from ..params import place_inference_params
 from ..sampling import SamplingParams, sample_token_dyn, split_key
 from ...comm.topology import MeshTopology, build_topology
-from ...monitor.reqtrace import NO_PHASE
+from ...monitor.reqtrace import MOE_TAIL_FIELDS, NO_PHASE
 from ...utils.logging import log_dist
 
 
@@ -324,7 +324,8 @@ class InferenceEngineV2:
         spans = self.round_spans
         return NO_PHASE if spans is None else spans.phase(name)
 
-    def _note_forward(self, descs, lengths, atoms: int = 0) -> None:
+    def _note_forward(self, descs, lengths, atoms: int = 0,
+                      rows: int = 0) -> None:
         """What the forward about to be launched covers, for the round's
         record; called BEFORE it, so ``ctx_tokens`` is the context the
         attention kernel must read and ``kv_blocks`` the tables it walks.
@@ -332,8 +333,12 @@ class InferenceEngineV2:
         batch was cut into; its one-token chunks, like every row of a
         ``decode_forward``, are ``decode_rows``, a one-row tile each.
         What those tiles cover (``attn_pairs``, ``dec_ctx_tokens``) is
-        ``ragged.attention_work``'s count. Its live tokens are counted
-        whether or not a round is recorded."""
+        ``ragged.attention_work``'s count. ``rows``: the forward's whole row
+        budget, pads included; a sparse-expert model's record gets
+        ``reqtrace.MOE_STATIC_FIELDS``: the rows of the tiles its grouped
+        GEMMs lay them in (static, by that shape) and the expert rows a live
+        token brings. Its live tokens are counted whether or not a round is
+        recorded."""
         self._forward_tokens += sum(lengths)
         if self.round_spans is None:
             return
@@ -348,6 +353,11 @@ class InferenceEngineV2:
                                if not (n == 1 and d.n_cached > 0)),
             ctx_tokens=sum(d.n_cached for d in descs),
             kv_blocks=sum(len(d.blocks) for d in descs))
+        if rows and self.kv.moe is not None:
+            cfg = self.model.config
+            self.round_spans.fields.update(
+                moe_tile_rows=moe_tile_rows(cfg, rows),
+                moe_rows_a_token=cfg.num_experts_per_tok * cfg.num_moe_layers)
 
     def moe_stats(self) -> Optional[Dict[str, Any]]:
         """A sparse-expert model's routing since the engine was built (None
@@ -408,7 +418,8 @@ class InferenceEngineV2:
             sampled = None
             if toks is None:     # a decode step: the token the sampler drew
                 sampled = self.sample_launch([uid], key, SamplingParams(),
-                                             tail=self.moe_tail())
+                                             tail=self.moe_tail(
+                                                 MOE_TAIL_FIELDS))
                 toks = [sampled.ref(uid)]
             out = self.put([uid], [toks], sampled=sampled)
             if sampled is not None:
@@ -834,7 +845,8 @@ class InferenceEngineV2:
         if self._no_sampled is None:
             self._no_sampled = jax.device_put(
                 np.zeros((self.config.max_sequences
-                          + len(self.moe_tail() or ()),), np.int32),
+                          + len(self.moe_tail(MOE_TAIL_FIELDS) or ()),),
+                         np.int32),
                 self._sampled_sharding)
         return self._no_sampled
 
@@ -863,7 +875,8 @@ class InferenceEngineV2:
                 chunks, cfg.max_tokens_per_batch, cfg.max_sequences,
                 cfg.blocks_per_seq,
                 atom_q=cfg.atom_q_size if self._use_atoms else None)
-            self._note_forward(*zip(*chunks), atoms=batch.live_atoms)
+            self._note_forward(*zip(*chunks), atoms=batch.live_atoms,
+                               rows=cfg.max_tokens_per_batch)
         with self._phase("dispatch"):
             tokens, sampled, take_from = self._token_operands(batch.tokens,
                                                               sampled)
@@ -911,7 +924,7 @@ class InferenceEngineV2:
             tokens = np.zeros((cfg.max_sequences,), np.int32)
             for slot, (d, _n) in enumerate(chunks):
                 tokens[slot] = d.pending[0]
-            self._note_forward(*zip(*chunks))
+            self._note_forward(*zip(*chunks), rows=cfg.max_sequences)
         with self._phase("dispatch"):
             tokens, sampled, take_from = self._token_operands(tokens,
                                                               sampled)
@@ -1151,16 +1164,21 @@ class InferenceEngineV2:
                 rows = jnp.where(mine, got, rows)
         return rows
 
-    def moe_tail(self) -> Optional[Tuple[jax.Array, ...]]:
-        """What a serving round gives :meth:`sample_drained` as ``tail``: a
-        sparse-expert model's ``moe_touched`` of the last forward and, where
-        it holds a share of the experts, its ``moe_rows`` (device int32
-        scalars), None for a dense model."""
+    def moe_tail(self, fields: Sequence[str] = ("moe_touched", "moe_rows")
+                 ) -> Optional[Tuple[jax.Array, ...]]:
+        """What rides behind the sampled tokens as ``tail``: of a
+        sparse-expert model's last forward, those of ``fields`` the pool
+        counts, in that order (device int32 scalars); None for a dense
+        model. ``moe_touched`` always; ``moe_rows`` where the program holds
+        a share of the experts; ``moe_tiles``, the row tiles its grouped
+        GEMMs visited, where asked for: a serving round asks for all of
+        ``reqtrace.MOE_TAIL_FIELDS``, and so does :meth:`warmup`."""
         moe = self.kv.moe
         if moe is None:
             return None
-        return (moe.touched,) if moe.rows is None else (moe.touched,
-                                                         moe.rows)
+        counted = {"moe_touched": moe.touched, "moe_tiles": moe.tiles,
+                   "moe_rows": moe.rows}
+        return tuple(counted[f] for f in fields if counted[f] is not None)
 
     def sample_launch(self, uids: Sequence[int], rng: jax.Array,
                       sampling: SamplingParams,

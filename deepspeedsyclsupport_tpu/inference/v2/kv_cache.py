@@ -35,10 +35,15 @@ class MoeCounters(NamedTuple):
     program holds a share of the experts (None, no leaf, where it holds
     them all: every live token then brings ``k`` rows a layer and the host
     can count them): of the last forward, the (token, choice) rows that
-    went through the experts held here, summed over layers."""
+    went through the experts held here, summed over layers. ``tiles`` scalar
+    int32 (None: a pool made by hand that does not count them): of the last
+    forward, the row tiles its grouped GEMMs visit, summed over layers: every
+    held expert's rows rounded up to whole tiles of
+    ``ops.grouped_gemm.row_tile`` rows, the forward's static choice."""
     load: jnp.ndarray
     touched: jnp.ndarray
     rows: Optional[jnp.ndarray] = None
+    tiles: Optional[jnp.ndarray] = None
 
 
 class BlockedKV(NamedTuple):
@@ -99,7 +104,8 @@ def init_blocked_kv(model_config, cfg: RaggedInferenceConfig,
         moe = jax.jit(lambda: MoeCounters(
             jnp.zeros((model_config.num_moe_layers, model_config.num_experts),
                       jnp.int32), jnp.zeros((), jnp.int32),
-            jnp.zeros((), jnp.int32) if share else None),
+            jnp.zeros((), jnp.int32) if share else None,
+            jnp.zeros((), jnp.int32)),
             out_shardings=topology.replicated())()
     return BlockedKV(zeros(), None if latent else zeros(), moe)
 

@@ -45,6 +45,7 @@ from .module_registry import register_impl, select_impl
 from ...models.layers import (alibi_slopes, apply_rope, mlp_block, norm,
                               qk_norm, rms_norm)
 from ...monitor.mfu import scope
+from ...ops.grouped_gemm import row_tile, tile_visits
 
 NEG_INF = jnp.finfo(jnp.float32).min
 
@@ -553,7 +554,14 @@ def _experts_in_place(layers, dtype):
     return {**layers, "moe": rest}, stack
 
 
-def _scan_layers(layer, x, kv: BlockedKV, params, held):
+def moe_tile_rows(cfg, tokens: int) -> int:
+    """Rows of the tiles a forward over ``tokens`` rows (its whole budget,
+    pads included) lays each expert's (token, choice) rows in: the grouped
+    GEMM's static choice by the shape (``ops.grouped_gemm.row_tile``)."""
+    return row_tile(tokens * cfg.num_experts_per_tok, cfg.num_experts)
+
+
+def _scan_layers(layer, x, kv: BlockedKV, params, cfg):
     """The layer loop of both serving forwards: the pool rides as CARRY
     beside ``x`` (never as the scan's xs/ys, which would slice it by layer
     and stack a second pool), the stacked params and the layer index as xs.
@@ -561,7 +569,7 @@ def _scan_layers(layer, x, kv: BlockedKV, params, held):
     that stack first, then the expert layers, one pool index through both.
 
     The routed experts' matrices are NOT among the xs
-    (:func:`_experts_in_place`): their consumer, ``jax.lax.ragged_dot``, is a
+    (:func:`_experts_in_place`): their consumer, the grouped GEMM, is a
     custom call on the TPU and takes whole buffers, so the scan's slice of
     a layer's ``[E, ., .]`` was materialised before each read, three copies
     a layer that cost 1.56 x the grouped GEMMs they fed. The loop closes over
@@ -574,7 +582,9 @@ def _scan_layers(layer, x, kv: BlockedKV, params, held):
     None)``: a sparse-expert model's rows stack to [L_moe, E], over the
     router's whole width, and fold into ``kv.moe``; a program that holds a
     share of the experts (``kv.moe.rows``) counts ``touched`` and ``rows``
-    over its own columns, ``held``. Returns ``(x, the new BlockedKV)``."""
+    over its own columns, ``cfg.held_experts``, and a pool that counts
+    ``tiles`` gets the row tiles those rows fill (:func:`moe_tile_rows`).
+    Returns ``(x, the new BlockedKV)``."""
     carry, first = (x, kv.pools), 0
     if "dense_layers" in params:
         dense = params["dense_layers"]
@@ -592,12 +602,13 @@ def _scan_layers(layer, x, kv: BlockedKV, params, held):
     moe = kv.moe
     if rows is not None:
         load = moe.load + rows
-        if moe.rows is None:
-            moe = MoeCounters(load, jnp.sum(rows > 0, dtype=jnp.int32))
-        else:
-            rows = rows[:, held]
-            moe = MoeCounters(load, jnp.sum(rows > 0, dtype=jnp.int32),
-                              jnp.sum(rows, dtype=jnp.int32))
+        if moe.rows is not None:
+            rows = rows[:, cfg.held_experts]
+        moe = MoeCounters(
+            load, jnp.sum(rows > 0, dtype=jnp.int32),
+            None if moe.rows is None else jnp.sum(rows, dtype=jnp.int32),
+            None if moe.tiles is None else tile_visits(
+                rows, moe_tile_rows(cfg, x.shape[-2])))
     return x, kv._replace(moe=moe, **dict(zip(("k", "v"), pools)))
 
 
@@ -679,7 +690,7 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
         x, rows = _block(cfg, p, x, attn_fn, ~pad, experts)
         return (x, pools), rows
 
-    x, kv = _scan_layers(layer, x, kv, params, cfg.held_experts)
+    x, kv = _scan_layers(layer, x, kv, params, cfg)
 
     x = _final_norm(params, x, cfg)
     h_last = x[last_tok_idx]  # [S, d] — logits_gather
@@ -755,7 +766,7 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
         x, rows = _block(cfg, p, x, attn_fn, active, experts)
         return (x, pools), rows
 
-    x, kv = _scan_layers(layer, x, kv, params, cfg.held_experts)
+    x, kv = _scan_layers(layer, x, kv, params, cfg)
     x = _final_norm(params, x, cfg)
     logits = _unembed(params, x, cfg)
     return logits.astype(jnp.float32), kv

@@ -1041,7 +1041,7 @@ class ServingSession:
             # read from the pool the NEXT dispatch replaces, so the sampler
             # goes first. The engine times its own gather and sample
             sampled = eng.sample_launch(drained, sub, self.sampling,
-                                        tail=eng.moe_tail())
+                                        tail=eng.moe_tail(MOE_TAIL_FIELDS))
             with self._phase("schedule"):
                 ending = self._plan_drained(reqs, sampled, now)
         else:

@@ -130,7 +130,18 @@ FORWARD_FIELDS = ("n_seqs", "tokens", "prefill_tokens", "ctx_tokens",
 #: held here, summed over layers; ``moe_touched`` then counts the held
 #: experts with a row. A program that holds every expert leaves it out: each
 #: live token brings ``num_experts_per_tok`` rows a layer, as a reader knows.
-MOE_TAIL_FIELDS = ("moe_touched", "moe_rows")
+#: ``moe_tiles``: the row tiles the forward's grouped GEMMs visited, summed
+#: over layers: each held expert's rows rounded up to whole tiles of the
+#: forward's ``moe_tile_rows`` (``ops.grouped_gemm.row_tile``: static by its
+#: shape). rows / (``moe_tiles`` x ``moe_tile_rows``) is how full the tiles
+#: were, ``moe_tiles`` / ``moe_touched`` the visits an expert's weights served.
+MOE_TAIL_FIELDS = ("moe_touched", "moe_tiles", "moe_rows")
+#: What a sparse-expert model's record says of the forward it LAUNCHED that
+#: no count is: the rows of a tile of its grouped GEMMs, and the (token,
+#: choice) rows a live token brings them over all expert layers where every
+#: expert is held (``num_experts_per_tok`` x expert layers). A dense model's
+#: record has neither.
+MOE_STATIC_FIELDS = ("moe_tile_rows", "moe_rows_a_token")
 
 #: what a phase is where nothing times the round: ``trace_stages`` off, or
 #: an engine driven without a session
@@ -534,7 +545,7 @@ def round_phases(streams: Iterable[Tuple[str, str, Sequence[Dict[str, Any]]]]
 
     # a field only some programs write (moe_rows) is reported where written
     fields = FORWARD_FIELDS + tuple(
-        f for f in MOE_TAIL_FIELDS
+        f for f in MOE_TAIL_FIELDS + MOE_STATIC_FIELDS
         if f not in FORWARD_FIELDS and any(f in d for d in rounds))
     by_program: Dict[str, List[Dict[str, Any]]] = {}
     for d in rounds:

@@ -162,7 +162,12 @@ def _layer_groups(w: jnp.ndarray, group_sizes: jnp.ndarray, layer, dtype
     ``[L x E]`` sizes: this layer's E ``group_sizes`` at ``layer x E`` among
     zeros, so the sorted rows fall on this layer's experts where they lie
     and no other layer's are read. Only one layer's leaf may be of another
-    ``dtype``: casting a stack would convert L x the bytes every layer."""
+    ``dtype``: casting a stack would convert L x the bytes every layer.
+
+    The path of every platform but the TPU (:func:`moe_mlp_nodrop`), and
+    the zero groups are its alone: the TPU's kernel takes ``layer`` as a
+    scalar-prefetch operand of the weights' ``BlockSpec`` and never sees
+    another layer's experts (``ops/grouped_gemm.py``)."""
     if w.ndim == 3:
         w, layer = w.astype(dtype)[None], 0
     elif w.dtype != dtype:
@@ -185,9 +190,20 @@ def moe_mlp_nodrop(p: Dict[str, Any], x: jnp.ndarray, cfg,
     ``moe_gather`` (``inference/v2/kernels/ragged_ops/``,
     ``modules/implementations/moe/cutlass_multi_gemm.py``). TPU-native
     equivalent: sort (token, choice) rows by expert and run the three expert
-    GEMMs as ``jax.lax.ragged_dot`` grouped matmuls. No capacity truncation —
-    inference must never drop a routed token (unlike the training path's
-    capacity buffers, :func:`moe_mlp`).
+    GEMMs as grouped matmuls. No capacity truncation — inference must never
+    drop a routed token (unlike the training path's capacity buffers,
+    :func:`moe_mlp`).
+
+    The PLATFORM picks the grouped matmul, nothing else does
+    (``ops.grouped_gemm.default_impl``). On the TPU: the Pallas kernel of
+    ``ops/grouped_gemm.py``, whose row tile fits the rows an expert gets
+    (``row_tile``, by the static shape); the sorted rows are laid out with
+    every expert's first on a tile boundary (``tile_rows``), gate and up
+    share one read of them with the activation on the float32 accumulators,
+    and the result is gathered back into sorted order. Anywhere else (and as
+    the tests' reference): ``jax.lax.ragged_dot`` over :func:`_layer_groups`,
+    three calls with the activation between. Routing, the sort and the
+    combine are the same code on both.
 
     ``live`` [T] bool: the serving forwards always carry their full row
     budget, pads included. A row that is not live gets NO expert: it sorts
@@ -206,23 +222,28 @@ def moe_mlp_nodrop(p: Dict[str, Any], x: jnp.ndarray, cfg,
 
     ``p["w_gate" | "w_up" | "w_down"]`` are one layer's ``[E_held, ., .]`` or
     the whole stack ``[L, E_held, ., .]`` with ``layer`` (static or traced)
-    the layer to read: on the TPU ``ragged_dot`` is a custom call, which
-    takes whole buffers, so a slice ``w[layer]`` handed to it is first COPIED
-    out of the stack (three matrices a layer, 1.56 x the GEMMs' own time in
-    OLMoE's decode step). The serving layer loop therefore hands the stack
-    whole (``inference/v2/model.py:_scan_layers``) and :func:`_layer_groups`
-    places the rows on the layer's experts. Every other leaf of ``p`` is the
-    layer's own.
+    the layer to read: a custom call (the TPU's kernel; ``ragged_dot`` where
+    the compiler makes it one) takes whole buffers, so a slice ``w[layer]``
+    handed to it is first COPIED out of the stack (three matrices a layer,
+    1.56 x the GEMMs' own time in OLMoE's decode step). The serving layer
+    loop therefore hands the stack whole
+    (``inference/v2/model.py:_scan_layers``); the kernel reads ``layer`` as a
+    scalar-prefetch operand, and on the other platforms
+    :func:`_layer_groups` places the rows on the layer's experts among
+    ``L x E`` groups, all but E of them empty. Every other leaf of ``p`` is
+    the layer's own.
 
     x: [T, D] flat tokens → (out [T, D], routed [E] int32: the (token,
     choice) rows the router gave each of ITS experts, here or not, ``sum ==
     k × live rows``).
     """
     from ..monitor.mfu import scope
+    from ..ops import grouped_gemm
 
     t, d = x.shape
     e, k = cfg.num_experts, cfg.num_experts_per_tok
     held, first = cfg.experts_held, cfg.first_expert_held
+    impl = grouped_gemm.default_impl()   # by platform: the kernel on the TPU
     with scope("moe_route"):
         logits = jnp.einsum("td,de->te", x.astype(jnp.float32),
                             p["router"].astype(jnp.float32))
@@ -249,19 +270,32 @@ def moe_mlp_nodrop(p: Dict[str, Any], x: jnp.ndarray, cfg,
         flat_tok = jnp.repeat(jnp.arange(t), k)
         order = jnp.argsort(here, stable=True)                # moe_scatter
         sorted_tok = flat_tok[order]
-        xs = x[sorted_tok]                                    # [T*k, D]
         routed = jnp.bincount(flat_expert, length=e).astype(jnp.int32)
         group_sizes = routed if held == e else routed[cfg.held_experts]
+        if impl != "xla":
+            # the kernel's rows: every expert's start on a tile boundary
+            tiles = grouped_gemm.tile_rows(
+                group_sizes, here[order], grouped_gemm.row_tile(t * k, e))
+            xs = x[sorted_tok[tiles.src]]                     # [tiles, D]
+        else:
+            xs = x[sorted_tok]                                # [T*k, D]
 
     act = jax.nn.silu if cfg.activation == "silu" else jax.nn.gelu
     with scope("moe_experts"):
-        def grouped(rows, w):
-            return jax.lax.ragged_dot(
-                rows, *_layer_groups(w, group_sizes, layer, x.dtype))
+        if impl == "xla":
+            def grouped(rows, w):
+                return jax.lax.ragged_dot(
+                    rows, *_layer_groups(w, group_sizes, layer, x.dtype))
 
-        gate = grouped(xs, p["w_gate"])
-        up = grouped(xs, p["w_up"])
-        ys = grouped(act(gate) * up, p["w_down"])             # [T*k, D]
+            gate = grouped(xs, p["w_gate"])
+            up = grouped(xs, p["w_up"])
+            ys = grouped(act(gate) * up, p["w_down"])         # [T*k, D]
+        else:
+            kw = dict(layer=layer, interpret=impl == "pallas_interpret")
+            mid = grouped_gemm.grouped_glu(xs, p["w_gate"], p["w_up"], tiles,
+                                           act=act, **kw)
+            ys = grouped_gemm.grouped_matmul(mid, p["w_down"], tiles,
+                                             **kw)[tiles.dest]   # [T*k, D]
 
     with scope("moe_combine"):
         ys = ys * gate_w.reshape(t * k)[order].astype(x.dtype)[:, None]

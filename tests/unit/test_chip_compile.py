@@ -251,6 +251,96 @@ def test_decode_forward_reads_the_expert_stack_in_place(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < one_matrix
 
 
+# ------------------------------- the grouped GEMM whose row tile fits the expert
+# (experts held, d, f, the program's rows, experts routed over): the two
+# serving forwards of the three sparse cells
+GROUPED = {
+    "olmoe_decode": (64, 2048, 1024, 32 * 8, 64),
+    "olmoe_chunk": (64, 2048, 1024, 768 * 8, 64),
+    "xing4_decode": (64, 3584, 1024, 16 * 4, 64),
+    "xing4_chunk": (64, 3584, 1024, 768 * 4, 64),
+    "dsv2_decode": (40, 5120, 1536, 64 * 6, 160),
+    "dsv2_chunk": (40, 5120, 1536, 768 * 6, 160),
+}
+
+
+@pytest.mark.parametrize("shape", GROUPED.values(), ids=GROUPED.keys())
+def test_the_grouped_gemm_compiles_at_the_cells_widths(one_chip, shape):
+    """Both kernels of an expert layer, the tile and the weight blocks the
+    shape picks, a two-layer stack read at a traced layer: what the chip's
+    compiler refuses (VMEM, a block off the tiling) it refuses here."""
+    from deepspeedsyclsupport_tpu.ops import grouped_gemm as gg
+
+    held, d, f, rows, experts = shape
+    tile = gg.row_tile(rows, experts)
+
+    def layer(x, w_gate, w_up, w_down, sizes, group, l):
+        tiles = gg.tile_rows(sizes, group, tile)
+        mid = gg.grouped_glu(x[tiles.src], w_gate, w_up, tiles, layer=l,
+                             act=jax.nn.silu)
+        return gg.grouped_matmul(mid, w_down, tiles, layer=l)[tiles.dest]
+
+    bf16 = jnp.bfloat16
+    compiled = _compile(
+        layer, one_chip, ((rows, d), bf16), ((2, held, d, f), bf16),
+        ((2, held, d, f), bf16), ((2, held, f, d), bf16),
+        ((held,), jnp.int32), ((rows,), jnp.int32), ((), jnp.int32))
+    calls = [ln.split(" = ")[0].strip().lstrip("%")
+             for ln in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert sorted(c.split(".")[0] for c in calls) == ["grouped_glu",
+                                                      "grouped_matmul"]
+    # no layer's matrix is copied out of the stack for them
+    assert compiled.memory_analysis().temp_size_in_bytes < held * d * f * 2
+
+
+def test_decode_forward_on_the_tpu_takes_the_kernel(one_chip, monkeypatch):
+    """``decode_forward`` at OLMoE's widths as the TPU traces it (the path
+    is the platform's; here the test stands in for it): no ``ragged-dot``
+    left, the two kernels under the ``moe_experts`` scope by their OWN
+    instruction names, where ``benchmark/scopes.py`` finds what
+    ``moe_roofline`` and ``moe_share_pct`` sum, and the stack read in
+    place."""
+    from benchmark import scopes
+    from deepspeedsyclsupport_tpu.inference.v2 import model as M
+    from deepspeedsyclsupport_tpu.inference.v2.kv_cache import (BlockedKV,
+                                                                MoeCounters)
+    from deepspeedsyclsupport_tpu.models import build_model
+    from deepspeedsyclsupport_tpu.ops import grouped_gemm as gg
+
+    monkeypatch.setattr(gg, "default_impl", lambda: "pallas")
+    model = build_model("olmoe-1b-7b", num_layers=2, dtype="bfloat16")
+    cfg = model.config
+    bs, slots, seqs, bps = 64, 64 * 64, 32, 64
+
+    def on_chip(tree, floats=None):
+        return jax.tree_util.tree_map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, floats if floats is not None and jnp.issubdtype(
+                x.dtype, jnp.floating) else x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(model.init_params), jnp.bfloat16)
+    pool = jnp.zeros((2, slots, cfg.num_kv_heads, cfg.head_dim), jnp.bfloat16)
+    zero = jnp.int32(0)
+    kv = on_chip(BlockedKV(pool, pool, MoeCounters(
+        jnp.zeros((2, cfg.num_experts), jnp.int32), zero, None, zero)))
+    i32 = on_chip(jnp.zeros((seqs,), jnp.int32))
+    compiled = M.build_decode_forward_fn(model, bs, "pallas").lower(
+        params, kv, i32, i32, on_chip(jnp.zeros((seqs, bps), jnp.int32)),
+        on_chip(jnp.zeros((seqs,), jnp.bool_))).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" not in text
+    under = scopes.instructions_under(text, ("moe_experts",))
+    assert {"grouped_glu", "grouped_matmul"} \
+        <= {name.split(".")[0] for name in under}
+    assert not any(name.startswith(prefix) for name in under
+                   for prefix, _label in scopes.RAGGED_DOT_KERNELS)
+    e, d, f = cfg.num_experts, cfg.hidden_size, cfg.intermediate_size
+    assert not [ln for ln in text.splitlines()
+                if f" = bf16[{e},{d},{f}]" in ln
+                or f" = bf16[{e},{f},{d}]" in ln]
+    assert compiled.memory_analysis().temp_size_in_bytes < e * d * f * 2
+
+
 # ------------------------------------- the forwards' tokens from the device
 @pytest.mark.parametrize("program", ["decode_forward", "ragged_forward"])
 def test_the_forwards_take_decode_tokens_from_the_sampler(one_chip, program):

@@ -155,7 +155,8 @@ def test_the_latent_pool_has_one_leaf_of_padded_rows(tiny):
     eng = engine_of(tiny, dtype="bfloat16", head_dim_lane_pad=128)
     slots = ENGINE["num_blocks"] * ENGINE["block_size"]
     assert eng.kv.v is None and eng.kv.k.shape == (3, slots, 128)
-    assert len(jax.tree_util.tree_leaves(eng.kv)) == 3   # k, load, touched
+    # k, load, touched, tiles
+    assert len(jax.tree_util.tree_leaves(eng.kv)) == 4
     assert eng.kv.moe.load.shape == (2, 8)
     stats = kv_pool_stats(eng.kv, eng.allocator)
     assert stats["pool_bytes"] == slots * 3 * 128 * 2

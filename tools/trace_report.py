@@ -641,12 +641,28 @@ def requests_report(root: str, worst_n: int = 5, window_s: float = 60.0,
         # read back; spec-rows: rows launched for a stream an EOS had ended)
         # a program that holds a share of the experts counts their rows too
         share = any("moe_rows" in m for m in rp["programs"].values())
+        # a forward whose grouped GEMMs counted their row tiles: how full
+        # they were, and the tiles an expert's one read of weights served
+        tiled = any(m.get("moe_tiles") for m in rp["programs"].values())
+
+        def tile_cols(m):
+            tiles = m.get("moe_tiles", 0)
+            if not tiles:
+                return f"{'-':>11}{'-':>11}{'-':>14}"
+            rows = m.get("moe_rows",
+                         m["tokens"] * m.get("moe_rows_a_token", 0))
+            room = tiles * m.get("moe_tile_rows", 0)
+            return (f"{m.get('moe_tile_rows', 0):>11.0f}"
+                    f"{100 * rows / room if room else 0:>10.1f}%"
+                    f"{tiles / max(m.get('moe_touched', 0), 1e-9):>14.2f}")
         lines.append(f"    {'launched':<20}{'rounds':>7}{'seqs':>8}"
                      f"{'tokens':>9}{'prompt':>9}{'context':>10}"
                      f"{'kv blocks':>11}{'1-row':>8}{'atoms':>8}"
                      f"{'pairs':>12}{'1-row-ctx':>11}{'experts':>9}"
                      f"{'ahead':>7}{'spec-rows':>11}"
-                     + (f"{'exp-rows':>10}" if share else ""))
+                     + (f"{'exp-rows':>10}" if share else "")
+                     + (f"{'tile-rows':>11}{'tile-fill':>11}{'tiles/expert':>14}"
+                        if tiled else ""))
         for name, m in sorted(rp["programs"].items()):
             lines.append(f"    {name:<20}{m['rounds']:>7}{m['n_seqs']:>8.1f}"
                          f"{m['tokens']:>9.1f}{m['prefill_tokens']:>9.1f}"
@@ -659,7 +675,7 @@ def requests_report(root: str, worst_n: int = 5, window_s: float = 60.0,
                          f"{m.get('ahead', 0):>7.2f}"
                          f"{m.get('spec_rows', 0):>11.2f}"
                          + (f"{m.get('moe_rows', 0):>10.1f}" if share
-                            else ""))
+                            else "") + (tile_cols(m) if tiled else ""))
     if att["cached_prefix_tokens_mean"]:
         lines.append(f"  cached prefix: "
                      f"{att['cached_prefix_tokens_mean']:.1f} token(s)/request "
