@@ -24,11 +24,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from .config import RaggedInferenceConfig
-from .kv_cache import init_blocked_kv
+from .kv_cache import init_blocked_kv, state_pool_stats
 from .model import build_ragged_forward_fn, moe_tile_rows
 from .ragged import (BlockedAllocator, LogitsRef, SequenceDescriptor,
                      attention_work, build_ragged_batch, device_token,
-                     split_device_tokens)
+                     split_device_tokens, ssm_pieces)
 from .scheduler import schedule_chunks
 from ..params import place_inference_params
 from ..sampling import SamplingParams, sample_token_dyn, split_key
@@ -156,6 +156,17 @@ class InferenceEngineV2:
         self.kv = init_blocked_kv(model.config, cfg, self.topology)
         self.allocator = BlockedAllocator(cfg.num_blocks)
         self.seqs: Dict[int, SequenceDescriptor] = {}
+        # a model with Mamba layers: the free places of the recurrent-state
+        # pool, one a live sequence from its descriptor's making to its
+        # flush or eviction (None: the model has no such state)
+        self._state_free: Optional[List[int]] = None
+        if self.kv.ssm is not None:
+            self._state_free = list(range(cfg.max_sequences))
+            if cfg.decode_steps_per_dispatch > 1:
+                raise ValueError(
+                    "decode_steps_per_dispatch > 1 (decode_multi_forward) "
+                    "is not written for a model with recurrent state: its "
+                    "device loop does not carry the state slots")
         # SLA layer (serving.ServingSession) installs a scheduler.SlackPolicy
         # here; put() then orders chunks by slack instead of arrival. None =
         # the pre-SLA least-recently-served ordering.
@@ -253,6 +264,8 @@ class InferenceEngineV2:
                 f"serialize() supports models carrying a ModelConfig "
                 f"(models.CausalLM family); got {type(self.model).__name__} "
                 f"— fail at save, not with a confusing load-time error")
+        self._refuse_stateful("serialize()", "a snapshot of the recurrent "
+                              "state beside the parameters")
         params = self.params
         if self.config.quantize_weights and "layers" in params:
             from ...compression.quantize import dequantize_tree
@@ -353,6 +366,17 @@ class InferenceEngineV2:
                                if not (n == 1 and d.n_cached > 0)),
             ctx_tokens=sum(d.n_cached for d in descs),
             kv_blocks=sum(len(d.blocks) for d in descs))
+        if self._state_free is not None:
+            # live rows through the Mamba layers, and the sequence pieces
+            # whose state they read and wrote (a one-token chunk is one
+            # piece, a longer one a piece every ssm_chunk_size rows), summed
+            # over those layers
+            mc = self.model.config
+            q = mc.ssm_chunk_size
+            self.round_spans.fields.update(
+                ssm_rows=sum(lengths),
+                ssm_pieces=sum(-(-n // q) for n in lengths)
+                * mc.pattern_count("M"))
         if rows and self.kv.moe is not None:
             cfg = self.model.config
             self.round_spans.fields.update(
@@ -377,6 +401,45 @@ class InferenceEngineV2:
             cfg = self.model.config
             stats["held"] = np.arange(cfg.num_experts)[cfg.held_experts]
         return stats
+
+    def state_stats(self) -> Optional[Dict[str, Any]]:
+        """The recurrent state of a model with Mamba layers (None for any
+        other): ``bytes_per_slot`` (SSM state and convolution tail, all its
+        layers), ``slots``, ``slots_live``, ``dtype``, ``pool_bytes``."""
+        return state_pool_stats(self.kv, sum(
+            d.state_slot is not None for d in self.seqs.values()))
+
+    def _refuse_stateful(self, what: str, missing: str) -> None:
+        if self._state_free is not None:
+            raise NotImplementedError(
+                f"{what} is not available for a model with recurrent state "
+                f"(ModelConfig.layer_pattern): it would need {missing}")
+
+    def _new_seq(self, uid: int, **fields) -> SequenceDescriptor:
+        """A fresh descriptor in ``seqs``; a model with recurrent state
+        gives it a place in the state pool (admission holds the sequences
+        to ``max_sequences``, which is how many places there are)."""
+        if self._state_free is not None:
+            if not self._state_free:
+                raise RuntimeError(
+                    f"no recurrent-state slot free for uid {uid}: "
+                    f"{len(self.seqs)} sequences live of max_sequences "
+                    f"{self.config.max_sequences}")
+            fields["state_slot"] = self._state_free.pop()
+        d = self.seqs[uid] = SequenceDescriptor(uid=uid, **fields)
+        return d
+
+    def _drop_seq(self, uid: int) -> Optional[SequenceDescriptor]:
+        """Take ``uid`` out of ``seqs``: its blocks back to the pool and
+        its state slot to the free places (whatever it holds: the next
+        sequence there starts from zeros at its position 0)."""
+        d = self.seqs.pop(uid, None)
+        if d is not None:
+            self.allocator.free(d.blocks)
+            if d.state_slot is not None:
+                self._state_free.append(d.state_slot)
+                d.state_slot = None
+        return d
 
     def compiled_programs(self) -> Dict[str, Any]:
         """``{name: jax.stages.Compiled}`` for every forward program this
@@ -649,7 +712,7 @@ class InferenceEngineV2:
             d = self.seqs.get(uid)
             skip = 0
             if d is None:
-                d = self.seqs[uid] = SequenceDescriptor(uid=uid)
+                d = self._new_seq(uid)
                 if self.prefix_cache is not None and toks:
                     skip = self.map_cached_prefix(uid, toks)
             d.pending.extend(int(t) for t in toks[skip:])
@@ -688,7 +751,7 @@ class InferenceEngineV2:
         orders this sequence by its slack. Unknown fields raise."""
         d = self.seqs.get(uid)
         if d is None:
-            d = self.seqs[uid] = SequenceDescriptor(uid=uid)
+            d = self._new_seq(uid)
         for name, value in fields.items():
             if not hasattr(d, name):
                 raise AttributeError(
@@ -709,6 +772,10 @@ class InferenceEngineV2:
         index)."""
         from .prefix_cache import PrefixCache
 
+        self._refuse_stateful(
+            "install_prefix_cache()", "a snapshot of the recurrent state at "
+            "every shared block boundary: a prefix's KV blocks can be "
+            "mapped, the state its tokens left behind was never kept")
         if self.prefix_cache is None:
             self.prefix_cache = PrefixCache(
                 self.allocator, self.config.block_size, scope=scope,
@@ -752,7 +819,7 @@ class InferenceEngineV2:
         if not cached:
             return 0
         if d is None:
-            d = self.seqs[uid] = SequenceDescriptor(uid=uid, tenant=tenant)
+            d = self._new_seq(uid, tenant=tenant)
         self.allocator.retain(blocks)
         d.blocks = list(blocks)
         d.n_cached = cached
@@ -823,10 +890,9 @@ class InferenceEngineV2:
         stalling on an exhausted pool. Shared blocks only lose this
         stream's reference — the prefix index and other streams keep
         theirs (the refcounted-release contract)."""
-        d = self.seqs.pop(uid, None)
+        d = self._drop_seq(uid)
         if d is None:
             return None
-        self.allocator.free(d.blocks)
         d.blocks = []
         d.n_cached = 0
         d.cached_prefix_len = 0
@@ -877,6 +943,9 @@ class InferenceEngineV2:
                 atom_q=cfg.atom_q_size if self._use_atoms else None)
             self._note_forward(*zip(*chunks), atoms=batch.live_atoms,
                                rows=cfg.max_tokens_per_batch)
+            state = () if self._state_free is None else (ssm_pieces(
+                chunks, cfg.max_tokens_per_batch, cfg.max_sequences,
+                self.model.config.ssm_chunk_size),)
         with self._phase("dispatch"):
             tokens, sampled, take_from = self._token_operands(batch.tokens,
                                                               sampled)
@@ -890,7 +959,8 @@ class InferenceEngineV2:
                 jnp.asarray(batch.block_tables),
                 jnp.asarray(batch.last_tok_idx),
                 *(a if a is None else jnp.asarray(a) for a in tiles),
-                sampled, take_from)
+                sampled, take_from,
+                *(jax.tree_util.tree_map(jnp.asarray, a) for a in state))
         return logits
 
     def _slot_arrays(self, descs):
@@ -924,6 +994,13 @@ class InferenceEngineV2:
             tokens = np.zeros((cfg.max_sequences,), np.int32)
             for slot, (d, _n) in enumerate(chunks):
                 tokens[slot] = d.pending[0]
+            state = ()
+            if self._state_free is not None:
+                # each row's place in the state pool (the sink elsewhere)
+                slots = np.full((cfg.max_sequences,), cfg.max_sequences,
+                                np.int32)
+                slots[:len(chunks)] = [d.state_slot for d, _n in chunks]
+                state = (slots,)
             self._note_forward(*zip(*chunks), rows=cfg.max_sequences)
         with self._phase("dispatch"):
             tokens, sampled, take_from = self._token_operands(tokens,
@@ -932,7 +1009,8 @@ class InferenceEngineV2:
                 "decode_forward", self._decode_forward,
                 self.params, self.kv, tokens,
                 jnp.asarray(positions), jnp.asarray(tables),
-                jnp.asarray(active), sampled, take_from)
+                jnp.asarray(active), sampled, take_from,
+                *map(jnp.asarray, state))
         return logits
 
     def _decode_multi_dispatch(self, running: Dict[int, int],
@@ -1256,9 +1334,7 @@ class InferenceEngineV2:
     def flush(self, uids: Sequence[int]) -> None:
         """Release sequences and their KV blocks (reference ``flush:228``)."""
         for uid in uids:
-            d = self.seqs.pop(uid, None)
-            if d is not None:
-                self.allocator.free(d.blocks)
+            self._drop_seq(uid)
 
     # --------------------------------------------------------------- generate
     def generate(self, prompts: Sequence[Sequence[int]],

@@ -21,6 +21,12 @@ import numpy as np
 
 from .config import RaggedInferenceConfig
 
+# A Mamba layer's recurrent state in the serving pool: float32 beside bf16
+# weights, as the family's serving instructions keep the SSM cache. Not a
+# setting: a narrower state is rounded at every step of every sequence, a
+# different result and not a faster one.
+SSM_STATE_DTYPE = jnp.float32
+
 
 class MoeCounters(NamedTuple):
     """What the serving forwards of a sparse-expert model count about
@@ -54,10 +60,26 @@ class BlockedKV(NamedTuple):
     # the device, through every forward's layer loop, donated and handed
     # back, so counting costs no launch and no transfer.
     moe: Optional[MoeCounters] = None
+    # a model with Mamba layers only (``ModelConfig.layer_pattern``; None
+    # elsewhere: no leaf, the same program): the recurrent state, per Mamba
+    # layer and sequence SLOT, fixed in size whatever the context. ``ssm``
+    # [L_m, S + 1, g, n, (h / g) x p] in :data:`SSM_STATE_DTYPE` and
+    # ``conv`` [L_m, kernel - 1, S + 1, channels], the convolution's tail,
+    # in the pool's dtype (``ops/ssm.py`` has the layout's why). Slot ``S``
+    # is the sink padding rows write to. They ride the forwards as the pool
+    # does: donated, in the layer loop's carry, updated in place. Nothing
+    # resets a slot: a piece whose first position is 0 starts from zeros.
+    ssm: Optional[jnp.ndarray] = None
+    conv: Optional[jnp.ndarray] = None
 
     @property
     def num_slots(self) -> int:
         return self.k.shape[1]
+
+    @property
+    def state(self):
+        """The recurrent-state arrays there are: (ssm, conv) or ()."""
+        return () if self.ssm is None else (self.ssm, self.conv)
 
     @property
     def pools(self):
@@ -91,7 +113,8 @@ def init_blocked_kv(model_config, cfg: RaggedInferenceConfig,
     kvh = model_config.num_kv_heads
     row = (lane_padded_head_dim(latent, pad),) if latent else (
         kvh, lane_padded_head_dim(model_config.head_dim, pad))
-    shape = (model_config.num_layers, cfg.num_blocks * cfg.block_size, *row)
+    shape = (model_config.num_kv_layers, cfg.num_blocks * cfg.block_size,
+             *row)
     tp = topology.axis_sizes["model"]
     sharding = (topology.sharding(None, None, "model", None)
                 if tp > 1 and kvh % tp == 0 and not latent
@@ -107,7 +130,32 @@ def init_blocked_kv(model_config, cfg: RaggedInferenceConfig,
             jnp.zeros((), jnp.int32) if share else None,
             jnp.zeros((), jnp.int32)),
             out_shardings=topology.replicated())()
-    return BlockedKV(zeros(), None if latent else zeros(), moe)
+    state = {}
+    if model_config.pattern_count("M"):
+        mc, lead = model_config, (model_config.pattern_count("M"),
+                                  cfg.max_sequences + 1)
+        g = mc.ssm_n_groups
+        state = jax.jit(lambda: dict(
+            ssm=jnp.zeros((*lead, g, mc.ssm_state_size, mc.ssm_d_inner // g),
+                          SSM_STATE_DTYPE),
+            conv=jnp.zeros((lead[0], mc.ssm_conv_kernel - 1, lead[1],
+                            mc.ssm_conv_dim), cfg.dtype)),
+            out_shardings=topology.replicated())()
+    return BlockedKV(zeros(), None if latent else zeros(), moe, **state)
+
+
+def state_pool_stats(kv: BlockedKV, live: int) -> Optional[dict]:
+    """What the recurrent state of a model with Mamba layers costs (None
+    for a model without): bytes a sequence slot over all its layers, the
+    slots there are (the sink not counted) and how many are a sequence's
+    now, and the state's dtype. Shape-only, no transfer."""
+    if kv.ssm is None:
+        return None
+    slots = kv.ssm.shape[1] - 1
+    per_slot = sum(a.size // (slots + 1) * a.dtype.itemsize
+                   for a in kv.state)
+    return {"bytes_per_slot": per_slot, "slots": slots, "slots_live": live,
+            "dtype": str(kv.ssm.dtype), "pool_bytes": per_slot * (slots + 1)}
 
 
 def kv_pool_stats(kv: BlockedKV, allocator) -> dict:

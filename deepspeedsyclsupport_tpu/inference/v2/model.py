@@ -32,7 +32,11 @@ them): latent attention (``cfg.kv_lora_rank``: :func:`_mla_rows` caches one
 kernels), hyper-connection streams (``cfg.hc_mult``: :func:`_hc_block`
 carries ``[n, T, d]`` and mixes it around each sublayer) and leading dense
 layers before the expert layers (``params["dense_layers"]``, a stack of its
-own, walked first by :func:`_scan_layers`).
+own, walked first by :func:`_scan_layers`). And a fourth: a hybrid stack
+(``cfg.layer_pattern``: Mamba-2, expert and attention layers, ONE mixer a
+layer), which :func:`_walk_pattern` walks over three stacks of parameters,
+the KV pool (a row for the attention layers only) and the recurrent state
+of the Mamba layers (``ops/ssm.py``), each with an index of its own.
 """
 from typing import Any, NamedTuple, Optional, Tuple
 
@@ -512,6 +516,32 @@ register_impl("decode_attn", "pallas_interpret", priority=-10,
 register_impl("decode_attn", "xla", priority=0)(_decode_dispatch("xla"))
 
 
+# ssm_step kind: a Mamba layer's one-token state update (``ops/ssm.py``:
+# the in-place Pallas kernel on the TPU, gather/update/scatter elsewhere)
+def _ssm_dispatch(impl_name):
+    def fn(*args):
+        from ...ops.ssm import STATE_STEPS
+
+        return STATE_STEPS[impl_name](*args)
+    return fn
+
+
+register_impl("ssm_step", "pallas", priority=10,
+              auto_eligible=lambda c: c.get("backend") == "tpu")(
+    _ssm_dispatch("pallas"))
+register_impl("ssm_step", "pallas_interpret", priority=-10,
+              auto_eligible=lambda c: False)(
+    _ssm_dispatch("pallas_interpret"))
+register_impl("ssm_step", "xla", priority=0)(_ssm_dispatch("xla"))
+
+
+def _ssm_step_fn():
+    """The platform's state step: no setting names one (the registry is the
+    seam a test or a third party puts another behind)."""
+    return select_impl("ssm_step", "auto",
+                       {"backend": jax.default_backend()}).fn
+
+
 def _pool_write(pools, layer, dest, rows):
     """Scatter the new tokens' rows (K and V [n, KVH, d] each; a latent
     pool's one [n, d]) into layer ``layer`` of ``pools`` at flat slots
@@ -599,17 +629,130 @@ def _scan_layers(layer, x, kv: BlockedKV, params, cfg):
 
     (x, pools), rows = jax.lax.scan(
         body, carry, (layers, jnp.arange(first, kv.k.shape[0])))
-    moe = kv.moe
-    if rows is not None:
-        load = moe.load + rows
-        if moe.rows is not None:
-            rows = rows[:, cfg.held_experts]
-        moe = MoeCounters(
-            load, jnp.sum(rows > 0, dtype=jnp.int32),
-            None if moe.rows is None else jnp.sum(rows, dtype=jnp.int32),
-            None if moe.tiles is None else tile_visits(
-                rows, moe_tile_rows(cfg, x.shape[-2])))
-    return x, kv._replace(moe=moe, **dict(zip(("k", "v"), pools)))
+    return x, kv._replace(moe=_count_moe(kv.moe, rows, cfg, x.shape[-2]),
+                          **dict(zip(("k", "v"), pools)))
+
+
+def _count_moe(moe, rows, cfg, tokens: int):
+    """``kv.moe`` after a forward whose expert layers' routers gave ``rows``
+    [L_moe, E] (None: a dense model, whose ``moe`` is None too)."""
+    if rows is None:
+        return moe
+    load = moe.load + rows
+    if moe.rows is not None:
+        rows = rows[:, cfg.held_experts]
+    return MoeCounters(
+        load, jnp.sum(rows > 0, dtype=jnp.int32),
+        None if moe.rows is None else jnp.sum(rows, dtype=jnp.int32),
+        None if moe.tiles is None else tile_visits(
+            rows, moe_tile_rows(cfg, tokens)))
+
+
+def layer_plan(pattern: str):
+    """``[(unit, repeats), ...]`` covering ``pattern`` left to right: where
+    a run of layers repeats (``EM`` pairs between attention layers, a whole
+    ``MEMEM*E`` period), the run is ONE ``lax.scan`` over its repeats, so
+    that 26 layers do not compile as 26 bodies. Greedy: at each layer the
+    (unit, repeats >= 2) that covers most, the shorter unit on a tie; else
+    the layer alone."""
+    plan, i = [], 0
+    while i < len(pattern):
+        best = (pattern[i], 1)
+        for u in range(1, (len(pattern) - i) // 2 + 1):
+            unit, reps = pattern[i:i + u], 1
+            while pattern[i + reps * u:i + (reps + 1) * u] == unit:
+                reps += 1
+            if reps > 1 and u * reps > len(best[0]) * best[1]:
+                best = (unit, reps)
+        plan.append(best)
+        i += len(best[0]) * best[1]
+    return plan
+
+
+def _mamba_mixer(cfg, p, x, ssm_fn):
+    """One Mamba-2 layer over flat tokens x [T, d]: ``in_proj`` to ``[z |
+    xBC | dt]``, the recurrence (``ssm_fn(p, xbc, dt) -> y [T, d_inner]``
+    float32: convolution, scan and ``D``, against the state pool), the gate
+    and its grouped norm, ``out_proj``."""
+    from ...ops.ssm import gated_norm
+
+    di, c = cfg.ssm_d_inner, cfg.ssm_conv_dim
+    y = norm(x, p["norm"], cfg)
+    with scope("ssm_proj"):
+        zxbcdt = y @ p["in_proj"]
+    out = ssm_fn(p, zxbcdt[:, di:di + c], zxbcdt[:, di + c:])
+    with scope("ssm_gate"):
+        u = gated_norm(out, zxbcdt[:, :di], p["gate_norm"]["scale"], cfg)
+    with scope("ssm_proj"):
+        return (x + u.astype(x.dtype) @ p["out_proj"]).astype(x.dtype)
+
+
+def _walk_pattern(cfg, params, x, kv: BlockedKV, attend, ssm_step, live):
+    """The layer loop of both serving forwards for a ``cfg.layer_pattern``
+    model: :func:`layer_plan`'s runs, each kind of layer indexing ITS stack
+    of parameters and ITS cache (``attn_layers`` and the KV pool for ``*``,
+    ``mamba_layers`` and the recurrent state for ``M``, ``layers`` and the
+    expert counters for ``E``). As in :func:`_scan_layers` the pools ride as
+    carry and the routed experts' matrices stay closed over; the other
+    leaves are read at the layer's (traced) index inside the loop body,
+    which is what a scan's xs are.
+
+    ``attend(p_attn, y, pools, l) -> (rows [T, H, D], pools)`` and
+    ``ssm_step(p, xbc, dt, state, l) -> (y [T, d_inner], state)`` are the
+    forward's own. Every layer is ``x + mixer(norm(x))``."""
+    layers, stack = _experts_in_place(params.get("layers", {}), x.dtype)
+    at = lambda tree, j: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: a[j], tree)
+
+    def one(kind, carry, j):
+        x, pools, state = carry
+        rows = None
+        if kind == "M":
+            def ssm_fn(p, xbc, dt):
+                nonlocal state
+                y, state = ssm_step(p, xbc, dt, state, j)
+                return y
+
+            x = _mamba_mixer(cfg, at(params["mamba_layers"], j), x, ssm_fn)
+        elif kind == "*":
+            p = at(params["attn_layers"], j)
+            rows_attn, pools = attend(p["attn"], norm(x, p["attn_norm"], cfg),
+                                      pools, j)
+            x = (x + _attn_out(p["attn"], rows_attn, cfg, x.shape[0])
+                 ).astype(x.dtype)
+        else:
+            p = _dequant(at(layers, j), x.dtype)
+            m, rows = _mlp(p, norm(x, p["mlp_norm"], cfg), cfg, live,
+                           (stack, j))
+            x = (x + m).astype(x.dtype)
+        return (x, pools, state), rows
+
+    carry, done, routed = (x, kv.pools, kv.state), dict.fromkeys("ME*", 0), []
+    for unit, reps in layer_plan(cfg.layer_pattern):
+        per = {kind: unit.count(kind) for kind in done}
+
+        def body(carry, i, unit=unit, per=per, base=dict(done)):
+            at_kind = {kind: base[kind] + i * per[kind] for kind in per}
+            rows = []
+            for kind in unit:
+                carry, r = one(kind, carry, at_kind[kind])
+                at_kind[kind] += 1
+                rows += [] if r is None else [r]
+            return carry, (jnp.stack(rows) if rows else None)
+
+        if reps == 1:
+            carry, rows = body(carry, 0)
+        else:
+            carry, rows = jax.lax.scan(body, carry, jnp.arange(reps))
+            rows = None if rows is None else rows.reshape(-1, rows.shape[-1])
+        routed += [] if rows is None else [rows]
+        for kind in done:
+            done[kind] += reps * per[kind]
+    x, pools, state = carry
+    moe = _count_moe(kv.moe, jnp.concatenate(routed) if routed else None,
+                     cfg, x.shape[-2])
+    return x, kv._replace(moe=moe, **dict(zip(("k", "v"), pools)),
+                          **dict(zip(("ssm", "conv"), state)))
 
 
 def _tokens_in(tokens, sampled, take_from):
@@ -630,7 +773,7 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
                    token_pos, block_tables, last_tok_idx,
                    atom_qidx=None, atom_pos0=None, atom_qlen=None,
                    atom_tables=None, atom_inv=None, dec_row=None,
-                   dec_len=None, sampled=None, take_from=None, *,
+                   dec_len=None, sampled=None, take_from=None, ssm=None, *,
                    block_size: int, attn_impl: str = "auto"
                    ) -> Tuple[jnp.ndarray, BlockedKV]:
     """Flat-token forward. Returns (per-slot last-token logits [S, V], new kv).
@@ -639,7 +782,9 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
     ``lax.scan`` here exactly as in training (``models/transformer.py``).
     ``atom_*`` and ``dec_*`` are ``RaggedBatch.tile_args``, what the
     ``kernel`` attention takes (the others route by ``token_seq`` alone).
-    ``sampled`` / ``take_from`` [T]: :func:`_tokens_in`.
+    ``sampled`` / ``take_from`` [T]: :func:`_tokens_in`. ``ssm``: a model
+    with Mamba layers' ``ragged.SsmBatch`` (which state slot each chunk's
+    sequence has, the one-token chunks, the pieces of the longer ones).
     """
     cfg = model.config
     assert cfg.scan_layers, "ragged engine requires scan_layers param layout"
@@ -658,10 +803,10 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
     x = _embed(params, _tokens_in(tokens, sampled, take_from), token_pos,
                cfg)
 
-    def layer(carry, p, l, experts):
-        x, pools = carry
-        p = _dequant(p, x.dtype)
-
+    def attend(p_attn, y, pools, l):
+        """Layer ``l``'s attention over the normed rows y: the new rows into
+        the pool, then every row against its sequence's cached context.
+        -> (rows [T, H, D], pools)."""
         # resolved through the pluggable registry (module_registry.py — the
         # reference's module_registry + heuristics seam). Static per trace:
         # atom presence and backend are trace-time constants.
@@ -669,28 +814,56 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
             "backend": jax.default_backend(),
             "has_atoms": atom_qidx is not None,
         })
+        q, new = _q_and_rows(p_attn, y, cfg, token_pos)
+        pools = _pool_write(pools, l, dest, new)
+        q = _lane_pad(q, pools[0].shape[-1], is_q=True)
+        k_cache, v_cache, v_dim, keep = _attn_views(cfg, pools)
+        ctx = PrefillAttnContext(
+            k_cache=k_cache, v_cache=v_cache, layer=l,
+            token_seq=token_seq,
+            token_pos=token_pos, block_tables=block_tables,
+            block_size=bs, alibi=ab, window=window,
+            atom_qidx=atom_qidx, atom_pos0=atom_pos0,
+            atom_qlen=atom_qlen, atom_tables=atom_tables,
+            atom_inv=atom_inv, dec_row=dec_row, dec_len=dec_len,
+            v_dim=v_dim)
+        return spec.fn(q, ctx)[..., :keep], pools
+
+    def layer(carry, p, l, experts):
+        x, pools = carry
+        p = _dequant(p, x.dtype)
 
         def attn_fn(y):
             nonlocal pools
-            q, new = _q_and_rows(p["attn"], y, cfg, token_pos)
-            pools = _pool_write(pools, l, dest, new)
-            q = _lane_pad(q, pools[0].shape[-1], is_q=True)
-            k_cache, v_cache, v_dim, keep = _attn_views(cfg, pools)
-            ctx = PrefillAttnContext(
-                k_cache=k_cache, v_cache=v_cache, layer=l,
-                token_seq=token_seq,
-                token_pos=token_pos, block_tables=block_tables,
-                block_size=bs, alibi=ab, window=window,
-                atom_qidx=atom_qidx, atom_pos0=atom_pos0,
-                atom_qlen=atom_qlen, atom_tables=atom_tables,
-                atom_inv=atom_inv, dec_row=dec_row, dec_len=dec_len,
-                v_dim=v_dim)
-            return spec.fn(q, ctx)[..., :keep]
+            out, pools = attend(p["attn"], y, pools, l)
+            return out
 
         x, rows = _block(cfg, p, x, attn_fn, ~pad, experts)
         return (x, pools), rows
 
-    x, kv = _scan_layers(layer, x, kv, params, cfg)
+    def ssm_step(p, xbc, dt, state, l):
+        """Mamba layer ``l``'s recurrence over the flat batch: the pieces of
+        the chunks of two tokens or more through the chunked scan, the
+        one-token chunks through the decode step (as their attention goes
+        through the one-row tile), each from ITS slot's state."""
+        from ...ops.ssm import chunked_scan, decode_step
+
+        y, *state = chunked_scan(
+            xbc, dt, p, *state, l,
+            (ssm.row0, ssm.length, ssm.slot, ssm.fresh, ssm.count), cfg)
+        one = ssm.dec_len > 0
+        y_dec, *state = decode_step(
+            xbc[ssm.dec_row], dt[ssm.dec_row], p, *state, l,
+            jnp.where(one, ssm.seq_slot, state[0].shape[1] - 1),
+            ssm.dec_len == 1, cfg, _ssm_step_fn())
+        # a slot with no one-token chunk scatters out of range (dropped)
+        y = y.at[jnp.where(one, ssm.dec_row, t)].set(y_dec, mode="drop")
+        return y, tuple(state)
+
+    if cfg.layer_pattern is not None:
+        x, kv = _walk_pattern(cfg, params, x, kv, attend, ssm_step, ~pad)
+    else:
+        x, kv = _scan_layers(layer, x, kv, params, cfg)
 
     x = _final_norm(params, x, cfg)
     h_last = x[last_tok_idx]  # [S, d] — logits_gather
@@ -719,7 +892,8 @@ def build_ragged_forward_fn(model, block_size: int, attn_impl: str = "auto"):
 
 # ------------------------------------------------------------ decode fast path
 def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
-                   block_tables, active, sampled=None, take_from=None, *,
+                   block_tables, active, sampled=None, take_from=None,
+                   state_slot=None, *,
                    block_size: int, attn_impl: str = "auto"
                    ) -> Tuple[jnp.ndarray, BlockedKV]:
     """All-decode forward: ONE token per slot, attention via the Pallas paged
@@ -729,7 +903,9 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
     cached (the new token writes slot ``positions[s]``). This is the program
     serving spends most of its life in, so it gets the kernel; mixed
     prefill+decode batches take :func:`ragged_forward`.
-    ``sampled`` / ``take_from`` [S]: :func:`_tokens_in`.
+    ``sampled`` / ``take_from`` [S]: :func:`_tokens_in`. ``state_slot``
+    [S]: a model with Mamba layers' recurrent-state slot of each row's
+    sequence (rows change place from forward to forward; a state does not).
     """
     cfg = model.config
     bs = block_size
@@ -745,28 +921,43 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
     x = _embed(params, _tokens_in(tokens, sampled, take_from), positions,
                cfg)
 
+    def attend(p_attn, y, pools, l):
+        spec = select_impl("decode_attn", attn_impl,
+                           {"backend": jax.default_backend()})
+        q, new = _q_and_rows(p_attn, y, cfg, positions)
+        pools = _pool_write(pools, l, dest, new)
+        q = _lane_pad(q, pools[0].shape[-1], is_q=True)
+        k_cache, v_cache, v_dim, keep = _attn_views(cfg, pools)
+        return spec.fn(q, DecodeAttnContext(
+            k_cache=k_cache, v_cache=v_cache, layer=l,
+            block_tables=block_tables, seq_lens=seq_lens, block_size=bs,
+            alibi=ab, window=window, v_dim=v_dim))[..., :keep], pools
+
     def layer(carry, p, l, experts):
         x, pools = carry
         p = _dequant(p, x.dtype)
 
-        spec = select_impl("decode_attn", attn_impl,
-                           {"backend": jax.default_backend()})
-
         def attn_fn(y):
             nonlocal pools
-            q, new = _q_and_rows(p["attn"], y, cfg, positions)
-            pools = _pool_write(pools, l, dest, new)
-            q = _lane_pad(q, pools[0].shape[-1], is_q=True)
-            k_cache, v_cache, v_dim, keep = _attn_views(cfg, pools)
-            return spec.fn(q, DecodeAttnContext(
-                k_cache=k_cache, v_cache=v_cache, layer=l,
-                block_tables=block_tables, seq_lens=seq_lens, block_size=bs,
-                alibi=ab, window=window, v_dim=v_dim))[..., :keep]
+            out, pools = attend(p["attn"], y, pools, l)
+            return out
 
         x, rows = _block(cfg, p, x, attn_fn, active, experts)
         return (x, pools), rows
 
-    x, kv = _scan_layers(layer, x, kv, params, cfg)
+    def ssm_step(p, xbc, dt, state, l):
+        from ...ops.ssm import decode_step
+
+        y, *state = decode_step(
+            xbc, dt, p, *state, l,
+            jnp.where(active, state_slot, state[0].shape[1] - 1),
+            positions == 0, cfg, _ssm_step_fn())
+        return y, tuple(state)
+
+    if cfg.layer_pattern is not None:
+        x, kv = _walk_pattern(cfg, params, x, kv, attend, ssm_step, active)
+    else:
+        x, kv = _scan_layers(layer, x, kv, params, cfg)
     x = _final_norm(params, x, cfg)
     logits = _unembed(params, x, cfg)
     return logits.astype(jnp.float32), kv

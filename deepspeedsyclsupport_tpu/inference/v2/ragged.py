@@ -199,6 +199,10 @@ class SequenceDescriptor:
     first_token_s: Optional[float] = None  # when the first token landed
     last_service_s: float = -1.0    # clock stamp of the last scheduled chunk
     #                                 (starvation aging in slack ordering)
+    # a model with Mamba layers: the sequence's place in the recurrent-state
+    # pool (``kv_cache.BlockedKV.ssm``) from its first token to its flush or
+    # eviction; None for every other model
+    state_slot: Optional[int] = None
 
     @property
     def needs_tokens(self) -> int:
@@ -208,6 +212,53 @@ class SequenceDescriptor:
         total = self.n_cached + new_tokens
         want = -(-total // block_size)  # ceil
         return max(0, want - len(self.blocks))
+
+
+class SsmBatch(NamedTuple):
+    """What the Mamba layers of one ``ragged_forward`` take
+    (``ops/ssm.py``). A chunk's place in the forward changes from forward
+    to forward; its sequence's state does not move: ``seq_slot`` [S] is
+    each chunk's state slot (the sink, ``S``, for an empty place). The
+    one-token chunks are ``dec_row`` / ``dec_len`` as the attention's (kept
+    here too: those are built only for an attention that takes atoms). The
+    chunks of two tokens or more are cut into PIECES of at most ``chunk``
+    consecutive rows, live ones first, in the order of the flat axis (so a
+    sequence's pieces follow one another): ``row0`` first flat row,
+    ``length`` rows (0 = dead), ``slot`` state slot, ``fresh`` whether the
+    piece starts at position 0 (it then starts from zeros, whatever the slot
+    holds); ``count`` the live pieces."""
+    seq_slot: np.ndarray
+    dec_row: np.ndarray
+    dec_len: np.ndarray
+    row0: np.ndarray
+    length: np.ndarray
+    slot: np.ndarray
+    fresh: np.ndarray
+    count: np.ndarray
+
+
+def ssm_pieces(chunks, max_tokens: int, max_sequences: int,
+               chunk: int) -> SsmBatch:
+    """:class:`SsmBatch` of scheduled ``(descriptor, n_tokens)`` chunks,
+    laid on the flat axis as :func:`build_ragged_batch` lays them."""
+    S = max_sequences
+    p_max = S + max_tokens // chunk + 1
+    seq_slot = np.full((S,), S, np.int32)
+    dec_row, dec_len = np.zeros((S,), np.int32), np.zeros((S,), np.int32)
+    row0, length = np.zeros((p_max,), np.int32), np.zeros((p_max,), np.int32)
+    slot, fresh = np.full((p_max,), S, np.int32), np.zeros((p_max,), bool)
+    a = cur = 0
+    for i, (desc, n) in enumerate(chunks):
+        seq_slot[i] = desc.state_slot
+        if n == 1:
+            dec_row[i], dec_len[i] = cur, desc.n_cached + 1
+        for k in range(0, n if n > 1 else 0, chunk):
+            row0[a], length[a] = cur + k, min(chunk, n - k)
+            slot[a], fresh[a] = desc.state_slot, desc.n_cached + k == 0
+            a += 1
+        cur += n
+    return SsmBatch(seq_slot, dec_row, dec_len, row0, length, slot, fresh,
+                    np.asarray(a, np.int32))
 
 
 @dataclass

@@ -32,7 +32,7 @@ class ModelConfig:
     rms_norm_eps: float = 1e-5
     tie_embeddings: bool = False
     attn_impl: str = "auto"  # auto | xla | flash | ring | ulysses
-    activation: str = "silu"   # silu | gelu | gelu_exact | relu
+    activation: str = "silu"   # silu | gelu | gelu_exact | relu | relu2
     use_bias: bool = False     # biases on attention/MLP projections
     qkv_bias: Optional[bool] = None  # override bias for q/k/v only (Qwen-style)
     attn_out_bias: Optional[bool] = None  # override bias for attn out proj (gptj)
@@ -110,6 +110,29 @@ class ModelConfig:
     # manifold-constrained hyper-connections: hc_mult residual streams,
     # mixed by a Sinkhorn-normalised matrix in every sublayer (1 = the
     # plain x + f(norm(x)) stream)
+    # A hybrid stack (nemotron_h): one character a layer, each layer ONE
+    # mixer behind one norm: ``M`` a Mamba-2 mixer, ``E`` sparse experts
+    # (two-matrix, ``mlp_type="mlp"``), ``*`` attention. None: the uniform
+    # attention + MLP block. Three stacks of parameters (``mamba_layers``,
+    # ``layers``, ``attn_layers``), a KV pool with a row for the ``*`` layers
+    # only and a recurrent state per sequence slot for the ``M`` layers
+    # (inference/v2/kv_cache.py). Serving only.
+    layer_pattern: Optional[str] = None
+    # Mamba-2 sizes, under the names nemotron_h publishes: d_inner is
+    # mamba_num_heads x mamba_head_dim (not an expansion of hidden_size);
+    # B and C come in ssm_n_groups groups of ssm_state_size
+    mamba_num_heads: int = 0
+    mamba_head_dim: int = 0
+    ssm_state_size: int = 0
+    ssm_n_groups: int = 1
+    ssm_conv_kernel: int = 4
+    ssm_chunk_size: int = 128       # rows of a piece of the chunked scan
+    time_step_min: float = 0.001    # the range dt is drawn over at init
+    time_step_max: float = 0.1
+    time_step_floor: float = 1e-4
+    # the shared expert's width where it is not n_shared_experts x one
+    # routed expert's (nemotron_h: moe_shared_expert_intermediate_size)
+    shared_expert_intermediate_size: Optional[int] = None
     hc_mult: int = 1
     hc_sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
@@ -190,6 +213,8 @@ class ModelConfig:
             raise ValueError(
                 "first_k_dense_replace needs experts in every later layer, "
                 "stacked (scan_layers)")
+        if self.layer_pattern is not None:
+            self._check_pattern()
         if self.attn_windows is not None:
             self.attn_windows = tuple(self.attn_windows)
             if len(self.attn_windows) != self.num_layers:
@@ -200,6 +225,49 @@ class ModelConfig:
                 # per-layer windows make layers heterogeneous — the stacked
                 # lax.scan trunk requires identical layer programs
                 self.scan_layers = False
+
+    def _check_pattern(self):
+        pat = self.layer_pattern
+        if set(pat) - set("ME*") or len(pat) != self.num_layers:
+            raise ValueError(
+                f"layer_pattern {pat!r}: {self.num_layers} characters of "
+                f"'M' (Mamba-2), 'E' (experts) and '*' (attention) wanted")
+        if ("E" in pat) != self.any_moe:
+            raise ValueError("layer_pattern: 'E' layers need num_experts, "
+                             "and num_experts needs an 'E' layer")
+        if "M" in pat and not (self.mamba_num_heads and self.mamba_head_dim
+                               and self.ssm_state_size
+                               and self.mamba_num_heads % self.ssm_n_groups
+                               == 0):
+            raise ValueError("layer_pattern: 'M' layers need mamba_num_heads"
+                             " (a multiple of ssm_n_groups), mamba_head_dim "
+                             "and ssm_state_size")
+        if (self.kv_lora_rank or self.hc_mult > 1 or self.parallel_block
+                or self.first_k_dense_replace or self.moe_layer_freq != 1
+                or self.attn_windows is not None or not self.scan_layers):
+            raise ValueError(
+                "layer_pattern walks three plain stacks (scan_layers): no "
+                "latent attention, hyper-connection streams, parallel "
+                "block, leading dense layers or per-layer windows")
+
+    def pattern_count(self, kind: str) -> int:
+        """Layers of ``kind`` ('M', 'E', '*') in ``layer_pattern``."""
+        return (self.layer_pattern or "").count(kind)
+
+    @property
+    def num_kv_layers(self) -> int:
+        """Layers that cache keys and values: the KV pool's leading axis."""
+        return self.num_layers if self.layer_pattern is None \
+            else self.pattern_count("*")
+
+    @property
+    def ssm_d_inner(self) -> int:
+        return self.mamba_num_heads * self.mamba_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Channels of the Mamba mixer's convolution: x | B | C."""
+        return self.ssm_d_inner + 2 * self.ssm_n_groups * self.ssm_state_size
 
     @property
     def rotary_dim(self) -> int:
@@ -231,6 +299,28 @@ class ModelConfig:
             else self.num_experts_held
 
     @property
+    def shared_expert_width(self) -> int:
+        """Width of the ONE MLP the shared experts are (0: none)."""
+        if self.shared_expert_intermediate_size is not None:
+            return self.shared_expert_intermediate_size
+        return self.n_shared_experts * (self.moe_intermediate_size
+                                        or self.intermediate_size)
+
+    @property
+    def expert_width_stored(self) -> int:
+        """A routed expert's width as its matrices are STORED: one wider
+        than the TPU's 128 lanes is rounded up to a multiple of them, the
+        extra columns of w_up (w_gate) and rows of w_down zero: the same
+        function (act(0) = 0 for every activation here), in a shape whose
+        minor dimension fills the lanes. At 1856 wide the compiler otherwise
+        stores ``[E, d, 1856]`` transposed and copies the whole stack (3.3
+        GB at nemotron-3-nano's cut) back for the grouped kernel, every
+        forward (PERF.md section 6, PR 37). Every other preset's width is
+        a multiple already; one under 128 (a test's) stays as drawn."""
+        f = self.moe_intermediate_size or self.intermediate_size
+        return f if f < 128 else -(-f // 128) * 128
+
+    @property
     def held_experts(self) -> slice:
         """The experts held, as the columns of the router's width."""
         return slice(self.first_expert_held,
@@ -238,6 +328,8 @@ class ModelConfig:
 
     @property
     def num_moe_layers(self) -> int:
+        if self.layer_pattern is not None:
+            return self.pattern_count("E")
         return self.num_layers - self.first_k_dense_replace \
             if self.any_moe else 0
 
@@ -278,8 +370,17 @@ class ModelConfig:
         mats = 3 if self.mlp_type == "glu" else 2
         dense = mats * d * f
         fe = self.moe_intermediate_size or f
-        moe = (mats * d * fe * (self.experts_held + self.n_shared_experts)
+        moe = (mats * d * (fe * self.experts_held + self.shared_expert_width)
                + d * self.num_experts)
+        if self.layer_pattern is not None:
+            di, h = self.ssm_d_inner, self.mamba_num_heads
+            mamba = (d * (di + self.ssm_conv_dim + h) + di * d
+                     + self.ssm_conv_dim * (self.ssm_conv_kernel + 1)
+                     + 3 * h + di + d)
+            return (mamba * self.pattern_count("M")
+                    + (moe + d) * self.pattern_count("E")
+                    + (attn + d) * self.pattern_count("*")
+                    + v * d * (1 if self.tie_embeddings else 2) + d)
         if self.qk_norm:
             attn += self.q_dim + self.kv_dim
         n_moe = self.num_moe_layers
@@ -420,6 +521,31 @@ PRESETS = {
         # near-tie that bf16 breaks the other way moves a row of logits by
         # up to ~0.07 logit-std, the routed experts left out by 0.15-0.4
         routed_write_share=0.04),
+    # nvidia/NVIDIA-Nemotron-3-Nano-30B-A3B-BF16 (model_type nemotron_h): 52
+    # layers of ONE mixer each: 23 Mamba-2 (64 heads of 64, 8 groups of
+    # state 128, conv 4), 23 of 128 routed relu^2 experts (two matrices, top-6
+    # by sigmoid scores + selection bias, renormalised, x 2.5) beside one
+    # shared expert of twice the width, 6 of GQA 32/2 attention with no
+    # positional encoding. Serving only (inference/v2); a chip of an
+    # expert-parallel deployment overrides num_experts_held.
+    "nemotron-3-nano": _p(
+        vocab_size=131072, hidden_size=2688, intermediate_size=1856,
+        num_layers=52, num_heads=32, num_kv_heads=2, head_dim=128,
+        max_seq_len=262144, rms_norm_eps=1e-5, pos_embed="none",
+        layer_pattern="MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME",
+        mamba_num_heads=64, mamba_head_dim=64, ssm_state_size=128,
+        ssm_n_groups=8, ssm_conv_kernel=4, ssm_chunk_size=128,
+        activation="relu2", mlp_type="mlp",
+        num_experts=128, num_experts_per_tok=6, moe_intermediate_size=1856,
+        n_shared_experts=1, shared_expert_intermediate_size=3712,
+        scoring_func="sigmoid", topk_method="noaux_tc", norm_topk_prob=True,
+        routed_scaling_factor=2.5,
+        # as deepseek-v2's: how much of a logit the seeded routed experts
+        # carry (benchmark/configs/nemotron3-nano-ep4-d26.json, assumed;
+        # PERF.md section 6, PR 37: by a sweep on the chip; at 1/20 a program
+        # without the x 2.5 passed parity, at 1/10 router near-ties that
+        # bf16 breaks the other way bring a sound run to 0.09 of its 0.1)
+        routed_write_share=0.075),
 }
 
 

@@ -561,7 +561,9 @@ def _attention_block_impl(p, x, cfg, positions, segment_ids, kv_cache, impl,
 def _activation(name: str):
     return {"silu": jax.nn.silu, "gelu": jax.nn.gelu,
             "gelu_exact": partial(jax.nn.gelu, approximate=False),
-            "relu": jax.nn.relu}[name]
+            "relu": jax.nn.relu,
+            # squared ReLU (Primer; nemotron_h's mlp_hidden_act)
+            "relu2": lambda x: jnp.square(jax.nn.relu(x))}[name]
 
 
 def glu_mlp(p: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:

@@ -61,6 +61,13 @@ class CausalLM:
             writes at about the embedding's size and a comparison of logits
             weighs attention, MLP and embedding alike (where hyper-
             connection maps gate every write themselves, ``H_post`` ~ 1)."""
+            if cfg.layer_pattern:
+                # a hybrid stack has ONE mixer a layer and is cut less deep
+                # than the latent families: all its mixers TOGETHER write
+                # about what the embedding does. At the embedding's size
+                # EACH, 26 layers' bf16 rounding compounds to 0.065
+                # logit-std at the median row (PERF.md section 6, PR 37)
+                return std / np.sqrt(fan_in * cfg.num_layers)
             if cfg.kv_lora_rank:
                 return std / np.sqrt(fan_in)
             return std / np.sqrt(2 * cfg.num_layers)
@@ -117,6 +124,41 @@ class CausalLM:
                     "w_up": dense((d, f), next(ks)),
                     "w_down": dense((f, d), next(ks), scale=down_scale(f))}
 
+        def mlp_params(ks, f) -> Params:
+            d = cfg.hidden_size
+            return {"fc1": dense((d, f), next(ks)),
+                    "fc2": dense((f, d), next(ks), scale=down_scale(f))}
+
+        def mamba_params(key) -> Params:
+            """One Mamba-2 mixer behind its norm: ``in_proj`` to ``[z | xBC
+            | dt]``, the depthwise convolution ``[kernel, channels]`` with
+            its bias, and per head ``A_log``, ``dt_bias`` and ``D`` over the
+            published init's range: A uniform in 1..16, dt log-uniform in
+            ``time_step_min..max`` (kept as the inverse softplus), D one."""
+            ks = iter(jax.random.split(key, 8))
+            d, di, h = cfg.hidden_size, cfg.ssm_d_inner, cfg.mamba_num_heads
+            c, kw = cfg.ssm_conv_dim, cfg.ssm_conv_kernel
+            bound = 1.0 / np.sqrt(kw)    # a depthwise conv's fan-in
+            dt = jnp.exp(jax.random.uniform(next(ks), (h,), jnp.float32)
+                         * (np.log(cfg.time_step_max)
+                            - np.log(cfg.time_step_min))
+                         + np.log(cfg.time_step_min))
+            dt = jnp.maximum(dt, cfg.time_step_floor)
+            return {
+                "norm": norm_params(),
+                "in_proj": dense((d, di + c + h), next(ks)),
+                "conv_w": jax.random.uniform(next(ks), (kw, c), jnp.float32,
+                                             -bound, bound),
+                "conv_b": jax.random.uniform(next(ks), (c,), jnp.float32,
+                                             -bound, bound),
+                "A_log": jnp.log(jax.random.uniform(
+                    next(ks), (h,), jnp.float32, 1.0, 16.0)),
+                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                "D": jnp.ones((h,), jnp.float32),
+                "gate_norm": {"scale": jnp.ones((di,), jnp.float32)},
+                "out_proj": dense((di, d), next(ks), scale=down_scale(di)),
+            }
+
         def hc_params(key) -> Params:
             """One sublayer's hyper-connection maps: the norm over all
             ``n * d`` stream values, ``phi`` [n*d, n + n + n*n] (columns
@@ -134,10 +176,15 @@ class CausalLM:
                         noise[:2 * n],
                         2.0 * jnp.eye(n).reshape(-1) + 0.5 * noise[2 * n:]])}
 
-        def layer_params(key, moe=cfg.any_moe) -> Params:
+        def layer_params(key, moe=cfg.any_moe, kind=None) -> Params:
+            """One layer; ``kind`` ('E' or '*') of a ``layer_pattern``
+            model: the one mixer behind its one norm."""
             ks = iter(jax.random.split(key, 16))
             d, f = cfg.hidden_size, cfg.intermediate_size
-            p: Params = {"attn_norm": norm_params(), "attn": attn_params(ks)}
+            if kind == "*":
+                return {"attn_norm": norm_params(), "attn": attn_params(ks)}
+            p: Params = {} if kind else {"attn_norm": norm_params(),
+                                         "attn": attn_params(ks)}
             if not cfg.shared_block_norm:
                 p["mlp_norm"] = norm_params()
             if cfg.hc_mult > 1:
@@ -154,18 +201,29 @@ class CausalLM:
                 down = down_scale(fe) / (e if cfg.n_shared_experts else 1)
                 if cfg.routed_write_share is not None:
                     down = down_scale(fe) * cfg.routed_write_share
+                glu = cfg.mlp_type == "glu"
+                # stored wider than drawn, zeros beyond the width
+                # (ModelConfig.expert_width_stored)
+                wide = cfg.expert_width_stored - fe
+                cols = lambda w: jnp.pad(  # noqa: E731
+                    w, ((0, 0), (0, 0), (0, wide))) if wide else w
                 p["moe"] = {
                     "router": dense((d, e), next(ks)),
-                    "w_gate": dense((held, d, fe), next(ks)),
-                    "w_up": dense((held, d, fe), next(ks)),
+                    # a two-matrix expert (mlp_type "mlp") has no gate
+                    **({"w_gate": cols(dense((held, d, fe), next(ks)))}
+                       if glu else {}),
+                    "w_up": cols(dense((held, d, fe), next(ks))),
                     "w_down": dense((held, fe, d), next(ks), scale=down),
                 }
+                if wide:
+                    p["moe"]["w_down"] = jnp.pad(
+                        p["moe"]["w_down"], ((0, 0), (0, wide), (0, 0)))
                 if cfg.topk_method == "noaux_tc":
                     # the selection-only bias
                     p["moe"]["router_bias"] = dense((e,), next(ks))
                 if cfg.n_shared_experts:
-                    p["moe"]["shared"] = glu_params(
-                        ks, fe * cfg.n_shared_experts)
+                    p["moe"]["shared"] = (glu_params if glu else mlp_params)(
+                        ks, cfg.shared_expert_width)
             elif cfg.mlp_type == "mlp":
                 p["mlp"] = {
                     "fc1": dense((d, f), next(ks)),
@@ -180,7 +238,21 @@ class CausalLM:
             return p
 
         n_dense = cfg.first_k_dense_replace
-        if cfg.scan_layers:
+        stacks: Params = {}
+        if cfg.layer_pattern is not None:
+            # three stacks, each in the order its layers come in the pattern
+            lkeys = jax.random.split(next(keys), cfg.num_layers)
+            of = lambda kind: lkeys[np.asarray(  # noqa: E731
+                [i for i, c in enumerate(cfg.layer_pattern) if c == kind],
+                np.int32)]
+            layers = jax.vmap(lambda k: layer_params(k, kind="E"))(of("E")) \
+                if cfg.pattern_count("E") else {}
+            if cfg.pattern_count("M"):
+                stacks["mamba_layers"] = jax.vmap(mamba_params)(of("M"))
+            if cfg.pattern_count("*"):
+                stacks["attn_layers"] = jax.vmap(
+                    lambda k: layer_params(k, kind="*"))(of("*"))
+        elif cfg.scan_layers:
             lkeys = jax.random.split(next(keys), cfg.num_layers)
             # stacked leaves [L, ...]; the leading dense layers of a
             # first_k_dense_replace model are a stack of their own
@@ -192,6 +264,7 @@ class CausalLM:
             "embed": {"embedding": dense((cfg.vocab_size, cfg.hidden_size),
                                          next(keys))},
             "layers": layers,
+            **stacks,
             "final_norm": norm_params(),
         }
         if n_dense:
@@ -268,6 +341,12 @@ class CausalLM:
                  ) -> Tuple[jnp.ndarray, Optional[KVCache], jnp.ndarray]:
         """Returns (logits [B,S,V] fp32, new_cache, total_aux_loss)."""
         cfg = self.config
+        if cfg.layer_pattern is not None:
+            raise NotImplementedError(
+                "a layer_pattern model (Mamba-2, expert and attention layers "
+                "in one stack) runs on the serving path only "
+                "(inference/v2/model.py): the chunked scan's backward is not "
+                "written")
         if cfg.kv_lora_rank or cfg.hc_mult > 1 or cfg.first_k_dense_replace \
                 or cfg.experts_held != cfg.num_experts \
                 or cfg.topk_method == "group_limited_greedy":
@@ -583,8 +662,9 @@ class CausalLM:
         layer dim, which must never shard (scan iterates it)."""
         names = [getattr(k, "key", getattr(k, "name", str(k))) for k in path]
         s = "/".join(str(n) for n in names)
-        stacked = self.config.scan_layers and (
-            "layers" in names or "dense_layers" in names)
+        stacked = self.config.scan_layers and any(
+            n in names for n in ("layers", "dense_layers", "mamba_layers",
+                                 "attn_layers"))
         if stacked:
             # under pipeline parallelism the stacked layer dim shards over
             # ``pipe`` (each stage owns its contiguous layer block — the

@@ -64,7 +64,14 @@ SUB_SCOPES = ("moe_route", "moe_experts", "moe_combine", "moe_shared",
               # the absorption of W_UK into q and of W_UV out of the output
               "mla_proj", "mla_absorb",
               # hyper-connection streams: norm, maps, Sinkhorn, the mixes
-              "mhc")
+              "mhc",
+              # a Mamba-2 mixer (inference/v2/model.py, ops/ssm.py): the in
+              # and out projections; the depthwise convolution and its tail;
+              # the recurrence with its D skip (decode step or chunked
+              # scan); the gate and its grouped norm; and, INSIDE ssm_scan,
+              # the chunked scan's pieces apart from the one-token rows'
+              # state step that a mixed round runs beside them
+              "ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate", "ssm_chunk")
 
 #: named_scope label prefix — ``mfu.attn`` etc. Kept short and distinctive
 #: so the metadata regex can't false-positive on user scopes.

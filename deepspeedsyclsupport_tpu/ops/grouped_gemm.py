@@ -138,7 +138,7 @@ def tile_rows(group_sizes: jnp.ndarray, sorted_group: jnp.ndarray,
 def _kernel(layer_ref, group_ref, live_ref, x_ref, *refs, act):
     """One program per (column tile, row tile): the tile's rows times its
     expert's block(s). Two weight refs: ``act(x wg) * (x wu)`` on the float32
-    accumulators; one: ``x w``."""
+    accumulators; one: ``x w``, or ``act(x w)`` where an ``act`` is given."""
     del layer_ref, group_ref      # the BlockSpecs' own
     *w_refs, out_ref = refs
 
@@ -147,7 +147,10 @@ def _kernel(layer_ref, group_ref, live_ref, x_ref, *refs, act):
         x = x_ref[...]
         acc = [jnp.dot(x, w[...], preferred_element_type=jnp.float32)
                for w in w_refs]
-        y = acc[0] if len(acc) == 1 else act(acc[0]) * acc[1]
+        if len(acc) == 2:
+            y = act(acc[0]) * acc[1]
+        else:
+            y = acc[0] if act is None else act(acc[0])
         out_ref[...] = y.astype(out_ref.dtype)
 
 
@@ -223,6 +226,15 @@ def grouped_glu(rows, w_gate, w_up, tiles: RowTiles, *, layer=0, act,
     profile."""
     return _grouped_call(rows, (w_gate, w_up), tiles, layer, act,
                          "grouped_glu", interpret)
+
+
+def grouped_act(rows, w, tiles: RowTiles, *, layer=0, act,
+                interpret: bool = False):
+    """``act(rows w[g])`` for each tile's expert ``g``, the activation on
+    the float32 accumulator: the up projection of a two-matrix expert,
+    ``grouped_act`` in a profile."""
+    return _grouped_call(rows, (w,), tiles, layer, act, "grouped_act",
+                         interpret)
 
 
 def grouped_matmul(rows, w, tiles: RowTiles, *, layer=0,

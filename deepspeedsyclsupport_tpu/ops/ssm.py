@@ -1,0 +1,287 @@
+"""Mamba-2 (SSD, arXiv:2405.21060) for the serving path: the recurrence of one
+mixer over a continuous batch, against a state that lives per sequence SLOT.
+
+``h`` heads of ``p`` channels, ``g`` groups of state ``n`` (head ``i`` reads
+group ``i // (h / g)``). Per token, after the depthwise causal convolution
+over the sequence's own ``xBC`` (kernel ``k``, zeros before its first token):
+
+    dt  = softplus(dt + dt_bias)             A = -exp(A_log)         [h], f32
+    S_t = exp(dt A) S_{t-1} + dt x_t (x) B_t                          [h, p, n]
+    y_t = S_t C_t + D x_t
+
+The state pool (``inference/v2/kv_cache.BlockedKV.ssm`` / ``.conv``) holds, per Mamba layer
+and slot, ``S`` as ``[g, n, (h / g) x p]`` (float32: the state's width on
+the lanes, ``n`` on the sublanes, so that the update is elementwise on whole
+registers and ``S C`` sums over sublanes) and the convolution's tail, the
+last ``k - 1`` rows of ``xBC``, as ``[k - 1, slots, channels]`` (the slots on
+the sublanes: with the three taps there the compiler kept two layouts of
+the pool and copied it whole between them, four times a forward). Slot ``S`` (the last) is the sink padding
+writes to. A piece whose first position is 0 starts from zeros, whatever its
+slot held: the host resets nothing.
+
+Two entries, as the attention kernels have two tiles:
+
+* :func:`decode_step` — ONE token for each of ``[rows]`` slots: shift the
+  tail, update the state IN PLACE. On the TPU a Pallas kernel whose state
+  block is the pool's own ``[layer, slot]`` (scalar-prefetch indices, the
+  pool aliased to the output): each state is read once and written once,
+  2 x 2 MiB a row and layer at Nemotron-3-Nano's sizes, which is the step's
+  whole cost. ``xla``: gather, update, scatter (the CPU tests' reference,
+  and what the kernel is held against).
+* :func:`chunked_scan` — the pieces of the chunks of two tokens or more in
+  one flat batch (``ragged.ssm_pieces``: single-sequence runs of at most
+  ``chunk`` rows, as the attention's atoms): inside a piece the quadratic
+  form, the state passed from piece to piece of one sequence THROUGH ITS
+  SLOT (the pieces run in order, under one loop whose trip count is the
+  live pieces), the first seeded from the slot or from zeros, the last left
+  there. Its recurrence carries the scope ``ssm_chunk`` inside ``ssm_scan``.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..monitor.mfu import scope
+
+
+def default_impl() -> str:
+    """``pallas`` on the TPU, ``xla`` elsewhere."""
+    return "pallas" if jax.default_backend() == "tpu" else "xla"
+
+
+def _conv_weights(p):
+    return (p["conv_w"].astype(jnp.float32),
+            p["conv_b"].astype(jnp.float32))
+
+
+def _split_xbc(out, cfg):
+    """The convolution's output ``[rows, channels]`` as x ``[rows, g, hp]``
+    (a group's heads side by side), B and C ``[rows, g, n]``."""
+    di, g, n = cfg.ssm_d_inner, cfg.ssm_n_groups, cfg.ssm_state_size
+    rows = out.shape[0]
+    return (out[:, :di].reshape(rows, g, di // g),
+            out[:, di:di + g * n].reshape(rows, g, n),
+            out[:, di + g * n:].reshape(rows, g, n))
+
+
+def _per_head(v, cfg):
+    """``[rows, h]`` (or ``[h]``) -> ``[rows, g, hp]``: a head's value over
+    its ``p`` channels, in the state's lane order."""
+    g, p = cfg.ssm_n_groups, cfg.mamba_head_dim
+    lead = v.shape[:-1]
+    return jnp.repeat(v.reshape(*lead, g, -1), p, axis=-1)
+
+
+def _dt_decay(dt, p, cfg):
+    """``(dt [rows, h] after bias and softplus, dt A [rows, h])``, float32;
+    ``time_step_limit`` (0, inf): no clamp."""
+    dtv = jax.nn.softplus(dt.astype(jnp.float32)
+                          + p["dt_bias"].astype(jnp.float32))
+    return dtv, dtv * -jnp.exp(p["A_log"].astype(jnp.float32))
+
+
+# ----------------------------------------------------------- the decode step
+def _state_step_xla(pool, layer, slots, keep, decay, dtx, b, c):
+    """``pool[layer, slots]`` one step on: gather, update, scatter (a
+    scatter on the loop-carried pool is in place; the gather is a copy).
+    ``keep`` [rows] float32 0/1: 0 starts the row from zeros."""
+    state = pool[layer, slots] * keep[:, None, None, None]
+    new = state * decay[:, :, None, :] + b[:, :, :, None] * dtx[:, :, None, :]
+    y = jnp.einsum("sgnq,sgn->sgq", new, c,
+                   precision=jax.lax.Precision.HIGHEST)
+    return y, pool.at[layer, slots].set(new.astype(pool.dtype))
+
+
+def _state_step_kernel(layer_ref, slots_ref, keep_ref, decay_ref, dtx_ref,
+                       bt_ref, ct_ref, st_ref, y_ref, out_ref, *, groups):
+    """One row's whole state ``[g, n, hp]``: read once, written once."""
+    del layer_ref, slots_ref          # the BlockSpecs' own
+    keep = keep_ref[pl.program_id(0)].astype(jnp.float32)
+    for g in range(groups):
+        new = (st_ref[g].astype(jnp.float32) * keep) * decay_ref[g:g + 1, :] \
+            + bt_ref[:, g:g + 1] * dtx_ref[g:g + 1, :]
+        out_ref[g] = new.astype(out_ref.dtype)
+        y_ref[g:g + 1, :] = jnp.sum(new * ct_ref[:, g:g + 1], axis=0,
+                                    keepdims=True)
+
+
+def _state_step_pallas(pool, layer, slots, keep, decay, dtx, b, c,
+                       interpret=False):
+    """The same step with the pool aliased to the output: block ``[layer,
+    slots[row]]`` of the pool in, the same block out. B and C come
+    transposed, ``[rows, n, g]`` (a column a group: what the sublanes of a
+    state block are multiplied by). Rows that share a slot (the sink) write
+    it one after another; nobody reads it."""
+    rows, g, n, hp = (slots.shape[0], *pool.shape[2:])
+    row = lambda s, *_: (s, 0, 0)                     # noqa: E731
+    state = lambda s, layer_ref, slots_ref, keep_ref: (  # noqa: E731
+        layer_ref[0], slots_ref[s], 0, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3, grid=(rows,),
+        in_specs=[pl.BlockSpec((None, g, hp), row),
+                  pl.BlockSpec((None, g, hp), row),
+                  pl.BlockSpec((None, n, g), row),
+                  pl.BlockSpec((None, n, g), row),
+                  pl.BlockSpec((None, None, g, n, hp), state)],
+        out_specs=[pl.BlockSpec((None, g, hp), row),
+                   pl.BlockSpec((None, None, g, n, hp), state)])
+    block = g * n * hp * pool.dtype.itemsize
+    y, pool = pl.pallas_call(
+        functools.partial(_state_step_kernel, groups=g),
+        out_shape=[jax.ShapeDtypeStruct((rows, g, hp), jnp.float32),
+                   jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
+        grid_spec=grid_spec,
+        # operands count the scalar-prefetch three: the pool is the 8th
+        input_output_aliases={7: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=min(max(6 * block + (8 << 20), 16 << 20),
+                                 100 << 20)),
+        interpret=interpret, name="ssm_state_step",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), slots.astype(jnp.int32),
+      keep.astype(jnp.int32), decay, dtx, b.swapaxes(1, 2), c.swapaxes(1, 2),
+      pool)
+    return y, pool
+
+
+STATE_STEPS = {
+    "xla": _state_step_xla,
+    "pallas": _state_step_pallas,
+    "pallas_interpret": functools.partial(_state_step_pallas,
+                                          interpret=True),
+}
+
+
+def decode_step(xbc, dt, p, ssm, conv, layer, slots, fresh, cfg, step=None):
+    """One token for each row. ``xbc`` [rows, channels] and ``dt`` [rows, h]
+    as ``in_proj`` gives them; ``p`` the layer's leaves; ``ssm`` / ``conv``
+    the pools, ``layer`` the Mamba layer, ``slots`` [rows] each row's state
+    slot (the sink for a row that is padding), ``fresh`` [rows] bool: the
+    row is its sequence's first token. ``step``: one of :data:`STATE_STEPS`
+    (None: by platform; the serving forwards resolve theirs through the
+    engine's ``module_registry``, kind ``ssm_step``). -> ``(y [rows,
+    d_inner] float32, ssm, conv)``."""
+    step = step or STATE_STEPS[default_impl()]
+    keep = jnp.logical_not(fresh)
+    with scope("ssm_conv"):
+        w, bias = _conv_weights(p)
+        k = w.shape[0]
+        # the window's rows, oldest first: the tail's k - 1, then the token
+        win = [jnp.where(keep[:, None], conv[layer, j, slots], 0)
+               for j in range(k - 1)] + [xbc.astype(conv.dtype)]
+        out = jax.nn.silu(bias + sum(
+            w[j] * win[j].astype(jnp.float32) for j in range(k)))
+        for j in range(k - 1):
+            conv = conv.at[layer, j, slots].set(win[j + 1])
+    with scope("ssm_scan"):
+        x, b, c = _split_xbc(out, cfg)
+        dtv, da = _dt_decay(dt, p, cfg)
+        y, ssm = step(ssm, layer, slots, keep.astype(jnp.float32),
+                      _per_head(jnp.exp(da), cfg), _per_head(dtv, cfg) * x,
+                      b, c)
+        y = y + _per_head(p["D"].astype(jnp.float32), cfg) * x
+    return y.reshape(y.shape[0], -1), ssm, conv
+
+
+# --------------------------------------------------------- the chunked scan
+def _piece_scan(x, b, c, dtv, da, state, cfg, dtype):
+    """One piece of ONE sequence, ``q`` rows (a row that is not the piece's
+    has ``dt`` 0: it decays nothing and adds nothing): the quadratic form
+    inside the piece, ``state`` [g, n, hp] in and out. x [q, g, hp]; b, c
+    [q, g, n]; dtv, da [q, h] float32. The products take their operands in
+    ``dtype`` (the activations') and accumulate in float32."""
+    q, g, hp = x.shape
+    hg = cfg.mamba_num_heads // g
+    f32 = jnp.float32
+    mm = functools.partial(jnp.einsum, preferred_element_type=f32,
+                           precision=jax.lax.Precision.HIGHEST
+                           if dtype == f32 else None)
+    cum = jnp.cumsum(da, axis=0)                               # [q, h]
+    seen = jnp.tril(jnp.ones((q, q), bool))
+    # exp(cum_i - cum_j) for j <= i: what row j's input has decayed to at i
+    lmat = jnp.exp(jnp.where(seen[:, :, None],
+                             cum[:, None, :] - cum[None, :, :], -jnp.inf))
+    cb = mm("ign,jgn->gij", c.astype(dtype), b.astype(dtype))  # [g, q, q]
+    w = lmat.transpose(2, 0, 1).reshape(g, hg, q, q) * cb[:, None]
+    dtx = (_per_head(dtv, cfg) * x).reshape(q, g, hg, -1)      # [q,g,hg,p]
+    y = mm("gkij,jgkp->igkp", w.astype(dtype), dtx.astype(dtype))
+    # the state the piece entered with, decayed to each row
+    st = state.astype(f32).reshape(g, -1, hg, hp // hg)        # [g,n,hg,p]
+    into = jnp.exp(cum).reshape(q, g, hg)
+    y = y + into[..., None] * mm("ign,gnkp->igkp", c.astype(f32), st,
+                                 precision=jax.lax.Precision.HIGHEST)
+    # and what it leaves: everything decayed to the last row
+    left = jnp.exp(cum[-1][None] - cum).reshape(q, g, hg)
+    new = jnp.exp(cum[-1]).reshape(g, 1, hg, 1) * st + mm(
+        "jgn,jgkp->gnkp", b.astype(dtype),
+        (left[..., None] * dtx).astype(dtype))
+    return y.reshape(q, g, hp), new.reshape(state.shape)
+
+
+def chunked_scan(xbc, dt, p, ssm, conv, layer, pieces, cfg):
+    """The chunks of two tokens or more of a flat batch. ``xbc`` [T,
+    channels], ``dt`` [T, h]; ``pieces`` = ``(row0, length, slot, fresh)``
+    each [pieces], live ones first, and their count (``ragged.ssm_pieces``):
+    rows ``row0 .. row0 + length`` of the flat axis are ``length <= chunk``
+    consecutive tokens of the sequence in state slot ``slot``, and ``fresh``
+    says the first of them is the sequence's first. -> ``(y [T, d_inner]
+    float32, zero where no piece lies; ssm; conv)``."""
+    row0, length, slots, fresh, count = pieces
+    q, k = cfg.ssm_chunk_size, cfg.ssm_conv_kernel
+    t, dtype = xbc.shape[0], xbc.dtype
+    w, bias = _conv_weights(p)
+    d_skip = _per_head(p["D"].astype(jnp.float32), cfg)
+    # a window of q rows from any row0 < T stays inside the padded arrays
+    xbc = jnp.pad(xbc, ((0, q), (0, 0)))
+    dt = jnp.pad(dt, ((0, q), (0, 0)))
+
+    def piece(i, carry):
+        ssm, conv, y_all = carry
+        r0, n, slot = row0[i], length[i], slots[i]
+        keep = jnp.logical_not(fresh[i])
+        valid = (jnp.arange(q) < n)[:, None]
+        with scope("ssm_conv"):
+            rows = jnp.where(valid, jax.lax.dynamic_slice_in_dim(xbc, r0, q),
+                             0)
+            tail = jnp.where(keep, conv[layer, :, slot], 0)
+            ext = jnp.concatenate([tail, rows.astype(conv.dtype)])
+            out = jax.nn.silu(bias + sum(
+                w[j] * ext[j:j + q].astype(jnp.float32) for j in range(k)))
+            # the last k - 1 inputs behind row n: the old tail's where n is
+            # shorter than it
+            conv = conv.at[layer, :, slot].set(
+                jax.lax.dynamic_slice_in_dim(ext, n, k - 1))
+        # ssm_chunk inside ssm_scan: the pieces' own time, apart from the
+        # state step of the one-token rows beside them (decode_step)
+        with scope("ssm_scan"), scope("ssm_chunk"):
+            x, b, c = _split_xbc(out, cfg)
+            dtv, da = _dt_decay(jax.lax.dynamic_slice_in_dim(dt, r0, q), p,
+                                cfg)
+            dtv, da = jnp.where(valid, dtv, 0), jnp.where(valid, da, 0)
+            state = jnp.where(keep, ssm[layer, slot], 0)
+            y, state = _piece_scan(x, b, c, dtv, da, state, cfg, dtype)
+            y = (y + d_skip * x).reshape(q, -1)
+            ssm = ssm.at[layer, slot].set(state.astype(ssm.dtype))
+            y_all = jax.lax.dynamic_update_slice_in_dim(
+                y_all, jnp.where(
+                    valid, y, jax.lax.dynamic_slice_in_dim(y_all, r0, q)),
+                r0, 0)
+        return ssm, conv, y_all
+
+    ssm, conv, y_all = jax.lax.fori_loop(
+        0, count, piece,
+        (ssm, conv, jnp.zeros((t + q, cfg.ssm_d_inner), jnp.float32)))
+    return y_all[:t], ssm, conv
+
+
+def gated_norm(y, z, scale, cfg):
+    """``RMSNorm(y * silu(z))`` in ``ssm_n_groups`` groups of ``d_inner / g``
+    channels (``eps`` the model's), times the ``d_inner``-wide weight.
+    float32 in, float32 out."""
+    rows, g = y.shape[0], cfg.ssm_n_groups
+    u = (y * jax.nn.silu(z.astype(jnp.float32))).reshape(rows, g, -1)
+    u = u * jax.lax.rsqrt(jnp.mean(jnp.square(u), -1, keepdims=True)
+                          + cfg.rms_norm_eps)
+    return u.reshape(rows, -1) * scale.astype(jnp.float32)

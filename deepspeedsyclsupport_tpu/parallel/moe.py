@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..models.layers import constrain, glu_mlp
+from ..models.layers import _activation, constrain, glu_mlp, std_mlp
 
 
 def topk_weights(probs: jnp.ndarray, k: int, normalise: bool,
@@ -113,9 +113,21 @@ def topk_gating(logits: jnp.ndarray, k: int, capacity: int,
     return dispatch, combine, aux_loss
 
 
+def _expert_form(cfg):
+    """``(gated, activation)`` of an expert, on both paths: ``mlp_type``
+    ``"glu"`` is three matrices, ``act(x w_gate) * (x w_up)`` then ``w_down``
+    (silu, else gelu, as it always was); ``"mlp"`` is two and no gate,
+    ``act(x w_up) w_down`` with the model's own activation, and
+    ``init_params`` draws no ``w_gate``."""
+    if cfg.mlp_type == "glu":
+        return True, (jax.nn.silu if cfg.activation == "silu"
+                      else jax.nn.gelu)
+    return False, _activation(cfg.activation)
+
+
 def moe_mlp(p: Dict[str, Any], x: jnp.ndarray, cfg,
             rng: Optional[jax.Array] = None) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """MoE GLU block (reference ``MOELayer.forward``, ``moe/sharded_moe.py:425``).
+    """MoE block (reference ``MOELayer.forward``, ``moe/sharded_moe.py:425``).
 
     x: [B, S, D] → (out [B, S, D], aux_loss scalar).
     """
@@ -137,15 +149,16 @@ def moe_mlp(p: Dict[str, Any], x: jnp.ndarray, cfg,
     expert_in = jnp.einsum("tec,td->ecd", dispatch.astype(x.dtype), xt)
     expert_in = constrain(expert_in, "expert", None, None)
 
-    act = jax.nn.silu if cfg.activation == "silu" else jax.nn.gelu
+    glu, act = _expert_form(cfg)
 
     def one_expert(w, h):  # h: [C, D]
-        gate = jnp.einsum("cd,df->cf", h, w["w_gate"])
         up = jnp.einsum("cd,df->cf", h, w["w_up"])
-        return jnp.einsum("cf,fd->cd", act(gate) * up, w["w_down"])
+        mid = act(jnp.einsum("cd,df->cf", h, w["w_gate"])) * up if glu \
+            else act(up)
+        return jnp.einsum("cf,fd->cd", mid, w["w_down"])
 
     expert_out = jax.vmap(one_expert)(
-        {"w_gate": p["w_gate"], "w_up": p["w_up"], "w_down": p["w_down"]},
+        {n: p[n] for n in ("w_gate", "w_up", "w_down") if n in p},
         expert_in)                                               # [E, C, D]
     expert_out = constrain(expert_out, "expert", None, None)
 
@@ -217,8 +230,13 @@ def moe_mlp_nodrop(p: Dict[str, Any], x: jnp.ndarray, cfg,
     added is left out, and the partial sum is the layer's result. On one
     chip there is no exchange, and nothing stands in for the other chips.
 
-    A model with shared experts (``p["shared"]``) adds their SwiGLU of every
+    A model with shared experts (``p["shared"]``) adds their MLP of every
     row beside the routed sum.
+
+    ``cfg.mlp_type == "mlp"``: an expert is TWO matrices and no gate,
+    ``act(x w_up) w_down`` (nemotron_h's ``relu^2`` experts), the activation
+    on the up projection's float32 accumulator (``grouped_act``); the shared
+    expert is then ``layers.std_mlp``'s ``fc1`` / ``fc2``.
 
     ``p["w_gate" | "w_up" | "w_down"]`` are one layer's ``[E_held, ., .]`` or
     the whole stack ``[L, E_held, ., .]`` with ``layer`` (static or traced)
@@ -280,20 +298,22 @@ def moe_mlp_nodrop(p: Dict[str, Any], x: jnp.ndarray, cfg,
         else:
             xs = x[sorted_tok]                                # [T*k, D]
 
-    act = jax.nn.silu if cfg.activation == "silu" else jax.nn.gelu
+    glu, act = _expert_form(cfg)
     with scope("moe_experts"):
         if impl == "xla":
             def grouped(rows, w):
                 return jax.lax.ragged_dot(
                     rows, *_layer_groups(w, group_sizes, layer, x.dtype))
 
-            gate = grouped(xs, p["w_gate"])
             up = grouped(xs, p["w_up"])
-            ys = grouped(act(gate) * up, p["w_down"])         # [T*k, D]
+            mid = act(grouped(xs, p["w_gate"])) * up if glu else act(up)
+            ys = grouped(mid, p["w_down"])                    # [T*k, D]
         else:
             kw = dict(layer=layer, interpret=impl == "pallas_interpret")
-            mid = grouped_gemm.grouped_glu(xs, p["w_gate"], p["w_up"], tiles,
-                                           act=act, **kw)
+            mid = grouped_gemm.grouped_glu(
+                xs, p["w_gate"], p["w_up"], tiles, act=act, **kw) if glu \
+                else grouped_gemm.grouped_act(xs, p["w_up"], tiles, act=act,
+                                              **kw)
             ys = grouped_gemm.grouped_matmul(mid, p["w_down"], tiles,
                                              **kw)[tiles.dest]   # [T*k, D]
 
@@ -306,5 +326,6 @@ def moe_mlp_nodrop(p: Dict[str, Any], x: jnp.ndarray, cfg,
         out = jnp.zeros((t, d), x.dtype).at[sorted_tok].add(ys)  # moe_gather
     if "shared" in p:
         with scope("moe_shared"):
-            out = out + glu_mlp(p["shared"], x[None], cfg)[0]
+            out = out + (glu_mlp if glu else std_mlp)(
+                p["shared"], x[None], cfg)[0]
     return out, routed

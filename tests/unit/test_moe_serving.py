@@ -223,3 +223,35 @@ def test_a_dense_model_carries_no_counter_and_an_unchanged_sampler():
               if r["data"].get("stage") == "round"]
     assert rounds and all(d["moe_touched"] == 0 for d in rounds)
     sess.close()
+
+
+def test_a_two_matrix_expert_is_one_function_on_both_paths():
+    """``mlp_type="mlp"`` with experts (a GPT-2-era block made sparse):
+    ``init_params`` draws no ``w_gate`` and both the training path's
+    capacity buffers and the serving path's grouped matmuls compute
+    ``act(x w_up) w_down`` with the model's own activation."""
+    from deepspeedsyclsupport_tpu.parallel.moe import moe_mlp
+
+    model = build_model("tiny-moe", mlp_type="mlp", activation="gelu",
+                        capacity_factor=2.0, dtype="float32")
+    cfg, params = model.config, model.init_params()
+    assert set(params["layers"]["moe"]) == {"router", "w_up", "w_down"}
+    p = _layer0(params)
+    x = jax.random.normal(jax.random.PRNGKey(3), (1, 12, cfg.hidden_size))
+    # capacity 12 x 2.0 x 2 / 4 = 12 rows an expert: nothing is dropped
+    trained, _aux = moe_mlp(p, x, cfg)
+    served, _rows = moe_mlp_nodrop(p, x[0], cfg)
+    np.testing.assert_allclose(np.asarray(trained[0]), np.asarray(served),
+                               rtol=1e-5, atol=1e-6)
+    # by hand, for one token's first choice
+    probs = jax.nn.softmax(x[0] @ p["router"], axis=-1)
+    w, idx = topk_weights(probs, cfg.num_experts_per_tok, cfg.norm_topk_prob)
+    want = sum(w[0, j] * (jax.nn.gelu(x[0, 0] @ p["w_up"][idx[0, j]])
+                          @ p["w_down"][idx[0, j]])
+               for j in range(cfg.num_experts_per_tok))
+    np.testing.assert_allclose(np.asarray(served[0]), np.asarray(want),
+                               rtol=1e-4, atol=1e-6)
+    # and the whole model's training forward runs on those leaves
+    ids = jnp.arange(16, dtype=jnp.int32).reshape(1, 16)
+    logits = model.apply(params, ids)[0]
+    assert np.isfinite(np.asarray(logits)).all()
