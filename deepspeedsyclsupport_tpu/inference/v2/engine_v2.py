@@ -233,16 +233,21 @@ class InferenceEngineV2:
         self._use_atoms = bool(spec.metadata.get("needs_atoms"))
         chose_atom = isinstance(config, RaggedInferenceConfig) or {
             **(config or {}), **kw}.get("atom_q_size") is not None
-        if self._use_atoms and not chose_atom:
-            # nobody chose the atom's rows: the pool's shape does (one kv
-            # head for a latent pool, which has no head axis)
-            from ...ops.paged_attention import default_atom_rows
+        from ...ops.paged_attention import default_atom_rows, kv_step_keys
 
-            kvh = 1 if self.kv.v is None else self.kv.k.shape[-2]
-            cfg.atom_q_size = default_atom_rows(
-                cfg.atom_q_size, model.config.num_heads, kvh,
-                self.kv.k.shape[-1], cfg.block_size,
-                jnp.dtype(cfg.dtype).itemsize)
+        # the pool's shape as the paged kernels see it (one kv head for a
+        # latent pool, which has no head axis)
+        latent = self.kv.v is None
+        shape = (model.config.num_heads,
+                 1 if latent else self.kv.k.shape[-2], self.kv.k.shape[-1],
+                 cfg.block_size, jnp.dtype(cfg.dtype).itemsize)
+        if self._use_atoms and not chose_atom:
+            # nobody chose the atom's rows: the pool's shape does
+            cfg.atom_q_size = default_atom_rows(cfg.atom_q_size, *shape)
+        # keys a loop step of the kernel covers under an atom and under a
+        # one-row tile (attention_work's kv_step_keys)
+        self._kv_step_keys = tuple(kv_step_keys(rows, *shape, latent)
+                                   for rows in (cfg.atom_q_size, 1))
         log_dist(f"ragged engine: {cfg.num_blocks} KV blocks × {cfg.block_size} "
                  f"tokens, budget {cfg.max_tokens_per_batch} tok/fwd, "
                  f"≤{cfg.max_sequences} seqs")
@@ -345,9 +350,11 @@ class InferenceEngineV2:
         ``atoms``: the live ``atom_q_size``-row tiles a ``ragged_forward``'s
         batch was cut into; its one-token chunks, like every row of a
         ``decode_forward``, are ``decode_rows``, a one-row tile each.
-        What those tiles cover (``attn_pairs``, ``dec_ctx_tokens``) is
-        ``ragged.attention_work``'s count. ``rows``: the forward's whole row
-        budget, pads included; a sparse-expert model's record gets
+        What those tiles cover (``attn_pairs``, ``dec_ctx_tokens``) and
+        what the kernels' loop steps walk for it (``kv_step_keys``,
+        ``kv_tile_keys``) is ``ragged.attention_work``'s count. ``rows``: the
+        forward's whole row budget, pads included; a sparse-expert model's
+        record gets
         ``reqtrace.MOE_STATIC_FIELDS``: the rows of the tiles its grouped
         GEMMs lay them in (static, by that shape) and the expert rows a live
         token brings. Its live tokens are counted whether or not a round is
@@ -355,9 +362,13 @@ class InferenceEngineV2:
         self._forward_tokens += sum(lengths)
         if self.round_spans is None:
             return
-        attn_pairs, dec_ctx_tokens = attention_work(descs, lengths)
+        attn_pairs, dec_ctx_tokens, kv_step_keys, kv_tile_keys = \
+            attention_work(descs, lengths,
+                           self.config.atom_q_size if self._use_atoms else 0,
+                           self._kv_step_keys)
         self.round_spans.fields.update(
             attn_pairs=attn_pairs, dec_ctx_tokens=dec_ctx_tokens,
+            kv_step_keys=kv_step_keys, kv_tile_keys=kv_tile_keys,
             n_seqs=len(descs), tokens=sum(lengths),
             decode_rows=sum(n == 1 for n in lengths), atoms=atoms,
             # the rule _run routes by: one token on top of cached context

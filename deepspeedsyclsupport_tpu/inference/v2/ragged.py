@@ -312,18 +312,39 @@ class RaggedBatch:
 
 
 def attention_work(descs: Sequence[SequenceDescriptor],
-                   lengths: Sequence[int]) -> Tuple[int, int]:
+                   lengths: Sequence[int], atom_q: int = 0,
+                   step_keys: Tuple[int, int] = (1, 1)
+                   ) -> Tuple[int, int, int, int]:
     """What the attention kernels of one forward over these chunks must
     cover, counted on the host from the chunks alone: ``attn_pairs``, the
     (row, cached token) pairs of the chunks of two tokens or more (the
     atoms' kernel; row r of a chunk that starts at position p0 attends
     p0 + r + 1 tokens, itself among them), and ``dec_ctx_tokens``, the
     context lengths of the one-token chunks, their own token included (the
-    one-row tile's kernel reads that many cached rows a layer)."""
+    one-row tile's kernel reads that many cached rows a layer).
+
+    Then what the kernels' loops walk for it, tile by tile, the atoms of
+    ``atom_q`` rows (0: the attention takes none, and the longer chunks
+    count nothing here) and the one-row tiles alike: ``kv_tile_keys``, the
+    keys each tile may see (an atom's last row's position + 1; a one-row
+    tile's context), and ``kv_step_keys``, those rounded up to the whole
+    loop steps that cover them, ``step_keys`` = (an atom's, a one-row
+    tile's) keys a step (``paged_attention.kv_step_keys``: by the tile's
+    shape). Their ratio is what a step of several blocks pays at each
+    sequence's tail. Each tile counts once, however many head tiles or
+    layers read it again; a sliding window's skipped steps are not taken
+    off."""
     pairs = sum(n * d.n_cached + n * (n + 1) // 2
                 for d, n in zip(descs, lengths) if n > 1)
     ctx = sum(d.n_cached + 1 for d, n in zip(descs, lengths) if n == 1)
-    return pairs, ctx
+    tiles = [(d.n_cached + 1, step_keys[1])
+             for d, n in zip(descs, lengths) if n == 1]
+    if atom_q:
+        tiles += [(d.n_cached + min(r + atom_q, n), step_keys[0])
+                  for d, n in zip(descs, lengths) if n > 1
+                  for r in range(0, n, atom_q)]
+    return (pairs, ctx, sum(-(-hi // step) * step for hi, step in tiles),
+            sum(hi for hi, _step in tiles))
 
 
 def build_ragged_batch(chunks: Sequence[Tuple[SequenceDescriptor, int]],

@@ -109,7 +109,10 @@ ROUND_PHASES = (
 #: ``ragged_forward`` were cut into (0 where the attention takes no atoms);
 #: and what those tiles cover (``ragged.attention_work``): ``attn_pairs``,
 #: the (row, cached token) pairs of the chunks of two tokens or more, and
-#: ``dec_ctx_tokens``, the one-token chunks' context lengths.
+#: ``dec_ctx_tokens``, the one-token chunks' context lengths; and what the
+#: kernels' loops walk for it, summed over the atoms and the one-row tiles:
+#: ``kv_tile_keys``, the keys each tile may see, and ``kv_step_keys``, those
+#: rounded up to whole loop steps (several KV blocks on a latent pool).
 #: ``moe_touched`` is the one field the DEVICE counts (a sparse-expert
 #: model's experts with at least one live row, summed over layers: the
 #: expert weights a forward had to read; 0 for a dense model). It comes back
@@ -122,7 +125,8 @@ ROUND_PHASES = (
 #: EOS, which the host learns at the read-back, one forward late.
 FORWARD_FIELDS = ("n_seqs", "tokens", "prefill_tokens", "ctx_tokens",
                   "kv_blocks", "decode_rows", "atoms", "attn_pairs",
-                  "dec_ctx_tokens", "moe_touched", "ahead", "spec_rows")
+                  "dec_ctx_tokens", "moe_touched", "ahead", "spec_rows",
+                  "kv_step_keys", "kv_tile_keys")
 #: What the device counts, in the order it rides behind the sampled tokens
 #: (``engine.moe_tail``). ``moe_rows`` is on the record ONLY of a program
 #: that holds a share of the router's experts (one chip of an expert-

@@ -25,10 +25,14 @@ Kernel shape (TPU-first, not a CUDA translation):
   position and its live rows ride in as SCALAR-PREFETCH args so KV block
   DMAs can be issued immediately (``PrefetchScalarGridSpec`` — the Pallas
   idiom for indirect addressing).
-* K/V stay in HBM; each loop iteration DMAs ONE KV block into VMEM scratch
-  and folds it into an online-softmax accumulator (flash recurrence), so VMEM
-  holds O(block_size · D) regardless of context length, and compute overlaps
-  the next block's fetch via the DMA queue.
+* K/V stay in HBM; each loop iteration (a STEP) DMAs its KV blocks into one
+  of two VMEM scratch slots, each block through its own block-table entry,
+  and folds them into an online-softmax accumulator (flash recurrence) with
+  ONE ``q k^T``, one mask / max / exp / sum, one ``p v`` and one rescale of
+  the accumulator, so VMEM holds O(step · D) regardless of context length,
+  and compute overlaps the next step's fetch via the DMA queue. A step is
+  ONE block on a K-and-V pool and several on a latent pool, by the tile's
+  shape (:func:`_kv_pages_per_step`).
 * GQA: queries reshape to [KVH, G, D] and each kv head batch-matmuls its
   group — grouped heads share the streamed KV block, the reason GQA decode is
   bandwidth-cheap on TPU.
@@ -40,8 +44,13 @@ normed latent; the row's tail is the rotated key all heads share). The same
 body then streams one tile a block instead of two, slices V from the K tile
 already in VMEM, returns ``[..., H, n]``, and feeds the MXU the pool's own
 dtype: under 32 query heads the absorbed form is ~70 kFLOP a (row, cached
-token), compute-bound, where float32 operands cost several passes. Decided
-at trace time: a K-and-V pool's program is what it was.
+token), compute-bound, where float32 operands cost several passes. Its block
+is small (80 KiB at 64 rows x 640 bf16: half the MXU's columns and depth,
+one short DMA), so its step takes several: on the v5e a 2,048-row tile pays
+5.5 us a block at one a step and 2.4 at four (the rescale of a 4 MiB
+accumulator and the [rows, 1] columns of the softmax are paid a step, not a
+block), a one-row tile 0.47-0.54 against 0.17-0.18 at eight (PERF.md, PR
+38). Decided at trace time: a K-and-V pool's program is what it was.
 
 An exact jnp reference (:func:`paged_decode_attention_reference`) serves
 off-TPU fallback and the kernel-vs-reference parity tests (the pattern the
@@ -131,7 +140,7 @@ def paged_decode_attention(q, k_cache, v_cache, block_tables, seq_lens, *,
 def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
                     q_ref, k_hbm, *refs,
                     block_size: int, max_blocks: int, group: int,
-                    use_alibi: bool, window, v_dim=None):
+                    use_alibi: bool, window, v_dim=None, pages: int = 1):
     """One program per ATOM: a ≤block_q-token slice of ONE sequence's packed
     prefill chunk — or, at ``BQ = 1`` (the decode entry), one sequence's
     newest token; the serving forwards never put a one-token chunk into a
@@ -148,12 +157,13 @@ def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
     leading lanes of the K tile) has neither V nor its scratch. A second
     grid axis, where the wrapper made one, tiles the heads of the ONE kv
     head: the body sees its tile's heads only and needs no index of it."""
-    if v_dim is None:
-        v_hbm, ab_ref, out_ref, k_vmem, v_vmem, sem = refs
-        mxu = jnp.float32
-    else:
+    latent = v_dim is not None
+    if latent:
         ab_ref, out_ref, k_vmem, sem = refs
         mxu = k_vmem.dtype
+    else:
+        v_hbm, ab_ref, out_ref, k_vmem, v_vmem, sem = refs
+        mxu = jnp.float32
     a = pl.program_id(0)
     pos0 = pos0_ref[a]
     qlen = qlen_ref[a]
@@ -168,69 +178,100 @@ def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
         lo_blk = jnp.maximum(pos0 + 1 - window, 0) // block_size
     else:
         lo_blk = jnp.int32(0)
-    q = q_ref[0].astype(jnp.float32)          # [BQ, H, D]
+    # the loop below walks STEPS of ``pages`` blocks: one block where the
+    # wrapper gave one (a K-and-V pool: the loop over blocks it always was)
+    step_keys = pages * block_size
+    lo_step = lo_blk if pages == 1 else lo_blk // pages
+    # a latent pool feeds the MXU its own dtype: no float32 copy of q
+    q = q_ref[0] if latent else q_ref[0].astype(jnp.float32)   # [BQ, H, D]
     bq, h, d = q.shape
-    d_v = d if v_dim is None else v_dim
-    kvh = 1 if v_dim is not None else k_vmem.shape[2]
+    d_v = v_dim if latent else d
+    kvh = 1 if latent else k_vmem.shape[2]
     g = group
     # [KVH, BQ·G, D]: kv head-major so each kv head batch-matmuls its group
     q_g = jnp.transpose(q.reshape(bq, kvh, g, d), (1, 0, 2, 3)) \
         .reshape(kvh, bq * g, d).astype(mxu)
     # q row of each [BQ·G] lane (its position is pos0 + row)
-    row = jax.lax.broadcasted_iota(jnp.int32, (kvh, bq * g, block_size),
+    row = jax.lax.broadcasted_iota(jnp.int32, (kvh, bq * g, step_keys),
                                    1) // g
 
-    def copies(j, slot):
-        blk = block_tables_ref[a, j]
-        pools = ((k_hbm, k_vmem),) if v_dim is not None \
-            else ((k_hbm, k_vmem), (v_hbm, v_vmem))
-        return [pltpu.make_async_copy(
-            hbm.at[layer, pl.ds(blk * block_size, block_size)],
-            vmem.at[slot], sem.at[slot, i])
-            for i, (hbm, vmem) in enumerate(pools)]
+    def block_of(step, i):
+        """Block ``i`` of ``step``, as an index into the table's row. A
+        wide step's blocks below the window's first or past the context's
+        last are read from the nearest block the loop of one block a step
+        would read: every key of such a block is masked by its POSITION,
+        but 0 x whatever lay in scratch or in a page the sequence does not
+        own is NaN where that is NaN."""
+        if pages == 1:
+            return step
+        return jnp.clip(step * pages + i, lo_blk, (kv_hi - 1) // block_size)
 
-    # guard on lo_blk (not just kv_hi > 0): with a sliding window and pos0
+    def copies(step, slot):
+        pools = ((k_hbm, k_vmem),) if latent \
+            else ((k_hbm, k_vmem), (v_hbm, v_vmem))
+        cps = []
+        for i in range(pages):       # each block through its own table entry
+            blk = block_tables_ref[a, block_of(step, i)]
+            for n, (hbm, vmem) in enumerate(pools):
+                dst = vmem.at[slot] if pages == 1 else \
+                    vmem.at[slot, pl.ds(i * block_size, block_size)]
+                cps.append(pltpu.make_async_copy(
+                    hbm.at[layer, pl.ds(blk * block_size, block_size)],
+                    dst, sem.at[slot, n]))
+        return cps
+
+    # guard on lo_step (not just kv_hi > 0): with a sliding window and pos0
     # beyond the table's capacity, lo_blk can reach max_blocks — the loop
     # below would run zero iterations, so an unguarded warm-up would index
     # the table out of bounds and start a DMA that is never awaited
-    @pl.when(lo_blk * block_size < kv_hi)
+    @pl.when(lo_step * step_keys < kv_hi)
     def _():
-        for cp in copies(lo_blk, jax.lax.rem(lo_blk, 2)):
+        for cp in copies(lo_step, jax.lax.rem(lo_step, 2)):
             cp.start()
 
     def body(j, carry):
         m, l, acc = carry
-        active = j * block_size < kv_hi
+        # always true inside the loop's bounds; a K-and-V pool's program
+        # keeps the selects on it that it was accepted with
+        active = j * step_keys < kv_hi
         cur = jax.lax.rem(j, 2)
 
-        @pl.when(jnp.logical_and((j + 1) * block_size < kv_hi,
-                                 j + 1 < max_blocks))
+        @pl.when(jnp.logical_and((j + 1) * step_keys < kv_hi,
+                                 j + 1 < -(-max_blocks // pages)))
         def _():
             for cp in copies(j + 1, jax.lax.rem(j + 1, 2)):
                 cp.start()
 
-        @pl.when(active)
-        def _():
+        def wait():
             for cp in copies(j, cur):
                 cp.wait()
 
-        if v_dim is not None:                  # rows [bs, D], one kv head
+        if latent:                             # rows [keys, D], one kv head
+            wait()
             k_t = k_vmem[cur][None]
             v_t = k_t[..., :d_v]
         else:
+            pl.when(active)(wait)
             k = k_vmem[cur].astype(jnp.float32)    # [bs, KVH, D]
             v = v_vmem[cur].astype(jnp.float32)
             k_t = jnp.transpose(k, (1, 0, 2))      # [KVH, bs, D]
             v_t = jnp.transpose(v, (1, 0, 2))
-        scores = jax.lax.dot_general(           # [KVH, BQ·G, bs]
+        scores = jax.lax.dot_general(           # [KVH, BQ·G, keys]
             q_g, k_t, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) / np.sqrt(d)
-        pos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (kvh, bq * g, block_size), 2)
+        pos = j * step_keys + jax.lax.broadcasted_iota(
+            jnp.int32, (kvh, bq * g, step_keys), 2)
         if use_alibi:
             scores = scores + ab_ref[...].astype(jnp.float32) * (
                 pos - (pos0 + row)).astype(jnp.float32)
-        valid = jnp.logical_and(pos <= pos0 + row,   # per-row causality
+        seen = pos0 + row                            # per-row causality
+        if pages > 1:
+            # a wide step's last blocks lie past kv_hi, where the loop of
+            # one block a step never went: a context longer than its table
+            # sees what the table holds, under either loop
+            seen = jnp.minimum(seen, kv_hi - 1)
+        valid = jnp.logical_and(pos <= seen,
+                                row < qlen if latent else
                                 jnp.logical_and(row < qlen, active))
         if window is not None:
             valid = jnp.logical_and(valid, (pos0 + row) - pos < window)
@@ -244,6 +285,8 @@ def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
             p.astype(mxu), v_t, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
         acc_new = acc * alpha + pv
+        if latent:
+            return m_new, l_new, acc_new
         return (jnp.where(active, m_new, m), jnp.where(active, l_new, l),
                 jnp.where(active, acc_new, acc))
 
@@ -253,8 +296,8 @@ def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
     # DYNAMIC trip count: dead atoms (kv_hi = 0) run zero iterations — with
     # A_max sized for the worst case, most grid programs of a typical batch
     # are dead and must not burn max_blocks MXU loops each
-    n_blk = (kv_hi + block_size - 1) // block_size
-    m, l, acc = jax.lax.fori_loop(lo_blk, n_blk, body, (m0, l0, acc0))
+    n_steps = (kv_hi + step_keys - 1) // step_keys
+    m, l, acc = jax.lax.fori_loop(lo_step, n_steps, body, (m0, l0, acc0))
     out = acc / jnp.maximum(l, 1e-30)
     out = jnp.transpose(out.reshape(kvh, bq, g, d_v), (1, 0, 2, 3))
     out_ref[0] = out.reshape(bq, h, d_v).astype(out_ref.dtype)
@@ -306,13 +349,58 @@ def default_atom_rows(bq: int, h: int, kvh: int, d: int, block_size: int,
     return bq
 
 
+# a loop step's float32 scores [rows x heads, pages x block_size] may take
+# this much, and a step at most this many blocks (_kv_pages_per_step)
+_STEP_SCORE_BYTES = 2 << 20
+_MAX_STEP_PAGES = 8
+
+
+def _kv_pages_per_step(bq: int, ht: int, kvh: int, d: int, block_size: int,
+                       itemsize: int, latent: bool) -> int:
+    """KV blocks one step of :func:`_prefill_kernel`'s loop takes, by the
+    SHAPE of the tile :func:`_head_tile` and :func:`default_atom_rows` chose
+    at one block a step: chosen after them, from the room they left.
+
+    A K-and-V pool: one. Its step moves 0.5-1 MiB, casts K and V to float32
+    and transposes them, and its model of VMEM is full at that.
+
+    A latent pool's block is ``block_size`` rows of one head (80 KiB at 64 x
+    640 bf16): one a step leaves half the MXU's columns and depth empty,
+    pays the rescale of the whole accumulator for 64 keys, and keeps one
+    small DMA in flight. So the most blocks, a power of two up to
+    ``_MAX_STEP_PAGES``, whose scores stay under ``_STEP_SCORE_BYTES`` and
+    whose model stays under ``_VMEM_CAP``."""
+    if not latent:
+        return 1
+    pages = _MAX_STEP_PAGES
+    while pages > 1 and (
+            bq * ht * pages * block_size * 4 > _STEP_SCORE_BYTES
+            or _ragged_vmem_need(bq, ht, kvh, d, block_size, itemsize,
+                                 pages) > _VMEM_CAP):
+        pages //= 2
+    return pages
+
+
+def kv_step_keys(bq: int, h: int, kvh: int, d: int, block_size: int,
+                 itemsize: int, latent: bool) -> int:
+    """Keys one loop step of the kernel covers for a tile of ``bq`` rows
+    under ``h`` query heads, as the wrapper below decides it: the head tile
+    first, then the blocks a step. What the engine counts a forward's steps
+    by (``ragged.attention_work``)."""
+    ht = _head_tile(bq, h, kvh, d, block_size, itemsize)
+    return block_size * _kv_pages_per_step(bq, ht, kvh, d, block_size,
+                                           itemsize, latent)
+
+
 def _ragged_vmem_need(bq: int, h: int, kvh: int, d: int, block_size: int,
-                      itemsize: int) -> int:
+                      itemsize: int, pages: int = 1) -> int:
     """Bytes of VMEM one grid step of :func:`_prefill_kernel` needs, by the
-    shape model :func:`_ragged_vmem_limit` explains."""
+    shape model :func:`_ragged_vmem_limit` explains; a loop step of
+    ``pages`` KV blocks holds that many in each scratch slot and scores
+    that many times the keys."""
     q_tile = bq * h * d
-    kv_tile = block_size * kvh * d
-    scores = bq * h * block_size
+    kv_tile = pages * block_size * kvh * d
+    scores = bq * h * pages * block_size
     return (4 * q_tile * itemsize        # q + out tiles, double-buffered
             + 5 * q_tile * 4             # fp32 q, q_g, acc, acc_new, pv
             + 4 * kv_tile * itemsize     # k/v scratch, two slots each
@@ -321,17 +409,23 @@ def _ragged_vmem_need(bq: int, h: int, kvh: int, d: int, block_size: int,
 
 
 def _ragged_vmem_limit(bq: int, h: int, kvh: int, d: int, block_size: int,
-                       itemsize: int) -> int:
+                       itemsize: int, pages: int = 1) -> int:
     """Scoped-VMEM limit stated to the compiler for one grid step of
     :func:`_prefill_kernel`. The q/out tiles are double-buffered by the
     pipeline and the body keeps fp32 copies of q, the accumulator and its
     update, so a 128-row atom at 32 heads x d 128 needs 21-22 MiB (bisected
     against the v5e compiler) — over Mosaic's 16 MiB default, which refused
     the kernel at every real width. The shape model below came within
-    0.8-1.06x of the bisected need across atoms 64-256 and KVH 4-32; the
-    limit is only a ceiling, so twice the model is stated. ``h`` is the
-    heads of ONE grid step (:func:`_head_tile`)."""
-    need = _ragged_vmem_need(bq, h, kvh, d, block_size, itemsize)
+    0.8-1.06x of the bisected need across atoms 64-256 and KVH 4-32 at one
+    block a step; the limit is only a ceiling, so twice the model is stated
+    (up to the cap). ``h`` is the heads of ONE grid step
+    (:func:`_head_tile`), ``pages`` the blocks of one loop step
+    (:func:`_kv_pages_per_step`): the model's KV scratch and its six arrays
+    of scores grow with them (a latent pool's 2,048-row tile: 38.9 MiB at
+    one block, 50.8 at four; the model still counts the float32 copy of q
+    and the K-and-V branch's float32 K and V, which the latent branch does
+    not make: room, not need)."""
+    need = _ragged_vmem_need(bq, h, kvh, d, block_size, itemsize, pages)
     if need > _VMEM_CAP:
         raise ValueError(
             f"ragged prefill atom of {bq} rows x {h} heads x d {d} needs "
@@ -373,6 +467,8 @@ def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
     ht = _head_tile(bq, h, kvh, d, block_size, itemsize)
     tiles = h // ht
     g = ht // kvh
+    # KV blocks a loop step takes: chosen AFTER the tile, from what it left
+    pages = _kv_pages_per_step(bq, ht, kvh, d, block_size, itemsize, latent)
     max_blocks = atom_tables.shape[1]
     if alibi is not None:
         # per-lane slope layout matches the kernel's [KVH, BQ·G] score rows:
@@ -405,7 +501,7 @@ def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
         out_specs=pl.BlockSpec((1, bq, ht, d_out), qo_map,
                                memory_space=pltpu.VMEM),
         scratch_shapes=[
-            *(pltpu.VMEM((2, block_size, *row), pool.dtype)
+            *(pltpu.VMEM((2, pages * block_size, *row), pool.dtype)
               for pool in pools),
             pltpu.SemaphoreType.DMA((2, len(pools))),
         ],
@@ -414,14 +510,14 @@ def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
                                max_blocks=max_blocks, group=g,
                                use_alibi=alibi is not None,
                                window=None if window is None else int(window),
-                               v_dim=v_dim if latent else None)
+                               v_dim=v_dim if latent else None, pages=pages)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((a, bq, h, d_out), q_atoms.dtype),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=_ragged_vmem_limit(
-                bq, ht, kvh, d, block_size, itemsize)),
+                bq, ht, kvh, d, block_size, itemsize, pages)),
         interpret=interpret,
         name=name,
     )(jnp.asarray(atom_tables, jnp.int32), jnp.asarray(atom_pos0, jnp.int32),

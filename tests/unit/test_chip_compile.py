@@ -168,6 +168,9 @@ LATENT = {
     "dsv2": dict(layers=5, slots=7680 * 64, row=640, v_dim=512, heads=128,
                  block_size=64, max_context=8192, max_sequences=64,
                  max_tokens=768, atom=16, head_tile=128)}
+# KV blocks a loop step of each entry takes at those tiles (the rule's own
+# choice: _kv_pages_per_step)
+LATENT_PAGES = {"ragged_prefill": 4, "paged_decode": 8}
 
 
 @pytest.mark.parametrize("kernel", ["ragged_prefill", "paged_decode"])
@@ -177,11 +180,20 @@ def test_latent_kernels_compile_at_the_cells_widths(one_chip, kernel, cell):
     HBM: "Slice shape along dimension 2 must be aligned to tiling (2)"); the
     128-row atom of 32 x 640 runs as two head tiles of 16, the 16-row atom
     of 128 x 640 as one of 128 (the same tile), and a 128-row atom of 128 x
-    640 would run as eight of 16."""
+    640 would run as eight of 16. Each compiles with the loop step the rule
+    picks for its tile, four KV blocks under the atoms and eight under one
+    row, inside the VMEM it states."""
     from deepspeedsyclsupport_tpu.ops.paged_attention import (
-        _head_tile, default_atom_rows)
+        _VMEM_CAP, _head_tile, _kv_pages_per_step, _ragged_vmem_limit,
+        default_atom_rows)
 
     g = LATENT[cell]
+    rows = g["atom"] if kernel == "ragged_prefill" else 1
+    tile = (rows, _head_tile(rows, g["heads"], 1, g["row"], g["block_size"],
+                             2), 1, g["row"], g["block_size"], 2)
+    pages = _kv_pages_per_step(*tile, True)
+    assert pages == LATENT_PAGES[kernel]
+    assert _ragged_vmem_limit(*tile, pages) <= _VMEM_CAP
     # the atom is the engine's own choice in both cells
     assert default_atom_rows(128, g["heads"], 1, g["row"], g["block_size"],
                              2) == g["atom"]

@@ -112,6 +112,87 @@ def test_decode_kernel_reads_a_latent_pool_with_an_idle_slot():
     assert not np.asarray(got)[1].any()
 
 
+# a loop step of P blocks (block_size 16, 12 blocks a table row): what each
+# case puts at the step's edges, as (first position, live rows) of the
+# ragged entry's 8-row tiles and as the one-row entry's context lengths
+WIDE_STEP = {
+    # neither a multiple of P x 16 keys, nor of 16
+    "a_ragged_tail": dict(atoms=[(70, 8), (101, 5)], lens=[78, 105, 33]),
+    # all of it inside the first block of the first step
+    "shorter_than_a_step": dict(atoms=[(0, 8), (3, 6)], lens=[1, 9, 14]),
+    # a tile with no row between two that have some
+    "a_dead_tile": dict(atoms=[(40, 8), (0, 0), (90, 3)], lens=[64, 0, 100]),
+    # the first block inside the window is block 3, 5 or 2: no multiple of 4
+    "a_window_off_the_steps": dict(atoms=[(90, 8), (121, 8)],
+                                   lens=[100, 130, 75], window=40),
+    # a context longer than its table of 11 blocks (176 keys; no multiple of
+    # a step of 2 or 4): what the table holds, and no key of the step's rest
+    "past_the_tables_end": dict(atoms=[(170, 8), (100, 8)],
+                                lens=[180, 176, 90], bps=11),
+}
+
+
+@pytest.mark.parametrize("entry", ["ragged_prefill", "paged_decode"])
+@pytest.mark.parametrize("pages", [1, 2, 4])
+@pytest.mark.parametrize("case", sorted(WIDE_STEP))
+def test_a_step_of_several_blocks_reads_what_one_block_a_step_does(
+        monkeypatch, case, pages, entry):
+    """Both entries of the kernel on a latent pool whose blocks OUTSIDE the
+    tables' live part are NaN (another sequence's, or never written): a
+    step's blocks past the context's end, or below the window's start, must
+    not be read as they lie, since 0 x NaN is NaN in ``p v``."""
+    monkeypatch.setattr(pa, "_kv_pages_per_step",
+                        lambda *a: pages if a[-1] else 1)
+    spec = WIDE_STEP[case]
+    window = spec.get("window")
+    bq, bps, blocks = 8, spec.get("bps", 12), 40
+    rng = np.random.default_rng(sorted(WIDE_STEP).index(case))
+    if entry == "ragged_prefill":
+        pos0, qlen = (jnp.asarray(x, jnp.int32) for x in zip(*spec["atoms"]))
+        hi = np.asarray(pos0 + qlen)
+    else:
+        hi = np.asarray(spec["lens"])
+        pos0, qlen = jnp.maximum(hi - 1, 0), hi > 0
+    n = len(hi)
+    tables = rng.permutation(blocks)[:n * bps].reshape(n, bps)
+    # live: the blocks the loop of one block a step reads (from the block
+    # that holds row 0's window start to the one that holds the last key)
+    lo = np.zeros(n, int) if window is None else \
+        np.maximum(np.asarray(pos0) + 1 - window, 0) // BS
+    live = [tables[i, lo[i]:-(-hi[i] // BS)] for i in range(n)]   # <= bps
+    pool = np.full((2, blocks * BS, DPAD), np.nan, np.float32)
+    for blk in np.concatenate(live):
+        pool[:, blk * BS:(blk + 1) * BS] = 0
+        pool[:, blk * BS:(blk + 1) * BS, :DK] = rng.standard_normal(
+            (2, BS, DK))
+    q = np.zeros((n, bq, H, DPAD), np.float32)
+    q[..., :DK] = rng.standard_normal((n, bq, H, DK)) * 0.3
+    kw = dict(block_size=BS, layer=jnp.int32(1), v_dim=DV, window=window)
+    # the reference gathers every block of the table: give it zeros where
+    # the kernel must not look
+    clean = jnp.asarray(np.nan_to_num(pool))
+    if entry == "ragged_prefill":
+        args = (jnp.asarray(tables, jnp.int32), pos0, qlen)
+        got = pa.ragged_prefill_attention_pallas(
+            jnp.asarray(q), jnp.asarray(pool), None, *args, interpret=True,
+            **kw)
+        want = pa.ragged_prefill_attention_reference(
+            jnp.asarray(q), clean, None, *args, **kw)
+        rows = np.arange(bq)[None, :] < np.asarray(qlen)[:, None]
+    else:
+        args = (jnp.asarray(tables, jnp.int32), jnp.asarray(hi, jnp.int32))
+        got = pa.paged_decode_attention_pallas(
+            jnp.asarray(q[:, 0]), jnp.asarray(pool), None, *args,
+            interpret=True, **kw)
+        want = pa.paged_decode_attention_reference(
+            jnp.asarray(q[:, 0]), clean, None, *args, **kw)
+        rows = hi > 0
+    got = np.asarray(got)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got[rows], np.asarray(want)[rows], atol=2e-5)
+    assert not got[~rows].any()              # dead rows and tiles: zeros
+
+
 def test_a_pool_without_v_needs_v_dim():
     q, pool, _rng = latent_case(2, 2)
     with pytest.raises(ValueError, match="v_dim"):
@@ -145,6 +226,43 @@ def test_heads_are_tiled_by_the_shape_under_one_kv_head(monkeypatch):
     tiled = pa.ragged_prefill_attention_pallas(*args, **kw)
     np.testing.assert_allclose(np.asarray(tiled), np.asarray(whole),
                                atol=1e-6)
+
+
+def test_the_step_is_chosen_after_the_tile_and_leaves_it_alone(monkeypatch):
+    """The head tile and the atom's rows are decided at one KV block a step,
+    whatever a step then takes: 16 heads x 128 rows (Xing4), 128 heads x 16
+    rows (DeepSeek-V2), as accepted; then the blocks a step, from the tile:
+    four under a 2,048-row tile (2 MiB of scores), eight under one row, one
+    on a K-and-V pool whatever its tile."""
+    xing4, dsv2 = (32, 1, 640, 64, 2), (128, 1, 640, 64, 2)
+
+    def chosen():
+        return [(pa.default_atom_rows(128, *shape),
+                 pa._head_tile(pa.default_atom_rows(128, *shape), *shape))
+                for shape in (xing4, dsv2)]
+
+    assert chosen() == [(128, 16), (16, 128)]
+    assert pa._kv_pages_per_step(128, 16, *xing4[1:], True) == 4
+    assert pa._kv_pages_per_step(16, 128, *dsv2[1:], True) == 4
+    assert pa._kv_pages_per_step(1, 32, *xing4[1:], True) == 8
+    assert pa._kv_pages_per_step(1, 128, *dsv2[1:], True) == 8
+    assert pa.kv_step_keys(128, *xing4, True) == 4 * 64
+    assert pa.kv_step_keys(1, *dsv2, True) == 8 * 64
+    for tile in ((128, 32, 32, 80, 64, 2), (128, 16, 16, 128, 64, 2),
+                 (1, 32, 8, 128, 64, 2), (1, 16, 1, 128, 64, 2)):
+        assert pa._kv_pages_per_step(*tile, False) == 1
+    assert pa.kv_step_keys(128, 32, 32, 80, 64, 2, False) == 64
+    # a step may take what room is left, never the tile's
+    assert pa._ragged_vmem_need(128, 16, 1, 640, 64, 2, 4) > \
+        pa._HEAD_TILE_BUDGET > pa._ragged_vmem_need(128, 16, 1, 640, 64, 2)
+    assert pa._ragged_vmem_limit(128, 16, 1, 640, 64, 2, 4) == pa._VMEM_CAP
+    # under a tighter cap the step shrinks and the tile stays
+    monkeypatch.setattr(pa, "_VMEM_CAP", 48 << 20)
+    assert pa._kv_pages_per_step(128, 16, *xing4[1:], True) == 2
+    assert chosen() == [(128, 16), (16, 128)]
+    # and a rule that asked for more would move neither
+    monkeypatch.setattr(pa, "_kv_pages_per_step", lambda *a: 8)
+    assert chosen() == [(128, 16), (16, 128)]
 
 
 # --------------------------------------------------------------- the pool
@@ -324,9 +442,20 @@ def test_attention_work_counts_pairs_and_decode_contexts():
     def desc(n):
         return SequenceDescriptor(uid=0, n_cached=n)
 
+    work = attention_work([desc(10), desc(0), desc(7), desc(0)],
+                          [5, 3, 1, 1])
+    assert work == (65 + 6, 8 + 1, 8 + 1, 8 + 1)      # no atoms: the rows'
+    assert attention_work([], []) == (0, 0, 0, 0)
+    # atoms of 4 rows under steps of 8 keys, one-row tiles under steps of
+    # 16: the 5-token chunk is atoms that see 14 and 15 keys (2 steps
+    # each), the 3-token prompt one that sees 3 (1 step); the one-row
+    # tiles see 8 and 1 (a step each)
     assert attention_work([desc(10), desc(0), desc(7), desc(0)],
-                          [5, 3, 1, 1]) == (65 + 6, 8 + 1)
-    assert attention_work([], []) == (0, 0)
+                          [5, 3, 1, 1], 4, (8, 16))[2:] == (
+        16 + 16 + 8 + 16 + 16, 14 + 15 + 3 + 8 + 1)
+    # a context that fills its steps pays nothing; one key more, a step
+    assert attention_work([desc(31), desc(32)], [1, 1], 4, (8, 16))[2:] == (
+        32 + 48, 32 + 33)
 
 
 def test_the_round_record_carries_both_counts(tiny):
@@ -350,6 +479,38 @@ def test_the_round_record_carries_both_counts(tiny):
         ("ragged_forward", 15 * 16 // 2, 8),          # 15 rows + one decode
         ("ragged_forward", 5 * 15 + 15, 9),
         ("decode_forward", 0, 10 + 21)]
+    # the xla attention takes no atoms: the one-row tiles alone walk steps,
+    # of what the kernel's rule gives this pool (16 blocks of 16 keys)
+    assert eng._kv_step_keys[1] == 16 * pa._kv_pages_per_step(
+        1, 4, 1, eng.kv.k.shape[-1], 16, 4, True)
+    step = eng._kv_step_keys[1]
+    assert [(d["kv_step_keys"], d["kv_tile_keys"]) for d in rounds] == [
+        (0, 0), (step, 8), (step, 9), (2 * step, 10 + 21)]
+    sess.close()
+
+
+def test_the_round_record_counts_the_atoms_steps(tiny, monkeypatch):
+    """With the kernel's atoms (8 rows here) under a step of 2 blocks of
+    16 keys: a 20-token prompt's chunks of 16 and 4 rows are atoms that see
+    8, 16 and then 20 keys."""
+    from deepspeedsyclsupport_tpu.inference.v2.config import (
+        ServingPolicyConfig)
+    from deepspeedsyclsupport_tpu.inference.v2.serving import ServingSession
+
+    monkeypatch.setattr(pa, "_kv_pages_per_step", lambda *a: 2 if a[-1]
+                        else 1)
+    eng = engine_of(tiny, prefill_attn="kernel_interpret",
+                    decode_attn="pallas_interpret", atom_q_size=8)
+    assert eng._kv_step_keys == (32, 32)
+    sess = ServingSession(eng, ServingPolicyConfig(admission="none"))
+    sess.submit(2, list(range(1, 21)), 2)             # 20: chunks 16 + 4
+    while not sess.idle:
+        sess.step()
+    rounds = [r["data"] for r in sess.drain_trace()
+              if r["data"].get("stage") == "round" and r["data"]["program"]]
+    assert [(d["atoms"], d["decode_rows"], d["kv_step_keys"],
+             d["kv_tile_keys"]) for d in rounds] == [
+        (2, 0, 32 + 32, 8 + 16), (1, 0, 32, 20), (0, 1, 32, 21)]
     sess.close()
 
 

@@ -660,6 +660,7 @@ def requests_report(root: str, worst_n: int = 5, window_s: float = 60.0,
                      f"{'kv blocks':>11}{'1-row':>8}{'atoms':>8}"
                      f"{'pairs':>12}{'1-row-ctx':>11}{'experts':>9}"
                      f"{'ahead':>7}{'spec-rows':>11}"
+                     f"{'step-keys':>11}{'tile-keys':>11}"
                      + (f"{'exp-rows':>10}" if share else "")
                      + (f"{'tile-rows':>11}{'tile-fill':>11}{'tiles/expert':>14}"
                         if tiled else ""))
@@ -674,6 +675,8 @@ def requests_report(root: str, worst_n: int = 5, window_s: float = 60.0,
                          f"{m.get('moe_touched', 0):>9.1f}"
                          f"{m.get('ahead', 0):>7.2f}"
                          f"{m.get('spec_rows', 0):>11.2f}"
+                         f"{m.get('kv_step_keys', 0):>11.1f}"
+                         f"{m.get('kv_tile_keys', 0):>11.1f}"
                          + (f"{m.get('moe_rows', 0):>10.1f}" if share
                             else "") + (tile_cols(m) if tiled else ""))
     if att["cached_prefix_tokens_mean"]:
