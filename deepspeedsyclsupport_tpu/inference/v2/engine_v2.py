@@ -47,14 +47,20 @@ def _gather_rows(logits, slots):
 def _sample_rows(logits, slots, rng, temperature, top_p, structure,
                  tail=None):
     """``sample_token_dyn`` over :func:`_gather_rows`; a ``tail`` (a tuple
-    of device int32 scalars) is appended to the tokens [S] -> [S + n], so
-    that it reaches the host in the ONE transfer that brings the tokens (a
-    sparse-expert model's ``moe_touched``, and ``moe_rows`` where it holds
-    a share of the experts: no launch and no transfer of their own)."""
+    of device int32 scalars or vectors) is appended to the tokens [S] -> [S
+    + n], so that it reaches the host in the ONE transfer that brings the
+    tokens (a sparse-expert model's ``moe_touched``, and ``moe_rows`` where
+    it holds a share of the experts; a looped stack's ``exit_pass``: no
+    launch and no transfer of their own)."""
     toks = sample_token_dyn(_gather_rows(logits, slots), rng, temperature,
                             top_p, structure)
     return toks if tail is None else jnp.concatenate(
-        [toks, *(t[None] for t in tail)])
+        [toks, *(t if t.ndim else t[None] for t in tail)])
+
+
+def _tail_len(tail) -> int:
+    """int32 values a sampler launch's ``tail`` puts behind the tokens."""
+    return sum(t.size for t in tail or ())
 
 
 class SampledTokens:
@@ -388,6 +394,12 @@ class InferenceEngineV2:
                 ssm_rows=sum(lengths),
                 ssm_pieces=sum(-(-n // q) for n in lengths)
                 * mc.pattern_count("M"))
+        if self.kv.exit_pass is not None:
+            # a looped stack: the passes the forward runs over its layers,
+            # and the cache rows it writes and attends a token
+            mc = self.model.config
+            self.round_spans.fields.update(passes=mc.total_ut_steps,
+                                           kv_rows=mc.num_kv_layers)
         if rows and self.kv.moe is not None:
             cfg = self.model.config
             self.round_spans.fields.update(
@@ -412,6 +424,18 @@ class InferenceEngineV2:
             cfg = self.model.config
             stats["held"] = np.arange(cfg.num_experts)[cfg.held_experts]
         return stats
+
+    def loop_stats(self) -> Optional[Dict[str, Any]]:
+        """A looped stack's exit counter (None for any other model):
+        ``passes``, ``kv_rows`` (cache rows a token: passes x layers) and
+        ``exit_pass`` [passes], the rows unembedded for a live sequence by
+        the pass the exit rule took their logits from, since the engine was
+        built, read from the device NOW (a transfer: for a report)."""
+        if self.kv.exit_pass is None:
+            return None
+        mc = self.model.config
+        return {"passes": mc.total_ut_steps, "kv_rows": mc.num_kv_layers,
+                "exit_pass": np.asarray(self.kv.exit_pass).tolist()}
 
     def state_stats(self) -> Optional[Dict[str, Any]]:
         """The recurrent state of a model with Mamba layers (None for any
@@ -492,8 +516,7 @@ class InferenceEngineV2:
             sampled = None
             if toks is None:     # a decode step: the token the sampler drew
                 sampled = self.sample_launch([uid], key, SamplingParams(),
-                                             tail=self.moe_tail(
-                                                 MOE_TAIL_FIELDS))
+                                             tail=self.round_tail())
                 toks = [sampled.ref(uid)]
             out = self.put([uid], [toks], sampled=sampled)
             if sampled is not None:
@@ -922,8 +945,7 @@ class InferenceEngineV2:
         if self._no_sampled is None:
             self._no_sampled = jax.device_put(
                 np.zeros((self.config.max_sequences
-                          + len(self.moe_tail(MOE_TAIL_FIELDS) or ()),),
-                         np.int32),
+                          + _tail_len(self.round_tail()),), np.int32),
                 self._sampled_sharding)
         return self._no_sampled
 
@@ -1269,6 +1291,24 @@ class InferenceEngineV2:
                    "moe_rows": moe.rows}
         return tuple(counted[f] for f in fields if counted[f] is not None)
 
+    def round_tail(self) -> Optional[Tuple[jax.Array, ...]]:
+        """What a serving round's sampler launch carries behind its tokens
+        (and :meth:`warmup`'s, so that both are one program): a
+        sparse-expert model's :meth:`moe_tail` over all of
+        ``reqtrace.MOE_TAIL_FIELDS``; a looped stack's exit counter
+        (``kv.exit_pass`` [passes], summed since the engine was built: a
+        looped stack has no experts); None for every other model."""
+        if self.kv.exit_pass is not None:
+            return (self.kv.exit_pass,)
+        return self.moe_tail(MOE_TAIL_FIELDS)
+
+    def tail_fields(self, counted: Sequence[int]) -> Dict[str, Any]:
+        """:meth:`round_tail`'s values, read back, under the names the
+        ``round`` record gives them."""
+        if self.kv.exit_pass is not None:
+            return {"exit_pass": list(counted)}
+        return dict(zip(MOE_TAIL_FIELDS, counted))
+
     def sample_launch(self, uids: Sequence[int], rng: jax.Array,
                       sampling: SamplingParams,
                       tail: Optional[Tuple[jax.Array, ...]] = None
@@ -1305,7 +1345,7 @@ class InferenceEngineV2:
             out.copy_to_host_async()
             if out.sharding != self._sampled_sharding:
                 self._sampled_sharding, self._no_sampled = out.sharding, None
-        return SampledTokens(out, uids, len(tail or ()))
+        return SampledTokens(out, uids, _tail_len(tail))
 
     def read_sampled(self, sampled: SampledTokens
                      ) -> Tuple[np.ndarray, Optional[Tuple[int, ...]]]:

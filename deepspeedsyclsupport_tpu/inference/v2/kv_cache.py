@@ -3,7 +3,13 @@
 Analog of ``BlockedKVCache`` (``inference/v2/ragged/kv_cache.py``): a pool of
 fixed-size KV blocks; sequences own arbitrary block lists, indirected through
 block tables. Layout [L, num_blocks * block_size, KVH, D] — flat slot axis so
-(de)referencing a slot is ``block_id * block_size + offset``. A latent-
+(de)referencing a slot is ``block_id * block_size + offset``. ``L`` counts
+the rows that cache, not the layers: a stack whose layers run several times
+over shared weights (``ModelConfig.total_ut_steps``) has one row for every
+(pass, layer) pair, pass ``u``'s layer ``l`` at ``u x num_layers + l``: a
+pass attends to its own keys and values, so a block of 64 tokens holds all
+``passes x layers`` rows of them and the allocator, the prefix cache and the
+block copy see blocks as they do for any model. A latent-
 attention model (``ModelConfig.kv_lora_rank``) caches ONE row a token and
 layer, [L, num_blocks * block_size, D]: the normed latent and the rotated
 key all heads share, and no V pool at all. The serving
@@ -71,6 +77,13 @@ class BlockedKV(NamedTuple):
     # resets a slot: a piece whose first position is 0 starts from zeros.
     ssm: Optional[jnp.ndarray] = None
     conv: Optional[jnp.ndarray] = None
+    # a looped stack only (``ModelConfig.total_ut_steps`` > 1; None
+    # elsewhere: no leaf, the same program): [passes] int32, the rows the
+    # forwards unembedded for a live sequence, by the pass the exit rule
+    # took their logits from, summed since the engine was built (at the
+    # published threshold 1.0 all in the last). Rides with the pool as
+    # ``moe`` does.
+    exit_pass: Optional[jnp.ndarray] = None
 
     @property
     def num_slots(self) -> int:
@@ -140,6 +153,10 @@ def init_blocked_kv(model_config, cfg: RaggedInferenceConfig,
                           SSM_STATE_DTYPE),
             conv=jnp.zeros((lead[0], mc.ssm_conv_kernel - 1, lead[1],
                             mc.ssm_conv_dim), cfg.dtype)),
+            out_shardings=topology.replicated())()
+    if model_config.total_ut_steps > 1:
+        state["exit_pass"] = jax.jit(
+            lambda: jnp.zeros((model_config.total_ut_steps,), jnp.int32),
             out_shardings=topology.replicated())()
     return BlockedKV(zeros(), None if latent else zeros(), moe, **state)
 

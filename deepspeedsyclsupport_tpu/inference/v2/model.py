@@ -36,7 +36,10 @@ own, walked first by :func:`_scan_layers`). And a fourth: a hybrid stack
 (``cfg.layer_pattern``: Mamba-2, expert and attention layers, ONE mixer a
 layer), which :func:`_walk_pattern` walks over three stacks of parameters,
 the KV pool (a row for the attention layers only) and the recurrent state
-of the Mamba layers (``ops/ssm.py``), each with an index of its own.
+of the Mamba layers (``ops/ssm.py``), each with an index of its own. And a
+fifth: a looped stack (``cfg.total_ut_steps``: the layers run several times
+over shared weights, :func:`_scan_passes`), whose pool has a row for every
+(pass, layer) pair and whose logits the exit gate chooses among the passes.
 """
 from typing import Any, NamedTuple, Optional, Tuple
 
@@ -263,8 +266,12 @@ def _block(cfg, p, x, attn_fn, live, experts=None):
         y = x_norm if cfg.shared_block_norm else norm(x, p["mlp_norm"], cfg)
         m, rows = _mlp(p, y, cfg, live, experts)
         return (x + h + m).astype(x.dtype), rows
+    if cfg.sandwich_norm:    # the sublayer's OUTPUT is normed too
+        h = norm(h, p["attn_post_norm"], cfg)
     x = (x + h).astype(x.dtype)
     m, rows = _mlp(p, norm(x, p["mlp_norm"], cfg), cfg, live, experts)
+    if cfg.sandwich_norm:
+        m = norm(m, p["mlp_post_norm"], cfg)
     return (x + m).astype(x.dtype), rows
 
 
@@ -633,6 +640,62 @@ def _scan_layers(layer, x, kv: BlockedKV, params, cfg):
                           **dict(zip(("k", "v"), pools)))
 
 
+def exit_choice(gate, h, threshold: float):
+    """The exit rule of a looped stack over the passes' normed outputs h
+    [U, S, d]: ``lam_u = sigmoid(h_u w + b)`` in float32, the exit
+    distribution ``p_u = lam_u prod_{j<u} (1 - lam_j)`` (the last pass takes
+    what is left), and a row's pass the first whose running sum of ``p``
+    reaches ``threshold``, else the last. -> [S] int32."""
+    lam = jax.nn.sigmoid(
+        jnp.einsum("usd,do->us", h.astype(jnp.float32),
+                   gate["kernel"].astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
+        + gate["bias"].astype(jnp.float32))[:-1]            # [U - 1, S]
+    stay = jnp.cumprod(1.0 - lam, axis=0)
+    before = jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]])
+    reached = jnp.cumsum(lam * before, axis=0) >= threshold
+    return jnp.where(reached.any(0), jnp.argmax(reached, axis=0),
+                     h.shape[0] - 1).astype(jnp.int32)
+
+
+def _scan_passes(layer, x, kv: BlockedKV, params, cfg, pick, live):
+    """:func:`_scan_layers` for a looped stack (``cfg.total_ut_steps`` = U >
+    1): an outer loop over the passes whose carry is ``(x, pools)``, inside
+    it the layer scan over the SAME stacked params, two indices: layer ``l``
+    of the weights, row ``u x L + l`` of the pool (``layer`` is handed the
+    row: a pass writes and attends to its own keys and values). The final
+    norm closes every pass and its output starts the next. Only the rows
+    that are unembedded are kept of each pass (``pick(x) -> [S, d]``, so
+    [U, S, d]); the exit gate and the rule (:func:`exit_choice`) choose each
+    row's pass among them, and ``kv.exit_pass`` counts the ``live`` [S] rows
+    by the pass chosen. Every pass is computed whatever the rule says.
+    Returns ``(the chosen normed rows [S, d], the new BlockedKV)``."""
+    n, u_steps = cfg.num_layers, cfg.total_ut_steps
+
+    def one_pass(carry, u):
+        with jax.named_scope("loop_pass"):
+            def body(carry, inp):
+                p, l = inp
+                return layer(carry, p, u * n + l, None)[0], None
+
+            (x, pools), _ = jax.lax.scan(
+                body, carry, (params["layers"], jnp.arange(n)))
+            x = _final_norm(params, x, cfg)
+            return (x, pools), pick(x)
+
+    (_, pools), h = jax.lax.scan(one_pass, (x, kv.pools),
+                                 jnp.arange(u_steps))
+    with jax.named_scope("loop_exit"):
+        chosen = exit_choice(params["exit_gate"], h,
+                             cfg.early_exit_threshold)
+        h_exit = jnp.take_along_axis(h, chosen[None, :, None], axis=0)[0]
+        counted = jnp.sum(
+            (chosen[:, None] == jnp.arange(u_steps)) & live[:, None],
+            axis=0, dtype=jnp.int32)
+    return h_exit, kv._replace(exit_pass=kv.exit_pass + counted,
+                               **dict(zip(("k", "v"), pools)))
+
+
 def _count_moe(moe, rows, cfg, tokens: int):
     """``kv.moe`` after a forward whose expert layers' routers gave ``rows``
     [L_moe, E] (None: a dense model, whose ``moe`` is None too)."""
@@ -860,6 +923,12 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
         y = y.at[jnp.where(one, ssm.dec_row, t)].set(y_dec, mode="drop")
         return y, tuple(state)
 
+    if cfg.total_ut_steps > 1:
+        # a slot's row is a sequence's where the batch has a chunk of it
+        h_last, kv = _scan_passes(
+            layer, x, kv, params, cfg, lambda x: x[last_tok_idx],
+            token_seq[last_tok_idx] == jnp.arange(s))
+        return _unembed(params, h_last, cfg).astype(jnp.float32), kv
     if cfg.layer_pattern is not None:
         x, kv = _walk_pattern(cfg, params, x, kv, attend, ssm_step, ~pad)
     else:
@@ -954,6 +1023,9 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
             positions == 0, cfg, _ssm_step_fn())
         return y, tuple(state)
 
+    if cfg.total_ut_steps > 1:
+        x, kv = _scan_passes(layer, x, kv, params, cfg, lambda x: x, active)
+        return _unembed(params, x, cfg).astype(jnp.float32), kv
     if cfg.layer_pattern is not None:
         x, kv = _walk_pattern(cfg, params, x, kv, attend, ssm_step, active)
     else:

@@ -43,8 +43,8 @@ from .kv_cache import kv_pool_stats
 from .scheduler import SlackPolicy, slack_of
 from ..sampling import SamplingParams, split_key
 from ...comm.watchdog import SERVE_HANG_EXIT_CODE, CollectiveWatchdog
-from ...monitor.reqtrace import (FORWARD_FIELDS, MOE_TAIL_FIELDS, NO_PHASE,
-                                 ROUND_PHASES, check_phase)
+from ...monitor.reqtrace import (FORWARD_FIELDS, NO_PHASE, ROUND_PHASES,
+                                 check_phase)
 from ...utils.fault_injection import get_fault_injector
 from ...utils.logging import logger
 
@@ -1037,11 +1037,12 @@ class ServingSession:
         if drained:
             # a sparse-expert model's last forward counted the experts it
             # touched on the device (and, holding a share of them, the rows
-            # it gave them): the scalars ride behind the tokens, and are
-            # read from the pool the NEXT dispatch replaces, so the sampler
-            # goes first. The engine times its own gather and sample
+            # it gave them; a looped stack's, the rows by their exit pass):
+            # the counts ride behind the tokens, and are read from the pool
+            # the NEXT dispatch replaces, so the sampler goes first. The
+            # engine times its own gather and sample
             sampled = eng.sample_launch(drained, sub, self.sampling,
-                                        tail=eng.moe_tail(MOE_TAIL_FIELDS))
+                                        tail=eng.round_tail())
             with self._phase("schedule"):
                 ending = self._plan_drained(reqs, sampled, now)
         else:
@@ -1168,7 +1169,7 @@ class ServingSession:
         # reference no forward ate
         toks, counted = eng.read_sampled(sampled)
         if counted is not None and self._spans is not None:
-            self._spans.fields.update(zip(MOE_TAIL_FIELDS, counted))
+            self._spans.fields.update(eng.tail_fields(counted))
         t1 = self.clock()
         if self._last_decode_s is not None:
             self.capacity.record_decode(1, t1 - self._last_decode_s)

@@ -138,6 +138,19 @@ class ModelConfig:
     hc_eps: float = 1e-6
     mhc_h_res_clamp_min: float = -30.0
     mhc_h_res_clamp_max: float = 30.0
+    # A looped stack (ouro), under the names its config.json publishes: the
+    # SAME num_layers layers run total_ut_steps times a token. The final
+    # norm closes every pass and its output starts the next; a pass caches
+    # its own keys and values (the KV pool's row ``pass x num_layers +
+    # layer``) and an exit gate (``params["exit_gate"]``) reads each pass's
+    # output: the logits are those of the first pass at which the running
+    # sum of the exit distribution reaches early_exit_threshold (at 1.0 the
+    # last). Every pass is computed whatever the threshold. Serving only.
+    total_ut_steps: int = 1
+    early_exit_threshold: float = 1.0
+    # each sublayer's OUTPUT is normed too (``attn_post_norm``,
+    # ``mlp_post_norm``) before it joins the stream: x + norm(f(norm(x)))
+    sandwich_norm: bool = False
 
     # Training-time behavior
     remat: bool = False             # jax.checkpoint each layer (activation ckpt)
@@ -215,6 +228,10 @@ class ModelConfig:
                 "stacked (scan_layers)")
         if self.layer_pattern is not None:
             self._check_pattern()
+        if self.total_ut_steps < 1:
+            raise ValueError(f"total_ut_steps {self.total_ut_steps} < 1")
+        if self.total_ut_steps > 1 or self.sandwich_norm:
+            self._check_loop()
         if self.attn_windows is not None:
             self.attn_windows = tuple(self.attn_windows)
             if len(self.attn_windows) != self.num_layers:
@@ -250,15 +267,38 @@ class ModelConfig:
                 "latent attention, hyper-connection streams, parallel "
                 "block, leading dense layers or per-layer windows")
 
+    def _check_loop(self):
+        """What a looped stack (or its post-sublayer norms) is not walked
+        with, each by name."""
+        wrong = [name for name, on in (
+            ("layer_pattern", self.layer_pattern is not None),
+            ("experts (num_experts)", self.any_moe),
+            ("leading dense layers (first_k_dense_replace)",
+             bool(self.first_k_dense_replace)),
+            ("hyper-connection streams (hc_mult)", self.hc_mult > 1),
+            ("a parallel block", self.parallel_block),
+            ("per-layer windows (attn_windows)",
+             self.attn_windows is not None),
+            ("unstacked layers (scan_layers false)", not self.scan_layers),
+            ("pipeline stages (pipe_stages)",
+             self.pipe_stages not in (None, 1))) if on]
+        if wrong:
+            raise ValueError(
+                "total_ut_steps > 1 / sandwich_norm walk ONE uniform stack "
+                "of sequential blocks; not written for: " + ", ".join(wrong))
+
     def pattern_count(self, kind: str) -> int:
         """Layers of ``kind`` ('M', 'E', '*') in ``layer_pattern``."""
         return (self.layer_pattern or "").count(kind)
 
     @property
     def num_kv_layers(self) -> int:
-        """Layers that cache keys and values: the KV pool's leading axis."""
-        return self.num_layers if self.layer_pattern is None \
+        """Rows of the KV pool's leading axis: one for every (pass, layer)
+        pair that caches keys and values. A looped stack's pass ``u`` has
+        rows ``u x L .. u x L + L - 1``: a pass attends to its own."""
+        layers = self.num_layers if self.layer_pattern is None \
             else self.pattern_count("*")
+        return self.total_ut_steps * layers
 
     @property
     def ssm_d_inner(self) -> int:
@@ -386,8 +426,12 @@ class ModelConfig:
         n_moe = self.num_moe_layers
         hc = 2 * (self.hc_mult * d * (2 + self.hc_mult) * self.hc_mult
                   + self.hc_mult * d) if self.hc_mult > 1 else 0
-        total = ((attn + 2 * d + hc) * self.num_layers + moe * n_moe
+        # weights a looped stack shares between passes are counted once
+        norms = 4 * d if self.sandwich_norm else 2 * d
+        total = ((attn + norms + hc) * self.num_layers + moe * n_moe
                  + dense * (self.num_layers - n_moe) + v * d + d)
+        if self.total_ut_steps > 1:
+            total += d + 1    # the exit gate
         if not self.tie_embeddings:
             total += d * v
         return total
@@ -546,6 +590,15 @@ PRESETS = {
         # without the x 2.5 passed parity, at 1/10 router near-ties that
         # bf16 breaks the other way bring a sound run to 0.09 of its 0.1)
         routed_write_share=0.075),
+    # ByteDance/Ouro-2.6B (model_type ouro, arXiv:2510.25741): 48 layers of
+    # MHA 16 x 128 and SwiGLU 5632 run FOUR times over shared weights, each
+    # sublayer's output normed before it joins the stream, the final norm
+    # between passes, an exit gate after each. Serving only (inference/v2).
+    "ouro-2.6b": _p(
+        vocab_size=49152, hidden_size=2048, intermediate_size=5632,
+        num_layers=48, num_heads=16, num_kv_heads=16, head_dim=128,
+        max_seq_len=65536, rms_norm_eps=1e-6, rope_theta=1000000.0,
+        total_ut_steps=4, early_exit_threshold=1.0, sandwich_norm=True),
 }
 
 

@@ -26,6 +26,18 @@ from .layers import BATCH, attention_block, constrain, mlp_block, norm
 
 Params = Dict[str, Any]
 
+# How init_params SEEDS the scales of a sandwich_norm stack's post-sublayer
+# norms (post_norm_params): what the attention sublayers and the MLPs of one
+# walk of the stack each write together, as a share of a unit residual
+# stream, and the spread of the log-normal draw over a scale's channels. By
+# sweeps on the v5e against the float32 reference (PERF.md section 6, PR 39;
+# benchmark/configs/ouro-2.6b.json, assumed.weights): a bf16 stream is
+# rounded at every write, 384 times a token at 48 layers x 4 passes, and that
+# rounding, not any sublayer's arithmetic, is what the served logits differ
+# by. A checkpoint overwrites all of it.
+POST_NORM_WRITE = (3.0, 0.5)
+POST_NORM_SPREAD = 2.0
+
 
 class KVCache(NamedTuple):
     """Per-model decode cache: stacked [L, B, max_len, kv_heads, head_dim]."""
@@ -77,6 +89,20 @@ class CausalLM:
             if cfg.norm_type == "layernorm":
                 p["bias"] = jnp.zeros((cfg.hidden_size,), jnp.float32)
             return p
+
+        def post_norm_params(key, write) -> Params:
+            """The norm over a sublayer's OUTPUT (``cfg.sandwich_norm``):
+            its scale IS the size of the sublayer's write into the stream,
+            whatever the projections draw. Seeded log-normal a channel
+            (:data:`POST_NORM_SPREAD`) around ``write / sqrt(2 L)`` in the
+            root mean square: the sublayers of one kind in ONE walk of the
+            stack together write ``write`` times a unit stream (what the
+            final norm hands the next pass of a looped stack). A checkpoint
+            overwrites it."""
+            z = jnp.exp(POST_NORM_SPREAD * jax.random.normal(
+                key, (cfg.hidden_size,), jnp.float32))
+            return {"scale": z * jax.lax.rsqrt(jnp.mean(z * z)) * (
+                write / np.sqrt(2 * cfg.num_layers))}
 
         def attn_params(ks) -> Params:
             d, q, kv = cfg.hidden_size, cfg.q_dim, cfg.kv_dim
@@ -187,6 +213,11 @@ class CausalLM:
                                          "attn": attn_params(ks)}
             if not cfg.shared_block_norm:
                 p["mlp_norm"] = norm_params()
+            if cfg.sandwich_norm:
+                p["attn_post_norm"] = post_norm_params(
+                    next(ks), POST_NORM_WRITE[0])
+                p["mlp_post_norm"] = post_norm_params(
+                    next(ks), POST_NORM_WRITE[1])
             if cfg.hc_mult > 1:
                 p["hc_attn"] = hc_params(next(ks))
                 p["hc_mlp"] = hc_params(next(ks))
@@ -278,6 +309,12 @@ class CausalLM:
                 next(keys))}
         if cfg.embed_norm:
             params["embed_norm"] = norm_params()
+        if cfg.total_ut_steps > 1:
+            # the exit gate: one number a token and pass from the pass's
+            # normed output (sigmoid of it: the share that leaves here)
+            params["exit_gate"] = {
+                "kernel": dense((cfg.hidden_size, 1), next(keys)),
+                "bias": jnp.zeros((1,), jnp.float32)}
         if not cfg.tie_embeddings:
             params["lm_head"] = {
                 "kernel": dense((cfg.hidden_size, cfg.vocab_size), next(keys))}
@@ -341,6 +378,14 @@ class CausalLM:
                  ) -> Tuple[jnp.ndarray, Optional[KVCache], jnp.ndarray]:
         """Returns (logits [B,S,V] fp32, new_cache, total_aux_loss)."""
         cfg = self.config
+        if cfg.total_ut_steps > 1 or cfg.sandwich_norm:
+            raise NotImplementedError(
+                "a looped stack (total_ut_steps > 1: the layers run several "
+                "times over shared weights, an exit gate after each pass) "
+                "and post-sublayer norms (sandwich_norm) run on the serving "
+                "path only (inference/v2/model.py): the gradients of weights "
+                "used several times and the loss over the exit distribution "
+                "are not written")
         if cfg.layer_pattern is not None:
             raise NotImplementedError(
                 "a layer_pattern model (Mamba-2, expert and attention layers "

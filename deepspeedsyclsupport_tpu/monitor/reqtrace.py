@@ -146,6 +146,15 @@ MOE_TAIL_FIELDS = ("moe_touched", "moe_tiles", "moe_rows")
 #: expert is held (``num_experts_per_tok`` x expert layers). A dense model's
 #: record has neither.
 MOE_STATIC_FIELDS = ("moe_tile_rows", "moe_rows_a_token")
+#: What the record of a looped stack (``ModelConfig.total_ut_steps`` > 1)
+#: says besides: ``passes``, how many times the forward it launched runs the
+#: layers, and ``kv_rows``, the cache rows it writes and attends a token
+#: (passes x layers); and, counted on the DEVICE and so a record late as the
+#: ``moe_*`` counts are, ``exit_pass`` [passes]: the rows unembedded for a
+#: live sequence by the pass the exit rule took their logits from, SUMMED
+#: since the engine was built (at the published threshold all in the last).
+#: Every other model's record has none of the three.
+LOOP_FIELDS = ("passes", "kv_rows", "exit_pass")
 
 #: what a phase is where nothing times the round: ``trace_stages`` off, or
 #: an engine driven without a session
@@ -525,7 +534,8 @@ def round_phases(streams: Iterable[Tuple[str, str, Sequence[Dict[str, Any]]]]
     the p50/p99/mean seconds a round spent in it, the same for the whole
     round and for how far into it the forward was launched (``launch_s``),
     and per program launched the number of rounds and the mean of what its
-    forward covered (:data:`FORWARD_FIELDS`). ``None`` when the streams
+    forward covered (:data:`FORWARD_FIELDS`); ``loop`` (:data:`LOOP_FIELDS`)
+    where the records are a looped stack's. ``None`` when the streams
     hold no such record (journals from before it, or ``trace_stages``
     off)."""
     rounds: List[Dict[str, Any]] = []
@@ -557,7 +567,11 @@ def round_phases(streams: Iterable[Tuple[str, str, Sequence[Dict[str, Any]]]]
                               []).append(d)
     launched = [d["launch_t"] - d["t0"] for d in rounds
                 if d.get("launch_t") is not None]
+    # a looped stack: its passes, and the exit counter where it stood last
+    loop = {f: next((d[f] for d in reversed(rounds) if f in d), None)
+            for f in LOOP_FIELDS}
     return {"rounds": len(rounds),
+            **({"loop": loop} if loop["passes"] else {}),
             "round_s": _summary([d["t1"] - d["t0"] for d in rounds]),
             "launch_s": _summary(launched) if launched else None,
             "programs": {name: {"rounds": len(ds), **{

@@ -418,3 +418,67 @@ def test_the_two_kernels_carry_their_names_onto_the_custom_call(one_chip):
         assert calls and all(
             ln.split(" = ")[0].split("%")[-1].split(".")[0] == name
             for ln in calls), calls
+
+
+# ----------------------------------- a looped stack holds its pool ONCE
+@pytest.mark.parametrize("program", ["decode_forward", "ragged_forward"])
+def test_the_looped_forwards_hold_the_pool_once(one_chip, program):
+    """Both serving forwards of ``ouro-2.6b`` WHOLE (48 layers, four passes,
+    the cell's shapes: 16 sequences, 256 rows, 80 blocks of 64): the outer
+    loop over passes carries the 7.5 GiB pool [192, 5120, 16, 128] x 2 as
+    the layer scan inside it does, in place. A copy of the carry through the
+    nested loops would be a second pool, which the chip's 15.75 GiB do not
+    hold beside 4.97 GiB of weights: the whole pool is aliased to the
+    result and the temporaries are 1.13 GiB, which are the q, k and v
+    stacks [48, 2048, 2048] laid out transposed once a forward (the v5e
+    compiler's choice as soon as a second loop walks the stack: the same
+    three copies with four layer scans in sequence, none at one pass):
+    arguments + temporaries less what is aliased are 13.6 GiB. The kernels
+    are the paged custom calls and the passes' scopes reach the compiled
+    text (``benchmark/scopes.py``)."""
+    from benchmark import flops, scopes
+    from deepspeedsyclsupport_tpu.inference.v2 import model as M
+    from deepspeedsyclsupport_tpu.inference.v2.kv_cache import BlockedKV
+    from deepspeedsyclsupport_tpu.models import build_model
+    from deepspeedsyclsupport_tpu.ops.paged_attention import default_atom_rows
+
+    model = build_model("ouro-2.6b", dtype="bfloat16")
+    cfg = model.config
+    bs, blocks, seqs, toks, bps = 64, 80, 16, 256, 8
+    atom = default_atom_rows(RaggedInferenceConfig().atom_q_size,
+                             cfg.num_heads, cfg.num_kv_heads, 128, bs, 2)
+
+    def on_chip(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: on_chip(x.shape, jnp.bfloat16 if jnp.issubdtype(
+            x.dtype, jnp.floating) else x.dtype),
+        jax.eval_shape(model.init_params))
+    pool = on_chip((cfg.num_kv_layers, blocks * bs, cfg.num_kv_heads, 128),
+                   jnp.bfloat16)
+    assert pool.shape[0] == 192 and pool.size < 2**31
+    kv = BlockedKV(pool, pool, exit_pass=on_chip((4,)))
+    sampled = on_chip((seqs + 4,))
+    if program == "decode_forward":
+        fn = M.build_decode_forward_fn(model, bs, "pallas")
+        args = (on_chip((seqs,)), on_chip((seqs,)), on_chip((seqs, bps)),
+                on_chip((seqs,), jnp.bool_), sampled, on_chip((seqs,)))
+    else:
+        fn = M.build_ragged_forward_fn(model, bs, "kernel")
+        atoms = seqs + toks // atom + 1
+        args = (on_chip((toks,)), on_chip((toks,)), on_chip((toks,)),
+                on_chip((seqs, bps)), on_chip((seqs,)),
+                on_chip((atoms, atom)), on_chip((atoms,)), on_chip((atoms,)),
+                on_chip((atoms, bps)), on_chip((toks,)), on_chip((seqs,)),
+                on_chip((seqs,)), sampled, on_chip((toks,)))
+    compiled = fn.lower(params, kv, *args).compile()
+    gib = flops.program_bytes(compiled) / 2**30
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= 2 * pool.size * 2
+    assert m.temp_size_in_bytes < 1.25 * 2**30
+    assert 12.0 < gib < 13.75, gib
+    text = compiled.as_text()
+    assert 'custom_call_target="tpu_custom_call"' in text
+    under = scopes.instructions_under(text, ("loop_pass", "loop_exit"))
+    assert {"loop_pass", "loop_exit"} == set(under.values())

@@ -636,6 +636,13 @@ def requests_report(root: str, worst_n: int = 5, window_s: float = 60.0,
             if qs["mean_s"] > 0:
                 lines.append(f"    {phase:<14}mean={_fmt_s(qs['mean_s'])}  "
                              f"{_qline(qs)}")
+        loop = rp.get("loop")
+        if loop:
+            lines.append(
+                f"  looped stack: {loop['passes']} passes a forward over "
+                f"shared weights, {loop['kv_rows']} cache rows a token"
+                + (f"; rows by exit pass {loop['exit_pass']}"
+                   if loop["exit_pass"] is not None else ""))
         # what each program's forward covered, a round's mean (ahead: the
         # share of its launches made before the last forward's tokens were
         # read back; spec-rows: rows launched for a stream an EOS had ended)
