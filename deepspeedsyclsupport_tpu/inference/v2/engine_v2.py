@@ -15,6 +15,7 @@ NEXT forward with its decode tokens still on the device
 (:class:`SampledTokens`), and only then reads the tokens back.
 """
 import dataclasses
+import re
 import time
 from collections.abc import Mapping
 from typing import Any, Dict, Iterator, List, Optional, Sequence, Tuple
@@ -28,7 +29,7 @@ from .kv_cache import init_blocked_kv, state_pool_stats
 from .model import build_ragged_forward_fn, moe_tile_rows
 from .ragged import (BlockedAllocator, LogitsRef, SequenceDescriptor,
                      attention_work, build_ragged_batch, device_token,
-                     split_device_tokens, ssm_pieces)
+                     ragged_shapes, split_device_tokens, ssm_pieces)
 from .scheduler import schedule_chunks
 from ..params import place_inference_params
 from ..sampling import SamplingParams, sample_token_dyn, split_key
@@ -84,6 +85,38 @@ class SampledTokens:
     def ref(self, uid: int) -> int:
         """``uid``'s token as ``put`` takes it before it is read."""
         return device_token(self.rows[uid])
+
+
+class ProgramShapes:
+    """One forward program compiled at several static shapes
+    (``compiled_programs``), smallest first. ``as_text()`` is the LARGEST
+    shape's text whole, behind every smaller shape's less the instructions
+    whose names the largest has too: a reader that maps instruction names to
+    ``jax.named_scope`` labels (a device trace names an operation by its
+    instruction alone, whichever shape ran) then finds the operations only a
+    smaller shape has, and reads every name the compiler used in both as
+    the largest shape uses it. ``memory_analysis()`` and everything else are
+    the largest shape's: the temporaries of two shapes are never live
+    together."""
+
+    _INSTRUCTION = re.compile(r"\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
+
+    def __init__(self, by_rows):
+        self.by_rows = list(by_rows)
+
+    def as_text(self) -> str:
+        def name(line):          # None: the line defines no instruction
+            m = self._INSTRUCTION.match(line)
+            return m and m.group(1)
+
+        *smaller, largest = (c.as_text() for c in self.by_rows)
+        taken = set(map(name, largest.splitlines())) - {None}
+        return "\n".join([line for text in smaller
+                          for line in text.splitlines()
+                          if name(line) not in taken] + [largest])
+
+    def __getattr__(self, name):
+        return getattr(self.by_rows[-1], name)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -197,9 +230,10 @@ class InferenceEngineV2:
 
         self._decode_multi: "OrderedDict[Any, Any]" = OrderedDict()
         self._decode_multi_cap = 16
-        # name -> (jitted fn, abstract args) of every forward program this
-        # engine has dispatched (compiled_programs)
-        self._dispatched: Dict[str, Tuple[Any, Any]] = {}
+        # name -> (jitted fn, {rows: abstract args}) of every forward
+        # program this engine has dispatched, at every static shape it ran
+        # in (compiled_programs)
+        self._dispatched: Dict[str, Tuple[Any, Dict[int, Any]]] = {}
         # forward programs dispatched (_dispatch) plus sampler calls
         # (sample_drained): 2 a per-token round, which with the rng split
         # are all of its device launches (benchmark: launches_per_round);
@@ -254,6 +288,16 @@ class InferenceEngineV2:
         # one-row tile (attention_work's kv_step_keys)
         self._kv_step_keys = tuple(kv_step_keys(rows, *shape, latent)
                                    for rows in (cfg.atom_q_size, 1))
+        # the static shapes of ragged_forward, smallest first, by the rows of
+        # an atom and of a Mamba piece (0: the model takes none): a mixed
+        # round runs at the first that holds it (_run), none under
+        # _rows_floor (warmup() compiles a shape by raising it)
+        self._tiles = (
+            cfg.atom_q_size if self._use_atoms else 0,
+            model.config.ssm_chunk_size if self.kv.ssm is not None else 0)
+        self._shapes = ragged_shapes(cfg.max_tokens_per_batch,
+                                     cfg.max_sequences, *self._tiles)
+        self._rows_floor = 0
         log_dist(f"ragged engine: {cfg.num_blocks} KV blocks × {cfg.block_size} "
                  f"tokens, budget {cfg.max_tokens_per_batch} tok/fwd, "
                  f"≤{cfg.max_sequences} seqs")
@@ -329,13 +373,16 @@ class InferenceEngineV2:
                    topology=topology)
 
     # ---------------------------------------------------- compiled programs
-    def _dispatch(self, name: str, fn, *args):
+    def _dispatch(self, name: str, fn, *args, rows: int = 0):
         """Call forward program ``fn``, remembering the abstract arguments
-        of its first dispatch (taken BEFORE the call: the pool is donated)."""
-        if name not in self._dispatched:
+        of its first dispatch at each static shape (``rows``: the one size a
+        program's shapes differ by; taken BEFORE the call: the pool is
+        donated)."""
+        shapes = self._dispatched.setdefault(name, (fn, {}))[1]
+        if rows not in shapes:
             from ...analysis.capture import abstract_step_args
 
-            self._dispatched[name] = (fn, abstract_step_args(args))
+            shapes[rows] = abstract_step_args(args)
         self.host_dispatches += 1
         out = fn(*args)
         if self.round_spans is not None:
@@ -359,8 +406,9 @@ class InferenceEngineV2:
         What those tiles cover (``attn_pairs``, ``dec_ctx_tokens``) and
         what the kernels' loop steps walk for it (``kv_step_keys``,
         ``kv_tile_keys``) is ``ragged.attention_work``'s count. ``rows``: the
-        forward's whole row budget, pads included; a sparse-expert model's
-        record gets
+        rows the forward runs at, pads included (the shape a mixed round
+        was built at; ``max_sequences`` for a decode step); a sparse-expert
+        model's record gets
         ``reqtrace.MOE_STATIC_FIELDS``: the rows of the tiles its grouped
         GEMMs lay them in (static, by that shape) and the expert rows a live
         token brings. Its live tokens are counted whether or not a round is
@@ -377,6 +425,7 @@ class InferenceEngineV2:
             kv_step_keys=kv_step_keys, kv_tile_keys=kv_tile_keys,
             n_seqs=len(descs), tokens=sum(lengths),
             decode_rows=sum(n == 1 for n in lengths), atoms=atoms,
+            rows=rows,
             # the rule _run routes by: one token on top of cached context
             # is a decode step, anything else is prompt
             prefill_tokens=sum(n for d, n in zip(descs, lengths)
@@ -481,9 +530,16 @@ class InferenceEngineV2:
         engine has actually run (``ragged_forward``, ``decode_forward``,
         ``decode_multi_<K>``), re-lowered at the arguments it ran with — so a
         caller can check WHAT ran (``.as_text()``: is the attention a
-        ``tpu_custom_call``?) and what it needs (``.memory_analysis()``)."""
-        return {name: fn.lower(*avals).compile()
-                for name, (fn, avals) in self._dispatched.items()}
+        ``tpu_custom_call``?) and what it needs (``.memory_analysis()``).
+        A program that ran in several static shapes (``ragged_forward``,
+        ``ragged.ragged_shapes``) answers as :class:`ProgramShapes`."""
+        out = {}
+        for name, (fn, shapes) in self._dispatched.items():
+            by_rows = [fn.lower(*shapes[rows]).compile()
+                       for rows in sorted(shapes)]
+            out[name] = by_rows[0] if len(by_rows) == 1 \
+                else ProgramShapes(by_rows)
+        return out
 
     # --------------------------------------------------------------- warmup
     def warmup(self, fused_ladder: bool = False) -> None:
@@ -493,7 +549,11 @@ class InferenceEngineV2:
         placement, so each program's SECOND call in that state is the one
         that compiles the steady-state variant — without this, the first
         real requests pay two spurious recompiles (measured ~1.7s each on
-        the CPU sim; worse on TPU). A decode step here takes its token from
+        the CPU sim; worse on TPU). Those four forwards run the prefill
+        program at its LARGEST shape; every smaller one (``ragged_shapes``)
+        is then compiled once, in the steady state, the only one a forward
+        after the engine's first ever sees: no round of any shape compiles
+        while serving. A decode step here takes its token from
         the device as a serving round's does: the sampler is launched, the
         forward eats its output, then it is read. ``fused_ladder=True`` additionally
         compiles EVERY fused-decode rung {K/2, ..., 2}, not just K — a
@@ -512,20 +572,29 @@ class InferenceEngineV2:
         # sampler over the forward's whole logits with the tail a serving
         # session gives it
         _, key = split_key(jax.random.PRNGKey(0))
-        for toks in steps:
-            sampled = None
-            if toks is None:     # a decode step: the token the sampler drew
-                sampled = self.sample_launch([uid], key, SamplingParams(),
-                                             tail=self.round_tail())
-                toks = [sampled.ref(uid)]
-            out = self.put([uid], [toks], sampled=sampled)
-            if sampled is not None:
-                self.read_sampled(sampled)
-            if uid not in out and out.admission.rejected:
+        try:
+            self._rows_floor = cfg.max_tokens_per_batch
+            for toks in steps:
+                sampled = None
+                if toks is None:  # a decode step: the token the sampler drew
+                    sampled = self.sample_launch([uid], key, SamplingParams(),
+                                                 tail=self.round_tail())
+                    toks = [sampled.ref(uid)]
+                out = self.put([uid], [toks], sampled=sampled)
+                if sampled is not None:
+                    self.read_sampled(sampled)
+                if uid not in out and out.admission.rejected:
+                    self.flush([uid])
+                    raise RuntimeError(
+                        f"warmup could not admit its sequence — call "
+                        f"warmup() on an idle engine "
+                        f"({dict(out.admission.reasons)})")
+            for shape in self._shapes[:-1]:
+                self._rows_floor = shape.rows
                 self.flush([uid])
-                raise RuntimeError(
-                    f"warmup could not admit its sequence — call warmup() "
-                    f"on an idle engine ({dict(out.admission.reasons)})")
+                self.put([uid], [[2, 2]])
+        finally:
+            self._rows_floor = 0
         if cfg.decode_steps_per_dispatch > 1:
             # compile the fused K-step steady-state program too, for
             # generate()'s default greedy/no-eos config (non-default sampling
@@ -960,6 +1029,15 @@ class InferenceEngineV2:
                 self._host_tokens_only() if sampled is None
                 else sampled.array, jnp.asarray(take_from))
 
+    def _shape_of(self, lengths: Sequence[int]):
+        """The smallest of the engine's static shapes that holds chunks of
+        ``lengths`` tokens (the largest holds whatever the scheduler
+        emits)."""
+        return next((s for s in self._shapes[:-1]
+                     if s.rows >= self._rows_floor
+                     and s.holds(lengths, *self._tiles)),
+                    self._shapes[-1])
+
     def _run(self, chunks, sampled: Optional[SampledTokens] = None
              ) -> jax.Array:
         """One forward over ``chunks``; its whole ``[max_sequences, V]``
@@ -970,15 +1048,17 @@ class InferenceEngineV2:
         if all(n == 1 and d.n_cached > 0 for d, n in chunks):
             return self._run_decode(chunks, sampled)  # kernel fast path
         with self._phase("build"):
+            descs, lengths = zip(*chunks)
+            shape = self._shape_of(lengths)
             batch = build_ragged_batch(
-                chunks, cfg.max_tokens_per_batch, cfg.max_sequences,
-                cfg.blocks_per_seq,
-                atom_q=cfg.atom_q_size if self._use_atoms else None)
-            self._note_forward(*zip(*chunks), atoms=batch.live_atoms,
-                               rows=cfg.max_tokens_per_batch)
+                chunks, shape.rows, cfg.max_sequences, cfg.blocks_per_seq,
+                atom_q=cfg.atom_q_size if self._use_atoms else None,
+                atoms=shape.atoms)
+            self._note_forward(descs, lengths, atoms=batch.live_atoms,
+                               rows=shape.rows)
             state = () if self._state_free is None else (ssm_pieces(
-                chunks, cfg.max_tokens_per_batch, cfg.max_sequences,
-                self.model.config.ssm_chunk_size),)
+                chunks, shape.rows, cfg.max_sequences,
+                self.model.config.ssm_chunk_size, shape.pieces),)
         with self._phase("dispatch"):
             tokens, sampled, take_from = self._token_operands(batch.tokens,
                                                               sampled)
@@ -993,7 +1073,8 @@ class InferenceEngineV2:
                 jnp.asarray(batch.last_tok_idx),
                 *(a if a is None else jnp.asarray(a) for a in tiles),
                 sampled, take_from,
-                *(jax.tree_util.tree_map(jnp.asarray, a) for a in state))
+                *(jax.tree_util.tree_map(jnp.asarray, a) for a in state),
+                rows=shape.rows)
         return logits
 
     def _slot_arrays(self, descs):

@@ -214,6 +214,58 @@ class SequenceDescriptor:
         return max(0, want - len(self.blocks))
 
 
+def tile_places(rows: int, max_tokens: int, max_sequences: int,
+                tile: int) -> int:
+    """Places a forward of ``rows`` rows has for single-sequence tiles of
+    ``tile`` rows: the attention's atoms (the last place reserved dead) and
+    the Mamba layers' pieces. The largest forward, ``max_tokens`` rows, must
+    hold whatever the scheduler emits: a tile more than its whole ones for
+    each of ``max_sequences`` chunks. A smaller one has room for three chunk
+    tails beside its whole tiles; a round with more takes the next shape."""
+    if rows >= max_tokens:
+        return max_sequences + max_tokens // tile + 1
+    return rows // tile + 4
+
+
+class RaggedShape(NamedTuple):
+    """One static shape of ``ragged_forward``: ``rows`` of the packed token
+    axis, ``atoms`` of the ragged kernel's grid (0: the attention takes
+    none) and ``pieces`` of the Mamba layers' chunked scan (0: the model has
+    none), chosen together so that shapes do not multiply."""
+    rows: int
+    atoms: int
+    pieces: int
+
+    def holds(self, lengths: Sequence[int], atom_q: int, chunk: int) -> bool:
+        """Whether chunks of ``lengths`` tokens fit: by their rows, by the
+        atoms of ``atom_q`` rows their longer ones are cut into, and by the
+        pieces of ``chunk`` rows."""
+        return sum(lengths) <= self.rows and all(
+            sum(-(-n // tile) for n in lengths if n > 1) <= places - 1
+            for places, tile in ((self.atoms, atom_q), (self.pieces, chunk))
+            if places)
+
+
+def ragged_shapes(max_tokens: int, max_sequences: int, atom_q: int = 0,
+                  chunk: int = 0) -> Tuple[RaggedShape, ...]:
+    """The static shapes a mixed round's batch is built at, smallest first:
+    ``max_tokens`` rows (the scheduler's budget: it holds any round, at the
+    worst-case atom count) and, where that is smaller, its quarter rounded
+    up to whole 128 rows, so a budget of 128 rows or fewer has ONE shape. A
+    round runs at the first that :meth:`RaggedShape.holds` it: 31 decode
+    rows and a 100-token prompt then cost a 256-row forward with 6 atoms,
+    not 768 rows and 39. Two shapes and not more: every shape is one more
+    program for ``warmup()`` to load (about a second each on the chip)."""
+    quarter = -(-max_tokens // 512) * 128
+    rows = (quarter, max_tokens) if quarter < max_tokens else (max_tokens,)
+
+    def places(r, tile):
+        return tile_places(r, max_tokens, max_sequences, tile) if tile else 0
+
+    return tuple(RaggedShape(r, places(r, atom_q), places(r, chunk))
+                 for r in rows)
+
+
 class SsmBatch(NamedTuple):
     """What the Mamba layers of one ``ragged_forward`` take
     (``ops/ssm.py``). A chunk's place in the forward changes from forward
@@ -238,11 +290,13 @@ class SsmBatch(NamedTuple):
 
 
 def ssm_pieces(chunks, max_tokens: int, max_sequences: int,
-               chunk: int) -> SsmBatch:
+               chunk: int, pieces: Optional[int] = None) -> SsmBatch:
     """:class:`SsmBatch` of scheduled ``(descriptor, n_tokens)`` chunks,
-    laid on the flat axis as :func:`build_ragged_batch` lays them."""
+    laid on the flat axis as :func:`build_ragged_batch` lays them, with
+    ``pieces`` places for them (None: the worst case of ``max_tokens`` rows,
+    :func:`tile_places`)."""
     S = max_sequences
-    p_max = S + max_tokens // chunk + 1
+    p_max = pieces or tile_places(max_tokens, max_tokens, S, chunk)
     seq_slot = np.full((S,), S, np.int32)
     dec_row, dec_len = np.zeros((S,), np.int32), np.zeros((S,), np.int32)
     row0, length = np.zeros((p_max,), np.int32), np.zeros((p_max,), np.int32)
@@ -350,12 +404,16 @@ def attention_work(descs: Sequence[SequenceDescriptor],
 def build_ragged_batch(chunks: Sequence[Tuple[SequenceDescriptor, int]],
                        max_tokens: int, max_sequences: int,
                        blocks_per_seq: int,
-                       atom_q: Optional[int] = None) -> RaggedBatch:
+                       atom_q: Optional[int] = None,
+                       atoms: Optional[int] = None) -> RaggedBatch:
     """Assemble metadata for scheduled ``(descriptor, n_tokens)`` chunks.
 
     The chunk's tokens are ``desc.pending[:n_tokens]``; positions continue from
     ``desc.n_cached``. Mirrors ``RaggedBatchWrapper.insert_sequence`` +
-    ``finalize``.
+    ``finalize``. ``max_tokens`` rows and ``atoms`` atoms (None: the worst
+    case of that many rows) are the forward's static shape
+    (:func:`ragged_shapes`): every array here is sized by them, and what
+    they hold beyond the chunks is padding the model never reads.
     """
     if len(chunks) > max_sequences:
         raise ValueError(f"{len(chunks)} chunks > max_sequences {max_sequences}")
@@ -383,7 +441,7 @@ def build_ragged_batch(chunks: Sequence[Tuple[SequenceDescriptor, int]],
         uids.append(desc.uid)
         cursor += n
 
-    atoms = {}
+    tiles = {}
     if atom_q:
         # atoms: ≤atom_q-row single-sequence q tiles (reference atom_builder)
         # of the chunks of two tokens or more. Worst case sum(ceil(n_i/BQ))
@@ -391,7 +449,7 @@ def build_ragged_batch(chunks: Sequence[Tuple[SequenceDescriptor, int]],
         # packed rows, and the one-token chunks' (dec_row / dec_len: a
         # whole atom would hold one live row), gather a guaranteed zero
         BQ = atom_q
-        A_max = S + T // BQ + 1
+        A_max = atoms or tile_places(T, T, S, BQ)
         atom_qidx = np.zeros((A_max, BQ), np.int32)
         atom_pos0 = np.zeros((A_max,), np.int32)
         atom_qlen = np.zeros((A_max,), np.int32)
@@ -418,8 +476,8 @@ def build_ragged_batch(chunks: Sequence[Tuple[SequenceDescriptor, int]],
                     a += 1
             cur += n
         assert a <= A_max - 1, "atom overflow — builder bug"
-        atoms = dict(atom_qidx=atom_qidx, atom_pos0=atom_pos0,
+        tiles = dict(atom_qidx=atom_qidx, atom_pos0=atom_pos0,
                      atom_qlen=atom_qlen, atom_tables=atom_tables,
                      atom_inv=atom_inv, dec_row=dec_row, dec_len=dec_len)
     return RaggedBatch(tokens, token_seq, token_pos, block_tables, last_tok,
-                       active, uids, **atoms)
+                       active, uids, **tiles)

@@ -113,6 +113,10 @@ ROUND_PHASES = (
 #: kernels' loops walk for it, summed over the atoms and the one-row tiles:
 #: ``kv_tile_keys``, the keys each tile may see, and ``kv_step_keys``, those
 #: rounded up to whole loop steps (several KV blocks on a latent pool).
+#: ``rows`` is the static row count the forward ran at, pads included: the
+#: shape a ``ragged_forward``'s batch was built at (the smallest of the
+#: engine's ``ragged.ragged_shapes`` that held the round), ``max_sequences``
+#: for a ``decode_forward``; ``tokens`` / ``rows`` is how full it was.
 #: ``moe_touched`` is the one field the DEVICE counts (a sparse-expert
 #: model's experts with at least one live row, summed over layers: the
 #: expert weights a forward had to read; 0 for a dense model). It comes back
@@ -126,7 +130,7 @@ ROUND_PHASES = (
 FORWARD_FIELDS = ("n_seqs", "tokens", "prefill_tokens", "ctx_tokens",
                   "kv_blocks", "decode_rows", "atoms", "attn_pairs",
                   "dec_ctx_tokens", "moe_touched", "ahead", "spec_rows",
-                  "kv_step_keys", "kv_tile_keys")
+                  "kv_step_keys", "kv_tile_keys", "rows")
 #: What the device counts, in the order it rides behind the sampled tokens
 #: (``engine.moe_tail``). ``moe_rows`` is on the record ONLY of a program
 #: that holds a share of the router's experts (one chip of an expert-

@@ -460,8 +460,6 @@ def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
     if k_cache.ndim == (2 if latent else 3):     # one layer's cache
         pools, layer = tuple(pool[None] for pool in pools), 0
     kvh = 1 if latent else k_cache.shape[-2]
-    row = pools[0].shape[2:]                     # (KVH, D), latent: (D,)
-    d_out = v_dim if latent else d
     itemsize = q_atoms.dtype.itemsize
     # heads of one grid step; > 1 tiles only under a single kv head
     ht = _head_tile(bq, h, kvh, d, block_size, itemsize)
@@ -469,7 +467,6 @@ def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
     g = ht // kvh
     # KV blocks a loop step takes: chosen AFTER the tile, from what it left
     pages = _kv_pages_per_step(bq, ht, kvh, d, block_size, itemsize, latent)
-    max_blocks = atom_tables.shape[1]
     if alibi is not None:
         # per-lane slope layout matches the kernel's [KVH, BQ·G] score rows:
         # lane (r·G + gi) of kv head kh carries q head kh·G + gi (under one
@@ -479,6 +476,41 @@ def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
             (1, bq, 1)).reshape(kvh * tiles, bq * g, 1)
     else:
         ab = jnp.zeros((kvh * tiles, bq * g, 1), jnp.float32)
+
+    return _tiled_call(
+        jnp.asarray(atom_tables, jnp.int32), jnp.asarray(atom_pos0, jnp.int32),
+        jnp.asarray(atom_qlen, jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1), q_atoms, pools, ab,
+        block_size=block_size, ht=ht, pages=pages,
+        use_alibi=alibi is not None,
+        window=None if window is None else int(window),
+        v_dim=v_dim if latent else None,
+        vmem_limit=_ragged_vmem_limit(bq, ht, kvh, d, block_size, itemsize,
+                                      pages),
+        interpret=interpret, name=name)
+
+
+@functools.partial(jax.jit, inline=True, static_argnames=(
+    "block_size", "ht", "pages", "use_alibi", "window", "v_dim", "vmem_limit",
+    "interpret", "name"))
+def _tiled_call(atom_tables, atom_pos0, atom_qlen, layer, q_atoms, pools, ab,
+                *, block_size, ht, pages, use_alibi, window, v_dim,
+                vmem_limit, interpret, name):
+    """The ``pallas_call`` of :func:`ragged_prefill_attention_pallas`, every
+    choice made (``ht`` heads a grid step, ``pages`` KV blocks a loop step).
+    Inlined into its caller's trace, so the program is the one a plain call
+    gives; but the trace of the kernel body is kept by shapes and choices,
+    and a forward calls the one body for each of its layer stacks at both
+    tile heights, ``decode_forward`` again at the one-row tile, and every
+    further static shape of ``ragged_forward`` at that tile too: tracing the
+    body is most of what tracing a serving forward costs."""
+    a, bq, h, d = q_atoms.shape
+    latent = len(pools) == 1
+    kvh = 1 if latent else pools[0].shape[-2]
+    row = pools[0].shape[2:]                     # (KVH, D), latent: (D,)
+    d_out = v_dim if latent else d
+    tiles = h // ht
+    g = ht // kvh
 
     def tile_of(grid_idx):      # (atom[, head tile]) of a grid step
         return grid_idx[0], (grid_idx[1] if tiles > 1 else 0)
@@ -507,22 +539,17 @@ def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
         ],
     )
     kernel = functools.partial(_prefill_kernel, block_size=block_size,
-                               max_blocks=max_blocks, group=g,
-                               use_alibi=alibi is not None,
-                               window=None if window is None else int(window),
-                               v_dim=v_dim if latent else None, pages=pages)
+                               max_blocks=atom_tables.shape[1], group=g,
+                               use_alibi=use_alibi, window=window,
+                               v_dim=v_dim, pages=pages)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((a, bq, h, d_out), q_atoms.dtype),
         grid_spec=grid_spec,
-        compiler_params=pltpu.CompilerParams(
-            vmem_limit_bytes=_ragged_vmem_limit(
-                bq, ht, kvh, d, block_size, itemsize, pages)),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
         name=name,
-    )(jnp.asarray(atom_tables, jnp.int32), jnp.asarray(atom_pos0, jnp.int32),
-      jnp.asarray(atom_qlen, jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), q_atoms, *pools, ab)
+    )(atom_tables, atom_pos0, atom_qlen, layer, q_atoms, *pools, ab)
 
 
 def ragged_prefill_attention_reference(q_atoms, k_cache, v_cache, atom_tables,

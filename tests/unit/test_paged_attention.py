@@ -311,3 +311,45 @@ def test_decode_dead_slot_exact_zero():
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
     assert float(jnp.abs(got[2]).max()) == 0.0
+
+
+@pytest.mark.parametrize("pages", [1, 2])
+def test_the_kernel_body_is_traced_once_a_tile_not_once_a_call(monkeypatch,
+                                                               pages):
+    """A forward calls the one body for each layer stack, ``decode_forward``
+    again, every further static shape of ``ragged_forward`` again: calls of
+    the same shapes and choices share one trace of it, whoever traces them;
+    a choice made differently (the KV blocks a loop step takes) is another
+    trace, and the program is the one a plain call gives."""
+    from deepspeedsyclsupport_tpu.ops import paged_attention as pa
+
+    traced = []
+    body = pa._prefill_kernel
+
+    def counting(*refs, **kw):
+        traced.append(kw["pages"])
+        return body(*refs, **kw)
+
+    monkeypatch.setattr(pa, "_prefill_kernel", counting)
+    monkeypatch.setattr(pa, "_kv_pages_per_step", lambda *a: pages)
+    # a tile no other test of this process asks for: 5 slots, 6 heads
+    q, k, v, tables, lens = _setup(3, s=5, h=6, kvh=3, d=16, bps=3,
+                                   seq_lens=[48, 19, 1, 0, 7])
+    k, v = _in_pool(k, 1), _in_pool(v, 1)
+
+    def stack(q, layer):
+        return pa.paged_decode_attention_pallas(
+            q, k, v, tables, lens, block_size=16, layer=layer,
+            interpret=True)
+
+    def forward(q):       # two layer stacks, as a model of two kinds has
+        return stack(q, jnp.int32(1)) + stack(q * 2.0, jnp.int32(1))
+
+    jax.jit(forward).lower(q)
+    assert traced == [pages]
+    out = jax.jit(lambda q: stack(q, jnp.int32(1)))(q)      # another program
+    assert traced == [pages]
+    ref = paged_decode_attention_reference(q, k, v, tables, lens,
+                                           block_size=16, layer=1)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)

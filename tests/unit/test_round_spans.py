@@ -372,7 +372,7 @@ def test_the_journal_carries_the_record_and_the_report_prints_its_phases(
     assert cover[0] == ["launched", "rounds", "seqs", "tokens", "prompt",
                         "context", "kv", "blocks", "1-row", "atoms",
                         "pairs", "1-row-ctx", "experts", "ahead",
-                        "spec-rows", "step-keys", "tile-keys"]
+                        "spec-rows", "step-keys", "tile-keys", "rows"]
     assert int(cover[1][1]) == decode["rounds"]
     assert [float(x) for x in cover[1][2:]] == [
         pytest.approx(decode[f], abs=0.05) for f in reqtrace.FORWARD_FIELDS]

@@ -643,9 +643,10 @@ def requests_report(root: str, worst_n: int = 5, window_s: float = 60.0,
                 f"shared weights, {loop['kv_rows']} cache rows a token"
                 + (f"; rows by exit pass {loop['exit_pass']}"
                    if loop["exit_pass"] is not None else ""))
-        # what each program's forward covered, a round's mean (ahead: the
-        # share of its launches made before the last forward's tokens were
-        # read back; spec-rows: rows launched for a stream an EOS had ended)
+        # what each program's forward covered, a round's mean (rows: the
+        # static rows it ran at, pads included; ahead: the share of its
+        # launches made before the last forward's tokens were read back;
+        # spec-rows: rows launched for a stream an EOS had ended)
         # a program that holds a share of the experts counts their rows too
         share = any("moe_rows" in m for m in rp["programs"].values())
         # a forward whose grouped GEMMs counted their row tiles: how full
@@ -667,7 +668,7 @@ def requests_report(root: str, worst_n: int = 5, window_s: float = 60.0,
                      f"{'kv blocks':>11}{'1-row':>8}{'atoms':>8}"
                      f"{'pairs':>12}{'1-row-ctx':>11}{'experts':>9}"
                      f"{'ahead':>7}{'spec-rows':>11}"
-                     f"{'step-keys':>11}{'tile-keys':>11}"
+                     f"{'step-keys':>11}{'tile-keys':>11}{'rows':>8}"
                      + (f"{'exp-rows':>10}" if share else "")
                      + (f"{'tile-rows':>11}{'tile-fill':>11}{'tiles/expert':>14}"
                         if tiled else ""))
@@ -684,6 +685,7 @@ def requests_report(root: str, worst_n: int = 5, window_s: float = 60.0,
                          f"{m.get('spec_rows', 0):>11.2f}"
                          f"{m.get('kv_step_keys', 0):>11.1f}"
                          f"{m.get('kv_tile_keys', 0):>11.1f}"
+                         f"{m.get('rows', 0):>8.1f}"
                          + (f"{m.get('moe_rows', 0):>10.1f}" if share
                             else "") + (tile_cols(m) if tiled else ""))
     if att["cached_prefix_tokens_mean"]:
