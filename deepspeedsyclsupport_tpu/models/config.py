@@ -154,7 +154,11 @@ class ModelConfig:
 
     # Training-time behavior
     remat: bool = False             # jax.checkpoint each layer (activation ckpt)
-    remat_policy: Optional[str] = None  # jax.checkpoint_policies name
+    # a jax.checkpoint_policies name, a rung of models/remat.py, or "auto":
+    # the richest rung that fits remat_free_bytes (one device's memory less
+    # the engine's resident state; None: no limit known, nothing is saved)
+    remat_policy: Optional[str] = None
+    remat_free_bytes: Optional[int] = None
     scan_layers: bool = True        # lax.scan over stacked layer params
     # pipeline microbatches per forward when the topology has pipe>1
     # (None => number of stages); config key pipeline.micro_batches

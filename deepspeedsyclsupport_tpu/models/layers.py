@@ -17,6 +17,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from .config import ModelConfig
 
@@ -479,6 +480,11 @@ def _attention_block_impl(p, x, cfg, positions, segment_ids, kv_cache, impl,
         q = q + p["bq"].astype(q.dtype)
         k = k + p["bk"].astype(k.dtype)
         v = v + p["bv"].astype(v.dtype)
+    # names for a checkpointed layer's policy (models/remat.py); inert
+    # without one. From here to the kernel everything is elementwise
+    q = checkpoint_name(q, "attn_q")
+    k = checkpoint_name(k, "attn_k")
+    v = checkpoint_name(v, "attn_v")
     q, k = qk_norm(p, q, k, cfg)
     q = q.reshape(b, s, cfg.num_heads, cfg.head_dim)
     k = k.reshape(b, s, cfg.num_kv_heads, cfg.head_dim)
@@ -555,6 +561,8 @@ def _attention_block_impl(p, x, cfg, positions, segment_ids, kv_cache, impl,
     out = jnp.einsum("bsq,qd->bsd", out, p["wo"])
     if cfg.attn_out_bias:
         out = out + p["bo"].astype(out.dtype)
+    # the MLP's weight gradients read the stream AFTER this sublayer
+    out = checkpoint_name(out, "attn_out")
     return constrain(out, BATCH, "seq", None), new_cache
 
 
@@ -571,8 +579,9 @@ def glu_mlp(p: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     ``csrc/transformer/inference/csrc/gelu.cu`` / v2 ``gated_activations``; XLA
     fuses the same chain into the matmul epilogue on TPU."""
     act = _activation(cfg.activation)
-    gate = jnp.einsum("bsd,df->bsf", x, p["w_gate"])
-    up = jnp.einsum("bsd,df->bsf", x, p["w_up"])
+    gate = checkpoint_name(jnp.einsum("bsd,df->bsf", x, p["w_gate"]),
+                           "mlp_gate")
+    up = checkpoint_name(jnp.einsum("bsd,df->bsf", x, p["w_up"]), "mlp_up")
     h = act(gate) * up
     h = constrain(h, BATCH, "seq", "model")
     return jnp.einsum("bsf,fd->bsd", h, p["w_down"])
@@ -586,7 +595,7 @@ def std_mlp(p: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
     h = jnp.einsum("bsd,df->bsf", x, p["fc1"])
     if cfg.use_bias:
         h = h + p["b1"].astype(h.dtype)
-    h = act(h)
+    h = act(checkpoint_name(h, "mlp_up"))
     h = constrain(h, BATCH, "seq", "model")
     out = jnp.einsum("bsf,fd->bsd", h, p["fc2"])
     if cfg.use_bias:

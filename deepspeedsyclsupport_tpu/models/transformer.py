@@ -21,8 +21,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import remat
 from .config import ModelConfig, get_config
-from .layers import BATCH, attention_block, constrain, mlp_block, norm
+from .layers import (BATCH, _manual_axes, attention_block, constrain,
+                     mlp_block, norm)
 
 Params = Dict[str, Any]
 
@@ -366,6 +368,51 @@ class CausalLM:
         h, aux = run_mlp(norm(x, p["mlp_norm"], cfg))
         return (x + h).astype(dtype), new_cache, aux
 
+    def _remat_policy(self, b: int, s: int, note: bool = True):
+        """The ``jax.checkpoint`` policy of a checkpointed layer (None:
+        nothing saved) from ``cfg.remat_policy``: a ``jax.checkpoint_policies``
+        name means what it says; a rung of ``models/remat.py`` keeps the
+        tensors it names; ``"auto"`` (what the engine passes when its
+        ``activation_checkpointing`` section names no policy) takes the
+        richest rung whose saved bytes, over all layers, fit in
+        ``cfg.remat_free_bytes``, the device's memory less the engine's
+        resident state: ``nothing_saveable`` where no limit is known. The
+        shapes are the trace's: ``b`` x ``s`` tokens and the widths, over
+        the mesh axes that split them and are not manual here (inside a
+        ``shard_map`` the trace sees a shard's own). What a TRAINING trace
+        took (``note``) is left in ``self.remat_choice`` for the engine to
+        log and publish. Two
+        stacks never come here and checkpoint WHOLE layers under any policy:
+        the pipelined trunk (``spmd_pipeline(remat=cfg.remat)``) and
+        random-LTD's middle stack (a bare ``jax.checkpoint``)."""
+        cfg = self.config
+        name = cfg.remat_policy
+        if name == "offload_dots_to_host":
+            # activation offload (reference cpu_checkpointing): saved
+            # dots land in pinned host memory instead of HBM
+            return jax.checkpoint_policies.offload_dot_with_no_batch_dims(
+                "device", "pinned_host")
+        if name != "auto" and name not in remat.RUNGS:
+            return getattr(jax.checkpoint_policies, name) if name else None
+        from ..comm import topology as topo_mod
+
+        topo = topo_mod._WORLD_TOPOLOGY
+        sizes = topo.axis_sizes if topo is not None else {}
+        manual = _manual_axes()
+
+        def shards(*axes):
+            return int(np.prod([sizes.get(a, 1) for a in axes
+                                if a not in manual]))
+
+        tokens = b * s / shards(*BATCH, "seq")
+        split = (shards("model"), shards("expert"))
+        rung = name if name != "auto" else remat.choose_rung(
+            cfg, tokens, cfg.remat_free_bytes, *split)
+        saved = remat.rung_bytes(cfg, tokens, *split)[rung] * cfg.num_layers
+        if note:
+            self.remat_choice = {"rung": rung, "saved_bytes": saved}
+        return remat.rung_policy(rung)
+
     def _forward(self, params: Params, input_ids: jnp.ndarray,
                  positions: Optional[jnp.ndarray] = None,
                  segment_ids: Optional[jnp.ndarray] = None,
@@ -438,19 +485,6 @@ class CausalLM:
             nck, ncv = (new_c[0], new_c[1]) if new_c is not None else (ck, cv)
             return x, nck, ncv, aux
 
-        if cfg.remat:
-            policy = None
-            if cfg.remat_policy == "offload_dots_to_host":
-                # activation offload (reference cpu_checkpointing): saved
-                # dots land in pinned host memory instead of HBM
-                policy = jax.checkpoint_policies.offload_dot_with_no_batch_dims(
-                    "device", "pinned_host")
-            elif cfg.remat_policy and cfg.remat_policy != "nothing_saveable":
-                policy = getattr(jax.checkpoint_policies, cfg.remat_policy)
-            # layer_idx is a STATIC python arg (per-layer window selection)
-            layer_fn = jax.checkpoint(layer_fn, policy=policy,
-                                      static_argnums=(5,))
-
         new_cache = None
         rltd_keep = cfg.random_ltd_current
         use_rltd = (cfg.random_ltd and train and cache is None
@@ -467,6 +501,18 @@ class CausalLM:
             pipe_n = cfg.pipe_stages
         else:
             pipe_n = wtopo.axis_sizes.get("pipe", 1) if wtopo is not None else 1
+        if cfg.remat and pipe_n == 1 and not use_rltd:
+            # layer_idx is a STATIC python arg (per-layer window selection).
+            # The pipelined trunk and random-LTD's middle stack below take
+            # the bare flag: whole-layer remat, whatever the policy.
+            # Under the scan the forward and the backward are two loops and
+            # nothing can merge the recomputation into the forward, so the
+            # barriers that prevent it are left out: they held every saved
+            # tensor's slice of the layer live at once (15.18 GiB against
+            # 14.77 for mistral-7b-d2's step under attn+mlp: PERF.md, PR 43)
+            layer_fn = jax.checkpoint(
+                layer_fn, policy=self._remat_policy(b, s, note=train),
+                prevent_cse=not cfg.scan_layers, static_argnums=(5,))
         if pipe_n > 1:
             # Pipeline-parallel trunk (reference ``runtime/pipe/module.py:636``
             # PipelineModule semantics, reachable from ``{"pipeline":

@@ -225,13 +225,23 @@ def build_opmap(hlo_text: str) -> Dict[str, Dict[str, Any]]:
 # of mistral-7b-d2's step took 44 ms on the v5e: a run that never asks pays
 # nothing)
 _PUBLISHED: Dict[str, Any] = {}
+_RECORDS: Dict[str, Dict[str, Any]] = {}
 
 
-def publish(program: str, compiled: Any) -> None:
+def publish(program: str, compiled: Any, **record: Any) -> None:
     """Keep ``compiled`` (anything with ``as_text()``) as the LAST compiled
     step of ``program`` — the name the profiler's ``XLA Modules`` line gives
-    it, less ``jit_``. ``Engine.compiled_train_step()`` calls this."""
+    it, less ``jit_``. ``Engine.compiled_train_step()`` calls this, and
+    hands with it what the program cannot say of itself (``remat``: the rung
+    its checkpointed layers took and the bytes they save): :func:`step_record`."""
     _PUBLISHED[program] = compiled
+    _RECORDS[program] = record
+
+
+def step_record(program: str) -> Dict[str, Any]:
+    """What was published beside the last step of ``program``; empty where
+    nothing was."""
+    return _RECORDS.get(program, {})
 
 
 def published(program: str) -> Optional[Dict[str, Dict[str, Any]]]:

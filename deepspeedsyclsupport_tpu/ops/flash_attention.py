@@ -33,6 +33,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -639,6 +640,18 @@ def _bwd_call(q, k, v, do, lse, delta, seg_q, seg_k, pos_q, pos_k, ab,
 
 
 # ----------------------------------------------------------------- custom_vjp
+# Names on the two residuals only the forward KERNEL can make, so that a
+# ``jax.checkpoint`` policy can keep them (``models/remat.py``): a Pallas call
+# is not a dot, and a backward pass that lacks them runs the forward kernel a
+# second time. Inert without a policy that names them.
+FLASH_RESIDUAL_NAMES = ("flash_o", "flash_lse")
+
+
+def _named_residuals(o, lse):
+    return tuple(checkpoint_name(x, n)
+                 for x, n in zip((o, lse), FLASH_RESIDUAL_NAMES))
+
+
 @functools.lru_cache(maxsize=None)
 def _make_flash(head_dim, causal, skip_offset, q_len, kv_len, block_q,
                 block_k, use_alibi, window, has_bias, has_kbias, has_layout,
@@ -659,8 +672,9 @@ def _make_flash(head_dim, causal, skip_offset, q_len, kv_len, block_q,
         return o
 
     def f_fwd(q, k, v, seg_q, seg_k, pos_q, pos_k, ab, bias, kbias, layout):
-        o, lse = _fwd_call(q, k, v, seg_q, seg_k, pos_q, pos_k, ab,
-                           *split(bias, kbias, layout), **call_kw)
+        o, lse = _named_residuals(*_fwd_call(
+            q, k, v, seg_q, seg_k, pos_q, pos_k, ab,
+            *split(bias, kbias, layout), **call_kw))
         return o, (q, k, v, seg_q, seg_k, pos_q, pos_k, ab, bias, kbias,
                    layout, o, lse)
 
@@ -716,8 +730,9 @@ def _make_flash_lse(head_dim, causal, skip_offset, q_len, kv_len, block_q,
                          *split(bias, kbias, layout), **call_kw)
 
     def f_fwd(q, k, v, seg_q, seg_k, pos_q, pos_k, ab, bias, kbias, layout):
-        o, lse = _fwd_call(q, k, v, seg_q, seg_k, pos_q, pos_k, ab,
-                           *split(bias, kbias, layout), **call_kw)
+        o, lse = _named_residuals(*_fwd_call(
+            q, k, v, seg_q, seg_k, pos_q, pos_k, ab,
+            *split(bias, kbias, layout), **call_kw))
         return (o, lse), (q, k, v, seg_q, seg_k, pos_q, pos_k, ab, bias,
                           kbias, layout, o, lse)
 

@@ -21,6 +21,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from ..models.layers import _activation, constrain, glu_mlp, std_mlp
 
@@ -152,9 +153,10 @@ def moe_mlp(p: Dict[str, Any], x: jnp.ndarray, cfg,
     glu, act = _expert_form(cfg)
 
     def one_expert(w, h):  # h: [C, D]
-        up = jnp.einsum("cd,df->cf", h, w["w_up"])
-        mid = act(jnp.einsum("cd,df->cf", h, w["w_gate"])) * up if glu \
-            else act(up)
+        # the dense MLP's names for a checkpointed layer (models/remat.py)
+        up = checkpoint_name(jnp.einsum("cd,df->cf", h, w["w_up"]), "mlp_up")
+        mid = act(checkpoint_name(jnp.einsum("cd,df->cf", h, w["w_gate"]),
+                                  "mlp_gate")) * up if glu else act(up)
         return jnp.einsum("cf,fd->cd", mid, w["w_down"])
 
     expert_out = jax.vmap(one_expert)(

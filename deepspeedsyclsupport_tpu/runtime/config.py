@@ -250,7 +250,38 @@ class ParallelismConfig:
 class ActivationCheckpointingConfig:
     """``activation_checkpointing`` (reference:
     ``runtime/activation_checkpointing/checkpointing.py``). Under XLA this maps to
-    ``jax.checkpoint`` policies rather than manual save/recompute."""
+    ``jax.checkpoint`` policies rather than manual save/recompute: the section
+    puts ``jax.checkpoint`` around every layer of the model's stack, and
+    ``policy`` says what a layer keeps for its backward pass.
+
+    WITHOUT ``policy`` the backward keeps what the device has room for: the
+    model's step takes the richest rung of ``models/remat.py`` whose saved
+    bytes, over all layers and on one device, fit in the device's memory
+    (``memory_stats()["bytes_limit"]`` less 5 %) less the engine's resident
+    state (parameters, optimizer state, float32 gradients) and the step's
+    counted working set. The rungs and their bytes a layer (bf16, ``T`` tokens
+    a device, widths ``d`` / ``q`` / ``kv`` / ``f``, ``h`` heads):
+
+    * ``attn+mlp``: the q / k / v projections, the attention sublayer's
+      output, the flash kernel's ``o`` and ``lse``, the MLP's gate and up
+      products: ``T x (4q + 4kv + 4h + 2d + 4f)`` B (0.71 GB at mistral-7b's
+      widths and T = 8,192); only norms, rotary and the activation are
+      recomputed;
+    * ``attn``: the attention sublayer's alone, ``T x (4q + 4kv + 4h + 2d)``
+      B (0.24 GB);
+    * ``nothing_saveable``: the layer's input alone, everything recomputed.
+      Taken where the device reports no limit (the CPU).
+
+    The engine logs the rung and its bytes at the step's first trace and
+    publishes them with the compiled step (``Engine.remat_choice``,
+    ``monitor.mfu.step_record``). If the compiled step still does not fit,
+    the engine steps down one rung, says so, and compiles again.
+
+    WITH ``policy`` (one of ``VALID_POLICIES``), or with
+    ``cpu_checkpointing``, the section means exactly that policy.
+
+    A pipelined trunk (``pipeline.stages > 1``) and random-LTD's middle stack
+    checkpoint whole layers whatever the policy."""
     # section presence turns checkpointing ON unless explicitly disabled
     # ("enabled" is a dstpu extension: the reference has no off-switch in
     # the section, and partition_activations means TP-partitioning there,
@@ -261,7 +292,7 @@ class ActivationCheckpointingConfig:
     number_checkpoints: Optional[int] = None
     contiguous_memory_optimization: bool = False
     cpu_checkpointing: bool = False
-    policy: str = "nothing_saveable"  # jax.checkpoint policy name
+    policy: Optional[str] = None  # jax.checkpoint policy name; None: by room
 
     # zero-arg jax.checkpoint_policies only — factory-style names (e.g.
     # save_only_these_names) would be silently misused as policies
@@ -273,8 +304,8 @@ class ActivationCheckpointingConfig:
 
     @classmethod
     def from_dict(cls, d: Dict[str, Any]) -> "ActivationCheckpointingConfig":
-        policy = str(d.get("policy", "nothing_saveable"))
-        if policy not in cls.VALID_POLICIES:
+        policy = d.get("policy")
+        if policy is not None and policy not in cls.VALID_POLICIES:
             raise ValueError(
                 f"activation_checkpointing.policy {policy!r} is not a "
                 f"supported jax.checkpoint policy; choose one of "

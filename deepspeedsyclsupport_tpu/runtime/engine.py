@@ -23,6 +23,7 @@ with reference user code, implemented over the same jitted kernels.
 """
 import glob as glob_mod
 import json
+import math
 import os
 import sys
 import time
@@ -41,6 +42,7 @@ from .loss_scaler import (LossScaleState, grads_finite, init_loss_scale, scale_l
 from .lr_schedules import build_schedule
 from .optimizers import build_optimizer, current_lr
 from .sentinel import SENTINEL_GATE_KEY
+from ..accelerator import get_accelerator
 from ..checkpoint.engine import LATEST_FILE
 from ..comm.comms_logging import comms_logger
 from ..comm.topology import MeshTopology, build_topology
@@ -308,6 +310,8 @@ class Engine:
 
         # ---------------------------------------------------------- step fns
         self._train_batch_fn = None  # built lazily (needs gas)
+        self._remat_logged = None  # the rung last logged (_note_remat_choice)
+        self._remat_auto = False   # the section names no policy: by room
         self._grad_fn = None
         self._apply_fn = None
         self._eval_fn = None
@@ -395,11 +399,28 @@ class Engine:
                     mcfg_overrides["remat_policy"] = "offload_dots_to_host"
                     log_dist("cpu_checkpointing: dot activations offload to "
                              "pinned host memory")
-                else:
+                elif ac.policy is not None:
                     mcfg_overrides["remat"] = True
                     mcfg_overrides["remat_policy"] = ac.policy
                     log_dist(f"activation checkpointing on "
                              f"(policy={ac.policy})")
+                else:
+                    # no policy: the backward keeps what this device has
+                    # room for (models/remat.py picks the rung where the
+                    # stack is traced and the batch's shape is known; the
+                    # rung is logged there: _note_remat_choice)
+                    free = self._device_free_bytes()
+                    mcfg_overrides["remat"] = True
+                    mcfg_overrides["remat_policy"] = "auto"
+                    self._remat_auto = True
+                    mcfg_overrides["remat_free_bytes"] = free
+                    log_dist(
+                        "activation checkpointing on (policy by room: "
+                        + ("the device reports no memory limit, "
+                           "nothing_saveable" if free is None else
+                           f"{free / 2**30:.2f} GiB of the device left to "
+                           f"saved activations and the step's working set")
+                        + ")")
             else:
                 # explicit "enabled": false turns remat OFF — the
                 # autotuner's off-arm on a shared model object. It also wins
@@ -929,6 +950,79 @@ class Engine:
             hysteresis=fp16.hysteresis)
         return new_params, new_opt, new_scaler
 
+    # ================================================================ remat
+    def _device_free_bytes(self) -> Optional[int]:
+        """What one device has left for a step's activations: its
+        ``bytes_limit`` less the margin of ``models/remat.py``, less the
+        engine's resident state (parameters, optimizer state where it lives
+        on the device, and the float32 gradients a step holds before the
+        update). None where the device reports no limit (the CPU)."""
+        from ..models.remat import MARGIN
+
+        device = self.topology.mesh.devices.flat[0]
+        limit = get_accelerator().total_memory(device)
+        if not limit:
+            return None
+
+        leaves = jax.tree_util.tree_leaves
+
+        def held(arrays, shardings=None, itemsize=None):
+            """Bytes of ``arrays`` on one device, as their own or the given
+            shardings split them."""
+            arrays = leaves(arrays)
+            shardings = leaves(shardings) or [x.sharding for x in arrays]
+            return sum(math.prod(s.shard_shape(x.shape))
+                       * (itemsize or x.dtype.itemsize)
+                       for x, s in zip(arrays, shardings))
+
+        resident = held(self.params) \
+            + held(self.params, self.grad_shardings, 4)
+        if self.offload_device is None:
+            resident += held(self.opt_state)
+        return int(limit * (1 - MARGIN)) - resident
+
+    @property
+    def remat_choice(self) -> Optional[Dict[str, Any]]:
+        """What the last traced step's checkpointed layers keep, where the
+        model took a rung of ``models/remat.py``: ``{"rung", "saved_bytes",
+        "auto"}`` (``auto``: by room, no ``policy`` in the section); None
+        before the first trace and for a model or policy that takes none."""
+        choice = getattr(self.module, "remat_choice", None)
+        return choice and {**choice, "auto": self._remat_auto}
+
+    def _note_remat_choice(self) -> None:
+        """Log the rung a freshly traced step took (once a rung)."""
+        choice = self.remat_choice
+        if choice is not None and choice != self._remat_logged:
+            self._remat_logged = dict(choice)
+            log_dist(f"activation checkpointing: rung {choice['rung']}, "
+                     f"{choice['saved_bytes'] / 2**30:.3f} GiB saved for the "
+                     f"backward pass on each device")
+
+    def _remat_step_down(self, err: Exception) -> bool:
+        """The compiled step did not fit (the compiler's temporaries are not
+        in the rung's arithmetic): take the next leaner rung and build the
+        step again. False where that is not the fault or nothing is left to
+        give up: an explicit policy, the leanest rung, a step that had
+        already consumed its donated state."""
+        from ..models.remat import RUNGS
+
+        choice = self.remat_choice
+        if "RESOURCE_EXHAUSTED" not in str(err) or not self._remat_auto \
+                or choice is None or choice["rung"] == RUNGS[-1] \
+                or any(x.is_deleted()
+                       for x in jax.tree_util.tree_leaves(self.params)):
+            return False
+        leaner = RUNGS[RUNGS.index(choice["rung"]) + 1]
+        logger.warning(
+            "the train step does not fit the device with rung %s (%.3f GiB "
+            "saved): falling back to %s and compiling again",
+            choice["rung"], choice["saved_bytes"] / 2**30, leaner)
+        self.module.config.remat_policy = leaner
+        self.module.remat_choice = None
+        self._train_batch_fn = self._build_train_batch_fn()
+        return True
+
     # ================================================================ fused path
     def _build_train_batch_fn(self):
         if self._zeropp_enabled:
@@ -1145,9 +1239,17 @@ class Engine:
                         (self.params, self.opt_state, self.scaler_state,
                          batch, rng))
                     self._last_aval_key = key
-                self.params, self.opt_state, self.scaler_state, metrics = \
-                    self._train_batch_fn(self.params, self.opt_state,
-                                         self.scaler_state, batch, rng)
+                while True:
+                    try:
+                        self.params, self.opt_state, self.scaler_state, \
+                            metrics = self._train_batch_fn(
+                                self.params, self.opt_state,
+                                self.scaler_state, batch, rng)
+                        break
+                    except jax.errors.JaxRuntimeError as e:
+                        if not self._remat_step_down(e):
+                            raise
+                self._note_remat_choice()
             if comms_logger.enabled:
                 # opt-in (comms_logger.enabled): straggler wall-clock must
                 # be device-accurate, so this config knowingly trades the
@@ -1309,14 +1411,17 @@ class Engine:
         handed to ``monitor/mfu.py`` under its program name
         (``train_batch_fn``), which reads every instruction's region and
         pass off its text for whoever asks (``mfu.published``): this
-        engine's ``mfu_ledger()``, the benchmark's ``train_*_ms`` readers."""
+        engine's ``mfu_ledger()``, the benchmark's ``train_*_ms`` readers.
+        With it goes what the step's checkpointed layers keep
+        (:attr:`remat_choice`; ``mfu.step_record``)."""
         avals = getattr(self, "_last_train_avals", None)
         if self._train_batch_fn is None or avals is None:
             raise RuntimeError("run train_batch() first")
         compiled = self._train_batch_fn.lower(*avals).compile()
         from ..monitor import mfu as mfu_mod
 
-        mfu_mod.publish(self._train_batch_fn.__name__, compiled)
+        mfu_mod.publish(self._train_batch_fn.__name__, compiled,
+                        remat=self.remat_choice)
         return compiled
 
     GRAPH_ANALYZERS = ("collectives", "donation", "resharding", "dtype")
@@ -1480,6 +1585,8 @@ class Engine:
         led = mfu_mod.ledger(table, measured, w["step_s"],
                              truncated_trace=meta["truncated"])
         led["window"] = {"step": w["step"], "trace_path": trace_path}
+        led["remat"] = mfu_mod.step_record(
+            self._train_batch_fn.__name__).get("remat")
         if persist:
             # the offline-report artifacts (tools/mfu_report.py reads the
             # trace dir on a jax-less node)
