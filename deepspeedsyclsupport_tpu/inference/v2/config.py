@@ -49,15 +49,6 @@ class RaggedInferenceConfig:
     # default) | lru (least-recently-scheduled) | newest (LIFO backoff) |
     # slack (least SLA slack — most likely to miss anyway; docs/serving.md)
     eviction_policy: str = "longest_context"
-    # steady-state decode fusion: when every live sequence is decoding and
-    # nothing is waiting, run up to this many decode steps (forward +
-    # on-device sample + paged-KV append + position advance) inside ONE
-    # jitted while_loop, returning all sampled tokens in a single host
-    # transfer. 1 = one host-scheduled forward per token (the reference's
-    # per-iteration MII loop, ``engine_v2.py:107``); >1 amortizes host
-    # scheduling + dispatch across K tokens — the steady-state analog of
-    # the reference's ragged-kernel amortization
-    decode_steps_per_dispatch: int = 1
     # KV-pool head-dim lane alignment (kv_cache.lane_padded_head_dim):
     # None = auto (round up to 128 on TPU — Mosaic DMA slices must be
     # lane-tile aligned; no padding elsewhere); an int forces that multiple.
@@ -84,9 +75,10 @@ class RaggedInferenceConfig:
         if self.atom_q_size < 1:
             raise ValueError(f"atom_q_size must be >= 1, got "
                              f"{self.atom_q_size}")
-        if self.decode_steps_per_dispatch < 1:
-            raise ValueError(f"decode_steps_per_dispatch must be >= 1, got "
-                             f"{self.decode_steps_per_dispatch}")
+        pad = self.head_dim_lane_pad
+        if pad is not None and (type(pad) is not int or pad < 1):
+            raise ValueError(f"head_dim_lane_pad must be None (auto) or a "
+                             f"positive int, got {pad!r}")
         if self.quant_bits not in (4, 8):
             raise ValueError(f"quant_bits must be 4 or 8, got "
                              f"{self.quant_bits}")
@@ -177,7 +169,7 @@ class ServingPolicyConfig:
     watchdog_enabled: bool = False
     watchdog_deadline_s: float = 60.0
     watchdog_warmup_deadline_s: Optional[float] = None  # default 10x: the
-    #   first round compiles (prefill + sampler + fused rungs)
+    #   first round compiles (prefill + sampler)
     watchdog_poll_s: float = 0.25
     # structured backpressure: consecutive no-progress scheduling rounds
     # (no events, no dispatches) with live streams before the session

@@ -201,18 +201,13 @@ class InferenceEngineV2:
         self._state_free: Optional[List[int]] = None
         if self.kv.ssm is not None:
             self._state_free = list(range(cfg.max_sequences))
-            if cfg.decode_steps_per_dispatch > 1:
-                raise ValueError(
-                    "decode_steps_per_dispatch > 1 (decode_multi_forward) "
-                    "is not written for a model with recurrent state: its "
-                    "device loop does not carry the state slots")
         # SLA layer (serving.ServingSession) installs a scheduler.SlackPolicy
         # here; put() then orders chunks by slack instead of arrival. None =
         # the pre-SLA least-recently-served ordering.
         self.slack_policy = None
         # ... and, for the length of one scheduling round, its phase clock
-        # (serving.RoundSpans): put() and the fused dispatch then charge
-        # their schedule/build/dispatch/collect time to the round's record.
+        # (serving.RoundSpans): put() then charges its
+        # schedule/build/dispatch/collect time to the round's record.
         # None = the engine is driven without a session, nothing is timed.
         self.round_spans = None
         # cross-request prefix cache (install_prefix_cache). None = every
@@ -223,21 +218,13 @@ class InferenceEngineV2:
         self._forward = build_ragged_forward_fn(model, cfg.block_size,
                                                 attn_impl=cfg.prefill_attn)
         self._decode_forward = None  # built lazily (kernel path)
-        # (K, sampling STRUCTURE) -> jitted K-step program; temperature/
-        # top_p/eos are traced operands so they never force a recompile.
-        # Bounded LRU: each entry is a full compiled model program
-        from collections import OrderedDict
-
-        self._decode_multi: "OrderedDict[Any, Any]" = OrderedDict()
-        self._decode_multi_cap = 16
         # name -> (jitted fn, {rows: abstract args}) of every forward
         # program this engine has dispatched, at every static shape it ran
         # in (compiled_programs)
         self._dispatched: Dict[str, Tuple[Any, Dict[int, Any]]] = {}
         # forward programs dispatched (_dispatch) plus sampler calls
         # (sample_drained): 2 a per-token round, which with the rng split
-        # are all of its device launches (benchmark: launches_per_round);
-        # a K-step round adds the gather of its logits0
+        # are all of its device launches (benchmark: launches_per_round)
         self.host_dispatches = 0
         # [V] rows cut out of a forward's logits because a caller READ one
         # (query(), a PutResult item), a launch each; sampling gathers by
@@ -247,7 +234,6 @@ class InferenceEngineV2:
         # only the sampling STRUCTURE is static; temperature/top_p are
         # operands (sweeping them reuses one compiled sampler)
         self._sample_fn = jax.jit(_sample_rows, static_argnums=(5,))
-        self._gather_fn = jax.jit(_gather_rows)
         # the forwards' ``sampled`` operand where every token is the host's
         # (_host_tokens_only), placed as the sampler places its output
         self._no_sampled = None
@@ -527,9 +513,9 @@ class InferenceEngineV2:
 
     def compiled_programs(self) -> Dict[str, Any]:
         """``{name: jax.stages.Compiled}`` for every forward program this
-        engine has actually run (``ragged_forward``, ``decode_forward``,
-        ``decode_multi_<K>``), re-lowered at the arguments it ran with — so a
-        caller can check WHAT ran (``.as_text()``: is the attention a
+        engine has actually run (``ragged_forward``, ``decode_forward``),
+        re-lowered at the arguments it ran with — so a caller can check
+        WHAT ran (``.as_text()``: is the attention a
         ``tpu_custom_call``?) and what it needs (``.memory_analysis()``).
         A program that ran in several static shapes (``ragged_forward``,
         ``ragged.ragged_shapes``) answers as :class:`ProgramShapes`."""
@@ -542,7 +528,7 @@ class InferenceEngineV2:
         return out
 
     # --------------------------------------------------------------- warmup
-    def warmup(self, fused_ladder: bool = False) -> None:
+    def warmup(self) -> None:
         """Compile the prefill and decode programs in BOTH KV-sharding
         states before serving. The first jitted forward returns a donated
         KV cache whose sharding differs from ``init_blocked_kv``'s
@@ -555,11 +541,7 @@ class InferenceEngineV2:
         after the engine's first ever sees: no round of any shape compiles
         while serving. A decode step here takes its token from
         the device as a serving round's does: the sampler is launched, the
-        forward eats its output, then it is read. ``fused_ladder=True`` additionally
-        compiles EVERY fused-decode rung {K/2, ..., 2}, not just K — a
-        timed window must not pay a mid-run compile when a short tail
-        first selects a smaller rung (off by default: tests and callers
-        that never hit the fused path shouldn't pay log2(K) compiles)."""
+        forward eats its output, then it is read."""
         cfg = self.config
         uid = -(1 << 40) - 1   # reserved: below any sane caller uid
         # leave room for the 4 follow-up tokens within max_context
@@ -595,44 +577,6 @@ class InferenceEngineV2:
                 self.put([uid], [[2, 2]])
         finally:
             self._rows_floor = 0
-        if cfg.decode_steps_per_dispatch > 1:
-            # compile the fused K-step steady-state program too, for
-            # generate()'s default greedy/no-eos config (non-default sampling
-            # STRUCTURES still compile on first use). Restart from a fresh
-            # 1-token sequence so context headroom never truncates the two
-            # dispatches below the full K the serving loop will use
-            k = cfg.decode_steps_per_dispatch
-            self.flush([uid])
-            self.put([uid], [[2]])
-            running = {uid: 2 * k + 1}
-            for _ in range(2):
-                if uid not in running:
-                    break
-                self._decode_multi_dispatch(running, SamplingParams(), None,
-                                            jax.random.PRNGKey(0))
-            if (k, SamplingParams().structure) not in self._decode_multi:
-                log_dist(f"warmup: fused decode program (K={k}) not "
-                         f"pre-compiled — KV pool too small to pre-fund it; "
-                         f"first steady-state generate() will compile")
-            if fused_ladder:
-                # mirror the serve-time rung sequence (max(2, rung // 2)
-                # stepping) so EVERY program the dispatch can select is
-                # compiled here — for non-power-of-two K the naive
-                # `rung //= 2` walk skips the 2-rung the pressure
-                # fallback snaps to
-                rung = k
-                while rung > 2:
-                    rung = max(2, rung // 2)
-                    self.flush([uid])
-                    self.put([uid], [[2]])
-                    # k_cap pins the ladder top at `rung`, forcing its
-                    # compile (a bare budget of `rung` steps would be
-                    # routed back to the already-compiled K program by
-                    # the prefer-compiled rung walk)
-                    self._decode_multi_dispatch({uid: rung},
-                                                SamplingParams(), None,
-                                                jax.random.PRNGKey(0),
-                                                k_cap=rung)
         self.flush([uid])
         self.host_dispatches = 0  # counter measures serving, not warmup
 
@@ -906,7 +850,7 @@ class InferenceEngineV2:
         chunked prefill enters at the first uncached token. Returns the
         cached token count (0 on miss, no cache, or a non-fresh stream).
 
-        Exactness: positions, sampling and the fused-decode pre-fund all
+        Exactness: positions and sampling both
         derive from ``n_cached``, so a mapped prefix is indistinguishable
         from a prefilled one; the probe always leaves ≥ 1 token novel so
         the stream still runs a forward to produce logits."""
@@ -1078,9 +1022,8 @@ class InferenceEngineV2:
         return logits
 
     def _slot_arrays(self, descs):
-        """Per-slot decode metadata padded to max_sequences — the ONE
-        assembly both the per-token and fused decode paths ship to device
-        (position, block table, live mask per slot)."""
+        """Per-slot decode metadata padded to max_sequences (position,
+        block table, live mask per slot)."""
         cfg = self.config
         s_max = cfg.max_sequences
         positions = np.zeros((s_max,), np.int32)
@@ -1127,177 +1070,6 @@ class InferenceEngineV2:
                 *map(jnp.asarray, state))
         return logits
 
-    def _decode_multi_dispatch(self, running: Dict[int, int],
-                               sp: "SamplingParams",
-                               eos_token_id: Optional[int],
-                               rng: jax.Array,
-                               k_cap: Optional[int] = None
-                               ) -> Optional[Dict[int, List[int]]]:
-        """Steady-state fused decode: up to K tokens per live sequence in ONE
-        device dispatch (``model.decode_multi_forward``).
-
-        ``running`` maps each live uid (input fully drained) to its remaining
-        new-token budget; it is updated in place, and retired sequences are
-        flushed. Returns {uid: emitted tokens} — or ``None`` when the KV pool
-        cannot pre-fund ≥2 steps for the worst case, in which case the caller
-        falls back to the per-token path (which evicts under pressure).
-
-        K selection walks the compiled ladder {K, K/2, ..., 2} (bounding the
-        program cache to log2(K) entries) and picks the smallest rung
-        covering the LARGEST number of steps any live sequence can still
-        absorb (budget ∧ context headroom) — one dispatch drains the whole
-        tail even below full occupancy, where the old fixed-K gate left the
-        per-token path paying a host round trip per token
-        (``host_dispatches_per_token`` ≈ 0.77 at light load, r05). Overshoot
-        is cheap: the device loop exits as soon as every slot retires.
-        ``k_cap`` lets a serving layer bound the dispatch (e.g. to the slack
-        of a queued request) without forking the ladder.
-
-        KV blocks for the worst-case K appends are allocated up front so the
-        block tables are loop-invariant on device; a retiring sequence's
-        unused blocks are released by its flush.
-        """
-        from .model import build_decode_multi_fn
-
-        cfg = self.config
-        uids = list(running)
-        with self._phase("schedule"):
-            k = self._fund_decode_multi(running, uids, sp, k_cap)
-        if k is None:
-            return None
-        key = (k, sp.structure)
-        fn = self._decode_multi.get(key)
-        if fn is None:
-            fn = self._decode_multi[key] = build_decode_multi_fn(
-                self.model, cfg.block_size, k, sp.structure,
-                cfg.max_context, attn_impl=cfg.decode_attn)
-            while len(self._decode_multi) > self._decode_multi_cap:
-                self._decode_multi.popitem(last=False)
-        else:
-            self._decode_multi.move_to_end(key)
-        s_max = cfg.max_sequences
-        n = len(uids)
-        with self._phase("build"):
-            positions, tables, active = self._slot_arrays(
-                [self.seqs[u] for u in uids])
-            steps_left = np.zeros((s_max,), np.int32)
-            steps_left[:n] = [running[u] for u in uids]
-            self._note_forward([self.seqs[u] for u in uids], [0] * n)
-        with self._phase("gather"):
-            logits0 = self._drained_rows(uids)
-        with self._phase("dispatch"):
-            toks_d, logits_f, pos_f, act_f, sl_f, self.kv = self._dispatch(
-                f"decode_multi_{k}", fn,
-                self.params, self.kv, logits0, jnp.asarray(positions),
-                jnp.asarray(tables), jnp.asarray(active),
-                jnp.asarray(steps_left), rng,
-                jnp.float32(sp.temperature), jnp.float32(sp.top_p),
-                jnp.int32(-1 if eos_token_id is None else eos_token_id))
-        self._tick += k
-        # ONE host transfer for the K×S token block + the small state rows
-        with self._phase("readback"):
-            toks = np.asarray(toks_d)
-            pos_h = np.asarray(pos_f)
-            act_h = np.asarray(act_f)
-            sl_h = np.asarray(sl_f)
-        # every step appended one live row per slot that advanced
-        self._forward_tokens += int(pos_h[:n].sum() - positions[:n].sum())
-        emitted: Dict[int, List[int]] = {}
-        served_s = time.perf_counter()
-        with self._phase("collect"):
-            for i, u in enumerate(uids):
-                d = self.seqs[u]
-                emitted[u] = [int(t) for t in toks[:, i] if t >= 0]
-                d.n_cached = int(pos_h[i])
-                d.last_scheduled = self._tick
-                d.last_service_s = served_s
-                d.emitted += len(emitted[u])
-                if self.prefix_cache is not None:
-                    # committed tokens this dispatch = sampled tokens
-                    # appended to KV; clamp to n_cached (an early-retiring
-                    # slot appends nothing past its final position)
-                    d.history.extend(emitted[u])
-                    del d.history[d.n_cached:]
-                    self._commit_prefix(d)
-                if act_h[i]:
-                    running[u] = int(sl_h[i])
-                    d.last_logits = LogitsRef(logits_f, i)
-                else:
-                    del running[u]
-                    self.flush([u])
-            if self.round_spans is not None:
-                self.round_spans.fields["tokens"] = sum(
-                    map(len, emitted.values()))
-        return emitted
-
-    def _fund_decode_multi(self, running: Dict[int, int], uids: List[int],
-                           sp: "SamplingParams", k_cap: Optional[int]
-                           ) -> Optional[int]:
-        """The rung K of :meth:`_decode_multi_dispatch`'s next program, with
-        the KV blocks of its worst-case K appends allocated; ``None`` when
-        the pool cannot pre-fund two steps."""
-        cfg = self.config
-        k = cfg.decode_steps_per_dispatch
-        if k_cap is not None:
-            cap = max(2, int(k_cap))
-            while k > 2 and k > cap:
-                k = max(2, k // 2)  # snap DOWN the rung ladder: an
-                #   arbitrary cap value must select a compiled program,
-                #   never compile a fresh K mid-serve (floor 2: an odd
-                #   rung halving to 1 would silently disable fusion)
-        absorb = max((min(running[u],
-                          max(0, cfg.max_context - self.seqs[u].n_cached))
-                      for u in uids), default=0)
-        if absorb < 1:
-            return None
-        # rung ladder {k, ..., 2}: snap to the smallest rung covering the
-        # longest tail, then prefer the smallest ALREADY-COMPILED rung —
-        # an uncompiled smaller program is never worth a mid-run compile
-        # (the larger program early-exits once every slot retires), and a
-        # plain-warmup() caller only has K itself compiled
-        ladder = [k]
-        while ladder[-1] > 2:
-            ladder.append(max(2, ladder[-1] // 2))
-        i = max((j for j, r in enumerate(ladder) if r >= absorb), default=0)
-        while i > 0 and (ladder[i], sp.structure) not in self._decode_multi:
-            i -= 1
-        k = ladder[i]
-
-        def _wants(k_steps: int) -> List[int]:
-            out = []
-            for u in uids:
-                d = self.seqs[u]
-                appends = min(k_steps, running[u],
-                              max(0, cfg.max_context - d.n_cached))
-                out.append(d.blocks_needed(appends, cfg.block_size))
-            return out
-
-        wants = _wants(k)
-        while sum(wants) > self.allocator.free_blocks and k > 2:
-            k = max(2, k // 2)  # odd K: still try K=2 before giving up
-            wants = _wants(k)
-        if k < 2 or sum(wants) > self.allocator.free_blocks:
-            return None
-        for u, w in zip(uids, wants):
-            if w:
-                got = self.allocator.try_allocate(w)
-                if got is None:
-                    # pool exhausted under us (injected kv_alloc_fail or
-                    # bookkeeping drift): fall back to the per-token path,
-                    # which evicts under pressure — blocks already handed
-                    # to earlier uids stay owned by their sequences (used
-                    # next append or reclaimed by their flush), so no
-                    # unwinding is needed and nothing raises mid-serve
-                    return None
-                self.seqs[u].blocks.extend(got)
-        if self.prefix_cache is not None:
-            for u in uids:
-                d = self.seqs[u]
-                self._ensure_writable(
-                    d, min(k, running[u],
-                           max(0, cfg.max_context - d.n_cached)))
-        return k
-
     # ------------------------------------------------------------ query/flush
     def has_logits(self, uid: int) -> bool:
         """Whether ``uid``'s input has drained and its last-token logits
@@ -1340,21 +1112,6 @@ class InferenceEngineV2:
             group[1][i] = ref.slot
             group[2].append(i)
         return list(groups.values())
-
-    def _drained_rows(self, uids: Sequence[int]) -> jax.Array:
-        """``[max_sequences, V]`` with ``uids[i]``'s logits in row ``i``:
-        the K-step program's ``logits0`` (rows past ``len(uids)`` belong to
-        inactive slots, whatever they hold)."""
-        rows = None
-        for array, slots, places in self._logit_groups(uids):
-            got = self._gather_fn(array, slots)
-            if rows is None:
-                rows = got
-            else:
-                mine = np.zeros((len(slots), 1), bool)
-                mine[places] = True
-                rows = jnp.where(mine, got, rows)
-        return rows
 
     def moe_tail(self, fields: Sequence[str] = ("moe_touched", "moe_rows")
                  ) -> Optional[Tuple[jax.Array, ...]]:
@@ -1477,7 +1234,7 @@ class InferenceEngineV2:
                  rng: Optional[jax.Array] = None) -> List[List[int]]:
         """Continuous-batching loop (the MII role above the reference engine).
 
-        Each iteration issues ONE fused put: every drained sequence's next
+        Each iteration issues ONE put: every drained sequence's next
         decode token plus as many waiting prompts as FIFO admission allows —
         the SplitFuse fusion the scheduler is built for. Sequences retire on
         EOS, length, or the context cap (truncation, not failure); under KV
@@ -1499,24 +1256,6 @@ class InferenceEngineV2:
         uid_base = 1 << 20  # avoid colliding with caller uids in shared engines
 
         while waiting or running:
-            # 0. steady state — every live sequence decoding and nothing
-            # admissible from the backlog (queue empty, or its head can't be
-            # admitted anyway — engine saturated): fuse up to K decode steps
-            # into one device dispatch (sample + paged-KV append + position
-            # advance all on device); fall through to the per-token path on
-            # KV pressure (it evicts) or mixed state
-            backlog_stuck = bool(waiting) and not self.can_schedule(
-                [uid_base + waiting[0][0]], [len(waiting[0][1])])
-            if (cfg.decode_steps_per_dispatch > 1 and running
-                    and (not waiting or backlog_stuck)
-                    and all(self.has_logits(u) for u in running)):
-                rng, sub = split_key(rng)
-                emitted = self._decode_multi_dispatch(running, sp,
-                                                      eos_token_id, sub)
-                if emitted is not None:
-                    for uid, toks in emitted.items():
-                        results[uid - uid_base].extend(toks)
-                    continue
             # 1. one batched sample over every drained sequence
             put_uids: List[int] = []
             put_toks: List[List[int]] = []

@@ -108,9 +108,10 @@ def lane_padded_head_dim(head_dim: int, pad) -> int:
     dim rounded up to the lane width on TPU; q/k/v are zero-padded at the
     attention seam (q pre-scaled by sqrt(d_pad/d) to compensate the impls'
     1/sqrt(trailing-dim) softmax scale) and the output sliced back, which
-    leaves scores mathematically identical. ``pad`` None/0 = auto (128 on
-    TPU, none elsewhere). HBM note: a d=64 model pays 2x KV pool for kernel decode."""
-    if pad in (None, 0):
+    leaves scores mathematically identical. ``pad`` None = auto (128 on
+    TPU, none elsewhere); otherwise a positive int, which the config
+    checks. HBM note: a d=64 model pays 2x KV pool for kernel decode."""
+    if pad is None:
         pad = 128 if jax.default_backend() == "tpu" else 1
     return -(-head_dim // pad) * pad
 

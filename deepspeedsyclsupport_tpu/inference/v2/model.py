@@ -1038,88 +1038,3 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
 def build_decode_forward_fn(model, block_size: int, attn_impl: str = "auto"):
     return _jit_program("decode_forward", decode_forward, model,
                         block_size=block_size, attn_impl=attn_impl)
-
-
-# ------------------------------------------- device-resident multi-step decode
-def decode_multi_forward(model, params: Any, kv: BlockedKV, logits0,
-                         positions, block_tables, active, steps_left, rng,
-                         temperature, top_p, eos_tok, *,
-                         block_size: int, num_steps: int, samp_struct,
-                         max_context: int, attn_impl: str = "auto"):
-    """Up to ``num_steps`` fused decode iterations in ONE jitted program.
-
-    Serving's steady state (every live sequence decoding, nothing waiting)
-    pays one host round trip per token in the reference's serving loop
-    (``inference/v2/engine_v2.py:107`` — MII re-enters ``put`` per
-    iteration). Here the whole loop body — sample from logits, append the
-    token's KV through the paged-decode forward, advance positions —
-    runs under ``lax.while_loop`` on device, so K tokens per sequence
-    cost ONE dispatch and ONE [K, S] host transfer.
-
-    Per-slot retirement mirrors the host loop exactly: a slot samples
-    (emitting the token), decrements its budget, then retires on budget
-    exhaustion, EOS, or the context cap — the EOS/terminal token is
-    emitted but never appended, matching ``InferenceEngineV2.generate``.
-    The loop exits early once every slot has retired, so a large
-    ``num_steps`` costs nothing on short tails.
-
-    ``logits0``: [S, V] last-token logits each slot drained with;
-    ``steps_left``: [S] per-slot new-token budgets. ``samp_struct`` is
-    ``SamplingParams.structure`` — the compile-relevant sampling shape;
-    ``temperature``/``top_p``/``eos_tok`` (int32 scalar, -1 = no EOS) stay
-    traced so one compiled program serves every setting of them. With
-    ``do_sample=True`` the rng split tree differs from the per-token host
-    loop (one split per device step here vs one per host round there), so
-    sampled streams are not bit-identical across ``decode_steps_per_
-    dispatch`` settings — greedy decoding is, and is what the parity tests
-    pin. Returns
-    ``(tokens [num_steps, S] int32 with -1 for retired-slot steps,
-    final logits [S, V], final positions [S], final active [S],
-    final steps_left [S], new kv)``.
-    """
-    from ..sampling import SamplingParams, sample_token as _sample
-
-    do_sample, top_k, use_top_p = samp_struct
-    sampling = SamplingParams(do_sample, temperature, top_k,
-                              top_p if use_top_p else 1.0)
-    s = positions.shape[0]
-    buf0 = jnp.full((num_steps, s), -1, jnp.int32)
-
-    def cond(carry):
-        step, _buf, _kv, _lg, _pos, act, _sl, _rng = carry
-        return jnp.logical_and(step < num_steps, jnp.any(act))
-
-    def body(carry):
-        step, buf, kv, logits, pos, act, sl, rng = carry
-        rng, sub = jax.random.split(rng)
-        tok = _sample(logits, sub, sampling)               # [S]
-        buf = buf.at[step].set(jnp.where(act, tok, -1))
-        sl = jnp.where(act, sl - 1, sl)
-        done = sl <= 0
-        done = jnp.logical_or(done,
-                              jnp.logical_and(eos_tok >= 0, tok == eos_tok))
-        done = jnp.logical_or(done, pos >= max_context)
-        append = jnp.logical_and(act, jnp.logical_not(done))
-        new_logits, kv = decode_forward(
-            model, params, kv, tok, pos, block_tables, append,
-            block_size=block_size, attn_impl=attn_impl)
-        logits = jnp.where(append[:, None], new_logits, logits)
-        pos = jnp.where(append, pos + 1, pos)
-        return step + 1, buf, kv, logits, pos, append, sl, rng
-
-    carry = (jnp.int32(0), buf0, kv, logits0.astype(jnp.float32),
-             positions, active, steps_left, rng)
-    (_, buf, kv, logits, pos, act, sl, _) = jax.lax.while_loop(
-        cond, body, carry)
-    return buf, logits, pos, act, sl, kv
-
-
-def build_decode_multi_fn(model, block_size: int, num_steps: int,
-                          samp_struct, max_context: int,
-                          attn_impl: str = "auto"):
-    """Jitted K-step decode program — compiled once per (K, sampling
-    STRUCTURE); temperature/top_p/eos are runtime operands."""
-    return _jit_program(f"decode_multi_{num_steps}", decode_multi_forward,
-                        model, block_size=block_size, num_steps=num_steps,
-                        samp_struct=samp_struct, max_context=max_context,
-                        attn_impl=attn_impl)

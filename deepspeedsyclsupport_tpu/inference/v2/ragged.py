@@ -25,13 +25,12 @@ class BlockedAllocator:
     """Refcounted KV block free-list (reference ``ragged/blocked_allocator.py``
     plus vLLM-style per-block reference counts for cross-request sharing).
 
-    Serving-loop callers (the scheduler's chunk admission, the fused-decode
-    pre-fund) go through :meth:`try_allocate`: exhaustion — real or
-    injected (``DSTPU_FAULT_INJECTION`` ``kv_alloc_fail``) — answers
-    ``None`` so the engine surfaces structured backpressure (the sequence
-    stays pending / falls back to the evicting per-token path) instead of
-    an exception tearing down the whole serving loop. :meth:`allocate`
-    keeps the raising contract for callers that pre-checked.
+    The serving loop (the scheduler's chunk admission) goes through
+    :meth:`try_allocate`: exhaustion — real or injected
+    (``DSTPU_FAULT_INJECTION`` ``kv_alloc_fail``) — answers ``None`` so the
+    engine surfaces structured backpressure (the sequence stays pending)
+    instead of an exception tearing down the whole serving loop.
+    :meth:`allocate` keeps the raising contract for callers that pre-checked.
 
     Sharing contract (prefix cache, docs/serving.md "prefix reuse"): a
     freshly allocated block has refcount 1; every additional holder

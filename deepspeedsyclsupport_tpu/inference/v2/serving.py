@@ -17,11 +17,7 @@ This layer makes overload degrade *gracefully* instead:
 * **overload-graceful eviction** — when the paged KV pool exhausts, the
   lowest-slack stream is preempted (`engine.preempt`: blocks freed,
   request rejected with partial output or requeued) rather than stalling
-  the whole batch;
-* **dispatch amortization** — whenever every live stream is decoding and
-  nothing admissible waits, up to K decode steps fuse into ONE device
-  dispatch (``engine._decode_multi_dispatch``), with K capped by the
-  slack of the most urgent queued request so fusion never starves TTFT.
+  the whole batch.
 
 Everything here is host-side policy over monotonic time
 (``time.perf_counter``); the ``clock`` hook exists so tests drive the
@@ -223,7 +219,7 @@ class RoundSpans:
     ``jax.profiler`` trace the same spans sit beside the device's timeline.
     Time no phase claims is ``other``, so ``phases`` partitions
     ``[t0, t1]``. ``fields`` collects what the round's ``round`` record says
-    besides: the session notes the mode and the sampled uids, the engine
+    besides: the session notes the sampled uids, the engine
     (handed this object for the round as ``engine.round_spans``) the program
     it launched and what that forward covered."""
 
@@ -268,10 +264,10 @@ class RoundSpans:
 class ServeEvent:
     """One observable serving outcome, stamped on the session clock.
 
-    kinds: ``token`` (``tokens`` delivered at ``t``; a fused dispatch
-    delivers several at once), ``finish`` (reason: done|eos|context|
-    evicted), ``shed`` (admission rejected the request; reason names why),
-    ``evict`` (KV-pressure preemption; reason: reject|requeue).
+    kinds: ``token`` (``tokens`` delivered at ``t``), ``finish`` (reason:
+    done|eos|context|evicted), ``shed`` (admission rejected the request;
+    reason names why), ``evict`` (KV-pressure preemption; reason:
+    reject|requeue).
     """
 
     kind: str
@@ -313,7 +309,7 @@ class ServingSession:
     frontend (or the benchmark harness's load loop) sits on.
 
     ``submit()`` is the admission gate; ``step()`` runs one scheduling
-    round — queue maintenance, slack-ordered batch composition, fused or
+    round — queue maintenance, slack-ordered batch composition, the
     per-token dispatch, KV-pressure eviction — and returns the round's
     :class:`ServeEvent` stream. The caller owns pacing (when to call
     ``step``) and delivery; the session owns policy.
@@ -478,11 +474,11 @@ class ServingSession:
         on the SESSION's clock — the record's own ``t`` is shifted onto the
         wall), what it dispatched and what that forward covered
         (``reqtrace.FORWARD_FIELDS``), and where its time went (``phases``
-        sums to ``t1 - t0``). ``uids``/``mode`` are what the join fans out
-        to each request's round count."""
+        sums to ``t1 - t0``). ``uids`` is what the join fans out to each
+        request's round count."""
         self._stage(-1, "round", spans.t1, **{
             "round": self._round, "t0": spans.t0, "t1": spans.t1,
-            "launch_t": None, "program": None, "mode": "per_token",
+            "launch_t": None, "program": None,
             "uids": [], **dict.fromkeys(FORWARD_FIELDS, 0),
             **spans.fields, "phases": dict(spans.phases)})
 
@@ -833,7 +829,7 @@ class ServingSession:
                     # natural serving-loop pattern while awaiting the first
                     # request) must not consume the one-shot warmup
                     # allowance — the first REAL round compiles prefill +
-                    # sampler + fused rungs and needs it
+                    # sampler and needs it
                     wd = self.watchdog if (self.running or self.queue) \
                         else None
                     if wd is not None:
@@ -846,9 +842,7 @@ class ServingSession:
                 # window — so the injected stall is exactly the hang the
                 # watchdog exists to convert into rc 219
                 injector.maybe_wedge_decode(self._round)
-                fused = self._can_fuse() and self._fused_round(now, events)
-                if not fused:
-                    self._per_token_round(now, events)
+                self._per_token_round(now, events)
             finally:
                 # disarm in a finally: an exception mid-round must not leave
                 # the deadline live to rc-219 the process during ordinary
@@ -940,75 +934,6 @@ class ServingSession:
                                      reason="evicted"))
         else:
             events.append(ServeEvent("shed", req.uid, now, reason=reason))
-
-    # --------------------------------------------------------- fused decode
-    def _can_fuse(self) -> bool:
-        """Steady state: every live stream is decoding with fresh logits and
-        nothing admissible is waiting (queue heads were just re-gated by
-        :meth:`_maintain_queue`) — the fused K-step program applies even
-        below full occupancy."""
-        if self.eng.config.decode_steps_per_dispatch <= 1 or not self.running:
-            return False
-        if self._pending_tok:
-            return False  # a sampled-but-unsubmitted token must ship first
-        for uid, req in self.running.items():
-            d = self.eng.seqs.get(uid)
-            if d is None or d.pending or d.last_logits is None:
-                return False
-            if req.first_token_s is None:
-                # a just-drained prefill must deliver its first token NOW
-                # (one per-token round), not after a whole K-step device
-                # loop — fusing here would bake K*step_time into TTFT
-                return False
-        return True
-
-    def _k_cap(self, now: float) -> Optional[int]:
-        """Bound the fused dispatch so a queued request with little TTFT
-        slack is not starved behind a long device loop: K ≤ that slack in
-        decode steps (the ladder in the engine rounds it down)."""
-        cap: Optional[int] = None
-        for req in self.queue:
-            if req.deadline_s is None:
-                continue
-            slack = (req.deadline_s - now
-                     - self.capacity.prefill_eta_s(req.n_prefill))
-            k = int(slack / self.capacity.decode_step_s)
-            cap = k if cap is None else min(cap, k)
-        return None if cap is None else max(2, cap)
-
-    def _fused_round(self, now: float, events: List[ServeEvent]) -> bool:
-        with self._phase("schedule"):
-            budgets = {u: self.running[u].budget for u in self.running}
-            self._rng, sub = split_key(self._rng)
-            k_cap = self._k_cap(now)
-        # the engine's own phases: schedule (rung, pre-funded blocks), build,
-        # dispatch, readback (the K x S token block), collect
-        emitted = self.eng._decode_multi_dispatch(
-            budgets, self.sampling, self.eos_token_id, sub, k_cap=k_cap)
-        if emitted is None:
-            return False  # KV pool can't pre-fund ≥2 steps → per-token path
-        t1 = self.clock()
-        steps = max((len(v) for v in emitted.values()), default=0)
-        self.capacity.record_decode(steps, t1 - now)
-        self._last_decode_s = t1
-        if self._spans is not None:
-            self._spans.fields.update(mode="fused", uids=sorted(emitted))
-        with self._phase("emit"):
-            for uid, toks in emitted.items():
-                req = self.running[uid]
-                req.budget -= len(toks)
-                if toks:
-                    events.append(
-                        ServeEvent("token", uid, t1, tokens=list(toks)))
-                    self._note_emission(req, toks, t1)
-                if uid not in budgets:  # retired on device; engine flushed it
-                    reason = ("eos" if (toks and self.eos_token_id is not None
-                                        and toks[-1] == self.eos_token_id)
-                              else ("done" if req.budget <= 0 else "context"))
-                    self._finish(uid, t1, events, reason, flush=False)
-                else:
-                    req.budget = budgets[uid]  # authoritative (device counted)
-        return True
 
     # ------------------------------------------------------ per-token round
     def _per_token_round(self, now: float, events: List[ServeEvent]) -> None:
@@ -1294,11 +1219,10 @@ class ServingSession:
         req.last_emit_s = t
 
     def _finish(self, uid: int, now: float, events: List[ServeEvent],
-                reason: str, flush: bool = True) -> None:
+                reason: str) -> None:
         req = self.running.pop(uid, None)
         self._pending_tok.pop(uid, None)
-        if flush:
-            self.eng.flush([uid])
+        self.eng.flush([uid])
         self._count("completed")
         if self.journal is not None:
             self.journal.close_request(uid, reason)

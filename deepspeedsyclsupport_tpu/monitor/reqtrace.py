@@ -2,7 +2,7 @@
 
 The serving-side sibling of the MFU ledger (``monitor/mfu.py``):
 ``Serve/ttft_s`` p95 says a request was slow, not whether edge admission,
-router queueing, replica spool transport, chunked prefill, fused-decode
+router queueing, replica spool transport, chunked prefill, decode
 rounds, preemption/requeue or failover replay ate the budget. This module
 owns the three pieces that answer it:
 
@@ -123,8 +123,8 @@ ROUND_PHASES = (
 #: behind the sampled tokens, so a record carries the count of the forward
 #: whose logits its round SAMPLED: the launch of the record before it.
 #: ``ahead`` is 1 where the forward was dispatched BEFORE the last forward's
-#: sampled tokens were read back (every per-token round but the first after
-#: idle, which has nothing to read; 0 for a fused round), and ``spec_rows``
+#: sampled tokens were read back (every round but the first after
+#: idle, which has nothing to read), and ``spec_rows``
 #: counts its rows that belonged to a stream which had already ended: on an
 #: EOS, which the host learns at the read-back, one forward late.
 FORWARD_FIELDS = ("n_seqs", "tokens", "prefill_tokens", "ctx_tokens",
@@ -293,7 +293,7 @@ def _new_trace(uid: int) -> Dict[str, Any]:
             "unattributed_s": 0.0, "reconciled_frac": None,
             "tokens": 0, "closes": 0, "close_reason": "", "outcome": "",
             "cached_prefix_len": None, "spool_wait_s": 0.0,
-            "rounds": {"fused": 0, "per_token": 0},
+            "rounds": 0,
             "ttft_sla_s": None, "tenant": "", "verdicts": [],
             "replays": 0, "replica_path": []}
 
@@ -374,8 +374,7 @@ def join_traces(streams: Iterable[Tuple[str, str, Sequence[Dict[str, Any]]]],
                     # the round's record names the uids it sampled for
                     # (journals from before it carried them on decode_round)
                     for u in data.get("uids", ()):
-                        _push(u, t, "round",
-                              {"mode": data.get("mode", "per_token")})
+                        _push(u, t, "round", {})
                 elif stage in ("queue_wait", "requeue_wait"):
                     _push(uid, t, "activate", dict(data))
                 elif stage == "preempt":
@@ -393,9 +392,7 @@ def join_traces(streams: Iterable[Tuple[str, str, Sequence[Dict[str, Any]]]],
         seg: Optional[Dict[str, Any]] = None
         for t, _i, kind, payload in evs:
             if kind == "round":
-                key = ("fused" if payload.get("mode") == "fused"
-                       else "per_token")
-                tr["rounds"][key] += 1
+                tr["rounds"] += 1
                 continue
             if kind == "stage":
                 stage = payload.get("stage", "")
@@ -737,10 +734,8 @@ def attribution(traces: Dict[int, Dict[str, Any]], worst_n: int = 5,
                                if growth else None)}
     else:
         out["tail"] = None
-    # ---- decode mode + prefix visibility -------------------------------
-    out["decode_rounds"] = {
-        "fused": sum(tr["rounds"]["fused"] for tr in done),
-        "per_token": sum(tr["rounds"]["per_token"] for tr in done)}
+    # ---- decode rounds + prefix visibility -----------------------------
+    out["decode_rounds"] = sum(tr["rounds"] for tr in done)
     cached = [tr["cached_prefix_len"] for tr in done
               if tr["cached_prefix_len"] is not None]
     out["cached_prefix_tokens_mean"] = (

@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 from benchmark import parity, spec
+from tests.unit import stream_ends
 
 HF = {
     "model_type": "nemotron_h", "hidden_size": 32, "num_hidden_layers": 5,
@@ -360,9 +361,6 @@ def test_the_mixers_scopes_reach_the_compiled_programs(built):
 
 # ---------------------------------------------------------------- refusals
 def test_what_a_model_with_recurrent_state_refuses_says_why(built, tmp_path):
-    from deepspeedsyclsupport_tpu.inference.v2.engine_v2 import (
-        InferenceEngineV2)
-
     model, params = built
     eng = engine_of(model, params)
     with pytest.raises(NotImplementedError, match="snapshot of the "
@@ -374,10 +372,6 @@ def test_what_a_model_with_recurrent_state_refuses_says_why(built, tmp_path):
     with pytest.raises(NotImplementedError,
                        match="chunked scan's backward is not written"):
         model.apply(params, jnp.zeros((1, 8), jnp.int32))
-    with pytest.raises(ValueError, match="decode_multi_forward.*does not "
-                       "carry the state slots"):
-        InferenceEngineV2(model, params, dtype="float32",
-                          **{**ENGINE, "decode_steps_per_dispatch": 4})
     # nothing of this for a model without state
     assert engine_of(*_plain()).state_stats() is None
 
@@ -456,3 +450,19 @@ def test_the_xla_and_the_pallas_interpret_scans_agree(built):
     assert not np.asarray(y[19]).any()           # no piece lies there
     np.testing.assert_allclose(ssm_c[:, :5], ssm_s[:, :5], atol=2e-5)
     np.testing.assert_allclose(conv_c[:, :, :5], conv_s[:, :, :5], atol=1e-6)
+
+
+# ------------------------------------------------- a stream that ends early
+@pytest.fixture(scope="module")
+def ending(built):
+    model, params = built
+    return stream_ends.family(engine_of(model, params, max_context=32,
+                                        num_blocks=12))
+
+
+@stream_ends.parametrize
+def test_a_stream_that_ends_early_gives_back_what_it_held(ending, driver,
+                                                          end):
+    """The state slots too: ``stream_ends`` counts them back, and a new
+    stream in a released slot starts from zeros."""
+    stream_ends.check(ending, driver, end)

@@ -5,6 +5,8 @@ construction) and model-implementation tests — plus the decisive correctness
 check: ragged paged-KV serving must produce exactly what the dense v1 engine
 produces for the same prompts.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -17,6 +19,25 @@ from deepspeedsyclsupport_tpu.inference.v2.ragged import (SequenceDescriptor,
                                                           build_ragged_batch)
 from deepspeedsyclsupport_tpu.inference.v2.scheduler import schedule_chunks
 from deepspeedsyclsupport_tpu.models import build_model
+from tests.unit import stream_ends
+
+
+# -------------------------------------------------------------------- config
+@pytest.mark.parametrize("given,says", [
+    # an option this engine no longer has: the unknown-key refusal names it
+    ({"decode_steps_per_dispatch": 4},
+     "unknown ragged config keys: ['decode_steps_per_dispatch']"),
+    ({"head_dim_lane_pad": 0},
+     "head_dim_lane_pad must be None (auto) or a positive int, got 0"),
+    ({"quant_bits": 3}, "quant_bits must be 4 or 8, got 3"),
+    ({"eviction_policy": "oldest"},
+     "eviction_policy must be longest_context|lru|newest|slack, got 'oldest'"),
+    ({"max_context": 100, "block_size": 64},
+     "max_context must be a multiple of block_size"),
+], ids=lambda v: next(iter(v)) if isinstance(v, dict) else "")
+def test_what_the_ragged_config_refuses_it_refuses_by_name(given, says):
+    with pytest.raises(ValueError, match=re.escape(says)):
+        RaggedInferenceConfig.from_config(given)
 
 
 # ----------------------------------------------------------------- allocator
@@ -612,153 +633,50 @@ class TestWarmup:
         assert got == _naive_greedy(model, params, prompt, 4)
 
 
-class TestMultiStepDecode:
-    """Device-resident fused decode (``decode_steps_per_dispatch`` > 1):
-    K steps — sample + paged-KV append + position advance — inside one
-    jitted while_loop (``model.decode_multi_forward``), vs the reference's
-    one host-scheduled forward per token (``engine_v2.py:107``)."""
-
-    def test_greedy_matches_per_token_and_naive(self, tiny):
-        model, params = tiny
-        prompts = [[7, 3, 11], [4, 100, 42, 8, 19], [9]]
-        base = _v2(model, params).generate(prompts, max_new_tokens=9)
-        eng = _v2(model, params, decode_steps_per_dispatch=4)
-        got = eng.generate(prompts, max_new_tokens=9)
-        assert got == base
-        for p, g in zip(prompts, got):
-            assert g == _naive_greedy(model, params, p, 9)
-        assert not eng.seqs  # everything retired + flushed
-
-    @pytest.mark.parametrize("model_name,axes", [
-        ("tiny", dict(tp=2)),
-        ("tiny-moe", dict(ep=2)),
-    ])
-    def test_fused_decode_composes_with_parallel_serving(self, model_name,
-                                                         axes):
-        """The fused K-step while_loop runs the same auto-SPMD forward as
-        per-token decode, so it must compose with TP/EP serving topologies
-        with greedy outputs unchanged."""
-        import deepspeedsyclsupport_tpu as ds
-        from deepspeedsyclsupport_tpu.comm.topology import (
-            reset_world_topology)
-
-        prompts = [[1, 5, 9], [7, 2]]
-
-        def gen(k, topo_axes):
-            reset_world_topology()
-            topo = (ds.build_topology(dp=-1, **topo_axes)
-                    if topo_axes else None)
-            model = build_model(model_name, dtype="float32")
-            params = model.init_params()
-            eng = InferenceEngineV2(model, params, dtype=jnp.float32,
-                                    block_size=8, max_context=64,
-                                    max_tokens_per_batch=16,
-                                    max_sequences=4,
-                                    decode_steps_per_dispatch=k,
-                                    topology=topo)
-            out = eng.generate(prompts, max_new_tokens=8)
-            return [list(o) for o in out]
-
-        try:
-            want = gen(1, axes)
-            got = gen(4, axes)
-        finally:
-            reset_world_topology()
-        assert got == want, (model_name, axes, got, want)
-
-    def test_dispatch_count_amortized(self, tiny):
-        """K-step fusion must collapse host dispatches: 12 tokens per seq
-        at K=6 needs ~prefill + ceil(12/6) dispatches, not ~13."""
-        model, params = tiny
-        per_tok = _v2(model, params)
-        per_tok.generate([[5, 6, 7]], max_new_tokens=12)
-        fused = _v2(model, params, decode_steps_per_dispatch=6)
-        fused.generate([[5, 6, 7]], max_new_tokens=12)
-        assert fused.host_dispatches <= per_tok.host_dispatches // 3
-
-    def test_eos_retires_mid_dispatch(self, tiny):
-        """EOS inside the fused loop truncates exactly where the per-token
-        path truncates (the EOS token is emitted, never appended)."""
-        model, params = tiny
-        prompts = [[7, 3, 11], [4, 100, 42, 8, 19]]
-        base = _v2(model, params).generate(prompts, max_new_tokens=8)
-        # pick an eos that actually occurs mid-stream in the greedy output
-        eos = base[0][2]
-        want = _v2(model, params).generate(prompts, max_new_tokens=8,
-                                           eos_token_id=eos)
-        eng = _v2(model, params, decode_steps_per_dispatch=8)
-        got = eng.generate(prompts, max_new_tokens=8, eos_token_id=eos)
-        assert got == want
-        assert got[0][-1] == eos
-        assert len(got[0]) == base[0].index(eos) + 1 < len(base[0])
-
-    def test_context_cap_inside_fused_loop(self, tiny):
-        model, params = tiny
-        long_p = list(np.random.RandomState(2).randint(1, 500, size=14))
-        base = _v2(model, params, max_context=16, block_size=8).generate(
-            [long_p], max_new_tokens=8)
-        eng = _v2(model, params, max_context=16, block_size=8,
-                  decode_steps_per_dispatch=8)
-        got = eng.generate([long_p], max_new_tokens=8)
-        assert got == base
-        assert eng.allocator.free_blocks == eng.config.num_blocks
-
-    def test_kv_pressure_falls_back_and_completes(self, tiny):
-        """When the pool cannot pre-fund K appends, the fused path declines
-        and the per-token path (with eviction) keeps decode progressing."""
-        model, params = tiny
-        eng = _v2(model, params, num_blocks=4, block_size=8, max_context=32,
-                  decode_steps_per_dispatch=8)
-        got = eng.generate([[1, 2, 3], [4, 5, 6], [7, 8, 9]],
-                           max_new_tokens=6)
-        assert all(len(g) >= 1 for g in got)
-        assert eng.allocator.free_blocks == 4
-
+class TestSampledGenerate:
     def test_sampled_decode_respects_budget_and_eos(self, tiny):
+        """Sampled streams stop at their budget or at the first EOS they
+        draw (emitted, then nothing), and the engine ends empty."""
         model, params = tiny
-        eng = _v2(model, params, decode_steps_per_dispatch=4)
-        got = eng.generate([[7, 3, 11], [4, 9]], max_new_tokens=7,
-                           do_sample=True, temperature=0.8, top_k=20,
-                           rng=jax.random.PRNGKey(3))
-        assert all(1 <= len(g) <= 7 for g in got)
-        assert not eng.seqs
-
-    def test_oversubscribed_waves_still_fuse(self, tiny):
-        """Admission waves (prompts > max_sequences): while the engine is
-        slot-saturated the backlog is unadmissible, so decode rounds STILL
-        take the fused path (the gate is 'nothing admissible', not 'queue
-        empty'); results stay exact."""
-        model, params = tiny
-        eng = _v2(model, params, max_sequences=2,
-                  decode_steps_per_dispatch=4)
-        rs = np.random.RandomState(1)
-        prompts = [list(rs.randint(1, 500, size=rs.randint(2, 6)))
-                   for _ in range(5)]
-        got = eng.generate(prompts, max_new_tokens=4)
-        for p, g in zip(prompts, got):
-            assert g == _naive_greedy(model, params, p, 4)
-        assert eng._decode_multi  # fused program ran despite the backlog
-
-    def test_warmup_compiles_fused_program_and_stays_clean(self, tiny):
-        model, params = tiny
-        eng = _v2(model, params, decode_steps_per_dispatch=4)
-        eng.warmup()
+        eng = _v2(model, params)
+        kw = dict(max_new_tokens=7, do_sample=True, temperature=0.8,
+                  top_k=20, rng=jax.random.PRNGKey(3))
+        plain = eng.generate([[7, 3, 11], [4, 9]], **kw)
+        assert [len(g) for g in plain] == [7, 7]
+        eos = plain[0][3]
+        got = eng.generate([[7, 3, 11], [4, 9]], eos_token_id=eos, **kw)
+        # the same key draws the same tokens until a stream leaves the batch
+        assert got[0] == plain[0][:plain[0].index(eos) + 1]
+        assert all(1 <= len(g) <= 7 and eos not in g[:-1] for g in got)
         assert not eng.seqs
         assert eng.allocator.free_blocks == eng.config.num_blocks
-        assert len(eng._decode_multi) == 1  # default greedy program built
-        prompt = [7, 3, 11]
-        assert eng.generate([prompt], max_new_tokens=6)[0] == \
-            _naive_greedy(model, params, prompt, 6)
 
     def test_temperature_topp_eos_do_not_recompile(self, tiny):
-        """temperature/top_p/eos are traced operands: sweeping them must
-        reuse ONE compiled K-step program (only structure — do_sample/
-        top_k/top_p-active — keys the cache)."""
+        """temperature/top_p are traced operands and the EOS is the host's:
+        sweeping them reuses ONE compiled sampler (only structure —
+        do_sample/top_k/top_p-active — is static)."""
         model, params = tiny
-        eng = _v2(model, params, decode_steps_per_dispatch=4)
+        eng = _v2(model, params)
+        compiled = []
         for i, (t, p, eos) in enumerate([(0.7, 0.9, None), (1.3, 0.8, 42),
                                          (0.5, 0.95, 7)]):
             eng.generate([[7, 3, 11]], max_new_tokens=4, do_sample=True,
                          temperature=t, top_p=p, eos_token_id=eos,
                          rng=jax.random.PRNGKey(i))
-        assert len(eng._decode_multi) == 1
+            # jit's cache is by the function, shared with earlier engines
+            compiled.append(eng._sample_fn._cache_size())
+        assert compiled[0] == compiled[1] == compiled[2]
+
+
+# ------------------------------------------------- a stream that ends early
+@pytest.fixture(scope="module")
+def ending(tiny):
+    model, params = tiny
+    return stream_ends.family(_v2(model, params, max_context=32,
+                                  num_blocks=12))
+
+
+@stream_ends.parametrize
+def test_a_stream_that_ends_early_gives_back_what_it_held(ending, driver,
+                                                          end):
+    stream_ends.check(ending, driver, end)

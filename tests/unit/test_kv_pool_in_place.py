@@ -5,11 +5,11 @@ through their layer loop and hand it whole, with the layer index, to the
 attention impls; before, the pool was the scan's xs/ys and XLA sliced,
 copied and wrote back one whole layer of it per layer for a few new rows.
 
-* frozen case: the three forwards return what the parent commit returned
+* frozen case: both forwards return what the parent commit returned
   (``data/kv_pool_forward_golden.npz``, written by running THIS file as a
   script against a checkout of the commit to freeze:
   ``PYTHONPATH=<checkout> python tests/unit/test_kv_pool_in_place.py``) —
-  logits, emitted tokens, the rows written, every other row bit-identical;
+  logits, the rows written, every other row bit-identical;
 * structure: compiled with a pool far larger than everything else, neither
   forward needs a temporary the size of one layer's K + V, and the donated
   pool is the output (a count from ``memory_analysis()``, not a time).
@@ -103,28 +103,8 @@ def _ragged_case(prefill_impl):
     return kv, {"logits": logits}, new, written
 
 
-def _multi_case(decode_impl):
-    """Fused greedy decode: slot 0 has a budget of 4 tokens, slot 1 of 2."""
-    model, params = _model()
-    kv = _pool(model.config, NUM_BLOCKS * BS)
-    logits0 = jax.random.normal(jax.random.PRNGKey(5),
-                                (S, model.config.vocab_size), jnp.float32)
-    buf, logits, pos, act, left, new = M.decode_multi_forward(
-        model, params, kv, logits0, jnp.asarray(CACHED), jnp.asarray(TABLES),
-        jnp.asarray([True, True, False]), jnp.asarray([4, 2, 0], jnp.int32),
-        jax.random.PRNGKey(0), jnp.float32(1.0), jnp.float32(1.0),
-        jnp.int32(-1), block_size=BS, num_steps=4,
-        samp_struct=(False, 0, False), max_context=BS * BPS,
-        attn_impl=decode_impl)
-    # the token that spends a budget is emitted, never appended
-    written = np.concatenate([_slots(0, range(11, 14)), _slots(1, [9])])
-    return kv, {"tokens": buf, "logits": logits[:2], "pos": pos,
-                "left": left}, new, written
-
-
 CASES = {"decode_forward": (_decode_case, 0),
-         "ragged_forward": (_ragged_case, 1),
-         "decode_multi_forward": (_multi_case, 0)}
+         "ragged_forward": (_ragged_case, 1)}
 
 
 def _run(program, impl):

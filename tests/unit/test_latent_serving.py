@@ -22,6 +22,7 @@ from deepspeedsyclsupport_tpu.models import build_model, get_config
 from deepspeedsyclsupport_tpu.models.layers import rope_frequencies
 from deepspeedsyclsupport_tpu.ops import paged_attention as pa
 from deepspeedsyclsupport_tpu.parallel.moe import moe_mlp_nodrop
+from tests.unit import stream_ends
 
 TINY = dict(
     hidden_size=64, intermediate_size=96, moe_intermediate_size=32,
@@ -520,3 +521,16 @@ def test_the_training_forward_refuses_what_only_serving_runs(tiny):
         model.apply(params, jnp.zeros((1, 8), jnp.int32))
     with pytest.raises(ValueError, match="first_k_dense_replace"):
         dataclasses.replace(get_config("tiny"), first_k_dense_replace=1)
+
+
+# ------------------------------------------------- a stream that ends early
+@pytest.fixture(scope="module")
+def ending(tiny):
+    return stream_ends.family(engine_of(tiny, max_context=48,
+                                        num_blocks=12))
+
+
+@stream_ends.parametrize
+def test_a_stream_that_ends_early_gives_back_what_it_held(ending, driver,
+                                                          end):
+    stream_ends.check(ending, driver, end)

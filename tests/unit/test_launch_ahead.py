@@ -242,46 +242,7 @@ def test_an_evicted_stream_is_requeued_and_says_every_token(built):
     sess.close()
 
 
-# ----------------------------------------------------- (vi): K-step rounds
-def test_fused_rounds_are_entered_and_left_with_nothing_in_flight(built):
-    """``decode_steps_per_dispatch`` 4: per-token rounds until every stream
-    has its first token, a fused round, a per-token round again when a new
-    request arrives, fused again. Greedy streams equal the unfused
-    reference's; the per-token rounds between launch ahead, a fused round
-    does not."""
-    requests, late = REQUESTS[:2], [(4, [5, 3, 5, 8, 9, 7], 9)]
-    requests = [(u, p, 12) for u, p, _b in requests]
-    _sess, plain, _want = _drive(built, requests, late)
-    sess = ServingSession(_engine(built, decode_steps_per_dispatch=4),
-                          ServingPolicyConfig(admission="none"))
-    for uid, prompt, budget in requests:
-        sess.submit(uid, prompt, budget)
-    got, late = [], list(late)
-    for _ in range(60):
-        if sess.idle and not late:
-            break
-        got.append(_said(sess.step()))
-        if late and len(got) == 4:
-            sess.submit(*late.pop(0))
-    assert sess.idle and not late
-    assert _streams(got) == _streams(plain)
-    rounds = _rounds(sess)
-    modes = [d["mode"] for d in rounds]
-    first_fused = modes.index("fused")
-    assert "per_token" in modes[first_fused:], modes
-    assert "fused" in modes[first_fused + modes[first_fused:].index(
-        "per_token"):], modes
-    assert all(d["ahead"] == 0 for d in rounds if d["mode"] == "fused")
-    after = [d for a, d in zip(rounds, rounds[1:])
-             if a["mode"] == "fused" and d["mode"] == "per_token"
-             and d["program"]]
-    assert after and all(d["ahead"] == 1 for d in after)
-    assert not sess._pending_tok
-    assert sess.eng.allocator.free_blocks == sess.eng.config.num_blocks
-    sess.close()
-
-
-# ------------------------------------------------- (vii): the prefix cache
+# -------------------------------------------------- (vi): the prefix cache
 def test_with_a_prefix_cache_history_is_whole_after_every_round(built):
     """``history`` (what the prefix index hashes) gets a decode token's
     VALUE at the read-back, not at the put: after every ``step()`` it is the

@@ -246,49 +246,3 @@ def test_a_sampling_session_repeats_itself_from_its_key(tiny):
         runs.append(out)
     assert runs[0] == runs[1] and all(len(v) == 6 for v in runs[0].values())
     assert eng.logit_rows_sliced == 0
-
-
-# ------------------------------------------------- (d) the K-step program
-def test_the_k_step_program_starts_from_the_gathered_rows(tiny):
-    """``logits0`` is row ``i`` = ``uids[i]``'s logits, from whichever
-    forward and slot: what the stack of cut-out rows was."""
-    eng = _three_live(tiny)
-    for uids in [(1, 2, 3), (3, 1), (2,)]:
-        got = np.asarray(eng._drained_rows(uids))
-        assert got.shape == (eng.config.max_sequences,
-                             eng.model.config.vocab_size)
-        want = np.stack([np.asarray(eng.query(u)) for u in uids])
-        np.testing.assert_array_equal(got[:len(uids)], want)
-
-
-@pytest.mark.parametrize("do_sample", [False, True], ids=["greedy", "sample"])
-def test_fused_rounds_give_the_per_token_rounds_tokens(tiny, do_sample):
-    """Greedy: a K-step session emits what a per-token session emits.
-    Sampling: it repeats itself from its key (its split tree is its own)."""
-    prompts = [(1, [5, 9, 2], 9), (2, list(range(30, 52)), 7), (3, [7], 11)]
-    sp = SamplingParams(do_sample, 0.9, 0, 1.0)
-
-    def run(k):
-        eng = _v2(tiny, decode_steps_per_dispatch=k)
-        sess = ServingSession(eng, ServingPolicyConfig(admission="none"),
-                              sampling=sp, rng=jax.random.PRNGKey(5))
-        out = {}
-        for uid, prompt, budget in prompts:
-            sess.submit(uid, prompt, budget)
-        while not sess.idle:
-            for ev in sess.step():
-                if ev.kind == "token":
-                    out.setdefault(ev.uid, []).extend(ev.tokens)
-        modes = {r["data"]["mode"] for r in sess.drain_trace()
-                 if r["data"].get("stage") == "round"}
-        sess.close()
-        assert eng.logit_rows_sliced == 0
-        return out, modes
-
-    fused, modes = run(4)
-    assert "fused" in modes
-    assert {u: len(t) for u, t in fused.items()} == {1: 9, 2: 7, 3: 11}
-    if do_sample:
-        assert run(4)[0] == fused
-    else:
-        assert run(1)[0] == fused

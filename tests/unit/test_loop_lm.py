@@ -24,6 +24,7 @@ from deepspeedsyclsupport_tpu.inference.v2 import (
 from deepspeedsyclsupport_tpu.inference.v2 import model as M
 from deepspeedsyclsupport_tpu.inference.v2.kv_cache import kv_pool_stats
 from deepspeedsyclsupport_tpu.models import ModelConfig, build_model
+from tests.unit import stream_ends
 
 TOL = 2e-5
 L, V = 3, 512
@@ -101,18 +102,15 @@ def test_served_logits_are_the_references(family, passes, attn):
 
 
 @pytest.mark.parametrize("passes", [2, 4])
-def test_the_fused_decode_loop_walks_the_passes_too(family, passes):
-    """``decode_multi_forward`` (K steps in one device loop) calls the same
-    ``decode_forward``: its greedy tokens are the per-token engine's and
-    the reference's own greedy continuation."""
+def test_generate_continues_as_the_reference_does(family, passes):
+    """Two prompts through ``generate()``, a decode step a token: each
+    stream's greedy tokens are the reference's own greedy continuation."""
     model, params = built(passes)
     prompts = [PROMPT[:9], PROMPT[9:30]]
-    base = engine_of(model, params).generate(prompts, max_new_tokens=7)
-    fused = engine_of(model, params, decode_steps_per_dispatch=4)
-    assert fused.generate(prompts, max_new_tokens=7) == base
-    for prompt, got in zip(prompts, base):
-        rows = reference(family, passes, params, prompt + got)
-        assert rows[len(prompt) - 1:-1].argmax(-1).tolist() == list(got)
+    got = engine_of(model, params).generate(prompts, max_new_tokens=7)
+    for prompt, toks in zip(prompts, got):
+        rows = reference(family, passes, params, prompt + toks)
+        assert rows[len(prompt) - 1:-1].argmax(-1).tolist() == list(toks)
 
 
 # ------------------------------------------------ a pass reads its own rows
@@ -449,3 +447,16 @@ def test_training_refuses_a_looped_stack_by_name(kw):
         model.loss(params, batch)
     with pytest.raises(NotImplementedError, match="serving path only"):
         model.apply(params, batch["input_ids"])
+
+
+# ------------------------------------------------- a stream that ends early
+@pytest.fixture(scope="module")
+def ending():
+    return stream_ends.family(engine_of(*built(2), max_context=48,
+                                        num_blocks=12))
+
+
+@stream_ends.parametrize
+def test_a_stream_that_ends_early_gives_back_what_it_held(ending, driver,
+                                                          end):
+    stream_ends.check(ending, driver, end)

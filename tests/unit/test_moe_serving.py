@@ -1,7 +1,7 @@
 """Sparse experts on the serving path (``parallel/moe.moe_mlp_nodrop``, the
 forwards' ``live`` mask, ``kv_cache.MoeCounters``): a pad row gets no expert,
 the device's load counter equals ``k x live tokens`` in every layer over
-rounds, through the fused path and through an eviction with requeue, the
+rounds and through an eviction with requeue, the
 round record carries the experts the forward before it touched, and
 mixtral's renormalised weighting is bit for bit what it was."""
 import jax
@@ -14,6 +14,7 @@ from deepspeedsyclsupport_tpu.inference.v2 import (
 from deepspeedsyclsupport_tpu.models import build_model, get_config
 from deepspeedsyclsupport_tpu.parallel.moe import (moe_mlp_nodrop,
                                                    topk_gating, topk_weights)
+from tests.unit import stream_ends
 
 
 @pytest.fixture(scope="module")
@@ -182,17 +183,6 @@ def test_touched_rides_behind_the_sampled_tokens(tiny_moe):
     assert eng.logit_rows_sliced == 0
 
 
-def test_load_counts_the_fused_decode_steps(tiny_moe):
-    eng = _engine(tiny_moe, decode_steps_per_dispatch=4)
-    sess = ServingSession(eng, ServingPolicyConfig(admission="none"))
-    _drive(sess, [(1, [1, 2, 3], 12), (2, [5, 6], 9)])
-    rounds = [r["data"] for r in sess.drain_trace()
-              if r["data"].get("stage") == "round"]
-    assert any(d["mode"] == "fused" for d in rounds)
-    _check_load(eng)
-    sess.close()
-
-
 def test_load_survives_an_eviction_and_requeue(tiny_moe):
     """A pool of 8 blocks where 4 live sequences want 16: streams are
     evicted, prefilled again and finish; every token of every forward,
@@ -255,3 +245,16 @@ def test_a_two_matrix_expert_is_one_function_on_both_paths():
     ids = jnp.arange(16, dtype=jnp.int32).reshape(1, 16)
     logits = model.apply(params, ids)[0]
     assert np.isfinite(np.asarray(logits)).all()
+
+
+# ------------------------------------------------- a stream that ends early
+@pytest.fixture(scope="module")
+def ending(tiny_moe):
+    return stream_ends.family(_engine(tiny_moe, max_context=32,
+                                      num_blocks=12))
+
+
+@stream_ends.parametrize
+def test_a_stream_that_ends_early_gives_back_what_it_held(ending, driver,
+                                                          end):
+    stream_ends.check(ending, driver, end)

@@ -61,22 +61,16 @@ def per_token_run(tiny):
     return _serve(_engine(tiny))
 
 
-@pytest.fixture(scope="module")
-def fused_run(tiny):
-    return _serve(_engine(tiny, decode_steps_per_dispatch=4), budget=12)
-
-
 PATHS = {
     "per_token": lambda d: d["program"] == "decode_forward",
     "mixed": lambda d: d["program"] == "ragged_forward"
     and 0 < d["prefill_tokens"] < d["tokens"],
-    "fused": lambda d: d["mode"] == "fused",
 }
 
 
 @pytest.mark.parametrize("path", sorted(PATHS))
-def test_phases_partition_every_round(per_token_run, fused_run, path):
-    _sess, records = fused_run if path == "fused" else per_token_run
+def test_phases_partition_every_round(per_token_run, path):
+    _sess, records = per_token_run
     rounds = [d for d in _rounds(records) if PATHS[path](d)]
     assert rounds, f"the drive made no {path} round"
     for d in rounds:
@@ -84,14 +78,10 @@ def test_phases_partition_every_round(per_token_run, fused_run, path):
         assert sum(d["phases"].values()) == pytest.approx(
             d["t1"] - d["t0"], rel=0.01)
         assert d["t0"] <= d["launch_t"] <= d["t1"]
-    if path == "fused":
-        assert all(d["program"].startswith("decode_multi_") and d["tokens"]
-                   and "readback" in d["phases"] for d in rounds)
-    else:
-        # a round that sampled passed through every phase of the registry
-        full = [d for d in rounds if d["uids"]]
-        assert full and all(
-            set(d["phases"]) == set(reqtrace.ROUND_PHASES) for d in full)
+    # a round that sampled passed through every phase of the registry
+    full = [d for d in rounds if d["uids"]]
+    assert full and all(
+        set(d["phases"]) == set(reqtrace.ROUND_PHASES) for d in full)
 
 
 def test_one_record_per_round_and_its_spans_name_it(per_token_run):
@@ -118,7 +108,7 @@ def test_one_record_per_round_and_its_spans_name_it(per_token_run):
                 if r["data"].get("stage") == "decode_round"]
     # the join still counts each request's rounds, now from ``round``
     traces = reqtrace.join_traces([("0", "", records)])
-    assert all(tr["rounds"]["per_token"] == 6 for tr in traces.values())
+    assert all(tr["rounds"] == 6 for tr in traces.values())
 
 
 def test_context_and_block_counters_equal_a_hand_count(tiny):
@@ -144,7 +134,7 @@ def test_context_and_block_counters_equal_a_hand_count(tiny):
         len(eng.seqs[u].blocks) for u in (1, 2, 3)) == 4
     # just what has a reader: the join, the report's tables, the benchmark
     assert set(second) == {"uid", "stage", "round", "t0", "t1", "launch_t",
-                           "program", "mode", "uids", "phases",
+                           "program", "uids", "phases",
                            *reqtrace.FORWARD_FIELDS}
     sess.close()
 
@@ -208,16 +198,14 @@ def test_a_round_that_begins_with_no_work_writes_no_record(tiny, monkeypatch,
 
 
 def test_the_forward_programs_carry_the_names_they_are_dispatched_by(
-        per_token_run, fused_run):
+        per_token_run):
     """What the device trace's ``XLA Modules`` line and the host's
     ``PjitFunction`` span print: ``jit_<name>``, not ``jit__unknown``."""
-    for (sess, _records), want in ((per_token_run, {"ragged_forward",
-                                                    "decode_forward"}),
-                                   (fused_run, {"decode_multi_4"})):
-        programs = sess.eng.compiled_programs()
-        assert want <= set(programs)
-        for name, compiled in programs.items():
-            assert compiled.as_text().startswith(f"HloModule jit_{name},")
+    sess, _records = per_token_run
+    programs = sess.eng.compiled_programs()
+    assert set(programs) == {"ragged_forward", "decode_forward"}
+    for name, compiled in programs.items():
+        assert compiled.as_text().startswith(f"HloModule jit_{name},")
 
 
 class Annotations:

@@ -5,8 +5,8 @@ The policy is host-side and clock-driven, so everything here runs on the CPU
 sim with a synthetic clock and a synthetic capacity model: admission
 accept/queue/shed decisions, slack-ordered chunk composition (starvation
 aging included), KV-exhaustion eviction picking the lowest-slack sequence
-and actually freeing its blocks, per-tenant fairness budgets, fused-K rung
-selection, and the ``Serve/*`` telemetry registration (strict-events safe).
+and actually freeing its blocks, per-tenant fairness budgets, and the
+``Serve/*`` telemetry registration (strict-events safe).
 """
 import math
 import os
@@ -443,129 +443,13 @@ class TestEviction:
         assert eng.allocator.free_blocks == 4
 
 
-# -------------------------------------------------------- fused-K selection
-class TestFusedKSelection:
-    def test_rung_covers_longest_tail(self, tiny):
-        """A 3-step tail on a ladder-warmed K=8 engine drains in ONE
-        dispatch (the old fixed-K gate would run it per-token) WITHOUT
-        compiling any new program (the 4-rung covers it)."""
-        from deepspeedsyclsupport_tpu.inference.sampling import SamplingParams
-
-        model, params = tiny
-        eng = _v2(model, params, decode_steps_per_dispatch=8)
-        eng.warmup(fused_ladder=True)
-        compiled = set(eng._decode_multi)
-        assert (4, SamplingParams().structure) in compiled
-        eng.put([1], [[7, 3, 11]])
-        d0 = eng.host_dispatches
-        running = {1: 3}
-        emitted = eng._decode_multi_dispatch(running, SamplingParams(), None,
-                                             jax.random.PRNGKey(0))
-        assert emitted is not None and len(emitted[1]) == 3
-        assert eng.host_dispatches - d0 == 1
-        assert set(eng._decode_multi) == compiled  # no mid-serve compile
-        assert 1 not in eng.seqs  # retired + flushed by the engine
-
-    def test_plain_warmup_tail_never_compiles_midrun(self, tiny):
-        """With only warmup() (no fused ladder), a short tail must use the
-        one compiled K program (early device exit) — selecting a smaller
-        uncompiled rung would pay the mid-generation compile plain-warmup
-        callers were promised not to."""
-        from deepspeedsyclsupport_tpu.inference.sampling import SamplingParams
-
-        model, params = tiny
-        eng = _v2(model, params, decode_steps_per_dispatch=8)
-        eng.warmup()
-        compiled = set(eng._decode_multi)
-        assert (8, SamplingParams().structure) in compiled
-        eng.put([1], [[7, 3, 11]])
-        running = {1: 3}
-        emitted = eng._decode_multi_dispatch(running, SamplingParams(), None,
-                                             jax.random.PRNGKey(0))
-        assert emitted is not None and len(emitted[1]) == 3
-        assert set(eng._decode_multi) == compiled  # reused the K program
-        eng.flush([1])
-
-    def test_k_cap_bounds_dispatch(self, tiny):
-        from deepspeedsyclsupport_tpu.inference.sampling import SamplingParams
-
-        model, params = tiny
-        eng = _v2(model, params, decode_steps_per_dispatch=8)
-        eng.put([1], [[7, 3, 11]])
-        running = {1: 8}
-        emitted = eng._decode_multi_dispatch(running, SamplingParams(), None,
-                                             jax.random.PRNGKey(0), k_cap=2)
-        assert emitted is not None and len(emitted[1]) == 2
-        assert (2, SamplingParams().structure) in eng._decode_multi
-        assert running == {1: 6}
-        eng.flush([1])
-
-    def test_odd_k_ladder_floors_at_two(self, tiny):
-        """Non-power-of-two K: the rung walk must floor at 2 (12→6→3→2),
-        never halve to 1 and silently disable fusion; the fused_ladder
-        warmup compiles that same rung set."""
-        from deepspeedsyclsupport_tpu.inference.sampling import SamplingParams
-
-        model, params = tiny
-        eng = _v2(model, params, decode_steps_per_dispatch=12)
-        eng.warmup(fused_ladder=True)
-        s = SamplingParams().structure
-        assert {(6, s), (3, s), (2, s)} <= set(eng._decode_multi)
-        eng.put([1], [[7, 3, 11]])
-        running = {1: 12}
-        emitted = eng._decode_multi_dispatch(running, SamplingParams(), None,
-                                             jax.random.PRNGKey(0), k_cap=2)
-        assert emitted is not None and len(emitted[1]) == 2
-        eng.flush([1])
-
-    def test_non_rung_k_cap_snaps_to_ladder(self, tiny):
-        """A slack-derived cap (any int) must SELECT a compiled rung, never
-        compile a fresh K mid-serve: cap 7 on a K=8 engine runs the 4-rung."""
-        from deepspeedsyclsupport_tpu.inference.sampling import SamplingParams
-
-        model, params = tiny
-        eng = _v2(model, params, decode_steps_per_dispatch=8)
-        eng.put([1], [[7, 3, 11]])
-        running = {1: 8}
-        emitted = eng._decode_multi_dispatch(running, SamplingParams(), None,
-                                             jax.random.PRNGKey(0), k_cap=7)
-        assert emitted is not None and len(emitted[1]) == 4
-        s = SamplingParams().structure
-        assert (4, s) in eng._decode_multi
-        assert (7, s) not in eng._decode_multi
-        eng.flush([1])
-
-    def test_fused_parity_with_short_budgets(self, tiny):
-        """generate() outputs stay exact when budgets are far below K (the
-        absorb-based rung selection must not change tokens)."""
-        model, params = tiny
-        prompts = [[7, 3, 11], [4, 100, 42, 8, 19]]
-        base = _v2(model, params).generate(prompts, max_new_tokens=3)
-        eng = _v2(model, params, decode_steps_per_dispatch=16)
-        got = eng.generate(prompts, max_new_tokens=3)
-        assert got == base
-
-    def test_warmup_fused_ladder_precompiles_rungs(self, tiny):
-        from deepspeedsyclsupport_tpu.inference.sampling import SamplingParams
-
-        model, params = tiny
-        eng = _v2(model, params, decode_steps_per_dispatch=8)
-        eng.warmup(fused_ladder=True)
-        s = SamplingParams().structure
-        assert {(8, s), (4, s), (2, s)} <= set(eng._decode_multi)
-        assert not eng.seqs
-        assert eng.allocator.free_blocks == eng.config.num_blocks
-        assert eng.host_dispatches == 0
-
-
 # ------------------------------------------------------------- session e2e
 class TestSessionEndToEnd:
     def test_greedy_parity_and_slack_eviction_policy(self, tiny):
         """Tokens served under the full policy layer (admission + slack
-        ordering + fused decode) are exactly the naive greedy tokens."""
+        ordering) are exactly the naive greedy tokens."""
         model, params = tiny
-        eng = _v2(model, params, decode_steps_per_dispatch=4,
-                  eviction_policy="slack")
+        eng = _v2(model, params, eviction_policy="slack")
         sess = ServingSession(eng, ServingPolicyConfig(ttft_sla_s=30.0))
         prompts = {1: [7, 3, 11], 2: [4, 100, 42, 8, 19], 3: [9, 9, 2]}
         for uid, p in prompts.items():

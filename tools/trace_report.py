@@ -621,10 +621,8 @@ def requests_report(root: str, worst_n: int = 5, window_s: float = 60.0,
             lines.append(f"    {stage:<14}median={_fmt_s(d['median_s'])}  "
                          f"tail={_fmt_s(d['tail_s'])}  "
                          f"growth={_fmt_s(d['growth_s'])}{dom}")
-    dr = att["decode_rounds"]
-    if dr["fused"] or dr["per_token"]:
-        lines.append(f"  decode rounds: {dr['fused']} fused, "
-                     f"{dr['per_token']} per-token")
+    if att["decode_rounds"]:
+        lines.append(f"  decode rounds: {att['decode_rounds']}")
     rp = rt.round_phases(streams)
     if rp:
         launch = (f"; forward launched {_qline(rp['launch_s'])} into it"
