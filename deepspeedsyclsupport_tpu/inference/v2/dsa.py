@@ -1,0 +1,224 @@
+"""Sparse attention behind a learned indexer, on the serving path
+(``ModelConfig.index_topk``; DeepSeek-V3.2's recipe under the sizes
+KeyeVL2's ``sa_config`` publishes).
+
+Beside q, k and v a token's normed row gives the INDEXER's ``index_heads``
+small queries ``qI``, its ONE key ``kI`` (behind a LayerNorm; both rotated
+over their whole width) and a weight a head ``w``. ``kI`` is cached as k and
+v are, in the pool's third array at the token's slot
+(``kv_cache.BlockedKV.idx``). A query scores every cached token of its
+sequence, ``I(t, s) = (heads x dim)^-1/2 x sum_j w_t[j] relu(qI_t[j] .
+kI_s)``, keeps the ``index_topk`` best of those it may see (ties to the
+lower position; all while there are no more) and attends over those alone.
+
+Three labels reach the device trace (``jax.named_scope``; the kernels carry
+their own names): ``dsa_index`` (the indexer's projections, norm, rotary,
+pool write and scores), ``dsa_select`` (the selection) and ``dsa_attend``
+(whatever gathers, masks and attends over the selected keys; the one-token
+rows' part under ``dsa_rows`` inside it).
+
+Two routes, by the chunk's length as everywhere on this path:
+
+* a chunk of two tokens or more, cut into atoms: the scores of an atom's
+  rows in one kernel, the selection in a second (``ops/sparse_index.py``),
+  and the ragged paged kernel under the selection's MASK: it visits every
+  cached pair of the atom and keeps the selected (a prefill that reads the
+  selected rows only is not written);
+* a one-token chunk (every row of ``decode_forward``): its scores and an
+  exact ``lax.top_k`` in XLA, a GATHER of the selected rows of K and V
+  through the block table, and softmax attention over those ``index_topk``
+  rows: what it reads of K and V does not grow with the context.
+
+An engine whose attention takes no atoms (``prefill_attn`` ``xla`` or
+``flash``: the CPU's, the tests') runs every row through the exact
+``jax.numpy`` twins, a tile of one row each.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ...models.layers import apply_rope, layer_norm
+from ...ops import sparse_index
+
+NEG_INF = jnp.finfo(jnp.float32).min
+
+
+def index_scale(cfg) -> float:
+    return float(cfg.index_heads * cfg.index_head_dim) ** -0.5
+
+
+def index_rows(p, y, cfg, positions):
+    """The indexer's part of flat tokens y [n, D]: ``(qI [n, Hi, Di], kI
+    [n, Di], w [n, Hi] float32)``, qI and kI rotated over their whole
+    width at the model's ``rope_theta``."""
+    n, hi, di = y.shape[0], cfg.index_heads, cfg.index_head_dim
+    rot = lambda t: apply_rope(  # noqa: E731
+        t[None], positions[None], cfg.rope_theta)[0]
+    q_i = rot((y @ p["w_qi"]).reshape(n, hi, di))
+    k_i = rot(layer_norm(y @ p["w_ki"], p["ki_norm"]["scale"],
+                         p["ki_norm"]["bias"], cfg.rms_norm_eps)[:, None])
+    w = jnp.einsum("td,dh->th", y, p["w_w"],
+                   preferred_element_type=jnp.float32)
+    return q_i, k_i[:, 0], w
+
+
+def pair_mates(token_seq, token_pos, live):
+    """[n] int32: for each flat row the row of the SAME batch that holds its
+    sequence's token at position ``pos ^ 1`` (the other slot of its row in
+    the indexer's pool), -1 where the batch has none. A chunk's tokens are
+    consecutive rows, so the mate is a neighbour or absent."""
+    n = token_pos.shape[0]
+    j = jnp.clip(jnp.arange(n) + jnp.where(token_pos % 2 == 0, 1, -1),
+                 0, n - 1)
+    found = live & live[j] & (token_seq[j] == token_seq) \
+        & (token_pos[j] == (token_pos ^ 1))
+    return jnp.where(found, j, -1)
+
+
+def index_pool_write(pool, layer, dest, k_i, mates):
+    """The new tokens' indexer keys k_i [n, Di] into layer ``layer`` of the
+    pool [L, slots / 2, 2 x Di] at flat slots ``dest`` [n] (out of range =
+    dropped): slot s is the lanes of its parity in row s // 2. A row is
+    written WHOLE: the other slot's lanes are its key in this batch
+    (``mates``: :func:`pair_mates`; both rows of a pair then write the same
+    row) or what the pool held."""
+    di = k_i.shape[1]
+    row = dest // 2                    # the drop sentinel stays out of range
+    odd = (dest % 2 == 1)[:, None]
+    held = pool[layer, jnp.minimum(row, pool.shape[1] - 1)]       # [n, 2Di]
+    other = jnp.where((mates >= 0)[:, None], k_i[jnp.maximum(mates, 0)],
+                      jnp.where(odd, held[:, :di], held[:, di:])
+                      .astype(k_i.dtype))
+    rows = jnp.where(odd, jnp.concatenate([other, k_i], axis=1),
+                     jnp.concatenate([k_i, other], axis=1))
+    return pool.at[layer, row].set(rows.astype(pool.dtype), mode="drop")
+
+
+def seq_index_keys(idx_pool, layer, block_tables, block_size: int):
+    """[S, C, Di]: every sequence slot's cached indexer keys of ``layer`` in
+    position order, a BLOCK at a time through its table (C = the table's
+    blocks x ``block_size``; what lies past a sequence's length is whatever
+    the blocks hold, and no row may see it)."""
+    width = idx_pool.shape[-1]
+    # whole blocks by (layer, block), as K and V's rows are gathered: the
+    # pool seen as [L, blocks, rows of a block, 2Di] (its rows regrouped,
+    # nothing moved)
+    blocks = idx_pool.reshape(idx_pool.shape[0], -1, block_size // 2, width)
+    keys = blocks[layer, block_tables]               # [S, Bps, bs / 2, 2Di]
+    return keys.reshape(block_tables.shape[0], -1, width // 2)
+
+
+def _kernel_impl(name: str) -> str:
+    """The paged kernels' ``impl`` word for a ``prefill_attn`` entry's name
+    (the entries that take no atoms run the ``jax.numpy`` twins)."""
+    return {"kernel": "pallas",
+            "kernel_interpret": "pallas_interpret"}.get(name, "xla")
+
+
+def attend_atoms(q, q_i, w, k_seq, k_cache, v_cache, layer, ctx, cfg, impl):
+    """The chunks of two tokens or more: per atom the scores, the selection
+    and the ragged kernel under its mask. -> [T, H, D] in packed rows (the
+    one-token and padding rows gather the reserved dead atom's zeros)."""
+    from ...ops.paged_attention import ragged_prefill_attention
+
+    s = ctx.block_tables.shape[0]
+    tile_seq = jnp.clip(ctx.token_seq[ctx.atom_qidx[:, 0]], 0, s - 1)
+    tile_hi = jnp.where(ctx.atom_qlen > 0, ctx.atom_pos0 + ctx.atom_qlen, 0)
+    with jax.named_scope("dsa_index"):
+        scores = sparse_index.index_scores(
+            q_i[ctx.atom_qidx], w[ctx.atom_qidx], k_seq, tile_seq, tile_hi,
+            scale=index_scale(cfg), impl=impl)
+    with jax.named_scope("dsa_select"):
+        sel = sparse_index.select_topk(scores, ctx.atom_pos0, ctx.atom_qlen,
+                                       k=cfg.index_topk, impl=impl)
+    with jax.named_scope("dsa_attend"):
+        out_at = ragged_prefill_attention(
+            q[ctx.atom_qidx], k_cache, v_cache, ctx.atom_tables,
+            ctx.atom_pos0, ctx.atom_qlen, block_size=ctx.block_size,
+            layer=layer, impl=impl, sel=sel)
+        return out_at.reshape(-1, *out_at.shape[2:])[ctx.atom_inv]
+
+
+def attend_rows(q, q_i, w, k_seq, k_cache, v_cache, layer, block_tables,
+                seq_lens, block_size: int, cfg):
+    """One-token rows, one a sequence slot: q [S, H, D], qI [S, Hi, Di], w
+    [S, Hi], ``seq_lens`` [S] the slot's length WITH the row's token (0: no
+    row). Scores over the whole context, the exact ``index_topk`` best, and
+    attention over the gathered rows of K and V. -> [S, H, D]."""
+    s, h, d = q.shape
+    c = k_seq.shape[1]
+    kvh = k_cache.shape[-2]
+    k = min(cfg.index_topk, c)
+    with jax.named_scope("dsa_index"):
+        scores = sparse_index.index_scores_reference(
+            q_i[:, None], w[:, None], k_seq, jnp.arange(s),
+            scale=index_scale(cfg))[:, 0]
+    with jax.named_scope("dsa_select"):
+        seen = jnp.arange(c)[None] < seq_lens[:, None]
+        _, top = jax.lax.top_k(jnp.where(seen, scores, -jnp.inf), k)
+    with jax.named_scope("dsa_attend"), jax.named_scope("dsa_rows"):
+        live = top < seq_lens[:, None]                       # [S, k]
+        block = jnp.take_along_axis(block_tables, top // block_size, axis=1)
+        slots = jnp.where(live, block * block_size + top % block_size, 0)
+        k_sel = k_cache[layer, slots].astype(jnp.float32)    # [S, k, KVH, D]
+        v_sel = v_cache[layer, slots].astype(jnp.float32)
+        q_g = q.astype(jnp.float32).reshape(s, kvh, h // kvh, d)
+        logits = jnp.einsum("sngd,scnd->sngc", q_g, k_sel) / np.sqrt(d)
+        logits = jnp.where(live[:, None, None, :], logits, NEG_INF)
+        probs = jax.nn.softmax(logits, axis=-1)
+        out = jnp.einsum("sngc,scnd->sngd", probs, v_sel)
+        out = jnp.where((seq_lens > 0)[:, None, None, None], out, 0.0)
+        return out.reshape(s, h, d).astype(q.dtype)
+
+
+def attend_tokens(q, q_i, w, k_seq, k_cache, v_cache, layer, ctx, cfg):
+    """Every packed row on its own, through the exact ``jax.numpy`` twins
+    (a tile of one row): the route of an attention that takes no atoms."""
+    from .model import _paged_attention
+
+    t = q.shape[0]
+    s = ctx.block_tables.shape[0]
+    with jax.named_scope("dsa_index"):
+        scores = sparse_index.index_scores_reference(
+            q_i[:, None], w[:, None], k_seq,
+            jnp.minimum(ctx.token_seq, s - 1), scale=index_scale(cfg))
+    with jax.named_scope("dsa_select"):
+        sel = sparse_index.select_topk_reference(
+            scores, ctx.token_pos, jnp.ones((t,), jnp.int32),
+            k=cfg.index_topk)[:, 0]
+    with jax.named_scope("dsa_attend"):
+        return _paged_attention(
+            q, k_cache[layer], v_cache[layer], ctx.token_seq, ctx.token_pos,
+            ctx.block_tables, ctx.block_size, sel=sel)
+
+
+def ragged_attend(q, q_i, w, pools, layer, ctx, cfg, impl_name: str):
+    """Attention of one ``ragged_forward`` layer over the selected keys: q
+    [T, H, D] (lane-padded as the pool is), the indexer's rows, the pools
+    AFTER this layer's write, ``ctx`` a ``PrefillAttnContext``. -> [T, H, D].
+    """
+    k_cache, v_cache, idx = pools
+    with jax.named_scope("dsa_index"):
+        k_seq = seq_index_keys(idx, layer, ctx.block_tables, ctx.block_size)
+    if ctx.atom_qidx is None or _kernel_impl(impl_name) == "xla":
+        return attend_tokens(q, q_i, w, k_seq, k_cache, v_cache, layer, ctx,
+                             cfg)
+    out = attend_atoms(q, q_i, w, k_seq, k_cache, v_cache, layer, ctx, cfg,
+                       _kernel_impl(impl_name))
+    out_dec = attend_rows(
+        q[ctx.dec_row], q_i[ctx.dec_row], w[ctx.dec_row], k_seq, k_cache,
+        v_cache, layer, ctx.block_tables, ctx.dec_len, ctx.block_size, cfg)
+    # a slot with no one-token chunk scatters out of range (dropped)
+    rows = jnp.where(ctx.dec_len > 0, ctx.dec_row, q.shape[0])
+    return out.at[rows].set(out_dec, mode="drop")
+
+
+def decode_attend(q, q_i, w, pools, layer, block_tables, seq_lens,
+                  block_size: int, cfg):
+    """Attention of one ``decode_forward`` layer: every row a one-token
+    row."""
+    k_cache, v_cache, idx = pools
+    with jax.named_scope("dsa_index"):
+        k_seq = seq_index_keys(idx, layer, block_tables, block_size)
+    return attend_rows(q, q_i, w, k_seq, k_cache, v_cache, layer,
+                       block_tables, seq_lens, block_size, cfg)

@@ -29,7 +29,8 @@ from .kv_cache import init_blocked_kv, state_pool_stats
 from .model import build_ragged_forward_fn, moe_tile_rows
 from .ragged import (BlockedAllocator, LogitsRef, SequenceDescriptor,
                      attention_work, build_ragged_batch, device_token,
-                     ragged_shapes, split_device_tokens, ssm_pieces)
+                     ragged_shapes, selection_work, split_device_tokens,
+                     ssm_pieces)
 from .scheduler import schedule_chunks
 from ..params import place_inference_params
 from ..sampling import SamplingParams, sample_token_dyn, split_key
@@ -272,8 +273,11 @@ class InferenceEngineV2:
             cfg.atom_q_size = default_atom_rows(cfg.atom_q_size, *shape)
         # keys a loop step of the kernel covers under an atom and under a
         # one-row tile (attention_work's kv_step_keys)
-        self._kv_step_keys = tuple(kv_step_keys(rows, *shape, latent)
-                                   for rows in (cfg.atom_q_size, 1))
+        # (an indexer's selection rides the atoms' steps: 128 keys each)
+        self._kv_step_keys = tuple(
+            kv_step_keys(rows, *shape, latent,
+                         bool(model.config.index_topk) and rows > 1)
+            for rows in (cfg.atom_q_size, 1))
         # the static shapes of ragged_forward, smallest first, by the rows of
         # an atom and of a Mamba piece (0: the model takes none): a mixed
         # round runs at the first that holds it (_run), none under
@@ -418,6 +422,12 @@ class InferenceEngineV2:
                                if not (n == 1 and d.n_cached > 0)),
             ctx_tokens=sum(d.n_cached for d in descs),
             kv_blocks=sum(len(d.blocks) for d in descs))
+        if self.kv.idx is not None:
+            # a sparse-attention indexer: what the attention reads of that
+            sel_pairs, dec_sel_tokens = selection_work(
+                descs, lengths, self.model.config.index_topk)
+            self.round_spans.fields.update(sel_pairs=sel_pairs,
+                                           dec_sel_tokens=dec_sel_tokens)
         if self._state_free is not None:
             # live rows through the Mamba layers, and the sequence pieces
             # whose state they read and wrote (a one-token chunk is one

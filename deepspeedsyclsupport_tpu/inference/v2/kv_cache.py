@@ -9,7 +9,18 @@ over shared weights (``ModelConfig.total_ut_steps``) has one row for every
 (pass, layer) pair, pass ``u``'s layer ``l`` at ``u x num_layers + l``: a
 pass attends to its own keys and values, so a block of 64 tokens holds all
 ``passes x layers`` rows of them and the allocator, the prefix cache and the
-block copy see blocks as they do for any model. A latent-
+block copy see blocks as they do for any model. A model with a sparse-
+attention indexer (``ModelConfig.index_topk``) has a THIRD pool beside K and
+V, ``idx``: the indexer's one key a token and layer, as normed and rotated,
+at the SAME flat slot as the token's K and V, so one block table addresses
+all three and a block that is shared, copied, freed or requeued takes its
+indexer keys with it. It is laid out TWO slots a row, [L, num_blocks *
+block_size / 2, 2 x index_head_dim] (slot s in row s // 2, its key in the
+lanes of its parity; a block's rows stay together): at 64 wide a row of its
+own left the v5e compiler to store the pool slot-minor for the write and
+copy all of it back for the read, every layer (48 ms a forward, PERF.md
+section 6, PR 45), and padded to the 128 lanes it would be twice the bytes.
+A latent-
 attention model (``ModelConfig.kv_lora_rank``) caches ONE row a token and
 layer, [L, num_blocks * block_size, D]: the normed latent and the rotated
 key all heads share, and no V pool at all. The serving
@@ -84,6 +95,12 @@ class BlockedKV(NamedTuple):
     # published threshold 1.0 all in the last). Rides with the pool as
     # ``moe`` does.
     exit_pass: Optional[jnp.ndarray] = None
+    # a model with a sparse-attention indexer only (``ModelConfig.
+    # index_topk``; None elsewhere: no leaf, the same program): the
+    # indexer's keys in the pool's dtype, one a token and layer at the slot
+    # of its K and V, two slots a row: [L, num_blocks*block_size / 2,
+    # 2 x index_head_dim]
+    idx: Optional[jnp.ndarray] = None
 
     @property
     def num_slots(self) -> int:
@@ -96,8 +113,20 @@ class BlockedKV(NamedTuple):
 
     @property
     def pools(self):
-        """The pool arrays there are: (k, v), or (k,) for a latent pool."""
-        return (self.k,) if self.v is None else (self.k, self.v)
+        """The pool arrays there are, under :data:`POOL_NAMES`: (k, v), (k,)
+        for a latent pool, (k, v, idx) beside a sparse-attention indexer."""
+        return tuple(pool for pool in (self.k, self.v, self.idx)
+                     if pool is not None)
+
+    def with_pools(self, pools) -> "BlockedKV":
+        """This cache with ``pools`` (as :attr:`pools` gives them) in the
+        place of its own."""
+        return self._replace(**dict(zip(
+            (n for n in POOL_NAMES if getattr(self, n) is not None), pools)))
+
+
+# the fields of :class:`BlockedKV` that are pools addressed by block tables
+POOL_NAMES = ("k", "v", "idx")
 
 
 def lane_padded_head_dim(head_dim: int, pad) -> int:
@@ -155,6 +184,13 @@ def init_blocked_kv(model_config, cfg: RaggedInferenceConfig,
             conv=jnp.zeros((lead[0], mc.ssm_conv_kernel - 1, lead[1],
                             mc.ssm_conv_dim), cfg.dtype)),
             out_shardings=topology.replicated())()
+    if model_config.index_topk:
+        if cfg.block_size % 2:
+            raise ValueError("a sparse-attention indexer's keys lie two "
+                             "slots a row: block_size must be even")
+        shape_i = (shape[0], shape[1] // 2, 2 * model_config.index_head_dim)
+        state["idx"] = jax.jit(lambda: jnp.zeros(shape_i, cfg.dtype),
+                               out_shardings=topology.replicated())()
     if model_config.total_ut_steps > 1:
         state["exit_pass"] = jax.jit(
             lambda: jnp.zeros((model_config.total_ut_steps,), jnp.int32),
@@ -193,22 +229,21 @@ def kv_pool_stats(kv: BlockedKV, allocator) -> dict:
     # physical, shared == 0 — the pre-sharing report
     logical = int(getattr(allocator, "logical_blocks", physical))
     shared = int(getattr(allocator, "shared_blocks", 0))
-    per_slot = sum(int(np.prod(pool.shape[2:])) * pool.dtype.itemsize
-                   * pool.shape[0] for pool in kv.pools)
     return {"blocks_total": total, "blocks_free": free,
             "blocks_physical": physical, "blocks_logical": logical,
             "blocks_shared": shared,
             "occupancy": 1.0 - free / total,
             "logical_occupancy": logical / total,
-            "pool_bytes": per_slot * kv.num_slots}
+            "pool_bytes": sum(pool.size * pool.dtype.itemsize
+                              for pool in kv.pools)}
 
 
 def build_block_copy_fn(block_size: int):
-    """Jitted copy of one KV block (k and, where the pool has one, v) to a
-    fresh block — the copy-on-write seam for the prefix cache. ``src``/``dst`` are traced
-    int32 operands, so ONE compiled program serves every block pair; the
-    pool is donated (the copy is an in-place update as far as the caller
-    is concerned)."""
+    """Jitted copy of one KV block (k and, where the pool has one, v; its
+    indexer keys where there are any) to a fresh block — the copy-on-write
+    seam for the prefix cache. ``src``/``dst`` are traced int32 operands, so
+    ONE compiled program serves every block pair; the pool is donated (the
+    copy is an in-place update as far as the caller is concerned)."""
 
     def _copy_pool(pool, src, dst):
         rest = (0,) * (pool.ndim - 2)
@@ -219,7 +254,7 @@ def build_block_copy_fn(block_size: int):
             pool, block, (0, dst * block_size, *rest))
 
     def _copy(kv: BlockedKV, src, dst) -> BlockedKV:
-        return kv._replace(**{name: _copy_pool(pool, src, dst) for name, pool
-                              in zip(("k", "v"), kv.pools)})
+        return kv.with_pools([_copy_pool(pool, src, dst)
+                              for pool in kv.pools])
 
     return jax.jit(_copy, donate_argnums=0)

@@ -329,9 +329,10 @@ def _hc_block(cfg, p, x, attn_fn, live, experts=None):
 
 
 def _paged_attention(q, k_cache, v_cache, token_seq, token_pos, block_tables,
-                     block_size: int, alibi=None, window=None):
+                     block_size: int, alibi=None, window=None, sel=None):
     """q: [T, H, D]; caches: [num_slots, KVH, D] (flat slot axis);
-    block_tables: [S, Bps]. Returns [T, H, D].
+    block_tables: [S, Bps]. Returns [T, H, D]. ``sel`` [T, max_ctx]: a
+    sparse-attention indexer's selection, nonzero where the row attends.
 
     Each token's query attends to its sequence's KV at positions <= its own.
     Per-sequence KV is materialized by resolving the block table to flat slot
@@ -367,6 +368,8 @@ def _paged_attention(q, k_cache, v_cache, token_seq, token_pos, block_tables,
     if window is not None:
         mask = jnp.logical_and(
             mask, (token_pos[:, None] - j[None, :] < window)[:, None, :])
+    if sel is not None:
+        mask = jnp.logical_and(mask, (sel != 0)[:, None, :])
     logits = jnp.where(mask, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("thc,tchd->thd", probs, v_tok.astype(jnp.float32))
@@ -555,12 +558,27 @@ def _pool_write(pools, layer, dest, rows):
     ``dest`` [n] (out-of-range = dropped). A scatter on the WHOLE
     loop-carried pool: XLA updates it in place, where a per-layer slice as
     the scan's xs/ys cost a slice, a copy and a write-back of the layer
-    (157 MB at phi-2's pool) for 32 rows."""
+    (157 MB at phi-2's pool) for 32 rows. A pool beyond ``rows`` (an
+    indexer's keys: :func:`_index_write` writes them) passes through."""
     with jax.named_scope("kv_pool_write"):
         return tuple(
             pool.at[layer, dest].set(
                 _lane_pad(new, pool.shape[-1]).astype(pool.dtype), mode="drop")
-            for pool, new in zip(pools, rows))
+            for pool, new in zip(pools, rows)) + tuple(pools[len(rows):])
+
+
+def _index_write(p_attn, y, cfg, positions, pools, layer, dest, mates):
+    """A sparse-attention indexer's rows of the normed tokens y
+    (``dsa.index_rows``), its keys written into the pool's third array at
+    the slots their K and V went to (``mates``: ``dsa.pair_mates`` of the
+    batch). -> (pools, (qI, w))."""
+    from .dsa import index_pool_write, index_rows
+
+    with jax.named_scope("dsa_index"):
+        q_i, k_i, w = index_rows(p_attn, y, cfg, positions)
+        pools = (*pools[:2],
+                 index_pool_write(pools[2], layer, dest, k_i, mates))
+    return pools, (q_i, w)
 
 
 def _attn_views(cfg, pools):
@@ -636,8 +654,8 @@ def _scan_layers(layer, x, kv: BlockedKV, params, cfg):
 
     (x, pools), rows = jax.lax.scan(
         body, carry, (layers, jnp.arange(first, kv.k.shape[0])))
-    return x, kv._replace(moe=_count_moe(kv.moe, rows, cfg, x.shape[-2]),
-                          **dict(zip(("k", "v"), pools)))
+    return x, kv.with_pools(pools)._replace(
+        moe=_count_moe(kv.moe, rows, cfg, x.shape[-2]))
 
 
 def exit_choice(gate, h, threshold: float):
@@ -692,8 +710,8 @@ def _scan_passes(layer, x, kv: BlockedKV, params, cfg, pick, live):
         counted = jnp.sum(
             (chosen[:, None] == jnp.arange(u_steps)) & live[:, None],
             axis=0, dtype=jnp.int32)
-    return h_exit, kv._replace(exit_pass=kv.exit_pass + counted,
-                               **dict(zip(("k", "v"), pools)))
+    return h_exit, kv.with_pools(pools)._replace(
+        exit_pass=kv.exit_pass + counted)
 
 
 def _count_moe(moe, rows, cfg, tokens: int):
@@ -814,8 +832,8 @@ def _walk_pattern(cfg, params, x, kv: BlockedKV, attend, ssm_step, live):
     x, pools, state = carry
     moe = _count_moe(kv.moe, jnp.concatenate(routed) if routed else None,
                      cfg, x.shape[-2])
-    return x, kv._replace(moe=moe, **dict(zip(("k", "v"), pools)),
-                          **dict(zip(("ssm", "conv"), state)))
+    return x, kv.with_pools(pools)._replace(
+        moe=moe, **dict(zip(("ssm", "conv"), state)))
 
 
 def _tokens_in(tokens, sampled, take_from):
@@ -865,6 +883,10 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
 
     x = _embed(params, _tokens_in(tokens, sampled, take_from), token_pos,
                cfg)
+    if cfg.index_topk:    # which rows share a row of the indexer's pool
+        from .dsa import pair_mates
+
+        mates = pair_mates(token_seq, token_pos, ~pad)
 
     def attend(p_attn, y, pools, l):
         """Layer ``l``'s attention over the normed rows y: the new rows into
@@ -890,6 +912,13 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
             atom_qlen=atom_qlen, atom_tables=atom_tables,
             atom_inv=atom_inv, dec_row=dec_row, dec_len=dec_len,
             v_dim=v_dim)
+        if cfg.index_topk:    # attention over the indexer's selection
+            from .dsa import ragged_attend
+
+            pools, idx_rows = _index_write(p_attn, y, cfg, token_pos, pools,
+                                           l, dest, mates)
+            return ragged_attend(q, *idx_rows, pools, l, ctx, cfg,
+                                 spec.name)[..., :keep], pools
         return spec.fn(q, ctx)[..., :keep], pools
 
     def layer(carry, p, l, experts):
@@ -997,6 +1026,15 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
         pools = _pool_write(pools, l, dest, new)
         q = _lane_pad(q, pools[0].shape[-1], is_q=True)
         k_cache, v_cache, v_dim, keep = _attn_views(cfg, pools)
+        if cfg.index_topk:    # attention over the indexer's selection
+            from .dsa import decode_attend
+
+            # (no mates: the token beside a row's is never a row here)
+            pools, idx_rows = _index_write(
+                p_attn, y, cfg, positions, pools, l, dest,
+                jnp.full((s,), -1, jnp.int32))
+            return decode_attend(q, *idx_rows, pools, l, block_tables,
+                                 seq_lens, bs, cfg)[..., :keep], pools
         return spec.fn(q, DecodeAttnContext(
             k_cache=k_cache, v_cache=v_cache, layer=l,
             block_tables=block_tables, seq_lens=seq_lens, block_size=bs,

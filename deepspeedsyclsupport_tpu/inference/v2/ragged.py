@@ -400,6 +400,25 @@ def attention_work(descs: Sequence[SequenceDescriptor],
             sum(hi for hi, _step in tiles))
 
 
+def selection_work(descs: Sequence[SequenceDescriptor],
+                   lengths: Sequence[int], topk: int) -> Tuple[int, int]:
+    """:func:`attention_work`'s two counts under a sparse-attention indexer
+    that keeps the ``topk`` best cached tokens a row
+    (``ModelConfig.index_topk``), from the chunks alone: ``sel_pairs``, the
+    (row, SELECTED token) pairs of the chunks of two tokens or more (the row
+    at position p attends ``min(p + 1, topk)``), and ``dec_sel_tokens``, the
+    same over the one-token chunks. Beside ``attn_pairs`` and
+    ``dec_ctx_tokens`` they say what share of its context the attention
+    reads."""
+    def kept(d, n):
+        full = max(0, min(d.n_cached + n, topk) - d.n_cached)   # rows < topk
+        return (n - full) * topk + full * d.n_cached + full * (full + 1) // 2
+
+    return (sum(kept(d, n) for d, n in zip(descs, lengths) if n > 1),
+            sum(min(d.n_cached + 1, topk)
+                for d, n in zip(descs, lengths) if n == 1))
+
+
 def build_ragged_batch(chunks: Sequence[Tuple[SequenceDescriptor, int]],
                        max_tokens: int, max_sequences: int,
                        blocks_per_seq: int,

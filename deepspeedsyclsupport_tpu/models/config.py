@@ -69,6 +69,20 @@ class ModelConfig:
     # ([q_dim] / [kv_dim], eps rms_norm_eps), before the head split and rotary
     # (OLMoE, HF modeling_olmoe OlmoeAttention.q_norm/k_norm)
     qk_norm: bool = False
+    # RMSNorm over each HEAD of q and of k ([head_dim], one learned scale
+    # for q's heads and one for k's, eps rms_norm_eps), after the head split
+    # and before rotary (the Keye / Qwen3 backbones)
+    qk_head_norm: bool = False
+    # A learned sparse-attention indexer (DeepSeek-V3.2's recipe, under the
+    # sizes KeyeVL2's ``sa_config`` publishes): index_heads small heads of
+    # index_head_dim score every cached token against the query, and
+    # attention reads the index_topk best of them only (all, while the
+    # context is no longer). 0 = none. The serving pool then caches a THIRD
+    # row a token and layer, the indexer's one key (inference/v2/kv_cache.py).
+    # Serving only (inference/v2/dsa.py).
+    index_topk: int = 0
+    index_heads: int = 0
+    index_head_dim: int = 0
 
     # The xing4_0 / DeepSeek-V3 family, under the names its config.json
     # publishes. Latent attention (MLA): kv_lora_rank > 0 turns it on; the
@@ -221,6 +235,20 @@ class ModelConfig:
                 f"experts {self.first_expert_held} to "
                 f"{self.first_expert_held + self.experts_held} held of "
                 f"{self.num_experts}")
+        if self.qk_norm and self.qk_head_norm:
+            raise ValueError("qk_norm (over the whole projection) and "
+                             "qk_head_norm (a head at a time): one of them")
+        if self.index_topk and (
+                not (self.index_heads and self.index_head_dim)
+                or self.kv_lora_rank or self.sliding_window
+                or self.pos_embed != "rope" or self.layer_pattern is not None
+                or self.total_ut_steps > 1 or self.hc_mult > 1
+                or self.attn_windows is not None):
+            raise ValueError(
+                "index_topk: the sparse-attention indexer needs index_heads "
+                "and index_head_dim, rotary positions and one uniform stack "
+                "of K-and-V attention (no latent attention, window, "
+                "layer_pattern, looped stack or hyper-connection streams)")
         if self.rope_scaling and self.rope_scaling.get("type") != "yarn":
             raise ValueError(f"unknown rope_scaling {self.rope_scaling!r}")
         if self.first_k_dense_replace and (
@@ -427,6 +455,11 @@ class ModelConfig:
                     + v * d * (1 if self.tie_embeddings else 2) + d)
         if self.qk_norm:
             attn += self.q_dim + self.kv_dim
+        if self.qk_head_norm:
+            attn += 2 * self.head_dim
+        if self.index_topk:    # w_qi, w_ki + its LayerNorm, w_w
+            hi, di = self.index_heads, self.index_head_dim
+            attn += d * (hi * di + di + hi) + 2 * di
         n_moe = self.num_moe_layers
         hc = 2 * (self.hc_mult * d * (2 + self.hc_mult) * self.hc_mult
                   + self.hc_mult * d) if self.hc_mult > 1 else 0
@@ -603,6 +636,36 @@ PRESETS = {
         num_layers=48, num_heads=16, num_kv_heads=16, head_dim=128,
         max_seq_len=65536, rms_norm_eps=1e-6, rope_theta=1000000.0,
         total_ut_steps=4, early_exit_threshold=1.0, sandwich_norm=True),
+    # Kwai-Keye/Keye-VL-2.0-30B-A3B (model_type KeyeVL2), the language
+    # model: 48 alike layers of GQA 32/4 x 128 with an RMSNorm per head on q
+    # and k, a sparse-attention indexer (16 heads of 64, the best 2048 cached
+    # tokens a query) and 128 SwiGLU experts of 768, top-8 by softmax
+    # renormalised, no shared expert; intermediate_size 6144 is the width of
+    # a dense MLP no layer has (mlp_only_layers []). The vision tower and
+    # the three position rows of M-RoPE are not here: text positions, whose
+    # three rows are equal, rotate as plain rotary. Serving only
+    # (inference/v2); a chip of an expert-parallel deployment overrides
+    # num_experts_held.
+    "keye-vl2-30b-a3b": _p(
+        vocab_size=151936, hidden_size=2048, intermediate_size=6144,
+        num_layers=48, num_heads=32, num_kv_heads=4, head_dim=128,
+        max_seq_len=262144, rms_norm_eps=1e-6, rope_theta=10000000.0,
+        qk_head_norm=True, index_topk=2048, index_heads=16,
+        index_head_dim=64,
+        num_experts=128, num_experts_per_tok=8, moe_intermediate_size=768,
+        norm_topk_prob=True,
+        # as deepseek-v2's: how much of a logit the seeded routed experts
+        # carry (there is no shared expert: at 1 the experts' write is the
+        # layer's whole MLP). By a sweep on the chip (PERF.md section 6, PR
+        # 45): the router's 128 noise logits leave 14 % of the positions
+        # with an 8th and 9th probability within bf16's rounding, and where
+        # that flips a HELD expert the row moves by that expert's whole
+        # write: at 1 the worst of 256 rows read 0.16-0.21 of the 0.1
+        # allowed, at 1/4 0.086, at 1/10 0.065 (the median row 0.036 at
+        # either: what is left is not the router's). The attention behind
+        # the selection is seeded by a rule of its own, not a knob:
+        # models/transformer.py:SELECTED_ATTN_WRITE
+        routed_write_share=0.1),
 }
 
 

@@ -95,6 +95,15 @@ def qk_norm(p: Params, q: jnp.ndarray, k: jnp.ndarray,
     scale each, BEFORE the split into heads and before rotary (HF
     ``modeling_olmoe``: ``q_norm(q_proj(x))``). Shared by the training block
     and the serving block's ``_qkv``."""
+    if cfg.qk_head_norm:
+        # one RMSNorm a HEAD, over its head_dim, one [head_dim] scale for
+        # q's heads and one for k's (``cfg.qk_head_norm``)
+        def per_head(t, scale):
+            heads = t.reshape(*t.shape[:-1], -1, cfg.head_dim)
+            return rms_norm(heads, scale, cfg.rms_norm_eps).reshape(t.shape)
+
+        return (per_head(q, p["q_norm"]["scale"]),
+                per_head(k, p["k_norm"]["scale"]))
     if not cfg.qk_norm:
         return q, k
     return (rms_norm(q, p["q_norm"]["scale"], cfg.rms_norm_eps),

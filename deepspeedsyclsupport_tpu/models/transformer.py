@@ -39,6 +39,19 @@ Params = Dict[str, Any]
 # by. A checkpoint overwrites all of it.
 POST_NORM_WRITE = (3.0, 0.5)
 POST_NORM_SPREAD = 2.0
+# How init_params SEEDS the output projection of an attention that reads a
+# SELECTION of its keys (ModelConfig.index_topk), as a share of the rule for
+# every other ``wo``. Over seeded values the softmax over the selected keys
+# is flat, so each of the ~1 % of a row's keys that bf16 moves across its
+# index_topk-th score carries a whole 1 / index_topk of the output, where a
+# trained indexer's marginal keys carry next to nothing: the seeded sublayer
+# is as discontinuous as a seeded router (routed_write_share is the same
+# rule for the experts behind one). By a sweep on the v5e against the
+# float32 reference (PERF.md section 6, PR 45): the worst of 256 rows at
+# 41 k of context read 0.099-0.116 logit-std at 1, 0.064 at 1/2, 0.055 at
+# 1/4, of which 0.052 moves with no share (the bf16 stream itself). A rule
+# of the draw, not an option: a checkpoint overwrites it.
+SELECTED_ATTN_WRITE = 0.5
 
 
 class KVCache(NamedTuple):
@@ -133,7 +146,8 @@ class CausalLM:
                 "wq": dense((d, q), next(ks)),
                 "wk": dense((d, kv), next(ks)),
                 "wv": dense((d, kv), next(ks)),
-                "wo": dense((q, d), next(ks), scale=down_scale(q)),
+                "wo": dense((q, d), next(ks), scale=down_scale(q) * (
+                    SELECTED_ATTN_WRITE if cfg.index_topk else 1.0)),
             }
             if cfg.qkv_bias:
                 attn.update(bq=jnp.zeros((q,), jnp.float32),
@@ -144,6 +158,21 @@ class CausalLM:
             if cfg.qk_norm:
                 attn.update(q_norm={"scale": jnp.ones((q,), jnp.float32)},
                             k_norm={"scale": jnp.ones((kv,), jnp.float32)})
+            if cfg.qk_head_norm:
+                hd = cfg.head_dim
+                attn.update(q_norm={"scale": jnp.ones((hd,), jnp.float32)},
+                            k_norm={"scale": jnp.ones((hd,), jnp.float32)})
+            if cfg.index_topk:
+                # the sparse-attention indexer: index_heads small query
+                # heads, ONE key a token (behind a LayerNorm) and a weight
+                # a head, all from the row q reads
+                hi, di = cfg.index_heads, cfg.index_head_dim
+                attn.update(
+                    w_qi=dense((d, hi * di), next(ks)),
+                    w_ki=dense((d, di), next(ks)),
+                    ki_norm={"scale": jnp.ones((di,), jnp.float32),
+                             "bias": jnp.zeros((di,), jnp.float32)},
+                    w_w=dense((d, hi), next(ks)))
             return attn
 
         def glu_params(ks, f) -> Params:
@@ -440,11 +469,12 @@ class CausalLM:
                 "(inference/v2/model.py): the chunked scan's backward is not "
                 "written")
         if cfg.kv_lora_rank or cfg.hc_mult > 1 or cfg.first_k_dense_replace \
-                or cfg.experts_held != cfg.num_experts \
+                or cfg.experts_held != cfg.num_experts or cfg.index_topk \
                 or cfg.topk_method == "group_limited_greedy":
             raise NotImplementedError(
                 "latent attention, hyper-connection streams, leading dense "
-                "layers, group-limited routing and a share of the experts "
+                "layers, group-limited routing, a share of the experts and "
+                "the sparse-attention indexer (index_topk) "
                 "run on the serving path only "
                 "(inference/v2/model.py): their training forward and "
                 "backward are not written")

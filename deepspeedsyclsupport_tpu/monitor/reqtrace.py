@@ -159,6 +159,14 @@ MOE_STATIC_FIELDS = ("moe_tile_rows", "moe_rows_a_token")
 #: since the engine was built (at the published threshold all in the last).
 #: Every other model's record has none of the three.
 LOOP_FIELDS = ("passes", "kv_rows", "exit_pass")
+#: What the record of a model with a sparse-attention indexer
+#: (``ModelConfig.index_topk``) says besides, counted on the host before the
+#: launch (``ragged.selection_work``): ``sel_pairs``, the (row, SELECTED
+#: token) pairs of the chunks of two tokens or more, and ``dec_sel_tokens``,
+#: the selected tokens of the one-token chunks: what the attention reads of
+#: ``attn_pairs`` and ``dec_ctx_tokens``. Every other model's record has
+#: neither.
+DSA_FIELDS = ("sel_pairs", "dec_sel_tokens")
 
 #: what a phase is where nothing times the round: ``trace_stages`` off, or
 #: an engine driven without a session
@@ -560,7 +568,7 @@ def round_phases(streams: Iterable[Tuple[str, str, Sequence[Dict[str, Any]]]]
 
     # a field only some programs write (moe_rows) is reported where written
     fields = FORWARD_FIELDS + tuple(
-        f for f in MOE_TAIL_FIELDS + MOE_STATIC_FIELDS
+        f for f in MOE_TAIL_FIELDS + MOE_STATIC_FIELDS + DSA_FIELDS
         if f not in FORWARD_FIELDS and any(f in d for d in rounds))
     by_program: Dict[str, List[Dict[str, Any]]] = {}
     for d in rounds:

@@ -140,7 +140,8 @@ def paged_decode_attention(q, k_cache, v_cache, block_tables, seq_lens, *,
 def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
                     q_ref, k_hbm, *refs,
                     block_size: int, max_blocks: int, group: int,
-                    use_alibi: bool, window, v_dim=None, pages: int = 1):
+                    use_alibi: bool, window, v_dim=None, pages: int = 1,
+                    masked: bool = False):
     """One program per ATOM: a ≤block_q-token slice of ONE sequence's packed
     prefill chunk — or, at ``BQ = 1`` (the decode entry), one sequence's
     newest token; the serving forwards never put a one-token chunk into a
@@ -156,11 +157,20 @@ def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
     scratch and the DMA semaphores; a latent pool (``v_dim``: V is the
     leading lanes of the K tile) has neither V nor its scratch. A second
     grid axis, where the wrapper made one, tiles the heads of the ONE kv
-    head: the body sees its tile's heads only and needs no index of it."""
+    head: the body sees its tile's heads only and needs no index of it.
+    ``masked`` (a K-and-V pool under a sparse-attention indexer): after V
+    the SELECTION ``[A, BQ, keys]`` int8 in HBM and, after V's, its scratch;
+    a step's ``[BQ, step keys]`` of it rides the step's DMAs and a pair
+    counts only where it is nonzero."""
     latent = v_dim is not None
+    sel_hbm = sel_vmem = None
     if latent:
         ab_ref, out_ref, k_vmem, sem = refs
         mxu = k_vmem.dtype
+    elif masked:
+        (v_hbm, sel_hbm, ab_ref, out_ref, k_vmem, v_vmem, sel_vmem,
+         sem) = refs
+        mxu = jnp.float32
     else:
         v_hbm, ab_ref, out_ref, k_vmem, v_vmem, sem = refs
         mxu = jnp.float32
@@ -218,6 +228,11 @@ def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
                 cps.append(pltpu.make_async_copy(
                     hbm.at[layer, pl.ds(blk * block_size, block_size)],
                     dst, sem.at[slot, n]))
+        if masked:       # the step's columns of the atom's selection
+            cps.append(pltpu.make_async_copy(
+                sel_hbm.at[a, :, pl.ds(pl.multiple_of(
+                    step * step_keys, step_keys), step_keys)],
+                sel_vmem.at[slot], sem.at[slot, len(pools)]))
         return cps
 
     # guard on lo_step (not just kv_hi > 0): with a sliding window and pos0
@@ -275,6 +290,13 @@ def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
                                 jnp.logical_and(row < qlen, active))
         if window is not None:
             valid = jnp.logical_and(valid, (pos0 + row) - pos < window)
+        if masked:
+            # [BQ, keys] -> a row's G lanes alike -> the scores' [BQ·G, keys]
+            chosen = sel_vmem[cur].astype(jnp.float32)
+            chosen = jnp.broadcast_to(
+                chosen[:, None, :], (bq, g, step_keys)).reshape(
+                    bq * g, step_keys)
+            valid = jnp.logical_and(valid, chosen[None] > 0.0)
         scores = jnp.where(valid, scores, NEG_INF)
 
         m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
@@ -381,12 +403,22 @@ def _kv_pages_per_step(bq: int, ht: int, kvh: int, d: int, block_size: int,
     return pages
 
 
+def _masked_pages_per_step(block_size: int) -> int:
+    """KV blocks a step takes under a sparse-attention indexer's selection
+    (a K-and-V pool): the step's slice of the selection is cut along the
+    LANES of its [BQ, keys] rows, whole lane tiles, so 128 keys a step."""
+    return max(1, min(_MAX_STEP_PAGES, 128 // block_size))
+
+
 def kv_step_keys(bq: int, h: int, kvh: int, d: int, block_size: int,
-                 itemsize: int, latent: bool) -> int:
+                 itemsize: int, latent: bool, masked: bool = False) -> int:
     """Keys one loop step of the kernel covers for a tile of ``bq`` rows
     under ``h`` query heads, as the wrapper below decides it: the head tile
-    first, then the blocks a step. What the engine counts a forward's steps
-    by (``ragged.attention_work``)."""
+    first, then the blocks a step (``masked``: under an indexer's
+    selection). What the engine counts a forward's steps by
+    (``ragged.attention_work``)."""
+    if masked:
+        return block_size * _masked_pages_per_step(block_size)
     ht = _head_tile(bq, h, kvh, d, block_size, itemsize)
     return block_size * _kv_pages_per_step(bq, ht, kvh, d, block_size,
                                            itemsize, latent)
@@ -439,7 +471,7 @@ def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
                                     block_size: int, layer=0, alibi=None,
                                     window=None, v_dim=None,
                                     interpret: bool = False,
-                                    name: str = "ragged_prefill"):
+                                    name: str = "ragged_prefill", sel=None):
     """q_atoms: [A, BQ, H, D] (one sequence per atom row block);
     k/v_cache: the whole pool [L, num_slots, KVH, D], read at ``layer``
     (a traced scalar inside the serving forwards' layer loop: the pool
@@ -450,9 +482,15 @@ def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
     sliding-window bound. ``name`` is what a profile calls the kernel: its
     custom call's instruction and scope (the decode entry passes its own).
     ``v_cache=None, v_dim=n``: a latent pool, V the leading ``n`` lanes of
-    K's rows (the module's docstring). Returns [A, BQ, H, D] ([.., n])."""
+    K's rows (the module's docstring). ``sel`` [A, BQ, keys] int8 (a
+    K-and-V pool only): a sparse-attention indexer's selection, nonzero
+    where the atom's row attends to the position; the kernel then walks
+    steps of 128 keys and a profile calls it ``dsa_prefill``. Returns
+    [A, BQ, H, D] ([.., n])."""
     a, bq, h, d = q_atoms.shape
     latent = v_cache is None
+    if sel is not None and latent:
+        raise ValueError("a selection over a latent pool is not written")
     if latent and not v_dim:
         raise ValueError("a pool without V needs v_dim, the lanes of K's "
                          "rows that are the value")
@@ -467,6 +505,14 @@ def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
     g = ht // kvh
     # KV blocks a loop step takes: chosen AFTER the tile, from what it left
     pages = _kv_pages_per_step(bq, ht, kvh, d, block_size, itemsize, latent)
+    if sel is not None:
+        pages = _masked_pages_per_step(block_size)
+        # whole steps of columns: the last step's DMA reads its full width
+        step = pages * block_size
+        keys = -(-atom_tables.shape[1] * block_size // step) * step
+        sel = jnp.pad(sel[..., :keys].astype(jnp.int8),
+                      ((0, 0), (0, 0), (0, max(0, keys - sel.shape[-1]))))
+        name = "dsa_prefill" if name == "ragged_prefill" else name
     if alibi is not None:
         # per-lane slope layout matches the kernel's [KVH, BQ·G] score rows:
         # lane (r·G + gi) of kv head kh carries q head kh·G + gi (under one
@@ -480,7 +526,7 @@ def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
     return _tiled_call(
         jnp.asarray(atom_tables, jnp.int32), jnp.asarray(atom_pos0, jnp.int32),
         jnp.asarray(atom_qlen, jnp.int32),
-        jnp.asarray(layer, jnp.int32).reshape(1), q_atoms, pools, ab,
+        jnp.asarray(layer, jnp.int32).reshape(1), q_atoms, pools, ab, sel,
         block_size=block_size, ht=ht, pages=pages,
         use_alibi=alibi is not None,
         window=None if window is None else int(window),
@@ -494,7 +540,7 @@ def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
     "block_size", "ht", "pages", "use_alibi", "window", "v_dim", "vmem_limit",
     "interpret", "name"))
 def _tiled_call(atom_tables, atom_pos0, atom_qlen, layer, q_atoms, pools, ab,
-                *, block_size, ht, pages, use_alibi, window, v_dim,
+                sel=None, *, block_size, ht, pages, use_alibi, window, v_dim,
                 vmem_limit, interpret, name):
     """The ``pallas_call`` of :func:`ragged_prefill_attention_pallas`, every
     choice made (``ht`` heads a grid step, ``pages`` KV blocks a loop step).
@@ -511,6 +557,7 @@ def _tiled_call(atom_tables, atom_pos0, atom_qlen, layer, q_atoms, pools, ab,
     d_out = v_dim if latent else d
     tiles = h // ht
     g = ht // kvh
+    masks = () if sel is None else (sel,)     # in HBM, after the pools
 
     def tile_of(grid_idx):      # (atom[, head tile]) of a grid step
         return grid_idx[0], (grid_idx[1] if tiles > 1 else 0)
@@ -525,7 +572,7 @@ def _tiled_call(atom_tables, atom_pos0, atom_qlen, layer, q_atoms, pools, ab,
         in_specs=[
             pl.BlockSpec((1, bq, ht, d), qo_map, memory_space=pltpu.VMEM),
             # K (and V) stay in HBM
-            *(pl.BlockSpec(memory_space=pl.ANY) for _ in pools),
+            *(pl.BlockSpec(memory_space=pl.ANY) for _ in pools + masks),
             pl.BlockSpec((kvh, bq * g, 1),
                          lambda *idx: (tile_of(idx)[1], 0, 0),
                          memory_space=pltpu.VMEM),  # slopes per lane
@@ -535,13 +582,15 @@ def _tiled_call(atom_tables, atom_pos0, atom_qlen, layer, q_atoms, pools, ab,
         scratch_shapes=[
             *(pltpu.VMEM((2, pages * block_size, *row), pool.dtype)
               for pool in pools),
-            pltpu.SemaphoreType.DMA((2, len(pools))),
+            *(pltpu.VMEM((2, bq, pages * block_size), m.dtype)
+              for m in masks),
+            pltpu.SemaphoreType.DMA((2, len(pools) + len(masks))),
         ],
     )
     kernel = functools.partial(_prefill_kernel, block_size=block_size,
                                max_blocks=atom_tables.shape[1], group=g,
                                use_alibi=use_alibi, window=window,
-                               v_dim=v_dim, pages=pages)
+                               v_dim=v_dim, pages=pages, masked=bool(masks))
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((a, bq, h, d_out), q_atoms.dtype),
@@ -549,13 +598,13 @@ def _tiled_call(atom_tables, atom_pos0, atom_qlen, layer, q_atoms, pools, ab,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
         name=name,
-    )(atom_tables, atom_pos0, atom_qlen, layer, q_atoms, *pools, ab)
+    )(atom_tables, atom_pos0, atom_qlen, layer, q_atoms, *pools, *masks, ab)
 
 
 def ragged_prefill_attention_reference(q_atoms, k_cache, v_cache, atom_tables,
                                        atom_pos0, atom_qlen, *,
                                        block_size: int, layer=0, alibi=None,
-                                       window=None, v_dim=None):
+                                       window=None, v_dim=None, sel=None):
     """Exact jnp oracle for the prefill kernel (parity tests + off-TPU).
     Given the whole pool it slices ``pool[layer]`` here, at its seam; given
     no V it takes the leading ``v_dim`` lanes of K's rows."""
@@ -590,6 +639,8 @@ def ragged_prefill_attention_reference(q_atoms, k_cache, v_cache, atom_tables,
         r < atom_qlen[:, None, None, None])
     if window is not None:
         mask = jnp.logical_and(mask, q_pos - j[None, None, None, :] < window)
+    if sel is not None:
+        mask = jnp.logical_and(mask, (sel[:, None, :, :max_ctx] != 0))
     logits = jnp.where(mask, logits, NEG_INF)
     p = jax.nn.softmax(logits, axis=-1)
     p = jnp.where(mask.any(-1, keepdims=True), p, 0.0)  # dead rows → 0
@@ -600,10 +651,10 @@ def ragged_prefill_attention_reference(q_atoms, k_cache, v_cache, atom_tables,
 def ragged_prefill_attention(q_atoms, k_cache, v_cache, atom_tables,
                              atom_pos0, atom_qlen, *, block_size: int,
                              impl: str = "auto", layer=0, alibi=None,
-                             window=None, v_dim=None):
+                             window=None, v_dim=None, sel=None):
     impl = _resolve_impl(impl, "ragged prefill")
     kw = dict(block_size=block_size, layer=layer, alibi=alibi, window=window,
-              v_dim=v_dim)
+              v_dim=v_dim, sel=sel)
     if impl == "xla":
         return ragged_prefill_attention_reference(
             q_atoms, k_cache, v_cache, atom_tables, atom_pos0, atom_qlen,

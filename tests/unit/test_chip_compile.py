@@ -482,3 +482,77 @@ def test_the_looped_forwards_hold_the_pool_once(one_chip, program):
     assert 'custom_call_target="tpu_custom_call"' in text
     under = scopes.instructions_under(text, ("loop_pass", "loop_exit"))
     assert {"loop_pass", "loop_exit"} == set(under.values())
+
+
+# ------------------------------------------- sparse attention behind an indexer
+@pytest.mark.parametrize("program", ["decode_forward", "ragged_forward"])
+def test_the_indexer_forwards_compile_and_copy_no_pool(one_chip, program,
+                                                       monkeypatch):
+    """Both serving forwards of ``keye-vl2-30b-a3b`` at the cell's widths
+    and shapes (two layers of the twelve; 8 sequences, 768 rows, contexts to
+    49,152, the whole pool of 6,272 blocks a layer): the three kernels of
+    the selection path are custom calls under their own names
+    (``dsa_index_scores``, ``dsa_select``, the ragged kernel under a mask as
+    ``dsa_prefill``), the scopes reach the compiled text, all three pools
+    are aliased to the result, and no pool is copied: the indexer's, two
+    slots a row, is written and gathered in the layout it is carried in (a
+    64-wide row of its own was stored slot-minor and copied whole every
+    layer: 48 ms a forward on the v5e, PERF.md section 6, PR 45)."""
+    from benchmark import scopes
+    from deepspeedsyclsupport_tpu.inference.v2 import model as M
+    from deepspeedsyclsupport_tpu.inference.v2.kv_cache import (BlockedKV,
+                                                                MoeCounters)
+    from deepspeedsyclsupport_tpu.models import build_model
+    from deepspeedsyclsupport_tpu.ops import grouped_gemm as gg
+
+    monkeypatch.setattr(gg, "default_impl", lambda: "pallas")
+    layers = 2
+    model = build_model("keye-vl2-30b-a3b", num_layers=layers,
+                        num_experts_held=16, vocab_size=18992,
+                        dtype="bfloat16")
+    cfg = model.config
+    bs, blocks, seqs, toks, bps, atom = 64, 6272, 8, 768, 768, 128
+
+    def on_chip(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: on_chip(x.shape, jnp.bfloat16 if jnp.issubdtype(
+            x.dtype, jnp.floating) else x.dtype),
+        jax.eval_shape(model.init_params))
+    pool = on_chip((layers, blocks * bs, cfg.num_kv_heads, 128), jnp.bfloat16)
+    idx = on_chip((layers, blocks * bs // 2, 2 * cfg.index_head_dim),
+                  jnp.bfloat16)
+    zero = on_chip(())
+    kv = BlockedKV(pool, pool, MoeCounters(on_chip((layers, 128)), zero,
+                                           zero, zero), idx=idx)
+    sampled = on_chip((seqs + 3,))
+    if program == "decode_forward":
+        fn = M.build_decode_forward_fn(model, bs, "pallas")
+        args = (on_chip((seqs,)), on_chip((seqs,)), on_chip((seqs, bps)),
+                on_chip((seqs,), jnp.bool_), sampled, on_chip((seqs,)))
+        kernels = set()
+    else:
+        fn = M.build_ragged_forward_fn(model, bs, "kernel")
+        atoms = seqs + toks // atom + 1
+        args = (on_chip((toks,)), on_chip((toks,)), on_chip((toks,)),
+                on_chip((seqs, bps)), on_chip((seqs,)),
+                on_chip((atoms, atom)), on_chip((atoms,)), on_chip((atoms,)),
+                on_chip((atoms, bps)), on_chip((toks,)), on_chip((seqs,)),
+                on_chip((seqs,)), sampled, on_chip((toks,)))
+        kernels = {"dsa_index_scores", "dsa_select", "dsa_prefill"}
+    compiled = fn.lower(params, kv, *args).compile()
+    text = compiled.as_text()
+    calls = {ln.split(" = ")[0].strip().lstrip("%").split(".")[0]
+             for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln}
+    assert kernels <= calls and "ragged_prefill" not in calls
+    under = scopes.instructions_under(
+        text, ("dsa_index", "dsa_select", "dsa_attend", "dsa_rows"))
+    assert set(under.values()) >= {"dsa_index", "dsa_select", "dsa_rows"}
+    m = compiled.memory_analysis()
+    pools = (2 * pool.size + idx.size) * 2
+    assert m.alias_size_in_bytes >= pools
+    assert m.temp_size_in_bytes < 0.5 * 2**30      # a pool's layer is 0.4
+    assert not [ln for ln in text.splitlines()
+                if " copy(" in ln and f"bf16[{layers},{blocks * bs // 2}," in ln]

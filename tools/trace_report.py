@@ -650,6 +650,8 @@ def requests_report(root: str, worst_n: int = 5, window_s: float = 60.0,
         # a forward whose grouped GEMMs counted their row tiles: how full
         # they were, and the tiles an expert's one read of weights served
         tiled = any(m.get("moe_tiles") for m in rp["programs"].values())
+        # a sparse-attention indexer: the pairs and one-row tokens SELECTED
+        dsa = any("sel_pairs" in m for m in rp["programs"].values())
 
         def tile_cols(m):
             tiles = m.get("moe_tiles", 0)
@@ -669,7 +671,8 @@ def requests_report(root: str, worst_n: int = 5, window_s: float = 60.0,
                      f"{'step-keys':>11}{'tile-keys':>11}{'rows':>8}"
                      + (f"{'exp-rows':>10}" if share else "")
                      + (f"{'tile-rows':>11}{'tile-fill':>11}{'tiles/expert':>14}"
-                        if tiled else ""))
+                        if tiled else "")
+                     + (f"{'sel-pairs':>12}{'1-row-sel':>11}" if dsa else ""))
         for name, m in sorted(rp["programs"].items()):
             lines.append(f"    {name:<20}{m['rounds']:>7}{m['n_seqs']:>8.1f}"
                          f"{m['tokens']:>9.1f}{m['prefill_tokens']:>9.1f}"
@@ -685,7 +688,10 @@ def requests_report(root: str, worst_n: int = 5, window_s: float = 60.0,
                          f"{m.get('kv_tile_keys', 0):>11.1f}"
                          f"{m.get('rows', 0):>8.1f}"
                          + (f"{m.get('moe_rows', 0):>10.1f}" if share
-                            else "") + (tile_cols(m) if tiled else ""))
+                            else "") + (tile_cols(m) if tiled else "")
+                         + (f"{m.get('sel_pairs', 0):>12.1f}"
+                            f"{m.get('dec_sel_tokens', 0):>11.1f}"
+                            if dsa else ""))
     if att["cached_prefix_tokens_mean"]:
         lines.append(f"  cached prefix: "
                      f"{att['cached_prefix_tokens_mean']:.1f} token(s)/request "
