@@ -273,7 +273,7 @@ class InferenceEngineV2:
             cfg.atom_q_size = default_atom_rows(cfg.atom_q_size, *shape)
         # keys a loop step of the kernel covers under an atom and under a
         # one-row tile (attention_work's kv_step_keys)
-        # (an indexer's selection rides the atoms' steps: 128 keys each)
+        # (an indexer's selection rides the atoms' steps: whole 128-key tiles)
         self._kv_step_keys = tuple(
             kv_step_keys(rows, *shape, latent,
                          bool(model.config.index_topk) and rows > 1)

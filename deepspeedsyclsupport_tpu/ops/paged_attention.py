@@ -31,8 +31,8 @@ Kernel shape (TPU-first, not a CUDA translation):
   ONE ``q k^T``, one mask / max / exp / sum, one ``p v`` and one rescale of
   the accumulator, so VMEM holds O(step · D) regardless of context length,
   and compute overlaps the next step's fetch via the DMA queue. A step is
-  ONE block on a K-and-V pool and several on a latent pool, by the tile's
-  shape (:func:`_kv_pages_per_step`).
+  ONE block under a K-and-V pool's one-row tile and several under every
+  other tile, by the tile's shape (:func:`_kv_pages_per_step`).
 * GQA: queries reshape to [KVH, G, D] and each kv head batch-matmuls its
   group — grouped heads share the streamed KV block, the reason GQA decode is
   bandwidth-cheap on TPU.
@@ -50,7 +50,22 @@ one short DMA), so its step takes several: on the v5e a 2,048-row tile pays
 5.5 us a block at one a step and 2.4 at four (the rescale of a 4 MiB
 accumulator and the [rows, 1] columns of the softmax are paid a step, not a
 block), a one-row tile 0.47-0.54 against 0.17-0.18 at eight (PERF.md, PR
-38). Decided at trace time: a K-and-V pool's program is what it was.
+38). Decided at trace time.
+
+K-and-V pools walk ``tile_step`` at either tile height: the mask made once
+a step for ONE kv head's rows and added to all as a float32 bias; q, K, V
+and ``p`` fed to the MXU in the pool's dtype (at the default precision
+Mosaic rounds a float32 operand to bf16 anyway: on the v5e a float32 product
+equals the product of the bf16-rounded operands to 7e-6, and the same step
+in either dtype is bit for bit the same result and the same time). A tile
+of several rows (a prompt's atoms) takes 128-512 keys a step by the rule
+the latent pool has, a one-row tile one block. 32 heads over 4 kv heads
+under a selection at 32 k: 2.94 us a 64-key block of a 128-row atom at 128
+keys a step in the body PR 45 left, 1.71 at 512, 1.62 here; taking the kv
+heads one at a time in a loop (so that only one head's scores stand in
+VMEM) read 2.39, and reading a head's K and V from the scratch by a
+sublane-strided slice 4.49; the one-row tile reads 2-7 % under the float32
+step it had at 8 to 32 kv heads and 4 % over at 2 (PERF.md, PR 46).
 
 An exact jnp reference (:func:`paged_decode_attention_reference`) serves
 off-TPU fallback and the kernel-vs-reference parity tests (the pattern the
@@ -138,10 +153,36 @@ def paged_decode_attention(q, k_cache, v_cache, block_tables, seq_lens, *,
 
 # ===================================================================== prefill
 def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
-                    q_ref, k_hbm, *refs,
-                    block_size: int, max_blocks: int, group: int,
-                    use_alibi: bool, window, v_dim=None, pages: int = 1,
-                    masked: bool = False):
+                    q_ref, k_hbm, *refs, v_dim=None, masked: bool = False,
+                    **choices):
+    """:func:`_attend_tile` for a grid step whose tile is live. A K-and-V
+    pool's DEAD tile (no row: the places of a forward's static grid that
+    the batch left empty, 33 of 39 atoms in a short mixed round) writes its
+    zeros and does nothing else: no q turned head-major, no accumulator, no
+    division (on the v5e, 39 atoms of 32 heads of which 4 live at 300 keys:
+    0.47 ms a call, 0.82 when the dead walked the body; PERF.md, PR 46). A
+    latent pool's program is the accepted one: every tile walks the body,
+    a dead one through a loop of zero steps."""
+    a = pl.program_id(0)
+    tile = functools.partial(
+        _attend_tile, a, block_tables_ref, pos0_ref, qlen_ref, layer_ref,
+        q_ref, k_hbm, *refs, v_dim=v_dim, masked=masked, **choices)
+    if v_dim is not None:
+        return tile()
+    out_ref = refs[3 if masked else 2]
+    live = qlen_ref[a] > 0
+    pl.when(live)(tile)
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        out_ref[0] = jnp.zeros(out_ref.shape[1:], out_ref.dtype)
+
+
+def _attend_tile(a, block_tables_ref, pos0_ref, qlen_ref, layer_ref,
+                 q_ref, k_hbm, *refs,
+                 block_size: int, max_blocks: int, group: int,
+                 use_alibi: bool, window, v_dim=None, pages: int = 1,
+                 masked: bool = False):
     """One program per ATOM: a ≤block_q-token slice of ONE sequence's packed
     prefill chunk — or, at ``BQ = 1`` (the decode entry), one sequence's
     newest token; the serving forwards never put a one-token chunk into a
@@ -160,21 +201,19 @@ def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
     head: the body sees its tile's heads only and needs no index of it.
     ``masked`` (a K-and-V pool under a sparse-attention indexer): after V
     the SELECTION ``[A, BQ, keys]`` int8 in HBM and, after V's, its scratch;
-    a step's ``[BQ, step keys]`` of it rides the step's DMAs and a pair
-    counts only where it is nonzero."""
+    a step's ``[BQ, step keys]`` of it (whole 128-key lane tiles) rides the
+    step's DMAs and a pair counts only where it is nonzero."""
     latent = v_dim is not None
     sel_hbm = sel_vmem = None
     if latent:
         ab_ref, out_ref, k_vmem, sem = refs
-        mxu = k_vmem.dtype
     elif masked:
         (v_hbm, sel_hbm, ab_ref, out_ref, k_vmem, v_vmem, sel_vmem,
          sem) = refs
-        mxu = jnp.float32
     else:
         v_hbm, ab_ref, out_ref, k_vmem, v_vmem, sem = refs
-        mxu = jnp.float32
-    a = pl.program_id(0)
+    # the MXU is fed the pool's own dtype: no float32 copy of q, K or V
+    mxu = k_vmem.dtype
     pos0 = pos0_ref[a]
     qlen = qlen_ref[a]
     layer = layer_ref[0]   # which [num_slots, KVH, D] of the pool to read
@@ -189,10 +228,12 @@ def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
     else:
         lo_blk = jnp.int32(0)
     # the loop below walks STEPS of ``pages`` blocks: one block where the
-    # wrapper gave one (a K-and-V pool: the loop over blocks it always was)
+    # wrapper gave one (a K-and-V pool's one-row tile: the loop over blocks
+    # it always was)
     step_keys = pages * block_size
     lo_step = lo_blk if pages == 1 else lo_blk // pages
-    # a latent pool feeds the MXU its own dtype: no float32 copy of q
+    # a K-and-V pool's q is turned kv head-major as float32, where a kv
+    # head's group of 8 is whole vregs (a move, no shuffle)
     q = q_ref[0] if latent else q_ref[0].astype(jnp.float32)   # [BQ, H, D]
     bq, h, d = q.shape
     d_v = v_dim if latent else d
@@ -201,9 +242,11 @@ def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
     # [KVH, BQ·G, D]: kv head-major so each kv head batch-matmuls its group
     q_g = jnp.transpose(q.reshape(bq, kvh, g, d), (1, 0, 2, 3)) \
         .reshape(kvh, bq * g, d).astype(mxu)
-    # q row of each [BQ·G] lane (its position is pos0 + row)
-    row = jax.lax.broadcasted_iota(jnp.int32, (kvh, bq * g, step_keys),
-                                   1) // g
+    # q row of each [BQ·G] lane (its position is pos0 + row); a K-and-V
+    # pool's mask is one kv head's, which all share
+    row = jax.lax.broadcasted_iota(
+        jnp.int32, (kvh, bq * g, step_keys) if latent
+        else (bq * g, step_keys), 1 if latent else 0) // g
 
     def block_of(step, i):
         """Block ``i`` of ``step``, as an index into the table's row. A
@@ -244,34 +287,28 @@ def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
         for cp in copies(lo_step, jax.lax.rem(lo_step, 2)):
             cp.start()
 
-    def body(j, carry):
-        m, l, acc = carry
-        # always true inside the loop's bounds; a K-and-V pool's program
-        # keeps the selects on it that it was accepted with
-        active = j * step_keys < kv_hi
-        cur = jax.lax.rem(j, 2)
-
+    def start_next(j):
         @pl.when(jnp.logical_and((j + 1) * step_keys < kv_hi,
                                  j + 1 < -(-max_blocks // pages)))
         def _():
             for cp in copies(j + 1, jax.lax.rem(j + 1, 2)):
                 cp.start()
 
-        def wait():
-            for cp in copies(j, cur):
-                cp.wait()
-
-        if latent:                             # rows [keys, D], one kv head
-            wait()
-            k_t = k_vmem[cur][None]
-            v_t = k_t[..., :d_v]
-        else:
-            pl.when(active)(wait)
-            k = k_vmem[cur].astype(jnp.float32)    # [bs, KVH, D]
-            v = v_vmem[cur].astype(jnp.float32)
-            k_t = jnp.transpose(k, (1, 0, 2))      # [KVH, bs, D]
-            v_t = jnp.transpose(v, (1, 0, 2))
-        scores = jax.lax.dot_general(           # [KVH, BQ·G, keys]
+    def latent_step(j, carry):
+        """A step of a latent pool's tile: rows [keys, D] of ONE kv head,
+        the value their leading lanes."""
+        m, l, acc = carry
+        # read by nothing since the K-and-V step left this body (it was
+        # that step's ``active``); with it a latent pool's Mosaic text is
+        # the accepted one byte for byte
+        j * step_keys < kv_hi  # noqa: B018
+        cur = jax.lax.rem(j, 2)
+        start_next(j)
+        for cp in copies(j, cur):
+            cp.wait()
+        k_t = k_vmem[cur][None]
+        v_t = k_t[..., :d_v]
+        scores = jax.lax.dot_general(           # [1, BQ·H, keys]
             q_g, k_t, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) / np.sqrt(d)
         pos = j * step_keys + jax.lax.broadcasted_iota(
@@ -285,18 +322,9 @@ def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
             # one block a step never went: a context longer than its table
             # sees what the table holds, under either loop
             seen = jnp.minimum(seen, kv_hi - 1)
-        valid = jnp.logical_and(pos <= seen,
-                                row < qlen if latent else
-                                jnp.logical_and(row < qlen, active))
+        valid = jnp.logical_and(pos <= seen, row < qlen)
         if window is not None:
             valid = jnp.logical_and(valid, (pos0 + row) - pos < window)
-        if masked:
-            # [BQ, keys] -> a row's G lanes alike -> the scores' [BQ·G, keys]
-            chosen = sel_vmem[cur].astype(jnp.float32)
-            chosen = jnp.broadcast_to(
-                chosen[:, None, :], (bq, g, step_keys)).reshape(
-                    bq * g, step_keys)
-            valid = jnp.logical_and(valid, chosen[None] > 0.0)
         scores = jnp.where(valid, scores, NEG_INF)
 
         m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
@@ -306,11 +334,65 @@ def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
         pv = jax.lax.dot_general(
             p.astype(mxu), v_t, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)
-        acc_new = acc * alpha + pv
-        if latent:
-            return m_new, l_new, acc_new
-        return (jnp.where(active, m_new, m), jnp.where(active, l_new, l),
-                jnp.where(active, acc_new, acc))
+        return m_new, l_new, acc * alpha + pv
+
+    def heads_of(x):
+        """A slot's ``[keys, KVH, D]`` head-major, in the pool's dtype. Two
+        kv heads of 16 bits share a 32-bit sublane: they are taken apart by
+        widening (on the v5e a one-row tile under 2 kv heads reads 0.52 us a
+        block that way and 0.55 turned as stored; 4 to 32 kv heads read 2-13
+        % less turned as stored)."""
+        if kvh * x.dtype.itemsize < 8:
+            return jnp.transpose(x.astype(jnp.float32), (1, 0, 2)).astype(mxu)
+        return jnp.transpose(x, (1, 0, 2))
+
+    def tile_step(j, carry):
+        """A step of a K-and-V pool's tile. What does not depend on the kv
+        head is made once, for one head's ``[BQ·G, keys]``, and shared: the
+        step's mask as a float32 bias, 0 where the row attends to the key
+        and far under ``m``'s first value where not, so a masked pair's
+        ``exp`` is 0 with no second select and a row that has seen nothing
+        yet keeps ``l`` 0. The MXU takes q as it arrived,
+        K and V as stored (turned head-major, no float32 copy) and ``p`` in
+        the pool's dtype: the values a float32 operand is rounded to at the
+        default precision anyway."""
+        m, l, acc = carry
+        cur = jax.lax.rem(j, 2)
+        start_next(j)
+        for cp in copies(j, cur):
+            cp.wait()
+        pos = j * step_keys + jax.lax.broadcasted_iota(
+            jnp.int32, (bq * g, step_keys), 1)
+        q_pos = pos0 + row
+        # a context longer than its table sees what the table holds
+        valid = pos <= jnp.minimum(q_pos, kv_hi - 1)
+        if window is not None:
+            valid = jnp.logical_and(valid, q_pos - pos < window)
+        if masked:
+            # [BQ, keys] -> a row's G lanes alike -> the scores' [BQ·G, keys]
+            chosen = sel_vmem[cur].astype(jnp.float32)
+            chosen = jnp.broadcast_to(
+                chosen[:, None, :], (bq, g, step_keys)).reshape(
+                    bq * g, step_keys)
+            valid = jnp.logical_and(valid, chosen > 0.0)
+        bias = jnp.where(valid, 0.0, 2 * NEG_INF)
+        scores = jax.lax.dot_general(           # [KVH, BQ·G, keys]
+            q_g, heads_of(k_vmem[cur]),
+            (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * np.float32(1 / np.sqrt(d))
+        if use_alibi:
+            scores = scores + ab_ref[...].astype(
+                jnp.float32) * (pos - q_pos).astype(jnp.float32)
+        scores = scores + bias
+        m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        p = jnp.exp(scores - m_new)
+        pv = jax.lax.dot_general(
+            p.astype(mxu), heads_of(v_vmem[cur]),
+            (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
+        return (m_new, l * alpha + jnp.sum(p, axis=-1, keepdims=True),
+                acc * alpha + pv)
 
     m0 = jnp.full((kvh, bq * g, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((kvh, bq * g, 1), jnp.float32)
@@ -319,7 +401,14 @@ def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
     # A_max sized for the worst case, most grid programs of a typical batch
     # are dead and must not burn max_blocks MXU loops each
     n_steps = (kv_hi + step_keys - 1) // step_keys
-    m, l, acc = jax.lax.fori_loop(lo_step, n_steps, body, (m0, l0, acc0))
+    m, l, acc = jax.lax.fori_loop(lo_step, n_steps,
+                                  latent_step if latent else tile_step,
+                                  (m0, l0, acc0))
+    if not latent:
+        # tile_step masks a pair by its key, never by the row: what a dead
+        # row summed goes here
+        acc = jnp.where(jax.lax.broadcasted_iota(
+            jnp.int32, (kvh, bq * g, 1), 1) // g < qlen, acc, 0.0)
     out = acc / jnp.maximum(l, 1e-30)
     out = jnp.transpose(out.reshape(kvh, bq, g, d_v), (1, 0, 2, 3))
     out_ref[0] = out.reshape(bq, h, d_v).astype(out_ref.dtype)
@@ -371,9 +460,11 @@ def default_atom_rows(bq: int, h: int, kvh: int, d: int, block_size: int,
     return bq
 
 
-# a loop step's float32 scores [rows x heads, pages x block_size] may take
-# this much, and a step at most this many blocks (_kv_pages_per_step)
+# the float32 scores a loop step multiplies out at once (one kv head's rows x
+# pages x block_size) may take this much, its blocks of K and V this much in
+# the pool's dtype, and a step at most this many blocks (_kv_pages_per_step)
 _STEP_SCORE_BYTES = 2 << 20
+_STEP_KV_BYTES = 2 << 20
 _MAX_STEP_PAGES = 8
 
 
@@ -383,31 +474,44 @@ def _kv_pages_per_step(bq: int, ht: int, kvh: int, d: int, block_size: int,
     SHAPE of the tile :func:`_head_tile` and :func:`default_atom_rows` chose
     at one block a step: chosen after them, from the room they left.
 
-    A K-and-V pool: one. Its step moves 0.5-1 MiB, casts K and V to float32
-    and transposes them, and its model of VMEM is full at that.
+    A K-and-V pool's one-row tile: one. Its contexts are 2-40 blocks in
+    every cell that has one, and on a latent pool a one-row step too wide
+    for its context lost 12-14 % (PR 38): ROADMAP, Speed 1.
 
-    A latent pool's block is ``block_size`` rows of one head (80 KiB at 64 x
-    640 bf16): one a step leaves half the MXU's columns and depth empty,
-    pays the rescale of the whole accumulator for 64 keys, and keeps one
-    small DMA in flight. So the most blocks, a power of two up to
-    ``_MAX_STEP_PAGES``, whose scores stay under ``_STEP_SCORE_BYTES`` and
-    whose model stays under ``_VMEM_CAP``."""
-    if not latent:
+    Every other tile pays a STEP, and not a key, for the rescale of its whole
+    accumulator, for the ``[rows, 1]`` columns of the softmax and for the two
+    cross-lane reductions of its scores, and one 64-key block a step leaves
+    half the MXU's columns empty. So the most blocks, a power of two up to
+    ``_MAX_STEP_PAGES``, at which one kv head's scores stay under
+    ``_STEP_SCORE_BYTES`` (a latent pool has one), the step's K and V under
+    ``_STEP_KV_BYTES`` (the first step's fetch hides behind nothing, and a
+    context's last step is computed whole: 32 kv heads x 128 move 1 MiB a
+    block and their contexts are short) and the model of VMEM under
+    ``_VMEM_CAP``. On the v5e, us a 64-key block of a 128-row atom at 1 / 2 /
+    4 / 8 blocks a step: 32 heads over 4 kv heads under a selection at 32 k
+    - / 2.74 / 1.96 / 1.65 (eight), over 8 at 2 k 5.4 / 3.1 / 2.0 / 1.75
+    (eight), 16 over 16 at 1 k 3.6 / 2.5 / 2.1 / 2.1 (four), 32 over 32 at
+    1 k 7.2 / 4.8 / 4.0 / 4.1 and at 256 keys 11.2 / 8.8 / 8.5 / 11.8 (two)
+    (PERF.md, PR 46); a latent pool's, PR 38."""
+    if not latent and bq == 1:
         return 1
+    kv_block = block_size * kvh * d * itemsize * (1 if latent else 2)
     pages = _MAX_STEP_PAGES
     while pages > 1 and (
-            bq * ht * pages * block_size * 4 > _STEP_SCORE_BYTES
+            bq * (ht // kvh) * pages * block_size * 4 > _STEP_SCORE_BYTES
+            or pages * kv_block > _STEP_KV_BYTES
             or _ragged_vmem_need(bq, ht, kvh, d, block_size, itemsize,
-                                 pages) > _VMEM_CAP):
+                                 pages, not latent) > _VMEM_CAP):
         pages //= 2
     return pages
 
 
-def _masked_pages_per_step(block_size: int) -> int:
-    """KV blocks a step takes under a sparse-attention indexer's selection
+def _selection_pages(pages: int, block_size: int) -> int:
+    """``pages`` blocks a step under a sparse-attention indexer's selection
     (a K-and-V pool): the step's slice of the selection is cut along the
-    LANES of its [BQ, keys] rows, whole lane tiles, so 128 keys a step."""
-    return max(1, min(_MAX_STEP_PAGES, 128 // block_size))
+    LANES of its [BQ, keys] rows, so a step is whole lane tiles of 128
+    keys."""
+    return max(pages, min(_MAX_STEP_PAGES, 128 // block_size))
 
 
 def kv_step_keys(bq: int, h: int, kvh: int, d: int, block_size: int,
@@ -417,31 +521,45 @@ def kv_step_keys(bq: int, h: int, kvh: int, d: int, block_size: int,
     first, then the blocks a step (``masked``: under an indexer's
     selection). What the engine counts a forward's steps by
     (``ragged.attention_work``)."""
-    if masked:
-        return block_size * _masked_pages_per_step(block_size)
     ht = _head_tile(bq, h, kvh, d, block_size, itemsize)
-    return block_size * _kv_pages_per_step(bq, ht, kvh, d, block_size,
-                                           itemsize, latent)
+    pages = _kv_pages_per_step(bq, ht, kvh, d, block_size, itemsize, latent)
+    if masked:
+        pages = _selection_pages(pages, block_size)
+    return block_size * pages
 
 
 def _ragged_vmem_need(bq: int, h: int, kvh: int, d: int, block_size: int,
-                      itemsize: int, pages: int = 1) -> int:
+                      itemsize: int, pages: int = 1,
+                      kv_tile: bool = False) -> int:
     """Bytes of VMEM one grid step of :func:`_prefill_kernel` needs, by the
     shape model :func:`_ragged_vmem_limit` explains; a loop step of
     ``pages`` KV blocks holds that many in each scratch slot and scores
-    that many times the keys."""
+    that many times the keys. ``kv_tile``: a K-and-V pool's tile, which
+    feeds the MXU the pool's dtype (no float32 K and V) and makes its mask
+    once for all kv heads, but may carry a selection's slots."""
     q_tile = bq * h * d
-    kv_tile = pages * block_size * kvh * d
+    kv = pages * block_size * kvh * d
     scores = bq * h * pages * block_size
-    return (4 * q_tile * itemsize        # q + out tiles, double-buffered
-            + 5 * q_tile * 4             # fp32 q, q_g, acc, acc_new, pv
-            + 4 * kv_tile * itemsize     # k/v scratch, two slots each
-            + 4 * kv_tile * 4            # fp32 k, v and their transposes
+    need = (4 * q_tile * itemsize        # q + out tiles, double-buffered
+            + 5 * q_tile * 4)            # fp32 q, q_g, acc, acc_new, pv
+    if kv_tile:
+        return (need
+                # k/v scratch, two slots each, the kv heads on sublanes
+                # (fewer than a vreg's 32 bytes of them are tiled up to it)
+                + 4 * kv * itemsize * max(1, 32 // (kvh * itemsize))
+                + 4 * kv * itemsize      # k, v head-major, and in passing
+                + 2 * bq * pages * block_size      # the selection's slots
+                + 4 * (scores // kvh) * 4          # pos, q_pos, valid, bias
+                + 4 * scores * 4)                  # scores, p twice, exp
+    return (need
+            + 4 * kv * itemsize          # k/v scratch, two slots each
+            + 4 * kv * 4                 # fp32 k, v and their transposes
             + 6 * scores * 4)            # scores, pos, valid, p, exp temps
 
 
 def _ragged_vmem_limit(bq: int, h: int, kvh: int, d: int, block_size: int,
-                       itemsize: int, pages: int = 1) -> int:
+                       itemsize: int, pages: int = 1,
+                       kv_tile: bool = False) -> int:
     """Scoped-VMEM limit stated to the compiler for one grid step of
     :func:`_prefill_kernel`. The q/out tiles are double-buffered by the
     pipeline and the body keeps fp32 copies of q, the accumulator and its
@@ -455,9 +573,14 @@ def _ragged_vmem_limit(bq: int, h: int, kvh: int, d: int, block_size: int,
     (:func:`_kv_pages_per_step`): the model's KV scratch and its six arrays
     of scores grow with them (a latent pool's 2,048-row tile: 38.9 MiB at
     one block, 50.8 at four; the model still counts the float32 copy of q
-    and the K-and-V branch's float32 K and V, which the latent branch does
-    not make: room, not need)."""
-    need = _ragged_vmem_need(bq, h, kvh, d, block_size, itemsize, pages)
+    and float32 copies of K and V, which the latent branch does not make:
+    room, not need). ``kv_tile``: a K-and-V pool's tile, whose step makes no
+    float32 K and V and ONE kv head's mask, holds K and V head-major in the
+    pool's dtype and, under a selection, its two slots (32 heads over 4 kv
+    heads at 512 keys a step: 64 MiB modelled, compiled under the cap it
+    states)."""
+    need = _ragged_vmem_need(bq, h, kvh, d, block_size, itemsize, pages,
+                             kv_tile)
     if need > _VMEM_CAP:
         raise ValueError(
             f"ragged prefill atom of {bq} rows x {h} heads x d {d} needs "
@@ -484,9 +607,10 @@ def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
     ``v_cache=None, v_dim=n``: a latent pool, V the leading ``n`` lanes of
     K's rows (the module's docstring). ``sel`` [A, BQ, keys] int8 (a
     K-and-V pool only): a sparse-attention indexer's selection, nonzero
-    where the atom's row attends to the position; the kernel then walks
-    steps of 128 keys and a profile calls it ``dsa_prefill``. Returns
-    [A, BQ, H, D] ([.., n])."""
+    where the atom's row attends to the position; the kernel then walks the
+    steps its tile's shape gives (whole 128-key lane tiles of the selection:
+    :func:`_selection_pages`) and a profile calls it ``dsa_prefill``.
+    Returns [A, BQ, H, D] ([.., n])."""
     a, bq, h, d = q_atoms.shape
     latent = v_cache is None
     if sel is not None and latent:
@@ -506,7 +630,7 @@ def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
     # KV blocks a loop step takes: chosen AFTER the tile, from what it left
     pages = _kv_pages_per_step(bq, ht, kvh, d, block_size, itemsize, latent)
     if sel is not None:
-        pages = _masked_pages_per_step(block_size)
+        pages = _selection_pages(pages, block_size)
         # whole steps of columns: the last step's DMA reads its full width
         step = pages * block_size
         keys = -(-atom_tables.shape[1] * block_size // step) * step
@@ -532,7 +656,7 @@ def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
         window=None if window is None else int(window),
         v_dim=v_dim if latent else None,
         vmem_limit=_ragged_vmem_limit(bq, ht, kvh, d, block_size, itemsize,
-                                      pages),
+                                      pages, not latent),
         interpret=interpret, name=name)
 
 

@@ -222,6 +222,87 @@ def test_latent_kernels_compile_at_the_cells_widths(one_chip, kernel, cell):
     assert len(calls) == 1 and kernel in calls[0].split(" = ")[0]
 
 
+# a K-and-V pool under an indexer's selection, at keye-video-sat's tile: 128
+# rows x 32 heads over 4 kv heads, d 128, blocks of 64, a 49,152-token table
+KEYE_STEP_KEYS = 512     # eight blocks of 64: four lane tiles of the selection
+KEYE_TILE = dict(rows=128, heads=32, kv_heads=4, d=128, block_size=64,
+                 max_context=49152, atoms=15, blocks=6272)
+
+
+def test_the_selected_k_and_v_tile_compiles_at_the_rules_width(one_chip):
+    """The ragged kernel under a selection (``dsa_prefill``) at the cell's
+    tile, with the loop step the rule picks for it (whole 128-key lane
+    tiles of the selection, one kv head's scores filling the budget a
+    step's scores have, K and V turned head-major in the pool's dtype),
+    inside the VMEM it states."""
+    from deepspeedsyclsupport_tpu.ops.paged_attention import (
+        _STEP_SCORE_BYTES, _VMEM_CAP, _head_tile, _kv_pages_per_step,
+        _ragged_vmem_limit, _selection_pages, kv_step_keys)
+
+    g = KEYE_TILE
+    shape = (g["heads"], g["kv_heads"], g["d"], g["block_size"], 2)
+    assert _head_tile(g["rows"], *shape) == g["heads"]
+    pages = _selection_pages(
+        _kv_pages_per_step(g["rows"], *shape, False), g["block_size"])
+    keys = pages * g["block_size"]
+    assert keys == KEYE_STEP_KEYS and keys % 128 == 0
+    assert kv_step_keys(g["rows"], *shape, False, True) == keys
+    # ONE kv head's scores fill the budget a step's scores have
+    rows = g["rows"] * g["heads"] // g["kv_heads"]
+    assert rows * keys * 4 <= _STEP_SCORE_BYTES
+    assert _ragged_vmem_limit(g["rows"], *shape, pages, True) <= _VMEM_CAP
+    bps = g["max_context"] // g["block_size"]
+    a = g["atoms"]
+    pool = ((2, g["blocks"] * g["block_size"], g["kv_heads"], g["d"]),
+            jnp.bfloat16)
+    compiled = _compile(
+        lambda q, k, v, tables, pos0, qlen, sel, layer:
+        ragged_prefill_attention_pallas(
+            q, k, v, tables, pos0, qlen, block_size=g["block_size"],
+            layer=layer, sel=sel),
+        one_chip, ((a, g["rows"], g["heads"], g["d"]), jnp.bfloat16), pool,
+        pool, ((a, bps), jnp.int32), ((a,), jnp.int32), ((a,), jnp.int32),
+        ((a, g["rows"], g["max_context"]), jnp.int8), ((), jnp.int32))
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1 and "dsa_prefill" in calls[0].split(" = ")[0]
+
+
+def test_the_engine_counts_a_selected_atoms_steps_as_the_wrapper_walks_them(
+        monkeypatch):
+    """``InferenceEngineV2._kv_step_keys`` (what ``ragged.attention_work``
+    rounds a tile's context up to) for a tiny model with an indexer: the
+    atoms' steps are the rule's for their tile made whole lane tiles of the
+    selection, the very choice the kernel's wrapper makes (it asks the same
+    two functions), and the one-row tile's stays one block."""
+    from deepspeedsyclsupport_tpu.inference.v2 import InferenceEngineV2
+    from deepspeedsyclsupport_tpu.models import build_model
+    from deepspeedsyclsupport_tpu.ops import paged_attention as pa
+
+    model = build_model(
+        "keye-vl2-30b-a3b", hidden_size=64, intermediate_size=96,
+        moe_intermediate_size=32, num_layers=2, num_heads=4, num_kv_heads=2,
+        head_dim=16, vocab_size=256, num_experts=8, num_experts_per_tok=3,
+        num_experts_held=4, index_topk=8, index_heads=2, index_head_dim=8,
+        max_seq_len=128, dtype="float32")
+    seen = []
+    call = pa._tiled_call
+    monkeypatch.setattr(pa, "_tiled_call", lambda *a, **kw: (
+        seen.append((a[4].shape[1], kw["pages"])), call(*a, **kw))[1])
+    eng = InferenceEngineV2(
+        model, model.init_params(), dtype=jnp.float32, block_size=4,
+        max_context=64, max_tokens_per_batch=16, max_sequences=4,
+        num_blocks=48, prefill_attn="kernel_interpret",
+        decode_attn="pallas_interpret", atom_q_size=8)
+    tile = (4, 2, eng.kv.k.shape[-1], 4, 4)
+    pages = pa._selection_pages(pa._kv_pages_per_step(8, *tile, False), 4)
+    assert eng._kv_step_keys == (4 * pages, 4)
+    assert pa.kv_step_keys(8, *tile, False, True) == 4 * pages
+    eng.put([0], [list(range(1, 12))])
+    # the selected atoms' call (8 rows); the one-token rows go by a gather
+    assert (8, pages) in seen and all(p == pages for r, p in seen if r == 8)
+
+
 # ------------------------------------------------- the expert weights' stack
 def test_decode_forward_reads_the_expert_stack_in_place(one_chip):
     """``decode_forward`` at OLMoE's widths, two layers, as the engine builds

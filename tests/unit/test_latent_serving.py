@@ -233,8 +233,7 @@ def test_the_step_is_chosen_after_the_tile_and_leaves_it_alone(monkeypatch):
     """The head tile and the atom's rows are decided at one KV block a step,
     whatever a step then takes: 16 heads x 128 rows (Xing4), 128 heads x 16
     rows (DeepSeek-V2), as accepted; then the blocks a step, from the tile:
-    four under a 2,048-row tile (2 MiB of scores), eight under one row, one
-    on a K-and-V pool whatever its tile."""
+    four under a 2,048-row tile (2 MiB of scores), eight under one row."""
     xing4, dsv2 = (32, 1, 640, 64, 2), (128, 1, 640, 64, 2)
 
     def chosen():
@@ -249,10 +248,6 @@ def test_the_step_is_chosen_after_the_tile_and_leaves_it_alone(monkeypatch):
     assert pa._kv_pages_per_step(1, 128, *dsv2[1:], True) == 8
     assert pa.kv_step_keys(128, *xing4, True) == 4 * 64
     assert pa.kv_step_keys(1, *dsv2, True) == 8 * 64
-    for tile in ((128, 32, 32, 80, 64, 2), (128, 16, 16, 128, 64, 2),
-                 (1, 32, 8, 128, 64, 2), (1, 16, 1, 128, 64, 2)):
-        assert pa._kv_pages_per_step(*tile, False) == 1
-    assert pa.kv_step_keys(128, 32, 32, 80, 64, 2, False) == 64
     # a step may take what room is left, never the tile's
     assert pa._ragged_vmem_need(128, 16, 1, 640, 64, 2, 4) > \
         pa._HEAD_TILE_BUDGET > pa._ragged_vmem_need(128, 16, 1, 640, 64, 2)
@@ -264,6 +259,49 @@ def test_the_step_is_chosen_after_the_tile_and_leaves_it_alone(monkeypatch):
     # and a rule that asked for more would move neither
     monkeypatch.setattr(pa, "_kv_pages_per_step", lambda *a: 8)
     assert chosen() == [(128, 16), (16, 128)]
+
+
+# (rows, heads, kv heads, d, block, itemsize) of a K-and-V pool's tile ->
+# blocks a step: what bounds it
+KV_TILE_PAGES = {
+    # keye-video-sat: one kv head's [1024, 512] float32 scores are the 2 MiB
+    "keye_atoms": ((128, 32, 4, 128, 64, 2), 8),
+    # 8 kv heads x 128 move 256 KiB a block of K and V: eight are the 2 MiB
+    "gqa8_atoms": ((128, 32, 8, 128, 64, 2), 8),
+    "olmoe_atoms": ((128, 16, 16, 128, 64, 2), 4),      # 512 KiB a block
+    # phi-2 (d 80 stored as 128): 1 MiB a block, and its contexts are short
+    "phi2_atoms": ((128, 32, 32, 128, 64, 2), 2),
+    "phi2_atoms_f32": ((128, 32, 32, 128, 64, 4), 1),
+    # one kv head under 32 heads: 4,096 rows of scores
+    "mqa_atoms": ((128, 32, 1, 128, 64, 2), 2),
+    # the one-row tile keeps the loop over blocks it always was
+    "phi2_row": ((1, 32, 32, 128, 64, 2), 1),
+    "gqa8_row": ((1, 32, 8, 128, 64, 2), 1),
+    "mqa_row": ((1, 16, 1, 128, 64, 2), 1),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KV_TILE_PAGES))
+def test_a_k_and_v_pools_step_follows_its_tile(name):
+    """ONE rule for every pool: the most blocks at which one kv head's
+    scores, the step's K and V and the model of VMEM stay under their
+    budgets; only a K-and-V pool's one-row tile is held at one."""
+    tile, pages = KV_TILE_PAGES[name]
+    assert pa._kv_pages_per_step(*tile, False) == pages
+    rows, heads, kvh, d, bs, itemsize = tile
+    assert pa.kv_step_keys(*tile, False) == pages * bs
+    # under a selection a step is whole lane tiles of 128 keys, no fewer
+    assert pa.kv_step_keys(*tile, False, True) == max(pages, 2) * bs
+    assert pa._selection_pages(pages, 128) == pages
+    assert pa._selection_pages(1, 4) == pa._MAX_STEP_PAGES
+    if pages > 1:
+        assert rows * heads // kvh * pages * bs * 4 <= pa._STEP_SCORE_BYTES
+        assert 2 * pages * bs * kvh * d * itemsize <= pa._STEP_KV_BYTES
+        assert pa._ragged_vmem_limit(*tile, pages, True) <= pa._VMEM_CAP
+    if pages < pa._MAX_STEP_PAGES and rows > 1:     # and twice would not
+        assert (rows * heads // kvh * 2 * pages * bs * 4
+                > pa._STEP_SCORE_BYTES
+                or 4 * pages * bs * kvh * d * itemsize > pa._STEP_KV_BYTES)
 
 
 # --------------------------------------------------------------- the pool

@@ -353,3 +353,86 @@ def test_the_kernel_body_is_traced_once_a_tile_not_once_a_call(monkeypatch,
                                            block_size=16, layer=1)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+# ------------------------------- a K-and-V pool under a tile of several rows
+# (pos0, qlen) of each atom over a table of 11 blocks of 128 keys (1,408):
+KV_TILES = [
+    (300, 8),     # 308 keys: a whole step at no width
+    (0, 0),       # a dead atom: zero steps, zeros
+    (1404, 8),    # longer than its table: what the table holds
+    (1030, 5),    # its first row stands inside the last step at any width
+    (600, 8),
+]
+
+
+def _kv_tile_selection(rng, bq, keys):
+    """int8 [atoms, bq, keys]: 3 keys in 10 of what a row may see, and the
+    rows a step's mask must not fool: atom 3's row 0 selects nothing under
+    key 1,024 (every step under its last holds no selected key: ``m`` stays
+    at its first value there and no ``exp`` may read 1), its row 1 only
+    keys 5-20 (all in the first step), its row 2 nothing at all."""
+    pos0 = np.asarray([p for p, _ in KV_TILES])
+    seen = np.arange(keys)[None, None, :] <= (
+        pos0[:, None, None] + np.arange(bq)[None, :, None])
+    sel = np.logical_and(rng.random((len(KV_TILES), bq, keys)) < 0.3, seen)
+    sel[3, 0, :1024] = False
+    sel[3, 0, 1024:1031] = True
+    sel[3, 1] = False
+    sel[3, 1, 5:21] = True
+    sel[3, 2] = False
+    return jnp.asarray(sel, jnp.int8)
+
+
+@pytest.mark.parametrize("arch", ["plain", "alibi_window"])
+@pytest.mark.parametrize("group", [1, 8])
+@pytest.mark.parametrize("masked", [False, True], ids=["dense", "selected"])
+@pytest.mark.parametrize("pages", [1, 2, 4, 8])
+def test_a_k_and_v_tile_of_several_rows_is_its_twin_at_any_step(
+        monkeypatch, pages, masked, group, arch):
+    """A K-and-V pool's tile of several rows walks steps of ``pages``
+    blocks, one kv head's scores at a time; under an indexer's selection a
+    pair counts only where the mask is nonzero. Blocks OUTSIDE the tables'
+    live part are NaN (another sequence's, or never written): a wide
+    step's blocks past the context's end, or below the window's start,
+    must not be read as they lie."""
+    from deepspeedsyclsupport_tpu.models.layers import alibi_slopes
+    from deepspeedsyclsupport_tpu.ops import paged_attention as pa
+
+    monkeypatch.setattr(pa, "_kv_pages_per_step", lambda *a: pages)
+    bs, bps, blocks, bq, kvh, d = 128, 11, 60, 8, 2, 16
+    h = kvh * group
+    rng = np.random.default_rng(pages + 10 * group + masked)
+    window = 200 if arch == "alibi_window" else None
+    pos0, qlen = (np.asarray(x) for x in zip(*KV_TILES))
+    hi = np.minimum(pos0 + qlen, bps * bs)
+    n = len(KV_TILES)
+    tables = rng.permutation(blocks)[:n * bps].reshape(n, bps)
+    lo = np.zeros(n, int) if window is None else \
+        np.maximum(pos0 + 1 - window, 0) // bs
+    pool = np.full((2, 2, blocks * bs, kvh, d), np.nan, np.float32)
+    for i in range(n):
+        for blk in tables[i, lo[i]:-(-hi[i] // bs)]:
+            pool[:, :, blk * bs:(blk + 1) * bs] = rng.standard_normal(
+                (2, 2, bs, kvh, d))
+    q = jnp.asarray(rng.standard_normal((n, bq, h, d)) * 0.5, jnp.float32)
+    kw = dict(block_size=bs, layer=jnp.int32(1), window=window)
+    if arch == "alibi_window":
+        kw["alibi"] = jnp.asarray(alibi_slopes(h))
+    if masked:
+        kw["sel"] = _kv_tile_selection(rng, bq, bps * bs)
+    args = (jnp.asarray(tables, jnp.int32), jnp.asarray(pos0, jnp.int32),
+            jnp.asarray(qlen, jnp.int32))
+    got = np.asarray(pa.ragged_prefill_attention_pallas(
+        q, jnp.asarray(pool[0]), jnp.asarray(pool[1]), *args,
+        interpret=True, **kw))
+    clean = jnp.asarray(np.nan_to_num(pool))
+    want = np.asarray(pa.ragged_prefill_attention_reference(
+        q, clean[0], clean[1], *args, **kw))
+    rows = np.arange(bq)[None, :] < qlen[:, None]
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got[rows], want[rows], atol=2e-5, rtol=2e-5)
+    assert not got[~rows].any()              # dead rows and tiles: zeros
+    if masked and window is None:
+        assert not got[3, 2].any()           # a row that selected nothing
+        assert np.abs(got[3, :2]).max() > 1e-3

@@ -307,12 +307,26 @@ KV_CALLS = [("ragged_prefill", 8), ("paged_decode", 8)]
     ("olmoe-1b-7b", dict(intermediate_size=256, num_kv_heads=4, head_dim=128,
                          num_experts=8, num_experts_per_tok=2))])
 def test_a_k_and_v_pool_lowers_to_the_calls_it_had(preset, more):
-    """The guard that latent attention moved no other model: phi-2's and
-    OLMoE's programs hold the same two custom calls with the same operand
-    counts as before it (K AND V both reach each kernel; the V-from-K path
-    is decided at trace time, by the pool)."""
-    calls = _lowered_ragged_forward(preset, (4, 128), **TINY_WIDTHS, **more)
+    """The guard that neither latent attention nor the atoms' wide step
+    moved what a K-and-V model's program calls: phi-2's and OLMoE's hold the
+    same two custom calls with the same operand counts (K AND V both reach
+    each kernel; the V-from-K path is decided at trace time, by the pool),
+    the atoms' call now at the step the rule gives its tile (4 heads over 4
+    kv heads x 128: a block of K and V is 128 KiB, so eight) and the
+    one-row call at one block, as it was."""
+    from deepspeedsyclsupport_tpu.ops import paged_attention as pa
+
+    seen = []
+    call = pa._tiled_call
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(pa, "_tiled_call", lambda *a, **kw: (
+            seen.append((kw["name"], a[4].shape[1], kw["pages"])),
+            call(*a, **kw))[1])
+        calls = _lowered_ragged_forward(preset, (4, 128), **TINY_WIDTHS,
+                                        **more)
     assert calls == KV_CALLS, calls
+    assert set(seen) == {("ragged_prefill", 128, 8), ("paged_decode", 1, 1)}
+    assert pa.kv_step_keys(128, 4, 4, 128, 64, 2, False) == 8 * 64
 
 
 def test_a_latent_pool_lowers_to_calls_with_one_pool_operand():
