@@ -62,11 +62,13 @@ def _widths(name):
 
 
 # ------------------------------------------------------------------- flash
-# training attention: mistral-7b (32/8 heads, d 128, S 4096, window 4096)
-# and phi-2's head_dim 80 (lane-padded to 128 inside the kernel wrapper)
+# training attention: mistral-7b (32/8 heads, d 128, S 4096, window 4096),
+# phi-2's head_dim 80 (lane-padded to 128 inside the kernel wrapper), and the
+# two training cells' own shape (mistral-7b widths, 4 x 2,048 tokens a chip)
 FLASH_CASES = {
-    "mistral-7b": dict(seq=4096, window=4096),
-    "phi-2": dict(seq=2048, window=None),
+    "mistral-7b": dict(preset="mistral-7b", batch=1, seq=4096, window=4096),
+    "phi-2": dict(preset="phi-2", batch=1, seq=2048, window=None),
+    "train-cells": dict(preset="mistral-7b", batch=4, seq=2048, window=4096),
 }
 
 
@@ -79,15 +81,41 @@ def _flash_fn(window, grad):
                     argnums=(0, 1, 2))
 
 
+def _flash_compiled(one_chip, name, grad):
+    case = FLASH_CASES[name]
+    h, kvh, d = _widths(case["preset"])
+    q = ((case["batch"], case["seq"], h, d), jnp.bfloat16)
+    kv = ((case["batch"], case["seq"], kvh, d), jnp.bfloat16)
+    return _compile(_flash_fn(case["window"], grad), one_chip, q, kv, kv)
+
+
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "fwd_bwd"])
 @pytest.mark.parametrize("name", sorted(FLASH_CASES))
 def test_flash_compiles(one_chip, name, grad):
-    h, kvh, d = _widths(name)
-    case = FLASH_CASES[name]
-    q = ((1, case["seq"], h, d), jnp.bfloat16)
-    kv = ((1, case["seq"], kvh, d), jnp.bfloat16)
-    compiled = _compile(_flash_fn(case["window"], grad), one_chip, q, kv, kv)
+    compiled = _flash_compiled(one_chip, name, grad)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_the_cells_step_holds_three_flash_calls_the_benchmark_can_tell_apart(
+        one_chip):
+    """``benchmark/metrics/flash_roofline.py`` counts EVERY custom call of
+    the train step as one of the three flash kernels and tells them apart by
+    result type alone (forward: two arrays of different shapes; dq: one; dkv:
+    two of one shape). So a layer's attention is exactly three calls, ``lse``
+    never has ``o``'s shape and dK / dV leave their kernel as one shape. The
+    blocks are the rule's own, inside the VMEM each kernel asks for (which
+    the compile above has held them to)."""
+    from benchmark.metrics.flash_roofline import kernel_of
+    from deepspeedsyclsupport_tpu.ops.flash_attention import (
+        _VMEM_CAP, _default_blocks, _vmem_limit)
+
+    text = _flash_compiled(one_chip, "train-cells", True).as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(kernel_of(c) for c in calls) == ["dkv", "dq", "fwd"]
+    sq_p, skv_p, block = _default_blocks(2048, 2048)
+    assert (sq_p, skv_p) == (2048, 2048)
+    assert _vmem_limit(block, 128, 2) <= _VMEM_CAP
 
 
 # ------------------------------------------------------------------- paged

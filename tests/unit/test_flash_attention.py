@@ -249,3 +249,141 @@ class TestAlibiAndWindow:
                                    rtol=1e-6)
         s6 = alibi_slopes(6)           # non-power-of-2 interpolation
         assert s6.shape == (6,) and np.all(s6 > 0) and np.all(np.diff(s6[:4]) < 0)
+
+
+# ------------------------------------------------------------ kinds of tile
+def _segments(*lengths):
+    return jnp.asarray(np.repeat(np.arange(len(lengths)), lengths)[None])
+
+
+def _own_positions(sq, skv):
+    return dict(q_positions=jnp.arange(sq, dtype=jnp.int32)[None] + skv - sq,
+                kv_positions=jnp.arange(skv, dtype=jnp.int32)[None])
+
+
+# every case holds a DEAD, an INTERIOR and a BOUNDARY tile at 128 x 128
+# (``_tile_kinds`` checks that from the full mask), by another route each:
+# name -> (sq, skv, q heads, kv heads, flash_attention / reference kwargs)
+TILE_CASES = {
+    "causal_3_blocks": (384, 384, 2, 2, {}),
+    "offset_sq_lt_skv": (256, 512, 2, 2, {}),
+    "length_not_a_block_multiple": (300, 300, 2, 2, {}),
+    "window_bites": (640, 640, 2, 2, dict(window=300)),
+    "window_cannot_bite": (384, 384, 2, 2, dict(window=4096)),
+    # one segment ends on a block edge (128), the next inside a block (428)
+    "segments_edge_and_inside": (640, 640, 2, 2,
+                                 dict(segment_ids=_segments(128, 300, 212))),
+    "custom_positions": (384, 384, 2, 2, _own_positions(384, 384)),
+    "group_of_4_heads": (384, 384, 8, 2, {}),
+    "alibi": (384, 384, 4, 4, dict(alibi=jnp.asarray([0.5, 0.25, 0.125,
+                                                      0.0625]))),
+}
+
+
+def _tile_kinds(sq, skv, kw, block=128):
+    """``{dead, interior, boundary}`` counts from the full validity mask."""
+    qp = np.arange(sq)[:, None] + skv - sq
+    kp = np.arange(skv)[None, :]
+    ok = kp <= qp
+    if kw.get("window") is not None:
+        ok &= qp - kp < kw["window"]
+    if "segment_ids" in kw:
+        seg = np.asarray(kw["segment_ids"])[0]
+        ok &= seg[:, None] == seg[None, :]
+    pad = lambda n: -(-n // block) * block
+    full = np.zeros((pad(sq), pad(skv)), bool)
+    full[:sq, :skv] = ok
+    kinds = dict(dead=0, interior=0, boundary=0)
+    for i in range(0, full.shape[0], block):
+        for j in range(0, full.shape[1], block):
+            tile = full[i:i + block, j:j + block]
+            kinds["dead" if not tile.any() else
+                  "interior" if tile.all() else "boundary"] += 1
+    return kinds
+
+
+def _assert_forward_and_grads_match(attn, reference, q, k, v):
+    """Forward at 2e-5 and dq / dk / dv at 2e-4: this file's tolerances."""
+    def run(f):
+        def loss(q, k, v):
+            o = f(q, k, v)
+            return jnp.sum(o * jnp.cos(o)), o
+        (_, o), g = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                       has_aux=True)(q, k, v)
+        return (o,) + g
+
+    got, want = run(attn), run(reference)
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               rtol=2e-5, atol=2e-5)
+    for a, b in zip(got[1:], want[1:]):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("name", sorted(TILE_CASES))
+def test_each_kind_of_tile_in_each_kernel(name):
+    """Forward and dq / dk / dv against the XLA reference on shapes whose
+    grids hold all three kinds of tile, so the unmasked body, the masked body
+    and the skipped step of the forward, dQ and dK/dV kernels all run."""
+    sq, skv, h, kvh, kw = TILE_CASES[name]
+    kinds = _tile_kinds(sq, skv, kw)
+    assert min(kinds.values()) > 0, kinds
+    q, k, v = _qkv(20, b=1, sq=sq, skv=skv, h=h, kvh=kvh, d=32)
+
+    _assert_forward_and_grads_match(
+        lambda q, k, v: flash_attention(q, k, v, causal=True, interpret=True,
+                                        block_q=128, block_k=128, **kw),
+        lambda q, k, v: reference_attention(q, k, v, causal=True, **kw),
+        q, k, v)
+
+
+@pytest.mark.parametrize("sq,skv,block,window", [
+    (2048, 2048, 512, 4096),    # the training cells' shape: 6 + 4 live, 6 dead
+    (640, 640, 128, 300),       # a window that bites
+    (256, 512, 128, None),      # Sq != Skv
+    (300, 300, 128, None),      # padded last blocks are boundary tiles
+])
+def test_tile_plan_counts_what_the_mask_holds(sq, skv, block, window):
+    from deepspeedsyclsupport_tpu.ops.flash_attention import tile_plan
+
+    plan = tile_plan(sq, skv, block, block, True, skv - sq, window)
+    want = _tile_kinds(sq, skv, dict(window=window), block)
+    assert plan == dict(want, live=want["interior"] + want["boundary"])
+    if (sq, block) == (2048, 512):
+        assert plan == dict(live=10, interior=6, boundary=4, dead=6)
+
+
+def test_default_blocks_never_pad_more_than_a_512_block():
+    from deepspeedsyclsupport_tpu.ops.flash_attention import _default_blocks
+
+    for s in (1, 128, 200, 512, 1500, 1664, 2048, 4096, 5000, 8192):
+        s_p, _, (bq, bk, cq, ck) = _default_blocks(s, s)
+        assert s_p == -(-s // min(512, -(-s // 128) * 128)) \
+            * min(512, -(-s // 128) * 128)
+        assert s_p % bq == 0 and s_p % bk == 0
+        assert bq % cq == 0 and bk % ck == 0 and cq % 128 == 0
+
+
+@pytest.mark.parametrize("sq,skv,h,kvh,kw", [
+    (512, 512, 4, 1, {}),
+    (500, 500, 2, 2, dict(alibi=jnp.asarray([0.5, 0.125]), window=300)),
+    (256, 512, 2, 2, {}),
+], ids=["group_of_4_heads", "unaligned_alibi_window", "offset_sq_lt_skv"])
+def test_a_grid_step_walked_as_several_compute_tiles(monkeypatch, sq, skv, h,
+                                                     kvh, kw):
+    """The rule's shape of a call: the block a grid step copies is larger
+    than the tile it computes on, one rolled loop whose every tile takes the body its own kind asks for. A call with per-key rows
+    (segments) keeps step and tile the same."""
+    from deepspeedsyclsupport_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_COMPUTE_TILE", 128)
+    monkeypatch.setattr(fa, "_PREFERRED_BLOCK", (256, 512))
+    assert fa._default_blocks(512, 512)[2] == (256, 512, 128, 128)
+    assert fa._default_blocks(512, 512, plain=False)[2] == (128,) * 4
+    q, k, v = _qkv(21, b=1, sq=sq, skv=skv, h=h, kvh=kvh, d=32)
+
+    _assert_forward_and_grads_match(
+        lambda q, k, v: fa.flash_attention(q, k, v, causal=True,
+                                           interpret=True, **kw),
+        lambda q, k, v: reference_attention(q, k, v, causal=True, **kw),
+        q, k, v)

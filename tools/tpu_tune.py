@@ -5,7 +5,11 @@ Sections, cheapest first:
   calib   — XLA matmul at known-FLOP shapes: separates dispatch overhead
             from device compute (a 1.1 TFLOP matmul at v5e peak is ~6 ms;
             if measured time is tens of ms, the gap is dispatch).
-  flash   — flash-attention block_q/block_k sweep at one training shape.
+  flash   — the three flash-attention kernels alone at the training cells'
+            shape (the tree's module beside a parent checkout's), with a
+            block_q/block_k sweep, the bf16-operand probe and the parity
+            against XLA:  flash [--parent DIR] [--seq N ...] [--sweep]
+            [--probe] [--parity]
   paged   — paged-decode block_size sweep at serving shapes.
 
 Usage:  python tools/tpu_tune.py [calib|flash|paged|all]
@@ -95,39 +99,233 @@ def calib():
          dispatch_floor_ms=round(dt0 * 1e3, 3), matmuls=rows)
 
 
-def flash():
-    from deepspeedsyclsupport_tpu.ops import flash_attention as fa
+# the training cells' attention (mistral-7b widths, BENCHMARK.json's two
+# training cells): 8,192 tokens a chip a step, 32 query heads over 8, bf16
+FLASH_CELL = dict(tokens=8192, heads=32, kv_heads=8, head_dim=128,
+                  window=4096)
+FLASH_BLOCKS = (256, 512, 1024, 2048)
+# blocks a grid step copies (walked in the rule's compute tiles)
+FLASH_STEPS = ((512, 512), (1024, 1024), (1024, 2048), (2048, 1024),
+               (2048, 2048), (4096, 4096), (8192, 8192))
 
-    b, s, h, d = 4, 2048, 16, 128
-    ks = jax.random.split(jax.random.PRNGKey(0), 3)
-    q = jax.random.normal(ks[0], (b, s, h, d), jnp.bfloat16)
-    k = jax.random.normal(ks[1], (b, s, h, d), jnp.bfloat16)
-    v = jax.random.normal(ks[2], (b, s, h, d), jnp.bfloat16)
-    fl = 4 * b * h * s * s * d * 0.5
-    rows = []
-    best = None
-    for bq in (128, 256, 512, 1024):
-        for bk in (128, 256, 512, 1024):
-            if bq > s or bk > s:
-                continue
+
+def _load_flash(root):
+    """``ops/flash_attention.py`` of the checkout at ``root`` as a module of
+    its own (it imports nothing of the package), so the parent's kernels run
+    beside the tree's in one process."""
+    import importlib.util
+
+    path = os.path.join(root, "deepspeedsyclsupport_tpu", "ops",
+                        "flash_attention.py")
+    spec = importlib.util.spec_from_file_location(
+        "flash_" + (os.path.basename(os.path.abspath(root)) or "tree"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _flash_operands(seq, seed=0):
+    c = FLASH_CELL
+    b = c["tokens"] // seq
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    shape = lambda h: (b, seq, h, c["head_dim"])
+    q = jax.random.normal(ks[0], shape(c["heads"]), jnp.bfloat16)
+    k = jax.random.normal(ks[1], shape(c["kv_heads"]), jnp.bfloat16)
+    v = jax.random.normal(ks[2], shape(c["kv_heads"]), jnp.bfloat16)
+    w = jax.random.normal(ks[3], shape(c["heads"]), jnp.bfloat16)
+    return q, k, v, w
+
+
+def _flash_step(mod, name, **blocks):
+    """Forward and all three gradients in one program named ``name``: the
+    three custom calls of a training step's layer."""
+    def step(q, k, v, w):
+        def loss(q, k, v):
+            o = mod.flash_attention(q, k, v, causal=True,
+                                    window=FLASH_CELL["window"], **blocks)
+            return jnp.sum(o.astype(jnp.float32) * w.astype(jnp.float32))
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+    step.__name__ = name
+    return jax.jit(step)
+
+
+def _traced_kernels(steps, args, runs=3):
+    """Run every ``{name: compiled step}`` ``runs`` times under ONE profiler
+    trace and return ``{name: {"fwd" | "dq" | "dkv": median ms, "xla": ms of
+    everything else in the program}}``, the kernels told apart as the
+    benchmark's ``flash_roofline`` tells them."""
+    import glob
+    import statistics
+    import tempfile
+
+    from benchmark import trace
+    from benchmark.metrics.flash_roofline import kernel_of
+
+    for f in steps.values():
+        jax.block_until_ready(f(*args))                     # warm
+    with tempfile.TemporaryDirectory() as d:
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(d, profiler_options=opts)
+        for f in steps.values():
+            for _ in range(runs):
+                jax.block_until_ready(f(*args))
+        jax.profiler.stop_trace()
+        tr = trace.read_xplane(sorted(glob.glob(os.path.join(
+            d, "plugins", "profile", "*", "*.xplane.pb")))[-1])
+    plane = sorted(tr["devices"])[0]
+    seen = {}
+    for program, text, _start, dur in trace.ops_by_program(tr, plane):
+        kind = (kernel_of(text) if trace.op_kind(text) == "kernel"
+                else "xla")
+        seen.setdefault(program, {}).setdefault(kind, []).append(dur)
+    out = {}
+    for name in steps:
+        row = seen.get(name, {})
+        out[name] = {k: round(1e3 * statistics.median(v), 4)
+                     for k, v in row.items() if k != "xla"}
+        out[name]["calls"] = {k: len(v) for k, v in row.items()
+                              if k != "xla"}
+        out[name]["xla"] = round(1e3 * sum(row.get("xla", [])) / runs, 4)
+    return out
+
+
+def _flash_roofline(kernel, ms, seq):
+    """Share of the bf16 peak by ``benchmark/flops.py``'s product counts."""
+    from benchmark import flops
+
+    c = FLASH_CELL
+    need = flops.flash_flops(kernel, c["tokens"] // seq, c["heads"], seq,
+                             c["head_dim"], c["window"])
+    return round(100 * need / V5E_PEAK / (ms * 1e-3), 1)
+
+
+def _flash_probe():
+    """Does Mosaic's product at the default precision see a float32 copy of a
+    bf16 operand as the bf16 operand (PR 46's finding, at the flash tile)?
+    Worst absolute difference between the two products; 0.0 is bit for bit."""
+    from jax.experimental import pallas as pl
+
+    def kern(a_ref, b_ref, p_ref, qk32, qk16, pv32, pv16):
+        a, b, p = a_ref[...], b_ref[...], p_ref[...]
+        nt = (((1,), (1,)), ((), ()))
+        f32 = jnp.float32
+        qk32[...] = jax.lax.dot_general(a.astype(f32), b.astype(f32), nt,
+                                        preferred_element_type=f32)
+        qk16[...] = jax.lax.dot_general(a, b, nt, preferred_element_type=f32)
+        pv32[...] = jnp.dot(p, b.astype(f32), preferred_element_type=f32)
+        pv16[...] = jnp.dot(p.astype(b.dtype), b, preferred_element_type=f32)
+
+    ks = jax.random.split(jax.random.PRNGKey(1), 3)
+    a = jax.random.normal(ks[0], (512, 128), jnp.bfloat16)
+    b = jax.random.normal(ks[1], (512, 128), jnp.bfloat16)
+    p = jax.random.uniform(ks[2], (512, 512), jnp.float32)
+    f32 = jnp.float32
+    qk32, qk16, pv32, pv16 = pl.pallas_call(
+        kern, out_shape=[jax.ShapeDtypeStruct((512, 512), f32)] * 2
+        + [jax.ShapeDtypeStruct((512, 128), f32)] * 2)(a, b, p)
+    return {"qk_f32_copy_vs_bf16": float(jnp.max(jnp.abs(qk32 - qk16))),
+            "pv_f32_p_vs_bf16_p": float(jnp.max(jnp.abs(pv32 - pv16)))}
+
+
+def _flash_parity(mods, seq=2048, batch=2):
+    """Forward and dq / dk / dv of each module against the XLA reference in
+    float32 at the cells' widths: worst absolute error over worst reference
+    magnitude, per output."""
+    from deepspeedsyclsupport_tpu.models.layers import reference_attention
+
+    q, k, v, w = (x[:batch] for x in _flash_operands(seq, seed=7))
+    f32 = jnp.float32
+
+    def both(attn):
+        def loss(q, k, v):
+            o = attn(q, k, v)
+            return jnp.sum(o.astype(f32) * w.astype(f32)), o
+        (_, o), g = jax.value_and_grad(loss, argnums=(0, 1, 2),
+                                       has_aux=True)(q, k, v)
+        return (o,) + g
+
+    ref = jax.jit(lambda: both(lambda q, k, v: reference_attention(
+        q.astype(f32), k.astype(f32), v.astype(f32), causal=True,
+        window=FLASH_CELL["window"])))()
+    out = {}
+    for tag, mod in mods.items():
+        got = jax.jit(lambda mod=mod: both(lambda q, k, v: mod.flash_attention(
+            q, k, v, causal=True, window=FLASH_CELL["window"])))()
+        out[tag] = {n: float(jnp.max(jnp.abs(g.astype(f32) - r.astype(f32)))
+                             / jnp.max(jnp.abs(r.astype(f32))))
+                    for n, g, r in zip(("fwd", "dq", "dk", "dv"), got, ref)}
+    return out
+
+
+def flash(argv=()):
+    """The three flash kernels ALONE at the training cells' shape: each
+    custom call's device time read off a profiler trace (as the benchmark's
+    ``flash_roofline`` reads it), the tree's module beside the parent's
+    (``--parent DIR``, a checkout of the parent commit), at the default
+    blocks, over a ``(block_q, block_k)`` sweep (``--sweep``: copied and
+    computed as one tile) and over the block a grid step COPIES under the
+    rule's compute tile, or another (``--steps [--tile N ...]``: the tree's
+    ``_PREFERRED_BLOCK`` and ``_COMPUTE_TILE``), at
+    ``--seq`` tokens a sequence (8,192 tokens a step whatever the length);
+    ``--probe`` and ``--parity`` make the bf16-operand and the
+    against-XLA checks."""
+    import argparse
+
+    ap = argparse.ArgumentParser(prog="tpu_tune.py flash")
+    ap.add_argument("--parent", default=None)
+    ap.add_argument("--seq", type=int, nargs="*", default=[2048])
+    ap.add_argument("--sweep", action="store_true")
+    ap.add_argument("--steps", action="store_true")
+    ap.add_argument("--tile", type=int, nargs="*", default=[])
+    ap.add_argument("--probe", action="store_true")
+    ap.add_argument("--parity", action="store_true")
+    a = ap.parse_args(list(argv))
+    mods = {"tree": _load_flash(os.path.join(os.path.dirname(__file__),
+                                             ".."))}
+    if a.parent:
+        mods["parent"] = _load_flash(a.parent)
+    if a.probe:
+        emit("flash_probe", **_flash_probe())
+    if a.parity:
+        emit("flash_parity", **_flash_parity(mods))
+    for seq in a.seq:
+        grid = [(bq, bk) for bq in FLASH_BLOCKS for bk in FLASH_BLOCKS
+                if bq <= seq and bk <= seq]
+        # (row name, module, flash_attention's keywords, the rule's blocks)
+        plan = [(f"{tag}_{seq}_default", mod, {}, None)
+                for tag, mod in mods.items()]
+        if a.sweep:
+            plan += [(f"{tag}_{seq}_{bq}x{bk}", mod,
+                      dict(block_q=bq, block_k=bk), None)
+                     for tag, mod in mods.items() for bq, bk in grid]
+        tree = mods["tree"]
+        rule, tile = tree._PREFERRED_BLOCK, tree._COMPUTE_TILE
+        if a.steps:
+            plan += [(f"tree_{seq}_tile{c}_steps{bq}x{bk}", tree, {},
+                      (c, (bq, bk))) for c in [tile] + a.tile
+                     for bq, bk in FLASH_STEPS
+                     if c <= min(bq, bk) and max(bq, bk) <= seq]
+        args = _flash_operands(seq)
+        steps, failed = {}, {}
+        for name, mod, kw, patch in plan:
+            if patch is not None:
+                tree._COMPUTE_TILE = patch[0]
+                tree._PREFERRED_BLOCK = patch[1]
             try:
-                dt, how = _bench_chain(
-                    lambda x, k, v, bq=bq, bk=bk: fa.flash_attention(
-                        x, k, v, causal=True, block_q=bq, block_k=bk),
-                    q, (k, v), 8)
-            except Exception as e:
-                rows.append({"bq": bq, "bk": bk,
-                             "error": str(e)[:120]})
-                continue
-            tf = fl / dt / 1e12
-            rows.append({"bq": bq, "bk": bk, "ms": round(dt * 1e3, 2),
-                         "timing": how, "tflops": round(tf, 1)})
-            # compare only within the 'chained' timing class — a
-            # dispatch_bound row carries ms of dispatch latency, and the
-            # FASTEST configs are the most likely to degrade to it
-            if how == "chained" and (best is None or tf > best["tflops"]):
-                best = rows[-1]
-    emit("flash", shape=[b, s, h, d], best=best, sweep=rows)
+                steps[name] = _flash_step(mod, name, **kw).lower(
+                    *args).compile()
+            except Exception as e:                 # e.g. over the VMEM limit
+                failed[name] = str(e).splitlines()[0][:160]
+            finally:
+                tree._PREFERRED_BLOCK, tree._COMPUTE_TILE = rule, tile
+        rows = _traced_kernels(steps, args)
+        for name, row in rows.items():
+            row["roofline_pct"] = {k: _flash_roofline(k, row[k], seq)
+                                   for k in ("fwd", "dq", "dkv") if k in row}
+            row["three_ms"] = round(sum(row.get(k, 0)
+                                        for k in ("fwd", "dq", "dkv")), 4)
+        emit("flash", seq=seq, cell=FLASH_CELL, rows=rows, failed=failed)
 
 
 def paged():
@@ -168,6 +366,6 @@ if __name__ == "__main__":
     if which in ("calib", "all"):
         calib()
     if which in ("flash", "all"):
-        flash()
+        flash(sys.argv[2:] if which == "flash" else ())
     if which in ("paged", "all"):
         paged()
