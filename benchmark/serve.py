@@ -194,7 +194,7 @@ def run_closed(loop, mix, seed, vocab, seconds, hooks):
     t0 = t1 = t_stop = None
     while True:
         for client, since in free:
-            loop.submit(plan.take(), due=since, client=client)
+            loop.submit(plan.take(client), due=since, client=client)
         free = []
         done = loop.step()
         now = loop.rounds[-1][1]

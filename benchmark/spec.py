@@ -26,6 +26,10 @@ from pathlib import Path
 ROOT = Path(__file__).resolve().parent.parent
 
 NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+NAME_RULE = "at most 64 of A-Za-z0-9_.- and starts with neither . nor -"
+# the most entries the driver's contract lets each list hold: a file outside
+# them is refused before a single run
+MOST = {"configs": 24, "workloads": 24, "end_to_end": 16, "per_layer": 128}
 UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
 TRAFFIC_KINDS = ("closed", "open-fixed-rate", "train-batches")
@@ -179,15 +183,22 @@ class Bench:
         spec.loader.exec_module(mod)
         return mod
 
+    def resolved(self, name):
+        """``(the reader's .py, its args)`` that the per-layer metric
+        ``name`` comes to through its aliases: what two entries may not
+        share together with their ``moves``."""
+        f, args = self._find("metrics", name, (".py", ".json")), {}
+        while f.suffix == ".json":
+            alias = load_json(f)
+            args = {**alias.get("args", {}), **args}
+            f = self._find("metrics", alias["reader"], (".py", ".json"))
+        return f.stem, args
+
     def reader(self, name):
         """``read(obs)`` of the per-layer metric ``name``."""
-        f = self._find("metrics", name, (".py", ".json"))
-        if f.suffix == ".json":
-            alias = load_json(f)
-            inner = self.reader(alias["reader"])
-            args = alias.get("args", {})
-            return lambda obs: inner(obs, **args)
-        return self._module("metrics", name).read
+        stem, args = self.resolved(name)
+        read = self._module("metrics", stem).read
+        return (lambda obs: read(obs, **args)) if args else read
 
     def family(self, cfg):
         """The module of the configuration's model family: its plain
@@ -211,18 +222,27 @@ class Bench:
     def problems(self):
         """Everything wrong with the benchmark's data, as a list of
         sentences (empty = sound). Checks what a later PR's added files must
-        also meet: names, units, sources, files found by name, and that each
+        also meet: the contract's limits (entries a list, names, four-chip
+        cells), units, sources, files found by name, and that each
         per-layer metric's ``moves`` is reported by every cell reporting it,
         and what each configuration's ``reduced`` cuts."""
         d, out = self.doc, []
         cells = [w["name"] for w in d["workloads"]]
         e2e = {m["name"]: m for m in d["end_to_end"]}
-        for section in ("configs", "workloads", "end_to_end", "per_layer"):
+        for section, most in MOST.items():
             names = [e["name"] for e in d[section]]
-            out += [f"{section}: bad name {n!r}" for n in names
-                    if not NAME_RE.match(n)]
+            if len(names) > most:
+                out.append(f"{section}: {len(names)} entries, the contract's "
+                           f"most is {most}")
+            out += [f"{section}: bad name {n!r} (a name is {NAME_RULE})"
+                    for n in names if not NAME_RE.match(n)]
             out += [f"{section}: duplicate name {n!r}" for n in set(names)
                     if names.count(n) > 1]
+        four = [w["name"] for w in d["workloads"] if w["chips"] == 4]
+        if len(four) > max(1, len(cells) // 4):
+            out.append(f"workloads: {len(four)} cells of {len(cells)} ask "
+                       f"for 4 chips {four}; at most a quarter, rounded "
+                       f"down, may ({len(cells) // 4}), and one always may")
         for m in d["end_to_end"] + d["per_layer"]:
             if not UNIT_RE.match(m["unit"]):
                 out.append(f"{m['name']}: bad unit {m['unit']!r}")
@@ -234,6 +254,7 @@ class Bench:
                     for w in m.get("workloads", ()) if w not in cells]
         if "setup_s" not in e2e:
             out.append("end_to_end: no setup_s")
+        first = {}      # (reader, args, moves) -> the entry that has it
         for m in d["per_layer"]:
             if m["moves"] not in e2e:
                 out.append(f"{m['name']}: moves unknown {m['moves']!r}")
@@ -243,9 +264,17 @@ class Bench:
                     f"{m['moves']!r}" for c in m.get("workloads", cells)
                     if c not in moved]
             try:
+                stem, args = self.resolved(m["name"])
                 self.reader(m["name"])
             except (FileNotFoundError, KeyError, AttributeError) as e:
                 out.append(f"{m['name']}: no reader ({e})")
+                continue
+            key = (stem, json.dumps(args, sort_keys=True), m["moves"])
+            if first.setdefault(key, m["name"]) != m["name"]:
+                out.append(f"{m['name']}: the reader ({stem} {args}) and the "
+                           f"moves ({m['moves']}) of {first[key]!r}: one "
+                           f"entry a reader and a moved metric, so append "
+                           f"the cell to that entry's workloads")
         for c in d["configs"]:
             try:
                 cfg = self._config_file(c["name"])
@@ -261,7 +290,8 @@ class Bench:
         for w in d["workloads"]:
             for key in ("config", "traffic"):
                 if not NAME_RE.match(w[key]):
-                    out.append(f"{w['name']}: bad {key} {w[key]!r}")
+                    out.append(f"{w['name']}: bad {key} {w[key]!r} (a name "
+                               f"is {NAME_RULE})")
             try:
                 cfg = self._config_file(w["config"])
                 mix = self.traffic(w["traffic"])

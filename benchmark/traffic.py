@@ -13,7 +13,11 @@ Kinds (``"kind"`` in the mix's file):
 
 * ``closed`` — ``clients`` callers, each sending its next request the
   instant its last one finished; requests cycle through a grid of ``count``
-  pairs, reshuffled each cycle.
+  pairs, reshuffled each cycle and dealt to whichever caller asks next.
+  With ``"order": "lanes"`` every caller walks the whole grid on ONE fixed
+  walk and the seed only deals the callers their places on it: for a mix
+  whose window holds about one cycle, where the shuffle decides whose long
+  prompts fall inside it (``ClosedPlan``).
 * ``open-fixed-rate`` — arrivals every ``1 / rate_per_s`` seconds whatever
   the server does, each moved by a seeded jitter of at most
   ``jitter_gaps`` (<= 0.5) of a gap; a ramp of ``ramp_seconds`` before the
@@ -53,10 +57,17 @@ def length_pairs(mix, n):
     grid = [(i + 0.5) / n for i in range(n)]
     prompts = [quantile(mix["prompt_len"], u) for u in grid]
     outputs = [quantile(mix["output_len"], u) for u in grid]
-    stride = max(1, round(0.618 * n))
+    stride = coprime_stride(n, 0.618)
+    return [(prompts[i], outputs[(i * stride) % n]) for i in range(n)]
+
+
+def coprime_stride(n, share):
+    """The stride nearest ``share * n`` from above that is coprime to ``n``:
+    ``(i * stride) % n`` then visits all of ``0..n-1``, far apart first."""
+    stride = max(1, round(share * n))
     while math.gcd(stride, n) != 1:
         stride += 1
-    return [(prompts[i], outputs[(i * stride) % n]) for i in range(n)]
+    return stride
 
 
 def offered_per_s(mix, seconds):
@@ -82,8 +93,24 @@ def _request(rng, vocab, prompt_len, output_len):
 
 
 class ClosedPlan:
-    """Requests for a closed loop, drawn without end: each cycle is the
-    whole grid in a fresh seeded order."""
+    """Requests for a closed loop, drawn without end.
+
+    Default: each cycle is the whole grid in a fresh seeded order, dealt to
+    whichever caller asks next. Over a window of many cycles every seed's
+    window holds the same work.
+
+    ``"order": "lanes"``: for a mix whose window holds about ONE cycle, where
+    the shuffle decides whether one or two of the longest prompts end inside
+    it (``video-32k-sat``: six seeds spread 4-6 % on the tail of the gaps,
+    one seed twice 0.3 %). Every caller sends the WHOLE grid, in one fixed
+    walk (grid index ``k * stride % count``, ``stride`` the coprime nearest
+    ``0.382 count``: a caller's consecutive prompts lie far apart in length),
+    and the callers stand at evenly spaced places on that walk, dealt by the
+    seed. Whenever the loop is looked at, the requests in flight are the grid
+    spread over the callers, and any stretch in which every caller ends one
+    request holds the whole grid once, whichever seed and wherever the window
+    falls. What a seed still draws: which caller stands where, and every
+    token id."""
 
     def __init__(self, mix, seed, vocab):
         self.pairs = length_pairs(mix, mix["count"])
@@ -91,13 +118,29 @@ class ClosedPlan:
         self.vocab = vocab
         self.rng = np.random.default_rng(seed)
         self._cycle = []
+        self._place = None
+        order = mix.get("order", "shuffle")
+        if order == "lanes":
+            n = len(self.pairs)
+            stride = coprime_stride(n, 0.382)
+            self._walk = [(k * stride) % n for k in range(n)]
+            self._place = [int(c) * n // self.clients
+                           for c in self.rng.permutation(self.clients)]
+        elif order != "shuffle":
+            raise ValueError(f"unknown closed-loop order {order!r}")
 
-    def take(self):
-        if not self._cycle:
-            order = self.rng.permutation(len(self.pairs))
-            self._cycle = [_request(self.rng, self.vocab, *self.pairs[i])
-                           for i in order[::-1]]
-        return self._cycle.pop()
+    def take(self, client):
+        """Caller ``client``'s next request (a shuffled deck does not look
+        at who asks)."""
+        if self._place is None:
+            if not self._cycle:
+                order = self.rng.permutation(len(self.pairs))
+                self._cycle = [_request(self.rng, self.vocab, *self.pairs[i])
+                               for i in order[::-1]]
+            return self._cycle.pop()
+        i = self._walk[self._place[client] % len(self._walk)]
+        self._place[client] += 1
+        return _request(self.rng, self.vocab, *self.pairs[i])
 
 
 def spread_order(rng, n, block=None):

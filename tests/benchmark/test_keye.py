@@ -20,13 +20,19 @@ NEW = ["dsa_share_pct", "dsa_select_share_pct", "dsa_prefill_roofline",
        "index_bytes_per_token"]
 ALIASES = {"dsa_select_share_pct": {"reader": "dsa_share_pct",
                                     "args": {"labels": ["dsa_select"]}},
-           "serve_tok_s.video": {"reader": "serve_tok_s"},
-           "ragged_fwd_ms.video": {"reader": "ragged_fwd_ms"},
-           "decode_fwd_ms.video": {"reader": "decode_fwd_ms"},
-           "moe_share_pct.video": {"reader": "moe_share_pct"},
-           "serve_idle_pct.video": {"reader": "serve_idle_pct"},
-           "share_ragged_rounds_pct.video": {
+           "serve_tok_s.p95": {"reader": "serve_tok_s"},
+           "ragged_fwd_ms.p95": {"reader": "ragged_fwd_ms"},
+           "decode_fwd_ms.p95": {"reader": "decode_fwd_ms"},
+           "moe_share_pct.p95": {"reader": "moe_share_pct"},
+           "serve_idle_pct.p95": {"reader": "serve_idle_pct"},
+           "share_ragged_rounds_pct.p95": {
                "reader": "ragged_round_share_pct"}}
+# the readers ISSUE 45 asked for and the full list had no place for: since
+# PR 48 the cell JOINS their entries (one a reader and a moved metric)
+JOINED = ["live_seqs_mean.p95", "kv_bytes_per_token",
+          "expert_load_max_over_mean.p95", "round_p50_ms.p95",
+          "serve_program_gib.p95", "kv_step_fill_pct",
+          "ragged_tile_fill_pct.p95"]
 V5E = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 TOPK = 8
 HF = {"model_type": "KeyeVL2", "hidden_size": 64, "intermediate_size": 96,
@@ -244,14 +250,13 @@ def test_the_benchmark_is_sound_with_the_new_entries():
     assert e2e >= {"itl_p95_ms", "setup_s"}
     reports = {m["name"] for m in bench.metrics_of(CELL, "per_layer")}
     # supersets: an entry appended later breaks nothing here
-    assert reports >= {"start_to_chip_s", *NEW, *ALIASES}
+    assert reports >= {"start_to_chip_s", *NEW, *ALIASES, *JOINED}
     for m in bench.doc["per_layer"]:
-        if m["name"] in (*NEW, *ALIASES):
+        if m["name"] in (*NEW, *ALIASES, *JOINED):
             assert CELL in m["workloads"] and m["moves"] == "itl_p95_ms"
     for name, alias in ALIASES.items():
         assert json.loads(bench._find(
             "metrics", name, (".json",)).read_text()) == alias
-    assert len(bench.doc["per_layer"]) <= 128
 
 
 def test_the_mix_is_the_issues_grid_and_fits_the_pool():
@@ -315,10 +320,15 @@ def test_the_cell_runs_is_checked_and_reports_what_the_real_cell_lists(
     obs, m = tiny_cell
     assert obs["correct"] and obs["failed"] == 0 and obs["attempted"] > 4
     bench = spec.Bench()
+    # (the tiny engine attends through XLA, which takes no atoms: the tiles'
+    # fill has nothing to read here; on the chip it read 98.99, PERF.md PR 48)
+    no_atoms = "ragged_tile_fill_pct.p95"
     untraced = {x["name"] for x in bench.metrics_of(CELL, "per_layer")
-                if x["source"] != "device_trace"} - {"start_to_chip_s"}
+                if x["source"] != "device_trace"} - {"start_to_chip_s",
+                                                     no_atoms}
     assert untraced <= set(m), untraced - set(m)
-    assert m["serve_tok_s.video"] > 0 and m["itl_p95_ms"] > 0
+    assert set(JOINED) - {no_atoms} <= untraced and no_atoms not in m
+    assert m["serve_tok_s.p95"] > 0 and m["itl_p95_ms"] > 0
     # 4 layers x one 8-wide float32 key a token
     assert m["index_bytes_per_token"] == 4 * 8 * 4
     assert 0 < m["dsa_kept_pct"] < 100

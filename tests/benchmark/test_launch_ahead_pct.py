@@ -99,7 +99,7 @@ def test_the_metric_is_declared_for_the_two_cells_that_claim():
     bench = spec.Bench()
     entry, = [m for m in bench.doc["per_layer"]
               if m["name"] == "launch_ahead_pct"]
-    assert entry["workloads"] == ["phi2-decode-sat", "olmoe-chat-sat"]
+    assert {"phi2-decode-sat", "olmoe-chat-sat"} <= set(entry["workloads"])
     assert (entry["moves"], entry["source"], entry["layer"]) == (
         "serve_tok_s", "program_span", "serve engine")
     assert not bench.problems()

@@ -107,10 +107,11 @@ def test_a_tiny_session_fills_its_tiles(monkeypatch):
 
 def test_the_metric_is_declared_for_the_one_cell_that_claims():
     bench = spec.Bench()
+    # no place in the list is held and no other cell is shut out: a later PR
+    # appends entries behind it and cells to its ``workloads``
     entry, = [m for m in bench.doc["per_layer"]
               if m["name"] == "moe_tile_fill_pct"]
-    assert entry == bench.doc["per_layer"][-1]
-    assert entry["workloads"] == ["olmoe-chat-sat"]
+    assert "olmoe-chat-sat" in entry["workloads"]
     assert (entry["moves"], entry["source"], entry["layer"]) == (
         "serve_tok_s", "program_counter", "kernels")
     assert not bench.problems()

@@ -13,9 +13,8 @@ from benchmark import spec
 
 CELL, CONFIG, MIX = "ouro-reason-sat", "ouro-2.6b", "reason-loop-sat"
 NEW = ["loop_decode_roofline", "loop_pass_ms", "loop_exit_share_pct"]
-ALIASES = {"kv_bytes_per_token.loop": {"reader": "kv_bytes_per_token"},
+ALIASES = {"kv_bytes_per_token.tok": {"reader": "kv_bytes_per_token"},
            "paged_loop_roofline": {"reader": "paged_roofline"},
-           "launch_ahead_pct.loop": {"reader": "launch_ahead_pct"},
            "loop_exit_share_pct": {"reader": "loop_pass_ms",
                                    "args": {"what": "exit_share_pct"}}}
 V5E = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
@@ -122,14 +121,15 @@ def test_the_benchmark_is_sound_with_the_new_entries():
     e2e = {m["name"] for m in bench.metrics_of(CELL, "end_to_end")}
     assert e2e == {"serve_tok_s", "setup_s"}
     reports = {m["name"] for m in bench.metrics_of(CELL, "per_layer")}
-    assert reports == {
+    # a superset: an entry appended later breaks nothing here
+    assert reports >= {
         "start_to_chip_s", "live_seqs_mean", "ragged_tile_fill_pct",
         "itl_p99_ms.moe", "round_p50_ms.moe", "share_ragged_rounds_pct.moe",
         "serve_program_gib.moe", "decode_fwd_ms.moe", "ragged_fwd_ms.moe",
-        "serve_idle_pct.moe", *NEW, *ALIASES}
+        "serve_idle_pct.moe", "launch_ahead_pct", *NEW, *ALIASES}
     # (no place in ``per_layer`` is held here: a later PR appends behind)
     for m in bench.doc["per_layer"]:
-        if m["name"] in (*NEW, *ALIASES):
+        if m["name"] in ("launch_ahead_pct", *NEW, *ALIASES):
             assert CELL in m["workloads"] and m["moves"] == "serve_tok_s"
     for name, alias in ALIASES.items():
         assert json.loads(bench._find(
@@ -198,8 +198,8 @@ def test_the_cell_runs_is_checked_and_reports_what_the_real_cell_lists(
     assert untraced <= set(m), untraced - set(m)
     assert m["serve_tok_s"] > 0 and m["live_seqs_mean"] > 1
     # 4 passes x 3 layers of K and V, 4 heads of 16, float32
-    assert m["kv_bytes_per_token.loop"] == 4 * L * 2 * 4 * 16 * 4
-    assert 0 < m["launch_ahead_pct.loop"] <= 100
+    assert m["kv_bytes_per_token.tok"] == 4 * L * 2 * 4 * 16 * 4
+    assert 0 < m["launch_ahead_pct"] <= 100
     eng = obs["engine"]
     stats = eng.loop_stats()
     assert stats["exit_pass"][:3] == [0, 0, 0] and stats["exit_pass"][3] > 0
@@ -319,7 +319,7 @@ def test_the_loop_readers_on_a_decode_step(family):
     # read the contexts; here no custom call ran, so the kernel's share has
     # nothing to divide by
     assert bench.reader("paged_loop_roofline")(obs) is None
-    assert bench.reader("kv_bytes_per_token.loop")(obs) == 1_572_864
+    assert bench.reader("kv_bytes_per_token.tok")(obs) == 1_572_864
 
 
 @pytest.mark.parametrize("name", NEW)

@@ -21,7 +21,7 @@ def a_trace(launches):
     host = [["PjitFunction(decode_forward)", 0.99, 0.001],
             ["PjitFunction(dynamic_slice)", 1.055, 0.001],
             ["PjitFunction(ragged_forward)", 1.99, 0.001],
-            ["PjitFunction(decode_multi_8)", 2.99, 0.001]]
+            ["PjitFunction(train_batch_fn)", 2.99, 0.001]]
     host += [[trace.LAUNCH, s, 1e-4]
              for s in (0.9905, 1.0555, 1.9905, 2.9905)[:launches]]
     return {"devices": {PLANE: {"modules": modules, "ops": ops}},
@@ -44,5 +44,5 @@ def test_named_modules_are_named_when_launches_and_modules_differ():
 
 def test_the_join_still_names_an_unnamed_module_when_they_pair():
     names = trace.program_names(a_trace(launches=4), PLANE)
-    assert names["jit__unknown(17)"] == "decode_multi_8"
+    assert names["jit__unknown(17)"] == "train_batch_fn"
     assert names["jit_decode_forward(11)"] == "decode_forward"

@@ -70,6 +70,31 @@ def test_closed_loop_cell_runs_and_counts_tokens_in_the_window(bench):
     assert "decode_fwd_ms" not in m and "serve_idle_pct" not in m
 
 
+def test_closed_loop_by_lanes_gives_each_caller_its_own_walk_of_the_grid(bench):
+    """``"order": "lanes"`` through ``serve.run_closed``: whichever caller
+    ends first, a caller's next request is the next point of ITS walk."""
+    from benchmark import traffic
+
+    obs, m = tiny.drive(bench, "tiny-closed-cell", seed=2**31 + 48,
+                        traffic="tiny-lanes")
+    assert obs["correct"] and obs["failed"] == 0 and obs["attempted"] > 8
+    assert m["serve_tok_s"] > 0
+    mix = bench.traffic("tiny-lanes")
+    pairs = traffic.length_pairs(mix, mix["count"])
+    walk = [pairs[(k * 3) % 8] for k in range(8)]
+    by_caller = {}
+    for r in obs["requests"]:
+        by_caller.setdefault(r["client"], []).append(
+            (len(r["prompt"]), r["budget"]))
+    assert sorted(by_caller) == [0, 1, 2, 3]
+    starts = set()
+    for sent in by_caller.values():
+        at = walk.index(sent[0])
+        starts.add(at)
+        assert sent == [walk[(at + k) % 8] for k in range(len(sent))]
+    assert starts == {0, 2, 4, 6}      # 4 callers evenly over 8 places
+
+
 def test_a_cell_of_a_family_added_by_files_alone_runs_and_is_checked(bench):
     """Sparse experts through the serve path; ``correct`` includes the
     added family's plain reference agreeing with what the engine emitted."""

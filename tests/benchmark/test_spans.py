@@ -108,10 +108,10 @@ def test_the_programs_own_launch_instants_confirm_the_offset(recorded,
         assert spans.traced_rounds({**recorded, "stages": wrong}) is None
         assert "launch_t lies" in capsys.readouterr().err
     # ... and so does one that names a program the trace did not see
-    wrong = [{**s, "data": {**s["data"], "program": "decode_multi_8"}}
+    wrong = [{**s, "data": {**s["data"], "program": "train_batch_fn"}}
              for s in recorded["stages"]]
     assert spans.traced_rounds({**recorded, "stages": wrong}) is None
-    assert "no PjitFunction(decode_multi_8)" in capsys.readouterr().err
+    assert "no PjitFunction(train_batch_fn)" in capsys.readouterr().err
 
 
 # ---------------------------------- each reader on the recorded v5e trace

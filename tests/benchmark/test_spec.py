@@ -256,3 +256,228 @@ def test_an_unknown_name_says_what_exists():
         BENCH.cell("no-such-cell")
     with pytest.raises(FileNotFoundError):
         BENCH.traffic("no-such-mix")
+
+
+# ------------------------------------- the fold of PR 48, held from its data
+# ``per_layer`` had one entry a CELL (128, the contract's most); it has one a
+# reader and a moved metric (102). New name: the names it took the place of.
+FOLDED = {
+    "decode_fwd_ms.p95": "decode_fwd_ms.answers decode_fwd_ms.video",
+    "expert_load_max_over_mean.p95":
+        "expert_load_max_over_mean.docs expert_load_max_over_mean.answers",
+    "itl_p99_ms.p95": "itl_p99_ms.docs itl_p99_ms.answers",
+    "kv_bytes_per_token": "kv_bytes_per_token kv_bytes_per_token.answers",
+    "kv_bytes_per_token.tok":
+        "kv_bytes_per_token.hybrid kv_bytes_per_token.loop",
+    "kv_step_fill_pct": "kv_step_fill_pct kv_step_fill_pct.answers",
+    "launch_ahead_pct":
+        "launch_ahead_pct launch_ahead_pct.hybrid launch_ahead_pct.loop",
+    "live_seqs_mean.p95": "live_seqs_mean.docs live_seqs_mean.answers",
+    "mla_prefill_roofline":
+        "mla_prefill_roofline mla_prefill_answers_roofline",
+    "mla_share_pct": "mla_share_pct mla_share_pct.answers",
+    "moe_p95_roofline": "moe_docs_roofline moe_answers_roofline",
+    "moe_share_pct.p95":
+        "moe_share_pct.docs moe_share_pct.answers moe_share_pct.video",
+    "moe_tile_fill_pct": "moe_tile_fill_pct moe_tile_fill_pct.hybrid",
+    "ragged_fwd_ms.p95":
+        "ragged_fwd_ms.docs ragged_fwd_ms.answers ragged_fwd_ms.video",
+    "share_ragged_rounds_pct.p95":
+        "share_ragged_rounds_pct.docs share_ragged_rounds_pct.answers "
+        "share_ragged_rounds_pct.video",
+    "ragged_tile_fill_pct.p95":
+        "ragged_tile_fill_pct.docs ragged_tile_fill_pct.answers",
+    "round_p50_ms.p95": "round_p50_ms.docs round_p50_ms.answers",
+    "serve_idle_pct.p95":
+        "serve_idle_pct.docs serve_idle_pct.answers serve_idle_pct.video",
+    "serve_program_gib.p95":
+        "serve_program_gib.docs serve_program_gib.answers",
+    "serve_tok_s.p95":
+        "serve_tok_s.docs serve_tok_s.answers serve_tok_s.video"}
+NEW_NAME = {old: new for new, olds in FOLDED.items() for old in olds.split()}
+# the parent's list (PR 47), each entry with what its name ``resolved`` to on
+# the parent's tree: the aliases of the names that went are deleted
+PARENT = spec.load_json(
+    spec.ROOT / "tests/benchmark/data/per_layer_pr47.json")
+# the readers ISSUE 45 wanted in ``keye-video-sat`` and the full list had no
+# place for; the cell joined their entries in PR 48 at no entry
+JOINED = {"keye-video-sat": {
+    "live_seqs_mean.p95", "kv_bytes_per_token",
+    "expert_load_max_over_mean.p95", "round_p50_ms.p95",
+    "serve_program_gib.p95", "kv_step_fill_pct", "ragged_tile_fill_pct.p95"}}
+
+
+def reads(stem, args, moves=None):
+    """What an entry comes to, as something a set can hold."""
+    return (stem, json.dumps(args, sort_keys=True), moves)
+
+
+def test_the_table_is_the_forty_six_and_the_list_keeps_its_order():
+    assert len(NEW_NAME) == 46 and len(FOLDED) == 20
+    assert len(PARENT) == 128
+    was = list(dict.fromkeys(NEW_NAME.get(e["name"], e["name"])
+                             for e in PARENT))
+    # the entries that stood alone keep name and place, a folded entry stands
+    # where its oldest name stood; a later PR appends behind the 102
+    assert [m["name"] for m in DOC["per_layer"]][:len(was)] == was
+    assert len(was) == 102
+
+
+@pytest.mark.parametrize("old", NEW_NAME)
+def test_a_folded_entry_is_its_old_names_reader_and_says_the_same(old):
+    before, = [e for e in PARENT if e["name"] == old]
+    after = BENCH._entry("per_layer", NEW_NAME[old])
+    assert BENCH.resolved(after["name"]) == (
+        before["resolved"]["reader"], before["resolved"]["args"])
+    for key in ("unit", "better", "source", "layer", "moves"):
+        assert after[key] == before[key]
+    assert set(before["workloads"]) <= set(after["workloads"])
+    # the union, in the order of the cells
+    assert after["workloads"] == sorted(after["workloads"], key=CELLS.index)
+    if old != after["name"]:
+        with pytest.raises(KeyError):
+            BENCH._entry("per_layer", old)
+        with pytest.raises(FileNotFoundError):
+            BENCH.reader(old)
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_every_cell_reads_what_the_parent_read_there(cell):
+    """Each (reader, args) the parent reported in the cell, under whatever
+    name, the cell still reports, moving the same end-to-end metric; and
+    nothing more than ``JOINED`` among the entries the parent had."""
+    before = {reads(e["resolved"]["reader"], e["resolved"]["args"],
+                    e["moves"])
+              for e in PARENT if cell in e.get("workloads", [cell])}
+    known = {NEW_NAME.get(e["name"], e["name"]) for e in PARENT}
+    now = {m["name"]: reads(*BENCH.resolved(m["name"]), m["moves"])
+           for m in BENCH.metrics_of(cell, "per_layer")
+           if m["name"] in known}
+    joined = {now[name] for name in JOINED.get(cell, ())}
+    assert set(now.values()) - joined == before
+    assert not joined & before
+
+
+def test_one_entry_a_reader_and_a_moved_metric():
+    seen = [reads(*BENCH.resolved(m["name"]), m["moves"])
+            for m in DOC["per_layer"]]
+    assert len(set(seen)) == len(seen)
+    # a share of a roofline is known by the END of its name, folded or not
+    for m in DOC["per_layer"]:
+        if BENCH.resolved(m["name"])[0].endswith("_roofline"):
+            assert m["name"].endswith("_roofline") and m["unit"] == "%"
+
+
+# ------------------------------------- the driver's limits, in problems()
+def tiny_doc(tmp_path, keep=None):
+    """``tiny.make_root``'s document cut down to its own cells (seven, one
+    of them on four chips), or to ``keep`` of them: the configurations they
+    use and the metrics that list them."""
+    from . import tiny
+
+    doc = tiny.make_root(tmp_path).doc
+    keep = set(keep or (c[0] for c in tiny.CELLS))
+    doc["workloads"] = [w for w in doc["workloads"] if w["name"] in keep]
+    used = {w["config"] for w in doc["workloads"]}
+    doc["configs"] = [c for c in doc["configs"] if c["name"] in used]
+    for section in ("end_to_end", "per_layer"):
+        for m in doc[section]:
+            if "workloads" in m:
+                m["workloads"] = [c for c in m["workloads"] if c in keep]
+        doc[section] = [m for m in doc[section] if m.get("workloads", 1)]
+    moved = {m["name"] for m in doc["end_to_end"]}
+    doc["per_layer"] = [m for m in doc["per_layer"] if m["moves"] in moved]
+    return doc
+
+
+def more_entries(tmp_path, doc, names):
+    """Entries of one reader under ``names``, each with args of its own (so
+    that none is another's double), in the one cell that reader has."""
+    like, = [m for m in doc["per_layer"] if m["name"] == "rounds_per_s"]
+    for i, name in enumerate(names):
+        (tmp_path / "extra" / "metrics" / f"{name}.json").write_text(
+            json.dumps({"reader": "rounds_per_s", "args": {"i": i}}))
+        doc["per_layer"].append({**like, "name": name})
+
+
+def more_cells(doc, n):
+    """``n`` cells more, each a twin of ``tiny-closed-cell``."""
+    for i in range(n):
+        doc["workloads"].append({
+            "name": f"more-cell-{i}", "config": "tiny-serve", "chips": 1,
+            "traffic": "tiny-closed", "why": "tiny"})
+        for m in doc["end_to_end"] + doc["per_layer"]:
+            if "tiny-closed-cell" in m.get("workloads", ()):
+                m["workloads"].append(f"more-cell-{i}")
+
+
+def four_chips(doc, cell):
+    doc["workloads"] = [{**w, "chips": 4} if w["name"] == cell else w
+                        for w in doc["workloads"]]
+
+
+# (what is done to the tiny document, the ONE sentence's parts; none = sound)
+LIMIT_CASES = {
+    "the_128th_entry": (lambda tmp, d: more_entries(
+        tmp, d, [f"more_{i}" for i in range(128 - len(d["per_layer"]))]),
+        None),
+    "a_129th_entry": (lambda tmp, d: more_entries(
+        tmp, d, [f"more_{i}" for i in range(129 - len(d["per_layer"]))]),
+        ("per_layer: 129 entries", "most is 128")),
+    "the_24th_cell": (lambda tmp, d: more_cells(d, 24 - 7), None),
+    "a_25th_cell": (lambda tmp, d: more_cells(d, 25 - 7),
+                    ("workloads: 25 entries", "most is 24")),
+    "a_name_of_64_letters": (lambda tmp, d: more_entries(tmp, d, ["x" * 64]),
+                             None),
+    "a_name_of_65_letters": (
+        lambda tmp, d: more_entries(tmp, d, ["x" * 65]),
+        ("per_layer: bad name 'xxxx", "at most 64 of A-Za-z0-9_.-")),
+    "a_name_that_starts_with_a_dot": (
+        lambda tmp, d: more_entries(tmp, d, [".hidden"]),
+        ("per_layer: bad name '.hidden'", "starts with neither . nor -")),
+    "a_second_four_chip_cell_among_seven": (
+        lambda tmp, d: four_chips(d, "tiny-train-cell"),
+        ("workloads: 2 cells of 7 ask for 4 chips",
+         "['tiny-train-cell', 'tiny-zero3-cell']", "and one always may")),
+    "a_second_four_chip_cell_among_eight": (
+        lambda tmp, d: (more_cells(d, 1), four_chips(d, "tiny-train-cell")),
+        None),
+}
+
+
+@pytest.mark.parametrize("case", LIMIT_CASES)
+def test_the_contracts_limits_are_problems_too(tmp_path, case):
+    """What the driver refuses a file for before a single run, a builder
+    reads here first: each breach is ONE sentence of ``problems()``."""
+    change, refused = LIMIT_CASES[case]
+    doc = tiny_doc(tmp_path)
+    assert len(doc["workloads"]) == 7
+    change(tmp_path, doc)
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(doc))
+    problems = spec.Bench(tmp_path).problems()
+    if refused is None:
+        assert problems == []
+        return
+    assert len(problems) == 1, problems
+    assert all(part in problems[0] for part in refused), problems
+
+
+def test_one_four_chip_cell_always_may(tmp_path):
+    doc = tiny_doc(tmp_path, keep=("tiny-closed-cell", "tiny-zero3-cell"))
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(doc))
+    assert [w["chips"] for w in doc["workloads"]] == [1, 4]
+    assert spec.Bench(tmp_path).problems() == []
+
+
+def test_a_double_of_an_entry_is_told_to_join_it(tmp_path):
+    """What cost the list its room: a cell's own name for an accepted
+    reader that moves what the accepted entry moves."""
+    doc = tiny_doc(tmp_path)
+    (tmp_path / "extra" / "metrics" / "rounds_per_s.mine.json").write_text(
+        json.dumps({"reader": "rounds_per_s"}))
+    like, = [m for m in doc["per_layer"] if m["name"] == "rounds_per_s"]
+    doc["per_layer"].append({**like, "name": "rounds_per_s.mine"})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(doc))
+    problem, = spec.Bench(tmp_path).problems()
+    assert problem.startswith("rounds_per_s.mine: the reader (rounds_per_s")
+    assert "of 'rounds_per_s'" in problem and "append the cell" in problem

@@ -14,12 +14,15 @@ SERVING = {w["traffic"]: BENCH.traffic(w["traffic"])
 SERVING.update({n: m for n, m in tiny.TRAFFIC.items()
                 if m["kind"] != "train-batches"})
 SEEDS = (0, 7, 2**31 + 11)
+# chat-short-sat at seed 0 by the generator of PR 47 (before lanes)
+GOLDEN = [(57, 80), (87, 68), (73, 84), (45, 72), (99, 76), (67, 112)]
 
 
 def lengths_of(mix, seed, seconds=20):
     if mix["kind"] == "closed":
         plan = traffic.ClosedPlan(mix, seed, vocab=1000)
-        reqs = [plan.take() for _ in range(2 * mix["count"])]
+        reqs = [plan.take(k % mix["clients"])
+                for k in range(2 * mix["count"])]
     else:
         reqs = [a for a in traffic.open_arrivals(mix, seed, seconds, 1000)
                 if 0 <= a["due"]]
@@ -54,11 +57,92 @@ def test_token_ids_follow_the_seed(name):
 
     def first(seed):
         if mix["kind"] == "closed":
-            return traffic.ClosedPlan(mix, seed, 1000).take()["tokens"]
+            return traffic.ClosedPlan(mix, seed, 1000).take(0)["tokens"]
         return traffic.open_arrivals(mix, seed, 5, 1000)[0]["tokens"]
 
     assert first(3) == first(3)
     assert first(3) != first(4)
+
+
+# ------------------------------------------------ a closed loop by lanes
+LANES = {n: m for n, m in SERVING.items() if m.get("order") == "lanes"}
+
+
+def sent_by_caller(mix, seed, rounds):
+    plan = traffic.ClosedPlan(mix, seed, vocab=1000)
+    sent = {c: [] for c in range(mix["clients"])}
+    for _ in range(rounds):
+        for c in reversed(range(mix["clients"])):   # any order of asking
+            r = plan.take(c)
+            sent[c].append((len(r["tokens"]), r["max_new_tokens"]))
+    return sent
+
+
+def test_the_video_mix_goes_by_lanes():
+    assert "video-32k-sat" in LANES and "tiny-lanes" in LANES
+
+
+@pytest.mark.parametrize("seed", SEEDS)
+@pytest.mark.parametrize("name", sorted(LANES))
+def test_by_lanes_every_caller_and_every_instant_hold_the_whole_grid(name,
+                                                                     seed):
+    mix = LANES[name]
+    n, clients = mix["count"], mix["clients"]
+    grid = collections.Counter(traffic.length_pairs(mix, n))
+    sent = sent_by_caller(mix, seed, 2 * n)
+    for mine in sent.values():
+        # a caller's any n consecutive requests are the grid
+        assert all(collections.Counter(mine[k:k + n]) == grid
+                   for k in range(n))
+    for k in range(2 * n):
+        # the k-th requests of the callers: distinct, evenly over the walk
+        now = [sent[c][k] for c in range(clients)]
+        assert len(set(now)) == clients
+        if clients == n:
+            assert collections.Counter(now) == grid
+
+
+@pytest.mark.parametrize("name", sorted(LANES))
+def test_by_lanes_a_seed_deals_the_places_and_never_changes_the_walk(name):
+    mix = LANES[name]
+    n = mix["count"]
+    walks = []
+    for seed in SEEDS:
+        sent = sent_by_caller(mix, seed, n)
+        firsts = [sent[c][0] for c in sorted(sent)]
+        walks.append(firsts)
+        # every caller's walk is a rotation of caller 0's
+        base = sent[0] + sent[0]
+        for mine in sent.values():
+            at = base.index(mine[0])
+            assert base[at:at + n] == mine
+    assert len({tuple(w) for w in walks}) > 1      # the places: the seed's
+    # and the walk: the same under every seed, consecutive points far apart
+    pairs = traffic.length_pairs(mix, n)
+    mine = sent_by_caller(mix, SEEDS[0], n)[0]
+    at = pairs.index(mine[0])
+    stride = traffic.coprime_stride(n, 0.382)
+    assert mine == [pairs[(at + k * stride) % n] for k in range(n)]
+
+
+def test_a_shuffled_deck_ignores_who_asks_and_draws_what_it_drew_before():
+    """Every mix but the lanes' keeps its deck: the same numbers whether or
+    not the caller says who it is, and for ``chat-short-sat`` at seed 0 the
+    lengths the generator gave before it knew of lanes."""
+    mix = SERVING["chat-short-sat"]
+    a = traffic.ClosedPlan(mix, 0, 1000)
+    b = traffic.ClosedPlan(mix, 0, 1000)
+    for k in range(3 * mix["count"]):
+        assert a.take(0) == b.take(k % mix["clients"])
+    plan = traffic.ClosedPlan(mix, 0, 1000)
+    first = [plan.take(0) for _ in range(6)]
+    assert [(len(r["tokens"]), r["max_new_tokens"]) for r in first] == GOLDEN
+
+
+def test_an_unknown_order_is_refused():
+    with pytest.raises(ValueError, match="order"):
+        traffic.ClosedPlan({**SERVING["tiny-closed"], "order": "sorted"},
+                           0, 1000)
 
 
 def test_lengths_stay_inside_the_stated_range_and_quantiles_are_ordered():

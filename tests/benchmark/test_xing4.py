@@ -240,18 +240,18 @@ def test_the_benchmark_is_sound_with_the_new_entries():
         "xing4-29b-a4b-d6", "docs-8k-sat", 1)
     reports = {m["name"] for section in ("end_to_end", "per_layer")
                for m in bench.metrics_of("xing4-docs-sat", section)}
-    assert {"itl_p95_ms", "setup_s", "serve_tok_s.docs", "mla_share_pct",
+    assert {"itl_p95_ms", "setup_s", "serve_tok_s.p95", "mla_share_pct",
             "mla_prefill_roofline", "mla_decode_roofline", "mhc_share_pct",
-            "kv_bytes_per_token", "moe_docs_roofline",
-            "expert_load_max_over_mean.docs",
-            "ragged_tile_fill_pct.docs"} <= reports
+            "kv_bytes_per_token", "moe_p95_roofline",
+            "expert_load_max_over_mean.p95",
+            "ragged_tile_fill_pct.p95"} <= reports
     # tokens/s swing too widely here to be judged (PERF.md section 2): the
     # cell's end-to-end metric is the tail of the gaps, and every per-layer
     # metric it reports moves that one
     assert "serve_tok_s" not in reports
     for m in bench.metrics_of("xing4-docs-sat", "per_layer"):
         if "workloads" in m:     # without the key: every cell's, setup_s
-            assert m["workloads"] == ["xing4-docs-sat"]
+            assert "xing4-docs-sat" in m["workloads"]
             assert m["moves"] == "itl_p95_ms"
 
 
@@ -307,12 +307,12 @@ def tiny_cell(tmp_path_factory, family):
 def test_the_cell_runs_is_checked_and_counts_its_pool(tiny_cell):
     obs, m = tiny_cell
     assert obs["correct"] and obs["failed"] == 0 and obs["attempted"] > 4
-    assert m["serve_tok_s.docs"] > 0 and m["live_seqs_mean.docs"] > 1
-    assert m["itl_p99_ms.docs"] >= m["itl_p95_ms"] > 0
+    assert m["serve_tok_s.p95"] > 0 and m["live_seqs_mean.p95"] > 1
+    assert m["itl_p99_ms.p95"] >= m["itl_p95_ms"] > 0
     eng = obs["engine"]
     # three layers of one 40-wide float32 row (no lane padding off the TPU)
     assert eng.kv.v is None and m["kv_bytes_per_token"] == 3 * 40 * 4
-    assert 1.0 <= m["expert_load_max_over_mean.docs"] < 4.0
+    assert 1.0 <= m["expert_load_max_over_mean.p95"] < 4.0
     stats = eng.moe_stats()
     assert stats["load"].shape == (2, 8)
     assert (stats["load"].sum(1) == 3 * stats["live_tokens"]).all()

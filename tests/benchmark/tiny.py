@@ -126,6 +126,10 @@ TRAFFIC = {
     "tiny-closed": {"kind": "closed", "clients": 4, "count": 8,
                     "prompt_len": {"dist": "uniform", "min": 4, "max": 40},
                     "output_len": {"dist": "uniform", "min": 3, "max": 8}},
+    "tiny-lanes": {"kind": "closed", "clients": 4, "count": 8,
+                   "order": "lanes",
+                   "prompt_len": {"dist": "uniform", "min": 4, "max": 40},
+                   "output_len": {"dist": "uniform", "min": 3, "max": 8}},
     "tiny-open": {"kind": "open-fixed-rate", "rate_per_s": 8.0,
                   "jitter_gaps": 0.5, "ramp_seconds": 0.5,
                   "prompt_len": {"dist": "lognormal", "median": 20,
@@ -222,13 +226,15 @@ class NoHooks:
         pass
 
 
-def drive(bench, cell_name, seed=0, seconds=1.0):
+def drive(bench, cell_name, seed=0, seconds=1.0, traffic=None):
     """What ``benchmark.run.main`` does after its device check, on the CPU:
-    returns the observations and every metric the cell's readers give."""
+    returns the observations and every metric the cell's readers give.
+    ``traffic`` names another mix than the cell's own."""
     from benchmark import run
 
     cell = bench.cell(cell_name)
-    cfg, mix = bench.config(cell["config"]), bench.traffic(cell["traffic"])
+    cfg = bench.config(cell["config"])
+    mix = bench.traffic(traffic or cell["traffic"])
     args = types.SimpleNamespace(seed=seed, seconds=seconds, trace=0)
     obs = {"cell": cell, "config": cfg, "traffic": mix, "chips": cell["chips"],
            "family": bench.family(cfg),
