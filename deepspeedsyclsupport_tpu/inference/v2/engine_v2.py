@@ -196,12 +196,16 @@ class InferenceEngineV2:
         self.kv = init_blocked_kv(model.config, cfg, self.topology)
         self.allocator = BlockedAllocator(cfg.num_blocks)
         self.seqs: Dict[int, SequenceDescriptor] = {}
-        # a model with Mamba layers: the free places of the recurrent-state
-        # pool, one a live sequence from its descriptor's making to its
-        # flush or eviction (None: the model has no such state)
+        # a model with recurrent state (Mamba-2 or power-retention layers):
+        # the free places of the state pool, one a live sequence from its
+        # descriptor's making to its flush or eviction (None: the model has
+        # no such state)
         self._state_free: Optional[List[int]] = None
-        if self.kv.ssm is not None:
+        if self.kv.state:
             self._state_free = list(range(cfg.max_sequences))
+        # a stack in which no layer caches a key has a pool with no rows: a
+        # sequence takes no block, whatever its context
+        self._caches_kv = model.config.num_kv_layers > 0
         # SLA layer (serving.ServingSession) installs a scheduler.SlackPolicy
         # here; put() then orders chunks by slack instead of arrival. None =
         # the pre-SLA least-recently-served ordering.
@@ -257,7 +261,8 @@ class InferenceEngineV2:
         except KeyError as e:
             # get_impl's message already names the registered impls
             raise ValueError(str(e)) from e
-        self._use_atoms = bool(spec.metadata.get("needs_atoms"))
+        self._use_atoms = bool(spec.metadata.get("needs_atoms")) \
+            and self._caches_kv
         chose_atom = isinstance(config, RaggedInferenceConfig) or {
             **(config or {}), **kw}.get("atom_q_size") is not None
         from ...ops.paged_attention import default_atom_rows, kv_step_keys
@@ -279,12 +284,13 @@ class InferenceEngineV2:
                          bool(model.config.index_topk) and rows > 1)
             for rows in (cfg.atom_q_size, 1))
         # the static shapes of ragged_forward, smallest first, by the rows of
-        # an atom and of a Mamba piece (0: the model takes none): a mixed
+        # an atom and of a state layer's piece (0: the model takes none): a
+        # mixed
         # round runs at the first that holds it (_run), none under
         # _rows_floor (warmup() compiles a shape by raising it)
         self._tiles = (
             cfg.atom_q_size if self._use_atoms else 0,
-            model.config.ssm_chunk_size if self.kv.ssm is not None else 0)
+            model.config.state_chunk_size if self.kv.state else 0)
         self._shapes = ragged_shapes(cfg.max_tokens_per_batch,
                                      cfg.max_sequences, *self._tiles)
         self._rows_floor = 0
@@ -406,10 +412,12 @@ class InferenceEngineV2:
         self._forward_tokens += sum(lengths)
         if self.round_spans is None:
             return
+        # (a pool with no rows: no attention reads anything, all four are 0)
         attn_pairs, dec_ctx_tokens, kv_step_keys, kv_tile_keys = \
             attention_work(descs, lengths,
                            self.config.atom_q_size if self._use_atoms else 0,
-                           self._kv_step_keys)
+                           self._kv_step_keys) if self._caches_kv \
+            else (0, 0, 0, 0)
         self.round_spans.fields.update(
             attn_pairs=attn_pairs, dec_ctx_tokens=dec_ctx_tokens,
             kv_step_keys=kv_step_keys, kv_tile_keys=kv_tile_keys,
@@ -429,16 +437,22 @@ class InferenceEngineV2:
             self.round_spans.fields.update(sel_pairs=sel_pairs,
                                            dec_sel_tokens=dec_sel_tokens)
         if self._state_free is not None:
-            # live rows through the Mamba layers, and the sequence pieces
+            # live rows through the state layers, and the sequence pieces
             # whose state they read and wrote (a one-token chunk is one
-            # piece, a longer one a piece every ssm_chunk_size rows), summed
-            # over those layers
+            # piece, a longer one a piece every state_chunk_size rows),
+            # summed over those layers: ssm_* for Mamba-2 layers, ret_* for
+            # power-retention layers, which also say how many of the pieces
+            # start a sequence (they read no state)
             mc = self.model.config
-            q = mc.ssm_chunk_size
-            self.round_spans.fields.update(
-                ssm_rows=sum(lengths),
-                ssm_pieces=sum(-(-n // q) for n in lengths)
-                * mc.pattern_count("M"))
+            q = mc.state_chunk_size
+            kind = "ret" if mc.retention_degree else "ssm"
+            self.round_spans.fields.update({
+                f"{kind}_rows": sum(lengths),
+                f"{kind}_pieces": sum(-(-n // q) for n in lengths)
+                * mc.state_layers})
+            if mc.retention_degree:
+                self.round_spans.fields["ret_first"] = sum(
+                    d.n_cached == 0 for d in descs) * mc.state_layers
         if self.kv.exit_pass is not None:
             # a looped stack: the passes the forward runs over its layers,
             # and the cache rows it writes and attends a token
@@ -483,9 +497,11 @@ class InferenceEngineV2:
                 "exit_pass": np.asarray(self.kv.exit_pass).tolist()}
 
     def state_stats(self) -> Optional[Dict[str, Any]]:
-        """The recurrent state of a model with Mamba layers (None for any
-        other): ``bytes_per_slot`` (SSM state and convolution tail, all its
-        layers), ``slots``, ``slots_live``, ``dtype``, ``pool_bytes``."""
+        """The recurrent state of a model that keeps one, of either kind
+        (None for any other): ``bytes_per_slot`` (all its state layers:
+        Mamba-2's SSM state and convolution tail, power retention's state
+        and normaliser), ``slots``, ``slots_live``, ``dtype``, ``layers``,
+        ``pool_bytes``."""
         return state_pool_stats(self.kv, sum(
             d.state_slot is not None for d in self.seqs.values()))
 
@@ -493,7 +509,8 @@ class InferenceEngineV2:
         if self._state_free is not None:
             raise NotImplementedError(
                 f"{what} is not available for a model with recurrent state "
-                f"(ModelConfig.layer_pattern): it would need {missing}")
+                f"(ModelConfig.state_layers: Mamba-2 or power-retention "
+                f"layers): it would need {missing}")
 
     def _new_seq(self, uid: int, **fields) -> SequenceDescriptor:
         """A fresh descriptor in ``seqs``; a model with recurrent state
@@ -506,7 +523,8 @@ class InferenceEngineV2:
                     f"{len(self.seqs)} sequences live of max_sequences "
                     f"{self.config.max_sequences}")
             fields["state_slot"] = self._state_free.pop()
-        d = self.seqs[uid] = SequenceDescriptor(uid=uid, **fields)
+        d = self.seqs[uid] = SequenceDescriptor(
+            uid=uid, caches_kv=self._caches_kv, **fields)
         return d
 
     def _drop_seq(self, uid: int) -> Optional[SequenceDescriptor]:
@@ -653,7 +671,8 @@ class InferenceEngineV2:
                 # probe's ≥1-novel-token rule)
                 shared = min(int(cached_prefix.get(u, 0)),
                              max(0, n - 1)) // cfg.block_size
-            want = max(0, -(-(cached + n) // cfg.block_size) - have - shared)
+            want = max(0, -(-(cached + n) // cfg.block_size) - have - shared) \
+                if self._caches_kv else 0
             if want > free:
                 rejected[u] = (f"kv: needs {want} blocks, "
                                f"{free} free in the pool")
@@ -1012,7 +1031,7 @@ class InferenceEngineV2:
                                rows=shape.rows)
             state = () if self._state_free is None else (ssm_pieces(
                 chunks, shape.rows, cfg.max_sequences,
-                self.model.config.ssm_chunk_size, shape.pieces),)
+                self.model.config.state_chunk_size, shape.pieces),)
         with self._phase("dispatch"):
             tokens, sampled, take_from = self._token_operands(batch.tokens,
                                                               sampled)

@@ -28,7 +28,11 @@ forwards carry the whole pool through their layer loop, scatter new rows
 into ``[layer, slot]`` in place and hand the kernels the pool and the layer
 (``model._pool_write`` / ``_scan_layers``): sliced by layer outside a
 kernel, each layer cost a slice, a copy and a write-back of itself on the
-v5e and the pool was held twice (PERF.md, PR 25).
+v5e and the pool was held twice (PERF.md, PR 25). A stack in which NO layer
+caches a key (``ModelConfig.num_kv_layers`` 0: power retention) has pools
+with no rows, ``[0, num_blocks * block_size, KVH, D]``: a sequence takes no
+block, nothing is ever evicted for want of one, a token costs the pool 0
+bytes, and what a sequence costs is its recurrent-state slot alone.
 """
 from typing import NamedTuple, Optional
 
@@ -43,6 +47,10 @@ from .config import RaggedInferenceConfig
 # setting: a narrower state is rounded at every step of every sequence, a
 # different result and not a faster one.
 SSM_STATE_DTYPE = jnp.float32
+# A power-retention layer's state and normaliser: float32 for the same
+# reason (a sum over a whole context of products that cancel), a constant
+# too.
+RETENTION_STATE_DTYPE = jnp.float32
 
 
 class MoeCounters(NamedTuple):
@@ -77,17 +85,23 @@ class BlockedKV(NamedTuple):
     # the device, through every forward's layer loop, donated and handed
     # back, so counting costs no launch and no transfer.
     moe: Optional[MoeCounters] = None
-    # a model with Mamba layers only (``ModelConfig.layer_pattern``; None
-    # elsewhere: no leaf, the same program): the recurrent state, per Mamba
-    # layer and sequence SLOT, fixed in size whatever the context. ``ssm``
-    # [L_m, S + 1, g, n, (h / g) x p] in :data:`SSM_STATE_DTYPE` and
+    # a model with recurrent state only (None elsewhere: no leaf, the same
+    # program): the state, per state layer (``ModelConfig.state_layers``)
+    # and sequence SLOT, fixed in size whatever the context, of ONE of two
+    # kinds (:attr:`state`). Mamba-2 layers (``ModelConfig.layer_pattern``):
+    # ``ssm`` [L_m, S + 1, g, n, (h / g) x p] in :data:`SSM_STATE_DTYPE` and
     # ``conv`` [L_m, kernel - 1, S + 1, channels], the convolution's tail,
-    # in the pool's dtype (``ops/ssm.py`` has the layout's why). Slot ``S``
+    # in the pool's dtype (``ops/ssm.py`` has the layout's why).
+    # Power-retention layers (``ModelConfig.retention_degree``): ``ret_s``
+    # [L, S + 1, KVH, D, features] and ``ret_z`` [L, S + 1, KVH, features]
+    # in :data:`RETENTION_STATE_DTYPE` (``ops/retention.py``). Slot ``S``
     # is the sink padding rows write to. They ride the forwards as the pool
     # does: donated, in the layer loop's carry, updated in place. Nothing
     # resets a slot: a piece whose first position is 0 starts from zeros.
     ssm: Optional[jnp.ndarray] = None
     conv: Optional[jnp.ndarray] = None
+    ret_s: Optional[jnp.ndarray] = None
+    ret_z: Optional[jnp.ndarray] = None
     # a looped stack only (``ModelConfig.total_ut_steps`` > 1; None
     # elsewhere: no leaf, the same program): [passes] int32, the rows the
     # forwards unembedded for a live sequence, by the pass the exit rule
@@ -107,9 +121,27 @@ class BlockedKV(NamedTuple):
         return self.k.shape[1]
 
     @property
+    def state_names(self):
+        """The fields that hold recurrent state: one kind's two, or ()."""
+        return next((pair for pair in STATE_NAMES
+                     if getattr(self, pair[0]) is not None), ())
+
+    @property
     def state(self):
-        """The recurrent-state arrays there are: (ssm, conv) or ()."""
-        return () if self.ssm is None else (self.ssm, self.conv)
+        """The recurrent-state arrays there are: (ssm, conv), (ret_s,
+        ret_z) or ()."""
+        return tuple(getattr(self, n) for n in self.state_names)
+
+    @property
+    def state_slots(self) -> int:
+        """Sequence slots of the recurrent state, the sink not counted (0:
+        the model keeps none)."""
+        return self.state[0].shape[1] - 1 if self.state else 0
+
+    def with_state(self, state) -> "BlockedKV":
+        """This cache with ``state`` (as :attr:`state` gives it) in the
+        place of its own."""
+        return self._replace(**dict(zip(self.state_names, state)))
 
     @property
     def pools(self):
@@ -127,6 +159,9 @@ class BlockedKV(NamedTuple):
 
 # the fields of :class:`BlockedKV` that are pools addressed by block tables
 POOL_NAMES = ("k", "v", "idx")
+# ... and those that are recurrent state addressed by sequence slot, a pair
+# a kind of state layer; the FIRST of a pair has its slots on axis 1
+STATE_NAMES = (("ssm", "conv"), ("ret_s", "ret_z"))
 
 
 def lane_padded_head_dim(head_dim: int, pad) -> int:
@@ -184,6 +219,21 @@ def init_blocked_kv(model_config, cfg: RaggedInferenceConfig,
             conv=jnp.zeros((lead[0], mc.ssm_conv_kernel - 1, lead[1],
                             mc.ssm_conv_dim), cfg.dtype)),
             out_shardings=topology.replicated())()
+    if model_config.retention_degree:
+        from ...ops.retention import state_dim
+
+        mc = model_config
+        lead = (mc.num_layers, cfg.max_sequences + 1, kvh)
+        dim = state_dim(mc.head_dim)
+        if np.prod(lead) * mc.head_dim * dim >= 2**31:
+            raise ValueError(
+                f"the retention state [{lead}, {mc.head_dim}, {dim}] passes "
+                f"2^31 elements, which one array may not: fewer "
+                f"max_sequences or layers")
+        state = jax.jit(lambda: dict(
+            ret_s=jnp.zeros((*lead, mc.head_dim, dim), RETENTION_STATE_DTYPE),
+            ret_z=jnp.zeros((*lead, dim), RETENTION_STATE_DTYPE)),
+            out_shardings=topology.replicated())()
     if model_config.index_topk:
         if cfg.block_size % 2:
             raise ValueError("a sparse-attention indexer's keys lie two "
@@ -199,17 +249,20 @@ def init_blocked_kv(model_config, cfg: RaggedInferenceConfig,
 
 
 def state_pool_stats(kv: BlockedKV, live: int) -> Optional[dict]:
-    """What the recurrent state of a model with Mamba layers costs (None
-    for a model without): bytes a sequence slot over all its layers, the
-    slots there are (the sink not counted) and how many are a sequence's
-    now, and the state's dtype. Shape-only, no transfer."""
-    if kv.ssm is None:
+    """What the recurrent state of a model costs, of either kind (Mamba-2
+    layers' SSM state and convolution tail, power-retention layers' state
+    and normaliser; None for a model without): bytes a sequence slot over
+    all its state layers, the slots there are (the sink not counted) and
+    how many are a sequence's now, the state's dtype and its layers.
+    Shape-only, no transfer."""
+    if not kv.state:
         return None
-    slots = kv.ssm.shape[1] - 1
+    slots = kv.state_slots
     per_slot = sum(a.size // (slots + 1) * a.dtype.itemsize
                    for a in kv.state)
     return {"bytes_per_slot": per_slot, "slots": slots, "slots_live": live,
-            "dtype": str(kv.ssm.dtype), "pool_bytes": per_slot * (slots + 1)}
+            "dtype": str(kv.state[0].dtype), "layers": kv.state[0].shape[0],
+            "pool_bytes": per_slot * (slots + 1)}
 
 
 def kv_pool_stats(kv: BlockedKV, allocator) -> dict:

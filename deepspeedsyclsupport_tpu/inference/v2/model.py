@@ -40,7 +40,12 @@ of the Mamba layers (``ops/ssm.py``), each with an index of its own. And a
 fifth: a looped stack (``cfg.total_ut_steps``: the layers run several times
 over shared weights, :func:`_scan_passes`), whose pool has a row for every
 (pass, layer) pair and whose logits the exit gate chooses among the passes.
+And a sixth: power retention (``cfg.retention_degree``) in the place of
+attention on the uniform block: :func:`_retention_rows` gives the rows,
+``ops/retention.py`` the recurrence against a state that rides the layer
+loop's carry behind the (empty) pools (:func:`_scan_layers`).
 """
+from contextlib import nullcontext
 from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
@@ -128,7 +133,9 @@ def _qkv(p, y, cfg, n):
 def _attn_out(p, attn, cfg, n):
     if cfg.kv_lora_rank:
         return _mla_out(p, attn, cfg, n)
-    out = jnp.einsum("tq,qd->td", attn.reshape(n, cfg.q_dim), p["wo"])
+    # (a power-retention layer's output projection counts with its others)
+    with scope("ret_proj") if cfg.retention_degree else nullcontext():
+        out = jnp.einsum("tq,qd->td", attn.reshape(n, cfg.q_dim), p["wo"])
     if cfg.attn_out_bias:
         out = out + p["bo"].astype(out.dtype)
     return out
@@ -526,30 +533,54 @@ register_impl("decode_attn", "pallas_interpret", priority=-10,
 register_impl("decode_attn", "xla", priority=0)(_decode_dispatch("xla"))
 
 
-# ssm_step kind: a Mamba layer's one-token state update (``ops/ssm.py``:
-# the in-place Pallas kernel on the TPU, gather/update/scatter elsewhere)
-def _ssm_dispatch(impl_name):
+# ssm_step / ret_step kinds: a state layer's one-token update, Mamba-2's
+# (``ops/ssm.py``) and power retention's (``ops/retention.py``): the in-place
+# Pallas kernel on the TPU, gather/update/scatter elsewhere
+def _state_dispatch(kind, impl_name):
     def fn(*args):
-        from ...ops.ssm import STATE_STEPS
+        from ...ops import retention, ssm
 
-        return STATE_STEPS[impl_name](*args)
+        steps = {"ssm_step": ssm, "ret_step": retention}[kind].STATE_STEPS
+        return steps[impl_name](*args)
     return fn
 
 
-register_impl("ssm_step", "pallas", priority=10,
-              auto_eligible=lambda c: c.get("backend") == "tpu")(
-    _ssm_dispatch("pallas"))
-register_impl("ssm_step", "pallas_interpret", priority=-10,
-              auto_eligible=lambda c: False)(
-    _ssm_dispatch("pallas_interpret"))
-register_impl("ssm_step", "xla", priority=0)(_ssm_dispatch("xla"))
+for _kind in ("ssm_step", "ret_step"):
+    register_impl(_kind, "pallas", priority=10,
+                  auto_eligible=lambda c: c.get("backend") == "tpu")(
+        _state_dispatch(_kind, "pallas"))
+    register_impl(_kind, "pallas_interpret", priority=-10,
+                  auto_eligible=lambda c: False)(
+        _state_dispatch(_kind, "pallas_interpret"))
+    register_impl(_kind, "xla", priority=0)(_state_dispatch(_kind, "xla"))
+
+
+def _state_step_fn(kind):
+    """The platform's state step: no setting names one (the registry is the
+    seam a test or a third party puts another behind)."""
+    return select_impl(kind, "auto", {"backend": jax.default_backend()}).fn
 
 
 def _ssm_step_fn():
-    """The platform's state step: no setting names one (the registry is the
-    seam a test or a third party puts another behind)."""
-    return select_impl("ssm_step", "auto",
-                       {"backend": jax.default_backend()}).fn
+    return _state_step_fn("ssm_step")
+
+
+def _ret_step_fn():
+    return _state_step_fn("ret_step")
+
+
+def _retention_rows(p, y, cfg, positions):
+    """What a power-retention layer takes of flat tokens y [n, D]: the
+    positioned queries [n, H, D_k], keys and values [n, KVH, D_k] (scope
+    ``ret_proj``) and the log of each KV head's gate [n, KVH], float32
+    (``ret_gate``)."""
+    with scope("ret_proj"):
+        q, (k, v) = _q_and_rows(p, y, cfg, positions)
+    with scope("ret_gate"):
+        gam = jax.nn.log_sigmoid(
+            y.astype(jnp.float32) @ p["g_proj"].astype(jnp.float32)
+            + p["g_bias"].astype(jnp.float32))
+    return q, k, v, gam
 
 
 def _pool_write(pools, layer, dest, rows):
@@ -640,7 +671,10 @@ def _scan_layers(layer, x, kv: BlockedKV, params, cfg):
     over its own columns, ``cfg.held_experts``, and a pool that counts
     ``tiles`` gets the row tiles those rows fill (:func:`moe_tile_rows`).
     Returns ``(x, the new BlockedKV)``."""
-    carry, first = (x, kv.pools), 0
+    # (a uniform stack with recurrent state, power retention, carries it
+    # behind its pools, which then have no rows)
+    n_pools = len(kv.pools)
+    carry, first = (x, kv.pools + kv.state), 0
     if "dense_layers" in params:
         dense = params["dense_layers"]
         first = jax.tree_util.tree_leaves(dense)[0].shape[0]
@@ -653,8 +687,9 @@ def _scan_layers(layer, x, kv: BlockedKV, params, cfg):
         return layer(carry, p, l, (stack, l - first))
 
     (x, pools), rows = jax.lax.scan(
-        body, carry, (layers, jnp.arange(first, kv.k.shape[0])))
-    return x, kv.with_pools(pools)._replace(
+        body, carry, (layers, jnp.arange(first, cfg.num_layers)))
+    return x, kv.with_pools(pools[:n_pools]).with_state(
+        pools[n_pools:])._replace(
         moe=_count_moe(kv.moe, rows, cfg, x.shape[-2]))
 
 
@@ -832,8 +867,7 @@ def _walk_pattern(cfg, params, x, kv: BlockedKV, attend, ssm_step, live):
     x, pools, state = carry
     moe = _count_moe(kv.moe, jnp.concatenate(routed) if routed else None,
                      cfg, x.shape[-2])
-    return x, kv.with_pools(pools)._replace(
-        moe=moe, **dict(zip(("ssm", "conv"), state)))
+    return x, kv.with_pools(pools).with_state(state)._replace(moe=moe)
 
 
 def _tokens_in(tokens, sampled, take_from):
@@ -864,8 +898,9 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
     ``atom_*`` and ``dec_*`` are ``RaggedBatch.tile_args``, what the
     ``kernel`` attention takes (the others route by ``token_seq`` alone).
     ``sampled`` / ``take_from`` [T]: :func:`_tokens_in`. ``ssm``: a model
-    with Mamba layers' ``ragged.SsmBatch`` (which state slot each chunk's
-    sequence has, the one-token chunks, the pieces of the longer ones).
+    with recurrent state's ``ragged.SsmBatch`` (which state slot each
+    chunk's sequence has, the one-token chunks, the pieces of the longer
+    ones), for Mamba-2 and power-retention layers alike.
     """
     cfg = model.config
     assert cfg.scan_layers, "ragged engine requires scan_layers param layout"
@@ -895,6 +930,8 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
         # resolved through the pluggable registry (module_registry.py — the
         # reference's module_registry + heuristics seam). Static per trace:
         # atom presence and backend are trace-time constants.
+        if cfg.retention_degree:
+            return retain(p_attn, y, pools, l)
         spec = select_impl("prefill_attn", attn_impl, {
             "backend": jax.default_backend(),
             "has_atoms": atom_qidx is not None,
@@ -920,6 +957,28 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
             return ragged_attend(q, *idx_rows, pools, l, ctx, cfg,
                                  spec.name)[..., :keep], pools
         return spec.fn(q, ctx)[..., :keep], pools
+
+    def retain(p_attn, y, pools, l):
+        """Layer ``l``'s power retention over the normed rows y, in the
+        place of attention: the pieces of the chunks of two tokens or more
+        through the chunked form, the one-token chunks through the decode
+        step, each against ITS slot's state, which is the carry's last two
+        leaves. -> (rows [T, H, D], pools)."""
+        from ...ops.retention import chunked, decode_step
+
+        q, k, v, gam = _retention_rows(p_attn, y, cfg, token_pos)
+        out, state = chunked(
+            q, k, v, gam, pools[-2:], l,
+            (ssm.row0, ssm.length, ssm.slot, ssm.fresh, ssm.count), cfg)
+        one, at = ssm.dec_len > 0, ssm.dec_row
+        with scope("ret_scan"):
+            out_dec, state = decode_step(
+                q[at], k[at], v[at], gam[at], state, l,
+                jnp.where(one, ssm.seq_slot, state[0].shape[1] - 1),
+                ssm.dec_len == 1, cfg, _ret_step_fn())
+        # a slot with no one-token chunk scatters out of range (dropped)
+        out = out.at[jnp.where(one, at, t)].set(out_dec, mode="drop")
+        return out.astype(y.dtype), (*pools[:-2], *state)
 
     def layer(carry, p, l, experts):
         x, pools = carry
@@ -1002,7 +1061,7 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
     serving spends most of its life in, so it gets the kernel; mixed
     prefill+decode batches take :func:`ragged_forward`.
     ``sampled`` / ``take_from`` [S]: :func:`_tokens_in`. ``state_slot``
-    [S]: a model with Mamba layers' recurrent-state slot of each row's
+    [S]: a model with recurrent state's slot of each row's
     sequence (rows change place from forward to forward; a state does not).
     """
     cfg = model.config
@@ -1020,6 +1079,16 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
                cfg)
 
     def attend(p_attn, y, pools, l):
+        if cfg.retention_degree:    # the state step in the place of attention
+            from ...ops.retention import decode_step
+
+            q, k, v, gam = _retention_rows(p_attn, y, cfg, positions)
+            with scope("ret_scan"):
+                out, state = decode_step(
+                    q, k, v, gam, pools[-2:], l,
+                    jnp.where(active, state_slot, pools[-2].shape[1] - 1),
+                    positions == 0, cfg, _ret_step_fn())
+            return out.astype(y.dtype), (*pools[:-2], *state)
         spec = select_impl("decode_attn", attn_impl,
                            {"backend": jax.default_backend()})
         q, new = _q_and_rows(p_attn, y, cfg, positions)

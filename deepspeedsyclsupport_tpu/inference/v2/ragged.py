@@ -198,16 +198,21 @@ class SequenceDescriptor:
     first_token_s: Optional[float] = None  # when the first token landed
     last_service_s: float = -1.0    # clock stamp of the last scheduled chunk
     #                                 (starvation aging in slack ordering)
-    # a model with Mamba layers: the sequence's place in the recurrent-state
-    # pool (``kv_cache.BlockedKV.ssm``) from its first token to its flush or
-    # eviction; None for every other model
+    # a model with recurrent state (Mamba-2 or power-retention layers): the
+    # sequence's place in the state pool (``kv_cache.BlockedKV.state``) from
+    # its first token to its flush or eviction; None for every other model
     state_slot: Optional[int] = None
+    # False: no layer of the model caches a key (``ModelConfig.num_kv_layers``
+    # 0), so the sequence needs no block whatever its context
+    caches_kv: bool = True
 
     @property
     def needs_tokens(self) -> int:
         return len(self.pending)
 
     def blocks_needed(self, new_tokens: int, block_size: int) -> int:
+        if not self.caches_kv:
+            return 0
         total = self.n_cached + new_tokens
         want = -(-total // block_size)  # ceil
         return max(0, want - len(self.blocks))
@@ -217,7 +222,7 @@ def tile_places(rows: int, max_tokens: int, max_sequences: int,
                 tile: int) -> int:
     """Places a forward of ``rows`` rows has for single-sequence tiles of
     ``tile`` rows: the attention's atoms (the last place reserved dead) and
-    the Mamba layers' pieces. The largest forward, ``max_tokens`` rows, must
+    the state layers' pieces. The largest forward, ``max_tokens`` rows, must
     hold whatever the scheduler emits: a tile more than its whole ones for
     each of ``max_sequences`` chunks. A smaller one has room for three chunk
     tails beside its whole tiles; a round with more takes the next shape."""
@@ -229,7 +234,7 @@ def tile_places(rows: int, max_tokens: int, max_sequences: int,
 class RaggedShape(NamedTuple):
     """One static shape of ``ragged_forward``: ``rows`` of the packed token
     axis, ``atoms`` of the ragged kernel's grid (0: the attention takes
-    none) and ``pieces`` of the Mamba layers' chunked scan (0: the model has
+    none) and ``pieces`` of the state layers' chunked form (0: the model has
     none), chosen together so that shapes do not multiply."""
     rows: int
     atoms: int
@@ -266,8 +271,9 @@ def ragged_shapes(max_tokens: int, max_sequences: int, atom_q: int = 0,
 
 
 class SsmBatch(NamedTuple):
-    """What the Mamba layers of one ``ragged_forward`` take
-    (``ops/ssm.py``). A chunk's place in the forward changes from forward
+    """What the state layers of one ``ragged_forward`` take (Mamba-2:
+    ``ops/ssm.py``; power retention: ``ops/retention.py``). A chunk's place
+    in the forward changes from forward
     to forward; its sequence's state does not move: ``seq_slot`` [S] is
     each chunk's state slot (the sink, ``S``, for an empty place). The
     one-token chunks are ``dec_row`` / ``dec_len`` as the attention's (kept

@@ -83,6 +83,22 @@ class ModelConfig:
     index_topk: int = 0
     index_heads: int = 0
     index_head_dim: int = 0
+    # Power retention (arXiv:2507.04239; brumby) in the place of softmax
+    # attention, on the uniform block: retention_degree p > 0 turns it on
+    # (2 is the one written). Every layer then keeps, per sequence and KV
+    # head, a float32 state over the symmetric square of the key
+    # (ops/retention.py: [head_dim, STATE_DIM(head_dim)] and a normaliser),
+    # decayed by a learned gate a KV head (``g_proj``, with a bias), and NO
+    # layer caches a key: num_kv_layers is 0, the serving pool has no rows
+    # (inference/v2/kv_cache.py) and a sequence costs one state slot
+    # whatever its context. Its logit scale is attn_scale (None:
+    # 1/sqrt(head_dim)), inside the power. retention_chunk_size: rows of a
+    # piece of the chunked form, as ssm_chunk_size is Mamba-2's.
+    # retention_half_life: the range, in tokens, over which init_params
+    # draws a head's gate bias (log-uniform). Serving only.
+    retention_degree: int = 0
+    retention_chunk_size: int = 256
+    retention_half_life: Tuple[float, float] = (64.0, 8192.0)
 
     # The xing4_0 / DeepSeek-V3 family, under the names its config.json
     # publishes. Latent attention (MLA): kv_lora_rank > 0 turns it on; the
@@ -249,6 +265,18 @@ class ModelConfig:
                 "and index_head_dim, rotary positions and one uniform stack "
                 "of K-and-V attention (no latent attention, window, "
                 "layer_pattern, looped stack or hyper-connection streams)")
+        if self.retention_degree and (
+                self.retention_degree != 2 or self.kv_lora_rank
+                or self.index_topk or self.sliding_window
+                or self.layer_pattern is not None or self.total_ut_steps > 1
+                or self.hc_mult > 1 or self.attn_windows is not None
+                or self.pos_embed == "alibi" or not self.scan_layers
+                or self.head_dim % 2):
+            raise ValueError(
+                "retention_degree: power retention is written for degree 2 "
+                "on one uniform stack (scan_layers) of an even head_dim: no "
+                "latent attention, indexer, window, alibi, layer_pattern, "
+                "looped stack or hyper-connection streams")
         if self.rope_scaling and self.rope_scaling.get("type") != "yarn":
             raise ValueError(f"unknown rope_scaling {self.rope_scaling!r}")
         if self.first_k_dense_replace and (
@@ -327,10 +355,27 @@ class ModelConfig:
     def num_kv_layers(self) -> int:
         """Rows of the KV pool's leading axis: one for every (pass, layer)
         pair that caches keys and values. A looped stack's pass ``u`` has
-        rows ``u x L .. u x L + L - 1``: a pass attends to its own."""
+        rows ``u x L .. u x L + L - 1``: a pass attends to its own. A
+        power-retention stack caches no key: 0."""
+        if self.retention_degree:
+            return 0
         layers = self.num_layers if self.layer_pattern is None \
             else self.pattern_count("*")
         return self.total_ut_steps * layers
+
+    @property
+    def state_layers(self) -> int:
+        """Layers that keep a recurrent state per sequence (0: none): a
+        ``layer_pattern``'s Mamba-2 layers, or every layer of a power-
+        retention stack."""
+        return self.num_layers if self.retention_degree \
+            else self.pattern_count("M")
+
+    @property
+    def state_chunk_size(self) -> int:
+        """Rows of a piece of the state layers' chunked form."""
+        return self.retention_chunk_size if self.retention_degree \
+            else self.ssm_chunk_size
 
     @property
     def ssm_d_inner(self) -> int:
@@ -457,6 +502,8 @@ class ModelConfig:
             attn += self.q_dim + self.kv_dim
         if self.qk_head_norm:
             attn += 2 * self.head_dim
+        if self.retention_degree:    # g_proj and its bias
+            attn += (d + 1) * self.num_kv_heads
         if self.index_topk:    # w_qi, w_ki + its LayerNorm, w_w
             hi, di = self.index_heads, self.index_head_dim
             attn += d * (hi * di + di + hi) + 2 * di
@@ -666,6 +713,17 @@ PRESETS = {
         # the selection is seeded by a rule of its own, not a knob:
         # models/transformer.py:SELECTED_ATTN_WRITE
         routed_write_share=0.1),
+    # manifestai/Brumby-14B-Base (model_type brumby, arXiv:2507.04239): the
+    # Qwen3-14B block (GQA 40/8 x 128, an RMSNorm per head on q and k,
+    # SwiGLU 17408, untied head) retrained with power retention of degree 2
+    # in the place of softmax attention: 40 alike layers, each keeping a
+    # gated float32 state of [128, 8320] a KV head and sequence, none
+    # caching a key. Serving only (inference/v2, ops/retention.py).
+    "brumby-14b": _p(
+        vocab_size=151936, hidden_size=5120, intermediate_size=17408,
+        num_layers=40, num_heads=40, num_kv_heads=8, head_dim=128,
+        max_seq_len=32768, rms_norm_eps=1e-6, rope_theta=1000000.0,
+        qk_head_norm=True, retention_degree=2),
 }
 
 

@@ -95,6 +95,14 @@ class CausalLM:
                 # EACH, 26 layers' bf16 rounding compounds to 0.065
                 # logit-std at the median row (PERF.md section 6, PR 37)
                 return std / np.sqrt(fan_in * cfg.num_layers)
+            if cfg.retention_degree:
+                # the same rule for a power-retention stack, which only
+                # serves: at GPT-2's depth scaling each sublayer writes ~25
+                # times what the embedding does, every layer's input is the
+                # rounded output of the layers before it, and the bf16
+                # roundings of 16 sublayers compound to 0.10 logit-std at
+                # the MEDIAN row on the v5e (PERF.md section 6, PR 49)
+                return std / np.sqrt(fan_in * cfg.num_layers)
             if cfg.kv_lora_rank:
                 return std / np.sqrt(fan_in)
             return std / np.sqrt(2 * cfg.num_layers)
@@ -162,6 +170,22 @@ class CausalLM:
                 hd = cfg.head_dim
                 attn.update(q_norm={"scale": jnp.ones((hd,), jnp.float32)},
                             k_norm={"scale": jnp.ones((hd,), jnp.float32)})
+            if cfg.retention_degree:
+                # power retention's gate, one a KV head: log sigmoid(y
+                # g_proj + g_bias) is what a head's state decays by a token.
+                # The bias is drawn so that a head's half-life is
+                # log-uniform over cfg.retention_half_life tokens (sigmoid(b)
+                # = 2^(-1 / half-life)): with N(0, 0.02) alone every gate is
+                # one half, the state forgets in ten tokens and a program
+                # that carried nothing between pieces would pass for right.
+                # A checkpoint overwrites it.
+                lo, hi = cfg.retention_half_life
+                life = jnp.exp(jax.random.uniform(
+                    next(ks), (cfg.num_kv_heads,), jnp.float32,
+                    np.log(lo), np.log(hi)))
+                keep = jnp.exp2(-1.0 / life)
+                attn.update(g_proj=dense((d, cfg.num_kv_heads), next(ks)),
+                            g_bias=jnp.log(keep) - jnp.log1p(-keep))
             if cfg.index_topk:
                 # the sparse-attention indexer: index_heads small query
                 # heads, ONE key a token (behind a LayerNorm) and a weight
@@ -467,6 +491,13 @@ class CausalLM:
                 "a layer_pattern model (Mamba-2, expert and attention layers "
                 "in one stack) runs on the serving path only "
                 "(inference/v2/model.py): the chunked scan's backward is not "
+                "written")
+        if cfg.retention_degree:
+            raise NotImplementedError(
+                "a power-retention model (retention_degree: a gated state "
+                "a sequence, layer and KV head in the place of attention) "
+                "runs on the serving path only (inference/v2/model.py, "
+                "ops/retention.py): the chunked form's backward is not "
                 "written")
         if cfg.kv_lora_rank or cfg.hc_mult > 1 or cfg.first_k_dense_replace \
                 or cfg.experts_held != cfg.num_experts or cfg.index_topk \

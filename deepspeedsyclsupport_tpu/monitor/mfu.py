@@ -71,7 +71,14 @@ SUB_SCOPES = ("moe_route", "moe_experts", "moe_combine", "moe_shared",
               # scan); the gate and its grouped norm; and, INSIDE ssm_scan,
               # the chunked scan's pieces apart from the one-token rows'
               # state step that a mixed round runs beside them
-              "ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate", "ssm_chunk")
+              "ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate", "ssm_chunk",
+              # a power-retention layer (inference/v2/model.py,
+              # ops/retention.py): the q, k, v and output projections with
+              # their norms and rotary; the gate's projection; the
+              # recurrence (decode step or chunked form); and, INSIDE
+              # ret_scan, the chunked form's pieces apart from the
+              # one-token rows' state step beside them
+              "ret_proj", "ret_gate", "ret_scan", "ret_chunk")
 
 #: named_scope label prefix — ``mfu.attn`` etc. Kept short and distinctive
 #: so the metadata regex can't false-positive on user scopes.

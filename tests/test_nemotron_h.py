@@ -363,7 +363,8 @@ def test_the_mixers_scopes_reach_the_compiled_programs(built):
 def test_what_a_model_with_recurrent_state_refuses_says_why(built, tmp_path):
     model, params = built
     eng = engine_of(model, params)
-    with pytest.raises(NotImplementedError, match="snapshot of the "
+    with pytest.raises(NotImplementedError, match="Mamba-2 or power-"
+                       "retention layers.*snapshot of the "
                        "recurrent state at every shared block boundary"):
         eng.install_prefix_cache()
     with pytest.raises(NotImplementedError, match="serialize.*snapshot of "
@@ -393,7 +394,8 @@ def test_the_state_pool_and_its_stats(built):
     per_slot = 2 * (4 * 8 * 16 * 4 + 3 * 96 * 4)
     assert eng.state_stats() == {
         "bytes_per_slot": per_slot, "slots": 4, "slots_live": 0,
-        "dtype": "float32", "pool_bytes": per_slot * 5}
+        "dtype": "float32", "layers": 2, "pool_bytes": per_slot * 5}
+    assert kv.state_names == ("ssm", "conv") and kv.state_slots == 4
     eng.warmup()
     assert eng.state_stats()["slots_live"] == 0
     assert sorted(eng._state_free) == [0, 1, 2, 3]

@@ -665,3 +665,48 @@ def test_the_indexer_forwards_compile_and_copy_no_pool(one_chip, program,
     assert m.temp_size_in_bytes < 0.5 * 2**30      # a pool's layer is 0.4
     assert not [ln for ln in text.splitlines()
                 if " copy(" in ln and f"bf16[{layers},{blocks * bs // 2}," in ln]
+
+
+# ----------------------------------------- power retention, the cell's widths
+@pytest.mark.parametrize("entry", ["decode_step", "chunked"])
+def test_the_retention_kernels_compile_at_the_cells_widths(one_chip, entry):
+    """``brumby-rollout-sat``: 16 rows (a 768-row mixed round in pieces of
+    256) against the pool ``[8 layers, 16 + 1 slots, 8 heads, 128, 8320]``
+    float32: both Pallas kernels are custom calls by their own names, the
+    pool is aliased (held once), and the pieces' kernel expands the features
+    inside it: no ``[rows, 8320]`` temporary (the XLA form held 0.98 GB)."""
+    from deepspeedsyclsupport_tpu.ops import retention
+
+    cfg = get_config("brumby-14b", num_layers=8)
+    h, hk, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    dim = retention.state_dim(d)
+    assert dim == 8320 and dim % retention.STEP_FEATURES == 0
+    pools = [((8, 17, hk, d, dim), jnp.float32),
+             ((8, 17, hk, dim), jnp.float32)]
+    rows = 16 if entry == "decode_step" else 768
+    acts = [((rows, h, d), jnp.bfloat16), ((rows, hk, d), jnp.bfloat16),
+            ((rows, hk, d), jnp.bfloat16), ((rows, hk), jnp.float32)]
+    if entry == "decode_step":
+        def f(q, k, v, gam, s, z, slots, fresh):
+            return retention.decode_step(
+                q, k, v, gam, (s, z), 3, slots, fresh, cfg,
+                retention.STATE_STEPS["pallas"])
+
+        last = [((16,), jnp.int32), ((16,), jnp.bool_)]
+    else:
+        def f(q, k, v, gam, s, z, row0, length, slot, fresh, count):
+            return retention.chunked(
+                q, k, v, gam, (s, z), 3, (row0, length, slot, fresh, count),
+                cfg, retention.PIECE_CARRIES["pallas"])
+
+        last = [((20,), jnp.int32)] * 3 + [((20,), jnp.bool_),
+                                           ((), jnp.int32)]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in acts + pools + last]
+    compiled = jax.jit(f, donate_argnums=(4, 5)).lower(*args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    name = {"decode_step": "ret_state_step", "chunked": "ret_piece"}[entry]
+    assert "tpu_custom_call" in text and name in text
+    pool_bytes = 8 * 17 * hk * (d + 1) * dim * 4
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 64 << 20, mem.temp_size_in_bytes
