@@ -30,12 +30,24 @@ is 0 starts from zeros, whatever its slot held: the host resets nothing.
 Two entries, as ``ops/ssm.py`` has:
 
 * :func:`decode_step` — ONE token for each of ``[rows]`` slots, the state
-  updated IN PLACE. On the TPU a Pallas kernel whose block is a tile of the
+  updated IN PLACE. On the TPU a Pallas kernel (``ret_state_step``) over the
   pool's own ``[layer, slot, kv head]`` (scalar-prefetch indices, the pool
-  aliased to the output): each state is read once and written once, and the
-  group's ``h / hk`` queries are read out of the same pass. ``xla``: gather,
-  update, scatter (the CPU tests' reference, and what the kernel is held
-  against). ``z`` is 1/128 of the bytes and stays in XLA for both.
+  aliased to the output and left in HBM): a grid step copies one KV head's
+  WHOLE state into VMEM (4.26 MB contiguous at ``d`` 128), walks it once in
+  place in compute tiles (a tile of the new state is made, stored and read
+  out for the group's ``h / hk`` queries while it is in registers; the
+  features of a diagonal are made there from ``q`` and ``k``, a lane rotation
+  and a product) and copies it back, with ONE direction of copy on the
+  memory's bus at a time and the copy out in thirteen side by side. What the
+  chip said of it (v5e, 16 rows x 8 heads, kernel alone; PERF.md section 6,
+  PR 50): the BlockSpec pipeline's copy in and copy out at once move 638
+  GB/s whatever the block (1,664 or 8,320 features) and whatever the body
+  (78 % of 819; the update with no read-out the same), a copy in alone 712, a
+  copy out alone 610; taking turns 82 %, the copy out in thirteen 85.4 %, the
+  features made here and not read 87 % (1.695 -> 1.525 ms a layer). A head
+  whose two buffers VMEM does not hold is refused. ``xla``: gather, update,
+  scatter (the CPU tests' reference, and what the kernel is held against).
+  ``z`` is 1/128 of the bytes and stays in XLA for both.
 * :func:`chunked` — the pieces of the chunks of two tokens or more in one
   flat batch (``ragged.ssm_pieces``): inside a piece the masked quadratic
   form ``(s q . k)^2`` under the gates' decay (XLA products over ``[rows,
@@ -98,99 +110,241 @@ def _scale(cfg) -> float:
 
 
 # ----------------------------------------------------------- the decode step
-def _state_step_xla(pool, layer, slots, decay, fq, fk, v):
+def _state_step_xla(pool, layer, slots, decay, qs, k, v):
     """``pool[layer, slots]`` one step on: gather, update, scatter (a
     scatter on the loop-carried pool is in place; the gather is a copy).
-    ``decay`` [rows, hk] float32: 0 starts the row from zeros. ``fq`` [rows,
-    hk, g, D], ``fk`` [rows, hk, D], ``v`` [rows, hk, d] float32. ->
-    ``(phi(q)^T S [rows, hk, g, d], pool)``."""
+    ``decay`` [rows, hk] float32: 0 starts the row from zeros. ``qs`` [rows,
+    hk, g, d] (scaled), ``k`` / ``v`` [rows, hk, d] float32. -> ``(phi(q)^T
+    S [rows, hk, g, d], pool)``."""
     state = pool[layer, slots] * decay[:, :, None, None]
-    new = state + v[:, :, :, None] * fk[:, :, None, :]
-    y = jnp.einsum("rjvf,rjgf->rjgv", new, fq, precision=HIGHEST)
+    new = state + v[:, :, :, None] * phi(k)[:, :, None, :]
+    y = jnp.einsum("rjvf,rjgf->rjgv", new, phi(qs), precision=HIGHEST)
     return y, pool.at[layer, slots].set(new.astype(pool.dtype))
 
 
-def _state_step_kernel(layer_ref, slots_ref, feat_ref, v_ref, st_ref, y_ref,
-                       out_ref, *, group):
-    """One tile ``[d, Dt]`` of one KV head's state: read once, written once,
-    the group's queries read out of what was written. ``feat`` [8k, Dt]:
-    rows ``0 .. group - 1`` the queries' features, row ``group`` the key's,
-    row ``group + 1`` the decay on every lane."""
-    del layer_ref, slots_ref          # the BlockSpecs' own
-    feat = feat_ref[...]
-    new = st_ref[...].astype(jnp.float32) * feat[group + 1:group + 2, :] \
-        + v_ref[...] * feat[group:group + 1, :]
-    out_ref[...] = new.astype(out_ref.dtype)
-    lane = jax.lax.broadcasted_iota(jnp.int32, y_ref.shape, 1)
-    part = jnp.zeros(y_ref.shape, jnp.float32)
-    for i in range(group):
-        col = jnp.sum(new * feat[i:i + 1, :], axis=1, keepdims=True)
-        part = jnp.where(lane == i, col, part)
+def _state_step_kernel(layer_ref, slots_ref, x_ref, pool_ref, y_ref, out_ref,
+                       buf, rsem, wsem, *, group):
+    """Grid step ``s`` of ``(rows, kv heads)``: one KV head's whole state
+    ``[d, D]``, brought into one of two VMEM buffers, walked ONCE in place
+    (:func:`_walk`) and sent back, with ONE direction of copy on the
+    memory's bus at a time: head ``s - 1`` goes out while head ``s`` is
+    computed, and only when it is out does head ``s + 1`` come in (into the
+    buffer ``s - 1`` left). The pool's refs are the arrays in HBM."""
+    hk = pl.num_programs(1)
+    s = pl.program_id(0) * hk + pl.program_id(1)
+    last = pl.num_programs(0) * hk - 1
+    d, wide = buf.shape[1:]
+    strips = wsem.shape[1]
+    cut = wide // strips              # lanes of one of a head's copies out
+    tail = 8 if d > 8 and d % 8 == 0 else d // 2   # rows of a read's tail
 
-    @pl.when(pl.program_id(2) == 0)
+    def head(ref, step):
+        return ref.at[layer_ref[0], slots_ref[jax.lax.div(step, hk)],
+                      jax.lax.rem(step, hk)]
+
+    def reads(step, k):
+        """A head IN, in two copies: the rows above ``tail`` and the tail,
+        each on a semaphore of its own."""
+        src = head(pool_ref, step)
+        return [pltpu.make_async_copy(src.at[rows], buf.at[k, rows],
+                                      rsem.at[k, i])
+                for i, rows in enumerate((pl.ds(0, d - tail),
+                                          pl.ds(d - tail, tail)))]
+
+    def writes(step, k):
+        """The copies a head goes OUT in, side by side on the lanes, each on
+        a semaphore of its own."""
+        dst = head(out_ref, step)
+        return [pltpu.make_async_copy(
+            buf.at[k, :, pl.ds(i * cut, cut)], dst.at[:, pl.ds(i * cut, cut)],
+            wsem.at[k, i]) for i in range(strips)]
+
+    def start(dmas):
+        for c in dmas:
+            c.start()
+
+    def wait(dmas):
+        for c in dmas:
+            c.wait()
+
+    # The ORDER is the semaphores', not the clock's. Head ``s + 1`` comes
+    # into the buffer head ``s - 1`` goes out of, so its copies in start
+    # only after the wait on EVERY copy out of that buffer (starting them a
+    # strip early read the same on the chip, 1.526 | 1.524 ms a layer, and
+    # had nothing but time between a strip's read and the copy in over it).
+    # The other way there is no buffer to share: head ``s - 1`` starts out
+    # of buffer ``1 - k`` while the 8-row tail of head ``s`` is still on its
+    # way into buffer ``k``, so that the bus is not idle while the copies
+    # out get going (2.3 % of the kernel: PERF.md section 6, PR 50), and
+    # the walk waits for the tail. The descriptors are made once a grid step
+    # (tracing them is what set-up pays for them).
+    k = jax.lax.rem(s, 2)
+    coming = reads(s, k)
+    then = reads(jnp.minimum(s + 1, last), 1 - k)
+    going = writes(jnp.maximum(s - 1, 0), 1 - k)
+    flush = writes(s, k)
+
+    @pl.when(s == 0)
     def _():
-        y_ref[...] = part
+        start(coming)
 
-    @pl.when(pl.program_id(2) > 0)
+    wait(coming[:1])
+
+    @pl.when(s > 0)
     def _():
-        y_ref[...] += part
+        start(going)
+
+    wait(coming[1:])
+    _walk(x_ref, buf.at[k], y_ref, group)
+
+    @pl.when(s > 0)
+    def _():
+        wait(going)
+
+    @pl.when(s < last)
+    def _():
+        start(then)
+
+    @pl.when(s == last)
+    def _():
+        start(flush)
+        wait(flush)
 
 
-# features a grid step of the kernel covers: [128, 1664] float32 is 0.85 MB,
-# in and out double-buffered 3.4 MB
-STEP_FEATURES = 1664
+def _walk(x_ref, st_ref, y_ref, group):
+    """``st_ref`` [d, D], a head's state, one step on IN PLACE, in compute
+    tiles of ``[COMPUTE_ROWS, d]`` (a band of the value's rows x one
+    diagonal): a tile of the new state is made, stored and read out for all
+    of the group's queries while it is in registers, the read-out summed
+    lane for lane in accumulators the loop carries and reduced over the
+    lanes once at the end. ``x`` [8k, d]: rows ``0 .. group - 1`` the scaled
+    queries, then the key, the value and the decay on every lane; the
+    FEATURES of a diagonal are made here, a lane rotation and a product for
+    all of the rows at once (as :func:`_carry_kernel` makes them): ``[rows,
+    features]`` never exists."""
+    f32 = jnp.float32
+    d = st_ref.shape[0]
+    x = x_ref[...]
+    decay = x[group + 2:group + 3, :]
+    # the value down the sublanes, on every lane
+    column = jnp.broadcast_to(x[group + 1:group + 2, :], (d, d)).T
+    rows = min(COMPUTE_ROWS, d)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, Y_LANES), 1)
+    for r0 in range(0, d, rows):
+        band = slice(r0, r0 + rows)
+        acc = _walk_band(x, decay, column[band, :], st_ref, band, group)
+        part = jnp.zeros((rows, Y_LANES), f32)
+        for j, a in enumerate(acc):
+            part = jnp.where(lane == j, jnp.sum(a, axis=1, keepdims=True),
+                             part)
+        y_ref[band, :] = part
+
+
+def _walk_band(x, decay, v, st_ref, band, group):
+    """Rows ``band`` of :func:`_walk`'s head over all of its diagonals ->
+    the group's accumulators ``[rows, d]``. A turn of the rolled loop is
+    :data:`COMPUTE_DIAGONALS` diagonals (the most that divide the head's:
+    five of the cell's 65), each one's rotation made from ``x`` itself: a
+    rotation is ~100 cycles before its result is there, and only rotations
+    that do not wait for one another hide it (PERF.md section 6, PR 50). The
+    turn is traced ONCE and unrolled when the kernel is lowered (every
+    traced copy of the tile is paid at every set-up)."""
+    f32 = jnp.float32
+    d = x.shape[-1]
+    diagonals = st_ref.shape[1] // d
+    count = _divisor(diagonals, COMPUTE_DIAGONALS)
+
+    def diagonal(o, acc):
+        w = jnp.where((o == 0) | (o == d // 2), 1.0, np.sqrt(2.0)).astype(f32)
+        feat = x * pltpu.roll(x, jax.lax.rem(d - o, d), 1) * w
+        at = pl.ds(pl.multiple_of(o * d, d), d)
+        new = st_ref[band, at].astype(f32) * decay \
+            + v * feat[group:group + 1, :]
+        st_ref[band, at] = new.astype(st_ref.dtype)
+        return tuple(a + new * feat[j:j + 1, :] for j, a in enumerate(acc))
+
+    def turn(t, acc):
+        return jax.lax.fori_loop(
+            0, count, lambda i, acc: diagonal(t * count + i, acc), acc,
+            unroll=count)
+
+    return jax.lax.fori_loop(0, diagonals // count, turn,
+                             (jnp.zeros(v.shape, f32),) * group)
+
+
+# What the state's two buffers of a grid step may take of VMEM: one KV head's
+# whole state at the cell's widths ([128, 8320] float32, 4.26 MB CONTIGUOUS in
+# the pool) takes 8.5 MB of it, and a head they do not hold is refused.
+# Smaller blocks lose: a block costs ~0.75 us beside its bytes (at 1,664
+# features the kernel read 68 % of 819 GB/s, at the whole head 87 %, and
+# PR 49's BlockSpec kernel 78 % at either: PERF.md section 6, PR 50).
+STEP_VMEM_BYTES = 32 << 20
+# lanes of one of the copies a head goes OUT in, side by side: one copy of
+# the whole head writes at 610 GB/s, thirteen of 640 lanes at ~700 (PR 50's
+# sweep: 1 / 5 / 13 / 65 copies 1.581 / 1.555 / 1.525 / 1.579 ms a layer); a
+# head these do not divide goes out in one
+WRITE_LANES = 640
+# the tile of a head the kernel computes at a time: a band of the value's
+# rows x one diagonal (8 registers of state beside the group's accumulators,
+# 5 x 8), and the diagonals a turn of the rolled loop walks. Swept with the
+# kernel alone (ms a layer; the copies' own floor 1.510): 32 | 64 | 128 rows
+# at one diagonal a turn 4.00 | 2.90 | 2.41, at five 1.760 | 1.525 | 1.549,
+# at thirteen 1.514 each; five at 64 rows is the least code that hides under
+# the copies, and the code is traced at every set-up.
+COMPUTE_ROWS = 64
+COMPUTE_DIAGONALS = 5
 Y_LANES = 128       # the read-out's block: query i in lane i
 
 
-def _feature_tile(dim: int) -> int:
-    """The largest tile of whole 128 lanes that divides ``dim`` and is at
-    most :data:`STEP_FEATURES`; ``dim`` itself where 128 does not divide it
-    (a test's width: the block is then the whole axis)."""
-    if dim % 128:
-        return dim
-    n = dim // 128
-    return 128 * max(t for t in range(1, STEP_FEATURES // 128 + 1)
-                     if n % t == 0)
+def _divisor(n: int, most: int) -> int:
+    """The largest divisor of ``n`` that is at most ``most`` (1 at least)."""
+    return max(t for t in range(1, n + 1) if n % t == 0 and t <= max(most, 1))
 
 
-def _state_step_pallas(pool, layer, slots, decay, fq, fk, v, interpret=False):
-    """The same step with the pool aliased to the output: tile ``[layer,
-    slots[row], head, :, tile]`` of the pool in, the same tile out, the
-    read-out summed over a head's tiles. Rows that share a slot (the sink)
-    write it one after another; nobody reads it."""
+def _state_step_pallas(pool, layer, slots, decay, qs, k, v, interpret=False):
+    """The same step with the pool aliased to the output and left in HBM:
+    the kernel copies head ``[layer, slots[row], head]`` in and the same
+    head out itself. Rows that share a slot (the sink) write it one after
+    another; nobody reads it (a row may read it as the row before last left
+    it)."""
     rows, hk, d, dim = (slots.shape[0], *pool.shape[2:])
-    g = fq.shape[2]
+    g = qs.shape[2]
     assert g <= Y_LANES, g
-    pad = -(g + 2) % 8
-    feat = jnp.concatenate(
-        [fq, fk[:, :, None], jnp.broadcast_to(decay[:, :, None, None],
-                                              (rows, hk, 1, dim)),
-         jnp.zeros((rows, hk, pad, dim), jnp.float32)], axis=2)
-    tile = _feature_tile(dim)
-    small = lambda r, j, t, *_: (r, j, 0, 0)              # noqa: E731
-    state = lambda r, j, t, layer_ref, slots_ref: (       # noqa: E731
-        layer_ref[0], slots_ref[r], j, 0, t)
+    held = 2 * d * dim * pool.dtype.itemsize
+    assert held <= STEP_VMEM_BYTES, (
+        f"two buffers of a head's state [{d}, {dim}] take {held} bytes of "
+        f"VMEM, over STEP_VMEM_BYTES = {STEP_VMEM_BYTES}")
+    pad = -(g + 3) % 8
+    x = jnp.concatenate(
+        [qs, k[:, :, None], v[:, :, None],
+         jnp.broadcast_to(decay[:, :, None, None], (rows, hk, 1, d)),
+         jnp.zeros((rows, hk, pad, d), jnp.float32)], axis=2)
+    strips = dim // WRITE_LANES if dim % WRITE_LANES == 0 else 1
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(rows, hk, dim // tile),
-        in_specs=[pl.BlockSpec((None, None, g + 2 + pad, tile),
-                               lambda r, j, t, *_: (r, j, 0, t)),
-                  pl.BlockSpec((None, None, d, 1), small),
-                  pl.BlockSpec((None, None, None, d, tile), state)],
-        out_specs=[pl.BlockSpec((None, None, d, Y_LANES), small),
-                   pl.BlockSpec((None, None, None, d, tile), state)])
+        num_scalar_prefetch=2, grid=(rows, hk),
+        in_specs=[pl.BlockSpec((None, None, g + 3 + pad, d),
+                               lambda r, j, *_: (r, j, 0, 0)),
+                  in_hbm],
+        out_specs=[pl.BlockSpec((None, None, d, Y_LANES),
+                                lambda r, j, *_: (r, j, 0, 0)),
+                   in_hbm],
+        scratch_shapes=[pltpu.VMEM((2, d, dim), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.SemaphoreType.DMA((2, strips))])
     y, pool = pl.pallas_call(
         functools.partial(_state_step_kernel, group=g),
         out_shape=[jax.ShapeDtypeStruct((rows, hk, d, Y_LANES), jnp.float32),
                    jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
         grid_spec=grid_spec,
-        # operands count the scalar-prefetch two: the pool is the 5th
-        input_output_aliases={4: 1},
+        # operands count the scalar-prefetch two: the pool is the 4th
+        input_output_aliases={3: 1},
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",) * 3,
+            dimension_semantics=("arbitrary",) * 2,
             vmem_limit_bytes=64 << 20),
         interpret=interpret, name="ret_state_step",
     )(jnp.asarray(layer, jnp.int32).reshape(1), slots.astype(jnp.int32),
-      feat, v[..., None], pool)
+      x, pool)
     return y[..., :g].swapaxes(2, 3), pool
 
 
@@ -218,12 +372,12 @@ def decode_step(q, k, v, gam, pools, layer, slots, fresh, cfg, step=None):
     hk = k.shape[1]
     f32 = jnp.float32
     decay = jnp.where(fresh[:, None], 0.0, jnp.exp(gam))
-    fq = phi(q.astype(f32).reshape(rows, hk, h // hk, d) * _scale(cfg))
-    fk = phi(k.astype(f32))
-    z = z_pool[layer, slots] * decay[:, :, None] + fk
+    qs = q.astype(f32).reshape(rows, hk, h // hk, d) * _scale(cfg)
+    k = k.astype(f32)
+    z = z_pool[layer, slots] * decay[:, :, None] + phi(k)
     z_pool = z_pool.at[layer, slots].set(z.astype(z_pool.dtype))
-    den = jnp.einsum("rjf,rjgf->rjg", z, fq, precision=HIGHEST)
-    num, s_pool = step(s_pool, layer, slots, decay, fq, fk, v.astype(f32))
+    den = jnp.einsum("rjf,rjgf->rjg", z, phi(qs), precision=HIGHEST)
+    num, s_pool = step(s_pool, layer, slots, decay, qs, k, v.astype(f32))
     y = _normalised(num, den)
     return y.reshape(rows, h, d), (s_pool, z_pool)
 

@@ -431,6 +431,92 @@ def test_the_xla_and_the_pallas_interpret_steps_agree(built):
     np.testing.assert_allclose(z_c[:, :5], state[1][:, :5], atol=1e-4)
 
 
+@pytest.mark.parametrize("steps", [1, 3], ids=["one_step", "three_steps"])
+def test_the_tiled_walk_agrees_at_the_cells_head_width(steps):
+    """The CELL's head (``d`` 128, 8,320 features: the one width at which
+    the kernel's walk has more than one compute tile, 65 of them in two
+    bands of rows, and a head goes out in more than one copy) under the
+    interpreter against the XLA form: three rows on three slots, one of them
+    fresh, and two rows of padding on the sink; two KV heads of five queries
+    each. One step, and three one after another on the state the last left
+    (the rows and their slots drawn anew, only the first step's row fresh)."""
+    import types
+
+    from deepspeedsyclsupport_tpu.ops import retention
+
+    d, hk, g, sink = 128, 2, 5, 3
+    cfg = types.SimpleNamespace(attn_scale=None, head_dim=d)
+    k = jax.random.split(jax.random.PRNGKey(5), 6)
+    # each slot's state as six earlier tokens left it: S = sum v phi(k)^T,
+    # z = sum phi(k), so the normaliser is a sum of squares as in a run
+    past = retention.phi(jax.random.normal(k[0], (1, sink + 1, hk, 6, d)))
+    pools = (jnp.einsum("lsjtv,lsjtf->lsjvf", jax.random.normal(
+        k[1], (1, sink + 1, hk, 6, d)), past), past.sum(3))
+    q = jax.random.normal(k[2], (steps, 5, hk * g, d))
+    kk, v = jax.random.normal(k[3], (2, steps, 5, hk, d))
+    gam = -jnp.abs(jax.random.normal(k[4], (steps, 5, hk))) * 0.1
+    slots = jnp.asarray([[1, sink, 0, sink, 2], [sink, 2, 1, sink, 0],
+                         [0, 1, sink, 2, sink]])
+    fresh = jnp.asarray([False, False, True, False, False])
+    (sa, za), (sb, zb) = pools, pools
+    for t in range(steps):
+        (ya, (sa, za)), (yb, (sb, zb)) = (
+            retention.decode_step(q[t], kk[t], v[t], gam[t], state, 0,
+                                  slots[t], fresh & (t == 0), cfg,
+                                  retention.STATE_STEPS[name])
+            for name, state in (("xla", (sa, za)),
+                                ("pallas_interpret", (sb, zb))))
+        live = np.flatnonzero(np.asarray(slots[t]) != sink)
+        np.testing.assert_allclose(np.asarray(ya)[live], np.asarray(yb)[live],
+                                   atol=2e-5, rtol=2e-5)
+        np.testing.assert_allclose(sa[:, :sink], sb[:, :sink], atol=1e-5)
+        np.testing.assert_allclose(za[:, :sink], zb[:, :sink], atol=1e-5)
+        if t == 0:
+            # the fresh row started from zeros: its state is its own write
+            np.testing.assert_allclose(
+                sb[0, 0], v[0, 2][:, :, None]
+                * retention.phi(kk[0, 2])[:, None, :], atol=1e-5)
+    assert not np.allclose(np.asarray(sb)[0, 1], np.asarray(pools[0])[0, 1])
+
+
+@pytest.mark.parametrize("d, budget, fits", [
+    (128, None, True),            # the cell: 8.5 MB of the budget's 32 MiB
+    (128, 8 << 20, False),        # two buffers of [128, 8320] float32: 8.5 MB
+    (32, 1 << 20, True),          # a test's width: 139 KB
+    (32, 1 << 10, False),
+], ids=["the_cells_head", "the_cells_head_under_8_MiB", "a_tests_head",
+        "a_tests_head_under_1_KiB"])
+def test_the_state_step_refuses_a_head_its_two_buffers_do_not_hold(
+        monkeypatch, d, budget, fits):
+    """A grid step copies one KV head's WHOLE state, into one of two VMEM
+    buffers: a head two of them do not hold under ``STEP_VMEM_BYTES`` is
+    refused when the step is traced, not walked in smaller blocks (slower on
+    the chip than the kernel this one replaced: PERF.md section 6, PR 50)."""
+    import functools
+
+    from deepspeedsyclsupport_tpu.ops import retention
+
+    if budget:
+        monkeypatch.setattr(retention, "STEP_VMEM_BYTES", budget)
+    dim = retention.state_dim(d)
+    assert (2 * d * dim * 4 <= retention.STEP_VMEM_BYTES) == fits
+    # (a function of this call's own: a trace is cached by the function)
+    step = functools.partial(
+        jax.eval_shape,
+        lambda *a: retention.STATE_STEPS["pallas_interpret"](*a),
+        jax.ShapeDtypeStruct((1, 2, 1, d, dim), jnp.float32), 0,
+        *(jax.ShapeDtypeStruct(s, t) for s, t in (
+            ((2,), jnp.int32), ((2, 1), jnp.float32),
+            ((2, 1, 5, d), jnp.float32), ((2, 1, d), jnp.float32),
+            ((2, 1, d), jnp.float32))))
+    if fits:
+        y, pool = step()
+        assert y.shape == (2, 1, 5, d) and pool.shape == (1, 2, 1, d, dim)
+    else:
+        with pytest.raises(AssertionError, match="STEP_VMEM_BYTES"):
+            step()
+
+
 # ------------------------------------------------- a stream that ends early
 @pytest.fixture(scope="module")
 def ending(built):

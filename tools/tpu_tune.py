@@ -11,8 +11,14 @@ Sections, cheapest first:
             against XLA:  flash [--parent DIR] [--seq N ...] [--sweep]
             [--probe] [--parity]
   paged   — paged-decode block_size sweep at serving shapes.
+  retention — the power-retention state step alone at ``brumby-rollout-sat``'s
+            shape: the tree's one-pass walk and its copies with no read-out
+            beside a parent checkout's kernel (PR 49's at its two blocks), us
+            a (row, head) and the share of the HBM peak; the parity against
+            XLA over several steps:  retention [--parent DIR ...] [--tiles]
+            [--copies] [--parity]
 
-Usage:  python tools/tpu_tune.py [calib|flash|paged|all]
+Usage:  python tools/tpu_tune.py [calib|flash|paged|retention|all]
 """
 import json
 import os
@@ -27,6 +33,7 @@ import numpy as np
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 V5E_PEAK = 197e12
+V5E_HBM = 819e9
 
 
 def _bench_chain(fn_one, x0, extra_args, iters):
@@ -109,19 +116,22 @@ FLASH_STEPS = ((512, 512), (1024, 1024), (1024, 2048), (2048, 1024),
                (2048, 2048), (4096, 4096), (8192, 8192))
 
 
-def _load_flash(root):
-    """``ops/flash_attention.py`` of the checkout at ``root`` as a module of
-    its own (it imports nothing of the package), so the parent's kernels run
-    beside the tree's in one process."""
+def _load_op(root, op, name):
+    """``ops/<op>.py`` of the checkout at ``root`` as a module ``name`` of
+    its own, so the parent's kernels run beside the tree's in one process."""
     import importlib.util
 
-    path = os.path.join(root, "deepspeedsyclsupport_tpu", "ops",
-                        "flash_attention.py")
-    spec = importlib.util.spec_from_file_location(
-        "flash_" + (os.path.basename(os.path.abspath(root)) or "tree"), path)
+    spec = importlib.util.spec_from_file_location(name, os.path.join(
+        root, "deepspeedsyclsupport_tpu", "ops", op + ".py"))
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def _load_flash(root):
+    """``ops/flash_attention.py`` (it imports nothing of the package)."""
+    return _load_op(root, "flash_attention", "flash_" + (
+        os.path.basename(os.path.abspath(root)) or "tree"))
 
 
 def _flash_operands(seq, seed=0):
@@ -149,27 +159,37 @@ def _flash_step(mod, name, **blocks):
     return jax.jit(step)
 
 
-def _traced_kernels(steps, args, runs=3):
+def _traced_kernels(steps, args, runs=3, kernel_of=None, carry=None):
     """Run every ``{name: compiled step}`` ``runs`` times under ONE profiler
     trace and return ``{name: {"fwd" | "dq" | "dkv": median ms, "xla": ms of
     everything else in the program}}``, the kernels told apart as the
-    benchmark's ``flash_roofline`` tells them."""
+    benchmark's ``flash_roofline`` tells them (or by ``kernel_of``, custom
+    call's text -> name). ``carry``: a donated first argument each step
+    takes and returns first (a pool held once)."""
     import glob
     import statistics
     import tempfile
 
     from benchmark import trace
-    from benchmark.metrics.flash_roofline import kernel_of
+    if kernel_of is None:
+        from benchmark.metrics.flash_roofline import kernel_of
+
+    def run(f):
+        nonlocal carry
+        if carry is None:
+            return jax.block_until_ready(f(*args))
+        carry, out = f(carry, *args)
+        return jax.block_until_ready(out)
 
     for f in steps.values():
-        jax.block_until_ready(f(*args))                     # warm
+        run(f)                                              # warm
     with tempfile.TemporaryDirectory() as d:
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         jax.profiler.start_trace(d, profiler_options=opts)
         for f in steps.values():
             for _ in range(runs):
-                jax.block_until_ready(f(*args))
+                run(f)
         jax.profiler.stop_trace()
         tr = trace.read_xplane(sorted(glob.glob(os.path.join(
             d, "plugins", "profile", "*", "*.xplane.pb")))[-1])
@@ -361,6 +381,203 @@ def paged():
                          "d": d}, sweep=rows)
 
 
+# ``brumby-rollout-sat``'s decode step: 16 rows on 16 slots + the sink, 8
+# layers, 40 query heads over 8 of 128: a head's state [128, 8320] float32
+RET_CELL = dict(layers=8, slots=17, rows=16, kv_heads=8, group=5,
+                head_dim=128)
+# features of the block a grid step of PR 49's kernel copies (its
+# ``STEP_FEATURES``): its own tile, the whole head
+RET_BLOCKS = (1664, 8320)
+# (COMPUTE_ROWS, COMPUTE_DIAGONALS) of the one-pass walk, under ``--tiles``
+RET_TILES = ((32, 1), (64, 1), (128, 1), (32, 5), (64, 5), (128, 5), (32, 13),
+             (64, 13), (128, 13))
+# WRITE_LANES, the lanes of one of the copies a head goes out in, under
+# ``--copies``: 1, 5, 13, 65 copies of the whole head
+RET_COPIES = (8320, 1664, 640, 128)
+RET_PARITY_STEPS = 6
+
+
+def _load_retention(root):
+    """``ops/retention.py`` beside the tree's: named into the tree's package,
+    where its two relative imports resolve."""
+    tag = os.path.basename(os.path.abspath(root)).strip("_")
+    return tag, _load_op(root, "retention",
+                         "deepspeedsyclsupport_tpu.ops.retention_" + tag)
+
+
+def _ret_update_only(x_ref, st_ref, y_ref, group):
+    """An update in place with NO read-out and no features (in the place of
+    the tree's ``_walk``): what the bytes of a whole head cost by
+    themselves."""
+    del x_ref, group
+    st_ref[...] = st_ref[...] * 0.99 + 0.01
+    y_ref[...] = jnp.zeros(y_ref.shape, y_ref.dtype)
+
+
+def _ret_operands(seed=0):
+    """The pool and ``(decay, qs, k, v, slots)`` of one decode step."""
+    c = RET_CELL
+    from deepspeedsyclsupport_tpu.ops import retention
+
+    d, hk, rows = c["head_dim"], c["kv_heads"], c["rows"]
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    qs = jax.random.normal(ks[0], (rows, hk, c["group"], d)) * d ** -0.5
+    k, v = jax.random.normal(ks[1], (2, rows, hk, d))
+    decay = jnp.exp(-jnp.abs(jax.random.normal(ks[2], (rows, hk))) * 0.01)
+    pool = jax.jit(lambda key: 0.1 * jax.random.normal(
+        key, (c["layers"], c["slots"], hk, d, retention.state_dim(d))))(ks[3])
+    return pool, (decay, qs, k, v, jnp.arange(rows, dtype=jnp.int32))
+
+
+def _ret_step(mod, name, expanded=False):
+    """Every layer's state step of ``mod`` in one program named ``name``,
+    the pool donated. ``expanded``: the module takes the features of the
+    queries and the key (PR 49's), not the rows themselves."""
+    def step(pool, decay, qs, k, v, slots):
+        if expanded:
+            qs, k = mod.phi(qs), mod.phi(k)
+
+        def layer(i, c):
+            pool, acc = c
+            y, pool = mod._state_step_pallas(pool, i, slots, decay, qs, k, v)
+            return pool, acc + y
+        return jax.lax.fori_loop(
+            0, RET_CELL["layers"], layer, (pool, jnp.zeros(qs.shape[:3]
+                                                          + v.shape[-1:])))
+    step.__name__ = name
+    return jax.jit(step, donate_argnums=0)
+
+
+def _ret_parity(tree, pool, args, steps=RET_PARITY_STEPS):
+    """The tree's kernel against the XLA form ON THE CHIP (where its copies
+    run beside one another; the interpreter's run one after another):
+    ``steps`` steps one after another on one layer's pool, the rows dealt
+    other slots at every step and two of them the sink; as the cell runs it
+    (a head out in thirteen copies) and with a head out in ONE copy, as a
+    width ``WRITE_LANES`` does not divide goes."""
+    decay, qs, k, v, slots = args
+    sink = RET_CELL["slots"] - 1
+    slots = slots.at[jnp.asarray([3, 11])].set(sink)
+    live = slots != sink
+
+    def run(step):
+        def one(t, c):
+            pool, acc = c
+            turn = lambda a: jnp.roll(a, t, axis=0)      # noqa: E731
+            y, pool = step(pool, 0, jnp.roll(slots, 3 * t), turn(decay),
+                           turn(qs), turn(k), turn(v))
+            y = jnp.where(jnp.roll(live, 3 * t)[:, None, None, None], y, 0)
+            return pool, acc + y * (1 + t)
+        return jax.jit(lambda pool: jax.lax.fori_loop(
+            0, steps, one, (pool, jnp.zeros(qs.shape[:3] + v.shape[-1:]))))(
+                pool)
+
+    want_pool, want = run(tree.STATE_STEPS["xla"])
+    for name, lanes in (("cell", tree.WRITE_LANES),
+                        ("one_copy_out", pool.shape[-1])):
+        kept, tree.WRITE_LANES = tree.WRITE_LANES, lanes
+        try:
+            got_pool, got = run(tree.STATE_STEPS["pallas"])
+        finally:
+            tree.WRITE_LANES = kept
+        emit("retention_parity", out=name, steps=steps,
+             y_max=float(jnp.max(jnp.abs(want))),
+             y_err=float(jnp.max(jnp.abs(want - got))),
+             pool_max=float(jnp.max(jnp.abs(want_pool[:, :sink]))),
+             pool_err=float(jnp.max(jnp.abs(
+                 want_pool[:, :sink] - got_pool[:, :sink]))))
+        del got_pool, got
+
+
+def retention(argv=()):
+    """The state step's kernel ALONE at the cell's shape, its device time
+    read off a profiler trace: ``update`` (the tree's copies with no
+    read-out: the bytes' own floor) and ``one_pass`` (the tree's); for each
+    ``--parent DIR`` its module beside them (``as_is``: PR 49's kernel at
+    the block of 1,664 features and at the whole head's 8,320, with
+    ``steps x a + bytes x c`` from the two; a later tree's as it stands);
+    ``--tiles`` adds the tree's walk at other compute tiles, ``--copies``
+    the whole head sent out in other numbers of copies, ``--parity`` holds
+    the tree's kernel against the XLA form on the chip over several steps.
+    Each row: ms a layer, us a (row, head), the share of 819 GB/s (the state
+    read once and written once), the XLA operations beside the kernel."""
+    import argparse
+
+    from deepspeedsyclsupport_tpu.ops import retention as tree
+
+    ap = argparse.ArgumentParser(prog="tpu_tune.py retention")
+    ap.add_argument("--parent", action="append", default=[])
+    ap.add_argument("--tiles", action="store_true")
+    ap.add_argument("--copies", action="store_true")
+    ap.add_argument("--parity", action="store_true")
+    a = ap.parse_args(list(argv))
+    c = RET_CELL
+    d, dim = c["head_dim"], tree.state_dim(c["head_dim"])
+    heads = c["rows"] * c["kv_heads"]
+    moved = 2 * heads * d * dim * 4
+    pool, args = _ret_operands()
+    if a.parity:
+        _ret_parity(tree, pool[:1], args)      # one layer's pool, held thrice
+    # (row name, features a grid step copies, module, {attribute: value
+    # while the row's program is traced})
+    plan, fitted = [], []
+    for tag, mod in map(_load_retention, a.parent):
+        if hasattr(mod, "STEP_FEATURES"):
+            plan += [(f"{tag}_as_is_{block}", block, mod,
+                      {"STEP_FEATURES": block}) for block in RET_BLOCKS]
+            fitted.append(f"{tag}_as_is")
+        else:
+            plan.append((f"{tag}_as_is_{dim}", dim, mod, {}))
+    plan += [(f"update_{dim}", dim, tree, {"_walk": _ret_update_only}),
+             (f"one_pass_{dim}", dim, tree, {})]
+    if a.tiles:
+        plan += [(f"one_pass_{dim}_tile{r}x{w}", dim, tree,
+                  {"COMPUTE_ROWS": r, "COMPUTE_DIAGONALS": w})
+                 for r, w in RET_TILES
+                 if (r, w) != (tree.COMPUTE_ROWS, tree.COMPUTE_DIAGONALS)]
+    if a.copies:
+        plan += [(f"one_pass_{dim}_out{dim // n}", dim, tree,
+                  {"WRITE_LANES": n})
+                 for n in RET_COPIES if n != tree.WRITE_LANES]
+    steps, failed = {}, {}
+    for name, _block, mod, patch in plan:
+        kept = {k: getattr(mod, k) for k in patch}
+        for k, val in patch.items():
+            setattr(mod, k, val)
+        try:
+            steps[name] = _ret_step(
+                mod, name, hasattr(mod, "STEP_FEATURES")).lower(
+                    pool, *args).compile()
+        except Exception as e:                     # e.g. over the VMEM limit
+            failed[name] = str(e).splitlines()[0][:160]
+        finally:
+            for k, val in kept.items():
+                setattr(mod, k, val)
+    rows = _traced_kernels(steps, args, kernel_of=lambda text: "kernel",
+                           carry=pool)
+    for name, block, _mod, _patch in plan:
+        row = rows.get(name, {})
+        if "kernel" not in row:
+            continue
+        row["steps"] = heads * (dim // block)
+        row["us_per_row_head"] = round(1e3 * row["kernel"] / heads, 3)
+        row["peak_pct"] = round(100 * moved / V5E_HBM
+                                / (row["kernel"] * 1e-3), 1)
+    fits = {}
+    for body in fitted:
+        two = [rows.get(f"{body}_{b}", {}) for b in RET_BLOCKS]
+        if all("kernel" in r for r in two):
+            per_step = 1e3 * (two[0]["kernel"] - two[1]["kernel"]) \
+                / (two[0]["steps"] - two[1]["steps"])
+            rest = 1e-3 * (two[1]["kernel"] - 1e-3 * per_step
+                           * two[1]["steps"])
+            fits[body] = {"a_us_per_step": round(per_step, 3),
+                          "c_peak_pct": round(100 * moved / V5E_HBM / rest,
+                                              1)}
+    emit("retention", cell=RET_CELL, bytes_a_layer=moved, rows=rows,
+         fits=fits, failed=failed)
+
+
 if __name__ == "__main__":
     which = sys.argv[1] if len(sys.argv) > 1 else "all"
     if which in ("calib", "all"):
@@ -369,3 +586,5 @@ if __name__ == "__main__":
         flash(sys.argv[2:] if which == "flash" else ())
     if which in ("paged", "all"):
         paged()
+    if which in ("retention", "all"):
+        retention(sys.argv[2:] if which == "retention" else ())
