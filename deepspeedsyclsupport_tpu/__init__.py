@@ -56,3 +56,10 @@ def init_inference(*args, **kwargs):
     from .inference.engine import init_inference as _impl
 
     return _impl(*args, **kwargs)
+
+
+# the set-up ledger is always on (monitor/telemetry.py): one jax.monitoring
+# listener that costs an append per compile event and nothing per step
+from .monitor import telemetry as _telemetry  # noqa: E402
+
+_telemetry.install_compile_listener()

@@ -36,6 +36,7 @@ from ..params import place_inference_params
 from ..sampling import SamplingParams, sample_token_dyn, split_key
 from ...comm.topology import MeshTopology, build_topology
 from ...monitor.reqtrace import MOE_TAIL_FIELDS, NO_PHASE
+from ...monitor.telemetry import setup_decision, setup_span
 from ...utils.logging import log_dist
 
 
@@ -167,6 +168,7 @@ class PutResult(Mapping):
 
 
 class InferenceEngineV2:
+    @setup_span("engine", side="serve")
     def __init__(self, model, params, config: Optional[dict] = None,
                  topology: Optional[MeshTopology] = None, **kw):
         self.config = (config if isinstance(config, RaggedInferenceConfig)
@@ -176,24 +178,26 @@ class InferenceEngineV2:
         self.topology = topology or build_topology(dp=-1)
 
         rules = getattr(model, "sharding_rules", None)
-        self.params, _ = place_inference_params(params, self.topology, rules,
-                                                cfg.dtype)
-        if cfg.quantize_weights and "layers" in self.params:
-            # ZeRO-Inference: int8 layer weights, dequantized per layer
-            # inside the ragged scan (model.py _dequant)
-            from ...compression.quantize import quantize_tree
+        with setup_span("params"):
+            self.params, _ = place_inference_params(params, self.topology,
+                                                    rules, cfg.dtype)
+            if cfg.quantize_weights and "layers" in self.params:
+                # ZeRO-Inference: int8 layer weights, dequantized per layer
+                # inside the ragged scan (model.py _dequant)
+                from ...compression.quantize import quantize_tree
 
-            stacked = bool(getattr(model.config, "scan_layers", False))
-            self.params = dict(self.params)
-            # no donation: placement may alias caller-held arrays (see
-            # InferenceEngine._quantize_weights)
-            self.params["layers"] = jax.jit(
-                lambda t: quantize_tree(t, cfg.quant_group_size,
-                                        stacked=stacked,
-                                        bits=cfg.quant_bits))(
-                self.params["layers"])
+                stacked = bool(getattr(model.config, "scan_layers", False))
+                self.params = dict(self.params)
+                # no donation: placement may alias caller-held arrays (see
+                # InferenceEngine._quantize_weights)
+                self.params["layers"] = jax.jit(
+                    lambda t: quantize_tree(t, cfg.quant_group_size,
+                                            stacked=stacked,
+                                            bits=cfg.quant_bits))(
+                    self.params["layers"])
 
-        self.kv = init_blocked_kv(model.config, cfg, self.topology)
+        with setup_span("pool"):
+            self.kv = init_blocked_kv(model.config, cfg, self.topology)
         self.allocator = BlockedAllocator(cfg.num_blocks)
         self.seqs: Dict[int, SequenceDescriptor] = {}
         # a model with recurrent state (Mamba-2 or power-retention layers):
@@ -556,6 +560,7 @@ class InferenceEngineV2:
         return out
 
     # --------------------------------------------------------------- warmup
+    @setup_span("warmup")
     def warmup(self) -> None:
         """Compile the prefill and decode programs in BOTH KV-sharding
         states before serving. The first jitted forward returns a donated
@@ -569,7 +574,14 @@ class InferenceEngineV2:
         after the engine's first ever sees: no round of any shape compiles
         while serving. A decode step here takes its token from
         the device as a serving round's does: the sampler is launched, the
-        forward eats its output, then it is read."""
+        forward eats its output, then it is read.
+
+        Each forward it runs is a set-up span ``warm/<program>@<rows>``
+        (``telemetry.setup_span``), so what a further shape costs is one
+        line of the set-up ledger; the shapes are a ``shapes`` decision. A
+        span ends when the forward is DISPATCHED (nothing here waits that
+        did not wait before): the device's first run of it ends inside
+        whichever later span first reads something back."""
         cfg = self.config
         uid = -(1 << 40) - 1   # reserved: below any sane caller uid
         # leave room for the 4 follow-up tokens within max_context
@@ -581,18 +593,33 @@ class InferenceEngineV2:
         # a round's other two programs: the key's split, and the greedy
         # sampler over the forward's whole logits with the tail a serving
         # session gives it
-        _, key = split_key(jax.random.PRNGKey(0))
-        try:
-            self._rows_floor = cfg.max_tokens_per_batch
-            for toks in steps:
-                sampled = None
-                if toks is None:  # a decode step: the token the sampler drew
-                    sampled = self.sample_launch([uid], key, SamplingParams(),
-                                                 tail=self.round_tail())
-                    toks = [sampled.ref(uid)]
+        with setup_span("warm/split_key@1"):
+            _, key = split_key(jax.random.PRNGKey(0))
+        setup_decision("shapes", program="ragged_forward",
+                       rows=[shape.rows for shape in self._shapes])
+        setup_decision("shapes", program="decode_forward",
+                       rows=[cfg.max_sequences])
+
+        def forward(program, rows, toks, sampled=None):
+            with setup_span(f"warm/{program}@{rows}"):
                 out = self.put([uid], [toks], sampled=sampled)
                 if sampled is not None:
                     self.read_sampled(sampled)
+            return out
+
+        try:
+            self._rows_floor = cfg.max_tokens_per_batch
+            for toks in steps:
+                if toks is None:  # a decode step: the token the sampler drew
+                    with setup_span(f"warm/sample_rows@{cfg.max_sequences}"):
+                        sampled = self.sample_launch(
+                            [uid], key, SamplingParams(),
+                            tail=self.round_tail())
+                    out = forward("decode_forward", cfg.max_sequences,
+                                  [sampled.ref(uid)], sampled)
+                else:
+                    out = forward("ragged_forward", self._shapes[-1].rows,
+                                  toks)
                 if uid not in out and out.admission.rejected:
                     self.flush([uid])
                     raise RuntimeError(
@@ -602,7 +629,7 @@ class InferenceEngineV2:
             for shape in self._shapes[:-1]:
                 self._rows_floor = shape.rows
                 self.flush([uid])
-                self.put([uid], [[2, 2]])
+                forward("ragged_forward", shape.rows, [2, 2])
         finally:
             self._rows_floor = 0
         self.flush([uid])

@@ -15,9 +15,15 @@ module is the shared spine they are re-pointed at:
 * :class:`GoodputAccounter` — attributes wall-clock to productive step time
   vs. checkpoint, compile, startup and residual overhead; the ``Goodput/*``
   events answer "what fraction of wall-clock was productive training?".
-* recompile detection — a ``jax.monitoring`` listener counting jit cache
-  misses and their wall-time, so a shape-thrash loop shows up as
-  ``Compile/*`` events with the offending arg-shape diff attached.
+* the set-up ledger — ONE ``jax.monitoring`` listener, installed when the
+  package is imported, that keeps every trace / lower / compile event with
+  the program's name and whether the persistent cache answered; set-up
+  spans (``setup_span``) and decisions (``setup_decision``) from the
+  engines land in the same bounded list. ``setup_summary()`` folds it by
+  program; ``compile_stats()`` is its running total (executables built or
+  loaded, and the seconds of tracing, lowering and backend compile added
+  together), so a shape-thrash loop shows up as ``Compile/*`` events with
+  the offending arg-shape diff attached.
 * :class:`Heartbeat` — a per-rank freshness file the elastic agent watches to
   tell hung steps from slow steps (stale heartbeat → ``faulthandler`` stack
   dump before restart).
@@ -57,6 +63,9 @@ from .mfu import REGIONS as MFU_REGIONS
 # request-lifecycle stage registry for the Serve/stage.* / Fleet/stage.*
 # families lives in the stdlib-only reqtrace module (same import direction:
 # tools/trace_report.py loads THAT file standalone on jax-less nodes)
+# the set-up ledger's folds, stdlib-only for the same reason
+# (tools/trace_report.py --setup loads THAT file standalone)
+from . import setup_folds
 from .reqtrace import (FLEET_STAGES as REQTRACE_FLEET_STAGES,
                        SERVE_STAGES as REQTRACE_SERVE_STAGES,
                        STAGE_HISTOGRAMS as REQTRACE_STAGE_HISTOGRAMS)
@@ -149,7 +158,10 @@ EVENT_NAMES = frozenset(
      "Offload/nvme_read_gbps", "Offload/host_compute_s", "Offload/stall_s",
      "Offload/overlap_efficiency",
      "Memory/bytes_in_use", "Memory/peak_bytes_in_use",
-     "Compile/count", "Compile/total_s",
+     # Compile/total_s adds tracing, lowering and the backend's compile (or
+     # cache load) together, as compile_stats() does; the three apart:
+     "Compile/count", "Compile/total_s", "Compile/trace_s",
+     "Compile/lower_s", "Compile/backend_s",
      "Ckpt/save_s", "Ckpt/bytes_written",
      # two-phase all-ranks commit (checkpoint/engine.py::pod_commit):
      # cumulative seconds spent in phase-1 manifest writes + the
@@ -548,44 +560,202 @@ def get_active_recorder() -> Optional[FlightRecorder]:
 
 
 # =========================================================================
-# Recompile detection (jit cache misses)
+# Set-up ledger: what jax traced, lowered and compiled (or loaded), the
+# set-up spans around that work, and what the engines decided meanwhile
 # =========================================================================
 
-_compile_lock = threading.Lock()
-_compile_count = 0
-_compile_seconds = 0.0
+#: records the ring keeps. A benchmark cell's whole run leaves 1,700-7,300
+#: on the v5e (every inner jit of a traced program is one); a server that
+#: recompiles for days drops its oldest and counts them.
+SETUP_LEDGER_CAPACITY = 32768
+
+_COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile"}
+# fired by jax's compile_or_get_cached INSIDE the backend_compile interval,
+# on the compiling thread, when the persistent cache held the executable
+_CACHE_HIT_EVENTS = ("/jax/compilation_cache/cache_hits",
+                     "/jax/compilation_cache/cache_retrieval_time_sec")
+
+
+class SetupLedger:
+    """Process-wide bounded list of set-up records (shapes and folds:
+    ``monitor/setup_folds.py``), on ``time.perf_counter``. One append per
+    compile event, span or decision; nothing per round or step. The running
+    totals behind :func:`compile_stats` live here too, so that they survive
+    what the ring drops."""
+
+    def __init__(self, capacity: int = SETUP_LEDGER_CAPACITY):
+        self.capacity = int(capacity)
+        self._lock = threading.Lock()
+        self._ring: deque = deque()
+        self._local = threading.local()   # .cache_hit, .span (innermost id)
+        self._span_seq = 0
+        self.dropped = 0
+        self.phase_seconds = dict.fromkeys(setup_folds.PHASES, 0.0)
+        self.executables = 0
+
+    def _push(self, rec: Dict[str, Any]) -> Dict[str, Any]:
+        """Into the ring; the caller holds the lock."""
+        rec["thread"] = threading.get_ident()
+        if len(self._ring) >= self.capacity:
+            self._ring.popleft()
+            self.dropped += 1
+        self._ring.append(rec)
+        return rec
+
+    def _append(self, rec: Dict[str, Any]) -> Dict[str, Any]:
+        with self._lock:
+            return self._push(rec)
+
+    def on_jax_event(self, event: str, duration_secs: Optional[float] = None,
+                     **kw) -> None:
+        """The ONE ``jax.monitoring`` listener, registered for duration
+        events and for plain events (the cache's hit is a plain one)."""
+        if event in _CACHE_HIT_EVENTS:
+            self._local.cache_hit = True
+            return
+        phase = _COMPILE_PHASES.get(event)
+        if phase is None or duration_secs is None:
+            return
+        rec = {"kind": "compile", "t": time.perf_counter(),
+               "program": str(kw.get("fun_name", "?")), "phase": phase,
+               "dur": float(duration_secs)}
+        if phase == "compile":
+            rec["cached"] = bool(getattr(self._local, "cache_hit", False))
+            self._local.cache_hit = False
+        with self._lock:
+            self.phase_seconds[phase] += rec["dur"]
+            self.executables += phase == "compile"
+            self._push(rec)
+
+    def open_span(self, name: str, fields: Dict[str, Any]) -> Dict[str, Any]:
+        with self._lock:
+            self._span_seq += 1
+            rec = self._push({
+                "kind": "span", "id": self._span_seq, "name": name,
+                "t0": time.perf_counter(), "t1": None,
+                "parent": getattr(self._local, "span", None),
+                "fields": fields})
+        self._local.span = rec["id"]
+        return rec
+
+    def close_span(self, rec: Dict[str, Any]) -> None:
+        rec["t1"] = time.perf_counter()
+        self._local.span = rec["parent"]
+
+    def decide(self, name: str, **fields) -> Dict[str, Any]:
+        return self._append({"kind": "decision", "t": time.perf_counter(),
+                             "name": name, **fields})
+
+    def totals(self) -> Tuple[int, Dict[str, float]]:
+        """(executables built or loaded, seconds by phase) since the process
+        started, whatever the ring has dropped."""
+        with self._lock:
+            return self.executables, dict(self.phase_seconds)
+
+    def records(self) -> List[Dict[str, Any]]:
+        """A copy, in time order (a compile record and a decision by ``t``,
+        a span by ``t0``: the order they were appended in)."""
+        with self._lock:
+            return [dict(r) for r in self._ring]
+
+    def reset(self) -> None:
+        """Forget the records (tests). The running totals stay: they are
+        differences to whoever holds a base (``Telemetry._compile_base``)."""
+        with self._lock:
+            self._ring.clear()
+            self.dropped = 0
+
+
+#: THE ledger (the analog of ``metrics_registry``: jax offers no unregister,
+#: so one listener feeds one store for the life of the process).
+setup_ledger_store = SetupLedger()
 _compile_listener_installed = False
-
-
-def _on_jax_event(event: str, duration_secs: float, **_kw) -> None:
-    global _compile_count, _compile_seconds
-    if not event.startswith("/jax/core/compile"):
-        return
-    with _compile_lock:
-        # one backend_compile per executable build; trace/lower sub-phases
-        # only contribute wall-time
-        if event.endswith("backend_compile_duration"):
-            _compile_count += 1
-        _compile_seconds += duration_secs
 
 
 def install_compile_listener() -> None:
     """Register the process-wide ``jax.monitoring`` listener (idempotent —
-    jax offers no unregister, so exactly one is ever installed)."""
+    jax offers no unregister, so exactly one is ever installed). Called when
+    the package is imported: the ledger is always on."""
     global _compile_listener_installed
-    with _compile_lock:
-        if _compile_listener_installed:
-            return
-        _compile_listener_installed = True
+    if _compile_listener_installed:
+        return
+    _compile_listener_installed = True
     import jax.monitoring
 
-    jax.monitoring.register_event_duration_secs_listener(_on_jax_event)
+    jax.monitoring.register_event_duration_secs_listener(
+        setup_ledger_store.on_jax_event)
+    jax.monitoring.register_event_listener(setup_ledger_store.on_jax_event)
 
 
 def compile_stats() -> Tuple[int, float]:
-    """(total executable compiles, total compile wall-seconds) so far."""
-    with _compile_lock:
-        return _compile_count, _compile_seconds
+    """(executables built or loaded so far, seconds jax spent on them so
+    far): the ledger's running totals. The seconds are tracing, lowering and
+    backend compile (or the load from the persistent cache) ADDED TOGETHER
+    as jax reports them, nested phases counted again in their parents';
+    :func:`compile_phase_seconds` has them apart and :func:`setup_summary`
+    without the double count."""
+    executables, phases = setup_ledger_store.totals()
+    return executables, sum(phases.values())
+
+
+def compile_phase_seconds() -> Dict[str, float]:
+    """``{"trace", "lower", "compile"}``: :func:`compile_stats`'s seconds by
+    phase (``compile``: the backend's compile, or its load from the
+    persistent cache)."""
+    return setup_ledger_store.totals()[1]
+
+
+@contextlib.contextmanager
+def setup_span(name: str, **fields):
+    """A set-up span ``name`` around the work it encloses: a ``span`` record
+    in the ledger (its ``parent`` the enclosing set-up span of this thread),
+    a ``dstpu/setup/<name>`` annotation so that under any profiler it lies on
+    the trace's clock beside the device's program loads, and, where a flight
+    recorder is active, a ``setup/<name>`` span record there. Yields the
+    record's ``fields`` (the caller may add to them)."""
+    import jax
+
+    rec = setup_ledger_store.open_span(name, fields)
+    try:
+        with jax.profiler.TraceAnnotation("dstpu/setup/" + name):
+            yield fields
+    finally:
+        setup_ledger_store.close_span(rec)
+        recorder = get_active_recorder()
+        if recorder is not None:
+            recorder.record("span", "setup/" + name,
+                            dur=rec["t1"] - rec["t0"],
+                            data={"id": rec["id"], "parent": rec["parent"],
+                                  **fields})
+
+
+def setup_decision(name: str, **fields) -> None:
+    """A ``decision`` record: something an engine chose while it was built
+    that the whole run then lives with (``remat``: the rung; ``shapes``: the
+    static shapes ``warmup()`` builds)."""
+    rec = setup_ledger_store.decide(name, **fields)
+    recorder = get_active_recorder()
+    if recorder is not None:
+        recorder.record("event", "setup/decision." + name,
+                        data={k: v for k, v in rec.items()
+                              if k not in ("kind", "name", "thread")})
+
+
+def setup_ledger() -> List[Dict[str, Any]]:
+    """The ledger's records (compile, span, decision) in time order."""
+    return setup_ledger_store.records()
+
+
+def setup_summary(until: Optional[float] = None) -> Dict[str, Any]:
+    """The ledger folded by program (``setup_folds.summarize``): trace /
+    lower / compile seconds, executables built, cache misses, the spans with
+    their self times, the decisions. ``until``: only what ended before that
+    ``time.perf_counter`` instant (a benchmark's window opening)."""
+    return setup_folds.summarize(setup_ledger_store.records(), until=until,
+                                 dropped=setup_ledger_store.dropped)
 
 
 def tree_shapes(tree: Any) -> Dict[str, str]:
@@ -890,7 +1060,6 @@ class Telemetry:
         self.jsonl = jsonl
         self._closed = False
         self._last_shapes: Optional[Dict[str, str]] = None
-        self._compile_base = (0, 0.0)
         self._last_memory_step = -1
         self._last_step_end: Optional[float] = None
         self._step_hist = self.registry.histogram("step_time_s")
@@ -919,7 +1088,7 @@ class Telemetry:
                 install_hang_dump(
                     os.path.join(cfg.output_dir, f"stacks_rank{rank}.txt"))
         install_compile_listener()
-        self._compile_base = compile_stats()
+        self._compile_base = setup_ledger_store.totals()
         if jsonl is not None and hasattr(jsonl, "attach_recorder"):
             jsonl.attach_recorder(self.recorder)
         self.recorder.record(
@@ -952,23 +1121,28 @@ class Telemetry:
             dur = (now - self._last_step_end
                    if self._last_step_end is not None else 0.0)
         self._last_step_end = now
-        count, seconds = compile_stats()
+        count, phases = setup_ledger_store.totals()
         d_count = count - self._compile_base[0]
-        d_seconds = seconds - self._compile_base[1]
+        d_phases = {f"{p}_s": phases[p] - self._compile_base[1][p]
+                    for p in phases}
+        # tracing, lowering and the backend's compile (or cache load) added
+        # together, as compile_stats() has them
+        d_seconds = sum(d_phases.values())
         # rebase unconditionally: trace/lower durations arrive even without a
         # backend compile (cache hits, HLO re-lowering) and must not be
         # re-deducted from 'productive' on every later step
-        self._compile_base = (count, seconds)
+        self._compile_base = (count, phases)
         span_data: Optional[Dict[str, Any]] = None
         if d_count > 0:
             self.registry.counter("recompiles").incr(d_count)
             new_shapes = tree_shapes(batch) if batch is not None else {}
             diff = shape_diff(self._last_shapes, new_shapes)
             self._last_shapes = new_shapes
-            self.recorder.record("event", "compile/train_step", step=step,
-                                 dur=d_seconds,
-                                 data={"compiles": d_count,
-                                       "shape_diff": diff})
+            # dur adds tracing, lowering and the backend's compile (or
+            # cache load) together; trace_s / lower_s / compile_s apart
+            self.recorder.record(
+                "event", "compile/train_step", step=step, dur=d_seconds,
+                data={"compiles": d_count, "shape_diff": diff, **d_phases})
             span_data = {"compiles": d_count, "compile_s": d_seconds}
         elif batch is not None and self._last_shapes is None:
             self._last_shapes = tree_shapes(batch)
@@ -1220,9 +1394,12 @@ class Telemetry:
             ev.append(("Memory/bytes_in_use", g["hbm_bytes_in_use"], step))
             ev.append(("Memory/peak_bytes_in_use",
                        g["hbm_peak_bytes_in_use"], step))
-        count, seconds = compile_stats()
+        count, phases = setup_ledger_store.totals()
         ev.append(("Compile/count", count, step))
-        ev.append(("Compile/total_s", seconds, step))
+        ev.append(("Compile/total_s", sum(phases.values()), step))
+        ev.append(("Compile/trace_s", phases["trace"], step))
+        ev.append(("Compile/lower_s", phases["lower"], step))
+        ev.append(("Compile/backend_s", phases["compile"], step))
         if snap["counters"].get("ckpt_bytes_written"):
             ev.append(("Ckpt/bytes_written",
                        snap["counters"]["ckpt_bytes_written"], step))
@@ -1242,6 +1419,12 @@ class Telemetry:
         if self.goodput is not None:
             self.recorder.record("goodput", "goodput/summary",
                                  data=self.goodput.summary())
+        # the set-up ledger whole (most of it predates this recorder: the
+        # engine builds its telemetry midway through its own set-up), for
+        # ``tools/trace_report.py --setup``
+        self.recorder.record("event", "setup/ledger",
+                             data={"records": setup_ledger(),
+                                   "dropped": setup_ledger_store.dropped})
         try:
             from ..comm.comms_logging import comms_logger
 

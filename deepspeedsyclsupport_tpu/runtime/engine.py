@@ -48,6 +48,7 @@ from ..comm.comms_logging import comms_logger
 from ..comm.topology import MeshTopology, build_topology
 from ..utils.fault_injection import get_fault_injector
 from ..monitor import MonitorMaster
+from ..monitor.telemetry import setup_decision, setup_span
 from ..utils.logging import log_dist, logger
 from ..utils.timer import (BACKWARD_GLOBAL_TIMER, FORWARD_GLOBAL_TIMER,
                            STEP_GLOBAL_TIMER, SynchronizedWallClockTimer,
@@ -115,6 +116,7 @@ def initialize(model: Any = None,
 
 
 class Engine:
+    @setup_span("engine", side="train")
     def __init__(self, loss_fn: Callable, params: Any, config: Any,
                  topology: Optional[MeshTopology] = None,
                  lr_schedule: Optional[Callable] = None,
@@ -287,29 +289,32 @@ class Engine:
                                                         off_par.device)
                                    else "cpu")
         self._swapper = None
-        if self.offload_device is not None:
-            self._init_offload(init_fn() if init_fn is not None else params,
-                               tx, off_opt, off_par)
-        else:
-            self.master_params = None
-            if init_fn is not None:
-                self.params = jax.jit(
-                    init_fn, out_shardings=self.param_shardings)()
+        with setup_span("state"):   # master weights, optimizer state
+            if self.offload_device is not None:
+                self._init_offload(
+                    init_fn() if init_fn is not None else params,
+                    tx, off_opt, off_par)
             else:
-                self.params = jax.tree_util.tree_map(
-                    lambda x, s: jax.device_put(jnp.asarray(x), s), params,
-                    self.param_shardings)
-            opt_shapes = jax.eval_shape(tx.init, self.params)
-            self.opt_shardings = zero_lib.tree_optimizer_shardings(
-                opt_shapes, self.params, self.param_shardings, self.topology,
-                stage)
-            self.opt_state = jax.jit(
-                tx.init, out_shardings=self.opt_shardings)(self.params)
+                self.master_params = None
+                if init_fn is not None:
+                    self.params = jax.jit(
+                        init_fn, out_shardings=self.param_shardings)()
+                else:
+                    self.params = jax.tree_util.tree_map(
+                        lambda x, s: jax.device_put(jnp.asarray(x), s),
+                        params, self.param_shardings)
+                opt_shapes = jax.eval_shape(tx.init, self.params)
+                self.opt_shardings = zero_lib.tree_optimizer_shardings(
+                    opt_shapes, self.params, self.param_shardings,
+                    self.topology, stage)
+                self.opt_state = jax.jit(
+                    tx.init, out_shardings=self.opt_shardings)(self.params)
         log_dist(zero_lib.describe_memory_plan(self.params, self.topology,
                                                stage, self.offload_device))
 
         # ---------------------------------------------------------- step fns
         self._train_batch_fn = None  # built lazily (needs gas)
+        self._first_step_pending = True  # train_batch's set-up span
         self._remat_logged = None  # the rung last logged (_note_remat_choice)
         self._remat_auto = False   # the section names no policy: by room
         self._grad_fn = None
@@ -995,6 +1000,7 @@ class Engine:
         choice = self.remat_choice
         if choice is not None and choice != self._remat_logged:
             self._remat_logged = dict(choice)
+            setup_decision("remat", **choice)
             log_dist(f"activation checkpointing: rung {choice['rung']}, "
                      f"{choice['saved_bytes'] / 2**30:.3f} GiB saved for the "
                      f"backward pass on each device")
@@ -1018,6 +1024,11 @@ class Engine:
             "the train step does not fit the device with rung %s (%.3f GiB "
             "saved): falling back to %s and compiling again",
             choice["rung"], choice["saved_bytes"] / 2**30, leaner)
+        # the step's second build stands in the set-up ledger behind this;
+        # what the leaner rung saves is the next remat decision's to say
+        setup_decision("remat", rung=leaner, saved_bytes=None,
+                       auto=True, stepped_down_from=choice["rung"],
+                       saved_bytes_before=choice["saved_bytes"])
         self.module.config.remat_policy = leaner
         self.module.remat_choice = None
         self._train_batch_fn = self._build_train_batch_fn()
@@ -1099,6 +1110,12 @@ class Engine:
         ``(gas, step_batch, ...)`` and scans). The analog of the reference loop
         forward→backward→step and of ``PipelineEngine.train_batch``
         (``pipe/engine.py:321``)."""
+        if self._first_step_pending:
+            # the engine's first step is set-up: trace, lowering, compile or
+            # cache load and the dispatch of the first execution
+            self._first_step_pending = False
+            with setup_span("first_step"):
+                return Engine.train_batch(self, batch)
         if self._sentinel is not None and self._sentinel.offer_batch():
             # journaled bad position being replayed (post-rollback or
             # post-restart): consume-and-discard BEFORE any dispatch. No

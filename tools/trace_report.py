@@ -14,6 +14,7 @@ Usage::
     python tools/trace_report.py telemetry_logs/ --pod      # pod-scope view
     python tools/trace_report.py fleet_root/ --fleet        # fleet view
     python tools/trace_report.py fleet_root/ --requests     # request waterfall
+    python tools/trace_report.py telemetry_logs/flightrec_rank0.jsonl --setup
 
 Inputs may be directories (their ``flightrec*.jsonl``), glob patterns, or
 explicit files; rank ids are inferred from the ``rank<N>`` filename
@@ -23,6 +24,13 @@ wall-clock (the SPMD analog of per-rank collective latency — a host far
 above the minimum is the straggler). ``--pod`` switches to the full
 pod-scope report (``tools/pod_report.py``): clock-aligned per-step skew,
 straggler ledger and the per-traffic-class bandwidth decomposition.
+
+``--setup`` reads the set-up ledger (``monitor/telemetry.py``: what every
+program cost to trace, lower and compile or load, whether the persistent
+cache held it, the set-up spans and what the engines decided) out of a
+flight-recorder JSONL (its last ``setup/ledger`` record) or out of a JSON
+file a live process wrote (``json.dump(telemetry.setup_ledger(), f)``): the
+operator's reading of a slow cold start.
 
 Exit code 0 on success, 2 when no input file yields any records.
 """
@@ -41,19 +49,44 @@ import pod_report  # noqa: E402
 _pod = pod_report.pod
 
 
-def _load_reqtrace():
-    """Load ``monitor/reqtrace.py`` by file path, NOT through the package
+def _load_monitor_module(name: str):
+    """Load ``monitor/<name>.py`` by file path, NOT through the package
     (same login-node contract as the pod.py loader above: the package
-    __init__ imports jax; reqtrace is deliberately stdlib-only)."""
+    __init__ imports jax; ``reqtrace`` and ``setup_folds`` are deliberately
+    stdlib-only)."""
     import importlib.util
 
     path = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "deepspeedsyclsupport_tpu", "monitor",
-        "reqtrace.py")
-    spec = importlib.util.spec_from_file_location("_dstpu_reqtrace", path)
+        name + ".py")
+    spec = importlib.util.spec_from_file_location("_dstpu_" + name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+def setup_report(path: str) -> Optional[str]:
+    """The set-up ledger in ``path`` rendered (``setup_folds.render``): one
+    line a program, the span tree with self times, the decisions. ``path``
+    is a flight-recorder JSONL (the last ``setup/ledger`` record a dump
+    wrote) or a JSON file holding the records themselves, as a list or as
+    ``{"records": [...], "dropped": n}``. None where it holds no ledger."""
+    records, dropped = None, 0
+    try:
+        with open(path) as f:
+            doc = json.load(f)
+        records = doc["records"] if isinstance(doc, dict) else doc
+        dropped = doc.get("dropped", 0) if isinstance(doc, dict) else 0
+    except (OSError, ValueError, KeyError, TypeError):
+        dumps = [r for r in load_records(path)
+                 if r.get("name") == "setup/ledger"]
+        if dumps:
+            data = dumps[-1].get("data") or {}
+            records, dropped = data.get("records"), data.get("dropped", 0)
+    if not records:
+        return None
+    return "\n".join(_load_monitor_module("setup_folds").render(
+        records, dropped=dropped))
+
 
 #: A goodput split must account for at least this fraction of wall-clock —
 #: the accounter computes ``other`` as the residual, so anything below this
@@ -136,11 +169,17 @@ def events_summary(records: List[Dict[str, Any]]) -> List[str]:
     compiles = [r for r in records if r.get("kind") == "event"
                 and r.get("name") == "compile/train_step"]
     for r in compiles[-5:]:
-        diff = (r.get("data") or {}).get("shape_diff", {})
+        data = r.get("data") or {}
+        diff = data.get("shape_diff", {})
         what = ("initial compile" if diff.get("initial")
                 else f"shape diff: {json.dumps(diff)[:120]}")
+        # the duration adds the three phases; a newer stream has them apart
+        phases = ", ".join(f"{p} {_fmt_s(data[p + '_s'])}"
+                           for p in ("trace", "lower", "compile")
+                           if p + "_s" in data)
         lines.append(f"  step {r.get('step', '?')}: recompile "
-                     f"({_fmt_s(r.get('dur', 0.0))}) — {what}")
+                     f"({_fmt_s(r.get('dur', 0.0))}"
+                     f"{': ' + phases if phases else ''}) — {what}")
     dumps = [r for r in records if r.get("kind") == "dump"]
     for r in dumps:
         reason = (r.get("data") or {}).get("reason", "?")
@@ -558,7 +597,7 @@ def requests_report(root: str, worst_n: int = 5, window_s: float = 60.0,
     quantiles, tail attribution, reconciliation, SLO burn and worst-request
     waterfalls. ``root`` may be a fleet root (``replica*/`` + router
     stream) or a single journal directory."""
-    rt = _load_reqtrace()
+    rt = _load_monitor_module("reqtrace")
     streams, router_records = rt.load_root(root)
     traces = rt.join_traces(streams, router_records)
     if not traces:
@@ -826,6 +865,12 @@ def main(argv: Optional[List[str]] = None) -> int:
                          "decomposition, tail attribution, SLO burn and "
                          "worst-request waterfalls from a fleet root or "
                          "journal directory")
+    ap.add_argument("--setup", action="store_true",
+                    help="the set-up ledger: one line a program (trace, "
+                         "lower, compile or load, executables, how many the "
+                         "persistent cache missed) and the set-up span tree "
+                         "with self times, from a flight-recorder JSONL or "
+                         "a JSON dump of telemetry.setup_ledger()")
     ap.add_argument("--worst", type=int, default=5,
                     help="worst-request exemplars to show with --requests")
     ap.add_argument("--slo-window", type=float, default=60.0,
@@ -835,6 +880,15 @@ def main(argv: Optional[List[str]] = None) -> int:
     args = ap.parse_args(argv)
     if args.pod:
         return pod_report.main([*args.files, "--last", str(args.last)])
+    if args.setup:
+        reports = [setup_report(os.path.expanduser(p)) for p in args.files]
+        reports = [r for r in reports if r]
+        if not reports:
+            print("no set-up ledger found in any input file",
+                  file=sys.stderr)
+            return 2
+        print("\n\n".join(reports))
+        return 0
     if args.requests:
         reports = [requests_report(os.path.expanduser(p),
                                    worst_n=args.worst,
