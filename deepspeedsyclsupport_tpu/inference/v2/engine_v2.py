@@ -37,6 +37,7 @@ from ..sampling import SamplingParams, sample_token_dyn, split_key
 from ...comm.topology import MeshTopology, build_topology
 from ...monitor.reqtrace import MOE_TAIL_FIELDS, NO_PHASE
 from ...monitor.telemetry import setup_decision, setup_span
+from ...ops.paged_attention import warm_tiles
 from ...utils.logging import log_dist
 
 
@@ -396,13 +397,16 @@ class InferenceEngineV2:
         return NO_PHASE if spans is None else spans.phase(name)
 
     def _note_forward(self, descs, lengths, atoms: int = 0,
-                      rows: int = 0) -> None:
+                      rows: int = 0, warm: int = 0) -> None:
         """What the forward about to be launched covers, for the round's
         record; called BEFORE it, so ``ctx_tokens`` is the context the
         attention kernel must read and ``kv_blocks`` the tables it walks.
         ``atoms``: the live ``atom_q_size``-row tiles a ``ragged_forward``'s
         batch was cut into; its one-token chunks, like every row of a
-        ``decode_forward``, are ``decode_rows``, a one-row tile each.
+        ``decode_forward``, are ``decode_rows``, a one-row tile each;
+        ``warm`` of all those tiles stand behind a live tile of their own
+        kernel call (``paged_attention.warm_tiles``, from the batch as the
+        kernels' grids lay it out).
         What those tiles cover (``attn_pairs``, ``dec_ctx_tokens``) and
         what the kernels' loop steps walk for it (``kv_step_keys``,
         ``kv_tile_keys``) is ``ragged.attention_work``'s count. ``rows``: the
@@ -427,7 +431,7 @@ class InferenceEngineV2:
             kv_step_keys=kv_step_keys, kv_tile_keys=kv_tile_keys,
             n_seqs=len(descs), tokens=sum(lengths),
             decode_rows=sum(n == 1 for n in lengths), atoms=atoms,
-            rows=rows,
+            warm_tiles=warm, rows=rows,
             # the rule _run routes by: one token on top of cached context
             # is a decode step, anything else is prompt
             prefill_tokens=sum(n for d, n in zip(descs, lengths)
@@ -1054,8 +1058,11 @@ class InferenceEngineV2:
                 chunks, shape.rows, cfg.max_sequences, cfg.blocks_per_seq,
                 atom_q=cfg.atom_q_size if self._use_atoms else None,
                 atoms=shape.atoms)
-            self._note_forward(descs, lengths, atoms=batch.live_atoms,
-                               rows=shape.rows)
+            self._note_forward(
+                descs, lengths, atoms=batch.live_atoms, rows=shape.rows,
+                # the one-row call's grid is the slots, the atoms' the atoms
+                warm=warm_tiles(batch.dec_len > 0)
+                + warm_tiles(batch.atom_qlen > 0) if batch.tile_args else 0)
             state = () if self._state_free is None else (ssm_pieces(
                 chunks, shape.rows, cfg.max_sequences,
                 self.model.config.state_chunk_size, shape.pieces),)
@@ -1114,7 +1121,9 @@ class InferenceEngineV2:
                                 np.int32)
                 slots[:len(chunks)] = [d.state_slot for d, _n in chunks]
                 state = (slots,)
-            self._note_forward(*zip(*chunks), rows=cfg.max_sequences)
+            self._note_forward(
+                *zip(*chunks), rows=cfg.max_sequences,
+                warm=warm_tiles(active) if self._caches_kv else 0)
         with self._phase("dispatch"):
             tokens, sampled, take_from = self._token_operands(tokens,
                                                               sampled)

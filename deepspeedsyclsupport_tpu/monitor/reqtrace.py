@@ -107,6 +107,10 @@ ROUND_PHASES = (
 #: chunks (a one-row tile each; every row of a ``decode_forward``), and
 #: ``atoms``, the live ``atom_q_size``-row tiles the longer chunks of a
 #: ``ragged_forward`` were cut into (0 where the attention takes no atoms);
+#: ``warm_tiles``, those of both kinds whose first KV step the tile before
+#: them in their kernel call's grid fetched (``ops.paged_attention``'s
+#: hand-over: the live tiles less one a call and one a dead gap; head tiles
+#: of one atom, always warm behind its first, are not counted);
 #: and what those tiles cover (``ragged.attention_work``): ``attn_pairs``,
 #: the (row, cached token) pairs of the chunks of two tokens or more, and
 #: ``dec_ctx_tokens``, the one-token chunks' context lengths; and what the
@@ -130,7 +134,7 @@ ROUND_PHASES = (
 FORWARD_FIELDS = ("n_seqs", "tokens", "prefill_tokens", "ctx_tokens",
                   "kv_blocks", "decode_rows", "atoms", "attn_pairs",
                   "dec_ctx_tokens", "moe_touched", "ahead", "spec_rows",
-                  "kv_step_keys", "kv_tile_keys", "rows")
+                  "kv_step_keys", "kv_tile_keys", "rows", "warm_tiles")
 #: What the device counts, in the order it rides behind the sampled tokens
 #: (``engine.moe_tail``). ``moe_rows`` is on the record ONLY of a program
 #: that holds a share of the router's experts (one chip of an expert-

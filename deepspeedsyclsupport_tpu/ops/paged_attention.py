@@ -33,6 +33,15 @@ Kernel shape (TPU-first, not a CUDA translation):
   and compute overlaps the next step's fetch via the DMA queue. A step is
   ONE block under a K-and-V pool's one-row tile and several under every
   other tile, by the tile's shape (:func:`_kv_pages_per_step`).
+* the overlap does not stop at a tile's end: a tile's LAST step starts the
+  FIRST step's copies of the next tile of the grid (where both walk at
+  least one step: :func:`tile_span`), into the scratch slot it does not
+  read itself, and that tile only waits for them. So the bus works through
+  a tile's last products, its division and write-out and the next tile's
+  q turned head-major, where it used to stand idle: only the first tile of
+  a call, and one behind a tile that walks nothing, start cold. The two
+  slots and their semaphores live across grid steps, so the grid must run
+  in order on one core (``dimension_semantics`` ``arbitrary``, stated).
 * GQA: queries reshape to [KVH, G, D] and each kv head batch-matmuls its
   group — grouped heads share the streamed KV block, the reason GQA decode is
   bandwidth-cheap on TPU.
@@ -152,6 +161,43 @@ def paged_decode_attention(q, k_cache, v_cache, block_tables, seq_lens, *,
 
 
 # ===================================================================== prefill
+def tile_span(pos0, qlen, *, block_size: int, max_blocks: int, pages: int = 1,
+              window=None, latent: bool = False, xp=jnp):
+    """``(lo_blk, lo_step, kv_hi, walks)`` of a tile from its two scalars:
+    the first block and the first loop step its window leaves it, the keys
+    it may see (clamped to its table), and whether its loop runs AT ALL.
+    ``walks`` is the one predicate of the hand-over between tiles: a tile
+    starts its successor's first copies iff both walk, and waits for copies
+    it did not start iff it and its predecessor walk; the kernel evaluates
+    it on its own scalars and on both neighbours' (``xp`` ``jnp``), a test
+    or the host on a whole batch (``xp=np``). A K-and-V pool's tile with no
+    row never enters the body; a latent pool's does, and walks what
+    ``pos0`` alone leaves it (nothing, as the engine builds a dead tile)."""
+    kv_hi = xp.minimum(pos0 + qlen, max_blocks * block_size)
+    # sliding window: blocks entirely below row 0's window are masked for
+    # EVERY row — skip their DMA and matmuls instead of NEG_INF-ing them
+    lo_blk = 0 * pos0 if window is None else \
+        xp.maximum(pos0 + 1 - window, 0) // block_size
+    lo_step = lo_blk if pages == 1 else lo_blk // pages
+    # on lo_step (not just kv_hi > 0): with a sliding window and pos0 beyond
+    # the table's capacity, lo_blk can reach max_blocks — the loop would run
+    # zero iterations, so a copy started for it would index the table out of
+    # bounds and never be awaited
+    walks = lo_step * (pages * block_size) < kv_hi
+    if not latent:
+        walks = xp.logical_and(walks, qlen > 0)
+    return lo_blk, lo_step, kv_hi, walks
+
+
+def warm_tiles(walks) -> int:
+    """Tiles of ONE kernel call whose first step the tile before them
+    fetched: ``walks`` [tiles] bool in grid order (:func:`tile_span`'s, or
+    what a host knows of it: a live row, a live atom). The tiles that walk,
+    less one for the first of every unbroken run of them."""
+    walks = np.asarray(walks, bool)
+    return int(np.count_nonzero(walks[1:] & walks[:-1]))
+
+
 def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
                     q_ref, k_hbm, *refs, v_dim=None, masked: bool = False,
                     **choices):
@@ -161,8 +207,8 @@ def _prefill_kernel(block_tables_ref, pos0_ref, qlen_ref, layer_ref,  # scalars
     zeros and does nothing else: no q turned head-major, no accumulator, no
     division (on the v5e, 39 atoms of 32 heads of which 4 live at 300 keys:
     0.47 ms a call, 0.82 when the dead walked the body; PERF.md, PR 46). A
-    latent pool's program is the accepted one: every tile walks the body,
-    a dead one through a loop of zero steps."""
+    latent pool's every tile walks the body, a dead one through a loop of
+    zero steps (it hands over nothing and is handed nothing)."""
     a = pl.program_id(0)
     tile = functools.partial(
         _attend_tile, a, block_tables_ref, pos0_ref, qlen_ref, layer_ref,
@@ -182,7 +228,7 @@ def _attend_tile(a, block_tables_ref, pos0_ref, qlen_ref, layer_ref,
                  q_ref, k_hbm, *refs,
                  block_size: int, max_blocks: int, group: int,
                  use_alibi: bool, window, v_dim=None, pages: int = 1,
-                 masked: bool = False):
+                 masked: bool = False, head_tiles: int = 1):
     """One program per ATOM: a ≤block_q-token slice of ONE sequence's packed
     prefill chunk — or, at ``BQ = 1`` (the decode entry), one sequence's
     newest token; the serving forwards never put a one-token chunk into a
@@ -195,43 +241,76 @@ def _attend_tile(a, block_tables_ref, pos0_ref, qlen_ref, layer_ref,
     NEVER materialized in HBM (the O(S·max_ctx) gather this replaces).
 
     ``refs`` after K: V in HBM, the alibi slopes, the output, the K and V
-    scratch and the DMA semaphores; a latent pool (``v_dim``: V is the
-    leading lanes of the K tile) has neither V nor its scratch. A second
-    grid axis, where the wrapper made one, tiles the heads of the ONE kv
-    head: the body sees its tile's heads only and needs no index of it.
+    scratch, the DMA semaphores and one int32 in SMEM; a latent pool
+    (``v_dim``: V is the leading lanes of the K tile) has neither V nor its
+    scratch. A second grid axis of ``head_tiles`` steps, where the wrapper
+    made one, tiles the heads of the ONE kv head: the body sees its tile's
+    heads only and needs no index of it but to name its neighbours.
     ``masked`` (a K-and-V pool under a sparse-attention indexer): after V
     the SELECTION ``[A, BQ, keys]`` int8 in HBM and, after V's, its scratch;
     a step's ``[BQ, step keys]`` of it (whole 128-key lane tiles) rides the
-    step's DMAs and a pair counts only where it is nonzero."""
+    step's DMAs and a pair counts only where it is nonzero.
+
+    The hand-over: the tile's last step starts the first step's copies of
+    its SUCCESSOR in the grid (the next head tile of the atom, else the
+    next atom) where that walks a step (:func:`tile_span`, on the
+    successor's own scalars), and a tile whose PREDECESSOR walked waits for
+    its first step without starting it. Which of the two slots that step
+    lies in is carried in the SMEM scalar: the tile that hands over writes
+    it (the slot its own last step does not read), so a step's slot follows
+    the steps the call has walked and no longer the step's own parity; a
+    cold tile starts in slot 0. Every kind of tile takes it, by the one
+    rule. On the v5e, us a tile beside its blocks, cold | warm: a one-row
+    tile 1.44 | 0.24 at 32 heads over 32, 0.89 | 0.24 at 16 over 16, 0.53 |
+    0.19 at 32 over 2, a latent pool's 2.03 | 1.10; a 128-row atom 13.9 |
+    12.4 at 16 over 16, 22.8 | 22.4 at 32 over 32, a latent pool's 36.7 |
+    29.4, and 13.6 | 13.75 at 32 over 2 and 18.15 | 18.24 under a selection
+    at 32 over 4: those two read 0.5-1.2 % MORE (their first copy already
+    rode under the atom's own prologue) and keep the rule all the same
+    (PERF.md section 6, PR 52)."""
     latent = v_dim is not None
     sel_hbm = sel_vmem = None
     if latent:
-        ab_ref, out_ref, k_vmem, sem = refs
+        ab_ref, out_ref, k_vmem, sem, slot_ref = refs
     elif masked:
         (v_hbm, sel_hbm, ab_ref, out_ref, k_vmem, v_vmem, sel_vmem,
-         sem) = refs
+         sem, slot_ref) = refs
     else:
-        v_hbm, ab_ref, out_ref, k_vmem, v_vmem, sem = refs
+        v_hbm, ab_ref, out_ref, k_vmem, v_vmem, sem, slot_ref = refs
     # the MXU is fed the pool's own dtype: no float32 copy of q, K or V
     mxu = k_vmem.dtype
-    pos0 = pos0_ref[a]
-    qlen = qlen_ref[a]
     layer = layer_ref[0]   # which [num_slots, KVH, D] of the pool to read
-    # kv tokens this atom may see, clamped to the block table's capacity so
-    # the prefetch below can never index past the table or start a DMA that
-    # is never awaited
-    kv_hi = jnp.minimum(pos0 + qlen, max_blocks * block_size)
-    # sliding window: blocks entirely below row 0's window are masked for
-    # EVERY row — skip their DMA and matmuls instead of NEG_INF-ing them
-    if window is not None:
-        lo_blk = jnp.maximum(pos0 + 1 - window, 0) // block_size
-    else:
-        lo_blk = jnp.int32(0)
     # the loop below walks STEPS of ``pages`` blocks: one block where the
     # wrapper gave one (a K-and-V pool's one-row tile: the loop over blocks
     # it always was)
     step_keys = pages * block_size
-    lo_step = lo_blk if pages == 1 else lo_blk // pages
+    span = functools.partial(tile_span, block_size=block_size,
+                             max_blocks=max_blocks, pages=pages,
+                             window=window, latent=latent)
+    # kv tokens this atom may see, clamped to the block table's capacity so
+    # no copy can index past the table or be started and never awaited
+    pos0 = pos0_ref[a]
+    qlen = qlen_ref[a]
+    lo_blk, lo_step, kv_hi, walks = span(pos0, qlen)
+    # the grid's tile before this one and the one after it: the atom's
+    # other head tiles first. Their scalars are scalar-prefetch arrays,
+    # readable at any index (clamped into the grid; ``has_*`` says whether
+    # the neighbour is there)
+    atoms = pl.num_programs(0)
+    if head_tiles > 1:
+        t = pl.program_id(1)
+        before = jnp.where(t > 0, a, a - 1)
+        after = jnp.where(t + 1 < head_tiles, a, a + 1)
+    else:
+        before, after = a - 1, a + 1
+    has_before, has_after = before >= 0, after < atoms
+    before, after = jnp.maximum(before, 0), jnp.minimum(after, atoms - 1)
+    warm = jnp.logical_and(
+        jnp.logical_and(walks, has_before),
+        span(pos0_ref[before], qlen_ref[before])[3])
+    next_lo_blk, next_lo_step, next_kv_hi, next_walks = span(
+        pos0_ref[after], qlen_ref[after])
+    next_walks = jnp.logical_and(next_walks, has_after)
     # a K-and-V pool's q is turned kv head-major as float32, where a kv
     # head's group of 8 is whole vregs (a move, no shuffle)
     q = q_ref[0] if latent else q_ref[0].astype(jnp.float32)   # [BQ, H, D]
@@ -248,23 +327,20 @@ def _attend_tile(a, block_tables_ref, pos0_ref, qlen_ref, layer_ref,
         jnp.int32, (kvh, bq * g, step_keys) if latent
         else (bq * g, step_keys), 1 if latent else 0) // g
 
-    def block_of(step, i):
-        """Block ``i`` of ``step``, as an index into the table's row. A
-        wide step's blocks below the window's first or past the context's
-        last are read from the nearest block the loop of one block a step
-        would read: every key of such a block is masked by its POSITION,
-        but 0 x whatever lay in scratch or in a page the sequence does not
-        own is NaN where that is NaN."""
-        if pages == 1:
-            return step
-        return jnp.clip(step * pages + i, lo_blk, (kv_hi - 1) // block_size)
-
-    def copies(step, slot):
+    def copies(step, slot, tile=a, first=lo_blk, hi=kv_hi):
+        """The copies of ``step`` of ``tile`` (this one, or the grid's next
+        with ITS first block and keys) into ``slot``. A wide step's blocks
+        below the window's first or past the context's last are read from
+        the nearest block the loop of one block a step would read: every
+        key of such a block is masked by its POSITION, but 0 x whatever lay
+        in scratch or in a page the sequence does not own is NaN where that
+        is NaN."""
         pools = ((k_hbm, k_vmem),) if latent \
             else ((k_hbm, k_vmem), (v_hbm, v_vmem))
         cps = []
         for i in range(pages):       # each block through its own table entry
-            blk = block_tables_ref[a, block_of(step, i)]
+            blk = block_tables_ref[tile, step if pages == 1 else jnp.clip(
+                step * pages + i, first, (hi - 1) // block_size)]
             for n, (hbm, vmem) in enumerate(pools):
                 dst = vmem.at[slot] if pages == 1 else \
                     vmem.at[slot, pl.ds(i * block_size, block_size)]
@@ -273,25 +349,48 @@ def _attend_tile(a, block_tables_ref, pos0_ref, qlen_ref, layer_ref,
                     dst, sem.at[slot, n]))
         if masked:       # the step's columns of the atom's selection
             cps.append(pltpu.make_async_copy(
-                sel_hbm.at[a, :, pl.ds(pl.multiple_of(
+                sel_hbm.at[tile, :, pl.ds(pl.multiple_of(
                     step * step_keys, step_keys), step_keys)],
                 sel_vmem.at[slot], sem.at[slot, len(pools)]))
         return cps
 
-    # guard on lo_step (not just kv_hi > 0): with a sliding window and pos0
-    # beyond the table's capacity, lo_blk can reach max_blocks — the loop
-    # below would run zero iterations, so an unguarded warm-up would index
-    # the table out of bounds and start a DMA that is never awaited
-    @pl.when(lo_step * step_keys < kv_hi)
+    # Every copy is started once and awaited once, on the semaphore of the
+    # slot it was started into; the ORDER is the grid's (sequential, one
+    # core). Step ``j`` of a tile is awaited at the top of its own loop
+    # turn, in ``slot_of(j)``. It was started (1) by the turn before it, of
+    # the same tile, into the slot that turn did not read; or, the tile's
+    # first step, (2) by this prologue into slot 0, iff the tile walks and
+    # is not ``warm``; or (3) by the LAST turn of the grid's tile before,
+    # into the slot that turn did not read, written to ``slot_ref``, iff
+    # that tile walked and this one walks: the same two facts, from the
+    # same scalars through the same function, as ``warm`` here. So exactly
+    # one of (2) and (3) holds for a tile that walks and neither for one
+    # that does not: a dead or absent successor is handed nothing, and no
+    # tile waits for what nobody started. A last turn's hand-over lands in
+    # the slot the turn before it read, as its own next step would have.
+    first_slot = jnp.where(warm, slot_ref[0], 0)
+
+    def slot_of(j):
+        return jax.lax.rem(first_slot + j - lo_step, 2)
+
+    @pl.when(jnp.logical_and(walks, jnp.logical_not(warm)))
     def _():
-        for cp in copies(lo_step, jax.lax.rem(lo_step, 2)):
+        for cp in copies(lo_step, first_slot):
             cp.start()
 
     def start_next(j):
-        @pl.when(jnp.logical_and((j + 1) * step_keys < kv_hi,
-                                 j + 1 < -(-max_blocks // pages)))
+        """In a turn that is not the tile's last, its next step's copies;
+        in its last, the first step's of the grid's next tile: one
+        conditional start, the scalars chosen."""
+        more = jnp.logical_and((j + 1) * step_keys < kv_hi,
+                               j + 1 < -(-max_blocks // pages))
+        pick = functools.partial(jnp.where, more)
+
+        @pl.when(jnp.logical_or(more, next_walks))
         def _():
-            for cp in copies(j + 1, jax.lax.rem(j + 1, 2)):
+            for cp in copies(pick(j + 1, next_lo_step), 1 - slot_of(j),
+                             pick(a, after), pick(lo_blk, next_lo_blk),
+                             pick(kv_hi, next_kv_hi)):
                 cp.start()
 
     def latent_step(j, carry):
@@ -299,10 +398,10 @@ def _attend_tile(a, block_tables_ref, pos0_ref, qlen_ref, layer_ref,
         the value their leading lanes."""
         m, l, acc = carry
         # read by nothing since the K-and-V step left this body (it was
-        # that step's ``active``); with it a latent pool's Mosaic text is
-        # the accepted one byte for byte
+        # that step's ``active``); it goes with the dead tiles' walk
+        # (ROADMAP, Speed 4), not with the hand-over
         j * step_keys < kv_hi  # noqa: B018
-        cur = jax.lax.rem(j, 2)
+        cur = slot_of(j)
         start_next(j)
         for cp in copies(j, cur):
             cp.wait()
@@ -357,7 +456,7 @@ def _attend_tile(a, block_tables_ref, pos0_ref, qlen_ref, layer_ref,
         the pool's dtype: the values a float32 operand is rounded to at the
         default precision anyway."""
         m, l, acc = carry
-        cur = jax.lax.rem(j, 2)
+        cur = slot_of(j)
         start_next(j)
         for cp in copies(j, cur):
             cp.wait()
@@ -401,6 +500,9 @@ def _attend_tile(a, block_tables_ref, pos0_ref, qlen_ref, layer_ref,
     # A_max sized for the worst case, most grid programs of a typical batch
     # are dead and must not burn max_blocks MXU loops each
     n_steps = (kv_hi + step_keys - 1) // step_keys
+    # where the last turn's hand-over lands, if it hands over (read by a
+    # warm successor only)
+    slot_ref[0] = 1 - slot_of(n_steps - 1)
     m, l, acc = jax.lax.fori_loop(lo_step, n_steps,
                                   latent_step if latent else tile_step,
                                   (m0, l0, acc0))
@@ -709,17 +811,24 @@ def _tiled_call(atom_tables, atom_pos0, atom_qlen, layer, q_atoms, pools, ab,
             *(pltpu.VMEM((2, bq, pages * block_size), m.dtype)
               for m in masks),
             pltpu.SemaphoreType.DMA((2, len(pools) + len(masks))),
+            # the slot a handed-over first step lies in (_attend_tile)
+            pltpu.SMEM((1,), jnp.int32),
         ],
     )
     kernel = functools.partial(_prefill_kernel, block_size=block_size,
                                max_blocks=atom_tables.shape[1], group=g,
                                use_alibi=use_alibi, window=window,
-                               v_dim=v_dim, pages=pages, masked=bool(masks))
+                               v_dim=v_dim, pages=pages, masked=bool(masks),
+                               head_tiles=tiles)
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((a, bq, h, d_out), q_atoms.dtype),
         grid_spec=grid_spec,
-        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
+        # in order, on one core: a tile's last step starts the next tile's
+        # first copies into scratch both see (_attend_tile)
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * (2 if tiles > 1 else 1),
+            vmem_limit_bytes=vmem_limit),
         interpret=interpret,
         name=name,
     )(atom_tables, atom_pos0, atom_qlen, layer, q_atoms, *pools, *masks, ab)

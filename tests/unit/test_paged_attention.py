@@ -436,3 +436,175 @@ def test_a_k_and_v_tile_of_several_rows_is_its_twin_at_any_step(
     if masked and window is None:
         assert not got[3, 2].any()           # a row that selected nothing
         assert np.abs(got[3, :2]).max() > 1e-3
+
+
+# --------------------------------------- the hand-over between a call's tiles
+# A tile's last step starts the first KV step of the grid's NEXT tile where
+# both walk a step. Each case: the pool, the tile's rows, (pos0, qlen) of the
+# call's tiles in grid order, and what else the tile runs under.
+def _rows_of(lens):
+    """(pos0, qlen) of one-row tiles at these sequence lengths (0: dead)."""
+    return [(max(n - 1, 0), int(n > 0)) for n in lens]
+
+
+HAND_OVER = {
+    # one-row tiles, K and V: dead slots between live ones, at the ends
+    "live_dead_live": dict(bq=1, tiles=_rows_of([40, 0, 17, 0, 0, 96, 1, 0])),
+    # a one-block context behind a long one and before one: the slot a
+    # tile's first step lies in no longer follows the step's parity
+    "one_block_between_long": dict(
+        bq=1, tiles=_rows_of([96, 5, 81, 16, 1, 33, 96])),
+    "the_first_tile_alone": dict(bq=1, tiles=_rows_of([70, 0, 0, 0])),
+    "the_first_tile_dead": dict(bq=1, tiles=_rows_of([0, 0, 50, 3])),
+    # a window that skips whole blocks: lo_blk 3, 0, 2, 4 (odd and even)
+    "window_one_row": dict(bq=1, window=20,
+                           tiles=_rows_of([80, 12, 0, 60, 96, 17])),
+    "window_atoms": dict(bq=8, window=20, pages=2,
+                         tiles=[(60, 8), (0, 5), (0, 0), (88, 8), (40, 3)]),
+    # a context longer than its table beside a short one: what the table
+    # holds, and a copy started for no step past it
+    "past_the_table": dict(bq=8, tiles=[(92, 8), (3, 8), (90, 6)]),
+    "atoms_of_128_rows": dict(bq=128, bs=64, bps=4, h=2, kvh=1, tiles=[
+        (0, 128), (128, 77), (0, 0), (64, 128), (0, 3)]),
+    "masked_atoms": dict(bq=8, bs=64, bps=4, masked=True, tiles=[
+        (100, 8), (0, 0), (30, 8), (200, 5), (0, 2)]),
+    # a latent pool under a head-tile axis (128 heads in four tiles): an
+    # atom's head tiles hand over among themselves and to the next atom; a
+    # dead tile at pos0 > 0 walks its loop with every row masked
+    "latent_head_tiles": dict(bq=8, latent=True, h=128, pages=2, tiles=[
+        (30, 8), (17, 0), (0, 0), (70, 4), (0, 8)]),
+    "latent_one_row": dict(bq=1, latent=True, h=4, pages=2,
+                           tiles=_rows_of([50, 0, 96, 1, 20])),
+}
+
+
+@pytest.mark.parametrize("case", list(HAND_OVER))
+def test_a_tile_hands_its_successor_the_first_kv_step(monkeypatch, case):
+    """The kernel (interpreted) against the reference at the tolerances the
+    other parity tests hold, on grids where tiles that walk and tiles that
+    do not follow one another in every order. Blocks outside the tables'
+    live part are NaN: a copy made for the wrong tile, step or slot reads
+    one."""
+    from deepspeedsyclsupport_tpu.ops import paged_attention as pa
+
+    c = dict(bs=16, bps=6, h=4, kvh=2, d=16, window=None, pages=None,
+             masked=False, latent=False)
+    c.update(HAND_OVER[case])
+    bq, bs, bps, h, d = c["bq"], c["bs"], c["bps"], c["h"], c["d"]
+    if c["pages"]:
+        monkeypatch.setattr(pa, "_kv_pages_per_step",
+                            lambda *a: c["pages"])
+    if c["latent"] and h > 32:           # four head tiles at these widths
+        monkeypatch.setattr(pa, "_HEAD_TILE_BUDGET", pa._ragged_vmem_need(
+            bq, h // 4, 1, d, bs, 4))
+    pos0, qlen = (np.asarray(x) for x in zip(*c["tiles"]))
+    n = len(pos0)
+    rng = np.random.default_rng(len(case))
+    blocks = n * bps + 3
+    tables = rng.permutation(blocks)[:n * bps].reshape(n, bps)
+    row = (d,) if c["latent"] else (c["kvh"], d)
+    pool = np.full((2, 2, blocks * bs) + row, np.nan, np.float32)
+    hi = np.minimum(pos0 + qlen, bps * bs)
+    lo = np.zeros(n, int) if c["window"] is None else \
+        np.maximum(pos0 + 1 - c["window"], 0) // bs
+    for i in range(n):
+        for blk in tables[i, lo[i]:-(-hi[i] // bs)]:
+            pool[:, :, blk * bs:(blk + 1) * bs] = rng.standard_normal(
+                (2, 2, bs) + row)
+    q = jnp.asarray(rng.standard_normal((n, bq, h, d)) * 0.5, jnp.float32)
+    kw = dict(block_size=bs, layer=jnp.int32(1), window=c["window"])
+    if c["latent"]:
+        kw["v_dim"] = d // 2
+    if c["masked"]:
+        seen = np.arange(bps * bs)[None, None, :] <= (
+            pos0[:, None, None] + np.arange(bq)[None, :, None])
+        kw["sel"] = jnp.asarray(np.logical_and(
+            rng.random((n, bq, bps * bs)) < 0.4, seen), jnp.int8)
+    args = (jnp.asarray(tables, jnp.int32), jnp.asarray(pos0, jnp.int32),
+            jnp.asarray(qlen, jnp.int32))
+    pools = (pool[0], None) if c["latent"] else (pool[0], pool[1])
+    got = np.asarray(pa.ragged_prefill_attention_pallas(
+        q, *(p if p is None else jnp.asarray(p) for p in pools), *args,
+        interpret=True, **kw))
+    want = np.asarray(pa.ragged_prefill_attention_reference(
+        q, *(p if p is None else jnp.asarray(np.nan_to_num(p))
+             for p in pools), *args, **kw))
+    rows = np.arange(bq)[None, :] < qlen[:, None]
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got[rows], want[rows], atol=2e-5, rtol=2e-5)
+    assert not got[~rows].any()              # dead rows and tiles: zeros
+
+
+def _walk_the_grid(pos0, qlen, head_tiles, **shape):
+    """The starts and the waits of one call as ``_attend_tile`` makes them,
+    tile by tile in grid order, with ``tile_span`` as the kernel has it;
+    two slots, one semaphore each. Returns ``(warm, handed)``: the tiles
+    that waited for a first step they did not start, and those that
+    started their successor's."""
+    from deepspeedsyclsupport_tpu.ops.paged_attention import tile_span
+
+    spans = [tile_span(pos0[a], qlen[a], xp=np, **shape)
+             for a in range(len(pos0)) for _ in range(head_tiles)]
+    step_keys = shape["pages"] * shape["block_size"]
+    in_flight = {}                       # slot -> (tile, step) on its way
+    carried = None                       # the SMEM scalar
+    warm, handed = [], []
+
+    def start(slot, what):
+        assert slot not in in_flight, f"{what} started over {in_flight}"
+        in_flight[slot] = what
+
+    for i, (_lo_blk, lo_step, kv_hi, walks) in enumerate(spans):
+        if not walks:
+            continue
+        after = spans[i + 1] if i + 1 < len(spans) else None
+        hands = after is not None and bool(after[3])
+        if i > 0 and spans[i - 1][3]:
+            warm.append(i)
+            first = carried
+        else:
+            first = 0
+            start(first, (i, lo_step))
+        n_steps = -(-kv_hi // step_keys)
+        for j in range(lo_step, n_steps):
+            cur = (first + j - lo_step) % 2
+            if j + 1 < n_steps:
+                start(1 - cur, (i, j + 1))
+            elif hands:
+                start(1 - cur, (i + 1, after[1]))
+                handed.append(i)
+                carried = 1 - cur
+            # awaited once, on the semaphore it was started on
+            assert in_flight.pop(cur, None) == (i, j)
+    assert not in_flight                 # started and never awaited
+    return warm, handed
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_every_hand_over_has_the_successor_that_waits_and_no_other(seed):
+    """Random batches through the pairing alone: whatever the contexts, the
+    dead tiles, the window and the blocks a step, every copy is started
+    once and awaited once on its slot's semaphore, a tile hands over iff
+    the next one starts warm, and the host's count is the kernel's."""
+    from deepspeedsyclsupport_tpu.ops.paged_attention import (tile_span,
+                                                              warm_tiles)
+
+    rng = np.random.default_rng(seed)
+    for _ in range(40):
+        shape = dict(block_size=int(rng.choice([4, 16])),
+                     max_blocks=int(rng.integers(1, 9)),
+                     pages=int(rng.choice([1, 2, 8])),
+                     window=[None, 1, 7, 40][rng.integers(4)],
+                     latent=bool(rng.integers(2)))
+        head_tiles = int(rng.choice([1, 1, 4])) if shape["latent"] else 1
+        n = int(rng.integers(1, 12))
+        cap = shape["max_blocks"] * shape["block_size"]
+        pos0 = rng.integers(0, cap + 9, n)          # some past the table
+        qlen = rng.integers(0, 9, n) * rng.integers(0, 2, n)
+        pos0[rng.integers(0, 2, n) * (qlen == 0) > 0] = 0
+        warm, handed = _walk_the_grid(pos0, qlen, head_tiles, **shape)
+        assert warm == [i + 1 for i in handed]
+        walks = np.repeat(tile_span(pos0, qlen, xp=np, **shape)[3],
+                          head_tiles)
+        assert len(warm) == warm_tiles(walks)
+        assert set(warm) <= set(np.flatnonzero(walks))

@@ -170,6 +170,62 @@ def test_the_record_counts_the_tiles_attention_ran(tiny, program):
     sess.close()
 
 
+@pytest.mark.parametrize("case", ["dead_gap", "one", "two_calls", "decode"])
+def test_the_record_counts_the_tiles_that_started_warm(tiny, case):
+    """``warm_tiles``: of a round's attention tiles, those that stand
+    behind a live tile of their own kernel call, so that it fetched their
+    first KV step: the live tiles less one a call and one a dead gap."""
+    eng = _engine(tiny, prefill_attn="kernel_interpret",
+                  decode_attn="pallas_interpret", atom_q_size=8)
+    sess = ServingSession(eng, ServingPolicyConfig(admission="none"))
+
+    def step():                     # the round's record, drained
+        sess.step()
+        return _rounds(sess.drain_trace())[-1]
+
+    if case == "one":               # a batch of one: its tile starts cold
+        sess.submit(1, [1, 2, 3], 4)
+        rec = step()
+        assert (rec["atoms"], rec["warm_tiles"]) == (1, 0)
+        rec = step()
+        assert (rec["program"], rec["decode_rows"],
+                rec["warm_tiles"]) == ("decode_forward", 1, 0)
+    else:
+        sess.submit(1, [1, 2, 3], 8)
+        sess.submit(2, [4, 5, 6, 7, 8], 8)
+        rec = step()                # two atoms of one call: the second warm
+        assert (rec["atoms"], rec["warm_tiles"]) == (2, 1)
+        if case == "decode":        # three rows of one call, compact
+            sess.submit(3, [9, 10], 8)
+            step()
+            rec = step()
+            assert (rec["program"], rec["decode_rows"],
+                    rec["warm_tiles"]) == ("decode_forward", 3, 2)
+        elif case == "two_calls":   # two rows | two atoms: one warm in each
+            sess.submit(3, list(range(10, 20)), 8)
+            rec = step()
+            assert (rec["decode_rows"], rec["atoms"],
+                    rec["warm_tiles"]) == (2, 2, 2)
+        else:
+            # the one-row call's grid is the batch's slots: a prompt chunk
+            # BETWEEN two decoding sequences leaves its slot dead there
+            from deepspeedsyclsupport_tpu.ops.paged_attention import (
+                warm_tiles)
+            from deepspeedsyclsupport_tpu.inference.v2.ragged import (
+                SequenceDescriptor, build_ragged_batch)
+            one, two = (SequenceDescriptor(uid, pending=[7], n_cached=n,
+                                           blocks=[uid])
+                        for uid, n in ((1, 3), (2, 5)))
+            newcomer = SequenceDescriptor(3, pending=list(range(10, 20)))
+            batch = build_ragged_batch(
+                [(one, 1), (newcomer, 10), (two, 1)],
+                16, 4, eng.config.blocks_per_seq, atom_q=8)
+            assert (batch.dec_len > 0).tolist() == [True, False, True, False]
+            assert warm_tiles(batch.dec_len > 0) == 0
+            assert warm_tiles(batch.atom_qlen > 0) == 1
+    sess.close()
+
+
 @pytest.mark.parametrize("polls", [1, 50])
 def test_a_round_that_begins_with_no_work_writes_no_record(tiny, monkeypatch,
                                                            polls):
@@ -363,10 +419,17 @@ def test_the_journal_carries_the_record_and_the_report_prints_its_phases(
     assert cover[0] == ["launched", "rounds", "seqs", "tokens", "prompt",
                         "context", "kv", "blocks", "1-row", "atoms",
                         "pairs", "1-row-ctx", "experts", "ahead",
-                        "spec-rows", "step-keys", "tile-keys", "rows"]
+                        "spec-rows", "step-keys", "tile-keys", "rows",
+                        "warm"]
     assert int(cover[1][1]) == decode["rounds"]
-    assert [float(x) for x in cover[1][2:]] == [
-        pytest.approx(decode[f], abs=0.05) for f in reqtrace.FORWARD_FIELDS]
+    counts = [f for f in reqtrace.FORWARD_FIELDS if f != "warm_tiles"]
+    assert [float(x) for x in cover[1][2:-1]] == [
+        pytest.approx(decode[f], abs=0.05) for f in counts]
+    # the warm tiles are printed as their share of the live tiles: all but
+    # the first row of every decode call
+    assert float(cover[1][-1].rstrip("%")) == pytest.approx(
+        100 * decode["warm_tiles"] / decode["decode_rows"], abs=0.05)
+    assert 0 < decode["warm_tiles"] < decode["decode_rows"]
     phases = [ln.split()[0] for ln in out.stdout.split("round phases")[1]
               .splitlines()[1:] if ln.startswith("    ")]
     assert {"gather", "dispatch", "collect"} <= set(phases)

@@ -10,7 +10,13 @@ Sections, cheapest first:
             block_q/block_k sweep, the bf16-operand probe and the parity
             against XLA:  flash [--parent DIR] [--seq N ...] [--sweep]
             [--probe] [--parity]
-  paged   — paged-decode block_size sweep at serving shapes.
+  paged   — the paged-attention kernel alone at the serving cells' tiles
+            (heads x whole blocks of context x a one-row tile or a prompt's
+            atom), the tree's module beside a parent checkout's: us a tile,
+            the fit ``a + b x blocks``, the share of 819 GB/s; the tree with
+            every other tile dead; the outputs against the parent's bit for
+            bit:  paged [--parent DIR ...] [--kind K ...] [--tile row atom]
+            [--gaps] [--parity]
   retention — the power-retention state step alone at ``brumby-rollout-sat``'s
             shape: the tree's one-pass walk and its copies with no read-out
             beside a parent checkout's kernel (PR 49's at its two blocks), us
@@ -20,6 +26,7 @@ Sections, cheapest first:
 
 Usage:  python tools/tpu_tune.py [calib|flash|paged|retention|all]
 """
+import functools
 import json
 import os
 import sys
@@ -348,37 +355,225 @@ def flash(argv=()):
         emit("flash", seq=seq, cell=FLASH_CELL, rows=rows, failed=failed)
 
 
-def paged():
-    from deepspeedsyclsupport_tpu.ops.paged_attention import (
-        paged_decode_attention_pallas)
+# The serving cells' attention tiles (query heads, kv heads, head_dim; a
+# latent pool: one row of ``d`` lanes a token, the value its leading ``v``;
+# ``sel``: under an indexer's selection), blocks of 64 keys, bf16. ``atom``:
+# rows of a prompt's tile (``default_atom_rows`` of the cell's engine).
+PAGED_BLOCK = 64
+PAGED_KINDS = {
+    "32/32": dict(h=32, kvh=32, d=128, atom=128),     # phi-2 (80 stored as 128)
+    "16/16": dict(h=16, kvh=16, d=128, atom=128),     # OLMoE, Ouro
+    "32/2": dict(h=32, kvh=2, d=128, atom=128),       # Nemotron
+    "32/4sel": dict(h=32, kvh=4, d=128, atom=128, sel=True),        # Keye
+    "32/latent": dict(h=32, d=640, v=512, atom=128),  # Xing4: two head tiles
+    "128/latent": dict(h=128, d=640, v=512, atom=16),  # DeepSeek-V2 (576 as 640)
+}
+PAGED_CONTEXTS = (1, 2, 4, 8, 32)     # whole blocks of context a tile
+PAGED_LONG = (128,)                   # and, a latent or selected pool's tile
+PAGED_TILES = {"row": 32, "atom": 8}  # tiles a call, by the tile's height
+PAGED_CALLS = 8                       # calls a program (a forward's layers)
 
-    h, kvh, d = 16, 4, 128
-    nseq, ctx = 32, 1024
-    rows = []
-    for bs in (32, 64, 128, 256):
-        bps = ctx // bs
-        slots = nseq * ctx
-        ks = jax.random.split(jax.random.PRNGKey(0), 3)
-        q = jax.random.normal(ks[0], (nseq, h, d), jnp.bfloat16)
-        kc = jax.random.normal(ks[1], (slots, kvh, d), jnp.bfloat16)
-        vc = jax.random.normal(ks[2], (slots, kvh, d), jnp.bfloat16)
-        bt = jnp.arange(nseq * bps, dtype=jnp.int32).reshape(nseq, bps)
-        sl = jnp.full((nseq,), ctx, jnp.int32)
-        try:
-            dt, how = _bench_chain(
-                lambda x, *rest, bs=bs: paged_decode_attention_pallas(
-                    x, *rest, block_size=bs).astype(x.dtype),
-                q, (kc, vc, bt, sl), 10)
-        except Exception as e:
-            rows.append({"block_size": bs, "error": str(e)[:120]})
-            continue
-        kv_bytes = 2 * nseq * ctx * kvh * d * 2
-        rows.append({"block_size": bs, "ms": round(dt * 1e3, 3),
-                     "timing": how,
-                     "kv_gbps": round(kv_bytes / dt / 1e9, 1),
-                     "tok_per_s": round(nseq / dt, 0)})
-    emit("paged", shape={"nseq": nseq, "ctx": ctx, "h": h, "kvh": kvh,
-                         "d": d}, sweep=rows)
+
+def _load_paged(root):
+    """``ops/paged_attention.py`` (it imports nothing of the package)."""
+    tag = os.path.basename(os.path.abspath(root)).strip("_")
+    return tag, _load_op(root, "paged_attention", "paged_" + tag)
+
+
+def _paged_rows(kind, height):
+    """Rows of a tile of that height."""
+    return 1 if height == "row" else kind["atom"]
+
+
+def _paged_call(mod, kind, height, q, pools, tables, pos0, qlen, layer, sel,
+                window=None):
+    """One call of ``mod``'s kernel under the name a forward gives it."""
+    return mod.ragged_prefill_attention_pallas(
+        q, pools[0], pools[1] if len(pools) > 1 else None, tables, pos0,
+        qlen, block_size=PAGED_BLOCK, layer=layer, window=window,
+        v_dim=kind.get("v"), sel=sel,
+        name="paged_decode" if height == "row" else "ragged_prefill")
+
+
+def _paged_lane_bytes(kind):
+    """Bytes of one cached token as the copies move them: K and V (a latent
+    pool: its one row) in bf16, the row's lanes tiled up to whole 128s."""
+    lanes = -(-kind["d"] // 128) * 128
+    return lanes * 2 * (1 if "v" in kind else 2 * kind["kvh"])
+
+
+def _paged_operands(kind, height, bps, seed=0):
+    """``(q, pools, tables)`` of one call: a pool of two layers that holds
+    every tile's ``bps`` blocks once, dealt to the tables in shuffled
+    order."""
+    tiles = PAGED_TILES[height]
+    rows = _paged_rows(kind, height)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    slots = tiles * bps * PAGED_BLOCK
+    row = (kind["d"],) if "v" in kind else (kind["kvh"], kind["d"])
+    make = jax.jit(lambda key: jax.random.normal(key, (2, slots) + row,
+                                                 jnp.bfloat16))
+    pools = (make(ks[1]),) if "v" in kind else (make(ks[1]), make(ks[2]))
+    q = jax.random.normal(ks[0], (tiles, rows, kind["h"], kind["d"]),
+                          jnp.bfloat16)
+    tables = np.asarray(jax.random.permutation(ks[3], tiles * bps),
+                        np.int32).reshape(tiles, bps)
+    return q, pools, jnp.asarray(tables)
+
+
+def _paged_tiles(kind, height, blocks, gaps=False):
+    """``(pos0, qlen)`` of a call's tiles at ``blocks`` whole blocks of
+    context each; ``gaps``: every other tile dead (each live tile then
+    starts cold)."""
+    tiles = PAGED_TILES[height]
+    keys = np.full(tiles, blocks * PAGED_BLOCK)
+    qlen = np.minimum(_paged_rows(kind, height), keys)
+    if gaps:
+        qlen[1::2] = 0
+    pos0 = np.where(qlen > 0, keys - qlen, 0)
+    return jnp.asarray(pos0, jnp.int32), jnp.asarray(qlen, jnp.int32)
+
+
+def _paged_selection(kind, height, bps, seed=1):
+    """An indexer's selection ``[tiles, rows, keys]``: 3 keys in 10."""
+    if not kind.get("sel"):
+        return None
+    return (jax.random.uniform(
+        jax.random.PRNGKey(seed),
+        (PAGED_TILES[height], _paged_rows(kind, height),
+         bps * PAGED_BLOCK)) < 0.3).astype(jnp.int8)
+
+
+def _paged_step(mod, name, kind, height, sel, calls=PAGED_CALLS):
+    """``calls`` calls of the kernel in one program named ``name``, the
+    pool's layers by turns (as a forward's layer loop calls it)."""
+    one = functools.partial(_paged_call, mod, kind, height, sel=sel)
+
+    def step(q, pools, tables, pos0, qlen):
+        out = one(q, pools, tables, pos0, qlen, jnp.int32(0))
+        return jax.lax.fori_loop(
+            1, calls, lambda i, acc: acc + one(
+                q, pools, tables, pos0, qlen, jax.lax.rem(i, 2)), out)
+    step.__name__ = name
+    return jax.jit(step)
+
+
+def _paged_parity(mods, kinds, heights):
+    """The tree's kernel against the parent's ON THE CHIP, output for
+    output: contexts of 1 to 8 blocks that end inside a block, a dead tile
+    between live ones and two at the end, with and without a sliding
+    window. ``differ``: outputs that are not the parent's bit for bit."""
+    (tag, parent), = [(t, m) for t, m in mods.items() if t != "tree"]
+    for kname in kinds:
+        kind = PAGED_KINDS[kname]
+        for height in heights:
+            if kind.get("sel") and height == "row":
+                continue
+            bps = 8
+            q, pools, tables = _paged_operands(kind, height, bps, seed=5)
+            tiles = PAGED_TILES[height]
+            rows = _paged_rows(kind, height)
+            rng = np.random.default_rng(tiles)
+            keys = rng.integers(1, bps * PAGED_BLOCK, tiles)
+            qlen = np.minimum(rng.integers(1, rows + 1, tiles), keys)
+            qlen[[2, tiles - 2, tiles - 1]] = 0
+            pos0 = np.where(qlen > 0, keys - qlen, 0)
+            sel = _paged_selection(kind, height, bps)
+            for window in (None, 3 * PAGED_BLOCK):
+                got = [np.asarray(jax.jit(
+                    lambda q, pools, mod=mod: _paged_call(
+                        mod, kind, height, q, pools, tables,
+                        jnp.asarray(pos0, jnp.int32),
+                        jnp.asarray(qlen, jnp.int32), jnp.int32(1), sel,
+                        window))(q, pools).astype(jnp.float32))
+                       for mod in (mods["tree"], parent)]
+                emit("paged_parity", kind=kname, tile=height, window=window,
+                     against=tag, outputs=int(got[0].size),
+                     differ=int(np.count_nonzero(got[0] != got[1])),
+                     worst=float(np.max(np.abs(got[0] - got[1]))),
+                     finite=bool(np.isfinite(got[0]).all()))
+
+
+def paged(argv=()):
+    """The paged-attention kernel ALONE at the serving cells' tiles, its
+    device time read off a profiler trace: us a tile at contexts of 1 to 32
+    whole blocks (a latent or selected pool's also 128) for a one-row tile
+    (32 a call) and a prompt's atom (8 a call), the fit ``a + b x blocks``
+    over them, and the share of 819 GB/s the blocks' bytes make of the
+    time; the tree's module beside each ``--parent DIR``'s. ``--gaps`` adds
+    the tree with every other tile dead (each live tile then starts cold:
+    what the hand-over between tiles is worth, in the tree's own code),
+    ``--parity`` holds the tree's outputs against the parent's bit for
+    bit."""
+    import argparse
+
+    ap = argparse.ArgumentParser(prog="tpu_tune.py paged")
+    ap.add_argument("--parent", action="append", default=[])
+    ap.add_argument("--kind", nargs="*", default=list(PAGED_KINDS))
+    ap.add_argument("--tile", nargs="*", default=list(PAGED_TILES))
+    ap.add_argument("--gaps", action="store_true")
+    ap.add_argument("--parity", action="store_true")
+    a = ap.parse_args(list(argv))
+    mods = {"tree": _load_op(os.path.join(os.path.dirname(__file__), ".."),
+                             "paged_attention", "paged_tree")}
+    mods.update(map(_load_paged, a.parent))
+    if a.parity:
+        _paged_parity(mods, a.kind, a.tile)
+    for kname in a.kind:
+        kind = PAGED_KINDS[kname]
+        wide = "v" in kind or kind.get("sel")
+        contexts = PAGED_CONTEXTS + (PAGED_LONG if wide else ())
+        for height in a.tile:
+            if kind.get("sel") and height == "row":
+                continue             # its one-row tile takes no selection
+            ops = _paged_operands(kind, height, max(contexts))
+            sel = _paged_selection(kind, height, max(contexts))
+            steps, failed = {}, {}
+            shapes = ops + _paged_tiles(kind, height, 1)
+            for tag, mod in mods.items():
+                try:
+                    steps[tag] = _paged_step(
+                        mod, f"{tag}_{height}", kind, height,
+                        sel).lower(*shapes).compile()
+                except Exception as e:             # e.g. over the VMEM limit
+                    failed[tag] = str(e).splitlines()[0][:160]
+            # (row name, module's tag, every other tile dead)
+            plan = [(f"{tag}_{height}", tag, False) for tag in steps]
+            if a.gaps and "tree" in steps:
+                plan.append((f"tree_{height}_gaps", "tree", True))
+            us = {name: {} for name, _tag, _gaps in plan}
+            for blocks in contexts:
+                for gaps in (False, True):
+                    # by the program's own name, which the trace knows
+                    run = {f"{tag}_{height}": (name, steps[tag])
+                           for name, tag, g in plan if g == gaps}
+                    if not run:
+                        continue
+                    rows = _traced_kernels(
+                        {prog: step for prog, (_name, step) in run.items()},
+                        ops + _paged_tiles(kind, height, blocks, gaps),
+                        kernel_of=lambda text: "kernel")
+                    live = PAGED_TILES[height] // (2 if gaps else 1)
+                    for prog, row in rows.items():
+                        if "kernel" in row:
+                            us[run[prog][0]][blocks] = \
+                                1e3 * row["kernel"] / live
+            out = {}
+            for name, by in us.items():
+                if len(by) < 2:
+                    continue
+                b, fixed = np.polyfit(list(by), list(by.values()), 1)
+                out[name] = {
+                    "us_a_tile": {n: round(t, 3) for n, t in by.items()},
+                    "a_us": round(float(fixed), 3),
+                    "b_us_a_block": round(float(b), 3),
+                    "peak_pct": {n: round(
+                        100 * n * PAGED_BLOCK * _paged_lane_bytes(kind)
+                        / V5E_HBM / (t * 1e-6), 1) for n, t in by.items()}}
+            emit("paged", kind=kname, tile=height, shape=kind,
+                 tiles_a_call=PAGED_TILES[height],
+                 block_bytes=PAGED_BLOCK * _paged_lane_bytes(kind),
+                 rows=out, failed=failed)
 
 
 # ``brumby-rollout-sat``'s decode step: 16 rows on 16 slots + the sink, 8
@@ -585,6 +780,6 @@ if __name__ == "__main__":
     if which in ("flash", "all"):
         flash(sys.argv[2:] if which == "flash" else ())
     if which in ("paged", "all"):
-        paged()
+        paged(sys.argv[2:] if which == "paged" else ())
     if which in ("retention", "all"):
         retention(sys.argv[2:] if which == "retention" else ())

@@ -702,12 +702,20 @@ def requests_report(root: str, worst_n: int = 5, window_s: float = 60.0,
             return (f"{m.get('moe_tile_rows', 0):>11.0f}"
                     f"{100 * rows / room if room else 0:>10.1f}%"
                     f"{tiles / max(m.get('moe_touched', 0), 1e-9):>14.2f}")
+
+        def warm_col(m):
+            """Of the live attention tiles, those whose first KV step the
+            tile before them fetched."""
+            live = m.get("decode_rows", 0) + m.get("atoms", 0)
+            return f"{100 * m.get('warm_tiles', 0) / live:.1f}%" if live \
+                else "-"
         lines.append(f"    {'launched':<20}{'rounds':>7}{'seqs':>8}"
                      f"{'tokens':>9}{'prompt':>9}{'context':>10}"
                      f"{'kv blocks':>11}{'1-row':>8}{'atoms':>8}"
                      f"{'pairs':>12}{'1-row-ctx':>11}{'experts':>9}"
                      f"{'ahead':>7}{'spec-rows':>11}"
                      f"{'step-keys':>11}{'tile-keys':>11}{'rows':>8}"
+                     f"{'warm':>8}"
                      + (f"{'exp-rows':>10}" if share else "")
                      + (f"{'tile-rows':>11}{'tile-fill':>11}{'tiles/expert':>14}"
                         if tiled else "")
@@ -726,6 +734,7 @@ def requests_report(root: str, worst_n: int = 5, window_s: float = 60.0,
                          f"{m.get('kv_step_keys', 0):>11.1f}"
                          f"{m.get('kv_tile_keys', 0):>11.1f}"
                          f"{m.get('rows', 0):>8.1f}"
+                         f"{warm_col(m):>8}"
                          + (f"{m.get('moe_rows', 0):>10.1f}" if share
                             else "") + (tile_cols(m) if tiled else "")
                          + (f"{m.get('sel_pairs', 0):>12.1f}"
