@@ -28,9 +28,9 @@ from .config import RaggedInferenceConfig
 from .kv_cache import init_blocked_kv, state_pool_stats
 from .model import build_ragged_forward_fn, moe_tile_rows
 from .ragged import (BlockedAllocator, LogitsRef, SequenceDescriptor,
-                     attention_work, build_ragged_batch, device_token,
-                     ragged_shapes, selection_work, split_device_tokens,
-                     ssm_pieces)
+                     WindowedAllocator, attention_work, build_ragged_batch,
+                     device_token, ragged_shapes, selection_work,
+                     split_device_tokens, ssm_pieces, window_work)
 from .scheduler import schedule_chunks
 from ..params import place_inference_params
 from ..sampling import SamplingParams, sample_token_dyn, split_key
@@ -60,6 +60,14 @@ def _sample_rows(logits, slots, rng, temperature, top_p, structure,
                             top_p, structure)
     return toks if tail is None else jnp.concatenate(
         [toks, *(t if t.ndim else t[None] for t in tail)])
+
+
+def _behind_state(state: list, window: list) -> list:
+    """A forward's last operands: a model with recurrent state's ``state``
+    and a stack of two attention kinds' ``window`` tables, which stand
+    BEHIND the state's place (None where the model keeps no state); a model
+    with neither passes nothing, and its call is what it always was."""
+    return state + window if state or not window else [None] + window
 
 
 def _tail_len(tail) -> int:
@@ -200,6 +208,15 @@ class InferenceEngineV2:
         with setup_span("pool"):
             self.kv = init_blocked_kv(model.config, cfg, self.topology)
         self.allocator = BlockedAllocator(cfg.num_blocks)
+        # a stack of two attention kinds (ModelConfig.attn_period): the
+        # windowed layers' pool beside the full layers', a free list each;
+        # ``allocator`` then answers over both and ``_full`` is the one
+        # ``num_blocks`` sizes (every other model: the two are one)
+        self._full = self.allocator
+        self._window = model.config.period_window
+        if self._window is not None:
+            self.allocator = WindowedAllocator(self._full, BlockedAllocator(
+                self.kv.window_slots // cfg.block_size))
         self.seqs: Dict[int, SequenceDescriptor] = {}
         # a model with recurrent state (Mamba-2 or power-retention layers):
         # the free places of the state pool, one a live sequence from its
@@ -438,6 +455,30 @@ class InferenceEngineV2:
                                if not (n == 1 and d.n_cached > 0)),
             ctx_tokens=sum(d.n_cached for d in descs),
             kv_blocks=sum(len(d.blocks) for d in descs))
+        if self._window is not None:
+            # two pools: the blocks each holds now, the new chunks' among
+            # them, and of the live sequences' context (this forward's
+            # tokens in it) the tokens whose windowed rows are resident
+            bs = self.config.block_size
+            ctx = {d.uid: d.n_cached for d in self.seqs.values()}
+            ctx.update((d.uid, d.n_cached + n)
+                       for d, n in zip(descs, lengths))
+            self.round_spans.fields.update(
+                kv_full_blocks_held=self._full.num_blocks
+                - self._full.free_blocks,
+                kv_window_blocks_held=self.allocator.window.num_blocks
+                - self.allocator.window.free_blocks,
+                kv_live_ctx_tokens=sum(ctx.values()),
+                kv_window_tokens=sum(
+                    ctx[d.uid] - d.window_freed * bs
+                    for d in self.seqs.values()),
+                # ... and what the atoms of ONE windowed and ONE full layer
+                # cover (attn_pairs is the full layer's pairs)
+                **dict(zip(
+                    ("swa_pairs", "swa_atom_keys", "full_atom_keys"),
+                    window_work(descs, lengths, self._window,
+                                self.config.atom_q_size
+                                if self._use_atoms else 0))))
         if self.kv.idx is not None:
             # a sparse-attention indexer: what the attention reads of that
             sel_pairs, dec_sel_tokens = selection_work(
@@ -531,6 +572,8 @@ class InferenceEngineV2:
                     f"{len(self.seqs)} sequences live of max_sequences "
                     f"{self.config.max_sequences}")
             fields["state_slot"] = self._state_free.pop()
+        if self._window is not None:
+            fields["window_blocks"] = []
         d = self.seqs[uid] = SequenceDescriptor(
             uid=uid, caches_kv=self._caches_kv, **fields)
         return d
@@ -538,10 +581,13 @@ class InferenceEngineV2:
     def _drop_seq(self, uid: int) -> Optional[SequenceDescriptor]:
         """Take ``uid`` out of ``seqs``: its blocks back to the pool and
         its state slot to the free places (whatever it holds: the next
-        sequence there starts from zeros at its position 0)."""
+        sequence there starts from zeros at its position 0); of a stack of
+        two attention kinds, the blocks of both pools."""
         d = self.seqs.pop(uid, None)
         if d is not None:
-            self.allocator.free(d.blocks)
+            self._full.free(d.blocks)
+            if d.window_blocks is not None:
+                self.allocator.window.free(d.window_held)
             if d.state_slot is not None:
                 self._state_free.append(d.state_slot)
                 d.state_slot = None
@@ -661,10 +707,16 @@ class InferenceEngineV2:
         block-aligned tokens map to SHARED blocks, so the KV-pressure check
         prices the request at its novel blocks only — a prefix hit admits
         work the cold check would reject. The context and slot checks are
-        unaffected (shared tokens still occupy context)."""
+        unaffected (shared tokens still occupy context).
+
+        A stack of two attention kinds is priced by its FULL pool alone: a
+        sequence holds at most ``kv_cache.window_blocks_a_sequence`` blocks
+        of the windowed layers' pool whatever its context, that pool has as
+        many for each of ``max_sequences``, and the slot check above is
+        therefore its admission."""
         cfg = self.config
         slots = len(self.seqs)
-        free = self.allocator.free_blocks
+        free = self._full.free_blocks
         if self.prefix_cache is not None:
             # cold unshared index pins surrender to allocation pressure
             # (allocator.reclaim_cb), so the KV check counts them as free —
@@ -769,6 +821,7 @@ class InferenceEngineV2:
             with self._phase("collect"):
                 self._tick += 1
                 served_s = time.perf_counter()  # aging base for slack order
+                freed = 0
                 for slot, (d, n) in enumerate(chunks):
                     d.last_scheduled = self._tick
                     d.last_service_s = served_s
@@ -781,6 +834,12 @@ class InferenceEngineV2:
                         d.history.extend(int(t) for t in d.pending[:n])
                     del d.pending[:n]
                     d.n_cached += n
+                    if d.window_blocks is not None:
+                        # what no later query of the sequence can see goes
+                        # back to the windowed layers' pool
+                        gone = d.out_of_window(self._window, cfg.block_size)
+                        self.allocator.window.release(gone)
+                        freed += len(gone)
                     if self.prefix_cache is not None:
                         self._commit_prefix(d)
                     if not d.pending:
@@ -788,6 +847,8 @@ class InferenceEngineV2:
                         # forward's array until somebody reads it
                         d.last_logits = out._refs[d.uid] = LogitsRef(
                             logits, slot)
+                if self._window is not None and self.round_spans is not None:
+                    self.round_spans.fields["kv_window_blocks_freed"] = freed
             if not drain:
                 break
             if all(not d.pending for d in self.seqs.values()):
@@ -883,6 +944,14 @@ class InferenceEngineV2:
             "install_prefix_cache()", "a snapshot of the recurrent state at "
             "every shared block boundary: a prefix's KV blocks can be "
             "mapped, the state its tokens left behind was never kept")
+        if self._window is not None:
+            raise NotImplementedError(
+                "install_prefix_cache() is not available for a model whose "
+                "layers are of two attention kinds (ModelConfig.attn_period)"
+                ": a prefix's full-layer blocks could be mapped, but its "
+                "windowed layers' blocks were given back as its owner moved "
+                "past them, and recomputing the last window's rows on a hit "
+                "is not written")
         if self.prefix_cache is None:
             self.prefix_cache = PrefixCache(
                 self.allocator, self.config.block_size, scope=scope,
@@ -1001,6 +1070,8 @@ class InferenceEngineV2:
         if d is None:
             return None
         d.blocks = []
+        if d.window_blocks is not None:
+            d.window_blocks, d.window_freed = [], 0
         d.n_cached = 0
         d.cached_prefix_len = 0
         d.history = []
@@ -1080,23 +1151,30 @@ class InferenceEngineV2:
                 jnp.asarray(batch.last_tok_idx),
                 *(a if a is None else jnp.asarray(a) for a in tiles),
                 sampled, take_from,
-                *(jax.tree_util.tree_map(jnp.asarray, a) for a in state),
+                *_behind_state(
+                    [jax.tree_util.tree_map(jnp.asarray, a) for a in state],
+                    [a if a is None else jnp.asarray(a)
+                     for a in batch.window_args]),
                 rows=shape.rows)
         return logits
 
     def _slot_arrays(self, descs):
         """Per-slot decode metadata padded to max_sequences (position,
-        block table, live mask per slot)."""
+        block table, live mask per slot; of a stack of two attention kinds
+        the windowed layers' tables behind them, else nothing)."""
         cfg = self.config
         s_max = cfg.max_sequences
         positions = np.zeros((s_max,), np.int32)
         tables = np.zeros((s_max, cfg.blocks_per_seq), np.int32)
         active = np.zeros((s_max,), bool)
+        window = () if self._window is None else (np.zeros_like(tables),)
         for slot, d in enumerate(descs):
             positions[slot] = d.n_cached
             tables[slot, :len(d.blocks)] = d.blocks
+            for t in window:
+                t[slot, :len(d.window_blocks)] = d.window_blocks
             active[slot] = True
-        return positions, tables, active
+        return positions, tables, active, window
 
     def _run_decode(self, chunks, sampled: Optional[SampledTokens] = None
                     ) -> jax.Array:
@@ -1109,7 +1187,7 @@ class InferenceEngineV2:
             self._decode_forward = build_decode_forward_fn(
                 self.model, cfg.block_size, attn_impl=cfg.decode_attn)
         with self._phase("build"):
-            positions, tables, active = self._slot_arrays(
+            positions, tables, active, window = self._slot_arrays(
                 [d for d, _n in chunks])
             tokens = np.zeros((cfg.max_sequences,), np.int32)
             for slot, (d, _n) in enumerate(chunks):
@@ -1132,7 +1210,8 @@ class InferenceEngineV2:
                 self.params, self.kv, tokens,
                 jnp.asarray(positions), jnp.asarray(tables),
                 jnp.asarray(active), sampled, take_from,
-                *map(jnp.asarray, state))
+                *_behind_state(list(map(jnp.asarray, state)),
+                               list(map(jnp.asarray, window))))
         return logits
 
     # ------------------------------------------------------------ query/flush

@@ -32,7 +32,18 @@ v5e and the pool was held twice (PERF.md, PR 25). A stack in which NO layer
 caches a key (``ModelConfig.num_kv_layers`` 0: power retention) has pools
 with no rows, ``[0, num_blocks * block_size, KVH, D]``: a sequence takes no
 block, nothing is ever evicted for want of one, a token costs the pool 0
-bytes, and what a sequence costs is its recurrent-state slot alone.
+bytes, and what a sequence costs is its recurrent-state slot alone. A stack
+whose layers are of TWO attention kinds (``ModelConfig.attn_period``: a
+period of windowed layers and full ones) has two pools side by side, each
+under a block table and an allocator of its own: K and V ``[L_f, num_blocks
+* block_size, KVH, D]`` for the full layers, whose rows live as long as the
+context, and ``wk`` and ``wv`` ``[L_w, window_blocks * block_size, KVH, D]``
+for the windowed layers, whose blocks go back to their allocator once no
+query of the sequence can see them (``ragged.SequenceDescriptor.
+free_window_blocks``). A sequence then holds at most
+:func:`window_blocks_a_sequence` blocks of the second whatever its context,
+so that pool is sized by ``max_sequences`` and the bound and can never run
+out; ``num_blocks`` sizes the first.
 """
 from typing import NamedTuple, Optional
 
@@ -115,10 +126,21 @@ class BlockedKV(NamedTuple):
     # of its K and V, two slots a row: [L, num_blocks*block_size / 2,
     # 2 x index_head_dim]
     idx: Optional[jnp.ndarray] = None
+    # a stack of two attention kinds only (``ModelConfig.attn_period``; None
+    # elsewhere: no leaf, the same program): the WINDOWED layers' keys and
+    # values, [L_w, window_blocks * block_size, KVH, D], addressed by the
+    # sequences' window tables
+    wk: Optional[jnp.ndarray] = None
+    wv: Optional[jnp.ndarray] = None
 
     @property
     def num_slots(self) -> int:
         return self.k.shape[1]
+
+    @property
+    def window_slots(self) -> int:
+        """Slots of the windowed layers' pool (0: the model has one pool)."""
+        return 0 if self.wk is None else self.wk.shape[1]
 
     @property
     def state_names(self):
@@ -146,8 +168,9 @@ class BlockedKV(NamedTuple):
     @property
     def pools(self):
         """The pool arrays there are, under :data:`POOL_NAMES`: (k, v), (k,)
-        for a latent pool, (k, v, idx) beside a sparse-attention indexer."""
-        return tuple(pool for pool in (self.k, self.v, self.idx)
+        for a latent pool, (k, v, idx) beside a sparse-attention indexer,
+        (k, v, wk, wv) for a stack of two attention kinds."""
+        return tuple(pool for pool in map(self.__getattribute__, POOL_NAMES)
                      if pool is not None)
 
     def with_pools(self, pools) -> "BlockedKV":
@@ -158,7 +181,7 @@ class BlockedKV(NamedTuple):
 
 
 # the fields of :class:`BlockedKV` that are pools addressed by block tables
-POOL_NAMES = ("k", "v", "idx")
+POOL_NAMES = ("k", "v", "idx", "wk", "wv")
 # ... and those that are recurrent state addressed by sequence slot, a pair
 # a kind of state layer; the FIRST of a pair has its slots on axis 1
 STATE_NAMES = (("ssm", "conv"), ("ret_s", "ret_z"))
@@ -178,6 +201,18 @@ def lane_padded_head_dim(head_dim: int, pad) -> int:
     if pad is None:
         pad = 128 if jax.default_backend() == "tpu" else 1
     return -(-head_dim // pad) * pad
+
+
+def window_blocks_a_sequence(window: int, cfg: RaggedInferenceConfig) -> int:
+    """The most blocks of the windowed layers' pool ONE sequence holds: what
+    a query at the sequence's next position can still see, ``window`` keys,
+    the longest chunk a forward appends before anything is given back,
+    ``max_tokens_per_batch``, and the two blocks those straddle at their
+    ends (``window + max_tokens_per_batch + block_size`` tokens where both
+    are whole blocks); never more than a context has."""
+    bs = cfg.block_size
+    return min((window + cfg.max_tokens_per_batch + 2 * bs - 3) // bs,
+               cfg.blocks_per_seq)
 
 
 def init_blocked_kv(model_config, cfg: RaggedInferenceConfig,
@@ -241,6 +276,13 @@ def init_blocked_kv(model_config, cfg: RaggedInferenceConfig,
         shape_i = (shape[0], shape[1] // 2, 2 * model_config.index_head_dim)
         state["idx"] = jax.jit(lambda: jnp.zeros(shape_i, cfg.dtype),
                                out_shardings=topology.replicated())()
+    if model_config.window_layers:
+        shape_w = (model_config.window_layers, cfg.max_sequences
+                   * window_blocks_a_sequence(model_config.period_window, cfg)
+                   * cfg.block_size, *row)
+        zeros_w = jax.jit(lambda: jnp.zeros(shape_w, cfg.dtype),
+                          out_shardings=sharding)
+        state.update(wk=zeros_w(), wv=zeros_w())
     if model_config.total_ut_steps > 1:
         state["exit_pass"] = jax.jit(
             lambda: jnp.zeros((model_config.total_ut_steps,), jnp.int32),
@@ -282,7 +324,16 @@ def kv_pool_stats(kv: BlockedKV, allocator) -> dict:
     # physical, shared == 0 — the pre-sharing report
     logical = int(getattr(allocator, "logical_blocks", physical))
     shared = int(getattr(allocator, "shared_blocks", 0))
-    return {"blocks_total": total, "blocks_free": free,
+    kinds = {}
+    if hasattr(allocator, "window"):
+        # a stack of two attention kinds: ``allocator`` answers over both
+        # pools; each kind's own blocks beside it
+        kinds = {f"{kind}_blocks_{what}": n
+                 for kind, a in (("full", allocator.full),
+                                 ("window", allocator.window))
+                 for what, n in (("total", a.num_blocks),
+                                 ("held", a.num_blocks - a.free_blocks))}
+    return {**kinds, "blocks_total": total, "blocks_free": free,
             "blocks_physical": physical, "blocks_logical": logical,
             "blocks_shared": shared,
             "occupancy": 1.0 - free / total,

@@ -43,7 +43,12 @@ over shared weights, :func:`_scan_passes`), whose pool has a row for every
 And a sixth: power retention (``cfg.retention_degree``) in the place of
 attention on the uniform block: :func:`_retention_rows` gives the rows,
 ``ops/retention.py`` the recurrence against a state that rides the layer
-loop's carry behind the (empty) pools (:func:`_scan_layers`).
+loop's carry behind the (empty) pools (:func:`_scan_layers`). And a seventh:
+a stack of two attention kinds (``cfg.attn_period``: windowed layers with
+rotary positions and full layers with none, in a fixed period), which
+:func:`_scan_layers` scans a PERIOD at a time, the period's layers unrolled
+with static kind (:class:`AttnKind`), each kind with a pool, a block table
+and a row index of its own.
 """
 from contextlib import nullcontext
 from typing import Any, NamedTuple, Optional, Tuple
@@ -60,6 +65,54 @@ from ...monitor.mfu import scope
 from ...ops.grouped_gemm import row_tile, tile_visits
 
 NEG_INF = jnp.finfo(jnp.float32).min
+
+
+class AttnKind(NamedTuple):
+    """What is static about the attention of layer ``j`` of a period of
+    ``cfg.attn_kinds``: its ``window`` (None: full) and ``pos_embed``; and,
+    of a stack of two kinds (``cfg.attn_period``; ``label`` None for every
+    other model, whose one kind has every pool and row ``l``), which of the
+    carried pools are its own (``pools``: (0, 2) K and V, (2, 4) the
+    windowed layers' ``wk`` and ``wv``; None: all), what a profile calls it
+    (``swa`` | ``full``), and where its rows lie in its pool: a period has ``per``
+    layers of the kind and this is the ``rank``-th of them."""
+    window: Optional[int]
+    pos_embed: str
+    label: Optional[str] = None
+    pools: Optional[Tuple[int, int]] = None
+    period: int = 1
+    per: int = 1
+    rank: int = 0
+
+    @classmethod
+    def of(cls, cfg, j: int) -> "AttnKind":
+        window, pos = cfg.attn_kinds[j]
+        if cfg.attn_period is None:
+            return cls(window, pos)
+        mine = [i for i, (w, _) in enumerate(cfg.attn_period)
+                if (w is None) == (window is None)]
+        return cls(window, pos, "full" if window is None else "swa",
+                   (0, 2) if window is None else (2, 4),
+                   len(cfg.attn_period), len(mine), mine.index(j))
+
+    @property
+    def windowed(self) -> bool:
+        """Whether the kind's rows lie in the windowed layers' pool."""
+        return self.pools is not None and self.pools[0] > 0
+
+    def split(self, pools):
+        """``(before, the kind's own, after)`` of the carried ``pools``."""
+        lo, hi = self.pools or (0, len(pools))
+        return pools[:lo], pools[lo:hi], pools[hi:]
+
+    def row(self, l):
+        """The row of its pool that layer ``l`` (traced) reads and writes."""
+        if self.label is None:
+            return l
+        return l // self.period * self.per + self.rank
+
+    def scope(self):
+        return scope(f"attn_{self.label}") if self.label else nullcontext()
 
 
 class PrefillAttnContext(NamedTuple):
@@ -88,6 +141,19 @@ class PrefillAttnContext(NamedTuple):
     dec_row: Any = None
     dec_len: Any = None
     v_dim: Optional[int] = None
+    # what a profile calls the kernels of a stack of two attention kinds
+    # (``paged_<kind>_prefill`` / ``paged_<kind>_decode``; None: the names
+    # every other model's have)
+    kind: Optional[str] = None
+
+
+def _kernel_names(kind: Optional[str]):
+    """``name=`` of the atoms' call and of the one-row call under ``kind``
+    (:attr:`AttnKind.label`), or nothing: the kernels' own names."""
+    if kind is None:
+        return {}, {}
+    return ({"name": f"paged_{kind}_prefill"},
+            {"name": f"paged_{kind}_decode"})
 
 
 def _dequant(p, dtype):
@@ -141,14 +207,16 @@ def _attn_out(p, attn, cfg, n):
     return out
 
 
-def _q_and_rows(p, y, cfg, positions):
+def _q_and_rows(p, y, cfg, positions, pos_embed=None):
     """What attention takes of flat tokens y [n, D]: the positioned queries
     [n, H, D_k] and the rows the pool caches of them, ``(k, v)`` [n, KVH, D]
-    each, or for latent attention the one ``[c_kv | k_r]`` row [n, D_k]."""
+    each, or for latent attention the one ``[c_kv | k_r]`` row [n, D_k].
+    ``pos_embed``: the layer's own (:class:`AttnKind`; None: the
+    model's)."""
     if cfg.kv_lora_rank:
         return _mla_rows(p, y, cfg, positions)
     q, k, v = _qkv(p, y, cfg, y.shape[0])
-    q, k = _positionize(cfg, q, k, positions)
+    q, k = _positionize(cfg, q, k, positions, pos_embed or cfg.pos_embed)
     return q, (k, v)
 
 
@@ -210,8 +278,8 @@ def _lane_pad(x, d_pad: int, is_q: bool = False):
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, d_pad - d)])
 
 
-def _positionize(cfg, q, k, positions):
-    if cfg.pos_embed == "rope":
+def _positionize(cfg, q, k, positions, pos_embed):
+    if pos_embed == "rope":
         q = apply_rope(q[None], positions[None], cfg.rope_theta,
                        cfg.rotary_dim)[0]
         k = apply_rope(k[None], positions[None], cfg.rope_theta,
@@ -220,9 +288,10 @@ def _positionize(cfg, q, k, positions):
 
 
 def _arch_bias(cfg):
-    ab = (jnp.asarray(alibi_slopes(cfg.num_heads) * cfg.alibi_scale)
-          if cfg.pos_embed == "alibi" else None)
-    return ab, cfg.sliding_window
+    """The alibi slopes (None: the model has none); a layer's window is its
+    :class:`AttnKind`'s."""
+    return (jnp.asarray(alibi_slopes(cfg.num_heads) * cfg.alibi_scale)
+            if cfg.pos_embed == "alibi" else None)
 
 
 def _embed(params, tokens, positions, cfg):
@@ -250,13 +319,14 @@ def _final_norm(params, x, cfg):
 
 def _unembed(params, x, cfg):
     if cfg.tie_embeddings:
-        return jnp.einsum("sd,vd->sv", x,
-                          params["embed"]["embedding"].astype(x.dtype))
-    logits = jnp.einsum("sd,dv->sv", x,
-                        params["lm_head"]["kernel"].astype(x.dtype))
-    if cfg.lm_head_bias:
-        logits = logits + params["lm_head"]["bias"].astype(logits.dtype)
-    return logits
+        logits = jnp.einsum("sd,vd->sv", x,
+                            params["embed"]["embedding"].astype(x.dtype))
+    else:
+        logits = jnp.einsum("sd,dv->sv", x,
+                            params["lm_head"]["kernel"].astype(x.dtype))
+        if cfg.lm_head_bias:
+            logits = logits + params["lm_head"]["bias"].astype(logits.dtype)
+    return logits if cfg.logit_scale == 1.0 else logits * cfg.logit_scale
 
 
 def _block(cfg, p, x, attn_fn, live, experts=None):
@@ -461,15 +531,16 @@ def _prefill_kernel_impl(q, ctx: PrefillAttnContext, interpret=False):
     impl = "pallas_interpret" if interpret else "pallas"
     kw = dict(block_size=ctx.block_size, layer=ctx.layer, alibi=ctx.alibi,
               window=ctx.window, v_dim=ctx.v_dim, impl=impl)
+    atoms_name, rows_name = _kernel_names(ctx.kind)
     q_at = q[ctx.atom_qidx]                          # [A, BQ, H, D]
     out_at = ragged_prefill_attention(
         q_at, ctx.k_cache, ctx.v_cache, ctx.atom_tables, ctx.atom_pos0,
-        ctx.atom_qlen, **kw)
+        ctx.atom_qlen, **kw, **atoms_name)
     flat = out_at.reshape(-1, *out_at.shape[2:])
     out = flat[ctx.atom_inv]                         # back to packed rows
     out_dec = paged_decode_attention(                # [S, H, D]
         q[ctx.dec_row], ctx.k_cache, ctx.v_cache, ctx.block_tables,
-        ctx.dec_len, **kw)
+        ctx.dec_len, **kw, **rows_name)
     # a slot with no one-token chunk scatters out of range (dropped)
     rows = jnp.where(ctx.dec_len > 0, ctx.dec_row, q.shape[0])
     return out.at[rows].set(out_dec, mode="drop")
@@ -507,7 +578,8 @@ def _decode_dispatch(impl_name):
         return paged_decode_attention(
             q, ctx.k_cache, ctx.v_cache, ctx.block_tables, ctx.seq_lens,
             block_size=ctx.block_size, impl=impl_name, layer=ctx.layer,
-            alibi=ctx.alibi, window=ctx.window, v_dim=ctx.v_dim)
+            alibi=ctx.alibi, window=ctx.window, v_dim=ctx.v_dim,
+            **_kernel_names(ctx.kind)[1])
     return fn
 
 
@@ -522,6 +594,7 @@ class DecodeAttnContext(NamedTuple):
     alibi: Any
     window: Optional[int]
     v_dim: Optional[int] = None
+    kind: Optional[str] = None     # as PrefillAttnContext's
 
 
 register_impl("decode_attn", "pallas", priority=10,
@@ -664,7 +737,16 @@ def _scan_layers(layer, x, kv: BlockedKV, params, cfg):
     the pool, one for the expert stack. Router, shared expert, norms and
     attention stay in the xs: dense operands, whose slices fuse.
 
-    ``layer(carry, p, l, experts)`` returns ``(carry, expert rows [E] or
+    A stack of several attention kinds (``cfg.attn_kinds``: a period of P
+    layers, ``cfg.attn_period``) is scanned a PERIOD at a time: the xs are
+    the stacked params seen as ``[periods, P, ...]`` (a reshape of the
+    leading axis), and the period's layers are unrolled in the body, each
+    handed its place ``j`` in the period, which is static and so are its
+    window, its positions and its pool. The expert stack is still read in
+    place, at ``period x P + j``. Every other model is a period of one
+    layer: the same scan over the same xs.
+
+    ``layer(carry, p, l, experts, j)`` returns ``(carry, expert rows [E] or
     None)``: a sparse-expert model's rows stack to [L_moe, E], over the
     router's whole width, and fold into ``kv.moe``; a program that holds a
     share of the experts (``kv.moe.rows``) counts ``touched`` and ``rows``
@@ -678,16 +760,32 @@ def _scan_layers(layer, x, kv: BlockedKV, params, cfg):
     if "dense_layers" in params:
         dense = params["dense_layers"]
         first = jax.tree_util.tree_leaves(dense)[0].shape[0]
-        carry, _ = jax.lax.scan(lambda c, inp: layer(c, *inp, None), carry,
-                                (dense, jnp.arange(first)))
+        carry, _ = jax.lax.scan(lambda c, inp: layer(c, *inp, None, 0),
+                                carry, (dense, jnp.arange(first)))
     layers, stack = _experts_in_place(params["layers"], x.dtype)
+    period = len(cfg.attn_kinds)
+    if period == 1:
+        def body(carry, inp):
+            p, l = inp
+            return layer(carry, p, l, (stack, l - first), 0)
+    else:
+        layers = jax.tree_util.tree_map(
+            lambda a: a.reshape(-1, period, *a.shape[1:]), layers)
 
-    def body(carry, inp):
-        p, l = inp
-        return layer(carry, p, l, (stack, l - first))
+        def body(carry, inp):
+            p, l0 = inp
+            rows = []
+            for j in range(period):
+                carry, r = layer(
+                    carry, jax.tree_util.tree_map(lambda a: a[j], p),
+                    l0 + j, (stack, l0 + j), j)
+                rows.append(r)
+            return carry, (None if rows[0] is None else jnp.stack(rows))
 
     (x, pools), rows = jax.lax.scan(
-        body, carry, (layers, jnp.arange(first, cfg.num_layers)))
+        body, carry, (layers, jnp.arange(first, cfg.num_layers)[::period]))
+    if rows is not None and period > 1:
+        rows = rows.reshape(-1, rows.shape[-1])
     return x, kv.with_pools(pools[:n_pools]).with_state(
         pools[n_pools:])._replace(
         moe=_count_moe(kv.moe, rows, cfg, x.shape[-2]))
@@ -729,7 +827,7 @@ def _scan_passes(layer, x, kv: BlockedKV, params, cfg, pick, live):
         with jax.named_scope("loop_pass"):
             def body(carry, inp):
                 p, l = inp
-                return layer(carry, p, u * n + l, None)[0], None
+                return layer(carry, p, u * n + l, None, 0)[0], None
 
             (x, pools), _ = jax.lax.scan(
                 body, carry, (params["layers"], jnp.arange(n)))
@@ -888,7 +986,8 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
                    token_pos, block_tables, last_tok_idx,
                    atom_qidx=None, atom_pos0=None, atom_qlen=None,
                    atom_tables=None, atom_inv=None, dec_row=None,
-                   dec_len=None, sampled=None, take_from=None, ssm=None, *,
+                   dec_len=None, sampled=None, take_from=None, ssm=None,
+                   window_tables=None, atom_window_tables=None, *,
                    block_size: int, attn_impl: str = "auto"
                    ) -> Tuple[jnp.ndarray, BlockedKV]:
     """Flat-token forward. Returns (per-slot last-token logits [S, V], new kv).
@@ -901,20 +1000,28 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
     with recurrent state's ``ragged.SsmBatch`` (which state slot each
     chunk's sequence has, the one-token chunks, the pieces of the longer
     ones), for Mamba-2 and power-retention layers alike.
+    ``window_tables`` [S, Bps] / ``atom_window_tables`` [A, Bps]: a stack
+    of two attention kinds' tables into its windowed layers' pool, as
+    ``block_tables`` / ``atom_tables`` are into the full layers'.
     """
     cfg = model.config
     assert cfg.scan_layers, "ragged engine requires scan_layers param layout"
     bs = block_size
-    num_slots = kv.num_slots
     t = tokens.shape[0]
     s = block_tables.shape[0]
-    ab, window = _arch_bias(cfg)
+    ab = _arch_bias(cfg)
 
     pad = token_seq >= s  # padding sentinel from RaggedBatch
-    # flat destination slot per token; padded tokens scatter out-of-range (drop)
-    dest_block = block_tables[jnp.minimum(token_seq, s - 1),
-                              token_pos // bs]
-    dest = jnp.where(pad, num_slots, dest_block * bs + token_pos % bs)
+
+    def dest_in(tables, num_slots):
+        """Flat destination slot per token under ``tables``; padded tokens
+        scatter out-of-range (drop)."""
+        block = tables[jnp.minimum(token_seq, s - 1), token_pos // bs]
+        return jnp.where(pad, num_slots, block * bs + token_pos % bs)
+
+    dest = dest_in(block_tables, kv.num_slots)
+    dest_w = None if window_tables is None \
+        else dest_in(window_tables, kv.window_slots)
 
     x = _embed(params, _tokens_in(tokens, sampled, take_from), token_pos,
                cfg)
@@ -923,9 +1030,10 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
 
         mates = pair_mates(token_seq, token_pos, ~pad)
 
-    def attend(p_attn, y, pools, l):
+    def attend(p_attn, y, pools, l, j=0):
         """Layer ``l``'s attention over the normed rows y: the new rows into
         the pool, then every row against its sequence's cached context.
+        ``j``: the layer's place in the period of attention kinds (static).
         -> (rows [T, H, D], pools)."""
         # resolved through the pluggable registry (module_registry.py — the
         # reference's module_registry + heuristics seam). Static per trace:
@@ -936,27 +1044,36 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
             "backend": jax.default_backend(),
             "has_atoms": atom_qidx is not None,
         })
-        q, new = _q_and_rows(p_attn, y, cfg, token_pos)
-        pools = _pool_write(pools, l, dest, new)
-        q = _lane_pad(q, pools[0].shape[-1], is_q=True)
-        k_cache, v_cache, v_dim, keep = _attn_views(cfg, pools)
-        ctx = PrefillAttnContext(
-            k_cache=k_cache, v_cache=v_cache, layer=l,
-            token_seq=token_seq,
-            token_pos=token_pos, block_tables=block_tables,
-            block_size=bs, alibi=ab, window=window,
-            atom_qidx=atom_qidx, atom_pos0=atom_pos0,
-            atom_qlen=atom_qlen, atom_tables=atom_tables,
-            atom_inv=atom_inv, dec_row=dec_row, dec_len=dec_len,
-            v_dim=v_dim)
-        if cfg.index_topk:    # attention over the indexer's selection
-            from .dsa import ragged_attend
+        # a stack of two attention kinds: the layer's kind has pools, tables
+        # and a row of its own; every other model's one kind has them all
+        kind = AttnKind.of(cfg, j)
+        before, mine, after = kind.split(pools)
+        row = kind.row(l)
+        tables, tile_tables, to = (window_tables, atom_window_tables, dest_w) \
+            if kind.windowed else (block_tables, atom_tables, dest)
+        with kind.scope():
+            q, new = _q_and_rows(p_attn, y, cfg, token_pos, kind.pos_embed)
+            mine = _pool_write(mine, row, to, new)
+            q = _lane_pad(q, mine[0].shape[-1], is_q=True)
+            k_cache, v_cache, v_dim, keep = _attn_views(cfg, mine)
+            ctx = PrefillAttnContext(
+                k_cache=k_cache, v_cache=v_cache, layer=row,
+                token_seq=token_seq,
+                token_pos=token_pos, block_tables=tables,
+                block_size=bs, alibi=ab, window=kind.window,
+                atom_qidx=atom_qidx, atom_pos0=atom_pos0,
+                atom_qlen=atom_qlen, atom_tables=tile_tables,
+                atom_inv=atom_inv, dec_row=dec_row, dec_len=dec_len,
+                v_dim=v_dim, kind=kind.label)
+            if cfg.index_topk:    # attention over the indexer's selection
+                from .dsa import ragged_attend
 
-            pools, idx_rows = _index_write(p_attn, y, cfg, token_pos, pools,
-                                           l, dest, mates)
-            return ragged_attend(q, *idx_rows, pools, l, ctx, cfg,
-                                 spec.name)[..., :keep], pools
-        return spec.fn(q, ctx)[..., :keep], pools
+                mine, idx_rows = _index_write(p_attn, y, cfg, token_pos,
+                                              mine, row, to, mates)
+                return ragged_attend(q, *idx_rows, mine, row, ctx, cfg,
+                                     spec.name)[..., :keep], mine
+            out = spec.fn(q, ctx)[..., :keep]
+        return out, (*before, *mine, *after)
 
     def retain(p_attn, y, pools, l):
         """Layer ``l``'s power retention over the normed rows y, in the
@@ -980,13 +1097,13 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
         out = out.at[jnp.where(one, at, t)].set(out_dec, mode="drop")
         return out.astype(y.dtype), (*pools[:-2], *state)
 
-    def layer(carry, p, l, experts):
+    def layer(carry, p, l, experts, j):
         x, pools = carry
         p = _dequant(p, x.dtype)
 
         def attn_fn(y):
             nonlocal pools
-            out, pools = attend(p["attn"], y, pools, l)
+            out, pools = attend(p["attn"], y, pools, l, j)
             return out
 
         x, rows = _block(cfg, p, x, attn_fn, ~pad, experts)
@@ -1050,7 +1167,7 @@ def build_ragged_forward_fn(model, block_size: int, attn_impl: str = "auto"):
 # ------------------------------------------------------------ decode fast path
 def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
                    block_tables, active, sampled=None, take_from=None,
-                   state_slot=None, *,
+                   state_slot=None, window_tables=None, *,
                    block_size: int, attn_impl: str = "auto"
                    ) -> Tuple[jnp.ndarray, BlockedKV]:
     """All-decode forward: ONE token per slot, attention via the Pallas paged
@@ -1063,22 +1180,27 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
     ``sampled`` / ``take_from`` [S]: :func:`_tokens_in`. ``state_slot``
     [S]: a model with recurrent state's slot of each row's
     sequence (rows change place from forward to forward; a state does not).
+    ``window_tables`` [S, Bps]: :func:`ragged_forward`'s.
     """
     cfg = model.config
     bs = block_size
-    num_slots = kv.num_slots
     s = tokens.shape[0]
-    ab, window = _arch_bias(cfg)
+    ab = _arch_bias(cfg)
 
-    dest_block = jnp.take_along_axis(
-        block_tables, (positions // bs)[:, None], axis=1)[:, 0]
-    dest = jnp.where(active, dest_block * bs + positions % bs, num_slots)
+    def dest_in(tables, num_slots):
+        block = jnp.take_along_axis(
+            tables, (positions // bs)[:, None], axis=1)[:, 0]
+        return jnp.where(active, block * bs + positions % bs, num_slots)
+
+    dest = dest_in(block_tables, kv.num_slots)
+    dest_w = None if window_tables is None \
+        else dest_in(window_tables, kv.window_slots)
     seq_lens = jnp.where(active, positions + 1, 0)
 
     x = _embed(params, _tokens_in(tokens, sampled, take_from), positions,
                cfg)
 
-    def attend(p_attn, y, pools, l):
+    def attend(p_attn, y, pools, l, j=0):
         if cfg.retention_degree:    # the state step in the place of attention
             from ...ops.retention import decode_step
 
@@ -1091,31 +1213,39 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
             return out.astype(y.dtype), (*pools[:-2], *state)
         spec = select_impl("decode_attn", attn_impl,
                            {"backend": jax.default_backend()})
-        q, new = _q_and_rows(p_attn, y, cfg, positions)
-        pools = _pool_write(pools, l, dest, new)
-        q = _lane_pad(q, pools[0].shape[-1], is_q=True)
-        k_cache, v_cache, v_dim, keep = _attn_views(cfg, pools)
-        if cfg.index_topk:    # attention over the indexer's selection
-            from .dsa import decode_attend
+        kind = AttnKind.of(cfg, j)     # as ragged_forward's attend
+        before, mine, after = kind.split(pools)
+        row = kind.row(l)
+        tables, to = (window_tables, dest_w) if kind.windowed \
+            else (block_tables, dest)
+        with kind.scope():
+            q, new = _q_and_rows(p_attn, y, cfg, positions, kind.pos_embed)
+            mine = _pool_write(mine, row, to, new)
+            q = _lane_pad(q, mine[0].shape[-1], is_q=True)
+            k_cache, v_cache, v_dim, keep = _attn_views(cfg, mine)
+            if cfg.index_topk:    # attention over the indexer's selection
+                from .dsa import decode_attend
 
-            # (no mates: the token beside a row's is never a row here)
-            pools, idx_rows = _index_write(
-                p_attn, y, cfg, positions, pools, l, dest,
-                jnp.full((s,), -1, jnp.int32))
-            return decode_attend(q, *idx_rows, pools, l, block_tables,
-                                 seq_lens, bs, cfg)[..., :keep], pools
-        return spec.fn(q, DecodeAttnContext(
-            k_cache=k_cache, v_cache=v_cache, layer=l,
-            block_tables=block_tables, seq_lens=seq_lens, block_size=bs,
-            alibi=ab, window=window, v_dim=v_dim))[..., :keep], pools
+                # (no mates: the token beside a row's is never a row here)
+                mine, idx_rows = _index_write(
+                    p_attn, y, cfg, positions, mine, row, to,
+                    jnp.full((s,), -1, jnp.int32))
+                return decode_attend(q, *idx_rows, mine, row, tables,
+                                     seq_lens, bs, cfg)[..., :keep], mine
+            out = spec.fn(q, DecodeAttnContext(
+                k_cache=k_cache, v_cache=v_cache, layer=row,
+                block_tables=tables, seq_lens=seq_lens, block_size=bs,
+                alibi=ab, window=kind.window, v_dim=v_dim,
+                kind=kind.label))[..., :keep]
+        return out, (*before, *mine, *after)
 
-    def layer(carry, p, l, experts):
+    def layer(carry, p, l, experts, j):
         x, pools = carry
         p = _dequant(p, x.dtype)
 
         def attn_fn(y):
             nonlocal pools
-            out, pools = attend(p["attn"], y, pools, l)
+            out, pools = attend(p["attn"], y, pools, l, j)
             return out
 
         x, rows = _block(cfg, p, x, attn_fn, active, experts)

@@ -136,6 +136,29 @@ class BlockedAllocator:
     free = release
 
 
+class WindowedAllocator:
+    """The block allocators of a stack of two attention kinds
+    (``ModelConfig.attn_period``): ``full``, the pool whose rows live as
+    long as their context, and ``window``, the windowed layers' pool, whose
+    blocks a sequence gives back as its queries move past them
+    (:meth:`SequenceDescriptor.out_of_window`). One discipline, two free
+    lists: a chunk is admitted with a block of EACH for every new logical
+    block (``scheduler._admit``), and a sequence that ends, is evicted or
+    requeued gives both lists back. What this object answers itself is over
+    BOTH pools: a block held at idle is a leak whichever pool holds it."""
+
+    def __init__(self, full: BlockedAllocator, window: BlockedAllocator):
+        self.full, self.window = full, window
+
+    @property
+    def num_blocks(self) -> int:
+        return self.full.num_blocks + self.window.num_blocks
+
+    @property
+    def free_blocks(self) -> int:
+        return self.full.free_blocks + self.window.free_blocks
+
+
 class LogitsRef(NamedTuple):
     """Where a drained sequence's last-token logits are: row ``slot`` of the
     ``[max_sequences, V]`` array ONE forward returned. The slot is the
@@ -205,10 +228,38 @@ class SequenceDescriptor:
     # False: no layer of the model caches a key (``ModelConfig.num_kv_layers``
     # 0), so the sequence needs no block whatever its context
     caches_kv: bool = True
+    # a stack of two attention kinds (``ModelConfig.attn_period``): the
+    # sequence's blocks in the WINDOWED layers' pool, by logical position as
+    # ``blocks`` is; the first ``window_freed`` were given back
+    # (:meth:`out_of_window`), their entries read 0 and no kernel
+    # dereferences them. None for every other model
+    window_blocks: Optional[List[int]] = None
+    window_freed: int = 0
 
     @property
     def needs_tokens(self) -> int:
         return len(self.pending)
+
+    @property
+    def window_held(self) -> List[int]:
+        """The window-pool blocks the sequence still holds."""
+        return (self.window_blocks or [])[self.window_freed:]
+
+    def out_of_window(self, window: int, block_size: int) -> List[int]:
+        """The window-pool blocks that NO query of the sequence can see any
+        more, taken off its list (their entries become 0) for the caller to
+        release. The next query stands at ``n_cached`` and sees key ``j``
+        iff ``n_cached - j < window``; a block whose LAST token is at or
+        below ``n_cached - window`` is dead for it and for every later one.
+        The kernels skip exactly those blocks (``paged_attention.tile_span``:
+        ``lo_blk = (pos0 + 1 - window) // block_size`` with ``pos0 >=
+        n_cached``), so a freed entry is never read."""
+        dead = min(max(0, (self.n_cached - window + 1) // block_size),
+                   len(self.window_blocks))
+        out = self.window_blocks[self.window_freed:dead]
+        self.window_blocks[self.window_freed:dead] = [0] * len(out)
+        self.window_freed = max(self.window_freed, dead)
+        return out
 
     def blocks_needed(self, new_tokens: int, block_size: int) -> int:
         if not self.caches_kv:
@@ -348,6 +399,19 @@ class RaggedBatch:
     dec_row: Optional[np.ndarray] = None      # [S] packed row of the token
     dec_len: Optional[np.ndarray] = None      # [S] sequence length WITH that
     #                   token (0 = this slot has no one-token chunk)
+    # a stack of two attention kinds: the sequences' tables into the
+    # WINDOWED layers' pool, as ``block_tables`` / ``atom_tables`` (a freed
+    # entry reads 0); None for every other model
+    window_tables: Optional[np.ndarray] = None       # [S, blocks_per_seq]
+    atom_window_tables: Optional[np.ndarray] = None  # [A, blocks_per_seq]
+
+    @property
+    def window_args(self) -> Tuple[np.ndarray, ...]:
+        """The window tables as ``ragged_forward`` takes them behind its
+        other operands; empty for a model with one pool."""
+        if self.window_tables is None:
+            return ()
+        return (self.window_tables, self.atom_window_tables)
 
     @property
     def current_tokens(self) -> int:
@@ -406,6 +470,34 @@ def attention_work(descs: Sequence[SequenceDescriptor],
             sum(hi for hi, _step in tiles))
 
 
+def window_work(descs: Sequence[SequenceDescriptor], lengths: Sequence[int],
+                window: int, atom_q: int) -> Tuple[int, int, int]:
+    """What the atoms' kernels of a stack of two attention kinds
+    (``ModelConfig.attn_period``) must cover in ONE windowed and ONE full
+    layer, from the chunks alone (the chunks of two tokens or more; a
+    one-token chunk is no atom): ``swa_pairs``, the (row, cached token)
+    pairs INSIDE the window (the row at position p attends ``min(p + 1,
+    window)``; the full layer's are :func:`attention_work`'s
+    ``attn_pairs``); ``swa_atom_keys``, the keys each atom of ``atom_q``
+    rows must read in a windowed layer, from its first row's window to its
+    last row, summed over the atoms; ``full_atom_keys``, the same in a full
+    layer, where an atom reads everything up to its last row. A kernel that
+    walked a windowed layer's whole context would read more than this, and
+    its share of the roofline less."""
+    pairs = swa_keys = full_keys = 0
+    for d, n in zip(descs, lengths):
+        if n < 2:
+            continue
+        p0 = d.n_cached
+        short = max(0, min(p0 + n, window) - p0)      # rows under the window
+        pairs += (n - short) * window + short * p0 + short * (short + 1) // 2
+        for r in range(0, n, atom_q or n):
+            first, end = p0 + r, p0 + min(r + (atom_q or n), n)
+            swa_keys += end - max(0, first - window + 1)
+            full_keys += end
+    return pairs, swa_keys, full_keys
+
+
 def selection_work(descs: Sequence[SequenceDescriptor],
                    lengths: Sequence[int], topk: int) -> Tuple[int, int]:
     """:func:`attention_work`'s two counts under a sparse-attention indexer
@@ -449,6 +541,9 @@ def build_ragged_batch(chunks: Sequence[Tuple[SequenceDescriptor, int]],
     last_tok = np.zeros((S,), np.int32)
     active = np.zeros((S,), bool)
     uids: List[int] = []
+    windowed = any(d.window_blocks is not None for d, _n in chunks)
+    window_tables = np.zeros((S, blocks_per_seq), np.int32) if windowed \
+        else None
 
     cursor = 0
     for slot, (desc, n) in enumerate(chunks):
@@ -460,12 +555,14 @@ def build_ragged_batch(chunks: Sequence[Tuple[SequenceDescriptor, int]],
         token_pos[cursor:cursor + n] = np.arange(desc.n_cached,
                                                  desc.n_cached + n)
         block_tables[slot, :len(desc.blocks)] = desc.blocks
+        if windowed:
+            window_tables[slot, :len(desc.window_blocks)] = desc.window_blocks
         last_tok[slot] = cursor + n - 1
         active[slot] = True
         uids.append(desc.uid)
         cursor += n
 
-    tiles = {}
+    tiles = {} if not windowed else dict(window_tables=window_tables)
     if atom_q:
         # atoms: ≤atom_q-row single-sequence q tiles (reference atom_builder)
         # of the chunks of two tokens or more. Worst case sum(ceil(n_i/BQ))
@@ -479,6 +576,7 @@ def build_ragged_batch(chunks: Sequence[Tuple[SequenceDescriptor, int]],
         atom_qlen = np.zeros((A_max,), np.int32)
         atom_tables = np.zeros((A_max, blocks_per_seq), np.int32)
         atom_inv = np.full((T,), (A_max - 1) * BQ, np.int32)
+        atom_window_tables = np.zeros_like(atom_tables) if windowed else None
         dec_row = np.zeros((S,), np.int32)
         dec_len = np.zeros((S,), np.int32)
         a = 0
@@ -496,12 +594,15 @@ def build_ragged_batch(chunks: Sequence[Tuple[SequenceDescriptor, int]],
                     atom_pos0[a] = pos0 + k
                     atom_qlen[a] = ql
                     atom_tables[a] = block_tables[slot]
+                    if windowed:
+                        atom_window_tables[a] = window_tables[slot]
                     atom_inv[rows] = a * BQ + np.arange(ql)
                     a += 1
             cur += n
         assert a <= A_max - 1, "atom overflow — builder bug"
-        tiles = dict(atom_qidx=atom_qidx, atom_pos0=atom_pos0,
+        tiles.update(atom_qidx=atom_qidx, atom_pos0=atom_pos0,
                      atom_qlen=atom_qlen, atom_tables=atom_tables,
-                     atom_inv=atom_inv, dec_row=dec_row, dec_len=dec_len)
+                     atom_inv=atom_inv, dec_row=dec_row, dec_len=dec_len,
+                     atom_window_tables=atom_window_tables)
     return RaggedBatch(tokens, token_seq, token_pos, block_tables, last_tok,
                        active, uids, **tiles)

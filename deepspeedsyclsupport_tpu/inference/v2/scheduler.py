@@ -182,15 +182,26 @@ def schedule_chunks(seqs: Sequence[SequenceDescriptor],
     return chunks
 
 
-def _admit(d: SequenceDescriptor, n: int, allocator: BlockedAllocator,
+def _admit(d: SequenceDescriptor, n: int, allocator,
            block_size: int, max_context: int) -> bool:
     want = d.blocks_needed(n, block_size)
     if want:
+        # a stack of two attention kinds (``ragged.WindowedAllocator``): a
+        # new logical block is one block of EACH pool, both or neither
+        window = allocator.window if d.window_blocks is not None else None
+        if window is not None and window.free_blocks < want:
+            return False
         # try_allocate: pool exhaustion (or an injected kv_alloc_fail)
         # skips the chunk this round — structured backpressure, never an
         # exception out of put()'s scheduling pass
-        got = allocator.try_allocate(want)
+        got = getattr(allocator, "full", allocator).try_allocate(want)
         if got is None:
             return False
+        if window is not None:
+            beside = window.try_allocate(want)
+            if beside is None:    # an injected fault: give the first back
+                allocator.full.release(got)
+                return False
+            d.window_blocks.extend(beside)
         d.blocks.extend(got)
     return True

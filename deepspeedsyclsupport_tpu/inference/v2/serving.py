@@ -1269,6 +1269,10 @@ class ServingSession:
         self._metrics.gauge("Serve/queue_depth").set(len(self.queue))
         self._metrics.gauge("Serve/kv_occupancy").set(self._kv_occupancy())
         self._metrics.gauge("Serve/live_seqs").set(len(self.running))
+        window = getattr(self.eng.allocator, "window", None)
+        if window is not None:    # a stack of two attention kinds
+            self._metrics.gauge("Serve/kv.window_occupancy").set(
+                1.0 - window.free_blocks / window.num_blocks)
         if now is not None:
             miss, shed, burn = self._slo_snapshot(now)
             self._metrics.gauge("Serve/slo.ttft_miss_frac").set(miss)

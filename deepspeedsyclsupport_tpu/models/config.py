@@ -54,6 +54,20 @@ class ModelConfig:
     # None entries = global). Heterogeneous layers, so requires
     # scan_layers=False (enforced in __post_init__).
     attn_windows: Optional[Tuple[Optional[int], ...]] = None
+    # A PERIOD of attention kinds (cohere2_moe's ``layer_types``): layer l is
+    # of kind attn_period[l % P], each ``(window or None, "rope" | "none")``,
+    # a windowed kind and a full one in every period, and num_layers a
+    # multiple of P. Unlike attn_windows the layers STAY STACKED: the
+    # serving trunk scans periods and unrolls the P layers of one with
+    # static kinds (inference/v2/model.py:_scan_layers), the windowed layers'
+    # rows and the full layers' live in pools of their own and a windowed
+    # row is given back once no query can see it (inference/v2/kv_cache.py).
+    # sliding_window and pos_embed then say nothing. Serving only.
+    attn_period: Optional[Tuple[Tuple[Optional[int], str], ...]] = None
+    # norm_type "layernorm" without the learned bias (cohere): scale only
+    norm_bias: bool = True
+    # the logits are multiplied by it (cohere's logit_scale)
+    logit_scale: float = 1.0
 
     # MoE (Mixtral-family; reference: deepspeed/moe/sharded_moe.py)
     num_experts: int = 0            # 0 => dense MLP
@@ -118,6 +132,9 @@ class ModelConfig:
     first_k_dense_replace: int = 0
     moe_intermediate_size: Optional[int] = None
     n_shared_experts: int = 0       # always-on experts beside the routed
+    # how the shared experts join the routed sum: "sum" adds each, "average"
+    # adds their MEAN (cohere2_moe's shared_expert_combination_strategy)
+    shared_expert_combine: str = "sum"
     scoring_func: str = "softmax"   # softmax | sigmoid router scores
     # "noaux_tc": a learned bias joins the scores for the top-k CHOICE only;
     # "group_limited_greedy" (DeepSeek-V2): the experts lie in n_group equal
@@ -251,6 +268,11 @@ class ModelConfig:
                 f"experts {self.first_expert_held} to "
                 f"{self.first_expert_held + self.experts_held} held of "
                 f"{self.num_experts}")
+        if self.shared_expert_combine not in ("sum", "average"):
+            raise ValueError(f"unknown shared_expert_combine "
+                             f"{self.shared_expert_combine!r}")
+        if self.attn_period is not None:
+            self._check_period()
         if self.qk_norm and self.qk_head_norm:
             raise ValueError("qk_norm (over the whole projection) and "
                              "qk_head_norm (a head at a time): one of them")
@@ -259,24 +281,28 @@ class ModelConfig:
                 or self.kv_lora_rank or self.sliding_window
                 or self.pos_embed != "rope" or self.layer_pattern is not None
                 or self.total_ut_steps > 1 or self.hc_mult > 1
-                or self.attn_windows is not None):
+                or self.attn_windows is not None
+                or self.attn_period is not None):
             raise ValueError(
                 "index_topk: the sparse-attention indexer needs index_heads "
                 "and index_head_dim, rotary positions and one uniform stack "
                 "of K-and-V attention (no latent attention, window, "
-                "layer_pattern, looped stack or hyper-connection streams)")
+                "layer_pattern, looped stack, hyper-connection streams or "
+                "period of attention kinds)")
         if self.retention_degree and (
                 self.retention_degree != 2 or self.kv_lora_rank
                 or self.index_topk or self.sliding_window
                 or self.layer_pattern is not None or self.total_ut_steps > 1
                 or self.hc_mult > 1 or self.attn_windows is not None
+                or self.attn_period is not None
                 or self.pos_embed == "alibi" or not self.scan_layers
                 or self.head_dim % 2):
             raise ValueError(
                 "retention_degree: power retention is written for degree 2 "
                 "on one uniform stack (scan_layers) of an even head_dim: no "
                 "latent attention, indexer, window, alibi, layer_pattern, "
-                "looped stack or hyper-connection streams")
+                "looped stack, hyper-connection streams or period of "
+                "attention kinds")
         if self.rope_scaling and self.rope_scaling.get("type") != "yarn":
             raise ValueError(f"unknown rope_scaling {self.rope_scaling!r}")
         if self.first_k_dense_replace and (
@@ -321,11 +347,78 @@ class ModelConfig:
                              "and ssm_state_size")
         if (self.kv_lora_rank or self.hc_mult > 1 or self.parallel_block
                 or self.first_k_dense_replace or self.moe_layer_freq != 1
-                or self.attn_windows is not None or not self.scan_layers):
+                or self.attn_windows is not None
+                or self.attn_period is not None or not self.scan_layers):
             raise ValueError(
                 "layer_pattern walks three plain stacks (scan_layers): no "
                 "latent attention, hyper-connection streams, parallel "
-                "block, leading dense layers or per-layer windows")
+                "block, leading dense layers, per-layer windows or period "
+                "of attention kinds")
+
+    def _check_period(self):
+        """``attn_period`` as tuples, and what a period of attention kinds
+        is not walked with, each by name."""
+        try:
+            period = tuple((None if w is None else int(w), str(pos))
+                           for w, pos in self.attn_period)
+        except (TypeError, ValueError):
+            raise ValueError(
+                f"attn_period {self.attn_period!r}: (window or None, "
+                f"'rope' | 'none') a layer of the period") from None
+        self.attn_period = period
+        windows = [w for w, _ in period]
+        if (any(pos not in ("rope", "none") for _, pos in period)
+                or any(w is not None and w < 1 for w in windows)
+                or None not in windows or all(w is None for w in windows)
+                or len({w for w in windows if w is not None}) != 1
+                or self.num_layers % len(period)):
+            raise ValueError(
+                f"attn_period {period!r}: kinds (window or None, 'rope' | "
+                f"'none'), a windowed kind (ONE window) and a full one in "
+                f"every period, and num_layers {self.num_layers} a multiple "
+                f"of its length (a stack of one kind is sliding_window and "
+                f"pos_embed)")
+        wrong = [name for name, on in (
+            ("latent attention (kv_lora_rank)", bool(self.kv_lora_rank)),
+            ("a window for all layers (sliding_window)",
+             self.sliding_window is not None),
+            ("per-layer windows (attn_windows)",
+             self.attn_windows is not None),
+            ("alibi or learned positions (pos_embed)",
+             self.pos_embed not in ("rope", "none")),
+            ("leading dense layers (first_k_dense_replace)",
+             bool(self.first_k_dense_replace)),
+            ("hyper-connection streams (hc_mult)", self.hc_mult > 1),
+            ("unstacked layers (scan_layers false)", not self.scan_layers),
+            ("pipeline stages (pipe_stages)",
+             self.pipe_stages not in (None, 1))) if on]
+        if wrong:
+            raise ValueError(
+                "attn_period walks ONE stack of K-and-V attention layers a "
+                "period at a time; not written for: " + ", ".join(wrong))
+
+    @property
+    def attn_kinds(self) -> Tuple[Tuple[Optional[int], str], ...]:
+        """The period of attention kinds the serving trunk unrolls, ``(window
+        or None, positions)`` a layer: ``attn_period``, or the ONE kind of
+        every other stack."""
+        return self.attn_period or ((self.sliding_window, self.pos_embed),)
+
+    @property
+    def window_layers(self) -> int:
+        """Layers whose cached rows live in the WINDOW pool (0: the model
+        has one pool; a stack that is windowed throughout keeps every row,
+        as it always did)."""
+        if self.attn_period is None:
+            return 0
+        per = sum(w is not None for w, _ in self.attn_period)
+        return self.num_layers // len(self.attn_period) * per
+
+    @property
+    def period_window(self) -> Optional[int]:
+        """The window of ``attn_period``'s windowed kind (None: no period)."""
+        return next((w for w, _ in self.attn_period or () if w is not None),
+                    None)
 
     def _check_loop(self):
         """What a looped stack (or its post-sublayer norms) is not walked
@@ -339,6 +432,8 @@ class ModelConfig:
             ("a parallel block", self.parallel_block),
             ("per-layer windows (attn_windows)",
              self.attn_windows is not None),
+            ("a period of attention kinds (attn_period)",
+             self.attn_period is not None),
             ("unstacked layers (scan_layers false)", not self.scan_layers),
             ("pipeline stages (pipe_stages)",
              self.pipe_stages not in (None, 1))) if on]
@@ -356,11 +451,13 @@ class ModelConfig:
         """Rows of the KV pool's leading axis: one for every (pass, layer)
         pair that caches keys and values. A looped stack's pass ``u`` has
         rows ``u x L .. u x L + L - 1``: a pass attends to its own. A
-        power-retention stack caches no key: 0."""
+        power-retention stack caches no key: 0. Under ``attn_period`` the
+        FULL layers' rows: the windowed layers' (``window_layers``) lie in
+        a pool of their own."""
         if self.retention_degree:
             return 0
-        layers = self.num_layers if self.layer_pattern is None \
-            else self.pattern_count("*")
+        layers = self.num_layers - self.window_layers \
+            if self.layer_pattern is None else self.pattern_count("*")
         return self.total_ut_steps * layers
 
     @property
@@ -724,6 +821,32 @@ PRESETS = {
         num_layers=40, num_heads=40, num_kv_heads=8, head_dim=128,
         max_seq_len=32768, rms_norm_eps=1e-6, rope_theta=1000000.0,
         qk_head_norm=True, retention_degree=2),
+    # CohereLabs/command-a-plus-05-2026 (model_type cohere2_moe), the
+    # language model: 32 layers in periods of three windowed (4,096 keys,
+    # rotary at theta 50000 over all 128 dims) and one full layer with NO
+    # positional term, GQA 128/8 x 128, under ONE bias-free LayerNorm a
+    # layer that feeds attention and experts alike (parallel block); 128
+    # SwiGLU experts of 4096, top-8 by sigmoid renormalised, beside four
+    # shared experts whose MEAN is added (one 16,384-wide GLU scaled by
+    # 1/4); tied embedding, logit_scale 1, no leading dense layer. The
+    # published rotary pairs are interleaved (rope_gptj); the program keeps
+    # its split-half apply_rope, which is the same function of weights
+    # whose q/k columns are permuted (models/layers.py:apply_rope). The
+    # vision tower is not here. Serving only (inference/v2); a chip of an
+    # expert-parallel deployment overrides num_experts_held.
+    "command-a-plus": _p(
+        vocab_size=262144, hidden_size=4096, intermediate_size=4096,
+        num_layers=32, num_heads=128, num_kv_heads=8, head_dim=128,
+        max_seq_len=200000, rms_norm_eps=1e-5, rope_theta=50000.0,
+        norm_type="layernorm", norm_bias=False, tie_embeddings=True,
+        logit_scale=1.0, parallel_block=True, shared_block_norm=True,
+        attn_period=((4096, "rope"),) * 3 + ((None, "none"),),
+        num_experts=128, num_experts_per_tok=8, norm_topk_prob=True,
+        scoring_func="sigmoid", n_shared_experts=4,
+        shared_expert_combine="average",
+        # as deepseek-v2's: how much of a logit the seeded routed experts
+        # carry beside the shared ones, so that parity can see them
+        routed_write_share=0.05),
 }
 
 

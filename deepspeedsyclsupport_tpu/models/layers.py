@@ -69,22 +69,25 @@ def rms_norm(x: jnp.ndarray, scale: jnp.ndarray, eps: float) -> jnp.ndarray:
     return (x * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)).astype(dtype)
 
 
-def layer_norm(x: jnp.ndarray, scale: jnp.ndarray, bias: jnp.ndarray,
-               eps: float) -> jnp.ndarray:
+def layer_norm(x: jnp.ndarray, scale: jnp.ndarray,
+               bias: Optional[jnp.ndarray], eps: float) -> jnp.ndarray:
     """LayerNorm with learned bias (reference ``csrc/transformer/inference/csrc/
-    layer_norm.cu``) — the GPT-2/OPT/BLOOM/Falcon-era norm."""
+    layer_norm.cu``) — the GPT-2/OPT/BLOOM/Falcon-era norm; ``bias`` None:
+    scale only (cohere)."""
     dtype = x.dtype
     x = x.astype(jnp.float32)
     mean = jnp.mean(x, axis=-1, keepdims=True)
     var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
-    y = (x - mean) * jax.lax.rsqrt(var + eps)
-    return (y * scale.astype(jnp.float32) + bias.astype(jnp.float32)).astype(dtype)
+    y = (x - mean) * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
+    return y.astype(dtype)
 
 
 def norm(x: jnp.ndarray, p: Params, cfg: ModelConfig) -> jnp.ndarray:
     """Norm dispatch on ``cfg.norm_type`` over a ``{"scale"[, "bias"]}`` leaf dict."""
     if cfg.norm_type == "layernorm":
-        return layer_norm(x, p["scale"], p["bias"], cfg.rms_norm_eps)
+        return layer_norm(x, p["scale"], p.get("bias"), cfg.rms_norm_eps)
     return rms_norm(x, p["scale"], cfg.rms_norm_eps)
 
 

@@ -109,7 +109,7 @@ class CausalLM:
 
         def norm_params() -> Params:
             p = {"scale": jnp.ones((cfg.hidden_size,), jnp.float32)}
-            if cfg.norm_type == "layernorm":
+            if cfg.norm_type == "layernorm" and cfg.norm_bias:
                 p["bias"] = jnp.zeros((cfg.hidden_size,), jnp.float32)
             return p
 
@@ -501,11 +501,14 @@ class CausalLM:
                 "written")
         if cfg.kv_lora_rank or cfg.hc_mult > 1 or cfg.first_k_dense_replace \
                 or cfg.experts_held != cfg.num_experts or cfg.index_topk \
-                or cfg.topk_method == "group_limited_greedy":
+                or cfg.topk_method == "group_limited_greedy" \
+                or cfg.attn_period is not None \
+                or cfg.shared_expert_combine != "sum":
             raise NotImplementedError(
                 "latent attention, hyper-connection streams, leading dense "
-                "layers, group-limited routing, a share of the experts and "
-                "the sparse-attention indexer (index_topk) "
+                "layers, group-limited routing, a share of the experts, "
+                "the sparse-attention indexer (index_topk), a period of "
+                "attention kinds (attn_period) and averaged shared experts "
                 "run on the serving path only "
                 "(inference/v2/model.py): their training forward and "
                 "backward are not written")

@@ -78,7 +78,11 @@ SUB_SCOPES = ("moe_route", "moe_experts", "moe_combine", "moe_shared",
               # recurrence (decode step or chunked form); and, INSIDE
               # ret_scan, the chunked form's pieces apart from the
               # one-token rows' state step beside them
-              "ret_proj", "ret_gate", "ret_scan", "ret_chunk")
+              "ret_proj", "ret_gate", "ret_scan", "ret_chunk",
+              # a stack of two attention kinds (ModelConfig.attn_period):
+              # a windowed layer's and a full layer's q, k, v, rotary, pool
+              # write and attention, so a profile tells the kinds apart
+              "attn_swa", "attn_full")
 
 #: named_scope label prefix — ``mfu.attn`` etc. Kept short and distinctive
 #: so the metadata regex can't false-positive on user scopes.

@@ -171,6 +171,22 @@ LOOP_FIELDS = ("passes", "kv_rows", "exit_pass")
 #: ``attn_pairs`` and ``dec_ctx_tokens``. Every other model's record has
 #: neither.
 DSA_FIELDS = ("sel_pairs", "dec_sel_tokens")
+#: What the record of a stack of two attention kinds
+#: (``ModelConfig.attn_period``: windowed layers and full ones, a pool each)
+#: says besides, counted on the host before the launch:
+#: ``kv_full_blocks_held`` and ``kv_window_blocks_held``, the blocks of each
+#: pool that are a sequence's now, this forward's new ones among them;
+#: ``kv_live_ctx_tokens``, the live sequences' context with this forward's
+#: tokens, and ``kv_window_tokens``, those of them whose windowed rows are
+#: resident (their ratio is what freeing saves: 1 = nothing was ever given
+#: back); ``swa_pairs``, ``swa_atom_keys`` and ``full_atom_keys``
+#: (``ragged.window_work``): what the atoms of ONE windowed and ONE full
+#: layer cover. And, counted AFTER the forward, ``kv_window_blocks_freed``:
+#: the window-pool blocks its sequences gave back as their next query moved
+#: past them. Every other model's record has none of them.
+WINDOW_FIELDS = ("kv_full_blocks_held", "kv_window_blocks_held",
+                 "kv_live_ctx_tokens", "kv_window_tokens", "swa_pairs",
+                 "swa_atom_keys", "full_atom_keys", "kv_window_blocks_freed")
 
 #: what a phase is where nothing times the round: ``trace_stages`` off, or
 #: an engine driven without a session

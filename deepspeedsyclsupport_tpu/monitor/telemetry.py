@@ -172,6 +172,9 @@ EVENT_NAMES = frozenset(
      # depth / KV-pool occupancy / live-stream gauges, admission outcome
      # counters, and TTFT/ITL latency histograms
      "Serve/queue_depth", "Serve/kv_occupancy", "Serve/live_seqs",
+     # a stack of two attention kinds only: the windowed layers' pool
+     # (Serve/kv_occupancy is then over both pools' blocks)
+     "Serve/kv.window_occupancy",
      "Serve/admitted", "Serve/queued", "Serve/shed", "Serve/evicted",
      "Serve/completed", "Serve/ttft_s", "Serve/itl_s",
      # serving-plane recovery (inference/v2/supervisor.py — request

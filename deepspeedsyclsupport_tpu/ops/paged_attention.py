@@ -100,12 +100,13 @@ _VMEM_CAP = 100 << 20
 def paged_decode_attention_pallas(q, k_cache, v_cache, block_tables, seq_lens,
                                   *, block_size: int, layer=0,
                                   alibi=None, window=None, v_dim=None,
-                                  interpret: bool = False):
+                                  interpret: bool = False,
+                                  name: str = "paged_decode"):
     """q: [S, H, D]; k/v_cache: [num_slots, KVH, D], or the whole pool
     [L, num_slots, KVH, D] with ``layer`` naming the one to read;
     block_tables: [S, Bps]; seq_lens: [S] valid KV tokens per slot.
-    ``alibi``: per-head slopes [H]; ``window``: sliding-window bound.
-    Returns [S, H, D].
+    ``alibi``: per-head slopes [H]; ``window``: sliding-window bound;
+    ``name``: what a profile calls the kernel. Returns [S, H, D].
 
     Decode IS the single-row case of the generalized ragged kernel below
     (the paper's prefill/decode unification): each slot becomes a BQ=1 atom
@@ -117,7 +118,7 @@ def paged_decode_attention_pallas(q, k_cache, v_cache, block_tables, seq_lens,
     out = ragged_prefill_attention_pallas(
         q[:, None], k_cache, v_cache, block_tables, pos0, qlen,
         block_size=block_size, layer=layer, alibi=alibi, window=window,
-        v_dim=v_dim, interpret=interpret, name="paged_decode")
+        v_dim=v_dim, interpret=interpret, name=name)
     return out[:, 0]
 
 
@@ -147,7 +148,8 @@ def _resolve_impl(impl: str, what: str) -> str:
 
 def paged_decode_attention(q, k_cache, v_cache, block_tables, seq_lens, *,
                            block_size: int, impl: str = "auto", layer=0,
-                           alibi=None, window=None, v_dim=None):
+                           alibi=None, window=None, v_dim=None,
+                           name: str = "paged_decode"):
     """Dispatch (the op-binding seam, like ``models/layers.attention``)."""
     impl = _resolve_impl(impl, "paged decode")
     kw = dict(block_size=block_size, layer=layer, alibi=alibi, window=window,
@@ -157,7 +159,7 @@ def paged_decode_attention(q, k_cache, v_cache, block_tables, seq_lens, *,
             q, k_cache, v_cache, block_tables, seq_lens, **kw)
     return paged_decode_attention_pallas(
         q, k_cache, v_cache, block_tables, seq_lens,
-        interpret=impl == "pallas_interpret", **kw)
+        interpret=impl == "pallas_interpret", name=name, **kw)
 
 
 # ===================================================================== prefill
@@ -551,10 +553,20 @@ def default_atom_rows(bq: int, h: int, kvh: int, d: int, block_size: int,
     640 over a 768-row chunk: 9.17 ms a layer at 128 rows (eight tiles of
     16 heads, a gather of 1.49 GB at 64 sequences), 6.30 at 32 (two of 64,
     0.47 GB), 5.83 at 16 (one, 0.30 GB). 32 heads x 640 keep the 128 rows
-    and two tiles of 16 they were accepted with."""
+    and two tiles of 16 they were accepted with. Under several kv heads:
+    the tallest halving whose ONE step of all heads stays in the budget."""
     def tiles(rows):
         return h // _head_tile(rows, h, kvh, d, block_size, itemsize)
 
+    if kvh > 1:
+        # heads of SEVERAL kv heads are never tiled (each tile would read
+        # the kv heads it has no use for): where one grid step of them all
+        # passes the budget, 128 heads over 8 x 128 at 128 rows (77 MiB
+        # modelled), the atom is halved until it fits: 64 rows there
+        while bq >= 32 and bq % 2 == 0 and _ragged_vmem_need(
+                bq, h, kvh, d, block_size, itemsize) > _HEAD_TILE_BUDGET:
+            bq //= 2
+        return bq
     if tiles(bq) <= 2:
         return bq
     while bq >= 32 and bq % 2 == 0 and tiles(bq) > 1:
@@ -884,7 +896,8 @@ def ragged_prefill_attention_reference(q_atoms, k_cache, v_cache, atom_tables,
 def ragged_prefill_attention(q_atoms, k_cache, v_cache, atom_tables,
                              atom_pos0, atom_qlen, *, block_size: int,
                              impl: str = "auto", layer=0, alibi=None,
-                             window=None, v_dim=None, sel=None):
+                             window=None, v_dim=None, sel=None,
+                             name: str = "ragged_prefill"):
     impl = _resolve_impl(impl, "ragged prefill")
     kw = dict(block_size=block_size, layer=layer, alibi=alibi, window=window,
               v_dim=v_dim, sel=sel)
@@ -894,4 +907,4 @@ def ragged_prefill_attention(q_atoms, k_cache, v_cache, atom_tables,
             **kw)
     return ragged_prefill_attention_pallas(
         q_atoms, k_cache, v_cache, atom_tables, atom_pos0, atom_qlen,
-        interpret=impl == "pallas_interpret", **kw)
+        interpret=impl == "pallas_interpret", name=name, **kw)

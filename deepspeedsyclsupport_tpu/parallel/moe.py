@@ -233,7 +233,9 @@ def moe_mlp_nodrop(p: Dict[str, Any], x: jnp.ndarray, cfg,
     chip there is no exchange, and nothing stands in for the other chips.
 
     A model with shared experts (``p["shared"]``) adds their MLP of every
-    row beside the routed sum.
+    row beside the routed sum: ONE MLP as wide as all of them, which is
+    their sum; ``cfg.shared_expert_combine == "average"`` adds their MEAN,
+    that MLP's output over ``cfg.n_shared_experts``.
 
     ``cfg.mlp_type == "mlp"``: an expert is TWO matrices and no gate,
     ``act(x w_up) w_down`` (nemotron_h's ``relu^2`` experts), the activation
@@ -328,6 +330,9 @@ def moe_mlp_nodrop(p: Dict[str, Any], x: jnp.ndarray, cfg,
         out = jnp.zeros((t, d), x.dtype).at[sorted_tok].add(ys)  # moe_gather
     if "shared" in p:
         with scope("moe_shared"):
-            out = out + (glu_mlp if glu else std_mlp)(
-                p["shared"], x[None], cfg)[0]
+            shared = (glu_mlp if glu else std_mlp)(p["shared"], x[None],
+                                                   cfg)[0]
+            if cfg.shared_expert_combine == "average":
+                shared = shared * (1.0 / cfg.n_shared_experts)
+            out = out + shared
     return out, routed

@@ -52,7 +52,8 @@ def family(eng) -> Family:
 
 def _holds_nothing(eng) -> None:
     assert not eng.seqs
-    assert eng.allocator.free_blocks == eng.config.num_blocks
+    # (a stack of two attention kinds has two pools: both, whole)
+    assert eng.allocator.free_blocks == eng.allocator.num_blocks
     if eng._state_free is not None:   # every slot back, none of them twice
         assert sorted(eng._state_free) == list(
             range(eng.config.max_sequences))

@@ -715,3 +715,84 @@ def test_the_retention_kernels_compile_at_the_cells_widths(one_chip, entry):
     pool_bytes = 8 * 17 * hk * (d + 1) * dim * 4
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < 64 << 20, mem.temp_size_in_bytes
+
+
+# ------------------------- two attention kinds, two pools, the cell's widths
+@pytest.mark.parametrize("program", ["decode_forward", "ragged_forward"])
+def test_the_two_kind_forwards_compile_at_the_cells_widths(one_chip, program,
+                                                           monkeypatch):
+    """Both serving forwards of ``command-a-plus`` at the cell's widths and
+    shapes (ONE period of four layers, 16 of 128 experts held; 24 sequences,
+    768 rows, contexts to 66,560, both whole pools): 128 query heads over 8
+    KV heads of 128 compile as atoms of 64 rows (``default_atom_rows``: at
+    128 rows one grid step models at 81 MiB of VMEM) and one-row tiles; the
+    kernels are custom calls under a name a KIND (``paged_swa_*`` on the
+    windowed layers' pool and table, ``paged_full_*`` on the full layer's),
+    the kinds' scopes reach the compiled text, all four pools are aliased to
+    the result, and ``argument_size`` is what the configuration's file
+    says is resident: 13.17 GiB."""
+    from benchmark import scopes
+    from deepspeedsyclsupport_tpu.inference.v2 import model as M
+    from deepspeedsyclsupport_tpu.inference.v2.kv_cache import (
+        BlockedKV, MoeCounters, window_blocks_a_sequence)
+    from deepspeedsyclsupport_tpu.models import build_model
+    from deepspeedsyclsupport_tpu.ops import grouped_gemm as gg
+    from deepspeedsyclsupport_tpu.ops.paged_attention import default_atom_rows
+
+    monkeypatch.setattr(gg, "default_impl", lambda: "pallas")
+    model = build_model("command-a-plus", num_layers=4, num_experts_held=16,
+                        vocab_size=32768, dtype="bfloat16")
+    cfg = model.config
+    engine = RaggedInferenceConfig(
+        block_size=64, num_blocks=12288, max_sequences=24,
+        max_tokens_per_batch=768, max_context=66560)
+    bs, seqs, toks, bps = 64, 24, 768, engine.blocks_per_seq
+    atom = default_atom_rows(128, cfg.num_heads, cfg.num_kv_heads, 128, bs, 2)
+    assert (atom, bps, window_blocks_a_sequence(4096, engine)) \
+        == (64, 1040, 77)
+
+    def on_chip(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: on_chip(x.shape, jnp.bfloat16 if jnp.issubdtype(
+            x.dtype, jnp.floating) else x.dtype),
+        jax.eval_shape(model.init_params))
+    full = on_chip((1, engine.num_blocks * bs, 8, 128), jnp.bfloat16)
+    window = on_chip((3, seqs * 77 * bs, 8, 128), jnp.bfloat16)
+    zero = on_chip(())
+    kv = BlockedKV(full, full, MoeCounters(on_chip((4, 128)), zero, zero,
+                                           zero), wk=window, wv=window)
+    sampled = on_chip((seqs + 3,))
+    if program == "decode_forward":
+        fn = M.build_decode_forward_fn(model, bs, "pallas")
+        args = (on_chip((seqs,)), on_chip((seqs,)), on_chip((seqs, bps)),
+                on_chip((seqs,), jnp.bool_), sampled, on_chip((seqs,)),
+                None, on_chip((seqs, bps)))
+        kernels = {"paged_swa_decode", "paged_full_decode"}
+    else:
+        fn = M.build_ragged_forward_fn(model, bs, "kernel")
+        atoms = seqs + toks // atom + 1
+        args = (on_chip((toks,)), on_chip((toks,)), on_chip((toks,)),
+                on_chip((seqs, bps)), on_chip((seqs,)),
+                on_chip((atoms, atom)), on_chip((atoms,)), on_chip((atoms,)),
+                on_chip((atoms, bps)), on_chip((toks,)), on_chip((seqs,)),
+                on_chip((seqs,)), sampled, on_chip((toks,)), None,
+                on_chip((seqs, bps)), on_chip((atoms, bps)))
+        kernels = {"paged_swa_decode", "paged_full_decode",
+                   "paged_swa_prefill", "paged_full_prefill"}
+    compiled = fn.lower(params, kv, *args).compile()
+    text = compiled.as_text()
+    calls = {ln.split(" = ")[0].strip().lstrip("%").split(".")[0]
+             for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln}
+    assert kernels <= calls
+    assert not calls & {"paged_decode", "ragged_prefill"}
+    under = scopes.instructions_under(text, ("attn_swa", "attn_full",
+                                             "moe_shared"))
+    assert set(under.values()) == {"attn_swa", "attn_full", "moe_shared"}
+    m = compiled.memory_analysis()
+    pools = 2 * (full.size + window.size) * 2
+    assert m.alias_size_in_bytes >= pools
+    assert round(m.argument_size_in_bytes / 2**30, 2) == 13.17
+    assert m.temp_size_in_bytes < 0.75 * 2**30

@@ -166,7 +166,7 @@ def test_mixed_round_attends_row_for_row_as_the_xla_path(arch):
     b = _mixed_batch()
     q = jax.random.normal(jax.random.PRNGKey(2),
                           (MIX_T, cfg.num_heads, cfg.head_dim), jnp.float32)
-    alibi, window = M._arch_bias(cfg)
+    alibi, window = M._arch_bias(cfg), M.AttnKind.of(cfg, 0).window
     ctx = M.PrefillAttnContext(
         k_cache=kv.k, v_cache=kv.v, layer=jnp.int32(1),
         token_seq=jnp.asarray(b.token_seq), token_pos=jnp.asarray(b.token_pos),

@@ -412,6 +412,13 @@ open(marker, "w").write(str(n + 1))
                                                       monkeypatch):
         monkeypatch.setenv("WORLD_SIZE", "8")
         body = """
+# (both ranks have counted this attempt before either acts: on a loaded
+# host a rank torn down BEFORE it wrote its marker would, relaunched, take
+# its first attempt's branch again and sleep its 30 s whole)
+t_end = time.time() + 10
+while n == 0 and time.time() < t_end and not all(
+        os.path.exists(marker[:-1] + str(r)) for r in range(2)):
+    time.sleep(0.02)
 if n == 0 and rank == 1:
     sys.exit(218)          # watchdog found a hung collective
 if n == 0:
@@ -593,6 +600,13 @@ sys.exit(0)
         misattribute the SIGTERMed siblings as crashes."""
         monkeypatch.setenv("WORLD_SIZE", "8")
         body = """
+# (both ranks have counted this attempt before either acts: on a loaded
+# host a rank torn down BEFORE it wrote its marker would, relaunched, take
+# its first attempt's branch again and sleep its 30 s whole)
+t_end = time.time() + 10
+while n == 0 and time.time() < t_end and not all(
+        os.path.exists(marker[:-1] + str(r)) for r in range(2)):
+    time.sleep(0.02)
 if n == 0 and rank == 0:
     sys.exit(220)          # sentinel: ladder exhausted
 if n == 0:

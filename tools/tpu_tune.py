@@ -365,6 +365,7 @@ PAGED_KINDS = {
     "16/16": dict(h=16, kvh=16, d=128, atom=128),     # OLMoE, Ouro
     "32/2": dict(h=32, kvh=2, d=128, atom=128),       # Nemotron
     "32/4sel": dict(h=32, kvh=4, d=128, atom=128, sel=True),        # Keye
+    "128/8": dict(h=128, kvh=8, d=128, atom=64),      # command-a-plus
     "32/latent": dict(h=32, d=640, v=512, atom=128),  # Xing4: two head tiles
     "128/latent": dict(h=128, d=640, v=512, atom=16),  # DeepSeek-V2 (576 as 640)
 }
