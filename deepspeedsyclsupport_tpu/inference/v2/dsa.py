@@ -17,17 +17,23 @@ pool write and scores), ``dsa_select`` (the selection) and ``dsa_attend``
 (whatever gathers, masks and attends over the selected keys; the one-token
 rows' part under ``dsa_rows`` inside it).
 
-Two routes, by the chunk's length as everywhere on this path:
+Two routes, by the chunk's length as everywhere on this path, through the
+same two kernels (``ops/sparse_index.py``: the scores, the exact selection):
 
-* a chunk of two tokens or more, cut into atoms: the scores of an atom's
-  rows in one kernel, the selection in a second (``ops/sparse_index.py``),
-  and the ragged paged kernel under the selection's MASK: it visits every
-  cached pair of the atom and keeps the selected (a prefill that reads the
-  selected rows only is not written);
-* a one-token chunk (every row of ``decode_forward``): its scores and an
-  exact ``lax.top_k`` in XLA, a GATHER of the selected rows of K and V
-  through the block table, and softmax attention over those ``index_topk``
-  rows: what it reads of K and V does not grow with the context.
+* a chunk of two tokens or more, cut into atoms: a tile of the scores
+  kernel and of the selection kernel is an atom, its rows at consecutive
+  positions, and the ragged paged kernel runs under the selection's MASK:
+  it visits every cached pair of the atom and keeps the selected (a prefill
+  that reads the selected rows only is not written);
+* a one-token chunk (every row of ``decode_forward``): the scores kernel
+  takes each row as a tile of its own (thousands of keys a grid step:
+  ``sparse_index.score_keys``), the selection kernel all of them as ONE
+  tile whose rows stand each at its own sequence's last position and which
+  walks up to the longest's; the mask's set is read out as positions
+  (``sparse_index.positions_from_mask``: no sort, no scatter), the selected
+  rows of K and V are GATHERED through the block table, and softmax
+  attention runs over those ``index_topk`` rows: what it reads of K and V
+  does not grow with the context.
 
 An engine whose attention takes no atoms (``prefill_attn`` ``xla`` or
 ``flash``: the CPU's, the tests') runs every row through the exact
@@ -108,11 +114,24 @@ def seq_index_keys(idx_pool, layer, block_tables, block_size: int):
     return keys.reshape(block_tables.shape[0], -1, width // 2)
 
 
-def _kernel_impl(name: str) -> str:
+def kernel_impl(name: str) -> str:
     """The paged kernels' ``impl`` word for a ``prefill_attn`` entry's name
     (the entries that take no atoms run the ``jax.numpy`` twins)."""
     return {"kernel": "pallas",
             "kernel_interpret": "pallas_interpret"}.get(name, "xla")
+
+
+def rows_walk(impl: str, cfg, c: int, itemsize: int):
+    """``(keys a scores step, keys a selection chunk)`` of the one-token
+    rows' route under ``impl`` over a table of ``c`` keys (the pool's
+    ``itemsize``): the kernels' own steps, by the shape of a one-row tile
+    (no more than the table: what they pad it by is not its keys); the
+    ``jax.numpy`` twins score and select over the whole table."""
+    if impl == "xla":
+        return c, c
+    return (min(c, sparse_index.score_keys(
+        1, cfg.index_heads, cfg.index_head_dim, itemsize, c)),
+            min(c, sparse_index.select_chunk(c)))
 
 
 def attend_atoms(q, q_i, w, k_seq, k_cache, v_cache, layer, ctx, cfg, impl):
@@ -140,25 +159,30 @@ def attend_atoms(q, q_i, w, k_seq, k_cache, v_cache, layer, ctx, cfg, impl):
 
 
 def attend_rows(q, q_i, w, k_seq, k_cache, v_cache, layer, block_tables,
-                seq_lens, block_size: int, cfg):
+                seq_lens, block_size: int, cfg, impl: str):
     """One-token rows, one a sequence slot: q [S, H, D], qI [S, Hi, Di], w
     [S, Hi], ``seq_lens`` [S] the slot's length WITH the row's token (0: no
-    row). Scores over the whole context, the exact ``index_topk`` best, and
-    attention over the gathered rows of K and V. -> [S, H, D]."""
+    row). The scores of each row as a tile of its own, the exact
+    ``index_topk`` best of all rows as one tile (row s at position
+    ``seq_lens[s] - 1``), their positions out of the mask, and attention
+    over the gathered rows of K and V. -> [S, H, D]."""
     s, h, d = q.shape
     c = k_seq.shape[1]
     kvh = k_cache.shape[-2]
     k = min(cfg.index_topk, c)
     with jax.named_scope("dsa_index"):
-        scores = sparse_index.index_scores_reference(
-            q_i[:, None], w[:, None], k_seq, jnp.arange(s),
-            scale=index_scale(cfg))[:, 0]
+        scores = sparse_index.index_scores(
+            q_i[:, None], w[:, None], k_seq, jnp.arange(s), seq_lens,
+            scale=index_scale(cfg), impl=impl)               # [S, 1, C]
     with jax.named_scope("dsa_select"):
-        seen = jnp.arange(c)[None] < seq_lens[:, None]
-        _, top = jax.lax.top_k(jnp.where(seen, scores, -jnp.inf), k)
+        sel = sparse_index.select_topk(
+            scores.reshape(1, s, c), (seq_lens - 1)[None],
+            jnp.full((1,), s, jnp.int32), k=k, impl=impl)
+        top = sparse_index.positions_from_mask(sel[0], k=k)  # [S, k]; C: none
     with jax.named_scope("dsa_attend"), jax.named_scope("dsa_rows"):
-        live = top < seq_lens[:, None]                       # [S, k]
-        block = jnp.take_along_axis(block_tables, top // block_size, axis=1)
+        live = top < seq_lens[:, None]
+        block = jnp.take_along_axis(
+            block_tables, jnp.minimum(top, c - 1) // block_size, axis=1)
         slots = jnp.where(live, block * block_size + top % block_size, 0)
         k_sel = k_cache[layer, slots].astype(jnp.float32)    # [S, k, KVH, D]
         v_sel = v_cache[layer, slots].astype(jnp.float32)
@@ -200,25 +224,27 @@ def ragged_attend(q, q_i, w, pools, layer, ctx, cfg, impl_name: str):
     k_cache, v_cache, idx = pools
     with jax.named_scope("dsa_index"):
         k_seq = seq_index_keys(idx, layer, ctx.block_tables, ctx.block_size)
-    if ctx.atom_qidx is None or _kernel_impl(impl_name) == "xla":
+    if ctx.atom_qidx is None or kernel_impl(impl_name) == "xla":
         return attend_tokens(q, q_i, w, k_seq, k_cache, v_cache, layer, ctx,
                              cfg)
     out = attend_atoms(q, q_i, w, k_seq, k_cache, v_cache, layer, ctx, cfg,
-                       _kernel_impl(impl_name))
+                       kernel_impl(impl_name))
     out_dec = attend_rows(
         q[ctx.dec_row], q_i[ctx.dec_row], w[ctx.dec_row], k_seq, k_cache,
-        v_cache, layer, ctx.block_tables, ctx.dec_len, ctx.block_size, cfg)
+        v_cache, layer, ctx.block_tables, ctx.dec_len, ctx.block_size, cfg,
+        kernel_impl(impl_name))
     # a slot with no one-token chunk scatters out of range (dropped)
     rows = jnp.where(ctx.dec_len > 0, ctx.dec_row, q.shape[0])
     return out.at[rows].set(out_dec, mode="drop")
 
 
 def decode_attend(q, q_i, w, pools, layer, block_tables, seq_lens,
-                  block_size: int, cfg):
+                  block_size: int, cfg, impl: str):
     """Attention of one ``decode_forward`` layer: every row a one-token
-    row."""
+    row. ``impl``: the ``decode_attn`` entry's name, the kernels' word as it
+    stands."""
     k_cache, v_cache, idx = pools
     with jax.named_scope("dsa_index"):
         k_seq = seq_index_keys(idx, layer, block_tables, block_size)
     return attend_rows(q, q_i, w, k_seq, k_cache, v_cache, layer,
-                       block_tables, seq_lens, block_size, cfg)
+                       block_tables, seq_lens, block_size, cfg, impl)

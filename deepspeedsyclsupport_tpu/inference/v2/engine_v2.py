@@ -305,6 +305,10 @@ class InferenceEngineV2:
             kv_step_keys(rows, *shape, latent,
                          bool(model.config.index_topk) and rows > 1)
             for rows in (cfg.atom_q_size, 1))
+        # a sparse-attention indexer: what its two steps walk for the
+        # one-token rows of each program (selection_work's dec_walk_keys)
+        self._dsa_walk = self._dsa_rows_routes(spec.name) \
+            if self.kv.idx is not None else None
         # the static shapes of ragged_forward, smallest first, by the rows of
         # an atom and of a state layer's piece (0: the model takes none): a
         # mixed
@@ -407,6 +411,33 @@ class InferenceEngineV2:
             self.round_spans.launched(name)
         return out
 
+    def _dsa_rows_routes(self, prefill_impl: str
+                         ) -> Dict[str, Tuple[int, int]]:
+        """{program: (keys a scores step, keys a selection chunk)} of the
+        route a sparse-attention indexer's one-token rows take in each
+        forward program (``dsa.rows_walk`` under the word its attention
+        entry gives the kernels), each a ``dsa_rows`` decision of the
+        set-up ledger."""
+        from .dsa import kernel_impl, rows_walk
+        from .module_registry import select_impl
+
+        cfg, mc = self.config, self.model.config
+        impls = {
+            "ragged_forward": kernel_impl(prefill_impl)
+            if self._use_atoms else "xla",
+            "decode_forward": select_impl(
+                "decode_attn", cfg.decode_attn,
+                {"backend": jax.default_backend()}).name}
+        walks = {}
+        for program, impl in impls.items():
+            walks[program] = rows_walk(
+                impl, mc, cfg.blocks_per_seq * cfg.block_size,
+                self.kv.idx.dtype.itemsize)
+            setup_decision("dsa_rows", program=program, impl=impl,
+                           score_keys=walks[program][0],
+                           select_keys=walks[program][1])
+        return walks
+
     def _phase(self, name: str):
         """The running round's span around ``name``
         (``reqtrace.ROUND_PHASES``); nothing without a session's clock."""
@@ -414,7 +445,8 @@ class InferenceEngineV2:
         return NO_PHASE if spans is None else spans.phase(name)
 
     def _note_forward(self, descs, lengths, atoms: int = 0,
-                      rows: int = 0, warm: int = 0) -> None:
+                      rows: int = 0, warm: int = 0,
+                      program: str = "ragged_forward") -> None:
         """What the forward about to be launched covers, for the round's
         record; called BEFORE it, so ``ctx_tokens`` is the context the
         attention kernel must read and ``kv_blocks`` the tables it walks.
@@ -432,8 +464,10 @@ class InferenceEngineV2:
         model's record gets
         ``reqtrace.MOE_STATIC_FIELDS``: the rows of the tiles its grouped
         GEMMs lay them in (static, by that shape) and the expert rows a live
-        token brings. Its live tokens are counted whether or not a round is
-        recorded."""
+        token brings; a sparse-attention indexer's record
+        ``reqtrace.DSA_FIELDS``, what its one-token rows walk by
+        ``program``'s route. Its live tokens are counted whether or not a
+        round is recorded."""
         self._forward_tokens += sum(lengths)
         if self.round_spans is None:
             return
@@ -481,10 +515,10 @@ class InferenceEngineV2:
                                 if self._use_atoms else 0))))
         if self.kv.idx is not None:
             # a sparse-attention indexer: what the attention reads of that
-            sel_pairs, dec_sel_tokens = selection_work(
-                descs, lengths, self.model.config.index_topk)
-            self.round_spans.fields.update(sel_pairs=sel_pairs,
-                                           dec_sel_tokens=dec_sel_tokens)
+            self.round_spans.fields.update(zip(
+                ("sel_pairs", "dec_sel_tokens", "dec_walk_keys"),
+                selection_work(descs, lengths, self.model.config.index_topk,
+                               self._dsa_walk[program])))
         if self._state_free is not None:
             # live rows through the state layers, and the sequence pieces
             # whose state they read and wrote (a one-token chunk is one
@@ -1201,7 +1235,8 @@ class InferenceEngineV2:
                 state = (slots,)
             self._note_forward(
                 *zip(*chunks), rows=cfg.max_sequences,
-                warm=warm_tiles(active) if self._caches_kv else 0)
+                warm=warm_tiles(active) if self._caches_kv else 0,
+                program="decode_forward")
         with self._phase("dispatch"):
             tokens, sampled, take_from = self._token_operands(tokens,
                                                               sampled)

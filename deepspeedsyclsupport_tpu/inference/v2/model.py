@@ -1231,7 +1231,8 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
                     p_attn, y, cfg, positions, mine, row, to,
                     jnp.full((s,), -1, jnp.int32))
                 return decode_attend(q, *idx_rows, mine, row, tables,
-                                     seq_lens, bs, cfg)[..., :keep], mine
+                                     seq_lens, bs, cfg,
+                                     spec.name)[..., :keep], mine
             out = spec.fn(q, DecodeAttnContext(
                 k_cache=k_cache, v_cache=v_cache, layer=row,
                 block_tables=tables, seq_lens=seq_lens, block_size=bs,

@@ -499,7 +499,8 @@ def window_work(descs: Sequence[SequenceDescriptor], lengths: Sequence[int],
 
 
 def selection_work(descs: Sequence[SequenceDescriptor],
-                   lengths: Sequence[int], topk: int) -> Tuple[int, int]:
+                   lengths: Sequence[int], topk: int,
+                   walk: Tuple[int, int] = (1, 1)) -> Tuple[int, int, int]:
     """:func:`attention_work`'s two counts under a sparse-attention indexer
     that keeps the ``topk`` best cached tokens a row
     (``ModelConfig.index_topk``), from the chunks alone: ``sel_pairs``, the
@@ -507,14 +508,26 @@ def selection_work(descs: Sequence[SequenceDescriptor],
     at position p attends ``min(p + 1, topk)``), and ``dec_sel_tokens``, the
     same over the one-token chunks. Beside ``attn_pairs`` and
     ``dec_ctx_tokens`` they say what share of its context the attention
-    reads."""
+    reads.
+
+    Then what the indexer's two steps WALK for the one-token chunks,
+    ``dec_walk_keys``: each row's context rounded up to the scores' step
+    (a tile of its own), plus for every row the longest's rounded up to the
+    selection's chunk (one tile walks them together); ``walk`` = (keys a
+    scores step, keys a selection chunk) (``sparse_index.score_keys`` /
+    ``select_chunk``; a route that takes no kernels walks the whole table:
+    both the table's width). Over ``2 x rows x`` the table's width it is
+    the share of the score matrix's columns still paid."""
     def kept(d, n):
         full = max(0, min(d.n_cached + n, topk) - d.n_cached)   # rows < topk
         return (n - full) * topk + full * d.n_cached + full * (full + 1) // 2
 
+    rows = [d.n_cached + 1 for d, n in zip(descs, lengths) if n == 1]
+    step, chunk = walk
     return (sum(kept(d, n) for d, n in zip(descs, lengths) if n > 1),
-            sum(min(d.n_cached + 1, topk)
-                for d, n in zip(descs, lengths) if n == 1))
+            sum(min(ctx, topk) for ctx in rows),
+            sum(-(-ctx // step) * step for ctx in rows)
+            + len(rows) * (-(-max(rows, default=0) // chunk) * chunk))
 
 
 def build_ragged_batch(chunks: Sequence[Tuple[SequenceDescriptor, int]],

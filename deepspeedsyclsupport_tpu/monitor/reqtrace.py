@@ -168,9 +168,13 @@ LOOP_FIELDS = ("passes", "kv_rows", "exit_pass")
 #: launch (``ragged.selection_work``): ``sel_pairs``, the (row, SELECTED
 #: token) pairs of the chunks of two tokens or more, and ``dec_sel_tokens``,
 #: the selected tokens of the one-token chunks: what the attention reads of
-#: ``attn_pairs`` and ``dec_ctx_tokens``. Every other model's record has
-#: neither.
-DSA_FIELDS = ("sel_pairs", "dec_sel_tokens")
+#: ``attn_pairs`` and ``dec_ctx_tokens``; and ``dec_walk_keys``, the keys the
+#: indexer's two steps WALK for the one-token chunks: each row's context
+#: rounded up to its scores step, plus a row the longest's rounded up to the
+#: selection's chunk (over 2 x ``decode_rows`` x the table's width: the
+#: share of the score matrix's columns still paid; 1 on a route that takes
+#: no kernels). Every other model's record has none of the three.
+DSA_FIELDS = ("sel_pairs", "dec_sel_tokens", "dec_walk_keys")
 #: What the record of a stack of two attention kinds
 #: (``ModelConfig.attn_period``: windowed layers and full ones, a pool each)
 #: says besides, counted on the host before the launch:

@@ -422,6 +422,62 @@ class TestTuneChainTimer:
                                     (b,), iters)
         assert dt > 0 and how in verdicts
 
+    def test_the_dsa_sweep_runs_every_step_and_holds_the_twins(
+            self, tune, monkeypatch, capsys):
+        """``tpu_tune.py dsa`` at a tiny cell, the kernels interpreted and
+        the profiler's reading stubbed (what the chip gives): every step of
+        the one-token rows' route and of the atom's tile is built and run,
+        a keys-a-step candidate is in force while ITS step is traced and
+        not after, and ``--parity`` reads no difference from the twins."""
+        import functools
+        import json
+
+        monkeypatch.setattr(tune, "DSA_CELL", dict(
+            seqs=4, table=1024, heads=2, dim=8, topk=64, atom=8))
+        monkeypatch.setattr(tune, "DSA_CONTEXTS", (512, 1024))
+        load, asked = tune._load_op, []
+
+        def interpreted(root, op, name):
+            mod = load(root, op, name)
+            scores = mod.index_scores_pallas
+
+            def listening(q, *a, **kw):
+                asked.append(mod.score_keys(q.shape[1], 2, 8, 2, 1024))
+                return scores(q, *a, **kw, interpret=True)
+            mod.index_scores_pallas = listening
+            mod.select_topk_pallas = functools.partial(
+                mod.select_topk_pallas, interpret=True)
+            return mod
+
+        def reading(steps, args, **_kw):
+            for step in steps.values():
+                jax.block_until_ready(step(*args))
+            return {name: {"kernel": 1.0, "xla": 0.25} for name in steps}
+
+        monkeypatch.setattr(tune, "_load_op", interpreted)
+        monkeypatch.setattr(tune, "_traced_kernels", reading)
+        tune.dsa(["--keys", "128", "256", "--parity"])
+        out = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
+        parity = {r["what"]: r for r in out if r["section"] == "dsa_parity"}
+        assert parity["rows_scores"]["worst"] < 1e-6
+        assert all(parity[w]["mask_differs"] == 0
+                   and parity[w]["positions_differ"] == 0
+                   for w in ("rows_select", "rows_select_ties"))
+        assert parity["rows_select"]["kept"] == [64, 0, 48, 64]
+        rows, atom = [r for r in out if r["section"] == "dsa"]
+        assert not rows["failed"] and not atom["failed"]
+        assert set(rows["us_a_call"]) == {
+            "rows_scores_128", "rows_scores_256", "rows_scores_tree",
+            "rows_select_tree", "rows_positions_tree",
+            "rows_xla_scores_and_top_k"}
+        assert set(atom["us_a_call"]) == {"atom_scores_tree",
+                                          "atom_select_tree"}
+        assert rows["us_a_call"]["rows_select_tree"] == {"512": 1000.0,
+                                                         "1024": 1000.0}
+        assert rows["xla_us_a_call"]["rows_positions_tree"]["512"] == 250.0
+        # parity's call, the two candidates, the rule's own, the atom's
+        assert asked == [1024, 128, 256, 1024, 1024]
+
 
 class TestSpatialAndTiling:
     """ops/spatial (diffusers fused bias-add family, reference

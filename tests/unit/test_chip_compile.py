@@ -13,6 +13,7 @@ never autouse), and it compiles in the test's own process.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -602,7 +603,12 @@ def test_the_indexer_forwards_compile_and_copy_no_pool(one_chip, program,
     49,152, the whole pool of 6,272 blocks a layer): the three kernels of
     the selection path are custom calls under their own names
     (``dsa_index_scores``, ``dsa_select``, the ragged kernel under a mask as
-    ``dsa_prefill``), the scopes reach the compiled text, all three pools
+    ``dsa_prefill``; ``decode_forward``, all one-token rows, holds the first
+    two), the scopes reach the compiled text, the one-token rows' route
+    sorts and scatters nothing under ``dsa_select`` and converts no
+    sequence's keys ``[8, 49152, 64]`` to float32 under ``dsa_index`` (the
+    route of PR 45 did all three in both programs: 4.4 ms of sorts and 7 ms
+    of copies a forward), all three pools
     are aliased to the result, and no pool is copied: the indexer's, two
     slots a row, is written and gathered in the layout it is carried in (a
     64-wide row of its own was stored slot-minor and copied whole every
@@ -640,7 +646,7 @@ def test_the_indexer_forwards_compile_and_copy_no_pool(one_chip, program,
         fn = M.build_decode_forward_fn(model, bs, "pallas")
         args = (on_chip((seqs,)), on_chip((seqs,)), on_chip((seqs, bps)),
                 on_chip((seqs,), jnp.bool_), sampled, on_chip((seqs,)))
-        kernels = set()
+        kernels = {"dsa_index_scores", "dsa_select"}
     else:
         fn = M.build_ragged_forward_fn(model, bs, "kernel")
         atoms = seqs + toks // atom + 1
@@ -659,6 +665,12 @@ def test_the_indexer_forwards_compile_and_copy_no_pool(one_chip, program,
     under = scopes.instructions_under(
         text, ("dsa_index", "dsa_select", "dsa_attend", "dsa_rows"))
     assert set(under.values()) >= {"dsa_index", "dsa_select", "dsa_rows"}
+    # (fused instructions carry their own op_name: every line is looked at)
+    lines = [ln for ln in text.splitlines() if "op_name=" in ln]
+    assert not [ln for ln in lines if "/dsa_select/" in ln
+                and re.search(r" (sort|scatter)\(", ln)]
+    keys = f"f32[{seqs},{bps * bs},{cfg.index_head_dim}]"
+    assert not [ln for ln in lines if "/dsa_index/" in ln and keys in ln]
     m = compiled.memory_analysis()
     pools = (2 * pool.size + idx.size) * 2
     assert m.alias_size_in_bytes >= pools

@@ -19,7 +19,7 @@ import pytest
 
 from benchmark import dsa_faults, parity, spec
 from deepspeedsyclsupport_tpu.inference.v2 import (
-    InferenceEngineV2, ServingPolicyConfig, ServingSession)
+    InferenceEngineV2, ServingPolicyConfig, ServingSession, dsa)
 from deepspeedsyclsupport_tpu.inference.v2.kv_cache import kv_pool_stats
 from deepspeedsyclsupport_tpu.inference.v2.ragged import (
     SequenceDescriptor, selection_work)
@@ -156,6 +156,9 @@ def test_mixed_rounds_serve_prompts_beside_decodes(family, built):
     rounds = [r["data"] for r in sess.drain_trace()
               if r["data"].get("stage") == "round" and r["data"]["program"]]
     assert all("sel_pairs" in d and "dec_sel_tokens" in d for d in rounds)
+    # what the one-token rows walk: the whole table's share, never more
+    assert all(0 < d["dec_walk_keys"] <= 2 * d["decode_rows"] * 64
+               for d in rounds if d["decode_rows"])
     assert any(d["sel_pairs"] and d["dec_sel_tokens"] for d in rounds)
     assert all(d["sel_pairs"] <= d["attn_pairs"]
                and d["dec_sel_tokens"] <= d["dec_ctx_tokens"] for d in rounds)
@@ -170,14 +173,46 @@ def _atoms(seed, a=3, r=8, c=48, hi=2, di=8):
     return q, w, k
 
 
-def test_the_scores_kernel_is_its_twin():
-    q, w, k = _atoms(0)
-    tile_seq, tile_hi = jnp.asarray([1, 0, 1]), jnp.asarray([40, 48, 0])
-    want = sparse_index.index_scores_reference(q, w, k, tile_seq, scale=0.25)
-    got = sparse_index.index_scores_pallas(q, w, k, tile_seq, tile_hi,
-                                           scale=0.25, interpret=True)
-    np.testing.assert_allclose(got[:2], want[:2], rtol=1e-5, atol=1e-6)
-    assert not np.asarray(got[2]).any()          # a dead tile scores nothing
+@pytest.mark.parametrize("tile", ["atoms", "one_row_wide_step"])
+def test_the_scores_kernel_is_its_twin(tile, monkeypatch):
+    """``atoms``: tiles of 8 rows of either sequence. ``one_row_wide_step``:
+    every row a tile of its own sequence (the one-token rows' route) over
+    600 keys, the step 256 keys by the rule (a budget that gives 8 rows 128)
+    where the rule reads the tile's shape: a tile scores what its row may
+    see, writes zeros past the step that holds its last key, and a dead
+    tile scores nothing."""
+    if tile == "atoms":
+        q, w, k = _atoms(0)
+        tile_seq, tile_hi = jnp.asarray([1, 0, 1]), jnp.asarray([40, 48, 0])
+        step = 128
+    else:
+        monkeypatch.setattr(sparse_index, "SCORE_STEP_BYTES", 300_000)
+        assert [sparse_index.score_keys(r, 2, 8, 4, 600)
+                for r in (1, 64)] == [256, 128]
+        q, w, k = _atoms(3, a=4, r=1, c=600)
+        k = jnp.concatenate([k, k[::-1] * 0.5])        # four sequences
+        tile_seq, tile_hi = jnp.arange(4), jnp.asarray([600, 130, 0, 300])
+        step = 256
+    want = np.asarray(sparse_index.index_scores_reference(
+        q, w, k, tile_seq, scale=0.25))
+    got = np.asarray(sparse_index.index_scores_pallas(
+        q, w, k, tile_seq, tile_hi, scale=0.25, interpret=True))
+    assert got.shape == want.shape
+    for a, hi in enumerate(np.asarray(tile_hi)):
+        walked = min(-(-hi // step) * step, want.shape[-1])
+        np.testing.assert_allclose(got[a, :, :walked], want[a, :, :walked],
+                                   rtol=1e-5, atol=1e-6)
+        assert not got[a, :, walked:].any()   # a dead tile: nothing at all
+
+
+def test_the_scores_step_follows_the_tiles_rows():
+    """At the cell's widths (16 heads of 64, bf16 keys, tables of 49,152):
+    512 keys a step under an atom of 128 rows, as it was; thousands under
+    one row, whole steps of the table."""
+    assert sparse_index.score_keys(128, 16, 64, 2, 49152) == 512
+    one = sparse_index.score_keys(1, 16, 64, 2, 49152)
+    assert one >= 2048 and 49152 % one == 0
+    assert sparse_index.score_keys(1, 16, 64, 2, 300) == 384
 
 
 @pytest.mark.parametrize("ties", [False, True])
@@ -203,6 +238,90 @@ def test_the_selection_kernel_is_its_twin_and_breaks_ties_low(ties):
             seen = scores[0, r, :30 + r]
             order = sorted(range(len(seen)), key=lambda s: (-seen[s], s))
             assert sorted(order[:8]) == np.flatnonzero(want[0, r]).tolist()
+
+
+@pytest.mark.parametrize("ties", [False, True])
+def test_the_selection_kernel_takes_rows_at_positions_of_their_own(
+        ties, monkeypatch):
+    """ONE tile of six rows, each at its own sequence's last position (the
+    one-token rows of a forward): contexts 200, 0 (a dead row), 5 (fewer
+    than ``k``: keeps all), 40, 129 and 8 of 300 keys, walked in chunks of
+    128 up to the longest's (the third chunk is blanked, not walked). With
+    ``ties`` every row's k-th value is tied and the LOWER positions win."""
+    monkeypatch.setattr(sparse_index, "SELECT_CHUNK", 128)
+    rng = np.random.default_rng(5)
+    scores = rng.standard_normal((1, 6, 300)).astype(np.float32)
+    if ties:
+        scores = np.round(scores).clip(-1, 2) + 0.0
+    lens = np.asarray([200, 0, 5, 40, 129, 8])
+    pos0, qlen = jnp.asarray(lens - 1)[None], jnp.asarray([6])
+    want = np.asarray(sparse_index.select_topk_reference(
+        jnp.asarray(scores), pos0, qlen, k=8))
+    got = np.asarray(sparse_index.select_topk_pallas(
+        jnp.asarray(scores), pos0, qlen, k=8, interpret=True))
+    np.testing.assert_array_equal(got, want)
+    assert want[0].sum(-1).tolist() == [8, 0, 5, 8, 8, 8]
+    for r, n in enumerate(lens):     # by hand: best first, a tied value low
+        seen = scores[0, r, :n]
+        order = sorted(range(n), key=lambda s: (-seen[s], s))
+        assert sorted(order[:8]) == np.flatnonzero(want[0, r]).tolist()
+    # a tile of consecutive rows says the same through either form of pos0
+    atom = np.asarray(sparse_index.select_topk_pallas(
+        jnp.asarray(scores), jnp.asarray([[100, 101, 102, 103, -1, -1]]),
+        jnp.asarray([6]), k=8, interpret=True))
+    np.testing.assert_array_equal(atom, np.asarray(
+        sparse_index.select_topk_pallas(
+            jnp.asarray(scores), jnp.asarray([100]), jnp.asarray([4]), k=8,
+            interpret=True)))
+
+
+@pytest.mark.parametrize("rows", ["full", "short", "empty", "over"])
+def test_the_positions_are_the_masks_set(rows):
+    """``positions_from_mask``: exactly the mask's positions, rising, then
+    the row's width in the slots left over; of more than ``k`` the lowest."""
+    rng = np.random.default_rng(6)
+    c, k = 700, 16
+    mask = np.zeros((5, c), np.int8)
+    for r in range(5):
+        n = {"full": k, "short": 3 * r, "empty": 0, "over": k + 9}[rows]
+        mask[r, rng.permutation(c)[:n]] = 1
+    if rows == "short":
+        mask[4] = 0
+        mask[4, [127, 128, 255, 256, 699]] = 1     # the groups' edges
+    got = np.asarray(sparse_index.positions_from_mask(jnp.asarray(mask),
+                                                      k=k))
+    for r in range(5):
+        held = np.flatnonzero(mask[r])[:k]
+        assert got[r].tolist() == held.tolist() + [c] * (k - len(held))
+
+
+@pytest.mark.parametrize("layer", [0, 1])
+def test_the_rows_route_through_the_kernels_is_the_twins(built, layer):
+    """``dsa.attend_rows`` over a served engine's own pools (three sequences
+    of 41, 5 and 23 cached tokens and a slot with no row; ``topk`` 8):
+    scores and selection through the two kernels in interpret mode give the
+    rows the ``jax.numpy`` twins give."""
+    model, _ = built
+    eng = engine_of(built)
+    for uid, n in enumerate((41, 5, 23)):
+        eng.put([uid], [PROMPT[uid:uid + n]])
+    descs = [eng.seqs[u] for u in range(3)]
+    positions, tables, active, _ = eng._slot_arrays(descs)
+    assert positions[:3].tolist() == [41, 5, 23] and not active[3]
+    lens = jnp.asarray(np.where(active, positions, 0), jnp.int32)
+    cfg = model.config
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    q = jax.random.normal(ks[0], (4, cfg.num_heads, cfg.head_dim))
+    q_i = jax.random.normal(ks[1], (4, cfg.index_heads, cfg.index_head_dim))
+    w = jax.random.normal(ks[2], (4, cfg.index_heads))
+    k_cache, v_cache, idx = eng.kv.pools
+    k_seq = dsa.seq_index_keys(idx, layer, jnp.asarray(tables), 4)
+    out = {impl: np.asarray(dsa.attend_rows(
+        q, q_i, w, k_seq, k_cache, v_cache, layer, jnp.asarray(tables), lens,
+        4, cfg, impl)) for impl in ("xla", "pallas_interpret")}
+    np.testing.assert_allclose(out["pallas_interpret"], out["xla"],
+                               rtol=1e-5, atol=1e-6)
+    assert np.abs(out["xla"][:3]).max() > 0.1 and not out["xla"][3].any()
 
 
 def test_the_ragged_kernel_under_a_selection_is_its_twin():
@@ -296,15 +415,45 @@ def test_the_pool_has_a_third_array_on_the_same_slots(built):
 
 
 @pytest.mark.parametrize("cached,new,want", [
-    (0, 5, (15, 0)),            # rows at 0-4 see 1..5 each
-    (0, 12, (36 + 4 * 8, 0)),   # rows 0-7 see 1..8, four more see topk
-    (20, 6, (48, 0)),           # every row past topk
-    (6, 4, (7 + 8 + 8 + 8, 0)),
-    (5, 1, (0, 6)), (30, 1, (0, 8))])
+    (0, 5, (15, 0, 0)),            # rows at 0-4 see 1..5 each
+    (0, 12, (36 + 4 * 8, 0, 0)),   # rows 0-7 see 1..8, four more see topk
+    (20, 6, (48, 0, 0)),           # every row past topk
+    (6, 4, (7 + 8 + 8 + 8, 0, 0)),
+    # a one-token row: what is kept of its context, and what the scores
+    # (steps of 16) and the selection (chunks of 4) walk for it
+    (5, 1, (0, 6, 16 + 8)), (30, 1, (0, 8, 32 + 32))])
 def test_the_hosts_count_of_what_is_selected(cached, new, want):
     d = SequenceDescriptor(uid=0)
     d.n_cached = cached
-    assert selection_work([d], [new], TOPK) == want
+    assert selection_work([d], [new], TOPK, (16, 4)) == want
+
+
+def test_the_rows_walk_follows_the_longest_row_and_the_route(built):
+    """Three one-token rows at contexts 40, 9 and 17 beside a chunk: each
+    row's context to its scores step, every row the LONGEST's to the
+    selection's chunk; the twins' route walks the whole table a row, twice.
+    An engine says which route each program takes (``dsa_rows`` decisions)
+    and counts by it."""
+    from deepspeedsyclsupport_tpu.monitor import telemetry as tel
+
+    descs = [SequenceDescriptor(uid=u) for u in range(4)]
+    for d, n in zip(descs, (39, 8, 16, 50)):
+        d.n_cached = n
+    lengths = [1, 1, 1, 6]
+    assert selection_work(descs, lengths, TOPK, (16, 32))[2] == \
+        (48 + 16 + 32) + 3 * 64
+    assert selection_work(descs, lengths, TOPK, (64, 64))[2] == 2 * 3 * 64
+    tel.setup_ledger_store.reset()
+    eng = engine_of(built, **ATTN["kernels"])
+    assert eng._dsa_walk == {"ragged_forward": (64, 64),
+                             "decode_forward": (64, 64)}
+    routes = [r for r in tel.setup_ledger() if r["kind"] == "decision"
+              and r["name"] == "dsa_rows"]
+    assert [(r["program"], r["impl"], r["score_keys"]) for r in routes] == [
+        ("ragged_forward", "pallas_interpret", 64),
+        ("decode_forward", "pallas_interpret", 64)]
+    mixed = engine_of(built, decode_attn="pallas_interpret")
+    assert mixed._dsa_walk["ragged_forward"] == (64, 64)   # the whole table
 
 
 def test_a_model_without_an_indexer_keeps_what_it_had():

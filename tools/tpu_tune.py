@@ -774,6 +774,209 @@ def retention(argv=()):
          fits=fits, failed=failed)
 
 
+# ``keye-video-sat``'s indexer: 8 sequences, block tables of 768 x 64 keys,
+# 16 indexer heads of 64, the 2,048 best kept; an atom of 128 rows
+DSA_CELL = dict(seqs=8, table=49152, heads=16, dim=64, topk=2048, atom=128)
+DSA_CONTEXTS = (16384, 32768, 49152)
+# keys a grid step of the scores kernel takes under a one-row tile
+DSA_KEYS = (512, 1024, 2048, 4096, 8192, 16384)
+
+
+def _dsa_operands(rows, seed=0):
+    """``(qI [8, rows, 16, 64], w [8, rows, 16], keys [8, 49152, 64], scores
+    [8, rows, 49152])`` of 8 tiles of ``rows`` rows, one a sequence."""
+    c = DSA_CELL
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    shape = (c["seqs"], rows, c["heads"])
+    q = jax.random.normal(ks[0], shape + (c["dim"],), jnp.bfloat16)
+    w = jax.random.normal(ks[1], shape, jnp.float32)
+    k = jax.jit(lambda key: jax.random.normal(
+        key, (c["seqs"], c["table"], c["dim"]), jnp.bfloat16))(ks[2])
+    scores = jax.jit(lambda key: jax.random.normal(
+        key, (c["seqs"], rows, c["table"]), jnp.float32))(ks[3])
+    return q, w, k, scores
+
+
+def _named(name, fn, tag):
+    """``fn`` as a program called ``name`` that also returns the number
+    ``tag``: two steps that are one computation but for the name (the
+    tree's rule and a candidate; an atom's scores in either module) would
+    share one executable, name and all, and the trace would count both
+    under the first."""
+    def step(*args):
+        return fn(*args), jnp.int32(tag)
+    step.__name__ = name
+    return jax.jit(step)
+
+
+def _dsa_rows_steps(mods, candidates):
+    """{name: (step, the keys a scores step takes while it is traced or
+    None: the rule's)} of the one-token rows' route over ``(qI, w, keys,
+    scores [8, 1, C], lens [8])``: the scores of 8 one-row tiles (the tree's
+    at each keys-a-step candidate, a parent's as it stands), the selection
+    of ONE 8-row tile, the positions out of a mask, and PR 45's route
+    (float32 scores and ``lax.top_k`` in XLA)."""
+    c = DSA_CELL
+    tree = mods["tree"]
+    seq = jnp.arange(c["seqs"])
+    steps = {}
+
+    def scores_of(mod):
+        return lambda q, w, k, s, lens: mod.index_scores_pallas(
+            q, w, k, seq, lens, scale=0.03125)
+
+    for keys in candidates:
+        steps[f"rows_scores_{keys}"] = (scores_of(tree), keys)
+    for tag, mod in mods.items():
+        steps[f"rows_scores_{tag}"] = (scores_of(mod), None)
+    steps["rows_select_tree"] = (
+        lambda q, w, k, s, lens: tree.select_topk_pallas(
+            s.reshape(1, c["seqs"], -1), (lens - 1)[None],
+            jnp.full((1,), c["seqs"]), k=c["topk"]), None)
+    # (about 2,000 of a row's first 16 k set: what a selection leaves)
+    steps["rows_positions_tree"] = (
+        lambda q, w, k, s, lens: tree.positions_from_mask(
+            (s[:, 0] > 1.16) & (jnp.arange(c["table"])[None]
+                                < lens[:, None]), k=c["topk"]), None)
+
+    def old_route(q, w, k, s, lens):
+        scores = tree.index_scores_reference(q, w, k, seq,
+                                             scale=0.03125)[:, 0]
+        seen = jnp.arange(c["table"])[None] < lens[:, None]
+        return jax.lax.top_k(jnp.where(seen, scores, -jnp.inf),
+                             c["topk"])[1]
+    steps["rows_xla_scores_and_top_k"] = (old_route, None)
+    return steps
+
+
+def _dsa_atom_steps(mods):
+    """:func:`_dsa_rows_steps` of the 128-row atom's tile, 8 a call, over
+    ``(qI, w, keys, scores [8, 128, C], hi [8])``: each module's scores and
+    selection kernel."""
+    c = DSA_CELL
+    seq = jnp.arange(c["seqs"])
+    steps = {}
+    for tag, mod in mods.items():
+        steps[f"atom_scores_{tag}"] = (
+            lambda q, w, k, s, hi, mod=mod: mod.index_scores_pallas(
+                q, w, k, seq, hi, scale=0.03125), None)
+        steps[f"atom_select_{tag}"] = (
+            lambda q, w, k, s, hi, mod=mod: mod.select_topk_pallas(
+                s, hi - c["atom"], jnp.full_like(hi, c["atom"]),
+                k=c["topk"]), None)
+    return steps
+
+
+def _dsa_parity(tree):
+    """The tree's kernels against their ``jax.numpy`` twins ON THE CHIP at
+    the cell's shapes: the scores of one-row tiles (worst difference over
+    the twin's largest score; bf16 products summed in another order), the
+    selection of an 8-row tile whose rows have lengths of their own (a dead
+    row, one under ``topk``, ties planted at the k-th value) mask for mask,
+    and the positions against numpy's."""
+    c = DSA_CELL
+    q, w, k, scores = _dsa_operands(1, seed=3)
+    table, topk = c["table"], c["topk"]
+    lens = jnp.asarray([table, 0, topk * 3 // 4, table // 3 + 1,
+                        table * 2 // 3 + 232, topk, table - 2151,
+                        table * 2 // 5][:c["seqs"]], jnp.int32)
+    seq = jnp.arange(c["seqs"])
+    want = jax.jit(lambda: tree.index_scores_reference(
+        q, w, k, seq, scale=0.03125))()
+    got = jax.jit(lambda: tree.index_scores_pallas(
+        q, w, k, seq, lens, scale=0.03125))()
+    seen = np.arange(c["table"])[None] < np.asarray(lens)[:, None]
+    emit("dsa_parity", what="rows_scores", keys_a_step=tree.score_keys(
+        1, c["heads"], c["dim"], 2, c["table"]),
+        worst=float(np.abs(np.where(seen, np.asarray(got - want)[:, 0], 0))
+                    .max()), largest=float(jnp.max(jnp.abs(want))))
+    # a few values only: the k-th is tied in every row
+    tied = jnp.round(scores[:, 0] * 2).clip(-2, 3)
+    for name, s in (("rows_select", scores[:, 0]),
+                    ("rows_select_ties", tied)):
+        args = (s[None], (lens - 1)[None], jnp.full((1,), c["seqs"]))
+        want = np.asarray(jax.jit(lambda: tree.select_topk_reference(
+            *args, k=c["topk"]))())
+        got = jax.jit(lambda: tree.select_topk_pallas(*args,
+                                                      k=c["topk"]))()
+        pos = np.asarray(jax.jit(lambda: tree.positions_from_mask(
+            got[0], k=c["topk"]))())
+        held = [np.flatnonzero(row)[:c["topk"]] for row in want[0]]
+        emit("dsa_parity", what=name,
+             kept=[int(row.sum()) for row in want[0]],
+             mask_differs=int(np.count_nonzero(np.asarray(got) != want)),
+             positions_differ=int(sum(
+                 p.tolist() != h.tolist() + [c["table"]] * (c["topk"]
+                                                            - len(h))
+                 for p, h in zip(pos, held))))
+
+
+def _dsa_sweep(mods, candidates):
+    """:func:`dsa`'s two tables: every step of the rows' route and of the
+    atom's tile compiled once, then traced at each context."""
+    c, tree = DSA_CELL, mods["tree"]
+    for tile, rows, plan in (
+            ("rows", 1, _dsa_rows_steps(mods, candidates)),
+            ("atom", c["atom"], _dsa_atom_steps(mods))):
+        ops = _dsa_operands(rows)
+        lens = jnp.full((c["seqs"],), c["table"], jnp.int32)
+        steps, failed = {}, {}
+        for tag, (name, (step, keys)) in enumerate(plan.items()):
+            kept = tree.score_keys
+            if keys:
+                tree.score_keys = lambda *_a, keys=keys: keys
+            try:
+                steps[name] = _named(name, step, tag).lower(
+                    *ops, lens).compile()
+            except Exception as e:                 # e.g. over the VMEM limit
+                failed[name] = str(e).splitlines()[0][:160]
+            finally:
+                tree.score_keys = kept
+        us, xla = ({name: {} for name in steps} for _ in range(2))
+        for ctx in DSA_CONTEXTS:
+            traced = _traced_kernels(
+                steps, ops + (jnp.full((c["seqs"],), ctx, jnp.int32),),
+                kernel_of=lambda text: "kernel")
+            for name, row in traced.items():
+                us[name][ctx] = round(1e3 * row.get("kernel", 0.0), 2)
+                xla[name][ctx] = round(1e3 * row["xla"], 2)
+        emit("dsa", tile=tile, cell=c, us_a_call=us, xla_us_a_call=xla,
+             failed=failed,
+             keys_a_step={tag: mod.score_keys(rows, c["heads"], c["dim"], 2,
+                                              c["table"])
+                          for tag, mod in mods.items()
+                          if hasattr(mod, "score_keys")})
+
+
+def dsa(argv=()):
+    """The indexer's kernels ALONE at ``keye-video-sat``'s shapes, their
+    device time read off a profiler trace, at contexts of 16 k, 32 k and
+    48 k in every row: us a call of the one-token rows' steps (8 rows: one
+    layer of a forward) and of the atom's two kernels (8 atoms a call), the
+    tree's module beside ``--parent DIR``'s; ``xla_us_a_call``: the XLA
+    operations beside the kernel (a step with no kernel is all there; under
+    a scores kernel it is the copy that lays the keys out for it, which a
+    forward's gather does on the way). ``--keys``: the keys-a-step
+    candidates of the one-row scores (the rule's own choice is the row
+    ``rows_scores_tree``). ``--parity``: the tree's kernels against the
+    twins on the chip."""
+    import argparse
+
+    ap = argparse.ArgumentParser(prog="tpu_tune.py dsa")
+    ap.add_argument("--parent", default=None)
+    ap.add_argument("--keys", type=int, nargs="*", default=list(DSA_KEYS))
+    ap.add_argument("--parity", action="store_true")
+    a = ap.parse_args(list(argv))
+    mods = {"tree": _load_op(os.path.join(os.path.dirname(__file__), ".."),
+                             "sparse_index", "sparse_index_tree")}
+    if a.parent:
+        mods["parent"] = _load_op(a.parent, "sparse_index",
+                                  "sparse_index_parent")
+    if a.parity:
+        _dsa_parity(mods["tree"])
+    _dsa_sweep(mods, a.keys)
+
+
 if __name__ == "__main__":
     which = sys.argv[1] if len(sys.argv) > 1 else "all"
     if which in ("calib", "all"):
@@ -784,3 +987,5 @@ if __name__ == "__main__":
         paged(sys.argv[2:] if which == "paged" else ())
     if which in ("retention", "all"):
         retention(sys.argv[2:] if which == "retention" else ())
+    if which in ("dsa", "all"):
+        dsa(sys.argv[2:] if which == "dsa" else ())

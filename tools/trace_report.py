@@ -719,7 +719,8 @@ def requests_report(root: str, worst_n: int = 5, window_s: float = 60.0,
                      + (f"{'exp-rows':>10}" if share else "")
                      + (f"{'tile-rows':>11}{'tile-fill':>11}{'tiles/expert':>14}"
                         if tiled else "")
-                     + (f"{'sel-pairs':>12}{'1-row-sel':>11}" if dsa else ""))
+                     + (f"{'sel-pairs':>12}{'1-row-sel':>11}{'1-row-walk':>12}"
+                        if dsa else ""))
         for name, m in sorted(rp["programs"].items()):
             lines.append(f"    {name:<20}{m['rounds']:>7}{m['n_seqs']:>8.1f}"
                          f"{m['tokens']:>9.1f}{m['prefill_tokens']:>9.1f}"
@@ -739,6 +740,7 @@ def requests_report(root: str, worst_n: int = 5, window_s: float = 60.0,
                             else "") + (tile_cols(m) if tiled else "")
                          + (f"{m.get('sel_pairs', 0):>12.1f}"
                             f"{m.get('dec_sel_tokens', 0):>11.1f}"
+                            f"{m.get('dec_walk_keys', 0):>12.1f}"
                             if dsa else ""))
     if att["cached_prefix_tokens_mean"]:
         lines.append(f"  cached prefix: "
