@@ -218,8 +218,8 @@ class InferenceEngineV2:
             self.allocator = WindowedAllocator(self._full, BlockedAllocator(
                 self.kv.window_slots // cfg.block_size))
         self.seqs: Dict[int, SequenceDescriptor] = {}
-        # a model with recurrent state (Mamba-2 or power-retention layers):
-        # the free places of the state pool, one a live sequence from its
+        # a model with recurrent state (Mamba-2, power-retention or
+        # delta-rule layers): the free places of the state pool, one a live sequence from its
         # descriptor's making to its flush or eviction (None: the model has
         # no such state)
         self._state_free: Optional[List[int]] = None
@@ -524,17 +524,18 @@ class InferenceEngineV2:
             # whose state they read and wrote (a one-token chunk is one
             # piece, a longer one a piece every state_chunk_size rows),
             # summed over those layers: ssm_* for Mamba-2 layers, ret_* for
-            # power-retention layers, which also say how many of the pieces
-            # start a sequence (they read no state)
+            # power-retention layers and kda_* for delta-rule layers, which
+            # also say how many of the pieces start a sequence (they read
+            # no state)
             mc = self.model.config
             q = mc.state_chunk_size
-            kind = "ret" if mc.retention_degree else "ssm"
+            kind = self.kv.state_kind
             self.round_spans.fields.update({
                 f"{kind}_rows": sum(lengths),
                 f"{kind}_pieces": sum(-(-n // q) for n in lengths)
                 * mc.state_layers})
-            if mc.retention_degree:
-                self.round_spans.fields["ret_first"] = sum(
+            if kind != "ssm":
+                self.round_spans.fields[f"{kind}_first"] = sum(
                     d.n_cached == 0 for d in descs) * mc.state_layers
         if self.kv.exit_pass is not None:
             # a looped stack: the passes the forward runs over its layers,
@@ -580,10 +581,11 @@ class InferenceEngineV2:
                 "exit_pass": np.asarray(self.kv.exit_pass).tolist()}
 
     def state_stats(self) -> Optional[Dict[str, Any]]:
-        """The recurrent state of a model that keeps one, of either kind
+        """The recurrent state of a model that keeps one, of any kind
         (None for any other): ``bytes_per_slot`` (all its state layers:
         Mamba-2's SSM state and convolution tail, power retention's state
-        and normaliser), ``slots``, ``slots_live``, ``dtype``, ``layers``,
+        and normaliser, the delta rule's state and convolution tail),
+        ``slots``, ``slots_live``, ``dtype``, ``layers``,
         ``pool_bytes``."""
         return state_pool_stats(self.kv, sum(
             d.state_slot is not None for d in self.seqs.values()))
@@ -593,7 +595,7 @@ class InferenceEngineV2:
             raise NotImplementedError(
                 f"{what} is not available for a model with recurrent state "
                 f"(ModelConfig.state_layers: Mamba-2 or power-retention "
-                f"layers): it would need {missing}")
+                f"layers, or delta-rule ones): it would need {missing}")
 
     def _new_seq(self, uid: int, **fields) -> SequenceDescriptor:
         """A fresh descriptor in ``seqs``; a model with recurrent state

@@ -62,6 +62,9 @@ SSM_STATE_DTYPE = jnp.float32
 # reason (a sum over a whole context of products that cancel), a constant
 # too.
 RETENTION_STATE_DTYPE = jnp.float32
+# A delta-rule layer's state: float32 for the same reason (every write first
+# subtracts what the state holds for its key), a constant too.
+KDA_STATE_DTYPE = jnp.float32
 
 
 class MoeCounters(NamedTuple):
@@ -98,14 +101,18 @@ class BlockedKV(NamedTuple):
     moe: Optional[MoeCounters] = None
     # a model with recurrent state only (None elsewhere: no leaf, the same
     # program): the state, per state layer (``ModelConfig.state_layers``)
-    # and sequence SLOT, fixed in size whatever the context, of ONE of two
+    # and sequence SLOT, fixed in size whatever the context, of ONE of three
     # kinds (:attr:`state`). Mamba-2 layers (``ModelConfig.layer_pattern``):
     # ``ssm`` [L_m, S + 1, g, n, (h / g) x p] in :data:`SSM_STATE_DTYPE` and
     # ``conv`` [L_m, kernel - 1, S + 1, channels], the convolution's tail,
     # in the pool's dtype (``ops/ssm.py`` has the layout's why).
     # Power-retention layers (``ModelConfig.retention_degree``): ``ret_s``
     # [L, S + 1, KVH, D, features] and ``ret_z`` [L, S + 1, KVH, features]
-    # in :data:`RETENTION_STATE_DTYPE` (``ops/retention.py``). Slot ``S``
+    # in :data:`RETENTION_STATE_DTYPE` (``ops/retention.py``). Delta-rule
+    # layers (``layer_pattern``'s ``K``): ``kda_s`` [L_k, S + 1, heads, dim,
+    # dim] in :data:`KDA_STATE_DTYPE` and ``kda_conv`` [L_k, kernel - 1, S +
+    # 1, 3 x heads x dim], the convolution's tail over q | k | v, in the
+    # pool's dtype (``ops/kda.py``). Slot ``S``
     # is the sink padding rows write to. They ride the forwards as the pool
     # does: donated, in the layer loop's carry, updated in place. Nothing
     # resets a slot: a piece whose first position is 0 starts from zeros.
@@ -113,6 +120,8 @@ class BlockedKV(NamedTuple):
     conv: Optional[jnp.ndarray] = None
     ret_s: Optional[jnp.ndarray] = None
     ret_z: Optional[jnp.ndarray] = None
+    kda_s: Optional[jnp.ndarray] = None
+    kda_conv: Optional[jnp.ndarray] = None
     # a looped stack only (``ModelConfig.total_ut_steps`` > 1; None
     # elsewhere: no leaf, the same program): [passes] int32, the rows the
     # forwards unembedded for a live sequence, by the pass the exit rule
@@ -149,9 +158,16 @@ class BlockedKV(NamedTuple):
                      if getattr(self, pair[0]) is not None), ())
 
     @property
+    def state_kind(self) -> Optional[str]:
+        """``ssm`` | ``ret`` | ``kda`` (None: no recurrent state): what the
+        ``round`` record's counts of the state layers are named by."""
+        names = self.state_names
+        return names[0].split("_")[0] if names else None
+
+    @property
     def state(self):
         """The recurrent-state arrays there are: (ssm, conv), (ret_s,
-        ret_z) or ()."""
+        ret_z), (kda_s, kda_conv) or ()."""
         return tuple(getattr(self, n) for n in self.state_names)
 
     @property
@@ -184,7 +200,7 @@ class BlockedKV(NamedTuple):
 POOL_NAMES = ("k", "v", "idx", "wk", "wv")
 # ... and those that are recurrent state addressed by sequence slot, a pair
 # a kind of state layer; the FIRST of a pair has its slots on axis 1
-STATE_NAMES = (("ssm", "conv"), ("ret_s", "ret_z"))
+STATE_NAMES = (("ssm", "conv"), ("ret_s", "ret_z"), ("kda_s", "kda_conv"))
 
 
 def lane_padded_head_dim(head_dim: int, pad) -> int:
@@ -269,6 +285,20 @@ def init_blocked_kv(model_config, cfg: RaggedInferenceConfig,
             ret_s=jnp.zeros((*lead, mc.head_dim, dim), RETENTION_STATE_DTYPE),
             ret_z=jnp.zeros((*lead, dim), RETENTION_STATE_DTYPE)),
             out_shardings=topology.replicated())()
+    if model_config.pattern_count("K"):
+        mc, lead = model_config, (model_config.pattern_count("K"),
+                                  cfg.max_sequences + 1)
+        h, d = mc.kda_num_heads, mc.kda_head_dim
+        if np.prod(lead) * h * d * d >= 2**31:
+            raise ValueError(
+                f"the delta-rule state [{lead}, {h}, {d}, {d}] passes 2^31 "
+                f"elements, which one array may not: fewer max_sequences "
+                f"or layers")
+        state = jax.jit(lambda: dict(
+            kda_s=jnp.zeros((*lead, h, d, d), KDA_STATE_DTYPE),
+            kda_conv=jnp.zeros((lead[0], mc.kda_conv_kernel - 1, lead[1],
+                                3 * mc.kda_dim), cfg.dtype)),
+            out_shardings=topology.replicated())()
     if model_config.index_topk:
         if cfg.block_size % 2:
             raise ValueError("a sparse-attention indexer's keys lie two "
@@ -291,9 +321,10 @@ def init_blocked_kv(model_config, cfg: RaggedInferenceConfig,
 
 
 def state_pool_stats(kv: BlockedKV, live: int) -> Optional[dict]:
-    """What the recurrent state of a model costs, of either kind (Mamba-2
+    """What the recurrent state of a model costs, of any kind (Mamba-2
     layers' SSM state and convolution tail, power-retention layers' state
-    and normaliser; None for a model without): bytes a sequence slot over
+    and normaliser, delta-rule layers' state and convolution tail; None for
+    a model without): bytes a sequence slot over
     all its state layers, the slots there are (the sink not counted) and
     how many are a sequence's now, the state's dtype and its layers.
     Shape-only, no transfer."""

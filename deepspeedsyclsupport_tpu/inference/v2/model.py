@@ -48,7 +48,11 @@ a stack of two attention kinds (``cfg.attn_period``: windowed layers with
 rotary positions and full layers with none, in a fixed period), which
 :func:`_scan_layers` scans a PERIOD at a time, the period's layers unrolled
 with static kind (:class:`AttnKind`), each kind with a pool, a block table
-and a row index of its own.
+and a row index of its own. And an eighth: the gated delta rule
+(``layer_pattern``'s ``K`` layers, ``ops/kda.py``: :func:`_kda_mixer`), a
+third kind of recurrent state behind the same slots, which
+:func:`_walk_pattern` walks as it walks Mamba-2's, beside attention layers
+whose output is gated (``cfg.attn_out_gate``).
 """
 from contextlib import nullcontext
 from typing import Any, NamedTuple, Optional, Tuple
@@ -606,19 +610,21 @@ register_impl("decode_attn", "pallas_interpret", priority=-10,
 register_impl("decode_attn", "xla", priority=0)(_decode_dispatch("xla"))
 
 
-# ssm_step / ret_step kinds: a state layer's one-token update, Mamba-2's
-# (``ops/ssm.py``) and power retention's (``ops/retention.py``): the in-place
-# Pallas kernel on the TPU, gather/update/scatter elsewhere
+# ssm_step / ret_step / kda_step kinds: a state layer's one-token update,
+# Mamba-2's (``ops/ssm.py``), power retention's (``ops/retention.py``) and
+# the delta rule's (``ops/kda.py``): the in-place Pallas kernel on the TPU,
+# gather/update/scatter elsewhere
 def _state_dispatch(kind, impl_name):
     def fn(*args):
-        from ...ops import retention, ssm
+        from ...ops import kda, retention, ssm
 
-        steps = {"ssm_step": ssm, "ret_step": retention}[kind].STATE_STEPS
+        steps = {"ssm_step": ssm, "ret_step": retention,
+                 "kda_step": kda}[kind].STATE_STEPS
         return steps[impl_name](*args)
     return fn
 
 
-for _kind in ("ssm_step", "ret_step"):
+for _kind in ("ssm_step", "ret_step", "kda_step"):
     register_impl(_kind, "pallas", priority=10,
                   auto_eligible=lambda c: c.get("backend") == "tpu")(
         _state_dispatch(_kind, "pallas"))
@@ -640,6 +646,10 @@ def _ssm_step_fn():
 
 def _ret_step_fn():
     return _state_step_fn("ret_step")
+
+
+def _kda_step_fn():
+    return _state_step_fn("kda_step")
 
 
 def _retention_rows(p, y, cfg, positions):
@@ -901,19 +911,62 @@ def _mamba_mixer(cfg, p, x, ssm_fn):
         return (x + u.astype(x.dtype) @ p["out_proj"]).astype(x.dtype)
 
 
-def _walk_pattern(cfg, params, x, kv: BlockedKV, attend, ssm_step, live):
+def _kda_mixer(cfg, p, x, conv_fn, scan_fn):
+    """One gated delta-rule layer over flat tokens x [T, d]: ``qkv_proj`` to
+    ``[q | k | v]`` and the depthwise convolution over them (``conv_fn(p,
+    qkv) -> [T, 3 x heads x dim]`` float32, against the tail in the state
+    pool); an L2 norm a head on q (scaled) and k; the log-decay a key
+    channel ``-exp(A_log) softplus(y f_a f_b + dt_bias)`` and ``beta =
+    kda_beta_scale sigmoid(y b_proj)``; the recurrence (``scan_fn(q, k, v,
+    g, beta) -> [T, heads, dim]`` float32, against the state); an RMS norm
+    a head times the output gate ``sigmoid(y g_a g_b)``; ``o_proj``."""
+    from ...ops.kda import l2norm
+
+    f32 = jnp.float32
+    n, h, d = x.shape[0], cfg.kda_num_heads, cfg.kda_head_dim
+    heads = lambda t: t.astype(f32).reshape(n, h, d)         # noqa: E731
+    y = norm(x, p["norm"], cfg)
+    with scope("kda_proj"):
+        qkv = y @ p["qkv_proj"]
+        decay = (y @ p["f_a"]) @ p["f_b"]
+        gate = (y @ p["g_a"]) @ p["g_b"]
+        beta = y @ p["b_proj"]
+    with scope("kda_conv"):
+        qkv = conv_fn(p, qkv)
+    with scope("kda_gate"):
+        q, k, v = (heads(qkv[:, i * h * d:(i + 1) * h * d])
+                   for i in range(3))
+        q, k = l2norm(q) * d ** -0.5, l2norm(k)
+        g = -jnp.exp(p["A_log"].astype(f32))[:, None] * jax.nn.softplus(
+            heads(decay) + p["dt_bias"].astype(f32).reshape(h, d))
+        beta = cfg.kda_beta_scale * jax.nn.sigmoid(beta.astype(f32))
+    with scope("kda_scan"):
+        out = scan_fn(q, k, v, g, beta)
+    with scope("kda_gate"):
+        out = out * jax.lax.rsqrt(
+            jnp.mean(jnp.square(out), -1, keepdims=True) + cfg.rms_norm_eps) \
+            * p["o_norm"]["scale"].astype(f32) * jax.nn.sigmoid(heads(gate))
+    with scope("kda_proj"):
+        return (x + out.reshape(n, -1).astype(x.dtype) @ p["o_proj"]
+                ).astype(x.dtype)
+
+
+def _walk_pattern(cfg, params, x, kv: BlockedKV, attend, ssm_step, live,
+                  kda=None):
     """The layer loop of both serving forwards for a ``cfg.layer_pattern``
     model: :func:`layer_plan`'s runs, each kind of layer indexing ITS stack
     of parameters and ITS cache (``attn_layers`` and the KV pool for ``*``,
-    ``mamba_layers`` and the recurrent state for ``M``, ``layers`` and the
-    expert counters for ``E``). As in :func:`_scan_layers` the pools ride as
-    carry and the routed experts' matrices stay closed over; the other
-    leaves are read at the layer's (traced) index inside the loop body,
-    which is what a scan's xs are.
+    ``mamba_layers`` or ``kda_layers`` and the recurrent state for ``M`` or
+    ``K``, ``layers`` and the expert counters for ``E``). As in
+    :func:`_scan_layers` the pools ride as carry and the routed experts'
+    matrices stay closed over; the other leaves are read at the layer's
+    (traced) index inside the loop body, which is what a scan's xs are.
 
-    ``attend(p_attn, y, pools, l) -> (rows [T, H, D], pools)`` and
-    ``ssm_step(p, xbc, dt, state, l) -> (y [T, d_inner], state)`` are the
-    forward's own. Every layer is ``x + mixer(norm(x))``."""
+    ``attend(p_attn, y, pools, l) -> (rows [T, H, D], pools)``,
+    ``ssm_step(p, xbc, dt, state, l) -> (y [T, d_inner], state)`` and
+    ``kda`` = ``(conv(p, qkv, state, l) -> (out, state), scan(q, k, v, g,
+    beta, state, l) -> (out, state))`` are the forward's own. Every layer is
+    ``x + mixer(norm(x))``."""
     layers, stack = _experts_in_place(params.get("layers", {}), x.dtype)
     at = lambda tree, j: jax.tree_util.tree_map(  # noqa: E731
         lambda a: a[j], tree)
@@ -928,10 +981,30 @@ def _walk_pattern(cfg, params, x, kv: BlockedKV, attend, ssm_step, live):
                 return y
 
             x = _mamba_mixer(cfg, at(params["mamba_layers"], j), x, ssm_fn)
+        elif kind == "K":
+            def conv_fn(p, qkv):
+                nonlocal state
+                out, state = kda[0](p, qkv, state, j)
+                return out
+
+            def scan_fn(*rows):
+                nonlocal state
+                out, state = kda[1](*rows, state, j)
+                return out
+
+            x = _kda_mixer(cfg, at(params["kda_layers"], j), x, conv_fn,
+                           scan_fn)
         elif kind == "*":
             p = at(params["attn_layers"], j)
-            rows_attn, pools = attend(p["attn"], norm(x, p["attn_norm"], cfg),
-                                      pools, j)
+            y = norm(x, p["attn_norm"], cfg)
+            rows_attn, pools = attend(p["attn"], y, pools, j)
+            if cfg.attn_out_gate:
+                with scope("attn_gate"):
+                    open_ = jax.nn.sigmoid(
+                        (y @ p["attn"]["w_g"]).astype(jnp.float32))
+                    rows_attn = (rows_attn.astype(jnp.float32)
+                                 * open_.reshape(rows_attn.shape)
+                                 ).astype(rows_attn.dtype)
             x = (x + _attn_out(p["attn"], rows_attn, cfg, x.shape[0])
                  ).astype(x.dtype)
         else:
@@ -941,7 +1014,7 @@ def _walk_pattern(cfg, params, x, kv: BlockedKV, attend, ssm_step, live):
             x = (x + m).astype(x.dtype)
         return (x, pools, state), rows
 
-    carry, done, routed = (x, kv.pools, kv.state), dict.fromkeys("ME*", 0), []
+    carry, done, routed = (x, kv.pools, kv.state), dict.fromkeys("MKE*", 0), []
     for unit, reps in layer_plan(cfg.layer_pattern):
         per = {kind: unit.count(kind) for kind in done}
 
@@ -1128,6 +1201,41 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
         y = y.at[jnp.where(one, ssm.dec_row, t)].set(y_dec, mode="drop")
         return y, tuple(state)
 
+    def kda_conv(p, qkv, state, l):
+        """Delta-rule layer ``l``'s convolution over the flat batch, as
+        :func:`ssm_step` splits it: the pieces, then the one-token rows."""
+        from ...ops.ssm import conv_pieces, conv_step
+
+        w = p["conv_w"].astype(jnp.float32)
+        out, conv = conv_pieces(
+            qkv, w, None, state[1], l,
+            (ssm.row0, ssm.length, ssm.slot, ssm.fresh, ssm.count),
+            cfg.kda_chunk_size)
+        one = ssm.dec_len > 0
+        out_dec, conv = conv_step(
+            qkv[ssm.dec_row], w, None, conv, l,
+            jnp.where(one, ssm.seq_slot, conv.shape[2] - 1),
+            ssm.dec_len != 1)
+        out = out.at[jnp.where(one, ssm.dec_row, t)].set(out_dec,
+                                                         mode="drop")
+        return out, (state[0], conv)
+
+    def kda_scan(q, k, v, g, beta, state, l):
+        """... and its recurrence: the pieces through the chunked form, the
+        one-token rows through the decode step, each from ITS slot."""
+        from ...ops.kda import chunked, decode_step
+
+        out, pool = chunked(
+            q, k, v, g, beta, state[0], l,
+            (ssm.row0, ssm.length, ssm.slot, ssm.fresh, ssm.count), cfg)
+        one, at = ssm.dec_len > 0, ssm.dec_row
+        out_dec, pool = decode_step(
+            q[at], k[at], v[at], g[at], beta[at], pool, l,
+            jnp.where(one, ssm.seq_slot, pool.shape[1] - 1),
+            ssm.dec_len == 1, cfg, _kda_step_fn())
+        out = out.at[jnp.where(one, at, t)].set(out_dec, mode="drop")
+        return out, (pool, state[1])
+
     if cfg.total_ut_steps > 1:
         # a slot's row is a sequence's where the batch has a chunk of it
         h_last, kv = _scan_passes(
@@ -1135,7 +1243,8 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
             token_seq[last_tok_idx] == jnp.arange(s))
         return _unembed(params, h_last, cfg).astype(jnp.float32), kv
     if cfg.layer_pattern is not None:
-        x, kv = _walk_pattern(cfg, params, x, kv, attend, ssm_step, ~pad)
+        x, kv = _walk_pattern(cfg, params, x, kv, attend, ssm_step, ~pad,
+                              (kda_conv, kda_scan))
     else:
         x, kv = _scan_layers(layer, x, kv, params, cfg)
 
@@ -1261,11 +1370,30 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
             positions == 0, cfg, _ssm_step_fn())
         return y, tuple(state)
 
+    def kda_conv(p, qkv, state, l):
+        from ...ops.ssm import conv_step
+
+        out, conv = conv_step(
+            qkv, p["conv_w"].astype(jnp.float32), None, state[1], l,
+            jnp.where(active, state_slot, state[1].shape[2] - 1),
+            positions != 0)
+        return out, (state[0], conv)
+
+    def kda_scan(q, k, v, g, beta, state, l):
+        from ...ops.kda import decode_step
+
+        out, pool = decode_step(
+            q, k, v, g, beta, state[0], l,
+            jnp.where(active, state_slot, state[0].shape[1] - 1),
+            positions == 0, cfg, _kda_step_fn())
+        return out, (pool, state[1])
+
     if cfg.total_ut_steps > 1:
         x, kv = _scan_passes(layer, x, kv, params, cfg, lambda x: x, active)
         return _unembed(params, x, cfg).astype(jnp.float32), kv
     if cfg.layer_pattern is not None:
-        x, kv = _walk_pattern(cfg, params, x, kv, attend, ssm_step, active)
+        x, kv = _walk_pattern(cfg, params, x, kv, attend, ssm_step, active,
+                              (kda_conv, kda_scan))
     else:
         x, kv = _scan_layers(layer, x, kv, params, cfg)
     x = _final_norm(params, x, cfg)

@@ -25,6 +25,7 @@ policy with a synthetic clock and capacity model. See ``docs/serving.md``
 for the overload-behavior contract and config keys.
 """
 import contextlib
+import heapq
 import math
 import os
 import time
@@ -366,11 +367,14 @@ class ServingSession:
         # wall stamps: every record rides ONE clock base, so the offline
         # join can order router and replica streams together.
         self._tracing = bool(self.policy.trace_stages)
-        # a record a token, six a request, one a round: 65,536 held under a
-        # minute at 1,150 tokens/s, and a window whose oldest rounds were
-        # pushed out gives the round readers nothing (benchmark/spans.py)
+        # a record a token and six a request: 65,536 held under a minute at
+        # 1,150 tokens/s. The one record a ROUND has a ring of its own: at
+        # 256 live a round leaves 257 records, the stream's ring turns over
+        # in ~1,000 rounds, and a window whose oldest rounds were pushed
+        # out gives the round readers nothing (benchmark/spans.py)
         self.trace_log: deque = deque(maxlen=262144)
-        self.trace_dropped = 0     # records the full ring pushed out
+        self.round_log: deque = deque(maxlen=32768)
+        self.trace_dropped = 0     # records a full ring pushed out
         self._round_spans = RoundSpans(clock) if self._tracing else None
         self._spans: Optional[RoundSpans] = None   # the running round's
         self._wall0 = time.time() - self.clock()  # dslint: allow(wall-clock-in-step-path)
@@ -434,14 +438,16 @@ class ServingSession:
             self.journal.close()
 
     # ----------------------------------------------- request-time attribution
-    def _trace(self, name: str, t: float, data: Dict[str, Any]) -> None:
+    def _trace(self, name: str, t: float, data: Dict[str, Any],
+               ring: Optional[deque] = None) -> None:
         """Mirror one lifecycle record (journal-record shape) into the
-        in-memory ring, stamped on the session-clock→wall mapping."""
+        in-memory ring (``ring``: the rounds'), stamped on the
+        session-clock→wall mapping."""
         if self._tracing:
-            if len(self.trace_log) == self.trace_log.maxlen:
+            ring = self.trace_log if ring is None else ring
+            if len(ring) == ring.maxlen:
                 self.trace_dropped += 1
-            self.trace_log.append(
-                {"name": name, "t": t + self._wall0, "data": data})
+            ring.append({"name": name, "t": t + self._wall0, "data": data})
 
     def _stage(self, uid: int, stage: str, t: float,
                dur: Optional[float] = None, **data: Any) -> None:
@@ -452,7 +458,8 @@ class ServingSession:
             return
         self._trace("serve/stage", t, {
             "uid": int(uid), "stage": stage,
-            **({"dur": float(dur)} if dur is not None else {}), **data})
+            **({"dur": float(dur)} if dur is not None else {}), **data},
+            self.round_log if stage == "round" else None)
         if self.journal is not None:
             self.journal.stage(uid, stage, dur=dur, **data)
 
@@ -483,11 +490,14 @@ class ServingSession:
             **spans.fields, "phases": dict(spans.phases)})
 
     def drain_trace(self) -> List[Dict[str, Any]]:
-        """Hand over and clear the in-memory lifecycle records — the
-        benchmark harness drains once per window so the waterfall joins only
-        that window's requests."""
-        out = list(self.trace_log)
+        """Hand over and clear the in-memory lifecycle records, both rings
+        as one stream in the order of ``t`` — the benchmark harness drains
+        once per window so the waterfall joins only that window's
+        requests."""
+        out = list(heapq.merge(self.trace_log, self.round_log,
+                               key=lambda rec: rec["t"]))
         self.trace_log.clear()
+        self.round_log.clear()
         return out
 
     def export_metrics(self, path: str) -> Optional[str]:

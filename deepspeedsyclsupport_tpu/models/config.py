@@ -157,14 +157,41 @@ class ModelConfig:
     # manifold-constrained hyper-connections: hc_mult residual streams,
     # mixed by a Sinkhorn-normalised matrix in every sublayer (1 = the
     # plain x + f(norm(x)) stream)
-    # A hybrid stack (nemotron_h): one character a layer, each layer ONE
-    # mixer behind one norm: ``M`` a Mamba-2 mixer, ``E`` sparse experts
-    # (two-matrix, ``mlp_type="mlp"``), ``*`` attention. None: the uniform
-    # attention + MLP block. Three stacks of parameters (``mamba_layers``,
-    # ``layers``, ``attn_layers``), a KV pool with a row for the ``*`` layers
-    # only and a recurrent state per sequence slot for the ``M`` layers
-    # (inference/v2/kv_cache.py). Serving only.
+    # A hybrid stack (nemotron_h, solar_open2): one character a layer, each
+    # layer ONE mixer behind one norm: ``M`` a Mamba-2 mixer, ``K`` a gated
+    # delta-rule mixer (below), ``E`` sparse experts (nemotron_h's two-matrix,
+    # ``mlp_type="mlp"``; solar_open2's gated), ``*`` attention. None: the
+    # uniform attention + MLP block. A stack of parameters a kind
+    # (``mamba_layers``, ``kda_layers``, ``layers``, ``attn_layers``), a KV
+    # pool with a row for the ``*`` layers only and a recurrent state per
+    # sequence slot for the ``M`` or the ``K`` layers
+    # (inference/v2/kv_cache.py). A published layer that is a mixer AND an
+    # expert block (solar_open2) is two characters here, so num_layers
+    # counts characters. Serving only.
     layer_pattern: Optional[str] = None
+    # The gated delta rule with a decay per key channel (Kimi delta
+    # attention; ops/kda.py), the ``K`` layers of a layer_pattern:
+    # kda_num_heads heads (0: none) of kda_head_dim for key and value alike,
+    # behind ONE depthwise convolution of kda_conv_kernel over q | k | v;
+    # the decay and the output gate come through pairs of projections of
+    # rank kda_gate_rank (solar_open2: kda_use_full_proj false, the rank a
+    # head's width); the write's strength is kda_beta_scale x sigmoid, 2
+    # where the transition may have negative eigenvalues
+    # (kda_allow_neg_eigval). Every ``K`` layer keeps, per sequence, a
+    # float32 state [heads, head_dim, head_dim] and the convolution's tail.
+    # kda_chunk_size: rows of a piece of the chunked form, as
+    # ssm_chunk_size is Mamba-2's. A and dt_bias are drawn as Mamba-2's
+    # (time_step_min .. time_step_max below).
+    kda_num_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv_kernel: int = 4
+    kda_gate_rank: int = 0
+    kda_beta_scale: float = 1.0
+    kda_chunk_size: int = 64
+    # softmax attention's output is multiplied by sigmoid(x Wg), elementwise
+    # over all q_dim values, from the row the queries read (solar_open2's
+    # use_gqa_gate; the ``*`` layers of a layer_pattern)
+    attn_out_gate: bool = False
     # Mamba-2 sizes, under the names nemotron_h publishes: d_inner is
     # mamba_num_heads x mamba_head_dim (not an expansion of hidden_size);
     # B and C come in ssm_n_groups groups of ssm_state_size
@@ -314,6 +341,10 @@ class ModelConfig:
                 "stacked (scan_layers)")
         if self.layer_pattern is not None:
             self._check_pattern()
+        elif self.attn_out_gate or self.kda_num_heads:
+            raise ValueError(
+                "attn_out_gate and kda_num_heads belong to a layer_pattern "
+                "('*' and 'K' layers): the uniform block has neither")
         if self.total_ut_steps < 1:
             raise ValueError(f"total_ut_steps {self.total_ut_steps} < 1")
         if self.total_ut_steps > 1 or self.sandwich_norm:
@@ -331,10 +362,18 @@ class ModelConfig:
 
     def _check_pattern(self):
         pat = self.layer_pattern
-        if set(pat) - set("ME*") or len(pat) != self.num_layers:
+        if set(pat) - set("MKE*") or len(pat) != self.num_layers:
             raise ValueError(
                 f"layer_pattern {pat!r}: {self.num_layers} characters of "
-                f"'M' (Mamba-2), 'E' (experts) and '*' (attention) wanted")
+                f"'M' (Mamba-2), 'K' (gated delta rule), 'E' (experts) and "
+                f"'*' (attention) wanted")
+        if "K" in pat and ("M" in pat or not (
+                self.kda_num_heads and self.kda_head_dim
+                and self.kda_gate_rank and self.kda_conv_kernel > 1)):
+            raise ValueError(
+                "layer_pattern: 'K' layers need kda_num_heads, kda_head_dim, "
+                "kda_gate_rank and a kda_conv_kernel of 2 or more, and no "
+                "'M' layer beside them (ONE kind of recurrent state a model)")
         if ("E" in pat) != self.any_moe:
             raise ValueError("layer_pattern: 'E' layers need num_experts, "
                              "and num_experts needs an 'E' layer")
@@ -354,6 +393,12 @@ class ModelConfig:
                 "latent attention, hyper-connection streams, parallel "
                 "block, leading dense layers, per-layer windows or period "
                 "of attention kinds")
+        if self.attn_out_gate and (self.index_topk or self.qkv_bias
+                                   or self.attn_out_bias):
+            raise ValueError(
+                "attn_out_gate: the output gate is written for the plain "
+                "K-and-V attention of a layer_pattern's '*' layers: no "
+                "indexer and no attention bias")
 
     def _check_period(self):
         """``attn_period`` as tuples, and what a period of attention kinds
@@ -443,7 +488,7 @@ class ModelConfig:
                 "of sequential blocks; not written for: " + ", ".join(wrong))
 
     def pattern_count(self, kind: str) -> int:
-        """Layers of ``kind`` ('M', 'E', '*') in ``layer_pattern``."""
+        """Layers of ``kind`` ('M', 'K', 'E', '*') in ``layer_pattern``."""
         return (self.layer_pattern or "").count(kind)
 
     @property
@@ -463,16 +508,24 @@ class ModelConfig:
     @property
     def state_layers(self) -> int:
         """Layers that keep a recurrent state per sequence (0: none): a
-        ``layer_pattern``'s Mamba-2 layers, or every layer of a power-
-        retention stack."""
+        ``layer_pattern``'s Mamba-2 or delta-rule layers, or every layer of
+        a power-retention stack."""
         return self.num_layers if self.retention_degree \
-            else self.pattern_count("M")
+            else self.pattern_count("M") + self.pattern_count("K")
 
     @property
     def state_chunk_size(self) -> int:
         """Rows of a piece of the state layers' chunked form."""
+        if self.pattern_count("K"):
+            return self.kda_chunk_size
         return self.retention_chunk_size if self.retention_degree \
             else self.ssm_chunk_size
+
+    @property
+    def kda_dim(self) -> int:
+        """Width of each of a delta-rule layer's q, k and v: heads x
+        head_dim (the convolution runs over three of them)."""
+        return self.kda_num_heads * self.kda_head_dim
 
     @property
     def ssm_d_inner(self) -> int:
@@ -591,7 +644,14 @@ class ModelConfig:
             mamba = (d * (di + self.ssm_conv_dim + h) + di * d
                      + self.ssm_conv_dim * (self.ssm_conv_kernel + 1)
                      + 3 * h + di + d)
+            dk, r = self.kda_dim, self.kda_gate_rank
+            kda = (4 * d * dk + 2 * (d * r + r * dk) + d * self.kda_num_heads
+                   + 3 * dk * self.kda_conv_kernel + self.kda_num_heads
+                   + dk + self.kda_head_dim + d)
+            if self.attn_out_gate:
+                attn += d * self.q_dim
             return (mamba * self.pattern_count("M")
+                    + kda * self.pattern_count("K")
                     + (moe + d) * self.pattern_count("E")
                     + (attn + d) * self.pattern_count("*")
                     + v * d * (1 if self.tie_embeddings else 2) + d)
@@ -847,6 +907,31 @@ PRESETS = {
         # as deepseek-v2's: how much of a logit the seeded routed experts
         # carry beside the shared ones, so that parity can see them
         routed_write_share=0.05),
+    # upstage/Solar-Open2-250B (model_type solar_open2, 250B-A15B): 48
+    # published layers, each a mixer AND an expert block, so 96 characters
+    # here: 12 layers of GQA 64/8 x 128 with NO positional term and an
+    # output gate (gqa_layers 0, 4, ..., 44: ``*E``), 36 of the gated delta
+    # rule (64 heads of 128, conv 4, a decay per key channel and an output
+    # gate through rank-128 pairs, beta up to 2: ``KE``), every one followed
+    # by 320 SwiGLU experts of 1280, top-8 by sigmoid scores with a
+    # selection bias, renormalised, x 1, beside one shared expert;
+    # intermediate_size 10240 is the width of a dense MLP no layer has
+    # (first_k_dense_replace 0). Serving only (inference/v2, ops/kda.py); a
+    # chip of an expert-parallel deployment overrides num_experts_held.
+    "solar-open2": _p(
+        vocab_size=196608, hidden_size=4096, intermediate_size=10240,
+        num_layers=96, num_heads=64, num_kv_heads=8, head_dim=128,
+        max_seq_len=1048576, rms_norm_eps=1e-5, pos_embed="none",
+        layer_pattern="*EKEKEKE" * 12, attn_out_gate=True,
+        kda_num_heads=64, kda_head_dim=128, kda_conv_kernel=4,
+        kda_gate_rank=128, kda_beta_scale=2.0, kda_chunk_size=64,
+        num_experts=320, num_experts_per_tok=8, moe_intermediate_size=1280,
+        n_shared_experts=1, scoring_func="sigmoid", topk_method="noaux_tc",
+        norm_topk_prob=True, routed_scaling_factor=1.0,
+        # as deepseek-v2's: how much of a logit the seeded routed experts
+        # carry beside the shared one, so that parity can see them
+        # (benchmark/configs/solar-open2-ep8-d4.json, assumed)
+        routed_write_share=0.075),
 }
 
 

@@ -186,6 +186,9 @@ class CausalLM:
                 keep = jnp.exp2(-1.0 / life)
                 attn.update(g_proj=dense((d, cfg.num_kv_heads), next(ks)),
                             g_bias=jnp.log(keep) - jnp.log1p(-keep))
+            if cfg.attn_out_gate:
+                # the output gate: sigmoid(y w_g) over all q_dim values
+                attn["w_g"] = dense((d, q), next(ks))
             if cfg.index_topk:
                 # the sparse-attention indexer: index_heads small query
                 # heads, ONE key a token (behind a LayerNorm) and a weight
@@ -210,6 +213,17 @@ class CausalLM:
             return {"fc1": dense((d, f), next(ks)),
                     "fc2": dense((f, d), next(ks), scale=down_scale(f))}
 
+        def step_bias(key, n):
+            """``n`` steps log-uniform in ``time_step_min..max`` (floored at
+            ``time_step_floor``), kept as the inverse softplus: Mamba-2's
+            published draw of ``dt_bias``, the delta rule's too."""
+            dt = jnp.exp(jax.random.uniform(key, (n,), jnp.float32)
+                         * (np.log(cfg.time_step_max)
+                            - np.log(cfg.time_step_min))
+                         + np.log(cfg.time_step_min))
+            dt = jnp.maximum(dt, cfg.time_step_floor)
+            return dt + jnp.log(-jnp.expm1(-dt))
+
         def mamba_params(key) -> Params:
             """One Mamba-2 mixer behind its norm: ``in_proj`` to ``[z | xBC
             | dt]``, the depthwise convolution ``[kernel, channels]`` with
@@ -220,11 +234,7 @@ class CausalLM:
             d, di, h = cfg.hidden_size, cfg.ssm_d_inner, cfg.mamba_num_heads
             c, kw = cfg.ssm_conv_dim, cfg.ssm_conv_kernel
             bound = 1.0 / np.sqrt(kw)    # a depthwise conv's fan-in
-            dt = jnp.exp(jax.random.uniform(next(ks), (h,), jnp.float32)
-                         * (np.log(cfg.time_step_max)
-                            - np.log(cfg.time_step_min))
-                         + np.log(cfg.time_step_min))
-            dt = jnp.maximum(dt, cfg.time_step_floor)
+            dt_bias = step_bias(next(ks), h)
             return {
                 "norm": norm_params(),
                 "in_proj": dense((d, di + c + h), next(ks)),
@@ -234,10 +244,43 @@ class CausalLM:
                                              -bound, bound),
                 "A_log": jnp.log(jax.random.uniform(
                     next(ks), (h,), jnp.float32, 1.0, 16.0)),
-                "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+                "dt_bias": dt_bias,
                 "D": jnp.ones((h,), jnp.float32),
                 "gate_norm": {"scale": jnp.ones((di,), jnp.float32)},
                 "out_proj": dense((di, d), next(ks), scale=down_scale(di)),
+            }
+
+        def kda_params(key) -> Params:
+            """One gated delta-rule mixer behind its norm (``ops/kda.py``):
+            ``qkv_proj`` to ``[q | k | v]``, ONE depthwise convolution
+            ``[kernel, 3 x heads x dim]`` over them (no bias); the decay's
+            pair ``f_a`` / ``f_b`` with ``A_log`` a head and ``dt_bias`` a
+            key channel, drawn as Mamba-2's (A uniform in 1..16, the step
+            log-uniform in ``time_step_min..max``, kept as the inverse
+            softplus: half-lives from under a token to some hundreds);
+            ``b_proj`` the write's strength a head; the output gate's pair
+            ``g_a`` / ``g_b``, the norm a head ``o_norm`` and ``o_proj``."""
+            ks = iter(jax.random.split(key, 12))
+            d, h, dk = cfg.hidden_size, cfg.kda_num_heads, cfg.kda_dim
+            r, kw = cfg.kda_gate_rank, cfg.kda_conv_kernel
+            bound = 1.0 / np.sqrt(kw)    # a depthwise conv's fan-in
+            dt_bias = step_bias(next(ks), dk)
+            return {
+                "norm": norm_params(),
+                "qkv_proj": dense((d, 3 * dk), next(ks)),
+                "conv_w": jax.random.uniform(next(ks), (kw, 3 * dk),
+                                             jnp.float32, -bound, bound),
+                "f_a": dense((d, r), next(ks)),
+                "f_b": dense((r, dk), next(ks)),
+                "A_log": jnp.log(jax.random.uniform(
+                    next(ks), (h,), jnp.float32, 1.0, 16.0)),
+                "dt_bias": dt_bias,
+                "b_proj": dense((d, h), next(ks)),
+                "g_a": dense((d, r), next(ks)),
+                "g_b": dense((r, dk), next(ks)),
+                "o_norm": {"scale": jnp.ones((cfg.kda_head_dim,),
+                                             jnp.float32)},
+                "o_proj": dense((dk, d), next(ks), scale=down_scale(dk)),
             }
 
         def hc_params(key) -> Params:
@@ -326,7 +369,7 @@ class CausalLM:
         n_dense = cfg.first_k_dense_replace
         stacks: Params = {}
         if cfg.layer_pattern is not None:
-            # three stacks, each in the order its layers come in the pattern
+            # a stack a kind, each in the order its layers come in the pattern
             lkeys = jax.random.split(next(keys), cfg.num_layers)
             of = lambda kind: lkeys[np.asarray(  # noqa: E731
                 [i for i, c in enumerate(cfg.layer_pattern) if c == kind],
@@ -335,6 +378,8 @@ class CausalLM:
                 if cfg.pattern_count("E") else {}
             if cfg.pattern_count("M"):
                 stacks["mamba_layers"] = jax.vmap(mamba_params)(of("M"))
+            if cfg.pattern_count("K"):
+                stacks["kda_layers"] = jax.vmap(kda_params)(of("K"))
             if cfg.pattern_count("*"):
                 stacks["attn_layers"] = jax.vmap(
                     lambda k: layer_params(k, kind="*"))(of("*"))
@@ -488,10 +533,10 @@ class CausalLM:
                 "are not written")
         if cfg.layer_pattern is not None:
             raise NotImplementedError(
-                "a layer_pattern model (Mamba-2, expert and attention layers "
-                "in one stack) runs on the serving path only "
-                "(inference/v2/model.py): the chunked scan's backward is not "
-                "written")
+                "a layer_pattern model (Mamba-2, gated delta-rule, expert "
+                "and attention layers in one stack) runs on the serving "
+                "path only (inference/v2/model.py): the chunked scan's "
+                "backward is not written")
         if cfg.retention_degree:
             raise NotImplementedError(
                 "a power-retention model (retention_degree: a gated state "

@@ -79,6 +79,15 @@ SUB_SCOPES = ("moe_route", "moe_experts", "moe_combine", "moe_shared",
               # ret_scan, the chunked form's pieces apart from the
               # one-token rows' state step beside them
               "ret_proj", "ret_gate", "ret_scan", "ret_chunk",
+              # a gated delta-rule mixer (inference/v2/model.py,
+              # ops/kda.py): the projections in and out; the depthwise
+              # convolution and its tail; the decay, beta, the L2 norms and
+              # the gated output norm; the recurrence, INSIDE which kda_step
+              # is the one-token rows' state step and kda_chunk the chunked
+              # form's pieces; and the output gate of the softmax attention
+              # layers beside them (ModelConfig.attn_out_gate)
+              "kda_proj", "kda_conv", "kda_gate", "kda_scan", "kda_step",
+              "kda_chunk", "attn_gate",
               # a stack of two attention kinds (ModelConfig.attn_period):
               # a windowed layer's and a full layer's q, k, v, rotary, pool
               # write and attention, so a profile tells the kinds apart

@@ -56,6 +56,73 @@ def _conv_weights(p):
             p["conv_b"].astype(jnp.float32))
 
 
+# ------------------------------------------------- the depthwise convolution
+# ONE causal depthwise convolution with its tail in the pool, for every
+# mixer that has one: Mamba-2's ``xBC`` here and the delta rule's q | k | v
+# (``ops/kda.py``), which has no bias (``bias`` None). ``w`` [kernel,
+# channels] float32; ``conv`` the pool ``[layers, kernel - 1, slots + 1,
+# channels]``; the caller names the scope.
+def conv_step(x, w, bias, conv, layer, slots, keep):
+    """One token for each row: ``x`` [rows, channels] behind the tail of
+    its slot (zeros where ``keep`` [rows] is false), the tail shifted one on.
+    -> ``(silu of the convolution [rows, channels] float32, conv)``."""
+    k = w.shape[0]
+    # the window's rows, oldest first: the tail's k - 1, then the token
+    win = [jnp.where(keep[:, None], conv[layer, j, slots], 0)
+           for j in range(k - 1)] + [x.astype(conv.dtype)]
+    acc = sum(w[j] * win[j].astype(jnp.float32) for j in range(k))
+    out = jax.nn.silu(acc if bias is None else bias + acc)
+    for j in range(k - 1):
+        conv = conv.at[layer, j, slots].set(win[j + 1])
+    return out, conv
+
+
+def conv_piece(rows, w, bias, conv, layer, slot, keep, n):
+    """One piece of ONE sequence: ``rows`` [q, channels], zero beyond its
+    ``n`` rows, behind the tail of ``slot`` (zeros where ``keep`` is false);
+    the tail left is the last ``kernel - 1`` inputs behind row ``n``. ->
+    ``(silu of the convolution [q, channels] float32, conv)``."""
+    q, k = rows.shape[0], w.shape[0]
+    tail = jnp.where(keep, conv[layer, :, slot], 0)
+    ext = jnp.concatenate([tail, rows.astype(conv.dtype)])
+    acc = sum(w[j] * ext[j:j + q].astype(jnp.float32) for j in range(k))
+    out = jax.nn.silu(acc if bias is None else bias + acc)
+    # the last k - 1 inputs behind row n: the old tail's where n is
+    # shorter than it
+    conv = conv.at[layer, :, slot].set(
+        jax.lax.dynamic_slice_in_dim(ext, n, k - 1))
+    return out, conv
+
+
+def conv_pieces(x, w, bias, conv, layer, pieces, chunk):
+    """The convolution alone over the pieces of a flat batch (``pieces`` as
+    :func:`chunked_scan` takes them), for a mixer whose recurrence does not
+    run in the same loop. ``x`` [T, channels]. -> ``(out [T, channels]
+    float32, zero where no piece lies; conv)``."""
+    row0, length, slots, fresh, count = pieces
+    t = x.shape[0]
+    x = jnp.pad(x, ((0, chunk), (0, 0)))
+
+    def piece(i, carry):
+        conv, out_all = carry
+        r0, n = row0[i], length[i]
+        valid = (jnp.arange(chunk) < n)[:, None]
+        rows = jnp.where(valid, jax.lax.dynamic_slice_in_dim(x, r0, chunk),
+                         0)
+        out, conv = conv_piece(rows, w, bias, conv, layer, slots[i],
+                               jnp.logical_not(fresh[i]), n)
+        out_all = jax.lax.dynamic_update_slice_in_dim(
+            out_all, jnp.where(
+                valid, out, jax.lax.dynamic_slice_in_dim(out_all, r0, chunk)),
+            r0, 0)
+        return conv, out_all
+
+    conv, out_all = jax.lax.fori_loop(
+        0, count, piece,
+        (conv, jnp.zeros((t + chunk, x.shape[1]), jnp.float32)))
+    return out_all[:t], conv
+
+
 def _split_xbc(out, cfg):
     """The convolution's output ``[rows, channels]`` as x ``[rows, g, hp]``
     (a group's heads side by side), B and C ``[rows, g, n]``."""
@@ -166,15 +233,8 @@ def decode_step(xbc, dt, p, ssm, conv, layer, slots, fresh, cfg, step=None):
     step = step or STATE_STEPS[default_impl()]
     keep = jnp.logical_not(fresh)
     with scope("ssm_conv"):
-        w, bias = _conv_weights(p)
-        k = w.shape[0]
-        # the window's rows, oldest first: the tail's k - 1, then the token
-        win = [jnp.where(keep[:, None], conv[layer, j, slots], 0)
-               for j in range(k - 1)] + [xbc.astype(conv.dtype)]
-        out = jax.nn.silu(bias + sum(
-            w[j] * win[j].astype(jnp.float32) for j in range(k)))
-        for j in range(k - 1):
-            conv = conv.at[layer, j, slots].set(win[j + 1])
+        out, conv = conv_step(xbc, *_conv_weights(p), conv, layer, slots,
+                              keep)
     with scope("ssm_scan"):
         x, b, c = _split_xbc(out, cfg)
         dtv, da = _dt_decay(dt, p, cfg)
@@ -229,7 +289,7 @@ def chunked_scan(xbc, dt, p, ssm, conv, layer, pieces, cfg):
     says the first of them is the sequence's first. -> ``(y [T, d_inner]
     float32, zero where no piece lies; ssm; conv)``."""
     row0, length, slots, fresh, count = pieces
-    q, k = cfg.ssm_chunk_size, cfg.ssm_conv_kernel
+    q = cfg.ssm_chunk_size
     t, dtype = xbc.shape[0], xbc.dtype
     w, bias = _conv_weights(p)
     d_skip = _per_head(p["D"].astype(jnp.float32), cfg)
@@ -245,14 +305,7 @@ def chunked_scan(xbc, dt, p, ssm, conv, layer, pieces, cfg):
         with scope("ssm_conv"):
             rows = jnp.where(valid, jax.lax.dynamic_slice_in_dim(xbc, r0, q),
                              0)
-            tail = jnp.where(keep, conv[layer, :, slot], 0)
-            ext = jnp.concatenate([tail, rows.astype(conv.dtype)])
-            out = jax.nn.silu(bias + sum(
-                w[j] * ext[j:j + q].astype(jnp.float32) for j in range(k)))
-            # the last k - 1 inputs behind row n: the old tail's where n is
-            # shorter than it
-            conv = conv.at[layer, :, slot].set(
-                jax.lax.dynamic_slice_in_dim(ext, n, k - 1))
+            out, conv = conv_piece(rows, w, bias, conv, layer, slot, keep, n)
         # ssm_chunk inside ssm_scan: the pieces' own time, apart from the
         # state step of the one-token rows beside them (decode_step)
         with scope("ssm_scan"), scope("ssm_chunk"):

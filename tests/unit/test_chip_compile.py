@@ -808,3 +808,43 @@ def test_the_two_kind_forwards_compile_at_the_cells_widths(one_chip, program,
     assert m.alias_size_in_bytes >= pools
     assert round(m.argument_size_in_bytes / 2**30, 2) == 13.17
     assert m.temp_size_in_bytes < 0.75 * 2**30
+
+
+# ----------------------------------- the gated delta rule, the cell's widths
+@pytest.mark.parametrize("entry", ["decode_step", "chunked"])
+def test_the_delta_rule_compiles_at_the_cells_widths(one_chip, entry):
+    """``solar2-agent-sat``: 256 rows (a 768-row mixed round in pieces of
+    64) against the pool ``[3 layers, 256 + 1 slots, 64 heads, 128, 128]``
+    float32 (3.0 GiB): the state step is a custom call by its own name, 16
+    heads a grid step, the pool is aliased (held once) in both entries, and
+    the chunked form, which is XLA, holds under 128 MiB of temporaries (a
+    piece's pair products of one sub-block are the largest: 32 MiB)."""
+    from deepspeedsyclsupport_tpu.ops import kda
+
+    cfg = get_config("solar-open2")
+    h, d = cfg.kda_num_heads, cfg.kda_head_dim
+    assert (h, d, cfg.kda_chunk_size, kda.STEP_HEADS) == (64, 128, 64, 16)
+    pool = ((3, 257, h, d, d), jnp.float32)
+    rows = 256 if entry == "decode_step" else 768
+    acts = [((rows, h, d), jnp.float32)] * 4 + [((rows, h), jnp.float32)]
+    if entry == "decode_step":
+        def f(q, k, v, g, beta, s, slots, fresh):
+            return kda.decode_step(q, k, v, g, beta, s, 1, slots, fresh, cfg,
+                                   kda.STATE_STEPS["pallas"])
+
+        last = [((rows,), jnp.int32), ((rows,), jnp.bool_)]
+    else:
+        def f(q, k, v, g, beta, s, row0, length, slot, fresh, count):
+            return kda.chunked(q, k, v, g, beta, s, 1,
+                               (row0, length, slot, fresh, count), cfg)
+
+        last = [((269,), jnp.int32)] * 3 + [((269,), jnp.bool_),
+                                            ((), jnp.int32)]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in acts + [pool] + last]
+    compiled = jax.jit(f, donate_argnums=5).lower(*args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert ("kda_state_step" in text and "tpu_custom_call" in text) \
+        == (entry == "decode_step")
+    assert mem.alias_size_in_bytes >= 3 * 257 * h * d * d * 4
+    assert mem.temp_size_in_bytes < 128 << 20, mem.temp_size_in_bytes

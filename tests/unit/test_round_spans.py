@@ -359,18 +359,34 @@ def test_the_lint_rule_holds_phase_literals_to_the_registry(tmp_path):
     assert "ROUND_PHASES" in found[0].message
 
 
-def test_a_full_ring_counts_what_it_drops(tiny):
+def test_a_full_ring_counts_what_it_drops_and_keeps_its_rounds(tiny):
+    """The stream's ring turns over; the rounds' ring, one record a round,
+    still holds every round, and ``drain_trace`` hands both over as one
+    stream in the order of ``t``."""
     eng = _engine(tiny)
     sess = ServingSession(eng, ServingPolicyConfig(admission="none"))
     sess.trace_log = deque(maxlen=8)
     for uid, prompt in PROMPTS:
         sess.submit(uid, prompt, 6)
+    rounds = 0
     while not sess.idle:
         sess.step()
+        rounds += 1
     dropped = sess.stats()["trace_dropped"]
     assert dropped > 0 and len(sess.trace_log) == 8
+    assert len(sess.round_log) == rounds and not any(
+        r["data"].get("stage") == "round" for r in sess.trace_log)
     # every record ever written is either still there or counted
-    assert dropped + 8 == sess.trace_dropped + len(sess.drain_trace())
+    drained = sess.drain_trace()
+    assert len(drained) == 8 + rounds and not sess.round_log
+    assert [r["t"] for r in drained] == sorted(r["t"] for r in drained)
+    assert [r["data"]["round"] for r in drained
+            if r["data"].get("stage") == "round"] == list(range(1, rounds + 1))
+    sess.round_log = deque(maxlen=2)
+    sess.submit(9, PROMPTS[0][1], 6)
+    while not sess.idle:
+        sess.step()
+    assert len(sess.round_log) == 2 and sess.trace_dropped > dropped
     sess.close()
 
 

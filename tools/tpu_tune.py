@@ -24,7 +24,16 @@ Sections, cheapest first:
             XLA over several steps:  retention [--parent DIR ...] [--tiles]
             [--copies] [--parity]
 
-Usage:  python tools/tpu_tune.py [calib|flash|paged|retention|all]
+  kda     — the gated delta rule alone at ``solar2-agent-sat``'s shape (256
+            rows on 256 slots + the sink, 3 layers, 64 heads of a [128, 128]
+            float32 state): the state step's kernel at several heads a grid
+            step, ms a layer and the share of the HBM peak; the chunked
+            form's pieces, ms a piece; with ``--parity`` both entries against
+            the SEQUENTIAL float32 recurrence on the chip, a decay strong
+            enough to overflow a naive ``e^-G`` among them:
+            kda [--heads N ...] [--parity]
+
+Usage:  python tools/tpu_tune.py [calib|flash|paged|retention|dsa|kda|all]
 """
 import functools
 import json
@@ -977,6 +986,173 @@ def dsa(argv=()):
     _dsa_sweep(mods, a.keys)
 
 
+# ``solar2-agent-sat``'s decode step: 256 rows on 256 slots + the sink, 3
+# delta-rule layers, 64 heads of a [128, 128] float32 state; pieces of 64
+KDA_CELL = dict(layers=3, slots=257, rows=256, heads=64, dim=128, chunk=64)
+KDA_HEADS = (8, 16, 32, 64)     # heads a grid step of the state step takes
+KDA_PIECES = 8                  # pieces the chunked form's program runs
+
+
+def _kda_rows(rows, seed=0, strong=False):
+    """``(q, k, v, g, beta)`` of ``rows`` rows as the model hands them over:
+    unit keys, scaled unit queries, log-decays from under a token's
+    half-life to hundreds (``strong``: -40 a row on a quarter of the
+    channels, so that a piece's running sum passes float32's exponent)."""
+    from deepspeedsyclsupport_tpu.ops.kda import l2norm
+
+    c = KDA_CELL
+    h, d = c["heads"], c["dim"]
+    ks = jax.random.split(jax.random.PRNGKey(seed), 5)
+    q, k, v = jax.random.normal(ks[0], (3, rows, h, d))
+    g = -jnp.exp(jax.random.uniform(ks[1], (rows, h, d), minval=np.log(1e-3),
+                                    maxval=np.log(1.6)))
+    if strong:
+        g = jnp.where(jnp.arange(d) % 4 == 0, -40.0, g)
+    beta = 2.0 * jax.nn.sigmoid(jax.random.normal(ks[2], (rows, h)))
+    return l2norm(q) * d ** -0.5, l2norm(k), v, g, beta
+
+
+def _kda_parity(tree):
+    """Both entries against the sequential recurrence (the XLA state step a
+    token at a time) on the chip: the Pallas step over several steps with
+    two rows on the sink and one fresh; the chunked form over three pieces
+    of two sequences, one ragged, under a plain and a strong decay."""
+    import types
+
+    c = KDA_CELL
+    h, d, q = c["heads"], c["dim"], c["chunk"]
+    cfg = types.SimpleNamespace(kda_chunk_size=q)
+    rows, slots_n = 16, 17
+    pool = 0.1 * jax.random.normal(jax.random.PRNGKey(7),
+                                   (1, slots_n, h, d, d))
+    slots = jnp.arange(rows, dtype=jnp.int32).at[jnp.asarray([3, 11])].set(
+        slots_n - 1)
+    fresh = jnp.zeros((rows,), bool).at[5].set(True)
+    live = slots != slots_n - 1
+    got = {}
+    for name in ("xla", "pallas"):
+        p, acc = pool, 0.0
+        for t in range(3):
+            args = _kda_rows(rows, seed=t)
+            y, p = jax.jit(lambda p, *a, name=name: tree.decode_step(
+                *a, p, 0, slots, fresh, cfg, tree.STATE_STEPS[name]))(
+                    p, *args)
+            acc = acc + jnp.where(live[:, None, None], y, 0) * (1 + t)
+        got[name] = (acc, p[:, :slots_n - 1])
+    emit("kda_parity", entry="decode_step", steps=3,
+         y_max=float(jnp.max(jnp.abs(got["xla"][0]))),
+         y_err=float(jnp.max(jnp.abs(got["xla"][0] - got["pallas"][0]))),
+         pool_max=float(jnp.max(jnp.abs(got["xla"][1]))),
+         pool_err=float(jnp.max(jnp.abs(got["xla"][1] - got["pallas"][1]))))
+    # three pieces: sequence A in slot 2 (64 rows fresh, then 21 more),
+    # sequence B in slot 0 (64 rows from what the slot holds)
+    tail = q // 3
+    t = 2 * q + tail
+    pieces = (jnp.asarray([0, q, q + tail, 0]), jnp.asarray([q, tail, q, 0]),
+              jnp.asarray([2, 2, 0, slots_n - 1]),
+              jnp.asarray([True, False, False, False]), jnp.asarray(3))
+    for strong in (False, True):
+        args = _kda_rows(t, seed=9, strong=strong)
+        y, p = jax.jit(lambda p, *a: tree.chunked(*a, p, 0, pieces, cfg))(
+            pool, *args)
+
+        def token(i, carry):
+            p, out = carry
+            slot = jnp.where(i < q + tail, 2, 0)
+            y_i, p = tree.decode_step(
+                *(jax.lax.dynamic_slice_in_dim(a, i, 1) for a in args), p, 0,
+                slot[None], (i == 0)[None], cfg, tree.STATE_STEPS["xla"])
+            return p, jax.lax.dynamic_update_slice_in_dim(out, y_i, i, 0)
+
+        p_seq, y_seq = jax.jit(lambda p: jax.lax.fori_loop(
+            0, t, token, (p, jnp.zeros_like(y))))(pool)
+        emit("kda_parity", entry="chunked", strong_decay=strong, rows=t,
+             y_max=float(jnp.max(jnp.abs(y_seq))),
+             y_err=float(jnp.max(jnp.abs(y - y_seq))),
+             pool_max=float(jnp.max(jnp.abs(p_seq[:, :3]))),
+             pool_err=float(jnp.max(jnp.abs(p[:, :3] - p_seq[:, :3]))),
+             finite=bool(jnp.isfinite(y).all()))
+
+
+def kda(argv=()):
+    """The delta rule's two entries ALONE at the cell's shape, their device
+    time read off a profiler trace: the state step's kernel at each of
+    ``--heads`` heads a grid step (ms a layer, the share of 819 GB/s: every
+    row's state read once and written once) and the chunked form over
+    ``KDA_PIECES`` full pieces of one layer (ms a piece, all of it XLA);
+    ``--parity`` holds both against the sequential recurrence first."""
+    import argparse
+    import types
+
+    from deepspeedsyclsupport_tpu.ops import kda as tree
+
+    ap = argparse.ArgumentParser(prog="tpu_tune.py kda")
+    ap.add_argument("--heads", type=int, nargs="*", default=list(KDA_HEADS))
+    ap.add_argument("--parity", action="store_true")
+    a = ap.parse_args(list(argv))
+    c = KDA_CELL
+    h, d, rows, q = c["heads"], c["dim"], c["rows"], c["chunk"]
+    cfg = types.SimpleNamespace(kda_chunk_size=q)
+    if a.parity:
+        _kda_parity(tree)
+    pool = jax.jit(lambda key: 0.1 * jax.random.normal(
+        key, (c["layers"], c["slots"], h, d, d)))(jax.random.PRNGKey(3))
+    args = _kda_rows(rows) + (jnp.arange(rows, dtype=jnp.int32),
+                              jnp.ones((rows,), jnp.float32))
+
+    def step_of(name, hb):
+        def step(pool, q_, k_, v_, g_, beta_, slots, keep):
+            def layer(i, carry):
+                pool, acc = carry
+                y, pool = tree._state_step_pallas(
+                    pool, i, slots, keep, q_, k_, v_, g_, beta_, heads=hb)
+                return pool, acc + y
+            return jax.lax.fori_loop(0, c["layers"], layer,
+                                     (pool, jnp.zeros(v_.shape)))
+        step.__name__ = name
+        return jax.jit(step, donate_argnums=0)
+
+    steps, failed = {}, {}
+    for hb in a.heads:
+        name = f"state_step_{hb}"
+        try:
+            steps[name] = step_of(name, hb).lower(pool, *args).compile()
+        except Exception as e:                     # e.g. over the VMEM limit
+            failed[name] = str(e).splitlines()[0][:160]
+    moved = 2 * rows * h * d * d * 4
+    out = _traced_kernels(steps, args, kernel_of=lambda text: "kernel",
+                          carry=pool)
+    for row in out.values():
+        if "kernel" in row:
+            row["peak_pct"] = round(100 * moved / V5E_HBM
+                                    / (row["kernel"] * 1e-3), 1)
+    emit("kda", cell=c, bytes_a_layer=moved, rows=out, failed=failed)
+    del steps
+    # the chunked form: KDA_PIECES full pieces of as many sequences
+    t = KDA_PIECES * q
+    pieces = (jnp.arange(KDA_PIECES) * q, jnp.full((KDA_PIECES,), q),
+              jnp.arange(KDA_PIECES), jnp.zeros((KDA_PIECES,), bool),
+              jnp.asarray(KDA_PIECES))
+
+    def chunk(pool, *rows_):
+        y, pool = tree.chunked(*rows_, pool, 1, pieces, cfg)
+        return pool, y
+
+    chunk.__name__ = "chunked"
+    pool = jax.jit(lambda key: 0.1 * jax.random.normal(
+        key, (c["layers"], c["slots"], h, d, d)))(jax.random.PRNGKey(3))
+    rows_t = _kda_rows(t, seed=1)
+    prog = {"chunked": jax.jit(chunk, donate_argnums=0).lower(
+        pool, *rows_t).compile()}
+    out = _traced_kernels(prog, rows_t, kernel_of=lambda text: "kernel",
+                          carry=pool)
+    ms = out["chunked"]["xla"]
+    emit("kda_chunked", pieces=KDA_PIECES, rows_a_piece=q, ms=ms,
+         ms_a_piece=round(ms / KDA_PIECES, 4),
+         temp_mib=round(prog["chunked"].memory_analysis().temp_size_in_bytes
+                        / 2**20, 1))
+
+
 if __name__ == "__main__":
     which = sys.argv[1] if len(sys.argv) > 1 else "all"
     if which in ("calib", "all"):
@@ -989,3 +1165,5 @@ if __name__ == "__main__":
         retention(sys.argv[2:] if which == "retention" else ())
     if which in ("dsa", "all"):
         dsa(sys.argv[2:] if which == "dsa" else ())
+    if which in ("kda", "all"):
+        kda(sys.argv[2:] if which == "kda" else ())
