@@ -612,19 +612,22 @@ register_impl("decode_attn", "xla", priority=0)(_decode_dispatch("xla"))
 
 # ssm_step / ret_step / kda_step kinds: a state layer's one-token update,
 # Mamba-2's (``ops/ssm.py``), power retention's (``ops/retention.py``) and
-# the delta rule's (``ops/kda.py``): the in-place Pallas kernel on the TPU,
-# gather/update/scatter elsewhere
+# the delta rule's (``ops/kda.py``); conv_step: the one-token rows of the
+# depthwise convolution before the first and the last (``ops/ssm.py``): the
+# in-place Pallas kernel on the TPU, gather/update/scatter elsewhere
 def _state_dispatch(kind, impl_name):
     def fn(*args):
         from ...ops import kda, retention, ssm
 
-        steps = {"ssm_step": ssm, "ret_step": retention,
-                 "kda_step": kda}[kind].STATE_STEPS
+        steps = {"ssm_step": ssm.STATE_STEPS,
+                 "ret_step": retention.STATE_STEPS,
+                 "kda_step": kda.STATE_STEPS,
+                 "conv_step": ssm.CONV_STEPS}[kind]
         return steps[impl_name](*args)
     return fn
 
 
-for _kind in ("ssm_step", "ret_step", "kda_step"):
+for _kind in ("ssm_step", "ret_step", "kda_step", "conv_step"):
     register_impl(_kind, "pallas", priority=10,
                   auto_eligible=lambda c: c.get("backend") == "tpu")(
         _state_dispatch(_kind, "pallas"))
@@ -642,6 +645,10 @@ def _state_step_fn(kind):
 
 def _ssm_step_fn():
     return _state_step_fn("ssm_step")
+
+
+def _conv_step_fn():
+    return _state_step_fn("conv_step")
 
 
 def _ret_step_fn():
@@ -1196,7 +1203,7 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
         y_dec, *state = decode_step(
             xbc[ssm.dec_row], dt[ssm.dec_row], p, *state, l,
             jnp.where(one, ssm.seq_slot, state[0].shape[1] - 1),
-            ssm.dec_len == 1, cfg, _ssm_step_fn())
+            ssm.dec_len == 1, cfg, _ssm_step_fn(), _conv_step_fn())
         # a slot with no one-token chunk scatters out of range (dropped)
         y = y.at[jnp.where(one, ssm.dec_row, t)].set(y_dec, mode="drop")
         return y, tuple(state)
@@ -1215,7 +1222,7 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
         out_dec, conv = conv_step(
             qkv[ssm.dec_row], w, None, conv, l,
             jnp.where(one, ssm.seq_slot, conv.shape[2] - 1),
-            ssm.dec_len != 1)
+            ssm.dec_len != 1, _conv_step_fn())
         out = out.at[jnp.where(one, ssm.dec_row, t)].set(out_dec,
                                                          mode="drop")
         return out, (state[0], conv)
@@ -1367,7 +1374,7 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
         y, *state = decode_step(
             xbc, dt, p, *state, l,
             jnp.where(active, state_slot, state[0].shape[1] - 1),
-            positions == 0, cfg, _ssm_step_fn())
+            positions == 0, cfg, _ssm_step_fn(), _conv_step_fn())
         return y, tuple(state)
 
     def kda_conv(p, qkv, state, l):
@@ -1376,7 +1383,7 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
         out, conv = conv_step(
             qkv, p["conv_w"].astype(jnp.float32), None, state[1], l,
             jnp.where(active, state_slot, state[1].shape[2] - 1),
-            positions != 0)
+            positions != 0, _conv_step_fn())
         return out, (state[0], conv)
 
     def kda_scan(q, k, v, g, beta, state, l):

@@ -26,8 +26,9 @@ sublanes, the value's on the lanes. The decay is then PER SUBLANE (a column
 write is a column times a row. Slot ``S`` (the last) is the sink padding
 writes to. A piece whose first position is 0 starts from zeros, whatever its
 slot held: the host resets nothing. The depthwise convolution before it and
-its tail are Mamba-2's (``ops/ssm.conv_step`` / ``conv_pieces``), called with
-the layer's ``3 x h x dk`` channels.
+its tail are Mamba-2's (``ops/ssm.conv_step``, on the TPU the in-place
+kernel ``conv_tail_step``, / ``conv_pieces``), called with the layer's ``3 x
+h x dk`` channels and no bias.
 
 Two entries, as ``ops/ssm.py`` and ``ops/retention.py`` have:
 
@@ -59,8 +60,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..monitor.mfu import scope
-from .retention import _divisor
-from .ssm import default_impl
+from .ssm import _divisor, default_impl
 
 HIGHEST = jax.lax.Precision.HIGHEST
 # the floor under a head's squared norm: x / sqrt(|x|^2 + eps)

@@ -69,7 +69,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..monitor.mfu import scope
-from .ssm import default_impl
+from .ssm import _divisor, default_impl
 
 HIGHEST = jax.lax.Precision.HIGHEST
 # the normaliser's floor: y = phi(q)^T S / (phi(q)^T z + eps)
@@ -294,11 +294,6 @@ WRITE_LANES = 640
 COMPUTE_ROWS = 64
 COMPUTE_DIAGONALS = 5
 Y_LANES = 128       # the read-out's block: query i in lane i
-
-
-def _divisor(n: int, most: int) -> int:
-    """The largest divisor of ``n`` that is at most ``most`` (1 at least)."""
-    return max(t for t in range(1, n + 1) if n % t == 0 and t <= max(most, 1))
 
 
 def _state_step_pallas(pool, layer, slots, decay, qs, k, v, interpret=False):

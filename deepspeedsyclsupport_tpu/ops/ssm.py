@@ -15,19 +15,30 @@ the lanes, ``n`` on the sublanes, so that the update is elementwise on whole
 registers and ``S C`` sums over sublanes) and the convolution's tail, the
 last ``k - 1`` rows of ``xBC``, as ``[k - 1, slots, channels]`` (the slots on
 the sublanes: with the three taps there the compiler kept two layouts of
-the pool and copied it whole between them, four times a forward). Slot ``S`` (the last) is the sink padding
-writes to. A piece whose first position is 0 starts from zeros, whatever its
-slot held: the host resets nothing.
+the pool and copied it whole between them, four times a forward; as it is,
+a slot's row shares its (8, 128)(2, 1) tile with 15 other slots', which is
+why no copy engine moves one row of it). Slot ``S`` (the last) is the sink
+padding writes to. A piece whose first position is 0 starts from zeros,
+whatever its slot held: the host resets nothing.
+
+Who moves the tail: the ONE-TOKEN rows' kernel (:func:`conv_step`,
+``conv_tail_step`` on the TPU: whole blocks of slots x channels in and the
+same blocks out, the pool aliased, each slot's row found by an index in
+SMEM; gather, convolve, scatter elsewhere) and the pieces' loops
+(:func:`conv_piece` under :func:`chunked_scan` / :func:`conv_pieces`: one
+slot's ``[k - 1, channels]`` read and written by XLA a piece, on other
+slots, before the kernel in a mixed forward). Mamba-2's ``xBC`` and the
+delta rule's q | k | v (``ops/kda.py``) go through the same three functions.
 
 Two entries, as the attention kernels have two tiles:
 
 * :func:`decode_step` — ONE token for each of ``[rows]`` slots: shift the
-  tail, update the state IN PLACE. On the TPU a Pallas kernel whose state
-  block is the pool's own ``[layer, slot]`` (scalar-prefetch indices, the
-  pool aliased to the output): each state is read once and written once,
-  2 x 2 MiB a row and layer at Nemotron-3-Nano's sizes, which is the step's
-  whole cost. ``xla``: gather, update, scatter (the CPU tests' reference,
-  and what the kernel is held against).
+  tail (:func:`conv_step`), update the state IN PLACE. On the TPU a Pallas
+  kernel whose state block is the pool's own ``[layer, slot]``
+  (scalar-prefetch indices, the pool aliased to the output): each state is
+  read once and written once, 2 x 2 MiB a row and layer at Nemotron-3-Nano's
+  sizes, which is the step's whole cost. ``xla``: gather, update, scatter
+  (the CPU tests' reference, and what the kernels are held against).
 * :func:`chunked_scan` — the pieces of the chunks of two tokens or more in
   one flat batch (``ragged.ssm_pieces``: single-sequence runs of at most
   ``chunk`` rows, as the attention's atoms): inside a piece the quadratic
@@ -51,6 +62,11 @@ def default_impl() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
+def _divisor(n: int, most: int) -> int:
+    """The largest divisor of ``n`` that is at most ``most`` (1 at least)."""
+    return max(t for t in range(1, n + 1) if n % t == 0 and t <= max(most, 1))
+
+
 def _conv_weights(p):
     return (p["conv_w"].astype(jnp.float32),
             p["conv_b"].astype(jnp.float32))
@@ -62,10 +78,8 @@ def _conv_weights(p):
 # (``ops/kda.py``), which has no bias (``bias`` None). ``w`` [kernel,
 # channels] float32; ``conv`` the pool ``[layers, kernel - 1, slots + 1,
 # channels]``; the caller names the scope.
-def conv_step(x, w, bias, conv, layer, slots, keep):
-    """One token for each row: ``x`` [rows, channels] behind the tail of
-    its slot (zeros where ``keep`` [rows] is false), the tail shifted one on.
-    -> ``(silu of the convolution [rows, channels] float32, conv)``."""
+def _conv_step_xla(x, w, bias, conv, layer, slots, keep):
+    """Gather the tail's rows, convolve, scatter them back one on."""
     k = w.shape[0]
     # the window's rows, oldest first: the tail's k - 1, then the token
     win = [jnp.where(keep[:, None], conv[layer, j, slots], 0)
@@ -75,6 +89,152 @@ def conv_step(x, w, bias, conv, layer, slots, keep):
     for j in range(k - 1):
         conv = conv.at[layer, j, slots].set(win[j + 1])
     return out, conv
+
+
+# The tail's kernel walks the POOL, not the rows: Mosaic copies no single
+# row of a tiled array (a slice of the second-minor axis must be whole
+# (8, 128)(2, 1) tiles: 8 float32 slots, 16 bfloat16 ones, two to a word), so
+# a grid step takes CONV_SLOTS consecutive slots x a tile of channels of the
+# layer's k - 1 taps as ONE aligned block in and the same block out, and
+# finds each slot's row (the token, the result) by a row index in SMEM. With
+# every slot live, as a saturated engine's are, that is the bytes the rows'
+# own tails are; with few live it is at most the layer's pool once in and
+# once out. The token's and the result's blocks hold ALL of the rows of a
+# channel tile for all of its steps: the tile is the most channels whose
+# token block stays under CONV_TILE_BYTES (8,192 of Solar's 24,576 at 256
+# rows, all of Nemotron's 6,144 at 128). By two sweeps on the v5e
+# (tools/tpu_tune.py conv; PERF.md section 6, PR 56; ms a layer at Solar's
+# shape): 16 | 32 | 64 | 128 slots at 4,096 channels 0.176 | 0.179 | 0.199 |
+# 0.237; 1,024 | 2,048 | 4,096 | 8,192 channels at 16 slots 0.363 | 0.237 |
+# 0.176 | 0.152 (a step's fixed cost against its bytes), all of the channels
+# 0.171 (the first step waits for 25 MB of tokens); the two loops over a
+# step's slots unrolled 0.142 against 0.152 rolled.
+CONV_SLOTS = 16
+CONV_TILE_BYTES = 8 << 20
+
+
+def _conv_tail_kernel(layer_ref, row_ref, keep_ref, *refs, taps, bias):
+    """Grid step ``(c, g)``: slots ``g x S .. (g + 1) x S`` of channel tile
+    ``c``. ``row_ref`` [slots] the row that stands at a slot (-1: none),
+    ``keep_ref`` [rows]; ``x_ref`` / ``out_ref`` [rows, lanes] float32 (all
+    of the rows, held for every ``g``), ``tail_ref`` / ``new_ref`` [taps -
+    1, S, lanes] the pool's block in and out, ``xs_ref`` / ``ys_ref`` [S,
+    lanes] float32 the tokens and the results in the slots' order,
+    ``code_ref`` [S, 128] a slot's 0 (no row), 1 (a row from zeros) or 2."""
+    del layer_ref                     # the BlockSpecs' own
+    x_ref, w_ref = refs[:2]
+    tail_ref, out_ref, new_ref, xs_ref, ys_ref, code_ref = refs[2 + bias:]
+    f32 = jnp.float32
+    count = xs_ref.shape[0]
+    base = pl.program_id(1) * count
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():                          # a row that stands at no slot: zeros
+        out_ref[...] = jnp.zeros(out_ref.shape, f32)
+
+    for i in range(count):
+        row = row_ref[base + i]
+        at = jnp.maximum(row, 0)
+        xs_ref[i:i + 1, :] = x_ref[pl.ds(at, 1), :]
+        code_ref[i:i + 1, :] = jnp.full(
+            (1, code_ref.shape[1]), jnp.where(row < 0, 0, 1 + keep_ref[at]),
+            jnp.int32)
+    code = code_ref[:, :1]
+    live, keep = code > 0, code > 1
+    old = [tail_ref[j].astype(f32) for j in range(taps - 1)]
+    # the window's rows, oldest first: the tail's k - 1, then the token
+    win = [jnp.where(keep, t, 0.0) for t in old] + [xs_ref[...]]
+    acc = sum(w_ref[j:j + 1, :] * win[j] for j in range(taps))
+    ys_ref[...] = jax.nn.silu(refs[2][...] + acc if bias else acc)
+    for j in range(taps - 1):
+        new_ref[j] = jnp.where(live, win[j + 1], old[j]).astype(new_ref.dtype)
+    for i in range(count):
+        row = row_ref[base + i]
+
+        @pl.when(row >= 0)
+        def _():
+            out_ref[pl.ds(row, 1), :] = ys_ref[i:i + 1, :]
+
+
+def conv_tile(rows, channels, slots_a_step=None, lanes=None):
+    """``(slots a grid step, channels a tile)`` of the tail's kernel: whole
+    sublane tiles of slots in either dtype (16), whole lane tiles that
+    divide the channels (all of them where 128 does not)."""
+    count = -(-(slots_a_step or CONV_SLOTS) // 16) * 16
+    if channels % 128:
+        return count, channels
+    most = lanes or CONV_TILE_BYTES // (4 * rows)
+    return count, 128 * _divisor(channels // 128, most // 128)
+
+
+def _conv_step_pallas(x, w, bias, conv, layer, slots, keep, interpret=False,
+                      slots_a_step=None, lanes=None):
+    """The same step with the pool aliased to the output, a block of slots
+    at a time (above). The sink's tail is left as it was and a row on the
+    sink reads zeros: nobody reads either."""
+    rows, c = x.shape
+    taps, total = w.shape[0], conv.shape[2]
+    f32 = jnp.float32
+    count, ct = conv_tile(rows, c, slots_a_step, lanes)
+    groups = -(-total // count)
+    # the row at each slot, by comparison (a scatter here is a loop on the
+    # TPU); the sink (the last slot) has none
+    ids = jnp.arange(groups * count, dtype=jnp.int32)[:, None]
+    row_of = jnp.max(jnp.where(
+        (slots.astype(jnp.int32)[None, :] == ids) & (ids != total - 1),
+        jnp.arange(rows, dtype=jnp.int32)[None, :], -1), axis=1)
+    tile = lambda ci, g, *_: (0, ci)                  # noqa: E731
+    block = lambda ci, g, layer_ref, *_: (layer_ref[0], 0, g, ci)  # noqa
+    ops = [x.astype(conv.dtype).astype(f32), w.astype(f32)]
+    specs = [pl.BlockSpec((rows, ct), tile), pl.BlockSpec((taps, ct), tile)]
+    if bias is not None:
+        ops.append(bias.astype(f32).reshape(1, c))
+        specs.append(pl.BlockSpec((1, ct), tile))
+    pool = pl.BlockSpec((None, taps - 1, count, ct), block)
+    # two buffers of the token's, the result's and the pool's blocks in and
+    # out, the scratch and the window's rows in float32
+    held = 4 * rows * ct * 4 + 4 * (taps - 1) * count * ct \
+        * conv.dtype.itemsize + (4 + 2 * taps) * count * ct * 4
+    out, conv = pl.pallas_call(
+        functools.partial(_conv_tail_kernel, taps=taps,
+                          bias=bias is not None),
+        out_shape=[jax.ShapeDtypeStruct((rows, c), f32),
+                   jax.ShapeDtypeStruct(conv.shape, conv.dtype)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(c // ct, groups),
+            in_specs=specs + [pool],
+            out_specs=[pl.BlockSpec((rows, ct), tile), pool],
+            scratch_shapes=[pltpu.VMEM((count, ct), f32),
+                            pltpu.VMEM((count, ct), f32),
+                            pltpu.VMEM((count, 128), jnp.int32)]),
+        # operands count the scalar-prefetch three: the pool is the last
+        input_output_aliases={3 + len(ops): 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",) * 2,
+            vmem_limit_bytes=min(max(held + (8 << 20), 16 << 20),
+                                 100 << 20)),
+        interpret=interpret, name="conv_tail_step",
+    )(jnp.asarray(layer, jnp.int32).reshape(1), row_of,
+      keep.astype(jnp.int32), *ops, conv)
+    return out, conv
+
+
+CONV_STEPS = {
+    "xla": _conv_step_xla,
+    "pallas": _conv_step_pallas,
+    "pallas_interpret": functools.partial(_conv_step_pallas, interpret=True),
+}
+
+
+def conv_step(x, w, bias, conv, layer, slots, keep, step=None):
+    """One token for each row: ``x`` [rows, channels] behind the tail of
+    its slot (zeros where ``keep`` [rows] is false), the tail shifted one on.
+    ``step``: one of :data:`CONV_STEPS` (None: by platform; the serving
+    forwards resolve theirs through the engine's ``module_registry``, kind
+    ``conv_step``). -> ``(silu of the convolution [rows, channels] float32,
+    conv)``."""
+    return (step or CONV_STEPS[default_impl()])(x, w, bias, conv, layer,
+                                                slots, keep)
 
 
 def conv_piece(rows, w, bias, conv, layer, slot, keep, n):
@@ -221,20 +381,22 @@ STATE_STEPS = {
 }
 
 
-def decode_step(xbc, dt, p, ssm, conv, layer, slots, fresh, cfg, step=None):
+def decode_step(xbc, dt, p, ssm, conv, layer, slots, fresh, cfg, step=None,
+                conv_fn=None):
     """One token for each row. ``xbc`` [rows, channels] and ``dt`` [rows, h]
     as ``in_proj`` gives them; ``p`` the layer's leaves; ``ssm`` / ``conv``
     the pools, ``layer`` the Mamba layer, ``slots`` [rows] each row's state
     slot (the sink for a row that is padding), ``fresh`` [rows] bool: the
     row is its sequence's first token. ``step``: one of :data:`STATE_STEPS`
     (None: by platform; the serving forwards resolve theirs through the
-    engine's ``module_registry``, kind ``ssm_step``). -> ``(y [rows,
-    d_inner] float32, ssm, conv)``."""
+    engine's ``module_registry``, kind ``ssm_step``); ``conv_fn``: one of
+    :data:`CONV_STEPS`, likewise. -> ``(y [rows, d_inner] float32, ssm,
+    conv)``."""
     step = step or STATE_STEPS[default_impl()]
     keep = jnp.logical_not(fresh)
     with scope("ssm_conv"):
         out, conv = conv_step(xbc, *_conv_weights(p), conv, layer, slots,
-                              keep)
+                              keep, conv_fn)
     with scope("ssm_scan"):
         x, b, c = _split_xbc(out, cfg)
         dtv, da = _dt_decay(dt, p, cfg)

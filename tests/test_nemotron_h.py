@@ -177,6 +177,50 @@ def test_chunked_prefill_then_decode_match_the_reference(built, family,
     assert served_errors(*built, family) < TOL
 
 
+def test_both_forwards_through_the_tails_kernel_are_the_xla_forms(
+        built, monkeypatch):
+    """The one-token rows' convolution through ``conv_tail_step`` (put first
+    in the registry, interpreted) against the XLA form, engine beside
+    engine: a prompt, a mixed round (A's one-token row beside B's pieces,
+    ``ragged_forward``), two decode rounds (``decode_forward``, two rows on
+    the sink): the same logits and the same pools in every slot but the
+    sink, the first state layer's tails bit for bit (what it is handed
+    has passed through no convolution; the later layers' through a silu
+    whose last bit the two forms round apart: the op's own test holds the
+    tails exactly, ``tests/unit/test_conv_tail.py``)."""
+    from deepspeedsyclsupport_tpu.inference.v2 import model as model_v2
+    from deepspeedsyclsupport_tpu.inference.v2 import module_registry as reg
+
+    def drive():
+        eng = engine_of(*built)
+        a, b = PROMPTS
+        rows = [np.asarray(eng.put([1], [a])[1])]
+        tok = int(rows[-1].argmax())
+        rows.append(np.asarray(eng.put([1, 2], [[tok], b], drain=False)[1]))
+        rows.append(np.asarray(eng.put([], [])[2]))
+        for _ in range(2):
+            out = eng.put([1, 2], [[int(rows[-2].argmax())],
+                                   [int(rows[-1].argmax())]])
+            rows += [np.asarray(out[1]), np.asarray(out[2])]
+        assert {"ragged_forward", "decode_forward"} <= set(eng._dispatched)
+        return np.stack(rows), [np.asarray(p) for p in eng.kv.state]
+
+    want, pools = drive()
+    first = dataclasses.replace(
+        reg.get_impl("conv_step", "pallas_interpret"), name="first",
+        priority=100, auto_eligible=lambda ctx: True)
+    monkeypatch.setitem(reg._REGISTRY["conv_step"], "first", first)
+    assert model_v2._conv_step_fn() is first.fn
+    got, pools_k = drive()
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(pools_k[0][:, :-1], pools[0][:, :-1],
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(pools_k[1][:, :, :-1], pools[1][:, :, :-1],
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(pools_k[1][0, :, :-1],
+                                  pools[1][0, :, :-1])
+
+
 def test_a_mixed_round_and_a_slot_reused(built, family):
     """Sequence A decodes while B's prompt comes in beside it (one-token
     rows and pieces in ONE forward, each from its own slot); then A is

@@ -479,6 +479,58 @@ class TestTuneChainTimer:
         assert asked == [1024, 128, 256, 1024, 1024]
 
 
+    def test_the_conv_sweep_runs_every_form_and_holds_the_kernel(
+            self, tune, monkeypatch, capsys):
+        """``tpu_tune.py conv`` at a tiny cell, the kernel interpreted and
+        the profiler's reading stubbed: the XLA form, the rule's own kernel
+        and every (slots, channels) candidate are built and run, each
+        program starts its sum from a number of its own (two that read the
+        same would share one executable), and ``--parity`` reads no
+        difference in the results or the tails."""
+        import functools
+        import json
+
+        from deepspeedsyclsupport_tpu.ops import ssm
+
+        monkeypatch.setattr(tune, "CONV_CELLS", {"tiny": dict(
+            layers=2, slots=21, rows=12, channels=256, bias=True)})
+        monkeypatch.setitem(ssm.CONV_STEPS, "pallas", functools.partial(
+            ssm._conv_step_pallas, interpret=True))
+        firsts = {}
+
+        def reading(steps, args, carry=None, **_kw):
+            for name, step in steps.items():
+                carry, out = step(carry, *args)
+                firsts[name] = float(out[10, -1])   # a row on the sink
+            return {name: {"kernel": 0.5, "xla": 0.25,
+                           "calls": {"kernel": 6}}
+                    if name.startswith("kernel") else {"xla": 2.0}
+                    for name in steps}
+
+        monkeypatch.setattr(tune, "_traced_kernels", reading)
+        tune.conv(["--cell", "tiny", "--slots", "16", "32", "--lanes",
+                   "128", "--parity"])
+        out = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
+        parity, = [r for r in out if r["section"] == "conv_parity"]
+        assert parity["out_err"] < 1e-5 and parity["tails_differ"] == 0
+        assert parity["out_max"] > 1
+        table, = [r for r in out if r["section"] == "conv"]
+        assert not table["failed"] and table["rule"] == {"slots": 16,
+                                                         "lanes": 256}
+        assert set(table["rows"]) == {"xla", "kernel_tree", "kernel_16x128",
+                                      "kernel_32x128"}
+        # the kernel's reading is a layer's, XLA's a whole program's
+        assert table["rows"]["kernel_tree"]["ms_a_layer"] == 0.625
+        assert table["rows"]["xla"]["ms_a_layer"] == 1.0
+        moved = 2 * 12 * 3 * 256 * 2
+        assert table["rows"]["xla"]["tail_gb_s"] == round(
+            moved / 1e-3 / 1e9, 1)
+        # a row on the sink reads zeros under the kernel: its sum is the
+        # number its program started from
+        assert [firsts[n] for n in ("kernel_tree", "kernel_16x128",
+                                    "kernel_32x128")] == [1.0, 2.0, 3.0]
+
+
 class TestSpatialAndTiling:
     """ops/spatial (diffusers fused bias-add family, reference
     csrc/spatial/) and runtime/tiling (reference runtime/zero/tiling.py)."""

@@ -848,3 +848,73 @@ def test_the_delta_rule_compiles_at_the_cells_widths(one_chip, entry):
         == (entry == "decode_step")
     assert mem.alias_size_in_bytes >= 3 * 257 * h * d * d * 4
     assert mem.temp_size_in_bytes < 128 << 20, mem.temp_size_in_bytes
+
+
+# ------------------------- the one-token rows' convolution, tail in place
+# layers, slots with the sink, channels, rows, a bias, a mixed round's rows,
+# its pieces and their rows
+CONV_CELLS = {
+    "solar2-agent-sat": (3, 257, 24576, 256, False, 768, 269, 64),
+    "nemo3-reason-sat": (12, 129, 6144, 128, True, 512, 133, 128),
+}
+
+
+@pytest.mark.parametrize("entry", ["decode", "mixed"])
+@pytest.mark.parametrize("cell", sorted(CONV_CELLS))
+def test_the_convolutions_tail_compiles_at_the_cells_widths(one_chip, cell,
+                                                            entry):
+    """The two cells that run ``ops/ssm.conv_step``, a bfloat16 pool
+    ``[layers, 3, slots + 1, channels]``: the tail's kernel is a custom call
+    by its own name over blocks of whole sublane tiles of slots (the chip's
+    compiler refuses a one-row copy of the pool), the pool is aliased (held
+    once) and nothing of its size is a temporary, alone (``decode``) and
+    behind the pieces' loop, which reads and writes the same pool on other
+    slots in XLA (``mixed``: a layout of the pool's that the kernel alone
+    asked for would be one more copy of it)."""
+    from deepspeedsyclsupport_tpu.ops import ssm
+
+    layers, slots, c, rows, bias, t, pieces, chunk = CONV_CELLS[cell]
+    held = layers * 3 * slots * c * 2
+    assert ssm.conv_tile(rows, c) == (16, min(c, 8192))
+
+    def program(step):
+        def f(x, w, b, conv, at, keep, row0, length, slot, fresh, count,
+              dec):
+            b = b if bias else None
+            if entry == "mixed":
+                out, conv = ssm.conv_pieces(
+                    x, w, b, conv, 1, (row0, length, slot, fresh, count),
+                    chunk)
+                x = x[dec]
+            one, conv = ssm.conv_step(x, w, b, conv, 1, at, keep,
+                                      ssm.CONV_STEPS[step])
+            return (one if entry == "decode" else out.at[dec].set(one)), conv
+        return jax.jit(f, donate_argnums=3).lower(*args).compile()
+
+    def pool_copies(text):
+        return [ln for ln in text.splitlines() if re.search(
+            rf"= bf16\[{layers},3,{slots},{c}\]\S* copy(-start)?\(", ln)]
+
+    n = rows if entry == "decode" else t
+    shapes = [((n, c), jnp.bfloat16), ((4, c), jnp.float32),
+              ((c,), jnp.float32), ((layers, 3, slots, c), jnp.bfloat16),
+              ((rows,), jnp.int32), ((rows,), jnp.bool_)] \
+        + [((pieces,), jnp.int32)] * 3 + [((pieces,), jnp.bool_),
+                                          ((), jnp.int32),
+                                          ((rows,), jnp.int32)]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    compiled = program("pallas")
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1 and "%conv_tail_step" in calls[0], calls
+    assert mem.alias_size_in_bytes >= held
+    # the pieces' loop lays the slot it reads out its own way, under the
+    # XLA form as well: the kernel adds no copy of the pool to that
+    assert len(pool_copies(text)) <= len(pool_copies(
+        program("xla").as_text())) == (entry == "mixed")
+    if entry == "decode":
+        # the token's float32 copy and the row at each slot: no pool
+        assert mem.temp_size_in_bytes < held // 2, mem.temp_size_in_bytes
+

@@ -33,7 +33,17 @@ Sections, cheapest first:
             enough to overflow a naive ``e^-G`` among them:
             kda [--heads N ...] [--parity]
 
-Usage:  python tools/tpu_tune.py [calib|flash|paged|retention|dsa|kda|all]
+  conv    — the one-token rows' convolution ALONE at the two cells that
+            run it (``solar2-agent-sat``: 256 rows x 24,576 channels, no
+            bias; ``nemo3-reason-sat``: 128 x 6,144, bias; kernel 4, a
+            bfloat16 pool): the tail's kernel at several (slots a grid step,
+            channels a tile) beside the XLA form (the tree's, and ``--parent
+            DIR``'s ``conv_step`` as it stands), ms a layer and the tail's
+            GB/s against 819; ``--parity`` holds the kernel against the XLA
+            form on the chip over several steps:
+            conv [--parent DIR] [--slots N ...] [--lanes N ...] [--parity]
+
+Usage:  python tools/tpu_tune.py [calib|flash|paged|retention|dsa|kda|conv|all]
 """
 import functools
 import json
@@ -1153,6 +1163,147 @@ def kda(argv=()):
                         / 2**20, 1))
 
 
+# The one-token rows' convolution of the two cells that run it: every slot
+# but the sink live, the rows dealt their slots in no order
+CONV_CELLS = {
+    "solar2-agent-sat": dict(layers=3, slots=257, rows=256, channels=24576,
+                             bias=False),
+    "nemo3-reason-sat": dict(layers=12, slots=129, rows=128, channels=6144,
+                             bias=True),
+}
+CONV_TAPS = 4
+CONV_SLOTS = (16, 32, 64)       # slots a grid step of the tail's kernel takes
+CONV_LANES = (2048, 4096, 8192)             # ... and channels
+CONV_PARITY_STEPS = 3
+
+
+def _conv_pool(c, seed=0):
+    """The cell's bfloat16 pool, drawn on the device."""
+    return jax.jit(lambda key: jax.random.normal(
+        key, (c["layers"], CONV_TAPS - 1, c["slots"], c["channels"]),
+        jnp.bfloat16))(jax.random.PRNGKey(seed))
+
+
+def _conv_rows(c, seed=0, step=0):
+    """``(x, w, bias, slots, keep)`` of one step: a slot a row in a shuffled
+    order, every 11th row the sink's, every 7th from zeros."""
+    rows, ch = c["rows"], c["channels"]
+    ks = jax.random.split(jax.random.PRNGKey(seed + 1), 3)
+    x = jax.random.normal(jax.random.fold_in(ks[0], step), (rows, ch),
+                          jnp.bfloat16)
+    w = 0.5 * jax.random.normal(ks[1], (CONV_TAPS, ch))
+    bias = jax.random.normal(ks[2], (ch,)) if c["bias"] else None
+    order = jax.random.permutation(jax.random.fold_in(ks[0], 100 + step),
+                                   c["slots"] - 1)[:rows]
+    at = jnp.arange(rows)
+    slots = jnp.where(at % 11 == 10, c["slots"] - 1, order).astype(jnp.int32)
+    return x, w, bias, slots, at % 7 != 6
+
+
+def _conv_program(name, fn, c, tag=0):
+    """Every layer's convolution step ``fn(x, w, bias, pool, layer, slots,
+    keep)`` in one program named ``name``, the pool donated; the results are
+    summed (an add of ``[rows, channels]`` float32 a layer among the XLA
+    operations of every row alike) from ``tag``: two rows whose programs
+    read the same would share ONE executable of the compile cache, and the
+    trace would name it once."""
+    def step(pool, x, w, bias, slots, keep):
+        def layer(i, carry):
+            pool, acc = carry
+            out, pool = fn(x, w, bias, pool, i, slots, keep)
+            return pool, acc + out
+        return jax.lax.fori_loop(
+            0, c["layers"], layer,
+            (pool, jnp.full(x.shape, float(tag), jnp.float32)))
+    step.__name__ = name
+    return jax.jit(step, donate_argnums=0)
+
+
+def _conv_parity(tree, cell, c):
+    """The tail's kernel against the XLA form ON THE CHIP at the cell's
+    shape: ``CONV_PARITY_STEPS`` steps one after another on every layer, the
+    rows dealt other slots at every step. The results of the rows on a slot
+    of their own and every slot's tail but the sink's."""
+    got = {}
+    for name in ("xla", "pallas"):
+        pool = _conv_pool(c)
+        prog = _conv_program(f"parity_{name}", tree.CONV_STEPS[name], c)
+        outs = []
+        for t in range(CONV_PARITY_STEPS):
+            args = _conv_rows(c, step=t)
+            pool, out = prog(pool, *args)
+            outs.append(jnp.where((args[3] != c["slots"] - 1)[:, None],
+                                  out, 0))
+        got[name] = (jnp.stack(outs), pool[:, :, :-1].astype(jnp.float32))
+    emit("conv_parity", cell=cell, steps=CONV_PARITY_STEPS,
+         out_max=float(jnp.max(jnp.abs(got["xla"][0]))),
+         out_err=float(jnp.max(jnp.abs(got["xla"][0] - got["pallas"][0]))),
+         tails_differ=int(jnp.sum(got["xla"][1] != got["pallas"][1])))
+
+
+def conv(argv=()):
+    """The one-token rows' convolution ALONE at the two cells' shapes, its
+    device time read off a profiler trace, every layer's step in one
+    program: ``xla`` (the tree's gather / convolve / scatter), ``--parent
+    DIR``'s ``conv_step`` as it stands, the tail's kernel under the rule's
+    own constants (``kernel_tree``) and at each of ``--slots`` slots a grid
+    step x ``--lanes`` channels a tile. Each row: ms a layer of the kernel
+    and of the XLA operations beside it (the token's cast, the row at each
+    slot, the sum of the results), and the TAIL's bytes (every row's ``k -
+    1`` rows read once and written once) a second of the two together
+    against 819 GB/s. ``--parity`` holds the kernel against the XLA form on
+    the chip first."""
+    import argparse
+
+    from deepspeedsyclsupport_tpu.ops import ssm as tree
+
+    ap = argparse.ArgumentParser(prog="tpu_tune.py conv")
+    ap.add_argument("--parent", default=None)
+    ap.add_argument("--cell", nargs="*", default=list(CONV_CELLS))
+    ap.add_argument("--slots", type=int, nargs="*", default=list(CONV_SLOTS))
+    ap.add_argument("--lanes", type=int, nargs="*", default=list(CONV_LANES))
+    ap.add_argument("--parity", action="store_true")
+    a = ap.parse_args(list(argv))
+    parent = a.parent and _load_op(
+        a.parent, "ssm", "deepspeedsyclsupport_tpu.ops.ssm_parent")
+    for cell in a.cell:
+        c = CONV_CELLS[cell]
+        if a.parity:
+            _conv_parity(tree, cell, c)
+        plan = {"xla": tree.CONV_STEPS["xla"],
+                "kernel_tree": tree.CONV_STEPS["pallas"]}
+        if parent:
+            plan["parent"] = parent.conv_step
+        plan.update({
+            f"kernel_{n}x{lanes}": functools.partial(
+                tree.CONV_STEPS["pallas"], slots_a_step=n, lanes=lanes)
+            for n in a.slots for lanes in a.lanes})
+        pool, args = _conv_pool(c), _conv_rows(c)
+        steps, failed = {}, {}
+        for tag, (name, fn) in enumerate(plan.items()):
+            try:
+                steps[name] = _conv_program(name, fn, c, tag).lower(
+                    pool, *args).compile()
+            except Exception as e:                 # e.g. over the VMEM limit
+                failed[name] = str(e).splitlines()[0][:160]
+        rows = _traced_kernels(steps, args, kernel_of=lambda text: "kernel",
+                               carry=pool)
+        moved = 2 * c["rows"] * (CONV_TAPS - 1) * c["channels"] * 2
+        for row in rows.values():
+            # the kernel's reading is a call's (a layer's), the XLA
+            # operations' a whole program's
+            ms = row.get("kernel", 0.0) + row["xla"] / c["layers"]
+            if not ms:                  # the trace holds nothing of it
+                continue
+            row["ms_a_layer"] = round(ms, 4)
+            row["tail_gb_s"] = round(moved / (ms * 1e-3) / 1e9, 1)
+            row["peak_pct"] = round(100 * moved / V5E_HBM / (ms * 1e-3), 1)
+        emit("conv", cell=cell, shape=c, tail_bytes_a_layer=moved,
+             rule=dict(zip(("slots", "lanes"),
+                           tree.conv_tile(c["rows"], c["channels"]))),
+             rows=rows, failed=failed)
+
+
 if __name__ == "__main__":
     which = sys.argv[1] if len(sys.argv) > 1 else "all"
     if which in ("calib", "all"):
@@ -1167,3 +1318,5 @@ if __name__ == "__main__":
         dsa(sys.argv[2:] if which == "dsa" else ())
     if which in ("kda", "all"):
         kda(sys.argv[2:] if which == "kda" else ())
+    if which in ("conv", "all"):
+        conv(sys.argv[2:] if which == "conv" else ())
