@@ -15,7 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark import parity, spec
+from tests.family_harness import (Harness, ending, engines,  # noqa: F401
+                                  family, moved)
 from tests.unit import stream_ends
 
 HF = {"model_type": "brumby", "hidden_size": 32, "num_hidden_layers": 2,
@@ -25,31 +26,19 @@ HF = {"model_type": "brumby", "hidden_size": 32, "num_hidden_layers": 2,
       "tie_word_embeddings": False}
 ENGINE = {"max_context": 128, "max_sequences": 4, "block_size": 8,
           "max_tokens_per_batch": 16}
+ENDING = {"max_context": 32}
 # both sides are float32 and differ in the FORM (a recurrence over a feature
 # map against attention weights): measured 3e-6 logit-std; the planted
 # faults measure 0.01 and more
 TOL = 1e-4
 PROMPTS = ([7, 3, 11, 100, 41, 9, 5], list(range(60, 101)))   # 7 and 41
-
-
-@pytest.fixture(scope="module")
-def family():
-    return spec.Bench().family(HF)
+H = Harness(HF, ENGINE, PROMPTS)
 
 
 def overrides(family):
     return {**family.program_widths(HF), "intermediate_size": 48,
             "max_seq_len": 256, "dtype": "float32", "retention_chunk_size": 8,
             "retention_half_life": (4.0, 64.0)}
-
-
-def moved(params, key=1, by=0.2):
-    """Every leaf off its init: the norms' scales start at one, the gate's
-    bias where a half-life put it."""
-    leaves, tree = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(key), len(leaves))
-    return jax.tree_util.tree_unflatten(tree, [
-        x + by * jax.random.normal(k, x.shape) for x, k in zip(leaves, keys)])
 
 
 @pytest.fixture(scope="module")
@@ -60,38 +49,7 @@ def built(family):
     widths.pop("num_kv_layers")
     model = build_model("brumby-14b", **widths)
     model.seed = 3
-    return model, moved(model.init_params())
-
-
-def engine_of(model, params, **engine):
-    import deepspeedsyclsupport_tpu as dstpu
-    from deepspeedsyclsupport_tpu.inference.v2.engine_v2 import (
-        InferenceEngineV2)
-
-    return InferenceEngineV2(
-        model, params, dtype="float32",
-        topology=dstpu.build_topology(dp=1, devices=jax.devices()[:1]),
-        **{**ENGINE, **engine})
-
-
-def reference(family, params, ids):
-    return np.asarray(family.sequence_logits(
-        family.arch(HF), params, jnp.asarray(ids, jnp.int32)))
-
-
-def served_errors(model, params, family, prompts=PROMPTS, n_follow=6,
-                  want_params=None, **engine):
-    """Worst row error of the served path over ``prompts`` (chunks of 16
-    rows, pieces of 8), ``n_follow`` decode steps each, against the
-    reference's forward of the whole sequence on ``want_params``."""
-    eng = engine_of(model, params, **engine)
-    worst = 0.0
-    for uid, prompt in enumerate(prompts):
-        logits, tokens = parity.served_logits(eng, uid, prompt, n_follow)
-        want = reference(family, want_params or params, prompt + tokens)
-        worst = max(worst, float(parity.row_errors(
-            logits, want[-len(logits):]).max()))
-    return worst
+    return model, moved(jax.jit(model.init_params)())
 
 
 # ------------------------------------------------------------ the structure
@@ -124,12 +82,11 @@ def test_the_uniform_block_with_a_gate_and_no_cached_key(built):
             ModelConfig(**{**dataclasses.asdict(cfg), **wrong})
 
 
-def test_the_state_pool_and_a_pool_with_no_rows(built):
+def test_the_state_pool_and_a_pool_with_no_rows(engines):
     from deepspeedsyclsupport_tpu.inference.v2.kv_cache import kv_pool_stats
     from deepspeedsyclsupport_tpu.ops.retention import state_dim
 
-    model, params = built
-    eng = engine_of(model, params)
+    eng = engines()
     kv, dim = eng.kv, state_dim(32)
     assert dim == 17 * 32
     # no layer caches a key: pools with no rows, two state leaves
@@ -154,12 +111,13 @@ def test_the_state_pool_and_a_pool_with_no_rows(built):
     res = eng.check_schedule([1, 2, 3, 4], [120] * 4)
     assert res.admitted == (1, 2, 3) and "slots" in res.reasons[4]
     assert "context" in eng.check_schedule([9], [29]).reasons[9]
+    eng.flush([9])
 
 
 # ------------------------------------------------ program against reference
 @pytest.mark.parametrize("step", ["xla", "pallas_interpret"])
-def test_chunked_prefill_then_decode_match_the_reference(built, family,
-                                                         monkeypatch, step):
+def test_chunked_prefill_then_decode_match_the_reference(built, monkeypatch,
+                                                         step):
     """41 tokens = three chunks of 16, 16 and 9 rows in pieces of 8 (every
     piece but the first starts from its slot's state), then six decode steps
     through the state pool."""
@@ -178,39 +136,15 @@ def test_chunked_prefill_then_decode_match_the_reference(built, family,
 
     monkeypatch.setitem(retention.PIECE_CARRIES, retention.default_impl(),
                         retention.PIECE_CARRIES[step])
-    assert served_errors(*built, family) < TOL
+    assert H.served_errors(*built) < TOL
 
 
-def test_a_mixed_round_and_a_slot_reused(built, family):
-    """Sequence A decodes while B's prompt comes in beside it (one-token
-    rows and pieces in ONE forward, each from its own slot); then A is
-    flushed and C takes its slot and starts from zero."""
-    model, params = built
-    eng = engine_of(model, params)
-    a, b = PROMPTS
-    la = [np.asarray(eng.put([1], [a])[1])]
-    toks_a = [int(la[-1].argmax())]
-    out = eng.put([1, 2], [[toks_a[-1]], b], drain=False)   # a mixed round
-    assert 1 in out and 2 not in out
-    la.append(np.asarray(out[1]))
-    lb = np.asarray(eng.put([], [])[2])                      # b's last chunks
-    want_a = reference(family, params, a + toks_a)
-    assert parity.row_errors(np.stack(la), want_a[-2:]).max() < TOL
-    assert parity.row_errors(lb[None], reference(family, params,
-                                                 b)[-1:]).max() < TOL
-    slot = eng.seqs[1].state_slot
-    assert eng.state_stats()["slots_live"] == 2
-    eng.flush([1])
-    assert eng.state_stats()["slots_live"] == 1
-    c = [5, 9, 2, 8, 1]
-    lc = np.asarray(eng.put([3], [c])[3])
-    assert eng.seqs[3].state_slot == slot      # A's place, A's state in it
-    assert parity.row_errors(lc[None], reference(family, params,
-                                                 c)[-1:]).max() < TOL
+def test_a_mixed_round_and_a_slot_reused(built, engines):
+    H.check_a_mixed_round_and_a_slot_reused(built[1], engines(), TOL)
 
 
 def test_eviction_under_requeue_finishes_with_the_references_tokens(
-        built, family):
+        built):
     """No block is ever wanted, so the session never evicts on its own; an
     operator's preemption (``requeue``) still takes a stream's slot, the
     stream is prefilled again from zero, prompt and emitted tokens, and
@@ -220,7 +154,7 @@ def test_eviction_under_requeue_finishes_with_the_references_tokens(
     from deepspeedsyclsupport_tpu.inference.v2.serving import ServingSession
 
     model, params = built
-    eng = engine_of(model, params, max_context=32)
+    eng = H.engine_of(model, params, max_context=32)
     sess = ServingSession(eng, ServingPolicyConfig(preempt_policy="requeue"))
     prompts = {1: [1, 2, 3], 2: [4, 5, 6], 3: [7, 8, 9]}
     for uid, p in prompts.items():
@@ -242,7 +176,7 @@ def test_eviction_under_requeue_finishes_with_the_references_tokens(
     assert eng.allocator.free_blocks == eng.allocator.num_blocks
     for uid, p in prompts.items():
         assert len(out[uid]) == 18
-        rows = reference(family, params, p + out[uid])[len(p) - 1:-1]
+        rows = H.reference(params, p + out[uid])[len(p) - 1:-1]
         picked = rows[np.arange(18), out[uid]]
         assert ((rows.max(-1) - picked) / rows.std(-1)).max() < TOL
 
@@ -276,11 +210,13 @@ FAULTS = ["state_not_carried", "gate_dropped", "normaliser_dropped",
 
 
 @pytest.mark.parametrize("fault", FAULTS)
-def test_a_planted_fault_is_refused(built, family, monkeypatch, fault):
+def test_a_planted_fault_is_refused(built, monkeypatch, fault):
     """Each misreading, served, against the reference of the RIGHT weights:
     beyond the tolerance by two orders or more (the bf16 state is a rounding
     of the state at every step, not a misreading, and is held to twice the
-    tolerance)."""
+    tolerance). The 41-token prompt runs in three chunks and six pieces, so
+    every fault shows in the logits of its last position: the prefill alone
+    is compiled and run."""
     from deepspeedsyclsupport_tpu.inference.v2 import kv_cache
     from deepspeedsyclsupport_tpu.ops import retention
 
@@ -309,8 +245,8 @@ def test_a_planted_fault_is_refused(built, family, monkeypatch, fault):
         wrong = _regrouped(params)
     elif fault == "state_in_bf16":
         monkeypatch.setattr(kv_cache, "RETENTION_STATE_DTYPE", jnp.bfloat16)
-    err = served_errors(model, wrong, family, PROMPTS[1:], 4,
-                        want_params=params)
+    err = H.served_errors(model, wrong, PROMPTS[1:], 0,
+                          want_params=params)
     assert err > (2 if fault == "state_in_bf16" else 100) * TOL, (fault, err)
 
 
@@ -331,15 +267,14 @@ def test_the_reference_is_the_attention_form_and_the_map_squares(family):
 
 
 # ------------------------------------------------------------------ scopes
-def test_the_layers_scopes_reach_the_compiled_programs(built):
+def test_the_layers_scopes_reach_the_compiled_programs(engines):
     """What the per-layer readers find by (``benchmark/scopes.py``): the
     three ``ret_*`` scopes in both forwards, and the chunked form's pieces
     under ``ret_chunk`` INSIDE ``ret_scan`` in the ragged forward alone (a
     decode step has no piece), apart from the one-token rows' state step."""
     from benchmark import scopes
 
-    model, params = built
-    eng = engine_of(model, params)
+    eng = engines()
     eng.warmup()
     labels = ("ret_proj", "ret_gate", "ret_scan", "ret_chunk")
     found = {name: set(scopes.instructions_under(c.as_text(), labels)
@@ -357,9 +292,10 @@ def test_the_layers_scopes_reach_the_compiled_programs(built):
 
 
 # ---------------------------------------------------------------- refusals
-def test_what_a_model_with_recurrent_state_refuses_says_why(built, tmp_path):
+def test_what_a_model_with_recurrent_state_refuses_says_why(built, engines,
+                                                            tmp_path):
     model, params = built
-    eng = engine_of(model, params)
+    eng = engines()
     with pytest.raises(NotImplementedError, match="Mamba-2 or power-"
                        "retention layers.*snapshot of the recurrent state "
                        "at every shared block boundary"):
@@ -389,8 +325,15 @@ def test_the_xla_and_the_pallas_interpret_steps_agree(built):
     gam = -jnp.abs(jax.random.normal(k[4], (6, 2))) * 0.1
     slots = jnp.asarray([3, 0, 5, 5, 4, 1])
     fresh = jnp.asarray([False, True, False, False, False, False])
-    got = {name: retention.decode_step(q, kk, v, gam, pools, 1, slots, fresh,
-                                       cfg, retention.STATE_STEPS[name])
+    def step(name):
+        """``decode_step`` through one state step, as ONE program (eagerly
+        every op of it is a program a shape)."""
+        return jax.jit(lambda q, k, v, gam, state, slots, fresh:
+                       retention.decode_step(q, k, v, gam, state, 1, slots,
+                                             fresh, cfg,
+                                             retention.STATE_STEPS[name]))
+
+    got = {name: step(name)(q, kk, v, gam, pools, slots, fresh)
            for name in ("xla", "pallas_interpret")}
     (ya, (sa, za)), (yb, (sb, zb)) = got["xla"], got["pallas_interpret"]
     live = np.asarray([0, 1, 3, 4])           # rows 2 and 3 wrote the sink
@@ -417,13 +360,11 @@ def test_the_xla_and_the_pallas_interpret_steps_agree(built):
     np.testing.assert_allclose(y_k, y, atol=2e-5, rtol=2e-5)
     np.testing.assert_allclose(s_k[:, :5], s_c[:, :5], atol=2e-5)
     np.testing.assert_allclose(z_k[:, :5], z_c[:, :5], atol=2e-5)
-    state, rows = pools, []
+    state, rows, one = pools, [], step("xla")
     for i in range(19):
         slot, first = (2, i == 0) if i < 11 else (0, False)
-        y_i, state = retention.decode_step(
-            q[i:i + 1], kk[i:i + 1], v[i:i + 1], gam[i:i + 1], state, 1,
-            jnp.asarray([slot]), jnp.asarray([first]), cfg,
-            retention.STATE_STEPS["xla"])
+        y_i, state = one(q[i:i + 1], kk[i:i + 1], v[i:i + 1], gam[i:i + 1],
+                         state, jnp.asarray([slot]), jnp.asarray([first]))
         rows.append(y_i[0])
     np.testing.assert_allclose(y[:19], np.stack(rows), atol=2e-4, rtol=2e-4)
     assert not np.asarray(y[19]).any()           # no piece lies there
@@ -440,6 +381,7 @@ def test_the_tiled_walk_agrees_at_the_cells_head_width(steps):
     fresh, and two rows of padding on the sink; two KV heads of five queries
     each. One step, and three one after another on the state the last left
     (the rows and their slots drawn anew, only the first step's row fresh)."""
+    import functools
     import types
 
     from deepspeedsyclsupport_tpu.ops import retention
@@ -459,11 +401,17 @@ def test_the_tiled_walk_agrees_at_the_cells_head_width(steps):
                          [0, 1, sink, 2, sink]])
     fresh = jnp.asarray([False, False, True, False, False])
     (sa, za), (sb, zb) = pools, pools
+    # (each form ONE program for all the steps: eagerly every op of a step
+    # is a program of its own)
+    step = {name: jax.jit(functools.partial(
+        lambda name, q, k, v, gam, state, slots, fresh: retention.decode_step(
+            q, k, v, gam, state, 0, slots, fresh, cfg,
+            retention.STATE_STEPS[name]), name))
+        for name in ("xla", "pallas_interpret")}
     for t in range(steps):
         (ya, (sa, za)), (yb, (sb, zb)) = (
-            retention.decode_step(q[t], kk[t], v[t], gam[t], state, 0,
-                                  slots[t], fresh & (t == 0), cfg,
-                                  retention.STATE_STEPS[name])
+            step[name](q[t], kk[t], v[t], gam[t], state, slots[t],
+                       fresh & (t == 0))
             for name, state in (("xla", (sa, za)),
                                 ("pallas_interpret", (sb, zb))))
         live = np.flatnonzero(np.asarray(slots[t]) != sink)
@@ -518,12 +466,6 @@ def test_the_state_step_refuses_a_head_its_two_buffers_do_not_hold(
 
 
 # ------------------------------------------------- a stream that ends early
-@pytest.fixture(scope="module")
-def ending(built):
-    model, params = built
-    return stream_ends.family(engine_of(model, params, max_context=32))
-
-
 @stream_ends.parametrize
 def test_a_stream_that_ends_early_gives_back_what_it_held(ending, driver,
                                                           end):

@@ -15,7 +15,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark import parity, spec
+from benchmark import parity
+from tests.family_harness import (Harness, engines, family,  # noqa: F401
+                                  moved)
 from tests.unit import stream_ends
 
 WINDOW, BLOCK, CHUNK = 16, 8, 16
@@ -32,8 +34,6 @@ HF = {"model_type": "cohere2_moe", "hidden_size": 64, "intermediate_size": 32,
       "num_shared_experts": 4, "norm_topk_prob": True,
       "expert_selection_fn": "sigmoid",
       "shared_expert_combination_strategy": "average"}
-ENGINE = {"max_context": 256, "max_sequences": 4, "block_size": BLOCK,
-          "max_tokens_per_batch": CHUNK, "num_blocks": 96}
 # both sides are float32 and differ in the FORM (a paged pool in two kinds,
 # split-half rotary, one wide shared GLU, grouped GEMMs against masks over a
 # whole sequence, interleaved rotary, four shared experts, one expert at a
@@ -46,11 +46,10 @@ PROMPTS = ([7, 3, 11, 100, 41, 9, 5], list(range(20, 61)),
 IMPLS = {"xla": dict(prefill_attn="xla", decode_attn="xla"),
          "kernel": dict(prefill_attn="kernel_interpret",
                         decode_attn="pallas_interpret", atom_q_size=8)}
-
-
-@pytest.fixture(scope="module")
-def family():
-    return spec.Bench().family(HF)
+ENGINE = {"max_context": 256, "max_sequences": 4, "block_size": BLOCK,
+          "max_tokens_per_batch": CHUNK, "num_blocks": 96, **IMPLS["xla"]}
+# (a bystander holds the first blocks of both pools in every comparison)
+H = Harness(HF, ENGINE, PROMPTS, bystander=True)
 
 
 def overrides(family, **more):
@@ -58,16 +57,6 @@ def overrides(family, **more):
             "dtype": "float32",
             # the routed experts at full weight
             "routed_write_share": None, **more}
-
-
-def moved(params, key=1, by=0.2):
-    """Every leaf off its init (the norms' scales start at one)."""
-    leaves, tree = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(key), len(leaves))
-    return jax.tree_util.tree_unflatten(tree, [
-        x + by * jax.random.normal(k, x.shape) * jnp.std(x)
-        if x.ndim > 1 else x + by * jax.random.normal(k, x.shape)
-        for x, k in zip(leaves, keys)])
 
 
 def build(family, **more):
@@ -80,62 +69,15 @@ def build(family, **more):
 
 @pytest.fixture(scope="module")
 def built(family):
+    """(Every matrix moved by its own spread: the norms' scales start at
+    one.)"""
     model = build(family)
     model.seed = 3
-    return model, moved(model.init_params())
-
-
-def engine_of(model, params, **engine):
-    import deepspeedsyclsupport_tpu as dstpu
-    from deepspeedsyclsupport_tpu.inference.v2.engine_v2 import (
-        InferenceEngineV2)
-
-    return InferenceEngineV2(
-        model, params, dtype="float32",
-        topology=dstpu.build_topology(dp=1, devices=jax.devices()[:1]),
-        **{**ENGINE, **IMPLS["xla"], **engine})
-
-
-def reference(family, params, ids):
-    return np.asarray(family.sequence_logits(
-        family.arch(HF), params, jnp.asarray(ids, jnp.int32)))
-
-
-def served_errors(model, params, family, prompts=PROMPTS, n_follow=6,
-                  want_params=None, eng=None, **engine):
-    """Worst row error of the served path over ``prompts`` (chunks of 16
-    rows), ``n_follow`` decode steps each, against the reference's forward
-    of the whole sequence on ``want_params``. A bystander holds the first
-    blocks of both pools, so that a table entry that reads 0 reads SOMEBODY
-    ELSE'S rows. ``eng``: an idle engine to run on (else a new one)."""
-    eng = eng or engine_of(model, params, **engine)
-    eng.put([99], [[1, 2, 3]])
-    worst = 0.0
-    for uid, prompt in enumerate(prompts):
-        logits, tokens = parity.served_logits(eng, uid, prompt, n_follow)
-        want = reference(family, want_params or params, prompt + tokens)
-        worst = max(worst, float(parity.row_errors(
-            logits, want[-len(logits):]).max()))
-    eng.flush([99])
-    return worst
-
-
-@pytest.fixture(scope="module")
-def engines(built):
-    """One engine an attention impl, shared by the tests that leave it idle
-    (building one compiles both forwards of eight unrolled layers)."""
-    made = {}
-
-    def engine(impl="xla"):
-        if impl not in made:
-            made[impl] = engine_of(*built, **IMPLS[impl])
-        assert not made[impl].seqs
-        return made[impl]
-    return engine
+    return model, moved(jax.jit(model.init_params)(), relative=True)
 
 
 # ------------------------------------------------------------ the structure
-def test_a_period_of_two_kinds_two_pools_and_no_bias(built):
+def test_a_period_of_two_kinds_two_pools_and_no_bias(built, engines):
     from deepspeedsyclsupport_tpu.inference.v2.kv_cache import (
         kv_pool_stats, window_blocks_a_sequence)
 
@@ -152,7 +94,7 @@ def test_a_period_of_two_kinds_two_pools_and_no_bias(built):
     # four shared experts of 32 as one GLU of 128; the head is the embedding
     assert layers["moe"]["shared"]["w_gate"].shape == (8, 64, 128)
     assert "lm_head" not in params
-    eng = engine_of(model, params)
+    eng = engines()
     bound = window_blocks_a_sequence(WINDOW, eng.config)
     assert bound == (WINDOW + CHUNK + 2 * BLOCK - 3) // BLOCK == 5
     assert eng.kv.k.shape == (2, 96 * BLOCK, 2, 16)
@@ -188,14 +130,14 @@ def test_what_the_preset_at_the_published_sizes_says():
 @pytest.mark.parametrize("probe", range(len(PROMPTS)),
                          ids=["under_window", "past_window_and_chunk",
                               "past_4_windows"])
-def test_chunked_prefill_then_decode_match_the_reference(built, family,
-                                                         engines, impl, probe):
+def test_chunked_prefill_then_decode_match_the_reference(built, engines, impl,
+                                                         probe):
     model, params = built
-    assert served_errors(model, params, family, PROMPTS[probe:probe + 1],
-                         eng=engines(impl)) < TOL
+    assert H.served_errors(model, params, PROMPTS[probe:probe + 1],
+                           eng=engines(**IMPLS[impl])) < TOL
 
 
-def test_a_mixed_round_of_three_sequences(built, family, engines):
+def test_a_mixed_round_of_three_sequences(built, engines):
     """A decode row, a whole short prompt and the first chunk of a longer
     one in ONE forward, each against its own tables of both pools."""
     model, params = built
@@ -207,7 +149,7 @@ def test_a_mixed_round_of_three_sequences(built, family, engines):
     out3 = eng.put([], [])
     for uid, ids, got in ((1, a + [tok], out[1]), (2, b, out[2]),
                           (3, c, out3[3])):
-        want = reference(family, params, ids)[-1:]
+        want = H.reference(params, ids)[-1:]
         assert parity.row_errors(np.asarray(got)[None], want).max() < TOL, uid
     eng.flush([1, 2, 3])
 
@@ -239,7 +181,10 @@ FAULTS = ["window_layers_run_full", "rotary_on_a_full_layer",
 @pytest.mark.parametrize("fault", FAULTS)
 def test_a_planted_fault_is_refused(built, family, monkeypatch, fault):
     """Each misreading, served on the SAME weights, against the reference:
-    beyond the tolerance by well over an order."""
+    beyond the tolerance by well over an order. The 41-token prompt runs in
+    three chunks past the window and the first freed block, so every fault
+    shows in the logits of its last position: the prefill alone is compiled
+    and run."""
     from deepspeedsyclsupport_tpu.inference.v2 import model as M
     from deepspeedsyclsupport_tpu.inference.v2.ragged import (
         SequenceDescriptor)
@@ -267,12 +212,12 @@ def test_a_planted_fault_is_refused(built, family, monkeypatch, fault):
         monkeypatch.setattr(
             SequenceDescriptor, "out_of_window",
             lambda d, window, bs: sound(d, window - bs, bs))
-    err = served_errors(model, params, family, PROMPTS[1:2], 4)
+    err = H.served_errors(model, params, PROMPTS[1:2], 0)
     assert err > 30 * TOL, (fault, err)
 
 
 # ------------------------------------------------------- session and pools
-def test_eviction_under_requeue_gives_both_lists_back(built, family):
+def test_eviction_under_requeue_gives_both_lists_back(built):
     """A full pool too small for three streams: the session evicts
     (``longest_context``), the stream is prefilled again, every stream ends
     with the tokens the reference's greedy choice gives, and at idle BOTH
@@ -282,7 +227,7 @@ def test_eviction_under_requeue_gives_both_lists_back(built, family):
     from deepspeedsyclsupport_tpu.inference.v2.serving import ServingSession
 
     model, params = built
-    eng = engine_of(model, params, num_blocks=14, max_context=64)
+    eng = H.engine_of(model, params, num_blocks=14, max_context=64)
     sess = ServingSession(eng, ServingPolicyConfig(
         admission="none", preempt_policy="requeue"))
     prompts = {1: list(range(1, 31)), 2: list(range(40, 70)),
@@ -303,7 +248,7 @@ def test_eviction_under_requeue_gives_both_lists_back(built, family):
     assert alloc.free_blocks == alloc.num_blocks == 34
     for uid, p in prompts.items():
         assert len(out[uid]) == 14
-        rows = reference(family, params, p + out[uid])[len(p) - 1:-1]
+        rows = H.reference(params, p + out[uid])[len(p) - 1:-1]
         picked = rows[np.arange(14), out[uid]]
         assert ((rows.max(-1) - picked) / rows.std(-1)).max() < TOL
 
@@ -391,8 +336,8 @@ def ending(family):
     repeat their first token, and the check wants an EOS mid-stream.)"""
     model = build(family, tie_embeddings=False)
     model.seed = 3
-    return stream_ends.family(engine_of(model, moved(model.init_params()),
-                                        max_context=64))
+    return stream_ends.family(H.engine_of(
+        model, moved(model.init_params(), relative=True), max_context=64))
 
 
 @stream_ends.parametrize

@@ -15,8 +15,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark import parity, spec
 from tests.unit import stream_ends
+from tests.family_harness import (Harness, ending, engines,  # noqa: F401
+                                  family, moved)
 
 HF = {
     "model_type": "nemotron_h", "hidden_size": 32, "num_hidden_layers": 5,
@@ -33,16 +34,13 @@ HF = {
 ENGINE = {"max_context": 128, "max_sequences": 4, "num_blocks": 64,
           "block_size": 8, "max_tokens_per_batch": 16,
           "prefill_attn": "xla", "decode_attn": "xla"}
+ENDING = {"max_context": 32, "num_blocks": 12}
 # both sides are float32 and differ in the order of summation and in the
 # FORM of the recurrence (chunked against token by token): measured 2e-6
 # logit-std; the planted faults measure 0.02 and more
 TOL = 1e-4
 PROMPTS = ([7, 3, 11, 100, 41, 9, 5], list(range(60, 101)))   # 7 and 41
-
-
-@pytest.fixture(scope="module")
-def family():
-    return spec.Bench().family(HF)
+H = Harness(HF, ENGINE, PROMPTS)
 
 
 def overrides(family, hf=HF):
@@ -54,53 +52,13 @@ def overrides(family, hf=HF):
             "routed_write_share": None}
 
 
-def moved(params, key=1, by=0.2):
-    """Every leaf off its init: norm scales start at one, D at one, the
-    routed experts small: where each sits would not matter otherwise."""
-    leaves, tree = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(key), len(leaves))
-    return jax.tree_util.tree_unflatten(tree, [
-        x + by * jax.random.normal(k, x.shape) for x, k in zip(leaves, keys)])
-
-
 @pytest.fixture(scope="module")
 def built(family):
     from deepspeedsyclsupport_tpu.models import build_model
 
     model = build_model("nemotron-3-nano", **overrides(family))
     model.seed = 3
-    return model, moved(model.init_params())
-
-
-def engine_of(model, params, **engine):
-    import deepspeedsyclsupport_tpu as dstpu
-    from deepspeedsyclsupport_tpu.inference.v2.engine_v2 import (
-        InferenceEngineV2)
-
-    return InferenceEngineV2(
-        model, params, dtype="float32",
-        topology=dstpu.build_topology(dp=1, devices=jax.devices()[:1]),
-        **{**ENGINE, **engine})
-
-
-def reference(family, params, ids):
-    return np.asarray(family.sequence_logits(
-        family.arch(HF), params, jnp.asarray(ids, jnp.int32)))
-
-
-def served_errors(model, params, family, prompts=PROMPTS, n_follow=6,
-                  want_params=None, **engine):
-    """Worst row error of the served path over ``prompts`` (chunks of 16
-    rows, pieces of 8), ``n_follow`` decode steps each, against the
-    reference's forward of the whole sequence on ``want_params``."""
-    eng = engine_of(model, params, **engine)
-    worst = 0.0
-    for uid, prompt in enumerate(prompts):
-        logits, tokens = parity.served_logits(eng, uid, prompt, n_follow)
-        want = reference(family, want_params or params, prompt + tokens)
-        worst = max(worst, float(parity.row_errors(
-            logits, want[-len(logits):]).max()))
-    return worst
+    return model, moved(jax.jit(model.init_params)())
 
 
 # ------------------------------------------------------------ the structure
@@ -154,13 +112,13 @@ def test_the_expert_width_is_stored_on_the_lanes(family):
     assert not np.asarray(moe["w_up"])[..., 136:].any()
     assert not np.asarray(moe["w_down"])[:, :, 136:].any()
     assert np.asarray(moe["w_up"])[..., 135].any()
-    assert served_errors(wide, params, family, PROMPTS[:1], 2) < TOL
+    assert H.served_errors(wide, params, PROMPTS[:1], 2) < TOL
 
 
 # ------------------------------------------------ program against reference
 @pytest.mark.parametrize("step", ["xla", "pallas_interpret"])
-def test_chunked_prefill_then_decode_match_the_reference(built, family,
-                                                         monkeypatch, step):
+def test_chunked_prefill_then_decode_match_the_reference(built, monkeypatch,
+                                                         step):
     """41 tokens = three chunks of 16, 16 and 9 rows in pieces of 8 (the
     second chunk starts from the first's state), then six decode steps
     through the state pool and the KV pool."""
@@ -174,83 +132,20 @@ def test_chunked_prefill_then_decode_match_the_reference(built, family,
         auto_eligible=lambda ctx: True)
     monkeypatch.setitem(reg._REGISTRY["ssm_step"], "first", first)
     assert model_v2._ssm_step_fn() is first.fn
-    assert served_errors(*built, family) < TOL
+    assert H.served_errors(*built) < TOL
 
 
 def test_both_forwards_through_the_tails_kernel_are_the_xla_forms(
         built, monkeypatch):
-    """The one-token rows' convolution through ``conv_tail_step`` (put first
-    in the registry, interpreted) against the XLA form, engine beside
-    engine: a prompt, a mixed round (A's one-token row beside B's pieces,
-    ``ragged_forward``), two decode rounds (``decode_forward``, two rows on
-    the sink): the same logits and the same pools in every slot but the
-    sink, the first state layer's tails bit for bit (what it is handed
-    has passed through no convolution; the later layers' through a silu
-    whose last bit the two forms round apart: the op's own test holds the
-    tails exactly, ``tests/unit/test_conv_tail.py``)."""
-    from deepspeedsyclsupport_tpu.inference.v2 import model as model_v2
-    from deepspeedsyclsupport_tpu.inference.v2 import module_registry as reg
-
-    def drive():
-        eng = engine_of(*built)
-        a, b = PROMPTS
-        rows = [np.asarray(eng.put([1], [a])[1])]
-        tok = int(rows[-1].argmax())
-        rows.append(np.asarray(eng.put([1, 2], [[tok], b], drain=False)[1]))
-        rows.append(np.asarray(eng.put([], [])[2]))
-        for _ in range(2):
-            out = eng.put([1, 2], [[int(rows[-2].argmax())],
-                                   [int(rows[-1].argmax())]])
-            rows += [np.asarray(out[1]), np.asarray(out[2])]
-        assert {"ragged_forward", "decode_forward"} <= set(eng._dispatched)
-        return np.stack(rows), [np.asarray(p) for p in eng.kv.state]
-
-    want, pools = drive()
-    first = dataclasses.replace(
-        reg.get_impl("conv_step", "pallas_interpret"), name="first",
-        priority=100, auto_eligible=lambda ctx: True)
-    monkeypatch.setitem(reg._REGISTRY["conv_step"], "first", first)
-    assert model_v2._conv_step_fn() is first.fn
-    got, pools_k = drive()
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(pools_k[0][:, :-1], pools[0][:, :-1],
-                               rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(pools_k[1][:, :, :-1], pools[1][:, :, :-1],
-                               rtol=1e-5, atol=1e-5)
-    np.testing.assert_array_equal(pools_k[1][0, :, :-1],
-                                  pools[1][0, :, :-1])
+    H.check_the_tails_kernel_is_the_xla_form(built, monkeypatch)
 
 
-def test_a_mixed_round_and_a_slot_reused(built, family):
-    """Sequence A decodes while B's prompt comes in beside it (one-token
-    rows and pieces in ONE forward, each from its own slot); then A is
-    flushed and C takes its slot and starts from zero."""
-    model, params = built
-    eng = engine_of(model, params)
-    a, b = PROMPTS
-    la = [np.asarray(eng.put([1], [a])[1])]
-    toks_a = [int(la[-1].argmax())]
-    out = eng.put([1, 2], [[toks_a[-1]], b], drain=False)   # a mixed round
-    assert 1 in out and 2 not in out
-    la.append(np.asarray(out[1]))
-    lb = np.asarray(eng.put([], [])[2])                      # b's last chunks
-    want_a = reference(family, params, a + toks_a)
-    assert parity.row_errors(np.stack(la), want_a[-2:]).max() < TOL
-    assert parity.row_errors(lb[None], reference(family, params,
-                                                 b)[-1:]).max() < TOL
-    slot = eng.seqs[1].state_slot
-    assert eng.state_stats()["slots_live"] == 2
-    eng.flush([1])
-    assert eng.state_stats()["slots_live"] == 1
-    c = [5, 9, 2, 8, 1]
-    lc = np.asarray(eng.put([3], [c])[3])
-    assert eng.seqs[3].state_slot == slot      # A's place, A's state in it
-    assert parity.row_errors(lc[None], reference(family, params,
-                                                 c)[-1:]).max() < TOL
+def test_a_mixed_round_and_a_slot_reused(built, engines):
+    H.check_a_mixed_round_and_a_slot_reused(built[1], engines(), TOL)
 
 
 def test_eviction_under_requeue_finishes_with_the_references_tokens(
-        built, family):
+        built):
     """A pool of 6 blocks under three streams that want 9: the session
     evicts, prefills again (a state slot from zero) and every stream ends
     with the tokens the reference's greedy choice gives."""
@@ -259,7 +154,7 @@ def test_eviction_under_requeue_finishes_with_the_references_tokens(
     from deepspeedsyclsupport_tpu.inference.v2.serving import ServingSession
 
     model, params = built
-    eng = engine_of(model, params, num_blocks=6, max_context=32)
+    eng = H.engine_of(model, params, num_blocks=6, max_context=32)
     sess = ServingSession(eng, ServingPolicyConfig(preempt_policy="requeue"))
     prompts = {1: [1, 2, 3], 2: [4, 5, 6], 3: [7, 8, 9]}
     for uid, p in prompts.items():
@@ -276,7 +171,7 @@ def test_eviction_under_requeue_finishes_with_the_references_tokens(
     assert eng.state_stats()["slots_live"] == 0
     for uid, p in prompts.items():
         assert len(out[uid]) == 18
-        rows = reference(family, params, p + out[uid])[len(p) - 1:-1]
+        rows = H.reference(params, p + out[uid])[len(p) - 1:-1]
         picked = rows[np.arange(18), out[uid]]
         assert ((rows.max(-1) - picked) / rows.std(-1)).max() < TOL
 
@@ -341,12 +236,14 @@ FAULTS = {
 
 
 @pytest.mark.parametrize("fault", sorted(FAULTS))
-def test_a_planted_fault_is_refused(built, family, monkeypatch, fault):
+def test_a_planted_fault_is_refused(built, monkeypatch, fault):
     """Each misreading of the publication, served, against the reference
     of the RIGHT weights: beyond the tolerance by two orders or more (the
     bf16 state reads 4.7e-4 against the right program's 2e-6: it is a
     rounding of the state at every step, not a misreading, and is held to
-    twice the tolerance)."""
+    twice the tolerance). The 41-token prompt runs in three chunks and six
+    pieces, so every fault shows in the logits of its last position: the
+    prefill alone is compiled and run."""
     from deepspeedsyclsupport_tpu.models import build_model
 
     model, params = built
@@ -371,21 +268,20 @@ def test_a_planted_fault_is_refused(built, family, monkeypatch, fault):
         from deepspeedsyclsupport_tpu.ops import ssm
 
         monkeypatch.setattr(ssm, "gated_norm", _ungrouped_norm)
-    err = served_errors(model, wrong, family, PROMPTS[1:], 2,
-                        want_params=params)
+    err = H.served_errors(model, wrong, PROMPTS[1:], 0,
+                          want_params=params)
     assert err > (2 if fault == "state_in_bf16" else 100) * TOL, err
 
 
 # ------------------------------------------------------------------ scopes
-def test_the_mixers_scopes_reach_the_compiled_programs(built):
+def test_the_mixers_scopes_reach_the_compiled_programs(engines):
     """What the per-layer readers find by (``benchmark/scopes.py``): the
     four ``ssm_*`` scopes in both forwards, and the chunked scan's pieces
     under ``ssm_chunk`` INSIDE ``ssm_scan`` in the ragged forward alone (a
     decode step has no piece), apart from the one-token rows' state step."""
     from benchmark import scopes
 
-    model, params = built
-    eng = engine_of(model, params)
+    eng = engines()
     eng.warmup()
     labels = ("ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate", "ssm_chunk")
     found = {name: set(scopes.instructions_under(c.as_text(), labels)
@@ -404,9 +300,10 @@ def test_the_mixers_scopes_reach_the_compiled_programs(built):
 
 
 # ---------------------------------------------------------------- refusals
-def test_what_a_model_with_recurrent_state_refuses_says_why(built, tmp_path):
+def test_what_a_model_with_recurrent_state_refuses_says_why(built, engines,
+                                                            tmp_path):
     model, params = built
-    eng = engine_of(model, params)
+    eng = engines()
     with pytest.raises(NotImplementedError, match="Mamba-2 or power-"
                        "retention layers.*snapshot of the "
                        "recurrent state at every shared block boundary"):
@@ -418,7 +315,7 @@ def test_what_a_model_with_recurrent_state_refuses_says_why(built, tmp_path):
                        match="chunked scan's backward is not written"):
         model.apply(params, jnp.zeros((1, 8), jnp.int32))
     # nothing of this for a model without state
-    assert engine_of(*_plain()).state_stats() is None
+    assert H.engine_of(*_plain()).state_stats() is None
 
 
 def _plain():
@@ -428,9 +325,8 @@ def _plain():
     return model, model.init_params()
 
 
-def test_the_state_pool_and_its_stats(built):
-    model, params = built
-    eng = engine_of(model, params)
+def test_the_state_pool_and_its_stats(engines):
+    eng = engines()
     kv = eng.kv
     # [Mamba layers, slots + the sink, groups, state, heads-in-group x dim]
     assert kv.ssm.shape == (2, 5, 2, 16, 16) and kv.ssm.dtype == jnp.float32
@@ -462,8 +358,14 @@ def test_the_xla_and_the_pallas_interpret_scans_agree(built):
     xbc, dt = jax.random.normal(k[2], (6, 96)), jax.random.normal(k[3], (6, 4))
     slots = jnp.asarray([3, 0, 5, 5, 4, 1])
     fresh = jnp.asarray([False, True, False, False, False, False])
-    got = {name: ssm.decode_step(xbc, dt, p, pool, conv, 1, slots, fresh,
-                                 cfg, ssm.STATE_STEPS[name])
+    def step(name):
+        """``decode_step`` through one state step, as ONE program (eagerly
+        every op of it is a program a shape)."""
+        return jax.jit(lambda xbc, dt, pool, conv, slots, fresh:
+                       ssm.decode_step(xbc, dt, p, pool, conv, 1, slots,
+                                       fresh, cfg, ssm.STATE_STEPS[name]))
+
+    got = {name: step(name)(xbc, dt, pool, conv, slots, fresh)
            for name in ("xla", "pallas_interpret")}
     for a, b in zip(got["xla"], got["pallas_interpret"]):
         live = np.asarray([0, 1, 2, 3, 4])     # the sink holds anything
@@ -484,13 +386,11 @@ def test_the_xla_and_the_pallas_interpret_scans_agree(built):
               jnp.asarray(3))
     y, ssm_c, conv_c = ssm.chunked_scan(xbc, dt, p, pool, conv, 1, pieces,
                                         cfg)
-    ssm_s, conv_s, rows = pool, conv, []
+    ssm_s, conv_s, rows, one = pool, conv, [], step("xla")
     for i in range(19):
         slot, first = (2, i == 0) if i < 11 else (0, False)
-        y_i, ssm_s, conv_s = ssm.decode_step(
-            xbc[i:i + 1], dt[i:i + 1], p, ssm_s, conv_s, 1,
-            jnp.asarray([slot]), jnp.asarray([first]), cfg,
-            ssm.STATE_STEPS["xla"])
+        y_i, ssm_s, conv_s = one(xbc[i:i + 1], dt[i:i + 1], ssm_s, conv_s,
+                                 jnp.asarray([slot]), jnp.asarray([first]))
         rows.append(y_i[0])
     np.testing.assert_allclose(y[:19], np.stack(rows), atol=2e-5)
     assert not np.asarray(y[19]).any()           # no piece lies there
@@ -499,13 +399,6 @@ def test_the_xla_and_the_pallas_interpret_scans_agree(built):
 
 
 # ------------------------------------------------- a stream that ends early
-@pytest.fixture(scope="module")
-def ending(built):
-    model, params = built
-    return stream_ends.family(engine_of(model, params, max_context=32,
-                                        num_blocks=12))
-
-
 @stream_ends.parametrize
 def test_a_stream_that_ends_early_gives_back_what_it_held(ending, driver,
                                                           end):

@@ -18,7 +18,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark import parity, spec
+from benchmark import parity
+from tests.family_harness import (Harness, ending, engines,  # noqa: F401
+                                  family, moved)
 from tests.unit import stream_ends
 
 HF = {
@@ -45,11 +47,8 @@ ENGINE = {"max_context": 128, "max_sequences": 4, "num_blocks": 64,
 # 1.2 and more, a bf16 state 4.4e-3
 TOL = 1e-4
 PROMPTS = ([7, 3, 11, 100, 41, 9, 5], list(range(60, 101)))   # 7 and 41
-
-
-@pytest.fixture(scope="module")
-def family():
-    return spec.Bench().family(HF)
+ENDING = {"max_context": 32, "num_blocks": 12}
+H = Harness(HF, ENGINE, PROMPTS)
 
 
 def overrides(family, hf=HF):
@@ -60,71 +59,13 @@ def overrides(family, hf=HF):
             "routed_write_share": None}
 
 
-def moved(params, key=1, by=0.2):
-    """Every leaf off its init: norm scales start at one, the routed experts
-    small: where each sits would not matter otherwise. (Drawn on the host:
-    a draw a leaf on the device is a program a shape to compile.)"""
-    rng = np.random.default_rng(key)
-    return jax.tree_util.tree_map(
-        lambda x: x + jnp.asarray(
-            by * rng.standard_normal(x.shape), x.dtype), params)
-
-
 @pytest.fixture(scope="module")
 def built(family):
     from deepspeedsyclsupport_tpu.models import build_model
 
     model = build_model("solar-open2", **overrides(family))
     model.seed = 3
-    return model, moved(model.init_params())
-
-
-def engine_of(model, params, **engine):
-    import deepspeedsyclsupport_tpu as dstpu
-    from deepspeedsyclsupport_tpu.inference.v2.engine_v2 import (
-        InferenceEngineV2)
-
-    return InferenceEngineV2(
-        model, params, dtype="float32",
-        topology=dstpu.build_topology(dp=1, devices=jax.devices()[:1]),
-        **{**ENGINE, **engine})
-
-
-_WANT, _WALK = {}, {}
-PAD = 48            # the longest sequence here is 41 + 6 tokens
-
-
-def reference(family, params, ids, hf=HF):
-    """The plain reference's logits, kept by (tree, sequence): the eight
-    planted faults are all held against ONE forward of the right program.
-    The reference is causal and takes one sequence, so every sequence runs
-    padded to ``PAD`` tokens through ONE compiled walk a depth and its own
-    rows are read off the front."""
-    depth = hf["num_hidden_layers"]
-    key = (id(params), tuple(ids), depth)
-    if key not in _WANT:
-        if depth not in _WALK:
-            arch = family.arch(hf)
-            _WALK[depth] = jax.jit(
-                lambda p, x: family.sequence_logits(arch, p, x))
-        padded = jnp.asarray(list(ids) + [0] * (PAD - len(ids)), jnp.int32)
-        _WANT[key] = np.asarray(_WALK[depth](params, padded))[:len(ids)]
-    return _WANT[key]
-
-
-def served_errors(model, params, family, prompts=PROMPTS, n_follow=6,
-                  want_params=None, **engine):
-    """Worst row error of the served path over ``prompts`` (chunks of 16
-    rows, pieces of 8), ``n_follow`` decode steps each, against the
-    reference's forward of the whole sequence on ``want_params``."""
-    eng = engine_of(model, params, **engine)
-    worst = 0.0
-    for uid, prompt in enumerate(prompts):
-        logits, tokens = parity.served_logits(eng, uid, prompt, n_follow)
-        want = reference(family, want_params or params, prompt + tokens)
-        worst = max(worst, float(parity.row_errors(
-            logits, want[-len(logits):]).max()))
-    return worst
+    return model, moved(jax.jit(model.init_params)())
 
 
 # ------------------------------------------------------------ the structure
@@ -194,8 +135,8 @@ def test_what_the_pattern_refuses_says_why(built, wrong, says):
 
 # ------------------------------------------------ program against reference
 @pytest.mark.parametrize("step", ["xla", "pallas_interpret"])
-def test_chunked_prefill_then_decode_match_the_reference(built, family,
-                                                         monkeypatch, step):
+def test_chunked_prefill_then_decode_match_the_reference(built, monkeypatch,
+                                                         step):
     """41 tokens = three chunks of 16, 16 and 9 rows in pieces of 8 (the
     second chunk starts from the first's state and tail), then six decode
     steps through the state pool and the KV pool."""
@@ -209,7 +150,7 @@ def test_chunked_prefill_then_decode_match_the_reference(built, family,
         auto_eligible=lambda ctx: True)
     monkeypatch.setitem(reg._REGISTRY["kda_step"], "first", first)
     assert model_v2._kda_step_fn() is first.fn
-    assert served_errors(*built, family) < TOL
+    assert H.served_errors(*built) < TOL
 
 
 def test_two_periods_scan_as_the_presets_twelve_do(family):
@@ -225,82 +166,19 @@ def test_two_periods_scan_as_the_presets_twelve_do(family):
     assert layer_plan(model.config.layer_pattern) == [("*EKEKEKE", 2)]
     model.seed = 5
     params = moved(model.init_params())
-    eng = engine_of(model, params)
+    eng = H.engine_of(model, params)
     logits, tokens = parity.served_logits(eng, 0, PROMPTS[1], 0)
-    want = reference(family, params, PROMPTS[1] + tokens, hf)
+    want = H.reference(params, PROMPTS[1] + tokens, hf)
     assert parity.row_errors(logits, want[-len(logits):]).max() < TOL
 
 
 def test_both_forwards_through_the_tails_kernel_are_the_xla_forms(
         built, monkeypatch):
-    """The one-token rows' convolution through ``conv_tail_step`` (put first
-    in the registry, interpreted) against the XLA form, engine beside
-    engine: a prompt, a mixed round (A's one-token row beside B's pieces,
-    ``ragged_forward``), two decode rounds (``decode_forward``, two rows on
-    the sink): the same logits and the same pools in every slot but the
-    sink, the first state layer's tails bit for bit (what it is handed
-    has passed through no convolution; the later layers' through a silu
-    whose last bit the two forms round apart: the op's own test holds the
-    tails exactly, ``tests/unit/test_conv_tail.py``)."""
-    from deepspeedsyclsupport_tpu.inference.v2 import model as model_v2
-    from deepspeedsyclsupport_tpu.inference.v2 import module_registry as reg
-
-    def drive():
-        eng = engine_of(*built)
-        a, b = PROMPTS
-        rows = [np.asarray(eng.put([1], [a])[1])]
-        tok = int(rows[-1].argmax())
-        rows.append(np.asarray(eng.put([1, 2], [[tok], b], drain=False)[1]))
-        rows.append(np.asarray(eng.put([], [])[2]))
-        for _ in range(2):
-            out = eng.put([1, 2], [[int(rows[-2].argmax())],
-                                   [int(rows[-1].argmax())]])
-            rows += [np.asarray(out[1]), np.asarray(out[2])]
-        assert {"ragged_forward", "decode_forward"} <= set(eng._dispatched)
-        return np.stack(rows), [np.asarray(p) for p in eng.kv.state]
-
-    want, pools = drive()
-    first = dataclasses.replace(
-        reg.get_impl("conv_step", "pallas_interpret"), name="first",
-        priority=100, auto_eligible=lambda ctx: True)
-    monkeypatch.setitem(reg._REGISTRY["conv_step"], "first", first)
-    assert model_v2._conv_step_fn() is first.fn
-    got, pools_k = drive()
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
-    np.testing.assert_allclose(pools_k[0][:, :-1], pools[0][:, :-1],
-                               rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(pools_k[1][:, :, :-1], pools[1][:, :, :-1],
-                               rtol=1e-5, atol=1e-5)
-    np.testing.assert_array_equal(pools_k[1][0, :, :-1],
-                                  pools[1][0, :, :-1])
+    H.check_the_tails_kernel_is_the_xla_form(built, monkeypatch)
 
 
-def test_a_mixed_round_and_a_slot_reused(built, family):
-    """Sequence A decodes while B's prompt comes in beside it (one-token
-    rows and pieces in ONE forward, each from its own slot); then A is
-    flushed and C takes its slot and starts from zero."""
-    model, params = built
-    eng = engine_of(model, params)
-    a, b = PROMPTS
-    la = [np.asarray(eng.put([1], [a])[1])]
-    toks_a = [int(la[-1].argmax())]
-    out = eng.put([1, 2], [[toks_a[-1]], b], drain=False)   # a mixed round
-    assert 1 in out and 2 not in out
-    la.append(np.asarray(out[1]))
-    lb = np.asarray(eng.put([], [])[2])                      # b's last chunks
-    want_a = reference(family, params, a + toks_a)
-    assert parity.row_errors(np.stack(la), want_a[-2:]).max() < TOL
-    assert parity.row_errors(lb[None], reference(family, params,
-                                                 b)[-1:]).max() < TOL
-    slot = eng.seqs[1].state_slot
-    assert eng.state_stats()["slots_live"] == 2
-    eng.flush([1])
-    assert eng.state_stats()["slots_live"] == 1
-    c = [5, 9, 2, 8, 1]
-    lc = np.asarray(eng.put([3], [c])[3])
-    assert eng.seqs[3].state_slot == slot      # A's place, A's state in it
-    assert parity.row_errors(lc[None], reference(family, params,
-                                                 c)[-1:]).max() < TOL
+def test_a_mixed_round_and_a_slot_reused(built, engines):
+    H.check_a_mixed_round_and_a_slot_reused(built[1], engines(), TOL)
 
 
 def test_the_four_shares_add_up_to_the_uncut_layer(built, family):
@@ -398,7 +276,7 @@ FAULTS = {
 
 
 @pytest.mark.parametrize("fault", sorted(FAULTS))
-def test_a_planted_fault_is_refused(built, family, monkeypatch, fault):
+def test_a_planted_fault_is_refused(built, monkeypatch, fault):
     """Each misreading of the publication, served, against the reference of
     the RIGHT program: beyond the tolerance by an order or more (a bf16
     state is a rounding of the state at every step, not a misreading, and is
@@ -412,12 +290,12 @@ def test_a_planted_fault_is_refused(built, family, monkeypatch, fault):
         model = build_model(dataclasses.replace(model.config,
                                                 **FAULTS[fault]))
     _plant(monkeypatch, fault)
-    err = served_errors(model, params, family, PROMPTS[1:], 0)
+    err = H.served_errors(model, params, PROMPTS[1:], 0)
     assert err > (2 if fault == "state_in_bf16" else 10) * TOL, err
 
 
 # ------------------------------------------------------------------ scopes
-def test_the_mixers_scopes_reach_the_compiled_programs(built):
+def test_the_mixers_scopes_reach_the_compiled_programs(engines):
     """What the per-layer readers find by (``benchmark/scopes.py``): the
     ``kda_*`` scopes and the attention's gate in both forwards, the state
     step under ``kda_step`` INSIDE ``kda_scan`` in both, and the pieces
@@ -425,8 +303,7 @@ def test_the_mixers_scopes_reach_the_compiled_programs(built):
     decode step has no piece)."""
     from benchmark import scopes
 
-    model, params = built
-    eng = engine_of(model, params)
+    eng = engines()
     eng.warmup()
     labels = ("kda_proj", "kda_conv", "kda_gate", "kda_step", "attn_gate",
               "kda_chunk")
@@ -445,10 +322,10 @@ def test_the_mixers_scopes_reach_the_compiled_programs(built):
 
 
 # ---------------------------------------------------------------- refusals
-def test_what_a_model_with_a_delta_rule_state_refuses_says_why(built,
-                                                               tmp_path):
+def test_what_a_model_with_a_delta_rule_state_refuses_says_why(
+        built, engines, tmp_path):
     model, params = built
-    eng = engine_of(model, params)
+    eng = engines()
     with pytest.raises(NotImplementedError, match="delta-rule ones.*snapshot"
                        " of the recurrent state at every shared block "
                        "boundary"):
@@ -462,9 +339,8 @@ def test_what_a_model_with_a_delta_rule_state_refuses_says_why(built,
         model.apply(params, jnp.zeros((1, 8), jnp.int32))
 
 
-def test_the_state_pool_and_its_stats(built):
-    model, params = built
-    eng = engine_of(model, params)
+def test_the_state_pool_and_its_stats(engines):
+    eng = engines()
     kv = eng.kv
     # [delta-rule layers, slots + the sink, heads, key channels, value ones]
     assert kv.kda_s.shape == (3, 5, 4, 16, 16)
@@ -483,13 +359,6 @@ def test_the_state_pool_and_its_stats(built):
 
 
 # ------------------------------------------------- a stream that ends early
-@pytest.fixture(scope="module")
-def ending(built):
-    model, params = built
-    return stream_ends.family(engine_of(model, params, max_context=32,
-                                        num_blocks=12))
-
-
 @stream_ends.parametrize
 def test_a_stream_that_ends_early_gives_back_what_it_held(ending, driver,
                                                           end):

@@ -811,3 +811,33 @@ class TestJaxProfilerHook:
             found.extend(f for f in files if "trace" in f or
                          f.endswith((".pb", ".json.gz", ".xplane.pb")))
         assert found, f"no trace artifacts under {trace_dir}"
+
+
+def test_tier1_times_reads_the_drivers_junit(tmp_path):
+    """``tools/tier1_times.py``: the wall, the sum, the sum by file (a class
+    belongs to its file) and the cases of 10 s or more, off a three-case
+    junit."""
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(__file__), "..", "..", "tools",
+                        "tier1_times.py")
+    spec = importlib.util.spec_from_file_location("tier1_times", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    junit = tmp_path / "t1.xml"
+    junit.write_text(
+        '<?xml version="1.0" encoding="utf-8"?><testsuites name="pytest">'
+        '<testsuite name="pytest" errors="0" failures="0" skipped="0" '
+        'tests="3" time="20.5">'
+        '<testcase classname="tests.unit.test_a.TestX" name="test_p[1]" '
+        'time="12.25" />'
+        '<testcase classname="tests.unit.test_a" name="test_q" time="0.75" />'
+        '<testcase classname="tests.test_b" name="test_r" time="3.0" />'
+        '</testsuite></testsuites>')
+    lines = tool.report(str(junit)).splitlines()
+    assert lines[0] == ("wall 20 s, sum 16 s, 3 cases, 1 of 10 s or more "
+                        "(12 s)")
+    assert lines[3].split() == ["13.0", "2", "tests.unit.test_a"]
+    assert lines[4].split() == ["3.0", "1", "tests.test_b"]
+    assert lines[-1].split() == ["12.2", "tests.unit.test_a.TestX::test_p[1]"]

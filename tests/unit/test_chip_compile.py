@@ -50,6 +50,13 @@ def one_chip():
         cc.reset_cache()
 
 
+def _once(compile_case):
+    """A case's compiled program, kept at module scope: the cases below that
+    inspect the same program's text compile it once (the sharding is the
+    module's one; the widths are the case's own)."""
+    return functools.lru_cache(maxsize=None)(compile_case)
+
+
 def _compile(f, one_chip, *shapes):
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
             for s, dt in shapes]
@@ -82,6 +89,7 @@ def _flash_fn(window, grad):
                     argnums=(0, 1, 2))
 
 
+@_once
 def _flash_compiled(one_chip, name, grad):
     case = FLASH_CASES[name]
     h, kvh, d = _widths(case["preset"])
@@ -149,6 +157,7 @@ def _with_layer(kernel, form, **static):
     return (lambda *args: f(*args[:-1], layer=args[-1])), (((), jnp.int32),)
 
 
+@_once
 def _paged_decode(one_chip, name, form="layer_cache"):
     cfg, h, d, cache = _paged_shapes(name, form)
     s = cfg.max_sequences
@@ -166,6 +175,7 @@ def test_paged_decode_compiles(one_chip, name, form):
     _paged_decode(one_chip, name, form)
 
 
+@_once
 def _ragged_default_atom(one_chip, name, form="layer_cache"):
     cfg, h, d, cache = _paged_shapes(name, form)
     bq = cfg.atom_q_size   # the DEFAULT atom: no user-picked atom_q_size

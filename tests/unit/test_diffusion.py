@@ -46,15 +46,17 @@ class TestVAE:
         cfg = VAEConfig(base_channels=8, channel_mults=(1, 2),
                         latent_channels=4)
         model = AutoencoderKL(cfg)
-        return model, model.init_params(jax.random.PRNGKey(0))
+        # (one program: a draw a leaf is one each otherwise)
+        return model, jax.jit(model.init_params)(jax.random.PRNGKey(0))
 
     def test_encode_decode_shapes(self, vae):
         model, params = vae
         x = jax.random.normal(jax.random.PRNGKey(1), (2, 16, 16, 3))
-        mean, logvar = model.encode(params, x)
+        # (each walk one program: eagerly every conv of it is one a shape)
+        mean, logvar = jax.jit(model.encode)(params, x)
         # one downsample level (len(mults)-1 = 1) → /2 spatial
         assert mean.shape == (2, 8, 8, 4) and logvar.shape == mean.shape
-        rec = model.decode(params, mean)
+        rec = jax.jit(model.decode)(params, mean)
         assert rec.shape == x.shape
 
     def test_trains_through_engine(self, vae):
@@ -81,33 +83,39 @@ class TestUNet:
                          attn_levels=(1,), num_heads=2,
                          cross_attention_dim=16)
         model = UNet2DCondition(cfg)
-        return model, model.init_params(jax.random.PRNGKey(0))
+        return model, jax.jit(model.init_params)(jax.random.PRNGKey(0))
 
-    def test_forward_shapes_all_resolutions(self, unet):
+    @pytest.fixture(scope="class")
+    def forward(self, unet):
+        """``model.apply`` as one program a shape (eagerly every conv and
+        norm of the walk is a program of its own: 40 s for two shapes)."""
+        return jax.jit(unet[0].apply)
+
+    def test_forward_shapes_all_resolutions(self, unet, forward):
         model, params = unet
         for hw in (8, 16):
             x = jax.random.normal(jax.random.PRNGKey(1), (2, hw, hw, 4))
             ctx = jax.random.normal(jax.random.PRNGKey(2), (2, 5, 16))
-            out = model.apply(params, x, jnp.array([3, 700]), ctx)
+            out = forward(params, x, jnp.array([3, 700]), ctx)
             assert out.shape == (2, hw, hw, 4)
 
-    def test_conditioning_matters(self, unet):
+    def test_conditioning_matters(self, unet, forward):
         """Cross-attention actually conditions the output."""
         model, params = unet
         x = jax.random.normal(jax.random.PRNGKey(3), (1, 8, 8, 4))
         c1 = jax.random.normal(jax.random.PRNGKey(4), (1, 5, 16))
         c2 = jax.random.normal(jax.random.PRNGKey(5), (1, 5, 16))
         t = jnp.array([100])
-        o1 = model.apply(params, x, t, c1)
-        o2 = model.apply(params, x, t, c2)
+        o1 = forward(params, x, t, c1)
+        o2 = forward(params, x, t, c2)
         assert float(jnp.abs(o1 - o2).max()) > 1e-6
 
-    def test_timestep_matters(self, unet):
+    def test_timestep_matters(self, unet, forward):
         model, params = unet
         x = jax.random.normal(jax.random.PRNGKey(6), (1, 8, 8, 4))
         ctx = jax.random.normal(jax.random.PRNGKey(7), (1, 5, 16))
-        o1 = model.apply(params, x, jnp.array([1]), ctx)
-        o2 = model.apply(params, x, jnp.array([999]), ctx)
+        o1 = forward(params, x, jnp.array([1]), ctx)
+        o2 = forward(params, x, jnp.array([999]), ctx)
         assert float(jnp.abs(o1 - o2).max()) > 1e-6
 
     def test_trains_through_engine(self, unet):

@@ -57,7 +57,8 @@ class TestEvoformerParity:
         def loss(fn):
             def inner(q, k, v, pair):
                 return (fn(q, k, v, pair) ** 2).sum()
-            return jax.grad(inner, argnums=(0, 1, 2, 3))(q, k, v, pair)
+            return jax.jit(jax.grad(inner, argnums=(0, 1, 2, 3)))(
+                q, k, v, pair)
 
         g_got = loss(lambda q, k, v, p: evoformer_attention(
             q, k, v, [mb, p], interpret=True))

@@ -22,6 +22,7 @@ index within it, and ``moe_mlp_nodrop`` reads the layer's E groups of the
 What the compiler makes of it at OLMoE's widths (no materialised slice, no
 temporaries the size of an expert matrix) is ``test_chip_compile.py``'s case.
 """
+import functools
 import os
 
 import jax
@@ -69,17 +70,22 @@ def built(request):
     return request.param, *_built(request.param)
 
 
+@functools.lru_cache(maxsize=None)
 def _built(name):
     """The model and seeded weights with EVERY leaf moved off its init (the
-    Xing4 preset draws its routed experts at 1/E of the shared one's)."""
+    Xing4 preset draws its routed experts at 1/E of the shared one's). Drawn
+    in ONE program: a draw a leaf is a program a shape otherwise."""
     preset, widths = MODELS[name]
     model = build_model(preset, **widths)
-    leaves, tree = jax.tree_util.tree_flatten(
-        model.init_params(jax.random.PRNGKey(3)))
-    keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
-    return model, jax.tree_util.tree_unflatten(tree, [
-        x + 0.1 * jax.random.normal(k, x.shape)
-        for x, k in zip(leaves, keys)])
+
+    def drawn():
+        leaves, tree = jax.tree_util.tree_flatten(
+            model.init_params(jax.random.PRNGKey(3)))
+        keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
+        return jax.tree_util.tree_unflatten(tree, [
+            x + 0.1 * jax.random.normal(k, x.shape)
+            for x, k in zip(leaves, keys)])
+    return model, jax.jit(drawn)()
 
 
 def _pool(cfg, seed=0):

@@ -90,8 +90,8 @@ class TestFlashGradParity:
             o = reference_attention(q, k, v, causal=causal)
             return jnp.sum(o * jnp.cos(o))
 
-        g_flash = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-        g_ref = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
+        g_flash = jax.jit(jax.grad(loss_flash, argnums=(0, 1, 2)))(q, k, v)
+        g_ref = jax.jit(jax.grad(loss_ref, argnums=(0, 1, 2)))(q, k, v)
         for a, b in zip(g_flash, g_ref):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=2e-4, atol=2e-4)
@@ -107,14 +107,14 @@ class TestFlashGradParity:
                 return jnp.sum(jnp.tanh(o))
             return inner
 
-        g_flash = jax.grad(
+        g_flash = jax.jit(jax.grad(
             loss(lambda q, k, v: flash_attention(
                 q, k, v, causal=True, segment_ids=seg, interpret=True,
-                block_q=128, block_k=128)), argnums=(0, 1, 2))(q, k, v)
-        g_ref = jax.grad(
+                block_q=128, block_k=128)), argnums=(0, 1, 2)))(q, k, v)
+        g_ref = jax.jit(jax.grad(
             loss(lambda q, k, v: reference_attention(
                 q, k, v, causal=True, segment_ids=seg)),
-            argnums=(0, 1, 2))(q, k, v)
+            argnums=(0, 1, 2)))(q, k, v)
         for a, b in zip(g_flash, g_ref):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=2e-4, atol=2e-4)
@@ -230,7 +230,7 @@ class TestAlibiAndWindow:
         def f(fn):
             def loss(q, k, v):
                 return (fn(q, k, v) ** 2).sum()
-            return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+            return jax.jit(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
 
         g_ref = f(lambda q, k, v: reference_attention(
             q, k, v, causal=True, alibi=sl, window=96))
@@ -308,8 +308,9 @@ def _assert_forward_and_grads_match(attn, reference, q, k, v):
         def loss(q, k, v):
             o = f(q, k, v)
             return jnp.sum(o * jnp.cos(o)), o
-        (_, o), g = jax.value_and_grad(loss, argnums=(0, 1, 2),
-                                       has_aux=True)(q, k, v)
+        # (ONE program a side: eagerly every op is one a shape)
+        (_, o), g = jax.jit(jax.value_and_grad(
+            loss, argnums=(0, 1, 2), has_aux=True))(q, k, v)
         return (o,) + g
 
     got, want = run(attn), run(reference)

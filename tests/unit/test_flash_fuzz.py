@@ -72,9 +72,13 @@ def test_flash_parity_randomized(case):
                                           side="right"))[None, :]
         seg = jnp.broadcast_to(seg, (b, sq))
 
-    got = flash_attention(q, k, v, causal=causal, segment_ids=seg,
-                          window=window, block_q=block, block_k=block)
-    want = dense_ref(q, k, v, causal, segment_ids=seg, window=window)
+    # (each side ONE program: eagerly every op of it is one a shape, and
+    # every case draws shapes of its own)
+    got = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=causal, segment_ids=seg, window=window,
+        block_q=block, block_k=block))(q, k, v)
+    want = jax.jit(lambda q, k, v: dense_ref(
+        q, k, v, causal, segment_ids=seg, window=window))(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4,
                                err_msg=f"case {case}: b={b} sq={sq} "
@@ -100,10 +104,10 @@ def test_flash_grad_parity_randomized(case):
     def loss(f):
         return lambda q, k, v: (f(q, k, v) * v.sum(2, keepdims=True)).sum()
 
-    g_got = jax.grad(loss(lambda *a: flash_attention(
-        *a, causal=causal, block_q=64, block_k=64)), (0, 1, 2))(q, k, v)
-    g_want = jax.grad(loss(lambda *a: dense_ref(*a, causal)),
-                      (0, 1, 2))(q, k, v)
+    g_got = jax.jit(jax.grad(loss(lambda *a: flash_attention(
+        *a, causal=causal, block_q=64, block_k=64)), (0, 1, 2)))(q, k, v)
+    g_want = jax.jit(jax.grad(loss(lambda *a: dense_ref(*a, causal)),
+                              (0, 1, 2)))(q, k, v)
     for name, a, b_ in zip("qkv", g_got, g_want):
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b_), rtol=5e-4, atol=5e-4,

@@ -14,6 +14,7 @@ import torch
 from deepspeedsyclsupport_tpu.checkpoint.hf import (config_from_hf,
                                                     load_hf_checkpoint)
 from deepspeedsyclsupport_tpu.comm.topology import build_topology
+from tests.unit.greedy import greedy
 
 HIDDEN, LAYERS, HEADS, KVHEADS, VOCAB, INTER = 32, 2, 4, 2, 128, 64
 
@@ -245,16 +246,6 @@ class TestEnginesServeRealWeights:
         model.config.dtype = "float32"
         return model, params
 
-    def _naive_greedy(self, model, params, prompt, n):
-        seq = list(prompt)
-        out = []
-        for _ in range(n):
-            logits = model.apply(params, jnp.asarray([seq], jnp.int32))
-            nxt = int(jnp.argmax(logits[0, -1]))
-            out.append(nxt)
-            seq.append(nxt)
-        return out
-
     def test_v1_greedy_parity(self, loaded):
         from deepspeedsyclsupport_tpu.inference import init_inference
 
@@ -265,7 +256,7 @@ class TestEnginesServeRealWeights:
         prompt = [3, 17, 88, 5]
         got = np.asarray(eng.generate(jnp.asarray([prompt], jnp.int32),
                                       max_new_tokens=8))[0].tolist()
-        want = self._naive_greedy(model, params, prompt, 8)
+        want = greedy(model, params, prompt, 8)
         assert got == want
 
     def test_v2_greedy_parity(self, loaded):
@@ -278,7 +269,7 @@ class TestEnginesServeRealWeights:
                                 max_tokens_per_batch=16, max_sequences=4)
         prompt = [3, 17, 88, 5]
         got = eng.generate([prompt], max_new_tokens=8)[0]
-        want = self._naive_greedy(model, params, prompt, 8)
+        want = greedy(model, params, prompt, 8)
         assert got == want
 
     def test_init_inference_from_path(self, tmp_path):

@@ -12,6 +12,7 @@ from deepspeedsyclsupport_tpu.inference import (DSTpuInferenceConfig,
 from deepspeedsyclsupport_tpu.inference.sampling import (SamplingParams,
                                                          sample_token)
 from deepspeedsyclsupport_tpu.models import build_model
+from tests.unit.greedy import greedy
 
 
 @pytest.fixture(scope="module")
@@ -26,16 +27,16 @@ def _engine(model, params, **cfg):
     return init_inference(model=model, params=params, config=cfg)
 
 
-def _naive_greedy(model, params, prompt, n):
-    """Reference decode: full forward each step, argmax of last position."""
-    seq = prompt.copy()
-    out = []
-    for _ in range(n):
-        logits = model.apply(params, jnp.asarray(seq[None, :]))
-        nxt = int(jnp.argmax(logits[0, -1]))
-        out.append(nxt)
-        seq = np.concatenate([seq, [nxt]])
-    return out
+@pytest.mark.parametrize("preset", ["tiny", "tiny-moe"])
+def test_the_padded_greedy_is_the_growing_loops(preset):
+    """``tests/unit/greedy.py`` held to the loop it replaced: one jitted
+    forward at a padded length says what the unjitted forward at every exact
+    length says, also where a router's capacity counts the pads."""
+    model = build_model(preset, dtype="float32")
+    params = model.init_params()
+    prompt = [4, 100, 42, 8, 19]
+    assert (greedy(model, params, prompt, 5)
+            == greedy(model, params, prompt, 5, exact_lengths=True))
 
 
 class TestGenerate:
@@ -43,7 +44,7 @@ class TestGenerate:
         model, params = tiny
         eng = _engine(model, params)
         prompt = np.array([1, 5, 9, 200, 3], dtype=np.int32)
-        want = _naive_greedy(model, params, prompt, 8)
+        want = greedy(model, params, prompt, 8)
         got = eng.generate(jnp.asarray(prompt[None, :]), max_new_tokens=8)
         assert got.shape == (1, 8)
         assert list(np.asarray(got[0])) == want
@@ -61,8 +62,8 @@ class TestGenerate:
         got = np.asarray(eng.generate(jnp.asarray(batch),
                                       prompt_lens=jnp.array([3, 5]),
                                       max_new_tokens=6))
-        assert list(got[0]) == _naive_greedy(model, params, p1, 6)
-        assert list(got[1]) == _naive_greedy(model, params, p2, 6)
+        assert list(got[0]) == greedy(model, params, p1, 6)
+        assert list(got[1]) == greedy(model, params, p2, 6)
 
     def test_eos_padding(self, tiny):
         model, params = tiny
@@ -214,8 +215,8 @@ class TestRaggedArchZoo:
         got = np.asarray(eng.generate(jnp.asarray(batch),
                                       prompt_lens=jnp.array([3, 5]),
                                       max_new_tokens=6))
-        assert list(got[0]) == _naive_greedy(model, params, p1, 6)
-        assert list(got[1]) == _naive_greedy(model, params, p2, 6)
+        assert list(got[0]) == greedy(model, params, p1, 6)
+        assert list(got[1]) == greedy(model, params, p2, 6)
 
 
 class TestKVOffload:

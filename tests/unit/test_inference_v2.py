@@ -20,6 +20,7 @@ from deepspeedsyclsupport_tpu.inference.v2.ragged import (SequenceDescriptor,
 from deepspeedsyclsupport_tpu.inference.v2.scheduler import schedule_chunks
 from deepspeedsyclsupport_tpu.models import build_model
 from tests.unit import stream_ends
+from tests.unit.greedy import greedy
 
 
 # -------------------------------------------------------------------- config
@@ -223,17 +224,6 @@ def _v2(model, params, **kw):
     return InferenceEngineV2(model, params, **kw)
 
 
-def _naive_greedy(model, params, prompt, n):
-    seq = np.asarray(prompt, np.int32)
-    out = []
-    for _ in range(n):
-        logits = model.apply(params, jnp.asarray(seq[None, :]))
-        nxt = int(jnp.argmax(logits[0, -1]))
-        out.append(nxt)
-        seq = np.concatenate([seq, [nxt]])
-    return out
-
-
 class TestEngineV2:
     def test_put_query_flush_contract(self, tiny):
         model, params = tiny
@@ -263,7 +253,7 @@ class TestEngineV2:
         assert eng.kv.k.shape[-1] == 128  # pool really is padded
         got = np.asarray(eng.put([1], [prompt])[1])
         np.testing.assert_allclose(got, base, rtol=1e-5, atol=1e-5)
-        want = _naive_greedy(model, params, prompt, 6)
+        want = greedy(model, params, prompt, 6)
         toks = eng.generate([prompt], max_new_tokens=6)[0]
         assert list(toks) == want, (toks, want)
 
@@ -357,7 +347,7 @@ class TestEngineV2:
         prompts = [[7, 3, 11], [4, 100, 42, 8, 19]]
         got = eng.generate(prompts, max_new_tokens=6)
         for p, g in zip(prompts, got):
-            assert g == _naive_greedy(model, params, p, 6)
+            assert g == greedy(model, params, p, 6)
 
     def test_moe_prefill_logits_match_dense(self):
         """MoE ragged serving (reference moe_scatter/grouped-GEMM/moe_gather):
@@ -382,7 +372,7 @@ class TestEngineV2:
         prompts = [[7, 3, 11], [4, 100, 42, 8, 19]]
         got = eng.generate(prompts, max_new_tokens=6)
         for p, g in zip(prompts, got):
-            assert g == _naive_greedy(model, params, p, 6)
+            assert g == greedy(model, params, p, 6)
 
     def test_moe_nodrop_matches_capacity_path(self):
         """Unit parity: grouped-GEMM no-drop MoE == capacity-einsum MoE when
@@ -411,7 +401,7 @@ class TestEngineV2:
                    for _ in range(5)]
         got = eng.generate(prompts, max_new_tokens=4)
         for p, g in zip(prompts, got):
-            assert g == _naive_greedy(model, params, p, 4)
+            assert g == greedy(model, params, p, 4)
 
     def test_context_cap_truncates_not_crashes(self, tiny):
         """A sequence hitting max_context retires with truncated output;
@@ -424,14 +414,14 @@ class TestEngineV2:
         got = eng.generate([long_p, short_p], max_new_tokens=8)
         assert len(got[0]) <= 8  # truncated at context cap (14 + n <= 16)
         assert len(got[0]) >= 2
-        assert got[1] == _naive_greedy(model, params, short_p, 8)
+        assert got[1] == greedy(model, params, short_p, 8)
 
     def test_empty_prompt_returns_empty(self, tiny):
         model, params = tiny
         eng = _v2(model, params)
         got = eng.generate([[], [7, 3, 11]], max_new_tokens=3)
         assert got[0] == []
-        assert got[1] == _naive_greedy(model, params, [7, 3, 11], 3)
+        assert got[1] == greedy(model, params, [7, 3, 11], 3)
 
     def test_oversized_prompt_rejected(self, tiny):
         model, params = tiny
@@ -525,7 +515,7 @@ class TestPackedFlashPrefill:
         prompts = [[7, 3, 11], [4, 100, 42, 8, 19]]
         got = eng.generate(prompts, max_new_tokens=6)
         for p, g in zip(prompts, got):
-            assert g == _naive_greedy(model, params, p, 6)
+            assert g == greedy(model, params, p, 6)
 
     def test_split_prompt_with_flash_prefill(self, tiny):
         model, params = tiny
@@ -581,7 +571,7 @@ class TestArchZooServing:
         prompts = [[7, 3, 11], [4, 100, 42, 8, 19]]
         got = eng.generate(prompts, max_new_tokens=6)
         for p, g in zip(prompts, got):
-            assert g == _naive_greedy(model, params, p, 6)
+            assert g == greedy(model, params, p, 6)
 
     def test_sliding_window_generate(self):
         """Mistral-style sliding window must serve consistently: v2 greedy ==
@@ -591,7 +581,7 @@ class TestArchZooServing:
         eng = _v2(model, params)
         prompts = [[7, 3, 11, 8, 2, 90, 17, 44]]
         got = eng.generate(prompts, max_new_tokens=5)
-        assert got[0] == _naive_greedy(model, params, prompts[0], 5)
+        assert got[0] == greedy(model, params, prompts[0], 5)
 
 
 class TestSerialize:
@@ -630,7 +620,7 @@ class TestWarmup:
         assert eng.allocator.free_blocks == eng.config.num_blocks
         prompt = [7, 3, 11]
         got = eng.generate([prompt], max_new_tokens=4)[0]
-        assert got == _naive_greedy(model, params, prompt, 4)
+        assert got == greedy(model, params, prompt, 4)
 
 
 class TestSampledGenerate:

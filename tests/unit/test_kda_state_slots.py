@@ -11,6 +11,7 @@ import pytest
 
 from deepspeedsyclsupport_tpu.inference.v2.config import ServingPolicyConfig
 from deepspeedsyclsupport_tpu.inference.v2.serving import ServingSession
+from tests.family_harness import Harness, engines  # noqa: F401
 
 WIDTHS = dict(
     hidden_size=32, intermediate_size=48, num_layers=8,
@@ -22,8 +23,11 @@ WIDTHS = dict(
 ENGINE = {"max_context": 32, "max_sequences": 3, "num_blocks": 6,
           "block_size": 8, "max_tokens_per_batch": 8,
           "prefill_attn": "xla", "decode_attn": "xla"}
+ROOMY = {"num_blocks": 24}
 PROMPTS = {1: [1, 2, 3], 2: [4, 5, 6, 7, 8], 3: [7, 8, 9]}
 BUDGET = 18
+H = Harness(None, ENGINE)
+engine_of = H.engine_of
 
 
 @pytest.fixture(scope="module")
@@ -32,18 +36,8 @@ def built():
 
     model = build_model("solar-open2", **WIDTHS)
     model.seed = 11
-    return model, model.init_params()
-
-
-def engine_of(model, params, **engine):
-    import deepspeedsyclsupport_tpu as dstpu
-    from deepspeedsyclsupport_tpu.inference.v2.engine_v2 import (
-        InferenceEngineV2)
-
-    return InferenceEngineV2(
-        model, params, dtype="float32",
-        topology=dstpu.build_topology(dp=1, devices=jax.devices()[:1]),
-        **{**ENGINE, **engine})
+    # (ONE program: a draw a leaf is one a shape otherwise)
+    return model, jax.jit(model.init_params)()
 
 
 def drive(sess, limit=600):
@@ -65,8 +59,9 @@ def drive(sess, limit=600):
 @pytest.fixture(scope="module")
 def fresh(built):
     """Each prompt's greedy tokens alone on an engine with room: what a
-    stream says when nothing is ever taken from it."""
-    eng = engine_of(*built, num_blocks=24)
+    stream says when nothing is ever taken from it. (The roomy engine the
+    cases below are handed: ``engines(**ROOMY)``.)"""
+    eng = H.idle_engine(*built, **ROOMY)
     return {uid: eng.generate([p], max_new_tokens=BUDGET)[0]
             for uid, p in PROMPTS.items()}
 
@@ -89,10 +84,10 @@ def test_eviction_and_requeue_give_back_the_slot_and_the_blocks(built, fresh):
     assert eng.allocator.free_blocks == eng.allocator.num_blocks
 
 
-def test_admission_is_by_state_slots_and_by_blocks(built, fresh):
+def test_admission_is_by_state_slots_and_by_blocks(engines, fresh):
     """Five requests on three slots: two wait for a slot, nobody is shed,
     never more than three slots live, and all five finish."""
-    eng = engine_of(*built, num_blocks=24)
+    eng = engines(**ROOMY)
     sess = ServingSession(eng, ServingPolicyConfig(admission="none"))
     for uid in range(5):
         sess.submit(uid, PROMPTS[1 + uid % 3], BUDGET)
@@ -107,12 +102,12 @@ def test_admission_is_by_state_slots_and_by_blocks(built, fresh):
     assert eng.state_stats()["slots_live"] == 0
 
 
-def test_the_round_record_carries_the_delta_rule_counts(built):
+def test_the_round_record_carries_the_delta_rule_counts(engines):
     """``kda_rows`` / ``kda_pieces`` / ``kda_first`` where a Mamba model's
     record has ``ssm_*``: rows through each of the three layers, pieces
     summed over them (a one-token chunk one piece, a longer one a piece
     every 4 rows), those that start a sequence."""
-    eng = engine_of(*built, num_blocks=24)
+    eng = engines(**ROOMY)
     sess = ServingSession(eng, ServingPolicyConfig(admission="none"))
     sess.submit(0, list(range(1, 11)), 3)            # 10 rows: 8 + 2
     drive(sess)
@@ -149,12 +144,11 @@ def test_what_is_refused_says_the_stateful_sentence(built, tmp_path):
     assert stats["bytes_per_slot"] == 3 * (2 * 8 * 8 * 4 + 3 * 48 * 4)
 
 
-def test_a_requeued_streams_logits_are_a_fresh_ones(built):
+def test_a_requeued_streams_logits_are_a_fresh_ones(engines):
     """The same through ``put()``: a sequence flushed mid-stream and fed
     again whole (what ``requeue`` does) gives the logits of one that was
     never interrupted, from whatever slot it is handed."""
-    model, params = built
-    eng = engine_of(model, params, num_blocks=24)
+    eng = engines(**ROOMY)
     prompt, said = list(range(20, 31)), [3, 14, 15]
     whole = np.asarray(eng.put([1], [prompt + said])[1])
     eng.flush([1])

@@ -11,9 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepspeedsyclsupport_tpu as dstpu
 from deepspeedsyclsupport_tpu.inference.v2 import model as M
-from deepspeedsyclsupport_tpu.inference.v2.engine_v2 import InferenceEngineV2
 from deepspeedsyclsupport_tpu.inference.v2.kv_cache import (
     build_block_copy_fn, kv_pool_stats)
 from deepspeedsyclsupport_tpu.inference.v2.ragged import (
@@ -22,6 +20,7 @@ from deepspeedsyclsupport_tpu.models import build_model, get_config
 from deepspeedsyclsupport_tpu.models.layers import rope_frequencies
 from deepspeedsyclsupport_tpu.ops import paged_attention as pa
 from deepspeedsyclsupport_tpu.parallel.moe import moe_mlp_nodrop
+from tests.family_harness import Harness
 from tests.unit import stream_ends
 
 TINY = dict(
@@ -40,15 +39,11 @@ ENGINE = dict(max_context=128, max_sequences=4, num_blocks=32, block_size=16,
 @pytest.fixture(scope="module")
 def tiny():
     model = build_model("xing4-29b-a4b", **TINY)
-    return model, model.init_params(jax.random.PRNGKey(3))
+    # (ONE program: a draw a leaf is one a shape otherwise)
+    return model, jax.jit(model.init_params)(jax.random.PRNGKey(3))
 
 
-def engine_of(tiny, **kw):
-    model, params = tiny
-    return InferenceEngineV2(
-        model, params, dtype=kw.pop("dtype", "float32"),
-        topology=dstpu.build_topology(dp=1, devices=jax.devices()[:1]),
-        **{**ENGINE, **kw})
+SERVING = Harness(None, ENGINE)
 
 
 # ------------------------------------------------------------ the kernels
@@ -309,7 +304,7 @@ def test_the_latent_pool_has_one_leaf_of_padded_rows(tiny):
     """``head_dim_lane_pad=128`` is what the TPU gets by default: 40-wide
     rows pad to 128 here as 576 pads to 640 there; no V, and the counters'
     ``load`` has a row for each EXPERT layer only."""
-    eng = engine_of(tiny, dtype="bfloat16", head_dim_lane_pad=128)
+    eng = SERVING.engine_of(*tiny, dtype="bfloat16", head_dim_lane_pad=128)
     slots = ENGINE["num_blocks"] * ENGINE["block_size"]
     assert eng.kv.v is None and eng.kv.k.shape == (3, slots, 128)
     # k, load, touched, tiles
@@ -332,7 +327,7 @@ def test_real_widths_give_7680_bytes_a_token():
 
 
 def test_block_copy_copies_a_latent_block(tiny):
-    eng = engine_of(tiny)
+    eng = SERVING.engine_of(*tiny)
     kv = eng.kv._replace(k=jax.random.normal(jax.random.PRNGKey(0),
                                              eng.kv.k.shape))
     before = np.asarray(kv.k)
@@ -350,9 +345,9 @@ def test_a_prefix_cache_hit_on_a_latent_pool_changes_no_logit(tiny):
     """The second request shares the first one's two full blocks: its
     logits are what a cold prefill of the same prompt gives."""
     prompt = list(range(5, 45))                       # 40 tokens: 2 blocks
-    cold = engine_of(tiny)
+    cold = SERVING.engine_of(*tiny)
     want = np.asarray(cold.put([1], [prompt])[1])
-    eng = engine_of(tiny)
+    eng = SERVING.engine_of(*tiny)
     eng.install_prefix_cache()
     eng.put([1], [prompt])
     eng.flush([1])
@@ -362,7 +357,7 @@ def test_a_prefix_cache_hit_on_a_latent_pool_changes_no_logit(tiny):
 
 
 def test_the_expert_counters_count_expert_layers_only(tiny):
-    eng = engine_of(tiny)
+    eng = SERVING.engine_of(*tiny)
     eng.put([1, 2], [list(range(1, 8)), list(range(20, 61))])
     eng.put([1], [[3]])
     stats = eng.moe_stats()
@@ -502,7 +497,7 @@ def test_the_round_record_carries_both_counts(tiny):
         ServingPolicyConfig)
     from deepspeedsyclsupport_tpu.inference.v2.serving import ServingSession
 
-    eng = engine_of(tiny)
+    eng = SERVING.engine_of(*tiny)
     sess = ServingSession(eng, ServingPolicyConfig(admission="none"))
     sess.submit(1, list(range(1, 8)), 4)              # 7 tokens
     sess.step()
@@ -538,7 +533,7 @@ def test_the_round_record_counts_the_atoms_steps(tiny, monkeypatch):
 
     monkeypatch.setattr(pa, "_kv_pages_per_step", lambda *a: 2 if a[-1]
                         else 1)
-    eng = engine_of(tiny, prefill_attn="kernel_interpret",
+    eng = SERVING.engine_of(*tiny, prefill_attn="kernel_interpret",
                     decode_attn="pallas_interpret", atom_q_size=8)
     assert eng._kv_step_keys == (32, 32)
     sess = ServingSession(eng, ServingPolicyConfig(admission="none"))
@@ -564,7 +559,7 @@ def test_the_training_forward_refuses_what_only_serving_runs(tiny):
 # ------------------------------------------------- a stream that ends early
 @pytest.fixture(scope="module")
 def ending(tiny):
-    return stream_ends.family(engine_of(tiny, max_context=48,
+    return stream_ends.family(SERVING.engine_of(*tiny, max_context=48,
                                         num_blocks=12))
 
 

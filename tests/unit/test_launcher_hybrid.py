@@ -18,6 +18,7 @@ from deepspeedsyclsupport_tpu.runtime.hybrid_engine import HybridEngine
 from deepspeedsyclsupport_tpu.utils.init_on_device import (OnDevice,
                                                            abstract_params,
                                                            materialize_sharded)
+from tests.unit.greedy import greedy
 
 
 # ------------------------------------------------------------------- launcher
@@ -294,11 +295,7 @@ class TestLoRA:
         out0 = np.asarray(eng.generate(jnp.asarray(prompt), max_new_tokens=4))
         # parity vs naive greedy over the merged weights
         merged = lm.merge(eng.params)
-        seq = list(prompt[0])
-        for _ in range(4):
-            logits = base_model.apply(merged, jnp.asarray([seq], jnp.int32))
-            seq.append(int(jnp.argmax(logits[0, -1])))
-        assert list(out0[0]) == seq[4:]
+        assert list(out0[0]) == greedy(base_model, merged, prompt[0], 4)
         # training moves the adapters; generate reflects it immediately
         ids = np.random.RandomState(1).randint(0, 512, (8, 16)).astype(np.int32)
         for _ in range(8):

@@ -12,18 +12,20 @@ logit-std is ten times what the rounding of two float32 programs that sum
 in different orders reads here (1-2e-6); a wrong cache row, a missing norm
 or a wrong exit pass read 0.1 to several (the cases below say which)."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark import parity, spec
-from deepspeedsyclsupport_tpu.inference.v2 import (
-    InferenceEngineV2, ServingPolicyConfig, ServingSession)
+from benchmark import parity
+from deepspeedsyclsupport_tpu.inference.v2 import (ServingPolicyConfig,
+                                                   ServingSession)
 from deepspeedsyclsupport_tpu.inference.v2 import model as M
 from deepspeedsyclsupport_tpu.inference.v2.kv_cache import kv_pool_stats
 from deepspeedsyclsupport_tpu.models import ModelConfig, build_model
+from tests.family_harness import PAD, Harness, family  # noqa: F401
 from tests.unit import stream_ends
 
 TOL = 2e-5
@@ -37,55 +39,49 @@ ENGINE = {"block_size": 16, "max_context": 128, "max_tokens_per_batch": 24,
           "decode_attn": "xla"}
 
 
-@pytest.fixture(scope="module")
-def family():
-    return spec.Bench().family(HF)
+PROMPT = np.random.default_rng(0).integers(0, V, 50).tolist()
+H = Harness(HF, ENGINE, [PROMPT])
+engine_of = H.engine_of
 
 
 def hf(passes, threshold=1.0):
     return {**HF, "total_ut_steps": passes, "early_exit_threshold": threshold}
 
 
+@functools.lru_cache(maxsize=None)
 def built(passes, threshold=1.0, seed=3):
     """The preset at the tiny widths, every leaf moved off its init (the
     norm scales are constants there and the gate's bias zero: a program
-    that left one out would not show)."""
+    that left one out would not show). One model and one tree a
+    configuration: a case that changes a leaf builds a tree of its own
+    around it."""
     model = build_model(
         "ouro-2.6b", hidden_size=64, intermediate_size=96, num_layers=L,
         num_heads=4, num_kv_heads=4, head_dim=16, vocab_size=V,
         max_seq_len=256, total_ut_steps=passes,
         early_exit_threshold=threshold, dtype="float32")
-    params = model.init_params(jax.random.PRNGKey(seed))
-    leaves, tree = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(seed + 1), len(leaves))
-    moved = [x * (1.0 + 0.2 * jax.random.normal(k, x.shape)) if x.ndim > 1
-             else x + 0.2 * jnp.abs(x).mean() * jax.random.normal(k, x.shape)
-             + 0.05 * (x.shape == (1,))
-             for x, k in zip(leaves, keys)]
-    params = jax.tree_util.tree_unflatten(tree, moved)
+
+    def drawn():      # ONE program: a draw a leaf is one a shape otherwise
+        params = model.init_params(jax.random.PRNGKey(seed))
+        leaves, tree = jax.tree_util.tree_flatten(params)
+        keys = jax.random.split(jax.random.PRNGKey(seed + 1), len(leaves))
+        moved = [x * (1.0 + 0.2 * jax.random.normal(k, x.shape))
+                 if x.ndim > 1
+                 else x + 0.2 * jnp.abs(x).mean()
+                 * jax.random.normal(k, x.shape) + 0.05 * (x.shape == (1,))
+                 for x, k in zip(leaves, keys)]
+        return jax.tree_util.tree_unflatten(tree, moved)
+
+    params = jax.jit(drawn)()
     if "exit_gate" in params:     # gates from ~0.05 to ~0.95, not all ~0.5
         params["exit_gate"]["kernel"] = params["exit_gate"]["kernel"] * 4.0
     return model, params
 
 
-def engine_of(model, params, **kw):
-    return InferenceEngineV2(model, params, dtype=jnp.float32,
-                             **{**ENGINE, **kw})
-
-
-def reference(family, passes, params, ids, threshold=1.0):
-    return np.asarray(family.sequence_logits(
-        family.arch(hf(passes, threshold)), params,
-        np.asarray(ids, np.int32)))
-
-
-PROMPT = np.random.default_rng(0).integers(0, V, 50).tolist()
-
-
 # ------------------------------------------------------- against the family
 @pytest.mark.parametrize("attn", ["xla", "kernels"])
 @pytest.mark.parametrize("passes", [1, 2, 4])
-def test_served_logits_are_the_references(family, passes, attn):
+def test_served_logits_are_the_references(passes, attn):
     """The prompt in three chunks of 24, then six of its own greedy tokens
     one at a time through the pool: every row within ``TOL`` of the family's
     float32 forward of the whole sequence, through the XLA attention and
@@ -96,20 +92,20 @@ def test_served_logits_are_the_references(family, passes, attn):
         "atom_q_size": 8}
     eng = engine_of(model, params, **kw)
     logits, tokens = parity.served_logits(eng, 1, PROMPT, 6)
-    want = reference(family, passes, params, PROMPT + tokens)[-7:]
+    want = H.reference(params, PROMPT + tokens, hf(passes))[-7:]
     assert parity.row_errors(logits, want).max() < TOL
     assert eng.allocator.free_blocks == eng.allocator.num_blocks
 
 
 @pytest.mark.parametrize("passes", [2, 4])
-def test_generate_continues_as_the_reference_does(family, passes):
+def test_generate_continues_as_the_reference_does(passes):
     """Two prompts through ``generate()``, a decode step a token: each
     stream's greedy tokens are the reference's own greedy continuation."""
     model, params = built(passes)
     prompts = [PROMPT[:9], PROMPT[9:30]]
     got = engine_of(model, params).generate(prompts, max_new_tokens=7)
     for prompt, toks in zip(prompts, got):
-        rows = reference(family, passes, params, prompt + toks)
+        rows = H.reference(params, prompt + toks, hf(passes))
         assert rows[len(prompt) - 1:-1].argmax(-1).tolist() == list(toks)
 
 
@@ -237,22 +233,26 @@ def test_the_exit_rule_and_its_counter(family, threshold):
     1.0 every row leaves after the last."""
     model, params = built(4, threshold)
     gate = params["exit_gate"]
-    params["exit_gate"] = {"kernel": gate["kernel"] * 3.0,
-                           "bias": jnp.full_like(gate["bias"], -1.0)}
+    params = {**params, "exit_gate": {
+        "kernel": gate["kernel"] * 3.0,
+        "bias": jnp.full_like(gate["bias"], -1.0)}}
     eng = engine_of(model, params)
     arch = family.arch(hf(4, threshold))
+    # (the reference's gates through ONE walk at a padded length, as
+    # ``H.reference`` reads its logits: the walk is causal)
+    states = jax.jit(lambda p, x: family.pass_states(arch, p, x))
     counted, worst, elsewhere = np.zeros(4, int), 0.0, 0.0
     for uid, n in enumerate((1, 2, 3, 5, 8, 13)):
         prompt = PROMPT[n:2 * n + 1]
         logits, tokens = parity.served_logits(eng, uid, prompt, 2)
         ids = np.asarray(prompt + tokens, np.int32)
-        _h, lam = family.pass_states(arch, params, ids)
-        counted += np.bincount(
-            np.asarray(family.exit_pass(lam, threshold))[-3:], minlength=4)
-        worst = max(worst, parity.row_errors(logits, reference(
-            family, 4, params, ids, threshold)[-3:]).max())
-        elsewhere = max(elsewhere, parity.row_errors(logits, reference(
-            family, 4, params, ids, 0.0 if threshold == 1 else 1.0)[-3:]
+        _h, lam = states(params, jnp.pad(ids, (0, PAD - len(ids))))
+        counted += np.bincount(np.asarray(family.exit_pass(
+            lam[:, :len(ids)], threshold))[-3:], minlength=4)
+        worst = max(worst, parity.row_errors(logits, H.reference(
+            params, ids.tolist(), hf(4, threshold))[-3:]).max())
+        elsewhere = max(elsewhere, parity.row_errors(logits, H.reference(
+            params, ids.tolist(), hf(4, 0.0 if threshold == 1 else 1.0))[-3:]
         ).max())
     assert worst < TOL and elsewhere > 0.3
     stats = eng.loop_stats()
@@ -301,7 +301,7 @@ def _drive(sess, requests, rounds=600):
 REQUESTS = [(u, PROMPT[u:u + 18 + 5 * u], 20) for u in range(4)]
 
 
-def test_eviction_with_requeue_gives_the_tokens_of_a_roomy_pool(family):
+def test_eviction_with_requeue_gives_the_tokens_of_a_roomy_pool():
     """A block holds all ``passes x layers`` rows of its tokens, so the
     allocator, eviction and requeue see blocks as for any model: under a
     pool of 8 blocks streams are evicted, prefilled again and finish with
@@ -318,11 +318,11 @@ def test_eviction_with_requeue_gives_the_tokens_of_a_roomy_pool(family):
     assert tight == roomy
     assert eng.allocator.free_blocks == 8
     uid, prompt, _ = REQUESTS[2]
-    rows = reference(family, 2, params, prompt + roomy[uid])
+    rows = H.reference(params, prompt + roomy[uid], hf(2))
     assert rows[len(prompt) - 1:-1].argmax(-1).tolist() == roomy[uid]
 
 
-def test_a_prefix_cache_hit_gives_the_same_logits(family):
+def test_a_prefix_cache_hit_gives_the_same_logits():
     """Two prompts that share 32 tokens (two blocks): the second maps the
     first's blocks, every (pass, layer) row of them, and its logits are
     those of an engine without the cache and the reference's."""
@@ -341,7 +341,7 @@ def test_a_prefix_cache_hit_gives_the_same_logits(family):
         tokens.append(int(rows[-1].argmax()))
         rows.append(np.asarray(eng.put([2], [[tokens[-1]]])[2]))
     np.testing.assert_allclose(np.stack(rows), cold, atol=1e-5)
-    want = reference(family, 4, params, shared + tail + tokens)[-4:]
+    want = H.reference(params, shared + tail + tokens, hf(4))[-4:]
     assert parity.row_errors(np.stack(rows), want).max() < TOL
 
 

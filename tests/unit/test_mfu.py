@@ -732,17 +732,20 @@ class TestRingInner:
             reset_world_topology()
             build_topology(dp=4, sp=2)
             q, k, v = self._qkv()
+            # (under jit, as the engine runs it: eagerly the shard_map walks
+            # the interpreted kernel op by op, 31 s for the same numbers)
             for causal in (True, False):
                 ref = reference_attention(q, k, v, causal=causal)
-                got = ring_attention(q, k, v, causal=causal, inner="flash")
+                got = jax.jit(lambda a, b, c: ring_attention(
+                    a, b, c, causal=causal, inner="flash"))(q, k, v)
                 np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                            atol=2e-5)
             # gradients flow through the lse combine exactly
             def loss(fn):
                 return lambda a, b, c: (fn(a, b, c) *
                                         jnp.arange(8)).sum()
-            g_fl = jax.grad(loss(lambda a, b, c: ring_attention(
-                a, b, c, causal=True, inner="flash")), (0, 1, 2))(q, k, v)
+            g_fl = jax.jit(jax.grad(loss(lambda a, b, c: ring_attention(
+                a, b, c, causal=True, inner="flash")), (0, 1, 2)))(q, k, v)
             g_ref = jax.grad(loss(lambda a, b, c: reference_attention(
                 a, b, c, causal=True)), (0, 1, 2))(q, k, v)
             for a, b in zip(g_fl, g_ref):
@@ -755,6 +758,8 @@ class TestRingInner:
             rwt()
 
     def test_attention_dispatch_colon_syntax(self):
+        import jax
+
         from deepspeedsyclsupport_tpu.comm.topology import (
             build_topology, reset_world_topology)
         from deepspeedsyclsupport_tpu.models.layers import (
@@ -768,7 +773,8 @@ class TestRingInner:
             # the flash-inner arm is priced by the A/B e2e below (interpret
             # mode is expensive); the dispatch seam itself is impl-agnostic
             for impl in ("ring:xla",):
-                got = attention(q, k, v, impl=impl, causal=True)
+                got = jax.jit(lambda a, b, c: attention(
+                    a, b, c, impl=impl, causal=True))(q, k, v)
                 np.testing.assert_allclose(np.asarray(got),
                                            np.asarray(ref), atol=2e-5)
         finally:

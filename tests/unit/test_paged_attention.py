@@ -8,6 +8,7 @@ import pytest
 
 from deepspeedsyclsupport_tpu.ops.paged_attention import (
     paged_decode_attention, paged_decode_attention_reference)
+from tests.unit.greedy import greedy
 
 
 def _setup(rng, s=3, h=8, kvh=4, d=32, bs=16, bps=4, seq_lens=None):
@@ -211,11 +212,7 @@ class TestEngineKernelPath:
         got = eng.generate([[7, 3, 11], [4, 100, 42, 8, 19]],
                            max_new_tokens=5)
         for p, g in zip([[7, 3, 11], [4, 100, 42, 8, 19]], got):
-            seq = list(p)
-            for _ in range(5):
-                logits = model.apply(params, jnp.asarray([seq], jnp.int32))
-                seq.append(int(jnp.argmax(logits[0, -1])))
-            assert g == seq[len(p):]
+            assert g == greedy(model, params, p, 5)
 
 
     @pytest.mark.parametrize("newcomer", [[4, 100, 42, 8, 19, 77, 5, 3, 61],
@@ -285,11 +282,7 @@ def test_engine_kernel_path_alibi_and_window():
                                 atom_q_size=8)
         prompts = [[7, 3, 11, 8, 2, 90]]
         got = eng.generate(prompts, max_new_tokens=4)
-        seq = list(prompts[0])
-        for _ in range(4):
-            logits = model.apply(params, jnp.asarray([seq], jnp.int32))
-            seq.append(int(jnp.argmax(logits[0, -1])))
-        assert got[0] == seq[6:]
+        assert got[0] == greedy(model, params, prompts[0], 4)
 
 
 def test_decode_dead_slot_exact_zero():

@@ -29,6 +29,7 @@ from deepspeedsyclsupport_tpu.inference.v2.prefix_cache import (
 from deepspeedsyclsupport_tpu.inference.v2.serving import (
     SERVE_PREFIX)
 from deepspeedsyclsupport_tpu.models import build_model
+from tests.unit.greedy import greedy
 
 
 class FakeClock:
@@ -56,17 +57,6 @@ def _v2(model, params, **kw):
     kw.setdefault("max_tokens_per_batch", 16)
     kw.setdefault("max_sequences", 4)
     return InferenceEngineV2(model, params, **kw)
-
-
-def _naive_greedy(model, params, prompt, n):
-    seq = np.asarray(prompt, np.int32)
-    out = []
-    for _ in range(n):
-        logits = model.apply(params, jnp.asarray(seq[None, :]))
-        nxt = int(jnp.argmax(logits[0, -1]))
-        out.append(nxt)
-        seq = np.concatenate([seq, [nxt]])
-    return out
 
 
 def _engine_greedy(eng, uid, prompt, n):
@@ -294,7 +284,7 @@ class TestEnginePrefixIntegration:
 
     def test_byte_identity_and_no_cow_in_steady_state(self, tiny):
         model, params = tiny
-        want = {u: _naive_greedy(model, params, SYSTEM + TAILS[u], 5)
+        want = {u: greedy(model, params, SYSTEM + TAILS[u], 5)
                 for u in (1, 2, 3)}
         eng = _v2(model, params)
         pc = eng.install_prefix_cache()
@@ -308,7 +298,7 @@ class TestEnginePrefixIntegration:
 
     def test_donor_preempt_keeps_sharer_intact(self, tiny):
         model, params = tiny
-        want = _naive_greedy(model, params, SYSTEM + TAILS[2], 5)
+        want = greedy(model, params, SYSTEM + TAILS[2], 5)
         eng = _v2(model, params)
         pc = eng.install_prefix_cache()
         eng.put([1], [SYSTEM + TAILS[1]])           # donor commits SYSTEM
@@ -410,7 +400,7 @@ class TestServingPrefixE2E:
         assert evicted, "7-block pool must preempt one of the streams"
         assert pc.counters["hits"] >= 3, \
             "2 admission hits + the requeue re-prefill hit"
-        want = {u: _naive_greedy(model, params, SYSTEM + TAILS[u], 20)
+        want = {u: greedy(model, params, SYSTEM + TAILS[u], 20)
                 for u in out}
         assert out == want
 
@@ -420,7 +410,7 @@ class TestServingPrefixE2E:
         and the replayed stream still reconstructs the exact pre-crash
         greedy continuation."""
         model, params = tiny
-        base = {u: _naive_greedy(model, params, SYSTEM + TAILS[u], 8)
+        base = {u: greedy(model, params, SYSTEM + TAILS[u], 8)
                 for u in (1, 3)}
         eng = _v2(model, params)
         clock = FakeClock()

@@ -223,8 +223,10 @@ class TestOneBitAdam:
                 outs.append(params)
             return jnp.stack(outs)
 
-        per_rank = _shard_map(topo, body, (P("data", None),),
-                              P("data", None))(
+        # (one program: eagerly the shard_map walks eight unrolled updates
+        # op by op)
+        per_rank = jax.jit(_shard_map(topo, body, (P("data", None),),
+                                      P("data", None)))(
             jax.random.normal(jax.random.PRNGKey(5), (8, 16)))
         # out_spec P('data') concatenates rank trajectories along dim 0:
         # [8 ranks × 8 steps, 16] → ranks × steps × params, all must be equal

@@ -13,14 +13,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import deepspeedsyclsupport_tpu as dstpu
-from deepspeedsyclsupport_tpu.inference.v2.engine_v2 import (
-    InferenceEngineV2, ProgramShapes)
+from deepspeedsyclsupport_tpu.inference.v2.engine_v2 import ProgramShapes
 from deepspeedsyclsupport_tpu.inference.v2.ragged import (
     BlockedAllocator, RaggedShape, SequenceDescriptor, build_ragged_batch,
     ragged_shapes, ssm_pieces, tile_places)
 from deepspeedsyclsupport_tpu.inference.v2.scheduler import schedule_chunks
 from deepspeedsyclsupport_tpu.models import build_model
+from tests.family_harness import Harness
 
 CONFIGS = Path(__file__).parents[2] / "benchmark" / "configs"
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -158,11 +157,7 @@ ENGINE = dict(max_context=512, num_blocks=96, block_size=16,
               decode_attn="xla")
 
 
-def engine_of(model, params, **kw):
-    return InferenceEngineV2(
-        model, params, dtype=jnp.float32,
-        topology=dstpu.build_topology(dp=1, devices=jax.devices()[:1]),
-        **{**ENGINE, **kw})
+engine_of = Harness(None, ENGINE).engine_of
 
 
 @pytest.fixture(scope="module")

@@ -26,6 +26,7 @@ from deepspeedsyclsupport_tpu.inference.v2.scheduler import (
 from deepspeedsyclsupport_tpu.inference.v2.serving import (
     SERVE_EVENT_NAMES)
 from deepspeedsyclsupport_tpu.models import build_model
+from tests.unit.greedy import greedy
 
 
 class FakeClock:
@@ -53,17 +54,6 @@ def _v2(model, params, **kw):
     kw.setdefault("max_tokens_per_batch", 16)
     kw.setdefault("max_sequences", 4)
     return InferenceEngineV2(model, params, **kw)
-
-
-def _naive_greedy(model, params, prompt, n):
-    seq = np.asarray(prompt, np.int32)
-    out = []
-    for _ in range(n):
-        logits = model.apply(params, jnp.asarray(seq[None, :]))
-        nxt = int(jnp.argmax(logits[0, -1]))
-        out.append(nxt)
-        seq = np.concatenate([seq, [nxt]])
-    return out
 
 
 def _drain(sess, out=None, max_steps=400):
@@ -457,7 +447,7 @@ class TestSessionEndToEnd:
         out = {}
         _drain(sess, out)
         for uid, p in prompts.items():
-            assert out[uid] == _naive_greedy(model, params, p, 6)
+            assert out[uid] == greedy(model, params, p, 6)
         assert eng.allocator.free_blocks == eng.config.num_blocks
 
     def test_overload_degrades_gracefully(self, tiny):

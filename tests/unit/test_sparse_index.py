@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark import dsa_faults, parity, spec
+from benchmark import dsa_faults, parity
 from deepspeedsyclsupport_tpu.inference.v2 import (
     InferenceEngineV2, ServingPolicyConfig, ServingSession, dsa)
 from deepspeedsyclsupport_tpu.inference.v2.kv_cache import kv_pool_stats
@@ -28,6 +28,7 @@ from deepspeedsyclsupport_tpu.models.transformer import SELECTED_ATTN_WRITE
 from deepspeedsyclsupport_tpu.ops import sparse_index
 from deepspeedsyclsupport_tpu.ops.paged_attention import (
     ragged_prefill_attention_pallas, ragged_prefill_attention_reference)
+from tests.family_harness import Harness, engines, family  # noqa: F401
 
 TOL = 2e-5
 TOPK, V = 8, 256
@@ -41,17 +42,13 @@ HF = {"model_type": "KeyeVL2", "hidden_size": 64, "intermediate_size": 96,
       "sa_config": {"indexer_head_dim": 8, "indexer_num_heads": 2,
                     "topk": TOPK},
       "reduced": {"num_experts": {"published": 8, "run": 4}}}
-ENGINE = {"block_size": 4, "max_context": 64, "max_tokens_per_batch": 16,
-          "max_sequences": 4, "num_blocks": 48}
 ATTN = {"xla": dict(prefill_attn="xla", decode_attn="xla"),
         "kernels": dict(prefill_attn="kernel_interpret",
                         decode_attn="pallas_interpret", atom_q_size=8)}
+ENGINE = {"block_size": 4, "max_context": 64, "max_tokens_per_batch": 16,
+          "max_sequences": 4, "num_blocks": 48, **ATTN["xla"]}
 PROMPT = np.random.default_rng(0).integers(0, V, 60).tolist()
-
-
-@pytest.fixture(scope="module")
-def family():
-    return spec.Bench().family(HF)
+H = Harness(HF, ENGINE, [PROMPT])
 
 
 @pytest.fixture(scope="module")
@@ -68,57 +65,52 @@ def built():
         head_dim=16, vocab_size=V, num_experts=8, num_experts_per_tok=3,
         num_experts_held=4, index_topk=TOPK, index_heads=2, index_head_dim=8,
         max_seq_len=128, routed_write_share=None, dtype="float32")
-    params = model.init_params(jax.random.PRNGKey(3))
-    attn = params["layers"]["attn"]
-    attn["wo"] = attn["wo"] / SELECTED_ATTN_WRITE
-    leaves, tree = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(4), len(leaves))
-    moved = [x * (1.0 + 0.2 * jax.random.normal(k, x.shape)) if x.ndim > 1
-             else x + 0.2 * jnp.abs(x).mean() * jax.random.normal(k, x.shape)
-             + 0.05 * (x.shape == (8,))
-             for x, k in zip(leaves, keys)]
-    return model, jax.tree_util.tree_unflatten(tree, moved)
+    def drawn():      # ONE program: a draw a leaf is one a shape otherwise
+        params = model.init_params(jax.random.PRNGKey(3))
+        attn = params["layers"]["attn"]
+        attn["wo"] = attn["wo"] / SELECTED_ATTN_WRITE
+        leaves, tree = jax.tree_util.tree_flatten(params)
+        keys = jax.random.split(jax.random.PRNGKey(4), len(leaves))
+        moved = [x * (1.0 + 0.2 * jax.random.normal(k, x.shape))
+                 if x.ndim > 1
+                 else x + 0.2 * jnp.abs(x).mean()
+                 * jax.random.normal(k, x.shape) + 0.05 * (x.shape == (8,))
+                 for x, k in zip(leaves, keys)]
+        return jax.tree_util.tree_unflatten(tree, moved)
+    return model, jax.jit(drawn)()
 
 
-def engine_of(built, **kw):
-    model, params = built
-    return InferenceEngineV2(model, params, dtype=jnp.float32,
-                             **{**ENGINE, **ATTN["xla"], **kw})
+def dense_reference(family, params, ids):
+    """Another selection (every key a row sees): the family's blocks, walked
+    by hand."""
+    from benchmark import reference as ref
 
-
-def reference(family, params, ids, **kw):
     arch = family.arch(HF)
-    if kw:      # another selection: the family's blocks, walked by hand
-        from benchmark import reference as ref
-        return np.asarray(ref.decoder_logits(
-            params, np.asarray(ids, np.int32),
-            lambda p, x: family.block(arch, p, x, **kw)[0],
-            lambda p, x: ref.rms_norm(p, x, arch["norm_eps"])))
-    return np.asarray(family.sequence_logits(arch, params,
-                                             np.asarray(ids, np.int32)))
+    return np.asarray(ref.decoder_logits(
+        params, np.asarray(ids, np.int32),
+        lambda p, x: family.block(arch, p, x, select="all")[0],
+        lambda p, x: ref.rms_norm(p, x, arch["norm_eps"])))
 
 
 # ------------------------------------------------------- against the family
 @pytest.mark.parametrize("attn", sorted(ATTN))
-def test_served_logits_are_the_references(family, built, attn):
+def test_served_logits_are_the_references(family, built, engines, attn):
     """A 37-token prompt prefilled in chunks of 16 (contexts to 4.6 x
     ``topk``), then ten of its own greedy tokens through the pool: every
     row's logits are the full forward's. ``kernels``: the scores, the
     selection and the masked ragged kernel in interpret mode over atoms of
     8 rows, the one-token rows through the gather."""
     served, tokens = parity.served_logits(
-        engine_of(built, **ATTN[attn]), 0, PROMPT[:37], 10)
-    want = reference(family, built[1], PROMPT[:37] + tokens)[36:]
+        engines(**ATTN[attn]), 0, PROMPT[:37], 10)
+    want = H.reference(built[1], PROMPT[:37] + tokens)[36:]
     assert parity.row_errors(served, want).max() < TOL
     # and the selection MATTERS here: dense attention reads otherwise
-    dense = reference(family, built[1], PROMPT[:37] + tokens,
-                      select="all")[36:]
+    dense = dense_reference(family, built[1], PROMPT[:37] + tokens)[36:]
     assert parity.row_errors(dense, want).max() > 0.02
 
 
 @pytest.mark.parametrize("fault", dsa_faults.FAULTS)
-def test_a_planted_fault_is_refused_by_the_harness_comparison(
-        family, built, fault):
+def test_a_planted_fault_is_refused_by_the_harness_comparison(built, fault):
     """ISSUE 45's four wrong programs (``benchmark.dsa_faults``: what the
     chip is held to at the cell's widths), planted in the served program at
     a context of 4.6 x ``topk``: each comes out beyond ``benchmark.parity``'s
@@ -126,32 +118,33 @@ def test_a_planted_fault_is_refused_by_the_harness_comparison(
     2e-5 (the test above)."""
     with dsa_faults.planted(fault):
         served, tokens = parity.served_logits(
-            engine_of(built), 0, PROMPT[:37], 10)
-    want = reference(family, built[1], PROMPT[:37] + tokens)[36:]
+            H.engine_of(*built), 0, PROMPT[:37], 10)
+    want = H.reference(built[1], PROMPT[:37] + tokens)[36:]
     assert parity.row_errors(served, want).max() > parity.TOLERANCE
-    # and the plant is lifted again
-    served, tokens = parity.served_logits(engine_of(built), 0, PROMPT[:37], 3)
-    want = reference(family, built[1], PROMPT[:37] + tokens)[36:]
+    # and the plant is lifted again (a new engine: a new trace)
+    served, tokens = parity.served_logits(H.engine_of(*built), 0,
+                                          PROMPT[:37], 3)
+    want = H.reference(built[1], PROMPT[:37] + tokens)[36:]
     assert parity.row_errors(served, want).max() < TOL
 
 
-def test_within_topk_the_attention_is_dense(family, built):
+def test_within_topk_the_attention_is_dense(family, built, engines):
     """While ``t + 1 <= topk`` a row attends everything it sees: the served
     logits of an 8-token prompt are the dense reference's."""
-    served, _ = parity.served_logits(engine_of(built), 0, PROMPT[:TOPK], 0)
-    dense = reference(family, built[1], PROMPT[:TOPK], select="all")[-1:]
+    served, _ = parity.served_logits(engines(), 0, PROMPT[:TOPK], 0)
+    dense = dense_reference(family, built[1], PROMPT[:TOPK])[-1:]
     assert parity.row_errors(served, dense).max() < TOL
 
 
-def test_mixed_rounds_serve_prompts_beside_decodes(family, built):
+def test_mixed_rounds_serve_prompts_beside_decodes(built, engines):
     """Four streams through a session, prompts arriving while others
     decode (mixed ``ragged_forward`` rounds: atoms and one-token rows in one
     batch): each stream's tokens are the reference's greedy choice."""
-    eng = engine_of(built, **ATTN["kernels"])
+    eng = engines(**ATTN["kernels"])
     sess = ServingSession(eng, ServingPolicyConfig(admission="none"))
     out = _drive(sess, REQUESTS)
     for uid, prompt, _ in REQUESTS[1:3]:
-        rows = reference(family, built[1], prompt + out[uid])
+        rows = H.reference(built[1], prompt + out[uid])
         assert rows[len(prompt) - 1:-1].argmax(-1).tolist() == out[uid]
     rounds = [r["data"] for r in sess.drain_trace()
               if r["data"].get("stage") == "round" and r["data"]["program"]]
@@ -296,13 +289,14 @@ def test_the_positions_are_the_masks_set(rows):
 
 
 @pytest.mark.parametrize("layer", [0, 1])
-def test_the_rows_route_through_the_kernels_is_the_twins(built, layer):
+def test_the_rows_route_through_the_kernels_is_the_twins(built, engines,
+                                                         layer):
     """``dsa.attend_rows`` over a served engine's own pools (three sequences
     of 41, 5 and 23 cached tokens and a slot with no row; ``topk`` 8):
     scores and selection through the two kernels in interpret mode give the
     rows the ``jax.numpy`` twins give."""
     model, _ = built
-    eng = engine_of(built)
+    eng = engines()
     for uid, n in enumerate((41, 5, 23)):
         eng.put([uid], [PROMPT[uid:uid + n]])
     descs = [eng.seqs[u] for u in range(3)]
@@ -322,6 +316,7 @@ def test_the_rows_route_through_the_kernels_is_the_twins(built, layer):
     np.testing.assert_allclose(out["pallas_interpret"], out["xla"],
                                rtol=1e-5, atol=1e-6)
     assert np.abs(out["xla"][:3]).max() > 0.1 and not out["xla"][3].any()
+    eng.flush([0, 1, 2])
 
 
 def test_the_ragged_kernel_under_a_selection_is_its_twin():
@@ -367,14 +362,15 @@ def _drive(sess, requests, rounds=600):
 REQUESTS = [(u, PROMPT[u:u + 22 + 5 * u], 12) for u in range(4)]
 
 
-def test_eviction_with_requeue_gives_the_tokens_of_a_roomy_pool(built):
+def test_eviction_with_requeue_gives_the_tokens_of_a_roomy_pool(built,
+                                                                engines):
     """A block holds its tokens' indexer keys beside their K and V, so a
     stream that is evicted and prefilled again selects as it did: under a
     pool of 16 blocks streams are requeued and finish with the tokens a
     roomy pool gives."""
     roomy = _drive(ServingSession(
-        engine_of(built), ServingPolicyConfig(admission="none")), REQUESTS)
-    eng = engine_of(built, num_blocks=16)
+        engines(), ServingPolicyConfig(admission="none")), REQUESTS)
+    eng = H.engine_of(*built, num_blocks=16)
     sess = ServingSession(eng, ServingPolicyConfig(
         admission="none", preempt_policy="requeue"))
     tight = _drive(sess, REQUESTS)
@@ -383,13 +379,13 @@ def test_eviction_with_requeue_gives_the_tokens_of_a_roomy_pool(built):
     assert eng.allocator.free_blocks == 16
 
 
-def test_a_prefix_cache_hit_keeps_the_indexer_keys(family, built):
+def test_a_prefix_cache_hit_keeps_the_indexer_keys(built, engines):
     """Two prompts that share 24 tokens (six blocks, three times ``topk``):
     the second maps the first's blocks, indexer keys and all, and its
     logits are those of an engine without the cache and the reference's."""
     shared, tail = PROMPT[:24], PROMPT[24:33]
-    cold, _ = parity.served_logits(engine_of(built), 2, shared + tail, 3)
-    eng = engine_of(built)
+    cold, _ = parity.served_logits(engines(), 2, shared + tail, 3)
+    eng = H.engine_of(*built)
     eng.install_prefix_cache()
     parity.served_logits(eng, 1, shared + PROMPT[40:45], 2)
     eng.map_cached_prefix(2, shared + tail)
@@ -400,13 +396,13 @@ def test_a_prefix_cache_hit_keeps_the_indexer_keys(family, built):
         tokens.append(int(rows[-1].argmax()))
         rows.append(np.asarray(eng.put([2], [[tokens[-1]]])[2]))
     np.testing.assert_allclose(np.stack(rows), cold, atol=1e-5)
-    want = reference(family, built[1], shared + tail + tokens)[-4:]
+    want = H.reference(built[1], shared + tail + tokens)[-4:]
     assert parity.row_errors(np.stack(rows), want).max() < TOL
 
 
 # ------------------------------------------------------ shapes and counts
 def test_the_pool_has_a_third_array_on_the_same_slots(built):
-    eng = engine_of(built)
+    eng = H.engine_of(*built)       # (a pool of zeros: nothing has run)
     kv = eng.kv
     assert kv.idx.shape == (2, 48 * 4 // 2, 2 * 8) and len(kv.pools) == 3
     per_token = 2 * (2 * 2 * 16 + 8) * 4         # layers x (K, V + idx) x f32
@@ -444,7 +440,7 @@ def test_the_rows_walk_follows_the_longest_row_and_the_route(built):
         (48 + 16 + 32) + 3 * 64
     assert selection_work(descs, lengths, TOPK, (64, 64))[2] == 2 * 3 * 64
     tel.setup_ledger_store.reset()
-    eng = engine_of(built, **ATTN["kernels"])
+    eng = H.engine_of(*built, **ATTN["kernels"])
     assert eng._dsa_walk == {"ragged_forward": (64, 64),
                              "decode_forward": (64, 64)}
     routes = [r for r in tel.setup_ledger() if r["kind"] == "decision"
@@ -452,7 +448,7 @@ def test_the_rows_walk_follows_the_longest_row_and_the_route(built):
     assert [(r["program"], r["impl"], r["score_keys"]) for r in routes] == [
         ("ragged_forward", "pallas_interpret", 64),
         ("decode_forward", "pallas_interpret", 64)]
-    mixed = engine_of(built, decode_attn="pallas_interpret")
+    mixed = H.engine_of(*built, decode_attn="pallas_interpret")
     assert mixed._dsa_walk["ragged_forward"] == (64, 64)   # the whole table
 
 
