@@ -196,6 +196,39 @@ def _layer_groups(w: jnp.ndarray, group_sizes: jnp.ndarray, layer, dtype
     return w.reshape((n_layers * e,) + w.shape[2:]), sizes
 
 
+def combine_rows(ys: jnp.ndarray, at: jnp.ndarray, gate_w: jnp.ndarray,
+                 has_expert: Optional[jnp.ndarray], dtype) -> jnp.ndarray:
+    """The experts' combine (the reference's ``moe_gather``): ``out[t] =
+    sum_j gate_w[t, j] * ys[at[t * k + j]]`` as ONE gather of a token's ``k``
+    rows and a weighted sum over them in float32, rounded once to ``dtype``.
+    ``ys`` [rows, D] the down projection's result wherever its rows lie;
+    ``at`` [T*k] the row of each (token, choice), token-major; ``gate_w``
+    [T, k] float32; ``has_expert`` [T*k] bool or None (every row has one).
+    A row with no expert here is SELECTED away, not multiplied: what lies at
+    its ``at`` is whatever the grouped matmul left there (NaN is allowed),
+    and it adds an exact zero."""
+    t, k = gate_w.shape
+    # choice-major, [k, T, D], and the sum written out over the k slices:
+    # one fusion reads the gathered rows once. Token-major, a token's k rows
+    # are padded to a tile of 8 and the float32 [T, k, D] is written out
+    # (120 MiB at DeepSeek-V2's 768 x 6 x 5,120); so is [k x T, D] in float32
+    # where all of ``rows`` is cast before it is sliced (compiled for the
+    # v5e, PR 58)
+    rows = ys[at.reshape(t, k).T]
+    w = gate_w.T[:, :, None]
+    has = None if has_expert is None \
+        else has_expert.reshape(t, k).T[:, :, None]
+    out = 0.0
+    for j in range(k):
+        term = rows[j].astype(jnp.float32) * w[j]
+        out = out + (term if has is None else jnp.where(has[j], term, 0.0))
+    # the sum ends HERE: left to itself the compiler fuses it into the
+    # layer's last residual add, and the gathered [k x T, D] rows (48 MiB at
+    # Command A+'s 8 x 768 x 4,096) stay alive across whatever stands between
+    # (a parallel block's whole attention: PERF.md section 6, PR 58)
+    return jax.lax.optimization_barrier(out.astype(dtype))
+
+
 def moe_mlp_nodrop(p: Dict[str, Any], x: jnp.ndarray, cfg,
                    live: Optional[jnp.ndarray] = None, layer=0
                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -215,10 +248,18 @@ def moe_mlp_nodrop(p: Dict[str, Any], x: jnp.ndarray, cfg,
     (``row_tile``, by the static shape); the sorted rows are laid out with
     every expert's first on a tile boundary (``tile_rows``), gate and up
     share one read of them with the activation on the float32 accumulators,
-    and the result is gathered back into sorted order. Anywhere else (and as
-    the tests' reference): ``jax.lax.ragged_dot`` over :func:`_layer_groups`,
-    three calls with the activation between. Routing, the sort and the
-    combine are the same code on both.
+    and the result stays in that layout for the combine. Anywhere else (and
+    as the tests' reference): ``jax.lax.ragged_dot`` over
+    :func:`_layer_groups`, three calls with the activation between. Routing,
+    the sort and the combine are the same code on both.
+
+    The combine (``moe_gather``) scatters nothing: every token has exactly
+    ``k`` rows at places the sort knows, so the sort is inverted (one more
+    small sort) and :func:`combine_rows` GATHERS each token's ``k`` rows,
+    out of the tile layout or the sorted rows, and sums them under the gate
+    weights in float32 with one rounding to ``x.dtype``. (A scatter-add of
+    ``T x k`` rows runs a row at a time on the TPU, ~26 GB/s: PERF.md
+    section 6, PRs 36 and 58.)
 
     ``live`` [T] bool: the serving forwards always carry their full row
     budget, pads included. A row that is not live gets NO expert: it sorts
@@ -262,7 +303,7 @@ def moe_mlp_nodrop(p: Dict[str, Any], x: jnp.ndarray, cfg,
     from ..monitor.mfu import scope
     from ..ops import grouped_gemm
 
-    t, d = x.shape
+    t = x.shape[0]
     e, k = cfg.num_experts, cfg.num_experts_per_tok
     held, first = cfg.experts_held, cfg.first_expert_held
     impl = grouped_gemm.default_impl()   # by platform: the kernel on the TPU
@@ -319,15 +360,15 @@ def moe_mlp_nodrop(p: Dict[str, Any], x: jnp.ndarray, cfg,
                 else grouped_gemm.grouped_act(xs, p["w_up"], tiles, act=act,
                                               **kw)
             ys = grouped_gemm.grouped_matmul(mid, p["w_down"], tiles,
-                                             **kw)[tiles.dest]   # [T*k, D]
+                                             **kw)       # [tiles, D]
 
     with scope("moe_combine"):
-        ys = ys * gate_w.reshape(t * k)[order].astype(x.dtype)[:, None]
-        if has_expert is not None:
-            # what ragged_dot leaves in rows past the last group is its own
-            # business: a row with no expert here contributes an exact zero
-            ys = jnp.where(has_expert[order][:, None], ys, 0)
-        out = jnp.zeros((t, d), x.dtype).at[sorted_tok].add(ys)  # moe_gather
+        # moe_gather: where each (token, choice) row went in the sort, and
+        # from there in the kernel's tiles; the down projection's result is
+        # read ONCE, where it lies, and nothing is scattered
+        inv = jnp.argsort(order)
+        out = combine_rows(ys, inv if impl == "xla" else tiles.dest[inv],
+                           gate_w, has_expert, x.dtype)
     if "shared" in p:
         with scope("moe_shared"):
             shared = (glu_mlp if glu else std_mlp)(p["shared"], x[None],

@@ -531,6 +531,53 @@ class TestTuneChainTimer:
                                     "kernel_32x128")] == [1.0, 2.0, 3.0]
 
 
+    def test_the_combine_sweep_runs_both_forms_and_holds_the_sum(
+            self, tune, monkeypatch, capsys):
+        """``tpu_tune.py combine`` at two tiny cells (a held share of the
+        experts, and all of them), the profiler's reading stubbed: the
+        scatter-add of PRs 26-57, the tree's combine, a checkout's (this
+        one) ``combine_rows`` and every candidate for the sort's inverse
+        alone are built and run; the tree's is no further from a float64 sum
+        than the scatter-add, and every candidate gives the same
+        permutation. The cells' shapes come off ``BENCHMARK.json``'s
+        configurations: the seven that serve experts."""
+        import json
+        import os
+
+        assert set(tune._combine_cells()) == {
+            "olmoe-chat-sat", "xing4-docs-sat", "dsv2-answers-sat",
+            "nemo3-reason-sat", "keye-video-sat", "cmdaplus-rag-sat",
+            "solar2-agent-sat"}
+        assert tune._combine_cells()["dsv2-answers-sat"] == dict(
+            k=6, d=5120, experts=160, held=40)
+        monkeypatch.setattr(tune, "_combine_cells", lambda: {
+            "share": dict(k=3, d=64, experts=16, held=8),
+            "whole": dict(k=2, d=32, experts=4, held=4)})
+        ran = []
+
+        def reading(steps, args, **_kw):
+            for name, step in steps.items():
+                jax.block_until_ready(step(*args))
+                ran.append(name)
+            return {name: {"xla": 0.5, "calls": {}} for name in steps}
+
+        monkeypatch.setattr(tune, "_traced_kernels", reading)
+        root = os.path.join(os.path.dirname(__file__), "..", "..")
+        tune.combine(["--rows", "8", "32", "--parent", root])
+        out = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
+        assert [(r["cell"], r["t"]) for r in out] == [
+            ("share", 8), ("share", 32), ("whole", 8), ("whole", 32)]
+        names = {"scatter_add", "tree", "parent"} | {
+            f"inv_{n}" for n in tune.COMBINE_INV}
+        assert set(ran) == names and len(ran) == 4 * len(names)
+        for r in out:
+            assert set(r["rows"]) == names and r["inv_differ"] == 0
+            assert r["err"]["tree"] <= r["err"]["scatter_add"] < 0.1
+            moved = (r["k"] + 1) * r["t"] * r["d"] * 2
+            assert r["rows"]["tree"] == {
+                "us": 500.0, "gb_s": round(moved / 500.0 / 1e3, 1)}
+
+
 class TestSpatialAndTiling:
     """ops/spatial (diffusers fused bias-add family, reference
     csrc/spatial/) and runtime/tiling (reference runtime/zero/tiling.py)."""
@@ -841,3 +888,45 @@ def test_tier1_times_reads_the_drivers_junit(tmp_path):
     assert lines[3].split() == ["13.0", "2", "tests.unit.test_a"]
     assert lines[4].split() == ["3.0", "1", "tests.test_b"]
     assert lines[-1].split() == ["12.2", "tests.unit.test_a.TestX::test_p[1]"]
+
+
+def test_parity_rows_tells_a_tied_worst_row_from_an_untied_one():
+    """``tools/parity_rows.py``'s record of one probe: a row's error is
+    ``benchmark.parity``'s (max |served - reference| over the reference's
+    std), a row is tied where some layer's router gap is under eps, the
+    worst rows come first with their smallest gap, and the checksum of the
+    fed-back ids tells two checkouts that compared different sequences."""
+    import importlib.util
+    import os
+
+    import numpy as np
+
+    path = os.path.join(os.path.dirname(__file__), "..", "..", "tools",
+                        "parity_rows.py")
+    spec = importlib.util.spec_from_file_location("parity_rows", path)
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    rng = np.random.default_rng(0)
+    want = rng.normal(size=(6, 32)).astype(np.float32)
+    off = np.array([0.01, 0.05, 0.02, 0.04, 0.0, 0.03], np.float32)
+    logits = want.copy()
+    logits[:, 0] += off * want.std(-1)
+    eps = 0.0078125
+    gaps = np.full((3, 6), 4 * eps)
+    gaps[1, 3] = eps / 2          # the second-worst row has a near-tie
+    gaps[2, 4] = eps / 4          # ... and so has the exact row
+    ids = np.arange(40)
+    rec = tool.rows_record(ids, 34, logits, want, gaps, eps)
+    assert (rec["tokens"], rec["prompt"], rec["rows"]) == (40, 34, 6)
+    assert rec["rows_with_a_near_tie"] == 2
+    np.testing.assert_allclose(
+        [rec["err_max"], rec["err_max_untied_rows"],
+         rec["err_max_tied_rows"], rec["err_p50_tied_rows"]],
+        [0.05, 0.05, 0.04, 0.02], rtol=1e-4)
+    assert [(w["row"], w["near_ties"], w["min_gap_over_eps"])
+            for w in rec["worst_rows"][:2]] == [(1, 0, 4.0), (3, 1, 0.5)]
+    assert rec["min_gap_over_eps_p50"] == 4.0
+    other = tool.rows_record(ids[::-1], 34, logits, want, gaps, eps)
+    assert other["ids_crc"] != rec["ids_crc"]
+    none_tied = tool.rows_record(ids, 34, logits, want, gaps * 100, eps)
+    assert none_tied["err_max_tied_rows"] is None

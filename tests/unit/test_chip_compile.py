@@ -473,6 +473,70 @@ def test_decode_forward_on_the_tpu_takes_the_kernel(one_chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < e * d * f * 2
 
 
+# ------------------------------------ the experts' combine scatters nothing
+@pytest.mark.parametrize("program", ["decode_forward", "ragged_forward"])
+def test_the_experts_combine_scatters_nothing(one_chip, program, monkeypatch):
+    """Both serving forwards of ``deepseek-v2`` at ``dsv2-answers-sat``'s
+    widths and shapes (the dense layer and ONE expert layer of the five, 40
+    of 160 experts held; 64 sequences, 768 rows, the latent pool): the
+    scopes of the expert layer reach the compiled text, and under
+    ``moe_combine`` there is no ``scatter(``: a token's k rows are gathered
+    out of the tile layout and summed (the scatter-add of PRs 26-57 ran a
+    row at a time, ~26 GB/s: 1.8-2.0 ms a layer of a mixed round here)."""
+    from benchmark import scopes
+    from deepspeedsyclsupport_tpu.inference.v2 import model as M
+    from deepspeedsyclsupport_tpu.inference.v2.kv_cache import (BlockedKV,
+                                                                MoeCounters)
+    from deepspeedsyclsupport_tpu.models import build_model
+    from deepspeedsyclsupport_tpu.ops import grouped_gemm as gg
+
+    monkeypatch.setattr(gg, "default_impl", lambda: "pallas")
+    g = LATENT["dsv2"]
+    model = build_model("deepseek-v2", num_layers=2, num_experts_held=40,
+                        vocab_size=25600, dtype="bfloat16")
+    cfg = model.config
+    bs, seqs, toks, atom = (g["block_size"], g["max_sequences"],
+                            g["max_tokens"], g["atom"])
+    bps = g["max_context"] // bs
+
+    def on_chip(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: on_chip(x.shape, jnp.bfloat16 if jnp.issubdtype(
+            x.dtype, jnp.floating) else x.dtype),
+        jax.eval_shape(model.init_params))
+    zero = on_chip(())
+    kv = BlockedKV(on_chip((2, g["slots"], g["row"]), jnp.bfloat16), None,
+                   MoeCounters(on_chip((1, cfg.num_experts)), zero, zero,
+                               zero))
+    sampled = on_chip((seqs + 3,))
+    if program == "decode_forward":
+        fn = M.build_decode_forward_fn(model, bs, "pallas")
+        args = (on_chip((seqs,)), on_chip((seqs,)), on_chip((seqs, bps)),
+                on_chip((seqs,), jnp.bool_), sampled, on_chip((seqs,)))
+    else:
+        fn = M.build_ragged_forward_fn(model, bs, "kernel")
+        atoms = seqs + toks // atom + 1
+        args = (on_chip((toks,)), on_chip((toks,)), on_chip((toks,)),
+                on_chip((seqs, bps)), on_chip((seqs,)),
+                on_chip((atoms, atom)), on_chip((atoms,)), on_chip((atoms,)),
+                on_chip((atoms, bps)), on_chip((toks,)), on_chip((seqs,)),
+                on_chip((seqs,)), sampled, on_chip((toks,)))
+    text = fn.lower(params, kv, *args).compile().as_text()
+    under = scopes.instructions_under(
+        text, ("moe_route", "moe_experts", "moe_combine", "moe_shared"))
+    assert set(under.values()) == {"moe_route", "moe_experts", "moe_combine",
+                                   "moe_shared"}
+    assert {"grouped_glu", "grouped_matmul"} \
+        <= {name.split(".")[0] for name in under}
+    # (fused instructions carry their own op_name: every line is looked at)
+    combine = [ln for ln in text.splitlines()
+               if "op_name=" in ln and "/moe_combine/" in ln]
+    assert combine and not [ln for ln in combine
+                            if re.search(r" scatter\(", ln)]
+
+
 # ------------------------------------- the forwards' tokens from the device
 @pytest.mark.parametrize("program", ["decode_forward", "ragged_forward"])
 def test_the_forwards_take_decode_tokens_from_the_sampler(one_chip, program):

@@ -2,8 +2,10 @@
 forwards' ``live`` mask, ``kv_cache.MoeCounters``): a pad row gets no expert,
 the device's load counter equals ``k x live tokens`` in every layer over
 rounds and through an eviction with requeue, the
-round record carries the experts the forward before it touched, and
-mixtral's renormalised weighting is bit for bit what it was."""
+round record carries the experts the forward before it touched,
+mixtral's renormalised weighting is what it was, and the combine (one gather
+of a token's k rows, a weighted sum in float32) is no further from a float64
+sum than the scatter-add it replaced."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,6 +14,8 @@ import pytest
 from deepspeedsyclsupport_tpu.inference.v2 import (
     InferenceEngineV2, ServingPolicyConfig, ServingSession)
 from deepspeedsyclsupport_tpu.models import build_model, get_config
+from deepspeedsyclsupport_tpu.ops import grouped_gemm
+from deepspeedsyclsupport_tpu.parallel import moe
 from deepspeedsyclsupport_tpu.parallel.moe import (moe_mlp_nodrop,
                                                    topk_gating, topk_weights)
 from tests.unit import stream_ends
@@ -85,6 +89,11 @@ def _nodrop_before(p, x, cfg):
 
 @pytest.mark.parametrize("live", [None, "some"])
 def test_mixtral_weighting_is_bit_identical_to_before(tiny_moe, live):
+    """The WEIGHTING of PR 25 (top-k of the softmax, renormalised) against
+    ``_nodrop_before``. Held to float32's rounding and no longer to the bit:
+    the combine sums a token's k rows in float32 in the choices' order and
+    rounds once, where ``_nodrop_before`` scatter-adds them in the sort's
+    order; a wrong weight would be off by far more than an ulp."""
     model, params = tiny_moe
     cfg = model.config
     assert cfg.norm_topk_prob
@@ -93,7 +102,111 @@ def test_mixtral_weighting_is_bit_identical_to_before(tiny_moe, live):
     want = np.asarray(_nodrop_before(_layer0(params), x, cfg))
     got, _rows = moe_mlp_nodrop(_layer0(params), x, cfg, mask)
     keep = slice(None) if mask is None else np.asarray(mask)
-    assert np.array_equal(np.asarray(got)[keep], want[keep])
+    np.testing.assert_allclose(np.asarray(got)[keep], want[keep],
+                               rtol=1e-6, atol=1e-7)
+
+
+# ------------------------------------------------------------- the combine
+def _scatter_add_before(ys, at, gate_w, has_expert, dtype):
+    """The combine of PRs 26-57 on the rows ``combine_rows`` is handed: each
+    (token, choice) row weighted in ``dtype``, a row with no expert zeroed,
+    one scatter-add (k - 1 roundings a token)."""
+    t, k = gate_w.shape
+    rows = ys[at] * gate_w.reshape(t * k).astype(dtype)[:, None]
+    if has_expert is not None:
+        rows = jnp.where(has_expert[:, None], rows, 0)
+    return jnp.zeros((t, ys.shape[1]), dtype).at[
+        jnp.repeat(jnp.arange(t), k)].add(rows)
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+@pytest.mark.parametrize("held", ["all", "share"])
+@pytest.mark.parametrize("live", [None, "some", "none"])
+@pytest.mark.parametrize("experts", ["glu", "mlp"])
+def test_the_combine_is_a_float64_sum_rounded_once(monkeypatch, experts, live,
+                                                   held, impl):
+    """``moe_mlp_nodrop`` in bfloat16 (8 experts, 4 a token; all held, or
+    ids 2-5), with what it hands ``combine_rows`` listened to: the result
+    against the same rows summed in float64 token by token is no further
+    off than the scatter-add form on the same rows, a token none of whose
+    choices has an expert here reads exact zeros, NaN in every row that no
+    choice with an expert reads (the tiles past ``tiles.live`` among them)
+    reaches no output, and the whole layer is the experts' MLPs of each
+    token's choices, computed from the weights in float64."""
+    share = dict(num_experts_held=4, first_expert_held=2) \
+        if held == "share" else {}
+    form = dict(mlp_type="mlp", activation="gelu") if experts == "mlp" else {}
+    model = build_model("tiny-moe", num_experts=8, num_experts_per_tok=4,
+                        num_layers=1, dtype="bfloat16", **share, **form)
+    cfg = model.config
+    p = jax.tree_util.tree_map(lambda w: w.astype(jnp.bfloat16),
+                               _layer0(model.init_params()))
+    t, k = 40, cfg.num_experts_per_tok
+    x = jax.random.normal(jax.random.PRNGKey(4), (t, cfg.hidden_size),
+                          jnp.bfloat16)
+    mask = {None: None, "some": jnp.arange(t) % 5 != 2,
+            "none": jnp.zeros(t, bool)}[live]
+    monkeypatch.setattr(grouped_gemm, "default_impl", lambda: impl)
+    heard = []
+    combine = moe.combine_rows
+    monkeypatch.setattr(moe, "combine_rows",
+                        lambda *a: heard.append(a) or combine(*a))
+    got, routed = moe_mlp_nodrop(p, x, cfg, mask)
+    (ys, at, gate_w, has_expert, dtype), = heard
+    assert got.dtype == dtype == jnp.bfloat16
+    got = np.asarray(got, np.float64)
+    assert int(routed.sum()) == k * (t if mask is None else int(mask.sum()))
+
+    has = np.ones((t, k), bool) if has_expert is None \
+        else np.asarray(has_expert).reshape(t, k)
+    assert (has_expert is None) == (live is None and held == "all")
+    rows64 = np.asarray(ys, np.float64)[np.asarray(at)].reshape(t, k, -1)
+    w64 = np.asarray(gate_w, np.float64)
+    want = np.zeros_like(got)
+    for tok in range(t):
+        for j in range(k):
+            if has[tok, j]:
+                want[tok] += w64[tok, j] * rows64[tok, j]
+    before = np.asarray(_scatter_add_before(ys, at, gate_w, has_expert, dtype),
+                        np.float64)
+    assert np.abs(got - want).max() <= np.abs(before - want).max()
+    assert np.abs(got - want).max() <= 2.0 ** -8 * np.abs(want).max() + 1e-30
+    dead = ~has.any(1)
+    assert dead.sum() >= {None: 0, "some": t // 5, "none": t}[live]
+    assert not got[dead].any()
+
+    # NaN wherever no (token, choice) with an expert reads
+    read = np.zeros(ys.shape[0], bool)
+    read[np.asarray(at)[has.reshape(-1)]] = True
+    if impl != "xla":      # the tiles' layout, dead tiles behind the live
+        assert ys.shape[0] > t * k and not read[-1]
+    planted = jnp.where(read[:, None], ys, jnp.nan)
+    again = combine(planted, at, gate_w, has_expert, dtype)
+    assert np.array_equal(np.asarray(again, np.float64), got)
+
+    # the layer from its weights: routing as the layer makes it, every
+    # choice's expert MLP in float64
+    def f64(a):
+        return np.asarray(a, np.float64)
+
+    _w, idx = topk_weights(
+        moe.router_scores(x.astype(jnp.float32) @ p["router"].astype(
+            jnp.float32), cfg), k, cfg.norm_topk_prob)
+    idx = np.asarray(idx) - cfg.first_expert_held
+    act = jax.nn.silu if experts == "glu" else jax.nn.gelu
+    layer = np.zeros_like(got)
+    for tok in range(t):
+        for j in range(k):
+            if not has[tok, j]:
+                continue
+            e, row = idx[tok, j], f64(x[tok])
+            up = row @ f64(p["w_up"][e])
+            mid = f64(act(jnp.asarray(row @ f64(p["w_gate"][e]),
+                                      jnp.float32))) * up \
+                if experts == "glu" else f64(act(jnp.asarray(up, jnp.float32)))
+            layer[tok] += w64[tok, j] * (mid @ f64(p["w_down"][e]))
+    np.testing.assert_allclose(got, layer, rtol=0,
+                               atol=0.03 * max(np.abs(layer).max(), 1e-30))
 
 
 def test_pad_rows_get_no_expert_and_give_zero(tiny_moe):

@@ -43,7 +43,18 @@ Sections, cheapest first:
             form on the chip over several steps:
             conv [--parent DIR] [--slots N ...] [--lanes N ...] [--parity]
 
-Usage:  python tools/tpu_tune.py [calib|flash|paged|retention|dsa|kda|conv|all]
+  combine — the experts' combine ALONE at the served sparse cells'
+            (experts a token, model width, experts held: read off
+            ``BENCHMARK.json``'s configurations) and 32 / 128 / 768 tokens a
+            forward: the scatter-add of PRs 26-57
+            beside ``parallel/moe.combine_rows`` (one gather of a token's k
+            rows out of the tile layout, a weighted sum in float32), us a
+            layer and GB/s, and the candidates for the inverse of the sort,
+            each alone:
+            combine [--parent DIR] [--cell C ...] [--rows N ...]
+
+Usage:  python tools/tpu_tune.py
+            [calib|flash|paged|retention|dsa|kda|conv|combine|all]
 """
 import functools
 import json
@@ -1304,6 +1315,181 @@ def conv(argv=()):
              rows=rows, failed=failed)
 
 
+COMBINE_ROWS = (32, 128, 768)     # tokens a forward
+
+
+def _combine_cells():
+    """``(k, d, experts, held)`` of every cell of ``BENCHMARK.json`` that
+    serves sparse experts, off the preset and overrides its configuration's
+    file names: the shapes the served combine runs at."""
+    from deepspeedsyclsupport_tpu.models import get_config
+
+    root = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    files = {c["name"]: c["file"] for c in bench["configs"]}
+    cells = {}
+    for w in bench["workloads"]:
+        with open(os.path.join(root, files[w["config"]])) as f:
+            cfg = json.load(f)
+        m = get_config(cfg["preset"], **cfg.get("overrides", {}))
+        if m.num_experts:
+            cells[w["name"]] = dict(k=m.num_experts_per_tok, d=m.hidden_size,
+                                    experts=m.num_experts,
+                                    held=m.experts_held)
+    return cells
+
+
+def _combine_operands(c, t, seed=0):
+    """One layer's routing of ``t`` tokens as ``moe_mlp_nodrop`` makes it on
+    the TPU (``k`` distinct experts a token drawn evenly over the router's
+    width, a row whose expert is not held sorted behind the last group), and
+    what the combine reads: ``(ys [tiles, d] bfloat16 in the kernel's tile
+    layout, gate_w [t, k] float32, has_expert [t*k], here [t*k], order,
+    sorted_tok, dest, group_sizes)``."""
+    from deepspeedsyclsupport_tpu.ops import grouped_gemm as gg
+
+    k, e, held = c["k"], c["experts"], c["held"]
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    scores = jax.random.uniform(ks[0], (t, e))
+    gate_w, idx = jax.lax.top_k(scores, k)
+    here = idx.reshape(t * k)
+    has_expert = here < held
+    here = jnp.where(has_expert, here, held)
+    order = jnp.argsort(here, stable=True)
+    group_sizes = jnp.bincount(here, length=held + 1)[:held].astype(jnp.int32)
+    tiles = gg.tile_rows(group_sizes, here[order], gg.row_tile(t * k, e))
+    ys = jax.random.normal(ks[1], (tiles.src.shape[0], c["d"]), jnp.bfloat16)
+    return (ys, gate_w, has_expert, here.astype(jnp.int32), order,
+            jnp.repeat(jnp.arange(t), k)[order], tiles.dest, group_sizes)
+
+
+def _combine_scatter_add(ys, gate_w, has_expert, here, order, sorted_tok,
+                         dest, group_sizes):
+    """The combine of PRs 26-57 as it stood: the tiles gathered to sorted
+    order, each row weighted in the rows' dtype, one scatter-add."""
+    t = gate_w.shape[0]
+    ys = ys[dest] * gate_w.reshape(-1)[order].astype(ys.dtype)[:, None]
+    ys = jnp.where(has_expert[order][:, None], ys, 0)
+    return jnp.zeros((t, ys.shape[1]), ys.dtype).at[sorted_tok].add(ys)
+
+
+def _inv_argsort(here, order, group_sizes):
+    return jnp.argsort(order)
+
+
+def _inv_scatter(here, order, group_sizes):
+    n = order.shape[0]
+    return jnp.zeros((n,), jnp.int32).at[order].set(
+        jnp.arange(n, dtype=jnp.int32), unique_indices=True)
+
+
+def _inv_rank(here, order, group_sizes):
+    """A row's place in the sort from the groups' running sum and its rank
+    among the rows of its own group (a cumulative one-hot; the rows in no
+    group are the last one's)."""
+    g = group_sizes.shape[0]
+    hot = jax.nn.one_hot(here, g + 1, dtype=jnp.int32)
+    rank = jnp.take_along_axis(jnp.cumsum(hot, axis=0) - hot,
+                               here[:, None], axis=1)[:, 0]
+    start = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                             jnp.cumsum(group_sizes)])
+    return start[here] + rank
+
+
+# the candidates for the sort's inverse, each timed ALONE;
+# ``moe_mlp_nodrop`` takes the first
+COMBINE_INV = {"argsort": _inv_argsort, "scatter": _inv_scatter,
+               "rank": _inv_rank}
+
+
+def _combine_tree(moe):
+    """``moe``'s combine as ``moe_mlp_nodrop`` calls it on the kernel's
+    path."""
+    def form(ys, gate_w, has_expert, here, order, sorted_tok, dest,
+             group_sizes):
+        return moe.combine_rows(ys, dest[jnp.argsort(order)], gate_w,
+                                has_expert, ys.dtype)
+    return form
+
+
+def combine(argv=()):
+    """The experts' combine ALONE at the served sparse cells' ``(k, d,
+    experts held)`` and ``--rows`` tokens a forward, its device time read
+    off a profiler trace, one layer a program: ``scatter_add`` (the form of
+    PRs 26-57, written out above), ``tree`` (``parallel/moe.combine_rows``
+    behind the inverse ``moe_mlp_nodrop`` takes), ``--parent DIR``'s
+    ``combine_rows`` where it has one, and each candidate for the sort's
+    inverse alone (``inv_<name>``).
+    Each row: us a layer, and the GB/s of what a combine has to move (every
+    (token, choice) row read once, every token's written once). ``err``: the
+    largest error of ``scatter_add`` and of ``tree`` against a float64 sum
+    on the host."""
+    import argparse
+    import importlib.util
+
+    from deepspeedsyclsupport_tpu.parallel import moe as tree
+
+    cells = _combine_cells()
+    ap = argparse.ArgumentParser(prog="tpu_tune.py combine")
+    ap.add_argument("--parent", default=None)
+    ap.add_argument("--cell", nargs="*", default=list(cells))
+    ap.add_argument("--rows", type=int, nargs="*", default=list(COMBINE_ROWS))
+    a = ap.parse_args(list(argv))
+    parent = None
+    if a.parent:
+        # a module of the package, so that its relative imports resolve
+        spec = importlib.util.spec_from_file_location(
+            "deepspeedsyclsupport_tpu.parallel.moe_parent", os.path.join(
+                a.parent, "deepspeedsyclsupport_tpu", "parallel", "moe.py"))
+        parent = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(parent)
+    for cell in a.cell:
+        c = cells[cell]
+        for t in a.rows:
+            args = _combine_operands(c, t)
+            plan = {"scatter_add": _combine_scatter_add,
+                    "tree": _combine_tree(tree)}
+            if parent is not None and hasattr(parent, "combine_rows"):
+                plan["parent"] = _combine_tree(parent)
+            for n, f in COMBINE_INV.items():
+                plan[f"inv_{n}"] = lambda *ar, f=f: f(ar[3], ar[4], ar[7])
+            steps = {name: _named(name, fn, tag).lower(*args).compile()
+                     for tag, (name, fn) in enumerate(plan.items())}
+            rows = _traced_kernels(steps, args,
+                                   kernel_of=lambda text: "kernel")
+            moved = (c["k"] + 1) * t * c["d"] * 2
+            for row in rows.values():
+                row.pop("calls", None)
+                row["us"] = round(1e3 * row.pop("xla"), 1)
+                if row["us"]:
+                    row["gb_s"] = round(moved / row["us"] / 1e3, 1)
+            want = _combine_float64(args)
+            err = {name: float(np.max(np.abs(np.asarray(
+                steps[name](*args)[0], np.float64) - want)))
+                for name in ("scatter_add", "tree")}
+            # every candidate's inverse is the same permutation
+            inv = [np.asarray(steps[f"inv_{n}"](*args)[0])
+                   for n in COMBINE_INV]
+            temp = steps["tree"].memory_analysis().temp_size_in_bytes
+            emit("combine", cell=cell, t=t, **c, moved_bytes=moved,
+                 rows=rows, err=err, tree_temp_mib=round(temp / 2**20, 1),
+                 inv_differ=int(sum((i != inv[0]).sum() for i in inv[1:])))
+
+
+def _combine_float64(args):
+    """The combine's sum on the host in float64, token by token."""
+    ys, gate_w, has_expert, _here, order, sorted_tok, dest, _sizes = (
+        np.asarray(a) for a in args)
+    ys = ys.astype(np.float64)
+    out = np.zeros((gate_w.shape[0], ys.shape[1]))
+    w = gate_w.reshape(-1).astype(np.float64)
+    for i, (row, tok) in enumerate(zip(order, sorted_tok)):
+        if has_expert[row]:
+            out[tok] += w[row] * ys[dest[i]]
+    return out
+
+
 if __name__ == "__main__":
     which = sys.argv[1] if len(sys.argv) > 1 else "all"
     if which in ("calib", "all"):
@@ -1320,3 +1506,5 @@ if __name__ == "__main__":
         kda(sys.argv[2:] if which == "kda" else ())
     if which in ("conv", "all"):
         conv(sys.argv[2:] if which == "conv" else ())
+    if which in ("combine", "all"):
+        combine(sys.argv[2:] if which == "combine" else ())
