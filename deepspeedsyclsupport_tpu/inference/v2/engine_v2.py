@@ -524,9 +524,9 @@ class InferenceEngineV2:
             # whose state they read and wrote (a one-token chunk is one
             # piece, a longer one a piece every state_chunk_size rows),
             # summed over those layers: ssm_* for Mamba-2 layers, ret_* for
-            # power-retention layers and kda_* for delta-rule layers, which
-            # also say how many of the pieces start a sequence (they read
-            # no state)
+            # power-retention layers, kda_* for delta-rule layers and la_*
+            # for lightning layers, which also say how many of the pieces
+            # start a sequence (they read no state)
             mc = self.model.config
             q = mc.state_chunk_size
             kind = self.kv.state_kind
@@ -584,7 +584,8 @@ class InferenceEngineV2:
         """The recurrent state of a model that keeps one, of any kind
         (None for any other): ``bytes_per_slot`` (all its state layers:
         Mamba-2's SSM state and convolution tail, power retention's state
-        and normaliser, the delta rule's state and convolution tail),
+        and normaliser, the delta rule's state and convolution tail,
+        lightning attention's state),
         ``slots``, ``slots_live``, ``dtype``, ``layers``,
         ``pool_bytes``."""
         return state_pool_stats(self.kv, sum(
@@ -594,8 +595,9 @@ class InferenceEngineV2:
         if self._state_free is not None:
             raise NotImplementedError(
                 f"{what} is not available for a model with recurrent state "
-                f"(ModelConfig.state_layers: Mamba-2 or power-retention "
-                f"layers, or delta-rule ones): it would need {missing}")
+                f"(ModelConfig.state_layers: lightning, Mamba-2 or "
+                f"power-retention layers, or delta-rule ones): it would "
+                f"need {missing}")
 
     def _new_seq(self, uid: int, **fields) -> SequenceDescriptor:
         """A fresh descriptor in ``seqs``; a model with recurrent state
@@ -1316,9 +1318,13 @@ class InferenceEngineV2:
         sparse-expert model's :meth:`moe_tail` over all of
         ``reqtrace.MOE_TAIL_FIELDS``; a looped stack's exit counter
         (``kv.exit_pass`` [passes], summed since the engine was built: a
-        looped stack has no experts); None for every other model."""
+        looped stack has no experts); what the sparse layers of a model
+        that reads selected blocks counted (``kv.bsa``, ``bsa.COUNTS``);
+        None for every other model."""
         if self.kv.exit_pass is not None:
             return (self.kv.exit_pass,)
+        if self.kv.bsa is not None:    # (dense feed-forward parts: no experts)
+            return (self.kv.bsa,)
         return self.moe_tail(MOE_TAIL_FIELDS)
 
     def tail_fields(self, counted: Sequence[int]) -> Dict[str, Any]:
@@ -1326,6 +1332,10 @@ class InferenceEngineV2:
         ``round`` record gives them."""
         if self.kv.exit_pass is not None:
             return {"exit_pass": list(counted)}
+        if self.kv.bsa is not None:
+            from .bsa import COUNTS
+
+            return dict(zip(COUNTS, counted))
         return dict(zip(MOE_TAIL_FIELDS, counted))
 
     def sample_launch(self, uids: Sequence[int], rng: jax.Array,

@@ -65,6 +65,10 @@ RETENTION_STATE_DTYPE = jnp.float32
 # A delta-rule layer's state: float32 for the same reason (every write first
 # subtracts what the state holds for its key), a constant too.
 KDA_STATE_DTYPE = jnp.float32
+# A lightning layer's state: float32 for the same reason (its slow heads sum
+# some hundreds of outer products, with no normaliser behind them), a
+# constant too.
+LIGHTNING_STATE_DTYPE = jnp.float32
 
 
 class MoeCounters(NamedTuple):
@@ -122,6 +126,11 @@ class BlockedKV(NamedTuple):
     ret_z: Optional[jnp.ndarray] = None
     kda_s: Optional[jnp.ndarray] = None
     kda_conv: Optional[jnp.ndarray] = None
+    # Lightning layers (``layer_pattern``'s ``L``): ``la_s`` [L_l, S + 1,
+    # heads, dim, dim] in :data:`LIGHTNING_STATE_DTYPE`, the key's channels
+    # on the sublanes (``ops/ssm.py``); a kind of ONE array: no convolution,
+    # no tail.
+    la_s: Optional[jnp.ndarray] = None
     # a looped stack only (``ModelConfig.total_ut_steps`` > 1; None
     # elsewhere: no leaf, the same program): [passes] int32, the rows the
     # forwards unembedded for a live sequence, by the pass the exit rule
@@ -141,6 +150,16 @@ class BlockedKV(NamedTuple):
     # sequences' window tables
     wk: Optional[jnp.ndarray] = None
     wv: Optional[jnp.ndarray] = None
+    # a model whose attention reads blocks chosen from pooled keys only
+    # (``ModelConfig.sparse_block_topk``; None elsewhere: no leaf, the same
+    # program): ``ck`` [L, num_blocks, block / stride, KVH, D] in the pool's
+    # dtype, the mean of every window of keys at the PAGE the window starts
+    # in, so the block table addresses it and a page that is freed or
+    # requeued takes its pooled keys with it; and ``bsa`` int32, what
+    # the last forward's sparse layers counted (``bsa.COUNTS``), which rides
+    # as ``moe`` does
+    ck: Optional[jnp.ndarray] = None
+    bsa: Optional[jnp.ndarray] = None
 
     @property
     def num_slots(self) -> int:
@@ -159,7 +178,7 @@ class BlockedKV(NamedTuple):
 
     @property
     def state_kind(self) -> Optional[str]:
-        """``ssm`` | ``ret`` | ``kda`` (None: no recurrent state): what the
+        """``ssm`` | ``ret`` | ``kda`` | ``la`` (None: no recurrent state): what the
         ``round`` record's counts of the state layers are named by."""
         names = self.state_names
         return names[0].split("_")[0] if names else None
@@ -167,7 +186,7 @@ class BlockedKV(NamedTuple):
     @property
     def state(self):
         """The recurrent-state arrays there are: (ssm, conv), (ret_s,
-        ret_z), (kda_s, kda_conv) or ()."""
+        ret_z), (kda_s, kda_conv), (la_s,) or ()."""
         return tuple(getattr(self, n) for n in self.state_names)
 
     @property
@@ -185,7 +204,8 @@ class BlockedKV(NamedTuple):
     def pools(self):
         """The pool arrays there are, under :data:`POOL_NAMES`: (k, v), (k,)
         for a latent pool, (k, v, idx) beside a sparse-attention indexer,
-        (k, v, wk, wv) for a stack of two attention kinds."""
+        (k, v, ck) beside pooled keys, (k, v, wk, wv) for a stack of two
+        attention kinds."""
         return tuple(pool for pool in map(self.__getattribute__, POOL_NAMES)
                      if pool is not None)
 
@@ -197,10 +217,11 @@ class BlockedKV(NamedTuple):
 
 
 # the fields of :class:`BlockedKV` that are pools addressed by block tables
-POOL_NAMES = ("k", "v", "idx", "wk", "wv")
-# ... and those that are recurrent state addressed by sequence slot, a pair
-# a kind of state layer; the FIRST of a pair has its slots on axis 1
-STATE_NAMES = (("ssm", "conv"), ("ret_s", "ret_z"), ("kda_s", "kda_conv"))
+POOL_NAMES = ("k", "v", "idx", "ck", "wk", "wv")
+# ... and those that are recurrent state addressed by sequence slot, the
+# arrays of a kind of state layer together; the FIRST has its slots on axis 1
+STATE_NAMES = (("ssm", "conv"), ("ret_s", "ret_z"), ("kda_s", "kda_conv"),
+               ("la_s",))
 
 
 def lane_padded_head_dim(head_dim: int, pad) -> int:
@@ -299,6 +320,33 @@ def init_blocked_kv(model_config, cfg: RaggedInferenceConfig,
             kda_conv=jnp.zeros((lead[0], mc.kda_conv_kernel - 1, lead[1],
                                 3 * mc.kda_dim), cfg.dtype)),
             out_shardings=topology.replicated())()
+    if model_config.pattern_count("L"):
+        mc, lead = model_config, (model_config.pattern_count("L"),
+                                  cfg.max_sequences + 1)
+        h, d = mc.lightning_heads, mc.lightning_head_dim
+        if np.prod(lead) * h * d * d >= 2**31:
+            raise ValueError(
+                f"the lightning state [{lead}, {h}, {d}, {d}] passes 2^31 "
+                f"elements, which one array may not: fewer max_sequences "
+                f"or layers")
+        state = jax.jit(lambda: dict(
+            la_s=jnp.zeros((*lead, h, d, d), LIGHTNING_STATE_DTYPE)),
+            out_shardings=topology.replicated())()
+    if model_config.sparse_block_topk:
+        mc = model_config
+        if cfg.block_size != mc.sparse_block_size:
+            raise ValueError(
+                f"block_size {cfg.block_size}: a block the sparse attention "
+                f"selects is a page of the pool, so block_size must equal "
+                f"sparse_block_size {mc.sparse_block_size}")
+        from .bsa import COUNTS
+
+        shape_c = (shape[0], cfg.num_blocks,
+                   mc.sparse_block_size // mc.sparse_block_stride, *row)
+        state.update(jax.jit(lambda: dict(
+            ck=jnp.zeros(shape_c, cfg.dtype),
+            bsa=jnp.zeros((len(COUNTS),), jnp.int32)),
+            out_shardings=topology.replicated())())
     if model_config.index_topk:
         if cfg.block_size % 2:
             raise ValueError("a sparse-attention indexer's keys lie two "
@@ -323,8 +371,8 @@ def init_blocked_kv(model_config, cfg: RaggedInferenceConfig,
 def state_pool_stats(kv: BlockedKV, live: int) -> Optional[dict]:
     """What the recurrent state of a model costs, of any kind (Mamba-2
     layers' SSM state and convolution tail, power-retention layers' state
-    and normaliser, delta-rule layers' state and convolution tail; None for
-    a model without): bytes a sequence slot over
+    and normaliser, delta-rule layers' state and convolution tail, lightning
+    layers' state; None for a model without): bytes a sequence slot over
     all its state layers, the slots there are (the sink not counted) and
     how many are a sequence's now, the state's dtype and its layers.
     Shape-only, no transfer."""

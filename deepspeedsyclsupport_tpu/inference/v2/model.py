@@ -52,7 +52,12 @@ and a row index of its own. And an eighth: the gated delta rule
 (``layer_pattern``'s ``K`` layers, ``ops/kda.py``: :func:`_kda_mixer`), a
 third kind of recurrent state behind the same slots, which
 :func:`_walk_pattern` walks as it walks Mamba-2's, beside attention layers
-whose output is gated (``cfg.attn_out_gate``).
+whose output is gated (``cfg.attn_out_gate``). And a ninth: lightning linear
+attention (``layer_pattern``'s ``L`` layers: :func:`_lightning_mixer`,
+``ops/ssm.py``'s convolution-free entries), a fourth kind of state behind the
+slots, beside dense feed-forward parts (``F``) and attention layers that read
+a SELECTION of blocks chosen from pooled keys (``cfg.sparse_block_topk``:
+``bsa.py``), every sublayer under muP's ``cfg.residual_scale``.
 """
 from contextlib import nullcontext
 from typing import Any, NamedTuple, Optional, Tuple
@@ -305,6 +310,8 @@ def _embed(params, tokens, positions, cfg):
         pos = jnp.clip(positions + cfg.pos_embed_offset, 0,
                        table.shape[0] - 1)
         x = x + jnp.take(table, pos, axis=0).astype(x.dtype)
+    if cfg.embed_scale != 1.0:
+        x = x * cfg.embed_scale
     x = x.astype(jnp.dtype(cfg.dtype))
     if cfg.embed_norm:
         x = norm(x, params["embed_norm"], cfg)
@@ -958,13 +965,50 @@ def _kda_mixer(cfg, p, x, conv_fn, scan_fn):
                 ).astype(x.dtype)
 
 
+def _branch(cfg, h):
+    """A sublayer's write under muP's depth scaling (``cfg.residual_scale``;
+    1.0: as it is)."""
+    return h if cfg.residual_scale == 1.0 else h * cfg.residual_scale
+
+
+def _lightning_mixer(cfg, p, x, positions, scan_fn):
+    """One lightning linear-attention layer over flat tokens x [T, d]: q, k,
+    v and the gate's row from the normed input; an RMS norm a head on q and
+    k and the rotation over the whole head; the recurrence (``scan_fn(q, k,
+    v) -> [T, heads, dim]`` float32, against the state; q scaled by
+    ``dim^-1/2``); an RMS norm over all the heads' outputs together times
+    ``sigmoid`` of the gate; ``wo``."""
+    f32 = jnp.float32
+    n, h, d = x.shape[0], cfg.lightning_heads, cfg.lightning_head_dim
+    rot = lambda t: apply_rope(  # noqa: E731
+        t[None], positions[None], cfg.rope_theta)[0]
+    y = norm(x, p["norm"], cfg)
+    with scope("la_proj"):
+        q, k, v, z = (y @ p[w] for w in ("wq", "wk", "wv", "wz"))
+    with scope("la_gate"):
+        q = rot(rms_norm(q.reshape(n, h, d), p["q_norm"]["scale"],
+                         cfg.rms_norm_eps))
+        k = rot(rms_norm(k.reshape(n, h, d), p["k_norm"]["scale"],
+                         cfg.rms_norm_eps))
+        q = q.astype(f32) * d ** -0.5
+    with scope("la_scan"):
+        out = scan_fn(q, k.astype(f32), v.reshape(n, h, d).astype(f32))
+    with scope("la_gate"):
+        out = rms_norm(out.reshape(n, -1), p["o_norm"]["scale"],
+                       cfg.rms_norm_eps) * jax.nn.sigmoid(z.astype(f32))
+    with scope("la_proj"):
+        return (x + _branch(cfg, out.astype(x.dtype) @ p["wo"])
+                ).astype(x.dtype)
+
+
 def _walk_pattern(cfg, params, x, kv: BlockedKV, attend, ssm_step, live,
-                  kda=None):
+                  kda=None, lightning=None):
     """The layer loop of both serving forwards for a ``cfg.layer_pattern``
     model: :func:`layer_plan`'s runs, each kind of layer indexing ITS stack
     of parameters and ITS cache (``attn_layers`` and the KV pool for ``*``,
     ``mamba_layers`` or ``kda_layers`` and the recurrent state for ``M`` or
-    ``K``, ``layers`` and the expert counters for ``E``). As in
+    ``K``, ``lightning_layers`` and the state for ``L``, ``ffn_layers`` for
+    ``F``, ``layers`` and the expert counters for ``E``). As in
     :func:`_scan_layers` the pools ride as carry and the routed experts'
     matrices stay closed over; the other leaves are read at the layer's
     (traced) index inside the loop body, which is what a scan's xs are.
@@ -972,8 +1016,11 @@ def _walk_pattern(cfg, params, x, kv: BlockedKV, attend, ssm_step, live,
     ``attend(p_attn, y, pools, l) -> (rows [T, H, D], pools)``,
     ``ssm_step(p, xbc, dt, state, l) -> (y [T, d_inner], state)`` and
     ``kda`` = ``(conv(p, qkv, state, l) -> (out, state), scan(q, k, v, g,
-    beta, state, l) -> (out, state))`` are the forward's own. Every layer is
-    ``x + mixer(norm(x))``."""
+    beta, state, l) -> (out, state))`` and ``lightning`` = ``(positions,
+    scan(q, k, v, state, l) -> (out, state))`` are the forward's own. Every
+    layer is ``x + mixer(norm(x))``. A model whose attention reads selected
+    blocks carries what it counts (``kv.bsa``) behind the pools, zeroed at
+    the forward's start."""
     layers, stack = _experts_in_place(params.get("layers", {}), x.dtype)
     at = lambda tree, j: jax.tree_util.tree_map(  # noqa: E731
         lambda a: a[j], tree)
@@ -1001,6 +1048,18 @@ def _walk_pattern(cfg, params, x, kv: BlockedKV, attend, ssm_step, live,
 
             x = _kda_mixer(cfg, at(params["kda_layers"], j), x, conv_fn,
                            scan_fn)
+        elif kind == "L":
+            def la_fn(*rows):
+                nonlocal state
+                out, state = lightning[1](*rows, state, j)
+                return out
+
+            x = _lightning_mixer(cfg, at(params["lightning_layers"], j), x,
+                                 lightning[0], la_fn)
+        elif kind == "F":
+            p = at(params["ffn_layers"], j)
+            m, _ = _mlp(p, norm(x, p["mlp_norm"], cfg), cfg, live)
+            x = (x + _branch(cfg, m)).astype(x.dtype)
         elif kind == "*":
             p = at(params["attn_layers"], j)
             y = norm(x, p["attn_norm"], cfg)
@@ -1012,16 +1071,19 @@ def _walk_pattern(cfg, params, x, kv: BlockedKV, attend, ssm_step, live,
                     rows_attn = (rows_attn.astype(jnp.float32)
                                  * open_.reshape(rows_attn.shape)
                                  ).astype(rows_attn.dtype)
-            x = (x + _attn_out(p["attn"], rows_attn, cfg, x.shape[0])
-                 ).astype(x.dtype)
+            x = (x + _branch(cfg, _attn_out(p["attn"], rows_attn, cfg,
+                                            x.shape[0]))).astype(x.dtype)
         else:
             p = _dequant(at(layers, j), x.dtype)
             m, rows = _mlp(p, norm(x, p["mlp_norm"], cfg), cfg, live,
                            (stack, j))
-            x = (x + m).astype(x.dtype)
+            x = (x + _branch(cfg, m)).astype(x.dtype)
         return (x, pools, state), rows
 
-    carry, done, routed = (x, kv.pools, kv.state), dict.fromkeys("MKE*", 0), []
+    n_pools = len(kv.pools)
+    counts = () if kv.bsa is None else (jnp.zeros_like(kv.bsa),)
+    carry, done, routed = (x, kv.pools + counts, kv.state), \
+        dict.fromkeys("MKLEF*", 0), []
     for unit, reps in layer_plan(cfg.layer_pattern):
         per = {kind: unit.count(kind) for kind in done}
 
@@ -1045,7 +1107,8 @@ def _walk_pattern(cfg, params, x, kv: BlockedKV, attend, ssm_step, live,
     x, pools, state = carry
     moe = _count_moe(kv.moe, jnp.concatenate(routed) if routed else None,
                      cfg, x.shape[-2])
-    return x, kv.with_pools(pools).with_state(state)._replace(moe=moe)
+    kv = kv.with_pools(pools[:n_pools]).with_state(state)._replace(moe=moe)
+    return x, kv._replace(bsa=pools[n_pools]) if counts else kv
 
 
 def _tokens_in(tokens, sampled, take_from):
@@ -1152,6 +1215,11 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
                                               mine, row, to, mates)
                 return ragged_attend(q, *idx_rows, mine, row, ctx, cfg,
                                      spec.name)[..., :keep], mine
+            if cfg.sparse_block_topk:    # ... over the selected blocks
+                from .bsa import ragged_attend
+
+                out, mine = ragged_attend(q, mine, row, ctx, cfg, spec.name)
+                return out[..., :keep], mine
             out = spec.fn(q, ctx)[..., :keep]
         return out, (*before, *mine, *after)
 
@@ -1243,6 +1311,23 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
         out = out.at[jnp.where(one, at, t)].set(out_dec, mode="drop")
         return out, (pool, state[1])
 
+    def la_scan(q, k, v, state, l):
+        """Lightning layer ``l``'s recurrence over the flat batch, as
+        :func:`ssm_step` splits it: the pieces, then the one-token rows."""
+        from ...ops.ssm import lightning_pieces, lightning_step
+
+        out, pool = lightning_pieces(
+            q, k, v, state[0], l,
+            (ssm.row0, ssm.length, ssm.slot, ssm.fresh, ssm.count),
+            cfg.lightning_chunk_size, x.dtype)
+        one, at = ssm.dec_len > 0, ssm.dec_row
+        out_dec, pool = lightning_step(
+            q[at], k[at], v[at], pool, l,
+            jnp.where(one, ssm.seq_slot, pool.shape[1] - 1),
+            ssm.dec_len == 1, _ssm_step_fn())
+        out = out.at[jnp.where(one, at, t)].set(out_dec, mode="drop")
+        return out, (pool,)
+
     if cfg.total_ut_steps > 1:
         # a slot's row is a sequence's where the batch has a chunk of it
         h_last, kv = _scan_passes(
@@ -1251,7 +1336,7 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
         return _unembed(params, h_last, cfg).astype(jnp.float32), kv
     if cfg.layer_pattern is not None:
         x, kv = _walk_pattern(cfg, params, x, kv, attend, ssm_step, ~pad,
-                              (kda_conv, kda_scan))
+                              (kda_conv, kda_scan), (token_pos, la_scan))
     else:
         x, kv = _scan_layers(layer, x, kv, params, cfg)
 
@@ -1349,6 +1434,12 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
                 return decode_attend(q, *idx_rows, mine, row, tables,
                                      seq_lens, bs, cfg,
                                      spec.name)[..., :keep], mine
+            if cfg.sparse_block_topk:    # ... over the selected blocks
+                from .bsa import decode_attend
+
+                out, mine = decode_attend(q, mine, row, tables, seq_lens, bs,
+                                          cfg, spec.name)
+                return out[..., :keep], mine
             out = spec.fn(q, DecodeAttnContext(
                 k_cache=k_cache, v_cache=v_cache, layer=row,
                 block_tables=tables, seq_lens=seq_lens, block_size=bs,
@@ -1395,12 +1486,21 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
             positions == 0, cfg, _kda_step_fn())
         return out, (pool, state[1])
 
+    def la_scan(q, k, v, state, l):
+        from ...ops.ssm import lightning_step
+
+        out, pool = lightning_step(
+            q, k, v, state[0], l,
+            jnp.where(active, state_slot, state[0].shape[1] - 1),
+            positions == 0, _ssm_step_fn())
+        return out, (pool,)
+
     if cfg.total_ut_steps > 1:
         x, kv = _scan_passes(layer, x, kv, params, cfg, lambda x: x, active)
         return _unembed(params, x, cfg).astype(jnp.float32), kv
     if cfg.layer_pattern is not None:
         x, kv = _walk_pattern(cfg, params, x, kv, attend, ssm_step, active,
-                              (kda_conv, kda_scan))
+                              (kda_conv, kda_scan), (positions, la_scan))
     else:
         x, kv = _scan_layers(layer, x, kv, params, cfg)
     x = _final_norm(params, x, cfg)

@@ -160,8 +160,10 @@ class ModelConfig:
     # A hybrid stack (nemotron_h, solar_open2): one character a layer, each
     # layer ONE mixer behind one norm: ``M`` a Mamba-2 mixer, ``K`` a gated
     # delta-rule mixer (below), ``E`` sparse experts (nemotron_h's two-matrix,
-    # ``mlp_type="mlp"``; solar_open2's gated), ``*`` attention. None: the
-    # uniform attention + MLP block. A stack of parameters a kind
+    # ``mlp_type="mlp"``; solar_open2's gated), ``*`` attention, ``L`` a
+    # lightning linear-attention mixer and ``F`` a dense feed-forward part
+    # alone (minicpm_sala; both below). None: the uniform attention + MLP
+    # block. A stack of parameters a kind
     # (``mamba_layers``, ``kda_layers``, ``layers``, ``attn_layers``), a KV
     # pool with a row for the ``*`` layers only and a recurrent state per
     # sequence slot for the ``M`` or the ``K`` layers
@@ -192,6 +194,44 @@ class ModelConfig:
     # over all q_dim values, from the row the queries read (solar_open2's
     # use_gqa_gate; the ``*`` layers of a layer_pattern)
     attn_out_gate: bool = False
+    # Lightning linear attention (arXiv:2401.04658; minicpm_sala's
+    # "lightning-attn"), the ``L`` layers of a layer_pattern: lightning_heads
+    # heads (0: none) of lightning_head_dim for query, key and value alike,
+    # S_t = lambda_h S_{t-1} + k_t v_t^T with the FIXED decay lambda_h =
+    # exp(-2^(-8 (h + 1) / heads)) a head, o_t = q_t^T S_t / sqrt(dim): no
+    # softmax, no normaliser, no convolution; q and k normed a head and
+    # rotated over the whole head at rope_theta, the heads' outputs normed
+    # over all of them together and gated. Every ``L`` layer keeps, per
+    # sequence, a float32 state [heads, dim, dim] (ops/ssm.py: Mamba-2's
+    # recurrence with a group a head). lightning_chunk_size: rows of a
+    # piece of the chunked form, as ssm_chunk_size is Mamba-2's.
+    lightning_heads: int = 0
+    lightning_head_dim: int = 0
+    lightning_chunk_size: int = 128
+    # Block-sparse attention chosen from pooled keys (InfLLM-v2; MiniCPM4's
+    # sparse_config), on the ``*`` layers of a layer_pattern:
+    # sparse_block_topk blocks of sparse_block_size tokens a query and KV
+    # GROUP reads (0: every block), of which the first sparse_block_init,
+    # the sparse_block_window ending with the query's own and the rest by
+    # score: the softmax of the group's queries over the MEAN of each
+    # window of sparse_block_kernel keys (every sparse_block_stride), summed
+    # over the group's heads and max-pooled to blocks. A row whose context
+    # is under sparse_block_dense_len reads every block. The serving pool's
+    # block_size must equal sparse_block_size: a selected block is a page.
+    # (ops/sparse_block.py, inference/v2/bsa.py.)
+    sparse_block_topk: int = 0
+    sparse_block_size: int = 64
+    sparse_block_kernel: int = 32
+    sparse_block_stride: int = 16
+    sparse_block_init: int = 1
+    sparse_block_window: int = 32
+    sparse_block_dense_len: int = 8192
+    # muP's scalings of the stream (minicpm's scale_emb and scale_depth /
+    # sqrt(published layers)): the embedding is multiplied by embed_scale,
+    # every sublayer of a layer_pattern is x + residual_scale f(norm(x));
+    # 1.0: neither (logit_scale is the third)
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
     # Mamba-2 sizes, under the names nemotron_h publishes: d_inner is
     # mamba_num_heads x mamba_head_dim (not an expansion of hidden_size);
     # B and C come in ssm_n_groups groups of ssm_state_size
@@ -341,10 +381,14 @@ class ModelConfig:
                 "stacked (scan_layers)")
         if self.layer_pattern is not None:
             self._check_pattern()
-        elif self.attn_out_gate or self.kda_num_heads:
+        elif (self.attn_out_gate or self.kda_num_heads
+              or self.lightning_heads or self.sparse_block_topk
+              or self.residual_scale != 1.0):
             raise ValueError(
-                "attn_out_gate and kda_num_heads belong to a layer_pattern "
-                "('*' and 'K' layers): the uniform block has neither")
+                "attn_out_gate, kda_num_heads, lightning_heads, "
+                "sparse_block_topk and residual_scale belong to a "
+                "layer_pattern ('*', 'K' and 'L' layers): the uniform block "
+                "has none of them")
         if self.total_ut_steps < 1:
             raise ValueError(f"total_ut_steps {self.total_ut_steps} < 1")
         if self.total_ut_steps > 1 or self.sandwich_norm:
@@ -362,11 +406,21 @@ class ModelConfig:
 
     def _check_pattern(self):
         pat = self.layer_pattern
-        if set(pat) - set("MKE*") or len(pat) != self.num_layers:
+        if set(pat) - set("MKLEF*") or len(pat) != self.num_layers:
             raise ValueError(
                 f"layer_pattern {pat!r}: {self.num_layers} characters of "
-                f"'M' (Mamba-2), 'K' (gated delta rule), 'E' (experts) and "
+                f"'M' (Mamba-2), 'K' (gated delta rule), 'L' (lightning "
+                f"attention), 'E' (experts), 'F' (dense feed-forward) and "
                 f"'*' (attention) wanted")
+        if "L" in pat and ("M" in pat or "K" in pat or not (
+                self.lightning_heads and self.lightning_head_dim
+                and self.lightning_head_dim % 2 == 0)):
+            raise ValueError(
+                "layer_pattern: 'L' layers need lightning_heads and an even "
+                "lightning_head_dim, and no 'M' or 'K' layer beside them "
+                "(ONE kind of recurrent state a model)")
+        if self.sparse_block_topk:
+            self._check_sparse_block()
         if "K" in pat and ("M" in pat or not (
                 self.kda_num_heads and self.kda_head_dim
                 and self.kda_gate_rank and self.kda_conv_kernel > 1)):
@@ -399,6 +453,30 @@ class ModelConfig:
                 "attn_out_gate: the output gate is written for the plain "
                 "K-and-V attention of a layer_pattern's '*' layers: no "
                 "indexer and no attention bias")
+
+    def _check_sparse_block(self):
+        """The block-sparse attention's sizes, and what it is not written
+        beside."""
+        bs, kern, stride = (self.sparse_block_size, self.sparse_block_kernel,
+                            self.sparse_block_stride)
+        forced = self.sparse_block_init + self.sparse_block_window
+        if (min(bs, kern, stride) < 1 or bs % stride or kern % stride
+                or kern > bs or self.sparse_block_init < 0
+                or self.sparse_block_window < 1
+                or self.sparse_block_topk < forced
+                or self.sparse_block_dense_len < forced * bs):
+            raise ValueError(
+                "sparse_block_*: the stride divides the kernel and the "
+                "block, the kernel is at most a block, the blocks read "
+                "(sparse_block_topk) hold the first sparse_block_init and "
+                "the window's sparse_block_window, and a context of "
+                "sparse_block_dense_len holds those apart")
+        if ("*" not in self.layer_pattern or self.index_topk
+                or self.pos_embed == "alibi" or self.qkv_bias):
+            raise ValueError(
+                "sparse_block_topk: block-sparse attention is written for "
+                "the plain K-and-V attention of a layer_pattern's '*' "
+                "layers: no indexer, alibi or attention bias")
 
     def _check_period(self):
         """``attn_period`` as tuples, and what a period of attention kinds
@@ -488,7 +566,8 @@ class ModelConfig:
                 "of sequential blocks; not written for: " + ", ".join(wrong))
 
     def pattern_count(self, kind: str) -> int:
-        """Layers of ``kind`` ('M', 'K', 'E', '*') in ``layer_pattern``."""
+        """Layers of ``kind`` ('M', 'K', 'L', 'E', 'F', '*') in
+        ``layer_pattern``."""
         return (self.layer_pattern or "").count(kind)
 
     @property
@@ -508,16 +587,18 @@ class ModelConfig:
     @property
     def state_layers(self) -> int:
         """Layers that keep a recurrent state per sequence (0: none): a
-        ``layer_pattern``'s Mamba-2 or delta-rule layers, or every layer of
-        a power-retention stack."""
+        ``layer_pattern``'s Mamba-2, delta-rule or lightning layers, or
+        every layer of a power-retention stack."""
         return self.num_layers if self.retention_degree \
-            else self.pattern_count("M") + self.pattern_count("K")
+            else sum(map(self.pattern_count, "MKL"))
 
     @property
     def state_chunk_size(self) -> int:
         """Rows of a piece of the state layers' chunked form."""
         if self.pattern_count("K"):
             return self.kda_chunk_size
+        if self.pattern_count("L"):
+            return self.lightning_chunk_size
         return self.retention_chunk_size if self.retention_degree \
             else self.ssm_chunk_size
 
@@ -526,6 +607,11 @@ class ModelConfig:
         """Width of each of a delta-rule layer's q, k and v: heads x
         head_dim (the convolution runs over three of them)."""
         return self.kda_num_heads * self.kda_head_dim
+
+    @property
+    def lightning_dim(self) -> int:
+        """Width of each of a lightning layer's q, k, v and gate."""
+        return self.lightning_heads * self.lightning_head_dim
 
     @property
     def ssm_d_inner(self) -> int:
@@ -650,8 +736,14 @@ class ModelConfig:
                    + dk + self.kda_head_dim + d)
             if self.attn_out_gate:
                 attn += d * self.q_dim
+            if self.qk_head_norm:
+                attn += 2 * self.head_dim
+            dl = self.lightning_dim
+            light = 5 * d * dl + 2 * self.lightning_head_dim + dl + d
             return (mamba * self.pattern_count("M")
                     + kda * self.pattern_count("K")
+                    + light * self.pattern_count("L")
+                    + (dense + d) * self.pattern_count("F")
                     + (moe + d) * self.pattern_count("E")
                     + (attn + d) * self.pattern_count("*")
                     + v * d * (1 if self.tie_embeddings else 2) + d)
@@ -932,6 +1024,29 @@ PRESETS = {
         # carry beside the shared one, so that parity can see them
         # (benchmark/configs/solar-open2-ep8-d4.json, assumed)
         routed_write_share=0.075),
+    # openbmb/MiniCPM-SALA (model_type minicpm_sala, 9B): 32 published
+    # layers, each a mixer AND a dense SwiGLU MLP of 16,384, so 64
+    # characters here: 8 layers of InfLLM-v2 block-sparse attention
+    # (mixer_types "minicpm4" at 0, 9, 16, 17, 22, 29, 30, 31: 32 heads over
+    # 2 KV heads of 128, normed a head, NO positional term, an output gate:
+    # ``*F``) and 24 of lightning linear attention (32 heads of 128, normed
+    # a head and rotated, output norm and gate: ``LF``); muP: the embedding
+    # x 12, every sublayer x 1.4 / sqrt(32), the logits / (4096 / 256).
+    # Serving only (inference/v2, ops/sparse_block.py, ops/ssm.py).
+    "minicpm-sala": _p(
+        vocab_size=73448, hidden_size=4096, intermediate_size=16384,
+        num_layers=64, num_heads=32, num_kv_heads=2, head_dim=128,
+        max_seq_len=524288, rms_norm_eps=1e-6, rope_theta=10000.0,
+        pos_embed="none", tie_embeddings=False, qk_head_norm=True,
+        layer_pattern="".join(
+            ("*" if i in (0, 9, 16, 17, 22, 29, 30, 31) else "L") + "F"
+            for i in range(32)),
+        attn_out_gate=True, lightning_heads=32, lightning_head_dim=128,
+        sparse_block_topk=96, sparse_block_size=64, sparse_block_kernel=32,
+        sparse_block_stride=16, sparse_block_init=1, sparse_block_window=32,
+        sparse_block_dense_len=8192,
+        embed_scale=12.0, residual_scale=1.4 / 32 ** 0.5,
+        logit_scale=256 / 4096),
 }
 
 

@@ -94,7 +94,11 @@ class CausalLM:
                 # about what the embedding does. At the embedding's size
                 # EACH, 26 layers' bf16 rounding compounds to 0.065
                 # logit-std at the median row (PERF.md section 6, PR 37)
-                return std / np.sqrt(fan_in * cfg.num_layers)
+                # (under muP's scalings the embedding is embed_scale times
+                # as large and a sublayer's write residual_scale times as
+                # small: the draw keeps the two in that proportion)
+                return std * cfg.embed_scale / cfg.residual_scale \
+                    / np.sqrt(fan_in * cfg.num_layers)
             if cfg.retention_degree:
                 # the same rule for a power-retention stack, which only
                 # serves: at GPT-2's depth scaling each sublayer writes ~25
@@ -283,6 +287,34 @@ class CausalLM:
                 "o_proj": dense((dk, d), next(ks), scale=down_scale(dk)),
             }
 
+        def lightning_params(key) -> Params:
+            """One lightning linear-attention mixer behind its norm
+            (``ops/ssm.py``'s convolution-free entries): ``wq`` / ``wk`` /
+            ``wv`` to heads of ``lightning_head_dim``, the norms a head of q
+            and k, the output gate ``wz``, the norm over all the heads'
+            outputs ``o_norm`` and ``wo``. The decay is no leaf: a constant
+            a head (``ops.ssm.lightning_decay``)."""
+            ks = iter(jax.random.split(key, 8))
+            d, dl, hd = (cfg.hidden_size, cfg.lightning_dim,
+                         cfg.lightning_head_dim)
+            return {
+                "norm": norm_params(),
+                "wq": dense((d, dl), next(ks)),
+                "wk": dense((d, dl), next(ks)),
+                "wv": dense((d, dl), next(ks)),
+                "wz": dense((d, dl), next(ks)),
+                "q_norm": {"scale": jnp.ones((hd,), jnp.float32)},
+                "k_norm": {"scale": jnp.ones((hd,), jnp.float32)},
+                "o_norm": {"scale": jnp.ones((dl,), jnp.float32)},
+                "wo": dense((dl, d), next(ks), scale=down_scale(dl)),
+            }
+
+        def ffn_params(key) -> Params:
+            """A dense feed-forward part alone behind its norm (``F``)."""
+            return {"mlp_norm": norm_params(),
+                    "mlp": glu_params(iter(jax.random.split(key, 3)),
+                                      cfg.intermediate_size)}
+
         def hc_params(key) -> Params:
             """One sublayer's hyper-connection maps: the norm over all
             ``n * d`` stream values, ``phi`` [n*d, n + n + n*n] (columns
@@ -380,6 +412,11 @@ class CausalLM:
                 stacks["mamba_layers"] = jax.vmap(mamba_params)(of("M"))
             if cfg.pattern_count("K"):
                 stacks["kda_layers"] = jax.vmap(kda_params)(of("K"))
+            if cfg.pattern_count("L"):
+                stacks["lightning_layers"] = jax.vmap(lightning_params)(
+                    of("L"))
+            if cfg.pattern_count("F"):
+                stacks["ffn_layers"] = jax.vmap(ffn_params)(of("F"))
             if cfg.pattern_count("*"):
                 stacks["attn_layers"] = jax.vmap(
                     lambda k: layer_params(k, kind="*"))(of("*"))
@@ -533,10 +570,11 @@ class CausalLM:
                 "are not written")
         if cfg.layer_pattern is not None:
             raise NotImplementedError(
-                "a layer_pattern model (Mamba-2, gated delta-rule, expert "
-                "and attention layers in one stack) runs on the serving "
-                "path only (inference/v2/model.py): the chunked scan's "
-                "backward is not written")
+                "a layer_pattern model (Mamba-2, gated delta-rule, "
+                "lightning, expert, feed-forward and attention layers in "
+                "one stack; block-sparse attention, sparse_block_topk) "
+                "runs on the serving path only (inference/v2/model.py): "
+                "the chunked scan's backward is not written")
         if cfg.retention_degree:
             raise NotImplementedError(
                 "a power-retention model (retention_degree: a gated state "
@@ -864,7 +902,8 @@ class CausalLM:
         s = "/".join(str(n) for n in names)
         stacked = self.config.scan_layers and any(
             n in names for n in ("layers", "dense_layers", "mamba_layers",
-                                 "attn_layers"))
+                                 "attn_layers", "lightning_layers",
+                                 "ffn_layers"))
         if stacked:
             # under pipeline parallelism the stacked layer dim shards over
             # ``pipe`` (each stage owns its contiguous layer block — the

@@ -91,7 +91,21 @@ SUB_SCOPES = ("moe_route", "moe_experts", "moe_combine", "moe_shared",
               # a stack of two attention kinds (ModelConfig.attn_period):
               # a windowed layer's and a full layer's q, k, v, rotary, pool
               # write and attention, so a profile tells the kinds apart
-              "attn_swa", "attn_full")
+              "attn_swa", "attn_full",
+              # block-sparse attention chosen from pooled keys
+              # (ModelConfig.sparse_block_topk; inference/v2/bsa.py,
+              # ops/sparse_block.py): the pooled keys' write; the scores
+              # and their pooling to blocks; the selection; whatever
+              # gathers, masks and attends, INSIDE which bsa_rows is the
+              # one-token rows' part
+              "bsa_pool", "bsa_score", "bsa_select", "bsa_attend",
+              "bsa_rows",
+              # a lightning linear-attention mixer (inference/v2/model.py,
+              # ops/ssm.py): the projections in and out; the head norms,
+              # rotation, output norm and gate; the recurrence, INSIDE
+              # which la_step is the one-token rows' state step and
+              # la_chunk the chunked form's pieces
+              "la_proj", "la_gate", "la_scan", "la_step", "la_chunk")
 
 #: named_scope label prefix — ``mfu.attn`` etc. Kept short and distinctive
 #: so the metadata regex can't false-positive on user scopes.

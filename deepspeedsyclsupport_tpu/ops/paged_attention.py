@@ -251,7 +251,10 @@ def _attend_tile(a, block_tables_ref, pos0_ref, qlen_ref, layer_ref,
     ``masked`` (a K-and-V pool under a sparse-attention indexer): after V
     the SELECTION ``[A, BQ, keys]`` int8 in HBM and, after V's, its scratch;
     a step's ``[BQ, step keys]`` of it (whole 128-key lane tiles) rides the
-    step's DMAs and a pair counts only where it is nonzero.
+    step's DMAs and a pair counts only where it is nonzero. A selection
+    with a kv-head axis, ``[A, KVH, BQ, keys]`` (blocks chosen a KV GROUP:
+    ``inference/v2/bsa.py``), rides the same way a head, and a kv head's
+    rows count under their own head's.
 
     The hand-over: the tile's last step starts the first step's copies of
     its SUCCESSOR in the grid (the next head tile of the atom, else the
@@ -350,9 +353,11 @@ def _attend_tile(a, block_tables_ref, pos0_ref, qlen_ref, layer_ref,
                     hbm.at[layer, pl.ds(blk * block_size, block_size)],
                     dst, sem.at[slot, n]))
         if masked:       # the step's columns of the atom's selection
+            cols = pl.ds(pl.multiple_of(step * step_keys, step_keys),
+                         step_keys)
             cps.append(pltpu.make_async_copy(
-                sel_hbm.at[tile, :, pl.ds(pl.multiple_of(
-                    step * step_keys, step_keys), step_keys)],
+                sel_hbm.at[tile, :, cols] if sel_vmem.ndim == 3
+                else sel_hbm.at[tile, :, :, cols],
                 sel_vmem.at[slot], sem.at[slot, len(pools)]))
         return cps
 
@@ -471,10 +476,12 @@ def _attend_tile(a, block_tables_ref, pos0_ref, qlen_ref, layer_ref,
             valid = jnp.logical_and(valid, q_pos - pos < window)
         if masked:
             # [BQ, keys] -> a row's G lanes alike -> the scores' [BQ·G, keys]
+            # (a selection a kv head: [KVH, BQ, keys] -> [KVH, BQ·G, keys])
             chosen = sel_vmem[cur].astype(jnp.float32)
+            lead = chosen.shape[:-2]
             chosen = jnp.broadcast_to(
-                chosen[:, None, :], (bq, g, step_keys)).reshape(
-                    bq * g, step_keys)
+                chosen[..., None, :], (*lead, bq, g, step_keys)).reshape(
+                    *lead, bq * g, step_keys)
             valid = jnp.logical_and(valid, chosen > 0.0)
         bias = jnp.where(valid, 0.0, 2 * NEG_INF)
         scores = jax.lax.dot_general(           # [KVH, BQ·G, keys]
@@ -644,13 +651,15 @@ def kv_step_keys(bq: int, h: int, kvh: int, d: int, block_size: int,
 
 def _ragged_vmem_need(bq: int, h: int, kvh: int, d: int, block_size: int,
                       itemsize: int, pages: int = 1,
-                      kv_tile: bool = False) -> int:
+                      kv_tile: bool = False, sel_heads: int = 1) -> int:
     """Bytes of VMEM one grid step of :func:`_prefill_kernel` needs, by the
     shape model :func:`_ragged_vmem_limit` explains; a loop step of
     ``pages`` KV blocks holds that many in each scratch slot and scores
     that many times the keys. ``kv_tile``: a K-and-V pool's tile, which
     feeds the MXU the pool's dtype (no float32 K and V) and makes its mask
-    once for all kv heads, but may carry a selection's slots."""
+    once for all kv heads, but may carry a selection's slots; ``sel_heads``:
+    the kv heads that selection has (1: one for all), whose mask is then a
+    head's own."""
     q_tile = bq * h * d
     kv = pages * block_size * kvh * d
     scores = bq * h * pages * block_size
@@ -662,8 +671,10 @@ def _ragged_vmem_need(bq: int, h: int, kvh: int, d: int, block_size: int,
                 # (fewer than a vreg's 32 bytes of them are tiled up to it)
                 + 4 * kv * itemsize * max(1, 32 // (kvh * itemsize))
                 + 4 * kv * itemsize      # k, v head-major, and in passing
-                + 2 * bq * pages * block_size      # the selection's slots
-                + 4 * (scores // kvh) * 4          # pos, q_pos, valid, bias
+                # the selection's slots
+                + 2 * sel_heads * bq * pages * block_size
+                # pos, q_pos, valid, bias (the last two a selection's head)
+                + (2 + 2 * sel_heads) * (scores // kvh) * 4
                 + 4 * scores * 4)                  # scores, p twice, exp
     return (need
             + 4 * kv * itemsize          # k/v scratch, two slots each
@@ -673,7 +684,7 @@ def _ragged_vmem_need(bq: int, h: int, kvh: int, d: int, block_size: int,
 
 def _ragged_vmem_limit(bq: int, h: int, kvh: int, d: int, block_size: int,
                        itemsize: int, pages: int = 1,
-                       kv_tile: bool = False) -> int:
+                       kv_tile: bool = False, sel_heads: int = 1) -> int:
     """Scoped-VMEM limit stated to the compiler for one grid step of
     :func:`_prefill_kernel`. The q/out tiles are double-buffered by the
     pipeline and the body keeps fp32 copies of q, the accumulator and its
@@ -694,7 +705,7 @@ def _ragged_vmem_limit(bq: int, h: int, kvh: int, d: int, block_size: int,
     heads at 512 keys a step: 64 MiB modelled, compiled under the cap it
     states)."""
     need = _ragged_vmem_need(bq, h, kvh, d, block_size, itemsize, pages,
-                             kv_tile)
+                             kv_tile, sel_heads)
     if need > _VMEM_CAP:
         raise ValueError(
             f"ragged prefill atom of {bq} rows x {h} heads x d {d} needs "
@@ -723,7 +734,9 @@ def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
     K-and-V pool only): a sparse-attention indexer's selection, nonzero
     where the atom's row attends to the position; the kernel then walks the
     steps its tile's shape gives (whole 128-key lane tiles of the selection:
-    :func:`_selection_pages`) and a profile calls it ``dsa_prefill``.
+    :func:`_selection_pages`) and a profile calls it ``dsa_prefill``. ``sel``
+    [A, KVH, BQ, keys]: a selection a KV head, each head's rows under their
+    own.
     Returns [A, BQ, H, D] ([.., n])."""
     a, bq, h, d = q_atoms.shape
     latent = v_cache is None
@@ -749,7 +762,8 @@ def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
         step = pages * block_size
         keys = -(-atom_tables.shape[1] * block_size // step) * step
         sel = jnp.pad(sel[..., :keys].astype(jnp.int8),
-                      ((0, 0), (0, 0), (0, max(0, keys - sel.shape[-1]))))
+                      ((0, 0),) * (sel.ndim - 1)
+                      + ((0, max(0, keys - sel.shape[-1])),))
         name = "dsa_prefill" if name == "ragged_prefill" else name
     if alibi is not None:
         # per-lane slope layout matches the kernel's [KVH, BQ·G] score rows:
@@ -769,8 +783,9 @@ def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
         use_alibi=alibi is not None,
         window=None if window is None else int(window),
         v_dim=v_dim if latent else None,
-        vmem_limit=_ragged_vmem_limit(bq, ht, kvh, d, block_size, itemsize,
-                                      pages, not latent),
+        vmem_limit=_ragged_vmem_limit(
+            bq, ht, kvh, d, block_size, itemsize, pages, not latent,
+            kvh if sel is not None and sel.ndim == 4 else 1),
         interpret=interpret, name=name)
 
 
@@ -820,7 +835,7 @@ def _tiled_call(atom_tables, atom_pos0, atom_qlen, layer, q_atoms, pools, ab,
         scratch_shapes=[
             *(pltpu.VMEM((2, pages * block_size, *row), pool.dtype)
               for pool in pools),
-            *(pltpu.VMEM((2, bq, pages * block_size), m.dtype)
+            *(pltpu.VMEM((2, *m.shape[1:-1], pages * block_size), m.dtype)
               for m in masks),
             pltpu.SemaphoreType.DMA((2, len(pools) + len(masks))),
             # the slot a handed-over first step lies in (_attend_tile)
@@ -885,7 +900,9 @@ def ragged_prefill_attention_reference(q_atoms, k_cache, v_cache, atom_tables,
     if window is not None:
         mask = jnp.logical_and(mask, q_pos - j[None, None, None, :] < window)
     if sel is not None:
-        mask = jnp.logical_and(mask, (sel[:, None, :, :max_ctx] != 0))
+        chosen = sel[..., :max_ctx] != 0
+        mask = jnp.logical_and(mask, chosen[:, None] if sel.ndim == 3 else
+                               jnp.repeat(chosen, h // kvh, axis=1))
     logits = jnp.where(mask, logits, NEG_INF)
     p = jax.nn.softmax(logits, axis=-1)
     p = jnp.where(mask.any(-1, keepdims=True), p, 0.0)  # dead rows → 0

@@ -283,7 +283,7 @@ def select_chunk(c: int) -> int:
 
 
 def select_topk_pallas(scores, pos0, qlen, *, k: int,
-                       interpret: bool = False):
+                       interpret: bool = False, name: str = "dsa_select"):
     """:func:`select_topk_reference` as one kernel: a grid step takes
     ``SELECT_ROWS`` rows of one tile, their scores whole in VMEM, each row's
     reach (:func:`_reach`) beside them, and walks up to the longest's."""
@@ -308,17 +308,20 @@ def select_topk_pallas(scores, pos0, qlen, *, k: int,
         out_shape=jax.ShapeDtypeStruct((a, r_pad, c_pad), jnp.int8),
         grid_spec=grid_spec,
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=interpret, name="dsa_select",
+        interpret=interpret, name=name,
     )(reach.reshape(a, r_pad // rows, rows).max(-1), scores,
       reach[..., None])
     return out if (r_pad, c_pad) == (r, c) else out[:, :r, :c]
 
 
-def select_topk(scores, pos0, qlen, *, k: int, impl: str = "xla"):
+def select_topk(scores, pos0, qlen, *, k: int, impl: str = "xla",
+                name: str = "dsa_select"):
+    """``name``: what a profile calls the kernel (the block selection of
+    ``ops/sparse_block.py`` passes its own)."""
     if impl == "xla":
         return select_topk_reference(scores, pos0, qlen, k=k)
     return select_topk_pallas(scores, pos0, qlen, k=k,
-                              interpret=impl == "pallas_interpret")
+                              interpret=impl == "pallas_interpret", name=name)
 
 
 # ================================================================== positions

@@ -30,6 +30,15 @@ slot's ``[k - 1, channels]`` read and written by XLA a piece, on other
 slots, before the kernel in a mixed forward). Mamba-2's ``xBC`` and the
 delta rule's q | k | v (``ops/kda.py``) go through the same three functions.
 
+Lightning linear attention (arXiv:2401.04658; minicpm_sala's ``L`` layers)
+is the same recurrence with a group a head, a CONSTANT decay a head
+(:func:`lightning_decay`), ``dt`` 1, ``D`` 0 and no convolution: ``S_t =
+lambda_h S_{t-1} + k_t v_t^T``, ``o_t = S_t^T q_t`` with B = k, C = q and x =
+v. Its two entries, :func:`lightning_step` and :func:`lightning_pieces`, are
+the state step and the piece below as they are, against a pool ``[layers,
+slots + 1, heads, dim, dim]`` (``BlockedKV.la_s``), under the scopes
+``la_step`` and ``la_chunk``; they touch no tail.
+
 Two entries, as the attention kernels have two tiles:
 
 * :func:`decode_step` — ONE token for each of ``[rows]`` slots: shift the
@@ -48,6 +57,7 @@ Two entries, as the attention kernels have two tiles:
   there. Its recurrence carries the scope ``ssm_chunk`` inside ``ssm_scan``.
 """
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -489,6 +499,74 @@ def chunked_scan(xbc, dt, p, ssm, conv, layer, pieces, cfg):
         0, count, piece,
         (ssm, conv, jnp.zeros((t + q, cfg.ssm_d_inner), jnp.float32)))
     return y_all[:t], ssm, conv
+
+
+# ----------------------------------------------- lightning linear attention
+class _HeadGroups(NamedTuple):
+    """What :func:`_piece_scan` and :func:`_per_head` read of a config, for
+    a mixer whose every head is a group of its own."""
+    mamba_num_heads: int
+    ssm_n_groups: int
+    mamba_head_dim: int
+
+
+def lightning_decay(heads: int):
+    """[heads] float32: the LOG of each head's decay a token, ``-2^(-8 (h +
+    1) / heads)`` (Lightning Attention's slopes: half-lives from under a
+    token to some hundreds)."""
+    return -jnp.exp2(-8.0 * (jnp.arange(heads, dtype=jnp.float32) + 1.0)
+                     / heads)
+
+
+def lightning_step(q, k, v, pool, layer, slots, fresh, step=None):
+    """One token for each row: ``q`` (scaled), ``k``, ``v`` [rows, h, d];
+    ``pool`` [layers, slots + 1, h, d, d] float32, the key's channels on
+    the sublanes; ``slots`` / ``fresh`` / ``step`` as :func:`decode_step`'s.
+    -> ``(o [rows, h, d] float32, pool)``."""
+    step = step or STATE_STEPS[default_impl()]
+    f32 = jnp.float32
+    rows, h, d = q.shape
+    with scope("la_step"):
+        decay = jnp.broadcast_to(jnp.exp(lightning_decay(h))[None, :, None],
+                                 (rows, h, d))
+        return step(pool, layer, slots, jnp.logical_not(fresh).astype(f32),
+                    decay, v.astype(f32), k.astype(f32), q.astype(f32))
+
+
+def lightning_pieces(q, k, v, pool, layer, pieces, chunk: int, dtype):
+    """The chunks of two tokens or more of a flat batch: ``q`` (scaled),
+    ``k``, ``v`` [T, h, d]; ``pieces`` as :func:`chunked_scan` takes them,
+    of at most ``chunk`` rows; the products' operands in ``dtype``. -> ``(o
+    [T, h, d] float32, zero where no piece lies; pool)``."""
+    row0, length, slots, fresh, count = pieces
+    t, h, d = q.shape
+    dims = _HeadGroups(h, h, d)
+    log_decay = lightning_decay(h)
+    q, k, v = (jnp.pad(a, ((0, chunk), (0, 0), (0, 0))) for a in (q, k, v))
+    window = lambda a, r0: jax.lax.dynamic_slice_in_dim(  # noqa: E731
+        a, r0, chunk)
+
+    def piece(i, carry):
+        pool, y_all = carry
+        r0, slot = row0[i], slots[i]
+        valid = (jnp.arange(chunk) < length[i])[:, None]
+        # la_chunk: the pieces' own time, apart from the state step of the
+        # one-token rows beside them (lightning_step)
+        with scope("la_chunk"):
+            dtv = jnp.broadcast_to(valid.astype(jnp.float32), (chunk, h))
+            state = jnp.where(fresh[i], 0.0, pool[layer, slot])
+            y, state = _piece_scan(
+                jnp.where(valid[..., None], window(v, r0), 0), window(k, r0),
+                window(q, r0), dtv, dtv * log_decay, state, dims, dtype)
+            pool = pool.at[layer, slot].set(state.astype(pool.dtype))
+            y_all = jax.lax.dynamic_update_slice_in_dim(
+                y_all, jnp.where(valid[..., None], y, window(y_all, r0)),
+                r0, 0)
+        return pool, y_all
+
+    pool, y_all = jax.lax.fori_loop(
+        0, count, piece, (pool, jnp.zeros((t + chunk, h, d), jnp.float32)))
+    return y_all[:t], pool
 
 
 def gated_norm(y, z, scale, cfg):

@@ -992,3 +992,79 @@ def test_the_convolutions_tail_compiles_at_the_cells_widths(one_chip, cell,
         # the token's float32 copy and the row at each slot: no pool
         assert mem.temp_size_in_bytes < held // 2, mem.temp_size_in_bytes
 
+
+
+# ------------------- blocks chosen from pooled keys beside a lightning state
+@pytest.mark.parametrize("program", ["decode_forward", "ragged_forward"])
+def test_the_sala_forwards_compile_and_copy_no_pool(one_chip, program,
+                                                    monkeypatch):
+    """Both serving forwards of ``minicpm-sala`` at the cell's widths and
+    shapes (one period of the twelve layers; 8 sequences, 768 rows, contexts
+    to 99,072, the whole pool of 12,544 pages a layer): the selection, the
+    atoms under its mask and the one-token rows over their own page tables
+    are custom calls under their own names (``bsa_select``, ``bsa_prefill``,
+    ``bsa_rows``; ``decode_forward``, all one-token rows, holds no atom) and
+    the lightning state step Mamba-2's kernel, the scopes reach the compiled
+    text, K, V, the pooled keys and the state are aliased to the result and
+    none of them is copied, and no sequence's ``[heads, rows, windows]``
+    probabilities stand whole: a tile at a time."""
+    from benchmark import scopes
+    from deepspeedsyclsupport_tpu.inference.v2 import model as M
+    from deepspeedsyclsupport_tpu.inference.v2.kv_cache import BlockedKV
+    from deepspeedsyclsupport_tpu.inference.v2.ragged import SsmBatch
+    from deepspeedsyclsupport_tpu.models import build_model
+
+    # no chip is attached: the registry would hand the state step's XLA form
+    monkeypatch.setattr(M, "_state_step_fn", lambda kind: M.select_impl(
+        kind, "pallas", {"backend": "tpu"}).fn)
+    model = build_model("minicpm-sala", num_layers=8,
+                        layer_pattern="*FLFLFLF", dtype="bfloat16")
+    bs, blocks, seqs, toks, bps, atom = 64, 12544, 8, 768, 1548, 128
+
+    def on_chip(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: on_chip(x.shape, jnp.bfloat16 if jnp.issubdtype(
+            x.dtype, jnp.floating) else x.dtype),
+        jax.eval_shape(model.init_params))
+    pool = on_chip((1, blocks * bs, 2, 128), jnp.bfloat16)
+    ck = on_chip((1, blocks, 4, 2, 128), jnp.bfloat16)
+    state = on_chip((3, seqs + 1, 32, 128, 128), jnp.float32)
+    kv = BlockedKV(pool, pool, ck=ck, bsa=on_chip((7,)), la_s=state)
+    if program == "decode_forward":
+        fn = M.build_decode_forward_fn(model, bs, "pallas")
+        args = (on_chip((seqs,)), on_chip((seqs,)), on_chip((seqs, bps)),
+                on_chip((seqs,), jnp.bool_), None, None, on_chip((seqs,)))
+        kernels = {"bsa_select", "bsa_rows", "ssm_state_step"}
+    else:
+        fn = M.build_ragged_forward_fn(model, bs, "kernel")
+        tiles = seqs + toks // atom + 1
+        batch = SsmBatch(*(on_chip((seqs,)),) * 3, *(on_chip((tiles,)),) * 3,
+                         on_chip((tiles,), jnp.bool_), on_chip(()))
+        args = (on_chip((toks,)), on_chip((toks,)), on_chip((toks,)),
+                on_chip((seqs, bps)), on_chip((seqs,)),
+                on_chip((tiles, atom)), on_chip((tiles,)), on_chip((tiles,)),
+                on_chip((tiles, bps)), on_chip((toks,)), on_chip((seqs,)),
+                on_chip((seqs,)), None, None, batch)
+        kernels = {"bsa_select", "bsa_prefill", "bsa_rows", "ssm_state_step"}
+    compiled = fn.lower(params, kv, *args).compile()
+    text = compiled.as_text()
+    calls = {ln.split(" = ")[0].strip().lstrip("%").split(".")[0]
+             for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln}
+    assert calls == kernels
+    labels = ("bsa_pool", "bsa_score", "bsa_select", "bsa_attend", "bsa_rows",
+              "la_proj", "la_gate", "la_step", "la_chunk", "attn_gate")
+    under = set(scopes.instructions_under(text, labels).values())
+    assert under >= set(labels) - (
+        {"bsa_attend", "la_chunk"} if program == "decode_forward" else set())
+    m = compiled.memory_analysis()
+    held = (2 * pool.size + ck.size) * 2 + state.size * 4
+    assert m.alias_size_in_bytes >= held
+    # a tile's probabilities [32, 128, 6192] float32 are 0.1 GB; all of a
+    # chunk's at once would be 0.6
+    assert m.temp_size_in_bytes < 1.75 * 2**30, m.temp_size_in_bytes
+    assert not [ln for ln in text.splitlines() if " copy(" in ln and (
+        f"bf16[1,{blocks * bs}," in ln or f"bf16[1,{blocks},4," in ln
+        or f"f32[3,{seqs + 1},32," in ln)]

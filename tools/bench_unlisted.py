@@ -87,8 +87,11 @@ def unlisted(extra, labels):
         if section != "per_layer":
             return out
         have = {m["name"] for m in out}
-        return out + [m for m in self.doc["per_layer"] + self.doc["end_to_end"]
-                      if m["name"] in extra - have] \
+        listed = [m for m in self.doc["per_layer"] + self.doc["end_to_end"]
+                  if m["name"] in extra - have]
+        # a reader with a file and no entry (the list is full) reads too
+        bare = sorted(extra - have - {m["name"] for m in listed})
+        return out + listed + [{"name": n, "unit": "x"} for n in bare] \
             + [{"name": "_breakdown", "unit": "x"}]
 
     def read_unlisted(self, name):

@@ -53,8 +53,23 @@ Sections, cheapest first:
             each alone:
             combine [--parent DIR] [--cell C ...] [--rows N ...]
 
+  bsa     — block-sparse attention chosen from pooled keys and lightning
+            attention ALONE at ``sala-docs-sat``'s shape (32 heads over 2 KV
+            heads of 128, pages of 64, 96 pages a row and KV head; 32
+            lightning heads of a [128, 128] float32 state), at each of
+            ``--ctx`` tokens of context (32 k, 64 k, 96 k): the pooled keys'
+            write, the block scores and the selection of one 128-row atom and
+            of 8 one-token rows, the atom under the selection's mask through
+            the ragged kernel (once a KV head), the one-token rows over their
+            own page tables beside a dense row over its whole context, ms a
+            call; the lightning state step (8 rows: ms a layer and the share
+            of the HBM peak) and six 128-row pieces; ``--parity`` holds the
+            selection's kernel and both attention routes against their
+            ``jax.numpy`` forms on the chip first:
+            bsa [--ctx N ...] [--parity]
+
 Usage:  python tools/tpu_tune.py
-            [calib|flash|paged|retention|dsa|kda|conv|combine|all]
+            [calib|flash|paged|retention|dsa|kda|conv|combine|bsa|all]
 """
 import functools
 import json
@@ -1477,6 +1492,186 @@ def combine(argv=()):
                  inv_differ=int(sum((i != inv[0]).sum() for i in inv[1:])))
 
 
+# ---------------------------------------------------------------- bsa
+BSA_CELL = dict(heads=32, kv_heads=2, dim=128, page=64, rows=8, atom=128,
+                sparse_layers=3, la_heads=32, la_layers=9, la_chunk=128)
+BSA_CTX = (32768, 65536, 98304)
+
+
+def _bsa_operands(ctx, seed=0):
+    """One sequence slot of ``ctx`` cached tokens a row (8 slots, pages dealt
+    in no order), its keys, values and pooled keys in pools of one layer,
+    and queries for an atom of 128 rows that ends at ``ctx`` and for 8
+    one-token rows."""
+    from deepspeedsyclsupport_tpu.ops import sparse_block as sb
+
+    c = BSA_CELL
+    sizes = sb.Sizes(64, 32, 16, 1, 32, 96, 8192)
+    bps, s = ctx // c["page"], c["rows"]
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    blocks = s * bps
+    pool = lambda k: jax.random.normal(                     # noqa: E731
+        k, (1, blocks * c["page"], c["kv_heads"], c["dim"]), jnp.bfloat16)
+    k_pool, v_pool = pool(keys[0]), pool(keys[1])
+    tables = jax.random.permutation(keys[2], blocks).reshape(s, bps).astype(
+        jnp.int32)
+    ck = jnp.zeros((1, blocks, 4, c["kv_heads"], c["dim"]), jnp.bfloat16)
+    q_atom = jax.random.normal(keys[3], (1, c["atom"], c["heads"], c["dim"]),
+                               jnp.bfloat16)
+    q_rows = jax.random.normal(keys[4], (s, c["heads"], c["dim"]),
+                               jnp.bfloat16)
+    return sizes, k_pool, v_pool, ck, tables, q_atom, q_rows
+
+
+def bsa(argv=()):
+    """See the module's docstring."""
+    import argparse
+
+    from deepspeedsyclsupport_tpu.ops import paged_attention as pa
+    from deepspeedsyclsupport_tpu.ops import sparse_block as sb
+    from deepspeedsyclsupport_tpu.ops import ssm
+
+    ap = argparse.ArgumentParser(prog="tpu_tune.py bsa")
+    ap.add_argument("--ctx", type=int, nargs="*", default=list(BSA_CTX))
+    ap.add_argument("--parity", action="store_true")
+    a = ap.parse_args(list(argv))
+    c = BSA_CELL
+    for ctx in a.ctx:
+        sizes, k_pool, v_pool, ck, tables, q_atom, q_rows = _bsa_operands(ctx)
+        s, bq, kvh = c["rows"], c["atom"], c["kv_heads"]
+        # the pooled keys of every sequence, written a 768-row stretch a call
+        def write(ck, at):
+            pos = at + jnp.arange(768)
+            for seq in range(s):
+                ck = sb.pool_write(ck, k_pool, 0, tables,
+                                   jnp.full((768,), seq), pos,
+                                   jnp.ones((768,), bool), sizes)
+            return ck
+        fill = jax.jit(write, donate_argnums=0)
+        for at in range(0, ctx, 768):
+            ck = fill(ck, at)
+        c_seq = jax.jit(lambda ck: sb.seq_pooled_keys(ck, 0, tables))(ck)
+        pos_atom = (ctx - bq + jnp.arange(bq))[None]
+        pos_rows = jnp.full((s,), ctx - 1)
+        one = jnp.ones((1,), jnp.int32)
+
+        def named(name, fn):
+            fn.__name__ = name
+            return jax.jit(fn)
+
+        def atom_select(impl):
+            def f(q):
+                sc = sb.block_scores(q, c_seq, jnp.zeros((1,), jnp.int32),
+                                     pos_atom, one * bq, sizes)
+                return sb.select_blocks(sc, pos_atom, one * bq, sizes, impl)
+            return f
+
+        def rows_select(impl):
+            def f(q):
+                sc = sb.block_scores(q[:, None], c_seq, jnp.arange(s),
+                                     pos_rows[:, None],
+                                     jnp.ones((s,), jnp.int32), sizes)
+                sel = sb.select_blocks(sc[:, 0][None], pos_rows[None],
+                                       one * s, sizes, impl)[0]
+                return sb.page_tables(sel, tables, pos_rows, sizes)
+            return f
+
+        sel_atom = jax.jit(atom_select("pallas"))(q_atom)
+        row_tables, row_lens = jax.jit(rows_select("pallas"))(q_rows)
+        if a.parity:
+            want = jax.jit(atom_select("xla"))(q_atom)
+            t_x, l_x = jax.jit(rows_select("xla"))(q_rows)
+            emit("bsa_parity", ctx=ctx,
+                 atom_selection_differs=int(jnp.sum(sel_atom != want)),
+                 rows_tables_differ=int(jnp.sum(row_tables != t_x)),
+                 rows_lens_differ=int(jnp.sum(row_lens != l_x)),
+                 blocks_a_row=int(sel_atom[0, -1, 0].sum()))
+        tiles = lambda t: jnp.repeat(t, kvh, axis=0)           # noqa: E731
+        mask = jnp.repeat(jnp.swapaxes(sel_atom, 1, 2).reshape(kvh, bq, -1),
+                          sizes.block, axis=-1)
+
+        def atom_attend(impl):
+            return lambda q: pa.ragged_prefill_attention(
+                tiles(q), k_pool, v_pool, tiles(tables[:1]),
+                tiles(one * (ctx - bq)), tiles(one * bq),
+                block_size=sizes.block, layer=0, impl=impl, sel=mask,
+                name="bsa_prefill")
+
+        def rows_attend(impl):
+            return lambda q: pa.paged_decode_attention(
+                tiles(q), k_pool, v_pool, row_tables, row_lens,
+                block_size=sizes.block, impl=impl, layer=0, name="bsa_rows")
+
+        def rows_dense(q):
+            return pa.paged_decode_attention(
+                q, k_pool, v_pool, tables, pos_rows + 1,
+                block_size=sizes.block, impl="pallas", layer=0)
+
+        if a.parity:
+            got = jax.jit(rows_attend("pallas"))(q_rows).astype(jnp.float32)
+            ref = jax.jit(rows_attend("xla"))(q_rows).astype(jnp.float32)
+            emit("bsa_parity_rows", ctx=ctx, max_abs=float(
+                jnp.max(jnp.abs(got - ref))), scale=float(jnp.std(ref)))
+        steps = {
+            "scores_select_atom": named("scores_select_atom",
+                                        atom_select("pallas")),
+            "scores_select_rows": named("scores_select_rows",
+                                        rows_select("pallas")),
+            "attend_atom_mask": named("attend_atom_mask",
+                                      atom_attend("pallas")),
+            "attend_rows_pages": named("attend_rows_pages",
+                                       rows_attend("pallas")),
+            "attend_rows_dense": named("attend_rows_dense", rows_dense)}
+        out = {}
+        for name, f in steps.items():
+            q = q_atom if "atom" in name else q_rows
+            res = _traced_kernels({name: f.lower(q).compile()}, (q,),
+                                  kernel_of=lambda text: "kernel")
+            out[name] = res[name]
+        # what a one-token row reads: its pages' K and V, a KV head's tile
+        # reading both heads' halves of a page (the pool is token-major)
+        read = s * kvh * int(row_lens[0] // sizes.block + 1) * sizes.block \
+            * kvh * c["dim"] * 2 * 2
+        emit("bsa", ctx=ctx, ms=out, rows_page_bytes=read,
+             dense_row_bytes=s * ctx * kvh * c["dim"] * 2 * 2)
+    # lightning: the state step of 8 rows and six pieces of 128, one layer
+    h, d, rows = c["la_heads"], c["dim"], c["rows"]
+    pool = jax.jit(lambda key: 0.1 * jax.random.normal(
+        key, (1, rows + 1, h, d, d)))(jax.random.PRNGKey(3))
+    q, k, v = (jax.random.normal(jax.random.PRNGKey(i), (768, h, d))
+               for i in range(3))
+
+    def step(pool, q, k, v):
+        y, pool = ssm.lightning_step(
+            q[:rows], k[:rows], v[:rows], pool, 0, jnp.arange(rows),
+            jnp.zeros((rows,), bool), ssm.STATE_STEPS["pallas"])
+        return pool, y
+
+    def pieces(pool, q, k, v):
+        n = 768 // c["la_chunk"]
+        y, pool = ssm.lightning_pieces(
+            q, k, v, pool, 0,
+            (jnp.arange(n) * c["la_chunk"], jnp.full((n,), c["la_chunk"]),
+             jnp.zeros((n,), jnp.int32), jnp.arange(n) == 0, jnp.asarray(n)),
+            c["la_chunk"], jnp.bfloat16)
+        return pool, y
+
+    out = {}
+    for name, fn in (("la_step", step), ("la_pieces", pieces)):
+        fn.__name__ = name
+        prog = jax.jit(fn, donate_argnums=0).lower(pool, q, k, v).compile()
+        res = _traced_kernels({name: prog}, (q, k, v),
+                              kernel_of=lambda text: "kernel", carry=pool)
+        out[name] = res[name]
+        pool = jax.jit(lambda key: 0.1 * jax.random.normal(
+            key, (1, rows + 1, h, d, d)))(jax.random.PRNGKey(3))
+    moved = 2 * rows * h * d * d * 4
+    kernel = out["la_step"].get("kernel")
+    emit("bsa_lightning", ms_a_layer=out, step_bytes=moved,
+         step_peak_pct=round(100 * moved / V5E_HBM / (kernel * 1e-3), 1)
+         if kernel else None)
+
+
 def _combine_float64(args):
     """The combine's sum on the host in float64, token by token."""
     ys, gate_w, has_expert, _here, order, sorted_tok, dest, _sizes = (
@@ -1508,3 +1703,5 @@ if __name__ == "__main__":
         conv(sys.argv[2:] if which == "conv" else ())
     if which in ("combine", "all"):
         combine(sys.argv[2:] if which == "combine" else ())
+    if which in ("bsa", "all"):
+        bsa(sys.argv[2:] if which == "bsa" else ())
