@@ -620,8 +620,9 @@ register_impl("decode_attn", "xla", priority=0)(_decode_dispatch("xla"))
 # ssm_step / ret_step / kda_step kinds: a state layer's one-token update,
 # Mamba-2's (``ops/ssm.py``), power retention's (``ops/retention.py``) and
 # the delta rule's (``ops/kda.py``); conv_step: the one-token rows of the
-# depthwise convolution before the first and the last (``ops/ssm.py``): the
-# in-place Pallas kernel on the TPU, gather/update/scatter elsewhere
+# depthwise convolution before the first and the last (``ops/ssm.py``);
+# kda_chunk: the delta rule's pieces (``ops/kda.py``): the in-place Pallas
+# kernel on the TPU, gather/update/scatter (a loop of XLA pieces) elsewhere
 def _state_dispatch(kind, impl_name):
     def fn(*args):
         from ...ops import kda, retention, ssm
@@ -629,12 +630,13 @@ def _state_dispatch(kind, impl_name):
         steps = {"ssm_step": ssm.STATE_STEPS,
                  "ret_step": retention.STATE_STEPS,
                  "kda_step": kda.STATE_STEPS,
+                 "kda_chunk": kda.PIECES,
                  "conv_step": ssm.CONV_STEPS}[kind]
         return steps[impl_name](*args)
     return fn
 
 
-for _kind in ("ssm_step", "ret_step", "kda_step", "conv_step"):
+for _kind in ("ssm_step", "ret_step", "kda_step", "kda_chunk", "conv_step"):
     register_impl(_kind, "pallas", priority=10,
                   auto_eligible=lambda c: c.get("backend") == "tpu")(
         _state_dispatch(_kind, "pallas"))
@@ -664,6 +666,10 @@ def _ret_step_fn():
 
 def _kda_step_fn():
     return _state_step_fn("kda_step")
+
+
+def _kda_chunk_fn():
+    return _state_step_fn("kda_chunk")
 
 
 def _retention_rows(p, y, cfg, positions):
@@ -1302,7 +1308,8 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
 
         out, pool = chunked(
             q, k, v, g, beta, state[0], l,
-            (ssm.row0, ssm.length, ssm.slot, ssm.fresh, ssm.count), cfg)
+            (ssm.row0, ssm.length, ssm.slot, ssm.fresh, ssm.count), cfg,
+            _kda_chunk_fn())
         one, at = ssm.dec_len > 0, ssm.dec_row
         out_dec, pool = decode_step(
             q[at], k[at], v[at], g[at], beta[at], pool, l,

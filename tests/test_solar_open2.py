@@ -143,13 +143,16 @@ def test_chunked_prefill_then_decode_match_the_reference(built, monkeypatch,
     from deepspeedsyclsupport_tpu.inference.v2 import model as model_v2
     from deepspeedsyclsupport_tpu.inference.v2 import module_registry as reg
 
-    # no setting names a state step: the registry is the seam, and the
-    # interpreted kernel is put first in it for the length of this test
-    first = dataclasses.replace(
-        reg.get_impl("kda_step", step), name="first", priority=100,
-        auto_eligible=lambda ctx: True)
-    monkeypatch.setitem(reg._REGISTRY["kda_step"], "first", first)
-    assert model_v2._kda_step_fn() is first.fn
+    # no setting names a state step or the pieces' form: the registry is
+    # the seam, and the interpreted kernels are put first in it for the
+    # length of this test
+    for kind, chosen in (("kda_step", model_v2._kda_step_fn),
+                         ("kda_chunk", model_v2._kda_chunk_fn)):
+        first = dataclasses.replace(
+            reg.get_impl(kind, step), name="first", priority=100,
+            auto_eligible=lambda ctx: True)
+        monkeypatch.setitem(reg._REGISTRY[kind], "first", first)
+        assert chosen() is first.fn
     assert H.served_errors(*built) < TOL
 
 

@@ -531,6 +531,62 @@ class TestTuneChainTimer:
                                     "kernel_32x128")] == [1.0, 2.0, 3.0]
 
 
+    def test_the_kda_sweep_runs_both_forms_of_the_pieces(
+            self, tune, monkeypatch, capsys):
+        """``tpu_tune.py kda`` at a tiny cell, the kernels interpreted and
+        the profiler's reading stubbed: ``--parity`` holds the state step
+        and BOTH forms of the chunked entry against the sequential
+        recurrence over five pieces with a hand-over, and the pieces' table
+        has the XLA loop beside the kernel, eight slots' pieces and one
+        slot's chunk, each with the bytes it has to move."""
+        import functools
+        import json
+
+        from deepspeedsyclsupport_tpu.ops import kda
+
+        cell = dict(layers=2, slots=19, rows=6, heads=4, dim=16, chunk=8)
+        monkeypatch.setattr(tune, "KDA_CELL", cell)
+        monkeypatch.setattr(tune, "KDA_PIECES", 2)
+        monkeypatch.setattr(tune, "KDA_CHUNK_PIECES", 3)
+        step = functools.partial(kda._state_step_pallas, interpret=True)
+        monkeypatch.setattr(kda, "_state_step_pallas", step)
+        monkeypatch.setitem(kda.STATE_STEPS, "pallas", step)
+        monkeypatch.setitem(kda.PIECES, "pallas", functools.partial(
+            kda._chunked_pallas, interpret=True))
+
+        def reading(steps, args, carry=None, **_kw):
+            for step in steps.values():
+                carry, _out = step(carry, *args)
+            return {name: {"kernel": 0.5, "xla": 0.25,
+                           "calls": {"kernel": 3}}
+                    if not name.endswith("xla") else {"xla": 2.0,
+                                                      "calls": {}}
+                    for name in steps}
+
+        monkeypatch.setattr(tune, "_traced_kernels", reading)
+        tune.kda(["--heads", "2", "--piece-heads", "4", "--parity"])
+        out = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
+        parity = [r for r in out if r["section"] == "kda_parity"]
+        assert [(r["entry"], r.get("form"), r.get("strong_decay"))
+                for r in parity] == [
+            ("decode_step", None, None), ("chunked", "xla", False),
+            ("chunked", "pallas", False), ("chunked", "xla", True),
+            ("chunked", "pallas", True)]
+        assert all(r["y_err"] < 2e-5 and r["pool_err"] < 2e-5
+                   and r["y_max"] > 0.01 for r in parity)
+        eight, chunk = [r for r in out if r["section"] == "kda_chunked"]
+        state, row = 4 * 16 * 16 * 4, 8 * 4 * 16 * 4
+        assert (eight["pieces"], eight["slots"], eight["bytes_moved"]) \
+            == (2, 2, 2 * 5 * row + 2 * 2 * state)
+        assert (chunk["pieces"], chunk["slots"], chunk["bytes_moved"]) \
+            == (3, 1, 3 * 5 * row + 2 * state)
+        for table in (eight, chunk):
+            assert not table["failed"]
+            assert set(table["rows"]) == {"chunked_xla", "kda_piece_4"}
+            assert table["rows"]["kda_piece_4"]["ms"] == 0.75
+            assert table["rows"]["chunked_xla"]["ms_a_piece"] == round(
+                2.0 / table["pieces"], 4)
+
     def test_the_combine_sweep_runs_both_forms_and_holds_the_sum(
             self, tune, monkeypatch, capsys):
         """``tpu_tune.py combine`` at two tiny cells (a held share of the

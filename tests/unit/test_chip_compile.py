@@ -889,10 +889,12 @@ def test_the_two_kind_forwards_compile_at_the_cells_widths(one_chip, program,
 def test_the_delta_rule_compiles_at_the_cells_widths(one_chip, entry):
     """``solar2-agent-sat``: 256 rows (a 768-row mixed round in pieces of
     64) against the pool ``[3 layers, 256 + 1 slots, 64 heads, 128, 128]``
-    float32 (3.0 GiB): the state step is a custom call by its own name, 16
-    heads a grid step, the pool is aliased (held once) in both entries, and
-    the chunked form, which is XLA, holds under 128 MiB of temporaries (a
-    piece's pair products of one sub-block are the largest: 32 MiB)."""
+    float32 (3.0 GiB): each entry is ONE custom call by its own name (the
+    state step 16 heads a grid step; the pieces' kernel over a 768-row
+    round's 269 pieces), the pool is aliased (held once) in both, and the
+    chunked form holds under 128 MiB of temporaries and turns no ``[768,
+    64, 128]`` operand in XLA: the rows go into the kernel token-major as
+    they come."""
     from deepspeedsyclsupport_tpu.ops import kda
 
     cfg = get_config("solar-open2")
@@ -910,7 +912,8 @@ def test_the_delta_rule_compiles_at_the_cells_widths(one_chip, entry):
     else:
         def f(q, k, v, g, beta, s, row0, length, slot, fresh, count):
             return kda.chunked(q, k, v, g, beta, s, 1,
-                               (row0, length, slot, fresh, count), cfg)
+                               (row0, length, slot, fresh, count), cfg,
+                               kda.PIECES["pallas"])
 
         last = [((269,), jnp.int32)] * 3 + [((269,), jnp.bool_),
                                             ((), jnp.int32)]
@@ -918,10 +921,17 @@ def test_the_delta_rule_compiles_at_the_cells_widths(one_chip, entry):
             for s, dt in acts + [pool] + last]
     compiled = jax.jit(f, donate_argnums=5).lower(*args).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
-    assert ("kda_state_step" in text and "tpu_custom_call" in text) \
-        == (entry == "decode_step")
+    name = "kda_state_step" if entry == "decode_step" else "kda_piece"
+    calls = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert len(calls) == 1 and name in calls[0], calls
     assert mem.alias_size_in_bytes >= 3 * 257 * h * d * d * 4
     assert mem.temp_size_in_bytes < 128 << 20, mem.temp_size_in_bytes
+    if entry == "chunked":
+        assert "kda_scan/kda_chunk" in calls[0]
+        turned = [line for line in text.splitlines()
+                  if re.search(r"= f32\[(768,64,128|64,768,128|768,8,8,128)\]"
+                               r"\S* (copy|transpose)\(", line)]
+        assert not turned, turned
 
 
 # ------------------------- the one-token rows' convolution, tail in place

@@ -2,8 +2,10 @@
 ``xla`` form and the Pallas kernel in interpret mode against each other; the
 chunked form against the SEQUENTIAL float32 recurrence (the state step a
 token at a time), a decay that overflows a naive ``e^-G`` among the cases;
-and ``ops/ssm.py``'s one convolution, which the delta rule calls with no
-bias."""
+the pieces' kernel (``kda_piece``) interpreted against both, the state's
+hand-over from piece to piece among its cases; and ``ops/ssm.py``'s one
+convolution, which the delta rule calls with no bias."""
+import functools
 import types
 
 import jax
@@ -12,10 +14,11 @@ import numpy as np
 import pytest
 
 
-def _rows(t, h=4, d=16, seed=0, strong=False):
+def _rows(t, h=4, d=16, seed=0, strong=False, beta_shift=0.0):
     """``(q, k, v, g, beta)`` as the mixer hands them over; ``strong``: -40
     a row on every fourth channel, so that a piece's running sum passes
-    float32's exponent."""
+    float32's exponent; ``beta_shift`` 4: every write's strength at 1.93 -
+    2.0, the transition's eigenvalue near -1."""
     from deepspeedsyclsupport_tpu.ops.kda import l2norm
 
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
@@ -24,7 +27,8 @@ def _rows(t, h=4, d=16, seed=0, strong=False):
                                     maxval=np.log(1.6)))
     if strong:
         g = jnp.where(jnp.arange(d) % 4 == 0, -40.0, g)
-    beta = 2.0 * jax.nn.sigmoid(jax.random.normal(ks[2], (t, h)))
+    beta = 2.0 * jax.nn.sigmoid(jax.random.normal(ks[2], (t, h))
+                                + beta_shift)
     return l2norm(q) * d ** -0.5, l2norm(k), v, g, beta
 
 
@@ -110,6 +114,100 @@ def test_the_chunked_form_is_the_sequential_recurrence(chunk, strong):
     np.testing.assert_allclose(y[:t], want, atol=2e-5)
     assert not np.asarray(y[t]).any()            # no piece lies there
     np.testing.assert_allclose(new[:, :5], want_pool[:, :5], atol=2e-5)
+    np.testing.assert_array_equal(np.asarray(new)[0], np.asarray(pool)[0])
+
+
+# a case of the pieces' kernel: the chunk, the heads, the pieces as (slot,
+# length, fresh, rows skipped before it) in the order they are walked, the
+# rows left after the last, and what the rows are drawn with; ``live``: the
+# count handed over (None: every piece), ``heads``: heads a grid step
+PIECE_CASES = {
+    "lengths_1_17_64": dict(chunk=64, pieces=[(0, 64, True, 0),
+                                              (0, 17, False, 0),
+                                              (3, 1, False, 2)], after=3),
+    "strong_decay": dict(chunk=16, pieces=[(2, 16, True, 0), (2, 6, False, 0),
+                                           (0, 16, False, 1)], after=1,
+                         rows=dict(strong=True)),
+    "beta_near_2": dict(chunk=16, pieces=[(1, 16, True, 0), (1, 16, False, 0),
+                                          (4, 9, False, 0)], after=2,
+                        rows=dict(beta_shift=4.0)),
+    "window_past_the_last_row": dict(chunk=16, pieces=[(0, 16, True, 0),
+                                                       (1, 5, False, 0)],
+                                     after=0),
+    "fresh_over_a_dirty_slot": dict(chunk=8, pieces=[(3, 8, True, 0),
+                                                     (3, 8, True, 0)],
+                                    after=1),
+    "three_of_one_slot_between_two_of_another": dict(
+        chunk=8, pieces=[(1, 8, False, 0), (4, 8, False, 0), (4, 8, False, 0),
+                         (4, 5, False, 0), (1, 8, False, 0)], after=0),
+    "dead_pieces_beyond_the_count": dict(
+        chunk=8, pieces=[(2, 8, True, 0), (2, 3, False, 0), (2, 8, False, 0),
+                         (0, 8, False, 0)], after=0, live=2),
+    "heads_the_step_does_not_divide": dict(
+        chunk=8, heads=24, pieces=[(0, 8, True, 0), (0, 8, False, 0),
+                                   (1, 4, False, 0)], after=1),
+    "one_head_tile_a_step": dict(
+        chunk=8, heads=16, step=8, pieces=[(0, 8, False, 0), (0, 2, False, 0),
+                                           (2, 8, True, 3)], after=0),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PIECE_CASES))
+def test_the_pieces_kernel_is_the_sequential_recurrence(case):
+    """``kda_piece`` interpreted against the state step a token at a time
+    and against the ``xla`` form (a loop of ``_piece``): the outputs, the
+    slots it wrote, and everything it must leave alone (the other layer,
+    the slots of no live piece, ``y`` where no live piece lies)."""
+    from deepspeedsyclsupport_tpu.ops import kda
+
+    spec = PIECE_CASES[case]
+    c, h = spec["chunk"], spec.get("heads", 4)
+    cfg = types.SimpleNamespace(kda_chunk_size=c)
+    row0, at = [], 0
+    for _slot, length, _fresh, skipped in spec["pieces"]:
+        row0.append(at + skipped)
+        at += skipped + length
+    t = at + spec["after"]
+    slot, length, fresh, _ = (list(x) for x in zip(*spec["pieces"]))
+    live = spec.get("live", len(row0))
+    # two dead pieces behind the live ones, pointing at live rows and slots
+    pieces = tuple(jnp.asarray(x + x[:1] * 2) for x in (row0, length, slot,
+                                                         fresh)) \
+        + (jnp.asarray(live),)
+    pool = jax.random.normal(jax.random.PRNGKey(7), (2, 6, h, 16, 16))
+    rows = _rows(t, h=h, seed=3, **spec.get("rows", {}))
+    form = functools.partial(kda._chunked_pallas, interpret=True,
+                             heads=spec.get("step"))
+    y, new = jax.jit(lambda *a: kda.chunked(*a, 1, pieces, cfg, form))(
+        *rows, pool)
+    y_x, new_x = jax.jit(lambda *a: kda.chunked(
+        *a, 1, pieces, cfg, kda.PIECES["xla"]))(*rows, pool)
+
+    def token(i, carry):
+        """Live row ``i`` (of the pieces' rows, in the order walked) through
+        the XLA state step."""
+        p, out = carry
+        r = order[i]
+        y_i, p = kda.decode_step(
+            *(jax.lax.dynamic_slice_in_dim(a, r, 1) for a in rows), p, 1,
+            slot_of[i][None], first[i][None], None, kda.STATE_STEPS["xla"])
+        return p, jax.lax.dynamic_update_slice_in_dim(out, y_i, r, 0)
+
+    walked = [(r0 + j, s, f and j == 0) for r0, n, s, f in
+              list(zip(row0, length, slot, fresh))[:live] for j in range(n)]
+    order, slot_of, first = (jnp.asarray(x) for x in zip(*walked))
+    want_pool, want = jax.jit(lambda p: jax.lax.fori_loop(
+        0, len(walked), token, (p, jnp.zeros(rows[2].shape))))(pool)
+    assert np.isfinite(np.asarray(y)).all()
+    np.testing.assert_allclose(y, want, atol=2e-5)
+    np.testing.assert_allclose(y, y_x, atol=2e-5)
+    np.testing.assert_allclose(new, want_pool, atol=2e-5)
+    np.testing.assert_allclose(new, new_x, atol=2e-5)
+    quiet = np.setdiff1d(np.arange(t), np.asarray(order))
+    assert not np.asarray(y)[quiet].any()
+    untouched = np.setdiff1d(np.arange(6), np.asarray(slot[:live]))
+    np.testing.assert_array_equal(np.asarray(new)[1, untouched],
+                                  np.asarray(pool)[1, untouched])
     np.testing.assert_array_equal(np.asarray(new)[0], np.asarray(pool)[0])
 
 

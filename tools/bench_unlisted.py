@@ -12,7 +12,8 @@ Usage, from the root of a checkout, on the chip::
 Everything after the two options is ``benchmark.run``'s. The result line is
 the harness's own with the extra entries in it (an entry whose reader finds
 nothing is left out, as always); the breakdown goes to stderr on lines that
-start ``BREAKDOWN``. ``benchmark/`` is not edited: the extra entries are
+start ``BREAKDOWN`` (by scope, by operation, and the six largest copies
+with their shapes and layouts). ``benchmark/`` is not edited: the extra entries are
 handed to ``spec.Bench`` for the length of this process. An entry that
 shares its reader with another takes the suffix its own cells have
 (``decode_fwd_ms.moe``); the defaults are those of a sparse serving cell
@@ -50,7 +51,7 @@ def breakdown(labels):
         plane = sorted(tr["devices"])[0]
         lo, hi = obs["trace_window"]
         label_at = {(p, s): lab for lab, p, s, _d in ops}
-        by, top, execs = {}, {}, {}
+        by, top, copies, execs = {}, {}, {}, {}
         names = trace.program_names(tr, plane)
         for m in tr["devices"][plane]["modules"]:
             if lo <= m[1] <= hi:
@@ -62,6 +63,9 @@ def breakdown(labels):
             by[(p, lab)] = by.get((p, lab), 0.0) + dur
             key = (p, lab, trace.op_kind(text), trace.op_name(text))
             top[key] = top.get(key, 0.0) + dur
+            if key[2] == "copy":
+                was = copies.get((p, text), (0.0, 0))
+                copies[(p, text)] = (was[0] + dur, was[1] + 1)
         print("BREAKDOWN execs", json.dumps(execs), file=sys.stderr)
         for (p, lab), d in sorted(by.items(), key=lambda kv: -kv[1]):
             print(f"BREAKDOWN scope {p:16s} {lab:14s} {1e3 * d:9.2f} ms  "
@@ -72,6 +76,13 @@ def breakdown(labels):
             print(f"BREAKDOWN op {key[0]:16s} {key[1]:12s} {key[2]:7s} "
                   f"{key[3]:40s} {1e3 * d:9.2f} ms {1e3 * d / n:7.3f} ms/exec",
                   file=sys.stderr)
+        # a copy's name says nothing: the largest with their shapes and
+        # layouts, as the trace spells them
+        for (p, text), (d, n) in sorted(copies.items(),
+                                        key=lambda kv: -kv[1][0])[:6]:
+            print(f"BREAKDOWN copy {p:16s} {1e3 * d:9.2f} ms "
+                  f"{n / max(1, execs.get(p, 1)):5.2f} a forward  "
+                  f"{text[:300]}", file=sys.stderr)
         return None
     return read
 

@@ -28,10 +28,14 @@ Sections, cheapest first:
             rows on 256 slots + the sink, 3 layers, 64 heads of a [128, 128]
             float32 state): the state step's kernel at several heads a grid
             step, ms a layer and the share of the HBM peak; the chunked
-            form's pieces, ms a piece; with ``--parity`` both entries against
-            the SEQUENTIAL float32 recurrence on the chip, a decay strong
-            enough to overflow a naive ``e^-G`` among them:
-            kda [--heads N ...] [--parity]
+            form's pieces as the XLA loop and as the kernel ``kda_piece`` at
+            several heads a grid step (ms a piece, the bytes it has to move,
+            their share of the HBM peak), eight pieces of eight slots and a
+            12-piece chunk of ONE slot; with ``--parity`` both entries, the
+            chunked one in both forms, against the SEQUENTIAL float32
+            recurrence on the chip, a decay strong enough to overflow a
+            naive ``e^-G`` and a slot's state handed from piece to piece
+            among them:  kda [--heads N ...] [--piece-heads N ...] [--parity]
 
   conv    — the one-token rows' convolution ALONE at the two cells that
             run it (``solar2-agent-sat``: 256 rows x 24,576 channels, no
@@ -1027,6 +1031,8 @@ def dsa(argv=()):
 KDA_CELL = dict(layers=3, slots=257, rows=256, heads=64, dim=128, chunk=64)
 KDA_HEADS = (8, 16, 32, 64)     # heads a grid step of the state step takes
 KDA_PIECES = 8                  # pieces the chunked form's program runs
+KDA_CHUNK_PIECES = 12           # ... and a 768-row chunk's, all of ONE slot
+KDA_PIECE_HEADS = (8, 16, 32, 64)   # heads a grid step of the pieces' kernel
 
 
 def _kda_rows(rows, seed=0, strong=False):
@@ -1080,34 +1086,39 @@ def _kda_parity(tree):
          y_err=float(jnp.max(jnp.abs(got["xla"][0] - got["pallas"][0]))),
          pool_max=float(jnp.max(jnp.abs(got["xla"][1]))),
          pool_err=float(jnp.max(jnp.abs(got["xla"][1] - got["pallas"][1]))))
-    # three pieces: sequence A in slot 2 (64 rows fresh, then 21 more),
-    # sequence B in slot 0 (64 rows from what the slot holds)
+    # five pieces: sequence A in slot 2 (64 rows fresh), three consecutive
+    # pieces of sequence B in slot 0 (64, 64 and 21 rows from what the slot
+    # holds: the state is handed on), then A's next 64 rows
     tail = q // 3
-    t = 2 * q + tail
-    pieces = (jnp.asarray([0, q, q + tail, 0]), jnp.asarray([q, tail, q, 0]),
-              jnp.asarray([2, 2, 0, slots_n - 1]),
-              jnp.asarray([True, False, False, False]), jnp.asarray(3))
+    t = 4 * q + tail
+    pieces = (jnp.asarray([0, q, 2 * q, 3 * q, 3 * q + tail, 0]),
+              jnp.asarray([q, q, q, tail, q, 0]),
+              jnp.asarray([2, 0, 0, 0, 2, slots_n - 1]),
+              jnp.asarray([True, False, False, False, False, False]),
+              jnp.asarray(5))
     for strong in (False, True):
         args = _kda_rows(t, seed=9, strong=strong)
-        y, p = jax.jit(lambda p, *a: tree.chunked(*a, p, 0, pieces, cfg))(
-            pool, *args)
 
         def token(i, carry):
             p, out = carry
-            slot = jnp.where(i < q + tail, 2, 0)
+            slot = jnp.where((i < q) | (i >= 3 * q + tail), 2, 0)
             y_i, p = tree.decode_step(
                 *(jax.lax.dynamic_slice_in_dim(a, i, 1) for a in args), p, 0,
                 slot[None], (i == 0)[None], cfg, tree.STATE_STEPS["xla"])
             return p, jax.lax.dynamic_update_slice_in_dim(out, y_i, i, 0)
 
         p_seq, y_seq = jax.jit(lambda p: jax.lax.fori_loop(
-            0, t, token, (p, jnp.zeros_like(y))))(pool)
-        emit("kda_parity", entry="chunked", strong_decay=strong, rows=t,
-             y_max=float(jnp.max(jnp.abs(y_seq))),
-             y_err=float(jnp.max(jnp.abs(y - y_seq))),
-             pool_max=float(jnp.max(jnp.abs(p_seq[:, :3]))),
-             pool_err=float(jnp.max(jnp.abs(p[:, :3] - p_seq[:, :3]))),
-             finite=bool(jnp.isfinite(y).all()))
+            0, t, token, (p, jnp.zeros(args[2].shape))))(pool)
+        for name in ("xla", "pallas"):
+            y, p = jax.jit(lambda p, *a, name=name: tree.chunked(
+                *a, p, 0, pieces, cfg, tree.PIECES[name]))(pool, *args)
+            emit("kda_parity", entry="chunked", form=name,
+                 strong_decay=strong, rows=t,
+                 y_max=float(jnp.max(jnp.abs(y_seq))),
+                 y_err=float(jnp.max(jnp.abs(y - y_seq))),
+                 pool_max=float(jnp.max(jnp.abs(p_seq[:, :3]))),
+                 pool_err=float(jnp.max(jnp.abs(p[:, :3] - p_seq[:, :3]))),
+                 finite=bool(jnp.isfinite(y).all()))
 
 
 def kda(argv=()):
@@ -1115,8 +1126,10 @@ def kda(argv=()):
     time read off a profiler trace: the state step's kernel at each of
     ``--heads`` heads a grid step (ms a layer, the share of 819 GB/s: every
     row's state read once and written once) and the chunked form over
-    ``KDA_PIECES`` full pieces of one layer (ms a piece, all of it XLA);
-    ``--parity`` holds both against the sequential recurrence first."""
+    ``KDA_PIECES`` full pieces of one layer and over a chunk of
+    ``KDA_CHUNK_PIECES`` of one slot, as the XLA loop and as the kernel at
+    each of ``--piece-heads`` (ms a piece); ``--parity`` holds both entries
+    against the sequential recurrence first."""
     import argparse
     import types
 
@@ -1124,6 +1137,8 @@ def kda(argv=()):
 
     ap = argparse.ArgumentParser(prog="tpu_tune.py kda")
     ap.add_argument("--heads", type=int, nargs="*", default=list(KDA_HEADS))
+    ap.add_argument("--piece-heads", type=int, nargs="*",
+                    default=list(KDA_PIECE_HEADS))
     ap.add_argument("--parity", action="store_true")
     a = ap.parse_args(list(argv))
     c = KDA_CELL
@@ -1164,29 +1179,51 @@ def kda(argv=()):
                                     / (row["kernel"] * 1e-3), 1)
     emit("kda", cell=c, bytes_a_layer=moved, rows=out, failed=failed)
     del steps
-    # the chunked form: KDA_PIECES full pieces of as many sequences
-    t = KDA_PIECES * q
-    pieces = (jnp.arange(KDA_PIECES) * q, jnp.full((KDA_PIECES,), q),
-              jnp.arange(KDA_PIECES), jnp.zeros((KDA_PIECES,), bool),
-              jnp.asarray(KDA_PIECES))
+    # the chunked form: KDA_PIECES full pieces of as many sequences through
+    # the XLA loop and through the kernel at each of --piece-heads heads a
+    # grid step; then a 768-row chunk, twelve pieces of ONE slot (the state
+    # stays in VMEM from piece to piece)
+    def pieces_of(n, one_slot):
+        slot = jnp.zeros((n,), jnp.int32) if one_slot else jnp.arange(n)
+        return (jnp.arange(n) * q, jnp.full((n,), q), slot,
+                jnp.zeros((n,), bool), jnp.asarray(n))
 
-    def chunk(pool, *rows_):
-        y, pool = tree.chunked(*rows_, pool, 1, pieces, cfg)
-        return pool, y
+    def chunk_of(name, form, pieces):
+        def chunk(pool, *rows_):
+            y, pool = tree.chunked(*rows_, pool, 1, pieces, cfg, form)
+            return pool, y
+        chunk.__name__ = name
+        return jax.jit(chunk, donate_argnums=0)
 
-    chunk.__name__ = "chunked"
-    pool = jax.jit(lambda key: 0.1 * jax.random.normal(
-        key, (c["layers"], c["slots"], h, d, d)))(jax.random.PRNGKey(3))
-    rows_t = _kda_rows(t, seed=1)
-    prog = {"chunked": jax.jit(chunk, donate_argnums=0).lower(
-        pool, *rows_t).compile()}
-    out = _traced_kernels(prog, rows_t, kernel_of=lambda text: "kernel",
-                          carry=pool)
-    ms = out["chunked"]["xla"]
-    emit("kda_chunked", pieces=KDA_PIECES, rows_a_piece=q, ms=ms,
-         ms_a_piece=round(ms / KDA_PIECES, 4),
-         temp_mib=round(prog["chunked"].memory_analysis().temp_size_in_bytes
-                        / 2**20, 1))
+    state, row = h * d * d * 4, q * h * d * 4
+    for n, one_slot in ((KDA_PIECES, False), (KDA_CHUNK_PIECES, True)):
+        pool = jax.jit(lambda key: 0.1 * jax.random.normal(
+            key, (c["layers"], c["slots"], h, d, d)))(jax.random.PRNGKey(3))
+        rows_t = _kda_rows(n * q, seed=1)
+        forms = {"chunked_xla": tree.PIECES["xla"]}
+        forms.update({f"kda_piece_{hb}": functools.partial(
+            tree.PIECES["pallas"], heads=hb) for hb in a.piece_heads})
+        prog, failed = {}, {}
+        for name, form in forms.items():
+            try:
+                prog[name] = chunk_of(name, form, pieces_of(n, one_slot)) \
+                    .lower(pool, *rows_t).compile()
+            except Exception as e:
+                failed[name] = str(e).splitlines()[0][:160]
+        out = _traced_kernels(prog, rows_t, kernel_of=lambda text: "kernel",
+                              carry=pool)
+        # what a piece has to move: q, k, g, v in and y out, its state in
+        # and out (once a SLOT where the kernel carries it)
+        moved = n * 5 * row + 2 * (1 if one_slot else n) * state
+        for name, r in out.items():
+            ms = r.get("kernel", 0.0) + r["xla"]
+            r.update(ms=round(ms, 4), ms_a_piece=round(ms / n, 4),
+                     peak_pct=round(100 * moved / V5E_HBM / (ms * 1e-3), 1),
+                     temp_mib=round(prog[name].memory_analysis()
+                                    .temp_size_in_bytes / 2**20, 1))
+        emit("kda_chunked", pieces=n, slots=1 if one_slot else n,
+             rows_a_piece=q, bytes_moved=moved, rows=out, failed=failed)
+        del prog
 
 
 # The one-token rows' convolution of the two cells that run it: every slot
