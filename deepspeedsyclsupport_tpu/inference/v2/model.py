@@ -621,8 +621,9 @@ register_impl("decode_attn", "xla", priority=0)(_decode_dispatch("xla"))
 # Mamba-2's (``ops/ssm.py``), power retention's (``ops/retention.py``) and
 # the delta rule's (``ops/kda.py``); conv_step: the one-token rows of the
 # depthwise convolution before the first and the last (``ops/ssm.py``);
-# kda_chunk: the delta rule's pieces (``ops/kda.py``): the in-place Pallas
-# kernel on the TPU, gather/update/scatter (a loop of XLA pieces) elsewhere
+# conv_pieces: the pieces of the same convolution; kda_chunk: the delta
+# rule's pieces (``ops/kda.py``): the in-place Pallas kernel on the TPU,
+# gather/update/scatter (a loop of XLA pieces) elsewhere
 def _state_dispatch(kind, impl_name):
     def fn(*args):
         from ...ops import kda, retention, ssm
@@ -631,12 +632,14 @@ def _state_dispatch(kind, impl_name):
                  "ret_step": retention.STATE_STEPS,
                  "kda_step": kda.STATE_STEPS,
                  "kda_chunk": kda.PIECES,
-                 "conv_step": ssm.CONV_STEPS}[kind]
+                 "conv_step": ssm.CONV_STEPS,
+                 "conv_pieces": ssm.CONV_PIECES}[kind]
         return steps[impl_name](*args)
     return fn
 
 
-for _kind in ("ssm_step", "ret_step", "kda_step", "kda_chunk", "conv_step"):
+for _kind in ("ssm_step", "ret_step", "kda_step", "kda_chunk", "conv_step",
+              "conv_pieces"):
     register_impl(_kind, "pallas", priority=10,
                   auto_eligible=lambda c: c.get("backend") == "tpu")(
         _state_dispatch(_kind, "pallas"))
@@ -658,6 +661,10 @@ def _ssm_step_fn():
 
 def _conv_step_fn():
     return _state_step_fn("conv_step")
+
+
+def _conv_pieces_fn():
+    return _state_step_fn("conv_pieces")
 
 
 def _ret_step_fn():
@@ -1291,7 +1298,7 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
         out, conv = conv_pieces(
             qkv, w, None, state[1], l,
             (ssm.row0, ssm.length, ssm.slot, ssm.fresh, ssm.count),
-            cfg.kda_chunk_size)
+            cfg.kda_chunk_size, _conv_pieces_fn())
         one = ssm.dec_len > 0
         out_dec, conv = conv_step(
             qkv[ssm.dec_row], w, None, conv, l,

@@ -26,9 +26,10 @@ sublanes, the value's on the lanes. The decay is then PER SUBLANE (a column
 write is a column times a row. Slot ``S`` (the last) is the sink padding
 writes to. A piece whose first position is 0 starts from zeros, whatever its
 slot held: the host resets nothing. The depthwise convolution before it and
-its tail are Mamba-2's (``ops/ssm.conv_step``, on the TPU the in-place
-kernel ``conv_tail_step``, / ``conv_pieces``), called with the layer's ``3 x
-h x dk`` channels and no bias.
+its tail are Mamba-2's (``ops/ssm.conv_step`` for the one-token rows and
+``ops/ssm.conv_pieces`` for the pieces: on the TPU the in-place kernels
+``conv_tail_step`` and ``conv_pieces``), called with the layer's ``3 x h x
+dk`` channels and no bias.
 
 Two entries, as ``ops/ssm.py`` and ``ops/retention.py`` have:
 

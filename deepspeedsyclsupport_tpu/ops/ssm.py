@@ -24,11 +24,17 @@ whatever its slot held: the host resets nothing.
 Who moves the tail: the ONE-TOKEN rows' kernel (:func:`conv_step`,
 ``conv_tail_step`` on the TPU: whole blocks of slots x channels in and the
 same blocks out, the pool aliased, each slot's row found by an index in
-SMEM; gather, convolve, scatter elsewhere) and the pieces' loops
-(:func:`conv_piece` under :func:`chunked_scan` / :func:`conv_pieces`: one
-slot's ``[k - 1, channels]`` read and written by XLA a piece, on other
-slots, before the kernel in a mixed forward). Mamba-2's ``xBC`` and the
-delta rule's q | k | v (``ops/kda.py``) go through the same three functions.
+SMEM; gather, convolve, scatter elsewhere) and the PIECES' kernel
+(:func:`conv_pieces`, ``conv_pieces`` on the TPU: ONE call a layer over all
+the live pieces, before the tail's kernel in a mixed forward and on other
+slots; a piece's slot comes and goes in the 16-slot block of the pool that
+holds it, by the kernel's own copies, so that the compiler lays the pool
+out no other way; elsewhere a loop of :func:`conv_piece` in XLA).
+Mamba-2's mixed path keeps the convolution inside its own loop of XLA
+(:func:`conv_piece` under :func:`chunked_scan`: hoisted into the kernel it
+read no faster in ``nemo3-reason-sat`` and cost a quarter more set-up, PERF.md
+section 6, PR 61). Mamba-2's ``xBC`` and the delta rule's q | k | v
+(``ops/kda.py``) share :func:`conv_step` and :func:`conv_piece`.
 
 Lightning linear attention (arXiv:2401.04658; minicpm_sala's ``L`` layers)
 is the same recurrence with a group a head, a CONSTANT decay a head
@@ -264,11 +270,9 @@ def conv_piece(rows, w, bias, conv, layer, slot, keep, n):
     return out, conv
 
 
-def conv_pieces(x, w, bias, conv, layer, pieces, chunk):
-    """The convolution alone over the pieces of a flat batch (``pieces`` as
-    :func:`chunked_scan` takes them), for a mixer whose recurrence does not
-    run in the same loop. ``x`` [T, channels]. -> ``(out [T, channels]
-    float32, zero where no piece lies; conv)``."""
+def _conv_pieces_xla(x, w, bias, conv, layer, pieces, chunk):
+    """:func:`conv_pieces` as a loop of :func:`conv_piece` in XLA: the CPU's
+    form, and what the kernel is held against."""
     row0, length, slots, fresh, count = pieces
     t = x.shape[0]
     x = jnp.pad(x, ((0, chunk), (0, 0)))
@@ -291,6 +295,327 @@ def conv_pieces(x, w, bias, conv, layer, pieces, chunk):
         0, count, piece,
         (conv, jnp.zeros((t + chunk, x.shape[1]), jnp.float32)))
     return out_all[:t], conv
+
+
+# The pieces' kernel walks the PIECES, to their count, inside one call (as
+# ``ops/kda.py``'s ``kda_piece`` does), and everything it moves is a copy of
+# its own in whole tiles of what XLA already holds, so that the compiler
+# lays nothing out anew: ``x`` [T, channels] and ``out`` [T, channels] stay
+# two-dimensional with the rows on the sublanes, the pool keeps its slots
+# there. A piece is seen through a FRAME of ``chunk + 16`` rows that starts
+# at a multiple of 16 (a tile of either dtype) at or before its first row:
+#
+# * the frame of ``x`` comes into one of two buffers (the next piece's while
+#   this one is computed) and is widened to float32 a strip of lanes at a
+#   time behind 8 spare rows, the slot's tail is written over the three rows
+#   before the piece's first (single rows of float32 by a dynamic index,
+#   which Mosaic allows where it allows no such row of a packed block), and
+#   tap ``j`` is the strip read ``j`` rows on: static, unaligned reads;
+# * the slot's tail comes and goes as the whole block of PIECE_SLOTS slots
+#   that holds it (Mosaic copies no single row of the tiled pool), kept in
+#   float32 while the block stays (consecutive pieces of one slot, and
+#   pieces of neighbouring slots, never go through HBM), and goes back when
+#   the block changes or the pieces end. The block that leaves goes out of
+#   one buffer while ANOTHER block's rows come into the other, and every
+#   copy out is waited for before the next one starts: a block that comes
+#   back is read only after its own copy out has landed;
+# * the result goes out in whole 8-row tiles, the tiles that hold a live
+#   row, in copies of 2^k tiles. The first and the last of them may hold
+#   other pieces' rows: they are read back (through the aliased output,
+#   after the piece before has landed) and added, the piece's own rows
+#   being zero there and the others' zero here.
+#
+# A piece on the sink reads zeros and leaves no tail, as the tail's kernel
+# has it. PIECES_TILE_BYTES: what a grid step's buffers may hold; all of the
+# channels at both cells' widths (35 MB at Solar's 24,576 x 80 rows).
+PIECE_SLOTS = 16
+PIECE_LANES = 256
+PIECES_TILE_BYTES = 48 << 20
+
+
+def _conv_pieces_kernel(layer_ref, row0_ref, length_ref, slot_ref, fresh_ref,
+                        count_ref, w_ref, *refs, taps, bias, lanes):
+    """Grid step ``c``: channel tile ``c`` of EVERY live piece, one after
+    another. ``xw`` [2, frame, ct] the frames of ``x`` as they come, ``xf``
+    [8 + frame, lanes] float32 a strip of one behind its tail, ``yw`` [2,
+    frame, ct] the results on their way out, ``edge`` [2, 8, ct] the first
+    and the last tile as HBM holds them, ``blk`` [2, taps - 1, slots, ct]
+    a block of the pool in and out, ``blkf`` the one held, in float32."""
+    b_ref = refs[0] if bias else None
+    (x_hbm, zero_hbm, pool_hbm, y_hbm, out_hbm, xw, xf, yw, edge, blk, blkf,
+     xsem, ysem, esem, bsem) = refs[bias:]
+    # out's zeros and the pool are aliased to the outputs, and both are read
+    # where an earlier piece wrote them: through the outputs' refs
+    del zero_hbm, pool_hbm
+    f32 = jnp.float32
+    k1 = taps - 1
+    frame, ct = xw.shape[1:]
+    tiles = frame // 8
+    held_slots, total = blk.shape[2], out_hbm.shape[2]
+    tp = x_hbm.shape[0]
+    cols = pl.ds(pl.multiple_of(pl.program_id(0) * ct, ct), ct) \
+        if ct % 128 == 0 else slice(None)
+    layer, count = layer_ref[0], count_ref[0]
+
+    def start_of(i):
+        """The frame's first row: the multiple of 16 at or before ``row0``,
+        or the last one a whole frame starts at."""
+        return pl.multiple_of(
+            jnp.minimum(row0_ref[i] // 16 * 16, tp - frame), 16)
+
+    def x_copy(i, par):
+        return pltpu.make_async_copy(
+            x_hbm.at[pl.ds(start_of(i), frame), cols], xw.at[par],
+            xsem.at[par])
+
+    def block_of(i):
+        """The first slot of the block that holds piece ``i``'s."""
+        return slot_ref[i] // held_slots * held_slots
+
+    def block_copy(b0, out):
+        at = (layer, slice(None),
+              pl.ds(pl.multiple_of(b0, held_slots), held_slots), cols)
+        return pltpu.make_async_copy(blk.at[1], out_hbm.at[at], bsem.at[1]) \
+            if out else pltpu.make_async_copy(out_hbm.at[at], blk.at[0],
+                                              bsem.at[0])
+
+    def span(i):
+        """``(first tile, tiles)`` of the frame that hold piece ``i``'s
+        rows."""
+        e = row0_ref[i] - start_of(i)
+        first = e // 8
+        return first, jnp.maximum((e + length_ref[i] + 7) // 8 - first, 1)
+
+    def y_copies(i, par):
+        """``(bit set, copy)`` for each power of two up to the frame's
+        tiles: the largest run first."""
+        first, m = span(i)
+        out = []
+        for bit in reversed(range(tiles.bit_length())):
+            size = 1 << bit
+            at = first + ((m >> (bit + 1)) << (bit + 1))
+            at = pl.multiple_of(jnp.minimum(at, tiles - size) * 8, 8)
+            out.append((((m >> bit) & 1) == 1, pltpu.make_async_copy(
+                yw.at[par, pl.ds(at, size * 8)],
+                y_hbm.at[pl.ds(start_of(i) + at, size * 8), cols],
+                ysem.at[par, bit])))
+        return out
+
+    def y_wait(i, par):
+        for on, copy in y_copies(i, par):
+            pl.when(on)(copy.wait)
+
+    def edge_copies(i):
+        first, m = span(i)
+        return [pltpu.make_async_copy(
+            y_hbm.at[pl.ds(start_of(i) + pl.multiple_of(at * 8, 8), 8), cols],
+            edge.at[n], esem.at[n])
+            for n, at in enumerate((first, first + m - 1))]
+
+    def put_back(b0, pending):
+        """The held block goes out: once the copy out before it has."""
+        pl.when(pending == 1)(block_copy(b0, True).wait)
+        blk[1] = blkf[...].astype(blk.dtype)
+        block_copy(b0, True).start()
+
+    pl.when(count > 0)(x_copy(0, 0).start)
+
+    def piece(i, carry):
+        held, pending = carry
+        par = jax.lax.rem(i, 2)
+        x_copy(i, par).wait()
+        pl.when(i + 1 < count)(x_copy(i + 1, 1 - par).start)
+        slot = slot_ref[i]
+        real = slot != total - 1
+        b0 = block_of(i)
+        row = slot - b0
+        change = real & (b0 != held)
+
+        @pl.when(change)
+        def _():
+            # ANOTHER block's rows: the copy in may pass the copy out
+            pl.when(held >= 0)(lambda: put_back(held, pending))
+            block_copy(b0, False).start()
+            block_copy(b0, False).wait()
+            blkf[...] = blk[0].astype(f32)
+
+        pending = jnp.where(change, (held >= 0).astype(jnp.int32), pending)
+        held = jnp.where(change, b0, held)
+
+        e = row0_ref[i] - start_of(i)
+        n = length_ref[i]
+        keep = real & (fresh_ref[i] == 0)
+        at = jax.lax.broadcasted_iota(jnp.int32, (frame, 1), 0)
+        live = (at >= e) & (at < e + n)
+
+        def strip(s, _):
+            ls = pl.ds(pl.multiple_of(s * lanes, lanes), lanes) \
+                if lanes % 128 == 0 else slice(None)
+            xf[8:, :] = xw[par, :, ls].astype(f32)
+            # the tail over the rows before the piece's first, then the
+            # last taps - 1 inputs behind row n: the old tail's where n is
+            # shorter than it
+            for j in range(k1):
+                xf[pl.ds(8 - k1 + e + j, 1), :] = jnp.where(
+                    keep, blkf[j, pl.ds(row, 1), ls], 0.0)
+
+            @pl.when(real)
+            def _():
+                for j in range(k1):
+                    blkf[j, pl.ds(row, 1), ls] = xf[
+                        pl.ds(8 - k1 + e + n + j, 1), :]
+
+            acc = sum(w_ref[j:j + 1, ls] * xf[8 - k1 + j:8 - k1 + j + frame, :]
+                      for j in range(taps))
+            y = jax.nn.silu(acc + b_ref[:, ls] if bias else acc)
+            yw[par, :, ls] = jnp.where(live, y, 0.0)
+            return 0
+
+        jax.lax.fori_loop(0, ct // lanes, strip, 0)
+        # the piece before has landed: the tiles it shares are HBM's now
+        pl.when(i >= 1)(lambda: y_wait(i - 1, 1 - par))
+        first, m = span(i)
+        reads = edge_copies(i)
+        reads[0].start()
+        pl.when(m > 1)(reads[1].start)
+        reads[0].wait()
+        rows = pl.ds(pl.multiple_of(first * 8, 8), 8)
+        yw[par, rows, :] = yw[par, rows, :] + edge[0]
+
+        @pl.when(m > 1)
+        def _():
+            reads[1].wait()
+            rows = pl.ds(pl.multiple_of((first + m - 1) * 8, 8), 8)
+            yw[par, rows, :] = yw[par, rows, :] + edge[1]
+
+        for on, copy in y_copies(i, par):
+            pl.when(on)(copy.start)
+        return held, pending
+
+    held, pending = jax.lax.fori_loop(
+        0, count, piece, (jnp.int32(-1), jnp.int32(0)))
+
+    @pl.when(count >= 1)
+    def _():
+        y_wait(count - 1, jax.lax.rem(count - 1, 2))
+
+    @pl.when(held >= 0)
+    def _():
+        put_back(held, pending)
+        block_copy(held, True).wait()
+
+
+def piece_frame(chunk):
+    """The rows a piece of at most ``chunk`` is seen through: whole tiles of
+    either dtype from the tile its first row stands in."""
+    return -(-chunk // 16) * 16 + 16
+
+
+def _piece_channel_bytes(frame, itemsize, taps, slots=PIECE_SLOTS):
+    """What a grid step of the pieces' kernel holds a channel: two frames
+    of ``x`` and of the results, the two edge tiles, a block of the pool in,
+    out and in float32, the taps and the bias in two buffers."""
+    return 2 * frame * (itemsize + 4) + 2 * 8 * 4 \
+        + (taps - 1) * slots * (2 * itemsize + 4) + 2 * (taps + 1) * 4
+
+
+def conv_pieces_tile(channels, frame, itemsize, taps, most=None):
+    """``(channels a grid step, lanes a strip)`` of the pieces' kernel: the
+    most whole lane tiles that divide the channels and whose buffers stay
+    under PIECES_TILE_BYTES (all of them where 128 does not divide)."""
+    if channels % 128:
+        return channels, channels
+    most = most or PIECES_TILE_BYTES // _piece_channel_bytes(frame, itemsize,
+                                                             taps)
+    ct = 128 * _divisor(channels // 128, most // 128)
+    return ct, 128 * _divisor(ct // 128, PIECE_LANES // 128)
+
+
+def _conv_pieces_pallas(x, w, bias, conv, layer, pieces, chunk,
+                        interpret=False, channels=None, lanes=None):
+    """:func:`conv_pieces` as ONE ``pallas_call`` over all the live pieces
+    (``conv_pieces``): ``x`` stays in HBM as it came, the pool is aliased
+    to the output, ``out`` is written over zeros (above)."""
+    row0, length, slots, fresh, count = pieces
+    t, c = x.shape
+    taps, total = w.shape[0], conv.shape[2]
+    if total > PIECE_SLOTS and (total - 1) % PIECE_SLOTS:
+        # a slot beyond the pool's last whole block: no aligned copy holds it
+        return _conv_pieces_xla(x, w, bias, conv, layer, pieces, chunk)
+    f32, i32 = jnp.float32, jnp.int32
+    frame = piece_frame(chunk)
+    # whole tiles of rows, and a frame at least
+    tp = max(-(-t // 16) * 16, frame)
+    x = x.astype(conv.dtype)
+    if tp != t:
+        x = jnp.pad(x, ((0, tp - t), (0, 0)))
+    size = conv.dtype.itemsize
+    ct, strip = conv_pieces_tile(c, frame, size, taps, channels)
+    strip = lanes or strip
+    held_slots = min(PIECE_SLOTS, total)
+    tile = lambda ci, *_: (0, ci)                     # noqa: E731
+    ops = [w.astype(f32)]
+    specs = [pl.BlockSpec((taps, ct), tile)]
+    if bias is not None:
+        ops.append(bias.astype(f32).reshape(1, c))
+        specs.append(pl.BlockSpec((1, ct), tile))
+    in_hbm = pl.BlockSpec(memory_space=pl.ANY)
+    held = ct * _piece_channel_bytes(frame, size, taps, held_slots) \
+        + (8 + frame) * strip * 4
+    out, conv = pl.pallas_call(
+        functools.partial(_conv_pieces_kernel, taps=taps,
+                          bias=bias is not None, lanes=strip),
+        out_shape=[jax.ShapeDtypeStruct((tp, c), f32),
+                   jax.ShapeDtypeStruct(conv.shape, conv.dtype)],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=6, grid=(c // ct,),
+            in_specs=specs + [in_hbm] * 3, out_specs=[in_hbm, in_hbm],
+            scratch_shapes=[
+                pltpu.VMEM((2, frame, ct), conv.dtype),
+                pltpu.VMEM((8 + frame, strip), f32),
+                pltpu.VMEM((2, frame, ct), f32),
+                pltpu.VMEM((2, 8, ct), f32),
+                pltpu.VMEM((2, taps - 1, held_slots, ct), conv.dtype),
+                pltpu.VMEM((taps - 1, held_slots, ct), f32),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((2, (frame // 8).bit_length())),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SemaphoreType.DMA((2,))]),
+        # operands count the scalar-prefetch six: out's zeros and the pool
+        # are the last two
+        input_output_aliases={7 + len(ops): 0, 8 + len(ops): 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=min(max(held + (16 << 20), 32 << 20),
+                                 100 << 20)),
+        interpret=interpret, name="conv_pieces",
+    )(jnp.asarray(layer, i32).reshape(1), row0.astype(i32),
+      length.astype(i32), slots.astype(i32), fresh.astype(i32),
+      jnp.asarray(count, i32).reshape(1), *ops, x,
+      jnp.zeros((tp, c), f32), conv)
+    return out[:t], conv
+
+
+CONV_PIECES = {
+    "xla": _conv_pieces_xla,
+    "pallas": _conv_pieces_pallas,
+    "pallas_interpret": functools.partial(_conv_pieces_pallas,
+                                          interpret=True),
+}
+
+
+def conv_pieces(x, w, bias, conv, layer, pieces, chunk, form=None):
+    """The convolution alone over the pieces of a flat batch (``pieces`` as
+    :func:`chunked_scan` takes them: of at most ``chunk`` rows, on rows of
+    their own), before a mixer's recurrence. ``x`` [T, channels]; a piece
+    of ``n`` rows stands behind the tail of its slot (zeros where it is
+    ``fresh``) and leaves the last ``kernel - 1`` inputs behind row ``n``
+    there. ``form``: one of :data:`CONV_PIECES` (None: by platform; the
+    serving forwards resolve theirs through the engine's
+    ``module_registry``, kind ``conv_pieces``). -> ``(out [T, channels]
+    float32, zero where no piece lies; conv)``."""
+    return (form or CONV_PIECES[default_impl()])(x, w, bias, conv, layer,
+                                                 pieces, chunk)
 
 
 def _split_xbc(out, cfg):

@@ -162,10 +162,11 @@ class Harness:
         eng.flush([2, 3])
 
     def check_the_tails_kernel_is_the_xla_form(self, built, monkeypatch):
-        """The one-token rows' convolution through ``conv_tail_step`` (put
-        first in the registry, interpreted) against the XLA form, engine
-        beside engine: a prompt, a mixed round (A's one-token row beside B's
-        pieces, ``ragged_forward``), two decode rounds (``decode_forward``,
+        """The one-token rows' convolution through ``conv_tail_step`` and the
+        pieces' through ``conv_pieces`` (both put first in the registry,
+        interpreted) against the XLA forms, engine beside engine: a prompt,
+        a mixed round (A's one-token row beside B's pieces,
+        ``ragged_forward``), two decode rounds (``decode_forward``,
         two rows on the sink): the same logits and the same pools in every
         slot but the sink, the first state layer's tails bit for bit (what it
         is handed has passed through no convolution; the later layers'
@@ -194,11 +195,13 @@ class Harness:
             return np.stack(rows), [np.asarray(p) for p in eng.kv.state]
 
         want, pools = drive()
-        first = dataclasses.replace(
-            reg.get_impl("conv_step", "pallas_interpret"), name="first",
-            priority=100, auto_eligible=lambda ctx: True)
-        monkeypatch.setitem(reg._REGISTRY["conv_step"], "first", first)
-        assert model_v2._conv_step_fn() is first.fn
+        for kind, resolved in (("conv_step", model_v2._conv_step_fn),
+                               ("conv_pieces", model_v2._conv_pieces_fn)):
+            first = dataclasses.replace(
+                reg.get_impl(kind, "pallas_interpret"), name="first",
+                priority=100, auto_eligible=lambda ctx: True)
+            monkeypatch.setitem(reg._REGISTRY[kind], "first", first)
+            assert resolved() is first.fn
         got, pools_k = drive()
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
         np.testing.assert_allclose(pools_k[0][:, :-1], pools[0][:, :-1],

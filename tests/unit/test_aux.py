@@ -531,6 +531,67 @@ class TestTuneChainTimer:
                                     "kernel_32x128")] == [1.0, 2.0, 3.0]
 
 
+    def test_the_conv_sweep_of_the_pieces_holds_both_forms(
+            self, tune, monkeypatch, capsys):
+        """``tpu_tune.py conv --pieces`` at a tiny cell, the kernel
+        interpreted and the profiler's reading stubbed: ``--parity`` holds
+        BOTH forms against the plain convolution of each whole sequence
+        over a ragged round and one of short pieces (a hand-over inside a
+        slot and inside a block, a frame that would pass the batch's end),
+        the tails they leave and every other slot bit for bit; the table
+        has the XLA loop beside the kernel under the rule's own tile and
+        every (channels, strip) candidate, over eight slots' pieces, one
+        slot's chunk and the ragged round, each with its bytes' floor."""
+        import functools
+        import json
+
+        from deepspeedsyclsupport_tpu.ops import ssm
+
+        monkeypatch.setattr(tune, "CONV_CELLS", {"tiny": dict(
+            layers=3, slots=49, rows=12, channels=256, bias=True)})
+        monkeypatch.setattr(tune, "CONV_MIXED", {"tiny": dict(
+            tokens=96, chunk=16, most=9)})
+        monkeypatch.setitem(ssm.CONV_PIECES, "pallas", functools.partial(
+            ssm._conv_pieces_pallas, interpret=True))
+
+        def reading(steps, args, carry=None, **_kw):
+            for step in steps.values():
+                carry, _out = step(carry, *args)
+            return {name: {"kernel": 0.5, "xla": 0.25,
+                           "calls": {"kernel": 3}}
+                    if "kernel" in name else {"xla": 1.5, "calls": {}}
+                    for name in steps}
+
+        monkeypatch.setattr(tune, "_traced_kernels", reading)
+        tune.conv(["--pieces", "--cell", "tiny", "--parity", "--channels",
+                   "128", "--strip", "128"])
+        out = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
+        parity = [r for r in out if r["section"] == "conv_pieces_parity"]
+        assert [(r["layout"], r["form"]) for r in parity] == [
+            ("ragged", "xla"), ("ragged", "pallas"), ("short", "xla"),
+            ("short", "pallas")]
+        assert all(r["out_err"] < 1e-5 and r["out_max"] > 1
+                   and r["tails_differ"] == 0 and r["others_differ"] == 0
+                   for r in parity)
+        tables = [r for r in out if r["section"] == "conv_pieces"]
+        assert [t["layout"] for t in tables] == ["eight_slots", "one_slot",
+                                                 "ragged"]
+        for t in tables:
+            assert not t["failed"] and t["rule"] == {"channels": 256,
+                                                     "strip": 256}
+            name = t["layout"]
+            assert set(t["rows"]) == {
+                f"{name}_{form}" for form in (
+                    "pieces_xla", "kernel_tree", "kernel_128x128")}
+            moved = t["rows_live"] * 256 * 6 + t["pieces"] * 2 * 3 * 256 * 2
+            assert t["floor_us_a_piece"] == round(
+                1e6 * moved / tune.V5E_HBM / t["pieces"], 2)
+            assert t["rows"][f"{name}_kernel_tree"]["us_a_piece"] == round(
+                750 / t["pieces"], 2)
+            assert t["rows"][f"{name}_pieces_xla"]["ms_a_forward"] == round(
+                1500 / t["pieces"] * 9 / 1e3, 3)
+        assert (tables[0]["pieces"], tables[1]["pieces"]) == (6, 6)
+
     def test_the_kda_sweep_runs_both_forms_of_the_pieces(
             self, tune, monkeypatch, capsys):
         """``tpu_tune.py kda`` at a tiny cell, the kernels interpreted and

@@ -952,32 +952,30 @@ def test_the_convolutions_tail_compiles_at_the_cells_widths(one_chip, cell,
     by its own name over blocks of whole sublane tiles of slots (the chip's
     compiler refuses a one-row copy of the pool), the pool is aliased (held
     once) and nothing of its size is a temporary, alone (``decode``) and
-    behind the pieces' loop, which reads and writes the same pool on other
-    slots in XLA (``mixed``: a layout of the pool's that the kernel alone
-    asked for would be one more copy of it)."""
+    behind the pieces' kernel (``mixed``: ``conv_pieces``, one call over
+    all of a round's pieces, which reads and writes the same pool on other
+    slots in blocks of the pool's own layout: NO copy of the pool, where
+    the loop of XLA it replaced turned the whole pool taps-minor once a
+    piece, and no ``[T + chunk, channels]`` float32 updated a piece)."""
     from deepspeedsyclsupport_tpu.ops import ssm
 
     layers, slots, c, rows, bias, t, pieces, chunk = CONV_CELLS[cell]
     held = layers * 3 * slots * c * 2
     assert ssm.conv_tile(rows, c) == (16, min(c, 8192))
+    # all of the channels a grid step, strips of 256 lanes
+    assert ssm.conv_pieces_tile(c, ssm.piece_frame(chunk), 2, 4) == (c,
+                                                                     256)
 
-    def program(step):
-        def f(x, w, b, conv, at, keep, row0, length, slot, fresh, count,
-              dec):
-            b = b if bias else None
-            if entry == "mixed":
-                out, conv = ssm.conv_pieces(
-                    x, w, b, conv, 1, (row0, length, slot, fresh, count),
-                    chunk)
-                x = x[dec]
-            one, conv = ssm.conv_step(x, w, b, conv, 1, at, keep,
-                                      ssm.CONV_STEPS[step])
-            return (one if entry == "decode" else out.at[dec].set(one)), conv
-        return jax.jit(f, donate_argnums=3).lower(*args).compile()
-
-    def pool_copies(text):
-        return [ln for ln in text.splitlines() if re.search(
-            rf"= bf16\[{layers},3,{slots},{c}\]\S* copy(-start)?\(", ln)]
+    def f(x, w, b, conv, at, keep, row0, length, slot, fresh, count, dec):
+        b = b if bias else None
+        if entry == "mixed":
+            out, conv = ssm.conv_pieces(
+                x, w, b, conv, 1, (row0, length, slot, fresh, count), chunk,
+                ssm.CONV_PIECES["pallas"])
+            x = x[dec]
+        one, conv = ssm.conv_step(x, w, b, conv, 1, at, keep,
+                                  ssm.CONV_STEPS["pallas"])
+        return (one if entry == "decode" else out.at[dec].set(one)), conv
 
     n = rows if entry == "decode" else t
     shapes = [((n, c), jnp.bfloat16), ((4, c), jnp.float32),
@@ -988,20 +986,27 @@ def test_the_convolutions_tail_compiles_at_the_cells_widths(one_chip, cell,
                                           ((rows,), jnp.int32)]
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
             for s, dt in shapes]
-    compiled = program("pallas")
+    compiled = jax.jit(f, donate_argnums=3).lower(*args).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
-    calls = [ln for ln in text.splitlines()
+    calls = [ln.split(" = ")[0].split()[-1].split(".")[0]
+             for ln in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
-    assert len(calls) == 1 and "%conv_tail_step" in calls[0], calls
+    assert calls == ["%conv_pieces"] * (entry == "mixed") \
+        + ["%conv_tail_step"], calls
     assert mem.alias_size_in_bytes >= held
-    # the pieces' loop lays the slot it reads out its own way, under the
-    # XLA form as well: the kernel adds no copy of the pool to that
-    assert len(pool_copies(text)) <= len(pool_copies(
-        program("xla").as_text())) == (entry == "mixed")
+    pool_copies = [ln for ln in text.splitlines() if re.search(
+        rf"= bf16\[{layers},3,{slots},{c}\]\S* copy(-start)?\(", ln)]
+    assert not pool_copies, pool_copies
     if entry == "decode":
         # the token's float32 copy and the row at each slot: no pool
         assert mem.temp_size_in_bytes < held // 2, mem.temp_size_in_bytes
-
+    else:
+        # the results once (aliased to the zeros they are written over) and
+        # the one-token rows' operands: not the pool, not [T + chunk,
+        # channels] float32 beside the results
+        assert mem.temp_size_in_bytes < min(held, (t + chunk) * c * 4), \
+            mem.temp_size_in_bytes
+        assert not re.search(rf"f32\[{t + chunk},{c}\]", text)
 
 
 # ------------------- blocks chosen from pooled keys beside a lightning state

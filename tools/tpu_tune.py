@@ -44,8 +44,16 @@ Sections, cheapest first:
             channels a tile) beside the XLA form (the tree's, and ``--parent
             DIR``'s ``conv_step`` as it stands), ms a layer and the tail's
             GB/s against 819; ``--parity`` holds the kernel against the XLA
-            form on the chip over several steps:
+            form on the chip over several steps. ``--pieces``: the PIECES'
+            convolution instead (768 rows in pieces of 64 | 512 in pieces of
+            128: a mixed forward's), the XLA loop beside the kernel
+            ``conv_pieces`` at each of ``--channels`` channels a grid step x
+            ``--strip`` lanes a strip, us a piece and a forward's worth
+            beside the bytes' floor, over eight pieces of eight slots, a
+            chunk of ONE slot and a ragged round; with ``--parity`` both
+            forms against a plain convolution of each whole sequence:
             conv [--parent DIR] [--slots N ...] [--lanes N ...] [--parity]
+            conv --pieces [--channels N ...] [--strip N ...] [--parity]
 
   combine — the experts' combine ALONE at the served sparse cells'
             (experts a token, model width, experts held: read off
@@ -1234,6 +1242,13 @@ CONV_CELLS = {
     "nemo3-reason-sat": dict(layers=12, slots=129, rows=128, channels=6144,
                              bias=True),
 }
+# ... and the pieces': a mixed round's rows, a piece's rows at most, the most
+# pieces a forward can hold (``ragged.tile_places``)
+CONV_MIXED = {"solar2-agent-sat": dict(tokens=768, chunk=64, most=269),
+              "nemo3-reason-sat": dict(tokens=512, chunk=128, most=133),
+              }
+CONV_PIECE_CHANNELS = (0,)      # channels a grid step (0: the rule's own)
+CONV_PIECE_STRIPS = (0,)        # lanes a strip (0: the rule's own)
 CONV_TAPS = 4
 CONV_SLOTS = (16, 32, 64)       # slots a grid step of the tail's kernel takes
 CONV_LANES = (2048, 4096, 8192)             # ... and channels
@@ -1304,6 +1319,159 @@ def _conv_parity(tree, cell, c):
          tails_differ=int(jnp.sum(got["xla"][1] != got["pallas"][1])))
 
 
+def _conv_piece_rows(c, t, seed=0):
+    """``(x [t, channels] bfloat16, w, bias)`` of a mixed round."""
+    ks = jax.random.split(jax.random.PRNGKey(seed + 1), 3)
+    ch = c["channels"]
+    return (jax.random.normal(ks[0], (t, ch), jnp.bfloat16),
+            0.5 * jax.random.normal(ks[1], (CONV_TAPS, ch)),
+            jax.random.normal(ks[2], (ch,)) if c["bias"] else None)
+
+
+def _conv_piece_table(rows):
+    """``[(row0, length, slot, fresh)]`` as ``conv_pieces`` takes them."""
+    row0, length, slot, fresh = (jnp.asarray(v) for v in zip(*rows))
+    i32 = jnp.int32
+    return (row0.astype(i32), length.astype(i32), slot.astype(i32),
+            fresh.astype(bool), jnp.asarray(len(rows), i32))
+
+
+def _conv_piece_layouts(c, m):
+    """``{name: pieces}`` of a mixed round's ``tokens`` rows: eight whole
+    pieces of eight slots a block apart; the round as ONE slot's chunk;
+    and a ragged round as the agents' traffic sends it (prompts of 11 to
+    ``chunk + 37`` rows on neighbouring slots, a one-token row between
+    them, no piece's first row on a tile's)."""
+    q, t, last = m["chunk"], m["tokens"], c["slots"] - 1
+    table = _conv_piece_table
+    eight = [(i * q, q, (i * 37) % last, i % 2 == 0)
+             for i in range(min(8, t // q))]
+    one = [(i * q, q, 5, i == 0) for i in range(t // q)]
+    ragged, row, slot = [], 3, 17
+    for n in (q + 37, 11, q, 2 * q - 9, 29, q + 1, 5):
+        if row + n > t:
+            break
+        for k in range(0, n, q):
+            ragged.append((row + k, min(q, n - k), slot, k == 0
+                           and slot % 2 == 1))
+        row, slot = row + n + 1, slot + (1 if slot % 3 else 14)
+    return {"eight_slots": table(eight), "one_slot": table(one),
+            "ragged": table(ragged)}
+
+
+def _conv_whole(x, w, bias, pieces, pool, layer, dtype):
+    """The plain convolution of each whole sequence the ``pieces`` cut:
+    ``(out [T, channels] float32, zero where no piece lies; {slot: its
+    last taps - 1 inputs})``, a sequence that is not ``fresh`` behind the
+    tail its slot holds."""
+    row0, length, slot, fresh, count = (np.asarray(v) for v in pieces)
+    k = w.shape[0]
+    f32 = jnp.float32
+    x = x.astype(dtype).astype(f32)
+    out = jnp.zeros(x.shape, f32)
+    tails = {}
+    for i in range(int(count)):
+        s = int(slot[i])
+        before = tails[s] if s in tails and not fresh[i] else (
+            jnp.zeros((k - 1, x.shape[1]), f32) if fresh[i]
+            else pool[layer, :, s].astype(f32))
+        ext = jnp.concatenate([before, x[row0[i]:row0[i] + length[i]]])
+        acc = sum(w[j] * ext[j:j + int(length[i])] for j in range(k))
+        out = out.at[row0[i]:row0[i] + length[i]].set(
+            jax.nn.silu(acc if bias is None else bias + acc))
+        tails[s] = ext[-(k - 1):]
+    return out, tails
+
+
+def _conv_pieces_parity(tree, cell, c, m):
+    """Both forms of the pieces' convolution ON THE CHIP at the cell's
+    width against the plain convolution of each whole sequence, over the
+    ragged round (hand-overs inside a slot and inside a block, pieces of
+    one to ``chunk`` rows, a frame that would pass the batch's end): the
+    results, the tails the pieces leave and every other slot bit for bit."""
+    q, t = m["chunk"], m["tokens"]
+    short = [(0, 1, 2, True), (1, 2, 3, False), (3, 3, 4, False),
+             (6, q - 1, 40, False), (5 + q, q, 40, False),
+             (t - q - 7, q, 41, True), (t - 7, 7, 41, False)]
+    tables = {"ragged": _conv_piece_layouts(c, m)["ragged"],
+              "short": _conv_piece_table(short)}
+    x, w, bias = _conv_piece_rows(c, t, seed=5)
+    for name, pieces in tables.items():
+        pool = _conv_pool(c, seed=3)
+        want, tails = _conv_whole(x, w, bias, pieces, pool, 1, pool.dtype)
+        for form in ("xla", "pallas"):
+            out, new = jax.jit(lambda pool, form=form: tree.conv_pieces(
+                x, w, bias, pool, 1, pieces, q, tree.CONV_PIECES[form]))(
+                    pool)
+            named = sorted(tails)
+            # every slot of layer 1 that no piece names, and the other layers
+            moved = (new != pool).at[1, :, jnp.asarray(named)].set(False)
+            emit("conv_pieces_parity", cell=cell, layout=name, form=form,
+                 pieces=int(pieces[4]), out_max=float(jnp.max(jnp.abs(want))),
+                 out_err=float(jnp.max(jnp.abs(out - want))),
+                 tails_differ=int(sum(jnp.sum(
+                     new[1, :, s].astype(jnp.float32) != tails[s])
+                     for s in named)),
+                 others_differ=int(jnp.sum(moved)))
+
+
+def _conv_pieces(a, tree):
+    """``conv --pieces``: a layer's convolution of a mixed round's pieces
+    ALONE at the two cells' shapes, the XLA loop beside the kernel."""
+    for cell in a.cell:
+        c, m = CONV_CELLS[cell], CONV_MIXED[cell]
+        if a.parity:
+            _conv_pieces_parity(tree, cell, c, m)
+        q, t, ch = m["chunk"], m["tokens"], c["channels"]
+        x, w, bias = _conv_piece_rows(c, t, seed=2)
+        plan = {"pieces_xla": tree.CONV_PIECES["xla"],
+                "kernel_tree": tree.CONV_PIECES["pallas"]}
+        plan.update({
+            f"kernel_{n}x{strip}": functools.partial(
+                tree.CONV_PIECES["pallas"], channels=n or None,
+                lanes=strip or None)
+            for n in a.channels for strip in a.strip if n or strip})
+        for layout, pieces in _conv_piece_layouts(c, m).items():
+            def program(name, form, tag):
+                def step(pool, x, w, bias):
+                    out, pool = tree.conv_pieces(x, w, bias, pool, 1, pieces,
+                                                 q, form)
+                    return pool, out + float(tag)
+                step.__name__ = f"{layout}_{name}"
+                return jax.jit(step, donate_argnums=0)
+
+            pool = _conv_pool(c)
+            steps, failed = {}, {}
+            for tag, (name, form) in enumerate(plan.items()):
+                try:
+                    steps[f"{layout}_{name}"] = program(
+                        name, form, tag).lower(pool, x, w, bias).compile()
+                except Exception as e:             # e.g. over the VMEM limit
+                    failed[name] = str(e).splitlines()[0][:160]
+            rows = _traced_kernels(steps, (x, w, bias),
+                                   kernel_of=lambda text: "kernel",
+                                   carry=pool)
+            n = int(pieces[4])
+            live = int(jnp.sum(pieces[1]))
+            # a piece's rows in and its results out, its slot's tail in and
+            # out
+            moved = live * ch * (2 + 4) + n * 2 * (CONV_TAPS - 1) * ch * 2
+            floor_us = 1e6 * moved / V5E_HBM / n
+            for row in rows.values():
+                # the add of the tag and the zeros out stands on are XLA's
+                us = 1e3 * (row.get("kernel", 0.0) + row["xla"]) / n
+                row.update(us_a_piece=round(us, 2),
+                           ms_a_forward=round(us * m["most"] / 1e3, 3),
+                           floor_pct=round(100 * floor_us / us, 1)
+                           if us else None)
+            emit("conv_pieces", cell=cell, layout=layout, pieces=n,
+                 rows_live=live, chunk=q, floor_us_a_piece=round(floor_us, 2),
+                 rule=dict(zip(("channels", "strip"), tree.conv_pieces_tile(
+                     ch, tree.piece_frame(q), 2, CONV_TAPS))),
+                 rows=rows, failed=failed)
+            del steps
+
+
 def conv(argv=()):
     """The one-token rows' convolution ALONE at the two cells' shapes, its
     device time read off a profiler trace, every layer's step in one
@@ -1326,7 +1494,14 @@ def conv(argv=()):
     ap.add_argument("--slots", type=int, nargs="*", default=list(CONV_SLOTS))
     ap.add_argument("--lanes", type=int, nargs="*", default=list(CONV_LANES))
     ap.add_argument("--parity", action="store_true")
+    ap.add_argument("--pieces", action="store_true")
+    ap.add_argument("--channels", type=int, nargs="*",
+                    default=list(CONV_PIECE_CHANNELS))
+    ap.add_argument("--strip", type=int, nargs="*",
+                    default=list(CONV_PIECE_STRIPS))
     a = ap.parse_args(list(argv))
+    if a.pieces:
+        return _conv_pieces(a, tree)
     parent = a.parent and _load_op(
         a.parent, "ssm", "deepspeedsyclsupport_tpu.ops.ssm_parent")
     for cell in a.cell:
