@@ -290,7 +290,7 @@ def train_flops_per_token(a, seq):
     return 6 * matmul_params(a) + selected + scored
 
 
-# what the readers of the dsa_* rooflines count (benchmark/metrics/dsa_*.py)
+# what the rooflines of the selection count (``layer_kinds`` below)
 def selected_attention_work(a, sel_pairs, ctx_tokens, itemsize=2):
     """``(FLOPs, bytes)`` the attention over atoms needs in all layers for
     ``sel_pairs`` (row, selected token) pairs: both products of every head,
@@ -315,3 +315,95 @@ def index_work(a, attn_pairs, dec_ctx_tokens, itemsize=2):
     return (a["num_layers"] * attn_pairs
             * a["index_heads"] * a["index_head_dim"] * 2,
             a["num_layers"] * dec_ctx_tokens * a["index_head_dim"] * itemsize)
+
+
+# ------------------------------ the kind of layer it has: a selection
+# (``benchmark.reference.layer_kind``; the loops' conditions below stood in
+# ``metrics/dsa_{share_pct,index_roofline,prefill_roofline,decode_roofline}
+# .py`` until PR 62)
+SCOPES = ("dsa_index", "dsa_select", "dsa_attend")
+# the three Pallas kernels, by the names they carry
+KERNELS = (("dsa_index_scores", "dsa_index"), ("dsa_select", "dsa_select"),
+           ("dsa_prefill", "dsa_attend"))
+
+
+def layer_kinds():
+    """Every layer's attention reads keys a learned indexer SELECTED.
+    ``dsa_index``: the indexer's projections, norm, rotary, pool write, the
+    gather of a sequence's keys through its block table and the scores;
+    ``dsa_select``: the selection; ``dsa_attend``: whatever gathers, masks
+    and attends over the selected keys (``dsa_rows``, the one-token rows'
+    part, inside it)."""
+    return {"selection": {
+        "scopes": SCOPES, "kernels": KERNELS,
+        "roles": {"score": ("dsa_index",), "select": ("dsa_select",),
+                  "attend": ("dsa_attend",)},
+        "score": {"scopes": ("dsa_index",),
+                  "kernels": (("dsa_index_scores", "dsa_index"),),
+                  "work": score_work},
+        "prefill": {"scopes": ("dsa_attend", "dsa_rows"),
+                    "kernels": (("dsa_prefill", "dsa_attend"),),
+                    "work": prefill_work},
+        "rows": {"scopes": ("dsa_rows",), "kernels": (),
+                 "work": rows_work}}}
+
+
+def score_work(obs):
+    """The indexer's floor, a traced round of either program: the one-token
+    rows read their whole context as indexer keys (``dec_ctx_tokens`` of the
+    ``round`` record x 128 B a token and layer) over the HBM bandwidth, PLUS
+    the prompt chunks' scores' FLOPs (``attn_pairs`` x 16 heads x 64 x 2 a
+    layer) through ``flops.roofline_seconds`` (``index_work``); against the
+    time under ``dsa_index``."""
+    a, peaks = arch(obs["config"]), obs["peaks"]
+
+    def work(d, _counted, seconds):
+        pairs, ctx = d.get("attn_pairs", 0), d.get("dec_ctx_tokens", 0)
+        took = seconds.get("dsa_index")
+        if not (pairs or ctx) or "sel_pairs" not in d or not took:
+            return None
+        ops_needed, bytes_needed = index_work(a, pairs, ctx)
+        return (flops.roofline_seconds(ops_needed, 0, peaks)[0]
+                + bytes_needed / peaks["hbm_bytes_per_s"], took)
+    return work
+
+
+def prefill_work(obs):
+    """The attention over atoms' floor, a traced ``ragged_forward`` round:
+    for every (row, SELECTED token) pair of the prompt chunks (``sel_pairs``
+    of the ``round`` record) and every head both products, ``4 x head_dim``
+    FLOPs, in each layer; and the chunk's context read once as K and V (the
+    record has no per-chunk contexts, so their floor: a chunk of n <=
+    ``max_tokens_per_batch`` rows that covers P pairs of ``attn_pairs``
+    reads at least P / n rows): ``selected_attention_work`` through
+    ``flops.roofline_seconds``; against the time under ``dsa_attend`` that
+    is not the one-token rows' (``dsa_rows``)."""
+    a, peaks = arch(obs["config"]), obs["peaks"]
+    chunk = obs["engine"].config.max_tokens_per_batch
+
+    def work(d, _counted, seconds):
+        pairs, took = d.get("sel_pairs"), seconds.get("dsa_attend")
+        if d["program"] != "ragged_forward" or not pairs or not took:
+            return None
+        return (flops.roofline_seconds(
+            *selected_attention_work(a, pairs, d.get("attn_pairs", 0) / chunk),
+            peaks)[0], took)
+    return work
+
+
+def rows_work(obs):
+    """The one-token rows' floor, a traced round of EITHER program (every
+    row of a ``decode_forward``, the one-token chunks of a mixed
+    ``ragged_forward``): K and V of every SELECTED token (``dec_sel_tokens``
+    of the ``round`` record; 2,048 B a token and layer at 4 kv heads of 128
+    in bf16) over the HBM bandwidth (``selected_rows_bytes``); against the
+    time under ``dsa_rows``: the gather of the selected rows through the
+    block table and the attention over them."""
+    a, peaks = arch(obs["config"]), obs["peaks"]
+
+    def work(d, _counted, seconds):
+        tokens, took = d.get("dec_sel_tokens"), seconds.get("dsa_rows")
+        if not tokens or not took:
+            return None
+        return selected_rows_bytes(a, tokens) / peaks["hbm_bytes_per_s"], took
+    return work

@@ -208,3 +208,76 @@ def train_flops_per_token(a, seq):
     f, d = features(a), a["head_dim"]
     step = 2 * (a["num_heads"] + a["num_kv_heads"]) * f * (d + 1)
     return 6 * matmul_params(a) + 3 * step * a["num_layers"]
+
+
+# --------------------------- the kind of layer it has: recurrent state
+# (``benchmark.reference.layer_kind``; the counts below stood in
+# ``metrics/ret_{share_pct,decode_roofline,chunk_roofline}.py`` until PR 62)
+def layer_kinds():
+    """Every layer's power retention is RECURRENT STATE. Its share counts
+    the ``ret_proj`` (the q, k, v and output projections with their norms
+    and rotary), ``ret_gate`` (the gate's projection) and ``ret_scan`` (the
+    recurrence: the in-place decode step, the chunked form's pieces) scopes;
+    the one-token rows' step is what lies under ``ret_scan`` in a decode
+    forward (``S`` and ``z`` in their dtype and the program's own layout a
+    slot-layer, ``engine.state_stats()``; the rows' expanded features are
+    not counted), its pieces the record's ``ret_pieces``; the chunked form's
+    pieces lie under ``ret_chunk``."""
+    return {"recurrent_state": {
+        "share_scopes": ("ret_proj", "ret_gate", "ret_scan"),
+        "step_scopes": ("ret_scan",),
+        "step_pieces": "ret_pieces",
+        "chunk_scopes": ("ret_chunk",),
+        "chunk_work": chunk_work}}
+
+
+def pieces_of(record, layers):
+    """``(rows, pieces, first)`` of the chunks of two tokens or more in the
+    forward a ``round`` record launched: rows through EACH layer (``ret_rows
+    - decode_rows``: a one-token chunk is a decode row), pieces
+    (``ret_pieces``, summed over the layers, a one-token chunk one piece,
+    less ``decode_rows`` x the layers) and those of them that start a
+    sequence (``ret_first``) in ONE layer. None where the record lacks a
+    count."""
+    rows, pieces = record.get("ret_rows"), record.get("ret_pieces")
+    ones = record.get("decode_rows")
+    if rows is None or pieces is None or ones is None:
+        return None
+    return (rows - ones, pieces // layers - ones,
+            record.get("ret_first", 0) // layers)
+
+
+def pieces_work(a, rows, pieces, first, layers):
+    """``(FLOPs, bytes)`` of one forward's pieces in all ``layers``, what no
+    chunking can avoid: ``pieces`` pieces of ``rows / pieces`` rows each
+    (taken as equal in rows, the least their quadratic part can cost: it is
+    convex in a piece's rows), ``first`` of them with no predecessor: the
+    FLOPs of ``retention_chunk_flops`` (the quadratic part over the causal
+    half, ``phi(Q) S`` only for a piece with a predecessor, the state's
+    update always), the rows in and out (``retention_row_bytes``) and every
+    piece's state, read where it has a predecessor and written always, at
+    its LEAST size (``retention_state_bytes``: the distinct products, not
+    the program's layout)."""
+    each = rows / pieces
+    fl = (first * retention_chunk_flops(a, each, True)
+          + (pieces - first) * retention_chunk_flops(a, each, False))
+    state = retention_state_bytes(a)
+    by = rows * retention_row_bytes(a) + (2 * pieces - first) * state
+    return layers * fl, layers * by
+
+
+def chunk_work(obs):
+    """``record -> (FLOPs, bytes)`` of a forward's pieces (``None`` where it
+    carried none), or ``None`` for an engine without a state pool."""
+    stats = getattr(obs.get("engine"), "state_stats", lambda: None)()
+    if not stats or not stats.get("layers"):
+        return None
+    a = arch(obs["config"])
+    layers = stats["layers"]
+
+    def work(record):
+        rows, pieces, first = pieces_of(record, layers) or (0, 0, 0)
+        if rows <= 0 or pieces <= 0:
+            return None
+        return pieces_work(a, rows, pieces, first, layers)
+    return work

@@ -70,6 +70,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from benchmark import flops
 from benchmark import reference as ref
 
 Q_BLOCK = 64        # queries a block of the reference's attention takes
@@ -458,3 +459,175 @@ def train_flops_per_token(a, seq):
         if seq >= a["dense_len"] else (seq + 1) / 2
     attn = 3 * 4 * a["head_dim"] * a["num_heads"] * n_sparse * read
     return 6 * matmul_params(a) + attn + 3 * la_step_flops(a) * n_light
+
+
+# ------------- the kinds of layer it has: a selection, and recurrent state
+# (``benchmark.reference.layer_kind``; the counts and conditions below stood
+# in ``metrics/{bsa,la}_*.py`` until PR 62)
+BSA_SCOPES = ("bsa_pool", "bsa_score", "bsa_select", "bsa_attend",
+              "bsa_rows")
+# the custom calls: ``name=`` of the selection (ops/sparse_block.py), of the
+# atoms' masked kernel and of the one-token rows' (inference/v2/bsa.py)
+BSA_KERNELS = (("bsa_select", "bsa_select"), ("bsa_prefill", "bsa_attend"),
+               ("bsa_rows", "bsa_rows"))
+# lightning attention's state step runs through Mamba-2's entry
+# (``ssm_state_step``, ``ops/ssm.py``; a program with ``L`` layers has no
+# Mamba-2 layer beside them)
+LA_KERNELS = (("ssm_state_step", "la_step"),)
+
+
+def layer_kinds():
+    """The ``*`` layers' attention reads blocks SELECTED from pooled keys:
+    ``bsa_pool`` (the pooled keys' write) and ``bsa_score`` (their gather a
+    sequence, the scores and the pooling to blocks) score, ``bsa_select``
+    (the selection and the one-token rows' page tables) selects,
+    ``bsa_attend`` (whatever gathers, masks and attends; ``bsa_rows``, the
+    one-token rows' part, inside it) attends. The ``L`` layers' lightning
+    attention is RECURRENT STATE: ``la_proj`` (the four projections in and
+    ``wo`` out), ``la_gate`` (the head norms, the rotation, the output norm
+    and gate) and ``la_scan`` (the recurrence: ``la_step``, the in-place
+    state step, and ``la_chunk``, the chunked form's pieces, inside it)."""
+    return {
+        "selection": {
+            "scopes": BSA_SCOPES, "kernels": BSA_KERNELS,
+            "roles": {"score": ("bsa_pool", "bsa_score"),
+                      "select": ("bsa_select",),
+                      "attend": ("bsa_attend", "bsa_rows")},
+            "score": {"scopes": ("bsa_score",), "kernels": BSA_KERNELS,
+                      "work": score_work},
+            "prefill": {"scopes": ("bsa_attend", "bsa_rows"),
+                        "kernels": BSA_KERNELS, "work": prefill_work},
+            "rows": {"scopes": ("bsa_score", "bsa_select", "bsa_rows"),
+                     "kernels": BSA_KERNELS, "work": rows_work}},
+        "recurrent_state": {
+            "share_scopes": ("la_proj", "la_gate", "la_scan", "la_step",
+                             "la_chunk"),
+            "share_kernels": LA_KERNELS,
+            "step_scopes": ("la_step",),
+            "step_kernels": LA_KERNELS,
+            "step_pieces": "la_pieces",
+            "chunk_scopes": ("la_chunk",),
+            "chunk_work": chunk_work}}
+
+
+def block_scores_work(a, windows, visible_blocks):
+    """``(FLOPs, bytes)`` of the scores of forwards whose rows saw
+    ``windows`` windows and whose tiles ``visible_blocks`` blocks a KV
+    head: every query head's product with every window's pooled key that
+    its row may see, the pooled keys read once a tile (the windows a block x
+    ``bsa_pool_bytes``) and a block score written a (row, KV head, visible
+    block) in float32."""
+    per_block = a["block"] // a["stride"]
+    return (windows * bsa_score_flops(a),
+            visible_blocks * per_block * bsa_pool_bytes(a)
+            + windows // per_block * a["num_kv_heads"] * 4)
+
+
+def score_work(obs):
+    """The block scores' floor, a traced round of either program, from what
+    the DEVICE counted of the forward (the following record's
+    ``bsa_windows`` and ``bsa_visible_blocks``, summed over the sparse
+    layers): ``block_scores_work`` through ``flops.roofline_seconds``;
+    against the time under ``bsa_score``: the gather of a sequence's pooled
+    keys through its block table, the products, the softmax over a row's
+    windows, the sum over a group's heads and the pooling to blocks."""
+    a, peaks = arch(obs["config"]), obs["peaks"]
+
+    def work(_d, counted, seconds):
+        if "bsa_pairs" not in counted or not seconds.get("bsa_score") \
+                or not counted["bsa_windows"]:
+            return None
+        return (flops.roofline_seconds(
+            *block_scores_work(a, counted["bsa_windows"],
+                               counted["bsa_visible_blocks"]), peaks)[0],
+                seconds["bsa_score"])
+    return work
+
+
+def prefill_work(obs):
+    """The attention over atoms' floor, a traced ``ragged_forward`` round:
+    for every (row, KV group, ATTENDED token) of the prompt chunks
+    (``bsa_pairs`` less ``bsa_row_pairs`` of the following record: counted
+    on the device from the selection itself, summed over the sparse layers)
+    the group's heads' two products (``bsa_attend_flops``), and the pages
+    the rows of an atom chose BETWEEN them read once a KV head (``bsa_pages``
+    less ``bsa_row_pages`` x ``bsa_page_bytes``: the union is what the
+    mathematics lets a tile share), through ``flops.roofline_seconds``;
+    against the time under ``bsa_attend`` that is not the one-token rows'
+    (``bsa_rows``). The count is the selection's own, the same whatever
+    route attends."""
+    a, peaks = arch(obs["config"]), obs["peaks"]
+
+    def work(d, counted, seconds):
+        if "bsa_pairs" not in counted:
+            return None
+        pairs = counted["bsa_pairs"] - counted.get("bsa_row_pairs", 0)
+        pages = counted["bsa_pages"] - counted.get("bsa_row_pages", 0)
+        if d["program"] != "ragged_forward" or pairs <= 0 \
+                or not seconds.get("bsa_attend"):
+            return None
+        return (flops.roofline_seconds(
+            pairs * bsa_attend_flops(a), pages * bsa_page_bytes(a),
+            peaks)[0], seconds["bsa_attend"])
+    return work
+
+
+def rows_work(obs):
+    """The one-token rows' floor, a traced ``decode_forward`` round: the
+    pages a row selected as K and V a KV head (``bsa_pages`` of the
+    following record x ``bsa_page_bytes``) and its context's pooled keys
+    (``bsa_windows`` x KV heads x ``bsa_pool_bytes``: 32 B a token and layer
+    where a dense row reads 1,024), over the HBM bandwidth; against the time
+    under ``bsa_score``, ``bsa_select`` and ``bsa_rows``: everything between
+    the pooled keys' write and the output (what a token indexer's ``rows``
+    and ``score`` count between them)."""
+    a, peaks = arch(obs["config"]), obs["peaks"]
+
+    def work(d, counted, seconds):
+        if "bsa_pairs" not in counted or d["program"] != "decode_forward" \
+                or not counted["bsa_pages"] or not seconds:
+            return None
+        need = counted["bsa_pages"] * bsa_page_bytes(a) \
+            + counted["bsa_windows"] * a["num_kv_heads"] * bsa_pool_bytes(a)
+        return need / peaks["hbm_bytes_per_s"], sum(seconds.values())
+    return work
+
+
+def pieces_work(a, rows, pieces, first, layers):
+    """``(FLOPs, bytes)`` of one forward's lightning pieces in all
+    ``layers``, what no chunking can avoid: ``rows`` rows in ``pieces``
+    pieces, ``first`` of them with no predecessor: the recurrence's own
+    FLOPs by the SEQUENTIAL form (``la_step_flops`` a row and layer; a
+    chunked form does more and reads lower), the rows in and out
+    (``la_row_bytes``) and every piece's state (``la_state_bytes``), read
+    where it has a predecessor and written always."""
+    state = la_state_bytes(a)
+    return (layers * rows * la_step_flops(a),
+            layers * (rows * la_row_bytes(a)
+                      + (2 * pieces - first) * state))
+
+
+def chunk_work(obs):
+    """``record -> (FLOPs, bytes)`` of a forward's lightning pieces (the
+    pieces' rows are ``la_rows - decode_rows``, the pieces ``la_pieces``,
+    summed over the layers, a one-token chunk one piece, less
+    ``decode_rows`` x the layers, those that start a sequence ``la_first``;
+    ``None`` where it carried none), or ``None`` for an engine without a
+    state pool."""
+    stats = getattr(obs.get("engine"), "state_stats", lambda: None)()
+    if not stats or not stats.get("layers"):
+        return None
+    a = arch(obs["config"])
+    layers = stats["layers"]
+
+    def work(d):
+        ones = d.get("decode_rows")
+        if ones is None or "la_rows" not in d or "la_pieces" not in d:
+            return None
+        rows = d["la_rows"] - ones
+        pieces = d["la_pieces"] // layers - ones
+        if rows <= 0 or pieces <= 0:
+            return None
+        return pieces_work(a, rows, pieces, d.get("la_first", 0) // layers,
+                           layers)
+    return work

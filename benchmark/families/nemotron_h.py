@@ -38,6 +38,8 @@ are computed.
 *``*``:* grouped-query causal softmax attention at ``head_dim^-1/2``, no
 bias, no rotary.
 """
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -335,3 +337,87 @@ def train_flops_per_token(a, seq):
     pairs = seq * (seq + 1) // 2
     attn = 3 * 4 * a["head_dim"] * a["num_heads"] * n["*"] * pairs / seq
     return 6 * matmul_params(a) + attn + 3 * ssm_scan_flops(a) * n["M"]
+
+
+# --------------------------- the kind of layer it has: recurrent state
+# (``benchmark.reference.layer_kind``; the counts below stood in
+# ``metrics/ssm_{share_pct,decode_roofline,chunk_roofline}.py`` until PR 62)
+def layer_kinds():
+    """The ``M`` layers are RECURRENT STATE. Their share counts the
+    ``ssm_proj`` (in and out projections), ``ssm_conv`` (the depthwise
+    convolution and its tail in the state pool), ``ssm_scan`` (the recurrence
+    with its ``D`` skip: the in-place decode step, the chunked scan's pieces)
+    and ``ssm_gate`` (the gate and its grouped norm) scopes; the one-token
+    rows' step is what lies under ``ssm_conv`` and ``ssm_scan`` in a decode
+    forward, its pieces the record's ``ssm_pieces``; the chunked scan's
+    pieces lie under ``ssm_chunk`` (inside ``ssm_scan``)."""
+    return {"recurrent_state": {
+        "share_scopes": ("ssm_proj", "ssm_conv", "ssm_scan", "ssm_gate"),
+        "step_scopes": ("ssm_conv", "ssm_scan"),
+        "step_pieces": "ssm_pieces",
+        "slot_layer_bytes": slot_layer_bytes,
+        "chunk_scopes": ("ssm_chunk",),
+        "chunk_work": chunk_work}}
+
+
+def slot_layer_bytes(obs):
+    """Bytes of one sequence's state in ONE Mamba layer as the engine holds
+    it (``engine.state_stats()``: the SSM state in its dtype and the
+    convolution's tail), or None."""
+    stats = getattr(obs.get("engine"), "state_stats", lambda: None)()
+    layers = getattr(getattr(obs.get("engine"), "kv", None), "ssm", None)
+    if not stats or layers is None:
+        return None
+    return stats["bytes_per_slot"] / layers.shape[0]
+
+
+def pool_state_bytes(obs):
+    """One sequence's SSM state in ONE Mamba layer as the engine holds it
+    (``engine.kv.ssm``; the convolution's tail is ``ssm_conv``'s, not
+    counted), or None."""
+    pool = getattr(getattr(obs.get("engine"), "kv", None), "ssm", None)
+    return None if pool is None \
+        else math.prod(pool.shape[2:]) * pool.dtype.itemsize
+
+
+def pieces_of(record, layers):
+    """``(rows, pieces)`` of the chunks of two tokens or more in the forward
+    a ``round`` record launched: rows through EACH Mamba layer (``ssm_rows -
+    decode_rows``: a one-token chunk is a decode row), pieces summed over
+    the ``layers`` of them (``ssm_pieces``, a one-token chunk one piece,
+    less ``decode_rows`` x those layers). None where the record lacks a
+    count."""
+    rows, pieces = record.get("ssm_rows"), record.get("ssm_pieces")
+    ones = record.get("decode_rows")
+    if rows is None or pieces is None or ones is None:
+        return None
+    return rows - ones, pieces - ones * layers
+
+
+def scan_work(a, rows, pieces, per_piece, layers):
+    """``(FLOPs, bytes)`` of one forward's pieces, what no chunking can
+    avoid: ``rows`` rows through each of ``layers`` Mamba layers (the
+    recurrence's own FLOPs, ``ssm_scan_flops``, and the rows in and out of
+    the scan, ``ssm_row_bytes``), ``pieces`` state pieces (summed over the
+    layers already) of ``per_piece`` bytes each, read and written once. The
+    quadratic form inside a piece spends more FLOPs than the recurrence
+    needs and is not counted: a floor."""
+    return (rows * layers * ssm_scan_flops(a),
+            rows * layers * ssm_row_bytes(a) + 2 * pieces * per_piece)
+
+
+def chunk_work(obs):
+    """``record -> (FLOPs, bytes)`` of a forward's pieces (``None`` where it
+    carried none), or ``None`` for an engine without a state pool."""
+    per_piece = pool_state_bytes(obs)
+    if not per_piece:
+        return None
+    a = arch(obs["config"])
+    layers = layer_counts(a)["M"]
+
+    def work(record):
+        rows, pieces = pieces_of(record, layers) or (0, 0)
+        if rows <= 0 or pieces <= 0:
+            return None
+        return scan_work(a, rows, pieces, per_piece, layers)
+    return work

@@ -341,3 +341,79 @@ def train_flops_per_token(a, seq):
     pairs = seq * (seq + 1) // 2
     attn = 3 * 4 * a["head_dim"] * a["num_heads"] * n_attn * pairs / seq
     return 6 * matmul_params(a) + attn + 3 * kda_step_flops(a) * n_kda
+
+
+# --------------------------- the kind of layer it has: recurrent state
+# (``benchmark.reference.layer_kind``; the counts below stood in
+# ``metrics/kda_{share_pct,decode_roofline,chunk_roofline}.py`` until PR 62)
+# the state step's custom call: ``name="kda_state_step"`` (ops/kda.py)
+STEP_KERNELS = (("kda_state_step", "kda_step"),)
+
+
+def layer_kinds():
+    """The delta-rule layers are RECURRENT STATE. Their share counts the
+    ``kda_proj`` (the projections in and out), ``kda_conv`` (the depthwise
+    convolution and its tail), ``kda_gate`` (the decay, beta, the L2 norms,
+    the gated output norm) and ``kda_scan`` (the recurrence: ``kda_step``,
+    the in-place state step, and ``kda_chunk``, the chunked form's pieces,
+    inside it) scopes, the state step's Pallas call by its name; the
+    one-token rows' step is what lies under ``kda_conv`` and ``kda_step`` in
+    a decode forward (the float32 state and the convolution's tail a
+    slot-layer, ``engine.state_stats()``), its pieces the record's
+    ``kda_pieces``; the chunked form's pieces lie under ``kda_chunk``."""
+    return {"recurrent_state": {
+        "share_scopes": ("kda_proj", "kda_conv", "kda_gate", "kda_scan",
+                         "kda_step", "kda_chunk"),
+        "share_kernels": STEP_KERNELS,
+        "step_scopes": ("kda_conv", "kda_step"),
+        "step_kernels": STEP_KERNELS,
+        "step_pieces": "kda_pieces",
+        "chunk_scopes": ("kda_chunk",),
+        "chunk_work": chunk_work}}
+
+
+def pieces_of(record, layers):
+    """``(rows, pieces, first)`` of the chunks of two tokens or more in the
+    forward a ``round`` record launched: rows through EACH layer (``kda_rows
+    - decode_rows``: a one-token chunk is a decode row), pieces
+    (``kda_pieces``, summed over the layers, a one-token chunk one piece,
+    less ``decode_rows`` x the layers) and those of them that start a
+    sequence (``kda_first``) in ONE layer. None where the record lacks a
+    count."""
+    rows, pieces = record.get("kda_rows"), record.get("kda_pieces")
+    ones = record.get("decode_rows")
+    if rows is None or pieces is None or ones is None:
+        return None
+    return (rows - ones, pieces // layers - ones,
+            record.get("kda_first", 0) // layers)
+
+
+def pieces_work(a, rows, pieces, first, layers):
+    """``(FLOPs, bytes)`` of one forward's pieces in all ``layers``, what no
+    chunking can avoid: ``rows`` rows in ``pieces`` pieces, ``first`` of
+    them with no predecessor: the recurrence's own FLOPs by the SEQUENTIAL
+    form (``kda_step_flops`` a row and layer; a chunked form does more and
+    reads lower), the rows in and out (``kda_row_bytes``) and every piece's
+    state (``kda_state_bytes``), read where it has a predecessor and written
+    always."""
+    state = kda_state_bytes(a)
+    return (layers * rows * kda_step_flops(a),
+            layers * (rows * kda_row_bytes(a)
+                      + (2 * pieces - first) * state))
+
+
+def chunk_work(obs):
+    """``record -> (FLOPs, bytes)`` of a forward's pieces (``None`` where it
+    carried none), or ``None`` for an engine without a state pool."""
+    stats = getattr(obs.get("engine"), "state_stats", lambda: None)()
+    if not stats or not stats.get("layers"):
+        return None
+    a = arch(obs["config"])
+    layers = stats["layers"]
+
+    def work(record):
+        rows, pieces, first = pieces_of(record, layers) or (0, 0, 0)
+        if rows <= 0 or pieces <= 0:
+            return None
+        return pieces_work(a, rows, pieces, first, layers)
+    return work

@@ -19,6 +19,39 @@ file and edits nothing here. A family's module gives:
 * ``train_flops_per_token(arch, seq)`` — forward + backward FLOPs a trained
   token needs (``benchmark.flops`` has the pieces).
 
+A module MAY also say what layers of a KIND it has, so that the per-layer
+readers of that kind read its cells and a later family lists no reader of its
+own: ``layer_kinds()`` gives ``{kind: what it says}`` (``layer_kind`` below
+asks; a family without the function, or without the kind, gives the readers
+nothing to read and they return ``None``). The arithmetic of a kind's floors
+(a layer's FLOPs and bytes, which record field counts its pieces) is the
+family's; the loop over the traced rounds is the reader's. The kinds:
+
+* ``"recurrent_state"`` (``metrics/state_*``) — ``share_scopes`` (+
+  ``share_kernels``): the scopes its share of the busy time counts;
+  ``step_scopes`` (+ ``step_kernels``) and ``step_pieces``: the scopes of
+  the one-token rows' state step and the ``round`` record's field that
+  counts the pieces it reads and writes; ``slot_layer_bytes(obs)``: one
+  sequence's state in one layer as the engine holds it (without it:
+  ``engine.state_stats()``'s ``bytes_per_slot / layers``); ``chunk_scopes``
+  (+ ``chunk_kernels``): how the chunked form is found in the trace;
+  ``chunk_work(obs)``: ``None``, or a function of a ``round`` record that
+  gives ``(FLOPs, bytes)`` of the forward's pieces, ``None`` where the
+  record carried none.
+* ``"selection"`` (attention over keys chosen by a scoring pass;
+  ``metrics/select_*``) — ``scopes`` and ``kernels``: everything of the
+  layer in the trace; ``roles``: ``{"score" | "select" | "attend": the
+  labels of that role}``; ``score``, ``prefill``, ``rows``: each ``{"scopes",
+  "kernels", "work"}``, the labels a roofline asks the trace for and
+  ``work(obs)``: ``None``, or a function ``(record, counted, seconds)`` of a
+  traced round that launched a forward (its record, the FOLLOWING record,
+  which holds what the device counted of it, and the seconds under each
+  label inside its execution) that gives ``(least seconds, seconds taken)``
+  or ``None``.
+
+``KERNELS`` are ``((prefix of a custom call's name, label), ...)``
+(``benchmark.scopes``).
+
 This file holds what the families share (norms, rotary embedding, softmax
 attention, the walk over stacked layers) and what decides ``correct`` from
 a family's logits (greedy margins, the LM loss). Departures from the
@@ -30,6 +63,15 @@ import math
 BF16_EPS = 2.0 ** -8
 FAMILY_API = ("arch", "program_widths", "sequence_logits",
               "train_flops_per_token")
+LAYER_KINDS = ("recurrent_state", "selection")
+
+
+def layer_kind(family, kind):
+    """What ``family`` says of its layers of ``kind`` (one of
+    ``LAYER_KINDS``), or ``None``: a family that has no such layer says
+    nothing, and a reader of the kind then has nothing to read."""
+    say = getattr(family, "layer_kinds", None)
+    return say().get(kind) if callable(say) else None
 
 
 def rms_norm(p, x, eps):

@@ -33,15 +33,43 @@ _INSTRUCTION = re.compile(
 
 def instructions_under(hlo_text, labels):
     """``{instruction name: label}`` for every instruction of ``hlo_text``
-    whose ``op_name`` path has one of ``labels`` as a component (the
-    innermost, where scopes nest)."""
+    (or of its ``(name, op_name path)`` pairs, read before) whose ``op_name``
+    path has one of ``labels`` as a component (the innermost, where scopes
+    nest)."""
     out = {}
-    for name, path in _INSTRUCTION.findall(hlo_text):
+    pairs = _INSTRUCTION.findall(hlo_text) if isinstance(hlo_text, str) \
+        else hlo_text
+    for name, path in pairs:
         label = next((part for part in reversed(path.split("/"))
                       if part in labels), None)
         if label:
             out[name] = label
     return out
+
+
+def compiled_programs(obs):
+    """``engine.compiled_programs()``, asked ONCE a run and kept on ``obs``:
+    the engine lowers and compiles every program it ran anew each time it is
+    asked (traced, lowered and loaded from the cache: tens of seconds at a
+    cell's size, which every reader that wanted a scope paid again until PR
+    62; a traced run of ``sala-docs-sat`` read its entries for minutes)."""
+    engine = obs["engine"]
+    kept = obs.get("compiled_programs")
+    if kept is None or kept[0] is not engine:
+        kept = obs["compiled_programs"] = (engine, engine.compiled_programs())
+    return kept[1]
+
+
+def instruction_paths(obs):
+    """``{program: [(instruction name, op_name path), ...]}`` of the compiled
+    programs' texts, kept on ``obs`` beside them."""
+    programs = compiled_programs(obs)
+    kept = obs.get("instruction_paths")
+    if kept is None or kept[0] is not programs:
+        kept = obs["instruction_paths"] = (programs, {
+            program: _INSTRUCTION.findall(compiled.as_text())
+            for program, compiled in programs.items()})
+    return kept[1]
 
 
 def scoped_ops(obs, labels, kernels=()):
@@ -56,8 +84,8 @@ def scoped_ops(obs, labels, kernels=()):
         return None
     key = ("scoped_ops", tuple(labels), tuple(kernels))
     if key not in obs:
-        names = {program: instructions_under(compiled.as_text(), labels)
-                 for program, compiled in engine.compiled_programs().items()}
+        names = {program: instructions_under(pairs, labels)
+                 for program, pairs in instruction_paths(obs).items()}
 
         def label_of(program, text):
             name = trace.op_name(text)

@@ -223,3 +223,26 @@ class Device:
         took = [d for a, d in self.kernels[k:bisect.bisect_right(
             self.kernels, (hi,))]]
         return len(took), sum(took)
+
+
+def floor_share(obs, ops, program, least_s):
+    """100 x the least seconds over the seconds taken, summed over the
+    traced rounds that launched ``program``: ``least_s(record)`` is a
+    round's floor (``None`` or 0: nothing of it in this round), the seconds
+    taken those of ``ops`` (``scopes.scoped_ops``) inside the forward's
+    execution. ``None`` where no round gives both."""
+    dev = Device(obs["trace"])
+    ideal = took = 0.0
+    for d in traced_rounds(obs) or ():
+        least = least_s(d) if d["program"] == program else None
+        ran = least and dev.forward(d["program"], d["t0"], d["t1"])
+        if not ran:
+            continue
+        seconds = sum(dur for _l, launched, start, dur in ops
+                      if launched == program and ran[0] <= start < ran[1])
+        if not seconds:
+            continue
+        ideal += least
+        took += seconds
+    return 100.0 * ideal / took if took else None
+

@@ -14,6 +14,9 @@ Kinds (``"kind"`` in the mix's file):
 * ``closed`` — ``clients`` callers, each sending its next request the
   instant its last one finished; requests cycle through a grid of ``count``
   pairs, reshuffled each cycle and dealt to whichever caller asks next.
+  With ``order_block`` a cycle's order is stratified as an open loop's is
+  (``spread_order``): for a deck of which a window sees a fraction, where
+  the shuffle decides which prompts that fraction holds.
   With ``"order": "lanes"`` every caller walks the whole grid on ONE fixed
   walk and the seed only deals the callers their places on it: for a mix
   whose window holds about one cycle, where the shuffle decides whose long
@@ -97,7 +100,11 @@ class ClosedPlan:
 
     Default: each cycle is the whole grid in a fresh seeded order, dealt to
     whichever caller asks next. Over a window of many cycles every seed's
-    window holds the same work.
+    window holds the same work. Where a window sees a FRACTION of a cycle,
+    ``order_block`` stratifies the order (``spread_order``): every stretch
+    of that many requests holds one of each class of neighbouring prompt
+    lengths, so any window's stretches send about the same prompt tokens
+    under every seed. A cycle still deals every pair once.
 
     ``"order": "lanes"``: for a mix whose window holds about ONE cycle, where
     the shuffle decides whether one or two of the longest prompts end inside
@@ -119,6 +126,7 @@ class ClosedPlan:
         self.rng = np.random.default_rng(seed)
         self._cycle = []
         self._place = None
+        self._block = mix.get("order_block")
         order = mix.get("order", "shuffle")
         if order == "lanes":
             n = len(self.pairs)
@@ -134,7 +142,7 @@ class ClosedPlan:
         at who asks)."""
         if self._place is None:
             if not self._cycle:
-                order = self.rng.permutation(len(self.pairs))
+                order = spread_order(self.rng, len(self.pairs), self._block)
                 self._cycle = [_request(self.rng, self.vocab, *self.pairs[i])
                                for i in order[::-1]]
             return self._cycle.pop()

@@ -15,11 +15,11 @@ from benchmark import spec
 
 CELL, CONFIG, MIX = "keye-video-sat", "keye-vl2-30b-a3b-ep8-d12", \
     "video-32k-sat"
-NEW = ["dsa_share_pct", "dsa_select_share_pct", "dsa_prefill_roofline",
-       "dsa_decode_roofline", "dsa_index_roofline", "dsa_kept_pct",
+NEW = ["select_share_pct", "select_pick_share_pct", "select_prefill_roofline",
+       "select_decode_roofline", "select_score_roofline", "dsa_kept_pct",
        "index_bytes_per_token"]
-ALIASES = {"dsa_select_share_pct": {"reader": "dsa_share_pct",
-                                    "args": {"labels": ["dsa_select"]}},
+ALIASES = {"select_pick_share_pct": {"reader": "select_share_pct",
+                                    "args": {"role": "select"}},
            "serve_tok_s.p95": {"reader": "serve_tok_s"},
            "ragged_fwd_ms.p95": {"reader": "ragged_fwd_ms"},
            "decode_fwd_ms.p95": {"reader": "decode_fwd_ms"},
@@ -439,9 +439,9 @@ def test_the_dsa_readers_on_two_mixed_rounds_and_a_decode_step():
     obs = traced_obs(family)
     busy = 2 * (0.010 + 0.012 + 0.060 + 0.020) + 0.020
     dsa = 2 * (0.010 + 0.012 + 0.060 + 0.015) + 0.015
-    assert bench.reader("dsa_share_pct")(obs) == pytest.approx(
+    assert bench.reader("select_share_pct")(obs) == pytest.approx(
         100 * dsa / busy, rel=1e-6)
-    assert bench.reader("dsa_select_share_pct")(obs) == pytest.approx(
+    assert bench.reader("select_pick_share_pct")(obs) == pytest.approx(
         100 * 2 * 0.012 / busy, rel=1e-6)
     # a 768-row chunk at 40 k: 768 x 2048 selected pairs, 32 heads x 512
     # FLOPs, 12 layers = 0.31 TFLOP = 1.57 ms at peak, against 60 ms of a
@@ -449,16 +449,16 @@ def test_the_dsa_readers_on_two_mixed_rounds_and_a_decode_step():
     pairs = 768 * 40000 + 768 * 769 // 2
     ideal = 12 * 768 * 2048 * 32 * 4 * 128 / 197e12
     assert 12 * (pairs / 768) * 2048 / 819e9 < ideal       # compute-bound
-    assert bench.reader("dsa_prefill_roofline")(obs) == pytest.approx(
+    assert bench.reader("select_prefill_roofline")(obs) == pytest.approx(
         100 * ideal / 0.060, rel=1e-6)
     # one-token rows: 7, 8, 7 rows x 2048 selected x 2,048 B x 12 layers
     need = 22 * 2048 * 2048 * 12 / 819e9
-    assert bench.reader("dsa_decode_roofline")(obs) == pytest.approx(
+    assert bench.reader("select_decode_roofline")(obs) == pytest.approx(
         100 * need / (3 * 0.009), rel=1e-6)
     # the indexer: the rows' contexts as keys + the chunks' scores
     floor = 22 * 30000 * 128 * 12 / 819e9 \
         + 2 * 12 * pairs * 16 * 64 * 2 / 197e12
-    assert bench.reader("dsa_index_roofline")(obs) == pytest.approx(
+    assert bench.reader("select_score_roofline")(obs) == pytest.approx(
         100 * floor / (2 * 0.016 + 0.006), rel=1e-6)
     kept = 4 * (768 + 7) * 2048 + 8 * 2048
     seen = 4 * (pairs + 7 * 30000) + 8 * 30000

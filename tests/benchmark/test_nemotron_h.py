@@ -17,8 +17,8 @@ CELL, CONFIG, MIX = ("nemo3-reason-sat", "nemotron3-nano-ep4-d26",
                      "reason-short-sat")
 REDUCED = ["hybrid_override_pattern", "n_routed_experts",
            "num_hidden_layers", "vocab_size"]
-NEW = ["moe_relu2_roofline", "ssm_share_pct", "ssm_decode_roofline",
-       "ssm_chunk_roofline", "state_bytes_per_seq"]
+NEW = ["moe_relu2_roofline", "state_share_pct", "state_decode_roofline",
+       "state_chunk_roofline", "state_bytes_per_seq"]
 V5E = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 
 
@@ -306,15 +306,15 @@ def test_the_state_readers_on_a_decode_step_with_every_slot_live(family):
     piece = 64 * 64 * 128 * 4 + 3 * 6144 * 2
     ideal = 2 * 12 * 128 * piece / 819e9
     assert ideal == pytest.approx(8.0e-3, rel=0.01)
-    got = bench.reader("ssm_decode_roofline")(obs)
+    got = bench.reader("state_decode_roofline")(obs)
     assert got == pytest.approx(100 * ideal / 0.011, rel=1e-6)
     at_floor = traced_obs(family, "decode_forward", scan_s=ideal, conv_s=0.0)
-    assert bench.reader("ssm_decode_roofline")(at_floor) == pytest.approx(
+    assert bench.reader("state_decode_roofline")(at_floor) == pytest.approx(
         100.0, rel=1e-6)
     # a decode step has no chunk: the chunk reader reads nothing there
-    assert bench.reader("ssm_chunk_roofline")(obs) is None
+    assert bench.reader("state_chunk_roofline")(obs) is None
     busy = 0.003 + 0.001 + 0.010 + 0.012
-    assert bench.reader("ssm_share_pct")(obs) == pytest.approx(
+    assert bench.reader("state_share_pct")(obs) == pytest.approx(
         100 * 0.014 / busy, rel=1e-6)
     assert bench.reader("state_bytes_per_seq")(obs) == 12 * piece
     # two matrices an expert at the published 1856: 352 expert-layers read
@@ -348,23 +348,23 @@ def test_the_chunk_reader_on_a_mixed_round(family):
     by = 641 * 12 * family.ssm_row_bytes(arch) + 2 * 12 * 6 * state
     ideal = max(fl / 197e12, by / 819e9)
     assert ideal == by / 819e9
-    got = bench.reader("ssm_chunk_roofline")(obs)
+    got = bench.reader("state_chunk_roofline")(obs)
     assert got == pytest.approx(100 * ideal / 0.004, rel=1e-6)
     assert 10 < got < 100
     # at the floor itself it reads 100, whatever the one-token rows cost
     at_floor = traced_obs(family, "ragged_forward", tokens=768,
                           pieces=12 * (127 + 6), scan_s=0.020,
                           chunk_s=ideal)
-    assert bench.reader("ssm_chunk_roofline")(at_floor) == pytest.approx(
+    assert bench.reader("state_chunk_roofline")(at_floor) == pytest.approx(
         100.0, rel=1e-6)
     # a mixed round without the scope (this PR's first program): nothing
-    assert bench.reader("ssm_chunk_roofline")(traced_obs(
+    assert bench.reader("state_chunk_roofline")(traced_obs(
         family, "ragged_forward", tokens=768, pieces=12 * 133)) is None
     # the mixers' share counts both, the piece under its inner scope too
     busy = 0.003 + 0.001 + 0.020 + 0.012 + 0.004
-    assert bench.reader("ssm_share_pct")(obs) == pytest.approx(
+    assert bench.reader("state_share_pct")(obs) == pytest.approx(
         100 * 0.028 / busy, rel=1e-6)
-    assert bench.reader("ssm_decode_roofline")(obs) is None
+    assert bench.reader("state_decode_roofline")(obs) is None
 
 
 @pytest.mark.parametrize("name", NEW)

@@ -17,6 +17,7 @@ ALIASES = {"kv_bytes_per_token.tok": {"reader": "kv_bytes_per_token"},
            "paged_loop_roofline": {"reader": "paged_roofline"},
            "loop_exit_share_pct": {"reader": "loop_pass_ms",
                                    "args": {"what": "exit_share_pct"}}}
+RETIRED = "loop_exit_share_pct"
 V5E = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 L = 3
 HF = {"model_type": "ouro", "num_attention_heads": 4, "hidden_size": 64,
@@ -126,7 +127,12 @@ def test_the_benchmark_is_sound_with_the_new_entries():
         "start_to_chip_s", "live_seqs_mean", "ragged_tile_fill_pct",
         "itl_p99_ms.moe", "round_p50_ms.moe", "share_ragged_rounds_pct.moe",
         "serve_program_gib.moe", "decode_fwd_ms.moe", "ragged_fwd_ms.moe",
-        "serve_idle_pct.moe", "launch_ahead_pct", *NEW, *ALIASES}
+        "serve_idle_pct.moe", "launch_ahead_pct",
+        *(set(NEW) | set(ALIASES)) - {RETIRED}}
+    # 0.0035 % on the ledger: it tells nothing until passes are skipped, and
+    # PR 62 retired the entry; the PR that brings skipping lists it again
+    # (the reader and the name's file stay, and the tests below read it)
+    assert RETIRED not in {m["name"] for m in bench.doc["per_layer"]}
     # (no place in ``per_layer`` is held here: a later PR appends behind)
     for m in bench.doc["per_layer"]:
         if m["name"] in ("launch_ahead_pct", *NEW, *ALIASES):

@@ -68,12 +68,15 @@ def test_the_metric_is_declared_for_the_cells_that_report_serve_tok_s():
     bench = spec.Bench()
     entry, = [m for m in bench.doc["per_layer"]
               if m["name"] == "ragged_row_fill_pct"]
+    cells = entry.pop("workloads")
     assert entry == {
         "name": "ragged_row_fill_pct", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "serve engine",
-        "moves": "serve_tok_s",
-        "workloads": ["phi2-decode-sat", "olmoe-chat-sat",
-                      "nemo3-reason-sat", "ouro-reason-sat"]}
+        "moves": "serve_tok_s"}
+    # at least the four it was accepted with: a later cell may join
+    assert set(cells) >= {"phi2-decode-sat", "olmoe-chat-sat",
+                          "nemo3-reason-sat", "ouro-reason-sat"}
+    entry["workloads"] = cells
     for cell in entry["workloads"]:
         assert "serve_tok_s" in {m["name"] for m in
                                  bench.metrics_of(cell, "end_to_end")}

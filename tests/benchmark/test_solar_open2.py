@@ -10,7 +10,7 @@ import pytest
 from benchmark import spec
 
 CELL, CONFIG, MIX = "solar2-agent-sat", "solar-open2-ep8-d4", "agent-steps-sat"
-NEW = ["kda_share_pct", "kda_decode_roofline", "kda_chunk_roofline",
+NEW = ["state_share_pct", "state_decode_roofline", "state_chunk_roofline",
        "kda_piece_rows_mean", "gqa_attn_share_pct"]
 V5E = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 REDUCED = ["gqa_layers", "n_routed_experts", "num_hidden_layers",
@@ -159,7 +159,7 @@ def test_the_benchmark_is_sound_with_the_new_entries():
     assert reports >= {"start_to_chip_s", *NEW}
     for m in bench.doc["per_layer"]:
         if m["name"] in NEW:
-            assert m["workloads"][0] == CELL and m["moves"] == "serve_tok_s"
+            assert CELL in m["workloads"] and m["moves"] == "serve_tok_s"
     assert sum(w["chips"] == 4 for w in bench.doc["workloads"]) == 1
     assert len(bench.doc["per_layer"]) <= 128
 
@@ -314,17 +314,17 @@ def test_the_state_readers_on_a_decode_step_with_every_slot_live(family):
     obs = traced_obs(family, "decode_forward")
     ideal = 2 * L * 256 * SLOT_LAYER / 819e9
     assert ideal == pytest.approx(8.15e-3, rel=2e-3)
-    got = bench.reader("kda_decode_roofline")(obs)
+    got = bench.reader("state_decode_roofline")(obs)
     assert got == pytest.approx(100 * ideal / (0.0033 + 0.0004), rel=1e-6)
     at_floor = traced_obs(family, "decode_forward", step_s=ideal, conv_s=0.0)
-    assert bench.reader("kda_decode_roofline")(at_floor) == pytest.approx(
+    assert bench.reader("state_decode_roofline")(at_floor) == pytest.approx(
         100.0, rel=1e-6)
     # a decode step has no chunk: the chunk readers read nothing there
-    assert bench.reader("kda_chunk_roofline")(obs) is None
+    assert bench.reader("state_chunk_roofline")(obs) is None
     assert bench.reader("kda_piece_rows_mean")(obs) is None
     kda = 0.004 + 0.0004 + 0.001 + 0.0033
     busy = kda + 0.0008 + 0.0002 + 0.010
-    assert bench.reader("kda_share_pct")(obs) == pytest.approx(
+    assert bench.reader("state_share_pct")(obs) == pytest.approx(
         100 * kda / busy, rel=1e-6)
     assert bench.reader("gqa_attn_share_pct")(obs) == pytest.approx(
         100 * 0.0010 / busy, rel=1e-6)
@@ -346,22 +346,22 @@ def test_the_chunk_readers_on_a_mixed_round(family):
     by = L * (300 * family.kda_row_bytes(a) + 9 * STATE)
     ideal = max(fl / 197e12, by / 819e9)
     assert ideal == by / 819e9          # the pieces are bound by the state
-    got = bench.reader("kda_chunk_roofline")(obs)
+    got = bench.reader("state_chunk_roofline")(obs)
     assert got == pytest.approx(100 * ideal / 0.0012, rel=1e-6)
     assert 5 < got < 100
     at_floor = traced_obs(family, "ragged_forward", chunk_s=ideal, **kw)
-    assert bench.reader("kda_chunk_roofline")(at_floor) == pytest.approx(
+    assert bench.reader("state_chunk_roofline")(at_floor) == pytest.approx(
         100.0, rel=1e-6)
     assert bench.reader("kda_piece_rows_mean")(obs) == 300 / 5
     # a mixed round without the inner scope: nothing
-    assert bench.reader("kda_chunk_roofline")(traced_obs(
+    assert bench.reader("state_chunk_roofline")(traced_obs(
         family, "ragged_forward", **kw)) is None
     # the layers' share counts both, the piece under its inner scope too
     kda = 0.004 + 0.0004 + 0.001 + 0.0033 + 0.0012
     busy = kda + 0.0008 + 0.0002 + 0.010
-    assert bench.reader("kda_share_pct")(obs) == pytest.approx(
+    assert bench.reader("state_share_pct")(obs) == pytest.approx(
         100 * kda / busy, rel=1e-6)
-    assert bench.reader("kda_decode_roofline")(obs) is None
+    assert bench.reader("state_decode_roofline")(obs) is None
 
 
 @pytest.mark.parametrize("name", NEW)

@@ -204,6 +204,83 @@ def test_each_stretch_holds_one_request_of_each_length_class():
     assert order != traffic.spread_order(np.random.default_rng(5), 100, 10)
 
 
+# ------------------- a closed deck of which a window sees a part, in strata
+# the two decks ISSUE 62 names, with the key whether or not their files have
+# it (the chip decides that: PERF.md section 2), and the stretches a cycle has
+DECKS = {name: ({**SERVING[name], "order_block": 64}, stretches)
+         for name, stretches in (("agent-steps-sat", 32),
+                                 ("reason-short-sat", 16))}
+
+
+def dealt(mix, seed, cycles=1):
+    plan = traffic.ClosedPlan(mix, seed, vocab=1000)
+    return [(len(r["tokens"]), r["max_new_tokens"])
+            for r in (plan.take(k % mix["clients"])
+                      for k in range(cycles * mix["count"]))]
+
+
+@pytest.mark.parametrize("name", sorted(DECKS))
+def test_a_stratified_closed_deck_deals_every_pair_once_a_cycle(name):
+    mix, _ = DECKS[name]
+    pairs, n = sorted(traffic.length_pairs(mix, mix["count"])), mix["count"]
+    two = dealt(mix, 5, cycles=2)
+    assert sorted(two[:n]) == pairs and sorted(two[n:]) == pairs
+    assert two[:n] != two[n:]              # a fresh order each cycle
+
+
+@pytest.mark.parametrize("name", sorted(DECKS))
+def test_every_stretch_of_a_closed_deck_holds_each_length_class_once(name):
+    """A stretch of ``order_block`` requests holds one prompt of each of 64
+    classes of neighbouring lengths, so its sorted prompts lie class by class
+    inside the grid's (ties between neighbouring classes fit both), and its
+    prompt tokens stand within a few per cent of any other stretch's, where
+    a plain shuffle's stretches stand tens of per cent apart."""
+    mix, stretches = DECKS[name]
+    grid = [p for p, _ in traffic.length_pairs(mix, mix["count"])]
+    assert grid == sorted(grid) and len(grid) == 64 * stretches
+    classes = [grid[k * stretches:(k + 1) * stretches] for k in range(64)]
+    for seed in SEEDS:
+        prompts = [p for p, _ in dealt(mix, seed)]
+        sums = []
+        for j in range(stretches):
+            stretch = sorted(prompts[64 * j:64 * (j + 1)])
+            assert all(c[0] <= p <= c[-1] for p, c in zip(stretch, classes))
+            sums.append(sum(stretch))
+        plain = [p for p, _ in dealt({**mix, "order_block": None}, seed)]
+        plain = [sum(plain[64 * j:64 * (j + 1)]) for j in range(stretches)]
+        assert max(sums) - min(sums) < 0.5 * (max(plain) - min(plain))
+
+
+@pytest.mark.parametrize("name", sorted(DECKS))
+def test_two_seeds_of_a_stratified_closed_deck_offer_the_same_multiset(name):
+    mix, _ = DECKS[name]
+    a, b = dealt(mix, SEEDS[1], 2), dealt(mix, SEEDS[2], 2)
+    assert collections.Counter(a) == collections.Counter(b) and a != b
+    # and the deck does not look at who asks, as every shuffled deck
+    one = traffic.ClosedPlan(mix, 3, 1000)
+    assert [(len(r["tokens"]), r["max_new_tokens"])
+            for r in (one.take(0) for _ in range(200))] == dealt(mix, 3)[:200]
+
+
+@pytest.mark.parametrize("block", [None, 0, 4096])
+def test_a_closed_deck_without_the_key_draws_what_it_drew(block):
+    """No ``order_block`` (or one no smaller than the deck): a cycle is the
+    plain permutation the generator drew before PR 62, request for request
+    (``chat-short-sat``'s golden list above holds the same by numbers)."""
+    import numpy as np
+
+    mix = {k: v for k, v in SERVING["agent-steps-sat"].items()
+           if k != "order_block"}
+    if block is not None:
+        mix["order_block"] = block
+    pairs = traffic.length_pairs(mix, mix["count"])
+    rng = np.random.default_rng(9)
+    order = rng.permutation(len(pairs))
+    want = [traffic._request(rng, 1000, *pairs[i]) for i in order[::-1]][::-1]
+    plan = traffic.ClosedPlan(mix, 9, 1000)
+    assert [plan.take(0) for _ in range(300)] == want[:300]
+
+
 def test_spread_order_is_a_permutation_with_and_without_blocks():
     import numpy as np
 
