@@ -106,7 +106,8 @@ class BlockedKV(NamedTuple):
     # a model with recurrent state only (None elsewhere: no leaf, the same
     # program): the state, per state layer (``ModelConfig.state_layers``)
     # and sequence SLOT, fixed in size whatever the context, of ONE of three
-    # kinds (:attr:`state`). Mamba-2 layers (``ModelConfig.layer_pattern``):
+    # kinds (:attr:`state`). Mamba-2 layers (``ModelConfig.layer_pattern``'s
+    # ``M``, or its ``H``, which have a row of ``k`` and ``v`` as well):
     # ``ssm`` [L_m, S + 1, g, n, (h / g) x p] in :data:`SSM_STATE_DTYPE` and
     # ``conv`` [L_m, kernel - 1, S + 1, channels], the convolution's tail,
     # in the pool's dtype (``ops/ssm.py`` has the layout's why).
@@ -281,8 +282,8 @@ def init_blocked_kv(model_config, cfg: RaggedInferenceConfig,
             jnp.zeros((), jnp.int32)),
             out_shardings=topology.replicated())()
     state = {}
-    if model_config.pattern_count("M"):
-        mc, lead = model_config, (model_config.pattern_count("M"),
+    if model_config.mamba_layers:
+        mc, lead = model_config, (model_config.mamba_layers,
                                   cfg.max_sequences + 1)
         g = mc.ssm_n_groups
         state = jax.jit(lambda: dict(

@@ -57,7 +57,11 @@ attention (``layer_pattern``'s ``L`` layers: :func:`_lightning_mixer`,
 ``ops/ssm.py``'s convolution-free entries), a fourth kind of state behind the
 slots, beside dense feed-forward parts (``F``) and attention layers that read
 a SELECTION of blocks chosen from pooled keys (``cfg.sparse_block_topk``:
-``bsa.py``), every sublayer under muP's ``cfg.residual_scale``.
+``bsa.py``), every sublayer under muP's ``cfg.residual_scale``. And a tenth:
+attention heads and a Mamba-2 mixer SIDE BY SIDE in one layer
+(``layer_pattern``'s ``H`` layers, falcon_h1): one norm, both mixers on the
+same normed rows, a KV row and a state slot at the same index, one sum into
+the stream, under muP's twelve multipliers inside the layer (``cfg.mup``).
 """
 from contextlib import nullcontext
 from typing import Any, NamedTuple, Optional, Tuple
@@ -69,7 +73,7 @@ import numpy as np
 from .kv_cache import BlockedKV, MoeCounters
 from .module_registry import register_impl, select_impl
 from ...models.layers import (alibi_slopes, apply_rope, mlp_block, norm,
-                              qk_norm, rms_norm)
+                              qk_norm, rms_norm, scaled)
 from ...monitor.mfu import scope
 from ...ops.grouped_gemm import row_tile, tile_visits
 
@@ -200,6 +204,7 @@ def _qkv(p, y, cfg, n):
         k = k + p["bk"].astype(k.dtype)
         v = v + p["bv"].astype(v.dtype)
     q, k = qk_norm(p, q, k, cfg)
+    k = scaled(k, cfg.mup.key)
     return (q.reshape(n, cfg.num_heads, cfg.head_dim),
             k.reshape(n, cfg.num_kv_heads, cfg.head_dim),
             v.reshape(n, cfg.num_kv_heads, cfg.head_dim))
@@ -329,15 +334,18 @@ def _final_norm(params, x, cfg):
 
 
 def _unembed(params, x, cfg):
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("sd,vd->sv", x,
-                            params["embed"]["embedding"].astype(x.dtype))
-    else:
-        logits = jnp.einsum("sd,dv->sv", x,
-                            params["lm_head"]["kernel"].astype(x.dtype))
-        if cfg.lm_head_bias:
-            logits = logits + params["lm_head"]["bias"].astype(logits.dtype)
-    return logits if cfg.logit_scale == 1.0 else logits * cfg.logit_scale
+    with scope("lm_head"):
+        if cfg.tie_embeddings:
+            logits = jnp.einsum("sd,vd->sv", x,
+                                params["embed"]["embedding"].astype(x.dtype))
+        else:
+            logits = jnp.einsum("sd,dv->sv", x,
+                                params["lm_head"]["kernel"].astype(x.dtype))
+            if cfg.lm_head_bias:
+                logits = logits + params["lm_head"]["bias"].astype(
+                    logits.dtype)
+        return logits if cfg.logit_scale == 1.0 \
+            else logits * cfg.logit_scale
 
 
 def _block(cfg, p, x, attn_fn, live, experts=None):
@@ -920,22 +928,34 @@ def layer_plan(pattern: str):
     return plan
 
 
-def _mamba_mixer(cfg, p, x, ssm_fn):
-    """One Mamba-2 layer over flat tokens x [T, d]: ``in_proj`` to ``[z |
-    xBC | dt]``, the recurrence (``ssm_fn(p, xbc, dt) -> y [T, d_inner]``
-    float32: convolution, scan and ``D``, against the state pool), the gate
-    and its grouped norm, ``out_proj``."""
+def _mamba_write(cfg, p, y, ssm_fn):
+    """What a Mamba-2 mixer writes of the normed rows y [T, d], the part an
+    ``M`` and an ``H`` layer share: ``in_proj`` to ``[z | xBC | dt]`` (under
+    muP each slice times its multiplier, ``cfg.mup_in_proj``), the
+    recurrence (``ssm_fn(p, xbc, dt) -> y [T, d_inner]`` float32:
+    convolution, scan and ``D``, against the state pool), the gate and its
+    grouped norm, ``out_proj`` (times ``mup.ssm_out``)."""
     from ...ops.ssm import gated_norm
 
     di, c = cfg.ssm_d_inner, cfg.ssm_conv_dim
-    y = norm(x, p["norm"], cfg)
     with scope("ssm_proj"):
         zxbcdt = y @ p["in_proj"]
+        if cfg.mup_in_proj is not None:
+            zxbcdt = (zxbcdt.astype(jnp.float32)
+                      * cfg.mup_in_proj).astype(y.dtype)
     out = ssm_fn(p, zxbcdt[:, di:di + c], zxbcdt[:, di + c:])
     with scope("ssm_gate"):
         u = gated_norm(out, zxbcdt[:, :di], p["gate_norm"]["scale"], cfg)
     with scope("ssm_proj"):
-        return (x + u.astype(x.dtype) @ p["out_proj"]).astype(x.dtype)
+        return scaled(u.astype(y.dtype) @ p["out_proj"], cfg.mup.ssm_out)
+
+
+def _mamba_mixer(cfg, p, x, ssm_fn):
+    """One Mamba-2 layer over flat tokens x [T, d]: ``x +``
+    :func:`_mamba_write` of its norm."""
+    m = _mamba_write(cfg, p, norm(x, p["norm"], cfg), ssm_fn)
+    with scope("ssm_proj"):
+        return (x + m).astype(x.dtype)
 
 
 def _kda_mixer(cfg, p, x, conv_fn, scan_fn):
@@ -1021,7 +1041,8 @@ def _walk_pattern(cfg, params, x, kv: BlockedKV, attend, ssm_step, live,
     of parameters and ITS cache (``attn_layers`` and the KV pool for ``*``,
     ``mamba_layers`` or ``kda_layers`` and the recurrent state for ``M`` or
     ``K``, ``lightning_layers`` and the state for ``L``, ``ffn_layers`` for
-    ``F``, ``layers`` and the expert counters for ``E``). As in
+    ``F``, ``layers`` and the expert counters for ``E``; ``hybrid_layers``
+    and BOTH caches at one index for ``H``). As in
     :func:`_scan_layers` the pools ride as carry and the routed experts'
     matrices stay closed over; the other leaves are read at the layer's
     (traced) index inside the loop body, which is what a scan's xs are.
@@ -1031,7 +1052,10 @@ def _walk_pattern(cfg, params, x, kv: BlockedKV, attend, ssm_step, live,
     ``kda`` = ``(conv(p, qkv, state, l) -> (out, state), scan(q, k, v, g,
     beta, state, l) -> (out, state))`` and ``lightning`` = ``(positions,
     scan(q, k, v, state, l) -> (out, state))`` are the forward's own. Every
-    layer is ``x + mixer(norm(x))``. A model whose attention reads selected
+    layer is ``x + mixer(norm(x))``; an ``H`` layer's mixer is two, attention
+    heads (scope ``h1_attn``) and a Mamba-2 mixer that read the SAME normed
+    rows, each scaled by muP's multipliers (``cfg.mup``), summed into one
+    residual add. A model whose attention reads selected
     blocks carries what it counts (``kv.bsa``) behind the pools, zeroed at
     the forward's start."""
     layers, stack = _experts_in_place(params.get("layers", {}), x.dtype)
@@ -1041,13 +1065,23 @@ def _walk_pattern(cfg, params, x, kv: BlockedKV, attend, ssm_step, live,
     def one(kind, carry, j):
         x, pools, state = carry
         rows = None
-        if kind == "M":
-            def ssm_fn(p, xbc, dt):
-                nonlocal state
-                y, state = ssm_step(p, xbc, dt, state, j)
-                return y
+        def ssm_fn(p, xbc, dt):
+            nonlocal state
+            y, state = ssm_step(p, xbc, dt, state, j)
+            return y
 
+        if kind == "M":
             x = _mamba_mixer(cfg, at(params["mamba_layers"], j), x, ssm_fn)
+        elif kind == "H":
+            p = at(params["hybrid_layers"], j)
+            y = norm(x, p["norm"], cfg)
+            with scope("h1_attn"):
+                rows_attn, pools = attend(
+                    p["attn"], scaled(y, cfg.mup.attention_in), pools, j)
+                a = scaled(_attn_out(p["attn"], rows_attn, cfg, x.shape[0]),
+                           cfg.mup.attention_out)
+            m = _mamba_write(cfg, p["mamba"], y, ssm_fn)
+            x = (x + _branch(cfg, a + m)).astype(x.dtype)
         elif kind == "K":
             def conv_fn(p, qkv):
                 nonlocal state
@@ -1096,7 +1130,7 @@ def _walk_pattern(cfg, params, x, kv: BlockedKV, attend, ssm_step, live,
     n_pools = len(kv.pools)
     counts = () if kv.bsa is None else (jnp.zeros_like(kv.bsa),)
     carry, done, routed = (x, kv.pools + counts, kv.state), \
-        dict.fromkeys("MKLEF*", 0), []
+        dict.fromkeys("MKLEFH*", 0), []
     for unit, reps in layer_plan(cfg.layer_pattern):
         per = {kind: unit.count(kind) for kind in done}
 

@@ -9,7 +9,26 @@ family; per-family presets live in :data:`PRESETS`.
 """
 import math
 from dataclasses import dataclass, field, replace
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
+
+
+class MuP(NamedTuple):
+    """muP's forward multipliers beyond the stream's three (``embed_scale``,
+    ``residual_scale``, ``logit_scale``), under falcon_h1's published names
+    less ``_multiplier``: twelve scalars, all 1.0 for every other family.
+    ``attention_in`` / ``ssm_in`` scale the normed input of an ``H`` layer's
+    halves, ``attention_out`` / ``ssm_out`` what each half writes; ``key``
+    the keys of any attention; ``ssm`` the ``[z | x | B | C | dt]`` slices of
+    a Mamba in-projection's output; ``mlp`` a gated MLP's gate (before its
+    activation) and its down-projection's output. The parameter tree holds
+    the UNSCALED matrices, as published."""
+    attention_in: float = 1.0
+    attention_out: float = 1.0
+    key: float = 1.0
+    ssm_in: float = 1.0
+    ssm_out: float = 1.0
+    ssm: Tuple[float, float, float, float, float] = (1.0,) * 5
+    mlp: Tuple[float, float] = (1.0, 1.0)
 
 
 @dataclass
@@ -163,13 +182,17 @@ class ModelConfig:
     # ``mlp_type="mlp"``; solar_open2's gated), ``*`` attention, ``L`` a
     # lightning linear-attention mixer and ``F`` a dense feed-forward part
     # alone (minicpm_sala; both below). None: the uniform attention + MLP
-    # block. A stack of parameters a kind
-    # (``mamba_layers``, ``kda_layers``, ``layers``, ``attn_layers``), a KV
-    # pool with a row for the ``*`` layers only and a recurrent state per
-    # sequence slot for the ``M`` or the ``K`` layers
-    # (inference/v2/kv_cache.py). A published layer that is a mixer AND an
-    # expert block (solar_open2) is two characters here, so num_layers
-    # counts characters. Serving only.
+    # block. ``H`` (falcon_h1) is the one letter with TWO mixers: attention
+    # heads and a Mamba-2 mixer side by side behind ONE norm, their outputs
+    # summed into one residual add; it stands beside ``E`` and ``F`` only.
+    # A stack of parameters a kind
+    # (``mamba_layers``, ``kda_layers``, ``layers``, ``attn_layers``,
+    # ``hybrid_layers``), a KV pool with a row for the ``*`` and ``H`` layers
+    # only and a recurrent state per sequence slot for the ``M``, ``K``,
+    # ``L`` or ``H`` layers (inference/v2/kv_cache.py): an ``H`` layer has
+    # both, at the same index. A published layer that is a mixer AND an
+    # expert or feed-forward block (solar_open2, falcon_h1) is two
+    # characters here, so num_layers counts characters. Serving only.
     layer_pattern: Optional[str] = None
     # The gated delta rule with a decay per key channel (Kimi delta
     # attention; ops/kda.py), the ``K`` layers of a layer_pattern:
@@ -232,6 +255,9 @@ class ModelConfig:
     # 1.0: neither (logit_scale is the third)
     embed_scale: float = 1.0
     residual_scale: float = 1.0
+    # ... and the twelve further multipliers of a family that scales inside
+    # the layer too (falcon_h1; a mapping of MuP's names is taken as one)
+    mup: MuP = MuP()
     # Mamba-2 sizes, under the names nemotron_h publishes: d_inner is
     # mamba_num_heads x mamba_head_dim (not an expansion of hidden_size);
     # B and C come in ssm_n_groups groups of ssm_state_size
@@ -379,6 +405,15 @@ class ModelConfig:
             raise ValueError(
                 "first_k_dense_replace needs experts in every later layer, "
                 "stacked (scan_layers)")
+        if not isinstance(self.mup, MuP):    # a mapping or its values
+            m = MuP(**self.mup) if isinstance(self.mup, dict) \
+                else MuP(*self.mup)
+            self.mup = m._replace(ssm=tuple(m.ssm), mlp=tuple(m.mlp))
+        if self.mup._replace(mlp=(1.0, 1.0)) != MuP() \
+                and "H" not in (self.layer_pattern or ""):
+            raise ValueError(
+                "mup: the attention_*, key and ssm_* multipliers are an 'H' "
+                "layer's (layer_pattern); only mup.mlp is read elsewhere")
         if self.layer_pattern is not None:
             self._check_pattern()
         elif (self.attn_out_gate or self.kda_num_heads
@@ -406,12 +441,20 @@ class ModelConfig:
 
     def _check_pattern(self):
         pat = self.layer_pattern
-        if set(pat) - set("MKLEF*") or len(pat) != self.num_layers:
+        if set(pat) - set("MKLEFH*") or len(pat) != self.num_layers:
             raise ValueError(
                 f"layer_pattern {pat!r}: {self.num_layers} characters of "
                 f"'M' (Mamba-2), 'K' (gated delta rule), 'L' (lightning "
-                f"attention), 'E' (experts), 'F' (dense feed-forward) and "
-                f"'*' (attention) wanted")
+                f"attention), 'E' (experts), 'F' (dense feed-forward), "
+                f"'H' (attention and Mamba-2 side by side) and '*' "
+                f"(attention) wanted")
+        if "H" in pat and (set(pat) & set("MKL*") or self.attn_out_gate
+                           or self.sparse_block_topk or self.index_topk):
+            raise ValueError(
+                "layer_pattern: an 'H' layer brings its own attention and "
+                "its own Mamba-2 mixer, a KV row and a state slot at ONE "
+                "index: no 'M', 'K', 'L' or '*' layer beside it, and plain "
+                "attention (no output gate, selected blocks or indexer)")
         if "L" in pat and ("M" in pat or "K" in pat or not (
                 self.lightning_heads and self.lightning_head_dim
                 and self.lightning_head_dim % 2 == 0)):
@@ -431,13 +474,13 @@ class ModelConfig:
         if ("E" in pat) != self.any_moe:
             raise ValueError("layer_pattern: 'E' layers need num_experts, "
                              "and num_experts needs an 'E' layer")
-        if "M" in pat and not (self.mamba_num_heads and self.mamba_head_dim
-                               and self.ssm_state_size
-                               and self.mamba_num_heads % self.ssm_n_groups
-                               == 0):
-            raise ValueError("layer_pattern: 'M' layers need mamba_num_heads"
-                             " (a multiple of ssm_n_groups), mamba_head_dim "
-                             "and ssm_state_size")
+        if self.mamba_layers and not (
+                self.mamba_num_heads and self.mamba_head_dim
+                and self.ssm_state_size
+                and self.mamba_num_heads % self.ssm_n_groups == 0):
+            raise ValueError("layer_pattern: 'M' and 'H' layers need "
+                             "mamba_num_heads (a multiple of ssm_n_groups), "
+                             "mamba_head_dim and ssm_state_size")
         if (self.kv_lora_rank or self.hc_mult > 1 or self.parallel_block
                 or self.first_k_dense_replace or self.moe_layer_freq != 1
                 or self.attn_windows is not None
@@ -566,9 +609,31 @@ class ModelConfig:
                 "of sequential blocks; not written for: " + ", ".join(wrong))
 
     def pattern_count(self, kind: str) -> int:
-        """Layers of ``kind`` ('M', 'K', 'L', 'E', 'F', '*') in
+        """Layers of ``kind`` ('M', 'K', 'L', 'E', 'F', 'H', '*') in
         ``layer_pattern``."""
         return (self.layer_pattern or "").count(kind)
+
+    @property
+    def mamba_layers(self) -> int:
+        """Layers with a Mamba-2 mixer: alone (``M``) or beside attention
+        heads (``H``)."""
+        return self.pattern_count("M") + self.pattern_count("H")
+
+    @property
+    def mup_in_proj(self):
+        """[d_inner + conv_dim + heads] float32: what a Mamba in-projection's
+        output is multiplied by under ``mup``: ``ssm_in`` (folded out of the
+        input: the product is linear) times ``ssm``'s multiplier of the
+        column's slice, ``[z | x | B | C | dt]``. None: by nothing."""
+        import numpy as np
+
+        if (self.mup.ssm_in, self.mup.ssm) == (1.0, (1.0,) * 5):
+            return None
+        gn = self.ssm_n_groups * self.ssm_state_size
+        widths = (self.ssm_d_inner, self.ssm_d_inner, gn, gn,
+                  self.mamba_num_heads)
+        return self.mup.ssm_in * np.repeat(
+            np.asarray(self.mup.ssm, np.float32), widths)
 
     @property
     def num_kv_layers(self) -> int:
@@ -577,20 +642,23 @@ class ModelConfig:
         rows ``u x L .. u x L + L - 1``: a pass attends to its own. A
         power-retention stack caches no key: 0. Under ``attn_period`` the
         FULL layers' rows: the windowed layers' (``window_layers``) lie in
-        a pool of their own."""
+        a pool of their own. Of a ``layer_pattern`` the ``*`` and the ``H``
+        layers' (a model has one of the two)."""
         if self.retention_degree:
             return 0
         layers = self.num_layers - self.window_layers \
-            if self.layer_pattern is None else self.pattern_count("*")
+            if self.layer_pattern is None \
+            else self.pattern_count("*") + self.pattern_count("H")
         return self.total_ut_steps * layers
 
     @property
     def state_layers(self) -> int:
         """Layers that keep a recurrent state per sequence (0: none): a
-        ``layer_pattern``'s Mamba-2, delta-rule or lightning layers, or
-        every layer of a power-retention stack."""
+        ``layer_pattern``'s Mamba-2 (alone or beside attention heads),
+        delta-rule or lightning layers, or every layer of a power-retention
+        stack."""
         return self.num_layers if self.retention_degree \
-            else sum(map(self.pattern_count, "MKL"))
+            else sum(map(self.pattern_count, "MKLH"))
 
     @property
     def state_chunk_size(self) -> int:
@@ -741,6 +809,8 @@ class ModelConfig:
             dl = self.lightning_dim
             light = 5 * d * dl + 2 * self.lightning_head_dim + dl + d
             return (mamba * self.pattern_count("M")
+                    # (one norm feeds both halves: mamba's count has it)
+                    + (mamba + attn) * self.pattern_count("H")
                     + kda * self.pattern_count("K")
                     + light * self.pattern_count("L")
                     + (dense + d) * self.pattern_count("F")
@@ -1047,6 +1117,28 @@ PRESETS = {
         sparse_block_dense_len=8192,
         embed_scale=12.0, residual_scale=1.4 / 32 ** 0.5,
         logit_scale=256 / 4096),
+    # tiiuae/Falcon-H1-34B-Instruct (model_type falcon_h1): 72 published
+    # layers, each attention heads (GQA 20/4 x 128, rotary at theta 1e11) AND
+    # a Mamba-2 mixer (32 heads of 128 = mamba_d_ssm 4096, 2 groups of state
+    # 256, conv 4 with bias, the gate before the grouped norm) side by side
+    # behind ONE norm, their outputs summed into one residual add (``H``),
+    # then a SwiGLU MLP of 21,504 (``F``): 144 characters here. muP: the
+    # embedding x 5.657, the logits x 2^-7 and twelve multipliers inside the
+    # layer (``mup``). Serving only (inference/v2, ops/ssm.py).
+    "falcon-h1-34b": _p(
+        vocab_size=261120, hidden_size=5120, intermediate_size=21504,
+        num_layers=144, num_heads=20, num_kv_heads=4, head_dim=128,
+        max_seq_len=262144, rms_norm_eps=1e-5, rope_theta=1e11,
+        layer_pattern="HF" * 72,
+        mamba_num_heads=32, mamba_head_dim=128, ssm_state_size=256,
+        ssm_n_groups=2, ssm_conv_kernel=4, ssm_chunk_size=128,
+        embed_scale=5.656854249492381, logit_scale=0.0078125,
+        mup=MuP(attention_in=1.0, attention_out=0.0375,
+                key=0.011048543456039804, ssm_in=0.25,
+                ssm_out=0.08838834764831845,
+                ssm=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                     0.3535533905932738),
+                mlp=(0.1767766952966369, 0.011160714285714284))),
 }
 
 

@@ -76,6 +76,13 @@ class CausalLM:
         cfg = self.config
         rng = rng if rng is not None else jax.random.PRNGKey(self.seed)
         std = cfg.initializer_range
+        # a matrix whose product a muP multiplier follows (or whose input one
+        # scales) is drawn THROUGH it, std / multiplier: the multipliers
+        # presume muP's larger matrices, and at N(0, 0.02) for every leaf
+        # falcon_h1's attention logits would be flat (q k^T / sqrt(128) ~
+        # 0.02) and its three branches write 1-4 % of what the embedding
+        # does (all 1.0, and nothing changes, for every other family)
+        mup = cfg.mup
         keys = iter(jax.random.split(rng, 64))
 
         def dense(shape, key, scale=std):
@@ -154,12 +161,14 @@ class CausalLM:
                     "wo": dense((h * cfg.v_head_dim, d), next(ks),
                                 scale=down_scale(cfg.v_head_dim)),
                 }
+            into = std / mup.attention_in
             attn: Params = {
-                "wq": dense((d, q), next(ks)),
-                "wk": dense((d, kv), next(ks)),
-                "wv": dense((d, kv), next(ks)),
+                "wq": dense((d, q), next(ks), scale=into),
+                "wk": dense((d, kv), next(ks), scale=into / mup.key),
+                "wv": dense((d, kv), next(ks), scale=into),
                 "wo": dense((q, d), next(ks), scale=down_scale(q) * (
-                    SELECTED_ATTN_WRITE if cfg.index_topk else 1.0)),
+                    SELECTED_ATTN_WRITE if cfg.index_topk else 1.0)
+                    / mup.attention_out),
             }
             if cfg.qkv_bias:
                 attn.update(bq=jnp.zeros((q,), jnp.float32),
@@ -208,9 +217,11 @@ class CausalLM:
 
         def glu_params(ks, f) -> Params:
             d = cfg.hidden_size
-            return {"w_gate": dense((d, f), next(ks)),
+            return {"w_gate": dense((d, f), next(ks),
+                                    scale=std / mup.mlp[0]),
                     "w_up": dense((d, f), next(ks)),
-                    "w_down": dense((f, d), next(ks), scale=down_scale(f))}
+                    "w_down": dense((f, d), next(ks),
+                                    scale=down_scale(f) / mup.mlp[1])}
 
         def mlp_params(ks, f) -> Params:
             d = cfg.hidden_size
@@ -239,7 +250,7 @@ class CausalLM:
             c, kw = cfg.ssm_conv_dim, cfg.ssm_conv_kernel
             bound = 1.0 / np.sqrt(kw)    # a depthwise conv's fan-in
             dt_bias = step_bias(next(ks), h)
-            return {
+            p = {
                 "norm": norm_params(),
                 "in_proj": dense((d, di + c + h), next(ks)),
                 "conv_w": jax.random.uniform(next(ks), (kw, c), jnp.float32,
@@ -251,8 +262,21 @@ class CausalLM:
                 "dt_bias": dt_bias,
                 "D": jnp.ones((h,), jnp.float32),
                 "gate_norm": {"scale": jnp.ones((di,), jnp.float32)},
-                "out_proj": dense((di, d), next(ks), scale=down_scale(di)),
+                "out_proj": dense((di, d), next(ks),
+                                  scale=down_scale(di) / mup.ssm_out),
             }
+            if cfg.mup_in_proj is not None:    # through ssm_in and ssm's
+                p["in_proj"] = p["in_proj"] / cfg.mup_in_proj
+            return p
+
+        def hybrid_params(key) -> Params:
+            """An ``H`` layer: ONE norm, attention heads and a Mamba-2 mixer
+            that both read it."""
+            k_attn, k_mamba = jax.random.split(key)
+            mamba = mamba_params(k_mamba)
+            return {"norm": mamba.pop("norm"),
+                    "attn": attn_params(iter(jax.random.split(k_attn, 16))),
+                    "mamba": mamba}
 
         def kda_params(key) -> Params:
             """One gated delta-rule mixer behind its norm (``ops/kda.py``):
@@ -410,6 +434,8 @@ class CausalLM:
                 if cfg.pattern_count("E") else {}
             if cfg.pattern_count("M"):
                 stacks["mamba_layers"] = jax.vmap(mamba_params)(of("M"))
+            if cfg.pattern_count("H"):
+                stacks["hybrid_layers"] = jax.vmap(hybrid_params)(of("H"))
             if cfg.pattern_count("K"):
                 stacks["kda_layers"] = jax.vmap(kda_params)(of("K"))
             if cfg.pattern_count("L"):
@@ -903,7 +929,7 @@ class CausalLM:
         stacked = self.config.scan_layers and any(
             n in names for n in ("layers", "dense_layers", "mamba_layers",
                                  "attn_layers", "lightning_layers",
-                                 "ffn_layers"))
+                                 "ffn_layers", "hybrid_layers"))
         if stacked:
             # under pipeline parallelism the stacked layer dim shards over
             # ``pipe`` (each stage owns its contiguous layer block — the

@@ -105,7 +105,13 @@ SUB_SCOPES = ("moe_route", "moe_experts", "moe_combine", "moe_shared",
               # rotation, output norm and gate; the recurrence, INSIDE
               # which la_step is the one-token rows' state step and
               # la_chunk the chunked form's pieces
-              "la_proj", "la_gate", "la_scan", "la_step", "la_chunk")
+              "la_proj", "la_gate", "la_scan", "la_step", "la_chunk",
+              # a layer of attention heads and a Mamba-2 mixer side by side
+              # (layer_pattern's ``H``; inference/v2/model.py): the
+              # attention half, projections, rotation, pool write, kernel
+              # and output projection (the Mamba half keeps its ssm_*); and
+              # the unembedding of every serving forward
+              "h1_attn", "lm_head")
 
 #: named_scope label prefix — ``mfu.attn`` etc. Kept short and distinctive
 #: so the metadata regex can't false-positive on user scopes.

@@ -1083,3 +1083,86 @@ def test_the_sala_forwards_compile_and_copy_no_pool(one_chip, program,
     assert not [ln for ln in text.splitlines() if " copy(" in ln and (
         f"bf16[1,{blocks * bs}," in ln or f"bf16[1,{blocks},4," in ln
         or f"f32[3,{seqs + 1},32," in ln)]
+
+
+# ------------- attention heads and a Mamba-2 mixer side by side in a layer
+@pytest.mark.parametrize("program", ["decode_forward", "ragged_forward"])
+def test_the_side_by_side_forwards_compile_at_the_cells_widths(
+        one_chip, program, monkeypatch):
+    """Both serving forwards of ``falcon-h1-34b`` at ``falconh1-chat-sat``'s
+    widths and shapes, the six layers whole (48 sequences, 768 rows,
+    contexts to 4,608, the pool of 3,520 pages and 49 state slots behind
+    every layer; 13.52 GiB of arguments): the paged kernels at FIVE query
+    heads a KV group, Mamba-2's state step with a ``[layer, slot]`` block of
+    4 MiB (2 groups x 256 x 2,048 float32) and the tail's kernel at 5,120
+    channels are custom calls under their own names and keep the scope they
+    were traced under (``h1_attn``, ``ssm_scan``, ``ssm_conv``), K, V, the
+    state and the tails are aliased to the result and none of them is
+    copied, and the temporaries stay under a GiB (0.04 GiB)."""
+    from benchmark import scopes
+    from deepspeedsyclsupport_tpu.inference.v2 import model as M
+    from deepspeedsyclsupport_tpu.inference.v2.kv_cache import BlockedKV
+    from deepspeedsyclsupport_tpu.inference.v2.ragged import SsmBatch
+    from deepspeedsyclsupport_tpu.models import build_model
+
+    # no chip is attached: the registry would hand the steps' XLA forms
+    monkeypatch.setattr(M, "_state_step_fn", lambda kind: M.select_impl(
+        kind, "pallas", {"backend": "tpu"}).fn)
+    model = build_model("falcon-h1-34b", num_layers=12,
+                        layer_pattern="HF" * 6, dtype="bfloat16")
+    assert model.config.num_heads // model.config.num_kv_heads == 5
+    bs, blocks, seqs, toks, bps, atom = 64, 3520, 48, 768, 72, 128
+
+    def on_chip(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda x: on_chip(x.shape, jnp.bfloat16 if jnp.issubdtype(
+            x.dtype, jnp.floating) else x.dtype),
+        jax.eval_shape(model.init_params))
+    pool = on_chip((6, blocks * bs, 4, 128), jnp.bfloat16)
+    state = on_chip((6, seqs + 1, 2, 256, 2048), jnp.float32)
+    tails = on_chip((6, 3, seqs + 1, 5120), jnp.bfloat16)
+    assert state.size // (6 * (seqs + 1)) * 4 == 4 << 20
+    kv = BlockedKV(pool, pool, ssm=state, conv=tails)
+    if program == "decode_forward":
+        fn = M.build_decode_forward_fn(model, bs, "pallas")
+        args = (on_chip((seqs,)), on_chip((seqs,)), on_chip((seqs, bps)),
+                on_chip((seqs,), jnp.bool_), None, None, on_chip((seqs,)))
+        kernels = {"paged_decode", "ssm_state_step", "conv_tail_step"}
+    else:
+        fn = M.build_ragged_forward_fn(model, bs, "kernel")
+        tiles = seqs + toks // atom + 1
+        batch = SsmBatch(*(on_chip((seqs,)),) * 3, *(on_chip((tiles,)),) * 3,
+                         on_chip((tiles,), jnp.bool_), on_chip(()))
+        args = (on_chip((toks,)), on_chip((toks,)), on_chip((toks,)),
+                on_chip((seqs, bps)), on_chip((seqs,)),
+                on_chip((tiles, atom)), on_chip((tiles,)), on_chip((tiles,)),
+                on_chip((tiles, bps)), on_chip((toks,)), on_chip((seqs,)),
+                on_chip((seqs,)), None, None, batch)
+        kernels = {"paged_decode", "ragged_prefill", "ssm_state_step",
+                   "conv_tail_step"}
+    compiled = fn.lower(params, kv, *args).compile()
+    text = compiled.as_text()
+    calls = {ln.split(" = ")[0].strip().lstrip("%").split(".")[0]
+             for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln}
+    assert calls == kernels
+    labels = ("h1_attn", "lm_head", "ssm_proj", "ssm_conv", "ssm_scan",
+              "ssm_gate", "ssm_chunk")
+    under = scopes.instructions_under(text, labels)
+    assert set(under.values()) == set(labels) - (
+        {"ssm_chunk"} if program == "decode_forward" else set())
+    # a custom call keeps its scope: the readers find the kernels by it
+    for call, label in (("paged_decode", "h1_attn"),
+                        ("ssm_state_step", "ssm_scan"),
+                        ("conv_tail_step", "ssm_conv")):
+        assert {v for k, v in under.items()
+                if k.split(".")[0] == call} == {label}, call
+    m = compiled.memory_analysis()
+    held = 2 * pool.size * 2 + state.size * 4 + tails.size * 2
+    assert m.alias_size_in_bytes >= held
+    assert m.temp_size_in_bytes < 2**30, m.temp_size_in_bytes
+    assert not [ln for ln in text.splitlines() if " copy(" in ln and (
+        f"bf16[6,{blocks * bs}," in ln or f"f32[6,{seqs + 1},2," in ln
+        or f"bf16[6,3,{seqs + 1}," in ln)]
