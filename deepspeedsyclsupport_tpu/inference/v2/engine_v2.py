@@ -26,7 +26,8 @@ import numpy as np
 
 from .config import RaggedInferenceConfig
 from .kv_cache import init_blocked_kv, state_pool_stats
-from .model import build_ragged_forward_fn, moe_tile_rows
+from .model import (build_ragged_forward_fn, moe_tile_rows, public_layout,
+                    serving_layout)
 from .ragged import (BlockedAllocator, LogitsRef, SequenceDescriptor,
                      WindowedAllocator, attention_work, build_ragged_batch,
                      device_token, ragged_shapes, selection_work,
@@ -187,23 +188,32 @@ class InferenceEngineV2:
         self.topology = topology or build_topology(dp=-1)
 
         rules = getattr(model, "sharding_rules", None)
-        with setup_span("params"):
-            self.params, _ = place_inference_params(params, self.topology,
-                                                    rules, cfg.dtype)
-            if cfg.quantize_weights and "layers" in self.params:
+        with setup_span("params") as span:
+            placed, _ = place_inference_params(params, self.topology, rules,
+                                               cfg.dtype)
+            if cfg.quantize_weights and "layers" in placed:
                 # ZeRO-Inference: int8 layer weights, dequantized per layer
                 # inside the ragged scan (model.py _dequant)
                 from ...compression.quantize import quantize_tree
 
                 stacked = bool(getattr(model.config, "scan_layers", False))
-                self.params = dict(self.params)
+                placed = dict(placed)
                 # no donation: placement may alias caller-held arrays (see
                 # InferenceEngine._quantize_weights)
-                self.params["layers"] = jax.jit(
+                placed["layers"] = jax.jit(
                     lambda t: quantize_tree(t, cfg.quant_group_size,
                                             stacked=stacked,
                                             bits=cfg.quant_bits))(
-                    self.params["layers"])
+                    placed["layers"])
+            # the forwards' own tree: the q, k and v projections laid out
+            # ONCE as their products read them (model.serving_layout), which
+            # a forward handed the public tree would do every time it runs
+            self._params = serving_layout(placed)
+            relaid = [new for new, old in zip(
+                jax.tree_util.tree_leaves(self._params),
+                jax.tree_util.tree_leaves(placed)) if new is not old]
+            span.update(relaid_leaves=len(relaid),
+                        relaid_bytes=sum(x.nbytes for x in relaid))
 
         with setup_span("pool"):
             self.kv = init_blocked_kv(model.config, cfg, self.topology)
@@ -324,6 +334,24 @@ class InferenceEngineV2:
                  f"tokens, budget {cfg.max_tokens_per_batch} tok/fwd, "
                  f"≤{cfg.max_sequences} seqs")
 
+    # ---------------------------------------------------------------- params
+    @property
+    def params(self):
+        """The weights as the model's PUBLIC tree (``[in, out]``
+        projections, what ``model.init_params`` gives and a plain reference
+        reads). The engine holds ONE copy of each weight, its own
+        (``model.serving_layout``): the leaves it re-laid are turned back
+        when asked and not kept, every other leaf is the engine's own
+        array."""
+        return public_layout(self._params)
+
+    @params.setter
+    def params(self, tree) -> None:
+        """New weights in the public layout, placed and cast as the caller
+        left them (the hybrid engine's hand-over, a planted fault): re-laid
+        for the forwards."""
+        self._params = serving_layout(tree)
+
     # ----------------------------------------------------------- persistence
     def serialize(self, save_path: str) -> None:
         """Model snapshot (reference ``engine_v2.serialize:237``: flattened
@@ -343,7 +371,7 @@ class InferenceEngineV2:
                 f"— fail at save, not with a confusing load-time error")
         self._refuse_stateful("serialize()", "a snapshot of the recurrent "
                               "state beside the parameters")
-        params = self.params
+        params = self.params       # the public layout: what a load reads
         if self.config.quantize_weights and "layers" in params:
             from ...compression.quantize import dequantize_tree
 
@@ -1183,7 +1211,7 @@ class InferenceEngineV2:
             tiles = batch.tile_args or (None,) * 7
             logits, self.kv = self._dispatch(
                 "ragged_forward", self._forward,
-                self.params, self.kv, tokens,
+                self._params, self.kv, tokens,
                 jnp.asarray(batch.token_seq), jnp.asarray(batch.token_pos),
                 jnp.asarray(batch.block_tables),
                 jnp.asarray(batch.last_tok_idx),
@@ -1246,7 +1274,7 @@ class InferenceEngineV2:
                                                               sampled)
             logits, self.kv = self._dispatch(
                 "decode_forward", self._decode_forward,
-                self.params, self.kv, tokens,
+                self._params, self.kv, tokens,
                 jnp.asarray(positions), jnp.asarray(tables),
                 jnp.asarray(active), sampled, take_from,
                 *_behind_state(list(map(jnp.asarray, state)),

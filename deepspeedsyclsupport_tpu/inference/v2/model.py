@@ -169,11 +169,81 @@ def _kernel_names(kind: Optional[str]):
             {"name": f"paged_{kind}_decode"})
 
 
-def _dequant(p, dtype):
-    """ZeRO-Inference: materialize int8 QuantTensor leaves per layer."""
-    from ...compression.quantize import dequantize_tree
+# ------------------------------------------------------ the serving layout
+# The model's public tree stores a projection ``[in, out]``
+# (``models/transformer.py``). The v5e compiler wants the weights of the q, k
+# and v products with the CONTRACTED axis minor, and a program's arguments
+# arrive in the default layout, so handed the public tree it re-lays them
+# inside the program, every time it runs (Ouro: three stacks, 1.13 GiB and
+# 3 ms a forward; PERF.md section 6, PR 64). The two serving forwards
+# therefore take these leaves ``[out, in]`` and contract over the LAST axis,
+# always: a square matrix cannot say which way it lies. Whoever hands a
+# forward a tree turns it first (the engine once, at load).
+TURNED = ("wq", "wk", "wv")     # :func:`_qkv`'s and :func:`_lightning_mixer`'s
 
-    return dequantize_tree(p, dtype)
+
+def _turns(path) -> bool:
+    """Whether the leaf at ``path`` (a key path, of the tree or of one
+    layer of it) is one the serving forwards read ``[out, in]``."""
+    return getattr(path[-1], "key", None) in TURNED
+
+
+def _swap(x):
+    return jnp.swapaxes(x, -1, -2)
+
+
+def _turn(x):
+    """``x`` with its last two axes swapped, its sharding's with them: an
+    array on a mesh (under ``jit``, one leaf alive at a time; the argument
+    is never donated: it is the caller's), a traced value, or a
+    ``ShapeDtypeStruct``."""
+    if isinstance(x, jax.core.Tracer) or not hasattr(x, "sharding"):
+        return _swap(x)
+    sharding = x.sharding
+    if isinstance(sharding, jax.sharding.NamedSharding):
+        spec = list(sharding.spec) + [None] * (x.ndim - len(sharding.spec))
+        spec[-1], spec[-2] = spec[-2], spec[-1]
+        sharding = jax.sharding.NamedSharding(
+            sharding.mesh, jax.sharding.PartitionSpec(*spec))
+    if isinstance(x, jax.ShapeDtypeStruct):
+        return jax.ShapeDtypeStruct(
+            (*x.shape[:-2], x.shape[-1], x.shape[-2]), x.dtype,
+            sharding=sharding)
+    return jax.jit(_swap, out_shardings=sharding)(x)
+
+
+def serving_layout(params):
+    """The model's public tree as the two serving forwards take it: every
+    leaf of :data:`TURNED` ``[..., out, in]``, everything else as it is.
+    On arrays, traced values and ``ShapeDtypeStruct``s alike; the only place
+    that knows which leaves turn. A ``QuantTensor`` keeps its form, the
+    public one: :func:`_dequant` turns what it materialises."""
+    from ...compression.quantize import QuantTensor
+
+    return jax.tree_util.tree_map_with_path(
+        lambda path, x: _turn(x)
+        if _turns(path) and not isinstance(x, QuantTensor) else x,
+        params, is_leaf=lambda x: isinstance(x, QuantTensor))
+
+
+def public_layout(params):
+    """:func:`serving_layout`'s inverse, which is the same turn."""
+    return serving_layout(params)
+
+
+def _dequant(p, dtype):
+    """ZeRO-Inference: materialize int8 QuantTensor leaves per layer, each
+    in the serving layout (the ``QuantTensor`` holds the public one)."""
+    from ...compression.quantize import QuantTensor
+
+    def leaf(path, x):
+        if not isinstance(x, QuantTensor):
+            return x
+        w = x.dequantize(dtype)
+        return _turn(w) if _turns(path) else w
+
+    return jax.tree_util.tree_map_with_path(
+        leaf, p, is_leaf=lambda x: isinstance(x, QuantTensor))
 
 
 def _mlp(p, y, cfg, live, experts=None):
@@ -195,10 +265,11 @@ def _mlp(p, y, cfg, live, experts=None):
 
 def _qkv(p, y, cfg, n):
     """Fused qkv projection over flat tokens [n, D] (+ optional biases,
-    + the projection-wide QK-norm of ``cfg.qk_norm``)."""
-    q = jnp.einsum("td,dq->tq", y, p["wq"])
-    k = jnp.einsum("td,dk->tk", y, p["wk"])
-    v = jnp.einsum("td,dk->tk", y, p["wv"])
+    + the projection-wide QK-norm of ``cfg.qk_norm``). The weights lie
+    ``[out, in]`` (:func:`serving_layout`)."""
+    q = jnp.einsum("td,qd->tq", y, p["wq"])
+    k = jnp.einsum("td,kd->tk", y, p["wk"])
+    v = jnp.einsum("td,kd->tk", y, p["wv"])
     if cfg.qkv_bias:
         q = q + p["bq"].astype(q.dtype)
         k = k + p["bk"].astype(k.dtype)
@@ -1017,7 +1088,9 @@ def _lightning_mixer(cfg, p, x, positions, scan_fn):
         t[None], positions[None], cfg.rope_theta)[0]
     y = norm(x, p["norm"], cfg)
     with scope("la_proj"):
-        q, k, v, z = (y @ p[w] for w in ("wq", "wk", "wv", "wz"))
+        # (q, k and v lie [out, in]: serving_layout)
+        q, k, v = (jnp.einsum("td,qd->tq", y, p[w]) for w in TURNED)
+        z = y @ p["wz"]
     with scope("la_gate"):
         q = rot(rms_norm(q.reshape(n, h, d), p["q_norm"]["scale"],
                          cfg.rms_norm_eps))

@@ -695,6 +695,50 @@ class TestTuneChainTimer:
                 "us": 500.0, "gb_s": round(moved / 500.0 / 1e3, 1)}
 
 
+    def test_the_proj_sweep_runs_both_layouts_of_every_shape(
+            self, tune, monkeypatch, capsys):
+        """``tpu_tune.py proj`` at two tiny cells, the profiler's reading
+        stubbed: every distinct ``(in, out)`` of the cells' q, k/v and
+        lightning products is built once, with the weights stored
+        ``[in, out]`` and ``[out, in]`` (``model.serving_layout``), and the
+        two programs of a shape give the same sum. The cells' shapes come
+        off ``BENCHMARK.json``'s configurations: every serving cell that is
+        not latent."""
+        import json
+
+        cells = tune._proj_cells()
+        assert "xing4-docs-sat" not in cells \
+            and "dsv2-answers-sat" not in cells
+        assert cells["ouro-reason-sat"] == {"q": (2048, 2048),
+                                            "kv": (2048, 2048)}
+        assert cells["cmdaplus-rag-sat"] == {"q": (4096, 16384),
+                                             "kv": (4096, 1024)}
+        assert cells["sala-docs-sat"]["la"] == (4096, 4096)
+        monkeypatch.setattr(tune, "_proj_cells", lambda: {
+            "square": {"q": (64, 64), "kv": (64, 64)},
+            "wide": {"q": (64, 256), "kv": (64, 32), "la": (64, 64)}})
+
+        def reading(steps, args, **_kw):
+            for step in steps.values():
+                jax.block_until_ready(step(*args))
+            return {name: {"xla": 0.6, "calls": {}} for name in steps}
+
+        monkeypatch.setattr(tune, "_traced_kernels", reading)
+        tune.proj(["--rows", "16", "48", "--layers", "3", "--passes", "2"])
+        out = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
+        assert [(r["d_in"], r["d_out"], r["t"]) for r in out] == [
+            (64, 64, 16), (64, 64, 48), (64, 256, 16), (64, 256, 48),
+            (64, 32, 16), (64, 32, 48)]
+        assert out[0]["cells"] == ["square:q", "square:kv", "wide:la"]
+        for r in out:
+            assert set(r["rows"]) == set(tune.PROJ_LAYOUTS)
+            a, b = (r["rows"][k] for k in ("in_out", "out_in"))
+            assert a["us"] == b["us"] == 100.0      # 0.6 ms / (3 x 2)
+            assert a["gb_s"] == round(r["d_in"] * r["d_out"] * 2 / 1e5, 1)
+            np.testing.assert_allclose(a["sum"], b["sum"], rtol=1e-3,
+                                       atol=1e-2)
+
+
 class TestSpatialAndTiling:
     """ops/spatial (diffusers fused bias-add family, reference
     csrc/spatial/) and runtime/tiling (reference runtime/zero/tiling.py)."""

@@ -69,6 +69,100 @@ def _widths(name):
     return cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
 
 
+def _serving_params(model, one_chip):
+    """The abstract tree the engine hands its forwards: every floating leaf
+    in the serving dtype (``jax.eval_shape(model.init_params)`` gives
+    float32), in the layout the forwards take (``model.serving_layout``, on
+    shapes)."""
+    from deepspeedsyclsupport_tpu.inference.v2.model import serving_layout
+
+    return serving_layout(jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(
+            x.shape, jnp.bfloat16 if jnp.issubdtype(x.dtype, jnp.floating)
+            else x.dtype, sharding=one_chip),
+        jax.eval_shape(model.init_params)))
+
+
+@_once
+def _cell_forward(one_chip, config, program):
+    """``program`` of the benchmark's configuration ``config`` WHOLE, at the
+    shapes its cell's engine gives it (the pool, the largest mixed round,
+    the state's pieces, the sampler's tail), compiled for the described
+    chip with the platform's kernels (no chip is attached: the registry and
+    the grouped GEMM would hand their XLA forms). -> ``(compiled, the
+    abstract pool, the abstract weights)``; compiled once a module."""
+    from unittest import mock
+
+    from benchmark import spec
+    from deepspeedsyclsupport_tpu.comm import topology as topo_mod
+    from deepspeedsyclsupport_tpu.inference.v2 import model as M
+    from deepspeedsyclsupport_tpu.inference.v2.kv_cache import init_blocked_kv
+    from deepspeedsyclsupport_tpu.inference.v2.ragged import (SsmBatch,
+                                                              ragged_shapes)
+    from deepspeedsyclsupport_tpu.models import build_model
+    from deepspeedsyclsupport_tpu.ops import grouped_gemm as gg
+    from deepspeedsyclsupport_tpu.ops.paged_attention import default_atom_rows
+
+    cfg = spec.Bench().config(config)
+    model = build_model(cfg["preset"], **cfg["overrides"], dtype=cfg["dtype"])
+    mc = model.config
+    eng = RaggedInferenceConfig.from_config(
+        None, dtype=cfg["dtype"], head_dim_lane_pad=128, **cfg["engine"])
+
+    def on_chip(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    class NoMesh:           # what the pool asks of a topology: one device
+        axis_sizes = {"model": 1}
+
+        @staticmethod
+        def replicated():
+            return None
+
+    kv = jax.tree_util.tree_map(
+        lambda x: on_chip(x.shape, x.dtype),
+        jax.eval_shape(lambda: init_blocked_kv(mc, eng, NoMesh)))
+    params = _serving_params(model, one_chip)
+    bs, seqs, toks, bps = (eng.block_size, eng.max_sequences,
+                           eng.max_tokens_per_batch, eng.blocks_per_seq)
+    tail = kv.exit_pass if kv.exit_pass is not None else kv.bsa
+    n_tail = tail.size if tail is not None else 0 if kv.moe is None else sum(
+        x is not None for x in (kv.moe.touched, kv.moe.tiles, kv.moe.rows))
+    sampled = on_chip((seqs + n_tail,))
+    windowed = mc.period_window is not None
+    if program == "decode_forward":
+        fn = M.build_decode_forward_fn(model, bs, "pallas")
+        args = [on_chip((seqs,)), on_chip((seqs,)), on_chip((seqs, bps)),
+                on_chip((seqs,), jnp.bool_), sampled, on_chip((seqs,))]
+        state = [on_chip((seqs,))] if kv.state else []
+        window = [on_chip((seqs, bps))] if windowed else []
+    else:
+        fn = M.build_ragged_forward_fn(model, bs, "kernel")
+        atom = default_atom_rows(eng.atom_q_size, mc.num_heads,
+                                 kv.k.shape[-2], kv.k.shape[-1], bs, 2) \
+            if mc.num_kv_layers else 0
+        shape = ragged_shapes(toks, seqs, atom,
+                              mc.state_chunk_size if kv.state else 0)[-1]
+        a, p = shape.atoms, shape.pieces
+        tiles = [on_chip((a, atom)), on_chip((a,)), on_chip((a,)),
+                 on_chip((a, bps)), on_chip((toks,)), on_chip((seqs,)),
+                 on_chip((seqs,))] if atom else [None] * 7
+        args = [on_chip((toks,)), on_chip((toks,)), on_chip((toks,)),
+                on_chip((seqs, bps)), on_chip((seqs,)), *tiles, sampled,
+                on_chip((toks,))]
+        state = [SsmBatch(*(on_chip((seqs,)),) * 3, *(on_chip((p,)),) * 3,
+                          on_chip((p,), jnp.bool_), on_chip(()))] \
+            if kv.state else []
+        window = [on_chip((seqs, bps)), on_chip((a, bps))] if windowed else []
+    behind = state + window if state or not window else [None] + window
+    with mock.patch.object(gg, "default_impl", lambda: "pallas"), \
+            mock.patch.object(M, "_state_step_fn", lambda kind: M.select_impl(
+                kind, "pallas", {"backend": "tpu"}).fn), \
+            mock.patch.object(topo_mod, "_WORLD_TOPOLOGY", None):
+        compiled = fn.lower(params, kv, *args, *behind).compile()
+    return compiled, kv, params
+
+
 # ------------------------------------------------------------------- flash
 # training attention: mistral-7b (32/8 heads, d 128, S 4096, window 4096),
 # phi-2's head_dim 80 (lane-padded to 128 inside the kernel wrapper), and the
@@ -364,8 +458,7 @@ def test_decode_forward_reads_the_expert_stack_in_place(one_chip):
             x.shape, floats if floats is not None and jnp.issubdtype(
                 x.dtype, jnp.floating) else x.dtype, sharding=one_chip), tree)
 
-    # as the engine places them: every floating leaf in the serving dtype
-    params = on_chip(jax.eval_shape(model.init_params), jnp.bfloat16)
+    params = _serving_params(model, one_chip)
     pool = jnp.zeros((2, slots, cfg.num_kv_heads, cfg.head_dim), jnp.bfloat16)
     kv = on_chip(BlockedKV(pool, pool, MoeCounters(
         jnp.zeros((2, cfg.num_experts), jnp.int32), jnp.int32(0))))
@@ -450,7 +543,7 @@ def test_decode_forward_on_the_tpu_takes_the_kernel(one_chip, monkeypatch):
             x.shape, floats if floats is not None and jnp.issubdtype(
                 x.dtype, jnp.floating) else x.dtype, sharding=one_chip), tree)
 
-    params = on_chip(jax.eval_shape(model.init_params), jnp.bfloat16)
+    params = _serving_params(model, one_chip)
     pool = jnp.zeros((2, slots, cfg.num_kv_heads, cfg.head_dim), jnp.bfloat16)
     zero = jnp.int32(0)
     kv = on_chip(BlockedKV(pool, pool, MoeCounters(
@@ -502,10 +595,7 @@ def test_the_experts_combine_scatters_nothing(one_chip, program, monkeypatch):
     def on_chip(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    params = jax.tree_util.tree_map(
-        lambda x: on_chip(x.shape, jnp.bfloat16 if jnp.issubdtype(
-            x.dtype, jnp.floating) else x.dtype),
-        jax.eval_shape(model.init_params))
+    params = _serving_params(model, one_chip)
     zero = on_chip(())
     kv = BlockedKV(on_chip((2, g["slots"], g["row"]), jnp.bfloat16), None,
                    MoeCounters(on_chip((1, cfg.num_experts)), zero, zero,
@@ -559,10 +649,7 @@ def test_the_forwards_take_decode_tokens_from_the_sampler(one_chip, program):
     def on_chip(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    params = jax.tree_util.tree_map(
-        lambda x: on_chip(x.shape, jnp.bfloat16 if jnp.issubdtype(
-            x.dtype, jnp.floating) else x.dtype),
-        jax.eval_shape(model.init_params))
+    params = _serving_params(model, one_chip)
     pool = on_chip((2, blocks * bs, cfg.num_kv_heads, 128), jnp.bfloat16)
     kv = BlockedKV(pool, pool)
     sampled = on_chip((seqs + 1,))
@@ -604,6 +691,56 @@ def test_the_two_kernels_carry_their_names_onto_the_custom_call(one_chip):
             for ln in calls), calls
 
 
+# ------------------------------------- no forward copies a weight it is handed
+# the benchmark's configurations whose forwards project q, k and v through
+# ``model._qkv`` or a lightning layer's three products (the latent ones
+# project through their ranks)
+WEIGHT_COPY_CONFIGS = (
+    "ouro-2.6b", "command-a-plus-ep8-d4", "brumby-14b-d8", "falcon-h1-34b-d6",
+    "minicpm-sala-d12", "phi-2", "nemotron3-nano-ep4-d26",
+    "solar-open2-ep8-d4", "olmoe-1b-7b-d10", "keye-vl2-30b-a3b-ep8-d12")
+_COPY = re.compile(r"%?([\w.\-]+) = (bf16|f32)\[([\d,]+)\]\S* copy\(")
+
+
+def _weight_copies(text, params, floor=8 << 20):
+    """The ``copy`` instructions of a compiled ``text`` that write ``floor``
+    bytes or more in the shape of a weight: a leaf of ``params`` whole or
+    one layer's slice of a stacked leaf, its dims in any order (the bitcast
+    before a copy may have turned them) and without the 1s."""
+    def dims(shape):
+        return tuple(sorted(d for d in shape if d > 1))
+
+    weights = {dims(shape) for leaf in jax.tree_util.tree_leaves(params)
+               for shape in (leaf.shape, leaf.shape[1:])}
+    found = []
+    for name, dtype, shape in _COPY.findall(text):
+        shape = [int(d) for d in shape.split(",")]
+        nbytes = (2 if dtype == "bf16" else 4) * functools.reduce(
+            lambda a, b: a * b, shape)
+        if nbytes >= floor and dims(shape) in weights:
+            found.append(f"{name} {dtype}{shape}")
+    return found
+
+
+@pytest.mark.parametrize("program", ["decode_forward", "ragged_forward"])
+@pytest.mark.parametrize("config", WEIGHT_COPY_CONFIGS)
+def test_the_serving_forwards_copy_no_weight(one_chip, config, program):
+    """Both serving forwards of ten families WHOLE at their cells' shapes,
+    handed the weights as the engine holds them (``model.serving_layout``):
+    the compiled text copies no parameter-shaped buffer of 8 MiB or more.
+    Handed the model's public ``[in, out]`` tree the v5e compiler re-laid
+    the q, k and v projections' weights inside the program, every forward:
+    Ouro's three stacks ``[48, 2048, 2048]`` (1.13 GiB of temporaries, 3 ms
+    of 43), SALA's three lightning stacks ``[9, 4096, 4096]``, Command A+'s
+    ``wq`` ``[4096, 16384]`` a layer behind a slice of its own, one or three
+    slices a layer in Brumby, Falcon-H1, Nemotron-3, Solar-Open2, OLMoE and
+    Keye; phi-2 alone copied nothing (PERF.md section 6, PR 64). What stays is the layer's slice into ``S(1)``: the
+    compiler's prefetch of the next operand, in the layout it is stored
+    in."""
+    compiled, _kv, params = _cell_forward(one_chip, config, program)
+    assert not _weight_copies(compiled.as_text(), params)
+
+
 # ----------------------------------- a looped stack holds its pool ONCE
 @pytest.mark.parametrize("program", ["decode_forward", "ragged_forward"])
 def test_the_looped_forwards_hold_the_pool_once(one_chip, program):
@@ -613,55 +750,21 @@ def test_the_looped_forwards_hold_the_pool_once(one_chip, program):
     the layer scan inside it does, in place. A copy of the carry through the
     nested loops would be a second pool, which the chip's 15.75 GiB do not
     hold beside 4.97 GiB of weights: the whole pool is aliased to the
-    result and the temporaries are 1.13 GiB, which are the q, k and v
-    stacks [48, 2048, 2048] laid out transposed once a forward (the v5e
-    compiler's choice as soon as a second loop walks the stack: the same
-    three copies with four layer scans in sequence, none at one pass):
-    arguments + temporaries less what is aliased are 13.6 GiB. The kernels
-    are the paged custom calls and the passes' scopes reach the compiled
-    text (``benchmark/scopes.py``)."""
+    result and the temporaries are a few MiB (handed ``[in, out]`` weights
+    the v5e compiler laid the q, k and v stacks out transposed once a
+    forward, 1.13 GiB: ``model.serving_layout``): arguments + temporaries
+    less what is aliased are 12.5 GiB. The kernels are the paged custom
+    calls and the passes' scopes reach the compiled text
+    (``benchmark/scopes.py``)."""
     from benchmark import flops, scopes
-    from deepspeedsyclsupport_tpu.inference.v2 import model as M
-    from deepspeedsyclsupport_tpu.inference.v2.kv_cache import BlockedKV
-    from deepspeedsyclsupport_tpu.models import build_model
-    from deepspeedsyclsupport_tpu.ops.paged_attention import default_atom_rows
 
-    model = build_model("ouro-2.6b", dtype="bfloat16")
-    cfg = model.config
-    bs, blocks, seqs, toks, bps = 64, 80, 16, 256, 8
-    atom = default_atom_rows(RaggedInferenceConfig().atom_q_size,
-                             cfg.num_heads, cfg.num_kv_heads, 128, bs, 2)
-
-    def on_chip(shape, dtype=jnp.int32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    params = jax.tree_util.tree_map(
-        lambda x: on_chip(x.shape, jnp.bfloat16 if jnp.issubdtype(
-            x.dtype, jnp.floating) else x.dtype),
-        jax.eval_shape(model.init_params))
-    pool = on_chip((cfg.num_kv_layers, blocks * bs, cfg.num_kv_heads, 128),
-                   jnp.bfloat16)
-    assert pool.shape[0] == 192 and pool.size < 2**31
-    kv = BlockedKV(pool, pool, exit_pass=on_chip((4,)))
-    sampled = on_chip((seqs + 4,))
-    if program == "decode_forward":
-        fn = M.build_decode_forward_fn(model, bs, "pallas")
-        args = (on_chip((seqs,)), on_chip((seqs,)), on_chip((seqs, bps)),
-                on_chip((seqs,), jnp.bool_), sampled, on_chip((seqs,)))
-    else:
-        fn = M.build_ragged_forward_fn(model, bs, "kernel")
-        atoms = seqs + toks // atom + 1
-        args = (on_chip((toks,)), on_chip((toks,)), on_chip((toks,)),
-                on_chip((seqs, bps)), on_chip((seqs,)),
-                on_chip((atoms, atom)), on_chip((atoms,)), on_chip((atoms,)),
-                on_chip((atoms, bps)), on_chip((toks,)), on_chip((seqs,)),
-                on_chip((seqs,)), sampled, on_chip((toks,)))
-    compiled = fn.lower(params, kv, *args).compile()
+    compiled, kv, _params = _cell_forward(one_chip, "ouro-2.6b", program)
+    assert kv.k.shape == (192, 80 * 64, 16, 128) and kv.k.size < 2**31
     gib = flops.program_bytes(compiled) / 2**30
     m = compiled.memory_analysis()
-    assert m.alias_size_in_bytes >= 2 * pool.size * 2
-    assert m.temp_size_in_bytes < 1.25 * 2**30
-    assert 12.0 < gib < 13.75, gib
+    assert m.alias_size_in_bytes >= 2 * kv.k.size * 2
+    assert m.temp_size_in_bytes < 0.05 * 2**30
+    assert 12.4 < gib < 12.6, gib
     text = compiled.as_text()
     assert 'custom_call_target="tpu_custom_call"' in text
     under = scopes.instructions_under(text, ("loop_pass", "loop_exit"))
@@ -670,10 +773,9 @@ def test_the_looped_forwards_hold_the_pool_once(one_chip, program):
 
 # ------------------------------------------- sparse attention behind an indexer
 @pytest.mark.parametrize("program", ["decode_forward", "ragged_forward"])
-def test_the_indexer_forwards_compile_and_copy_no_pool(one_chip, program,
-                                                       monkeypatch):
-    """Both serving forwards of ``keye-vl2-30b-a3b`` at the cell's widths
-    and shapes (two layers of the twelve; 8 sequences, 768 rows, contexts to
+def test_the_indexer_forwards_compile_and_copy_no_pool(one_chip, program):
+    """Both serving forwards of ``keye-vl2-30b-a3b`` WHOLE at the cell's
+    widths and shapes (twelve layers; 8 sequences, 768 rows, contexts to
     49,152, the whole pool of 6,272 blocks a layer): the three kernels of
     the selection path are custom calls under their own names
     (``dsa_index_scores``, ``dsa_select``, the ragged kernel under a mask as
@@ -688,49 +790,14 @@ def test_the_indexer_forwards_compile_and_copy_no_pool(one_chip, program,
     64-wide row of its own was stored slot-minor and copied whole every
     layer: 48 ms a forward on the v5e, PERF.md section 6, PR 45)."""
     from benchmark import scopes
-    from deepspeedsyclsupport_tpu.inference.v2 import model as M
-    from deepspeedsyclsupport_tpu.inference.v2.kv_cache import (BlockedKV,
-                                                                MoeCounters)
-    from deepspeedsyclsupport_tpu.models import build_model
-    from deepspeedsyclsupport_tpu.ops import grouped_gemm as gg
 
-    monkeypatch.setattr(gg, "default_impl", lambda: "pallas")
-    layers = 2
-    model = build_model("keye-vl2-30b-a3b", num_layers=layers,
-                        num_experts_held=16, vocab_size=18992,
-                        dtype="bfloat16")
-    cfg = model.config
-    bs, blocks, seqs, toks, bps, atom = 64, 6272, 8, 768, 768, 128
-
-    def on_chip(shape, dtype=jnp.int32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    params = jax.tree_util.tree_map(
-        lambda x: on_chip(x.shape, jnp.bfloat16 if jnp.issubdtype(
-            x.dtype, jnp.floating) else x.dtype),
-        jax.eval_shape(model.init_params))
-    pool = on_chip((layers, blocks * bs, cfg.num_kv_heads, 128), jnp.bfloat16)
-    idx = on_chip((layers, blocks * bs // 2, 2 * cfg.index_head_dim),
-                  jnp.bfloat16)
-    zero = on_chip(())
-    kv = BlockedKV(pool, pool, MoeCounters(on_chip((layers, 128)), zero,
-                                           zero, zero), idx=idx)
-    sampled = on_chip((seqs + 3,))
-    if program == "decode_forward":
-        fn = M.build_decode_forward_fn(model, bs, "pallas")
-        args = (on_chip((seqs,)), on_chip((seqs,)), on_chip((seqs, bps)),
-                on_chip((seqs,), jnp.bool_), sampled, on_chip((seqs,)))
-        kernels = {"dsa_index_scores", "dsa_select"}
-    else:
-        fn = M.build_ragged_forward_fn(model, bs, "kernel")
-        atoms = seqs + toks // atom + 1
-        args = (on_chip((toks,)), on_chip((toks,)), on_chip((toks,)),
-                on_chip((seqs, bps)), on_chip((seqs,)),
-                on_chip((atoms, atom)), on_chip((atoms,)), on_chip((atoms,)),
-                on_chip((atoms, bps)), on_chip((toks,)), on_chip((seqs,)),
-                on_chip((seqs,)), sampled, on_chip((toks,)))
-        kernels = {"dsa_index_scores", "dsa_select", "dsa_prefill"}
-    compiled = fn.lower(params, kv, *args).compile()
+    compiled, kv, _params = _cell_forward(
+        one_chip, "keye-vl2-30b-a3b-ep8-d12", program)
+    layers, slots, seqs, bps, index_dim = 12, 6272 * 64, 8, 768, 64
+    assert kv.k.shape[:2] == (layers, slots)
+    assert kv.idx.shape == (layers, slots // 2, 2 * index_dim)
+    kernels = {"dsa_index_scores", "dsa_select"} | (
+        {"dsa_prefill"} if program == "ragged_forward" else set())
     text = compiled.as_text()
     calls = {ln.split(" = ")[0].strip().lstrip("%").split(".")[0]
              for ln in text.splitlines()
@@ -743,14 +810,13 @@ def test_the_indexer_forwards_compile_and_copy_no_pool(one_chip, program,
     lines = [ln for ln in text.splitlines() if "op_name=" in ln]
     assert not [ln for ln in lines if "/dsa_select/" in ln
                 and re.search(r" (sort|scatter)\(", ln)]
-    keys = f"f32[{seqs},{bps * bs},{cfg.index_head_dim}]"
+    keys = f"f32[{seqs},{bps * 64},{index_dim}]"
     assert not [ln for ln in lines if "/dsa_index/" in ln and keys in ln]
     m = compiled.memory_analysis()
-    pools = (2 * pool.size + idx.size) * 2
-    assert m.alias_size_in_bytes >= pools
+    assert m.alias_size_in_bytes >= (2 * kv.k.size + kv.idx.size) * 2
     assert m.temp_size_in_bytes < 0.5 * 2**30      # a pool's layer is 0.4
     assert not [ln for ln in text.splitlines()
-                if " copy(" in ln and f"bf16[{layers},{blocks * bs // 2}," in ln]
+                if " copy(" in ln and f"bf16[{layers},{slots // 2}," in ln]
 
 
 # ----------------------------------------- power retention, the cell's widths
@@ -805,8 +871,7 @@ def test_the_retention_kernels_compile_at_the_cells_widths(one_chip, entry):
 
 # ------------------------- two attention kinds, two pools, the cell's widths
 @pytest.mark.parametrize("program", ["decode_forward", "ragged_forward"])
-def test_the_two_kind_forwards_compile_at_the_cells_widths(one_chip, program,
-                                                           monkeypatch):
+def test_the_two_kind_forwards_compile_at_the_cells_widths(one_chip, program):
     """Both serving forwards of ``command-a-plus`` at the cell's widths and
     shapes (ONE period of four layers, 16 of 128 experts held; 24 sequences,
     768 rows, contexts to 66,560, both whole pools): 128 query heads over 8
@@ -815,59 +880,27 @@ def test_the_two_kind_forwards_compile_at_the_cells_widths(one_chip, program,
     kernels are custom calls under a name a KIND (``paged_swa_*`` on the
     windowed layers' pool and table, ``paged_full_*`` on the full layer's),
     the kinds' scopes reach the compiled text, all four pools are aliased to
-    the result, and ``argument_size`` is what the configuration's file
-    says is resident: 13.17 GiB."""
+    the result, ``argument_size`` is what the configuration's file
+    says is resident, 13.17 GiB, and the temporaries stay under 0.35 GiB
+    (0.31; 0.71 while each layer's ``wq`` was sliced out of the ``[in,
+    out]`` stack and turned, 640 MiB of it)."""
     from benchmark import scopes
-    from deepspeedsyclsupport_tpu.inference.v2 import model as M
     from deepspeedsyclsupport_tpu.inference.v2.kv_cache import (
-        BlockedKV, MoeCounters, window_blocks_a_sequence)
-    from deepspeedsyclsupport_tpu.models import build_model
-    from deepspeedsyclsupport_tpu.ops import grouped_gemm as gg
+        window_blocks_a_sequence)
     from deepspeedsyclsupport_tpu.ops.paged_attention import default_atom_rows
 
-    monkeypatch.setattr(gg, "default_impl", lambda: "pallas")
-    model = build_model("command-a-plus", num_layers=4, num_experts_held=16,
-                        vocab_size=32768, dtype="bfloat16")
-    cfg = model.config
     engine = RaggedInferenceConfig(
         block_size=64, num_blocks=12288, max_sequences=24,
         max_tokens_per_batch=768, max_context=66560)
-    bs, seqs, toks, bps = 64, 24, 768, engine.blocks_per_seq
-    atom = default_atom_rows(128, cfg.num_heads, cfg.num_kv_heads, 128, bs, 2)
-    assert (atom, bps, window_blocks_a_sequence(4096, engine)) \
-        == (64, 1040, 77)
-
-    def on_chip(shape, dtype=jnp.int32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    params = jax.tree_util.tree_map(
-        lambda x: on_chip(x.shape, jnp.bfloat16 if jnp.issubdtype(
-            x.dtype, jnp.floating) else x.dtype),
-        jax.eval_shape(model.init_params))
-    full = on_chip((1, engine.num_blocks * bs, 8, 128), jnp.bfloat16)
-    window = on_chip((3, seqs * 77 * bs, 8, 128), jnp.bfloat16)
-    zero = on_chip(())
-    kv = BlockedKV(full, full, MoeCounters(on_chip((4, 128)), zero, zero,
-                                           zero), wk=window, wv=window)
-    sampled = on_chip((seqs + 3,))
-    if program == "decode_forward":
-        fn = M.build_decode_forward_fn(model, bs, "pallas")
-        args = (on_chip((seqs,)), on_chip((seqs,)), on_chip((seqs, bps)),
-                on_chip((seqs,), jnp.bool_), sampled, on_chip((seqs,)),
-                None, on_chip((seqs, bps)))
-        kernels = {"paged_swa_decode", "paged_full_decode"}
-    else:
-        fn = M.build_ragged_forward_fn(model, bs, "kernel")
-        atoms = seqs + toks // atom + 1
-        args = (on_chip((toks,)), on_chip((toks,)), on_chip((toks,)),
-                on_chip((seqs, bps)), on_chip((seqs,)),
-                on_chip((atoms, atom)), on_chip((atoms,)), on_chip((atoms,)),
-                on_chip((atoms, bps)), on_chip((toks,)), on_chip((seqs,)),
-                on_chip((seqs,)), sampled, on_chip((toks,)), None,
-                on_chip((seqs, bps)), on_chip((atoms, bps)))
-        kernels = {"paged_swa_decode", "paged_full_decode",
-                   "paged_swa_prefill", "paged_full_prefill"}
-    compiled = fn.lower(params, kv, *args).compile()
+    assert (default_atom_rows(128, 128, 8, 128, 64, 2), engine.blocks_per_seq,
+            window_blocks_a_sequence(4096, engine)) == (64, 1040, 77)
+    compiled, kv, _params = _cell_forward(one_chip, "command-a-plus-ep8-d4",
+                                          program)
+    assert kv.k.shape == (1, 12288 * 64, 8, 128)
+    assert kv.wk.shape == (3, 24 * 77 * 64, 8, 128)
+    kernels = {"paged_swa_decode", "paged_full_decode"} | (
+        {"paged_swa_prefill", "paged_full_prefill"}
+        if program == "ragged_forward" else set())
     text = compiled.as_text()
     calls = {ln.split(" = ")[0].strip().lstrip("%").split(".")[0]
              for ln in text.splitlines()
@@ -878,10 +911,9 @@ def test_the_two_kind_forwards_compile_at_the_cells_widths(one_chip, program,
                                              "moe_shared"))
     assert set(under.values()) == {"attn_swa", "attn_full", "moe_shared"}
     m = compiled.memory_analysis()
-    pools = 2 * (full.size + window.size) * 2
-    assert m.alias_size_in_bytes >= pools
+    assert m.alias_size_in_bytes >= 2 * (kv.k.size + kv.wk.size) * 2
     assert round(m.argument_size_in_bytes / 2**30, 2) == 13.17
-    assert m.temp_size_in_bytes < 0.75 * 2**30
+    assert m.temp_size_in_bytes < 0.35 * 2**30
 
 
 # ----------------------------------- the gated delta rule, the cell's widths
@@ -1011,59 +1043,29 @@ def test_the_convolutions_tail_compiles_at_the_cells_widths(one_chip, cell,
 
 # ------------------- blocks chosen from pooled keys beside a lightning state
 @pytest.mark.parametrize("program", ["decode_forward", "ragged_forward"])
-def test_the_sala_forwards_compile_and_copy_no_pool(one_chip, program,
-                                                    monkeypatch):
-    """Both serving forwards of ``minicpm-sala`` at the cell's widths and
-    shapes (one period of the twelve layers; 8 sequences, 768 rows, contexts
-    to 99,072, the whole pool of 12,544 pages a layer): the selection, the
-    atoms under its mask and the one-token rows over their own page tables
-    are custom calls under their own names (``bsa_select``, ``bsa_prefill``,
-    ``bsa_rows``; ``decode_forward``, all one-token rows, holds no atom) and
-    the lightning state step Mamba-2's kernel, the scopes reach the compiled
-    text, K, V, the pooled keys and the state are aliased to the result and
-    none of them is copied, and no sequence's ``[heads, rows, windows]``
-    probabilities stand whole: a tile at a time."""
+def test_the_sala_forwards_compile_and_copy_no_pool(one_chip, program):
+    """Both serving forwards of ``minicpm-sala`` WHOLE at the cell's widths
+    and shapes (twelve layers and their feed-forward parts; 8 sequences, 768
+    rows, contexts to 99,072, the whole pool of 12,544 pages a layer): the
+    selection, the atoms under its mask and the one-token rows over their
+    own page tables are custom calls under their own names (``bsa_select``,
+    ``bsa_prefill``, ``bsa_rows``; ``decode_forward``, all one-token rows,
+    holds no atom) and the lightning state step Mamba-2's kernel, the scopes
+    reach the compiled text, K, V, the pooled keys and the state are aliased
+    to the result and none of them is copied, and no sequence's ``[heads,
+    rows, windows]`` probabilities stand whole: a tile at a time (0.74 GiB
+    of temporaries; 1.58 while the three lightning stacks ``[9, 4096,
+    4096]`` were laid out transposed once a forward)."""
     from benchmark import scopes
-    from deepspeedsyclsupport_tpu.inference.v2 import model as M
-    from deepspeedsyclsupport_tpu.inference.v2.kv_cache import BlockedKV
-    from deepspeedsyclsupport_tpu.inference.v2.ragged import SsmBatch
-    from deepspeedsyclsupport_tpu.models import build_model
 
-    # no chip is attached: the registry would hand the state step's XLA form
-    monkeypatch.setattr(M, "_state_step_fn", lambda kind: M.select_impl(
-        kind, "pallas", {"backend": "tpu"}).fn)
-    model = build_model("minicpm-sala", num_layers=8,
-                        layer_pattern="*FLFLFLF", dtype="bfloat16")
-    bs, blocks, seqs, toks, bps, atom = 64, 12544, 8, 768, 1548, 128
-
-    def on_chip(shape, dtype=jnp.int32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    params = jax.tree_util.tree_map(
-        lambda x: on_chip(x.shape, jnp.bfloat16 if jnp.issubdtype(
-            x.dtype, jnp.floating) else x.dtype),
-        jax.eval_shape(model.init_params))
-    pool = on_chip((1, blocks * bs, 2, 128), jnp.bfloat16)
-    ck = on_chip((1, blocks, 4, 2, 128), jnp.bfloat16)
-    state = on_chip((3, seqs + 1, 32, 128, 128), jnp.float32)
-    kv = BlockedKV(pool, pool, ck=ck, bsa=on_chip((7,)), la_s=state)
-    if program == "decode_forward":
-        fn = M.build_decode_forward_fn(model, bs, "pallas")
-        args = (on_chip((seqs,)), on_chip((seqs,)), on_chip((seqs, bps)),
-                on_chip((seqs,), jnp.bool_), None, None, on_chip((seqs,)))
-        kernels = {"bsa_select", "bsa_rows", "ssm_state_step"}
-    else:
-        fn = M.build_ragged_forward_fn(model, bs, "kernel")
-        tiles = seqs + toks // atom + 1
-        batch = SsmBatch(*(on_chip((seqs,)),) * 3, *(on_chip((tiles,)),) * 3,
-                         on_chip((tiles,), jnp.bool_), on_chip(()))
-        args = (on_chip((toks,)), on_chip((toks,)), on_chip((toks,)),
-                on_chip((seqs, bps)), on_chip((seqs,)),
-                on_chip((tiles, atom)), on_chip((tiles,)), on_chip((tiles,)),
-                on_chip((tiles, bps)), on_chip((toks,)), on_chip((seqs,)),
-                on_chip((seqs,)), None, None, batch)
-        kernels = {"bsa_select", "bsa_prefill", "bsa_rows", "ssm_state_step"}
-    compiled = fn.lower(params, kv, *args).compile()
+    compiled, kv, _params = _cell_forward(one_chip, "minicpm-sala-d12",
+                                          program)
+    blocks, seqs = 12544, 8
+    assert kv.k.shape == (3, blocks * 64, 2, 128)
+    assert kv.ck.shape == (3, blocks, 4, 2, 128)
+    assert kv.la_s.shape == (9, seqs + 1, 32, 128, 128)
+    kernels = {"bsa_select", "bsa_rows", "ssm_state_step"} | (
+        {"bsa_prefill"} if program == "ragged_forward" else set())
     text = compiled.as_text()
     calls = {ln.split(" = ")[0].strip().lstrip("%").split(".")[0]
              for ln in text.splitlines()
@@ -1075,20 +1077,20 @@ def test_the_sala_forwards_compile_and_copy_no_pool(one_chip, program,
     assert under >= set(labels) - (
         {"bsa_attend", "la_chunk"} if program == "decode_forward" else set())
     m = compiled.memory_analysis()
-    held = (2 * pool.size + ck.size) * 2 + state.size * 4
+    held = (2 * kv.k.size + kv.ck.size) * 2 + kv.la_s.size * 4
     assert m.alias_size_in_bytes >= held
     # a tile's probabilities [32, 128, 6192] float32 are 0.1 GB; all of a
     # chunk's at once would be 0.6
-    assert m.temp_size_in_bytes < 1.75 * 2**30, m.temp_size_in_bytes
+    assert m.temp_size_in_bytes < 2**30, m.temp_size_in_bytes
     assert not [ln for ln in text.splitlines() if " copy(" in ln and (
-        f"bf16[1,{blocks * bs}," in ln or f"bf16[1,{blocks},4," in ln
-        or f"f32[3,{seqs + 1},32," in ln)]
+        f"bf16[3,{blocks * 64}," in ln or f"bf16[3,{blocks},4," in ln
+        or f"f32[9,{seqs + 1},32," in ln)]
 
 
 # ------------- attention heads and a Mamba-2 mixer side by side in a layer
 @pytest.mark.parametrize("program", ["decode_forward", "ragged_forward"])
-def test_the_side_by_side_forwards_compile_at_the_cells_widths(
-        one_chip, program, monkeypatch):
+def test_the_side_by_side_forwards_compile_at_the_cells_widths(one_chip,
+                                                               program):
     """Both serving forwards of ``falcon-h1-34b`` at ``falconh1-chat-sat``'s
     widths and shapes, the six layers whole (48 sequences, 768 rows,
     contexts to 4,608, the pool of 3,520 pages and 49 state slots behind
@@ -1100,49 +1102,15 @@ def test_the_side_by_side_forwards_compile_at_the_cells_widths(
     state and the tails are aliased to the result and none of them is
     copied, and the temporaries stay under a GiB (0.04 GiB)."""
     from benchmark import scopes
-    from deepspeedsyclsupport_tpu.inference.v2 import model as M
-    from deepspeedsyclsupport_tpu.inference.v2.kv_cache import BlockedKV
-    from deepspeedsyclsupport_tpu.inference.v2.ragged import SsmBatch
-    from deepspeedsyclsupport_tpu.models import build_model
 
-    # no chip is attached: the registry would hand the steps' XLA forms
-    monkeypatch.setattr(M, "_state_step_fn", lambda kind: M.select_impl(
-        kind, "pallas", {"backend": "tpu"}).fn)
-    model = build_model("falcon-h1-34b", num_layers=12,
-                        layer_pattern="HF" * 6, dtype="bfloat16")
-    assert model.config.num_heads // model.config.num_kv_heads == 5
-    bs, blocks, seqs, toks, bps, atom = 64, 3520, 48, 768, 72, 128
-
-    def on_chip(shape, dtype=jnp.int32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    params = jax.tree_util.tree_map(
-        lambda x: on_chip(x.shape, jnp.bfloat16 if jnp.issubdtype(
-            x.dtype, jnp.floating) else x.dtype),
-        jax.eval_shape(model.init_params))
-    pool = on_chip((6, blocks * bs, 4, 128), jnp.bfloat16)
-    state = on_chip((6, seqs + 1, 2, 256, 2048), jnp.float32)
-    tails = on_chip((6, 3, seqs + 1, 5120), jnp.bfloat16)
-    assert state.size // (6 * (seqs + 1)) * 4 == 4 << 20
-    kv = BlockedKV(pool, pool, ssm=state, conv=tails)
-    if program == "decode_forward":
-        fn = M.build_decode_forward_fn(model, bs, "pallas")
-        args = (on_chip((seqs,)), on_chip((seqs,)), on_chip((seqs, bps)),
-                on_chip((seqs,), jnp.bool_), None, None, on_chip((seqs,)))
-        kernels = {"paged_decode", "ssm_state_step", "conv_tail_step"}
-    else:
-        fn = M.build_ragged_forward_fn(model, bs, "kernel")
-        tiles = seqs + toks // atom + 1
-        batch = SsmBatch(*(on_chip((seqs,)),) * 3, *(on_chip((tiles,)),) * 3,
-                         on_chip((tiles,), jnp.bool_), on_chip(()))
-        args = (on_chip((toks,)), on_chip((toks,)), on_chip((toks,)),
-                on_chip((seqs, bps)), on_chip((seqs,)),
-                on_chip((tiles, atom)), on_chip((tiles,)), on_chip((tiles,)),
-                on_chip((tiles, bps)), on_chip((toks,)), on_chip((seqs,)),
-                on_chip((seqs,)), None, None, batch)
-        kernels = {"paged_decode", "ragged_prefill", "ssm_state_step",
-                   "conv_tail_step"}
-    compiled = fn.lower(params, kv, *args).compile()
+    compiled, kv, _params = _cell_forward(one_chip, "falcon-h1-34b-d6",
+                                          program)
+    blocks, seqs = 3520, 48
+    assert kv.k.shape == (6, blocks * 64, 4, 128)
+    assert kv.ssm.shape == (6, seqs + 1, 2, 256, 2048)
+    assert kv.conv.shape == (6, 3, seqs + 1, 5120)
+    kernels = {"paged_decode", "ssm_state_step", "conv_tail_step"} | (
+        {"ragged_prefill"} if program == "ragged_forward" else set())
     text = compiled.as_text()
     calls = {ln.split(" = ")[0].strip().lstrip("%").split(".")[0]
              for ln in text.splitlines()
@@ -1160,9 +1128,10 @@ def test_the_side_by_side_forwards_compile_at_the_cells_widths(
         assert {v for k, v in under.items()
                 if k.split(".")[0] == call} == {label}, call
     m = compiled.memory_analysis()
-    held = 2 * pool.size * 2 + state.size * 4 + tails.size * 2
+    held = 2 * kv.k.size * 2 + kv.ssm.size * 4 + kv.conv.size * 2
     assert m.alias_size_in_bytes >= held
+    assert round(m.argument_size_in_bytes / 2**30, 2) == 13.52
     assert m.temp_size_in_bytes < 2**30, m.temp_size_in_bytes
     assert not [ln for ln in text.splitlines() if " copy(" in ln and (
-        f"bf16[6,{blocks * bs}," in ln or f"f32[6,{seqs + 1},2," in ln
+        f"bf16[6,{blocks * 64}," in ln or f"f32[6,{seqs + 1},2," in ln
         or f"bf16[6,3,{seqs + 1}," in ln)]

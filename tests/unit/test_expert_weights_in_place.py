@@ -74,7 +74,8 @@ def built(request):
 def _built(name):
     """The model and seeded weights with EVERY leaf moved off its init (the
     Xing4 preset draws its routed experts at 1/E of the shared one's). Drawn
-    in ONE program: a draw a leaf is a program a shape otherwise."""
+    in ONE program: a draw a leaf is a program a shape otherwise. In the
+    layout the forwards take (``M.serving_layout``)."""
     preset, widths = MODELS[name]
     model = build_model(preset, **widths)
 
@@ -85,7 +86,7 @@ def _built(name):
         return jax.tree_util.tree_unflatten(tree, [
             x + 0.1 * jax.random.normal(k, x.shape)
             for x, k in zip(leaves, keys)])
-    return model, jax.jit(drawn)()
+    return model, M.serving_layout(jax.jit(drawn)())
 
 
 def _pool(cfg, seed=0):

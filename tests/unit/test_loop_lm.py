@@ -191,10 +191,10 @@ def test_one_pass_without_the_extra_norms_is_the_program_that_stood():
     i32 = lambda *shape: jnp.zeros(shape, jnp.int32)  # noqa: E731
     dec = jax.make_jaxpr(lambda p, kv, *a: M.decode_forward(
         model, p, kv, *a, block_size=16, attn_impl="xla"))(
-        params, eng.kv, i32(s), i32(s), i32(s, 8), jnp.zeros((s,), bool))
+        eng._params, eng.kv, i32(s), i32(s), i32(s, 8), jnp.zeros((s,), bool))
     rag = jax.make_jaxpr(lambda p, kv, *a: M.ragged_forward(
         model, p, kv, *a, block_size=16, attn_impl="xla"))(
-        params, eng.kv, i32(t), i32(t), i32(t), i32(s, 8), i32(s))
+        eng._params, eng.kv, i32(t), i32(t), i32(t), i32(s, 8), i32(s))
     for text in (str(dec), str(rag)):
         assert "loop_pass" not in text and "loop_exit" not in text
         assert text.count("scan[") == 1      # the layer scan alone
@@ -202,7 +202,7 @@ def test_one_pass_without_the_extra_norms_is_the_program_that_stood():
     leng = engine_of(looped, lparams)
     text = str(jax.make_jaxpr(lambda p, kv, *a: M.decode_forward(
         looped, p, kv, *a, block_size=16, attn_impl="xla"))(
-        lparams, leng.kv, i32(s), i32(s), i32(s, 8), jnp.zeros((s,), bool)))
+        leng._params, leng.kv, i32(s), i32(s), i32(s, 8), jnp.zeros((s,), bool)))
     assert text.count("scan[") == 2          # passes around layers
 
 

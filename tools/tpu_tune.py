@@ -80,8 +80,20 @@ Sections, cheapest first:
             ``jax.numpy`` forms on the chip first:
             bsa [--ctx N ...] [--parity]
 
+  proj    — the q and the k/v projection ALONE, ``[T, in] x W`` at 16 /
+            32 / 48 / 256 / 768 rows and every served cell's ``(in, out)``
+            (read off ``BENCHMARK.json``'s configurations; a lightning
+            layer's among them), W stored ``[in, out]`` (the model's public
+            tree) and ``[out, in]`` (``model.serving_layout``), sliced from
+            a stack inside a scan as the forwards slice it, ``--passes`` of
+            the stack (a looped model's second loop is what makes the v5e
+            compiler copy a whole ``[in, out]`` stack): us a layer, the GB/s
+            of the weights and the MiB of temporaries of each program, and
+            the largest copy in its text:
+            proj [--cell C ...] [--rows N ...] [--layers N] [--passes N]
+
 Usage:  python tools/tpu_tune.py
-            [calib|flash|paged|retention|dsa|kda|conv|combine|bsa|all]
+            [calib|flash|paged|retention|dsa|kda|conv|combine|bsa|proj|all]
 """
 import functools
 import json
@@ -1545,26 +1557,31 @@ def conv(argv=()):
 COMBINE_ROWS = (32, 128, 768)     # tokens a forward
 
 
-def _combine_cells():
-    """``(k, d, experts, held)`` of every cell of ``BENCHMARK.json`` that
-    serves sparse experts, off the preset and overrides its configuration's
-    file names: the shapes the served combine runs at."""
+def _serve_configs():
+    """``{cell: ModelConfig}`` of every serving cell of ``BENCHMARK.json``,
+    off the preset and overrides its configuration's file names."""
     from deepspeedsyclsupport_tpu.models import get_config
 
     root = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
     files = {c["name"]: c["file"] for c in bench["configs"]}
-    cells = {}
+    out = {}
     for w in bench["workloads"]:
         with open(os.path.join(root, files[w["config"]])) as f:
             cfg = json.load(f)
-        m = get_config(cfg["preset"], **cfg.get("overrides", {}))
-        if m.num_experts:
-            cells[w["name"]] = dict(k=m.num_experts_per_tok, d=m.hidden_size,
-                                    experts=m.num_experts,
-                                    held=m.experts_held)
-    return cells
+        if cfg["path"] == "serve":
+            out[w["name"]] = get_config(cfg["preset"],
+                                        **cfg.get("overrides", {}))
+    return out
+
+
+def _combine_cells():
+    """``(k, d, experts, held)`` of every cell of ``BENCHMARK.json`` that
+    serves sparse experts: the shapes the served combine runs at."""
+    return {name: dict(k=m.num_experts_per_tok, d=m.hidden_size,
+                       experts=m.num_experts, held=m.experts_held)
+            for name, m in _serve_configs().items() if m.num_experts}
 
 
 def _combine_operands(c, t, seed=0):
@@ -1884,6 +1901,109 @@ def bsa(argv=()):
          if kernel else None)
 
 
+# ---------------------------------------------------------------- proj
+PROJ_ROWS = (16, 32, 48, 256, 768)
+# how a product reads its weight, by the layout the stack is stored in
+PROJ_LAYOUTS = {"in_out": "td,dq->tq", "out_in": "td,qd->tq"}
+
+
+def _proj_cells():
+    """``{cell: {"q" | "kv" | "la": (in, out)}}`` of every serving cell whose
+    forwards run :func:`model._qkv` (a latent model projects through its
+    ranks) or a lightning layer's three products."""
+    cells = {}
+    for name, m in _serve_configs().items():
+        if m.kv_lora_rank:
+            continue
+        cells[name] = {"q": (m.hidden_size, m.q_dim),
+                       "kv": (m.hidden_size, m.kv_dim)}
+        if m.lightning_heads:
+            cells[name]["la"] = (m.hidden_size,
+                                 m.lightning_heads * m.lightning_head_dim)
+    return cells
+
+
+def _proj_program(layout, passes):
+    """``passes`` walks of a stack of weights, each a scan whose xs are the
+    stack (the layer loop of the serving forwards; the outer loop a looped
+    model's): the rows times the layer's weight, summed over the layers."""
+    def walk(x, stack):
+        def layer(acc, w):
+            y = jnp.einsum(PROJ_LAYOUTS[layout], x, w)
+            return acc + y.astype(jnp.float32), None
+
+        def one_pass(acc, _):
+            return jax.lax.scan(layer, acc, stack)[0], None
+
+        acc = jnp.zeros((x.shape[0], stack.shape[1 if layout == "out_in"
+                                                  else 2]), jnp.float32)
+        return jax.lax.scan(one_pass, acc, None, length=passes)[0]
+    return walk
+
+
+def _largest_copy(text):
+    """``(MiB, shape as the text writes it)`` of the largest ``copy`` of a
+    compiled program's text, ``(0, None)`` where it has none."""
+    import re
+
+    best = (0.0, None)
+    for m in re.finditer(r"= (bf16|f32)\[([\d,]+)\]\S* copy\(", text):
+        dims = [int(d) for d in m.group(2).split(",")]
+        mib = np.prod(dims) * (2 if m.group(1) == "bf16" else 4) / 2**20
+        if mib > best[0]:
+            best = (round(float(mib), 1), f"{m.group(1)}[{m.group(2)}]")
+    return best
+
+
+def proj(argv=()):
+    """The q / k / v projection alone, both stored layouts, one program a
+    (shape, rows, layout): see the module's text. Each row: us a layer (the
+    program's device time off a profiler trace over layers x passes), the
+    GB/s of the weights it reads, the program's temporaries and its largest
+    copy."""
+    import argparse
+
+    cells = _proj_cells()
+    ap = argparse.ArgumentParser(prog="tpu_tune.py proj")
+    ap.add_argument("--cell", nargs="*", default=list(cells))
+    ap.add_argument("--rows", type=int, nargs="*", default=list(PROJ_ROWS))
+    ap.add_argument("--layers", type=int, default=4)
+    ap.add_argument("--passes", type=int, default=1)
+    a = ap.parse_args(list(argv))
+    shapes = {}                   # (in, out) -> the cells' products there
+    for cell in a.cell:
+        for which, shape in cells[cell].items():
+            shapes.setdefault(shape, []).append(f"{cell}:{which}")
+    for (d_in, d_out), users in shapes.items():
+        key = jax.random.PRNGKey(d_in + d_out)
+        stack = (jax.random.normal(key, (a.layers, d_in, d_out), jnp.float32)
+                 * d_in ** -0.5).astype(jnp.bfloat16)
+        stacks = {"in_out": stack,
+                  "out_in": jax.block_until_ready(jnp.swapaxes(stack, 1, 2))}
+        for t in a.rows:
+            x = jax.random.normal(jax.random.fold_in(key, t), (t, d_in),
+                                  jnp.bfloat16)
+            rows = {}
+            for tag, layout in enumerate(PROJ_LAYOUTS):
+                args = (x, stacks[layout])
+                step = _named(layout, _proj_program(layout, a.passes),
+                              tag).lower(*args).compile()
+                got = _traced_kernels({layout: step}, args,
+                                      kernel_of=lambda text: "kernel")
+                us = 1e3 * got[layout]["xla"] / (a.layers * a.passes)
+                mib, shape = _largest_copy(step.as_text())
+                rows[layout] = {
+                    "us": round(us, 2),
+                    "gb_s": round(d_in * d_out * 2 / us / 1e3, 1)
+                    if us else None,
+                    "temp_mib": round(step.memory_analysis(
+                    ).temp_size_in_bytes / 2**20, 1),
+                    "copy_mib": mib, "copy": shape,
+                    "sum": float(jnp.sum(step(*args)[0]))}
+            emit("proj", d_in=d_in, d_out=d_out, t=t, layers=a.layers,
+                 passes=a.passes, cells=users, rows=rows)
+
+
 def _combine_float64(args):
     """The combine's sum on the host in float64, token by token."""
     ys, gate_w, has_expert, _here, order, sorted_tok, dest, _sizes = (
@@ -1917,3 +2037,5 @@ if __name__ == "__main__":
         combine(sys.argv[2:] if which == "combine" else ())
     if which in ("bsa", "all"):
         bsa(sys.argv[2:] if which == "bsa" else ())
+    if which in ("proj", "all"):
+        proj(sys.argv[2:] if which == "proj" else ())
