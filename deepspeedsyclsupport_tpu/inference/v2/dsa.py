@@ -1,15 +1,22 @@
 """Sparse attention behind a learned indexer, on the serving path
 (``ModelConfig.index_topk``; DeepSeek-V3.2's recipe under the sizes
-KeyeVL2's ``sa_config`` publishes).
+KeyeVL2's ``sa_config`` and GLM-5's config publish), over a pool of K and V
+a KV head (Keye) or over a LATENT pool, one row a token for all heads and no
+V (``ModelConfig.kv_lora_rank``: GLM-5): one module, the pool's kind by the
+configuration.
 
-Beside q, k and v a token's normed row gives the INDEXER's ``index_heads``
-small queries ``qI``, its ONE key ``kI`` (behind a LayerNorm; both rotated
-over their whole width) and a weight a head ``w``. ``kI`` is cached as k and
-v are, in the pool's third array at the token's slot
-(``kv_cache.BlockedKV.idx``). A query scores every cached token of its
-sequence, ``I(t, s) = (heads x dim)^-1/2 x sum_j w_t[j] relu(qI_t[j] .
-kI_s)``, keeps the ``index_topk`` best of those it may see (ties to the
-lower position; all while there are no more) and attends over those alone.
+Beside the attention's own rows a token gives the INDEXER's ``index_heads``
+small queries ``qI``, its ONE key ``kI`` (behind a LayerNorm) and a weight a
+head ``w``. The key and the weights are projections of the layer's normed
+row; the queries too (Keye), or of latent attention's normed query latent
+``c_q`` (``ModelConfig.index_q_latent``: the recipe's own, GLM-5's). Queries
+and key are rotated over their leading ``index_rope_dim`` dims (0: their
+whole width). ``kI`` is cached as the attention's rows are, in the pool's
+last array at the token's slot (``kv_cache.BlockedKV.idx``). A query scores
+every cached token of its sequence, ``I(t, s) = (heads x dim)^-1/2 x sum_j
+w_t[j] relu(qI_t[j] . kI_s)``, keeps the ``index_topk`` best of those it may
+see (ties to the lower position; all while there are no more) and attends
+over those alone.
 
 Three labels reach the device trace (``jax.named_scope``; the kernels carry
 their own names): ``dsa_index`` (the indexer's projections, norm, rotary,
@@ -22,18 +29,20 @@ same two kernels (``ops/sparse_index.py``: the scores, the exact selection):
 
 * a chunk of two tokens or more, cut into atoms: a tile of the scores
   kernel and of the selection kernel is an atom, its rows at consecutive
-  positions, and the ragged paged kernel runs under the selection's MASK:
-  it visits every cached pair of the atom and keeps the selected (a prefill
-  that reads the selected rows only is not written);
+  positions, and the ragged paged kernel runs under the selection's MASK,
+  over either kind of pool: it visits every cached pair of the atom and
+  keeps the selected (a prefill that reads the selected rows only is not
+  written);
 * a one-token chunk (every row of ``decode_forward``): the scores kernel
   takes each row as a tile of its own (thousands of keys a grid step:
   ``sparse_index.score_keys``), the selection kernel all of them as ONE
   tile whose rows stand each at its own sequence's last position and which
   walks up to the longest's; the mask's set is read out as positions
   (``sparse_index.positions_from_mask``: no sort, no scatter), the selected
-  rows of K and V are GATHERED through the block table, and softmax
-  attention runs over those ``index_topk`` rows: what it reads of K and V
-  does not grow with the context.
+  rows of the pool are GATHERED through the block table (K and V a KV head,
+  or ONE latent row that serves every head: absorbed attention, the value
+  its leading lanes), and softmax attention runs over those ``index_topk``
+  rows: what it reads of the pool does not grow with the context.
 
 An engine whose attention takes no atoms (``prefill_attn`` ``xla`` or
 ``flash``: the CPU's, the tests') runs every row through the exact
@@ -54,13 +63,20 @@ def index_scale(cfg) -> float:
 
 
 def index_rows(p, y, cfg, positions):
-    """The indexer's part of flat tokens y [n, D]: ``(qI [n, Hi, Di], kI
-    [n, Di], w [n, Hi] float32)``, qI and kI rotated over their whole
-    width at the model's ``rope_theta``."""
+    """The indexer's part of flat tokens: ``(qI [n, Hi, Di], kI [n, Di], w
+    [n, Hi] float32)``, qI and kI rotated over their leading
+    ``cfg.index_rope_dim`` dims (0: their whole width) at the model's
+    ``rope_theta``. ``y``: the layer's normed rows [n, D], which key and
+    weights read and, without ``cfg.index_q_latent``, the queries too; with
+    it the pair ``(y, c_q)``, c_q [n, q_lora_rank] latent attention's normed
+    query latent (``model._mla_query_latent`` makes it), which the queries
+    read."""
+    y, q_in = y if cfg.index_q_latent else (y, y)
     n, hi, di = y.shape[0], cfg.index_heads, cfg.index_head_dim
     rot = lambda t: apply_rope(  # noqa: E731
-        t[None], positions[None], cfg.rope_theta)[0]
-    q_i = rot((y @ p["w_qi"]).reshape(n, hi, di))
+        t[None], positions[None], cfg.rope_theta,
+        rotary_dim=cfg.index_rope_dim or None)[0]
+    q_i = rot((q_in @ p["w_qi"]).reshape(n, hi, di))
     k_i = rot(layer_norm(y @ p["w_ki"], p["ki_norm"]["scale"],
                          p["ki_norm"]["bias"], cfg.rms_norm_eps)[:, None])
     w = jnp.einsum("td,dh->th", y, p["w_w"],
@@ -134,10 +150,21 @@ def rows_walk(impl: str, cfg, c: int, itemsize: int):
             min(c, sparse_index.select_chunk(c)))
 
 
-def attend_atoms(q, q_i, w, k_seq, k_cache, v_cache, layer, ctx, cfg, impl):
+def pool_views(cfg, pools):
+    """``(k_cache, v_cache, v_dim, idx)`` of a layer's ``pools``: (k, v, idx)
+    of a K-and-V pool; (latent, idx) of a latent one, whose value is its
+    rows' leading ``kv_lora_rank`` lanes."""
+    if cfg.kv_lora_rank:
+        return pools[0], None, cfg.kv_lora_rank, pools[1]
+    return pools[0], pools[1], None, pools[2]
+
+
+def attend_atoms(q, q_i, w, k_seq, k_cache, v_cache, layer, ctx, cfg, impl,
+                 v_dim=None):
     """The chunks of two tokens or more: per atom the scores, the selection
-    and the ragged kernel under its mask. -> [T, H, D] in packed rows (the
-    one-token and padding rows gather the reserved dead atom's zeros)."""
+    and the ragged kernel under its mask. -> [T, H, D] ([.., ``v_dim``]
+    over a latent pool) in packed rows (the one-token and padding rows
+    gather the reserved dead atom's zeros)."""
     from ...ops.paged_attention import ragged_prefill_attention
 
     s = ctx.block_tables.shape[0]
@@ -154,21 +181,23 @@ def attend_atoms(q, q_i, w, k_seq, k_cache, v_cache, layer, ctx, cfg, impl):
         out_at = ragged_prefill_attention(
             q[ctx.atom_qidx], k_cache, v_cache, ctx.atom_tables,
             ctx.atom_pos0, ctx.atom_qlen, block_size=ctx.block_size,
-            layer=layer, impl=impl, sel=sel)
+            layer=layer, impl=impl, sel=sel, v_dim=v_dim)
         return out_at.reshape(-1, *out_at.shape[2:])[ctx.atom_inv]
 
 
 def attend_rows(q, q_i, w, k_seq, k_cache, v_cache, layer, block_tables,
-                seq_lens, block_size: int, cfg, impl: str):
+                seq_lens, block_size: int, cfg, impl: str, v_dim=None):
     """One-token rows, one a sequence slot: q [S, H, D], qI [S, Hi, Di], w
     [S, Hi], ``seq_lens`` [S] the slot's length WITH the row's token (0: no
     row). The scores of each row as a tile of its own, the exact
     ``index_topk`` best of all rows as one tile (row s at position
     ``seq_lens[s] - 1``), their positions out of the mask, and attention
-    over the gathered rows of K and V. -> [S, H, D]."""
+    over the gathered rows of K and V, or of a latent pool (``v_cache``
+    None: ONE gathered row serves all H heads, its leading ``v_dim`` lanes
+    the value; the products in the pool's dtype, as the paged kernels feed
+    them). -> [S, H, D] ([S, H, ``v_dim``])."""
     s, h, d = q.shape
     c = k_seq.shape[1]
-    kvh = k_cache.shape[-2]
     k = min(cfg.index_topk, c)
     with jax.named_scope("dsa_index"):
         scores = sparse_index.index_scores(
@@ -184,6 +213,19 @@ def attend_rows(q, q_i, w, k_seq, k_cache, v_cache, layer, block_tables,
         block = jnp.take_along_axis(
             block_tables, jnp.minimum(top, c - 1) // block_size, axis=1)
         slots = jnp.where(live, block * block_size + top % block_size, 0)
+        if v_cache is None:
+            rows = k_cache[layer, slots]                     # [S, k, D]
+            logits = jnp.einsum("shd,scd->shc", q, rows,
+                                preferred_element_type=jnp.float32) \
+                / np.sqrt(d)
+            logits = jnp.where(live[:, None, :], logits, NEG_INF)
+            probs = jax.nn.softmax(logits, axis=-1)
+            out = jnp.einsum("shc,scv->shv", probs.astype(rows.dtype),
+                             rows[..., :v_dim],
+                             preferred_element_type=jnp.float32)
+            out = jnp.where((seq_lens > 0)[:, None, None], out, 0.0)
+            return out.astype(q.dtype)
+        kvh = k_cache.shape[-2]
         k_sel = k_cache[layer, slots].astype(jnp.float32)    # [S, k, KVH, D]
         v_sel = v_cache[layer, slots].astype(jnp.float32)
         q_g = q.astype(jnp.float32).reshape(s, kvh, h // kvh, d)
@@ -195,10 +237,10 @@ def attend_rows(q, q_i, w, k_seq, k_cache, v_cache, layer, block_tables,
         return out.reshape(s, h, d).astype(q.dtype)
 
 
-def attend_tokens(q, q_i, w, k_seq, k_cache, v_cache, layer, ctx, cfg):
+def attend_tokens(q, q_i, w, k_seq, ctx, cfg):
     """Every packed row on its own, through the exact ``jax.numpy`` twins
     (a tile of one row): the route of an attention that takes no atoms."""
-    from .model import _paged_attention
+    from .model import _layer_kv, _paged_attention
 
     t = q.shape[0]
     s = ctx.block_tables.shape[0]
@@ -212,27 +254,27 @@ def attend_tokens(q, q_i, w, k_seq, k_cache, v_cache, layer, ctx, cfg):
             k=cfg.index_topk)[:, 0]
     with jax.named_scope("dsa_attend"):
         return _paged_attention(
-            q, k_cache[layer], v_cache[layer], ctx.token_seq, ctx.token_pos,
+            q, *_layer_kv(ctx), ctx.token_seq, ctx.token_pos,
             ctx.block_tables, ctx.block_size, sel=sel)
 
 
 def ragged_attend(q, q_i, w, pools, layer, ctx, cfg, impl_name: str):
     """Attention of one ``ragged_forward`` layer over the selected keys: q
     [T, H, D] (lane-padded as the pool is), the indexer's rows, the pools
-    AFTER this layer's write, ``ctx`` a ``PrefillAttnContext``. -> [T, H, D].
-    """
-    k_cache, v_cache, idx = pools
+    AFTER this layer's write, ``ctx`` a ``PrefillAttnContext`` (its K, V
+    and ``v_dim`` are the pools'). -> [T, H, D] ([.., ``v_dim``] over a
+    latent pool)."""
+    k_cache, v_cache, v_dim, idx = pool_views(cfg, pools)
     with jax.named_scope("dsa_index"):
         k_seq = seq_index_keys(idx, layer, ctx.block_tables, ctx.block_size)
     if ctx.atom_qidx is None or kernel_impl(impl_name) == "xla":
-        return attend_tokens(q, q_i, w, k_seq, k_cache, v_cache, layer, ctx,
-                             cfg)
+        return attend_tokens(q, q_i, w, k_seq, ctx, cfg)
     out = attend_atoms(q, q_i, w, k_seq, k_cache, v_cache, layer, ctx, cfg,
-                       kernel_impl(impl_name))
+                       kernel_impl(impl_name), v_dim)
     out_dec = attend_rows(
         q[ctx.dec_row], q_i[ctx.dec_row], w[ctx.dec_row], k_seq, k_cache,
         v_cache, layer, ctx.block_tables, ctx.dec_len, ctx.block_size, cfg,
-        kernel_impl(impl_name))
+        kernel_impl(impl_name), v_dim)
     # a slot with no one-token chunk scatters out of range (dropped)
     rows = jnp.where(ctx.dec_len > 0, ctx.dec_row, q.shape[0])
     return out.at[rows].set(out_dec, mode="drop")
@@ -243,8 +285,8 @@ def decode_attend(q, q_i, w, pools, layer, block_tables, seq_lens,
     """Attention of one ``decode_forward`` layer: every row a one-token
     row. ``impl``: the ``decode_attn`` entry's name, the kernels' word as it
     stands."""
-    k_cache, v_cache, idx = pools
+    k_cache, v_cache, v_dim, idx = pool_views(cfg, pools)
     with jax.named_scope("dsa_index"):
         k_seq = seq_index_keys(idx, layer, block_tables, block_size)
     return attend_rows(q, q_i, w, k_seq, k_cache, v_cache, layer,
-                       block_tables, seq_lens, block_size, cfg, impl)
+                       block_tables, seq_lens, block_size, cfg, impl, v_dim)

@@ -10,10 +10,11 @@ over shared weights (``ModelConfig.total_ut_steps``) has one row for every
 pass attends to its own keys and values, so a block of 64 tokens holds all
 ``passes x layers`` rows of them and the allocator, the prefix cache and the
 block copy see blocks as they do for any model. A model with a sparse-
-attention indexer (``ModelConfig.index_topk``) has a THIRD pool beside K and
-V, ``idx``: the indexer's one key a token and layer, as normed and rotated,
-at the SAME flat slot as the token's K and V, so one block table addresses
-all three and a block that is shared, copied, freed or requeued takes its
+attention indexer (``ModelConfig.index_topk``) has ONE MORE pool, ``idx``,
+beside K and V (Keye: three arrays) or beside a latent pool's one array
+(GLM-5: two): the indexer's one key a token and layer, as normed and rotated,
+at the SAME flat slot as the token's other rows, so one block table addresses
+them all and a block that is shared, copied, freed or requeued takes its
 indexer keys with it. It is laid out TWO slots a row, [L, num_blocks *
 block_size / 2, 2 x index_head_dim] (slot s in row s // 2, its key in the
 lanes of its parity; a block's rows stay together): at 64 wide a row of its
@@ -204,7 +205,8 @@ class BlockedKV(NamedTuple):
     @property
     def pools(self):
         """The pool arrays there are, under :data:`POOL_NAMES`: (k, v), (k,)
-        for a latent pool, (k, v, idx) beside a sparse-attention indexer,
+        for a latent pool, (k, v, idx) or a latent pool's (k, idx) beside
+        a sparse-attention indexer,
         (k, v, ck) beside pooled keys, (k, v, wk, wv) for a stack of two
         attention kinds."""
         return tuple(pool for pool in map(self.__getattribute__, POOL_NAMES)

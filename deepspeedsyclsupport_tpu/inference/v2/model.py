@@ -62,6 +62,11 @@ attention heads and a Mamba-2 mixer SIDE BY SIDE in one layer
 (``layer_pattern``'s ``H`` layers, falcon_h1): one norm, both mixers on the
 same normed rows, a KV row and a state slot at the same index, one sum into
 the stream, under muP's twelve multipliers inside the layer (``cfg.mup``).
+And an eleventh: a learned indexer's selection of cached tokens
+(``cfg.index_topk``: ``dsa.py``) over K and V a KV head or INSIDE latent
+attention, over the latent pool's one row a token, its queries then a
+projection of the query latent (``cfg.index_q_latent``:
+:func:`_mla_query_latent` makes it once for both).
 """
 from contextlib import nullcontext
 from typing import Any, NamedTuple, Optional, Tuple
@@ -295,17 +300,31 @@ def _attn_out(p, attn, cfg, n):
 def _q_and_rows(p, y, cfg, positions, pos_embed=None):
     """What attention takes of flat tokens y [n, D]: the positioned queries
     [n, H, D_k] and the rows the pool caches of them, ``(k, v)`` [n, KVH, D]
-    each, or for latent attention the one ``[c_kv | k_r]`` row [n, D_k].
+    each, or for latent attention the one ``[c_kv | k_r]`` row [n, D_k];
+    and third what a sparse-attention indexer reads (``dsa.index_rows``):
+    the normed rows, or with latent attention's query latent beside them.
     ``pos_embed``: the layer's own (:class:`AttnKind`; None: the
     model's)."""
     if cfg.kv_lora_rank:
-        return _mla_rows(p, y, cfg, positions)
+        # (an indexer that reads the query latent: made once for both)
+        c_q = _mla_query_latent(p, y, cfg) if cfg.index_q_latent else None
+        return (*_mla_rows(p, y, cfg, positions, c_q),
+                y if c_q is None else (y, c_q))
     q, k, v = _qkv(p, y, cfg, y.shape[0])
     q, k = _positionize(cfg, q, k, positions, pos_embed or cfg.pos_embed)
-    return q, (k, v)
+    return q, (k, v), y
 
 
-def _mla_rows(p, y, cfg, positions):
+def _mla_query_latent(p, y, cfg):
+    """``c_q = RMSNorm(y W_qa)`` [n, q_lora_rank]: what latent attention's
+    queries are a projection of and, under ``cfg.index_q_latent``, a
+    sparse-attention indexer's queries too."""
+    with scope("mla_proj"):
+        return rms_norm(y @ p["w_qa"], p["q_norm"]["scale"],
+                        cfg.rms_norm_eps)
+
+
+def _mla_rows(p, y, cfg, positions, c_q=None):
     """Latent attention (DeepSeek-V2's MLA) in ABSORBED form. The pool row
     is ``[RMSNorm(c_kv) | rope(k_r)]``, ``kv_lora_rank + qk_rope_head_dim``
     wide, one for all heads. Each head's query is laid against it:
@@ -314,14 +333,18 @@ def _mla_rows(p, y, cfg, positions):
     The value is the row's leading ``kv_lora_rank`` lanes; :func:`_mla_out`
     takes the attended latent up through ``W_UV``. The softmax scale
     (``cfg.softmax_scale``, YaRN's mscale squared in it) rides in q: every
-    impl divides by sqrt of the row's width, so q carries that too."""
+    impl divides by sqrt of the row's width, so q carries that too.
+    ``c_q``: the normed query latent where the caller made it already
+    (:func:`_mla_query_latent`: a sparse-attention indexer reads it too)."""
     n, h = y.shape[0], cfg.num_heads
     r, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
     rot = lambda t: apply_rope(  # noqa: E731
         t[None], positions[None], cfg.rope_theta,
         scaling=cfg.rope_scaling)[0]
     with scope("mla_proj"):
-        c_q = rms_norm(y @ p["w_qa"], p["q_norm"]["scale"], cfg.rms_norm_eps)
+        if c_q is None:
+            c_q = rms_norm(y @ p["w_qa"], p["q_norm"]["scale"],
+                           cfg.rms_norm_eps)
         q = (c_q @ p["w_qb"]).reshape(n, h, -1)
         ckv = y @ p["w_kva"]
         row = jnp.concatenate([
@@ -764,7 +787,7 @@ def _retention_rows(p, y, cfg, positions):
     ``ret_proj``) and the log of each KV head's gate [n, KVH], float32
     (``ret_gate``)."""
     with scope("ret_proj"):
-        q, (k, v) = _q_and_rows(p, y, cfg, positions)
+        q, (k, v), _ = _q_and_rows(p, y, cfg, positions)
     with scope("ret_gate"):
         gam = jax.nn.log_sigmoid(
             y.astype(jnp.float32) @ p["g_proj"].astype(jnp.float32)
@@ -788,22 +811,23 @@ def _pool_write(pools, layer, dest, rows):
 
 
 def _index_write(p_attn, y, cfg, positions, pools, layer, dest, mates):
-    """A sparse-attention indexer's rows of the normed tokens y
-    (``dsa.index_rows``), its keys written into the pool's third array at
-    the slots their K and V went to (``mates``: ``dsa.pair_mates`` of the
-    batch). -> (pools, (qI, w))."""
+    """A sparse-attention indexer's rows of the normed tokens y, or of them
+    and the query latent (``dsa.index_rows``), its keys written into the
+    pools' LAST array at the slots the attention's own rows went to
+    (``mates``: ``dsa.pair_mates`` of the batch). -> (pools, (qI, w))."""
     from .dsa import index_pool_write, index_rows
 
     with jax.named_scope("dsa_index"):
         q_i, k_i, w = index_rows(p_attn, y, cfg, positions)
-        pools = (*pools[:2],
-                 index_pool_write(pools[2], layer, dest, k_i, mates))
+        pools = (*pools[:-1],
+                 index_pool_write(pools[-1], layer, dest, k_i, mates))
     return pools, (q_i, w)
 
 
 def _attn_views(cfg, pools):
     """``(k_cache, v_cache, v_dim, width of the output to keep)`` of the
-    loop-carried ``pools`` for the attention contexts."""
+    loop-carried ``pools`` for the attention contexts (a latent pool's (row,)
+    or, beside a sparse-attention indexer, (row, idx): no V either way)."""
     if cfg.kv_lora_rank:
         return pools[0], None, cfg.kv_lora_rank, cfg.kv_lora_rank
     return pools[0], pools[1], None, cfg.head_dim
@@ -1315,7 +1339,8 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
         tables, tile_tables, to = (window_tables, atom_window_tables, dest_w) \
             if kind.windowed else (block_tables, atom_tables, dest)
         with kind.scope():
-            q, new = _q_and_rows(p_attn, y, cfg, token_pos, kind.pos_embed)
+            q, new, y_idx = _q_and_rows(p_attn, y, cfg, token_pos,
+                                        kind.pos_embed)
             mine = _pool_write(mine, row, to, new)
             q = _lane_pad(q, mine[0].shape[-1], is_q=True)
             k_cache, v_cache, v_dim, keep = _attn_views(cfg, mine)
@@ -1331,7 +1356,7 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
             if cfg.index_topk:    # attention over the indexer's selection
                 from .dsa import ragged_attend
 
-                mine, idx_rows = _index_write(p_attn, y, cfg, token_pos,
+                mine, idx_rows = _index_write(p_attn, y_idx, cfg, token_pos,
                                               mine, row, to, mates)
                 return ragged_attend(q, *idx_rows, mine, row, ctx, cfg,
                                      spec.name)[..., :keep], mine
@@ -1541,7 +1566,8 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
         tables, to = (window_tables, dest_w) if kind.windowed \
             else (block_tables, dest)
         with kind.scope():
-            q, new = _q_and_rows(p_attn, y, cfg, positions, kind.pos_embed)
+            q, new, y_idx = _q_and_rows(p_attn, y, cfg, positions,
+                                        kind.pos_embed)
             mine = _pool_write(mine, row, to, new)
             q = _lane_pad(q, mine[0].shape[-1], is_q=True)
             k_cache, v_cache, v_dim, keep = _attn_views(cfg, mine)
@@ -1550,7 +1576,7 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
 
                 # (no mates: the token beside a row's is never a row here)
                 mine, idx_rows = _index_write(
-                    p_attn, y, cfg, positions, mine, row, to,
+                    p_attn, y_idx, cfg, positions, mine, row, to,
                     jnp.full((s,), -1, jnp.int32))
                 return decode_attend(q, *idx_rows, mine, row, tables,
                                      seq_lens, bs, cfg,

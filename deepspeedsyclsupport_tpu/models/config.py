@@ -107,15 +107,23 @@ class ModelConfig:
     # and before rotary (the Keye / Qwen3 backbones)
     qk_head_norm: bool = False
     # A learned sparse-attention indexer (DeepSeek-V3.2's recipe, under the
-    # sizes KeyeVL2's ``sa_config`` publishes): index_heads small heads of
-    # index_head_dim score every cached token against the query, and
-    # attention reads the index_topk best of them only (all, while the
-    # context is no longer). 0 = none. The serving pool then caches a THIRD
-    # row a token and layer, the indexer's one key (inference/v2/kv_cache.py).
-    # Serving only (inference/v2/dsa.py).
+    # sizes KeyeVL2's ``sa_config`` and GLM-5's config publish): index_heads
+    # small heads of index_head_dim score every cached token against the
+    # query, and attention reads the index_topk best of them only (all,
+    # while the context is no longer). 0 = none. The serving pool then
+    # caches one more row a token and layer, the indexer's one key, beside K
+    # and V or beside a latent pool's one row (inference/v2/kv_cache.py).
+    # index_rope_dim: the LEADING dims of an indexer head that rotate (0:
+    # all of them, Keye's; GLM-5 rotates qk_rope_head_dim = 64 of its 128).
+    # index_q_latent: the indexer's queries are a projection of latent
+    # attention's normed QUERY LATENT c_q (q_lora_rank wide; the recipe's
+    # own, GLM-5's) and not of the layer's normed row (Keye's, which has no
+    # latent). Serving only (inference/v2/dsa.py).
     index_topk: int = 0
     index_heads: int = 0
     index_head_dim: int = 0
+    index_rope_dim: int = 0
+    index_q_latent: bool = False
     # Power retention (arXiv:2507.04239; brumby) in the place of softmax
     # attention, on the uniform block: retention_degree p > 0 turns it on
     # (2 is the one written). Every layer then keeps, per sequence and KV
@@ -371,7 +379,7 @@ class ModelConfig:
                              "qk_head_norm (a head at a time): one of them")
         if self.index_topk and (
                 not (self.index_heads and self.index_head_dim)
-                or self.kv_lora_rank or self.sliding_window
+                or self.sliding_window
                 or self.pos_embed != "rope" or self.layer_pattern is not None
                 or self.total_ut_steps > 1 or self.hc_mult > 1
                 or self.attn_windows is not None
@@ -379,9 +387,19 @@ class ModelConfig:
             raise ValueError(
                 "index_topk: the sparse-attention indexer needs index_heads "
                 "and index_head_dim, rotary positions and one uniform stack "
-                "of K-and-V attention (no latent attention, window, "
+                "of K-and-V or of latent attention (no window, "
                 "layer_pattern, looped stack, hyper-connection streams or "
                 "period of attention kinds)")
+        if self.index_rope_dim % 2 or not (
+                0 <= self.index_rope_dim <= self.index_head_dim):
+            raise ValueError(
+                f"index_rope_dim {self.index_rope_dim}: an even count of "
+                f"the indexer head's {self.index_head_dim} dims (0: all)")
+        if self.index_q_latent and not (self.index_topk
+                                        and self.kv_lora_rank):
+            raise ValueError(
+                "index_q_latent: the indexer's queries come of latent "
+                "attention's query latent, so index_topk and kv_lora_rank")
         if self.retention_degree and (
                 self.retention_degree != 2 or self.kv_lora_rank
                 or self.index_topk or self.sliding_window
@@ -825,7 +843,8 @@ class ModelConfig:
             attn += (d + 1) * self.num_kv_heads
         if self.index_topk:    # w_qi, w_ki + its LayerNorm, w_w
             hi, di = self.index_heads, self.index_head_dim
-            attn += d * (hi * di + di + hi) + 2 * di
+            attn += (self.q_lora_rank if self.index_q_latent else d) \
+                * hi * di + d * (di + hi) + 2 * di
         n_moe = self.num_moe_layers
         hc = 2 * (self.hc_mult * d * (2 + self.hc_mult) * self.hc_mult
                   + self.hc_mult * d) if self.hc_mult > 1 else 0
@@ -1032,6 +1051,41 @@ PRESETS = {
         # the selection is seeded by a rule of its own, not a knob:
         # models/transformer.py:SELECTED_ATTN_WRITE
         routed_write_share=0.1),
+    # zai-org/GLM-5 (model_type glm_moe_dsa): 78 layers of latent attention
+    # at 64 heads (192 un-rotated + 64 rotated a q/k head, values of 256,
+    # plain rotary at theta 1e6) under a sparse-attention indexer (32 heads
+    # of 128 whose queries come of the QUERY latent and whose first 64 dims
+    # rotate, a LayerNorm on its one key; the best 2048 cached tokens a
+    # query); three leading dense layers, then 256 SwiGLU experts of 2048,
+    # top-8 by sigmoid scores + selection bias, renormalised, x 2.5, beside
+    # one shared expert. The multi-token-prediction block
+    # (num_nextn_predict_layers 1) is not part of the trunk's forward and
+    # has no field here. Serving only (inference/v2); a chip of an
+    # expert-parallel deployment overrides num_experts_held.
+    "glm-5": _p(
+        vocab_size=154880, hidden_size=6144, intermediate_size=12288,
+        num_layers=78, num_heads=64, num_kv_heads=64, head_dim=256,
+        max_seq_len=202752, rms_norm_eps=1e-5, rope_theta=1000000.0,
+        kv_lora_rank=512, q_lora_rank=2048, qk_nope_head_dim=192,
+        qk_rope_head_dim=64, v_head_dim=256,
+        index_topk=2048, index_heads=32, index_head_dim=128,
+        index_rope_dim=64, index_q_latent=True,
+        num_experts=256, num_experts_per_tok=8, moe_intermediate_size=2048,
+        first_k_dense_replace=3, n_shared_experts=1, scoring_func="sigmoid",
+        topk_method="noaux_tc", norm_topk_prob=True,
+        routed_scaling_factor=2.5,
+        # as nemotron-3-nano's, whose router this is at twice the width
+        # (sigmoid + selection bias, renormalised, x 2.5, one shared
+        # expert): how much of a logit the seeded routed experts carry. By
+        # a sweep on the v5e (benchmark/configs/glm-5-ep16-d5.json,
+        # assumed.weights; PERF.md section 6, PR 65): the sigmoid of a
+        # noise logit of std 1.6 saturates, 80 % of the (position, layer)
+        # pairs have an 8th and 9th score within bf16's rounding, and the
+        # worst of 141 rows read 0.102 with the routed experts mute, 0.118
+        # at 1/50 and 0.140 at Nemotron's 0.075, of the 0.1 allowed. The
+        # attention behind the selection is seeded by a rule of its own:
+        # models/transformer.py:SELECTED_LATENT_WRITE
+        routed_write_share=0.02),
     # manifestai/Brumby-14B-Base (model_type brumby, arXiv:2507.04239): the
     # Qwen3-14B block (GQA 40/8 x 128, an RMSNorm per head on q and k,
     # SwiGLU 17408, untied head) retrained with power retention of degree 2

@@ -52,6 +52,26 @@ POST_NORM_SPREAD = 2.0
 # 1/4, of which 0.052 moves with no share (the bf16 stream itself). A rule
 # of the draw, not an option: a checkpoint overwrites it.
 SELECTED_ATTN_WRITE = 0.5
+# ... and where the selection lies INSIDE latent attention (kv_lora_rank with
+# index_topk), whose ``wo`` is drawn by ONE head's fan-in over 64 heads: by a
+# sweep on the v5e (PERF.md section 6, PR 65; the 8.8 k probe, routed experts
+# mute): with the attention's write at nothing the median of 141 rows read
+# 0.043 logit-std, with every key selected 0.055 (the bf16 stream rounds at
+# every residual add that adds something), at 1/2 0.081 (worst row 0.102):
+# 0.059 in quadrature that follows the share, from 6 of layer 0's 2,048
+# selected keys that bf16 moves across the threshold and more in the layers
+# behind it, each a whole key of a flat softmax. At 1/4 a wrong selection
+# still reads several times the limit.
+SELECTED_LATENT_WRITE = 0.25
+# How init_params SEEDS the scale of latent attention's query-latent norm
+# where a sparse-attention indexer's queries read that latent
+# (ModelConfig.index_q_latent): log-normal a channel with this sigma, at unit
+# root mean square. At a scale of ones the norm is a positive factor a ROW,
+# which moves no row's selection: a program whose indexer read the latent
+# BEFORE its norm would select the same keys and pass for right
+# (tools/glm5_faults.py plants it); at sigma 1 the two rows' scores
+# correlate at exp(-1/2) = 0.61. A checkpoint overwrites it.
+INDEX_Q_NORM_SPREAD = 1.0
 
 
 class KVCache(NamedTuple):
@@ -138,6 +158,23 @@ class CausalLM:
             return {"scale": z * jax.lax.rsqrt(jnp.mean(z * z)) * (
                 write / np.sqrt(2 * cfg.num_layers))}
 
+        def index_params(ks) -> Params:
+            """The sparse-attention indexer's leaves (none without
+            ``cfg.index_topk``): index_heads small query heads, ONE key a
+            token (behind a LayerNorm) and a weight a head. Key and weights
+            come of the row q reads; the queries too, or
+            (``cfg.index_q_latent``) of latent attention's query latent."""
+            if not cfg.index_topk:
+                return {}
+            d, hi, di = cfg.hidden_size, cfg.index_heads, cfg.index_head_dim
+            q_in = cfg.q_lora_rank if cfg.index_q_latent else d
+            return {
+                "w_qi": dense((q_in, hi * di), next(ks)),
+                "w_ki": dense((d, di), next(ks)),
+                "ki_norm": {"scale": jnp.ones((di,), jnp.float32),
+                            "bias": jnp.zeros((di,), jnp.float32)},
+                "w_w": dense((d, hi), next(ks))}
+
         def attn_params(ks) -> Params:
             d, q, kv = cfg.hidden_size, cfg.q_dim, cfg.kv_dim
             if cfg.kv_lora_rank:
@@ -145,10 +182,16 @@ class CausalLM:
                 # up again; w_kva also gives the one rotated key all heads
                 # share, w_kvb each head's un-rotated key and its value
                 h, r = cfg.num_heads, cfg.kv_lora_rank
+                k_qa = next(ks)
+                q_scale = jnp.ones((cfg.q_lora_rank,), jnp.float32)
+                if cfg.index_q_latent:    # INDEX_Q_NORM_SPREAD has why
+                    z = jnp.exp(INDEX_Q_NORM_SPREAD * jax.random.normal(
+                        jax.random.fold_in(k_qa, 1), q_scale.shape,
+                        jnp.float32))
+                    q_scale = z * jax.lax.rsqrt(jnp.mean(z * z))
                 return {
-                    "w_qa": dense((d, cfg.q_lora_rank), next(ks)),
-                    "q_norm": {"scale": jnp.ones((cfg.q_lora_rank,),
-                                                 jnp.float32)},
+                    "w_qa": dense((d, cfg.q_lora_rank), k_qa),
+                    "q_norm": {"scale": q_scale},
                     "w_qb": dense((cfg.q_lora_rank, h * (
                         cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)),
                         next(ks)),
@@ -159,7 +202,10 @@ class CausalLM:
                     # by ONE head's fan-in: what it projects is a mean of
                     # values over the tokens attended, small already
                     "wo": dense((h * cfg.v_head_dim, d), next(ks),
-                                scale=down_scale(cfg.v_head_dim)),
+                                scale=down_scale(cfg.v_head_dim) * (
+                                    SELECTED_LATENT_WRITE if cfg.index_topk
+                                    else 1.0)),
+                    **index_params(ks),
                 }
             into = std / mup.attention_in
             attn: Params = {
@@ -202,17 +248,7 @@ class CausalLM:
             if cfg.attn_out_gate:
                 # the output gate: sigmoid(y w_g) over all q_dim values
                 attn["w_g"] = dense((d, q), next(ks))
-            if cfg.index_topk:
-                # the sparse-attention indexer: index_heads small query
-                # heads, ONE key a token (behind a LayerNorm) and a weight
-                # a head, all from the row q reads
-                hi, di = cfg.index_heads, cfg.index_head_dim
-                attn.update(
-                    w_qi=dense((d, hi * di), next(ks)),
-                    w_ki=dense((d, di), next(ks)),
-                    ki_norm={"scale": jnp.ones((di,), jnp.float32),
-                             "bias": jnp.zeros((di,), jnp.float32)},
-                    w_w=dense((d, hi), next(ks)))
+            attn.update(index_params(ks))
             return attn
 
         def glu_params(ks, f) -> Params:
@@ -616,7 +652,8 @@ class CausalLM:
             raise NotImplementedError(
                 "latent attention, hyper-connection streams, leading dense "
                 "layers, group-limited routing, a share of the experts, "
-                "the sparse-attention indexer (index_topk), a period of "
+                "the sparse-attention indexer (index_topk, over K and V or "
+                "over a latent pool), a period of "
                 "attention kinds (attn_period) and averaged shared experts "
                 "run on the serving path only "
                 "(inference/v2/model.py): their training forward and "

@@ -59,7 +59,11 @@ one short DMA), so its step takes several: on the v5e a 2,048-row tile pays
 5.5 us a block at one a step and 2.4 at four (the rescale of a 4 MiB
 accumulator and the [rows, 1] columns of the softmax are paid a step, not a
 block), a one-row tile 0.47-0.54 against 0.17-0.18 at eight (PERF.md, PR
-38). Decided at trace time.
+38). Decided at trace time. Under a sparse-attention indexer's selection
+(``sel``: GLM-5's, over a latent pool) the latent tile takes the step's
+``[BQ, keys]`` of the selection with the step's DMAs, as a K-and-V pool's
+does, one row's flags for all its heads' lanes, and a pair counts only where
+they are set (64 heads x 640 over 32-row atoms, 256 keys a step: PR 65).
 
 K-and-V pools walk ``tile_step`` at either tile height: the mask made once
 a step for ONE kv head's rows and added to all as a float32 bias; q, K, V
@@ -248,8 +252,9 @@ def _attend_tile(a, block_tables_ref, pos0_ref, qlen_ref, layer_ref,
     scratch. A second grid axis of ``head_tiles`` steps, where the wrapper
     made one, tiles the heads of the ONE kv head: the body sees its tile's
     heads only and needs no index of it but to name its neighbours.
-    ``masked`` (a K-and-V pool under a sparse-attention indexer): after V
-    the SELECTION ``[A, BQ, keys]`` int8 in HBM and, after V's, its scratch;
+    ``masked`` (a pool under a sparse-attention indexer): after the pools
+    the SELECTION ``[A, BQ, keys]`` int8 in HBM and, after theirs, its
+    scratch;
     a step's ``[BQ, step keys]`` of it (whole 128-key lane tiles) rides the
     step's DMAs and a pair counts only where it is nonzero. A selection
     with a kv-head axis, ``[A, KVH, BQ, keys]`` (blocks chosen a KV GROUP:
@@ -275,7 +280,9 @@ def _attend_tile(a, block_tables_ref, pos0_ref, qlen_ref, layer_ref,
     (PERF.md section 6, PR 52)."""
     latent = v_dim is not None
     sel_hbm = sel_vmem = None
-    if latent:
+    if latent and masked:
+        sel_hbm, ab_ref, out_ref, k_vmem, sel_vmem, sem, slot_ref = refs
+    elif latent:
         ab_ref, out_ref, k_vmem, sem, slot_ref = refs
     elif masked:
         (v_hbm, sel_hbm, ab_ref, out_ref, k_vmem, v_vmem, sel_vmem,
@@ -431,6 +438,12 @@ def _attend_tile(a, block_tables_ref, pos0_ref, qlen_ref, layer_ref,
         valid = jnp.logical_and(pos <= seen, row < qlen)
         if window is not None:
             valid = jnp.logical_and(valid, (pos0 + row) - pos < window)
+        if masked:
+            # [BQ, keys] -> a row's H lanes alike -> the scores' [BQ·H, keys]
+            chosen = jnp.broadcast_to(
+                sel_vmem[cur].astype(jnp.float32)[:, None, :],
+                (bq, g, step_keys)).reshape(bq * g, step_keys)
+            valid = jnp.logical_and(valid, chosen[None] > 0.0)
         scores = jnp.where(valid, scores, NEG_INF)
 
         m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
@@ -628,8 +641,8 @@ def _kv_pages_per_step(bq: int, ht: int, kvh: int, d: int, block_size: int,
 
 
 def _selection_pages(pages: int, block_size: int) -> int:
-    """``pages`` blocks a step under a sparse-attention indexer's selection
-    (a K-and-V pool): the step's slice of the selection is cut along the
+    """``pages`` blocks a step under a sparse-attention indexer's
+    selection: the step's slice of the selection is cut along the
     LANES of its [BQ, keys] rows, so a step is whole lane tiles of 128
     keys."""
     return max(pages, min(_MAX_STEP_PAGES, 128 // block_size))
@@ -730,18 +743,19 @@ def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
     sliding-window bound. ``name`` is what a profile calls the kernel: its
     custom call's instruction and scope (the decode entry passes its own).
     ``v_cache=None, v_dim=n``: a latent pool, V the leading ``n`` lanes of
-    K's rows (the module's docstring). ``sel`` [A, BQ, keys] int8 (a
-    K-and-V pool only): a sparse-attention indexer's selection, nonzero
+    K's rows (the module's docstring). ``sel`` [A, BQ, keys] int8 (either
+    kind of pool): a sparse-attention indexer's selection, nonzero
     where the atom's row attends to the position; the kernel then walks the
     steps its tile's shape gives (whole 128-key lane tiles of the selection:
     :func:`_selection_pages`) and a profile calls it ``dsa_prefill``. ``sel``
-    [A, KVH, BQ, keys]: a selection a KV head, each head's rows under their
-    own.
+    [A, KVH, BQ, keys] (a K-and-V pool only): a selection a KV head, each
+    head's rows under their own.
     Returns [A, BQ, H, D] ([.., n])."""
     a, bq, h, d = q_atoms.shape
     latent = v_cache is None
-    if sel is not None and latent:
-        raise ValueError("a selection over a latent pool is not written")
+    if sel is not None and latent and sel.ndim != 3:
+        raise ValueError("a latent pool has one row for all heads: a "
+                         "selection a KV head over it is not written")
     if latent and not v_dim:
         raise ValueError("a pool without V needs v_dim, the lanes of K's "
                          "rows that are the value")

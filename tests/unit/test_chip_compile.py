@@ -138,8 +138,10 @@ def _cell_forward(one_chip, config, program):
         window = [on_chip((seqs, bps))] if windowed else []
     else:
         fn = M.build_ragged_forward_fn(model, bs, "kernel")
+        # (a latent pool has no head axis: one kv head, as the engine says)
         atom = default_atom_rows(eng.atom_q_size, mc.num_heads,
-                                 kv.k.shape[-2], kv.k.shape[-1], bs, 2) \
+                                 1 if kv.v is None else kv.k.shape[-2],
+                                 kv.k.shape[-1], bs, 2) \
             if mc.num_kv_layers else 0
         shape = ragged_shapes(toks, seqs, atom,
                               mc.state_chunk_size if kv.state else 0)[-1]
@@ -399,6 +401,85 @@ def test_the_selected_k_and_v_tile_compiles_at_the_rules_width(one_chip):
     calls = [ln for ln in compiled.as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
     assert len(calls) == 1 and "dsa_prefill" in calls[0].split(" = ")[0]
+
+
+# a LATENT pool under an indexer's selection, at glm5-docs-sat's tile: 64
+# heads x 640 over one row a token, blocks of 64, a 24,576-token table
+GLM5_TILE = dict(rows=32, heads=64, d=640, v_dim=512, block_size=64,
+                 max_context=24576, atoms=16 + 768 // 32 + 1, blocks=6272)
+
+
+def test_the_selected_latent_tile_compiles_at_the_rules_width(one_chip):
+    """The ragged kernel's LATENT tile under a selection (``dsa_prefill``
+    over a pool with no V: PR 65) at the cell's tile: the engine's own atom
+    (32 rows, all 64 heads in one grid step), the loop step the rule picks
+    (four blocks = 256 keys, whole 128-key lane tiles of the selection),
+    inside the VMEM it states."""
+    from deepspeedsyclsupport_tpu.ops.paged_attention import (
+        _VMEM_CAP, _head_tile, _kv_pages_per_step, _ragged_vmem_limit,
+        _selection_pages, default_atom_rows, kv_step_keys)
+
+    g = GLM5_TILE
+    shape = (g["heads"], 1, g["d"], g["block_size"], 2)
+    assert default_atom_rows(128, *shape) == g["rows"]
+    assert _head_tile(g["rows"], *shape) == g["heads"]
+    pages = _selection_pages(
+        _kv_pages_per_step(g["rows"], *shape, True), g["block_size"])
+    assert pages == 4 and pages * g["block_size"] % 128 == 0
+    assert kv_step_keys(g["rows"], *shape, True, True) == 256
+    assert _ragged_vmem_limit(g["rows"], *shape, pages) <= _VMEM_CAP
+    bps = g["max_context"] // g["block_size"]
+    a = g["atoms"]
+    compiled = _compile(
+        lambda q, k, tables, pos0, qlen, sel, layer:
+        ragged_prefill_attention_pallas(
+            q, k, None, tables, pos0, qlen, block_size=g["block_size"],
+            layer=layer, sel=sel, v_dim=g["v_dim"]),
+        one_chip, ((a, g["rows"], g["heads"], g["d"]), jnp.bfloat16),
+        ((5, g["blocks"] * g["block_size"], g["d"]), jnp.bfloat16),
+        ((a, bps), jnp.int32), ((a,), jnp.int32), ((a,), jnp.int32),
+        ((a, g["rows"], g["max_context"]), jnp.int8), ((), jnp.int32))
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1 and "dsa_prefill" in calls[0].split(" = ")[0]
+
+
+def test_the_latent_indexer_forward_compiles_and_fits(one_chip):
+    """``ragged_forward`` of ``glm-5`` WHOLE at the cell's widths and shapes
+    (five layers; 16 sequences, 768 rows, contexts to 24,576, the whole
+    pool of 6,272 blocks): the selection's three kernels are custom calls
+    under their own names over a pool of TWO arrays (the latent rows and
+    the indexer's keys, both aliased to the result, neither copied), the
+    scopes reach the compiled text with latent attention's beside them, and
+    weights, pool and temporaries fit the chip's 15.75 GiB with room for the
+    plain reference beside them."""
+    from benchmark import scopes
+
+    compiled, kv, _params = _cell_forward(one_chip, "glm-5-ep16-d5",
+                                          "ragged_forward")
+    layers, slots = 5, 6272 * 64
+    assert kv.v is None and kv.k.shape == (layers, slots, 640)
+    assert kv.idx.shape == (layers, slots // 2, 256)
+    text = compiled.as_text()
+    calls = {ln.split(" = ")[0].strip().lstrip("%").split(".")[0]
+             for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln}
+    assert {"dsa_index_scores", "dsa_select", "dsa_prefill"} <= calls
+    assert "ragged_prefill" not in calls and "paged_decode" not in calls
+    under = scopes.instructions_under(
+        text, ("dsa_index", "dsa_select", "dsa_attend", "dsa_rows",
+               "mla_proj", "mla_absorb", "moe_experts"))
+    assert set(under.values()) >= {"dsa_index", "dsa_select", "dsa_rows",
+                                   "mla_proj", "mla_absorb", "moe_experts"}
+    m = compiled.memory_analysis()
+    assert m.alias_size_in_bytes >= (kv.k.size + kv.idx.size) * 2
+    assert not [ln for ln in text.splitlines() if " copy(" in ln
+                and (f"bf16[{layers},{slots}," in ln
+                     or f"bf16[{layers},{slots // 2}," in ln)]
+    gib = 2**30
+    assert m.argument_size_in_bytes / gib == pytest.approx(13.26, abs=0.05)
+    assert m.temp_size_in_bytes < 0.75 * gib
+    assert (m.argument_size_in_bytes + m.temp_size_in_bytes) / gib < 14.0
 
 
 def test_the_engine_counts_a_selected_atoms_steps_as_the_wrapper_walks_them(

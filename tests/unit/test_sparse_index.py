@@ -485,10 +485,10 @@ def test_a_model_without_an_indexer_keeps_what_it_had():
 
 @pytest.mark.parametrize("what,kw", [
     ("index_heads", dict(index_topk=8)),
-    ("latent attention", dict(index_topk=8, index_heads=2, index_head_dim=8,
-                              kv_lora_rank=16, q_lora_rank=16,
-                              qk_nope_head_dim=8, qk_rope_head_dim=8,
-                              v_head_dim=8)),
+    # (latent attention it is written for since PR 65:
+    # tests/unit/test_glm5_config.py; a looped stack it is not)
+    ("looped stack", dict(index_topk=8, index_heads=2, index_head_dim=8,
+                          total_ut_steps=2)),
     ("window", dict(index_topk=8, index_heads=2, index_head_dim=8,
                     sliding_window=16))])
 def test_an_indexer_refuses_what_it_is_not_written_for(what, kw):
