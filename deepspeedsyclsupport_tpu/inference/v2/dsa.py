@@ -76,7 +76,8 @@ def index_rows(p, y, cfg, positions):
     rot = lambda t: apply_rope(  # noqa: E731
         t[None], positions[None], cfg.rope_theta,
         rotary_dim=cfg.index_rope_dim or None)[0]
-    q_i = rot((q_in @ p["w_qi"]).reshape(n, hi, di))
+    # (w_qi lies [out, in]: model.serving_layout)
+    q_i = rot(jnp.einsum("tc,qc->tq", q_in, p["w_qi"]).reshape(n, hi, di))
     k_i = rot(layer_norm(y @ p["w_ki"], p["ki_norm"]["scale"],
                          p["ki_norm"]["bias"], cfg.rms_norm_eps)[:, None])
     w = jnp.einsum("td,dh->th", y, p["w_w"],

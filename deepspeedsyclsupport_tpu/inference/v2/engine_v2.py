@@ -205,13 +205,14 @@ class InferenceEngineV2:
                                             stacked=stacked,
                                             bits=cfg.quant_bits))(
                     placed["layers"])
-            # the forwards' own tree: the q, k and v projections laid out
-            # ONCE as their products read them (model.serving_layout), which
-            # a forward handed the public tree would do every time it runs
-            self._params = serving_layout(placed)
-            relaid = [new for new, old in zip(
-                jax.tree_util.tree_leaves(self._params),
-                jax.tree_util.tree_leaves(placed)) if new is not old]
+            # the forwards' own tree: the projections a forward would
+            # re-lay every time it runs (q, k and v; latent attention's and
+            # an indexer's) laid out ONCE as their products read them
+            # (model.serving_layout)
+            self._params = serving_layout(placed, model.config)
+            given = {id(x) for x in jax.tree_util.tree_leaves(placed)}
+            relaid = [x for x in jax.tree_util.tree_leaves(self._params)
+                      if id(x) not in given]
             span.update(relaid_leaves=len(relaid),
                         relaid_bytes=sum(x.nbytes for x in relaid))
 
@@ -350,7 +351,7 @@ class InferenceEngineV2:
         """New weights in the public layout, placed and cast as the caller
         left them (the hybrid engine's hand-over, a planted fault): re-laid
         for the forwards."""
-        self._params = serving_layout(tree)
+        self._params = serving_layout(tree, self.model.config)
 
     # ----------------------------------------------------------- persistence
     def serialize(self, save_path: str) -> None:

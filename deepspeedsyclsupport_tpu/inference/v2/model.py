@@ -68,6 +68,7 @@ attention, over the latent pool's one row a token, its queries then a
 projection of the query latent (``cfg.index_q_latent``:
 :func:`_mla_query_latent` makes it once for both).
 """
+import functools
 from contextlib import nullcontext
 from typing import Any, NamedTuple, Optional, Tuple
 
@@ -176,79 +177,146 @@ def _kernel_names(kind: Optional[str]):
 
 # ------------------------------------------------------ the serving layout
 # The model's public tree stores a projection ``[in, out]``
-# (``models/transformer.py``). The v5e compiler wants the weights of the q, k
-# and v products with the CONTRACTED axis minor, and a program's arguments
-# arrive in the default layout, so handed the public tree it re-lays them
-# inside the program, every time it runs (Ouro: three stacks, 1.13 GiB and
-# 3 ms a forward; PERF.md section 6, PR 64). The two serving forwards
-# therefore take these leaves ``[out, in]`` and contract over the LAST axis,
-# always: a square matrix cannot say which way it lies. Whoever hands a
-# forward a tree turns it first (the engine once, at load).
-TURNED = ("wq", "wk", "wv")     # :func:`_qkv`'s and :func:`_lightning_mixer`'s
-
-
-def _turns(path) -> bool:
-    """Whether the leaf at ``path`` (a key path, of the tree or of one
-    layer of it) is one the serving forwards read ``[out, in]``."""
-    return getattr(path[-1], "key", None) in TURNED
+# (``models/transformer.py``). The v5e compiler wants a product's weight with
+# the CONTRACTED axis minor, and a program's arguments arrive in the default
+# layout, so handed the public tree it re-lays them inside the program, every
+# time it runs (Ouro's q, k and v: three stacks, 1.13 GiB and 3 ms a forward,
+# PERF.md section 6, PR 64; DeepSeek-V2's ``w_qb`` and ``w_kvb``: 176 MiB a
+# layer, PR 66). The two serving forwards therefore take the leaves below as
+# their products read them, always: a square matrix cannot say which way it
+# lies. Whoever hands a forward a tree lays it out first (the engine once,
+# at load).
+QKV = ("wq", "wk", "wv")        # :func:`_qkv`'s and :func:`_lightning_mixer`'s
+# ``[..., out, in]``: those, latent attention's queries (:func:`_mla_rows`)
+# and a sparse-attention indexer's (``dsa.index_rows``)
+TURNED = (*QKV, "w_qb", "w_qi")
+# latent attention's ``w_kvb`` [..., r, h x (nope + v)] is read twice, a
+# head at a time: as ``W_UK`` under the queries (:func:`_mla_rows`) and as
+# ``W_UV`` over the attended latents (:func:`_mla_out`). A reshape and a lane
+# slice inside the forward cost a copy each, so it is held as the two parts,
+# head-major: ``w_uk`` [..., h, r, nope] and ``w_uv`` [..., h, v, r].
+SPLIT, PARTS = "w_kvb", ("w_uk", "w_uv")
 
 
 def _swap(x):
     return jnp.swapaxes(x, -1, -2)
 
 
-def _turn(x):
-    """``x`` with its last two axes swapped, its sharding's with them: an
-    array on a mesh (under ``jit``, one leaf alive at a time; the argument
-    is never donated: it is the caller's), a traced value, or a
-    ``ShapeDtypeStruct``."""
+@functools.lru_cache(maxsize=None)
+def _split(heads: int, nope: int):
+    """``w_kvb -> (w_uk, w_uv)`` (one function a pair of widths: a ``jit``
+    of it is traced once)."""
+    def split(w):
+        w = w.reshape(*w.shape[:-1], heads, -1)          # [..., r, h, n + v]
+        return (jnp.moveaxis(w[..., :nope], -3, -2),     # [..., h, r, n]
+                jnp.moveaxis(w[..., nope:], -3, -1))     # [..., h, v, r]
+    return split
+
+
+def _join(w_uk, w_uv):
+    """:func:`_split`'s inverse, bit for bit."""
+    w = jnp.concatenate([jnp.moveaxis(w_uk, -2, -3),
+                         jnp.moveaxis(w_uv, -1, -3)], axis=-1)
+    return w.reshape(*w.shape[:-2], -1)
+
+
+def _swap_spec(s):
+    return [[*s[:-2], s[-1], s[-2]]]
+
+
+def _split_spec(s):     # w_kvb's (..., r's axis, out's): the heads take out's
+    return [[*s[:-2], s[-1], s[-2], None], [*s[:-2], s[-1], None, s[-2]]]
+
+
+def _join_spec(s):      # w_uk's (..., the heads' axis, r's, None)
+    return [[*s[:-3], s[-2], s[-3]]]
+
+
+def _moved(fn, specs, *xs):
+    """``fn(*xs)`` where ``xs`` live, the shardings following the axes
+    (``specs``: the first argument's partition spec, padded to its rank, ->
+    each result's): arrays on a mesh (under ``jit``, one leaf alive at a
+    time; the arguments are never donated: they are the caller's), traced
+    values, or ``ShapeDtypeStruct``s."""
+    x = xs[0]
     if isinstance(x, jax.core.Tracer) or not hasattr(x, "sharding"):
-        return _swap(x)
-    sharding = x.sharding
-    if isinstance(sharding, jax.sharding.NamedSharding):
-        spec = list(sharding.spec) + [None] * (x.ndim - len(sharding.spec))
-        spec[-1], spec[-2] = spec[-2], spec[-1]
-        sharding = jax.sharding.NamedSharding(
-            sharding.mesh, jax.sharding.PartitionSpec(*spec))
+        return fn(*xs)
+    outs, tree = jax.tree_util.tree_flatten(jax.eval_shape(fn, *xs))
+    shardings = [x.sharding] * len(outs)
+    if isinstance(x.sharding, jax.sharding.NamedSharding):
+        spec = [*x.sharding.spec] + [None] * (x.ndim - len(x.sharding.spec))
+        shardings = [jax.sharding.NamedSharding(
+            x.sharding.mesh, jax.sharding.PartitionSpec(*s))
+            for s in specs(spec)]
     if isinstance(x, jax.ShapeDtypeStruct):
-        return jax.ShapeDtypeStruct(
-            (*x.shape[:-2], x.shape[-1], x.shape[-2]), x.dtype,
-            sharding=sharding)
-    return jax.jit(_swap, out_shardings=sharding)(x)
+        return tree.unflatten([
+            jax.ShapeDtypeStruct(o.shape, o.dtype, sharding=at)
+            for o, at in zip(outs, shardings)])
+    return jax.jit(fn, out_shardings=tree.unflatten(shardings))(*xs)
 
 
-def serving_layout(params):
-    """The model's public tree as the two serving forwards take it: every
-    leaf of :data:`TURNED` ``[..., out, in]``, everything else as it is.
-    On arrays, traced values and ``ShapeDtypeStruct``s alike; the only place
-    that knows which leaves turn. A ``QuantTensor`` keeps its form, the
-    public one: :func:`_dequant` turns what it materialises."""
+def _lay(node, cfg, plain):
+    """The dict ``node`` (the tree, or a layer of it) with each leaf the
+    forwards read otherwise laid out as they read it, BY ITS NAME.
+    ``plain(x)``: the leaf as a plain array (or shape), None to leave it as
+    it is."""
+    out = {}
+    for name, x in node.items():
+        if isinstance(x, dict):
+            out[name] = _lay(x, cfg, plain)
+            continue
+        w = plain(x)
+        if w is None:
+            out[name] = x
+        elif name == SPLIT:
+            out.update(zip(PARTS, _moved(
+                _split(cfg.num_heads, cfg.qk_nope_head_dim), _split_spec, w)))
+        elif name in TURNED:
+            out[name] = _moved(_swap, _swap_spec, w)
+        else:
+            out[name] = w
+    return out
+
+
+def serving_layout(params, cfg):
+    """The model's public tree as the two serving forwards of a model of
+    configuration ``cfg`` take it: every leaf of :data:`TURNED` ``[..., out,
+    in]``, :data:`SPLIT` as its :data:`PARTS`, everything else as it is (the
+    very object). On arrays, traced values and ``ShapeDtypeStruct``s alike;
+    with :func:`public_layout` the only place that knows which leaves
+    differ. A ``QuantTensor`` keeps its form, the public one:
+    :func:`_dequant` lays out what it materialises."""
     from ...compression.quantize import QuantTensor
 
-    return jax.tree_util.tree_map_with_path(
-        lambda path, x: _turn(x)
-        if _turns(path) and not isinstance(x, QuantTensor) else x,
-        params, is_leaf=lambda x: isinstance(x, QuantTensor))
+    return _lay(params, cfg,
+                lambda x: None if isinstance(x, QuantTensor) else x)
 
 
 def public_layout(params):
-    """:func:`serving_layout`'s inverse, which is the same turn."""
-    return serving_layout(params)
+    """:func:`serving_layout`'s inverse, bit for bit: the turned leaves
+    turned back, the parts joined."""
+    from ...compression.quantize import QuantTensor
+
+    out = {}
+    for name, x in params.items():
+        if isinstance(x, dict):
+            out[name] = public_layout(x)
+        elif name == PARTS[0]:
+            out[SPLIT] = _moved(_join, _join_spec, x, params[PARTS[1]])
+        elif name in TURNED and not isinstance(x, QuantTensor):
+            out[name] = _moved(_swap, _swap_spec, x)
+        elif name != PARTS[1]:
+            out[name] = x
+    return out
 
 
-def _dequant(p, dtype):
+def _dequant(p, dtype, cfg):
     """ZeRO-Inference: materialize int8 QuantTensor leaves per layer, each
     in the serving layout (the ``QuantTensor`` holds the public one)."""
     from ...compression.quantize import QuantTensor
 
-    def leaf(path, x):
-        if not isinstance(x, QuantTensor):
-            return x
-        w = x.dequantize(dtype)
-        return _turn(w) if _turns(path) else w
-
-    return jax.tree_util.tree_map_with_path(
-        leaf, p, is_leaf=lambda x: isinstance(x, QuantTensor))
+    return _lay(p, cfg, lambda x: x.dequantize(dtype)
+                if isinstance(x, QuantTensor) else None)
 
 
 def _mlp(p, y, cfg, live, experts=None):
@@ -324,6 +392,15 @@ def _mla_query_latent(p, y, cfg):
                         cfg.rms_norm_eps)
 
 
+def _latent_layer(p, cfg):
+    """A latent attention layer as :func:`_mla_rows` and :func:`_mla_out`
+    read it: a layer of the serving tree as it is. One of the PUBLIC tree
+    (told by ``w_kvb``'s name: ``tests/benchmark/test_xing4.py``, which holds
+    the absorbed form against the expanded, hands them one) is laid out
+    here."""
+    return _lay(p, cfg, lambda x: x) if SPLIT in p else p
+
+
 def _mla_rows(p, y, cfg, positions, c_q=None):
     """Latent attention (DeepSeek-V2's MLA) in ABSORBED form. The pool row
     is ``[RMSNorm(c_kv) | rope(k_r)]``, ``kv_lora_rank + qk_rope_head_dim``
@@ -336,6 +413,7 @@ def _mla_rows(p, y, cfg, positions, c_q=None):
     impl divides by sqrt of the row's width, so q carries that too.
     ``c_q``: the normed query latent where the caller made it already
     (:func:`_mla_query_latent`: a sparse-attention indexer reads it too)."""
+    p = _latent_layer(p, cfg)
     n, h = y.shape[0], cfg.num_heads
     r, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
     rot = lambda t: apply_rope(  # noqa: E731
@@ -345,15 +423,18 @@ def _mla_rows(p, y, cfg, positions, c_q=None):
         if c_q is None:
             c_q = rms_norm(y @ p["w_qa"], p["q_norm"]["scale"],
                            cfg.rms_norm_eps)
-        q = (c_q @ p["w_qb"]).reshape(n, h, -1)
+        # (w_qb lies [out, in]: serving_layout. The product ENDS here: seen
+        # through the reshape, the v5e compiler lays 128 heads of 192 in
+        # the lanes and regroups the weight by head for it, every forward)
+        q = jax.lax.optimization_barrier(
+            jnp.einsum("tc,qc->tq", c_q, p["w_qb"])).reshape(n, h, -1)
         ckv = y @ p["w_kva"]
         row = jnp.concatenate([
             rms_norm(ckv[:, :r], p["kv_norm"]["scale"], cfg.rms_norm_eps),
             rot(ckv[:, None, r:])[:, 0]], axis=-1)
         q_r = rot(q[..., nope:])
     with scope("mla_absorb"):
-        w_uk = p["w_kvb"].reshape(r, h, -1)[..., :nope]
-        q_lat = jnp.einsum("thn,rhn->thr", q[..., :nope], w_uk,
+        q_lat = jnp.einsum("thn,hrn->thr", q[..., :nope], p["w_uk"],
                            preferred_element_type=jnp.float32)
         q = jnp.concatenate([q_lat, q_r.astype(jnp.float32)], axis=-1) \
             * (cfg.softmax_scale * np.sqrt(cfg.latent_kv_dim))
@@ -363,10 +444,9 @@ def _mla_rows(p, y, cfg, positions, c_q=None):
 def _mla_out(p, attn, cfg, n):
     """attn [n, H, kv_lora_rank], the attended latents: up through each
     head's ``W_UV`` to [n, H, v_head_dim], then the output projection."""
-    r, h = cfg.kv_lora_rank, cfg.num_heads
+    p = _latent_layer(p, cfg)
     with scope("mla_absorb"):
-        w_uv = p["w_kvb"].reshape(r, h, -1)[..., cfg.qk_nope_head_dim:]
-        out = jnp.einsum("thr,rhv->thv", attn, w_uv)
+        out = jnp.einsum("thr,hvr->thv", attn, p["w_uv"])
     with scope("mla_proj"):
         return out.reshape(n, -1) @ p["wo"]
 
@@ -1113,7 +1193,7 @@ def _lightning_mixer(cfg, p, x, positions, scan_fn):
     y = norm(x, p["norm"], cfg)
     with scope("la_proj"):
         # (q, k and v lie [out, in]: serving_layout)
-        q, k, v = (jnp.einsum("td,qd->tq", y, p[w]) for w in TURNED)
+        q, k, v = (jnp.einsum("td,qd->tq", y, p[w]) for w in QKV)
         z = y @ p["wz"]
     with scope("la_gate"):
         q = rot(rms_norm(q.reshape(n, h, d), p["q_norm"]["scale"],
@@ -1218,7 +1298,7 @@ def _walk_pattern(cfg, params, x, kv: BlockedKV, attend, ssm_step, live,
             x = (x + _branch(cfg, _attn_out(p["attn"], rows_attn, cfg,
                                             x.shape[0]))).astype(x.dtype)
         else:
-            p = _dequant(at(layers, j), x.dtype)
+            p = _dequant(at(layers, j), x.dtype, cfg)
             m, rows = _mlp(p, norm(x, p["mlp_norm"], cfg), cfg, live,
                            (stack, j))
             x = (x + _branch(cfg, m)).astype(x.dtype)
@@ -1392,7 +1472,7 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
 
     def layer(carry, p, l, experts, j):
         x, pools = carry
-        p = _dequant(p, x.dtype)
+        p = _dequant(p, x.dtype, cfg)
 
         def attn_fn(y):
             nonlocal pools
@@ -1596,7 +1676,7 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
 
     def layer(carry, p, l, experts, j):
         x, pools = carry
-        p = _dequant(p, x.dtype)
+        p = _dequant(p, x.dtype, cfg)
 
         def attn_fn(y):
             nonlocal pools

@@ -198,8 +198,11 @@ def test_index_rows_read_the_query_latent_and_rotate_half_a_head(built):
     """``dsa.index_rows``: the queries are a projection of ``c_q`` (a change
     of ``y`` alone moves keys and weights, not them), and of a head's 16
     dims the last 8 do not depend on the position."""
+    from deepspeedsyclsupport_tpu.inference.v2.model import serving_layout
+
     cfg = built[0].config
-    p = jax.tree_util.tree_map(lambda w: w[0], built[1]["layers"]["attn"])
+    p = jax.tree_util.tree_map(
+        lambda w: w[0], serving_layout(built[1], cfg)["layers"]["attn"])
     rng = np.random.default_rng(2)
     y = jnp.asarray(rng.standard_normal((3, 64)), jnp.float32)
     c_q = jnp.asarray(rng.standard_normal((3, 24)), jnp.float32)

@@ -706,13 +706,10 @@ class TestTuneChainTimer:
         lightning products is built once, with the weights stored
         ``[in, out]`` and ``[out, in]`` (``model.serving_layout``), and the
         two programs of a shape give the same sum. The cells' shapes come
-        off ``BENCHMARK.json``'s configurations: every serving cell that is
-        not latent."""
+        off ``BENCHMARK.json``'s configurations: every serving cell."""
         import json
 
         cells = tune._proj_cells()
-        assert "xing4-docs-sat" not in cells \
-            and "dsv2-answers-sat" not in cells
         assert cells["ouro-reason-sat"] == {"q": (2048, 2048),
                                             "kv": (2048, 2048)}
         assert cells["cmdaplus-rag-sat"] == {"q": (4096, 16384),
@@ -739,6 +736,51 @@ class TestTuneChainTimer:
             a, b = (r["rows"][k] for k in ("in_out", "out_in"))
             assert a["us"] == b["us"] == 100.0      # 0.6 ms / (3 x 2)
             assert a["gb_s"] == round(r["d_in"] * r["d_out"] * 2 / 1e5, 1)
+            np.testing.assert_allclose(a["sum"], b["sum"], rtol=1e-3,
+                                       atol=1e-2)
+
+
+    def test_the_proj_sweep_runs_the_latent_products(
+            self, tune, monkeypatch, capsys):
+        """``tpu_tune.py proj`` over latent attention's products: the cells'
+        shapes off ``BENCHMARK.json`` (``w_qb``'s and an indexer's ``w_qi``'s
+        ``(in, out)``, the two batched products over the parts of ``w_kvb``
+        by ``(heads, in, out, the other part's width)``); at a tiny cell,
+        the profiler's reading stubbed, the public leaf WHOLE, reshaped and
+        cut inside the program, and the part alone, head-major
+        (``model.serving_layout``), give the same sum, and the GB/s counts
+        the part's bytes."""
+        import json
+
+        cells = tune._proj_cells()
+        assert cells["dsv2-answers-sat"] == {
+            "qb": (1536, 24576), "uk": (128, 128, 512, 128),
+            "uv": (128, 512, 128, 128)}
+        assert cells["glm5-docs-sat"] == {
+            "qb": (2048, 16384), "uk": (64, 192, 512, 256),
+            "uv": (64, 512, 256, 192), "qi": (2048, 4096)}
+        assert cells["xing4-docs-sat"]["qb"] == (768, 6144)
+        assert cells["keye-video-sat"]["qi"] == (2048, 1024)
+        monkeypatch.setattr(tune, "_proj_cells", lambda: {
+            "latent": {"qb": (32, 96), "uk": (4, 16, 32, 8),
+                       "uv": (4, 32, 8, 16), "qi": (32, 64)}})
+
+        def reading(steps, args, **_kw):
+            for step in steps.values():
+                jax.block_until_ready(step(*args))
+            return {name: {"xla": 0.4, "calls": {}} for name in steps}
+
+        monkeypatch.setattr(tune, "_traced_kernels", reading)
+        tune.proj(["--rows", "16", "--layers", "2"])
+        out = [json.loads(ln) for ln in capsys.readouterr().out.splitlines()]
+        assert [(r.get("part"), r.get("heads"), r["d_in"], r["d_out"])
+                for r in out] == [(None, None, 32, 96), ("uk", 4, 16, 32),
+                                  ("uv", 4, 32, 8), (None, None, 32, 64)]
+        for r in out:
+            a, b = (r["rows"][k] for k in ("in_out", "out_in"))
+            assert a["us"] == b["us"] == 200.0      # 0.4 ms / 2 layers
+            assert a["gb_s"] == round(
+                r.get("heads", 1) * r["d_in"] * r["d_out"] * 2 / 2e5, 1)
             np.testing.assert_allclose(a["sum"], b["sum"], rtol=1e-3,
                                        atol=1e-2)
 

@@ -80,7 +80,7 @@ def _serving_params(model, one_chip):
         lambda x: jax.ShapeDtypeStruct(
             x.shape, jnp.bfloat16 if jnp.issubdtype(x.dtype, jnp.floating)
             else x.dtype, sharding=one_chip),
-        jax.eval_shape(model.init_params)))
+        jax.eval_shape(model.init_params)), model.config)
 
 
 @_once
@@ -774,41 +774,96 @@ def test_the_two_kernels_carry_their_names_onto_the_custom_call(one_chip):
 
 # ------------------------------------- no forward copies a weight it is handed
 # the benchmark's configurations whose forwards project q, k and v through
-# ``model._qkv`` or a lightning layer's three products (the latent ones
-# project through their ranks)
-WEIGHT_COPY_CONFIGS = (
-    "ouro-2.6b", "command-a-plus-ep8-d4", "brumby-14b-d8", "falcon-h1-34b-d6",
-    "minicpm-sala-d12", "phi-2", "nemotron3-nano-ep4-d26",
-    "solar-open2-ep8-d4", "olmoe-1b-7b-d10", "keye-vl2-30b-a3b-ep8-d12")
+# ``model._qkv`` or a lightning layer's three products, at the floor of 8 MiB
+# PR 64 held them to; and the three whose latent attention projects through
+# its ranks (``model._mla_rows`` / ``_mla_out``; GLM-5's indexer beside it),
+# at 1 MiB, so that the parts of ``w_kvb`` count (Xing4's are 4 MiB)
+WEIGHT_COPY_CONFIGS = {
+    **dict.fromkeys((
+        "ouro-2.6b", "command-a-plus-ep8-d4", "brumby-14b-d8",
+        "falcon-h1-34b-d6", "minicpm-sala-d12", "phi-2",
+        "nemotron3-nano-ep4-d26", "solar-open2-ep8-d4", "olmoe-1b-7b-d10",
+        "keye-vl2-30b-a3b-ep8-d12"), 8 << 20),
+    # (GLM-5's two programs are the indexer's cases' too: compiled once)
+    "glm-5-ep16-d5": 1 << 20, "deepseek-v2-ep4-d5": 1 << 20,
+    "xing4-29b-a4b-d6": 1 << 20}
 _COPY = re.compile(r"%?([\w.\-]+) = (bf16|f32)\[([\d,]+)\]\S* copy\(")
+
+
+def _merges(fine, coarse):
+    """Whether the dims ``fine`` merge into the dims ``coarse``: each of
+    ``coarse`` one of ``fine`` or the product of two (heads x a head's
+    width), each of ``fine`` used once."""
+    if not coarse:
+        return not fine
+    want, rest = coarse[0], coarse[1:]
+    for i, a in enumerate(fine):
+        left = fine[:i] + fine[i + 1:]
+        if a == want and _merges(left, rest):
+            return True
+        if want % a == 0 and any(
+                a * b == want and _merges(left[:j] + left[j + 1:], rest)
+                for j, b in enumerate(left)):
+            return True
+    return False
 
 
 def _weight_copies(text, params, floor=8 << 20):
     """The ``copy`` instructions of a compiled ``text`` that write ``floor``
     bytes or more in the shape of a weight: a leaf of ``params`` whole or
     one layer's slice of a stacked leaf, its dims in any order (the bitcast
-    before a copy may have turned them) and without the 1s."""
+    before a copy may have turned them), one or more of them regrouped in
+    two (``[h x d, c]`` copied as ``[h, d, c]``) and without the 1s. Not
+    the copy behind an ``optimization_barrier``: that one moves a product's
+    RESULT (rows x out, which in Xing4's mixed forward, 768 rows over a
+    query latent of 768, has the dims of the weight)."""
     def dims(shape):
         return tuple(sorted(d for d in shape if d > 1))
 
     weights = {dims(shape) for leaf in jax.tree_util.tree_leaves(params)
                for shape in (leaf.shape, leaf.shape[1:])}
     found = []
-    for name, dtype, shape in _COPY.findall(text):
-        shape = [int(d) for d in shape.split(",")]
+    for line in text.splitlines():
+        m = _COPY.search(line)
+        if m is None or "optimization_barrier" in line:
+            continue
+        name, dtype, shape = m.groups()
+        shape = dims(int(d) for d in shape.split(","))
         nbytes = (2 if dtype == "bf16" else 4) * functools.reduce(
-            lambda a, b: a * b, shape)
-        if nbytes >= floor and dims(shape) in weights:
-            found.append(f"{name} {dtype}{shape}")
+            lambda a, b: a * b, shape, 1)
+        if nbytes >= floor and any(_merges(shape, w) for w in weights):
+            found.append(f"{name} {dtype}{list(shape)}")
     return found
+
+
+def test_a_regrouped_copy_is_told_from_an_activation():
+    """``_weight_copies`` on hand-made lines: DeepSeek-V2's ``w_qb``
+    regrouped by head and turned is a weight's copy, at any floor under its
+    bytes; the attended latents of 768 rows are not, nor is a product's
+    result behind a barrier."""
+    params = {"w_qb": jax.ShapeDtypeStruct((5, 24576, 1536), jnp.bfloat16),
+              "w_uk": jax.ShapeDtypeStruct((5, 128, 512, 128), jnp.bfloat16)}
+    text = "\n".join([
+        "%copy.1 = bf16[128,192,1536]{2,0,1:T(8,128)(2,1)} copy(%p.1)",
+        "%copy.2 = bf16[768,128,512]{2,0,1:T(8,128)(2,1)} copy(%p.2)",
+        "%copy.3 = bf16[1,128,64,1024]{3,1,2,0} copy(%p.3)",
+        "%copy.4 = bf16[1536,24576]{0,1} copy(%f.4), metadata={op_name="
+        "\"jit(f)/mla_proj/optimization_barrier\"}",
+        "%copy.5 = f32[8,16,512,128]{3,2,1,0} copy(%p.5)"])
+    assert _weight_copies(text, params, floor=1 << 20) == [
+        "copy.1 bf16[128, 192, 1536]", "copy.5 f32[8, 16, 128, 512]"]
+    assert _weight_copies(text, params, floor=80 << 20) == []
+    assert _merges((2, 3, 4), (6, 4)) and _merges((2, 3, 4), (3, 8))
+    assert not _merges((2, 3, 4), (24,)) and not _merges((6, 4), (2, 3, 4))
 
 
 @pytest.mark.parametrize("program", ["decode_forward", "ragged_forward"])
 @pytest.mark.parametrize("config", WEIGHT_COPY_CONFIGS)
 def test_the_serving_forwards_copy_no_weight(one_chip, config, program):
-    """Both serving forwards of ten families WHOLE at their cells' shapes,
-    handed the weights as the engine holds them (``model.serving_layout``):
-    the compiled text copies no parameter-shaped buffer of 8 MiB or more.
+    """Both serving forwards of thirteen families WHOLE at their cells'
+    shapes, handed the weights as the engine holds them
+    (``model.serving_layout``): the compiled text copies no parameter-shaped
+    buffer of 8 MiB or more (1 MiB or more in the three latent families).
     Handed the model's public ``[in, out]`` tree the v5e compiler re-laid
     the q, k and v projections' weights inside the program, every forward:
     Ouro's three stacks ``[48, 2048, 2048]`` (1.13 GiB of temporaries, 3 ms
@@ -817,9 +872,27 @@ def test_the_serving_forwards_copy_no_weight(one_chip, config, program):
     slices a layer in Brumby, Falcon-H1, Nemotron-3, Solar-Open2, OLMoE and
     Keye; phi-2 alone copied nothing (PERF.md section 6, PR 64). What stays is the layer's slice into ``S(1)``: the
     compiler's prefetch of the next operand, in the layout it is stored
-    in."""
+    in.
+
+    The latent families (PR 66), MiB a layer at the public tree, every
+    forward: DeepSeek-V2 (128 heads) ``w_qb`` ``[1536, 24576]`` turned (72)
+    and THEN regrouped by head, ``[128, 192, 1536]{2,0,1}`` (72 more), and
+    ``w_kvb`` ``[512, 32768]`` turned (32) in both programs, 176 of
+    weights; GLM-5 ``w_qb`` ``[2048, 16384]`` 64, ``w_kvb`` ``[512, 28672]``
+    28 and the indexer's ``w_qi`` ``[2048, 4096]`` 16; Xing4 ``w_qb`` 9 and
+    ``w_kvb`` 8. (The ``f32[128, 512, 64]`` beside them in DeepSeek-V2's
+    ``decode_forward`` is not a part of ``w_kvb``: it is the queries in the
+    latent, 64 rows x 128 heads x 512, and stays.) ``w_kvb`` is read twice,
+    contracted over ``nope`` and over the latent, so ONE leaf in either
+    order is still copied in the mixed forward (32 MiB at 128 heads): the
+    serving tree holds its two parts. With ``w_qb`` ``[out, in]`` the
+    regrouping stays at 128 heads in both programs, and with the rotated
+    columns a leaf of their own, in ``decode_forward`` (24 MiB): seen
+    through the reshape to heads the compiler puts 128 heads in the lanes;
+    ``_mla_rows`` ends the product at a barrier and no form is copied."""
     compiled, _kv, params = _cell_forward(one_chip, config, program)
-    assert not _weight_copies(compiled.as_text(), params)
+    assert not _weight_copies(compiled.as_text(), params,
+                              WEIGHT_COPY_CONFIGS[config])
 
 
 # ----------------------------------- a looped stack holds its pool ONCE

@@ -86,7 +86,7 @@ def _built(name):
         return jax.tree_util.tree_unflatten(tree, [
             x + 0.1 * jax.random.normal(k, x.shape)
             for x, k in zip(leaves, keys)])
-    return model, M.serving_layout(jax.jit(drawn)())
+    return model, M.serving_layout(jax.jit(drawn)(), model.config)
 
 
 def _pool(cfg, seed=0):

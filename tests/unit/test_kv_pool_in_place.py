@@ -41,7 +41,7 @@ def _model(num_layers=3, **kw):
     cfg = dataclasses.replace(get_config("tiny"), dtype="float32",
                               num_layers=num_layers, **kw)
     model = build_model(cfg)
-    return model, M.serving_layout(model.init_params())
+    return model, M.serving_layout(model.init_params(), model.config)
 
 
 def _pool(cfg, num_slots, seed=0):

@@ -253,7 +253,8 @@ def test_ragged_forward_holds_two_kernel_calls_a_layer(arch):
                               num_heads=4, num_kv_heads=2, hidden_size=512,
                               **kw)
     model = build_model(cfg)
-    params = M.serving_layout(jax.eval_shape(model.init_params))
+    params = M.serving_layout(jax.eval_shape(model.init_params),
+                              model.config)
     s, t, bs, bps, bq = 8, 256, 64, 4, 128
     a = s + t // bq + 1
     pool = sds((cfg.num_layers, 40 * bs, cfg.num_kv_heads, 128))
@@ -280,7 +281,8 @@ def _lowered_ragged_forward(preset, pool_row, **overrides):
 
     model = build_model(preset, **overrides)
     cfg = model.config
-    params = M.serving_layout(jax.eval_shape(model.init_params))
+    params = M.serving_layout(jax.eval_shape(model.init_params),
+                              model.config)
     s, t, bs, bps, bq = 8, 256, 64, 4, 128
     a = s + t // bq + 1
     pool = sds((cfg.num_layers, 40 * bs, *pool_row))

@@ -80,11 +80,14 @@ Sections, cheapest first:
             ``jax.numpy`` forms on the chip first:
             bsa [--ctx N ...] [--parity]
 
-  proj    — the q and the k/v projection ALONE, ``[T, in] x W`` at 16 /
-            32 / 48 / 256 / 768 rows and every served cell's ``(in, out)``
-            (read off ``BENCHMARK.json``'s configurations; a lightning
-            layer's among them), W stored ``[in, out]`` (the model's public
-            tree) and ``[out, in]`` (``model.serving_layout``), sliced from
+  proj    — a projection ALONE, ``[T, in] x W`` at 16 / 32 / 48 / 256 /
+            768 rows and every served cell's ``(in, out)`` (read off
+            ``BENCHMARK.json``'s configurations: q and k/v, a lightning
+            layer's, latent attention's ``w_qb``, an indexer's ``w_qi``), W
+            stored ``[in, out]`` (the model's public tree) and ``[out, in]``
+            (``model.serving_layout``), and latent attention's two batched
+            products a head over ``w_kvb`` (whole and cut inside, or its
+            part alone, head-major), sliced from
             a stack inside a scan as the forwards slice it, ``--passes`` of
             the stack (a looped model's second loop is what makes the v5e
             compiler copy a whole ``[in, out]`` stack): us a layer, the GB/s
@@ -1903,40 +1906,87 @@ def bsa(argv=()):
 
 # ---------------------------------------------------------------- proj
 PROJ_ROWS = (16, 32, 48, 256, 768)
-# how a product reads its weight, by the layout the stack is stored in
+# how a product reads its weight, by the layout the stack is stored in: a
+# projection ``[T, in] x W``; latent attention's two batched products over
+# the parts of ``w_kvb``, ``in_out`` the public leaf WHOLE ``[r, h x (nope +
+# v)]`` (reshaped by head and cut inside the program, as a forward handed the
+# public tree does), ``out_in`` the part alone, head-major
 PROJ_LAYOUTS = {"in_out": "td,dq->tq", "out_in": "td,qd->tq"}
+PROJ_PARTS = {"uk": {"in_out": "thn,rhn->thr", "out_in": "thn,hrn->thr"},
+              "uv": {"in_out": "thr,rhv->thv", "out_in": "thr,hvr->thv"}}
 
 
 def _proj_cells():
-    """``{cell: {"q" | "kv" | "la": (in, out)}}`` of every serving cell whose
-    forwards run :func:`model._qkv` (a latent model projects through its
-    ranks) or a lightning layer's three products."""
+    """``{cell: {product: shape}}`` of every serving cell. A projection's
+    shape is ``(in, out)``: ``q`` / ``kv`` (:func:`model._qkv`), ``la`` (a
+    lightning layer's three), ``qb`` (latent attention's queries from their
+    latent), ``qi`` (an indexer's queries). A batched product's is ``(heads,
+    in, out, the other part's width)``: ``uk`` (the queries into the latent,
+    contracted over ``nope``), ``uv`` (the attended latents up to values)."""
     cells = {}
     for name, m in _serve_configs().items():
         if m.kv_lora_rank:
-            continue
-        cells[name] = {"q": (m.hidden_size, m.q_dim),
-                       "kv": (m.hidden_size, m.kv_dim)}
+            h, r = m.num_heads, m.kv_lora_rank
+            n, v = m.qk_nope_head_dim, m.v_head_dim
+            cells[name] = {
+                "qb": (m.q_lora_rank, h * (n + m.qk_rope_head_dim)),
+                "uk": (h, n, r, v), "uv": (h, r, v, n)}
+        else:
+            cells[name] = {"q": (m.hidden_size, m.q_dim),
+                           "kv": (m.hidden_size, m.kv_dim)}
         if m.lightning_heads:
             cells[name]["la"] = (m.hidden_size,
                                  m.lightning_heads * m.lightning_head_dim)
+        if m.index_topk:
+            cells[name]["qi"] = (
+                m.q_lora_rank if m.index_q_latent else m.hidden_size,
+                m.index_heads * m.index_head_dim)
     return cells
 
 
-def _proj_program(layout, passes):
+def _proj_operands(which, shape, layers, key):
+    """One product at ``shape``: ``(x's shape behind the rows, {layout: the
+    stack of weights}, {layout: product(x, a layer's weight)})``."""
+    from deepspeedsyclsupport_tpu.inference.v2 import model as M
+
+    def drawn(*dims):
+        return (jax.random.normal(key, (layers, *dims), jnp.float32)
+                * dims[0] ** -0.5).astype(jnp.bfloat16)
+
+    if which not in PROJ_PARTS:
+        stack = drawn(*shape)
+        return ((shape[0],),
+                {"in_out": stack, "out_in": jnp.swapaxes(stack, 1, 2)},
+                {layout: functools.partial(jnp.einsum, form)
+                 for layout, form in PROJ_LAYOUTS.items()})
+    heads, d_in = shape[:2]
+    nope, r, v = shape[1:] if which == "uk" else (shape[3], *shape[1:3])
+    whole = drawn(r, heads * (nope + v))
+    part = PROJ_PARTS[which]
+    cut = slice(None, nope) if which == "uk" else slice(nope, None)
+    f32 = dict(preferred_element_type=jnp.float32) if which == "uk" else {}
+    return ((heads, d_in),
+            {"in_out": whole,
+             "out_in": M._split(heads, nope)(whole)[which == "uv"]},
+            {"in_out": lambda x, w: jnp.einsum(
+                part["in_out"], x, w.reshape(r, heads, -1)[..., cut], **f32),
+             "out_in": lambda x, w: jnp.einsum(part["out_in"], x, w, **f32)})
+
+
+def _proj_program(product, passes):
     """``passes`` walks of a stack of weights, each a scan whose xs are the
     stack (the layer loop of the serving forwards; the outer loop a looped
-    model's): the rows times the layer's weight, summed over the layers."""
+    model's): ``product(the rows, the layer's weight)`` summed over the
+    layers."""
     def walk(x, stack):
         def layer(acc, w):
-            y = jnp.einsum(PROJ_LAYOUTS[layout], x, w)
-            return acc + y.astype(jnp.float32), None
+            return acc + product(x, w).astype(jnp.float32), None
 
         def one_pass(acc, _):
             return jax.lax.scan(layer, acc, stack)[0], None
 
-        acc = jnp.zeros((x.shape[0], stack.shape[1 if layout == "out_in"
-                                                  else 2]), jnp.float32)
+        acc = jnp.zeros(jax.eval_shape(product, x, stack[0]).shape,
+                        jnp.float32)
         return jax.lax.scan(one_pass, acc, None, length=passes)[0]
     return walk
 
@@ -1956,11 +2006,11 @@ def _largest_copy(text):
 
 
 def proj(argv=()):
-    """The q / k / v projection alone, both stored layouts, one program a
-    (shape, rows, layout): see the module's text. Each row: us a layer (the
-    program's device time off a profiler trace over layers x passes), the
-    GB/s of the weights it reads, the program's temporaries and its largest
-    copy."""
+    """A projection alone (or one of latent attention's batched products),
+    both stored layouts, one program a (shape, rows, layout): see the
+    module's text. Each row: us a layer (the program's device time off a
+    profiler trace over layers x passes), the GB/s of the weights it reads,
+    the program's temporaries and its largest copy."""
     import argparse
 
     cells = _proj_cells()
@@ -1970,38 +2020,40 @@ def proj(argv=()):
     ap.add_argument("--layers", type=int, default=4)
     ap.add_argument("--passes", type=int, default=1)
     a = ap.parse_args(list(argv))
-    shapes = {}                   # (in, out) -> the cells' products there
+    shapes = {}     # (a batched product's name, its shape) -> the cells' there
     for cell in a.cell:
         for which, shape in cells[cell].items():
-            shapes.setdefault(shape, []).append(f"{cell}:{which}")
-    for (d_in, d_out), users in shapes.items():
-        key = jax.random.PRNGKey(d_in + d_out)
-        stack = (jax.random.normal(key, (a.layers, d_in, d_out), jnp.float32)
-                 * d_in ** -0.5).astype(jnp.bfloat16)
-        stacks = {"in_out": stack,
-                  "out_in": jax.block_until_ready(jnp.swapaxes(stack, 1, 2))}
+            shapes.setdefault((which if which in PROJ_PARTS else "", shape),
+                              []).append(f"{cell}:{which}")
+    for (which, shape), users in shapes.items():
+        key = jax.random.PRNGKey(sum(shape))
+        behind, stacks, products = _proj_operands(which, shape, a.layers, key)
+        jax.block_until_ready(stacks)
+        weight = 2 * int(np.prod(shape[:3] if which else shape))
         for t in a.rows:
-            x = jax.random.normal(jax.random.fold_in(key, t), (t, d_in),
+            x = jax.random.normal(jax.random.fold_in(key, t), (t, *behind),
                                   jnp.bfloat16)
             rows = {}
             for tag, layout in enumerate(PROJ_LAYOUTS):
                 args = (x, stacks[layout])
-                step = _named(layout, _proj_program(layout, a.passes),
+                step = _named(layout, _proj_program(products[layout],
+                                                    a.passes),
                               tag).lower(*args).compile()
                 got = _traced_kernels({layout: step}, args,
                                       kernel_of=lambda text: "kernel")
                 us = 1e3 * got[layout]["xla"] / (a.layers * a.passes)
-                mib, shape = _largest_copy(step.as_text())
+                mib, copied = _largest_copy(step.as_text())
                 rows[layout] = {
                     "us": round(us, 2),
-                    "gb_s": round(d_in * d_out * 2 / us / 1e3, 1)
-                    if us else None,
+                    "gb_s": round(weight / us / 1e3, 1) if us else None,
                     "temp_mib": round(step.memory_analysis(
                     ).temp_size_in_bytes / 2**20, 1),
-                    "copy_mib": mib, "copy": shape,
+                    "copy_mib": mib, "copy": copied,
                     "sum": float(jnp.sum(step(*args)[0]))}
+            d_in, d_out = shape[1:3] if which else shape
             emit("proj", d_in=d_in, d_out=d_out, t=t, layers=a.layers,
-                 passes=a.passes, cells=users, rows=rows)
+                 passes=a.passes, cells=users, rows=rows,
+                 **({"part": which, "heads": shape[0]} if which else {}))
 
 
 def _combine_float64(args):
