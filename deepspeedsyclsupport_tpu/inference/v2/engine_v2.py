@@ -258,8 +258,9 @@ class InferenceEngineV2:
         self._decode_forward = None  # built lazily (kernel path)
         # name -> (jitted fn, {rows: abstract args}) of every forward
         # program this engine has dispatched, at every static shape it ran
-        # in (compiled_programs)
+        # in, and what compiled_programs() built of them when it was asked
         self._dispatched: Dict[str, Tuple[Any, Dict[int, Any]]] = {}
+        self._compiled: Dict[Tuple[str, int], Any] = {}
         # forward programs dispatched (_dispatch) plus sampler calls
         # (sample_drained): 2 a per-token round, which with the rng split
         # are all of its device launches (benchmark: launches_per_round)
@@ -427,8 +428,8 @@ class InferenceEngineV2:
     def _dispatch(self, name: str, fn, *args, rows: int = 0):
         """Call forward program ``fn``, remembering the abstract arguments
         of its first dispatch at each static shape (``rows``: the one size a
-        program's shapes differ by; taken BEFORE the call: the pool is
-        donated)."""
+        program's shapes differ by, what its ``round`` record says too;
+        taken BEFORE the call: the pool is donated)."""
         shapes = self._dispatched.setdefault(name, (fn, {}))[1]
         if rows not in shapes:
             from ...analysis.capture import abstract_step_args
@@ -667,14 +668,39 @@ class InferenceEngineV2:
         WHAT ran (``.as_text()``: is the attention a
         ``tpu_custom_call``?) and what it needs (``.memory_analysis()``).
         A program that ran in several static shapes (``ragged_forward``,
-        ``ragged.ragged_shapes``) answers as :class:`ProgramShapes`."""
+        ``ragged.ragged_shapes``) answers as :class:`ProgramShapes`.
+
+        Each ``(program, rows)`` is lowered and compiled ONCE an engine,
+        the first time somebody asks after it was dispatched, and kept; and
+        handed to ``monitor/mfu.publish`` as ``<program>@<rows>`` with its
+        ``rows`` beside it, so that whoever holds a device trace of this
+        engine's forwards finds each shape's own map of instruction to MFU
+        region, scope and root (:meth:`published_programs` has the names;
+        the ``round`` record's ``rows`` says which shape an execution ran
+        at). Nothing is lowered, compiled or parsed for a caller that never
+        asks."""
+        from ...monitor import mfu
+
         out = {}
         for name, (fn, shapes) in self._dispatched.items():
-            by_rows = [fn.lower(*shapes[rows]).compile()
-                       for rows in sorted(shapes)]
+            for rows in shapes:
+                if (name, rows) not in self._compiled:
+                    compiled = self._compiled[name, rows] = fn.lower(
+                        *shapes[rows]).compile()
+                    mfu.publish(f"{name}@{rows}", compiled, rows=rows)
+            by_rows = [self._compiled[name, rows] for rows in sorted(shapes)]
             out[name] = by_rows[0] if len(by_rows) == 1 \
                 else ProgramShapes(by_rows)
         return out
+
+    def published_programs(self) -> Dict[str, Dict[int, str]]:
+        """``{program: {rows: name}}``: the name :meth:`compiled_programs`
+        published each static shape of each forward under, for
+        ``monitor/mfu.published(name)`` (``{"ragged_forward": {128:
+        "ragged_forward@128", 512: ...}, "decode_forward": {32: ...}}``)."""
+        self.compiled_programs()
+        return {name: {rows: f"{name}@{rows}" for rows in sorted(shapes)}
+                for name, (_fn, shapes) in self._dispatched.items()}
 
     # --------------------------------------------------------------- warmup
     @setup_span("warmup")
@@ -1279,7 +1305,8 @@ class InferenceEngineV2:
                 jnp.asarray(positions), jnp.asarray(tables),
                 jnp.asarray(active), sampled, take_from,
                 *_behind_state(list(map(jnp.asarray, state)),
-                               list(map(jnp.asarray, window))))
+                               list(map(jnp.asarray, window))),
+                rows=cfg.max_sequences)
         return logits
 
     # ------------------------------------------------------------ query/flush

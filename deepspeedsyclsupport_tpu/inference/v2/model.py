@@ -67,6 +67,23 @@ And an eleventh: a learned indexer's selection of cached tokens
 attention, over the latent pool's one row a token, its queries then a
 projection of the query latent (``cfg.index_q_latent``:
 :func:`_mla_query_latent` makes it once for both).
+
+Both forwards trace under the training model's MFU regions
+(``monitor/mfu.region_scope``; labels in the compiled text's metadata, no
+other trace of them): ``embed`` (:func:`_embed` with the choice of the token
+ids), ``attn`` (the token mixer of whatever kind with the norm that feeds it,
+its residual add, a hyper-connection sublayer's maps around it, and the pool
+addresses the forward computes once for all layers), ``mlp`` (the channel
+mixer, dense or sparse, with its norm, its residual add and the experts'
+counters) and ``head`` (:func:`_final_norm`, the gather of the rows that are
+unembedded, :func:`_unembed`, a looped stack's exit). They are opened at the
+few shared functions (:func:`_block`, :func:`_hc_block`,
+:func:`_walk_pattern`'s ``one``, :func:`_scan_passes`, the two forwards'
+own first and last lines); every named sub-scope nests inside one. What is
+left under NO region is what no line here asked for: the layer loops'
+counters and slices of the stacked weights, and whatever copy, pad or
+transpose the compiler placed outside every scope
+(``docs/observability.md``).
 """
 import functools
 from contextlib import nullcontext
@@ -80,7 +97,7 @@ from .kv_cache import BlockedKV, MoeCounters
 from .module_registry import register_impl, select_impl
 from ...models.layers import (alibi_slopes, apply_rope, mlp_block, norm,
                               qk_norm, rms_norm, scaled)
-from ...monitor.mfu import scope
+from ...monitor.mfu import region_scope, scope
 from ...ops.grouped_gemm import row_tile, tile_visits
 
 NEG_INF = jnp.finfo(jnp.float32).min
@@ -482,33 +499,40 @@ def _arch_bias(cfg):
             if cfg.pos_embed == "alibi" else None)
 
 
-def _embed(params, tokens, positions, cfg):
-    x = jnp.take(params["embed"]["embedding"], tokens, axis=0)
-    if cfg.pos_embed == "learned":
-        table = params["pos_embed"]["embedding"]
-        pos = jnp.clip(positions + cfg.pos_embed_offset, 0,
-                       table.shape[0] - 1)
-        x = x + jnp.take(table, pos, axis=0).astype(x.dtype)
-    if cfg.embed_scale != 1.0:
-        x = x * cfg.embed_scale
-    x = x.astype(jnp.dtype(cfg.dtype))
-    if cfg.embed_norm:
-        x = norm(x, params["embed_norm"], cfg)
-    if cfg.hc_mult > 1:    # every residual stream starts as the embedding
-        with scope("mhc"):
-            x = jnp.broadcast_to(x, (cfg.hc_mult, *x.shape))
-    return x
+def _embed(params, tokens, sampled, take_from, positions, cfg):
+    """The residual stream's first value (MFU region ``embed``): the rows
+    of the table at the ids :func:`_tokens_in` chooses, the learned
+    positions, the multipliers, the norm."""
+    with region_scope("embed"):
+        tokens = _tokens_in(tokens, sampled, take_from)
+        x = jnp.take(params["embed"]["embedding"], tokens, axis=0)
+        if cfg.pos_embed == "learned":
+            table = params["pos_embed"]["embedding"]
+            pos = jnp.clip(positions + cfg.pos_embed_offset, 0,
+                           table.shape[0] - 1)
+            x = x + jnp.take(table, pos, axis=0).astype(x.dtype)
+        if cfg.embed_scale != 1.0:
+            x = x * cfg.embed_scale
+        x = x.astype(jnp.dtype(cfg.dtype))
+        if cfg.embed_norm:
+            x = norm(x, params["embed_norm"], cfg)
+        if cfg.hc_mult > 1:    # every residual stream starts as the embedding
+            with scope("mhc"):
+                x = jnp.broadcast_to(x, (cfg.hc_mult, *x.shape))
+        return x
 
 
 def _final_norm(params, x, cfg):
-    if cfg.hc_mult > 1:    # the streams close by their sum
-        with scope("mhc"):
-            x = x.astype(jnp.float32).sum(0).astype(x.dtype)
-    return norm(x, params["final_norm"], cfg)
+    with region_scope("head"):
+        if cfg.hc_mult > 1:    # the streams close by their sum
+            with scope("mhc"):
+                x = x.astype(jnp.float32).sum(0).astype(x.dtype)
+        return norm(x, params["final_norm"], cfg)
 
 
 def _unembed(params, x, cfg):
-    with scope("lm_head"):
+    """Float32 logits of the normed rows x [S, d] (MFU region ``head``)."""
+    with region_scope("head"), scope("lm_head"):
         if cfg.tie_embeddings:
             logits = jnp.einsum("sd,vd->sv", x,
                                 params["embed"]["embedding"].astype(x.dtype))
@@ -518,31 +542,37 @@ def _unembed(params, x, cfg):
             if cfg.lm_head_bias:
                 logits = logits + params["lm_head"]["bias"].astype(
                     logits.dtype)
-        return logits if cfg.logit_scale == 1.0 \
-            else logits * cfg.logit_scale
+        return (logits if cfg.logit_scale == 1.0
+                else logits * cfg.logit_scale).astype(jnp.float32)
 
 
 def _block(cfg, p, x, attn_fn, live, experts=None):
     """One transformer block over flat tokens, covering sequential and
     parallel (GPT-J/NeoX/Falcon/Phi) residual forms. ``live`` [T]: the rows
     that are tokens, not padding; ``experts``: :func:`_mlp`'s. Returns
-    (x, :func:`_mlp`'s expert rows)."""
+    (x, :func:`_mlp`'s expert rows). Each sublayer with its norm and its
+    residual add is an MFU region (``attn``, ``mlp``)."""
     if cfg.hc_mult > 1:
         return _hc_block(cfg, p, x, attn_fn, live, experts)
-    x_norm = norm(x, p["attn_norm"], cfg)
-    attn = attn_fn(x_norm)
-    h = _attn_out(p["attn"], attn, cfg, x.shape[0])
-    if cfg.parallel_block:
-        y = x_norm if cfg.shared_block_norm else norm(x, p["mlp_norm"], cfg)
-        m, rows = _mlp(p, y, cfg, live, experts)
-        return (x + h + m).astype(x.dtype), rows
-    if cfg.sandwich_norm:    # the sublayer's OUTPUT is normed too
-        h = norm(h, p["attn_post_norm"], cfg)
-    x = (x + h).astype(x.dtype)
-    m, rows = _mlp(p, norm(x, p["mlp_norm"], cfg), cfg, live, experts)
-    if cfg.sandwich_norm:
-        m = norm(m, p["mlp_post_norm"], cfg)
-    return (x + m).astype(x.dtype), rows
+    with region_scope("attn"):
+        x_norm = norm(x, p["attn_norm"], cfg)
+        attn = attn_fn(x_norm)
+        h = _attn_out(p["attn"], attn, cfg, x.shape[0])
+    if cfg.parallel_block:   # (the one sum of both branches: the MLP's)
+        with region_scope("mlp"):
+            y = x_norm if cfg.shared_block_norm \
+                else norm(x, p["mlp_norm"], cfg)
+            m, rows = _mlp(p, y, cfg, live, experts)
+            return (x + h + m).astype(x.dtype), rows
+    with region_scope("attn"):
+        if cfg.sandwich_norm:    # the sublayer's OUTPUT is normed too
+            h = norm(h, p["attn_post_norm"], cfg)
+        x = (x + h).astype(x.dtype)
+    with region_scope("mlp"):
+        m, rows = _mlp(p, norm(x, p["mlp_norm"], cfg), cfg, live, experts)
+        if cfg.sandwich_norm:
+            m = norm(m, p["mlp_post_norm"], cfg)
+        return (x + m).astype(x.dtype), rows
 
 
 def _hc_maps(hc, x, cfg):
@@ -592,10 +622,13 @@ def _hc_block(cfg, p, x, attn_fn, live, experts=None):
         return x, rows
 
     t = x.shape[1]
-    x, _ = sublayer(x, p["hc_attn"], lambda u: (_attn_out(
-        p["attn"], attn_fn(norm(u, p["attn_norm"], cfg)), cfg, t), None))
-    return sublayer(x, p["hc_mlp"], lambda u: _mlp(
-        p, norm(u, p["mlp_norm"], cfg), cfg, live, experts))
+    # (a sublayer's maps and mixes count with the sublayer they are around)
+    with region_scope("attn"):
+        x, _ = sublayer(x, p["hc_attn"], lambda u: (_attn_out(
+            p["attn"], attn_fn(norm(u, p["attn_norm"], cfg)), cfg, t), None))
+    with region_scope("mlp"):
+        return sublayer(x, p["hc_mlp"], lambda u: _mlp(
+            p, norm(u, p["mlp_norm"], cfg), cfg, live, experts))
 
 
 def _paged_attention(q, k_cache, v_cache, token_seq, token_pos, block_tables,
@@ -1039,7 +1072,8 @@ def _scan_passes(layer, x, kv: BlockedKV, params, cfg, pick, live):
     that are unembedded are kept of each pass (``pick(x) -> [S, d]``, so
     [U, S, d]); the exit gate and the rule (:func:`exit_choice`) choose each
     row's pass among them, and ``kv.exit_pass`` counts the ``live`` [S] rows
-    by the pass chosen. Every pass is computed whatever the rule says.
+    by the pass chosen (all of it the MFU region ``head``'s, as every pass's
+    final norm is). Every pass is computed whatever the rule says.
     Returns ``(the chosen normed rows [S, d], the new BlockedKV)``."""
     n, u_steps = cfg.num_layers, cfg.total_ut_steps
 
@@ -1052,19 +1086,19 @@ def _scan_passes(layer, x, kv: BlockedKV, params, cfg, pick, live):
             (x, pools), _ = jax.lax.scan(
                 body, carry, (params["layers"], jnp.arange(n)))
             x = _final_norm(params, x, cfg)
-            return (x, pools), pick(x)
+            with region_scope("head"):
+                return (x, pools), pick(x)
 
     (_, pools), h = jax.lax.scan(one_pass, (x, kv.pools),
                                  jnp.arange(u_steps))
-    with jax.named_scope("loop_exit"):
+    with region_scope("head"), jax.named_scope("loop_exit"):
         chosen = exit_choice(params["exit_gate"], h,
                              cfg.early_exit_threshold)
         h_exit = jnp.take_along_axis(h, chosen[None, :, None], axis=0)[0]
-        counted = jnp.sum(
+        counted = kv.exit_pass + jnp.sum(
             (chosen[:, None] == jnp.arange(u_steps)) & live[:, None],
             axis=0, dtype=jnp.int32)
-    return h_exit, kv.with_pools(pools)._replace(
-        exit_pass=kv.exit_pass + counted)
+    return h_exit, kv.with_pools(pools)._replace(exit_pass=counted)
 
 
 def _count_moe(moe, rows, cfg, tokens: int):
@@ -1072,14 +1106,15 @@ def _count_moe(moe, rows, cfg, tokens: int):
     [L_moe, E] (None: a dense model, whose ``moe`` is None too)."""
     if rows is None:
         return moe
-    load = moe.load + rows
-    if moe.rows is not None:
-        rows = rows[:, cfg.held_experts]
-    return MoeCounters(
-        load, jnp.sum(rows > 0, dtype=jnp.int32),
-        None if moe.rows is None else jnp.sum(rows, dtype=jnp.int32),
-        None if moe.tiles is None else tile_visits(
-            rows, moe_tile_rows(cfg, tokens)))
+    with region_scope("mlp"):    # the experts' counters: their layers'
+        load = moe.load + rows
+        if moe.rows is not None:
+            rows = rows[:, cfg.held_experts]
+        return MoeCounters(
+            load, jnp.sum(rows > 0, dtype=jnp.int32),
+            None if moe.rows is None else jnp.sum(rows, dtype=jnp.int32),
+            None if moe.tiles is None else tile_visits(
+                rows, moe_tile_rows(cfg, tokens)))
 
 
 def layer_plan(pattern: str):
@@ -1240,8 +1275,15 @@ def _walk_pattern(cfg, params, x, kv: BlockedKV, attend, ssm_step, live,
         lambda a: a[j], tree)
 
     def one(kind, carry, j):
+        # a layer is ONE mixer with its norm and its residual add: the
+        # channel mixers (F, E) the MFU region mlp, every other kind attn
+        with region_scope("mlp" if kind in "FE" else "attn"):
+            return mix(kind, carry, j)
+
+    def mix(kind, carry, j):
         x, pools, state = carry
         rows = None
+
         def ssm_fn(p, xbc, dt):
             nonlocal state
             y, state = ssm_step(p, xbc, dt, state, j)
@@ -1329,8 +1371,9 @@ def _walk_pattern(cfg, params, x, kv: BlockedKV, attend, ssm_step, live,
         for kind in done:
             done[kind] += reps * per[kind]
     x, pools, state = carry
-    moe = _count_moe(kv.moe, jnp.concatenate(routed) if routed else None,
-                     cfg, x.shape[-2])
+    with region_scope("mlp"):
+        routed = jnp.concatenate(routed) if routed else None
+    moe = _count_moe(kv.moe, routed, cfg, x.shape[-2])
     kv = kv.with_pools(pools[:n_pools]).with_state(state)._replace(moe=moe)
     return x, kv._replace(bsa=pools[n_pools]) if counts else kv
 
@@ -1386,16 +1429,19 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
         block = tables[jnp.minimum(token_seq, s - 1), token_pos // bs]
         return jnp.where(pad, num_slots, block * bs + token_pos % bs)
 
-    dest = dest_in(block_tables, kv.num_slots)
-    dest_w = None if window_tables is None \
-        else dest_in(window_tables, kv.window_slots)
+    # (where every layer's mixer writes and whom it reads beside: the
+    # mixers', computed once)
+    with region_scope("attn"):
+        dest = dest_in(block_tables, kv.num_slots)
+        dest_w = None if window_tables is None \
+            else dest_in(window_tables, kv.window_slots)
 
-    x = _embed(params, _tokens_in(tokens, sampled, take_from), token_pos,
-               cfg)
+    x = _embed(params, tokens, sampled, take_from, token_pos, cfg)
     if cfg.index_topk:    # which rows share a row of the indexer's pool
         from .dsa import pair_mates
 
-        mates = pair_mates(token_seq, token_pos, ~pad)
+        with region_scope("attn"):
+            mates = pair_mates(token_seq, token_pos, ~pad)
 
     def attend(p_attn, y, pools, l, j=0):
         """Layer ``l``'s attention over the normed rows y: the new rows into
@@ -1479,7 +1525,9 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
             out, pools = attend(p["attn"], y, pools, l, j)
             return out
 
-        x, rows = _block(cfg, p, x, attn_fn, ~pad, experts)
+        with region_scope("mlp"):    # the rows the experts count
+            live = ~pad
+        x, rows = _block(cfg, p, x, attn_fn, live, experts)
         return (x, pools), rows
 
     def ssm_step(p, xbc, dt, state, l):
@@ -1556,20 +1604,23 @@ def ragged_forward(model, params: Any, kv: BlockedKV, tokens, token_seq,
 
     if cfg.total_ut_steps > 1:
         # a slot's row is a sequence's where the batch has a chunk of it
+        with region_scope("head"):    # (the rows the exit counts)
+            in_batch = token_seq[last_tok_idx] == jnp.arange(s)
         h_last, kv = _scan_passes(
-            layer, x, kv, params, cfg, lambda x: x[last_tok_idx],
-            token_seq[last_tok_idx] == jnp.arange(s))
-        return _unembed(params, h_last, cfg).astype(jnp.float32), kv
+            layer, x, kv, params, cfg, lambda x: x[last_tok_idx], in_batch)
+        return _unembed(params, h_last, cfg), kv
     if cfg.layer_pattern is not None:
-        x, kv = _walk_pattern(cfg, params, x, kv, attend, ssm_step, ~pad,
+        with region_scope("mlp"):    # the rows the experts count
+            live = ~pad
+        x, kv = _walk_pattern(cfg, params, x, kv, attend, ssm_step, live,
                               (kda_conv, kda_scan), (token_pos, la_scan))
     else:
         x, kv = _scan_layers(layer, x, kv, params, cfg)
 
     x = _final_norm(params, x, cfg)
-    h_last = x[last_tok_idx]  # [S, d] — logits_gather
-    logits = _unembed(params, h_last, cfg)
-    return logits.astype(jnp.float32), kv
+    with region_scope("head"):
+        h_last = x[last_tok_idx]  # [S, d] — logits_gather
+    return _unembed(params, h_last, cfg), kv
 
 
 def _jit_program(name: str, fn, model, **static):
@@ -1619,13 +1670,13 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
             tables, (positions // bs)[:, None], axis=1)[:, 0]
         return jnp.where(active, block * bs + positions % bs, num_slots)
 
-    dest = dest_in(block_tables, kv.num_slots)
-    dest_w = None if window_tables is None \
-        else dest_in(window_tables, kv.window_slots)
-    seq_lens = jnp.where(active, positions + 1, 0)
+    with region_scope("attn"):    # as ragged_forward's
+        dest = dest_in(block_tables, kv.num_slots)
+        dest_w = None if window_tables is None \
+            else dest_in(window_tables, kv.window_slots)
+        seq_lens = jnp.where(active, positions + 1, 0)
 
-    x = _embed(params, _tokens_in(tokens, sampled, take_from), positions,
-               cfg)
+    x = _embed(params, tokens, sampled, take_from, positions, cfg)
 
     def attend(p_attn, y, pools, l, j=0):
         if cfg.retention_degree:    # the state step in the place of attention
@@ -1724,15 +1775,13 @@ def decode_forward(model, params: Any, kv: BlockedKV, tokens, positions,
 
     if cfg.total_ut_steps > 1:
         x, kv = _scan_passes(layer, x, kv, params, cfg, lambda x: x, active)
-        return _unembed(params, x, cfg).astype(jnp.float32), kv
+        return _unembed(params, x, cfg), kv
     if cfg.layer_pattern is not None:
         x, kv = _walk_pattern(cfg, params, x, kv, attend, ssm_step, active,
                               (kda_conv, kda_scan), (positions, la_scan))
     else:
         x, kv = _scan_layers(layer, x, kv, params, cfg)
-    x = _final_norm(params, x, cfg)
-    logits = _unembed(params, x, cfg)
-    return logits.astype(jnp.float32), kv
+    return _unembed(params, _final_norm(params, x, cfg), cfg), kv
 
 
 def build_decode_forward_fn(model, block_size: int, attn_impl: str = "auto"):

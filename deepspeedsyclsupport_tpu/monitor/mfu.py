@@ -9,15 +9,19 @@ one timed event per executed HLO op named by instruction. This module owns
 the three joins between those worlds:
 
 * :func:`build_opmap` — compiled-HLO text → ``{instruction: {region, pass,
-  category}}`` (the named_scope metadata is read here; collectives override
-  to the ``collective`` region by opcode, since the partitioner inserts
-  them with no scope; the same ``op_name`` path says which PASS of the step
-  the instruction belongs to: forward, backward, remat's recomputed forward).
+  category, scope, root}}`` (the named_scope metadata is read here;
+  collectives override to the ``collective`` region by opcode, since the
+  partitioner inserts them with no scope; the same ``op_name`` path says
+  which PASS of the step the instruction belongs to: forward, backward,
+  remat's recomputed forward, and which :data:`SUB_SCOPES` label it lies
+  under; ``root`` is what a fusion's fused computation ends in).
 * :func:`publish` / :func:`published` — the map of the step the engine
-  compiled, kept by program name (``train_batch_fn``) for whoever holds a
-  trace of that program: :func:`ledger`'s caller, ``tools/mfu_report.py``
-  through the persisted ``mfu_opmap.json``, and the benchmark's
-  ``train_*_ms`` readers.
+  compiled, kept by program name (``train_batch_fn``; a serving forward's
+  ``ragged_forward@<rows>``, one a static shape:
+  ``InferenceEngineV2.published_programs()`` has the names) for whoever
+  holds a trace of that program: :func:`ledger`'s caller,
+  ``tools/mfu_report.py`` through the persisted ``mfu_opmap.json``, and the
+  benchmark's ``train_*_ms`` and ``fwd_split_pct`` readers.
 * :func:`parse_trace` — ``trace.json.gz`` (Chrome-trace) → timed op events,
   with truncation salvage: a torn gzip / half-written JSON from a killed
   run yields everything parseable plus a ``truncated`` flag, never a crash
@@ -113,6 +117,8 @@ SUB_SCOPES = ("moe_route", "moe_experts", "moe_combine", "moe_shared",
               # the unembedding of every serving forward
               "h1_attn", "lm_head")
 
+_SUB_SCOPES = frozenset(SUB_SCOPES)
+
 #: named_scope label prefix — ``mfu.attn`` etc. Kept short and distinctive
 #: so the metadata regex can't false-positive on user scopes.
 SCOPE_PREFIX = "mfu."
@@ -179,6 +185,14 @@ def region_of(op_name: str) -> Optional[str]:
     return name if name in SCOPE_REGIONS else None
 
 
+def scope_of(op_name: str) -> Optional[str]:
+    """The innermost :data:`SUB_SCOPES` label among the ``/``-separated
+    components of an HLO ``metadata op_name`` path (``.../mfu.mlp/
+    moe_experts/ragged_dot`` → ``moe_experts``); ``None`` under none."""
+    return next((part for part in reversed((op_name or "").split("/"))
+                 if part in _SUB_SCOPES), None)
+
+
 def pass_of(op_name: str, region: Optional[str]) -> Optional[str]:
     """Pass of the training step an HLO ``metadata op_name`` path lies in,
     by its ``/``-separated components: ``recompute`` where one is
@@ -228,24 +242,64 @@ _INSTR_RE = re.compile(
 _METADATA_RE = re.compile(r'metadata=\{[^}]*op_name="([^"]*)"')
 
 
+# a computation's header, `%fused_computation.3 (param_0: f32[8]) -> f32[8] {`
+# (or `ENTRY %main ...`), and what a fusion's line says it runs
+_COMPUTATION_RE = re.compile(r"^\s*(?:ENTRY\s+)?%?([\w.\-]+)\s+\(.*->.*\{\s*$")
+_CALLS_RE = re.compile(r"\bcalls=%?([\w.\-]+)")
+_OPERAND_RE = re.compile(r"[\w.\-]+")   # (and a type's tokens: no names)
+#: opcodes a fusion's ROOT is seen THROUGH to what they wrap: a `bitcast`
+#: moves nothing, and a multi-output fusion ends in a `tuple` of its results
+_ROOT_THROUGH = ("bitcast", "tuple")
+
+
+def _root_of(computation: Dict[str, Tuple[str, str]], name: str,
+             seen: int = 0) -> str:
+    """Opcode(s) the instruction ``name`` of ``computation`` (``{name:
+    (opcode, its operands as the line spells them)}``) comes to: its own, or
+    through a ``bitcast`` / ``tuple`` its operands' (several that differ are
+    joined by ``+`` in their order: a fusion with two results of two kinds
+    is neither)."""
+    opcode, operands = computation[name]
+    if opcode not in _ROOT_THROUGH or seen > 8:
+        return opcode
+    inner = [o for o in _OPERAND_RE.findall(operands) if o in computation
+             and computation[o][0] != "parameter"]
+    return "+".join(dict.fromkeys(
+        _root_of(computation, o, seen + 1) for o in inner)) or opcode
+
+
 def build_opmap(hlo_text: str) -> Dict[str, Dict[str, Any]]:
     """Compiled-HLO text → ``{instruction_name: {"region", "pass",
-    "category", "opcode", "op_name"}}`` for every instruction in every
-    computation (trace events are named by instruction; names are unique
-    module-wide).
+    "category", "opcode", "op_name", "scope", "root"}}`` for every
+    instruction in every computation (trace events are named by instruction;
+    names are unique module-wide).
 
     Region precedence: collective opcode > ``mfu.<region>`` scope in the
     op_name metadata > ``other``. ``pass`` is :func:`pass_of` of the same
     path (a fusion's line carries its root's path, so a fusion is its
-    root's region and pass). Trivial bookkeeping opcodes (parameter/
-    constant/tuple plumbing) are skipped — they never carry measured time.
+    root's region and pass) and ``scope`` :func:`scope_of` of it. ``root``:
+    of a fusion, the opcode its fused computation's ROOT comes to
+    (:func:`_root_of`: a ``copy``, a ``pad`` behind its producer's convert,
+    a ``convolution``), of any other instruction its own opcode; a reader
+    that asks what only MOVES data asks it. Trivial bookkeeping opcodes
+    (parameter/constant/tuple plumbing) are skipped — they never carry
+    measured time.
     """
     out: Dict[str, Dict[str, Any]] = {}
+    roots: Dict[str, str] = {}          # computation -> what its ROOT is
+    fused: Dict[str, str] = {}          # fusion instruction -> computation
+    current, body = None, {}            # the computation the lines stand in
     for line in hlo_text.splitlines():
         m = _INSTR_RE.match(line)
         if not m:
+            c = _COMPUTATION_RE.match(line)
+            if c:
+                current, body = c.group(1), {}
             continue
         name, opcode = m.group(1), m.group(2)
+        body[name] = (opcode, line[m.end():].split(")", 1)[0])
+        if line.lstrip().startswith("ROOT "):
+            roots[current] = _root_of(body, name)
         if opcode in ("parameter", "constant", "tuple", "get-tuple-element"):
             continue
         meta = _METADATA_RE.search(line)
@@ -254,9 +308,16 @@ def build_opmap(hlo_text: str) -> Dict[str, Dict[str, Any]]:
             region = "collective"
         else:
             region = region_of(path) or "other"
+        if opcode == "fusion":
+            calls = _CALLS_RE.search(line)
+            if calls:
+                fused[name] = calls.group(1)
         out[name] = {"region": region, "pass": pass_of(path, region),
                      "category": _category_of(opcode), "opcode": opcode,
-                     "op_name": path}
+                     "op_name": path, "scope": scope_of(path),
+                     "root": opcode}
+    for name, computation in fused.items():
+        out[name]["root"] = roots.get(computation, "fusion")
     return out
 
 

@@ -12,8 +12,10 @@ Usage, from the root of a checkout, on the chip::
 Everything after the two options is ``benchmark.run``'s. The result line is
 the harness's own with the extra entries in it (an entry whose reader finds
 nothing is left out, as always); the breakdown goes to stderr on lines that
-start ``BREAKDOWN`` (by scope, by operation, and the six largest copies
-with their shapes and layouts). ``benchmark/`` is not edited: the extra entries are
+start ``BREAKDOWN`` (by scope, by operation, the six largest copies
+with their shapes and layouts, and the serving forwards' time by MFU region
+and by what each region's operations end in: ``fwd_split_pct.split``).
+``benchmark/`` is not edited: the extra entries are
 handed to ``spec.Bench`` for the length of this process. An entry that
 shares its reader with another takes the suffix its own cells have
 (``decode_fwd_ms.moe``); the defaults are those of a sparse serving cell
@@ -26,6 +28,7 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmark import run, scopes, spec, trace  # noqa: E402
+from benchmark.metrics import fwd_split_pct  # noqa: E402
 
 READERS = ("decode_fwd_ms.moe", "ragged_fwd_ms.moe", "moe_share_pct",
            "moe_roofline", "expert_load_max_over_mean", "live_seqs_mean",
@@ -83,8 +86,31 @@ def breakdown(labels):
             print(f"BREAKDOWN copy {p:16s} {1e3 * d:9.2f} ms "
                   f"{n / max(1, execs.get(p, 1)):5.2f} a forward  "
                   f"{text[:300]}", file=sys.stderr)
+        regions(obs)
         return None
     return read
+
+
+def regions(obs):
+    """The window's busy time by owner (an MFU region inside the two
+    forwards, ``unmapped``, any other program by its name) and, inside each
+    region, by what its operations end in."""
+    table = fwd_split_pct.split(obs)
+    if not table:
+        print("BREAKDOWN region -  (the program publishes no map or opens "
+              "no region)", file=sys.stderr)
+        return
+    busy = table["busy_s"] or 1.0
+    print(f"BREAKDOWN region {'busy':16s} {1e3 * busy:9.2f} ms  of which "
+          f"the forwards {1e3 * table['forwards_s']:9.2f} ms",
+          file=sys.stderr)
+    for owner, s in sorted(table["owners"].items(), key=lambda kv: -kv[1]):
+        print(f"BREAKDOWN region {owner:16s} {1e3 * s:9.2f} ms "
+              f"{100 * s / busy:6.2f} %", file=sys.stderr)
+    for owner, row in table["roots"].items():
+        for root, s in sorted(row.items(), key=lambda kv: -kv[1])[:12]:
+            print(f"BREAKDOWN root {owner:10s} {root:28s} {1e3 * s:9.2f} ms "
+                  f"{100 * s / busy:6.2f} %", file=sys.stderr)
 
 
 def unlisted(extra, labels):
