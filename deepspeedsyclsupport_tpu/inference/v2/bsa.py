@@ -19,8 +19,9 @@ Two routes, by the chunk's length as everywhere on this path:
 
 * a chunk of two tokens or more, cut into atoms: a tile of the scores and of
   the selection is an atom, and the ragged paged kernel runs under the
-  selection's MASK, a KV head's rows under their own head's
-  (``ragged_prefill_attention``'s ``sel`` with a kv-head axis). It visits
+  selection of BLOCKS, a KV head's rows under their own head's
+  (``ragged_prefill_attention``'s ``sel`` with a kv-head axis: no mask of
+  keys is made outside the kernel). It visits
   every cached page of the atom and keeps the selected. (The union of an
   atom's pages as a list, which the same kernel could walk, is not written:
   over seeded weights the 128 rows of an atom choose nearly every visible
@@ -108,12 +109,12 @@ def attend_atoms(q, c_seq, k_cache, v_cache, layer, ctx, sizes, impl):
         sel = sparse_block.select_blocks(scores, pos, ctx.atom_qlen, sizes,
                                          impl)
     with scope("bsa_attend"):
-        # a page's keys alike: [A, BQ, KVH, blocks] -> [A, KVH, BQ, keys]
-        mask = jnp.repeat(jnp.swapaxes(sel, 1, 2), sizes.block, axis=-1)
+        # the blocks as they were chosen, a block's rows on the lanes: the
+        # kernel widens a step's to its keys, nothing here does
         out = ragged_prefill_attention(
             q_at, k_cache, v_cache, ctx.atom_tables, ctx.atom_pos0,
             ctx.atom_qlen, block_size=ctx.block_size, layer=layer, impl=impl,
-            sel=mask, name="bsa_prefill")
+            sel=jnp.transpose(sel, (0, 2, 3, 1)), name="bsa_prefill")
         out = out.reshape(-1, *out.shape[2:])[ctx.atom_inv]
     return out, _counts(sel, pos, ctx.atom_qlen, sizes, union=True)
 

@@ -256,10 +256,13 @@ def _attend_tile(a, block_tables_ref, pos0_ref, qlen_ref, layer_ref,
     the SELECTION ``[A, BQ, keys]`` int8 in HBM and, after theirs, its
     scratch;
     a step's ``[BQ, step keys]`` of it (whole 128-key lane tiles) rides the
-    step's DMAs and a pair counts only where it is nonzero. A selection
-    with a kv-head axis, ``[A, KVH, BQ, keys]`` (blocks chosen a KV GROUP:
-    ``inference/v2/bsa.py``), rides the same way a head, and a kv head's
-    rows count under their own head's.
+    step's DMAs and a pair counts only where it is nonzero. A selection of
+    BLOCKS a kv head (chosen a KV GROUP: ``inference/v2/bsa.py``) arrives
+    cut into steps, ``[A, steps, KVH, pages, BQ]``: a step's ``pages`` rows
+    of ``BQ`` lanes a head ride its DMAs, are widened to the step's keys
+    here (a product with the one-hot ``[pages, step keys]``, which turns
+    them row-major on the way), and a kv head's rows count under their own
+    head's. No array of keys is made outside the kernel.
 
     The hand-over: the tile's last step starts the first step's copies of
     its SUCCESSOR in the grid (the next head tile of the atom, else the
@@ -359,13 +362,17 @@ def _attend_tile(a, block_tables_ref, pos0_ref, qlen_ref, layer_ref,
                 cps.append(pltpu.make_async_copy(
                     hbm.at[layer, pl.ds(blk * block_size, block_size)],
                     dst, sem.at[slot, n]))
-        if masked:       # the step's columns of the atom's selection
+        if masked and sel_vmem.ndim == 3:
+            # the step's columns of the atom's selection of keys
             cols = pl.ds(pl.multiple_of(step * step_keys, step_keys),
                          step_keys)
             cps.append(pltpu.make_async_copy(
-                sel_hbm.at[tile, :, cols] if sel_vmem.ndim == 3
-                else sel_hbm.at[tile, :, :, cols],
-                sel_vmem.at[slot], sem.at[slot, len(pools)]))
+                sel_hbm.at[tile, :, cols], sel_vmem.at[slot],
+                sem.at[slot, len(pools)]))
+        elif masked:     # the step's blocks a kv head, whole rows of BQ
+            cps.append(pltpu.make_async_copy(
+                sel_hbm.at[tile, step], sel_vmem.at[slot],
+                sem.at[slot, len(pools)]))
         return cps
 
     # Every copy is started once and awaited once, on the semaphore of the
@@ -488,9 +495,20 @@ def _attend_tile(a, block_tables_ref, pos0_ref, qlen_ref, layer_ref,
         if window is not None:
             valid = jnp.logical_and(valid, q_pos - pos < window)
         if masked:
+            chosen = sel_vmem[cur].astype(jnp.float32)
+            if chosen.ndim == 3:
+                # blocks a kv head [KVH, pages, BQ] -> [KVH, BQ, keys]: a
+                # block's flag at each of its keys (0 or 1: exact)
+                block_of = jax.lax.broadcasted_iota(
+                    jnp.int32, (pages, step_keys), 1) // block_size
+                spread = (block_of == jax.lax.broadcasted_iota(
+                    jnp.int32, (pages, step_keys), 0)).astype(jnp.float32)
+                chosen = jnp.stack([jax.lax.dot_general(
+                    chosen[n], spread, (((0,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                    for n in range(kvh)])
             # [BQ, keys] -> a row's G lanes alike -> the scores' [BQ·G, keys]
             # (a selection a kv head: [KVH, BQ, keys] -> [KVH, BQ·G, keys])
-            chosen = sel_vmem[cur].astype(jnp.float32)
             lead = chosen.shape[:-2]
             chosen = jnp.broadcast_to(
                 chosen[..., None, :], (*lead, bq, g, step_keys)).reshape(
@@ -671,8 +689,8 @@ def _ragged_vmem_need(bq: int, h: int, kvh: int, d: int, block_size: int,
     that many times the keys. ``kv_tile``: a K-and-V pool's tile, which
     feeds the MXU the pool's dtype (no float32 K and V) and makes its mask
     once for all kv heads, but may carry a selection's slots; ``sel_heads``:
-    the kv heads that selection has (1: one for all), whose mask is then a
-    head's own."""
+    the kv heads that selection has (1: one of keys for all; more: one of
+    blocks a head), whose mask is then a head's own."""
     q_tile = bq * h * d
     kv = pages * block_size * kvh * d
     scores = bq * h * pages * block_size
@@ -684,8 +702,9 @@ def _ragged_vmem_need(bq: int, h: int, kvh: int, d: int, block_size: int,
                 # (fewer than a vreg's 32 bytes of them are tiled up to it)
                 + 4 * kv * itemsize * max(1, 32 // (kvh * itemsize))
                 + 4 * kv * itemsize      # k, v head-major, and in passing
-                # the selection's slots
-                + 2 * sel_heads * bq * pages * block_size
+                # the selection's slots: a step's keys, or its blocks a head
+                + 2 * bq * pages * (block_size if sel_heads == 1
+                                    else sel_heads)
                 # pos, q_pos, valid, bias (the last two a selection's head)
                 + (2 + 2 * sel_heads) * (scores // kvh) * 4
                 + 4 * scores * 4)                  # scores, p twice, exp
@@ -744,12 +763,14 @@ def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
     custom call's instruction and scope (the decode entry passes its own).
     ``v_cache=None, v_dim=n``: a latent pool, V the leading ``n`` lanes of
     K's rows (the module's docstring). ``sel`` [A, BQ, keys] int8 (either
-    kind of pool): a sparse-attention indexer's selection, nonzero
+    kind of pool): a sparse-attention indexer's selection of TOKENS, nonzero
     where the atom's row attends to the position; the kernel then walks the
     steps its tile's shape gives (whole 128-key lane tiles of the selection:
     :func:`_selection_pages`) and a profile calls it ``dsa_prefill``. ``sel``
-    [A, KVH, BQ, keys] (a K-and-V pool only): a selection a KV head, each
-    head's rows under their own.
+    [A, KVH, blocks, BQ] int8 (a K-and-V pool only): a selection of BLOCKS a
+    KV head, nonzero where the row attends to the table's block, each head's
+    rows under their own; the same steps, and the kernel widens a step's
+    blocks to its keys in VMEM.
     Returns [A, BQ, H, D] ([.., n])."""
     a, bq, h, d = q_atoms.shape
     latent = v_cache is None
@@ -770,15 +791,23 @@ def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
     g = ht // kvh
     # KV blocks a loop step takes: chosen AFTER the tile, from what it left
     pages = _kv_pages_per_step(bq, ht, kvh, d, block_size, itemsize, latent)
+    sel_heads = 1
     if sel is not None:
         pages = _selection_pages(pages, block_size)
-        # whole steps of columns: the last step's DMA reads its full width
-        step = pages * block_size
-        keys = -(-atom_tables.shape[1] * block_size // step) * step
-        sel = jnp.pad(sel[..., :keys].astype(jnp.int8),
-                      ((0, 0),) * (sel.ndim - 1)
-                      + ((0, max(0, keys - sel.shape[-1])),))
+        steps = -(-atom_tables.shape[1] // pages)
         name = "dsa_prefill" if name == "ragged_prefill" else name
+        if sel.ndim == 3:
+            # whole steps of columns: the last step's DMA reads its full
+            # width
+            keys = steps * pages * block_size
+            sel = jnp.pad(sel[..., :keys].astype(jnp.int8), (
+                (0, 0), (0, 0), (0, max(0, keys - sel.shape[-1]))))
+        else:
+            # whole steps of blocks, a step's [KVH, pages, BQ] contiguous
+            sel_heads, blocks = kvh, steps * pages
+            sel = jnp.pad(sel[:, :, :blocks].astype(jnp.int8), (
+                (0, 0), (0, 0), (0, max(0, blocks - sel.shape[2])), (0, 0)))
+            sel = jnp.swapaxes(sel.reshape(a, kvh, steps, pages, bq), 1, 2)
     if alibi is not None:
         # per-lane slope layout matches the kernel's [KVH, BQ·G] score rows:
         # lane (r·G + gi) of kv head kh carries q head kh·G + gi (under one
@@ -799,7 +828,7 @@ def ragged_prefill_attention_pallas(q_atoms, k_cache, v_cache, atom_tables,
         v_dim=v_dim if latent else None,
         vmem_limit=_ragged_vmem_limit(
             bq, ht, kvh, d, block_size, itemsize, pages, not latent,
-            kvh if sel is not None and sel.ndim == 4 else 1),
+            sel_heads),
         interpret=interpret, name=name)
 
 
@@ -849,8 +878,10 @@ def _tiled_call(atom_tables, atom_pos0, atom_qlen, layer, q_atoms, pools, ab,
         scratch_shapes=[
             *(pltpu.VMEM((2, pages * block_size, *row), pool.dtype)
               for pool in pools),
-            *(pltpu.VMEM((2, *m.shape[1:-1], pages * block_size), m.dtype)
-              for m in masks),
+            # a step of the selection: [BQ, step keys] of a tile's keys,
+            # [KVH, pages, BQ] of a kv head's blocks
+            *(pltpu.VMEM((2, bq, pages * block_size) if m.ndim == 3
+                         else (2, *m.shape[2:]), m.dtype) for m in masks),
             pltpu.SemaphoreType.DMA((2, len(pools) + len(masks))),
             # the slot a handed-over first step lies in (_attend_tile)
             pltpu.SMEM((1,), jnp.int32),
@@ -913,10 +944,12 @@ def ragged_prefill_attention_reference(q_atoms, k_cache, v_cache, atom_tables,
         r < atom_qlen[:, None, None, None])
     if window is not None:
         mask = jnp.logical_and(mask, q_pos - j[None, None, None, :] < window)
-    if sel is not None:
-        chosen = sel[..., :max_ctx] != 0
-        mask = jnp.logical_and(mask, chosen[:, None] if sel.ndim == 3 else
-                               jnp.repeat(chosen, h // kvh, axis=1))
+    if sel is not None and sel.ndim == 3:        # tokens a tile
+        mask = jnp.logical_and(mask, (sel[..., :max_ctx] != 0)[:, None])
+    elif sel is not None:       # blocks a kv head [A, KVH, blocks, BQ]
+        chosen = jnp.repeat(jnp.swapaxes(sel[:, :, :bps] != 0, 2, 3),
+                            block_size, axis=-1)
+        mask = jnp.logical_and(mask, jnp.repeat(chosen, h // kvh, axis=1))
     logits = jnp.where(mask, logits, NEG_INF)
     p = jax.nn.softmax(logits, axis=-1)
     p = jnp.where(mask.any(-1, keepdims=True), p, 0.0)  # dead rows → 0

@@ -161,6 +161,23 @@ def test_what_is_refused_says_the_stateful_sentence(built, tmp_path):
     assert stats["bytes_per_slot"] == 3 * 2 * 8 * 8 * 4
 
 
+@pytest.mark.parametrize("uid", [1, 2])
+def test_the_atoms_under_their_blocks_say_what_every_row_alone_says(
+        built, engines, fresh, uid):
+    """The whole route at the engine: chunks of 8 rows as atoms of 4 under
+    the selection of BLOCKS a KV head (the ragged kernel interpreted: steps
+    of 8 pages over tables of 12, so the last step lies half past the
+    table's end and the contexts are no multiple of it; the grid's unused
+    atoms dead) and the one-token rows over their own page tables say the
+    greedy tokens of the engine that runs every row alone through
+    ``jax.numpy``, prompts past ``dense_len`` where rows read 4 blocks of
+    up to 7."""
+    eng = engines(prefill_attn="kernel_interpret",
+                  decode_attn="pallas_interpret", atom_q_size=4, **ROOMY)
+    assert eng.generate([PROMPTS[uid]], max_new_tokens=BUDGET)[0] \
+        == fresh[uid]
+
+
 def test_a_requeued_streams_logits_are_a_fresh_ones(engines):
     """The same through ``put()``: a sequence flushed mid-stream and fed
     again whole (what ``requeue`` does) gives the logits of one that was

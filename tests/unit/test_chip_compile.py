@@ -17,6 +17,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -401,6 +402,70 @@ def test_the_selected_k_and_v_tile_compiles_at_the_rules_width(one_chip):
     calls = [ln for ln in compiled.as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
     assert len(calls) == 1 and "dsa_prefill" in calls[0].split(" = ")[0]
+
+
+# blocks chosen a KV head, at sala-docs-sat's tile: 32 heads over 2 kv heads,
+# 128-row atoms, tables of 1,548 blocks of 64 (99,072 keys), 15 atoms a call
+SALA_TILE = dict(rows=128, heads=32, kv_heads=2, d=128, block_size=64,
+                 table=1548, atoms=15, blocks=12544)
+
+
+def test_the_block_selection_compiles_and_no_mask_of_keys_is_made(one_chip):
+    """The ragged kernel under a selection of BLOCKS a kv head
+    (``bsa_prefill``: ``sel [A, KVH, blocks, BQ]``) at the cell's tile: four
+    blocks a step (256 keys, 387 whole steps of the table), the operand cut
+    into steps ``[15, 387, 2, 4, 128]`` int8 (5.9 MB) and a step's ``[2, 4,
+    128]`` widened to its keys in VMEM (a product with a one-hot contracted
+    over the step's four blocks: the chip's compiler takes the transposed
+    left side), inside the VMEM it states. And the guard that the mechanism
+    ENGAGED where it is served: the compiled ``ragged_forward`` of
+    ``minicpm-sala`` at the cell's shapes holds no int8 array larger than
+    the block selection as its kernel writes it (PRs 59-67 held ``s8[15, 2,
+    128, 99072]``, 380 MB a sparse layer, written, turned and read)."""
+    from deepspeedsyclsupport_tpu.ops.paged_attention import (
+        _VMEM_CAP, _head_tile, _kv_pages_per_step, _ragged_vmem_limit,
+        _selection_pages, kv_step_keys)
+
+    g = SALA_TILE
+    shape = (g["heads"], g["kv_heads"], g["d"], g["block_size"], 2)
+    assert _head_tile(g["rows"], *shape) == g["heads"]
+    pages = _selection_pages(
+        _kv_pages_per_step(g["rows"], *shape, False), g["block_size"])
+    assert pages == 4 and g["table"] % pages == 0
+    assert kv_step_keys(g["rows"], *shape, False, True) == 256
+    assert _ragged_vmem_limit(g["rows"], *shape, pages, True,
+                              g["kv_heads"]) <= _VMEM_CAP
+    a = g["atoms"]
+    pool = ((3, g["blocks"] * g["block_size"], g["kv_heads"], g["d"]),
+            jnp.bfloat16)
+    chosen = (a, g["kv_heads"], g["table"], g["rows"])
+    compiled = _compile(
+        lambda q, k, v, tables, pos0, qlen, sel, layer:
+        ragged_prefill_attention_pallas(
+            q, k, v, tables, pos0, qlen, block_size=g["block_size"],
+            layer=layer, sel=sel, name="bsa_prefill"),
+        one_chip, ((a, g["rows"], g["heads"], g["d"]), jnp.bfloat16), pool,
+        pool, ((a, g["table"]), jnp.int32), ((a,), jnp.int32),
+        ((a,), jnp.int32), (chosen, jnp.int8), ((), jnp.int32))
+    text = compiled.as_text()
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1 and "bsa_prefill" in calls[0].split(" = ")[0]
+    steps = g["table"] // pages
+    assert f"s8[{a},{steps},{g['kv_heads']},{pages},{g['rows']}]" in text
+
+    def widest_int8(text):
+        return max(int(np.prod([int(n) for n in dims.split(",")]))
+                   for dims in re.findall(r"\bs8\[([0-9,]+)\]", text))
+
+    assert widest_int8(text) == int(np.prod(chosen))
+    forward, _kv, _params = _cell_forward(one_chip, "minicpm-sala-d12",
+                                          "ragged_forward")
+    # the widest is the selection as ``bsa_select`` writes it, its blocks
+    # padded to whole lane tiles: s8[30, 128, 1664]
+    lanes = -(-g["table"] // 128) * 128
+    assert widest_int8(forward.as_text()) \
+        == a * g["kv_heads"] * g["rows"] * lanes
 
 
 # a LATENT pool under an indexer's selection, at glm5-docs-sat's tile: 64
@@ -1207,9 +1272,11 @@ def test_the_sala_forwards_compile_and_copy_no_pool(one_chip, program):
     holds no atom) and the lightning state step Mamba-2's kernel, the scopes
     reach the compiled text, K, V, the pooled keys and the state are aliased
     to the result and none of them is copied, and no sequence's ``[heads,
-    rows, windows]`` probabilities stand whole: a tile at a time (0.74 GiB
-    of temporaries; 1.58 while the three lightning stacks ``[9, 4096,
-    4096]`` were laid out transposed once a forward)."""
+    rows, windows]`` probabilities stand whole: a tile at a time (0.10 GiB
+    of temporaries in ``ragged_forward`` since the atoms take their
+    selection a block; 0.74 while its mask of keys stood in HBM, PRs 59-67;
+    1.58 while the three lightning stacks ``[9, 4096, 4096]`` were laid out
+    transposed once a forward)."""
     from benchmark import scopes
 
     compiled, kv, _params = _cell_forward(one_chip, "minicpm-sala-d12",
@@ -1234,8 +1301,8 @@ def test_the_sala_forwards_compile_and_copy_no_pool(one_chip, program):
     held = (2 * kv.k.size + kv.ck.size) * 2 + kv.la_s.size * 4
     assert m.alias_size_in_bytes >= held
     # a tile's probabilities [32, 128, 6192] float32 are 0.1 GB; all of a
-    # chunk's at once would be 0.6
-    assert m.temp_size_in_bytes < 2**30, m.temp_size_in_bytes
+    # chunk's at once would be 0.6, the atoms' mask of keys 0.38 twice
+    assert m.temp_size_in_bytes < 2**28, m.temp_size_in_bytes
     assert not [ln for ln in text.splitlines() if " copy(" in ln and (
         f"bf16[3,{blocks * 64}," in ln or f"bf16[3,{blocks},4," in ln
         or f"f32[9,{seqs + 1},32," in ln)]

@@ -377,15 +377,37 @@ def _kv_tile_selection(rng, bq, keys):
     return jnp.asarray(sel, jnp.int8)
 
 
+def _kv_tile_blocks(rng, bq, kvh, bps):
+    """int8 [atoms, kvh, blocks, bq], a selection of BLOCKS a kv head: half
+    of the table's blocks a (head, row), each head its own, and the rows a
+    step's widening must not fool: atom 3's row 0 selects its own block
+    alone (the last step's; nothing under it), its row 1 block 0 alone,
+    its row 2 nothing; atom 4's rows select DISJOINT blocks (row r block r
+    mod 4 and no other, under either head), so a flag that reached a
+    neighbouring row, block or head shows."""
+    sel = rng.random((len(KV_TILES), kvh, bps, bq)) < 0.5
+    sel[3, :, :, :3] = False
+    sel[3, :, 1030 // 128, 0] = True
+    sel[3, :, 0, 1] = True
+    sel[4] = np.arange(bps)[:, None] == np.arange(bq)[None, :] % 4
+    return jnp.asarray(sel, jnp.int8)
+
+
 @pytest.mark.parametrize("arch", ["plain", "alibi_window"])
 @pytest.mark.parametrize("group", [1, 8])
-@pytest.mark.parametrize("masked", [False, True], ids=["dense", "selected"])
+@pytest.mark.parametrize("masked", [False, True, "blocks"],
+                         ids=["dense", "selected", "blocks"])
 @pytest.mark.parametrize("pages", [1, 2, 4, 8])
 def test_a_k_and_v_tile_of_several_rows_is_its_twin_at_any_step(
         monkeypatch, pages, masked, group, arch):
     """A K-and-V pool's tile of several rows walks steps of ``pages``
     blocks, one kv head's scores at a time; under an indexer's selection a
-    pair counts only where the mask is nonzero. Blocks OUTSIDE the tables'
+    pair counts only where the mask is nonzero, and under a selection of
+    BLOCKS a kv head (two heads here; a step's blocks widened to its keys
+    inside the kernel) only where the row's own head chose the key's block:
+    a context that is no multiple of the step, a dead atom, rows of
+    disjoint blocks, and at 4 and 8 blocks a step a last step past the
+    table's end (11 blocks). Blocks OUTSIDE the tables'
     live part are NaN (another sequence's, or never written): a wide
     step's blocks past the context's end, or below the window's start,
     must not be read as they lie."""
@@ -395,7 +417,8 @@ def test_a_k_and_v_tile_of_several_rows_is_its_twin_at_any_step(
     monkeypatch.setattr(pa, "_kv_pages_per_step", lambda *a: pages)
     bs, bps, blocks, bq, kvh, d = 128, 11, 60, 8, 2, 16
     h = kvh * group
-    rng = np.random.default_rng(pages + 10 * group + masked)
+    rng = np.random.default_rng(pages + 10 * group + bool(masked)
+                                + 100 * (masked == "blocks"))
     window = 200 if arch == "alibi_window" else None
     pos0, qlen = (np.asarray(x) for x in zip(*KV_TILES))
     hi = np.minimum(pos0 + qlen, bps * bs)
@@ -412,7 +435,9 @@ def test_a_k_and_v_tile_of_several_rows_is_its_twin_at_any_step(
     kw = dict(block_size=bs, layer=jnp.int32(1), window=window)
     if arch == "alibi_window":
         kw["alibi"] = jnp.asarray(alibi_slopes(h))
-    if masked:
+    if masked == "blocks":
+        kw["sel"] = _kv_tile_blocks(rng, bq, kvh, bps)
+    elif masked:
         kw["sel"] = _kv_tile_selection(rng, bq, bps * bs)
     args = (jnp.asarray(tables, jnp.int32), jnp.asarray(pos0, jnp.int32),
             jnp.asarray(qlen, jnp.int32))
@@ -429,6 +454,19 @@ def test_a_k_and_v_tile_of_several_rows_is_its_twin_at_any_step(
     if masked and window is None:
         assert not got[3, 2].any()           # a row that selected nothing
         assert np.abs(got[3, :2]).max() > 1e-3
+    if masked == "blocks" and window is None:
+        # the twin itself, against blocks read off by hand: atom 4's row r
+        # attends to block r mod 4 alone (all of it under the row)
+        k, v = np.asarray(clean[0][1]), np.asarray(clean[1][1])
+        for r in (0, 1, 6):
+            slots = tables[4, r % 4] * bs + np.arange(bs)
+            for head in range(h):
+                g = head // group
+                logit = k[slots, g] @ np.asarray(q[4, r, head]) / np.sqrt(d)
+                w = np.exp(logit - logit.max())
+                np.testing.assert_allclose(
+                    got[4, r, head], (w / w.sum()) @ v[slots, g],
+                    atol=2e-5, rtol=2e-5)
 
 
 # --------------------------------------- the hand-over between a call's tiles

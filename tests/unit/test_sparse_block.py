@@ -197,8 +197,8 @@ def attention_twin(q, k, v, read, pos):
 @pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
 def test_both_routes_attend_over_the_twins_selection(impl):
     """``bsa.ragged_attend`` over a batch that holds a chunk of 11 rows (two
-    atoms under the mask, the first two rows under ``dense_len``), a one-token row
-    (its own page table) and pads, then ``bsa.decode_attend`` over two
+    atoms under their blocks, the first two rows under ``dense_len``), a
+    one-token row (its own page table) and pads, then ``bsa.decode_attend`` over two
     rows: each row's output is softmax attention over exactly the blocks
     the twins choose, every head under its own group's choice, and the
     counts are the selection's own."""
@@ -277,6 +277,44 @@ def test_both_routes_attend_over_the_twins_selection(impl):
             attention_twin(np.asarray(qd[s]), k[s], v[s], read, pos),
             rtol=2e-4, atol=2e-5)
     assert np.asarray(pools_d[3])[3] == 2 * KVH * 6     # 6 pages a group
+
+
+@pytest.mark.parametrize("name", ["kernel", "kernel_interpret"])
+def test_the_atoms_route_makes_no_array_of_keys(name):
+    """``bsa.attend_atoms`` hands the ragged kernel the selection of BLOCKS
+    (``[A, KVH, blocks, BQ]``) and the kernel widens a STEP's to its keys:
+    nowhere in the traced route, inside the kernel's body or outside it,
+    stands a value of ``atoms x KVH x BQ x keys`` elements (the mask PRs
+    59-67 built, turned and padded in HBM: 380 MB a layer at the cell's
+    widths), and no int8 value is larger than the selection itself."""
+    from deepspeedsyclsupport_tpu.analysis.jaxpr_walk import iter_eqns
+    from deepspeedsyclsupport_tpu.inference.v2 import bsa
+    from deepspeedsyclsupport_tpu.inference.v2.dsa import kernel_impl
+
+    atoms, bq, bps = 3, 8, 128     # a lane tile of blocks: no pad
+    ctx = type("Ctx", (), dict(
+        block_tables=jnp.zeros((2, bps), jnp.int32), block_size=8,
+        atom_qidx=jnp.zeros((atoms, bq), jnp.int32),
+        token_seq=jnp.zeros((16,), jnp.int32),
+        atom_pos0=jnp.asarray([950, 958, 0]), atom_qlen=jnp.asarray([8, 3, 0]),
+        atom_tables=jnp.zeros((atoms, bps), jnp.int32),
+        atom_inv=jnp.zeros((16,), jnp.int32)))
+    pool = jnp.zeros((1, 48 * 8, KVH, D))
+    c_seq = jnp.zeros((2, bps * 4, KVH, D))
+    jaxpr = jax.make_jaxpr(lambda q: bsa.attend_atoms(
+        q, c_seq, pool, pool, 0, ctx, SIZES, kernel_impl(name)))(
+            jnp.zeros((16, H, D)))
+    keys = atoms * KVH * bq * bps * SIZES.block
+    sel = atoms * KVH * bq * bps
+    # every value the route makes, a kernel's body and a ``jit``'s too
+    shapes = {(v.aval.shape, str(v.aval.dtype))
+              for eqn, _ in iter_eqns(jaxpr.jaxpr) for v in eqn.outvars
+              if hasattr(v.aval, "shape")}
+    assert ((atoms, bq, KVH, bps), "int8") in shapes      # select_blocks'
+    assert ((atoms, KVH, bps, bq), "int8") in shapes      # the kernel's
+    assert not [s for s in shapes if np.prod(s[0]) >= keys], shapes
+    assert not [s for s in shapes if s[1] == "int8"
+                and np.prod(s[0]) > 2 * sel]
 
 
 # ------------------------------------------------- lightning's two entries

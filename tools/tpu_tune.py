@@ -71,14 +71,19 @@ Sections, cheapest first:
             lightning heads of a [128, 128] float32 state), at each of
             ``--ctx`` tokens of context (32 k, 64 k, 96 k): the pooled keys'
             write, the block scores and the selection of one 128-row atom and
-            of 8 one-token rows, the atom under the selection's mask through
-            the ragged kernel (once a KV head), the one-token rows over their
+            of 8 one-token rows, the atom under its selection of BLOCKS a KV
+            head through the ragged kernel (``kernel`` the masked kernel
+            alone, ``xla`` whatever the program runs around it) and, with
+            ``--parent DIR``, that checkout's kernel under the same selection
+            widened to KEYS as PRs 59-67 handed it (the widening inside the
+            program, so ``xla`` holds it), the one-token rows over their
             own page tables beside a dense row over its whole context, ms a
             call; the lightning state step (8 rows: ms a layer and the share
             of the HBM peak) and six 128-row pieces; ``--parity`` holds the
             selection's kernel and both attention routes against their
-            ``jax.numpy`` forms on the chip first:
-            bsa [--ctx N ...] [--parity]
+            ``jax.numpy`` forms (and the atom against the parent's) on the
+            chip first:
+            bsa [--ctx N ...] [--parent DIR] [--parity]
 
   proj    — a projection ALONE, ``[T, in] x W`` at 16 / 32 / 48 / 256 /
             768 rows and every served cell's ``(in, out)`` (read off
@@ -1765,9 +1770,11 @@ def bsa(argv=()):
 
     ap = argparse.ArgumentParser(prog="tpu_tune.py bsa")
     ap.add_argument("--ctx", type=int, nargs="*", default=list(BSA_CTX))
+    ap.add_argument("--parent", default=None)
     ap.add_argument("--parity", action="store_true")
     a = ap.parse_args(list(argv))
     c = BSA_CELL
+    parent = _load_paged(a.parent)[1] if a.parent else None
     for ctx in a.ctx:
         sizes, k_pool, v_pool, ck, tables, q_atom, q_rows = _bsa_operands(ctx)
         s, bq, kvh = c["rows"], c["atom"], c["kv_heads"]
@@ -1819,15 +1826,20 @@ def bsa(argv=()):
                  rows_lens_differ=int(jnp.sum(row_lens != l_x)),
                  blocks_a_row=int(sel_atom[0, -1, 0].sum()))
         tiles = lambda t: jnp.repeat(t, kvh, axis=0)           # noqa: E731
-        mask = jnp.repeat(jnp.swapaxes(sel_atom, 1, 2).reshape(kvh, bq, -1),
-                          sizes.block, axis=-1)
 
-        def atom_attend(impl):
-            return lambda q: pa.ragged_prefill_attention(
-                tiles(q), k_pool, v_pool, tiles(tables[:1]),
-                tiles(one * (ctx - bq)), tiles(one * bq),
-                block_size=sizes.block, layer=0, impl=impl, sel=mask,
-                name="bsa_prefill")
+        def atom_attend(impl, mod=pa, keys=False):
+            """The atom under its selection a KV head as ``bsa.attend_atoms``
+            hands it over: the blocks; ``keys``: widened to keys first, the
+            form PRs 59-67 took."""
+            def f(q):
+                sel = jnp.repeat(jnp.swapaxes(sel_atom, 1, 2), sizes.block,
+                                 axis=-1) if keys \
+                    else jnp.transpose(sel_atom, (0, 2, 3, 1))
+                return mod.ragged_prefill_attention(
+                    q, k_pool, v_pool, tables[:1], one * (ctx - bq), one * bq,
+                    block_size=sizes.block, layer=0, impl=impl, sel=sel,
+                    name="bsa_prefill")
+            return f
 
         def rows_attend(impl):
             return lambda q: pa.paged_decode_attention(
@@ -1844,13 +1856,25 @@ def bsa(argv=()):
             ref = jax.jit(rows_attend("xla"))(q_rows).astype(jnp.float32)
             emit("bsa_parity_rows", ctx=ctx, max_abs=float(
                 jnp.max(jnp.abs(got - ref))), scale=float(jnp.std(ref)))
+            got = jax.jit(atom_attend("pallas"))(q_atom).astype(jnp.float32)
+            ref = jax.jit(atom_attend("xla"))(q_atom).astype(jnp.float32)
+            was = {} if parent is None else dict(differs_from_parent=int(
+                jnp.sum(got != jax.jit(atom_attend("pallas", parent, True))(
+                    q_atom).astype(jnp.float32))))
+            emit("bsa_parity_atom", ctx=ctx, max_abs=float(
+                jnp.max(jnp.abs(got - ref))), scale=float(jnp.std(ref)),
+                 **was)
         steps = {
             "scores_select_atom": named("scores_select_atom",
                                         atom_select("pallas")),
             "scores_select_rows": named("scores_select_rows",
                                         rows_select("pallas")),
-            "attend_atom_mask": named("attend_atom_mask",
-                                      atom_attend("pallas")),
+            "attend_atom_blocks": named("attend_atom_blocks",
+                                        atom_attend("pallas")),
+            **({} if parent is None else {
+                "attend_atom_keys_parent": named(
+                    "attend_atom_keys_parent",
+                    atom_attend("pallas", parent, True))}),
             "attend_rows_pages": named("attend_rows_pages",
                                        rows_attend("pallas")),
             "attend_rows_dense": named("attend_rows_dense", rows_dense)}
